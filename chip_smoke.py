@@ -1,0 +1,399 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the default device path still
+starts on the chip.
+
+    python chip_smoke.py            # on a machine with a TPU; one process
+
+Drives, once, what a user gets with no ``--mca`` overrides, through the
+entry points a user calls: ``ompi_tpu.init()`` -> the single-process
+device world -> ``COMM_WORLD.*_array`` collectives owned by ``coll/xla``;
+then the flagship train step at ``OTPU_MODEL_SCALE=64`` (the repo's
+widest configuration) with the flash block kernel a TPU selects; then
+the Pallas kernels that path selects, standalone against their XLA
+twins.  Every result is checked against numpy / the jnp twin.
+
+It is a smoke, not a benchmark: it reports seconds per phase (first call,
+which compiles, apart from later calls) and no rate.  Any exception ends
+the run non-zero with its traceback; a whole-run watchdog turns a hang
+into a failure with stacks.  It needs a TPU: on any other platform it
+exits non-zero before doing any work.  It starts no child that needs the
+chip (a chip belongs to one process).  The last line of stdout is
+``{"ok": true, "device": {...}}``.
+"""
+import contextlib
+import faulthandler
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+WATCHDOG_S = 1100           # the contract allows 1200 s, compile included
+MODEL_SCALE = 64            # bench.py's TPU scale: d 512, head dim 256
+PRIMARY_BYTES = 16 << 20    # bench.PRIMARY: float32 allreduce per rank
+SPOT_BYTES = 4 << 20
+FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bench.py's kernel row, bf16
+# three steps, as many as fall on every mesh: at this scale the toy's
+# fixed-rate SGD on a summed loss diverges at the 4th step of the
+# sp=2,tp=2 mesh — on the CPU exactly as on the chip (PERF.md Findings)
+TRAIN_STEPS = 3
+MOSAIC_CALL = "tpu_custom_call"         # Mosaic's custom-call target
+
+
+class Clock:
+    """Seconds per phase, the first (compiling) call of each program
+    kept apart from the calls after it."""
+
+    def __init__(self) -> None:
+        self.phase: dict = {}
+        self.cold = 0.0
+        self.steady = 0.0
+        self.steady_calls = 0
+
+    @contextlib.contextmanager
+    def timed(self, name: str):
+        t0 = time.perf_counter()
+        yield
+        self.phase[name] = round(time.perf_counter() - t0, 2)
+
+    def call(self, fn, *args, first: bool):
+        """``fn(*args)`` closed by ``block_until_ready``, booked as a
+        cold or a steady call."""
+        import jax
+
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        dt = time.perf_counter() - t0
+        if first:
+            self.cold += dt
+        else:
+            self.steady += dt
+            self.steady_calls += 1
+        return out
+
+
+class CompileCounters:
+    """What JAX itself reports about compilation: seconds in the
+    backend compiler and persistent-cache hits / writes."""
+
+    def __init__(self) -> None:
+        import jax.monitoring as mon
+
+        self.compile_s = 0.0
+        self.requests = self.hits = self.writes = 0
+        mon.register_event_listener(self._event)
+        mon.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.writes += 1     # recorded where an entry is written
+
+    def _duration(self, event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compile_s += secs
+
+
+def _require(cond, msg: str) -> None:
+    if not cond:
+        raise RuntimeError(f"chip_smoke: {msg}")
+
+
+def _on_platform(arr, platform: str, what: str) -> None:
+    found = sorted({d.platform for d in arr.devices()})
+    _require(found == [platform],
+             f"{what} lives on {found}, expected only {platform!r}")
+
+
+def _has_mosaic(compiled) -> bool:
+    return MOSAIC_CALL in compiled.as_text()
+
+
+# -- 1. identify -----------------------------------------------------------
+def describe(devs, cache_dir: str) -> None:
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+
+    from ompi_tpu import native
+    from ompi_tpu.base import hwloc
+
+    print(f"platform {devs[0].platform}  device_kind "
+          f"{devs[0].device_kind!r}  count {len(devs)}")
+    print("coords " + " ".join(
+        f"{t.index}:{t.coords}/{t.core_on_chip}"
+        for t in hwloc.device_topology(devs)))
+    print(f"jax {jax.__version__}  jaxlib {jaxlib.__version__}  "
+          f"libtpu {md.version('libtpu')}")
+    entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    print(f"compile cache {cache_dir} ({entries} entries at start)")
+    # a fact, not a gate: which lane the host paths are on
+    print(f"native.available() {native.available()}", flush=True)
+
+
+# -- 2. boot through the normal entry --------------------------------------
+def boot(devs):
+    import ompi_tpu
+    from ompi_tpu.mca.coll.xla import XlaCollModule
+
+    world = ompi_tpu.init()
+    _require(world.rte.is_device_world,
+             f"init() booted {type(world.rte).__name__}, not the "
+             "single-process device world")
+    _require(world.size == len(devs),
+             f"world.size {world.size} != {len(devs)} devices")
+    owner = world.c_coll["allreduce_array"].__self__
+    _require(isinstance(owner, XlaCollModule),
+             f"allreduce_array is owned by {type(owner).__name__}, "
+             "not XlaCollModule")
+    print(f"world.size {world.size}  allreduce_array owner "
+          f"{type(owner).__name__}", flush=True)
+    return world
+
+
+# -- 3. collectives through COMM_WORLD -------------------------------------
+def collectives(world, clock: Clock, platform: str = "tpu",
+                primary_bytes: int = PRIMARY_BYTES,
+                spot_bytes: int = SPOT_BYTES) -> None:
+    import ompi_tpu
+
+    n = world.size
+    xla = world.c_coll["allreduce_array"].__self__
+    rng = np.random.default_rng(0)
+
+    def run(name, fn, host, want, rtol=1e-5, atol=1e-5):
+        x = xla.make_world_array(host)
+        _on_platform(x, platform, f"{name} input")
+        for first in (True, False):
+            out = clock.call(fn, x, first=first)
+            _on_platform(out, platform, f"{name} result")
+            np.testing.assert_allclose(np.asarray(out), want, rtol=rtol,
+                                       atol=atol, err_msg=name)
+        print(f"  {name} {host.nbytes // n} B/rank ok", flush=True)
+
+    host = rng.standard_normal((n, primary_bytes // 4)).astype(np.float32)
+    run("allreduce_array", world.allreduce_array, host, host.sum(0))
+
+    spot = spot_bytes // 4
+    host = rng.standard_normal((n, spot)).astype(np.float32)
+    run("bcast_array", lambda x: world.bcast_array(x, root=n - 1), host,
+        np.broadcast_to(host[n - 1], host.shape), rtol=0, atol=0)
+    run("allgather_array", world.allgather_array, host, host, rtol=0,
+        atol=0)
+    blk = max(1, spot // n)
+    host2 = rng.standard_normal((n, n, blk)).astype(np.float32)
+    run("reduce_scatter_array", world.reduce_scatter_array, host2,
+        host2.sum(0))
+    run("alltoall_array", world.alltoall_array, host2,
+        np.swapaxes(host2, 0, 1), rtol=0, atol=0)
+    # an op with no native collective: gather + whatever fold mca/op
+    # selects on these devices (op/pallas_vpu outranks op/xla on a TPU)
+    from ompi_tpu.api import op as op_mod
+
+    stack = op_mod.jax_stack_reduce(ompi_tpu.PROD, np.dtype("float32"))
+    print(f"  mca/op stack fold for PROD: "
+          f"{getattr(stack, 'func', stack).__module__}", flush=True)
+    hostp = rng.uniform(0.5, 1.5, (n, 1 << 18)).astype(np.float32)
+    run("allreduce_array[PROD]",
+        lambda x: world.allreduce_array(x, ompi_tpu.PROD), hostp,
+        hostp.prod(0))
+
+    # one persistent handle (MPI_Allreduce_init analog), called twice
+    x = xla.make_world_array(host)
+    handle = world.allreduce_array_init(x)
+    for _ in range(2):
+        out = clock.call(handle, x, first=False)
+        _on_platform(out, platform, "persistent allreduce result")
+        np.testing.assert_allclose(np.asarray(out), host.sum(0),
+                                   rtol=1e-5, atol=1e-5)
+    print("  allreduce_array_init handle x2 ok", flush=True)
+
+
+# -- 4. the flagship trainer -----------------------------------------------
+def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
+            expect_mosaic: bool = True) -> None:
+    import jax
+
+    import __graft_entry__
+    from ompi_tpu.base.var import registry
+    from ompi_tpu.parallel import train  # noqa: F401  (registers the var)
+    from ompi_tpu.parallel.dryrun import make_step_and_args
+    from ompi_tpu.parallel.mesh import MeshSpec
+
+    configs = [("float32", None), ("bfloat16", None)]
+    if len(devs) == 4:
+        # the pipeline-active mesh run_training_step adds on four chips
+        configs.append(("float32", MeshSpec(dp=1, pp=2, sp=1, tp=2)))
+    dtype_var = registry.lookup("otpu_parallel_compute_dtype")
+    old_dtype = dtype_var.value
+    old_scale = os.environ.get("OTPU_MODEL_SCALE")
+    os.environ["OTPU_MODEL_SCALE"] = str(scale)
+    try:
+        for dtype, spec in configs:
+            dtype_var.set(dtype)
+            step, (params, xd), mspec = make_step_and_args(devs, spec)
+            t0 = time.perf_counter()
+            compiled = step.lower(params, xd).compile()
+            clock.cold += time.perf_counter() - t0
+            # a silent drop to the jnp attention branch must not pass
+            _require(_has_mosaic(compiled) == expect_mosaic,
+                     f"train step {mspec.sizes()} {dtype}: compiled HLO "
+                     f"{'lacks' if expect_mosaic else 'has'} the Mosaic "
+                     f"custom call ({MOSAIC_CALL})")
+            losses = []
+            for _ in range(TRAIN_STEPS):
+                params, loss = clock.call(compiled, params, xd,
+                                          first=False)
+                losses.append(float(loss))
+            _require(all(np.isfinite(losses)),
+                     f"non-finite loss in {losses}")
+            _require(all(b < a for a, b in zip(losses, losses[1:])),
+                     f"loss not falling at every step: {losses}")
+            print(f"  train mesh={mspec.sizes()} {dtype} scale {scale} "
+                  f"mosaic={expect_mosaic} losses "
+                  + " -> ".join(f"{v:.4f}" for v in losses), flush=True)
+        # the driver's own entry, jitted the way the driver jits it
+        dtype_var.set(old_dtype)
+        fn, args = __graft_entry__.entry()
+        _, loss = clock.call(jax.jit(fn), *args, first=True)
+        _require(np.isfinite(float(loss)), "entry() loss not finite")
+        print(f"  __graft_entry__.entry() jitted: loss {float(loss):.4f}",
+              flush=True)
+    finally:
+        dtype_var.set(old_dtype)
+        if old_scale is None:
+            os.environ.pop("OTPU_MODEL_SCALE", None)
+        else:
+            os.environ["OTPU_MODEL_SCALE"] = old_scale
+
+
+# -- 5. the kernels that path selects, against their XLA twins -------------
+def kernels(clock: Clock, expect_interpret: bool = False,
+            flash_shape=FLASH_SHAPE, dtype: str = "bfloat16",
+            reduce_elems: int = 1 << 20) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ompi_tpu.base.jaxenv import pallas_interpret
+    from ompi_tpu.ops import flash_attention as fa
+    from ompi_tpu.ops import pallas_reduce as pr
+
+    # interpret is left to resolve by itself, here and in every call
+    _require(pallas_interpret() == expect_interpret,
+             f"pallas interpret resolved to {pallas_interpret()}, "
+             f"expected {expect_interpret}")
+
+    def compile_checked(name, jitted, *args):
+        t0 = time.perf_counter()
+        compiled = jitted.lower(*args).compile()
+        clock.cold += time.perf_counter() - t0
+        _require(_has_mosaic(compiled) != expect_interpret,
+                 f"{name}: compiled HLO {'has' if expect_interpret else 'lacks'}"
+                 f" the Mosaic custom call ({MOSAIC_CALL})")
+        return compiled
+
+    b, h, sq, skv, d = flash_shape
+    dt = jnp.dtype(dtype)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, (b, h, sq, d), dt)
+    k = jax.random.normal(kk, (b, h, skv, d), dt)
+    v = jax.random.normal(kv, (b, h, skv, d), dt)
+    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
+    num0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    den0 = jnp.zeros((b, h, sq), jnp.float32)
+    bias = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(skv)[None, :],
+                     0.0, -jnp.inf).astype(jnp.float32)
+    # the twin, in float32 at full matmul precision, is the reference;
+    # the band is the input dtype's (the kernel rounds p to it for p@v)
+    tol = 2e-2 if dt == jnp.bfloat16 else 1e-4
+    twin = jax.jit(fa._update_jnp)
+
+    def check(name, kernel, args):
+        up = tuple(a.astype(jnp.float32) for a in args)
+        with jax.default_matmul_precision("highest"):
+            want = jax.block_until_ready(twin(*up))
+        compiled = compile_checked(name, kernel, *args)
+        for _ in range(2):
+            got = clock.call(compiled, *args, first=False)
+        for part, g, w in zip(("m", "num", "den"), got, want):
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            _require(g.shape == w.shape and np.all(np.isfinite(g)),
+                     f"{name}.{part}: shape {g.shape} / non-finite")
+            err = float(np.max(np.abs(g - w)) / max(1.0, np.max(np.abs(w))))
+            _require(err <= tol, f"{name}.{part}: error {err:.3e} of "
+                                 f"max|ref| exceeds {tol:g}")
+        print(f"  {name} {flash_shape} {dtype} matches its jnp twin",
+              flush=True)
+
+    check("flash_block_update",
+          jax.jit(lambda *a: fa.flash_block_update(*a)),
+          (q, k, v, m0, num0, den0))
+    check("flash_block_update_biased",
+          jax.jit(lambda *a: fa.flash_block_update_biased(*a)),
+          (q, k, v, m0, num0, den0, bias))
+
+    a = jax.random.normal(kq, (reduce_elems,), jnp.float32)
+    bb = jax.random.normal(kk, (reduce_elems,), jnp.float32)
+    stack = jax.random.normal(kv, (8, reduce_elems // 8), jnp.float32)
+    compile_checked("combine2", pr.combine2, "SUM", a, bb)
+    compile_checked("reduce_stack", pr.reduce_stack, "MAX", stack)
+    for first in (True, False):
+        got = clock.call(pr.combine2, "SUM", a, bb, first=first)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(a + bb))
+    for first in (True, False):
+        got = clock.call(pr.reduce_stack, "MAX", stack, first=first)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.max(stack, axis=0)))
+    got = clock.call(pr.reduce_stack, "SUM", stack, first=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(jnp.sum(stack, axis=0)),
+                               rtol=1e-5, atol=1e-5)
+    print(f"  pallas_reduce combine2 / reduce_stack ({reduce_elems} "
+          "elems) match jnp", flush=True)
+
+
+def main() -> int:
+    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True)
+    t_start = time.perf_counter()
+    clock = Clock()
+    with clock.timed("identify"):
+        from ompi_tpu.base.jaxenv import compile_cache_dir, require_tpu
+
+        devs = require_tpu("chip_smoke")    # or exit, before any work
+        counters = CompileCounters()
+        cache_dir = compile_cache_dir()     # before the first compile
+        describe(devs, cache_dir)
+    with clock.timed("boot"):
+        world = boot(devs)
+    with clock.timed("collectives"):
+        collectives(world, clock)
+    with clock.timed("trainer"):
+        trainer(devs, clock)
+    with clock.timed("kernels"):
+        kernels(clock)
+    with clock.timed("finalize"):
+        import ompi_tpu
+
+        ompi_tpu.finalize()
+    faulthandler.cancel_dump_traceback_later()
+    print("summary: "
+          + " | ".join(f"{k} {v}s" for k, v in clock.phase.items())
+          + f" | total {time.perf_counter() - t_start:.1f}s"
+          + f" | first calls (compile) {clock.cold:.1f}s"
+          + f" | {clock.steady_calls} steady calls {clock.steady:.2f}s"
+          + f" | backend compile {counters.compile_s:.1f}s over "
+            f"{counters.requests} cacheable compiles: "
+            f"{counters.hits} cache hits, {counters.writes} written")
+    print(json.dumps({"ok": True, "device": {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(devs)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -5,20 +5,15 @@ over sp, Megatron-style tp matmuls, MoE alltoall dispatch, GPipe
 microbatching over pp — with every cross-device exchange an explicit
 mesh collective (the framework's device-side coll path).
 
-Run on any device set:
-  python examples/train_sharded.py            # real chip(s)
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      OTPU_DEMO_CPU=1 python examples/train_sharded.py   # 8-dev CPU mesh
+Run on whatever devices jax finds:
+  python examples/train_sharded.py            # the machine's chip(s)
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python examples/train_sharded.py        # 8-dev CPU mesh
 """
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-if os.environ.get("OTPU_DEMO_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import jax  # noqa: E402
 

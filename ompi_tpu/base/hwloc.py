@@ -103,9 +103,6 @@ def host_topology(refresh: bool = False) -> HostTopology:
 def device_topology(devices=None) -> list:
     """Describe the jax device list (ICI coords on real TPU)."""
     if devices is None:
-        from ompi_tpu.base.jaxenv import apply_platform_env
-
-        apply_platform_env()
         import jax
 
         devices = jax.devices()

@@ -195,17 +195,13 @@ class Instance:
             raise RuntimeError(
                 "OTPU_DEVICE_WORLD is set but no jax coordinator address "
                 "was published (launch with tpurun --device-world)")
-        from ompi_tpu.base.jaxenv import apply_platform_env
-
-        apply_platform_env()
         import jax
 
+        from ompi_tpu.base.jaxenv import compile_cache_dir
+
+        compile_cache_dir()   # before this world's first compile
         if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass  # older jaxlib without gloo: initialize still works
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         procs = list(getattr(rte, "job_ranks", range(rte.world_size)))
         from jax._src import distributed as _jd
 

@@ -353,7 +353,7 @@ class XlaHierarchicalColl:
     def allreduce(self, x):
         """Hierarchical psum of the world rows of ``x`` (replicated out)."""
         import jax
-        from ompi_tpu.base.jaxenv import shard_map
+        from jax import shard_map
 
         x = self.make_world_array(x) if not hasattr(x, "sharding") else x
         key = ("hier_allreduce", x.shape, x.dtype)
@@ -380,7 +380,7 @@ class XlaHierarchicalColl:
     def reduce_scatter(self, x):
         """World (n, n, *S) → reduced block per device, two-level."""
         import jax
-        from ompi_tpu.base.jaxenv import shard_map
+        from jax import shard_map
 
         x = self.make_world_array(x) if not hasattr(x, "sharding") else x
         key = ("hier_reduce_scatter", x.shape, x.dtype)

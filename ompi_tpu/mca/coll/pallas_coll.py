@@ -400,8 +400,9 @@ class PallasCollComponent(Component):
             return False
         if v in ("1", "true", "yes"):
             return True
-        return not all(
-            getattr(d, "platform", "") == "tpu" for d in devices)
+        from ompi_tpu.base.jaxenv import pallas_interpret
+
+        return pallas_interpret(devices)
 
     def comm_query(self, comm):
         rte = comm.rte

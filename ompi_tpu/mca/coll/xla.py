@@ -190,7 +190,7 @@ class XlaCollModule:
         # axes checker cannot infer; correctness is covered by tests/test_coll.
         import jax
 
-        from ompi_tpu.base.jaxenv import shard_map
+        from jax import shard_map
 
         return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=check_vma))
@@ -761,7 +761,7 @@ class XlaMpCollModule:
         by_proc: dict = {}
         for d in rte.global_devices:
             by_proc.setdefault(d.process_index, []).append(d)
-        rows = [by_proc[p] for p in procs]   # KeyError -> not selectable
+        rows = [by_proc[p] for p in procs]
         width = min(len(r) for r in rows)
         if width < 1 or any(len(r) != width for r in rows):
             raise MpiError(ErrorClass.ERR_UNSUPPORTED_OPERATION,
@@ -789,7 +789,7 @@ class XlaMpCollModule:
     def _shard_map(self, fn, in_specs, out_specs):
         import jax
 
-        from ompi_tpu.base.jaxenv import shard_map
+        from jax import shard_map
 
         return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
@@ -946,11 +946,10 @@ class XlaCollComponent(Component):
                 return None
             if comm.is_inter:
                 return None
-            try:
-                module = XlaMpCollModule(comm, rte, self._axis.value)
-            except Exception:
-                return None
-            return self._prio.value, module
+            # a booted device world that cannot build its module must
+            # say so, not silently lose its device collectives
+            return self._prio.value, XlaMpCollModule(
+                comm, rte, self._axis.value)
         try:
             devices = [rte.device_of(r) for r in comm.group.world_ranks]
         except Exception:

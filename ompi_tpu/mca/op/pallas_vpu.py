@@ -2,15 +2,14 @@
 
 Reference: ``ompi/mca/op/avx/op_avx_component.c`` registers with a high
 priority and per-type flag checks against the host CPU's capabilities;
-here the capability check is the jax backend (TPU: compiled Mosaic
+here the capability check is the devices' platform (TPU: compiled Mosaic
 kernels; elsewhere the kernels still work via the Pallas interpreter but
 plain XLA is just as good, so priority drops below op/xla off-TPU).
 """
 from __future__ import annotations
 
-import jax
-
 from ompi_tpu.base import mca
+from ompi_tpu.base.jaxenv import pallas_interpret
 from ompi_tpu.ops import pallas_reduce
 
 
@@ -25,7 +24,7 @@ class PallasVpuComponent(mca.Component):
 
     def open(self) -> bool:
         self.priority = int(self._prio_var.value)
-        if jax.default_backend() != "tpu":
+        if pallas_interpret():
             # interpreter mode works but wins nothing; defer to op/xla
             self.priority = min(self.priority, 5)
         return True

@@ -3,8 +3,9 @@
 Lazy ctypes binding over ``otpu_native.cc`` (datatype pack/unpack element
 loops + the btl/sm SPSC ring).  The library is compiled on first use with
 the in-image g++ into a per-source-hash cache path; if the toolchain or
-compile is unavailable every caller silently stays on its numpy fallback —
-``available()`` reports which world you are in.
+compile is unavailable every caller stays on its numpy fallback (the
+compiler's error is printed once) — ``available()`` reports which world
+you are in.
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 from typing import Optional
@@ -61,7 +63,13 @@ def _load() -> Optional[ctypes.CDLL]:
                     check=True, capture_output=True, timeout=120)
                 os.replace(tmp, so)
             lib = ctypes.CDLL(so)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as exc:
+            # callers stay on their numpy lanes, but say why — once,
+            # _tried is set: a silent miss hides a broken toolchain
+            cc_err = getattr(exc, "stderr", None) or b""
+            print("ompi_tpu.native: build/load failed, numpy fallback "
+                  f"lanes in use: {exc}\n{cc_err.decode(errors='replace')}",
+                  file=sys.stderr)
             return None
         lib.otpu_pack_elems.restype = ctypes.c_int64
         lib.otpu_pack_elems.argtypes = [

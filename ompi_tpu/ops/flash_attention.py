@@ -26,13 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ompi_tpu.base.jaxenv import pallas_interpret
+
 Q_TILE = 256
-
-
-def _interpret() -> bool:
-    from ompi_tpu.base.jaxenv import pallas_interpret_default
-
-    return pallas_interpret_default()
 
 
 def _block_kernel(scale, biased, *refs):
@@ -88,24 +84,28 @@ def _update_jnp(q, k_blk, v_blk, m, num, den, bias=None):
     return new_m, new_num, new_den
 
 
-@jax.custom_vjp
-def flash_block_update(q, k_blk, v_blk, m, num, den):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def flash_block_update(q, k_blk, v_blk, m, num, den, interpret=None):
     """One online-softmax accumulation step against a K/V block.
 
     q: (b, h, sq, d); k_blk/v_blk: (b, h, skv, d); m/den: (b, h, sq);
     num: (b, h, sq, d).  Returns updated (m, num, den).  Forward runs the
     fused Pallas kernel; reverse-mode recomputes through the jnp block
     math (the Pallas custom-VJP pattern — kernels have no autodiff rule).
+    ``interpret``: None resolves from the process's default devices; a
+    caller tracing for other devices (a mesh) passes their mode.
     """
-    return _update_pallas(q, k_blk, v_blk, m, num, den)
+    return _update_pallas(q, k_blk, v_blk, m, num, den,
+                          interpret=interpret)
 
 
-def _flash_fwd(q, k_blk, v_blk, m, num, den):
-    return (_update_pallas(q, k_blk, v_blk, m, num, den),
+def _flash_fwd(q, k_blk, v_blk, m, num, den, interpret):
+    return (_update_pallas(q, k_blk, v_blk, m, num, den,
+                           interpret=interpret),
             (q, k_blk, v_blk, m, num, den))
 
 
-def _flash_bwd(res, ct):
+def _flash_bwd(interpret, res, ct):
     _, vjp = jax.vjp(_update_jnp, *res)
     return vjp(ct)
 
@@ -113,16 +113,19 @@ def _flash_bwd(res, ct):
 flash_block_update.defvjp(_flash_fwd, _flash_bwd)
 
 
-@jax.custom_vjp
-def flash_block_update_biased(q, k_blk, v_blk, m, num, den, bias):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def flash_block_update_biased(q, k_blk, v_blk, m, num, den, bias,
+                              interpret=None):
     """Block update with an additive score bias (sq, skv): -inf masks
     (causal ring attention, padding), finite shifts (ALiBi).  Same
     fused Pallas forward; reverse recomputes through the jnp twin."""
-    return _update_pallas(q, k_blk, v_blk, m, num, den, bias=bias)
+    return _update_pallas(q, k_blk, v_blk, m, num, den, bias=bias,
+                          interpret=interpret)
 
 
-def _flash_biased_fwd(q, k_blk, v_blk, m, num, den, bias):
-    return (_update_pallas(q, k_blk, v_blk, m, num, den, bias=bias),
+def _flash_biased_fwd(q, k_blk, v_blk, m, num, den, bias, interpret):
+    return (_update_pallas(q, k_blk, v_blk, m, num, den, bias=bias,
+                           interpret=interpret),
             (q, k_blk, v_blk, m, num, den, bias))
 
 
@@ -136,7 +139,10 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
                    interpret=None):
     # ``interpret`` is part of the jit cache key: an explicit False (the
     # AOT Mosaic gate) can never be served a cached interpreter trace,
-    # and vice versa.  None = resolve from the backend at trace time.
+    # and vice versa.  None = the process's default devices, at trace
+    # time.
+    if interpret is None:
+        interpret = pallas_interpret()
     b, h, sq, d = q.shape
     skv = k_blk.shape[2]
     scale = 1.0 / math.sqrt(d)
@@ -170,17 +176,20 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
         in_specs.insert(0, pl.BlockSpec((tq, skv), lambda i, j: (j, 0)))
         operands.insert(0, bias.astype(jnp.float32))
 
+    # inside shard_map(check_vma=True) — the train step — every output
+    # must say which mesh axes it varies over: those of its operands
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
     mo, numo, deno = pl.pallas_call(
         functools.partial(_block_kernel, scale, biased),
         out_shape=(
-            jax.ShapeDtypeStruct(mf.shape, jnp.float32),
-            jax.ShapeDtypeStruct(nf.shape, nf.dtype),
-            jax.ShapeDtypeStruct(df.shape, jnp.float32),
+            jax.ShapeDtypeStruct(mf.shape, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(nf.shape, nf.dtype, vma=vma),
+            jax.ShapeDtypeStruct(df.shape, jnp.float32, vma=vma),
         ),
         grid=grid,
         in_specs=in_specs,
         out_specs=(s_spec, q_spec, s_spec),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(*operands)
 
     return (mo[..., 0].reshape(b, h, sq).astype(m.dtype),

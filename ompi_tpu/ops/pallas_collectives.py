@@ -54,8 +54,21 @@ same way ``ompi_op``'s function table parameterizes the reference's ring
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import numpy as np
+
+from ompi_tpu.base.jaxenv import pallas_interpret
+
+
+def _interpret_for(mesh, interpret: Optional[bool]) -> bool:
+    """The public wrappers' ``interpret=None``: the mode of the devices
+    the call is built for (``mesh``), never a fixed default — a caller
+    on a TPU that omits the flag must not run the interpreter."""
+    if interpret is None:
+        return pallas_interpret(mesh.devices.flat)
+    return bool(interpret)
+
 
 def _op_fn(jnp, op: str):
     """Elementwise fold for a ring-kernel reduction op name."""
@@ -1387,7 +1400,7 @@ def _build_bcast(n: int, axis: str, nseg: int, srows: int,
 def _jit_right_permute(mesh, axis: str, payload_shape, dtype_str: str,
                        interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1397,9 +1410,10 @@ def _jit_right_permute(mesh, axis: str, payload_shape, dtype_str: str,
                              out_specs=P(axis), check_vma=False))
 
 
-def right_permute(x, mesh, axis: str, interpret: bool = True):
+def right_permute(x, mesh, axis: str, interpret: Optional[bool] = None):
     """Rotate the leading (rank) axis by +1 via neighbor remote DMA —
     the PP activation-handoff primitive (``lax.ppermute`` twin)."""
+    interpret = _interpret_for(mesh, interpret)
     if mesh.shape[axis] == 1:
         return x
     return _jit_right_permute(mesh, axis, tuple(x.shape[1:]),
@@ -1410,7 +1424,7 @@ def right_permute(x, mesh, axis: str, interpret: bool = True):
 def _jit_all_gather(mesh, axis: str, blk_shape, dtype_str: str,
                     interpret: bool, variant: str = "ring"):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1425,13 +1439,14 @@ def _jit_all_gather(mesh, axis: str, blk_shape, dtype_str: str,
                              out_specs=P(), check_vma=False))
 
 
-def all_gather(x, mesh, axis: str, interpret: bool = True,
+def all_gather(x, mesh, axis: str, interpret: Optional[bool] = None,
                variant: str = "ring"):
     """(n, *S) sharded -> (n, *S) replicated via the DMA ring.
 
     ``variant="bidi"`` runs the bidirectional schedule (both ICI
     directions per step, ceil((n-1)/2) steps); n<=2 degenerates to the
     plain ring (one remote block — nothing to pair)."""
+    interpret = _interpret_for(mesh, interpret)
     n = mesh.shape[axis]
     if n == 1:
         return x
@@ -1512,7 +1527,7 @@ def _jit_reduce_scatter(mesh, axis: str, payload_shape, dtype_str: str,
                         op: str, interpret: bool, variant: str,
                         seg_elems):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1550,12 +1565,13 @@ def _jit_reduce_scatter(mesh, axis: str, payload_shape, dtype_str: str,
 
 
 def reduce_scatter(x, mesh, axis: str, op: str = "sum",
-                   interpret: bool = True, variant: str = "fused",
+                   interpret: Optional[bool] = None, variant: str = "fused",
                    seg_elems: int | None = None):
     """(n, n, *S) sharded on the leading rank axis -> (n, *S) sharded:
     rank i receives the reduction of everyone's block i via the DMA
     ring.  ``variant='seg'`` uses the HBM-resident segmented kernel
     (window of ``seg_elems``) for payloads too large for VMEM."""
+    interpret = _interpret_for(mesh, interpret)
     payload_shape = tuple(x.shape[2:])
     if mesh.shape[axis] == 1:
         return x.reshape((1,) + payload_shape)
@@ -1563,7 +1579,8 @@ def reduce_scatter(x, mesh, axis: str, op: str = "sum",
                                op, interpret, variant, seg_elems)(x)
 
 
-def reduce_scatter_sum(x, mesh, axis: str, interpret: bool = True):
+def reduce_scatter_sum(x, mesh, axis: str,
+                       interpret: Optional[bool] = None):
     return reduce_scatter(x, mesh, axis, "sum", interpret)
 
 
@@ -1571,7 +1588,7 @@ def reduce_scatter_sum(x, mesh, axis: str, interpret: bool = True):
 def _jit_all_reduce(mesh, axis: str, payload_shape, dtype_str: str,
                     op: str, interpret: bool, variant: str, seg_elems):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1624,7 +1641,7 @@ def _jit_all_reduce(mesh, axis: str, payload_shape, dtype_str: str,
 
 
 def all_reduce(x, mesh, axis: str, op: str = "sum",
-               interpret: bool = True, variant: str = "fused",
+               interpret: Optional[bool] = None, variant: str = "fused",
                seg_elems: int | None = None):
     """(n, *S) sharded -> (*S) replicated reduction via a ring kernel.
 
@@ -1647,6 +1664,7 @@ def all_reduce(x, mesh, axis: str, op: str = "sum",
       unbounded under cancellation) — the opt-in gradient-compression
       trade; f32 payloads only.
     """
+    interpret = _interpret_for(mesh, interpret)
     payload_shape = tuple(x.shape[1:])
     if mesh.shape[axis] == 1:
         return x.reshape(payload_shape)
@@ -1654,7 +1672,8 @@ def all_reduce(x, mesh, axis: str, op: str = "sum",
                            interpret, variant, seg_elems)(x)
 
 
-def all_reduce_sum(x, mesh, axis: str, interpret: bool = True):
+def all_reduce_sum(x, mesh, axis: str,
+                   interpret: Optional[bool] = None):
     return all_reduce(x, mesh, axis, "sum", interpret)
 
 
@@ -1662,7 +1681,7 @@ def all_reduce_sum(x, mesh, axis: str, interpret: bool = True):
 def _jit_all_to_all(mesh, axis: str, blk_shape, dtype_str: str,
                     interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1675,10 +1694,11 @@ def _jit_all_to_all(mesh, axis: str, blk_shape, dtype_str: str,
                              out_specs=P(axis), check_vma=False))
 
 
-def all_to_all(x, mesh, axis: str, interpret: bool = True):
+def all_to_all(x, mesh, axis: str, interpret: Optional[bool] = None):
     """(n, n, *S) sharded on the leading rank axis: rank i's block j
     moves to rank j's slot i (``x[i, j] -> out[j, i]``, the coll/xla
     ``alltoall_array`` convention) via direct per-peer remote DMA."""
+    interpret = _interpret_for(mesh, interpret)
     n = mesh.shape[axis]
     if x.ndim < 2 or x.shape[0] != n or x.shape[1] != n:
         # the kernel indexes n blocks per rank: anything else would be
@@ -1696,7 +1716,7 @@ def all_to_all(x, mesh, axis: str, interpret: bool = True):
 def _jit_all_gather_v(mesh, axis: str, max_rows: int, width: int,
                       chunk: int, dtype_str: str, interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1711,7 +1731,7 @@ def _jit_all_gather_v(mesh, axis: str, max_rows: int, width: int,
 
 
 def all_gather_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Ragged all-gather (true allgatherv): ``x`` is (n, R, W) sharded
     on the leading rank axis — rank i's block carries ``counts[i]``
     valid rows (≤ R) — and every rank receives (n, R, W) with
@@ -1719,6 +1739,7 @@ def all_gather_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
     one compile serves every raggedness.  Wire bytes per block are
     ceil(count/chunk_rows)*chunk_rows rows where the padded all_gather
     always moves R.  W must be a multiple of 128 lanes."""
+    interpret = _interpret_for(mesh, interpret)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]
@@ -1760,7 +1781,7 @@ def all_gather_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
 def _jit_all_to_all_v(mesh, axis: str, max_rows: int, width: int,
                       chunk: int, dtype_str: str, interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -1775,7 +1796,7 @@ def _jit_all_to_all_v(mesh, axis: str, max_rows: int, width: int,
 
 
 def all_to_all_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Ragged all-to-all (true alltoallv): ``x`` is (n, n, R, W)
     sharded on the leading rank axis — rank i's block j carries
     ``counts[i, j]`` valid rows (≤ R) for rank j — and rank j receives
@@ -1789,6 +1810,7 @@ def all_to_all_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
     ≤1.2x the ideal ragged byte count for real dispatch sizes, where
     the padded ``all_to_all`` moves the full R regardless.  W must be
     a multiple of 128 lanes (MoE hidden dims are)."""
+    interpret = _interpret_for(mesh, interpret)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]
@@ -1833,7 +1855,7 @@ def all_to_all_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
 def _jit_all_reduce_torus(mesh, axes, payload_shape, dtype_str: str,
                           op: str, interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     a0, a1 = axes
@@ -1878,7 +1900,7 @@ def _jit_all_reduce_torus(mesh, axes, payload_shape, dtype_str: str,
 
 
 def all_reduce_torus(x, mesh, axes=("x", "y"), op: str = "sum",
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """(n0, n1, *S) sharded over both torus axes -> (*S) replicated
     reduction: reduce-scatter rings along ``axes[0]``, all-reduce rings
     along ``axes[1]`` on the scattered blocks, all-gather rings along
@@ -1888,6 +1910,7 @@ def all_reduce_torus(x, mesh, axes=("x", "y"), op: str = "sum",
     2D schedule the reference reaches for with coll/han's hierarchical
     composition (``coll_han``), expressed as three explicit-DMA phases.
     """
+    interpret = _interpret_for(mesh, interpret)
     axes = tuple(axes)
     payload_shape = tuple(x.shape[2:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -1920,7 +1943,7 @@ def _torus_flat_mesh(mesh, a0, a1):
 def _jit_reduce_scatter_torus(mesh, axes, payload_shape, dtype_str: str,
                               op: str, interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     a0, a1 = axes
@@ -1959,13 +1982,14 @@ def _jit_reduce_scatter_torus(mesh, axes, payload_shape, dtype_str: str,
 
 
 def reduce_scatter_torus(x, mesh, axes=("x", "y"), op: str = "sum",
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """(N, N, *S) sharded -> (N, *S) sharded over the torus, N=n0*n1:
     two scatter-reduce phases (columns then rows), each ring walking
     physical ICI neighbors of its own torus dimension — the decomposed
     form of ``all_reduce_torus``'s first phase, for callers that want
     the scattered result (TP gradient buckets, han-style hierarchies).
     """
+    interpret = _interpret_for(mesh, interpret)
     axes = tuple(axes)
     payload_shape = tuple(x.shape[2:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -1983,7 +2007,7 @@ def reduce_scatter_torus(x, mesh, axes=("x", "y"), op: str = "sum",
 def _jit_all_gather_torus(mesh, axes, blk_shape, dtype_str: str,
                           interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     a0, a1 = axes
@@ -2013,10 +2037,12 @@ def _jit_all_gather_torus(mesh, axes, blk_shape, dtype_str: str,
                              out_specs=P(), check_vma=False))
 
 
-def all_gather_torus(x, mesh, axes=("x", "y"), interpret: bool = True):
+def all_gather_torus(x, mesh, axes=("x", "y"),
+                     interpret: Optional[bool] = None):
     """(N, *S) sharded over the torus -> (N, *S) replicated: row rings
     then column rings, each on its own ICI dimension — (n1-1) + (n0-1)
     steps instead of the 1-D ring's N-1."""
+    interpret = _interpret_for(mesh, interpret)
     axes = tuple(axes)
     blk_shape = tuple(x.shape[1:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -2032,7 +2058,7 @@ def all_gather_torus(x, mesh, axes=("x", "y"), interpret: bool = True):
 def _jit_bcast(mesh, axis: str, payload_shape, dtype_str: str,
                interpret: bool, seg_elems: int):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -2053,11 +2079,12 @@ def _jit_bcast(mesh, axis: str, payload_shape, dtype_str: str,
                              out_specs=P(axis), check_vma=False))
 
 
-def bcast(x, mesh, axis: str, root: int = 0, interpret: bool = True,
+def bcast(x, mesh, axis: str, root: int = 0, interpret: Optional[bool] = None,
           seg_elems: int = 65536):
     """(n, *S) sharded -> (n, *S) with every row equal to root's row,
     via the pipelined segmented ring (time ≈ (S + n - 2) segment-hops).
     ``root`` is a runtime operand — every root shares one compile."""
+    interpret = _interpret_for(mesh, interpret)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]

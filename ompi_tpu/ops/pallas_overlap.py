@@ -28,10 +28,12 @@ serially; on hardware the DMA/compute overlap is real.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import numpy as np
 
-from ompi_tpu.ops.pallas_collectives import _ag_phase, _mods, _ring_kernels
+from ompi_tpu.ops.pallas_collectives import (_ag_phase, _interpret_for,
+                                             _mods, _ring_kernels)
 
 
 def _prep_operands(a, b, mesh, axis):
@@ -162,7 +164,7 @@ def _jit_matmul_reduce_scatter(mesh, axis: str, m: int, k_loc: int,
                                n_out: int, dtype_str: str,
                                interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -184,12 +186,13 @@ def _jit_matmul_reduce_scatter(mesh, axis: str, m: int, k_loc: int,
 
 
 def matmul_reduce_scatter(a, b, mesh, axis: str,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Row-parallel fused GEMM: device i returns row-block i of
     Σ_j A_j @ B_j (global shape (n, M/n-padded, N) sharded on the mesh
     axis) — the reduce-scatter half of :func:`matmul_allreduce`, the
     Megatron-style TP output projection.  M is padded to a multiple of
     n; callers slice the tail block if M % n != 0."""
+    interpret = _interpret_for(mesh, interpret)
     a, b, n, m, k_loc, n_out, dtype = _prep_operands(a, b, mesh, axis)
     if n == 1:
         return (a[0] @ b[0])[None]
@@ -201,7 +204,7 @@ def matmul_reduce_scatter(a, b, mesh, axis: str,
 def _jit_matmul_allreduce(mesh, axis: str, m: int, k_loc: int,
                           n_out: int, dtype_str: str, interpret: bool):
     jax, jnp, lax, pl, pltpu = _mods()
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -223,7 +226,8 @@ def _jit_matmul_allreduce(mesh, axis: str, m: int, k_loc: int,
                              out_specs=P(), check_vma=False))
 
 
-def matmul_allreduce(a, b, mesh, axis: str, interpret: bool = True):
+def matmul_allreduce(a, b, mesh, axis: str,
+                     interpret: Optional[bool] = None):
     """Contraction-sharded matmul with fused ring reduction.
 
     ``a``: (n, M, K/n) — per-device A shards on the leading mesh axis;
@@ -231,6 +235,7 @@ def matmul_allreduce(a, b, mesh, axis: str, interpret: bool = True):
     replicated (M, N) product Σ_i A_i @ B_i, computed by the fused
     just-in-time-block ring (compute overlaps each step's DMA).
     """
+    interpret = _interpret_for(mesh, interpret)
     a, b, n, m, k_loc, n_out, dtype = _prep_operands(a, b, mesh, axis)
     if n == 1:
         return a[0] @ b[0]

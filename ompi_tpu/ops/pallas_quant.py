@@ -38,14 +38,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ompi_tpu.base.jaxenv import pallas_interpret
+
 LANES = 128          # one codec block = one lane row
 ROW_TILE = 256       # 256x128 f32 tile = 128 KiB per operand in VMEM
-
-
-def _interpret() -> bool:
-    from ompi_tpu.base.jaxenv import pallas_interpret_default
-
-    return pallas_interpret_default()
 
 
 def _pad_rows(flat, rows_mult: int):
@@ -90,7 +86,7 @@ def encode_int8(x, *, interpret=None):
         out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
                    jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
         grid=grid, in_specs=[spec], out_specs=(spec, spec),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(x2)
     return q, s[:, :1]
 
@@ -126,7 +122,7 @@ def dequant_accumulate(q, s, *, interpret=None):
         in_specs=[pl.BlockSpec((k, tile, LANES), lambda i: (0, i, 0)),
                   pl.BlockSpec((k, tile, LANES), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(q, sb)
     return out[:rows]
 
@@ -156,6 +152,6 @@ def decode_int8(q, s, *, interpret=None):
         _dec_kernel,
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.float32),
         grid=(rows_p // tile,), in_specs=[spec, spec], out_specs=spec,
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(q2, s2)
     return out[:rows].reshape(lead + (q.shape[-2], LANES))

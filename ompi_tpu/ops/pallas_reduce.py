@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ompi_tpu.base.jaxenv import pallas_interpret
+
 LANES = 128
 ROW_TILE = 512  # 512x128 f32 tile = 256 KiB per operand in VMEM
 
@@ -47,12 +49,6 @@ _BITWISE = ("BAND", "BOR", "BXOR")
 
 def supported_ops() -> tuple:
     return tuple(_FOLDS)
-
-
-def _interpret() -> bool:
-    from ompi_tpu.base.jaxenv import pallas_interpret_default
-
-    return pallas_interpret_default()
 
 
 def _supported_dtype(op_name: str, dtype) -> bool:
@@ -95,7 +91,7 @@ def combine2(op_name: str, a, b, *, interpret=None):
         functools.partial(_combine_kernel, fold),
         out_shape=jax.ShapeDtypeStruct(a2.shape, a2.dtype),
         grid=grid, in_specs=[spec, spec], out_specs=spec,
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(a2, b2)
     return out.ravel()[: a.size].reshape(a.shape)
 
@@ -130,7 +126,7 @@ def reduce_stack(op_name: str, x, *, interpret=None):
         grid=(rows_k // tile,),
         in_specs=[pl.BlockSpec((k, tile, LANES), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(xp)
     return out.ravel()[:per].reshape(x.shape[1:])
 

@@ -13,19 +13,15 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ompi_tpu.base.jaxenv import pallas_interpret
+
 
 def rmsnorm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _use_flash_default() -> bool:
-    import jax as _jax
-
-    return _jax.default_backend() == "tpu"
-
-
 def ring_attention(q, k, v, axis: str, n_shards: int, use_flash=None,
-                   causal: bool = False):
+                   causal: bool = False, interpret=None):
     """Flash-style ring attention over the sequence-parallel axis.
 
     q/k/v local: (b, h_local, s_local, hd).  K/V blocks rotate around the
@@ -46,12 +42,18 @@ def ring_attention(q, k, v, axis: str, n_shards: int, use_flash=None,
     is the hot op: on TPU it drops into the fused Pallas kernel
     (``ompi_tpu/ops/flash_attention.py``); the ring structure itself stays
     at the XLA level so the compiler schedules the ICI ppermute.
+
+    ``interpret`` is the Pallas mode of the devices this is traced for
+    (None: the process's default devices); the fused kernel is the
+    default exactly where it compiles through Mosaic.
     """
     hd = q.shape[-1]
     s_local = q.shape[-2]
     scale = 1.0 / math.sqrt(hd)
+    if interpret is None:
+        interpret = pallas_interpret()
     if use_flash is None:
-        use_flash = _use_flash_default()
+        use_flash = not interpret
     # derive the accumulator inits FROM q (0*q + const) so they inherit
     # q's varying-manifest axes: fresh jnp.zeros/full would be unvarying
     # and the scan carry would trip the vma checker under check_vma=True
@@ -80,10 +82,10 @@ def ring_attention(q, k, v, axis: str, n_shards: int, use_flash=None,
 
             if causal:
                 new_m, num, den = flash_block_update_biased(
-                    q, k_blk, v_blk, m, num, den, bias)
+                    q, k_blk, v_blk, m, num, den, bias, interpret)
             else:
                 new_m, num, den = flash_block_update(q, k_blk, v_blk, m,
-                                                     num, den)
+                                                     num, den, interpret)
         else:
             s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk) * scale
             if bias is not None:
@@ -145,7 +147,8 @@ def _full_attention(q, k, v, causal: bool = False):
 
 
 def attention_block(p, x, *, sp: int, tp: int, n_heads_local: int,
-                    sp_impl: str = "ring", causal: bool = False):
+                    sp_impl: str = "ring", causal: bool = False,
+                    interpret=None):
     """Sequence-parallel attention with tp-sharded heads; psum output proj.
 
     x local: (b, s_local, d) replicated over tp.  Head projections are
@@ -175,8 +178,8 @@ def attention_block(p, x, *, sp: int, tp: int, n_heads_local: int,
         o = ulysses_attention(q, k, v, "sp", sp,
                               causal=causal)        # (b, h_l, s_l, hd)
     else:
-        o = ring_attention(q, k, v, "sp", sp,
-                           causal=causal)           # (b, h_l, s_l, hd)
+        o = ring_attention(q, k, v, "sp", sp, causal=causal,
+                           interpret=interpret)     # (b, h_l, s_l, hd)
     o = o.transpose(0, 2, 1, 3).reshape(b, s_l, -1)  # (b, s_l, h_l*hd)
     o = o @ p["wo"]
     if tp > 1:
@@ -250,9 +253,10 @@ def moe_block(p, x, *, tp: int, n_experts: int, capacity: int):
 
 
 def transformer_block(p, x, *, sp, tp, n_heads_local, n_experts, capacity,
-                      sp_impl: str = "ring", causal: bool = False):
+                      sp_impl: str = "ring", causal: bool = False,
+                      interpret=None):
     x = attention_block(p, x, sp=sp, tp=tp, n_heads_local=n_heads_local,
-                        sp_impl=sp_impl, causal=causal)
+                        sp_impl=sp_impl, causal=causal, interpret=interpret)
     x = mlp_block(p, x, tp=tp)
     x = moe_block(p, x, tp=tp, n_experts=n_experts, capacity=capacity)
     return x

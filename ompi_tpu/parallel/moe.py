@@ -538,7 +538,7 @@ def build_moe_train_step(mesh, spec: MeshSpec, lr: float = 0.02):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
 
     dims = moe_model_dims(spec)
     ep = spec.ep
@@ -627,7 +627,7 @@ def run_moe_training_step(devices=None, spec: MeshSpec = None,
 
 
 def expert_ffn_fused(a, b, mesh, axis: str = EXPERT_AXIS,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Expert-sharded GEMM with its reduction epilogue through the
     coll/tuned DEVICE ladder cell (``ops/pallas_overlap``
     ``matmul_allreduce``) when the ladder admits it; otherwise the

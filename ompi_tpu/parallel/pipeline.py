@@ -33,11 +33,9 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *, pp: int,
     perm = [(i, (i + 1) % pp) for i in range(pp)]
     state = jnp.zeros_like(x_microbatches[0])
     outbuf = jnp.zeros_like(x_microbatches)
-    from ompi_tpu.base.jaxenv import pcast
-
-    state = pcast(state, vary_axes, to="varying")
-    outbuf = pcast(outbuf, vary_axes, to="varying")
-    x_microbatches = pcast(x_microbatches, vary_axes, to="varying")
+    state = jax.lax.pcast(state, vary_axes, to="varying")
+    outbuf = jax.lax.pcast(outbuf, vary_axes, to="varying")
+    x_microbatches = jax.lax.pcast(x_microbatches, vary_axes, to="varying")
 
     def body(carry, t):
         state, outbuf = carry

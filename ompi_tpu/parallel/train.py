@@ -146,10 +146,16 @@ def build_train_step(mesh, spec: MeshSpec, lr: float = 1e-4,
     """
     import jax
     import jax.numpy as jnp
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from ompi_tpu.base.jaxenv import pallas_interpret
+
     dims = model_dims(spec, layers)
+    # the attention kernel follows the MESH's devices, not the
+    # process's: an offline compile for a TPU topology takes the flash
+    # path a chip would
+    interpret = pallas_interpret(mesh.devices.flat)
     tp, sp_n, pp = spec.tp, spec.sp, spec.pp
     M, mb, s_l, d = dims["M"], dims["mb"], dims["s_local"], dims["d"]
     sp_impl = str(_sp_impl_var.value)
@@ -169,7 +175,7 @@ def build_train_step(mesh, spec: MeshSpec, lr: float = 1e-4,
             layer, x_mb, sp=sp_n, tp=tp,
             n_heads_local=dims["h_local"],
             n_experts=dims["n_experts"], capacity=dims["capacity"],
-            sp_impl=sp_impl, causal=causal)
+            sp_impl=sp_impl, causal=causal, interpret=interpret)
         return out
 
     if bool(_remat_var.value):

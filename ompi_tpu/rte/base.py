@@ -71,15 +71,13 @@ class DeviceWorldRte(Rte):
     is_device_world = True
 
     def __init__(self, devices=None, axis_name: str = "world") -> None:
-        from ompi_tpu.base.jaxenv import apply_platform_env
-
-        apply_platform_env()
         import jax
 
+        from ompi_tpu.base.jaxenv import compile_cache_dir
+
+        compile_cache_dir()   # before this world's first compile
         if devices is None:
             devices = jax.devices()
-            if len(devices) == 1 and devices[0].platform != "cpu":
-                pass  # single real chip: world of 1 device-rank
         self.devices = list(devices)
         self.axis_name = axis_name
         self.world_size = len(self.devices)
@@ -114,34 +112,17 @@ class DeviceWorldRte(Rte):
         return 0
 
 
-class SingletonRte(Rte):
-    """Size-1 world with no devices (COMM_SELF-only / pure host usage)."""
-
-    def __init__(self) -> None:
-        self._kv: dict[tuple[int, str], Any] = {}
-
-    def modex_put(self, key: str, value: Any) -> None:
-        self._kv[(0, key)] = value
-
-    def modex_get(self, rank: int, key: str, wait: bool = True) -> Any:
-        return self._kv.get((rank, key))
-
-    def fence(self) -> None:
-        pass
-
-
 def detect() -> Rte:
     """Pick the RTE for this process (``ompi_rte_init`` equivalent).
 
     Launched under ``tpurun`` (OTPU_RANK/OTPU_NPROCS in env) → the
     multi-process ProcRte (``ompi_tpu.rte.proc``).  Otherwise the
-    device-world SPMD model over local jax devices.
+    device-world SPMD model over local jax devices — and a JAX that
+    cannot open them raises: a size-1 host world in their place would
+    quietly take every ``*_array`` call off the device path.
     """
     if "OTPU_RANK" in os.environ and "OTPU_NPROCS" in os.environ:
         from ompi_tpu.rte.proc import ProcRte
 
         return ProcRte()
-    try:
-        return DeviceWorldRte()
-    except Exception:
-        return SingletonRte()
+    return DeviceWorldRte()

@@ -128,7 +128,7 @@ class TestFlashAttention:
 
     def test_ring_attention_flash_matches_jnp(self):
         """Flash and jnp ring paths agree on the 8-device sp mesh."""
-        from ompi_tpu.base.jaxenv import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from ompi_tpu.parallel.model import ring_attention

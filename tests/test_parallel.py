@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from ompi_tpu.base.jaxenv import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ompi_tpu.parallel.mesh import MeshSpec, default_axis_sizes, make_mesh
@@ -92,7 +92,7 @@ def test_ulysses_matches_ring_and_full():
     """Ulysses (all-to-all SP) == ring attention == unsharded reference."""
     import jax
     import jax.numpy as jnp
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ompi_tpu.parallel.model import (_full_attention, ring_attention,
@@ -189,7 +189,7 @@ def test_causal_ring_and_ulysses_match_masked_reference():
     steps (shard-offset block bias), not local ones."""
     import jax
     import jax.numpy as jnp
-    from ompi_tpu.base.jaxenv import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ompi_tpu.parallel.model import (_full_attention, ring_attention,
