@@ -2499,11 +2499,16 @@ def _ladder_probe(b: "DeviceBench", interp: bool, sizes,
 def _pallas_aot_gate(here: str) -> dict:
     """Pre-gate: AOT-compile every coll/pallas kernel for a real TPU
     topology (no hardware needed — libtpu's Mosaic compiler runs
-    offline).  Runs in a CPU-pinned subprocess BEFORE this process
-    touches jax: the compile-only child must not contend for the chip
-    the parent is about to hold.  Writes PALLAS_AOT.json; a kernel
-    failing here would fail on a live pod, so the device sweep
-    shouldn't bother until this is green."""
+    offline).  A kernel failing here would fail on a live pod, so the
+    device sweep shouldn't bother until this is green.
+
+    Runs in a CPU-pinned subprocess BEFORE this process touches jax:
+    once the parent holds the chip, libtpu refuses the compile-only
+    child too ("Internal error when accessing libtpu multi-process
+    lockfile", seen on the v5e).  So the gate cannot wait for
+    ``require_tpu``: without a TPU, pod-smoke still rewrites
+    PALLAS_AOT.json — an offline compile record, true of any machine —
+    before it exits non-zero."""
     import importlib.util
 
     if importlib.util.find_spec("libtpu") is None:

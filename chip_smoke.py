@@ -34,10 +34,9 @@ MODEL_SCALE = 64            # bench.py's TPU scale: d 512, head dim 256
 PRIMARY_BYTES = 16 << 20    # bench.PRIMARY: float32 allreduce per rank
 SPOT_BYTES = 4 << 20
 FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bench.py's kernel row, bf16
-# three steps, as many as fall on every mesh: at this scale the toy's
-# fixed-rate SGD on a summed loss diverges at the 4th step of the
-# sp=2,tp=2 mesh — on the CPU exactly as on the chip (PERF.md Findings)
-TRAIN_STEPS = 3
+# each loss is taken BEFORE its update: four losses observe three
+# updates, and every one of them must have lowered the loss
+TRAIN_STEPS = 4
 MOSAIC_CALL = "tpu_custom_call"         # Mosaic's custom-call target
 
 
@@ -256,13 +255,13 @@ def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
                      f"loss not falling at every step: {losses}")
             print(f"  train mesh={mspec.sizes()} {dtype} scale {scale} "
                   f"mosaic={expect_mosaic} losses "
-                  + " -> ".join(f"{v:.4f}" for v in losses), flush=True)
+                  + " -> ".join(f"{v:.6f}" for v in losses), flush=True)
         # the driver's own entry, jitted the way the driver jits it
         dtype_var.set(old_dtype)
         fn, args = __graft_entry__.entry()
         _, loss = clock.call(jax.jit(fn), *args, first=True)
         _require(np.isfinite(float(loss)), "entry() loss not finite")
-        print(f"  __graft_entry__.entry() jitted: loss {float(loss):.4f}",
+        print(f"  __graft_entry__.entry() jitted: loss {float(loss):.6f}",
               flush=True)
     finally:
         dtype_var.set(old_dtype)

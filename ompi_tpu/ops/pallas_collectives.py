@@ -61,13 +61,9 @@ import numpy as np
 from ompi_tpu.base.jaxenv import pallas_interpret
 
 
-def _interpret_for(mesh, interpret: Optional[bool]) -> bool:
-    """The public wrappers' ``interpret=None``: the mode of the devices
-    the call is built for (``mesh``), never a fixed default — a caller
-    on a TPU that omits the flag must not run the interpreter."""
-    if interpret is None:
-        return pallas_interpret(mesh.devices.flat)
-    return bool(interpret)
+# ``interpret=None`` on every public wrapper below resolves from the
+# devices the call is built for (``mesh``), never a fixed default: a
+# caller on a TPU that omits the flag must not run the interpreter.
 
 
 def _op_fn(jnp, op: str):
@@ -1413,7 +1409,8 @@ def _jit_right_permute(mesh, axis: str, payload_shape, dtype_str: str,
 def right_permute(x, mesh, axis: str, interpret: Optional[bool] = None):
     """Rotate the leading (rank) axis by +1 via neighbor remote DMA —
     the PP activation-handoff primitive (``lax.ppermute`` twin)."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     if mesh.shape[axis] == 1:
         return x
     return _jit_right_permute(mesh, axis, tuple(x.shape[1:]),
@@ -1446,7 +1443,8 @@ def all_gather(x, mesh, axis: str, interpret: Optional[bool] = None,
     ``variant="bidi"`` runs the bidirectional schedule (both ICI
     directions per step, ceil((n-1)/2) steps); n<=2 degenerates to the
     plain ring (one remote block — nothing to pair)."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     n = mesh.shape[axis]
     if n == 1:
         return x
@@ -1571,7 +1569,8 @@ def reduce_scatter(x, mesh, axis: str, op: str = "sum",
     rank i receives the reduction of everyone's block i via the DMA
     ring.  ``variant='seg'`` uses the HBM-resident segmented kernel
     (window of ``seg_elems``) for payloads too large for VMEM."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     payload_shape = tuple(x.shape[2:])
     if mesh.shape[axis] == 1:
         return x.reshape((1,) + payload_shape)
@@ -1664,7 +1663,8 @@ def all_reduce(x, mesh, axis: str, op: str = "sum",
       unbounded under cancellation) — the opt-in gradient-compression
       trade; f32 payloads only.
     """
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     payload_shape = tuple(x.shape[1:])
     if mesh.shape[axis] == 1:
         return x.reshape(payload_shape)
@@ -1698,7 +1698,8 @@ def all_to_all(x, mesh, axis: str, interpret: Optional[bool] = None):
     """(n, n, *S) sharded on the leading rank axis: rank i's block j
     moves to rank j's slot i (``x[i, j] -> out[j, i]``, the coll/xla
     ``alltoall_array`` convention) via direct per-peer remote DMA."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     n = mesh.shape[axis]
     if x.ndim < 2 or x.shape[0] != n or x.shape[1] != n:
         # the kernel indexes n blocks per rank: anything else would be
@@ -1739,7 +1740,8 @@ def all_gather_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
     one compile serves every raggedness.  Wire bytes per block are
     ceil(count/chunk_rows)*chunk_rows rows where the padded all_gather
     always moves R.  W must be a multiple of 128 lanes."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]
@@ -1810,7 +1812,8 @@ def all_to_all_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
     ≤1.2x the ideal ragged byte count for real dispatch sizes, where
     the padded ``all_to_all`` moves the full R regardless.  W must be
     a multiple of 128 lanes (MoE hidden dims are)."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]
@@ -1910,7 +1913,8 @@ def all_reduce_torus(x, mesh, axes=("x", "y"), op: str = "sum",
     2D schedule the reference reaches for with coll/han's hierarchical
     composition (``coll_han``), expressed as three explicit-DMA phases.
     """
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     axes = tuple(axes)
     payload_shape = tuple(x.shape[2:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -1989,7 +1993,8 @@ def reduce_scatter_torus(x, mesh, axes=("x", "y"), op: str = "sum",
     form of ``all_reduce_torus``'s first phase, for callers that want
     the scattered result (TP gradient buckets, han-style hierarchies).
     """
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     axes = tuple(axes)
     payload_shape = tuple(x.shape[2:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -2042,7 +2047,8 @@ def all_gather_torus(x, mesh, axes=("x", "y"),
     """(N, *S) sharded over the torus -> (N, *S) replicated: row rings
     then column rings, each on its own ICI dimension — (n1-1) + (n0-1)
     steps instead of the 1-D ring's N-1."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     axes = tuple(axes)
     blk_shape = tuple(x.shape[1:])
     n0, n1 = mesh.shape[axes[0]], mesh.shape[axes[1]]
@@ -2084,7 +2090,8 @@ def bcast(x, mesh, axis: str, root: int = 0, interpret: Optional[bool] = None,
     """(n, *S) sharded -> (n, *S) with every row equal to root's row,
     via the pipelined segmented ring (time ≈ (S + n - 2) segment-hops).
     ``root`` is a runtime operand — every root shares one compile."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     jax, jnp, lax, pl, pltpu = _mods()
 
     n = mesh.shape[axis]

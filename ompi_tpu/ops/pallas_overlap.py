@@ -32,8 +32,8 @@ from typing import Optional
 
 import numpy as np
 
-from ompi_tpu.ops.pallas_collectives import (_ag_phase, _interpret_for,
-                                             _mods, _ring_kernels)
+from ompi_tpu.base.jaxenv import pallas_interpret
+from ompi_tpu.ops.pallas_collectives import _ag_phase, _mods, _ring_kernels
 
 
 def _prep_operands(a, b, mesh, axis):
@@ -192,7 +192,8 @@ def matmul_reduce_scatter(a, b, mesh, axis: str,
     axis) — the reduce-scatter half of :func:`matmul_allreduce`, the
     Megatron-style TP output projection.  M is padded to a multiple of
     n; callers slice the tail block if M % n != 0."""
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     a, b, n, m, k_loc, n_out, dtype = _prep_operands(a, b, mesh, axis)
     if n == 1:
         return (a[0] @ b[0])[None]
@@ -235,7 +236,8 @@ def matmul_allreduce(a, b, mesh, axis: str,
     replicated (M, N) product Σ_i A_i @ B_i, computed by the fused
     just-in-time-block ring (compute overlaps each step's DMA).
     """
-    interpret = _interpret_for(mesh, interpret)
+    if interpret is None:
+        interpret = pallas_interpret(mesh.devices.flat)
     a, b, n, m, k_loc, n_out, dtype = _prep_operands(a, b, mesh, axis)
     if n == 1:
         return a[0] @ b[0]

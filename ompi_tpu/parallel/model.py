@@ -147,9 +147,13 @@ def _full_attention(q, k, v, causal: bool = False):
 
 
 def attention_block(p, x, *, sp: int, tp: int, n_heads_local: int,
-                    sp_impl: str = "ring", causal: bool = False,
-                    interpret=None):
+                    interpret: bool, sp_impl: str = "ring",
+                    causal: bool = False):
     """Sequence-parallel attention with tp-sharded heads; psum output proj.
+
+    ``interpret`` is the Pallas mode of the mesh this is traced for,
+    resolved once by the caller (``build_train_step``): it decides both
+    whether ring attention takes the fused kernel and how that compiles.
 
     x local: (b, s_local, d) replicated over tp.  Head projections are
     column-sharded over tp (h_local = H/tp); the output projection is
@@ -253,8 +257,8 @@ def moe_block(p, x, *, tp: int, n_experts: int, capacity: int):
 
 
 def transformer_block(p, x, *, sp, tp, n_heads_local, n_experts, capacity,
-                      sp_impl: str = "ring", causal: bool = False,
-                      interpret=None):
+                      interpret: bool, sp_impl: str = "ring",
+                      causal: bool = False):
     x = attention_block(p, x, sp=sp, tp=tp, n_heads_local=n_heads_local,
                         sp_impl=sp_impl, causal=causal, interpret=interpret)
     x = mlp_block(p, x, tp=tp)

@@ -88,6 +88,54 @@ def test_train_step_descends(n):
     assert float(l2) < float(l1)
 
 
+@pytest.mark.parametrize("spec_text", ["dp=2,pp=1,sp=2,tp=2",
+                                       "dp=1,pp=2,sp=2,tp=2"])
+def test_update_is_one_gradient_step(spec_text):
+    """The applied update is lr times THE gradient on every mesh: to
+    first order one step lowers the loss by |delta|^2 / lr.  Taking the
+    gradient w.r.t. the replicated params (whose transpose already
+    psums over dp and sp) and then psum-ing it again took a
+    dp*sp-times larger step — ratio 1/(dp*sp) here — and the widest
+    meshes diverged within five steps."""
+    from ompi_tpu.parallel.dryrun import parse_spec
+
+    lr = 0.1
+    spec = parse_spec(spec_text)
+    mesh, spec = make_mesh(
+        jax.devices()[:spec.dp * spec.pp * spec.sp * spec.tp], spec)
+    dims = model_dims(spec)
+    step, place = build_train_step(mesh, spec, lr=lr)
+    x = np.random.RandomState(2).normal(
+        0, 1, (dims["batch"], dims["seq"], dims["d"]))
+    p0 = init_params(spec)
+    params, xd = place(p0, x)
+    p1, l0 = step(params, xd)
+    _, l1 = step(p1, xd)
+    delta2 = sum(float(np.sum((np.asarray(p1[k], np.float64) - p0[k]) ** 2))
+                 for k in p0)
+    ratio = (float(l1) - float(l0)) / (-delta2 / lr)
+    assert 0.9 < ratio < 1.1, ratio
+
+
+def test_widest_config_descends_on_the_four_device_mesh(monkeypatch):
+    """The case the first four-chip run failed: at the widest
+    configuration (``OTPU_MODEL_SCALE=64``) on the default four-device
+    mesh (sp=2, tp=2) the summed loss at a fixed lr tripled at the
+    fourth step and reached inf at the sixth — chip and CPU alike.
+    The mean loss keeps the effective step from growing with batch,
+    sequence and width, which all grow with the mesh and the scale."""
+    from ompi_tpu.parallel.dryrun import make_step_and_args
+
+    monkeypatch.setenv("OTPU_MODEL_SCALE", "64")
+    step, (params, xd), spec = make_step_and_args(jax.devices()[:4])
+    assert spec.sizes() == {"dp": 1, "pp": 1, "sp": 2, "tp": 2}
+    losses = []
+    for _ in range(6):
+        params, loss = step(params, xd)
+        losses.append(float(loss))
+    assert all(b < a for a, b in zip(losses, losses[1:])), losses
+
+
 def test_ulysses_matches_ring_and_full():
     """Ulysses (all-to-all SP) == ring attention == unsharded reference."""
     import jax
