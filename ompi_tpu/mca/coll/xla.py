@@ -24,6 +24,7 @@ bound compiled program directly — the MPI-4 persistent-collective
 from __future__ import annotations
 
 import threading
+import time
 
 import numpy as np
 
@@ -42,19 +43,50 @@ def _ar_key(x, op):
     return ("allreduce", op.name, x.shape, x.dtype)
 
 
-def _traced_dispatch(fn, coll: str, nbytes: int):
-    """Wrap a compiled program so its XLA *dispatch* (the async launch,
-    not device completion — the stream is the progress engine) appears as
-    a ``device`` span.  Only installed while tracing is enabled, so the
-    steady-state cache hit stays probe + SPC bump + dispatch."""
-    def dispatch(*a):
-        t0 = trace.now()
+def _program_name(coll: str, variant=None) -> str:
+    """The ``__name__`` a collective's program is jitted under, from the
+    cache key's first fields: ``otpu_allreduce_sum``, ``otpu_bcast_sa``,
+    ``otpu_alltoall``.  The profiler shows it as ``PjitFunction(<name>)``
+    on the host and ``jit_<name>`` on the device."""
+    if variant is None:
+        return f"otpu_{coll}"
+    return f"otpu_{coll}_{getattr(variant, 'name', variant).lower()}"
+
+
+def _spanned(name: str, coll: str, x, fn, *args):
+    """``fn(*args)``, inside an ``otpu.coll.<name>`` span while a profiler
+    session is open.  For the paths that are not hot: the span carries
+    the collective and the buffer's shape and dtype (the program-cache
+    key's variable part), which is what an operator needs to see when a
+    step recompiles inside their own ``jax.profiler.trace(...)``."""
+    if trace.profiler_on():
+        with trace.profiler_span("otpu.coll." + name, coll=coll,
+                                 shape=str(tuple(x.shape)),
+                                 dtype=str(x.dtype)):
+            return fn(*args)
+    return fn(*args)
+
+
+def _first_call(fn, coll: str, x):
+    """One-shot wrapper around the FIRST call of a newly built program —
+    trace, lower, compile or cache load, first dispatch — counted into
+    ``device_program_first_call_us`` and, under a profiler session, an
+    ``otpu.coll.first_call`` span.  The cache holds ``fn`` itself."""
+    def first(arg):
+        t0 = time.perf_counter()
         try:
-            return fn(*a)
+            return _spanned("first_call", coll, x, fn, arg)
         finally:
-            trace.span(f"xla_{coll}", "device", t0,
-                       args={"nbytes": int(nbytes)})
-    return dispatch
+            spc.record("device_program_first_call_us",
+                       (time.perf_counter() - t0) * 1e6)
+    return first
+
+
+def _freed(coll: str):
+    def fn(_x):
+        raise MpiError(ErrorClass.ERR_REQUEST,
+                       f"persistent {coll} handle was freed")
+    return fn
 
 
 class PersistentColl:
@@ -63,34 +95,35 @@ class PersistentColl:
     ``__call__`` runs it eagerly; ``start`` returns a request completing
     with the result (device dispatch is already asynchronous, so the
     request is born complete — the XLA stream is the progress engine).
+    The handle bypasses ``c_coll``, so it writes its own
+    ``otpu.coll.<coll>_init`` span while a profiler session is open.
     """
 
-    __slots__ = ("fn", "coll", "_nbytes", "_bump")
+    __slots__ = ("fn", "coll", "_nbytes", "_bump", "_profiling", "_span")
 
     def __init__(self, fn, coll: str, nbytes: int) -> None:
         self.fn = fn
         self.coll = coll
         self._nbytes = nbytes
         self._bump = spc.bump_device   # pre-bound: ~sub-µs steady state
+        trace.bind_profiler()          # fn is a jitted program: jax is in
+        self._profiling = trace.profiler_on
+        self._span = f"otpu.coll.{coll}_init"
 
     def __call__(self, x):
         self._bump(self._nbytes)
-        if trace.enabled:
-            return _traced_dispatch(self.fn, self.coll, self._nbytes)(x)
+        if self._profiling():
+            with trace.profiler_span(self._span):
+                return self.fn(x)
         return self.fn(x)
 
     def start(self, x):
-        spc.bump_device(self._nbytes)
         r = CompletedRequest()
-        if trace.enabled:
-            r.result = _traced_dispatch(self.fn, self.coll,
-                                        self._nbytes)(x)
-        else:
-            r.result = self.fn(x)
+        r.result = self(x)
         return r
 
     def free(self) -> None:
-        self.fn = None
+        self.fn = _freed(self.coll)
 
 
 class XlaCollModule:
@@ -110,6 +143,7 @@ class XlaCollModule:
         self._sharded = NamedSharding(self.mesh, P(axis_name))
         self._replicated = NamedSharding(self.mesh, P())
         self._jax_array = jax.Array   # fast isinstance gate for _fast
+        trace.bind_profiler()
 
     # -- helpers ---------------------------------------------------------
     def _check(self, comm, x, inner_n: bool = False):
@@ -156,44 +190,56 @@ class XlaCollModule:
         if entry is None:
             return None
         spc.bump_device(entry[1])
-        if trace.enabled:
-            return _traced_dispatch(entry[0], key[0], entry[1])
         return entry[0]
 
     def _get(self, comm, key, x, builder, inner_n: bool = False):
-        """One-probe fast path; build+validate under the lock on miss.
+        """Every call that left ``_fast``: probe again; build+validate
+        under the lock on miss.  Counted (``device_slow_path``) and,
+        under a profiler session, an ``otpu.coll.get`` span.
 
         Host (numpy) inputs always go through _check for explicit sharded
         placement — a warm cache must not hand a raw host array to the
         compiled program."""
+        spc.record("device_slow_path")
+        return _spanned("get", key[0], x, self._lookup,
+                        comm, key, x, builder, inner_n)
+
+    def _lookup(self, comm, key, x, builder, inner_n: bool):
         checked = isinstance(x, np.ndarray)
         if checked:
             x = self._check(comm, x, inner_n)
         entry = self._cache.get(key)
+        first = None
         if entry is None:
             if not checked:
                 x = self._check(comm, x, inner_n)
             with self._lock:
                 entry = self._cache.get(key)
                 if entry is None:
-                    entry = (builder(), x.nbytes)
+                    spc.record("device_program_builds")
+                    entry = (_spanned("build", key[0], x, builder),
+                             x.nbytes)
                     self._cache[key] = entry
+                    first = _first_call(entry[0], key[0], x)
         fn, nbytes = entry
         spc.bump_device(nbytes)
-        if trace.enabled:
-            return _traced_dispatch(fn, key[0], nbytes), x
-        return fn, x
+        return first or fn, x
 
-    def _shard_map(self, fn, in_specs, out_specs, check_vma: bool = False):
+    def _shard_map(self, fn, in_specs, out_specs, check_vma: bool = False,
+                   *, name: str):
         # check_vma off by default: several collective results (all_gather,
         # gather+fold) are replicated in ways jax 0.9's static varying-mesh-
         # axes checker cannot infer; correctness is covered by tests/test_coll.
+        # ``name`` is what the profiler shows: PjitFunction(<name>) on the
+        # host line, jit_<name> on the device's XLA Modules line.
         import jax
 
         from jax import shard_map
 
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma))
+        mapped = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=check_vma)
+        mapped.__name__ = mapped.__qualname__ = name
+        return jax.jit(mapped)
 
     def _reduce_in_shard(self, op: op_mod.Op):
         """Per-shard reduction body: native collective or gather+fold."""
@@ -244,7 +290,7 @@ class XlaCollModule:
             comm, self._keyfor("allreduce", x, op), x,
             lambda: self._shard_map(
                 lambda t: self._reduce_in_shard(op)(t[0]),
-                P(self.axis), P()))
+                P(self.axis), P(), name=_program_name("allreduce", op)))
         return fn(x)
 
     def _quant_allreduce(self, comm, x, op: op_mod.Op, codec: str):
@@ -275,7 +321,9 @@ class XlaCollModule:
         # pick() already required a real dtype, so x carries shape/dtype
         fn, x = self._get(
             comm, ("allreduce_quant", codec, op.name, x.shape, x.dtype),
-            x, lambda: self._shard_map(body, P(self.axis), P()))
+            x, lambda: self._shard_map(
+                body, P(self.axis), P(),
+                name=_program_name("allreduce_quant", codec)))
         return fn(x)
 
     def reduce_array(self, comm, x, op: op_mod.Op = op_mod.SUM,
@@ -318,7 +366,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("reduce", x, op, root), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("reduce", op)))
         return fn(x)
 
     def bcast_array(self, comm, x, root: int = 0):
@@ -378,7 +427,10 @@ class XlaCollModule:
                 else body_tree)
         fn, x = self._get(
             comm, self._keyfor("bcast", x, root), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(
+                body, P(self.axis), P(self.axis),
+                name=_program_name(
+                    "bcast", "sa" if body is body_sa else "tree")))
         return fn(x)
 
     def allgather_array(self, comm, x):
@@ -402,7 +454,7 @@ class XlaCollModule:
             comm, self._keyfor("allgather", x), x,
             lambda: self._shard_map(
                 lambda t: jax.lax.all_gather(t[0], self.axis),
-                P(self.axis), P()))
+                P(self.axis), P(), name=_program_name("allgather")))
         return fn(x)
 
     def _quant_allgather(self, comm, x, codec: str):
@@ -432,7 +484,9 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, ("allgather_quant", codec, x.shape, x.dtype), x,
-            lambda: self._shard_map(body, P(self.axis), P()))
+            lambda: self._shard_map(
+                body, P(self.axis), P(),
+                name=_program_name("allgather_quant", codec)))
         return fn(x)
 
     def allgatherv_array(self, comm, x, counts):
@@ -496,7 +550,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("gather", x, root), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("gather")))
         return fn(x)
 
     def reduce_scatter_array(self, comm, x, op: op_mod.Op = op_mod.SUM):
@@ -525,7 +580,9 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("reduce_scatter", x, op), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)),
+            lambda: self._shard_map(
+                body, P(self.axis), P(self.axis),
+                name=_program_name("reduce_scatter", op)),
             inner_n=True)
         return fn(x)
 
@@ -549,7 +606,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("alltoall", x), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)),
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("alltoall")),
             inner_n=True)
         return fn(x)
 
@@ -569,7 +627,8 @@ class XlaCollModule:
             comm, self._keyfor("ppermute", x, perm), x,
             lambda: self._shard_map(
                 lambda t: jax.lax.ppermute(t, self.axis, perm),
-                P(self.axis), P(self.axis)))
+                P(self.axis), P(self.axis),
+                name=_program_name("ppermute")))
         return fn(x)
 
     def scatter_array(self, comm, x, root: int = 0):
@@ -620,7 +679,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("scatter", x, root), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)),
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("scatter")),
             inner_n=True)
         return fn(x)
 
@@ -641,7 +701,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("scan", x, op), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("scan", op)))
         return fn(x)
 
     def exscan_array(self, comm, x, op: op_mod.Op = op_mod.SUM):
@@ -662,7 +723,8 @@ class XlaCollModule:
 
         fn, x = self._get(
             comm, self._keyfor("exscan", x, op), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+                                    name=_program_name("exscan", op)))
         return fn(x)
 
     # -- persistent collectives (MPI_Allreduce_init analog) --------------
@@ -722,7 +784,8 @@ class XlaCollModule:
         fn, tok = self._get(
             comm, ("barrier",), tok,
             lambda: self._shard_map(
-                lambda t: jax.lax.psum(t, self.axis), P(self.axis), P()))
+                lambda t: jax.lax.psum(t, self.axis), P(self.axis), P(),
+                name=_program_name("barrier")))
         jax.block_until_ready(fn(tok))
 
     def barrier(self, comm) -> None:
@@ -786,13 +849,15 @@ class XlaMpCollModule:
         return jax.make_array_from_process_local_data(
             self._row_sharding, arr[None], (self.n,) + arr.shape)
 
-    def _shard_map(self, fn, in_specs, out_specs):
+    def _shard_map(self, fn, in_specs, out_specs, *, name: str):
         import jax
 
         from jax import shard_map
 
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False))
+        mapped = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+        mapped.__name__ = mapped.__qualname__ = name
+        return jax.jit(mapped)
 
     def _reduce_body(self, op: op_mod.Op):
         import jax
@@ -832,7 +897,7 @@ class XlaMpCollModule:
             ("allreduce", op.name, xg.shape, str(xg.dtype)),
             lambda: self._shard_map(
                 lambda t: self._reduce_body(op)(t[0]),
-                P(self.axis), P()))
+                P(self.axis), P(), name=_program_name("allreduce", op)))
         spc.bump_device(xg.nbytes)
         return fn(xg)
 
@@ -851,7 +916,8 @@ class XlaMpCollModule:
 
         fn = self._get(
             ("bcast", int(root), xg.shape, str(xg.dtype)),
-            lambda: self._shard_map(body, P(ax), P()))
+            lambda: self._shard_map(body, P(ax), P(),
+                                    name=_program_name("bcast", "psum")))
         spc.bump_device(xg.nbytes)
         return fn(xg)
 
@@ -864,7 +930,7 @@ class XlaMpCollModule:
             ("allgather", xg.shape, str(xg.dtype)),
             lambda: self._shard_map(
                 lambda t: jax.lax.all_gather(t[0], self.axis),
-                P(self.axis), P()))
+                P(self.axis), P(), name=_program_name("allgather")))
         spc.bump_device(xg.nbytes)
         return fn(xg)
 
@@ -898,7 +964,9 @@ class XlaMpCollModule:
 
         fn = self._get(
             ("reduce_scatter", op.name, xg.shape, str(xg.dtype)),
-            lambda: self._shard_map(body, P(self.axis), P(self.axis)))
+            lambda: self._shard_map(
+                body, P(self.axis), P(self.axis),
+                name=_program_name("reduce_scatter", op)))
         spc.bump_device(xg.nbytes)
         return fn(xg)
 

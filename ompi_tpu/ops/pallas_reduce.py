@@ -91,6 +91,7 @@ def combine2(op_name: str, a, b, *, interpret=None):
         functools.partial(_combine_kernel, fold),
         out_shape=jax.ShapeDtypeStruct(a2.shape, a2.dtype),
         grid=grid, in_specs=[spec, spec], out_specs=spec,
+        name="otpu_combine2",
         interpret=pallas_interpret() if interpret is None else interpret,
     )(a2, b2)
     return out.ravel()[: a.size].reshape(a.shape)
@@ -126,6 +127,7 @@ def reduce_stack(op_name: str, x, *, interpret=None):
         grid=(rows_k // tile,),
         in_specs=[pl.BlockSpec((k, tile, LANES), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((tile, LANES), lambda i: (i, 0)),
+        name="otpu_reduce_stack",
         interpret=pallas_interpret() if interpret is None else interpret,
     )(xp)
     return out.ravel()[:per].reshape(x.shape[1:])

@@ -14,6 +14,14 @@ _COUNTERS = (
     "rget_msgs", "striped_msgs",
     "part_pready", "part_parrived", "part_msgs", "part_bytes",
     "device_collectives", "device_bytes",
+    # coll/xla program cache, off the hot path (a cache hit in _fast
+    # bumps none of them): calls that left _fast for _get, programs
+    # built on a miss, and the host microseconds their first calls took
+    # (trace, lower, compile or cache load, first dispatch) — set-up
+    # runs before any profiler session, so counters cover what the
+    # otpu.coll.get/build/first_call spans cannot
+    "device_slow_path", "device_program_builds",
+    "device_program_first_call_us",
     # fastpath counters: the zero-copy host-datapath contract, pinned by
     # test_perf_guard (payload copies on the contiguous tcp send path
     # must stay 0; the schedule cache must hit on repeated collectives)

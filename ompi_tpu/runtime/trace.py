@@ -76,7 +76,6 @@ CATEGORIES = {
     "btl": "transport-layer wire operations (sendmsg, ring push)",
     "chaos": "injected-fault instants (ft/chaos)",
     "coll": "collective invocations (c_coll interposition)",
-    "device": "device-world dispatch (coll/xla)",
     "ft": "failure detection/propagation/agreement + elastic recovery",
     "io": "MPI-IO (ompio) operations",
     "osc": "one-sided epochs (fence/lock/PSCW/flush)",
@@ -536,14 +535,42 @@ _SIZED_COLLS = {
 }
 
 
+def _no_profiler() -> bool:
+    return False
+
+
+#: The device path's second switch: an open JAX profiler session.  The
+#: device slots write one ``otpu.coll.<slot>`` span per call into the
+#: profiler's own trace (``jax.profiler.trace`` / ``start_trace``), on
+#: the clock the device timeline is on, and nothing when no session is
+#: open.  Both stay jax-free until a device module — which imports jax
+#: anyway — calls :func:`bind_profiler`: the launcher imports this
+#: module with the base layer alone.
+profiler_on = _no_profiler      # TraceAnnotation.is_enabled once bound
+profiler_span = None            # jax.profiler.TraceAnnotation once bound
+
+
+def bind_profiler() -> None:
+    global profiler_on, profiler_span
+    if profiler_span is None:
+        from jax.profiler import TraceAnnotation
+
+        profiler_span = TraceAnnotation
+        profiler_on = TraceAnnotation.is_enabled
+
+
 def wrap_coll_table(comm) -> None:
     """coll/trace interposition: wrap every selected c_coll slot with a
     span + histogram recorder.  Installed unconditionally at comm_select
     (tracing can be switched on mid-run through MPI_T); the wrapper's
-    disabled path is one flag check, verified by test_perf_guard."""
+    disabled path is one flag check, verified by test_perf_guard — and,
+    on the device slots (``*_array``), one ``profiler_on()`` before it:
+    with a profiler session open the call runs inside
+    ``TraceAnnotation("otpu.coll.<slot>")``, which is never constructed
+    otherwise."""
 
     def make(name, fn):
-        def traced(comm_arg, *args, **kw):
+        def ring(comm_arg, *args, **kw):
             if not enabled:
                 return fn(comm_arg, *args, **kw)
             # .nbytes is an attribute on both numpy and jax arrays — no
@@ -565,6 +592,19 @@ def wrap_coll_table(comm) -> None:
                     eargs["cseq"] = cseq
                 span(name, "coll", t0, t1, args=eargs)
                 hist_record(name, int(nbytes), t1 - t0)
+
+        if not name.endswith("_array"):
+            traced = ring
+        else:
+            pname = "otpu.coll." + name     # built once per slot
+
+            def traced(comm_arg, *args, **kw):
+                if profiler_on():
+                    with profiler_span(pname):
+                        return ring(comm_arg, *args, **kw)
+                if not enabled:
+                    return fn(comm_arg, *args, **kw)
+                return ring(comm_arg, *args, **kw)
 
         # carry the inner slot's marker attributes (__sync_wrapped__,
         # __monitored__, ...) — interposition layers and tests probe the
