@@ -45,12 +45,23 @@ def _ar_key(x, op):
 
 def _program_name(coll: str, variant=None) -> str:
     """The ``__name__`` a collective's program is jitted under, from the
-    cache key's first fields: ``otpu_allreduce_sum``, ``otpu_bcast_sa``,
+    cache key's first fields: ``otpu_allreduce_sum``, ``otpu_bcast_psum``,
     ``otpu_alltoall``.  The profiler shows it as ``PjitFunction(<name>)``
     on the host and ``jit_<name>`` on the device."""
     if variant is None:
         return f"otpu_{coll}"
     return f"otpu_{coll}_{getattr(variant, 'name', variant).lower()}"
+
+
+def _root_only_psum(t, ax, root: int):
+    """Root's ``t`` on every rank of ``ax``: one all-reduce to which the
+    others contribute zeros (psum widens bool; every other dtype comes
+    back as it went)."""
+    import jax
+    import jax.numpy as jnp
+
+    contrib = jnp.where(jax.lax.axis_index(ax) == root, t, jnp.zeros_like(t))
+    return jax.lax.psum(contrib, ax).astype(t.dtype)
 
 
 def _spanned(name: str, coll: str, x, fn, *args):
@@ -377,11 +388,14 @@ class XlaCollModule:
         * small payloads — binomial ppermute tree, log2(n) rounds
           (XLA's CollectivePermute disallows one-to-many pairs, so the
           tree is explicit), latency-optimal;
-        * payloads ≥ ``bcast_sa_min_bytes`` — scatter+allgather:
-          root's buffer is masked into a psum_scatter (each link
-          carries S/n-sized shards, zeros fold in free) and an
-          all_gather restores it everywhere — two pipelined ring phases
-          moving ~2S/n per link instead of log2(n) serial full-S hops.
+        * payloads ≥ ``bcast_sa_min_bytes`` — one masked all-reduce over
+          the shard as it arrives (``otpu_bcast_psum``): every rank but
+          root contributes zeros, so XLA lowers one tuned all-reduce,
+          2(n-1)/n x S per chip's links, and nothing else.  The sum is
+          the dtype's own, so a float payload's ``-0.0`` arrives as
+          ``+0.0`` (on the TPU subnormals flush and NaN payloads are
+          canonical too, as in any float all-reduce); summing the bits
+          as integers is exact but costs a pass more (PERF.md, PR 25).
         """
         if isinstance(x, self._jax_array):
             fn = self._fast(self._keyfor("bcast", x, root))
@@ -409,28 +423,17 @@ class XlaCollModule:
                 k *= 2
             return cur
 
-        def body_sa(t):  # t: (1, *S)
-            me = jax.lax.axis_index(ax)
-            contrib = jnp.where(me == root, t[0], jnp.zeros_like(t[0]))
-            flat = contrib.reshape(-1)
-            size = flat.shape[0]
-            blk = -(-size // n)
-            if blk * n != size:
-                flat = jnp.pad(flat, (0, blk * n - size))
-            part = jax.lax.psum_scatter(flat.reshape(n, blk), ax,
-                                        scatter_dimension=0,
-                                        tiled=False)
-            full = jax.lax.all_gather(part, ax)        # (n, blk)
-            return full.reshape(-1)[:size].reshape(t.shape)
+        def body_psum(t):  # t: (1, *S)
+            return _root_only_psum(t, ax, root)
 
-        body = (body_sa if per_payload >= self.bcast_sa_min_bytes
+        body = (body_psum if per_payload >= self.bcast_sa_min_bytes
                 else body_tree)
         fn, x = self._get(
             comm, self._keyfor("bcast", x, root), x,
             lambda: self._shard_map(
                 body, P(self.axis), P(self.axis),
                 name=_program_name(
-                    "bcast", "sa" if body is body_sa else "tree")))
+                    "bcast", "psum" if body is body_psum else "tree")))
         return fn(x)
 
     def allgather_array(self, comm, x):
@@ -902,22 +905,14 @@ class XlaMpCollModule:
         return fn(xg)
 
     def bcast_array(self, comm, x, root: int = 0):
-        import jax
-        import jax.numpy as jnp
-
         xg = self.make_world_array(x)
         P = self._P
         ax = self.axis
-
-        def body(t):   # mask + psum: one ring phase, replicated result
-            contrib = jnp.where(jax.lax.axis_index(ax) == root,
-                                t[0], jnp.zeros_like(t[0]))
-            return jax.lax.psum(contrib, ax)
-
-        fn = self._get(
+        fn = self._get(   # one ring phase, replicated result
             ("bcast", int(root), xg.shape, str(xg.dtype)),
-            lambda: self._shard_map(body, P(ax), P(),
-                                    name=_program_name("bcast", "psum")))
+            lambda: self._shard_map(
+                lambda t: _root_only_psum(t[0], ax, root), P(ax), P(),
+                name=_program_name("bcast", "psum")))
         spc.bump_device(xg.nbytes)
         return fn(xg)
 
@@ -994,11 +989,11 @@ class XlaCollComponent(Component):
             help="Mesh axis name used for coll/xla collective programs")
         self._bcast_sa = self.register_var(
             "bcast_sa_min_bytes", vtype=VarType.SIZE, default="256k",
-            help="Payloads at least this large broadcast via "
-                 "scatter+allgather (~2S/n per link, two pipelined ring "
-                 "phases) instead of the binomial tree (log2(n) serial "
-                 "full-S hops) — the large-message switch of the "
-                 "reference's coll_bcast_decision ladder "
+            help="Payloads at least this large broadcast as one masked "
+                 "all-reduce (2(n-1)/n x S per chip's links, one "
+                 "pipelined program) instead of the binomial ppermute "
+                 "tree (log2(n) serial full-S hops) — the large-message "
+                 "switch of the reference's coll_bcast_decision ladder "
                  "(coll_tuned_decision_fixed.c bcast rules)")
 
     def comm_query(self, comm):
