@@ -551,13 +551,29 @@ class Comm(AttributeHost):
                            "persistent request form")
         return fn(self, "allreduce", template, op)
 
-    def alltoall_array(self, x):
+    def alltoall_array(self, x, sendtype=None, recvtype=None,
+                       count: int = 1):
+        """``x[i, j]`` moves to ``result[j, i]``.  With ``sendtype`` /
+        ``recvtype`` (derived datatypes; None is contiguous) each block is
+        packed and unpacked on the device inside the slot's one program:
+        ``count`` elements of ``sendtype`` out of each ``x[i, j]``, landing
+        through ``recvtype`` in a buffer zero outside its type map."""
         self._check_state()
-        return self._coll("alltoall_array")(self, x)
+        if sendtype is None and recvtype is None:
+            return self._coll("alltoall_array")(self, x)
+        return self._coll("alltoall_array")(
+            self, x, sendtype=sendtype, recvtype=recvtype, count=count)
 
-    def ppermute_array(self, x, perm: Sequence[tuple]):
+    def ppermute_array(self, x, perm: Sequence[tuple], sendtype=None,
+                       recvtype=None, count: int = 1):
+        """Row s of ``x`` moves to row d for each ``(s, d)`` of ``perm``;
+        typed as :meth:`alltoall_array`.  On a one-rank communicator
+        ``perm=((0, 0),)`` is a send to self."""
         self._check_state()
-        return self._coll("ppermute_array")(self, x, perm)
+        if sendtype is None and recvtype is None:
+            return self._coll("ppermute_array")(self, x, perm)
+        return self._coll("ppermute_array")(
+            self, x, perm, sendtype=sendtype, recvtype=recvtype, count=count)
 
     # -- p2p dispatch (→ selected pml, like MCA_PML_CALL) ---------------
     def send(self, buf, dest: int, tag: int = 0) -> None:

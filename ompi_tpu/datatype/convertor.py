@@ -9,8 +9,12 @@ elements move through a precomputed byte-offset template (the flattened type
 map), partial elements walk segment prefix sums.  Flags mirror
 ``opal_convertor.h:50-57``: CHECKSUM (CRC32 of the stream), EXTERNAL32
 (canonical big-endian), DEVICE (buffer lives in TPU HBM — the
-``CONVERTOR_CUDA`` analog; such buffers take the XLA path and must be staged
-before host packing).
+``CONVERTOR_CUDA`` analog).  A convertor bound to a ``jax.Array`` sets
+DEVICE itself and moves whole streams on the device through the
+accelerator component (``mca/accelerator/jax_acc.device_pack`` /
+``device_unpack`` over the datatype's device plan, ``datatype/plan``):
+``pack()`` returns a ``jax.Array`` in the elementary dtype, ``unpack()``
+returns the new buffer, since a device array cannot be written in place.
 """
 from __future__ import annotations
 
@@ -41,6 +45,14 @@ class ConvertorFlags(enum.IntFlag):
     DEVICE = 4
 
 
+def _is_device(buffer) -> bool:
+    if isinstance(buffer, (bytes, bytearray, memoryview)):
+        return False
+    from ompi_tpu.mca.accelerator import jax_acc
+
+    return jax_acc.is_device_array(buffer)
+
+
 def _as_byte_view(buffer) -> np.ndarray:
     """A writable (when possible) flat uint8 view of the caller's buffer."""
     if isinstance(buffer, np.ndarray):
@@ -68,10 +80,19 @@ class Convertor:
         self.flags = flags
         self.base_offset = base_offset
         self._mem: Optional[np.ndarray] = None
-        if buffer is not None:
-            self.prepare(buffer)
+        self._device_buf = None
         self.position = 0
         self.checksum = 0
+        if buffer is not None:
+            self.prepare(buffer)
+        if self.flags & ConvertorFlags.DEVICE:
+            if self.flags & (ConvertorFlags.EXTERNAL32
+                             | ConvertorFlags.CHECKSUM) or base_offset:
+                raise RuntimeError(
+                    "DEVICE-flagged convertor: external32, checksums and a "
+                    "base offset are host work; stage the buffer through "
+                    "the accelerator component (jax_acc.to_host) first")
+            return          # the device plan stands in for the host tables
         segs = datatype.segments
         self._native = None
         # the segment tables depend only on the datatype: build once and
@@ -109,10 +130,16 @@ class Convertor:
     # -- buffer binding --------------------------------------------------
     def prepare(self, buffer) -> "Convertor":
         """Bind the user buffer (``opal_convertor_prepare_for_send/recv``)."""
+        # numpy first: a host message must not pay for the question (nor
+        # import jax to ask it)
+        if not isinstance(buffer, np.ndarray) and _is_device(buffer):
+            self.flags |= ConvertorFlags.DEVICE
+            self._device_buf = buffer
+            return self
         if self.flags & ConvertorFlags.DEVICE:
             raise RuntimeError(
-                "DEVICE-flagged convertor: stage through the accelerator "
-                "component (coll/xla path) before host pack/unpack")
+                "DEVICE-flagged convertor bound to a host buffer: place it "
+                "on the device first (jax_acc.from_host)")
         self._mem = _as_byte_view(buffer)
         # Reject layouts that would index outside the buffer: numpy would
         # wrap negative indices to the buffer's end and silently corrupt.
@@ -260,7 +287,10 @@ class Convertor:
 
         Returns an OWNED uint8 array (bytes-like; btls write it straight
         to the wire — returning ``bytes`` would add a full-size copy per
-        fragment on the host hot path)."""
+        fragment on the host hot path).  On a device buffer: the whole
+        stream as a ``jax.Array`` of the elementary dtype."""
+        if self.flags & ConvertorFlags.DEVICE:
+            return self._device_pack(max_bytes)
         if self._mem is None:
             raise RuntimeError("convertor has no buffer bound")
         if self.packed_size == 0:
@@ -350,7 +380,11 @@ class Convertor:
         self.position = min(self.position + n, self.packed_size)
 
     def unpack(self, data: Union[bytes, memoryview, np.ndarray]) -> int:
-        """Consume an incoming packed chunk at the current position."""
+        """Consume an incoming packed chunk at the current position.
+        Returns the bytes consumed; on a device convertor, the buffer
+        (see :meth:`_device_unpack`)."""
+        if self.flags & ConvertorFlags.DEVICE:
+            return self._device_unpack(data)
         if self._mem is None:
             raise RuntimeError("convertor has no buffer bound")
         if self.packed_size == 0:
@@ -394,6 +428,40 @@ class Convertor:
                 self._mem[lo:hi] = chunk[written + so: written + so + (hi - lo)]
         self.position = start + n
         return n
+
+    # -- the device path -------------------------------------------------
+    def _device_pack(self, max_bytes: Optional[int]):
+        if self._device_buf is None:
+            raise RuntimeError("convertor has no buffer bound")
+        if self.position or (max_bytes is not None
+                             and max_bytes < self.packed_size):
+            raise ValueError("a device convertor packs the whole stream in "
+                             "one program: no position, no max_bytes")
+        from ompi_tpu.mca.accelerator import jax_acc
+
+        out = jax_acc.device_pack(self._device_buf, self.count,
+                                  self.datatype)
+        self.position = self.packed_size
+        return out
+
+    def _device_unpack(self, packed):
+        """The whole packed stream (a ``jax.Array`` of the elementary
+        dtype) into the bound buffer, functionally: returns the bound
+        buffer with exactly the type map's elements replaced, or, where
+        none is bound, a new 1-D buffer of the datatype's span, zero
+        outside the map.  The result becomes the bound buffer."""
+        if self.position:
+            raise ValueError("a device convertor unpacks the whole stream "
+                             "in one program: no position")
+        from ompi_tpu.mca.accelerator import jax_acc
+
+        if isinstance(packed, np.ndarray) or not _is_device(packed):
+            raise TypeError("a device convertor unpacks a jax.Array; place "
+                            "host data first (jax_acc.from_host)")
+        self._device_buf = jax_acc.device_unpack(
+            packed, self.count, self.datatype, into=self._device_buf)
+        self.position = self.packed_size
+        return self._device_buf
 
     def _align_external32(self, n: int) -> int:
         """Round a chunk size down to an item boundary in external32 mode."""

@@ -64,6 +64,44 @@ def _coalesce(segments: Iterable[Segment]) -> tuple[Segment, ...]:
     return tuple(out)
 
 
+def _coalesce_runs(offs: np.ndarray, counts: np.ndarray,
+                   itemsize: int) -> tuple[np.ndarray, np.ndarray]:
+    """:func:`_coalesce` for runs of ONE elementary type held as arrays
+    (byte offsets, item counts): the same merge, with no Python loop, so
+    a type map of millions of blocks costs a few passes of numpy."""
+    keep = counts != 0
+    if not keep.all():
+        offs, counts = offs[keep], counts[keep]
+    if len(offs) < 2:
+        return offs, counts
+    joined = offs[:-1] + counts[:-1] * itemsize == offs[1:]
+    if not joined.any():
+        return offs, counts
+    first = np.flatnonzero(np.concatenate(([True], ~joined)))
+    return offs[first], np.add.reduceat(counts, first)
+
+
+def ramp(counts: np.ndarray) -> np.ndarray:
+    """0 .. n-1 for each n of ``counts``, end to end: the index of every
+    item within its own run, with no loop over runs."""
+    first = np.cumsum(counts) - counts
+    return np.arange(int(counts.sum()), dtype=np.int64) \
+        - np.repeat(first, counts)
+
+
+def _as_runs(segments) -> Optional[tuple]:
+    """``(offsets, counts, dtype)`` arrays for a sequence of Segments of
+    one elementary type; None for a heterogeneous or empty sequence."""
+    segments = tuple(segments)
+    if not segments or any(s.dtype != segments[0].dtype for s in segments):
+        return None
+    return (np.fromiter((s.offset for s in segments), np.int64,
+                        len(segments)),
+            np.fromiter((s.count for s in segments), np.int64,
+                        len(segments)),
+            segments[0].dtype)
+
+
 class Datatype(AttributeHost):
     """An MPI-style datatype: committed type map + extent bookkeeping.
 
@@ -72,20 +110,46 @@ class Datatype(AttributeHost):
 
     def __init__(
         self,
-        segments: Sequence[Segment],
+        segments: Sequence[Segment] = (),
         lb: Optional[int] = None,
         ub: Optional[int] = None,
         name: str = "",
         combiner: str = "named",
         contents: tuple = (),
+        runs: Optional[tuple] = None,
     ) -> None:
-        self.segments = _coalesce(segments)
-        self.size = sum(s.nbytes for s in self.segments)
-        if self.segments:
-            self.true_lb = min(s.offset for s in self.segments)
-            self.true_ub = max(s.end for s in self.segments)
+        # A homogeneous type map is held as arrays (``runs``: byte
+        # offsets, item counts, the one elementary dtype), so that an
+        # index list of millions of blocks is built, coalesced and read
+        # by the device plan without one Python object a block; the
+        # ``segments`` tuple is made from them on first use.  A
+        # heterogeneous map (struct of different types) stays a tuple.
+        if runs is None:
+            runs = _as_runs(segments)
+        if runs is not None:
+            offs, counts, dtype = runs
+            offs, counts = _coalesce_runs(
+                np.asarray(offs, np.int64), np.asarray(counts, np.int64),
+                dtype.itemsize)
+            if not len(offs):
+                runs = None
+                segments = ()
+        self._runs = None
+        self._segments: Optional[tuple] = None
+        if runs is not None:
+            self._runs = (offs, counts, dtype)
+            nbytes = counts * dtype.itemsize
+            self.size = int(nbytes.sum())
+            self.true_lb = int(offs.min())
+            self.true_ub = int((offs + nbytes).max())
         else:
-            self.true_lb = self.true_ub = 0
+            self._segments = _coalesce(segments)
+            self.size = sum(s.nbytes for s in self._segments)
+            if self._segments:
+                self.true_lb = min(s.offset for s in self._segments)
+                self.true_ub = max(s.end for s in self._segments)
+            else:
+                self.true_lb = self.true_ub = 0
         self.lb = self.true_lb if lb is None else lb
         self.ub = self.true_ub if ub is None else ub
         self.name = name
@@ -94,10 +158,32 @@ class Datatype(AttributeHost):
         self.committed = False
         # single contiguous run starting at lb covering the whole extent
         self.is_contiguous = (
-            len(self.segments) <= 1
+            self.nseg <= 1
             and self.lb == self.true_lb
             and self.extent == self.size
         )
+
+    @property
+    def segments(self) -> tuple[Segment, ...]:
+        """The flattened, coalesced type map as a tuple of Segments."""
+        if self._segments is None:
+            offs, counts, dtype = self._runs
+            self._segments = tuple(
+                Segment(o, dtype, c)
+                for o, c in zip(offs.tolist(), counts.tolist()))
+        return self._segments
+
+    @property
+    def runs(self) -> Optional[tuple]:
+        """``(byte offsets, item counts, dtype)`` of the coalesced type
+        map as int64 arrays, or None where it holds more than one
+        elementary type (or nothing).  What the device plan reads."""
+        return self._runs
+
+    @property
+    def nseg(self) -> int:
+        return len(self._runs[0]) if self._runs is not None \
+            else len(self._segments)
 
     # -- MPI accessors ---------------------------------------------------
     @property
@@ -116,8 +202,8 @@ class Datatype(AttributeHost):
         self.committed = False
 
     def dup(self) -> "Datatype":
-        d = Datatype(self.segments, self.lb, self.ub, self.name, "dup",
-                     (self,))
+        d = Datatype(self._segments or (), self.lb, self.ub, self.name,
+                     "dup", (self,), runs=self._runs)
         d.committed = self.committed
         self._attrs_copy_to(d)   # MPI_Type_dup runs the keyval copy fns
         return d
@@ -142,7 +228,9 @@ class Datatype(AttributeHost):
     @property
     def elementary(self) -> Optional[np.dtype]:
         """The single elementary numpy dtype, if homogeneous (op kernels)."""
-        dtypes = {s.dtype for s in self.segments}
+        if self._runs is not None:
+            return self._runs[2]
+        dtypes = {s.dtype for s in self._segments}
         return next(iter(dtypes)) if len(dtypes) == 1 else None
 
     def element_count(self, nbytes: int) -> int:
@@ -150,6 +238,8 @@ class Datatype(AttributeHost):
         if self.size == 0:
             return 0
         full, rem = divmod(nbytes, self.size)
+        if self._runs is not None and not rem:
+            return full * int(self._runs[1].sum())
         n = full * sum(s.count for s in self.segments)
         for s in self.segments:
             if rem <= 0:
@@ -161,15 +251,22 @@ class Datatype(AttributeHost):
 
     def __repr__(self) -> str:
         return (f"Datatype({self.name or self.combiner}, size={self.size}, "
-                f"extent={self.extent}, nseg={len(self.segments)})")
+                f"extent={self.extent}, nseg={self.nseg})")
 
     def __eq__(self, other) -> bool:
-        return (isinstance(other, Datatype)
-                and self.segments == other.segments
-                and self.lb == other.lb and self.ub == other.ub)
+        if not (isinstance(other, Datatype) and self.lb == other.lb
+                and self.ub == other.ub and self.size == other.size
+                and self.nseg == other.nseg):
+            return False
+        if self._runs is None or other._runs is None:
+            return self.segments == other.segments
+        return (self._runs[2] == other._runs[2]
+                and np.array_equal(self._runs[0], other._runs[0])
+                and np.array_equal(self._runs[1], other._runs[1]))
 
     def __hash__(self) -> int:
-        return hash((self.segments, self.lb, self.ub))
+        return hash((self.size, self.nseg, self.true_lb, self.true_ub,
+                     self.lb, self.ub))
 
 
 def _named(np_dtype, name: str) -> Datatype:
@@ -257,38 +354,54 @@ def from_numpy_dtype(dt) -> Datatype:
 # Constructors (``ompi/datatype/ompi_datatype_create_*.c`` equivalents)
 # ---------------------------------------------------------------------------
 
-def _replicate(old: Datatype, displacements_bytes: Iterable[int],
-               blocklen: int = 1) -> list[Segment]:
-    """Place ``blocklen`` consecutive copies of ``old`` at each displacement."""
-    segs: list[Segment] = []
+def _replicate(old: Datatype, displacements_bytes, blocklens=1):
+    """Place ``blocklens`` consecutive copies of ``old`` at each
+    displacement (one length for all, or one a displacement).  Returns
+    ``Datatype`` keywords: ``runs`` arrays where ``old`` has one
+    elementary type (numpy broadcasting, no loop over blocks), else
+    ``segments``."""
+    disps = np.asarray(displacements_bytes, np.int64).reshape(-1)
+    uniform = np.ndim(blocklens) == 0
     ext = old.extent
-    for disp in displacements_bytes:
-        for b in range(blocklen):
-            base = disp + b * ext
-            for s in old.segments:
-                segs.append(Segment(base + s.offset, s.dtype, s.count))
-    return segs
+    if old.runs is None:
+        bls = [int(blocklens)] * len(disps) if uniform else list(blocklens)
+        return {"segments": [
+            Segment(int(d) + b * ext + s.offset, s.dtype, s.count)
+            for d, bl in zip(disps, bls) for b in range(bl)
+            for s in old.segments]}
+    offs, counts, dtype = old.runs
+    if len(offs) == 1 and ext == old.size:
+        # gap-free old: a block of n copies is one run of n x its items
+        bl = np.asarray(blocklens, np.int64)
+        return {"runs": (disps + offs[0],
+                         np.broadcast_to(bl * counts[0], disps.shape),
+                         dtype)}
+    if uniform:
+        starts = (disps[:, None]
+                  + np.arange(int(blocklens), dtype=np.int64) * ext
+                  ).reshape(-1)
+    else:
+        bl = np.asarray(blocklens, np.int64)
+        starts = np.repeat(disps, bl) + ramp(bl) * ext
+    return {"runs": ((starts[:, None] + offs).reshape(-1),
+                     np.tile(counts, len(starts)), dtype)}
 
 
-def _bounds(old: Datatype, displacements_bytes: Sequence[int],
+def _bounds(old: Datatype, displacements_bytes,
             blocklens) -> tuple[Optional[int], Optional[int]]:
     """MPI lb/ub rules: propagate explicit bounds through constructors."""
-    if not displacements_bytes:
+    disps = np.asarray(displacements_bytes, np.int64).reshape(-1)
+    if not len(disps):
         return 0, 0
-    if isinstance(blocklens, int):
-        blocklens = [blocklens] * len(displacements_bytes)
-    lbs = [d + old.lb for d in displacements_bytes]
-    ubs = [d + old.lb + bl * old.extent + (old.ub - old.lb - old.extent)
-           for d, bl in zip(displacements_bytes, blocklens)]
-    # old.ub - old.lb == old.extent always, so ubs simplify to
-    # d + old.lb + bl*extent; kept explicit for clarity with resized types.
-    return min(lbs), max(ubs)
+    bl = np.asarray(blocklens, np.int64)
+    return (int(disps.min()) + old.lb,
+            int((disps + bl * old.extent).max()) + old.lb)
 
 
 def contiguous(count: int, old: Datatype) -> Datatype:
-    segs = _replicate(old, [0], count)
-    return Datatype(segs, lb=old.lb, ub=old.lb + count * old.extent,
-                    combiner="contiguous", contents=(count, old))
+    return Datatype(lb=old.lb, ub=old.lb + count * old.extent,
+                    combiner="contiguous", contents=(count, old),
+                    **_replicate(old, [0], count))
 
 
 def vector(count: int, blocklength: int, stride: int, old: Datatype) -> Datatype:
@@ -303,66 +416,81 @@ def hvector(count: int, blocklength: int, stride_bytes: int,
 
 
 def _hvector(count, blocklength, stride_bytes, old, combiner, contents):
-    disps = [i * stride_bytes for i in range(count)]
-    segs = _replicate(old, disps, blocklength)
+    disps = np.arange(count, dtype=np.int64) * stride_bytes
     lb, ub = _bounds(old, disps, blocklength)
-    return Datatype(segs, lb=lb, ub=ub, combiner=combiner, contents=contents)
+    return Datatype(lb=lb, ub=ub, combiner=combiner, contents=contents,
+                    **_replicate(old, disps, blocklength))
+
+
+def _as_tuple(values) -> tuple:
+    return tuple(np.asarray(values).tolist())
 
 
 def indexed(blocklengths: Sequence[int], displacements: Sequence[int],
             old: Datatype) -> Datatype:
-    disps = [d * old.extent for d in displacements]
+    disps = np.asarray(displacements, np.int64) * old.extent
     return _hindexed(blocklengths, disps, old, "indexed",
-                     (tuple(blocklengths), tuple(displacements), old))
+                     (_as_tuple(blocklengths), _as_tuple(displacements),
+                      old))
 
 
 def hindexed(blocklengths: Sequence[int], displacements_bytes: Sequence[int],
              old: Datatype) -> Datatype:
     return _hindexed(blocklengths, displacements_bytes, old, "hindexed",
-                     (tuple(blocklengths), tuple(displacements_bytes), old))
+                     (_as_tuple(blocklengths),
+                      _as_tuple(displacements_bytes), old))
 
 
 def _hindexed(blocklengths, disps, old, combiner, contents):
-    segs: list[Segment] = []
-    for bl, d in zip(blocklengths, disps):
-        segs.extend(_replicate(old, [d], bl))
-    lb, ub = _bounds(old, disps, list(blocklengths))
-    return Datatype(segs, lb=lb, ub=ub, combiner=combiner, contents=contents)
+    n = min(len(blocklengths), len(disps))      # zip's rule, as before
+    bls = np.asarray(blocklengths, np.int64)[:n]
+    disps = np.asarray(disps, np.int64)[:n]
+    lb, ub = _bounds(old, disps, bls)
+    return Datatype(lb=lb, ub=ub, combiner=combiner, contents=contents,
+                    **_replicate(old, disps, bls))
 
 
 def hindexed_block(blocklength: int, displacements_bytes: Sequence[int],
                    old: Datatype) -> Datatype:
     """``MPI_Type_create_hindexed_block``: equal-length blocks at byte
     displacements (``ompi/mpi/c/type_create_hindexed_block.c``)."""
-    return _hindexed([blocklength] * len(displacements_bytes),
-                     list(displacements_bytes), old, "hindexed_block",
-                     (blocklength, tuple(displacements_bytes), old))
+    return _hindexed(np.full(len(displacements_bytes), blocklength),
+                     displacements_bytes, old, "hindexed_block",
+                     (blocklength, _as_tuple(displacements_bytes), old))
 
 
 def indexed_block(blocklength: int, displacements: Sequence[int],
                   old: Datatype) -> Datatype:
-    return indexed([blocklength] * len(displacements), displacements, old)
+    return indexed(np.full(len(displacements), blocklength, np.int64),
+                   displacements, old)
 
 
 def create_struct(blocklengths: Sequence[int],
                   displacements_bytes: Sequence[int],
                   types: Sequence[Datatype]) -> Datatype:
-    segs: list[Segment] = []
-    lbs, ubs = [], []
+    parts, lbs, ubs = [], [], []
     for bl, d, t in zip(blocklengths, displacements_bytes, types):
-        segs.extend(_replicate(t, [d], bl))
+        parts.append(Datatype(**_replicate(t, [d], bl)))
         lbs.append(d + t.lb)
         ubs.append(d + t.lb + bl * t.extent)
     lb = min(lbs) if lbs else 0
     ub = max(ubs) if ubs else 0
-    return Datatype(segs, lb=lb, ub=ub, combiner="struct",
+    kinds = {p.elementary for p in parts if p.size}
+    if len(kinds) == 1 and None not in kinds:   # one elementary type
+        body = {"runs": (np.concatenate([p.runs[0] for p in parts if p.size]),
+                         np.concatenate([p.runs[1] for p in parts if p.size]),
+                         kinds.pop())}
+    else:
+        body = {"segments": [s for p in parts for s in p.segments]}
+    return Datatype(lb=lb, ub=ub, combiner="struct", **body,
                     contents=(tuple(blocklengths), tuple(displacements_bytes),
                               tuple(types)))
 
 
 def resized(old: Datatype, lb: int, extent: int) -> Datatype:
-    return Datatype(old.segments, lb=lb, ub=lb + extent, combiner="resized",
-                    contents=(old, lb, extent))
+    return Datatype(old._segments or (), lb=lb, ub=lb + extent,
+                    combiner="resized", contents=(old, lb, extent),
+                    runs=old.runs)
 
 
 def subarray(sizes: Sequence[int], subsizes: Sequence[int],
@@ -441,10 +569,10 @@ def darray(size: int, rank: int, gsizes: Sequence[int],
     grids = np.meshgrid(*per_dim, indexing="ij")
     lin = sum(g.astype(np.int64) * s for g, s in zip(grids, strides))
     lin = np.sort(lin.ravel())
-    segs = _replicate(old, [int(x) for x in lin])
-    out = Datatype(segs, lb=0, ub=ext * math.prod(gsizes), combiner="darray",
+    out = Datatype(lb=0, ub=ext * math.prod(gsizes), combiner="darray",
                    contents=(size, rank, tuple(gsizes), tuple(distribs),
-                             tuple(dargs), tuple(psizes), order, old))
+                             tuple(dargs), tuple(psizes), order, old),
+                   **_replicate(old, lin))
     return out
 
 

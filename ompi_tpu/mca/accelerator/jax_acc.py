@@ -1,11 +1,15 @@
-"""accelerator/jax — TPU HBM residency + staging.
+"""accelerator/jax — TPU HBM residency, device pack/unpack, staging.
 
 The ``opal_cuda_check_bufs`` analog (``common_cuda.c``): tells the datatype
 engine, the pml, and the coll decision path whether a buffer lives in device
 HBM (→ XLA collective path, DEVICE convertor flag) or host memory (→ host
-pack/unpack).  Registration of device memory is implicit in jax.Array
-ownership; ``register``/``deregister`` keep an interval-tree bookkeeping of
-exposed host regions for the RMA path (rcache equivalent).
+pack/unpack).  For a buffer in HBM the convertor asks this component to
+move it: :func:`device_pack` / :func:`device_unpack` run a datatype's
+device plan (``datatype/plan``) as one jitted program, a ``jax.Array`` in
+and a ``jax.Array`` out, no host copy.  Registration of device memory is
+implicit in jax.Array ownership; ``register``/``deregister`` keep an
+interval-tree bookkeeping of exposed host regions for the RMA path (rcache
+equivalent).
 
 The **staging pool** is the ``rcache/grdma`` reuse analog
 (``opal/mca/rcache/grdma/rcache_grdma.c``): grdma exists so repeated
@@ -348,6 +352,66 @@ def is_device_array(x: Any) -> bool:
     # included (virtual-device meshes in tests): the mesh is what matters,
     # not the platform.
     return isinstance(x, jax.Array)
+
+
+def _ddt_program(plan, which: str, with_into: bool = False):
+    """The jitted pack or unpack of ``plan``, built once and kept on it.
+    Named ``otpu_ddt_<which>_<form>``: ``PjitFunction(otpu_ddt_...)`` on
+    the profiler's host line, ``jit_otpu_ddt_...`` on the device's."""
+    key = (which, with_into)
+    fn = plan.programs.get(key)
+    if fn is None:
+        import jax
+
+        if which == "pack":
+            def body(x, *index):
+                return plan.pack(x, *index)
+        elif with_into:
+            def body(packed, into, *index):
+                return plan.unpack(packed, *index, into=into)
+        else:
+            def body(packed, *index):
+                return plan.unpack(packed, *index)
+        body.__name__ = body.__qualname__ = f"otpu_ddt_{which}_{plan.form}"
+        fn = plan.programs[key] = jax.jit(body)
+    return fn
+
+
+def _ddt_run(span: str, counter: str, plan, fn, *args):
+    spc.record(counter)
+    spc.record("device_ddt_bytes", plan.packed * plan.dtype.itemsize)
+    args += plan.index_args()
+    if trace.profiler_on():
+        with trace.profiler_span(span):
+            return fn(*args)
+    return fn(*args)
+
+
+def device_pack(x, count: int, datatype):
+    """``count`` elements of ``datatype`` out of the device buffer ``x``:
+    the packed stream as a 1-D ``jax.Array`` of the datatype's elementary
+    dtype.  One program a call; the plan and the program are built on the
+    first."""
+    from ompi_tpu.datatype.plan import plan_for
+
+    plan = plan_for(datatype, count)
+    return _ddt_run("otpu.ddt.pack", "device_ddt_packs", plan,
+                    _ddt_program(plan, "pack"), x)
+
+
+def device_unpack(packed, count: int, datatype, into=None):
+    """The packed stream back into described memory.  A ``jax.Array``
+    cannot be written in place, so this returns the buffer: ``into`` with
+    exactly the type map's elements replaced (its shape kept; a caller
+    that jits around this may donate it), or, with no ``into``, a new 1-D
+    buffer of the datatype's span, zero outside the type map."""
+    from ompi_tpu.datatype.plan import plan_for
+
+    plan = plan_for(datatype, count)
+    fn = _ddt_program(plan, "unpack", into is not None)
+    args = (packed,) if into is None else (packed, into)
+    return _ddt_run("otpu.ddt.unpack", "device_ddt_unpacks", plan, fn,
+                    *args)
 
 
 def to_host(x) -> np.ndarray:

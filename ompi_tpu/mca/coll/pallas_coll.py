@@ -75,14 +75,14 @@ class PallasCollModule:
             None)
 
     # -- helpers ---------------------------------------------------------
-    def _delegate(self, name, comm, x, *args):
+    def _delegate(self, name, comm, x, *args, **kw):
         if self._fallback is None:
             from ompi_tpu.api.errors import ErrorClass, MpiError
 
             raise MpiError(ErrorClass.ERR_UNSUPPORTED_OPERATION,
                            f"coll/pallas cannot run {name} and no "
                            "fallback module is present")
-        return getattr(self._fallback, name)(comm, x, *args)
+        return getattr(self._fallback, name)(comm, x, *args, **kw)
 
     def _place(self, comm, x):
         if isinstance(x, self._jax_array):
@@ -182,7 +182,9 @@ class PallasCollModule:
                                  interpret=self.interpret, variant=variant,
                                  seg_elems=seg_elems)
 
-    def alltoall_array(self, comm, x):
+    def alltoall_array(self, comm, x, **typed):
+        if typed:       # derived datatypes: coll/xla's one typed program
+            return self._delegate("alltoall_array", comm, x, **typed)
         x = self._place(comm, x)
         # pure DMA, no arithmetic: any dtype qualifies — only size and
         # the (n, n, *S) layout gate (a malformed shape must surface as
@@ -324,7 +326,9 @@ class PallasCollModule:
         # the SUM reduce-scatter by another name (coll/xla parity)
         return self.reduce_scatter_array(comm, x, op_mod.SUM)
 
-    def ppermute_array(self, comm, x, perm):
+    def ppermute_array(self, comm, x, perm, **typed):
+        if typed:       # derived datatypes: coll/xla's one typed program
+            return self._delegate("ppermute_array", comm, x, perm, **typed)
         perm = tuple((int(s), int(d)) for s, d in perm)
         rot = tuple((i, (i + 1) % self.n) for i in range(self.n))
         x = self._place(comm, x)

@@ -592,27 +592,33 @@ class XlaCollModule:
     def psum_scatter_array(self, comm, x):
         return self.reduce_scatter_array(comm, x, op_mod.SUM)
 
-    def alltoall_array(self, comm, x):
-        """x[i, j] moves to result[j, i] (rank j receives x[:, j])."""
+    def alltoall_array(self, comm, x, sendtype=None, recvtype=None,
+                       count: int = 1):
+        """x[i, j] moves to result[j, i] (rank j receives x[:, j]).  With
+        ``sendtype`` each x[i, j] is a described buffer of which ``count``
+        elements of the type are sent; with ``recvtype`` each block lands
+        through that type (:meth:`_typed`).  One program either way."""
+        if sendtype is not None or recvtype is not None:
+            return self._typed(comm, "alltoall", x, sendtype, recvtype,
+                               count)
         if isinstance(x, self._jax_array):
             fn = self._fast(self._keyfor("alltoall", x))
             if fn is not None:
                 return fn(x)
-        import jax
-        import jax.numpy as jnp
-
-        P = self._P
-
-        def body(t):  # (1, n, *S)
-            y = jax.lax.all_to_all(t, self.axis, split_axis=1, concat_axis=0)
-            return jnp.swapaxes(y, 0, 1)  # (1, n, *S): row = my received blocks
-
         fn, x = self._get(
             comm, self._keyfor("alltoall", x), x,
-            lambda: self._shard_map(body, P(self.axis), P(self.axis),
+            lambda: self._shard_map(self._alltoall_body, self._P(self.axis),
+                                    self._P(self.axis),
                                     name=_program_name("alltoall")),
             inner_n=True)
         return fn(x)
+
+    def _alltoall_body(self, t):  # (1, n, *S)
+        import jax
+        import jax.numpy as jnp
+
+        y = jax.lax.all_to_all(t, self.axis, split_axis=1, concat_axis=0)
+        return jnp.swapaxes(y, 0, 1)  # (1, n, *S): row = my received blocks
 
     def alltoallv_array(self, comm, x, counts):
         """Padded alltoallv: x (n, n, Smax, ...), counts[i][j] = rows rank j
@@ -621,17 +627,88 @@ class XlaCollModule:
         return [[full[i, j, :int(counts[j][i])] for j in range(self.n)]
                 for i in range(self.n)]
 
-    def ppermute_array(self, comm, x, perm):
+    def ppermute_array(self, comm, x, perm, sendtype=None, recvtype=None,
+                       count: int = 1):
+        """Row s of x moves to row d for each (s, d) of ``perm``.  With
+        ``sendtype`` / ``recvtype`` the rows are described buffers
+        (:meth:`_typed`); on a one-rank world ``((0, 0),)`` is a send to
+        self.  One program either way."""
         import jax
 
         P = self._P
         perm = tuple((int(s), int(d)) for s, d in perm)
+        if sendtype is not None or recvtype is not None:
+            return self._typed(comm, "ppermute", x, sendtype, recvtype,
+                               count, perm)
         fn, x = self._get(
             comm, self._keyfor("ppermute", x, perm), x,
             lambda: self._shard_map(
                 lambda t: jax.lax.ppermute(t, self.axis, perm),
                 P(self.axis), P(self.axis),
                 name=_program_name("ppermute")))
+        return fn(x)
+
+    def _typed(self, comm, coll: str, x, sendtype, recvtype, count: int,
+               *args):
+        """``ppermute`` / ``alltoall`` with derived datatypes, as ONE
+        program: each rank's buffer (each of its n blocks, for alltoall)
+        is packed through ``sendtype``'s device plan, the packed streams
+        cross, and each lands through ``recvtype``'s plan in a new buffer
+        that is zero outside the type map.  A type left None is
+        contiguous: the rows are (sendtype None), or come back as
+        (recvtype None), packed streams.  ``count`` elements of each
+        type, whose packed sizes must agree.  Keyed in the program cache
+        by the plans' keys: two datatypes of one regular map share a
+        program."""
+        import jax
+
+        from ompi_tpu.datatype.plan import plan_for
+
+        plans = [None if t is None else plan_for(t, count)
+                 for t in (sendtype, recvtype)]
+        send, recv = plans
+        if send is not None and recv is not None \
+                and send.packed != recv.packed:
+            raise MpiError(
+                ErrorClass.ERR_TRUNCATE,
+                f"typed {coll}: sendtype packs {send.packed} elements, "
+                f"recvtype {recv.packed}")
+        key = self._keyfor(coll, x, *args) + tuple(
+            None if p is None else p.key for p in plans)
+        batch = 2 if coll == "alltoall" else 1    # leading axes of a row
+
+        def build():
+            ax, P = self.axis, self._P
+            if coll == "alltoall":
+                cross = self._alltoall_body
+            else:
+                def cross(t):
+                    return jax.lax.ppermute(t, ax, args[0])
+            n_send = 0 if send is None else len(send.index_args())
+
+            def body(t, *index):
+                pack = None if send is None else (
+                    lambda b: send.pack(b, *index[:n_send]))
+                unpack = None if recv is None else (
+                    lambda b: recv.unpack(b, *index[n_send:]))
+                for _ in range(batch):  # one buffer a (rank[, block])
+                    pack = pack and jax.vmap(pack)
+                    unpack = unpack and jax.vmap(unpack)
+                if pack is not None:
+                    t = pack(t)
+                t = cross(t)
+                return t if unpack is None else unpack(t)
+
+            index = tuple(a for p in plans if p is not None
+                          for a in p.index_args(self._replicated))
+            prog = self._shard_map(
+                body, (P(ax),) + (P(),) * len(index), P(ax),
+                name=_program_name(coll, "ddt"))
+            if not index:
+                return prog
+            return lambda t: prog(t, *index)
+
+        fn, x = self._get(comm, key, x, build, inner_n=batch == 2)
         return fn(x)
 
     def scatter_array(self, comm, x, root: int = 0):

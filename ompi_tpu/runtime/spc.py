@@ -22,6 +22,12 @@ _COUNTERS = (
     # otpu.coll.get/build/first_call spans cannot
     "device_slow_path", "device_program_builds",
     "device_program_first_call_us",
+    # the datatype engine's device path (datatype/plan behind
+    # mca/accelerator): pack_array / unpack_array calls, the bytes of
+    # their packed streams, plans built (one a datatype and count, so
+    # none once a loop is warm) and how many of those were index lists
+    "device_ddt_packs", "device_ddt_unpacks", "device_ddt_bytes",
+    "device_ddt_plan_builds", "device_ddt_index_plans",
     # fastpath counters: the zero-copy host-datapath contract, pinned by
     # test_perf_guard (payload copies on the contiguous tcp send path
     # must stay 0; the schedule cache must hit on repeated collectives)
