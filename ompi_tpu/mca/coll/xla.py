@@ -269,7 +269,11 @@ class XlaCollModule:
             # component provides one; else chained folds
             stack = op_mod.jax_stack_reduce(op, t.dtype)
             if stack is not None:
-                return stack(gathered)
+                # handed over with rank >= 3: a gathered stack lies a row
+                # at a time on the chip (T(1,128)), which reduce_stack's
+                # (k, rows, 128) blocks read as it stands; its 2-D block
+                # is for a program input's T(4,128) and costs a copy here
+                return stack(gathered[:, None])[0]
             fold = op_mod.jax_fold(op, t.dtype)
             acc = gathered[0]
             for i in range(1, self.n):

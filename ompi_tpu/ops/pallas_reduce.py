@@ -14,10 +14,16 @@ Two entry points, both shape-polymorphic over arbitrary operand shapes:
     reduce leaves) — a bandwidth win over materialising each intermediate
     in HBM.
 
-Operands are flattened and padded to (rows, 128) lanes; the grid walks
-row-tiles so arbitrarily large buffers stream through VMEM.  Off-TPU the
-kernels run in interpreter mode so the same code path is exercised by the
-CPU test mesh.
+``combine2`` flattens and pads its operands to (rows, 128) lanes, and so
+does ``reduce_stack`` for a stack of rank 3 or more (a gathered
+``(n, 1, S)`` stack reshapes to ``(n, rows, 128)`` for free).  A 2-D
+``(k, N)`` stack of a 32-bit dtype with N a multiple of 128 is blocked as
+it stands, ``(k, C)`` in and ``(1, C)`` out: on the chip such an array is
+tiled ``T(4,128)``, and a reshape to ``(k, rows, 128)`` in front of the
+kernel is a copy of the whole stack (63% of the call, PERF.md PR 28).
+The grid walks the tiles so arbitrarily large buffers stream through
+VMEM.  Off-TPU the kernels run in interpreter mode so the same code path
+is exercised by the CPU test mesh.
 """
 from __future__ import annotations
 
@@ -31,6 +37,12 @@ from ompi_tpu.base.jaxenv import pallas_interpret
 
 LANES = 128
 ROW_TILE = 512  # 512x128 f32 tile = 256 KiB per operand in VMEM
+# 2-D stacks: a (k, C) block holds this many elements once k is padded to
+# its sublane tile (4 MiB of 32-bit; with the (1, C) output, both
+# double-buffered, 10 MiB of a v5e's 16 MiB of scoped VMEM at k = 4).
+# Swept on the chip at k = 4, 4 x 64-256 MiB (PR 28): C 16Ki 582 GB/s,
+# 64Ki 682, 256Ki 704; XLA's own reduce 660.
+ROWS_BLOCK_ELEMS = 1 << 20
 
 _FOLDS = {
     "SUM": lambda a, b: a + b,
@@ -104,6 +116,33 @@ def _stack_kernel(fold, k, x_ref, o_ref):
     o_ref[:] = acc
 
 
+def _stack_rows_kernel(fold, k, x_ref, o_ref):
+    acc = x_ref[0:1, :]
+    for i in range(1, k):  # one row of the (k, C) block at a time
+        acc = fold(acc, x_ref[i:i + 1, :])
+    o_ref[:] = acc
+
+
+def _reduce_rows(fold, x, interpret):
+    """``reduce_stack`` of a 2-D ``(k, N)`` stack, N a multiple of LANES:
+    no reshape and no pad in front of the kernel, a bitcast behind it.
+    The last block may be partial: the fold is elementwise, so what its
+    padding holds never reaches the clipped output."""
+    k, n = x.shape
+    k_tiled = 4 if k <= 4 else -(-k // 8) * 8  # rows the block takes in VMEM
+    c = min(n, max(LANES, ROWS_BLOCK_ELEMS // k_tiled // LANES * LANES))
+    out = pl.pallas_call(
+        functools.partial(_stack_rows_kernel, fold, k),
+        out_shape=jax.ShapeDtypeStruct((1, n), x.dtype),
+        grid=(pl.cdiv(n, c),),
+        in_specs=[pl.BlockSpec((k, c), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((1, c), lambda j: (0, j)),
+        name="otpu_reduce_stack_rows",
+        interpret=pallas_interpret() if interpret is None else interpret,
+    )(x)
+    return out.reshape(n)
+
+
 @functools.partial(jax.jit, static_argnames=("op_name", "interpret"))
 def reduce_stack(op_name: str, x, *, interpret=None):
     """Reduce ``x[k, ...]`` along axis 0 in one streaming VMEM pass.
@@ -113,6 +152,8 @@ def reduce_stack(op_name: str, x, *, interpret=None):
     k = x.shape[0]
     if k == 1:
         return x[0]
+    if x.ndim == 2 and x.dtype.itemsize == 4 and x.shape[1] % LANES == 0:
+        return _reduce_rows(fold, x, interpret)
     # row tile sized so k operand tiles + out fit VMEM comfortably
     tile = max(8, min(ROW_TILE, 4096 // k * 8))
     per = x[0].size

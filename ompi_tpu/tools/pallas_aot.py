@@ -239,6 +239,20 @@ def cases(mesh1d, mesh2d):
     case("vpu_reduce_stack_max", lambda: (
         pr.reduce_stack, ("MAX", _sds((8, PAY), f32, one, P())),
         {"interpret": False}))
+    # reduce_stack at a benchmark cell's size (4 rows of 64 MiB), as a
+    # program input and as a gather hands it over: the module must hold
+    # the kernel and bitcasts only (``entry_ops``) -- a relayout copy in
+    # front of the kernel was 63% of the call before anyone looked (PR 28)
+    ROW = (64 << 20) // 4
+    case("vpu_reduce_stack_rows_prod_f32", lambda: (
+        pr.reduce_stack, ("PROD", _sds((4, ROW), f32, one, P())),
+        {"interpret": False}))
+    case("vpu_reduce_stack_rows_band_i32", lambda: (
+        pr.reduce_stack, ("BAND", _sds((4, ROW), jnp.int32, one, P())),
+        {"interpret": False}))
+    case("vpu_reduce_stack_gathered_prod_f32", lambda: (
+        pr.reduce_stack, ("PROD", _sds((4, 1, ROW // 4), f32, one, P())),
+        {"interpret": False}))
 
     # -- coll/quant codec kernels: encode / dequant-accumulate / decode
     # lower through Mosaic at sweep scale (1M-element operands, 8-rank
@@ -287,6 +301,19 @@ def cases(mesh1d, mesh2d):
     return out
 
 
+def entry_ops(compiled) -> dict:
+    """Opcode counts of a compiled module's ENTRY computation, parameters
+    left out: what XLA put around a kernel (``custom-call``)."""
+    import collections
+    import re
+
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    ops = re.findall(r"^\s*(?:ROOT )?\S+ = .*?\s([a-z][\w\-]*)\(",
+                     entry[:entry.index("\n}")], re.M)
+    return dict(collections.Counter(o for o in ops if o != "parameter"))
+
+
 def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
         verbose: bool = True) -> dict:
     t0 = time.time()
@@ -312,6 +339,7 @@ def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
             compiled = lowered.compile()
             row["compiled"] = True
             row["compile_s"] = round(time.time() - ts, 2)
+            row["entry_ops"] = entry_ops(compiled)
             try:
                 mem = compiled.memory_analysis()
                 row["peak_vmem_bytes"] = int(

@@ -70,6 +70,27 @@ def test_device_allreduce_prod_band(world, xla):
         np.bitwise_and.reduce(hosti, 0))
 
 
+def test_gathered_stack_keeps_the_rows_of_128_kernel(world, xla):
+    """allreduce of an op with no native collective hands its gathered
+    (n, S) stack to ``reduce_stack`` with rank 3: on the chip a gathered
+    stack lies a row at a time, which the (k, rows, 128) blocks read
+    with no copy, and the 2-D block (``otpu_reduce_stack_rows``, for a
+    program input) would need one."""
+    import re
+
+    import jax
+
+    from ompi_tpu.api import op
+
+    P = xla._P
+    fn = xla._shard_map(lambda t: xla._reduce_in_shard(op.PROD)(t[0]),
+                        P(xla.axis), P(), name="otpu_allreduce_prod")
+    x = xla.make_world_array(np.ones((8, 1024), np.float32))
+    names = re.findall(r"name=(otpu_reduce_stack\w*)",
+                       str(jax.make_jaxpr(fn)(x)))
+    assert names == ["otpu_reduce_stack"]
+
+
 def test_device_bcast(world, xla):
     host, dev = _world_data(xla, seed=2)
     out = np.asarray(world.bcast_array(dev, root=3))

@@ -5,7 +5,9 @@ Reference model: the op/avx kernel tests ``test/datatype/reduce_local.c``
 computation — and the op framework selection in
 ``ompi/mca/op/base/op_base_op_select.c``.
 """
+import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,12 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ops import pallas_reduce as pr
+
+
+def _stack_kernel_names(op, x):
+    """Names of the ``pallas_call``s ``reduce_stack(op, x)`` traces to."""
+    jaxpr = jax.make_jaxpr(functools.partial(pr.reduce_stack, op))(x)
+    return re.findall(r"name=(otpu_\w+)", str(jaxpr))
 
 
 class TestPallasReduce:
@@ -60,6 +68,44 @@ class TestPallasReduce:
         np.testing.assert_array_equal(
             np.asarray(pr.reduce_stack("SUM", jnp.asarray(big))),
             np.full(70000, 4, np.float32))
+
+    @pytest.mark.parametrize("op,dtype", [
+        ("PROD", np.float32), ("BAND", np.int32), ("MAX", np.int32)])
+    @pytest.mark.parametrize("n", [
+        128, 65536, 65536 + 128, 3 * 65536,
+        262144 + 128])  # a partial last block at every k (C <= 262144)
+    @pytest.mark.parametrize("k", [2, 3, 4, 5, 8])
+    def test_reduce_stack_2d_bit_equal(self, k, n, op, dtype):
+        """A (k, N) stack of a 32-bit dtype, N a multiple of 128, is
+        blocked as it stands ((k, C) in, (1, C) out)."""
+        rng = np.random.RandomState(k * 1000 + n % 997)
+        if dtype is np.float32:
+            x = rng.normal(size=(k, n)).astype(dtype)
+        else:
+            x = rng.randint(-(1 << 31), 1 << 31, size=(k, n),
+                            dtype=np.int64).astype(dtype)
+        assert _stack_kernel_names(op, x) == ["otpu_reduce_stack_rows"]
+        want = functools.reduce(
+            {"PROD": np.multiply, "BAND": np.bitwise_and,
+             "MAX": np.maximum}[op], list(x))
+        got = np.asarray(pr.reduce_stack(op, jnp.asarray(x)))
+        assert got.dtype == want.dtype and got.shape == (n,)
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("shape,dtype,name", [
+        ((4, 1024), jnp.float32, "otpu_reduce_stack_rows"),
+        ((4, 1024), jnp.int32, "otpu_reduce_stack_rows"),
+        ((4, 1, 1024), jnp.float32, "otpu_reduce_stack"),   # a gathered stack
+        ((4, 1000), jnp.float32, "otpu_reduce_stack"),      # ragged N
+        ((4, 1024), jnp.bfloat16, "otpu_reduce_stack"),     # sub-32-bit
+        ((5, 3, 411), jnp.float32, "otpu_reduce_stack"),
+    ])
+    def test_reduce_stack_path_by_input(self, shape, dtype, name):
+        """The input decides the block shape, at trace time; the kernel's
+        name says which (what ``kernel.in_kernel_share`` reads on the
+        chip)."""
+        x = jax.ShapeDtypeStruct(shape, dtype)
+        assert _stack_kernel_names("SUM", x) == [name]
 
     def test_device_fold_coverage(self):
         assert pr.device_fold("SUM", jnp.float32) is not None

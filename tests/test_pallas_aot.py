@@ -61,6 +61,30 @@ def test_train_step_aot_compiles_with_flash():
     assert not bad, json.dumps(bad, indent=1)
 
 
+def test_reduce_stack_aot_holds_the_kernel_alone():
+    """``reduce_stack`` at a benchmark cell's size (4 rows of 64 MiB) for
+    one v5e device: a 2-D program-input stack (PROD f32, BAND i32) and
+    the rank-3 stack a gather hands over must compile to the kernel and
+    bitcasts, with no ``copy`` and no ``fusion`` beside it.  The relayout
+    copy XLA used to put in front of the kernel (63% of every call on
+    the chip) shows here, offline, as a ``fusion``."""
+    pytest.importorskip("libtpu")
+    res = _run_aot_subprocess("--only", "vpu_reduce_stack", "--topology",
+                              "v5e:2x2")
+    assert res.get("rows"), res.get("error")
+    rows = {r["kernel"]: r for r in res["rows"]}
+    assert set(rows) == {"vpu_reduce_stack_max",
+                         "vpu_reduce_stack_rows_prod_f32",
+                         "vpu_reduce_stack_rows_band_i32",
+                         "vpu_reduce_stack_gathered_prod_f32"}
+    bad = [r for r in rows.values() if not r.get("compiled")]
+    assert not bad, json.dumps(bad, indent=1)
+    for name, row in rows.items():
+        ops = row["entry_ops"]
+        assert ops.get("custom-call") == 1, (name, ops)
+        assert set(ops) <= {"custom-call", "bitcast"}, (name, ops)
+
+
 @pytest.mark.slow
 def test_all_kernels_aot_compile():
     pytest.importorskip("libtpu")
@@ -86,6 +110,9 @@ def test_all_kernels_aot_compile():
                    # single-chip hot kernels (the MFU path)
                    "flash_attention_bf16_2k", "vpu_combine2_sum",
                    "vpu_reduce_stack_max",
+                   "vpu_reduce_stack_rows_prod_f32",
+                   "vpu_reduce_stack_rows_band_i32",
+                   "vpu_reduce_stack_gathered_prod_f32",
                    # the composed flagship step
                    "train_step_1dev", "train_step_2x2"):
         assert expect in names, f"AOT case list lost {expect}"
