@@ -30,10 +30,10 @@ import time
 import numpy as np
 
 WATCHDOG_S = 1100           # the contract allows 1200 s, compile included
-MODEL_SCALE = 64            # bench.py's TPU scale: d 512, head dim 256
-PRIMARY_BYTES = 16 << 20    # bench.PRIMARY: float32 allreduce per rank
+MODEL_SCALE = 64            # d 512, head dim 256
+PRIMARY_BYTES = 16 << 20    # BASELINE.json's headline: f32 allreduce a rank
 SPOT_BYTES = 4 << 20
-FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bench.py's kernel row, bf16
+FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bf16
 # each loss is taken BEFORE its update: four losses observe three
 # updates, and every one of them must have lowered the loss
 TRAIN_STEPS = 4

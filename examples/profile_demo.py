@@ -9,28 +9,21 @@ Self-launching: run this script directly (no tpurun needed) and it
 2. runs ``otpu_analyze`` over the trace directory and prints the
    per-rank host-overhead table: the per-message
    pack/queue/wire/parse/deliver breakdown, the exposed-host fraction,
-   and the profiler's phase/GIL estimates,
-3. demonstrates the perf-history plane: two ``bench.py --history``-style
-   runs into a temp BENCH_HISTORY.jsonl with an injected slowdown on
-   the second, then ``otpu_perf --diff`` flagging the regression
-   (nonzero exit).
+   and the profiler's phase/GIL estimates.
 
 Inside a real job the same data is produced by::
 
     tpurun -n N --mca otpu_profile_stages 1 ... app.py
     python -m ompi_tpu.tools.otpu_analyze <otpu_trace_dir>
-    python bench.py --history && python -m ompi_tpu.tools.otpu_perf --diff
 """
-import json
 import os
 import subprocess
 import sys
 import tempfile
-import time
 
 
 def main() -> int:
-    from ompi_tpu.tools import otpu_analyze, otpu_perf
+    from ompi_tpu.tools import otpu_analyze
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     worker = os.path.join(repo, "tests", "telemetry_worker.py")
@@ -55,24 +48,6 @@ def main() -> int:
 
     print("== 2. per-message breakdown (otpu_analyze) ==")
     otpu_analyze.main([tdir])
-
-    print()
-    print("== 3. perf-history plane (otpu_perf --diff) ==")
-    hist = os.path.join(tdir, "BENCH_HISTORY.jsonl")
-    with open(hist, "w") as f:
-        t = time.time()
-        for run, lat in (("clean", 910.0), ("slow", 5410.0)):
-            for key, v in (("allreduce_4096b_n2", lat),
-                           ("pingpong_4096b_n2", lat * 1.3)):
-                f.write(json.dumps(
-                    {"v": 1, "kind": "bench", "run": run, "t": t,
-                     "topology": "host_sm_n2", "key": key,
-                     "lat_us": v, "k": 6}) + "\n")
-            t += 1.0
-    rc = otpu_perf.main([hist, "--diff"])
-    print(f"otpu_perf --diff exit code: {rc} (nonzero = regression "
-          "gate trips; in a clean tree run `python bench.py --history` "
-          "then `python -m ompi_tpu.tools.otpu_perf --diff`)")
     return 0
 
 

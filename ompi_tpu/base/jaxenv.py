@@ -17,7 +17,7 @@ _REPO_CACHE = os.path.join(
 def require_tpu(who: str) -> list:
     """``jax.devices()``, every one a TPU — or exit non-zero at once,
     naming what was found.  For entry points whose output is only true
-    of a chip (``chip_smoke.py``, ``bench.py``'s device rows): nothing
+    of a chip (``chip_smoke.py``): nothing
     is measured or written from another platform.  Sets no platform
     itself."""
     import jax

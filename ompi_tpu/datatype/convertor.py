@@ -29,8 +29,8 @@ from ompi_tpu.runtime.hotpath import hot_path
 
 # whole-element pack jobs at least this many bytes fan out over the
 # threads-framework worker pool instead of the single-thread native loop.
-# fastpath: raised from 256KB — the bench threads_pool_pack_4MB row
-# measured the pool barely breaking even at 4MB (1.09x) because pool
+# fastpath: raised from 256KB — on a one-core CPU host the pool
+# barely broke even at 4MB (1.09x) because pool
 # dispatch (job split + cross-thread handoff + wait) costs tens of µs
 # that a sub-megabyte native pack never earns back; below this the
 # serial native loop is flatly faster and skips the dispatch entirely

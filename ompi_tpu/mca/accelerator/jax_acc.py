@@ -18,8 +18,8 @@ here, repeated host-path collectives reuse warmed staging buffers
 (LRU keyed on (shape, dtype)) instead of re-allocating.  A fresh
 ``np.empty`` is lazily mapped and re-faults its pages on every call —
 measured ~6x the warmed-checkout cost (36µs vs 6µs per 1MB buffer,
-``bench.py staging_micro_row``).  On the 1-core host harness that tax
-is <1% of a 25ms collective (end-to-end within noise); it matters
+on a one-core CPU host).  On that host the tax is
+<1% of a 25ms collective (end-to-end within noise); it matters
 where transfers are fast relative to allocation, which is exactly the
 regime grdma targets.
 """
@@ -72,7 +72,7 @@ class _StagingPool:
     acquire — warmth is the whole point.
 
     The previous exact-(shape, dtype)-keyed design measured an e2e
-    **regression** (BENCH_SWEEP `staging_pool_e2e` 0.78x) despite a
+    **regression** (0.78x on a one-core CPU host) despite a
     6.65x reuse micro: every release ran an O(n) identity scan of the
     key's free list, eviction dumped the ENTIRE least-recently-used key
     (a repeated-collective loop whose one hot key rotated to the front

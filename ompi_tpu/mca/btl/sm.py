@@ -195,8 +195,8 @@ class SmBtl(Btl):
     # shared memory pays per-handoff (scheduling + matching) cost, not
     # per-byte: with the zero-copy send path a single big eager frame is
     # one ring write, while RNDV costs 3 handoffs — measured ~2x on the
-    # 512KB pingpong (see BENCH_SWEEP.md host rows).  The 4MB ring
-    # comfortably holds two in-flight 512KB frames per peer.
+    # 512KB pingpong (a one-core CPU host, PR 4; no chip number).  The
+    # 4MB ring comfortably holds two in-flight 512KB frames per peer.
     eager_limit = 512 * 1024
     rndv_eager_limit = 512 * 1024
     max_send_size = 1024 * 1024

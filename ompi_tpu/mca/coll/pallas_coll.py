@@ -358,8 +358,8 @@ class PallasCollComponent(Component):
             help="Smallest per-rank payload routed to the DMA ring; "
                  "smaller calls fall through to coll/xla (latency-bound "
                  "small collectives are usually better "
-                 "compiler-scheduled — derive the crossover from "
-                 "LADDER_PROBE.json on real hardware)")
+                 "compiler-scheduled; no crossover has been "
+                 "measured on a chip)")
         self._max = self.register_var(
             "max_bytes", vtype=VarType.SIZE, default="1g",
             help="Largest per-rank payload routed to the DMA ring; "

@@ -279,7 +279,7 @@ def allgather_blockq(comm, sendbuf, codec: str):
 
 #: measured wire volume (module ints, bump_device discipline): original
 #: vs encoded bytes of every quantized frame this process sent — the
-#: bench row's bytes-on-wire evidence.
+#: bytes-on-wire evidence tests/test_quant.py reads.
 _wire_orig = 0
 _wire_enc = 0
 

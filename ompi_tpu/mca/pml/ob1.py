@@ -894,7 +894,7 @@ class Ob1Component(Component):
                  "(pml_ob1_sendreq.h:375-401) on rdma-capable btls; 0 "
                  "disables RGET — measured 3.7x (4MB) / 2.4x (16MB) the "
                  "RNDV FRAG stream's bandwidth over btl/sm "
-                 "(BENCH_SWEEP.md rget rows)")
+                 "(one-core CPU host; no chip number)")
         self._rget_emu_var = self.register_var(
             "rget_emulate", vtype=VarType.BOOL, default=False,
             help="Allow RGET's request/stream pull emulation on btls "

@@ -132,8 +132,8 @@ def default_workers() -> int:
         return int(var.value)
     # a single-core host gets ONE worker: pool.size==1 makes every
     # fan-out site (convertor packs, host reductions) keep its serial
-    # path — steady-state the pool is ~neutral there (bench
-    # threads_pool_pack_4MB row: ~0.98x warm), but with no second core
+    # path — steady-state the pool is ~neutral there (a 4MB pack:
+    # ~0.98x warm on a one-core CPU host), but with no second core
     # there is nothing to win, and the serial path skips worker
     # startup and cross-thread traffic entirely
     return max(1, min(4, os.cpu_count() or 1))
@@ -146,7 +146,7 @@ def get_pool() -> WorkPool:
     inline-serial pool: a host reduction or pack racing finalize must
     not respawn native worker threads the runtime just joined — the
     lazy recreation here used to do exactly that.  A plain
-    ``shutdown_pool()`` keeps the lazy rebuild: bench and tests use it
+    ``shutdown_pool()`` keeps the lazy rebuild: tests use it
     to reconfigure the worker count."""
     global _pool
     with _pool_lock:

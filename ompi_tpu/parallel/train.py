@@ -80,9 +80,9 @@ def model_dims(spec: MeshSpec, layers: int = None) -> dict:
     pp=2-vs-pp=1 equivalence tests depend on it.
 
     ``OTPU_MODEL_SCALE`` multiplies the width/sequence dims (default 1:
-    the compile-check scale every correctness test uses).  The bench's
-    single-chip MFU row raises it so the SAME flagship program is
-    measured at MXU-saturating sizes instead of tracing-scale ones."""
+    the compile-check scale every correctness test uses).
+    ``chip_smoke.py`` raises it so the SAME flagship program runs at
+    MXU-saturating sizes instead of tracing-scale ones."""
     import os
 
     scale = max(1, int(os.environ.get("OTPU_MODEL_SCALE", "1") or 1))

@@ -6,10 +6,9 @@ inter-arrival gaps) with mixed prompt/decode lengths, fed into a
 report reads p50/p99 request latency out of the otpu-trace
 ``serve_request`` log2 histogram (the percentile estimator of
 ``runtime/trace.py``) and computes tokens/sec from the completed set —
-the serving benchmark surface ``bench.py --serving`` publishes,
-qualitatively different from the OSU-style sweeps (open-loop offered
-load against a queueing system instead of a closed request/reply
-ping-pong).
+the serving report, qualitatively different from the OSU-style sweeps
+(open-loop offered load against a queueing system instead of a closed
+request/reply ping-pong).
 
 :class:`MixedPoissonDriver`: the FLEET version — several tenants, each
 with its own seeded arrival process, request rate, prompt/decode

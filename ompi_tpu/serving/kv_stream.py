@@ -82,8 +82,7 @@ class _KvSlabBase:
     @property
     def capacity_multiplier(self) -> float:
         """How many more sequences a fixed byte budget holds under the
-        codec (1.0 for raw slabs) — the users-per-chip multiplier the
-        bench row pins."""
+        codec (1.0 for raw slabs) — the users-per-chip multiplier."""
         return (4.0 * self.elems_per_slot) / self.slot_nbytes
 
     def _check_slot(self, slot: int) -> int:

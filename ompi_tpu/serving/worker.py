@@ -55,8 +55,8 @@ _KV_MOD = 997
 #: simulated model-forward costs (f32 tanh pass sizes).  Autoregressive
 #: decode pays one TARGET pass per emitted token; a speculative verify
 #: round pays one target pass for the whole window plus one cheap DRAFT
-#: pass per proposed token — the gap IS the speculative win the bench
-#: A/B rows measure, so both sides must price their passes.
+#: pass per proposed token — the gap IS the speculative win, so both
+#: sides must price their passes.
 _TARGET_PASS_ELEMS = 1 << 20
 _DRAFT_PASS_ELEMS = 1 << 14
 

@@ -51,8 +51,8 @@ skew report's eyeball pass cannot:
   wait, so the ratio must land in (0, ~1]).
 
 The report is a regression-friendly JSON document (stable key order,
-rounded numbers); ``--diff OLD.json`` compares two runs the way
-``bench.py`` diffs its sweep rows and flags straggler/skew movement.
+rounded numbers); ``--diff OLD.json`` compares two runs and flags
+straggler/skew movement.
 """
 from __future__ import annotations
 
@@ -755,9 +755,8 @@ def suggest_ladder(report: dict, comm_size: int) -> str:
     extend that cell's pick to every smaller message, since the
     grammar has no lower bound), with the measured critical share
     annotated on the rows the hot cells land in.  Loading it changes
-    NO pick — it marks exactly which cells ``bench.py --ladder`` is
-    worth sweeping, and the autotuner's improved picks then diff
-    against a checked-in baseline.  Commutativity caveat: the rule
+    NO pick — it marks exactly which cells are worth measuring before
+    a rule is changed.  Commutativity caveat: the rule
     grammar cannot express it, so tuned applies dynamic rules to
     commutative reductions only (non-commutative ops keep the fixed
     ladder's order-safe picks) and the draft pins the commutative
@@ -778,7 +777,7 @@ def suggest_ladder(report: dict, comm_size: int) -> str:
         "# behavior-identical draft: every row pins the fixed ladder's",
         "# own incumbent (commutative form; non-commutative ops ignore",
         "# dynamic rules); rows marked critical_us sat on the measured",
-        "# critical path — sweep those with bench.py --ladder before",
+        "# critical path — measure those on the target machine before",
         "# promoting a different algorithm",
     ]
     # hot-cell upper bounds per collective: (cap_bytes, {max_bin_bound:
@@ -953,9 +952,8 @@ def analyze(events: list, step_span: Optional[str] = None,
 
 
 def diff_reports(old: dict, new: dict) -> dict:
-    """Regression-friendly comparison of two reports (what bench.py
-    diffs across runs): straggler movement, skew deltas, exposed-comm
-    deltas per rank."""
+    """Regression-friendly comparison of two reports: straggler
+    movement, skew deltas, exposed-comm deltas per rank."""
     out: dict = {"straggler_changed":
                  old.get("straggler", {}).get("rank")
                  != new.get("straggler", {}).get("rank"),

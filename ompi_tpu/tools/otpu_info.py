@@ -114,9 +114,8 @@ def main(argv=None) -> int:
     ap.add_argument("--profile", action="store_true",
                     help="Show the otpu-prof plane: the declared "
                          "datapath stage table (runtime/profile.py "
-                         "STAGES), the stage-clock / sampling-profiler "
-                         "MCA vars, and the perf-history file "
-                         "otpu_perf reads")
+                         "STAGES) and the stage-clock / "
+                         "sampling-profiler MCA vars")
     ap.add_argument("--progress", action="store_true",
                     help="Show the progress-engine plane: the "
                          "registry-enumerated progress vars (native "
@@ -254,17 +253,12 @@ def main(argv=None) -> int:
         # registry-enumerated like --telemetry: the STAGES table and
         # the profile var group, never a hand-kept list
         from ompi_tpu.runtime import profile as _profile
-        from ompi_tpu.tools.otpu_perf import DEFAULT_HISTORY
 
         for stage, desc in _profile.STAGES.items():
             out.append(_fmt(f"profile stage {stage}", desc, p))
         for var in registry.all_vars("profile"):
             out.append(_fmt(f"profile var {var.name}",
                             f"{var.value!r} — {var.help}", p))
-        out.append(_fmt("profile history",
-                        f"{DEFAULT_HISTORY} (bench.py --history / "
-                        "--ladder append; otpu_perf --diff/--check "
-                        "compare)", p))
 
     if args.all or args.progress:
         # registry-enumerated like --telemetry/--profile: importing the

@@ -15,8 +15,7 @@ transport layer (``opal/mca/btl/btl.h:878-1078``): a kernel that fails
 here would fail on a real v5e slice.
 
 Run: ``python -m ompi_tpu.tools.pallas_aot --out PALLAS_AOT.json``
-(CPU client; no TPU needed).  ``bench.py --pod-smoke`` runs it as a
-pre-gate before the device sweep.
+(CPU client; no TPU needed).
 """
 from __future__ import annotations
 
@@ -201,7 +200,7 @@ def cases(mesh1d, mesh2d):
          _sds((n, n, 2048, 1024), f32, mesh1d, P("x")))))
 
     # -- single-chip hot kernels: the MFU path must be Mosaic-proven
-    # too (flash-attention block update at bench scale + the VPU
+    # too (flash-attention block update at chip_smoke's scale + the VPU
     # reduction kernels behind mca/op).  They take no mesh to read the
     # platform from, so interpret=False is passed EXPLICITLY (a static
     # jit-cache-key ingredient: no cached interpreter trace is served).

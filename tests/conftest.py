@@ -22,9 +22,8 @@ def prewarm_native():
 
     The first ``native.available()`` call may pay a ~2-minute g++
     compile into OTPU_NATIVE_CACHE; letting that land inside whichever
-    test happens to call it first skews timing-sensitive tests (the
-    bench-pin windows in test_perf_guard) and double-compiles under
-    multi-process launches.  Warming here makes every later call a
+    test happens to call it first eats that test's subprocess timeout
+    and double-compiles under multi-process launches.  Warming here makes every later call a
     cheap cache hit — including the tpurun children, which inherit the
     populated cache directory."""
     if os.environ.get("OTPU_NATIVE_DISABLE"):

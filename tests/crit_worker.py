@@ -1,5 +1,5 @@
 """otpu-crit test worker: a fixed number of step-spanned rounds, each
-one chaos-paceable ('delay:ms=8,rank=2,site=step' designs ONE slow
+one chaos-paceable ('delay:ms=40,rank=2,site=step' designs ONE slow
 rank), mixing a collective with a p2p ring exchange so the merged
 timeline carries both barrier edges (coll round keys) and message
 edges (pml flow keys)."""

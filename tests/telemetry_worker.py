@@ -24,7 +24,7 @@ iters = os.environ.get("TW_ITERS")
 if iters is not None:
     for _ in range(int(iters)):
         if chaos.enabled:
-            # the designed-straggler pacing point: 'delay:ms=8,rank=2,
+            # the designed-straggler pacing point: 'delay:ms=40,rank=2,
             # site=step' makes rank 2 arrive late at every collective
             chaos.pace("step")
         w.allreduce(x, op.SUM)

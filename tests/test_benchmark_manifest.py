@@ -1,11 +1,11 @@
 """The benchmark's manifest inside the tier-1 run: ``BENCHMARK.json`` and
 every file it names pass the rules that code can check
 (``benchmark/harness/manifest.validate`` and ``validate_harness``: what
-``benchmark/check_manifest.py`` runs by hand), and the cell ``rank1-ddt``
-is rehearsed at tiny sizes on the CPU devices through ``run_cell``, in a
-copied tree and a process of its own (``run_cell`` boots and finalizes the
-program, freezes the collector and sets JAX's cache options, none of which
-a test worker should keep).  Nothing here is a measurement."""
+``benchmark/check_manifest.py`` runs by hand), and every cell is rehearsed
+at tiny sizes on CPU devices through ``run_cell``, in a copied tree and a
+process of its own (``run_cell`` boots and finalizes the program, freezes
+the collector and sets JAX's cache options, none of which a test worker
+should keep).  Nothing here is a measurement."""
 import json
 import os
 import shutil
@@ -17,21 +17,94 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 CELL = "rank1-ddt"
-DEVICES = 8
+KIB = 1 << 10
+MIB = 1 << 20
 
-# a point's own parameters, cut to a rehearsal; bytes follow from them
+# rank1-ddt: a point's own parameters, cut to a rehearsal; bytes follow
 TINY = {"grid": {514: 18, 130: 10}, "n": {4096: 16, 8192: 64},
         "atoms": {33554432: 4096}, "sent": {4194304: 512}}
 
+
+def _cut_ddt(p):
+    for key, small in TINY.items():
+        if key in p:
+            p[key] = small[p[key]]
+    p["bytes"] = 4 * ((p["grid"] - 2) ** 2 if "grid" in p else
+                      2 * p["n"] ** 2 if "n" in p else 3 * p["sent"])
+
+
+def _cut_bytes(small):
+    """Large points cut to at most 64 KiB, each size to its own so that
+    no two points share a program they do not share at full size.  A
+    point stays on its side of the program's one size threshold: a
+    ``bcast`` of ``bcast_sa_min_bytes`` (256 KiB) or more is the masked
+    all-reduce, a smaller one the tree."""
+    def cut(p):
+        if p["bytes"] >= 4 * MIB:
+            p["bytes"] = 256 * KIB if p["kind"] == "bcast" \
+                else small[p["bytes"]]
+    return cut
+
+
+# a cell: CPU devices (its own ranks, but rank1-ddt, whose typed
+# exchanges need more than one and whose configuration is widened in the
+# copy), points, end-to-end metrics, the traffic files cut in the copy
+# and how, and the shift that cuts the pools with the points
+CELLS = {
+    "osu-2x2-mix": dict(
+        devices=4, points=20, pool_shift=10,
+        metrics={"small_msg_us", "allreduce_busbw", "coll_busbw",
+                 "setup_s"},
+        cut={"large-set": _cut_bytes(
+            {4 * MIB: 16 * KIB, 16 * MIB: 48 * KIB, 64 * MIB: 64 * KIB})}),
+    "rank1-mix": dict(
+        devices=1, points=14, pool_shift=10,
+        metrics={"small_msg_us", "reduce_local_bw", "setup_s"},
+        cut={"reduce-local-set": _cut_bytes(
+            {4 * MIB: 16 * KIB, 16 * MIB: 32 * KIB, 64 * MIB: 64 * KIB})}),
+    "rank1-blocking-xl": dict(
+        devices=1, points=6, pool_shift=12,
+        metrics={"reduce_local_bw", "setup_s"},
+        cut={"reduce-local-xl-set": _cut_bytes(
+            {64 * MIB: 16 * KIB, 128 * MIB: 32 * KIB, 256 * MIB: 64 * KIB})}),
+    "rank1-ddt": dict(
+        devices=8, points=13, pool_shift=10, widen="ddt-device-1chip",
+        metrics={"small_msg_us", "reduce_local_bw", "setup_s"},
+        cut={"ddt-face-transpose-mix": _cut_ddt}),
+}
+NEW_CELLS = [c for c in CELLS if c != CELL]
+
+# the child: run_cell as the command calls it, but for the three
+# arguments it keeps for rehearsals.  What the program counts is read
+# around it: the programs coll/xla names as it builds them, and SPC
+# ``device_program_builds`` when the measured time starts and at the end.
 REHEARSAL = """
 import json, sys
 sys.path[:0] = [{bench!r}, {repo!r}]
 import run
+from harness import protocol
+from ompi_tpu.mca.coll import xla
+from ompi_tpu.runtime import spc
+
+programs, builds = [], []
+name_of, measure = xla._program_name, protocol.measure
+
+def named(coll, variant=None):
+    programs.append(name_of(coll, variant))
+    return programs[-1]
+
+def measured(*args, **kw):
+    builds.append(spc.read("device_program_builds"))
+    return measure(*args, **kw)
+
+xla._program_name, protocol.measure = named, measured
 result = run.run_cell({cell!r}, seed=2147483999, seconds=0.3, trace=False,
                       platform="cpu", root={root!r}, min_window_s=0.002)
-from ompi_tpu.runtime import spc
+builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
-                                 if k.startswith("device_ddt_")}}))
+                                 if k.startswith("device_")}}))
+print("programs " + json.dumps(sorted(set(programs))))
+print("builds " + json.dumps(builds))
 print("result " + json.dumps(result))
 """
 
@@ -85,11 +158,11 @@ def test_rank1_ddt_is_one_chip_under_the_two_one_chip_metrics(mf, real):
         == set(chosen["ddt.vs_manual"])
 
 
-@pytest.fixture(scope="module")
-def rehearsal(tmp_path_factory):
-    """One run of the cell at tiny sizes on ``DEVICES`` CPU devices, in a
-    copy of the benchmark and a process of its own."""
-    root = str(tmp_path_factory.mktemp("bench"))
+def _rehearse(cell, root):
+    """One run of ``cell`` at tiny sizes on its CPU devices, in a copy of
+    the benchmark under ``root`` and a process of its own."""
+    spec = CELLS[cell]
+    devices = spec["devices"]
     bench = os.path.join(root, "benchmark")
     shutil.copytree(BENCH, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -102,27 +175,23 @@ def rehearsal(tmp_path_factory):
             json.dump(obj, f)
 
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
-    edit(os.path.join(root, "BENCHMARK.json"), lambda m: [
-        w.update(chips=DEVICES) for w in m["workloads"]])
-    edit(os.path.join(bench, "configs", "ddt-device-1chip.json"),
-         lambda c: c.update(ranks=DEVICES, chips=DEVICES))
-    edit(os.path.join(bench, "cells", CELL + ".json"),
-         lambda c: c.update(pool_bytes_per_point=64 << 10))
-
-    def cut(mix):
-        for p in mix["points"]:
-            for key, small in TINY.items():
-                if key in p:
-                    p[key] = small[p[key]]
-            p["bytes"] = 4 * ((p["grid"] - 2) ** 2 if "grid" in p else
-                              2 * p["n"] ** 2 if "n" in p else 3 * p["sent"])
-    edit(os.path.join(bench, "traffic", "ddt-face-transpose-mix.json"), cut)
+    if "widen" in spec:     # more devices than the cell's own ranks
+        edit(os.path.join(root, "BENCHMARK.json"), lambda m: [
+            w.update(chips=devices) for w in m["workloads"]])
+        edit(os.path.join(bench, "configs", spec["widen"] + ".json"),
+             lambda c: c.update(ranks=devices, chips=devices))
+    edit(os.path.join(bench, "cells", cell + ".json"),
+         lambda c: c.update(pool_bytes_per_point=max(
+             64 * KIB, c["pool_bytes_per_point"] >> spec["pool_shift"])))
+    for traffic, cut in spec["cut"].items():
+        edit(os.path.join(bench, "traffic", traffic + ".json"),
+             lambda mix: [cut(p) for p in mix["points"]])
 
     env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
-        f"--xla_force_host_platform_device_count={DEVICES}"))
+        f"--xla_force_host_platform_device_count={devices}"))
     done = subprocess.run(
         [sys.executable, "-c", REHEARSAL.format(
-            bench=BENCH, repo=REPO, cell=CELL, root=root)],
+            bench=BENCH, repo=REPO, cell=cell, root=root)],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
     lines = done.stdout.splitlines()
@@ -130,31 +199,116 @@ def rehearsal(tmp_path_factory):
     def tagged(tag):
         return [json.loads(ln[len(tag) + 1:]) for ln in lines
                 if ln.startswith(tag + " ")]
-    return {"points": {p["name"]: p for p in tagged("point")},
+    return {"cell": cell, "spec": spec,
+            "points": {p["name"]: p for p in tagged("point")},
             "run": tagged("run")[0], "counters": tagged("counters")[0],
+            "programs": tagged("programs")[0], "builds": tagged("builds")[0],
             "result": tagged("result")[0]}
 
 
+@pytest.fixture(scope="module")
+def rehearsals(tmp_path_factory):
+    """Each cell's one child, started by the first test that asks."""
+    done = {}
+
+    def of(cell):
+        if cell not in done:
+            done[cell] = _rehearse(
+                cell, str(tmp_path_factory.mktemp("bench-" + cell)))
+        return done[cell]
+    return of
+
+
+@pytest.fixture
+def rehearsal(request, rehearsals):
+    return rehearsals(request.param)
+
+
+def of_cells(*cells):
+    return pytest.mark.parametrize("rehearsal", cells, indirect=True)
+
+
+every_cell = of_cells(*CELLS)
+
+
+@every_cell
 def test_the_rehearsal_is_correct_at_every_point(rehearsal):
-    result = rehearsal["result"]
+    result, spec = rehearsal["result"], rehearsal["spec"]
     assert result["correct"] and result["failed"] == 0
-    assert result["attempted"] > 13 * 2
-    assert result["device"]["count"] == DEVICES
-    assert len(rehearsal["points"]) == 13
+    assert result["attempted"] > spec["points"] * 2
+    assert result["device"]["count"] == spec["devices"]
+    assert len(rehearsal["points"]) == spec["points"]
+    assert all(p["windows"] >= 1 for p in rehearsal["points"].values())
 
 
+@every_cell
 def test_the_rehearsal_reports_the_cells_end_to_end_metrics(rehearsal):
     metrics = rehearsal["result"]["metrics"]
-    assert set(metrics) == {"small_msg_us", "reduce_local_bw", "setup_s"}
+    assert set(metrics) == rehearsal["spec"]["metrics"]
     assert all(m["value"] > 0 for m in metrics.values())
 
 
+@of_cells(*NEW_CELLS)
+def test_the_counters_account_for_the_calls(rehearsal):
+    """SPC ``device_collectives`` moved by what the harness issued
+    (``correct`` holds the two equal): at least the timed calls of every
+    collective point, and not at all where the cell holds none.  Every
+    program was built in set-up."""
+    points = rehearsal["points"].values()
+    timed = sum(p["k"] * p["windows"] for p in points
+                if p["kind"] != "stack_reduce")
+    seen = rehearsal["run"]["spc_device_collectives"]
+    assert seen > timed if timed else seen == 0
+    assert rehearsal["counters"]["device_collectives"] >= seen
+    at_measure, at_end = rehearsal["builds"]
+    assert at_measure == at_end
+    # one program a slot, shape and op; the handle shares allreduce's
+    slots = {(p["kind"].replace("_init", ""), p["op"], p["bytes"])
+             for p in points if p["kind"] != "stack_reduce"}
+    assert at_end == len(slots)
+
+
+@of_cells(*NEW_CELLS)
+def test_a_points_bytes_follow_from_its_parameters(rehearsal):
+    """``bytes`` is S as the traffic file gives it; the bus bytes are
+    nccl-tests' factor times S on four ranks and absent on one; a stack
+    moves every row once and writes one."""
+    n = rehearsal["spec"]["devices"]
+    factor = {"allreduce": 2 * (n - 1) / n, "allgather": (n - 1) / n,
+              "reduce_scatter": (n - 1) / n, "alltoall": (n - 1) / n,
+              "bcast": 1.0}
+    for row in rehearsal["points"].values():
+        assert row["n"] == n and row["bytes"] <= 256 * KIB, row["name"]
+        if row["kind"] == "stack_reduce":
+            assert row["moved_bytes"] == 5 * row["bytes"], row["name"]
+            assert "bus_bytes" not in row
+        elif n > 1:
+            slot = row["kind"].replace("_init", "")
+            assert row["bus_bytes"] == factor[slot] * row["bytes"], \
+                row["name"]
+        else:
+            assert "bus_bytes" not in row and "moved_bytes" not in row
+
+
+@of_cells("osu-2x2-mix")
+def test_both_regimes_of_bcast_are_walked(rehearsal):
+    """The 1 KiB point is the ``ppermute`` tree, the two large ones
+    (cut to ``bcast_sa_min_bytes``, not below) the masked all-reduce."""
+    assert {"otpu_bcast_tree", "otpu_bcast_psum"} <= set(
+        rehearsal["programs"])
+    assert {"otpu_allreduce_sum", "otpu_allreduce_prod", "otpu_allgather",
+            "otpu_reduce_scatter_sum", "otpu_alltoall"} <= set(
+        rehearsal["programs"])
+
+
+@of_cells(CELL)
 def test_moved_bytes_is_twice_the_packed_size(rehearsal):
     for row in rehearsal["points"].values():
         assert row["moved_bytes"] == 2 * row["bytes"], row["name"]
         assert "bus_bytes" not in row
 
 
+@of_cells(CELL)
 def test_plans_and_programs_are_built_in_set_up(rehearsal):
     """One plan a datatype object and count: every point commits its own
     datatype, so 13 (equal regular maps share the plan object, not the

@@ -12,7 +12,7 @@ Four layers of coverage:
 * ``--suggest-ladder``: the draft rules file is schema-valid for
   ``coll/tuned._load_rules``, versioned, and skips colls with no
   ladder;
-* THE acceptance run — a chaos ``delay:ms=8,rank=2,site=step`` 3-rank
+* THE acceptance run — a chaos ``delay:ms=40,rank=2,site=step`` 3-rank
   job: ``--critical-path`` attributes >= 90% of steps to rank 2 with a
   per-stage blame breakdown, flow events link >= 95% of pml sends to
   their recvs in the merged Chrome export, and ``--suggest-ladder``
@@ -438,7 +438,7 @@ def test_analyze_includes_zero_span_payload_ranks(tmp_path):
 
 def test_critical_path_acceptance_designed_slow_rank(tmp_path):
     """THE otpu-crit acceptance (ISSUE 14): chaos
-    ``delay:ms=8,rank=2,site=step`` on a 3-rank job — the critical
+    ``delay:ms=40,rank=2,site=step`` on a 3-rank job — the critical
     path attributes >= 90% of steps to rank 2 with a per-stage blame
     breakdown, flow events link >= 95% of pml sends to their recvs in
     the merged Chrome export, and --suggest-ladder emits a draft rules
@@ -449,7 +449,7 @@ def test_critical_path_acceptance_designed_slow_rank(tmp_path):
     env.pop("OTPU_NPROCS", None)
     env.pop("OTPU_COORD", None)
     cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "3",
-           "--mca", "otpu_chaos_spec", "delay:ms=8,p=1,rank=2,site=step",
+           "--mca", "otpu_chaos_spec", "delay:ms=40,p=1,rank=2,site=step",
            "--mca", "otpu_trace_enable", "1",
            "--mca", "otpu_trace_dir", str(tdir),
            # collectives through the pml datapath so sends are spanned

@@ -25,8 +25,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_aot_subprocess(*extra, **env_extra) -> dict:
-    """Run the AOT gate in a CPU-pinned subprocess, like bench.py's
-    ``_pallas_aot_gate``: compile-only, bounded, and with the topology
+    """Run the AOT gate in a CPU-pinned subprocess: compile-only,
+    bounded, and with the topology
     client's state kept out of the pytest process.  A lowering failure
     fails loudly from the result file."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)

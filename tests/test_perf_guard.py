@@ -1,17 +1,18 @@
-"""Host-path performance regression guards.
+"""Host-path regression guards, as counts.
 
 Round-2 review found `allreduce_host_tuned` collapsing superlinearly at
-4MB (265ms on the 1-core VM — ~12x worse per byte than the 256KB point).
-The fixes (escalating idle backoff + doorbell wakeups, header/payload
-split frames, contiguous-datatype fast paths, zero-copy eager sends,
-scratch-buffer reuse) brought it to ~40ms.  These guards pin the shape of
-the curve, not absolute speed: per-byte cost may not regress superlinearly
-again.  Mirrors the linear degradation of the reference's ring
-(``coll_base_allreduce.c:341``) under fixed bandwidth.
+4MB (~12x worse per byte than the 256KB point on the 1-core VM).  The
+fixes (escalating idle backoff + doorbell wakeups, header/payload split
+frames, contiguous-datatype fast paths, zero-copy eager sends,
+scratch-buffer reuse) are pinned here by what the datapath counts, not
+by a clock: messages and bytes a call puts on the wire, payload copies,
+"disabled means nothing recorded" identities.  Speed is measured on the
+chip (PERF.md, PERF_LEDGER.jsonl); no assertion in this file reads a
+wall clock.  Mirrors the linear volume of the reference's ring
+(``coll_base_allreduce.c:341``).
 """
 import json
 import os
-import statistics
 import subprocess
 import sys
 import textwrap
@@ -19,55 +20,54 @@ import textwrap
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = textwrap.dedent("""
-    import json, statistics, time
+    import json
     import numpy as np, ompi_tpu
+    from ompi_tpu.runtime import spc
 
+    KEYS = ("isend", "bytes_sent", "bytes_packed",
+            "fastpath_payload_copies")
     w = ompi_tpu.init()
     out = []
     for nbytes in (262144, 4194304):
         x = np.ones(nbytes // 4, np.float32)
-        for _ in range(2):
-            w.allreduce(x)
-        lat = []
-        for _ in range(5):
-            w.barrier()
-            t0 = time.perf_counter()
-            w.allreduce(x)
-            lat.append(time.perf_counter() - t0)
-        out.append((nbytes, statistics.median(lat)))
-    if w.rank == 0:
-        print("GUARD " + json.dumps(out))
+        w.allreduce(x)                     # warm: schedule, scratch
+        w.barrier()
+        c0 = spc.counters()
+        y = w.allreduce(x)
+        c1 = spc.counters()
+        assert (np.asarray(y) == w.size).all()
+        out.append([c1.get(k, 0) - c0.get(k, 0) for k in KEYS])
+    print(f"GUARD{w.rank} " + json.dumps(out))
     ompi_tpu.finalize()
 """)
 
 
 def test_allreduce_per_byte_cost_stays_linear(tmp_path):
+    """16x the bytes may put at most 16x the bytes and 16x the messages
+    on the wire, and copy no payload: the round-2 pathology as a count.
+    ^sm_coll isolates the tuned ladder (on one host coll/sm owns
+    sub-slot payloads and sends nothing through the pml)."""
     script = tmp_path / "guard.py"
     script.write_text(_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "4",
-         sys.executable, str(script)],
+         "--mca", "coll", "^sm_coll", sys.executable, str(script)],
         capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
-    line = next(ln for ln in r.stdout.splitlines() if "GUARD" in ln)
-    (small_b, small_t), (big_b, big_t) = json.loads(
-        line.split("GUARD ", 1)[1])
-    per_byte_small = small_t / small_b
-    per_byte_big = big_t / big_b
-    # superlinear collapse guard: 16x the bytes may cost at most ~3x more
-    # per byte (scheduling noise margin included; the round-2 pathology
-    # measured ~12x)
-    assert per_byte_big <= 3.5 * per_byte_small, (
-        f"per-byte cost grew {per_byte_big / per_byte_small:.1f}x "
-        f"from 256KB ({small_t * 1e3:.1f}ms) to 4MB ({big_t * 1e3:.1f}ms)")
-    # absolute backstops: sweep measures 4MB ≈25ms / 256KB ≈1.3ms and
-    # the in-suite harness runs ~1.4x slower (~35ms / ~2ms).  The
-    # linearity assert above is the primary guard; these only catch a
-    # catastrophic (order-of-magnitude) collapse, with enough headroom
-    # that a loaded single-core CI host doesn't flake them
-    assert big_t < 0.30, f"4MB allreduce took {big_t * 1e3:.0f}ms"
-    assert small_t < 0.032, f"256KB allreduce took {small_t * 1e3:.1f}ms"
+    for rank in range(4):
+        line = next(ln for ln in r.stdout.splitlines()
+                    if f"GUARD{rank} " in ln)
+        small, big = json.loads(line.split(f"GUARD{rank} ", 1)[1])
+        s_msgs, s_bytes, s_packed, s_copies = small
+        b_msgs, b_bytes, b_packed, b_copies = big
+        assert s_msgs >= 1 and s_bytes >= 262144, (rank, small)
+        # a ring moves 2(n-1)/n x S a rank: 6 messages, 1.5 x 4MB here
+        assert b_bytes <= 16 * s_bytes, (rank, small, big)
+        assert b_msgs <= 16 * s_msgs, (rank, small, big)
+        # contiguous float32: nothing is packed or copied at either size
+        assert (s_packed, s_copies, b_packed, b_copies) == (0, 0, 0, 0), \
+            (rank, small, big)
 
 
 _FASTPATH_COPY_SCRIPT = textwrap.dedent("""
@@ -317,7 +317,7 @@ def test_small_pack_skips_pool_dispatch(monkeypatch):
 
 
 _TRACE_PIN_SCRIPT = textwrap.dedent("""
-    import json, time
+    import json
     import numpy as np, ompi_tpu
     from ompi_tpu.api import op as op_mod
     from ompi_tpu.runtime import trace
@@ -326,36 +326,17 @@ _TRACE_PIN_SCRIPT = textwrap.dedent("""
     # conductor-world stacked layout: one 1KB row per hosted rank
     x = np.ones((w.size, 256), np.float32)
     wrapped = w.c_coll["allreduce"]          # trace wrapper (outermost)
-    inner = wrapped
-    while hasattr(inner, "__wrapped__"):
-        inner = inner.__wrapped__
-
-    def one(fn, n=2000):
-        for _ in range(100):
-            fn(w, x, op_mod.SUM)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            fn(w, x, op_mod.SUM)
-        return (time.perf_counter() - t0) / n
-
-    # paired, interleaved reps: host-load drift hits both callables in
-    # the same window instead of biasing whichever ran second
-    t_wrapped = t_direct = float("inf")
-    for rep in range(6):
-        if rep % 2:
-            a, b = one(inner), one(wrapped)
-        else:
-            b, a = one(wrapped), one(inner)
-        t_direct = min(t_direct, a)
-        t_wrapped = min(t_wrapped, b)
+    for _ in range(100):
+        wrapped(w, x, op_mod.SUM)
     print("TRACEPIN " + json.dumps(
-        [t_wrapped, t_direct, trace.recorded_count(), len(trace.histograms())]))
+        [hasattr(wrapped, "__wrapped__"), trace.recorded_count(),
+         len(trace.histograms())]))
     ompi_tpu.finalize()
 """)
 
 
 _PREADY_PIN_SCRIPT = textwrap.dedent("""
-    import json, time
+    import json
     import numpy as np, ompi_tpu
     from ompi_tpu.base.var import registry
     from ompi_tpu.mca.part import part_framework
@@ -374,26 +355,19 @@ _PREADY_PIN_SCRIPT = textwrap.dedent("""
     s = a.psend_init(x, P, dest=1, tag=1)
     r = b.precv_init(y, P, source=0, tag=1)
 
-    def epoch():
+    for _ in range(2):
         s.start(); r.start()
-        t0 = time.perf_counter()
-        for p in range(P - 1):
+        for p in range(P):
             s.pready(p)
-        dt = time.perf_counter() - t0
-        s.pready(P - 1)
         s.wait(); r.wait()
-        return dt / (P - 1)
-
-    epoch()                           # warmup
-    per_call = min(epoch() for _ in range(5))
     print("PREADYPIN " + json.dumps(
-        [per_call, trace.recorded_count(), len(trace.histograms())]))
+        [trace.recorded_count(), len(trace.histograms())]))
     ompi_tpu.finalize()
 """)
 
 
 _SESSION_PIN_SCRIPT = textwrap.dedent("""
-    import json, time
+    import json
     import ompi_tpu
     from ompi_tpu.runtime import trace
     from ompi_tpu import instance as inst_mod
@@ -401,29 +375,22 @@ _SESSION_PIN_SCRIPT = textwrap.dedent("""
     w = ompi_tpu.init()           # boots the instance ONCE (held by world)
     boot_inst = inst_mod.current()
 
-    def cycle(n=400):
-        t0 = time.perf_counter()
-        for _ in range(n):
-            s = ompi_tpu.Session.init()
-            s.finalize()
-        return (time.perf_counter() - t0) / n
-
-    cycle(50)                     # warmup
-    per = min(cycle() for _ in range(3))
-    assert inst_mod.current() is boot_inst   # never re-booted
+    for _ in range(50):
+        s = ompi_tpu.Session.init()
+        s.finalize()
+        assert inst_mod.current() is boot_inst   # never re-booted
     print("SESSIONPIN " + json.dumps(
-        [per, trace.recorded_count(), len(trace.histograms())]))
+        [trace.recorded_count(), len(trace.histograms())]))
     ompi_tpu.finalize()
 """)
 
 
 def test_session_acquire_disabled_path_cost(tmp_path):
     """Refcounted Session.init/finalize on an already-booted instance
-    must be bookkeeping only: (a) no RTE re-boot (same instance object
-    throughout — an accidental re-fence/pml re-select would cost ms and
-    trip the bound), (b) zero otpu-trace events/histograms while tracing
-    is disabled (the boot spans are enabled-path only), (c) per-cycle
-    cost far below any boot work; headroom absorbs 1-core CI noise."""
+    must be bookkeeping only: (a) no RTE re-boot (the same instance
+    object after every cycle, asserted in the child), (b) zero
+    otpu-trace events/histograms while tracing is disabled (the boot
+    spans are enabled-path only)."""
     script = tmp_path / "session_pin.py"
     script.write_text(_SESSION_PIN_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -433,24 +400,15 @@ def test_session_acquire_disabled_path_cost(tmp_path):
                        cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "SESSIONPIN" in ln)
-    per_cycle, recorded, hists = json.loads(
-        line.split("SESSIONPIN ", 1)[1])
+    recorded, hists = json.loads(line.split("SESSIONPIN ", 1)[1])
     assert recorded == 0, f"{recorded} trace events while disabled"
     assert hists == 0, f"{hists} histogram bins while disabled"
-    # measured ~3us/cycle (lock + refcount + Session object); 100us of
-    # headroom still catches any boot-path work (fence/pml/modex are
-    # milliseconds) leaking into the refcounted acquire
-    assert per_cycle < 100e-6, \
-        f"session acquire/release costs {per_cycle * 1e6:.1f}us/cycle"
 
 
 def test_pready_disabled_path_overhead(tmp_path):
     """The Pready hot call (one per gradient bucket per step in the
-    overlap pattern) with tracing disabled must stay bookkeeping-cheap
-    and record nothing: (a) zero trace events/histogram bins, (b)
-    per-call cost bounded far below a wire send — a catastrophic
-    regression (per-call flush scan, accidental tracing) trips it, CI
-    scheduler noise does not."""
+    overlap pattern) with tracing disabled must record nothing: zero
+    trace events and histogram bins over two whole epochs."""
     script = tmp_path / "pready_pin.py"
     script.write_text(_PREADY_PIN_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -460,21 +418,16 @@ def test_pready_disabled_path_overhead(tmp_path):
                        cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "PREADYPIN" in ln)
-    per_call, recorded, hists = json.loads(line.split("PREADYPIN ", 1)[1])
+    recorded, hists = json.loads(line.split("PREADYPIN ", 1)[1])
     assert recorded == 0, f"{recorded} trace events while disabled"
     assert hists == 0, f"{hists} histogram bins while disabled"
-    # measured ~3us/call on the 1-core CI VM (spc bump + checks + bitmap
-    # + run merge); 50us of headroom absorbs host load without letting
-    # an O(partitions) scan per call (~0.5ms at P=512) sneak in
-    assert per_call < 50e-6, f"pready costs {per_call * 1e6:.1f}us/call"
 
 
 def test_tracing_disabled_overhead_is_one_flag_check(tmp_path):
     """The otpu-trace coll-table wrapper is installed unconditionally at
     comm_select; with tracing disabled (the default) its cost on the
-    allreduce hot path must be one flag check — pinned as (a) zero
-    events/histograms recorded and (b) per-call overhead vs the
-    unwrapped slot within scheduling noise of the seed."""
+    allreduce hot path must be one flag check — pinned as zero
+    events/histograms recorded over calls through the wrapper."""
     script = tmp_path / "trace_pin.py"
     script.write_text(_TRACE_PIN_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -484,24 +437,12 @@ def test_tracing_disabled_overhead_is_one_flag_check(tmp_path):
                        cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "TRACEPIN" in ln)
-    t_wrapped, t_direct, recorded, hists = json.loads(
+    is_wrapped, recorded, hists = json.loads(
         line.split("TRACEPIN ", 1)[1])
+    assert is_wrapped, "the coll table carries no trace wrapper"
     # the disabled path must not have recorded anything at all
     assert recorded == 0, f"{recorded} events recorded while disabled"
     assert hists == 0, f"{hists} histogram bins touched while disabled"
-    # the measured disabled-path cost is ~0.5us (flag check + argument
-    # forwarding).  The bound is absolute-or-relative: 4us of fixed
-    # headroom, widened to 30% of the direct call on hosts where the
-    # baseline itself is tens of us (scheduler noise scales with call
-    # time on the loaded 1-core CI VM).  Gross per-call work creeping
-    # into the disabled path still trips it, and the zero-records
-    # asserts above catch any accidental recording regardless of
-    # timing.
-    overhead = t_wrapped - t_direct
-    assert overhead < max(4e-6, 0.3 * t_direct), (
-        f"tracing-disabled wrapper costs {overhead * 1e9:.0f}ns/call "
-        f"(wrapped {t_wrapped * 1e6:.2f}us vs direct "
-        f"{t_direct * 1e6:.2f}us)")
 
 
 def test_flow_disabled_zero_overhead():
@@ -717,52 +658,27 @@ _TELEMETRY_PIN_SCRIPT = textwrap.dedent("""
     w = ompi_tpu.init()
     x = np.ones(1024, np.float32)               # the 4KB hot loop
 
-    def one(n=1500):
-        for _ in range(100):
-            w.allreduce(x, op_mod.SUM)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            w.allreduce(x, op_mod.SUM)
-        return (time.perf_counter() - t0) / n
-
     registry.lookup("otpu_telemetry_interval_ms").set(50)
-    # paired, interleaved reps: sampler armed vs disarmed in the same
-    # load window (the TRACEPIN discipline)
-    t_on = t_off = float("inf")
-    for rep in range(6):
-        if rep % 2:
-            telemetry.start(rt.get_rte())
-            a = one()
-            telemetry.stop()
-            b = one()
-        else:
-            b = one()
-            telemetry.start(rt.get_rte())
-            a = one()
-            telemetry.stop()
-        t_on = min(t_on, a)
-        t_off = min(t_off, b)
-    # the 1-rank timing reps can finish inside one 50ms interval; give
-    # the sampler one dedicated window to prove it actually publishes
+    # the sampler publishes on its own thread while the 4KB hot loop
+    # runs; the deadline is for a sampler that never publishes, not a
+    # bound on speed
     telemetry.start(rt.get_rte())
-    time.sleep(0.25)
+    deadline = time.monotonic() + 30
+    while (spc.read("telemetry_samples") < 1
+           and time.monotonic() < deadline):
+        w.allreduce(x, op_mod.SUM)
     telemetry.stop()
     samples = spc.read("telemetry_samples")
-    print("TELEPIN " + json.dumps([t_on, t_off, samples]))
+    print("TELEPIN " + json.dumps([samples]))
     ompi_tpu.finalize()
     srv.close()
 """)
 
 
-def test_telemetry_enabled_overhead_bounded(tmp_path):
+def test_telemetry_enabled_sampler_publishes(tmp_path):
     """The enabled-sampler pin: at a 50ms interval the sampler touches
-    NO hot path (it snapshots counters on its own thread), so the 4KB
-    allreduce loop must cost the same with it running.  The designed
-    overhead is sub-1%; the asserted bound is absolute-or-relative
-    (2us fixed headroom, widened to 30% of the baseline) because the
-    1-core CI VM's scheduler noise dwarfs 1% — gross per-call work
-    (a lock on the allreduce path, a snapshot per call) still trips
-    it.  The sampler must also have actually sampled."""
+    NO hot path (it snapshots counters on its own thread); while the
+    4KB allreduce loop runs it must actually have sampled."""
     script = tmp_path / "tele_pin.py"
     script.write_text(_TELEMETRY_PIN_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -772,12 +688,8 @@ def test_telemetry_enabled_overhead_bounded(tmp_path):
                        cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "TELEPIN" in ln)
-    t_on, t_off, samples = json.loads(line.split("TELEPIN ", 1)[1])
+    (samples,) = json.loads(line.split("TELEPIN ", 1)[1])
     assert samples >= 1, "sampler never published a sample"
-    overhead = t_on - t_off
-    assert overhead < max(2e-6, 0.3 * t_off), (
-        f"telemetry-enabled allreduce costs {overhead * 1e9:.0f}ns/call "
-        f"extra (on {t_on * 1e6:.2f}us vs off {t_off * 1e6:.2f}us)")
 
 
 def test_profile_disabled_zero_overhead():
@@ -823,7 +735,7 @@ def test_profile_disabled_zero_overhead():
 
 
 _PROFILE_PIN_SCRIPT = textwrap.dedent("""
-    import json, os, time
+    import json, os
     from ompi_tpu.rte.coord import CoordServer
 
     srv = CoordServer(1)
@@ -832,7 +744,6 @@ _PROFILE_PIN_SCRIPT = textwrap.dedent("""
     os.environ["OTPU_NPROCS"] = "1"
 
     import numpy as np, ompi_tpu
-    from ompi_tpu.api import op as op_mod
     from ompi_tpu.base.var import registry
     from ompi_tpu.runtime import profile
 
@@ -840,54 +751,29 @@ _PROFILE_PIN_SCRIPT = textwrap.dedent("""
     x = np.ones(1024, np.float32)               # 4KB payload
     buf = np.empty_like(x)
 
-    def one(n=1200):
-        # self send/recv crosses the instrumented pml datapath
-        # (pack -> deliver -> complete) on a 1-rank world, where an
-        # allreduce would shortcut past pml/btl entirely
-        for _ in range(100):
-            w.send(x, dest=0, tag=7)
-            w.recv(buf, source=0, tag=7)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            w.send(x, dest=0, tag=7)
-            w.recv(buf, source=0, tag=7)
-        return (time.perf_counter() - t0) / n
-
+    # self send/recv crosses the instrumented pml datapath
+    # (pack -> deliver -> complete) on a 1-rank world, where an
+    # allreduce would shortcut past pml/btl entirely
     stages_var = registry.lookup("otpu_profile_stages")
-    # paired, interleaved min-of-6 reps: stage clocks armed vs
-    # disarmed in the same load window (the TRACEPIN discipline)
-    t_on = t_off = float("inf")
-    for rep in range(6):
-        if rep % 2:
-            stages_var.set(True)
-            a = one()
-            stages_var.set(False)
-            b = one()
-        else:
-            b = one()
-            stages_var.set(True)
-            a = one()
-            stages_var.set(False)
-        t_on = min(t_on, a)
-        t_off = min(t_off, b)
+    w.send(x, dest=0, tag=7)
+    w.recv(buf, source=0, tag=7)
+    idle = sum(v["n"] for v in profile.stage_stats().values())
     stages_var.set(True)
     w.send(x, dest=0, tag=7)
     w.recv(buf, source=0, tag=7)
     recorded = sum(v["n"] for v in profile.stage_stats().values())
     stages_var.set(False)
-    print("PROFPIN " + json.dumps([t_on, t_off, recorded]))
+    print("PROFPIN " + json.dumps([idle, recorded]))
     ompi_tpu.finalize()
     srv.close()
 """)
 
 
-def test_profile_enabled_overhead_bounded(tmp_path):
+def test_profile_enabled_stage_clocks_record(tmp_path):
     """The enabled-stage-clock pin: armed, a 4KB self send/recv pays a
-    few perf_counter_ns pairs + locked histogram folds per message —
-    designed low single-digit us on a tens-of-us e2e.  Asserted
-    absolute-or-relative (4us fixed headroom, widened to 35% of the
-    baseline: 1-core CI scheduler noise) via paired interleaved
-    min-of-6 reps.  The clocks must also have actually recorded."""
+    few perf_counter_ns pairs + locked histogram folds per message, so
+    the clocks must have recorded; disarmed, the same message must
+    have recorded nothing."""
     script = tmp_path / "prof_pin.py"
     script.write_text(_PROFILE_PIN_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -897,9 +783,6 @@ def test_profile_enabled_overhead_bounded(tmp_path):
                        cwd=REPO, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "PROFPIN" in ln)
-    t_on, t_off, recorded = json.loads(line.split("PROFPIN ", 1)[1])
+    idle, recorded = json.loads(line.split("PROFPIN ", 1)[1])
+    assert idle == 0, f"{idle} stage records while disarmed"
     assert recorded >= 1, "stage clocks never recorded while armed"
-    overhead = t_on - t_off
-    assert overhead < max(4e-6, 0.35 * t_off), (
-        f"stage-clock-armed allreduce costs {overhead * 1e9:.0f}ns/call "
-        f"extra (on {t_on * 1e6:.2f}us vs off {t_off * 1e6:.2f}us)")

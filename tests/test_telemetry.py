@@ -521,7 +521,7 @@ def test_flight_bundle_on_chaos_kill(tmp_path):
 
 def test_analyzer_names_designed_straggler(tmp_path):
     """THE analyzer acceptance: a chaos ``delay`` scoped to one rank
-    (``delay:ms=8,rank=2,site=step`` — the per-step pacing point) of a
+    (``delay:ms=40,rank=2,site=step`` — the per-step pacing point) of a
     3-rank collective loop — otpu_analyze names rank 2 as the
     straggler for >= 90% of collectives."""
     tdir = tmp_path / "trace"
@@ -529,7 +529,7 @@ def test_analyzer_names_designed_straggler(tmp_path):
     env.pop("OTPU_RANK", None)
     env.pop("OTPU_NPROCS", None)
     cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "3",
-           "--mca", "otpu_chaos_spec", "delay:ms=8,p=1,rank=2,site=step",
+           "--mca", "otpu_chaos_spec", "delay:ms=40,p=1,rank=2,site=step",
            "--mca", "otpu_trace_enable", "1",
            "--mca", "otpu_trace_dir", str(tdir),
            sys.executable, str(WORKER)]
