@@ -13,8 +13,19 @@ never from the constructor that wrote it:
   reshape, a slice and a transpose, which XLA runs as one strided copy
   that reads only the tiles it needs; an unpack is the same backwards
   (a pad into zeros, or a ``dynamic_update_slice`` into ``into``).
-* **index list**: everything else.  A device ``int32`` array of block
-  starts in elements, gathered and scattered.
+* **index list**: everything else, with two executions of a pack, chosen
+  at build from the index array alone.  *Gathered*: a device ``int32``
+  array of block starts in elements, ``lax.gather``: 19-21 ns an index on
+  a v5e whatever the list looks like.  *Streamed*: where the starts are
+  sorted and distinct, the type is 4 bytes wide and the list is dense
+  (128 packed elements come from at most ``STREAM_SLABS`` x 8 consecutive
+  128-lane rows of the buffer: about one element in 128 or more), the
+  span is read once through VMEM and compacted
+  there (``ops/pallas_ddt.compact``): the plan holds, for every packed
+  element, its source row within its output row's run and its lane as
+  one ``int32``, and the index list itself reaches the device only when
+  an unpack asks for it.  Unsorted, overlapping, sparse and 2- or 8-byte
+  lists are gathered as before; an unpack is a scatter either way.
 
 A plan traces; it owns no program.  ``mca/accelerator/jax_acc`` jits it
 for ``pack_array`` / ``unpack_array``, ``mca/coll/xla`` traces it inside a
@@ -95,6 +106,7 @@ class Plan:
     (equal keys trace equal programs)."""
 
     form = ""
+    stream = None       # an index list's streaming tables, where it has them
 
     def __init__(self, dtype, packed: int, need: int, hi: int, key) -> None:
         self.dtype = np.dtype(dtype)
@@ -104,9 +116,10 @@ class Plan:
         self.key = key
         self.programs: dict = {}        # jax_acc's jitted pack / unpack
 
-    def index_args(self, sharding=None) -> tuple:
-        """Device arrays the traced functions take after the buffer (an
-        index list is an argument, not a constant of the program)."""
+    def index_args(self, which: str, sharding=None) -> tuple:
+        """Device arrays the traced ``which`` (``"pack"`` or ``"unpack"``)
+        takes after the buffer (an index list is an argument, not a
+        constant of the program)."""
         return ()
 
     def _flat(self, x, length: Optional[int] = None):
@@ -277,6 +290,27 @@ class RegularPlan(Plan):
         return out.reshape(-1)[:length].reshape(shape)
 
 
+# What decides how an index list packs.  XLA:TPU's gather costs 19-21 ns
+# an index sorted or not, by slice or by element (12,582,912 elements in
+# 239.7-270.9 ms on a v5e, PR 27 chip runs; 267.5 ms, PR 30), so a row of
+# 128 packed elements costs it about 2.5 us.  The streaming kernel pays
+# for a row about 4 ns a slab of 8 source rows (98,304 rows: 1.76 ms at 2
+# slabs, 2.17 at 3, 8 rows a pass; 1.10 ms at 2 slabs and 32 rows a pass:
+# PR 30 chip runs) and 0.7 ns a 128-lane row it streams past.  At
+# STREAM_SLABS a row is still under 0.1 us where the gather's is 2.5; the
+# limit is where the unrolled kernel body stops being small, not where
+# the gather would win.
+STREAM_SLABS = 16       # slabs of 8 source rows one output row may span
+STREAM_TILE = 256       # output rows a grid step, halved until a step's
+STREAM_WINDOW = 4096    # source rows fit (2 MiB, held twice in VMEM)
+
+
+def _stream_rows(length: int, height: int) -> int:
+    """The 128-lane rows the streaming kernel sees of a buffer of
+    ``length`` elements: whole (8, 128) tiles, at least one window."""
+    return max(-(-length // 1024) * 8, height)
+
+
 class IndexPlan(Plan):
     form = "index"
 
@@ -290,16 +324,69 @@ class IndexPlan(Plan):
         self._device: dict = {}
         super().__init__(dtype, len(starts) * block, need, hi,
                          ("index", next(_serial)))
+        self.stream = self._stream_tables()
+        if self.stream is not None:
+            spc.record("device_ddt_stream_plans")
 
-    def index_args(self, sharding=None) -> tuple:
-        """The index list on the device, placed once a placement."""
+    def _stream_tables(self):
+        """``((windows, bases, table), {tile, height, slabs})``, the
+        arrays and the keywords of ``ops/pallas_ddt.compact``, where the
+        list streams (the module docstring says when), else None.
+        Whole-array numpy: nothing a block in Python."""
+        if not self.unique or self.dtype.itemsize != 4:
+            return None
+        from ompi_tpu.ops.pallas_ddt import LANES
+
+        out_rows = -(-self.packed // LANES)
+        tile = STREAM_TILE
+        while tile > 8 and tile // 2 >= out_rows:
+            tile //= 2          # a short list: one step, no larger than it
+        # every packed element's source, in whole steps of output rows
+        # (the last element repeated to the end)
+        table = np.empty(-(-out_rows // tile) * tile * LANES, np.int32)
+        np.add(self.index[:, None], np.arange(self.block, dtype=np.int32),
+               out=table[:self.packed].reshape(-1, self.block))
+        table[self.packed:] = table[self.packed - 1]
+        table = table.reshape(-1, LANES)
+        # source rows of each output row's first and last element
+        first, last = table[:, 0] >> 7, table[:, -1] >> 7
+        slabs = int((last - first).max()) // 8 + 1
+        if slabs > STREAM_SLABS:
+            return None
+
+        def span(tile):         # each step's first row, the tallest window
+            windows = first[::tile] & ~7
+            return windows, (int((last[tile - 1::tile] - windows).max())
+                             + 8) & ~7
+
+        windows, height = span(tile)
+        while height > STREAM_WINDOW:   # ends: 8 rows of 16 slabs are 1032
+            tile //= 2
+            windows, height = span(tile)
+        # the buffer as the kernel sees it: whole (8, 128) tiles, at least
+        # one window; the last windows start early enough to end inside
+        # it, and a row's slabs inside its window
+        windows = np.minimum(windows,
+                             _stream_rows(self.need, height) - height)
+        origin = np.repeat(windows, tile)
+        bases = np.minimum(first - origin, height - 8 * slabs)
+        table -= (origin + bases)[:, None] << 7
+        return (windows, bases, table), dict(tile=tile, height=height,
+                                             slabs=slabs)
+
+    def index_args(self, which: str, sharding=None) -> tuple:
+        """A streamed pack takes its three tables, everything else the
+        index list; each on the device once a placement, from the first
+        call that needs it."""
         import jax
 
-        arr = self._device.get(sharding)
-        if arr is None:
-            arr = self._device[sharding] = jax.device_put(self.index,
-                                                          sharding)
-        return (arr,)
+        stream = which == "pack" and self.stream is not None
+        args = self._device.get((stream, sharding))
+        if args is None:
+            host = self.stream[0] if stream else (self.index,)
+            args = self._device[stream, sharding] = tuple(
+                jax.device_put(a, sharding) for a in host)
+        return args
 
     def _dnums(self):
         from jax import lax
@@ -311,11 +398,24 @@ class IndexPlan(Plan):
                     update_window_dims=(1,), inserted_window_dims=(),
                     scatter_dims_to_operand_dims=(0,)))
 
-    def pack(self, x, index):
+    def pack(self, x, *tables):
+        flat = self._flat(x)
+        if self.stream is not None:
+            import jax.numpy as jnp
+
+            from ompi_tpu.ops.pallas_ddt import LANES, compact
+
+            geometry = self.stream[1]
+            rows = _stream_rows(flat.shape[0], geometry["height"])
+            if rows * LANES != flat.shape[0]:   # not whole tiles: a copy
+                flat = jnp.pad(flat, (0, rows * LANES - flat.shape[0]))
+            out = compact(flat.reshape(1, rows, LANES), *tables,
+                          **geometry).reshape(-1)
+            return out if out.shape[0] == self.packed else out[:self.packed]
         from jax import lax
 
         out = lax.gather(
-            self._flat(x), index[:, None], self._dnums()[0],
+            flat, tables[0][:, None], self._dnums()[0],
             slice_sizes=(self.block,), indices_are_sorted=self.sorted,
             unique_indices=self.unique,
             mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)

@@ -377,10 +377,13 @@ def _ddt_program(plan, which: str, with_into: bool = False):
     return fn
 
 
-def _ddt_run(span: str, counter: str, plan, fn, *args):
+def _ddt_run(which: str, plan, fn, *args):
+    span, counter = f"otpu.ddt.{which}", f"device_ddt_{which}s"
     spc.record(counter)
     spc.record("device_ddt_bytes", plan.packed * plan.dtype.itemsize)
-    args += plan.index_args()
+    if which == "pack" and plan.stream is not None:
+        spc.record("device_ddt_stream_packs")
+    args += plan.index_args(which)
     if trace.profiler_on():
         with trace.profiler_span(span):
             return fn(*args)
@@ -395,8 +398,7 @@ def device_pack(x, count: int, datatype):
     from ompi_tpu.datatype.plan import plan_for
 
     plan = plan_for(datatype, count)
-    return _ddt_run("otpu.ddt.pack", "device_ddt_packs", plan,
-                    _ddt_program(plan, "pack"), x)
+    return _ddt_run("pack", plan, _ddt_program(plan, "pack"), x)
 
 
 def device_unpack(packed, count: int, datatype, into=None):
@@ -410,8 +412,7 @@ def device_unpack(packed, count: int, datatype, into=None):
     plan = plan_for(datatype, count)
     fn = _ddt_program(plan, "unpack", into is not None)
     args = (packed,) if into is None else (packed, into)
-    return _ddt_run("otpu.ddt.unpack", "device_ddt_unpacks", plan, fn,
-                    *args)
+    return _ddt_run("unpack", plan, fn, *args)
 
 
 def to_host(x) -> np.ndarray:

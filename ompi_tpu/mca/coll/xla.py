@@ -688,7 +688,11 @@ class XlaCollModule:
             else:
                 def cross(t):
                     return jax.lax.ppermute(t, ax, args[0])
-            n_send = 0 if send is None else len(send.index_args())
+            index = tuple(
+                () if p is None else p.index_args(which, self._replicated)
+                for p, which in zip(plans, ("pack", "unpack")))
+            n_send = len(index[0])
+            index = index[0] + index[1]
 
             def body(t, *index):
                 pack = None if send is None else (
@@ -703,8 +707,6 @@ class XlaCollModule:
                 t = cross(t)
                 return t if unpack is None else unpack(t)
 
-            index = tuple(a for p in plans if p is not None
-                          for a in p.index_args(self._replicated))
             prog = self._shard_map(
                 body, (P(ax),) + (P(),) * len(index), P(ax),
                 name=_program_name(coll, "ddt"))

@@ -25,9 +25,12 @@ _COUNTERS = (
     # the datatype engine's device path (datatype/plan behind
     # mca/accelerator): pack_array / unpack_array calls, the bytes of
     # their packed streams, plans built (one a datatype and count, so
-    # none once a loop is warm) and how many of those were index lists
+    # none once a loop is warm), how many of those were index lists, how
+    # many of the index lists pack by streaming their span (sorted, dense,
+    # 4-byte) and the pack_array calls that ran that kernel
     "device_ddt_packs", "device_ddt_unpacks", "device_ddt_bytes",
     "device_ddt_plan_builds", "device_ddt_index_plans",
+    "device_ddt_stream_plans", "device_ddt_stream_packs",
     # fastpath counters: the zero-copy host-datapath contract, pinned by
     # test_perf_guard (payload copies on the contiguous tcp send path
     # must stay 0; the schedule cache must hit on repeated collectives)

@@ -253,6 +253,35 @@ def cases(mesh1d, mesh2d):
         pr.reduce_stack, ("PROD", _sds((4, 1, ROW // 4), f32, one, P())),
         {"interpret": False}))
 
+    # -- the datatype engine's streaming pack of an index list, at the
+    # benchmark cell's own size and ids (``benchmark/harness/ddtkit
+    # .atom_ids`` of ``ddt_pack.lammps_atomic.f32.4Mof32M``: 4,194,304 of
+    # 33,554,432 atoms, three float32 each).  The whole of ``IndexPlan
+    # .pack`` is compiled, so ``entry_ops`` shows what XLA puts around the
+    # kernel: the three element gathers it replaced were ``fusion``s
+    # (270 ms a call on the chip), a relayout would be a ``copy``
+    def ddt_compact():
+        import zlib
+
+        import jax
+
+        from ompi_tpu import datatype as dt
+        from ompi_tpu.datatype.plan import plan_for
+        from ompi_tpu.ops import pallas_ddt
+
+        name, atoms, sent = "ddt_pack.lammps_atomic.f32.4Mof32M", 1 << 25, \
+            1 << 22
+        rng = _np.random.default_rng(zlib.crc32(name.encode()) & 0x7FFFFFFF)
+        ids = _np.sort(rng.choice(atoms, sent, replace=False))
+        plan = plan_for(dt.indexed_block(3, 3 * ids, dt.FLOAT32).commit())
+        # this process's devices are CPUs; the described chip takes Mosaic
+        pallas_ddt.pallas_interpret = lambda: False
+        return jax.jit(plan.pack), (
+            _sds((3 * atoms,), f32, one, P()),
+            *(_sds(a.shape, a.dtype, one, P()) for a in plan.stream[0]))
+
+    case("ddt_compact_lammps_f32", ddt_compact)
+
     # -- coll/quant codec kernels: encode / dequant-accumulate / decode
     # lower through Mosaic at sweep scale (1M-element operands, 8-rank
     # stacks).

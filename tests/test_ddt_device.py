@@ -42,14 +42,27 @@ def _pattern(name, size, named):
         t = dt.resized(dt.vector(n, 2, 2 * n, named), 0, 8).commit()
         return (n, 2 * n), t, n, lambda x: x.reshape(n, n, 2).transpose(
             1, 0, 2).ravel()
-    ids = _ids(size, size // 8)
-    t = dt.indexed_block(3, 3 * ids, named).commit()
-    return (3 * size,), t, 1, lambda x: x.reshape(-1, 3)[ids].ravel()
+    # an index list: ``size`` atoms of which one in 8 is sent, three
+    # elements each (LAMMPS_atomic), or (atoms, one in how many, elements)
+    atoms, one_in, block = size if name == "list" else (size, 8, 3)
+    ids = _ids(atoms, atoms // one_in)
+    t = dt.indexed_block(block, block * ids, named).commit()
+    return (block * atoms,), t, 1, lambda x: x.reshape(-1, block)[
+        ids].ravel()
 
 
+# sorted, distinct and dense: every one streams (``plan.stream``), from one
+# element in two to one in 64, blocks of 1, 2, 3 and 100; 240,000 atoms
+# make three grid steps of which the last is short (its window is moved
+# back inside the buffer), 16,384 of 2**20 take steps of 32 rows to keep a
+# window under ``STREAM_WINDOW``, 600 atoms of 100 end in half an output row
+LISTS = [("lammps_atomic", 4096), ("lammps_atomic", 240000),
+         ("list", (6000, 2, 1)), ("list", (14400, 16, 2)),
+         ("list", (1 << 20, 64, 1)), ("list", (600, 3, 100))]
 PATTERNS = [(f, g) for g in (10, 18) for f in FACES] + [
-    ("fft2", 16), ("fft2", 64), ("lammps_atomic", 4096)]
-IDS = [f"{n}.{s}" for n, s in PATTERNS]
+    ("fft2", 16), ("fft2", 64)] + LISTS
+IDS = ["{}.{}".format(n, "x".join(map(str, s)) if n == "list" else s)
+       for n, s in PATTERNS]
 
 
 def _values(shape, dtype, seed=0):
@@ -133,6 +146,100 @@ def test_the_form_is_read_from_the_map(pattern, form):
     assert type(plan) is form
     assert plan_for(t, count) is plan           # cached on the datatype
     assert plan.packed * 4 == count * t.size
+
+
+def _bits(n, seed=9):
+    """float32 of every kind: random bit patterns (NaNs with payloads and
+    subnormals among them) behind a few chosen by hand."""
+    bits = np.random.default_rng(seed).integers(0, 1 << 32, n,
+                                                dtype=np.uint32)
+    bits[:6] = [0x80000000, 0x7FC00001, 0xFFA5A5A5, 0x00000001,
+                0x807FFFFF, 0x7F800000]  # -0.0, two NaNs, subnormals, inf
+    return bits
+
+
+@pytest.mark.parametrize("pattern", LISTS, ids=IDS[-len(LISTS):])
+def test_a_dense_sorted_list_streams_and_moves_bits(pattern):
+    """The streaming form is taken and the packed stream is numpy's
+    indexing of the same bytes bit for bit, NaN payloads, ``-0.0`` and
+    subnormals included; the index list itself stays on the host until
+    an unpack asks for it."""
+    import jax
+
+    (n,), t, count, index = _pattern(*pattern, dt.FLOAT32)
+    t = t.dup()                                     # a plan of its own
+    spc.init()
+    before = [spc.read("device_ddt_stream_plans"),
+              spc.read("device_ddt_stream_packs")]
+    bits = np.roll(_bits(n), int(index(np.arange(n))[0]))  # specials sent
+    got = dt.pack_array(_dev(bits.view(np.float32)), count, t)
+    plan = plan_for(t, count)
+    assert isinstance(plan, IndexPlan) and plan.stream is not None
+    ops = str(jax.make_jaxpr(plan.pack)(_dev(bits.view(np.float32)),
+                                        *plan.index_args("pack")))
+    assert "pallas_call" in ops
+    assert got.dtype == np.float32
+    assert _same_bits(np.asarray(got).view(np.uint32), index(bits))
+    assert [spc.read("device_ddt_stream_plans"),
+            spc.read("device_ddt_stream_packs")] == [before[0] + 1,
+                                                     before[1] + 1]
+    # a buffer longer than the map needs, and not whole tiles
+    longer = np.concatenate([bits, _bits(77, seed=10)])
+    got = dt.pack_array(_dev(longer.view(np.float32)), count, t)
+    assert _same_bits(np.asarray(got).view(np.uint32), index(bits))
+    assert list(plan._device) == [(True, None)]     # the tables alone
+    dt.unpack_array(got, count, t)
+    assert set(plan._device) == {(True, None), (False, None)}
+    assert plan._device[False, None][0].shape == plan.index.shape
+
+
+def _unsorted(named):
+    ids = _ids(4096, 512)[::-1].copy()
+    return dt.indexed_block(3, 3 * ids, named), 3 * 4096
+
+
+def _overlapping(named):
+    starts = np.arange(0, 3000, 2)          # blocks of 3 share an element
+    return dt.indexed_block(3, starts, named), 3002
+
+
+def _sparse(named):
+    ids = _ids(1 << 22, 400)                # one block in 10,000
+    return dt.indexed_block(3, 3 * ids, named), 3 << 22
+
+
+def _dense(named):
+    return dt.indexed_block(3, 3 * _ids(4096, 512), named), 3 * 4096
+
+
+@pytest.mark.parametrize("make,named", [
+    (_unsorted, dt.FLOAT32), (_overlapping, dt.FLOAT32),
+    (_sparse, dt.INT32), (_dense, dt.INT16), (_dense, dt.BFLOAT16),
+    (_dense, dt.INT64)], ids=["unsorted", "overlapping", "sparse", "int16",
+                              "bfloat16", "int64"])
+def test_every_other_list_keeps_the_gather(make, named):
+    """Chosen from the index array and the type alone: an unsorted list,
+    one whose blocks overlap, one in 10,000 (a window over
+    ``STREAM_SLABS``) and 2- or 8-byte types trace the program they
+    traced before there was a kernel."""
+    import jax
+
+    t, n = make(named)
+    t = t.commit()
+    spc.init()
+    before = spc.read("device_ddt_stream_plans")
+    plan = plan_for(t, 1)
+    assert isinstance(plan, IndexPlan) and plan.stream is None
+    assert spc.read("device_ddt_stream_plans") == before
+    dtype = np.dtype(t.runs[2])
+    if dtype.itemsize == 8:
+        return                              # no 8-byte arrays without x64
+    x = np.random.default_rng(11).integers(0, 1 << 16, n).astype(dtype)
+    tables = plan.index_args("pack")
+    assert [a.shape for a in tables] == [plan.index.shape]
+    ops = str(jax.make_jaxpr(plan.pack)(_dev(x), *tables))
+    assert "gather" in ops and "pallas_call" not in ops
+    assert _same_bits(dt.pack_array(_dev(x), 1, t), _host_pack(x, 1, t))
 
 
 def test_fft2_is_one_transpose():
@@ -469,6 +576,27 @@ def test_typed_ppermute_is_pack_plain_slot_unpack(module, recv):
     assert _same_bits(moved, numpy_way)
 
 
+@pytest.mark.parametrize("pattern", LISTS[:1] + LISTS[2:4],
+                         ids=IDS[-len(LISTS):][:1] + IDS[-len(LISTS):][2:4])
+def test_typed_ppermute_streams_an_index_list(module, pattern):
+    """The streaming pack inside the slot's one program, vmapped over the
+    ranks: the tables are the program's arguments, the index list is not
+    (no unpack), and the bits are numpy's."""
+    n = module.n
+    (size,), t, count, index = _pattern(*pattern, dt.FLOAT32)
+    t = t.dup()
+    bits = np.stack([_bits(size, seed=20 + i) for i in range(n)])
+    perm = tuple((i, (i + 1) % n) for i in range(n))
+    out = module.ppermute_array(
+        None, module.make_world_array(bits.view(np.float32)), perm,
+        sendtype=t, count=count)
+    plan = plan_for(t, count)
+    assert plan.stream is not None
+    assert list(plan._device) == [(True, module._replicated)]
+    assert _same_bits(np.asarray(out).view(np.uint32), np.stack(
+        [index(bits[(i - 1) % n]) for i in range(n)]))
+
+
 def test_to_self_on_the_identity_permutation(module):
     n, g = module.n, 18
     _, face, _, index = _pattern("mg_z", g, dt.FLOAT32)
@@ -479,8 +607,10 @@ def test_to_self_on_the_identity_permutation(module):
     assert _same_bits(out, np.stack([index(r) for r in x]))
 
 
-@pytest.mark.parametrize("pattern", [("fft2", 16), ("lammps_atomic", 64)],
-                         ids=["regular", "index"])
+@pytest.mark.parametrize("pattern", [
+    ("fft2", 16), ("lammps_atomic", 64), ("lammps_atomic", 4096),
+    ("list", (600, 3, 100))], ids=["regular", "index", "stream",
+                                   "stream100"])
 def test_typed_alltoall_is_pack_plain_slot_unpack(module, pattern):
     n = module.n
     shape, t, count, index = _pattern(*pattern, dt.FLOAT32)

@@ -85,6 +85,26 @@ def test_reduce_stack_aot_holds_the_kernel_alone():
         assert set(ops) <= {"custom-call", "bitcast"}, (name, ops)
 
 
+def test_index_list_stream_aot_holds_the_kernel_alone():
+    """``IndexPlan.pack`` of the LAMMPS list at ``rank1-ddt``'s own size
+    (4,194,304 blocks of 3 out of a (100663296,) float32 buffer, the
+    cell's ids) for one v5e device: the streaming kernel and bitcasts.
+    The parent's program was three element-gather ``fusion``s with a
+    ``copy`` and a ``reshape`` (270 ms a call on the chip); a relayout in
+    front of or behind the kernel would show as a ``copy`` or a
+    ``fusion`` too."""
+    pytest.importorskip("libtpu")
+    res = _run_aot_subprocess("--only", "ddt_compact", "--topology",
+                              "v5e:2x2")
+    assert res.get("rows"), res.get("error")
+    (row,) = res["rows"]
+    assert row["kernel"] == "ddt_compact_lammps_f32"
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    ops = row["entry_ops"]
+    assert ops.get("custom-call") == 1, ops
+    assert set(ops) <= {"custom-call", "bitcast"}, ops
+
+
 @pytest.mark.slow
 def test_all_kernels_aot_compile():
     pytest.importorskip("libtpu")
@@ -113,6 +133,7 @@ def test_all_kernels_aot_compile():
                    "vpu_reduce_stack_rows_prod_f32",
                    "vpu_reduce_stack_rows_band_i32",
                    "vpu_reduce_stack_gathered_prod_f32",
+                   "ddt_compact_lammps_f32",
                    # the composed flagship step
                    "train_step_1dev", "train_step_2x2"):
         assert expect in names, f"AOT case list lost {expect}"
