@@ -13,8 +13,9 @@ times S over the time, where S, the size nccl-tests prints, is
 * alltoall: the bytes one rank puts in (n blocks); factor (n-1)/n;
 * broadcast: the bytes of the message; factor 1.
 
-A point's ``bytes`` is S.  (``bench._bus_factor`` gives broadcast
-(n-1)/n; nccl-tests gives it 1, and the benchmark follows nccl-tests.)
+A point's ``bytes`` is S.  (The old ``bench.py``, deleted in PR 29, gave
+broadcast (n-1)/n; nccl-tests gives it 1, and the benchmark follows
+nccl-tests.)
 """
 from __future__ import annotations
 

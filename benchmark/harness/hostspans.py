@@ -202,8 +202,9 @@ class Run:
                              "trace's windows")
         lo, hi = tr.window_of(host)
         dev = min(events["device"], key=int)    # as reduce_trace: the first
+        self.programs = tr.programs_per_call(events)    # observed, a point
         dev_windows = tr.device_windows(self.windows, events["modules"][dev],
-                                        events["calls"])
+                                        tr.window_programs(events))
         shift = max([0] + [hs - ds for (_, hs, _), (_, ds, _)
                            in zip(self.windows, dev_windows)])
         busy = tr.clip(tr.merge((s + shift, s + d + shift)
@@ -288,7 +289,8 @@ def write_table(ctx: dict, reader_file: str, name: str, table) -> None:
         json.dump(table, f, indent=1)
 
 
-def split_point(run: Run, point: str, k: int, span, part=None, child=None):
+def split_point(run: Run, point: str, k: int, span, part=None, child=None,
+                per: str = "call"):
     """One part of a call, over every call of ``point``'s traced
     windows: per call, the spans matching ``part`` inside the one
     matching ``span`` (the span itself without ``part``), less what
@@ -296,18 +298,27 @@ def split_point(run: Run, point: str, k: int, span, part=None, child=None):
     worker threads the call waited for (``issuing_thread``), and what
     is counted is the time of the call in which the thread or any
     worker was in a matching event (workers run beside one another:
-    wall time, not thread time).  Returns a row, or a string saying why
-    not: a window that does not hold exactly ``k`` spans, a ``part`` or a
-    ``child`` that matches nothing anywhere."""
+    wall time, not thread time).  With ``per`` ``"launch"`` the span is
+    one a program launched, not one a call (a step of B buckets holds B
+    of them), and a value is one launch's.  Returns a row, or a string
+    saying why not: a window that does not hold exactly ``k`` spans
+    (``k`` times the point's observed programs a call where the span is
+    per launch), a ``part`` or a ``child`` that matches nothing
+    anywhere."""
     values, issue_ns, windows = [], 0, 0
     found_part = found_child = False
+    if per not in ("call", "launch"):
+        return f"{point}: per is 'call' or 'launch', not {per!r}"
+    want = k * run.programs.get(point, 1) if per == "launch" else k
     for issue in run.issues:
         if issue.name != tr.ISSUE + point:
             continue
         calls = issue.outermost(span)
-        if len(calls) != k:
+        if len(calls) != want:
             return (f"{point}: a traced window holds {len(calls)} spans "
-                    f"matching {span.pattern!r}, the harness issued {k}")
+                    f"matching {span.pattern!r}, the harness issued {k} "
+                    f"calls" + (f" of {want // k} programs each"
+                                if per == "launch" else ""))
         windows += 1
         issue_ns += issue.dur
         for call in calls:

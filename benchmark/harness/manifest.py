@@ -8,6 +8,7 @@ file).  A rule that fails is one line of text; an empty list passes.
 """
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -430,6 +431,8 @@ def validate_harness(manifest: dict, root: str = REPO_ROOT) -> list:
     for k in sorted(kinds):
         if not os.path.isfile(code_file("kinds", k, bench_dir)):
             errors.append(f"call kind {k!r}: no kinds/{k}.py")
+        else:
+            errors += _check_tolerance(k, code_file("kinds", k, bench_dir))
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         try:
             reader = metric_spec(m["name"], bench_dir).get("reader")
@@ -452,6 +455,36 @@ def validate_harness(manifest: dict, root: str = REPO_ROOT) -> list:
                     errors.append(f"{sub}/{fn}: text outside printable "
                                   f"ASCII: {text[:40]!r}")
     return errors
+
+
+def _check_tolerance(kind: str, path: str) -> list:
+    """A kind's results equal its reference bit for bit unless it states
+    ``TOLERANCE = {"rtol": ..., "atol": ..., "why": "<one line>"}`` as a
+    literal at the top of its file (read, not run): two numbers from 0,
+    and a reason that is not empty."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), path)
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "TOLERANCE"
+                for t in node.targets):
+            try:
+                tol = ast.literal_eval(node.value)
+            except ValueError:
+                return [f"call kind {kind!r}: TOLERANCE is not a literal"]
+            ok = (isinstance(tol, dict)
+                  and set(tol) == {"rtol", "atol", "why"}
+                  and all(isinstance(tol[k], (int, float))
+                          and not isinstance(tol[k], bool) and tol[k] >= 0
+                          for k in ("rtol", "atol")))
+            if not ok:
+                return [f"call kind {kind!r}: TOLERANCE has exactly rtol "
+                        "and atol (numbers from 0) and why"]
+            if not (one_line(tol["why"]) and tol["why"].strip()):
+                return [f"call kind {kind!r}: TOLERANCE needs a why, one "
+                        "line that says what a result within it still "
+                        "proves"]
+    return []
 
 
 def _strings(node):

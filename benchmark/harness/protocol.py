@@ -65,17 +65,18 @@ class Env:
         self.axis = probe.sharding.spec[0]
         self._generators: dict = {}
 
-    def generator(self, shape, dtype: str, op: str, sharding):
+    def generator(self, shape, dtype: str, op: str, sharding,
+                  values: str | None = None):
         """The jitted ``key -> array`` that makes one input on the
-        device.  Points of one shape, type and op share it: every
-        program a run builds or loads is set-up (about 0.4 s each on the
-        v5e machine, cache hit or not)."""
+        device.  Points of one shape, type, op and ``values`` share it:
+        every program a run builds or loads is set-up (about 0.4 s each
+        on the v5e machine, cache hit or not)."""
         import jax
 
-        spec = (shape, dtype, op, sharding)
+        spec = (shape, dtype, op, sharding, values)
         if spec not in self._generators:
             self._generators[spec] = jax.jit(
-                lambda key: data.values(key, shape, dtype, op),
+                lambda key: data.values(key, shape, dtype, op, values),
                 out_shardings=sharding)
         return self._generators[spec]
 
@@ -116,14 +117,24 @@ class PointRun:
         self.n = env.n
         gen = env.generator(kind.input_shape(point, env.n), point["dtype"],
                             point.get("op", "SUM"),
-                            kind.input_sharding(env))
+                            kind.input_sharding(env), point.get("values"))
         key = jax.random.fold_in(jax.random.PRNGKey(seed),
                                  data.stable_hash(self.name))
-        first = gen(key)
-        self.chip_bytes = max(s.data.nbytes
-                              for s in first.addressable_shards)
+        prepare = getattr(kind, "prepare", None)
+
+        def entry(key):
+            """One pool entry: the generated array, or what the kind's
+            ``prepare`` makes of it (the array itself is then dropped
+            here, so the pool is held once).  The pool's size is that of
+            the generated array either way."""
+            x = gen(key)
+            self.chip_bytes = max(s.data.nbytes
+                                  for s in x.addressable_shards)
+            return x if prepare is None else prepare(env, point, x)
+
+        first = entry(key)
         count = max(2, min(pool_max, pool_bytes // self.chip_bytes))
-        self.pool = [first] + [gen(jax.random.fold_in(key, i))
+        self.pool = [first] + [entry(jax.random.fold_in(key, i))
                                for i in range(1, count)]
         self.call, self.bind_collectives = kind.bind(env, point, first)
         self.raw = None
@@ -133,7 +144,11 @@ class PointRun:
                                  f"raw twin and kind {point['kind']!r} "
                                  "has none")
             self.raw = kind.bind_raw(env, point, first)
-        self.collectives_per_call = kind.COLLECTIVES_PER_CALL
+        per_call = getattr(kind, "collectives_per_call", None)
+        self.collectives_per_call = per_call(point) if per_call \
+            else kind.COLLECTIVES_PER_CALL
+        self.tolerance = getattr(kind, "TOLERANCE", None)
+        self.programs_per_call = None   # observed in a traced run's trace
         self.k = 1
         self.windows: list = []         # (window_s, issue_s) per window
         self.raw_windows: list = []
@@ -164,7 +179,12 @@ class PointRun:
             "windows": len(self.windows),
             "per_call_us": t * 1e6, "per_call_mean_us": t_all * 1e6,
             "issue_us": self.issue_s() * 1e6,
+            "collectives_per_call": self.collectives_per_call,
         }
+        if self.programs_per_call is not None:
+            row["programs_per_call"] = self.programs_per_call
+        if self.tolerance is not None:
+            row["tolerance"] = self.tolerance
         bus = self.kind.bus_bytes(self.point, self.n)
         if bus:
             row["bus_bytes"] = bus
@@ -248,23 +268,78 @@ def _take_last(arr, pos):
     return _take_program()(arr, pos)
 
 
+def mismatches(want: np.ndarray, got: np.ndarray, tolerance) -> int:
+    """The positions at which a result is not its reference: bit for
+    bit, or, under a kind's ``TOLERANCE``, outside ``np.isclose``'s
+    ``atol + rtol x |want|``.  The limit is 0 either way, so a stated
+    tolerance is all the room there is.  Another dtype or shape fails at
+    every position."""
+    if want.dtype != got.dtype or want.shape != got.shape:
+        return max(want.size, 1)
+    if tolerance is None:
+        return int(np.count_nonzero(want != got))
+    close = np.isclose(got, want, rtol=tolerance["rtol"],
+                       atol=tolerance["atol"])
+    return int(close.size - np.count_nonzero(close))
+
+
 def check(pr: PointRun, rng: np.random.Generator) -> bool:
-    """One call on a seeded input of the pool, compared bit for bit with
-    the kind's plain numpy reference.  Where the kind computes every
-    position of the last axis independently, a long array is compared at
-    ``sample_positions`` only, so that only those cross to the host."""
-    x = pr.pool[int(rng.integers(len(pr.pool)))]
-    out = pr.call(x)
-    if pr.kind.ELEMENTWISE_LAST_AXIS and x.shape[-1] > SAMPLE:
-        if out.shape[-1] != x.shape[-1]:
+    """One call on a seeded input of the pool, compared with the kind's
+    plain numpy reference: bit for bit, or within the kind's
+    ``TOLERANCE`` where it states one (``mismatches``).  Where the kind
+    computes every position of the last axis independently, a long array
+    is compared at ``sample_positions`` only, so that only those cross
+    to the host.  A kind whose call takes a set of arrays gives them by
+    ``inputs_of``; its reference and its call then return as many.  The
+    number compared and its limit are printed, pass or fail."""
+    bad, compared = check_counts(pr, rng)
+    how = "differ" if pr.tolerance is None else (
+        f"lie outside rtol {pr.tolerance['rtol']} atol "
+        f"{pr.tolerance['atol']}")
+    print(f"check {pr.name}: {bad} of {compared} positions {how}; limit 0",
+          flush=True)
+    return bad == 0
+
+
+def check_counts(pr: PointRun, rng: np.random.Generator,
+                 lowered=None) -> tuple:
+    """(positions that fail, positions compared) for one call on a
+    seeded input of the pool.  With ``lowered`` (a numpy dtype) it is the
+    **control** that is compared and not the program: the kind's
+    reference put in the program's place and computed in that lower
+    precision, from the same inputs (``tools/control.py``)."""
+    state = pr.pool[int(rng.integers(len(pr.pool)))]
+    inputs_of = getattr(pr.kind, "inputs_of", None)
+    xs = list(inputs_of(state)) if inputs_of else [state]
+    outs = []
+    if lowered is None:
+        out = pr.call(state)
+        outs = list(out) if inputs_of else [out]
+    if pr.kind.ELEMENTWISE_LAST_AXIS and xs[0].shape[-1] > SAMPLE:
+        length = xs[0].shape[-1]
+        if any(a.shape[-1] != length for a in xs + outs):
             raise ValueError(f"{pr.name}: a kind that is elementwise along "
                              "the last axis keeps its length")
-        pos = sample_positions(x.shape[-1], rng)
-        x, out = _take_last(x, pos), _take_last(out, pos)
-    want = pr.kind.reference(pr.point, pr.n, np.asarray(x))
-    got = np.asarray(out)
-    return (want.dtype == got.dtype and want.shape == got.shape
-            and np.array_equal(want, got))
+        pos = sample_positions(length, rng)
+        xs = [_take_last(a, pos) for a in xs]
+        outs = [_take_last(a, pos) for a in outs]
+    xs = [np.asarray(a) for a in xs]
+    want = pr.kind.reference(pr.point, pr.n, xs if inputs_of else xs[0])
+    wants = list(want) if inputs_of else [want]
+    if lowered is not None:
+        low = [x.astype(lowered) for x in xs]
+        out = pr.kind.reference(pr.point, pr.n, low if inputs_of else low[0])
+        outs = [np.asarray(o).astype(np.asarray(w).dtype) for o, w in zip(
+            list(out) if inputs_of else [out], wants)]
+    if len(wants) != len(outs):
+        raise ValueError(f"{pr.name}: the call returned {len(outs)} arrays, "
+                         f"the reference {len(wants)}")
+    bad = compared = 0
+    for w, g in zip(wants, outs):
+        w = np.asarray(w)
+        bad += mismatches(w, np.asarray(g), pr.tolerance)
+        compared += w.size
+    return bad, compared
 
 
 def measure(points: list, seconds: float, seed: int,

@@ -7,16 +7,27 @@ small recorded trace (``tests/fixtures``) without a profiler::
     {"device":  {"0": [[name, start_ns, dur_ns], ...], ...},  # XLA ops
      "modules": {"0": [[name, start_ns, dur_ns], ...], ...},  # program runs
      "host":    [[name, start_ns, dur_ns], ...],              # bench.* spans
+     "launches": [[name, start_ns, dur_ns], ...],   # the issuing thread's
      "calls":   {point: k}}                 # calls a window, by the harness
 
-``load_xplane`` makes the first three from the ``.xplane.pb`` the JAX
+``load_xplane`` makes the first four from the ``.xplane.pb`` the JAX
 profiler writes; the harness adds ``calls``.
 
 Which device op belongs to which point is settled by **count, not by
-clock**: the device runs programs in the order they were issued, every
-call is one program run (one event of the ``XLA Modules`` line), and the
-harness closes a window before it opens the next.  So the first k runs
-belong to the first ``bench.issue.<point>`` span, the next k to the next.
+clock**: the device runs programs in the order they were launched, every
+launch is one program run (one event of the ``XLA Modules`` line), and
+the harness closes a window before it opens the next.  How many programs
+a window launched is **observed, not assumed** (PR 32): a call may be a
+step of several launches (one a gradient bucket) or, one day, several
+calls one launch, so ``window_programs`` counts the launch events
+(``launch.json`` names them: ``PjitFunction(...)``) that the issuing
+thread wrote inside each ``bench.issue.<point>`` span.  Window w takes
+that many of the device's runs, in order; the sum over the windows has
+to be the number of runs on every device, and a window's count a whole
+multiple of its k, or nothing is read.  The quotient is the point's
+**programs a call**.  A neutral form recorded before PR 32 holds no
+``launches``: its windows are read at one program a call, as they were
+read then, and the count of runs on every device still has to agree.
 The first trace looked at by hand (v5e, PR 23) showed why: the device's
 timeline ran about 1 ms ahead of the host's, which would have moved four
 or five calls of every window into its neighbour.  For naming idle gaps
@@ -28,6 +39,7 @@ where a reader passes a pattern.
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
 
@@ -38,6 +50,9 @@ HOST_PREFIX = "bench."
 ROUND = "bench.round"           # the spans protocol.py writes
 ISSUE = "bench.issue."
 SYNC = "bench.sync"
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "launch.json"), encoding="utf-8") as _f:
+    LAUNCH_RE = re.compile(json.load(_f)["launch"])     # JAX's name: data
 
 
 _HLO_RE = re.compile(r"^%?([\w.\-]+) = \(?(\w+\[[\d,]*\])")
@@ -63,14 +78,15 @@ def find_xplane(log_dir: str) -> str:
 
 def load_xplane(path: str) -> dict:
     """The neutral form of one ``.xplane.pb``: the ``XLA Ops`` and ``XLA
-    Modules`` lines of every TPU device plane, and the harness's own
-    spans from the host."""
+    Modules`` lines of every TPU device plane, the harness's own spans
+    from the host, and the launch events of the line that holds them."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     device: dict = {}
     modules: dict = {}
     host: list = []
+    launches: list = []
     for plane in data.planes:
         m = DEVICE_PLANE_RE.match(plane.name)
         if m:
@@ -83,12 +99,21 @@ def load_xplane(path: str) -> dict:
                         key=lambda e: e[1])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
+                mine, issuing = [], False
                 for e in line.events:
                     if e.name.startswith(HOST_PREFIX):
                         host.append([e.name, int(e.start_ns),
                                      int(e.duration_ns)])
+                        issuing = issuing or e.name == ROUND
+                    elif LAUNCH_RE.search(e.name):
+                        mine.append([e.name, int(e.start_ns),
+                                     int(e.duration_ns)])
+                if issuing:     # JAX writes them where the spans are
+                    launches += mine
     host.sort(key=lambda e: e[1])
-    return {"device": device, "modules": modules, "host": host}
+    launches.sort(key=lambda e: (e[1], -e[2]))
+    return {"device": device, "modules": modules, "host": host,
+            "launches": launches}
 
 
 def describe_xplane(path: str, limit: int = 12) -> list:
@@ -189,18 +214,63 @@ def windows_of(host: list) -> list:
     return out
 
 
-def device_windows(windows: list, runs: list, calls: dict) -> list:
+def window_programs(events: dict) -> list:
+    """The program runs each traced window launched, in the windows'
+    order: the outermost launch events (JAX writes ``PjitFunction(f)``
+    twice, one inside the other: that is one launch) that start inside
+    the window's ``bench.issue.<point>`` span.  A count that is not a
+    whole multiple of the window's k is refused."""
+    calls = events["calls"]
+    issues = [(n[len(ISSUE):], s, s + d) for n, s, d in events["host"]
+              if n.startswith(ISSUE)]
+    if "launches" not in events:        # recorded before PR 32
+        return [calls[p] for p, _, _ in issues]
+    out, i, open_until = [], 0, -1
+    launches = sorted(events["launches"], key=lambda e: (e[1], -e[2]))
+    for point, lo, hi in issues:
+        count = 0
+        while i < len(launches) and launches[i][1] < hi:
+            _, s, d = launches[i]
+            if s >= lo and s >= open_until:
+                count += 1
+                open_until = s + d
+            i += 1
+        if count == 0 or count % calls[point]:
+            raise ValueError(
+                f"window {len(out)} ({point}): {count} launches inside "
+                f"its issue span are not a whole multiple of its k = "
+                f"{calls[point]} calls")
+        out.append(count)
+    return out
+
+
+def programs_per_call(events: dict) -> dict:
+    """{point: programs one call launches}, observed in the trace.  A
+    point whose windows disagree is refused."""
+    out: dict = {}
+    issued = [n[len(ISSUE):] for n, _, _ in events["host"]
+              if n.startswith(ISSUE)]
+    for point, count in zip(issued, window_programs(events)):
+        per = count // events["calls"][point]
+        if out.setdefault(point, per) != per:
+            raise ValueError(f"{point}: one window launched {out[point]} "
+                             f"programs a call, another {per}")
+    return out
+
+
+def device_windows(windows: list, runs: list, programs: list) -> list:
     """(point, start_ns, end_ns) on the device's own clock for every
-    host window: the program runs in order, ``calls[point]`` to each."""
-    want = sum(calls[p] for p, _, _ in windows)
-    if len(runs) != want:
+    host window: the program runs in order, ``programs[w]`` to window w
+    (``window_programs``)."""
+    if len(programs) != len(windows) or len(runs) != sum(programs):
         raise ValueError(f"the trace holds {len(runs)} program runs on a "
-                         f"device; the harness issued {want} calls in "
-                         f"{len(windows)} windows")
+                         f"device; the issuing thread launched "
+                         f"{sum(programs)} programs in {len(programs)} "
+                         f"issue spans of {len(windows)} windows")
     out, at = [], 0
-    for point, _, _ in windows:
-        mine = runs[at:at + calls[point]]
-        at += calls[point]
+    for (point, _, _), count in zip(windows, programs):
+        mine = runs[at:at + count]
+        at += count
         out.append((point, mine[0][1], max(s + d for _, s, d in mine)))
     return out
 
@@ -222,10 +292,11 @@ def reduce_trace(events: dict) -> dict:
     op_ns: dict = {}
     points: dict = {}
     windows = windows_of(host)
+    programs = window_programs(events)
     first_gaps = None
     for dev in sorted(device, key=int):
         dev_windows = device_windows(windows, events["modules"][dev],
-                                     events["calls"])
+                                     programs)
         # no run starts before the host issued it: shift a device
         # timeline that says otherwise
         shift = max([0] + [hs - ds for (_, hs, _), (_, ds, _)

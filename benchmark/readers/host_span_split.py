@@ -10,9 +10,14 @@ is the geometric mean over the points.  Every traced window must hold
 exactly k spans matching ``span`` (k from the per-point table), else
 nothing is read and the reason is printed: a count that is off means the
 names moved, and a time read from the wrong spans is worse than none.
+Where a call is a step of several launches and the span is one a launch
+(``per`` ``"launch"``), a window must hold k times the point's programs a
+call, as the trace itself showed them (``tracered.programs_per_call``),
+and a point's value is the median over its launches.
 
 params: ``span``, ``part`` and ``child`` (regular expressions, the last
-two optional), ``select``/``exclude``, ``table`` (the full per-point
+two optional), ``per`` (``"call"``, the default, or ``"launch"``),
+``select``/``exclude``, ``table`` (the full per-point
 table goes to ``.bench_out/<cell>.<table>.json``, with the mean and the
 harness's own issue time a call beside each median)."""
 import re
@@ -28,7 +33,8 @@ def read(ctx, params):
         return None
     span, part, child = (re.compile(params[key]) if params.get(key) else None
                          for key in ("span", "part", "child"))
-    table = [hostspans.split_point(run, r["name"], r["k"], span, part, child)
+    table = [hostspans.split_point(run, r["name"], r["k"], span, part, child,
+                                   params.get("per", "call"))
              for r in rows]
     refused = [t for t in table if isinstance(t, str)]
     if refused:

@@ -216,10 +216,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         events = tracered.load_xplane(tracered.find_xplane(log_dir))
         events["calls"] = {pr.name: pr.k for pr in points}
+        # how many programs one call launches is what the trace shows,
+        # never what a kind says: a trace it cannot account for raises
+        programs = tracered.programs_per_call(events)
         with open(os.path.join(log_dir, "calls.json"), "w",
-                  encoding="utf-8") as f:
-            json.dump(events["calls"], f)       # for tools/describe_trace
+                  encoding="utf-8") as f:         # for tools/describe_trace
+            json.dump({"calls": events["calls"], "programs": programs}, f)
         reduced = tracered.reduce_trace(events)
+        for pr in points:
+            pr.programs_per_call = programs[pr.name]
     for pr in points:
         calls = len(pr.windows) * pr.k
         attempted += calls
@@ -278,7 +283,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                    "trace_points": reduced and reduced["points"],
                    "windows_us": {pr.name: [w / pr.k * 1e6
                                             for w, _ in pr.windows]
-                                  for pr in points}}, f, indent=1)
+                                  for pr in points},
+                   # the issue half of each window: a stall sits in it
+                   # or in the closing sync
+                   "issue_windows_us": {pr.name: [i / pr.k * 1e6
+                                                  for _, i in pr.windows]
+                                        for pr in points}}, f, indent=1)
     ompi_tpu.finalize()
     return result
 

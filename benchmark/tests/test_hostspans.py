@@ -114,6 +114,81 @@ def test_the_k_spans_check_and_missing_patterns():
     assert "no traced window" in hs.split_point(run, "zzz", 1, COLL)
 
 
+# a step of two buckets: one otpu.part.step span a call (no such span in
+# the program: a name for the test), two launches inside it.  k = 2.
+STEP_LINE = [
+    ["bench.round", 0, 1000],
+    ["bench.issue.s", 100, 500],
+    ["otpu.part.step", 110, 200],
+    ["PjitFunction(otpu_a)", 120, 60], ["PjitFunction(otpu_a)", 121, 58],
+    ["PJRT_Execute", 130, 40],
+    ["PjitFunction(otpu_a)", 220, 80], ["PJRT_Execute", 230, 60],
+    ["otpu.part.step", 350, 220],
+    ["PjitFunction(otpu_a)", 360, 70], ["PJRT_Execute", 370, 50],
+    ["PjitFunction(otpu_a)", 460, 90], ["PJRT_Execute", 470, 70],
+    ["bench.sync", 600, 100],
+]
+STEP = {
+    "calls": {"s": 2},
+    "host": [e for e in STEP_LINE if e[0].startswith("bench.")],
+    "launches": [e for e in STEP_LINE if e[0].startswith("PjitFunction(")],
+    "host_lines": {"/host:CPU/python3/0": STEP_LINE},
+    "modules": {"0": [["jit_a(1)", 140 + 100 * i, 30] for i in range(4)]},
+    "device": {"0": [["copy.1", 140 + 100 * i, 30] for i in range(4)]},
+}
+
+
+def test_a_span_a_launch_is_counted_k_times_the_programs_a_call(monkeypatch):
+    run = hs.Run(STEP)
+    assert run.programs == {"s": 2}
+    step = re.compile(r"^otpu\.part\.")
+    # a span that is one a call stays k a window
+    whole = hs.split_point(run, "s", 2, step)
+    assert whole["calls"] == 2
+    assert math.isclose(whole["mean_us"], (200 + 220) / 2 / 1e3)
+    # a span that is one a launch: k x 2, a value a launch
+    pjrt = hs.split_point(run, "s", 2, PJRT, per="launch")
+    assert pjrt["calls"] == 4 and pjrt["windows"] == 1
+    assert math.isclose(pjrt["mean_us"], (40 + 60 + 50 + 70) / 4 / 1e3)
+    assert math.isclose(pjrt["median_us"], 55 / 1e3)
+    pjit = hs.split_point(run, "s", 2, PJIT, child=PJRT, per="launch")
+    assert math.isclose(pjit["mean_us"], 20 / 1e3)
+    # read as one a call it is refused, and says what it found
+    why = hs.split_point(run, "s", 2, PJRT)
+    assert isinstance(why, str) and "holds 4 spans" in why
+    why = hs.split_point(run, "s", 2, step, per="launch")
+    assert "holds 2 spans" in why and "2 programs each" in why
+    assert "per is" in hs.split_point(run, "s", 2, PJRT, per="bucket")
+    # the readers: a launch's PJRT time through host_span_split, and the
+    # rest of the issue span a bucket, (500 - 300) / (2 x 2) ns
+    monkeypatch.setattr(hs, "run_of", lambda ctx, f: run)
+    written = {}
+    monkeypatch.setattr(hs, "write_table",
+                        lambda ctx, f, name, t: written.update({name: t}))
+    rows = [{"name": "s", "e2e": "step_us", "k": 2,
+             "collectives_per_call": 2}]
+    ctx = {"points": rows, "run": {"workload": "hand"},
+           "trace": tr.reduce_trace(STEP)}
+    split = pt.load_module("readers", "host_span_split", mf.BENCH_DIR)
+    rest = pt.load_module("readers", "host_issue_rest", mf.BENCH_DIR)
+    params = {"span": PJRT.pattern, "select": {"e2e": "step_us"},
+              "table": "t"}
+    assert split.read(ctx, params) is None              # one a call: no
+    assert math.isclose(split.read(ctx, {**params, "per": "launch"}), 0.055)
+    got = rest.read(ctx, {"inside": r"^PjitFunction\(", "table": "r",
+                          "over_field": "collectives_per_call",
+                          "select": {"e2e": "step_us"}})
+    assert math.isclose(got, (500 - 300) / 4 / 1e3)
+    assert written["r"][0]["spans_inside"] == 4
+    assert math.isclose(written["r"][0]["issue_us_per_unit"], 0.125)
+    # a call, where no column divides further
+    assert math.isclose(rest.read(ctx, {"inside": r"^PjitFunction\(",
+                                        "table": "r"}), 200 / 2 / 1e3)
+    assert rest.read(ctx, {"inside": "^nothing$", "table": "r"}) is None
+    assert rest.read({**ctx, "trace": None},
+                     {"inside": "^x$", "table": "r"}) is None
+
+
 def test_idle_attribution_sums_to_the_idle_total():
     run = hs.Run(HAND)
     # device 0, as tracered: busy 150-250, 300-400, 650-950 of 0-1000
@@ -340,6 +415,45 @@ def test_idle_of_the_recorded_trace_is_all_named(recorded):
     launch = sum(ns for path, ns in idle
                  if any(n.startswith("PjitFunction(") for n in path))
     assert 0 < fw < 0.1 * launch
+
+
+def test_the_recorded_steps_are_counted_from_their_launches():
+    """Three steps of four buckets and three single calls of 64 MiB,
+    recorded on one v5e (``fixtures/rank1_steps_v5e.json``, PR 32): the
+    programs a call come from JAX's own launch events, the device's 15
+    runs fall to the two windows as 12 and 3, and a part that is one a
+    launch is read over 12 launches where one a call is refused."""
+    events = json.load(open(os.path.join(os.path.dirname(FIXTURE),
+                                         "rank1_steps_v5e.json"),
+                            encoding="utf-8"))
+    step, single = "pallreduce.sum.f32.4x25MiB", "allreduce.sum.f32.64MiB"
+    assert events["calls"] == {step: 3, single: 3}
+    assert len(events["launches"]) == 30        # 15, each written twice
+    assert tr.window_programs(events) == [12, 3]
+    run = hs.Run(events)
+    assert run.programs == {step: 4, single: 1}
+    reduced = tr.reduce_trace(events)
+    assert reduced["points"][step]["calls"] == 3
+    # a one-rank psum leaves one copy of the bucket a launch
+    assert list(reduced["points"][step]["ops"]) == ["copy.1 f32[6553600]"]
+    assert list(reduced["points"][single]["ops"]) == ["copy.1 f32[16777216]"]
+    per_bucket = reduced["points"][step]["busy_s"] / 12
+    assert 70e-6 < per_bucket < 90e-6           # 25 MiB in and out
+    pjrt = re.compile(r"^PJRT_LoadedExecutable_Execute$")
+    alloc = re.compile("DeferredTpuAllocator::Allocate")
+    row = hs.split_point(run, step, 3, pjrt, per="launch")
+    assert row["calls"] == 12 and 150 < row["median_us"] < 400
+    part = hs.split_point(run, step, 3, pjrt, part=alloc, per="launch")
+    assert 50 < part["median_us"] < row["median_us"]
+    assert "holds 12 spans" in hs.split_point(run, step, 3, pjrt)
+    # where a call is one launch the two ways agree
+    assert hs.split_point(run, single, 3, pjrt, per="launch") == \
+        hs.split_point(run, single, 3, pjrt)
+    # the persistent handle writes its span once a bucket
+    handle = hs.split_point(run, step, 3, re.compile(r"^otpu\.coll\."),
+                            child=re.compile(r"^PjitFunction\(otpu_"),
+                            per="launch")
+    assert handle["calls"] == 12 and 1 < handle["median_us"] < 10
 
 
 def test_load_host_lines_reads_every_name(tmp_path):

@@ -24,6 +24,7 @@ def _rehearse(root, cell, trace=False):
                      "setup_s"}),
     ("rank1-mix", {"small_msg_us", "reduce_local_bw", "setup_s"}),
     ("rank1-blocking-xl", {"reduce_local_bw", "setup_s"}),
+    ("rank1-partitioned", {"small_msg_us", "setup_s"}),
 ])
 def test_rehearsal_of_every_cell(tiny_root, cell, metrics, capsys):
     result = _rehearse(tiny_root, cell)
@@ -49,6 +50,16 @@ def test_rehearsal_of_every_cell(tiny_root, cell, metrics, capsys):
     # count of device collectives equals the harness's
     timed = sum(r["windows"] * r["k"] for r in rows)
     assert result["attempted"] == timed + 2 * len(points)
+    # each check prints the number it compared beside its limit
+    checks = [ln for ln in out.splitlines() if ln.startswith("check ")]
+    assert len(checks) == 2 * len(points)
+    assert all(" 0 of " in ln and ln.endswith("limit 0") for ln in checks)
+    # no trace, so no programs a call; the collectives a call always
+    assert not any("programs_per_call" in r for r in rows)
+    buckets = {p["name"]: p.get("buckets") for p in points}
+    assert all(r["collectives_per_call"] == (buckets[r["name"]] or
+                                             min(1, r["collectives_per_call"]))
+               for r in rows)
     assert facts["hold"] == pt.HOLD
     saved = os.path.join(tiny_root, ".bench_out",
                          f"{cell}.seed3.trace0.json")
@@ -211,6 +222,206 @@ def test_tagged_points_in_a_new_file_reach_an_old_metric(tiny_root, capsys):
     assert result["metrics"]["small_msg_us"]["value"] == pytest.approx(
         stats.geomean(rows[n]["per_call_us"] for n in tagged))
     assert _snapshot(bench, only=before) == before, "a file was edited"
+
+
+THROWAWAY_KIND = '''
+"""A step of PARTS programs over a set of arrays, for the tests: the set
+is cut out of one generated array in set-up, the call adds 1 to each
+part in a program of its own and issues one collective a part."""
+import numpy as np
+from harness import collkit
+
+ELEMENTWISE_LAST_AXIS = True
+{tolerance}
+seen = {{"prepared": 0}}
+
+
+def input_shape(point, n):
+    return (n, point["parts"], collkit.elems(point, point["parts"]))
+
+
+def input_sharding(env):
+    return env.rank_sharding
+
+
+def collectives_per_call(point):
+    return point["parts"]
+
+
+def prepare(env, point, x):
+    seen["prepared"] += 1
+    return [x[:, i] for i in range(point["parts"])]
+
+
+def inputs_of(parts):
+    return parts
+
+
+def bind(env, point, parts):
+    assert isinstance(parts, list) and len(parts) == point["parts"]
+    return (lambda parts: [env.world.allreduce_array(p) + {offset}
+                           for p in parts]), 0
+
+
+def reference(point, n, xs):
+    return [x.sum(axis=0, dtype=x.dtype) + np.float32(1) for x in xs]
+
+
+def bus_bytes(point, n):
+    return 0.0
+
+
+def moved_bytes(point, n):
+    return 0
+'''
+
+
+def _throwaway_step_cell(root, tolerance="", offset="1"):
+    """A cell of one tagged point of a throw-away step kind, and one old
+    kind's point without a tag, added as new files and entries."""
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "kinds", "throwaway_step.py"), "w",
+              encoding="utf-8") as f:
+        f.write(THROWAWAY_KIND.format(tolerance=tolerance, offset=offset))
+    with open(os.path.join(bench, "traffic", "throwaway-steps.json"), "w",
+              encoding="utf-8") as f:
+        json.dump({"why": "a test", "points": [
+            {"name": "step.f32.3x256B", "kind": "throwaway_step",
+             "dtype": "float32", "bytes": 768, "parts": 3,
+             "e2e": "small_msg_us"},
+            {"name": "allreduce.sum.f32.2KiB", "kind": "allreduce",
+             "dtype": "float32", "bytes": 2048, "op": "SUM"}]}, f)
+    with open(os.path.join(bench, "cells", "throwaway-cell.json"), "w",
+              encoding="utf-8") as f:
+        json.dump({"pool_bytes_per_point": 4096, "pool_max": 4,
+                   "trace_rounds": 1}, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    manifest = json.load(open(path))
+    manifest["workloads"].append({
+        "name": "throwaway-cell", "config": "dp-grad-buckets-1chip",
+        "traffic": "throwaway-steps", "chips": 4, "why": "a test"})
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if m["name"] in ("small_msg_us", "part.step_mean_us"):
+            m["workloads"].append("throwaway-cell")
+    json.dump(manifest, open(path, "w"))
+    return manifest
+
+
+def test_a_kind_may_prepare_its_inputs_and_count_its_collectives(
+        tiny_root, capsys):
+    """The three optional hooks through a throw-away kind, and what PR 32
+    made room for: a later PR's step kind reports an end-to-end metric
+    from its own tagged point, with new files and appended entries
+    alone."""
+    bench = os.path.join(tiny_root, "benchmark")
+    before = _snapshot(bench)
+    manifest = _throwaway_step_cell(tiny_root)
+    assert mf.validate_harness(manifest, tiny_root) == []
+    assert mf.points_by_metric(
+        manifest, "throwaway-cell",
+        mf.traffic_points("throwaway-steps", bench), bench)[
+            "part.step_mean_us"] == ["step.f32.3x256B"]
+    result = _rehearse(tiny_root, "throwaway-cell")
+    # correct holds SPC device_collectives to 3 a step, 1 a plain call
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["metrics"]) == {"small_msg_us", "setup_s"}
+    out = capsys.readouterr().out
+    rows = {r["name"]: r for r in (json.loads(line[6:])
+                                   for line in out.splitlines()
+                                   if line.startswith("point "))}
+    step = rows["step.f32.3x256B"]
+    assert step["collectives_per_call"] == 3
+    assert rows["allreduce.sum.f32.2KiB"]["collectives_per_call"] == 1
+    assert result["metrics"]["small_msg_us"]["value"] == pytest.approx(
+        step["per_call_us"])
+    # the pool is sized by the generated array (4096 // 768 = 5, at most
+    # pool_max) and every entry went through prepare once
+    assert step["pool"] == 4
+    assert "check step.f32.3x256B: 0 of 192 positions differ; limit 0" \
+        in out
+    assert _snapshot(bench, only=before) == before, "a file was edited"
+
+
+@pytest.mark.parametrize("offset,correct", [
+    ("1.004", True),            # inside rtol 1e-2 of values from 1 up
+    ("1.5", False),             # outside it
+])
+def test_a_kind_may_state_how_its_result_is_compared(tiny_root, capsys,
+                                                     offset, correct):
+    tolerance = ('TOLERANCE = {"rtol": 1e-2, "atol": 5e-3, '
+                 '"why": "a test: a float matmul is not bit-exact"}')
+    manifest = _throwaway_step_cell(tiny_root, tolerance, offset)
+    assert mf.validate_harness(manifest, tiny_root) == []
+    result = _rehearse(tiny_root, "throwaway-cell")
+    assert result["correct"] is correct
+    assert result["failed"] == (0 if correct else 2)
+    out = capsys.readouterr().out
+    rows = {r["name"]: r for r in (json.loads(line[6:])
+                                   for line in out.splitlines()
+                                   if line.startswith("point "))}
+    assert rows["step.f32.3x256B"]["tolerance"]["rtol"] == 1e-2
+    assert "tolerance" not in rows["allreduce.sum.f32.2KiB"]
+    assert "positions lie outside rtol 0.01 atol 0.005; limit 0" in out
+    saved = json.load(open(os.path.join(
+        tiny_root, ".bench_out", "throwaway-cell.seed3.trace0.json")))
+    assert saved["points"][0]["tolerance"]["why"].startswith("a test")
+    # the same kind without a tolerance is held to every bit
+    if correct:
+        _throwaway_step_cell_again = THROWAWAY_KIND.format(
+            tolerance="", offset=offset)
+        with open(os.path.join(tiny_root, "benchmark", "kinds",
+                               "throwaway_step.py"), "w") as f:
+            f.write(_throwaway_step_cell_again)
+        assert _rehearse(tiny_root, "throwaway-cell")["correct"] is False
+
+
+def test_mismatches_bit_for_bit_and_within_a_tolerance():
+    a = np.array([1.0, 2.0, 4.0], np.float32)
+    assert pt.mismatches(a, a.copy(), None) == 0
+    assert pt.mismatches(a, a + np.float32(1e-7), None) == 1    # an ulp at 1
+    tol = {"rtol": 1e-3, "atol": 0.0, "why": "x"}
+    assert pt.mismatches(a, a * np.float32(1.0005), tol) == 0
+    assert pt.mismatches(a, a * np.float32(1.002), tol) == 3
+    # another dtype or shape fails at every position, tolerance or not
+    assert pt.mismatches(a, a.astype(np.float64), tol) == 3
+    assert pt.mismatches(a, a[:2], None) == 3
+
+
+def test_a_released_bucket_cannot_be_released_twice(tiny_root):
+    """The configuration's guarantees beside the sum: a bucket released
+    twice in an epoch raises, and so does a Pready before start()."""
+    import ompi_tpu
+    from ompi_tpu.api.errors import MpiError
+
+    world = ompi_tpu.init()
+    try:
+        env = pt.Env(world, __import__("jax").devices())
+        point = {"name": "p", "kind": "pallreduce", "dtype": "float32",
+                 "bytes": 3 * 64, "buckets": 3, "op": "SUM"}
+        pr = pt.PointRun(env, point, pt.load_kind(
+            point, os.path.join(tiny_root, "benchmark")), 5, 4096, 4, False)
+        assert pr.collectives_per_call == 3 and pr.bind_collectives == 3
+        assert len(pr.pool) == 4 and all(len(b) == 3 for b in pr.pool)
+        assert pr.chip_bytes == 3 * 64          # the generated array's
+        out = pr.call(pr.pool[1])
+        want = pr.kind.reference(point, env.n,
+                                 [np.asarray(b) for b in pr.pool[1]])
+        assert all(np.array_equal(w, np.asarray(g))
+                   for w, g in zip(want, out))
+        assert pt.check(pr, np.random.default_rng(0)) is True
+        req = world.pallreduce_init(pr.pool[0])
+        with pytest.raises(MpiError, match="inactive"):
+            req.pready(0)
+        req.start(pr.pool[0])
+        req.pready(2)
+        with pytest.raises(MpiError, match="already released"):
+            req.pready(2)
+        req.pready(1)
+        req.pready(0)
+        req.wait()
+        assert len(req.result) == 3
+    finally:
+        ompi_tpu.finalize()
 
 
 def test_a_blocking_call_is_ready_when_it_returns(tiny_root, monkeypatch):

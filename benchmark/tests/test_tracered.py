@@ -96,7 +96,7 @@ def test_a_trace_the_harness_cannot_account_for_is_an_error():
         tr.reduce_trace({**HAND, "device": {}})
     with pytest.raises(ValueError, match="bench.round"):
         tr.reduce_trace({**HAND, "host": []})
-    with pytest.raises(ValueError, match="3 program runs .* issued 4"):
+    with pytest.raises(ValueError, match="3 program runs .* launched 4"):
         tr.reduce_trace({**HAND, "calls": {"a": 2, "b": 2}})
 
 
@@ -153,7 +153,7 @@ def test_reduction_of_the_recorded_trace():
     # the device's timeline runs about 1 ms ahead of the host's here
     host_windows = tr.windows_of(events["host"])
     dev_windows = tr.device_windows(host_windows, events["modules"]["0"],
-                                    events["calls"])
+                                    tr.window_programs(events))
     for (_, hs, _), (_, ds, _) in zip(host_windows, dev_windows):
         assert 0.9e6 < hs - ds < 1.2e6
     for i, (point, _, _) in enumerate(host_windows):
@@ -176,6 +176,117 @@ def test_reduction_of_the_recorded_trace():
     assert set(gaps) <= {e[0] for e in events["host"]}
     issue = sum(s for n, s in gaps.items() if n.startswith(tr.ISSUE))
     assert issue / sum(gaps.values()) > 0.98
+
+
+# A call may be a step (PR 32).  One round, three windows on one device:
+#   window s (k = 3 steps of 4 launches): issue 100..1300, sync to 1400
+#   window b (k = 2 calls of 1 launch):   issue 1500..1700, sync to 1800
+#   window s again:                       issue 1900..3100, sync to 3200
+# Every launch is written twice, one inside the other, as JAX writes it;
+# one launch lies outside every issue span (the harness's own, between
+# two windows) and has no run in the trace's window.  Each run on the
+# device lasts 50 ns and starts 20 ns after its launch.
+def _steps(k, per, t0, gap=100):
+    return [["PjitFunction(otpu_x)", t0 + gap * i, 60]
+            for i in range(k * per)]
+
+
+def _step_trace():
+    launches = _steps(3, 4, 100) + _steps(2, 1, 1500) + _steps(3, 4, 1900)
+    nested = [[n, s + 1, d - 2] for n, s, d in launches]
+    runs = [["jit_x(1)", s + 20, 50] for _, s, _ in launches]
+    return {
+        "calls": {"s": 3, "b": 2},
+        "host": [["bench.round", 0, 3300],
+                 ["bench.issue.s", 100, 1200], ["bench.sync", 1300, 100],
+                 ["bench.issue.b", 1500, 200], ["bench.sync", 1700, 100],
+                 ["bench.issue.s", 1900, 1200], ["bench.sync", 3100, 100]],
+        "launches": launches + nested + [["PjitFunction(mine)", 1450, 10]],
+        "modules": {"0": runs},
+        "device": {"0": [["all-reduce.1", s, d] for _, s, d in runs]},
+    }
+
+
+def test_a_windows_program_runs_are_counted_not_assumed():
+    events = _step_trace()
+    assert tr.window_programs(events) == [12, 2, 12]
+    assert tr.programs_per_call(events) == {"s": 4, "b": 1}
+    r = tr.reduce_trace(events)
+    s, b = r["points"]["s"], r["points"]["b"]
+    assert (s["windows"], s["calls"]) == (2, 6)         # calls, not launches
+    assert (b["windows"], b["calls"]) == (1, 2)
+    assert math.isclose(s["busy_s"], 24 * 50e-9)        # 24 runs are s's
+    assert math.isclose(b["busy_s"], 2 * 50e-9)
+    assert math.isclose(r["busy_s"], 26 * 50e-9)
+    host_windows = tr.windows_of(events["host"])
+    dev = tr.device_windows(host_windows, events["modules"]["0"],
+                            tr.window_programs(events))
+    assert [(p, lo) for p, lo, _ in dev] == [("s", 120), ("b", 1520),
+                                             ("s", 1920)]
+    assert dev[0][2] == 100 + 11 * 100 + 20 + 50        # its twelfth run
+
+
+def test_a_run_too_many_on_the_device_is_refused_with_the_count():
+    events = _step_trace()
+    events["modules"]["0"].append(["jit_x(1)", 3150, 10])
+    with pytest.raises(ValueError, match="27 program runs .* launched 26 "
+                                         "programs in 3 issue spans"):
+        tr.reduce_trace(events)
+    # and one too few: a launch the device never ran
+    events = _step_trace()
+    del events["modules"]["0"][-1]
+    with pytest.raises(ValueError, match="25 program runs .* launched 26"):
+        tr.reduce_trace(events)
+
+
+def test_launches_that_are_no_multiple_of_k_are_refused():
+    events = _step_trace()
+    events["launches"].append(["PjitFunction(otpu_x)", 1270, 20])
+    events["modules"]["0"].append(["jit_x(1)", 1280, 10])
+    events["modules"]["0"].sort(key=lambda e: e[1])
+    with pytest.raises(ValueError, match=r"window 0 \(s\): 13 launches "
+                                         ".* multiple of its k = 3"):
+        tr.reduce_trace(events)
+    # no launch event at all (JAX renamed it): every window is refused
+    with pytest.raises(ValueError, match="0 launches"):
+        tr.reduce_trace({**_step_trace(), "launches": []})
+    # a point whose windows disagree: 4 programs a call, then 3
+    events = _step_trace()
+    events["launches"] = [e for e in events["launches"]
+                          if not 2800 <= e[1] < 3100]
+    with pytest.raises(ValueError, match="4 programs a call, another 3"):
+        tr.programs_per_call(events)
+
+
+def test_the_recorded_traces_reduce_as_before_pr_32():
+    """The two fixtures were recorded when a call was one program, and
+    hold no ``launches``: their reduction is byte for byte what the
+    reader of PR 31's tree made of them (the digests are of its
+    ``json.dumps(reduce_trace(events))``).  The second holds the issuing
+    thread's lines: with the launch events taken from them, the count is
+    observed, and is one program a call at every point."""
+    import hashlib
+
+    was = {"rank1_small_v5e.json": "4d79e66bc7f39160cba22f58485b1032511da9"
+                                   "14392a1cf6f8df5d777b9e94e5",
+           "rank1_hostlines_v5e.json": "f5ee443155ca64fa76d9b8b16719ebd873"
+                                       "dd3a157549810af73baffce6abd606"}
+    for name, digest in was.items():
+        events = json.load(open(os.path.join(os.path.dirname(FIXTURE), name),
+                                encoding="utf-8"))
+        assert "launches" not in events
+        assert tr.programs_per_call(events) == dict.fromkeys(
+            events["calls"], 1)
+        text = json.dumps(tr.reduce_trace(events))
+        assert hashlib.sha256(text.encode()).hexdigest() == digest, name
+    main = next(evs for evs in events["host_lines"].values()
+                if any(n == tr.ROUND for n, _, _ in evs))
+    events["launches"] = [e for e in main if tr.LAUNCH_RE.search(e[0])]
+    assert len(events["launches"]) > 24         # written twice, nested
+    assert tr.window_programs(events) == [8, 8, 8]
+    assert tr.programs_per_call(events) == dict.fromkeys(events["calls"], 1)
+    assert hashlib.sha256(json.dumps(tr.reduce_trace(events)).encode()
+                          ).hexdigest() == digest
 
 
 def test_short_op_names():

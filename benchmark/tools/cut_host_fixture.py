@@ -5,14 +5,16 @@
     python3 benchmark/tools/cut_host_fixture.py <cell> --points a,b,c --calls 16 --out fixture.json [--span REGEX]
 
 Keeps, of the first traced window of each named point, the first
-``--calls`` calls (a call is a top-level event of the window matching
-``--span``): the issuing thread's events up to the end of the last of
+``--calls`` calls (a call is as many top-level events of the window
+matching ``--span`` as the point launches programs a call: one, or one a
+bucket of a step): the issuing thread's events up to the end of the last of
 them, a line a thread as ``harness/hostspans.py`` loads them (the other
 threads' events of that time too), the device's program runs and ops
 of those calls (by count, as ``tracered`` tells them apart), the window's
 ``bench.issue`` span cut to end with its last kept call, and its
 ``bench.sync``.  ``calls`` in the fixture is the kept count, so the
-k-spans check holds on it.  What lay between the kept calls and the sync
+k-spans check holds on it; ``launches`` are the issuing thread's launch
+events of the kept calls, from which the programs a call are read.  What lay between the kept calls and the sync
 is gone: the device reads as idle there, under ``bench.round``."""
 import argparse
 import json
@@ -41,7 +43,8 @@ def main() -> int:
     events = tracered.load_xplane(path)
     events["host_lines"] = hostspans.load_host_lines(path)
     with open(os.path.join(log_dir, "calls.json"), encoding="utf-8") as f:
-        events["calls"] = json.load(f)
+        said = json.load(f)
+    events["calls"] = said.get("calls", said)   # the calls alone before PR 32
     run = hostspans.Run(events)
     wanted = args.points.split(",")
     call_re = re.compile(args.span)
@@ -50,9 +53,10 @@ def main() -> int:
         if point in wanted and point not in first:
             first[point] = i
     starts, at = [], 0              # index of each window's first run
-    for point, _, _ in run.windows:
+    programs = tracered.window_programs(events)     # as the trace shows
+    for count in programs:
         starts.append(at)
-        at += events["calls"][point]
+        at += count
     syncs = [e for e in events["host"] if e[0] == tracered.SYNC]
     main = next(k for k, evs in events["host_lines"].items()
                 if any(n == tracered.ROUND for n, _, _ in evs))
@@ -62,12 +66,14 @@ def main() -> int:
     for point in sorted(first, key=first.get):
         i, n = first[point], args.calls
         issue = run.issues[i]
-        last = [c for c in issue.children if call_re.search(c.name)][n - 1]
+        per = programs[i] // events["calls"][point]     # programs a call
+        last = [c for c in issue.children
+                if call_re.search(c.name)][n * per - 1]
         host += [[issue.name, issue.start, last.end - issue.start + 1],
                  syncs[i]]
         ranges.append((issue.start, last.end))
         for d in modules:
-            runs = events["modules"][d][starts[i]:starts[i] + n]
+            runs = events["modules"][d][starts[i]:starts[i] + n * per]
             modules[d] += runs
             lo, hi = runs[0][1], max(s + dur for _, s, dur in runs)
             device[d] += [op for op in events["device"][d]
@@ -86,6 +92,9 @@ def main() -> int:
     out = {"recorded": args.recorded,
            "calls": {p: args.calls for p in first},
            "host": sorted(host, key=lambda e: e[1]),
+           "launches": [e for e in events["launches"]
+                        if any(a <= e[1] and e[1] + e[2] <= b
+                               for a, b in ranges)],
            "host_lines": lines, "modules": modules, "device": device}
     hostspans.Run(out)              # the cut still accounts for itself
     with open(args.out, "w", encoding="utf-8") as f:

@@ -32,7 +32,12 @@ def main() -> int:
     events = tracered.load_xplane(path)
     with open(os.path.join(os.path.dirname(BENCH_DIR), ".bench_out", "trace",
                            args.cell, "calls.json"), encoding="utf-8") as f:
-        events["calls"] = json.load(f)
+        said = json.load(f)
+    # {"calls": {point: k}, "programs": {point: programs a call}} since
+    # PR 32; before it the file was the calls alone
+    events["calls"] = said.get("calls", said)
+    print("programs a call, as the trace showed them:",
+          json.dumps(tracered.programs_per_call(events)))
     if args.events:
         with open(args.events, "w", encoding="utf-8") as f:
             json.dump(events, f)
