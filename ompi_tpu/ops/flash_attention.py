@@ -190,6 +190,7 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
         in_specs=in_specs,
         out_specs=(s_spec, q_spec, s_spec),
         interpret=interpret,
+        name="otpu_flash_block_update",
     )(*operands)
 
     return (mo[..., 0].reshape(b, h, sq).astype(m.dtype),

@@ -8,6 +8,7 @@ binomial pipelines) rather than GSPMD auto-propagation.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -264,3 +265,176 @@ def transformer_block(p, x, *, sp, tp, n_heads_local, n_experts, capacity,
     x = mlp_block(p, x, tp=tp)
     x = moe_block(p, x, tp=tp, n_experts=n_experts, capacity=capacity)
     return x
+
+
+# -- a public model's block (OLMoE): parallel/train.py's model path --------
+def matmul(a, w, compute_dtype):
+    """``a @ w`` with inputs in ``compute_dtype`` and a float32 result:
+    bfloat16 inputs accumulate in float32 on the MXU; float32 inputs
+    multiply at the highest precision (on a TPU the default would round
+    them to bfloat16 on the way in)."""
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return jnp.dot(a.astype(jnp.float32), w.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    return jnp.dot(a.astype(compute_dtype), w.astype(compute_dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def rmsnorm_gain(x, gain, eps: float):
+    """RMSNorm with a learned gain, in float32 whatever ``x`` is."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * gain
+
+
+def rope(x, theta: float):
+    """Rotary position embedding on ``x`` (b, h, s, hd) at positions
+    0..s-1, the half-split form of the HF models (``rotate_half``)."""
+    hd, s = x.shape[-1], x.shape[-2]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)           # (s, hd)
+    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+
+
+def _tri_bias(block: int):
+    i = jnp.arange(block)
+    return jnp.where(i[:, None] >= i[None, :], 0.0,
+                     -jnp.inf).astype(jnp.float32)
+
+
+def _contract(eq, a, b, compute_dtype):
+    """A float32 einsum of blocked attention, its inputs in
+    ``compute_dtype``."""
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return jnp.einsum(eq, a, b, precision=jax.lax.Precision.HIGHEST)
+    return jnp.einsum(eq, a.astype(compute_dtype), b.astype(compute_dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _causal_fwd_blocks(q, k, v, block, interpret):
+    """Causal attention by blocks of ``block`` positions: q block i meets
+    kv blocks 0..i, the diagonal one under a triangular bias, each
+    through one online-softmax update (the fused Pallas kernel where
+    Mosaic compiles it, its jnp twin with float32 scores elsewhere).
+    The running max, numerator and denominator are float32 whatever
+    q, k, v are.  Returns (o float32, logsumexp float32)."""
+    from ompi_tpu.ops.flash_attention import (flash_block_update,
+                                              flash_block_update_biased)
+
+    b, h, s, hd = q.shape
+    nb = s // block
+    scale = 1.0 / math.sqrt(hd)
+    bias = _tri_bias(block)
+    outs, lses = [], []
+    for i in range(nb):
+        qi = q[:, :, i * block:(i + 1) * block]
+        zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
+        m, den = zero - jnp.inf, zero
+        num = (qi * 0).astype(jnp.float32)
+        for j in range(i + 1):
+            kj = k[:, :, j * block:(j + 1) * block]
+            vj = v[:, :, j * block:(j + 1) * block]
+            if not interpret:
+                if j == i:
+                    m, num, den = flash_block_update_biased(
+                        qi, kj, vj, m, num, den, bias, False)
+                else:
+                    m, num, den = flash_block_update(qi, kj, vj, m, num,
+                                                     den, False)
+                continue
+            sc = _contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
+            if j == i:
+                sc = sc + bias
+            new_m = jnp.maximum(m, sc.max(axis=-1))
+            c = jnp.exp(m - new_m)
+            p = jnp.exp(sc - new_m[..., None])
+            num = num * c[..., None] + _contract("bhqk,bhkd->bhqd", p, vj,
+                                                 q.dtype)
+            den = den * c + p.sum(axis=-1)
+            m = new_m
+        outs.append(num / den[..., None])
+        lses.append(m + jnp.log(den))
+    return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def causal_flash_attention(q, k, v, block: int, interpret: bool):
+    """Causal self-attention of (b, h, s, hd) q, k, v whose length is a
+    multiple of ``block``.  Forward: ``_causal_fwd_blocks``.  Backward:
+    the flash backward by the same blocks (scores recomputed from q, k
+    and the saved logsumexp in float32; no (s, s) array is ever held),
+    its matmul inputs in q's dtype."""
+    return _causal_fwd_blocks(q, k, v, block, interpret)[0]
+
+
+def _causal_fwd(q, k, v, block, interpret):
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(block, interpret, res, do):
+    q, k, v, o, lse = res
+    dt = q.dtype
+    nb = q.shape[2] // block
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    bias = _tri_bias(block)
+    do = do.astype(jnp.float32)
+    delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
+    cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
+    dq = [0.0] * nb
+    dk = [0.0] * nb
+    dv = [0.0] * nb
+    for i in range(nb):
+        qi, doi = cut(q, i), cut(do, i)
+        lse_i, delta_i = cut(lse, i), cut(delta, i)
+        for j in range(i + 1):
+            kj, vj = cut(k, j), cut(v, j)
+            sc = _contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
+            if j == i:
+                sc = sc + bias
+            p = jnp.exp(sc - lse_i[..., None])
+            dv[j] = dv[j] + _contract("bhqk,bhqd->bhkd", p, doi, dt)
+            dp = _contract("bhqd,bhkd->bhqk", doi, vj, dt)
+            ds = p * (dp - delta_i[..., None]) * scale
+            dq[i] = dq[i] + _contract("bhqk,bhkd->bhqd", ds, kj, dt)
+            dk[j] = dk[j] + _contract("bhqk,bhqd->bhkd", ds, qi, dt)
+    cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
+    return cat(dq), cat(dk), cat(dv)
+
+
+causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+def olmoe_attention(p, x, cfg, *, interpret: bool):
+    """OLMoE's attention sublayer on the residual stream ``x`` (b, s, d)
+    float32: pre-norm; q, k, v, o projections without bias; RMSNorm with
+    a gain over the whole width of q and of k **before** the heads are
+    split (QK-norm); RoPE; causal attention; residual add."""
+    b, s, d = x.shape
+    nh, dt = cfg.num_attention_heads, cfg.compute_dtype
+    h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+    q = rmsnorm_gain(matmul(h, p["wq"], dt), p["q_norm"], cfg.rms_norm_eps)
+    k = rmsnorm_gain(matmul(h, p["wk"], dt), p["k_norm"], cfg.rms_norm_eps)
+    v = matmul(h, p["wv"], dt)
+    heads = lambda t: t.reshape(b, s, nh, -1).transpose(0, 2, 1, 3)
+    q, k = rope(heads(q), cfg.rope_theta), rope(heads(k), cfg.rope_theta)
+    o = causal_flash_attention(q.astype(dt), k.astype(dt),
+                               heads(v).astype(dt),
+                               min(cfg.attn_block, s), interpret)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+    return x + matmul(o, p["wo"], dt)
+
+
+def olmoe_block(p, x, cfg, *, interpret: bool):
+    """One OLMoE decoder layer; returns (x, the router's statistics,
+    what the router read and made by token row:
+    ``moe.moe_sorted_block``)."""
+    from ompi_tpu.parallel.moe import moe_sorted_block
+
+    with jax.named_scope("otpu_attention"):
+        x = olmoe_attention(p, x, cfg, interpret=interpret)
+    with jax.named_scope("otpu_moe"):
+        y, stats, routed = moe_sorted_block(p, x, cfg)
+    return x + y, stats, routed

@@ -803,3 +803,94 @@ def main(argv: Optional[list] = None) -> int:
 
 if __name__ == "__main__":
     raise SystemExit(main())
+
+
+# -- a learned top-k router with no dropped token (parallel/train.py's
+# model path; the hash-gated host trainer above is untouched) -----------
+def route_topk(logits, top_k: int, normalize: bool = False):
+    """``softmax`` over every expert, then the ``top_k`` largest:
+    returns (probs (T, E), weights (T, k)) and experts (T, k).
+    The weights are the chosen probabilities as they stand unless
+    ``normalize`` (OLMoE's ``norm_topk_prob`` is false)."""
+    import jax
+    import jax.numpy as jnp
+
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if normalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, weights, experts
+
+
+def sorted_dispatch(experts, n_experts: int):
+    """Sort-and-gather dispatch of the T x k token-slots: returns
+    (token of each sorted slot, where each (token, k) slot went, the
+    slots each expert received).  Every slot is kept: a group is as
+    long as its expert is popular, so no token is ever dropped."""
+    import jax.numpy as jnp
+
+    t, k = experts.shape
+    flat = experts.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    place = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    return order // k, place.reshape(t, k), sizes
+
+
+def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
+    """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
+    up x)`` as three grouped matmuls (``lax.ragged_dot``: group e is the
+    ``sizes[e]`` rows that expert e received), inputs in
+    ``compute_dtype``, float32 results."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.dtype(compute_dtype) == jnp.float32
+    prec = jax.lax.Precision.HIGHEST if f32 else None
+
+    def gmm(a, w):
+        return jax.lax.ragged_dot(
+            a.astype(compute_dtype), w.astype(compute_dtype), sizes,
+            precision=prec, preferred_element_type=jnp.float32)
+
+    hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
+    return gmm(hidden, down)
+
+
+def moe_sorted_block(p, x, cfg):
+    """OLMoE's sparse MLP sublayer on the residual stream ``x`` (b, s,
+    d): pre-norm, a learned router (logits and softmax in float32), the
+    top k of all experts with no capacity, sort-and-gather dispatch,
+    grouped expert matmuls, weighted combine.  Returns (the sublayer's
+    output, before the residual add; the router's statistics: slots an
+    expert received, summed probabilities an expert, summed squared
+    logsumexp of the logits; and by token row what the router read and
+    made: ``in`` (T, d), ``logits`` (T, E), ``lse`` (T,), ``weights`` and
+    ``experts`` (T, k))."""
+    import jax
+    import jax.numpy as jnp
+
+    from ompi_tpu.parallel.model import rmsnorm_gain
+
+    b, s, d = x.shape
+    t, k = b * s, cfg.num_experts_per_tok
+    h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
+    with jax.named_scope("otpu_router"):
+        logits = jnp.dot(h, p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        probs, weights, experts = route_topk(logits, k, cfg.norm_topk_prob)
+        token, place, sizes = sorted_dispatch(experts, cfg.num_experts)
+    with jax.named_scope("otpu_experts"):
+        y = grouped_expert_ffn(h.astype(cfg.compute_dtype)[token],
+                               p["gate"], p["up"], p["down"], sizes,
+                               cfg.compute_dtype)
+    with jax.named_scope("otpu_combine"):
+        out = jnp.sum(y[place] * weights[..., None], axis=1)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    stats = {"slots": sizes.astype(jnp.float32),
+             "prob_sum": jnp.sum(probs, axis=0),
+             "z_sum": jnp.sum(lse * lse)}
+    return out.reshape(b, s, d), stats, {
+        "in": h, "logits": logits, "lse": lse, "weights": weights,
+        "experts": experts}

@@ -100,6 +100,13 @@ _COUNTERS = (
     # (max-expert-load / mean-load * 1000 — a gauge kept as a
     # monotonic high-water so the counter plane stays append-only)
     "moe_dispatch_tokens", "moe_dropped_tokens", "moe_imbalance_max",
+    # a public model's train step (parallel/train.py's model path):
+    # optimiser steps issued, the tokens and the routed token-slots
+    # (tokens x experts a token x layers) in them, and the fullest
+    # expert's slots in any step read back so far (a high-water gauge,
+    # read outside the step: ``train.record_step_stats``)
+    "train_steps", "train_tokens", "moe_token_slots",
+    "moe_max_expert_load",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

@@ -64,6 +64,7 @@ def cases(mesh1d, mesh2d):
     """Yield (name, build) pairs; build() -> (jitted_fn, args tuple of
     ShapeDtypeStruct).  Shapes are small but structurally honest: every
     kernel takes its multi-step ring/segment path."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
@@ -231,6 +232,30 @@ def cases(mesh1d, mesh2d):
         flash_args(4, 8, 2048, 2048, 128, bf16)
         + (_sds((2048, 2048), jnp.float32, one, P()),),
         {"interpret": False}))
+    # the OLMoE train step's attention (``parallel/model
+    # .causal_flash_attention``) at the benchmark cell's shape (2 x 16
+    # heads x 4,096 x 128, bfloat16, causal): ten block updates of 1,024
+    # positions, the four diagonal ones under their bias, and the two
+    # kernels alone.  The whole sequence as ONE biased update does not
+    # compile: K and V ride whole in VMEM beside a (256, 4096) float32
+    # tile of scores and one of bias, and Mosaic refuses it
+    # (RESOURCE_EXHAUSTED in vmem, offline for a v5e, PR 33)
+    def olmoe_attention():
+        from ompi_tpu.parallel import model
+
+        qkv = _sds((2, 16, 4096, 128), bf16, one, P())
+        return jax.jit(lambda q, k, v: model._causal_fwd_blocks(
+            q, k, v, 1024, False)), (qkv, qkv, qkv)
+
+    case("olmoe_causal_attention_4k", olmoe_attention)
+    case("olmoe_flash_block_1k", lambda: (
+        fa._update_pallas, flash_args(2, 16, 1024, 1024, 128, bf16),
+        {"interpret": False}))
+    case("olmoe_flash_block_1k_biased", lambda: (
+        fa._update_pallas,
+        flash_args(2, 16, 1024, 1024, 128, bf16)
+        + (_sds((1024, 1024), jnp.float32, one, P()),),
+        {"interpret": False}))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),
@@ -322,7 +347,31 @@ def cases(mesh1d, mesh2d):
                  P("dp", "sp", None))
         return step, (params, x)
 
+    # -- a public model's step (OLMoE-1B-7B, one layer of 16) at the
+    # benchmark cell's own configuration file, on one device: forward,
+    # the flash kernel, the grouped expert matmuls, backward and AdamW
+    def olmoe_step(devices):
+        import os
+
+        from ompi_tpu.parallel.mesh import MeshSpec
+
+        mesh, spec = make_mesh(devices, MeshSpec())
+        cfg = train.load_model_config(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))),
+            "benchmark", "configs", "olmoe-1b-7b-train-1chip.json"))
+        step, _ = train.build_train_step(mesh, spec, model=cfg)
+        tree = jax.tree.map(lambda s: _sds(s, f32, mesh, P()),
+                            train.model_param_shapes(cfg),
+                            is_leaf=lambda x: isinstance(x, tuple))
+        tokens = _sds((cfg.micro_batch, cfg.seq_len), jnp.int32, mesh,
+                      P("dp", None))
+        return step.jitted, ((tree, tree, tree,
+                              _sds((), jnp.int32, mesh, P())),
+                             tokens, tokens)
+
     topo_devs = list(_np.asarray(mesh1d.devices).reshape(-1))
+    case("olmoe_step_1chip", lambda: olmoe_step(topo_devs[:1]))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

@@ -33,6 +33,39 @@ def _cut_ddt(p):
                       2 * p["n"] ** 2 if "n" in p else 3 * p["sent"])
 
 
+def _cut_buckets(p):
+    """A gradient set cut to 64 bytes a bucket (the whole set where the
+    point is one call): every point keeps its count of buckets, so a
+    step still launches what it launches at full size."""
+    p["bytes"] = p.get("buckets", 1) * 64
+
+
+# olmoe-train-1chip: the kind reads its widths from the configuration
+# file its point names, so the rehearsal writes tiny widths there (the
+# first satellite's: 2 layers, as the CPU tests of the model) and cuts
+# the point's batch to match
+TINY_MODEL = dict(hidden_size=64, intermediate_size=32,
+                  num_attention_heads=4, num_key_value_heads=4,
+                  num_experts=8, num_experts_per_tok=2, vocab_size=256,
+                  layers_here=2)
+# float32 compute: with 64 tokens one routing choice that bfloat16 flips
+# moves an expert's load by a sixteenth of the mean, far over a tolerance
+# set at 8,192 tokens; the walk is of the code, not of the precision
+TINY_TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16,
+                  loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_model(config):
+    config.update(TINY_MODEL)
+    config["train"].update(TINY_TRAIN)
+
+
+def _cut_batch(p):
+    p.update(sequences=TINY_TRAIN["micro_batch"],
+             seq_len=TINY_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 1)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -71,8 +104,20 @@ CELLS = {
         devices=8, points=13, pool_shift=10, widen="ddt-device-1chip",
         metrics={"small_msg_us", "reduce_local_bw", "setup_s"},
         cut={"ddt-face-transpose-mix": _cut_ddt}),
+    "rank1-partitioned": dict(
+        devices=1, points=4, pool_shift=10,
+        metrics={"small_msg_us", "setup_s"},
+        cut={"grad-bucket-steps": _cut_buckets}),
+    "olmoe-train-1chip": dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("olmoe-1b-7b-train-1chip", _tiny_model),
+        cut={"packed-4k-steps": _cut_batch}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
+# cells whose calls are steps: many collectives or none a call
+STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip")
+CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the child: run_cell as the command calls it, but for the three
 # arguments it keeps for rehearsals.  What the program counts is read
@@ -102,7 +147,8 @@ result = run.run_cell({cell!r}, seed=2147483999, seconds=0.3, trace=False,
                       platform="cpu", root={root!r}, min_window_s=0.002)
 builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
-                                 if k.startswith("device_")}}))
+                                 if k.startswith(("device_", "train_",
+                                                  "moe_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
 print("result " + json.dumps(result))
@@ -158,9 +204,9 @@ def test_rank1_ddt_is_one_chip_under_the_two_one_chip_metrics(mf, real):
         == set(chosen["ddt.vs_manual"])
 
 
-def _rehearse(cell, root):
-    """One run of ``cell`` at tiny sizes on its CPU devices, in a copy of
-    the benchmark under ``root`` and a process of its own."""
+def _stage(cell, root):
+    """A copy of the benchmark under ``root`` with ``cell`` cut to tiny
+    sizes; returns the child's environment (the cell's CPU devices)."""
     spec = CELLS[cell]
     devices = spec["devices"]
     bench = os.path.join(root, "benchmark")
@@ -180,6 +226,9 @@ def _rehearse(cell, root):
             w.update(chips=devices) for w in m["workloads"]])
         edit(os.path.join(bench, "configs", spec["widen"] + ".json"),
              lambda c: c.update(ranks=devices, chips=devices))
+    if "config" in spec:    # a model at the widths of a rehearsal
+        name, tiny = spec["config"]
+        edit(os.path.join(bench, "configs", name + ".json"), tiny)
     edit(os.path.join(bench, "cells", cell + ".json"),
          lambda c: c.update(pool_bytes_per_point=max(
              64 * KIB, c["pool_bytes_per_point"] >> spec["pool_shift"])))
@@ -187,8 +236,15 @@ def _rehearse(cell, root):
         edit(os.path.join(bench, "traffic", traffic + ".json"),
              lambda mix: [cut(p) for p in mix["points"]])
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+    return dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
         f"--xla_force_host_platform_device_count={devices}"))
+
+
+def _rehearse(cell, root):
+    """One run of ``cell`` at tiny sizes on its CPU devices, in a copy of
+    the benchmark under ``root`` and a process of its own."""
+    spec = CELLS[cell]
+    env = _stage(cell, root)
     done = subprocess.run(
         [sys.executable, "-c", REHEARSAL.format(
             bench=BENCH, repo=REPO, cell=cell, root=root)],
@@ -248,7 +304,7 @@ def test_the_rehearsal_reports_the_cells_end_to_end_metrics(rehearsal):
     assert all(m["value"] > 0 for m in metrics.values())
 
 
-@of_cells(*NEW_CELLS)
+@of_cells(*CALL_CELLS)
 def test_the_counters_account_for_the_calls(rehearsal):
     """SPC ``device_collectives`` moved by what the harness issued
     (``correct`` holds the two equal): at least the timed calls of every
@@ -321,3 +377,66 @@ def test_plans_and_programs_are_built_in_set_up(rehearsal):
     calls = sum(p["k"] * p["windows"] for p in rehearsal["points"].values()
                 if p["kind"] == "ddt_to_self")
     assert rehearsal["run"]["spc_device_collectives"] > calls
+
+
+@of_cells("rank1-partitioned")
+def test_a_step_counts_a_collective_a_bucket(rehearsal):
+    """SPC ``device_collectives`` moves once a bucket released: by more
+    than the timed steps' buckets (``correct`` holds the harness's count
+    and the program's equal), and nothing is built after set-up."""
+    points = rehearsal["points"].values()
+    assert {p["collectives_per_call"] for p in points} == {4, 51, 32, 1}
+    timed = sum(p["k"] * p["windows"] * p["collectives_per_call"]
+                for p in points)
+    assert rehearsal["run"]["spc_device_collectives"] > timed
+    at_measure, at_end = rehearsal["builds"]
+    assert at_measure == at_end
+
+
+@of_cells("olmoe-train-1chip")
+def test_a_train_step_counts_its_tokens_and_moves_no_collective(rehearsal):
+    """The step's ``psum`` passes no ``world.*_array`` slot; the
+    trainer's own counters follow from the steps issued (tokens, routed
+    slots: tokens x 2 experts x 2 layers at the rehearsal's widths), the
+    fullest expert holds at least the mean load, and the step's program
+    is the one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_TRAIN["micro_batch"] * TINY_TRAIN["seq_len"]
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert row["collectives_per_call"] == 0 and row["tolerance"]["why"]
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["train_tokens"] == c["train_steps"] * tokens
+    assert c["moe_token_slots"] == c["train_tokens"] * 2 * 2
+    assert c["moe_max_expert_load"] >= tokens * 2 / 8
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_train_check_tells_the_program_from_its_control(tmp_path):
+    """``benchmark/tools/train_check.py`` at the rehearsal's widths: one
+    step of the program lies within the kind's tolerance of the
+    benchmark's reference at every compared position; the reference
+    computed in bfloat16 lies far outside the program's, and each
+    float32 part as bfloat16 would have made it outside the
+    tolerance."""
+    env = _stage("olmoe-train-1chip", str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "train_check.py"),
+         "--platform", "cpu", "--root", str(tmp_path), "--seeds", "2",
+         "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["outside"] == 0
+        assert row["program"]["widest_units"] < 0.01
+        assert row["program"]["param_dev_in_lr"] < 0.01
+        # at 64 tokens the losses are half the size and the tolerance,
+        # set at 8,192, is not tight: the control lies a hundred times
+        # farther out than the program, and outside on the chip
+        assert row["control_bf16"]["widest_units"] > 0.5
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        assert all(u > 1 for u in
+                   row["control_parts"]["units_by_group"].values())

@@ -105,6 +105,43 @@ def test_index_list_stream_aot_holds_the_kernel_alone():
     assert set(ops) <= {"custom-call", "bitcast"}, ops
 
 
+@pytest.fixture(scope="module")
+def olmoe_rows():
+    """One child for the OLMoE cases: the cell's attention, its two
+    kernels alone and the whole step, for one v5e device.  The child
+    has a time limit (an offline compile has run for 40 minutes before
+    now, PR 27): 240 s, of which the step takes about 15."""
+    pytest.importorskip("libtpu")
+    res = _run_aot_subprocess("--only", "olmoe", "--topology", "v5e:2x2")
+    assert res.get("rows"), res.get("error")
+    return {r["kernel"]: r for r in res["rows"]}
+
+
+def test_olmoe_attention_aot_compiles_at_the_cells_shape(olmoe_rows):
+    """``flash_block_update[_biased]`` as the OLMoE step calls it: causal
+    attention of 2 x 16 heads x 4,096 x 128 in bfloat16 by blocks of
+    1,024 (ten updates, four of them under the triangular bias), and
+    each kernel alone at one pair of blocks."""
+    for name in ("olmoe_causal_attention_4k", "olmoe_flash_block_1k",
+                 "olmoe_flash_block_1k_biased"):
+        assert olmoe_rows[name].get("compiled"), json.dumps(
+            olmoe_rows[name], indent=1)
+    assert olmoe_rows["olmoe_causal_attention_4k"]["entry_ops"][
+        "custom-call"] >= 10
+
+
+def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
+        olmoe_rows):
+    """The whole step of ``benchmark/configs/olmoe-1b-7b-train-1chip
+    .json`` (published widths, one layer): the flash kernel ten times,
+    nine grouped expert matmuls, one loop over the head's row blocks."""
+    row = olmoe_rows["olmoe_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["custom-call"] >= 19
+    assert row["entry_ops"]["while"] == 1
+    assert row["compile_s"] < 120
+
+
 @pytest.mark.slow
 def test_all_kernels_aot_compile():
     pytest.importorskip("libtpu")
