@@ -212,6 +212,27 @@ def collectives(world, clock: Clock, platform: str = "tpu",
                                    rtol=1e-5, atol=1e-5)
     print("  allreduce_array_init handle x2 ok", flush=True)
 
+    # a partitioned allreduce: three buckets released last to first are
+    # one group launch, and its sums are a launch a bucket's bit for bit
+    # (across chips only while XLA's combiner does not merge the
+    # members' all-reduces: coll/xla _group_fn)
+    bk = [xla.make_world_array(
+        rng.standard_normal((n, spot)).astype(np.float32))
+        for _ in range(3)]
+    req = world.pallreduce_init(bk)
+    _require([len(members) for members, _ in req._plan] == [3],
+             f"3 x {spot_bytes} B planned as {req._plan}")
+    req.start()
+    req.pready_list(range(2, -1, -1))
+    req.wait()
+    for out, b in zip(req.result, bk):
+        _on_platform(out, platform, "pallreduce result")
+        np.testing.assert_array_equal(
+            np.asarray(out).view(np.uint32),
+            np.asarray(world.allreduce_array(b)).view(np.uint32))
+    req.free()
+    print("  pallreduce_init 3 buckets, one launch, bits ok", flush=True)
+
 
 # -- 4. the flagship trainer -----------------------------------------------
 def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,

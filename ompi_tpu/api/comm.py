@@ -719,23 +719,33 @@ class Comm(AttributeHost):
     def pallreduce_init(self, buckets, op: op_mod.Op = op_mod.SUM) -> Request:
         """Partitioned persistent allreduce (the ``MPI_Pallreduce_init``
         analog of MPI-4's partitioned model applied to a collective):
-        each entry of ``buckets`` is bound once as its own persistent
-        allreduce; ``req.pready(i)`` releases bucket i — on the device
-        path that is one pre-compiled XLA dispatch, so bucket i's
-        reduction overlaps the computation still producing bucket i+1
-        (bucketed gradient overlap).  ``req.parrived(i)`` tests bucket
-        completion; after all preadys the request is complete and
-        ``req.result[i]`` holds bucket i's reduction.  On host comms
-        without a device binding each pready runs the blocking
-        allreduce (every rank must pready in the same order)."""
+        ``buckets`` are bound once; ``req.pready(i)`` releases bucket i.
+        On the device path the launches are planned here, once: runs of
+        consecutive buckets that together hold less than 64 MiB of one
+        rank (``coll/xla`` ``PGROUP_MIN_BYTES``) share ONE
+        pre-compiled program, dispatched when the run's last member is
+        released, and a bucket at or over that size is dispatched at
+        its own ``pready`` — so a group's reduction overlaps the
+        computation still producing the next group's buckets (bucketed
+        gradient overlap) and a step of many small buckets does not pay
+        a launch each.  Nothing is compiled after this returns.
+        ``req.parrived(i)`` tests bucket completion (and, like
+        ``req.test()``, dispatches a released bucket that still waits
+        for its group through the bucket's own program: a caller that
+        polls between ``pready``s is back to a launch a polled bucket,
+        and its launch count depends on when it polls); after all
+        preadys the request is complete and ``req.result[i]`` holds
+        bucket i's reduction.  On host comms without a device binding
+        each pready runs the blocking allreduce (every rank must pready
+        in the same order)."""
         self._check_state()
         from ompi_tpu.mca.part.pcoll import PartitionedCollRequest
 
         fn = self.c_coll.get("partitioned_coll")
-        handles = fn(self, "allreduce", buckets, op) \
-            if fn is not None else None
+        handles, plan = fn(self, "allreduce", buckets, op) \
+            if fn is not None else (None, None)
         return PartitionedCollRequest(self, "allreduce", buckets, (op,),
-                                      handles)
+                                      handles, plan)
 
     def sendrecv_replace(self, buf, dest: int, source: int = ANY_SOURCE,
                          sendtag: int = 0, recvtag: int = ANY_TAG) -> Status:

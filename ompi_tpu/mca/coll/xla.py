@@ -137,9 +137,66 @@ class PersistentColl:
         self.fn = _freed(self.coll)
 
 
+class GroupedColl:
+    """One bound, pre-compiled program for a run of buckets of a
+    partitioned collective (``partitioned_coll``): the members in, their
+    results out, one launch.  SPC ``device_collectives`` counts
+    collectives, not launches, so a call moves it by the member count.
+    Under a profiler session it writes ``otpu.coll.<coll>_pgroup``."""
+
+    __slots__ = ("fn", "count", "nbytes", "_profiling", "_span")
+
+    def __init__(self, fn, coll: str, count: int, nbytes: int) -> None:
+        self.fn = fn
+        self.count = count
+        self.nbytes = nbytes
+        trace.bind_profiler()
+        self._profiling = trace.profiler_on
+        self._span = f"otpu.coll.{coll}_pgroup"
+
+    def __call__(self, xs):
+        spc.bump_device(self.nbytes, self.count)
+        if self._profiling():
+            with trace.profiler_span(self._span):
+                return self.fn(*xs)
+        return self.fn(*xs)
+
+
+#: One rank's bytes at which ``plan_groups`` closes a group of buckets of
+#: a partitioned allreduce.  It stands for the most reduction work a
+#: bucket may hold back while it waits for its neighbours: 64 MiB keep
+#: one v5e chip busy for 204 us, about what one launch costs the host
+#: (215 us), and each bucket that joins a group saves the host 60-130 us
+#: (PERF.md section 6, PR 34).  PROVISIONAL: read on one chip with no
+#: producer between the Preadys, where a larger bar always reads faster
+#: (fewer launches, nothing to overlap); what it delays on four chips
+#: under a backward pass no cell measures yet (PERF.md section 7).
+PGROUP_MIN_BYTES = 64 << 20
+
+
+def plan_groups(sizes, min_bytes: int) -> list:
+    """The launches of a partitioned collective, planned once: walk the
+    buckets in index order and close a group as soon as its bytes reach
+    ``min_bytes``; the tail is the last group.  A bucket that reaches
+    the bar alone is a group of one.  Static: the plan, and so the
+    launch count of a step that only releases and waits, does not depend
+    on timing."""
+    groups, run, acc = [], [], 0
+    for i, size in enumerate(sizes):
+        run.append(i)
+        acc += size
+        if acc >= min_bytes:
+            groups.append(tuple(run))
+            run, acc = [], 0
+    if run:
+        groups.append(tuple(run))
+    return groups
+
+
 class XlaCollModule:
     def __init__(self, comm, devices, axis_name: str = "mpi",
-                 bcast_sa_min_bytes: int = 256 << 10) -> None:
+                 bcast_sa_min_bytes: int = 256 << 10,
+                 pgroup_min_bytes: int = PGROUP_MIN_BYTES) -> None:
         import jax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -148,6 +205,7 @@ class XlaCollModule:
         self.mesh = Mesh(np.array(self.devices), (axis_name,))
         self.n = len(self.devices)
         self.bcast_sa_min_bytes = int(bcast_sa_min_bytes)
+        self.pgroup_min_bytes = int(pgroup_min_bytes)
         self._cache: dict = {}
         self._lock = threading.Lock()
         self._P = P
@@ -825,7 +883,10 @@ class XlaCollModule:
         if method is None:
             raise MpiError(ErrorClass.ERR_UNSUPPORTED_OPERATION,
                            f"no device collective '{coll}'")
-        template = self._check(comm, template)
+        return self._bind(method, comm, coll,
+                          self._check(comm, template), args)
+
+    def _bind(self, method, comm, coll: str, template, args):
         method(comm, template, *args)   # build + cache + validate
         fn, nbytes = self._cache[self._keyfor(coll, template, *args)]
         return PersistentColl(fn, coll, nbytes)
@@ -833,11 +894,86 @@ class XlaCollModule:
     def partitioned_coll(self, comm, coll: str, buckets, *args):
         """Device side of the partitioned persistent collective (MPI-4
         ``Pallreduce_init`` analog, ``api/comm.py pallreduce_init``):
-        bind one pre-compiled program PER BUCKET so each ``Pready``
-        costs one SPC bump + one async XLA dispatch — bucket i's
-        reduction overlaps whatever is still computing bucket i+1."""
-        return [self.persistent_coll(comm, coll, b, *args)
-                for b in buckets]
+        the launches of a step, planned and compiled once.  Every bucket
+        is bound as its own persistent collective (what a ``Parrived``
+        poll falls back on), then ``plan_groups`` cuts the buckets into
+        runs of at least ``pgroup_min_bytes`` (one rank's bytes) and each
+        run of two or more gets ONE program, members in and members
+        out, so B ``Pready``s cost a launch a group and not a launch a
+        bucket.  Returns ``(handles, plan)``: ``plan[g]`` is ``(members,
+        GroupedColl)``, or ``(members, None)`` for a group of one, which
+        launches through ``handles`` as it always did."""
+        if coll != "allreduce":
+            raise MpiError(ErrorClass.ERR_UNSUPPORTED_OPERATION,
+                           f"no partitioned device collective '{coll}'")
+        buckets = [self._check(comm, b) for b in buckets]
+        handles = [self._bind(self.allreduce_array, comm, coll, b, args)
+                   for b in buckets]
+        op = args[0] if args else op_mod.SUM
+        plan = []
+        for members in plan_groups([b.nbytes // self.n for b in buckets],
+                                   self.pgroup_min_bytes):
+            grouped = None
+            if len(members) > 1:
+                fn, nbytes = self._group_program(
+                    coll, op, [buckets[i] for i in members])
+                grouped = GroupedColl(fn, coll, len(members), nbytes)
+            plan.append((members, grouped))
+        return handles, plan
+
+    def _group_program(self, coll: str, op: op_mod.Op, templates):
+        """The program of one group and the bytes a call moves, cached
+        by the members' shapes and dtypes (equal groups share one
+        executable).  A miss builds it (``_group_fn``) and runs it once
+        on the templates, so nothing compiles inside a step; that call
+        counts no collective."""
+        key = ("pgroup", coll, op.name,
+               tuple((t.shape, t.dtype) for t in templates))
+        entry = self._cache.get(key)
+        first = None
+        if entry is None:
+            with self._lock:
+                entry = self._cache.get(key)
+                if entry is None:
+                    spc.record("device_program_builds")
+                    fn = _spanned(
+                        "build", key[0], templates[0],
+                        lambda: self._group_fn(op, len(templates)))
+                    entry = (fn, sum(t.nbytes for t in templates))
+                    self._cache[key] = entry
+                    first = _first_call(lambda ts: fn(*ts), key[0],
+                                        templates[0])
+        if first is not None:
+            first(templates)
+        return entry
+
+    def _group_fn(self, op: op_mod.Op, m: int):
+        """The jitted program of a group of m members: the per-bucket
+        body applied to each, every reduction made to wait for the one
+        before it.  Left independent, XLA's all-reduce combiner merges
+        the members' psums into ONE all-reduce, which sums in another
+        order than a bucket's own program: on four v5e chips a third of
+        the positions then differ from the per-bucket results (PERF.md
+        section 6, PR 34).  Chained through an optimization barrier each
+        member keeps its own all-reduce, the per-bucket program's op at
+        the per-bucket size, and its results bit for bit."""
+        import jax
+
+        P = self._P
+        body = self._reduce_in_shard(op)
+
+        def group(*ts):
+            ts, outs = list(ts), []
+            for i in range(m):
+                out = body(ts[i][0])
+                if i + 1 < m:
+                    out, ts[i + 1] = jax.lax.optimization_barrier(
+                        (out, ts[i + 1]))
+                outs.append(out)
+            return tuple(outs)
+
+        return self._shard_map(group, (P(self.axis),) * m, (P(),) * m,
+                               name=_program_name("pallreduce", op))
 
     def _keyfor(self, coll: str, x, *args):
         """Single source of truth for program-cache keys (used by the

@@ -13,6 +13,10 @@ _COUNTERS = (
     "unexpected_msgs", "out_of_sequence_msgs", "matched_msgs",
     "rget_msgs", "striped_msgs",
     "part_pready", "part_parrived", "part_msgs", "part_bytes",
+    # programs a partitioned collective launched (mca/part/pcoll): one a
+    # group of buckets, so over part_pready it says how often grouping
+    # engages (1.0 where every Pready is its own launch)
+    "part_group_launches",
     "device_collectives", "device_bytes",
     # coll/xla program cache, off the hot path (a cache hit in _fast
     # bumps none of them): calls that left _fast for _get, programs
@@ -144,14 +148,16 @@ _dev_calls_n = 0
 _dev_bytes_n = 0
 
 
-def bump_device(nbytes: int) -> None:
+def bump_device(nbytes: int, calls: int = 1) -> None:
     """Hot-path SPC bump for device collectives: two plain integer adds
     on module globals (folded into the pvars at read time), mirroring the
     reference's inline non-atomic counter increments (``ompi_spc.c`` —
     SPC counters are not atomic unless multithreaded accuracy is
-    requested)."""
+    requested).  ``calls`` is the collectives one launch carries (a
+    group of buckets of a partitioned collective): the counter counts
+    collectives, not launches."""
     global _dev_calls_n, _dev_bytes_n
-    _dev_calls_n += 1
+    _dev_calls_n += calls
     _dev_bytes_n += nbytes
 
 

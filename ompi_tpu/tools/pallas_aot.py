@@ -370,11 +370,28 @@ def cases(mesh1d, mesh2d):
                               _sds((), jnp.int32, mesh, P())),
                              tokens, tokens)
 
+    # -- a partitioned allreduce's group program (coll/xla _group_fn) over
+    # four chips, at the sizes ``rank1-partitioned`` releases: ``entry_ops``
+    # must show one ``all-reduce`` a member.  Where XLA's combiner merges
+    # them into one, the sums come out in another order than the
+    # per-bucket program's (seen on four v5e chips, PR 34)
+    def pallreduce_group(members, nbytes):
+        from ompi_tpu.api import op as op_mod
+        from ompi_tpu.mca.coll.xla import XlaCollModule
+
+        mod = XlaCollModule(None, topo_devs[:4])
+        return mod._group_fn(op_mod.SUM, members), (
+            _sds((4, nbytes // 4), f32, mod.mesh, P(mod.axis)),) * members
+
     topo_devs = list(_np.asarray(mesh1d.devices).reshape(-1))
     case("olmoe_step_1chip", lambda: olmoe_step(topo_devs[:1]))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))
+        case("pallreduce_group_3x25MiB_2x2",
+             lambda: pallreduce_group(3, 25 << 20))
+        case("pallreduce_group_32x2MiB_2x2",
+             lambda: pallreduce_group(32, 2 << 20))
     return out
 
 

@@ -105,6 +105,26 @@ def test_index_list_stream_aot_holds_the_kernel_alone():
     assert set(ops) <= {"custom-call", "bitcast"}, ops
 
 
+def test_pallreduce_group_aot_keeps_an_all_reduce_a_member():
+    """A partitioned allreduce's group program for four v5e chips, at
+    ``rank1-partitioned``'s sizes: one ``all-reduce`` a member.  With
+    the members' psums left independent XLA's combiner makes ONE
+    all-reduce of them, and on the chips its sums differ from the
+    per-bucket program's in a third of the positions (PR 34)."""
+    pytest.importorskip("libtpu")
+    res = _run_aot_subprocess("--only", "pallreduce_group", "--topology",
+                              "v5e:2x2")
+    assert res.get("rows"), res.get("error")
+    rows = {r["kernel"]: r for r in res["rows"]}
+    assert set(rows) == {"pallreduce_group_3x25MiB_2x2",
+                         "pallreduce_group_32x2MiB_2x2"}
+    for name, members in (("pallreduce_group_3x25MiB_2x2", 3),
+                          ("pallreduce_group_32x2MiB_2x2", 32)):
+        assert rows[name].get("compiled"), json.dumps(rows[name], indent=1)
+        assert rows[name]["entry_ops"].get("all-reduce") == members, \
+            rows[name]["entry_ops"]
+
+
 @pytest.fixture(scope="module")
 def olmoe_rows():
     """One child for the OLMoE cases: the cell's attention, its two
@@ -172,5 +192,7 @@ def test_all_kernels_aot_compile():
                    "vpu_reduce_stack_gathered_prod_f32",
                    "ddt_compact_lammps_f32",
                    # the composed flagship step
-                   "train_step_1dev", "train_step_2x2"):
+                   "train_step_1dev", "train_step_2x2",
+                   "pallreduce_group_3x25MiB_2x2",
+                   "pallreduce_group_32x2MiB_2x2"):
         assert expect in names, f"AOT case list lost {expect}"

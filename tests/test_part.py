@@ -415,6 +415,252 @@ def test_pallreduce_matches_plain_allreduce(world):
                                    rtol=1e-5)
 
 
+def _xla(world):
+    return world.c_coll["allreduce_array"].__self__
+
+
+@pytest.fixture
+def pgroup_bar(world):
+    """Set-and-restore handle on the planner's bar (the module's
+    ``pgroup_min_bytes``, ``coll/xla`` ``PGROUP_MIN_BYTES`` by default)."""
+    m = _xla(world)
+    old = m.pgroup_min_bytes
+    yield lambda v: setattr(m, "pgroup_min_bytes", v)
+    m.pgroup_min_bytes = old
+
+
+#: float32 elements one rank holds of each bucket (4 bytes each)
+_BUCKET_ELEMS = {
+    "equal": [16] * 8,
+    "ragged": [4, 64, 8, 8, 40, 4, 4],
+    "single": [32],
+}
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _release(req, order, idx, check):
+    """Release every bucket the way ``order`` names; ``check`` runs
+    after each single ``pready`` with the set released so far."""
+    if order == "pready_range":
+        req.pready_range(0, len(idx) - 1)
+    elif order == "pready_list":
+        req.pready_list(reversed(idx))
+    elif order == "threads":
+        import threading
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            lanes = [threading.Thread(target=req.pready_list,
+                                      args=(idx[k::4],)) for k in range(4)]
+            for t in lanes:
+                t.start()
+            for t in lanes:
+                t.join(60)
+            assert not any(t.is_alive() for t in lanes)
+        finally:
+            sys.setswitchinterval(old)
+    else:
+        seq = {"last_to_first": idx[::-1], "first_to_last": idx,
+               "shuffled": list(np.random.RandomState(5).permutation(idx))
+               }[order]
+        released = set()
+        for p in seq:
+            req.pready(p)
+            released.add(int(p))
+            check(released)
+
+
+@pytest.mark.parametrize("order", ["last_to_first", "first_to_last",
+                                   "shuffled", "pready_range",
+                                   "pready_list", "threads"])
+@pytest.mark.parametrize("bar", [1, 128, 1 << 30])
+@pytest.mark.parametrize("sizes", list(_BUCKET_ELEMS))
+def test_pallreduce_groups_launch_as_planned(world, pgroup_bar, sizes,
+                                             bar, order):
+    """One launch a planned group, whatever the release order: every
+    result equals the per-bucket program's bit for bit, a group launches
+    with its last member (a bucket at or over the bar at its own
+    pready), SPC ``device_collectives`` counts buckets and not launches,
+    and nothing is built after ``pallreduce_init``."""
+    from ompi_tpu.mca.coll.xla import plan_groups
+    from ompi_tpu.runtime import spc
+
+    elems = _BUCKET_ELEMS[sizes]
+    n, B = world.size, len(elems)
+    idx = list(range(B))
+    m = _xla(world)
+    rng = np.random.RandomState(11)
+    epochs = [[m.make_world_array(rng.normal(size=(n, e))
+                                  .astype(np.float32)) for e in elems]
+              for _ in range(2)]
+    want = [[_bits(world.allreduce_array(b)) for b in bs] for bs in epochs]
+    plan = plan_groups([4 * e for e in elems], bar)
+    assert sorted(i for g in plan for i in g) == idx
+    if bar == 1:
+        assert plan == [(i,) for i in idx]
+    if bar == 1 << 30:
+        assert plan == [tuple(idx)]
+
+    pgroup_bar(bar)
+    c0 = spc.read("device_collectives")
+    req = world.pallreduce_init(epochs[0])
+    assert spc.read("device_collectives") - c0 == B
+    assert [members for members, _ in req._plan] == plan
+    builds = spc.read("device_program_builds")
+
+    def check(released):
+        for members in plan:
+            launched = [req.result[i] is not None for i in members]
+            assert all(launched) == set(members).issubset(released)
+            assert any(launched) == all(launched)
+
+    launches = []
+    for buckets, bits in zip(epochs, want):
+        c0 = spc.read("device_collectives")
+        l0 = spc.read("part_group_launches")
+        req.start(buckets)
+        _release(req, order, idx, check)
+        req.wait()
+        for got, ref in zip(req.result, bits):
+            np.testing.assert_array_equal(_bits(got), ref)
+        assert spc.read("device_collectives") - c0 == B
+        launches.append(spc.read("part_group_launches") - l0)
+    assert launches == [len(plan)] * 2
+    assert spc.read("device_program_builds") == builds
+
+
+def _arrives(req, p, seconds: float = 60.0) -> bool:
+    """Poll ``parrived(p)`` until it is true: the dispatch is async, and
+    a count of polls instead of a deadline fails on a loaded host."""
+    import time
+
+    deadline = time.monotonic() + seconds
+    while not req.parrived(p) and time.monotonic() < deadline:
+        time.sleep(0)
+    return req.parrived(p)
+
+
+@pytest.mark.parametrize("poll", ["parrived", "test"])
+def test_pallreduce_poll_progresses_a_waiting_bucket(world, pgroup_bar,
+                                                     poll):
+    """A caller that polls one bucket before it produces the next still
+    gets its result: ``parrived`` (and ``test``) dispatch a released
+    bucket whose group waits for members through its own program, and
+    the group's later members still arrive."""
+    from ompi_tpu.runtime import spc
+
+    n = world.size
+    buckets = [np.full((n, 4), float(i + 1), np.float32)
+               for i in range(4)]
+    pgroup_bar(1 << 30)                     # one group of four
+    req = world.pallreduce_init(buckets)
+    builds = spc.read("device_program_builds")
+    l0 = spc.read("part_group_launches")
+    req.start()
+    req.pready(3)
+    assert req.result[3] is None            # waits for its group
+    assert not req.parrived(2)              # not released: no dispatch
+    assert req.result[2] is None
+    if poll == "test":
+        assert req.test() == (False, None)
+    assert _arrives(req, 3)
+    assert spc.read("part_group_launches") - l0 == 1
+    for i in (1, 0, 2):
+        req.pready(i)
+    req.wait()
+    for i in range(4):
+        assert _arrives(req, i)
+        np.testing.assert_allclose(np.asarray(req.result[i]), (i + 1) * n)
+    assert spc.read("part_group_launches") - l0 == 4
+    assert spc.read("device_program_builds") == builds
+    # the next epoch, not polled, is one launch again
+    req.start()
+    req.pready_list(range(4))
+    req.wait()
+    assert spc.read("part_group_launches") - l0 == 5
+
+
+@pytest.mark.parametrize("order", [(0, 1, 2), (2, 1, 0)])
+def test_pallreduce_failed_group_dispatch_does_not_wedge(world, pgroup_bar,
+                                                         order):
+    """A group dispatch that raises (a rebind with one member
+    mismatching the bound template) un-marks the bucket whose pready
+    triggered it: the same error on retry, never 'already released',
+    and the request stays restartable."""
+    n = world.size
+    good = [np.full((n, 4), float(i + 1), np.float32) for i in range(3)]
+    pgroup_bar(1 << 30)
+    req = world.pallreduce_init(good)
+    bad = list(good)
+    bad[1] = np.ones((n + 1, 4), np.float32)   # not divisible by the mesh
+    req.start(bad)
+    req.pready(order[0])
+    req.pready(order[1])                       # joins its group: no launch
+    with pytest.raises(Exception) as first:
+        req.pready(order[2])
+    assert "already released" not in str(first.value)
+    with pytest.raises(Exception) as again:    # rollback: same error
+        req.pready(order[2])
+    assert type(again.value) is type(first.value)
+    assert "already released" not in str(again.value)
+    with pytest.raises(MpiError, match="already released"):
+        req.pready(order[0])                   # the others stay released
+    assert req.result == [None] * 3
+    req.free()
+    req.start(good)
+    req.pready_list(order)
+    req.wait()
+    for i in range(3):
+        np.testing.assert_allclose(np.asarray(req.result[i]), (i + 1) * n)
+
+
+def test_pallreduce_failed_poll_after_last_pready_ends_the_wait(
+        world, pgroup_bar):
+    """A poll's dispatch that fails after the group's last member was
+    released (by another thread, here from inside the failing dispatch)
+    has no ``pready`` left to take its buckets up again: the request
+    completes in error, ``wait()`` raises it and does not spin, and the
+    request restarts."""
+    n = world.size
+    good = [np.full((n, 4), float(i + 1), np.float32) for i in range(3)]
+    pgroup_bar(1 << 30)
+    req = world.pallreduce_init(good)
+    handle = req._handles[1]
+
+    def racing(x):
+        req.pready(2)                # the last member arrives meanwhile
+        raise ValueError("bucket 1 cannot be dispatched")
+
+    req._handles[1] = racing
+    req.start()
+    req.pready(0)
+    req.pready(1)
+    with pytest.raises(ValueError, match="bucket 1"):
+        req.parrived(0)              # claims 0 and 1; 1 fails
+    assert req.result[2] is not None     # launched alone, as a poll left it
+    assert req.result[0] is None and req.result[1] is None
+    with pytest.raises(MpiError, match="bucket 1 cannot be dispatched"):
+        req.wait(timeout=30)
+    done, status = req.get_status()
+    assert done and status.error == ErrorClass.ERR_OTHER
+    req._handles[1] = handle
+    req.start()
+    req.pready_list(range(3))
+    req.wait()
+    for i in range(3):
+        np.testing.assert_allclose(np.asarray(req.result[i]), (i + 1) * n)
+
+
+def test_partitioned_coll_is_the_allreduce_only(world):
+    with pytest.raises(MpiError, match="no partitioned device collective"):
+        _xla(world).partitioned_coll(world, "allgather", [np.ones(
+            (world.size, 4), np.float32)])
+
+
 def test_bucket_overlap_dryrun_bit_identical():
     """The acceptance pin: parallel_bucket_overlap produces bit-identical
     parameters to the non-overlapped trainer step (8-device virtual
