@@ -41,9 +41,9 @@ def _block_kernel(scale, biased, *refs):
         bias_ref = None
     q = q_ref[0]            # (tq, d)
     k = k_ref[0]            # (skv, d)
-    v = v_ref[0]
+    v = v_ref[0]            # (skv, dv)
     m = m_ref[0]            # (tq, LANES) broadcast copies, col 0 is live
-    num = num_ref[0]        # (tq, d)
+    num = num_ref[0]        # (tq, dv)
     den = den_ref[0]        # (tq, LANES)
 
     s = jax.lax.dot_general(
@@ -60,7 +60,7 @@ def _block_kernel(scale, biased, *refs):
     p = jnp.exp(s - new_m)                               # (tq, skv)
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (tq, d)
+        preferred_element_type=jnp.float32)              # (tq, dv)
     numo_ref[0] = (num * c + pv).astype(num.dtype)
     deno_ref[0] = (den[:, :1] * c + jnp.sum(p, axis=-1, keepdims=True)
                    ) * jnp.ones_like(den)
@@ -88,8 +88,11 @@ def _update_jnp(q, k_blk, v_blk, m, num, den, bias=None):
 def flash_block_update(q, k_blk, v_blk, m, num, den, interpret=None):
     """One online-softmax accumulation step against a K/V block.
 
-    q: (b, h, sq, d); k_blk/v_blk: (b, h, skv, d); m/den: (b, h, sq);
-    num: (b, h, sq, d).  Returns updated (m, num, den).  Forward runs the
+    q: (b, h, sq, d); k_blk: (b, h, skv, d); v_blk: (b, h, skv, dv);
+    m/den: (b, h, sq); num: (b, h, sq, dv).  ``dv`` may differ from ``d``
+    (latent attention: q and k 192 wide, v and the numerator 128): the
+    scores contract over ``d``, whatever it is, and only ``dv`` shapes
+    the numerator.  Returns updated (m, num, den).  Forward runs the
     fused Pallas kernel; reverse-mode recomputes through the jnp block
     math (the Pallas custom-VJP pattern — kernels have no autodiff rule).
     ``interpret``: None resolves from the process's default devices; a
@@ -144,7 +147,7 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
     if interpret is None:
         interpret = pallas_interpret()
     b, h, sq, d = q.shape
-    skv = k_blk.shape[2]
+    skv, dv = k_blk.shape[2], v_blk.shape[-1]
     scale = 1.0 / math.sqrt(d)
     bh = b * h
     tq = min(Q_TILE, sq)
@@ -154,21 +157,26 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
     lanes = 128
     qf = q.reshape(bh, sq, d)
     kf = k_blk.reshape(bh, skv, d)
-    vf = v_blk.reshape(bh, skv, d)
+    vf = v_blk.reshape(bh, skv, dv)
     # carry scalars per row are lane-broadcast so refs stay (…, 128)-tiled
     mf = jnp.broadcast_to(m.reshape(bh, sq)[..., None], (bh, sq, lanes))
-    nf = num.reshape(bh, sq, d)
+    nf = num.reshape(bh, sq, dv)
     df = jnp.broadcast_to(den.reshape(bh, sq)[..., None], (bh, sq, lanes))
 
     grid = (bh, sq // tq)
     row = lambda i, j: (i, j, 0)
     blk = lambda i, j: (i, 0, 0)
+    # a block's last dimension is the array's own, so a width that is no
+    # multiple of the 128 lanes (192) is Mosaic's to lay out: it pads the
+    # tile in VMEM and the contraction takes the MXU passes of 256
     q_spec = pl.BlockSpec((1, tq, d), row)
-    kv_spec = pl.BlockSpec((1, skv, d), blk)
+    k_spec = pl.BlockSpec((1, skv, d), blk)
+    v_spec = pl.BlockSpec((1, skv, dv), blk)
+    n_spec = pl.BlockSpec((1, tq, dv), row)
     s_spec = pl.BlockSpec((1, tq, lanes), row)
 
     biased = bias is not None
-    in_specs = [q_spec, kv_spec, kv_spec, s_spec, q_spec, s_spec]
+    in_specs = [q_spec, k_spec, v_spec, s_spec, n_spec, s_spec]
     operands = [qf, kf, vf, mf.astype(jnp.float32), nf,
                 df.astype(jnp.float32)]
     if biased:
@@ -188,7 +196,7 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=(s_spec, q_spec, s_spec),
+        out_specs=(s_spec, n_spec, s_spec),
         interpret=interpret,
         name="otpu_flash_block_update",
     )(*operands)

@@ -319,7 +319,9 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
     through one online-softmax update (the fused Pallas kernel where
     Mosaic compiles it, its jnp twin with float32 scores elsewhere).
     The running max, numerator and denominator are float32 whatever
-    q, k, v are.  Returns (o float32, logsumexp float32)."""
+    q, k, v are.  v, and so the numerator and o, may be of another width
+    than q and k (latent attention: 192 and 128).  Returns (o float32,
+    logsumexp float32)."""
     from ompi_tpu.ops.flash_attention import (flash_block_update,
                                               flash_block_update_biased)
 
@@ -332,7 +334,7 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
         qi = q[:, :, i * block:(i + 1) * block]
         zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
         m, den = zero - jnp.inf, zero
-        num = (qi * 0).astype(jnp.float32)
+        num = jnp.zeros(v.shape[-1:], jnp.float32) + zero[..., None]
         for j in range(i + 1):
             kj = k[:, :, j * block:(j + 1) * block]
             vj = v[:, :, j * block:(j + 1) * block]
@@ -361,8 +363,8 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def causal_flash_attention(q, k, v, block: int, interpret: bool):
-    """Causal self-attention of (b, h, s, hd) q, k, v whose length is a
-    multiple of ``block``.  Forward: ``_causal_fwd_blocks``.  Backward:
+    """Causal self-attention of (b, h, s, hd) q, k and (b, h, s, hv) v
+    whose length is a multiple of ``block``.  Forward: ``_causal_fwd_blocks``.  Backward:
     the flash backward by the same blocks (scores recomputed from q, k
     and the saved logsumexp in float32; no (s, s) array is ever held),
     its matmul inputs in q's dtype."""
@@ -374,6 +376,28 @@ def _causal_fwd(q, k, v, block, interpret):
     return o, (q, k, v, o, lse)
 
 
+#: up to this many blocks the backward pass's block pairs are unrolled
+#: (10 pairs at OLMoE's 4 blocks: what that step has always compiled
+#: to); beyond it they are walked by one ``lax.scan``, a pair's scores
+#: held at a time.  Unrolled, the 36 pairs of 8 blocks let XLA hold 15
+#: and more (h, block, block) float32 score blocks at once: 19.6 GB for
+#: the JoyAI step (offline compile for a v5e, PR 35)
+UNROLLED_BLOCKS = 4
+
+
+def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
+    """One block pair of the flash backward: (dq, dk, dv) parts."""
+    sc = _contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
+    if bias is not None:
+        sc = sc + bias
+    p = jnp.exp(sc - lse_i[..., None])
+    dv = _contract("bhqk,bhqd->bhkd", p, doi, dt)
+    dp = _contract("bhqd,bhkd->bhqk", doi, vj, dt)
+    ds = p * (dp - delta_i[..., None]) * scale
+    return (_contract("bhqk,bhkd->bhqd", ds, kj, dt),
+            _contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
+
+
 def _causal_bwd(block, interpret, res, do):
     q, k, v, o, lse = res
     dt = q.dtype
@@ -382,6 +406,8 @@ def _causal_bwd(block, interpret, res, do):
     bias = _tri_bias(block)
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
+    if nb > UNROLLED_BLOCKS:
+        return _causal_bwd_scanned(q, k, v, do, lse, delta, block)
     cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
     dq = [0.0] * nb
     dk = [0.0] * nb
@@ -390,18 +416,45 @@ def _causal_bwd(block, interpret, res, do):
         qi, doi = cut(q, i), cut(do, i)
         lse_i, delta_i = cut(lse, i), cut(delta, i)
         for j in range(i + 1):
-            kj, vj = cut(k, j), cut(v, j)
-            sc = _contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
-            if j == i:
-                sc = sc + bias
-            p = jnp.exp(sc - lse_i[..., None])
-            dv[j] = dv[j] + _contract("bhqk,bhqd->bhkd", p, doi, dt)
-            dp = _contract("bhqd,bhkd->bhqk", doi, vj, dt)
-            ds = p * (dp - delta_i[..., None]) * scale
-            dq[i] = dq[i] + _contract("bhqk,bhkd->bhqd", ds, kj, dt)
-            dk[j] = dk[j] + _contract("bhqk,bhqd->bhkd", ds, qi, dt)
+            dq_c, dk_c, dv_c = _bwd_pair(
+                qi, cut(k, j), cut(v, j), doi, lse_i, delta_i,
+                bias if j == i else None, scale, dt)
+            dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
     cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
     return cat(dq), cat(dk), cat(dv)
+
+
+def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
+    """The same pairs in the same order (q block by q block, kv blocks
+    ascending), one a step of a ``lax.scan`` over float32 accumulators."""
+    dt = q.dtype
+    b, h, s, _ = q.shape
+    nb = s // block
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    tri = _tri_bias(block)
+    blocks = lambda a: jnp.moveaxis(
+        a.reshape(b, h, nb, block, *a.shape[3:]), 2, 0)
+    qb, kb, vb, dob = blocks(q), blocks(k), blocks(v), blocks(do)
+    lseb, deltab = blocks(lse), blocks(delta)
+    pairs = [(i, j) for i in range(nb) for j in range(i + 1)]
+    zero = lambda a: (a * 0).astype(jnp.float32)         # carries a's vma
+
+    def step(acc, ij):
+        i, j = ij
+        dq_c, dk_c, dv_c = _bwd_pair(
+            qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i],
+            jnp.where(i == j, tri, 0.0), scale, dt)
+        dq, dk, dv = acc
+        return (dq.at[i].add(dq_c), dk.at[j].add(dk_c),
+                dv.at[j].add(dv_c)), None
+
+    (dq, dk, dv), _ = jax.lax.scan(
+        step, (zero(qb), zero(kb), zero(vb)),
+        (jnp.asarray([p[0] for p in pairs]),
+         jnp.asarray([p[1] for p in pairs])))
+    whole = lambda a: jnp.moveaxis(a, 0, 2).reshape(
+        b, h, s, a.shape[-1]).astype(dt)
+    return whole(dq), whole(dk), whole(dv)
 
 
 causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
@@ -427,14 +480,96 @@ def olmoe_attention(p, x, cfg, *, interpret: bool):
     return x + matmul(o, p["wo"], dt)
 
 
-def olmoe_block(p, x, cfg, *, interpret: bool):
-    """One OLMoE decoder layer; returns (x, the router's statistics,
-    what the router read and made by token row:
-    ``moe.moe_sorted_block``)."""
-    from ompi_tpu.parallel.moe import moe_sorted_block
+def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
+    """Rotary position embedding on interleaved pairs (x[2i], x[2i+1])
+    (DeepSeek-V3's ``rope_interleave``) of the entries from ``first`` on
+    of ``x``'s last axis, at positions 0..s-1 along ``seq_axis``; the
+    entries before ``first`` pass unchanged.  One elementwise pass over
+    the whole width (the pair's partner comes by a lane rotation), so a
+    head's rotary tail is neither cut off nor put back: the strided
+    halves of a 64-wide tail each pad to 128 lanes, four times their
+    size (2 GiB of them in the JoyAI step, offline compile, PR 35)."""
+    width, s = x.shape[-1], x.shape[seq_axis]
+    hd = width - first
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    pad = lambda a, fill: jnp.concatenate(
+        [jnp.full((s, first), fill, jnp.float32), jnp.repeat(a, 2, -1)], -1)
+    shape = [1] * x.ndim
+    shape[seq_axis], shape[-1] = s, width
+    cos = pad(jnp.cos(ang), 1.0).reshape(shape)
+    sin = pad(jnp.sin(ang), 0.0).reshape(shape)
+    is_first = (jnp.arange(width) - first) % 2 == 0
+    partner = jnp.where(is_first, -jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
+    return x * cos + partner * sin
 
-    with jax.named_scope("otpu_attention"):
-        x = olmoe_attention(p, x, cfg, interpret=interpret)
+
+def mla_attention(p, x, cfg, *, interpret: bool):
+    """DeepSeek-V3's latent attention sublayer (arXiv:2412.19437 section
+    2.1.1) on the residual stream ``x`` (b, s, d) float32: pre-norm; q
+    through a normed latent of ``q_lora_rank``; k's no-position part and
+    v through a normed latent of ``kv_lora_rank``; one rotary key of
+    ``qk_rope_head_dim`` that every head shares; causal ``softmax(q k^T
+    / sqrt(nope + rope)) v`` with q, k of one width and v of another;
+    residual add.  The two inner norms, RoPE and the softmax in float32;
+    matmul inputs in ``compute_dtype``.  Training holds no cache, so the
+    latents are expanded to full keys and values."""
+    b, s, _ = x.shape
+    nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
+    nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    h = rmsnorm_gain(x, p["ln1"], eps)
+    cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
+    q = matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
+    q = rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
+    kv = matmul(h, p["wkv_a"], dt)                       # (b, s, rank + rot)
+    ckv = rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+    kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
+    k_rot = rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
+    k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
+        k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
+    heads = lambda t: t.transpose(0, 2, 1, 3)            # (b, nh, s, .)
+    o = causal_flash_attention(heads(q.astype(dt)), heads(k),
+                               heads(kvb[..., nope:].astype(dt)),
+                               min(cfg.attn_block, s), interpret)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
+    return x + matmul(o, p["wo"], dt)
+
+
+def swiglu(h, gate, up, down, compute_dtype):
+    """``down(silu(gate h) * up h)``: a dense feed-forward, or a shared
+    expert, on rows ``h`` (T, d)."""
+    act = jax.nn.silu(matmul(h, gate, compute_dtype)) \
+        * matmul(h, up, compute_dtype)
+    return matmul(act, down, compute_dtype)
+
+
+def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
+    """One decoder layer of a public model, its sublayers chosen by what
+    the layer holds and the configuration's published keys say: latent
+    attention where ``kv_lora_rank`` is set, else OLMoE's; a dense
+    SwiGLU where the layer has no router, else the sparse MLP
+    (``moe.moe_sorted_block``: every expert here, softmax scores; or
+    ``moe.moe_shared_local_block``: a share of the experts beside a
+    shared one, sigmoid scores chosen under ``bias``).  Returns (x, the
+    router's statistics, what the router read and made by token row);
+    the last two are empty for a dense layer."""
+    from ompi_tpu.parallel import moe
+
+    if cfg.kv_lora_rank:
+        with jax.named_scope("otpu_mla"):
+            x = mla_attention(p, x, cfg, interpret=interpret)
+    else:
+        with jax.named_scope("otpu_attention"):
+            x = olmoe_attention(p, x, cfg, interpret=interpret)
+    if "router" not in p:
+        with jax.named_scope("otpu_dense_mlp"):
+            h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps)
+            y = swiglu(h.reshape(-1, h.shape[-1]), p["gate"], p["up"],
+                       p["down"], cfg.compute_dtype)
+        return x + y.reshape(x.shape), {}, {}
     with jax.named_scope("otpu_moe"):
-        y, stats, routed = moe_sorted_block(p, x, cfg)
+        if cfg.scoring_func == "sigmoid":
+            y, stats, routed = moe.moe_shared_local_block(p, x, cfg, bias)
+        else:
+            y, stats, routed = moe.moe_sorted_block(p, x, cfg)
     return x + y, stats, routed

@@ -894,3 +894,147 @@ def moe_sorted_block(p, x, cfg):
     return out.reshape(b, s, d), stats, {
         "in": h, "logits": logits, "lse": lse, "weights": weights,
         "experts": experts}
+
+
+# -- a share of the routed experts beside a shared one (DeepSeek-V3's
+# expert layer on one rank of an expert-parallel deployment) -------------
+def route_sigmoid_bias(logits, bias, top_k: int, normalize: bool,
+                       scale: float):
+    """DeepSeek-V3's ``noaux_tc`` routing with one group: ``sigmoid``
+    scores over every expert, the ``top_k`` largest of score + ``bias``
+    (the balancing bias enters the choice and nothing else), weights the
+    chosen scores themselves, normalised to sum to one if ``normalize``
+    and times ``scale``.  Returns (scores (T, E), weights (T, k),
+    experts (T, k))."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return scores, weights * scale, experts
+
+
+def local_dispatch(experts, first: int, n_here: int):
+    """The T x k token-slots with those of the ``n_here`` experts held
+    here (``first`` and up) in front, sorted by expert: returns (the
+    slots in that order, the slots each held expert received).  Only
+    integers are sorted; no row of activations moves here."""
+    import jax.numpy as jnp
+
+    t, k = experts.shape
+    here = experts.reshape(t * k) - first
+    key = jnp.where((here >= 0) & (here < n_here), here, n_here)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((n_here + 1,), jnp.int32).at[key].add(1)[:n_here]
+    return order, sizes
+
+
+def local_expert_ffn(h, order, weights, sizes, p, cfg):
+    """The held experts' weighted part of the layer's output (T, d):
+    gather the held slots' rows, grouped matmuls, scatter-add by token.
+    The slots held vary from step to step (0 to every slot a token can
+    send here) and none is dropped.  They are walked in chunks of twice
+    the mean load's rows by a loop that runs as many times as the held
+    slots need (``lax.fori_loop`` to a count read on the device): gather,
+    matmuls and scatter cost by the slots that are here, to a chunk, and
+    not by T x k, and one chunk's buffers are held at a time.  Such a
+    loop has no transpose, so the backward pass is written out: the same
+    loop over the same chunks, each chunk's forward recomputed and its
+    cotangents added up."""
+    import jax
+    import jax.numpy as jnp
+
+    t, d = h.shape
+    k, n_here = cfg.num_experts_per_tok, sizes.shape[0]
+    mean = max(1, t * k * n_here // cfg.num_experts)
+    rows = 2 * max(4, 1 << (mean - 1).bit_length())
+    padded = -(-t * k // rows) * rows
+    order = jnp.pad(order, (0, padded - t * k))
+
+    def chunk(lo, order, sizes, h, flat_w, gate, up, down):
+        """Chunk ``lo``'s (token of each row, its weighted output)."""
+        slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        token = slot // k
+        ends = jnp.cumsum(sizes)
+        live = lo + jnp.arange(rows) < ends[-1]
+        here = jnp.clip(jnp.minimum(ends, lo + rows)
+                        - jnp.maximum(ends - sizes, lo), 0, rows)
+        # rows past the last held slot belong to no group: a grouped
+        # matmul leaves them as they were in memory (seen on the v5e:
+        # NaN), in its transposes too, so they are cut off on both sides
+        xs = jnp.where(live[:, None], h[token], 0.0)
+        y = grouped_expert_ffn(xs, gate, up, down, here, cfg.compute_dtype)
+        w = jnp.where(live, flat_w[slot], 0.0)
+        return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
+
+    def trips(sizes):
+        return (jnp.sum(sizes) + rows - 1) // rows
+
+    @jax.custom_vjp
+    def run(order, sizes, h, flat_w, gate, up, down):
+        def body(c, out):
+            token, y = chunk(c * rows, order, sizes, h, flat_w, gate, up,
+                             down)
+            return out.at[token].add(y)
+        return jax.lax.fori_loop(0, trips(sizes), body, h * 0)
+
+    def fwd(*args):
+        return run(*args), args
+
+    def bwd(args, ct):
+        order, sizes, *diff = args
+
+        def body(c, acc):
+            token = jax.lax.dynamic_slice_in_dim(order, c * rows, rows) // k
+            _, vjp = jax.vjp(lambda *diff: chunk(
+                c * rows, order, sizes, *diff)[1], *diff)
+            return jax.tree.map(jnp.add, acc, vjp(ct[token]))
+
+        return (None, None) + tuple(jax.lax.fori_loop(
+            0, trips(sizes), body, tuple(a * 0 for a in diff)))
+
+    run.defvjp(fwd, bwd)
+    return run(order, sizes, h, weights.reshape(t * k), p["gate"], p["up"],
+               p["down"])
+
+
+def moe_shared_local_block(p, x, cfg, bias):
+    """DeepSeek-V3's sparse MLP sublayer (arXiv:2412.19437 section
+    2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
+    ``experts_here`` of the routed experts: pre-norm; the router's
+    sigmoid scores over **all** the experts in float32; the top k of
+    score + ``bias`` (E,); the shared expert on every token; the held
+    experts on the slots routed to them (``local_expert_ffn``).  What
+    the absent experts would add is left out.  Returns (the sublayer's
+    output before the residual add; ``slots`` an expert of all of them
+    received; by token row what the router read and made: ``in``,
+    ``logits``, ``scores`` (T, E), ``weights`` and ``experts`` (T, k))."""
+    import jax
+    import jax.numpy as jnp
+
+    from ompi_tpu.parallel.model import rmsnorm_gain, swiglu
+
+    b, s, d = x.shape
+    t, k = b * s, cfg.num_experts_per_tok
+    h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
+    with jax.named_scope("otpu_router"):
+        logits = jnp.dot(h, p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        scores, weights, experts = route_sigmoid_bias(
+            logits, bias, k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
+        order, sizes = local_dispatch(experts, cfg.first_expert_here,
+                                      cfg.n_experts_here)
+        slots = jnp.zeros((cfg.num_experts,), jnp.int32).at[
+            experts.reshape(t * k)].add(1)
+    with jax.named_scope("otpu_shared_expert"):
+        out = swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"],
+                     cfg.compute_dtype)
+    with jax.named_scope("otpu_experts"):
+        out = out + local_expert_ffn(h, order, weights, sizes, p, cfg)
+    return out.reshape(b, s, d), {"slots": slots.astype(jnp.float32)}, {
+        "in": h, "logits": logits, "scores": scores, "weights": weights,
+        "experts": experts}

@@ -114,8 +114,8 @@ def grads(params, tokens, labels, cfg: ModelConfig):
 
 def adamw_step(params, mom, var, t: int, g, cfg: ModelConfig):
     """One update, ``t`` counted from 1: (params, mom, var)."""
-    out = ({"layers": {}}, {"layers": {}}, {"layers": {}})
-    for name, path in leaf_names():
+    out = ({}, {}, {})
+    for name, path in leaf_names(cfg):
         p, m, v, gi = (_leaf(tr, path) for tr in (params, mom, var, g))
         m = cfg.adam_b1 * m + (1 - cfg.adam_b1) * gi
         v = cfg.adam_b2 * v + (1 - cfg.adam_b2) * gi * gi

@@ -380,33 +380,49 @@ def build_train_step(mesh, spec: MeshSpec, lr: float = 2.0,
     return step, place
 
 
-# -- a public model's training step (OLMoE): widths from a configuration
-# file, not from the mesh -----------------------------------------------
-#: a layer's leaves, stacked over the layers this rank holds
+# -- a public model's training step (OLMoE, JoyAI-LLM-Flash): widths from
+# a configuration file, not from the mesh; the kinds of sublayer from its
+# published keys ---------------------------------------------------------
+#: OLMoE's layer leaves, stacked over the layers this rank holds
 LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
                 "router", "gate", "up", "down")
+GAINS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "q_a_norm",
+         "kv_a_norm", "enorm", "hnorm", "norm")
 PROBE = 64              # entries of each leaf that a step reports
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
 #: them outside the step (no unit, no scaling).  ``losses`` (the total,
 #: cross-entropy, and the load-balancing and router z losses as weighted
-#: into the total); ``loads`` (L, E) the slots an expert received;
-#: ``rows`` (T, 2) every row's logsumexp over the vocabulary and its
-#: label's logit; ``experts`` (L, T, k) the experts every token chose;
-#: by leaf in ``leaf_names()``'s order ``grad_sq`` (the gradient's sum of
-#: squares), ``grad_probe`` and ``param_probe`` (the gradient and the
-#: updated parameter at ``probe_positions``); and ``sample``, what went
-#: into and came out of the float32 parts at ``sample_rows`` of each
-#: shard: ``router_in`` (L, R, d), ``router_logits`` (L, R, E),
-#: ``router_lse`` (L, R), ``router_weights`` (L, R, k), ``head_in``
-#: (R, d), so that their precision can be read from one step alone
+#: into the total, then the next-next-token loss likewise where the model
+#: has that module); ``loads`` (L, E) the slots an expert received, of
+#: all the experts a router knows, a row a sparse layer (the module's
+#: last); ``rows`` (T, 2) every row's logsumexp over the vocabulary and
+#: its label's logit (``mtp_rows``: the module's head);
+#: ``experts`` (L, T, k) the experts every token chose; ``local_slots``
+#: the slots that went to experts held here, where the rank holds a
+#: share of them; by leaf in ``leaf_names(cfg)``'s order ``grad_sq`` (the
+#: gradient's sum of squares), ``grad_probe`` and ``param_probe`` (the
+#: gradient and the updated parameter at ``probe_positions``); and
+#: ``sample``, what went into and came out of the float32 parts at
+#: ``sample_rows`` of each shard: ``router_in`` (L, R, d),
+#: ``router_logits`` (L, R, E), ``router_lse`` (L, R) or, of a sigmoid
+#: router, ``router_scores`` (L, R, E), ``router_weights`` (L, R, k),
+#: ``head_in`` (R, d) (``mtp_head_in``), so that their precision can be
+#: read from one step alone
 
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """A public model's widths (the keys of its published
-    ``config.json``), how much of it this rank holds (``layers_here``)
-    and how it is trained (the ``train`` group of the file)."""
+    ``config.json``), how much of it this rank holds (``layers_here``:
+    the leading dense layers, then sparse ones; ``experts_here`` routed
+    experts from ``expert_share`` x ``experts_here`` on, 0 for all;
+    ``vocab_here`` rows of the vocabulary, 0 for all) and how it is
+    trained (the ``train`` group of the file).  Which sublayers a layer
+    has follows from the published keys: ``kv_lora_rank`` set is latent
+    attention; layers before ``first_k_dense_replace`` are dense;
+    ``scoring_func`` and ``topk_method`` say how a router scores and
+    chooses; ``n_shared_experts``; ``num_nextn_predict_layers``."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -432,6 +448,54 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     attn_block: int = 1024
     loss_block_rows: int = 1024
+    # DeepSeek-V3's keys (JoyAI-LLM-Flash); OLMoE's file has none of them
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0      # an expert's width, where the
+    #                                     dense one is intermediate_size
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    topk_method: str = "greedy"
+    routed_scaling_factor: float = 1.0
+    num_nextn_predict_layers: int = 0
+    experts_here: int = 0
+    expert_share: int = 0
+    vocab_here: int = 0
+    mtp_loss_coef: float = 0.0
+    bias_update_gamma: float = 0.0
+
+    @property
+    def n_dense_here(self) -> int:
+        return min(self.first_k_dense_replace, self.layers_here)
+
+    @property
+    def n_sparse_here(self) -> int:
+        return self.layers_here - self.n_dense_here
+
+    @property
+    def n_routers(self) -> int:
+        """Sparse layers in the walk, the next-next-token module's too."""
+        return self.n_sparse_here + self.num_nextn_predict_layers
+
+    @property
+    def n_experts_here(self) -> int:
+        return self.experts_here or self.num_experts
+
+    @property
+    def first_expert_here(self) -> int:
+        return self.expert_share * self.n_experts_here
+
+    @property
+    def vocab_rows(self) -> int:
+        return self.vocab_here or self.vocab_size
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
 
     def __post_init__(self):
         if self.num_key_value_heads != self.num_attention_heads:
@@ -439,6 +503,15 @@ class ModelConfig:
                                       "num_key_value_heads != heads")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is not a multiple of the heads")
+        if self.num_nextn_predict_layers > 1:
+            raise NotImplementedError("more than one next-n module")
+        if (self.scoring_func, self.topk_method) not in (
+                ("softmax", "greedy"), ("sigmoid", "noaux_tc")):
+            raise NotImplementedError(
+                f"router {self.scoring_func} / {self.topk_method}")
+        if self.first_expert_here + self.n_experts_here > self.num_experts:
+            raise ValueError("the experts held here are not among the "
+                             "router's")
 
 
 def load_model_config(path: str, **overrides) -> ModelConfig:
@@ -450,36 +523,95 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
         body = json.load(f)
     if body.get("hidden_act") != "silu" or body.get("attention_bias") \
             or body.get("clip_qkv") or body.get("tie_word_embeddings") \
-            or body.get("rope_scaling"):
+            or body.get("rope_scaling") or body.get("n_group", 1) != 1 \
+            or body.get("topk_group", 1) != 1 \
+            or body.get("moe_layer_freq", 1) != 1 \
+            or ("kv_lora_rank" in body and not body.get("rope_interleave")):
         raise NotImplementedError(
             f"{path}: the model path runs silu experts, no biases, no "
-            "clipping, an untied head and plain RoPE")
+            "clipping, an untied head, plain RoPE (on interleaved pairs "
+            "under latent attention), one group of experts and every "
+            "layer past the dense ones sparse")
     known = {f.name for f in dataclasses.fields(ModelConfig)}
     merged = {**body, **body.get("train", {}), **overrides}
+    if "n_routed_experts" in merged:        # DeepSeek-V3's name for it
+        merged.setdefault("num_experts", merged["n_routed_experts"])
     return ModelConfig(**{k: v for k, v in merged.items() if k in known})
 
 
+def attention_shapes(cfg: ModelConfig) -> dict:
+    d, nh = cfg.hidden_size, cfg.num_attention_heads
+    if not cfg.kv_lora_rank:
+        return {"ln1": (d,), "wq": (d, d), "wk": (d, d), "wv": (d, d),
+                "wo": (d, d), "q_norm": (d,), "k_norm": (d,)}
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {"ln1": (d,), "wq_a": (d, cfg.q_lora_rank),
+            "q_a_norm": (cfg.q_lora_rank,),
+            "wq_b": (cfg.q_lora_rank, nh * qk),
+            "wkv_a": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            "kv_a_norm": (cfg.kv_lora_rank,),
+            "wkv_b": (cfg.kv_lora_rank,
+                      nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": (nh * cfg.v_head_dim, d)}
+
+
+def sparse_layer_shapes(cfg: ModelConfig) -> dict:
+    """One sparse layer's leaves: attention, the router over all the
+    experts, the experts held here, the shared expert if any."""
+    d, f, e = cfg.hidden_size, cfg.expert_width, cfg.n_experts_here
+    mlp = {"ln2": (d,), "router": (d, cfg.num_experts),
+           "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
+    if cfg.n_shared_experts:
+        fs = f * cfg.n_shared_experts
+        mlp.update(shared_gate=(d, fs), shared_up=(d, fs),
+                   shared_down=(fs, d))
+    return {**attention_shapes(cfg), **mlp}
+
+
 def model_param_shapes(cfg: ModelConfig) -> dict:
-    d, f, e = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
-    n, v = cfg.layers_here, cfg.vocab_size
-    layer = {"ln1": (n, d), "wq": (n, d, d), "wk": (n, d, d),
-             "wv": (n, d, d), "wo": (n, d, d), "q_norm": (n, d),
-             "k_norm": (n, d), "ln2": (n, d), "router": (n, d, e),
-             "gate": (n, e, d, f), "up": (n, e, d, f), "down": (n, e, f, d)}
-    return {"embed": (v, d), "layers": layer, "final_norm": (d,),
-            "head": (d, v)}
+    """The parameter tree's shapes: ``embed``, ``dense`` (the leading
+    dense layers, stacked; absent where there are none), ``layers`` (the
+    sparse layers, stacked), ``mtp`` (the next-next-token module: two
+    norms, the projection of their joined outputs, one sparse layer, a
+    last norm; absent where the model has none), ``final_norm``,
+    ``head``."""
+    d, ff, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_rows
+    stack = lambda n, shapes: {k: (n,) + s for k, s in shapes.items()}
+    tree = {"embed": (v, d)}
+    if cfg.n_dense_here:
+        tree["dense"] = stack(cfg.n_dense_here, {
+            **attention_shapes(cfg), "ln2": (d,), "gate": (d, ff),
+            "up": (d, ff), "down": (ff, d)})
+    tree["layers"] = stack(cfg.n_sparse_here, sparse_layer_shapes(cfg))
+    if cfg.num_nextn_predict_layers:
+        tree["mtp"] = {"enorm": (d,), "hnorm": (d,), "proj": (2 * d, d),
+                       **sparse_layer_shapes(cfg), "norm": (d,)}
+    tree.update(final_norm=(d,), head=(d, v))
+    return tree
 
 
 def is_gain(name: str) -> bool:
     """A norm's gain: starts at one, and is not decayed."""
-    return name in ("ln1", "ln2", "q_norm", "k_norm", "final_norm")
+    return name.rsplit(".", 1)[-1] in GAINS
 
 
-def leaf_names() -> list:
-    """(name, path) of every leaf in a fixed order."""
-    return [("embed", ("embed",))] + [
-        (k, ("layers", k)) for k in LAYER_LEAVES] + [
-        ("final_norm", ("final_norm",)), ("head", ("head",))]
+def leaf_names(cfg: ModelConfig = None) -> list:
+    """(name, path) of every trained leaf in a fixed order.  A sparse
+    layer's leaves go by their own names, a dense layer's and the
+    module's by ``dense.<leaf>`` and ``mtp.<leaf>``; without ``cfg``,
+    OLMoE's."""
+    if cfg is None:
+        return [("embed", ("embed",))] + [
+            (k, ("layers", k)) for k in LAYER_LEAVES] + [
+            ("final_norm", ("final_norm",)), ("head", ("head",))]
+    out = []
+    for key, sub in model_param_shapes(cfg).items():
+        if isinstance(sub, tuple):
+            out.append((key, (key,)))
+        else:
+            out += [(k if key == "layers" else f"{key}.{k}", (key, k))
+                    for k in sub]
+    return out
 
 
 def _leaf(tree, path):
@@ -489,10 +621,9 @@ def _leaf(tree, path):
 
 
 def _set_leaf(tree, path, leaf) -> None:
-    """Put ``leaf`` at ``path`` of a tree being built (``{"layers": {}}``
-    to start with)."""
+    """Put ``leaf`` at ``path`` of a tree being built."""
     for k in path[:-1]:
-        tree = tree[k]
+        tree = tree.setdefault(k, {})
     tree[path[-1]] = leaf
 
 
@@ -526,11 +657,10 @@ def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         return cfg.init_std * jax.random.normal(k, shape, jnp.float32)
 
-    shapes = model_param_shapes(cfg)
-    return {"embed": draw("embed", shapes["embed"]),
-            "layers": {k: draw(k, s) for k, s in shapes["layers"].items()},
-            "final_norm": draw("final_norm", shapes["final_norm"]),
-            "head": draw("head", shapes["head"])}
+    shapes, tree = model_param_shapes(cfg), {}
+    for name, path in leaf_names(cfg):
+        _set_leaf(tree, path, draw(name, _leaf(shapes, path)))
+    return tree
 
 
 def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
@@ -590,51 +720,116 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
     return ce(h, w)
 
 
+def _walk_layers(run, stacked, x, bias, n: int):
+    """``n`` like layers in turn: ``run(layer, x, bias row) -> (x,
+    out)``; returns (x, the outs stacked).  More than one is a
+    ``lax.scan`` over the stacked leaves, so the layer is traced and
+    compiled once however many there are."""
+    import jax
+
+    if n == 1:
+        x, out = run(jax.tree.map(lambda a: a[0], stacked), x,
+                     None if bias is None else bias[0])
+        return x, jax.tree.map(lambda a: a[None], out)
+    return jax.lax.scan(
+        lambda x, xs: run(xs[0], x, xs[1]), x, (stacked, bias))
+
+
 def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
-               n_global: int, axes: tuple = ()):
+               n_global: int, axes: tuple = (), bias=None):
     """The training loss of one micro-batch shard and what a step
     reports of it.  ``n_global`` is the tokens of the whole batch and
     ``axes`` the mesh axes it is sharded over: sums cross them by
-    ``psum``, so every shard returns the whole batch's loss."""
+    ``psum``, so every shard returns the whole batch's loss.  ``bias``
+    holds the routers' balancing biases where they choose under one
+    (``layers`` (L, E) and ``mtp`` (1, E)); nothing is differentiated
+    with respect to it.  Where the model has a next-next-token module,
+    ``labels`` is one position longer than ``tokens``: ``labels[:, i]``
+    follows ``tokens[:, i]`` and ``labels[:, i + 1]`` follows that."""
     import jax
     import jax.numpy as jnp
 
-    from ompi_tpu.parallel.model import olmoe_block, rmsnorm_gain
+    from ompi_tpu.parallel.model import decoder_layer, matmul, rmsnorm_gain
 
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
+    at = sample_rows(b * s)
+    bias = bias or {}
+
+    def run(layer, x, bias_row):
+        x, st, routed = decoder_layer(layer, x, cfg, interpret=interpret,
+                                      bias=bias_row)
+        experts = routed.pop("experts", None)
+        return x, (jax.tree.map(psum, st), experts,
+                   {"router_" + k: v[at] for k, v in routed.items()})
+
+    if cfg.n_dense_here + cfg.n_routers > 1:
+        # a layer's activations are recomputed in its backward pass, so
+        # that one layer's are held at a time and not every layer's;
+        # with one layer there is nothing to save
+        run = jax.checkpoint(run)
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32
-    slots = prob_sum = z_sum = 0.0
-    loads, chosen, samples = [], [], []
-    at = sample_rows(b * s)
-    for i in range(cfg.layers_here):
-        layer = jax.tree.map(lambda a: a[i], params["layers"])
-        x, st, routed = olmoe_block(layer, x, cfg, interpret=interpret)
-        st = jax.tree.map(psum, st)
-        loads.append(st["slots"])
-        chosen.append(routed.pop("experts"))
-        samples.append({"router_" + k: v[at] for k, v in routed.items()})
-        slots, prob_sum = slots + st["slots"], prob_sum + st["prob_sum"]
-        z_sum = z_sum + st["z_sum"]
+    if cfg.n_dense_here:
+        x, _ = _walk_layers(run, params["dense"], x, None, cfg.n_dense_here)
+    x, (st, chosen, sample) = _walk_layers(
+        run, params["layers"], x, bias.get("layers"), cfg.n_sparse_here)
+    head_rows = min(cfg.loss_block_rows, b * s)
     with jax.named_scope("otpu_head"):
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
-            h.reshape(b * s, -1), params["head"], labels.reshape(b * s),
-            min(cfg.loss_block_rows, b * s), cfg.compute_dtype)
-    routed = cfg.layers_here * n_global     # rows of all routers' logits
+            h.reshape(b * s, -1), params["head"],
+            labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
+    routed = cfg.n_sparse_here * n_global   # rows of all routers' logits
     ce = psum(ce_sum) / n_global
-    # HF's load_balancing_loss_func: every layer's rows in one mean
-    lb = cfg.num_experts * jnp.sum((slots / routed) * (prob_sum / routed))
-    z = z_sum / routed
+    lb = z = jnp.zeros((), jnp.float32)
+    if "prob_sum" in st:
+        # HF's load_balancing_loss_func: every layer's rows in one mean
+        slots, prob_sum = jnp.sum(st["slots"], 0), jnp.sum(st["prob_sum"], 0)
+        lb = cfg.num_experts * jnp.sum((slots / routed)
+                                       * (prob_sum / routed))
+        z = jnp.sum(st["z_sum"], 0) / routed
     lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
     total = ce + lb + z
-    sample = jax.tree.map(lambda *a: jnp.stack(a), *samples)
+    losses, loads = [ce, lb, z], st["slots"]
     sample["head_in"] = h.reshape(b * s, -1)[at]
-    return total, {"losses": jnp.stack([total, ce, lb, z]),
-                   "loads": jnp.stack(loads), "rows": rows,
-                   "experts": jnp.stack(chosen),
-                   "sample": sample}
+    aux = {}
+    if cfg.num_nextn_predict_layers:
+        # DeepSeek-V3's multi-token prediction, depth one: the last
+        # layer's output (before the final norm) joined with the next
+        # token's embedding, one more sparse layer, the same embedding
+        # and head, a cross-entropy against the token after the next
+        mtp = params["mtp"]
+        with jax.named_scope("otpu_mtp"):
+            nxt = rmsnorm_gain(params["embed"][labels[:, :s]], mtp["enorm"],
+                               cfg.rms_norm_eps)
+            prev = rmsnorm_gain(x, mtp["hnorm"], cfg.rms_norm_eps)
+            joined = jnp.concatenate([nxt, prev], -1).reshape(b * s, -1)
+            x2 = matmul(joined, mtp["proj"], cfg.compute_dtype
+                        ).reshape(b, s, -1)
+            x2, (st2, chosen2, sample2) = _walk_layers(
+                run, jax.tree.map(lambda a: a[None], {
+                    k: v for k, v in mtp.items()
+                    if k not in ("enorm", "hnorm", "proj", "norm")}),
+                x2, bias.get("mtp"), 1)
+            h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
+            ce2_sum, aux["mtp_rows"] = head_cross_entropy(
+                h2.reshape(b * s, -1), params["head"],
+                labels[:, 1:].reshape(b * s), head_rows, cfg.compute_dtype)
+        losses.append(cfg.mtp_loss_coef * psum(ce2_sum) / n_global)
+        total = total + losses[-1]
+        loads = jnp.concatenate([loads, st2["slots"]])
+        chosen = jnp.concatenate([chosen, chosen2])
+        sample = {**{k: jnp.concatenate([sample[k], sample2[k]])
+                     for k in sample2},
+                  "head_in": sample["head_in"],
+                  "mtp_head_in": h2.reshape(b * s, -1)[at]}
+    if cfg.n_experts_here < cfg.num_experts:
+        first = cfg.first_expert_here
+        aux["local_slots"] = jnp.sum(
+            loads[:, first:first + cfg.n_experts_here])
+    return total, {"losses": jnp.stack([total] + losses), "loads": loads,
+                   "rows": rows, "experts": chosen, "sample": sample, **aux}
 
 
 def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
@@ -652,6 +847,17 @@ def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
         step = step + cfg.weight_decay * p
     lr = cfg.lr * jnp.minimum(1.0, t / cfg.warmup_steps)
     return p - lr * step, m, v
+
+
+def bias_update(cfg: ModelConfig, bias, loads):
+    """The routers' balancing biases (L, E) after a step in which the
+    experts received ``loads`` (L, E) slots of the whole batch: plus
+    ``bias_update_gamma`` where an expert took fewer than the mean,
+    minus where more (arXiv:2412.19437 section 2.1.2)."""
+    import jax.numpy as jnp
+
+    mean = jnp.mean(loads, axis=-1, keepdims=True)
+    return bias + cfg.bias_update_gamma * jnp.sign(mean - loads)
 
 
 def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
@@ -673,18 +879,19 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
                          f"over dp = {spec.dp}")
     interpret = pallas_interpret(mesh.devices.flat)
     n_global = cfg.micro_batch * cfg.seq_len
-    names = leaf_names()
+    names = leaf_names(cfg)
     shapes = model_param_shapes(cfg)
+    biased = cfg.topk_method == "noaux_tc"
     probes = {n: np.unravel_index(
         probe_positions(n, int(np.prod(_leaf(shapes, path)))),
         _leaf(shapes, path)) for n, path in names}
 
     def body(state, tokens, labels):
-        params, mom, var, t = state
+        params, mom, var, t, bias = state
 
         def loss_fn(ps):
             return model_loss(ps, tokens, labels, cfg, interpret=interpret,
-                              n_global=n_global, axes=("dp",))
+                              n_global=n_global, axes=("dp",), bias=bias)
 
         # as in the toy's step: differentiate a per-shard view, so the
         # gradients come back as each shard's partial and the psum below
@@ -695,7 +902,7 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
         grads = jax.tree.map(lambda g: jax.lax.psum(g, "dp"), grads)
         t = t + 1
         tf = t.astype(jnp.float32)
-        new_p, new_m, new_v = {"layers": {}}, {"layers": {}}, {"layers": {}}
+        new_p, new_m, new_v = {}, {}, {}
         sq, g_probe, p_probe = [], [], []
         with jax.named_scope("otpu_adamw"):
             for name, path in names:
@@ -709,17 +916,31 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
                 p_probe.append(p[probes[name]])
         aux.update(grad_sq=jnp.stack(sq), grad_probe=jnp.stack(g_probe),
                    param_probe=jnp.stack(p_probe))
-        return (new_p, new_m, new_v, t), aux
+        if biased:
+            # DeepSeek-V3's auxiliary-loss-free balancing: after the
+            # step an expert that took more than the mean of the whole
+            # batch's slots is chosen a little less readily, one that
+            # took fewer a little more; a sign rule, not AdamW's
+            with jax.named_scope("otpu_bias_update"):
+                n = cfg.n_sparse_here       # the module's row is the last
+                rows = {"layers": aux["loads"][:n], "mtp": aux["loads"][n:]}
+                bias = {k: bias_update(cfg, b, rows[k])
+                        for k, b in bias.items()}
+        return (new_p, new_m, new_v, t, bias), aux
 
     rep = P()
     batch = P("dp", None)
     rows = P(None, "dp", None)
+    sample = {"router_" + k: P(None, "dp") if k == "lse" else rows
+              for k in (("in", "logits", "scores", "weights") if biased
+                        else ("in", "logits", "lse", "weights"))}
     aux_specs = {"losses": rep, "loads": rep, "rows": batch,
                  "experts": rows, "grad_sq": rep, "grad_probe": rep,
-                 "param_probe": rep,
-                 "sample": {"router_in": rows, "router_logits": rows,
-                            "router_lse": P(None, "dp"),
-                            "router_weights": rows, "head_in": batch}}
+                 "param_probe": rep, "sample": {**sample, "head_in": batch}}
+    if cfg.num_nextn_predict_layers:
+        aux_specs["mtp_rows"] = aux_specs["sample"]["mtp_head_in"] = batch
+    if cfg.n_experts_here < cfg.num_experts:
+        aux_specs["local_slots"] = rep
 
     def otpu_train_step(state, tokens, labels):
         return shard_map(body, mesh=mesh, in_specs=(rep, batch, batch),
@@ -727,7 +948,7 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
             state, tokens, labels)
 
     jitted = jax.jit(otpu_train_step, donate_argnums=(0,))
-    slots = n_global * cfg.num_experts_per_tok * cfg.layers_here
+    slots = n_global * cfg.num_experts_per_tok * cfg.n_routers
     trace.bind_profiler()
     count = [0]
 
@@ -739,6 +960,10 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
         spc.record("train_steps")
         spc.record("train_tokens", n_global)
         spc.record("moe_token_slots", slots)
+        if cfg.num_nextn_predict_layers:
+            spc.record("train_mtp_tokens", n_global)
+        if biased:
+            spc.record("moe_bias_updates", cfg.n_routers)
         if count[0] == 1:
             # the first call traces, lowers and compiles (or loads the
             # cached program): counted as every device program's is
@@ -758,11 +983,21 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
 
     def place(params, tokens, labels):
         """``(state, tokens, labels)`` on the mesh: the state is the
-        parameters, AdamW's two moments at zero and the step count."""
+        parameters, AdamW's two moments at zero, the step count, and
+        the routers' balancing biases at zero where they choose under
+        one (not trained: the step moves them by ``bias_update``)."""
         put = lambda a, s: jax.device_put(a, NamedSharding(mesh, s))
         params = jax.tree.map(lambda a: put(a, rep), params)
         zeros = lambda: jax.tree.map(jnp.zeros_like, params)
-        state = (params, zeros(), zeros(), put(jnp.zeros((), jnp.int32), rep))
+        bias = {}
+        if biased:
+            row = lambda n: put(jnp.zeros((n, cfg.num_experts),
+                                          jnp.float32), rep)
+            bias = {"layers": row(cfg.n_sparse_here)}
+            if cfg.num_nextn_predict_layers:
+                bias["mtp"] = row(1)
+        state = (params, zeros(), zeros(), put(jnp.zeros((), jnp.int32), rep),
+                 bias)
         return state, put(tokens, batch), put(labels, batch)
 
     return step, place
@@ -771,10 +1006,19 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
 def record_step_stats(aux) -> int:
     """Read a finished step's expert loads (this blocks on the device:
     call it outside anything timed) and keep SPC ``moe_max_expert_load``
-    at the fullest expert's slots of any step read so far."""
+    at the fullest expert's slots of any step read so far.  Where the
+    rank holds a share of the experts, the slots that went to held
+    experts and to absent ones add to ``moe_local_slots`` and
+    ``moe_absent_slots``; ``train_steps_read`` counts the steps read."""
     from ompi_tpu.runtime import spc
 
-    fullest = int(np.asarray(aux["loads"]).max())
+    loads = np.asarray(aux["loads"])
+    spc.record("train_steps_read")
+    if "local_slots" in aux:
+        here = int(np.asarray(aux["local_slots"]))
+        spc.record("moe_local_slots", here)
+        spc.record("moe_absent_slots", int(loads.sum()) - here)
+    fullest = int(loads.max())
     seen = spc.read("moe_max_expert_load")
     if fullest > seen:
         spc.record("moe_max_expert_load", fullest - seen)

@@ -111,6 +111,13 @@ _COUNTERS = (
     # read outside the step: ``train.record_step_stats``)
     "train_steps", "train_tokens", "moe_token_slots",
     "moe_max_expert_load",
+    # a model with a next-next-token module and a rank that holds a share
+    # of the routed experts: the module's tokens and the routers'
+    # balancing-bias updates in the steps issued; over the steps read
+    # back (``train_steps_read``), the slots that went to experts held
+    # here and to absent ones
+    "train_mtp_tokens", "moe_bias_updates", "train_steps_read",
+    "moe_local_slots", "moe_absent_slots",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

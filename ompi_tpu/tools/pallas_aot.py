@@ -256,6 +256,21 @@ def cases(mesh1d, mesh2d):
         flash_args(2, 16, 1024, 1024, 128, bf16)
         + (_sds((1024, 1024), jnp.float32, one, P()),),
         {"interpret": False}))
+    # latent attention's block update (JoyAI-LLM-Flash's step): q and k
+    # 192 wide, v and the numerator 128; 192 is no multiple of the 128
+    # lanes, and Mosaic lays the tile out itself
+    def mla_args(biased):
+        q = _sds((1, 32, 1024, 192), bf16, one, P())
+        v = _sds((1, 32, 1024, 128), bf16, one, P())
+        row = _sds((1, 32, 1024), jnp.float32, one, P())
+        num = _sds((1, 32, 1024, 128), jnp.float32, one, P())
+        return (q, q, v, row, num, row) + (
+            (_sds((1024, 1024), jnp.float32, one, P()),) if biased else ())
+
+    case("joyai_flash_block_1k", lambda: (
+        fa._update_pallas, mla_args(False), {"interpret": False}))
+    case("joyai_flash_block_1k_biased", lambda: (
+        fa._update_pallas, mla_args(True), {"interpret": False}))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),
@@ -347,10 +362,11 @@ def cases(mesh1d, mesh2d):
                  P("dp", "sp", None))
         return step, (params, x)
 
-    # -- a public model's step (OLMoE-1B-7B, one layer of 16) at the
-    # benchmark cell's own configuration file, on one device: forward,
-    # the flash kernel, the grouped expert matmuls, backward and AdamW
-    def olmoe_step(devices):
+    # -- a public model's step at a benchmark cell's own configuration
+    # file, on one device: forward, the flash kernel, the grouped expert
+    # matmuls, backward and AdamW (OLMoE-1B-7B, one layer of 16;
+    # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment)
+    def model_step(devices, config):
         import os
 
         from ompi_tpu.parallel.mesh import MeshSpec
@@ -359,16 +375,21 @@ def cases(mesh1d, mesh2d):
         cfg = train.load_model_config(os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__)))),
-            "benchmark", "configs", "olmoe-1b-7b-train-1chip.json"))
+            "benchmark", "configs", config + ".json"))
         step, _ = train.build_train_step(mesh, spec, model=cfg)
-        tree = jax.tree.map(lambda s: _sds(s, f32, mesh, P()),
-                            train.model_param_shapes(cfg),
+        rep = lambda s, dt=f32: _sds(s, dt, mesh, P())
+        tree = jax.tree.map(rep, train.model_param_shapes(cfg),
                             is_leaf=lambda x: isinstance(x, tuple))
-        tokens = _sds((cfg.micro_batch, cfg.seq_len), jnp.int32, mesh,
-                      P("dp", None))
-        return step.jitted, ((tree, tree, tree,
-                              _sds((), jnp.int32, mesh, P())),
-                             tokens, tokens)
+        bias = {}
+        if cfg.topk_method == "noaux_tc":
+            bias = {"layers": rep((cfg.n_sparse_here, cfg.num_experts)),
+                    "mtp": rep((1, cfg.num_experts))}
+        ids = lambda n: _sds((cfg.micro_batch, n), jnp.int32, mesh,
+                             P("dp", None))
+        return step.jitted, (
+            (tree, tree, tree, rep((), jnp.int32), bias),
+            ids(cfg.seq_len),
+            ids(cfg.seq_len + cfg.num_nextn_predict_layers))
 
     # -- a partitioned allreduce's group program (coll/xla _group_fn) over
     # four chips, at the sizes ``rank1-partitioned`` releases: ``entry_ops``
@@ -384,7 +405,10 @@ def cases(mesh1d, mesh2d):
             _sds((4, nbytes // 4), f32, mod.mesh, P(mod.axis)),) * members
 
     topo_devs = list(_np.asarray(mesh1d.devices).reshape(-1))
-    case("olmoe_step_1chip", lambda: olmoe_step(topo_devs[:1]))
+    case("olmoe_step_1chip", lambda: model_step(
+        topo_devs[:1], "olmoe-1b-7b-train-1chip"))
+    case("joyai_step_1chip", lambda: model_step(
+        topo_devs[:1], "joyai-flash-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

@@ -66,6 +66,30 @@ def _cut_batch(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 1)
 
 
+# joyai-train-1chip: the same, at the widths of tests/test_joyai_train.py
+# (1 dense + 2 sparse layers and the module, 4 of 16 experts, 64 of 512 ids)
+TINY_SHARE = dict(hidden_size=64, intermediate_size=96,
+                  num_attention_heads=4, num_key_value_heads=4,
+                  q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=16,
+                  moe_intermediate_size=32, n_routed_experts=16,
+                  num_experts_per_tok=4, vocab_size=512, vocab_here=64,
+                  experts_here=4, layers_here=3)
+TINY_SHARE_TRAIN = dict(seq_len=32, micro_batch=1, attn_block=16,
+                        loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_share(config):
+    config.update(TINY_SHARE)
+    config["train"].update(TINY_SHARE_TRAIN)
+
+
+def _cut_batch_share(p):
+    p.update(sequences=TINY_SHARE_TRAIN["micro_batch"],
+             seq_len=TINY_SHARE_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -113,10 +137,15 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("olmoe-1b-7b-train-1chip", _tiny_model),
         cut={"packed-4k-steps": _cut_batch}),
+    "joyai-train-1chip": dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("joyai-flash-train-1chip", _tiny_share),
+        cut={"packed-8k-steps": _cut_batch_share}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
-STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip")
+STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip")
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the child: run_cell as the command calls it, but for the three
@@ -411,6 +440,28 @@ def test_a_train_step_counts_its_tokens_and_moves_no_collective(rehearsal):
     assert rehearsal["builds"] == [1, 1]
 
 
+@of_cells("joyai-train-1chip")
+def test_a_share_step_counts_its_slots_here_and_elsewhere(rehearsal):
+    """The trainer's counters on one chip's share: the module's tokens
+    and the routers' bias updates follow from the steps issued (2 sparse
+    layers and the module at the rehearsal's widths); over the steps read
+    back every slot went to a held expert or to an absent one; the
+    step's program is the one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_SHARE_TRAIN["micro_batch"] * TINY_SHARE_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_share" and row["tolerance"]["why"]
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["train_tokens"] == c["train_steps"] * tokens
+    assert c["train_mtp_tokens"] == c["train_tokens"]
+    assert c["moe_token_slots"] == c["train_tokens"] * 4 * 3
+    assert c["moe_bias_updates"] == c["train_steps"] * 3
+    assert c["train_steps_read"] >= 3
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 4 * 3
+    assert rehearsal["builds"] == [1, 1]
+
+
 def test_train_check_tells_the_program_from_its_control(tmp_path):
     """``benchmark/tools/train_check.py`` at the rehearsal's widths: one
     step of the program lies within the kind's tolerance of the
@@ -440,3 +491,55 @@ def test_train_check_tells_the_program_from_its_control(tmp_path):
             > 100 * row["program"]["widest_units"]
         assert all(u > 1 for u in
                    row["control_parts"]["units_by_group"].values())
+
+
+def test_share_check_tells_the_program_from_its_controls(tmp_path):
+    """``benchmark/tools/share_check.py`` at the rehearsal's widths: one
+    step of the program, after a few steps have moved the balancing
+    biases, lies within the kind's tolerance of the kit's reference; the
+    reference computed in bfloat16 lies far outside the program's, and a
+    bfloat16 router and a softmax in the sigmoid's place outside the
+    tolerance (the bias in the weights only as far as four steps of
+    gamma = 0.001 move a weight of order 0.6: seen on the chip after a
+    run's sixty)."""
+    env = _stage("joyai-train-1chip", str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "share_check.py"),
+         "--platform", "cpu", "--root", str(tmp_path), "--seeds", "2",
+         "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["widest_units"] < 0.05
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        assert row["parts_bf16"]["widest_units"] > 1
+        assert row["parts_softmax"]["widest_units"] > 1
+        assert row["parts_bias_in_weights"]["widest_units"] \
+            > 10 * row["program"]["units_by_group"]["router_weights"]
+
+
+def test_a_share_of_the_busy_seconds_counts_no_loop_twice(tmp_path,
+                                                          monkeypatch):
+    """``trace_op_busy_share``: the matching leaf ops' seconds over the
+    point's device-busy seconds, whatever ``while`` ops the table also
+    lists; nothing to read without a match or a trace."""
+    sys.path.insert(0, BENCH)
+    try:
+        from harness import hostspans, protocol
+        reader = protocol.load_module("readers", "trace_op_busy_share", BENCH)
+        monkeypatch.setattr(hostspans, "write_table", lambda *a: None)
+        ops = {"while.3 s32[]": 6.0, "otpu_flash_block_update.2": 1.0,
+               "otpu_flash_block_update.5": 0.5, "fusion.9 f32[8]": 4.5}
+        ctx = {"points": [{"name": "p", "kind": "train_step_share"}],
+               "trace": {"points": {"p": {"ops": ops, "busy_s": 7.5}}}}
+        params = {"pattern": "^otpu_flash", "table": "t",
+                  "select": {"kind": "train_step_share"}}
+        assert reader.read(ctx, params) == pytest.approx(20.0)
+        assert reader.read(ctx, {**params, "pattern": "^ragged"}) is None
+        assert reader.read({**ctx, "trace": None}, params) is None
+    finally:
+        sys.path.remove(BENCH)

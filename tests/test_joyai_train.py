@@ -1,0 +1,528 @@
+"""JoyAI-LLM-Flash's training step on the normal path (``parallel/train
+.py``'s model path: latent attention, a dense layer, sparse layers with a
+shared expert beside a share of the routed ones, sigmoid routing under a
+balancing bias, a next-next-token module) against the plain reference
+(``parallel/joyai_reference.py``) at small widths on seeded random
+weights: hidden 64, 4 heads of 16 + 8 / 16, latents 32 and 16, dense width
+96, 16 experts of width 32 of which 4 are held (share 1 of 4), top 4, a
+slice of 64 of 512 ids, sequences of 32, 1 dense + 2 sparse layers and the
+module.  Float32 compute meets the reference at rtol 1e-5.  The benchmark's
+own copy of the reference (``benchmark/harness/joyaikit.py``) is held to
+the same, and its deliberately wrong variants must fail."""
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ompi_tpu.parallel import joyai_reference as ref
+from ompi_tpu.parallel import model, moe, train
+from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+from ompi_tpu.runtime import spc
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+PUBLISHED = dict(
+    hidden_size=64, intermediate_size=96, num_attention_heads=4,
+    num_key_value_heads=4, num_experts_per_tok=4, vocab_size=512,
+    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, first_k_dense_replace=1,
+    moe_intermediate_size=32, n_shared_experts=1, scoring_func="sigmoid",
+    topk_method="noaux_tc", routed_scaling_factor=2.5, norm_topk_prob=True,
+    num_nextn_predict_layers=1, rope_theta=32e6, rms_norm_eps=1e-6)
+SHARE = dict(layers_here=3, experts_here=4, expert_share=1, vocab_here=64)
+TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16, loss_block_rows=16,
+             lr=1e-2, aux_loss_coef=0.0, z_loss_coef=0.0, mtp_loss_coef=0.3,
+             bias_update_gamma=0.001)
+F32 = train.ModelConfig(compute_dtype="float32", num_experts=16,
+                        **PUBLISHED, **SHARE, **TRAIN)
+LEAVES = [name for name, _ in train.leaf_names(F32)]
+CLOSE = dict(rtol=1e-5, atol=1e-6)
+
+
+def batch_of(seed, vocab=64):
+    """(inputs (2, 32), labels (2, 33): the next token and the one after)
+    from 34 ids a sequence."""
+    ids = np.random.default_rng(seed).integers(0, vocab, (2, 34)).astype(
+        np.int32)
+    return jnp.asarray(ids[:, :-2]), jnp.asarray(ids[:, 1:])
+
+
+def some_bias(cfg=F32, scale=0.01):
+    """Biases that are not zero, so that a choice made without them, or
+    weights made with them, differ."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(7))
+    return {"layers": scale * jax.random.normal(
+        k1, (cfg.n_sparse_here, cfg.num_experts)),
+        "mtp": scale * jax.random.normal(k2, (1, cfg.num_experts))}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return train.init_model_params(F32, seed=3)
+
+
+@pytest.fixture(scope="module")
+def reference(params):
+    (total, (ce, mtp, loads)), grads = ref.grads(
+        params, *batch_of(0), F32, some_bias())
+    return dict(parts=np.asarray([total, ce, mtp]), loads=np.asarray(loads),
+                grads=grads)
+
+
+def system_loss(params, cfg, batch, bias):
+    tokens, labels = batch
+    return train.model_loss(params, tokens, labels, cfg, interpret=True,
+                            n_global=tokens.size, bias=bias)
+
+
+@pytest.fixture(scope="module")
+def system(params):
+    (total, aux), grads = jax.value_and_grad(
+        lambda p: system_loss(p, F32, batch_of(0), some_bias()),
+        has_aux=True)(params)
+    return dict(total=total, aux=aux, grads=grads)
+
+
+def run_steps(cfg, params, seeds, dp=1):
+    """The state and each step's ``aux`` after one optimiser step a
+    seed's batch, through ``build_train_step`` on ``dp`` CPU devices."""
+    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
+    step, place = train.build_train_step(mesh, spec, model=cfg)
+    state, out = None, []
+    for seed in seeds:
+        tokens, labels = batch_of(seed)
+        if state is None:
+            state, tokens, labels = place(jax.tree.map(jnp.copy, params),
+                                          tokens, labels)
+        state, aux = step(state, tokens, labels)
+        out.append(aux)
+    return state, out
+
+
+def test_forward_logits_of_both_heads(params, system):
+    """Every row's logsumexp and label logit, of the head and of the
+    module's use of it."""
+    tokens, labels = batch_of(0)
+    with jax.default_matmul_precision("highest"):
+        logits, logits2, _ = ref.forward(params, tokens, labels, F32,
+                                         some_bias())
+    for got, lg, lab in ((system["aux"]["rows"], logits, labels[:, :-1]),
+                         (system["aux"]["mtp_rows"], logits2, labels[:, 1:])):
+        lg = lg.reshape(-1, F32.vocab_rows)
+        want = jnp.stack([jax.nn.logsumexp(lg, -1), jnp.take_along_axis(
+            lg, lab.reshape(-1, 1), -1)[:, 0]], -1)
+        np.testing.assert_allclose(got, want, **CLOSE)
+
+
+def test_loss_and_its_parts(system, reference):
+    losses = np.asarray(system["aux"]["losses"])   # total, ce, lb, z, mtp
+    np.testing.assert_allclose(losses[[0, 1, 4]], reference["parts"],
+                               **CLOSE)
+    assert losses[2] == losses[3] == 0             # no auxiliary loss
+    np.testing.assert_array_equal(system["aux"]["loads"], reference["loads"])
+    assert system["aux"]["loads"].shape == (3, 16)
+    assert (system["aux"]["loads"].sum(-1) == 64 * 4).all()
+    held = reference["loads"][:, 4:8].sum()
+    assert system["aux"]["local_slots"] == held
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_gradient_of_every_leaf(leaf, system, reference):
+    path = dict(train.leaf_names(F32))[leaf]
+    got = train._leaf(system["grads"], path)
+    want = train._leaf(reference["grads"], path)
+    assert float(jnp.abs(want).max()) > 0
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_parameters_and_bias_after_three_steps(dp, params):
+    """``dp = 2`` gives the parameters and the balancing biases of ``dp
+    = 1`` and of the reference on the same global batches (the loads
+    that move the bias are the whole batch's)."""
+    seeds = (0, 1, 2)
+    state, auxes = run_steps(F32, params, seeds, dp=dp)
+    want, bias, losses = ref.train_steps(
+        params, [batch_of(s) for s in seeds], F32)
+    np.testing.assert_allclose([a["losses"][0] for a in auxes], losses,
+                               rtol=1e-5)
+    for name, path in train.leaf_names(F32):
+        np.testing.assert_allclose(
+            train._leaf(state[0], path), train._leaf(want, path), rtol=1e-5,
+            atol=0.01 * 3 * F32.lr, err_msg=name)
+    for key in ("layers", "mtp"):
+        np.testing.assert_array_equal(state[4][key], bias[key])
+    moved = np.abs(np.asarray(state[4]["layers"]))
+    assert moved.max() <= 3 * F32.bias_update_gamma + 1e-9 and moved.any()
+
+
+def test_a_step_reports_what_it_counted(params):
+    if "train_steps" not in spc.counters():
+        spc.init()
+    names = ("train_steps", "train_tokens", "moe_token_slots",
+             "train_mtp_tokens", "moe_bias_updates", "train_steps_read",
+             "moe_local_slots", "moe_absent_slots")
+    before = {k: spc.read(k) for k in names}
+    _, (aux,) = run_steps(F32, params, (0,))
+    fullest = train.record_step_stats(aux)
+    moved = {k: spc.read(k) - before[k] for k in names}
+    assert moved["train_steps"] == moved["train_steps_read"] == 1
+    assert moved["train_tokens"] == moved["train_mtp_tokens"] == 64
+    assert moved["moe_token_slots"] == 64 * 4 * 3   # 2 layers + the module
+    assert moved["moe_bias_updates"] == 3
+    assert moved["moe_local_slots"] == int(aux["local_slots"])
+    assert moved["moe_local_slots"] + moved["moe_absent_slots"] == 768
+    assert fullest == np.asarray(aux["loads"]).max() >= 16
+    assert aux["grad_probe"].shape == (len(LEAVES), train.PROBE)
+    assert aux["sample"]["router_scores"].shape == (3, train.SAMPLE_ROWS, 16)
+    assert aux["sample"]["mtp_head_in"].shape == (train.SAMPLE_ROWS, 64)
+
+
+def sparse_leaves(cfg, seed=5, hot=None):
+    """One sparse layer's MLP leaves (every routed expert, not a share);
+    with ``hot`` that expert's router column is aligned with every
+    normed row, so that every token chooses it."""
+    rng = np.random.default_rng(seed)
+    d, e, f = cfg.hidden_size, cfg.num_experts, cfg.expert_width
+    draw = lambda *shape: jnp.asarray(rng.normal(0, 0.2, shape), jnp.float32)
+    router = jnp.asarray(rng.normal(0, 0.02, (d, e)), jnp.float32)
+    if hot is not None:
+        router = router.at[:, hot].set(1.0)
+    return {"ln2": jnp.ones((d,)), "router": router, "gate": draw(e, d, f),
+            "up": draw(e, d, f), "down": draw(e, f, d),
+            "shared_gate": draw(d, f), "shared_up": draw(d, f),
+            "shared_down": draw(f, d)}
+
+
+def share_of(p, cfg):
+    """The leaves a rank of ``cfg``'s share holds of ``p``."""
+    lo = cfg.first_expert_here
+    cut = {k: p[k][lo:lo + cfg.n_experts_here]
+           for k in ("gate", "up", "down")}
+    return {**p, **cut}
+
+
+def test_a_hot_held_expert_drops_no_slot():
+    """Every token's first choice is held expert 5: its group is as long
+    as the batch, the held slots are several chunks of the mean load,
+    and the output is what the dense reference computes, so nothing fell
+    through."""
+    p = share_of(sparse_leaves(F32, hot=5), F32)
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.uniform(0.5, 1.5, (2, 32, 64)), jnp.float32)
+    bias = jnp.zeros((16,))
+    out, stats, routed = moe.moe_shared_local_block(p, x, F32, bias)
+    assert (routed["experts"][:, 0] == 5).all()
+    assert stats["slots"][5] == 64 and stats["slots"].sum() == 256
+    held = int(stats["slots"][4:8].sum())
+    assert held > 64 + 16                   # more than one chunk of 64 rows
+    h = ref._norm(x, p["ln2"], F32.rms_norm_eps).reshape(64, 64)
+    with jax.default_matmul_precision("highest"):
+        want, load = ref.sparse_mlp(p, h, bias, F32)
+    np.testing.assert_array_equal(stats["slots"], load)
+    np.testing.assert_allclose(out.reshape(64, 64), want, rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_no_held_slot_is_an_empty_part():
+    """A router that never chooses a held expert: the layer's output is
+    the shared expert alone, and every chunk is skipped."""
+    p = sparse_leaves(F32)
+    p["router"] = p["router"].at[:, 4:8].set(-1.0)
+    p = share_of(p, F32)
+    x = jnp.asarray(np.random.default_rng(2).uniform(0.5, 1.5, (2, 32, 64)),
+                    jnp.float32)
+    out, stats, _ = moe.moe_shared_local_block(p, x, F32, jnp.zeros((16,)))
+    assert stats["slots"][4:8].sum() == 0
+    h = ref._norm(x, p["ln2"], F32.rms_norm_eps).reshape(64, 64)
+    with jax.default_matmul_precision("highest"):
+        want = ref.swiglu(h, p["shared_gate"], p["shared_up"],
+                          p["shared_down"])
+    np.testing.assert_allclose(out.reshape(64, 64), want, **CLOSE)
+
+
+def test_the_shares_add_up():
+    """The four shares' routed parts plus the shared expert once equal
+    the uncut layer of the reference: what ties the chip's share to the
+    model."""
+    p = sparse_leaves(F32, seed=11)
+    x = jnp.asarray(np.random.default_rng(12).normal(0, 1, (2, 32, 64)),
+                    jnp.float32)
+    bias = some_bias()["layers"][0]
+    uncut = dataclasses.replace(F32, experts_here=0, expert_share=0)
+    h = ref._norm(x, p["ln2"], F32.rms_norm_eps).reshape(64, 64)
+    with jax.default_matmul_precision("highest"):
+        whole, _ = ref.sparse_mlp(p, h, bias, uncut)
+        shared = ref.swiglu(h, p["shared_gate"], p["shared_up"],
+                            p["shared_down"])
+    total, slots = shared, 0
+    for share in range(4):
+        cfg = dataclasses.replace(F32, expert_share=share)
+        out, stats, _ = moe.moe_shared_local_block(share_of(p, cfg), x, cfg,
+                                                   bias)
+        total = total + (out.reshape(64, 64) - shared)
+        lo = cfg.first_expert_here
+        slots += int(stats["slots"][lo:lo + 4].sum())
+    assert slots == 64 * 4                  # every slot is some share's
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+
+
+def test_a_sliced_vocabulary_is_a_smaller_vocabulary(params):
+    """The share's embedding and head have ``vocab_here`` rows and
+    columns, and the step is that of an uncut model whose vocabulary is
+    that small."""
+    assert params["embed"].shape == (64, 64)
+    assert params["head"].shape == (64, 64)
+    small = dataclasses.replace(F32, vocab_size=64, vocab_here=0)
+    assert train.model_param_shapes(small) == train.model_param_shapes(F32)
+    got = system_loss(params, small, batch_of(0), some_bias())[0]
+    want = system_loss(params, F32, batch_of(0), some_bias())[0]
+    assert float(got) == float(want)
+
+
+def test_the_attention_backward_by_scan_is_the_unrolled_one():
+    """Beyond ``UNROLLED_BLOCKS`` blocks the flash backward walks its
+    block pairs by a scan: the same pairs in the same order, so the
+    same gradients (8 blocks of 4 against 2 of 16)."""
+    rng = np.random.default_rng(0)
+    q, k = (jnp.asarray(rng.normal(0, 1, (2, 4, 32, 24)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(0, 1, (2, 4, 32, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 1, (2, 4, 32, 16)), jnp.float32)
+
+    def grads(block):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            model.causal_flash_attention(q, k, v, block, True) * w),
+            argnums=(0, 1, 2))(q, k, v)
+
+    assert 32 // 4 > model.UNROLLED_BLOCKS >= 32 // 16
+    for got, want in zip(grads(4), grads(16)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    full = jax.grad(lambda q, k, v: jnp.sum(
+        model._full_attention(q, k, v, True) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for got, want in zip(grads(4), full):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_the_configuration_file_gives_the_published_widths():
+    cfg = train.load_model_config(os.path.join(
+        BENCH, "configs", "joyai-flash-train-1chip.json"))
+    assert (cfg.hidden_size, cfg.num_attention_heads, cfg.q_lora_rank,
+            cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim, cfg.intermediate_size, cfg.expert_width,
+            cfg.num_experts, cfg.num_experts_per_tok, cfg.n_shared_experts,
+            cfg.routed_scaling_factor, cfg.scoring_func, cfg.vocab_size,
+            cfg.num_nextn_predict_layers) == (
+        2048, 32, 1536, 512, 128, 64, 128, 7168, 768, 256, 8, 1, 2.5,
+        "sigmoid", 129280, 1)
+    assert (cfg.n_dense_here, cfg.n_sparse_here, cfg.n_experts_here,
+            cfg.first_expert_here, cfg.vocab_rows, cfg.seq_len,
+            cfg.micro_batch) == (1, 4, 16, 0, 16160, 8192, 1)
+    shapes = train.model_param_shapes(cfg)
+    count = sum(int(np.prod(s)) for s in jax.tree.leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)))
+    assert count == 680_439_808             # 10.9 GB at 16 bytes each
+
+
+def test_a_model_the_path_cannot_run_is_refused(tmp_path):
+    with open(os.path.join(BENCH, "configs",
+                           "joyai-flash-train-1chip.json")) as f:
+        body = json.load(f)
+    for key, value in (("n_group", 8), ("rope_interleave", False),
+                       ("rope_scaling", {"type": "yarn"})):
+        path = tmp_path / f"{key}.json"
+        path.write_text(json.dumps({**body, key: value}))
+        with pytest.raises(NotImplementedError):
+            train.load_model_config(str(path))
+    with pytest.raises(NotImplementedError, match="router"):
+        dataclasses.replace(F32, topk_method="greedy")
+
+
+# OLMoE's losses (total, cross-entropy, load balancing, z) of three steps
+# from seed 3's parameters on the batches of seeds 0, 1, 2, as float32 bit
+# patterns, read at the parent of the PR that generalised the walk (PR 35:
+# commit 9997a7b) with one layer, as the benchmark's OLMoE configuration
+# has; float32 and bfloat16 compute
+OLMOE_AT_PARENT = {
+    "float32": [[1085419428, 1085367925, 1017469360, 999248892],
+                [1085407509, 1085356210, 1017444550, 999139182],
+                [1085478217, 1085425777, 1017826014, 998781521]],
+    "bfloat16": [[1085419398, 1085367894, 1017469584, 999249311],
+                 [1085407115, 1085355858, 1017436727, 999127645],
+                 [1085474075, 1085421858, 1017763143, 998805607]]}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_olmoes_losses_are_bit_for_bit_the_parents(dtype):
+    """OLMoE runs through the same walk as before it was generalised:
+    with its one layer nothing is rematerialised and nothing scanned, so
+    the program is the parent's (with two layers the layers are scanned
+    and recomputed, and one loss of twelve differs in its last bit)."""
+    cfg = train.ModelConfig(
+        hidden_size=64, intermediate_size=32, num_attention_heads=4,
+        num_key_value_heads=4, num_experts=8, num_experts_per_tok=2,
+        vocab_size=256, layers_here=1, seq_len=32, micro_batch=2,
+        attn_block=16, loss_block_rows=16, lr=1e-2, compute_dtype=dtype)
+    params = train.init_model_params(cfg, seed=3)
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=cfg)
+    state, got = None, []
+    for seed in (0, 1, 2):
+        ids = np.random.default_rng(seed).integers(0, 256, (2, 33)).astype(
+            np.int32)
+        batch = jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+        if state is None:
+            state, *batch = place(params, *batch)
+        state, aux = step(state, *batch)
+        got.append(np.asarray(aux["losses"], np.float32).view(
+            np.uint32).tolist())
+    assert state[4] == {}                   # no bias where none is chosen under
+    assert got == OLMOE_AT_PARENT[dtype]
+
+
+# -- the benchmark's own copy of the reference ------------------------------
+@pytest.fixture(scope="module")
+def kit():
+    sys.path.insert(0, BENCH)
+    try:
+        from harness import joyaikit
+        yield joyaikit
+    finally:
+        sys.path.remove(BENCH)
+
+
+KIT_CFG = {**PUBLISHED, **SHARE, **TRAIN, "n_routed_experts": 16,
+           "adam_b1": 0.9, "adam_b2": 0.95, "adam_eps": 1e-8,
+           "weight_decay": 0.1, "compute_dtype": "float32"}
+
+
+def kit_step(kit, params, wrong=None, routed=None):
+    return kit.reference_step(params, *batch_of(0), KIT_CFG, some_bias(),
+                              tuple(LEAVES), wrong, routed)
+
+
+def test_the_kits_leaves_are_the_programs(kit, params):
+    assert list(kit.LEAVES) == LEAVES
+    sizes = kit.leaf_sizes(KIT_CFG)
+    for name, path in train.leaf_names(F32):
+        assert kit.leaf_of(params, name) is train._leaf(params, path)
+        assert sizes[name] == train._leaf(params, path).size
+    rebuilt = kit.tree_of({n: kit.leaf_of(params, n) for n in kit.LEAVES})
+    assert jax.tree.structure(rebuilt) == jax.tree.structure(params)
+
+
+def test_the_benchmarks_kit_is_the_repos_reference(kit, params, reference):
+    out = kit_step(kit, params)
+    np.testing.assert_allclose(out["losses"], reference["parts"], **CLOSE)
+    np.testing.assert_array_equal(out["loads"], reference["loads"])
+    want_bias = ref.bias_step(some_bias(), reference["loads"], F32)
+    np.testing.assert_allclose(
+        out["bias"], np.concatenate([want_bias["layers"], want_bias["mtp"]]),
+        rtol=1e-6)
+    for name, path in train.leaf_names(F32):
+        want = train._leaf(reference["grads"], path)
+        np.testing.assert_allclose(
+            out["grads"][name], want, rtol=1e-5,
+            atol=2e-5 * float(jnp.abs(want).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_a_step_reports_what_the_kit_computes(dp, kit, params):
+    """The quantities the benchmark's check compares, from the step's raw
+    statistics and the biases its state holds (on two shards the same as
+    on one), under the step's own routing, and the updated parameters."""
+    state, (aux,) = run_steps(F32, params, (0,), dp=dp)
+    aux, after = jax.device_get((aux, state[4]))
+    zero = ref.zero_bias(F32)
+    out = kit.reference_step(params, *batch_of(0), KIT_CFG, zero,
+                             tuple(LEAVES), routed=aux["experts"])
+    got = kit.compared(kit.step_stats(aux, after), KIT_CFG, tuple(LEAVES))
+    want = kit.compared(out, KIT_CFG, tuple(LEAVES))
+    assert set(got) == {"losses", "load_share", "local_share", "row_means",
+                        "route_regret", "bias", "grad_log_rms", "grad_probe"}
+    assert np.abs(got["bias"]).max() == 1   # one step of gamma
+    for key in got:
+        np.testing.assert_allclose(got[key], want[key], rtol=1e-4,
+                                   atol=1e-4, err_msg=key)
+    for i, name in enumerate(LEAVES):
+        p = kit.leaf_of(params, name)
+        new = kit.adamw_leaf(name, p, out["grads"][name], KIT_CFG)
+        pos = kit.probe_positions(name, p.size)
+        np.testing.assert_allclose(
+            aux["param_probe"][i], new.reshape(-1)[pos], rtol=1e-5,
+            atol=0.01 * F32.lr, err_msg=name)
+
+
+@pytest.mark.parametrize("variant", ["bf16", "bias_in_weights", "softmax"])
+def test_a_float32_part_is_told_from_a_wrong_one(variant, kit, params):
+    """The routers' logits, sigmoid scores and chosen weights and both
+    heads' rows, recomputed from the step's own inputs to each part: the
+    step's are within a twentieth of the benchmark's tolerance; a
+    bfloat16 router, weights that include the bias and a softmax in the
+    sigmoid's place each lie outside it."""
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=F32)
+    state, tokens, labels = place(jax.tree.map(jnp.copy, params),
+                                  *batch_of(0))
+    bias = some_bias(scale=0.05)
+    before = np.concatenate([bias["layers"], bias["mtp"]])
+    state = state[:4] + (bias,)             # donated into the step
+    _, aux = step(state, tokens, labels)
+    aux = jax.device_get(aux)
+    routers = np.concatenate([params["layers"]["router"],
+                              params["mtp"]["router"][None]])
+    args = (aux, routers, before, params["head"], batch_of(0)[1], KIT_CFG)
+    got, want = kit.precision_got(aux, KIT_CFG), kit.precision_want(*args)
+    wrong = kit.precision_want(*args, variant=variant)
+    outside = []
+    for key in got:
+        tol = 0.005 + 0.000375 * np.abs(want[key])
+        assert got[key].shape == want[key].shape
+        assert (np.abs(got[key] - want[key]) < 0.05 * tol).all(), key
+        outside.append((np.abs(wrong[key] - want[key]) > tol).any())
+    assert any(outside)
+
+
+# a wrong variant and a leaf whose gradient it moves
+WITNESS = {"softmax": "down", "bias_in_weights": "down",
+           "rope_on_nope": "wq_b", "unnormalised": "down",
+           "mtp_fed_t_i": "mtp.proj"}
+
+
+@pytest.mark.parametrize("wrong", sorted(WITNESS))
+def test_a_wrong_variant_fails_the_comparison(wrong, kit, params, system):
+    """Softmax scores, the bias in the weights, RoPE on the part without
+    position, weights not normalised, the module fed the token itself:
+    under the step's own routing the gradient of a leaf behind the
+    variant lies a hundred times farther from the program's than the
+    right model's does (1e-5), and the losses differ too."""
+    assert set(WITNESS) == set(kit.WRONG)
+    routed = None if wrong == "softmax" else jnp.asarray(
+        system["aux"]["experts"])
+    out = kit_step(kit, params, wrong, routed)
+    got = np.asarray(system["aux"]["losses"])[[0, 1, 4]]
+    assert (got != np.asarray(out["losses"])).any()
+    leaf = WITNESS[wrong]
+    mine = train._leaf(system["grads"], dict(train.leaf_names(F32))[leaf])
+    off = float(jnp.linalg.norm(mine - out["grads"][leaf])
+                / jnp.linalg.norm(mine))
+    assert off > 1e-3, (wrong, off)
+
+
+def test_the_kit_counts_the_steps_flop(kit):
+    cfg = kit.load_config(os.path.join(BENCH, "configs",
+                                       "joyai-flash-train-1chip.json"))
+    flops = kit.step_flops(cfg)
+    assert sum(kit.leaf_sizes(cfg).values()) == 680_439_808
+    assert round(flops["step"] / 1e12, 1) == 27.8
+    latent = (flops["attention"] + flops["latent_proj"]) / flops["step"]
+    assert 0.71 < latent < 0.73             # latent attention is 72%
+    assert flops["flash_forward"] * 3 == flops["attention"]
+    assert round(flops["experts"] / flops["step"], 2) == 0.02

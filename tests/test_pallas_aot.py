@@ -24,7 +24,7 @@ pytestmark = pytest.mark.skipif(
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_aot_subprocess(*extra, **env_extra) -> dict:
+def _run_aot_subprocess(*extra, limit: int = 240, **env_extra) -> dict:
     """Run the AOT gate in a CPU-pinned subprocess: compile-only,
     bounded, and with the topology
     client's state kept out of the pytest process.  A lowering failure
@@ -35,7 +35,7 @@ def _run_aot_subprocess(*extra, **env_extra) -> dict:
     proc = subprocess.run(
         [sys.executable, "-m", "ompi_tpu.tools.pallas_aot",
          "--out", out, *extra],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=240)
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=limit)
     if proc.returncode not in (0, 1) or not os.path.exists(out):
         raise RuntimeError(
             f"pallas_aot gate crashed (rc={proc.returncode}):\n"
@@ -160,6 +160,40 @@ def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
     assert row["entry_ops"]["custom-call"] >= 19
     assert row["entry_ops"]["while"] == 1
     assert row["compile_s"] < 120
+
+
+@pytest.fixture(scope="module")
+def joyai_rows():
+    """One child for the JoyAI-LLM-Flash cases: the block update at 192 /
+    128 alone, plain and biased, and the whole step of the cell's own
+    configuration file for one v5e device (about 65 s of the 600)."""
+    pytest.importorskip("libtpu")
+    res = _run_aot_subprocess("--only", "joyai", "--topology", "v5e:2x2",
+                              limit=600)
+    assert res.get("rows"), res.get("error")
+    return {r["kernel"]: r for r in res["rows"]}
+
+
+def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
+    """q and k 192 wide, v and the numerator 128: one kernel, no
+    padding outside it."""
+    for name in ("joyai_flash_block_1k", "joyai_flash_block_1k_biased"):
+        row = joyai_rows[name]
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
+
+
+def test_joyai_train_step_aot_compiles_from_the_cells_configuration(
+        joyai_rows):
+    """The whole step of ``benchmark/configs/joyai-flash-train-1chip
+    .json`` (published widths; 1 dense + 4 sparse layers, the module, 16
+    of 256 experts): it fits the chip beside its 7.6 GiB of state, and
+    the four sparse layers are one loop, so the compile stays near a
+    minute."""
+    row = joyai_rows["joyai_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 400
 
 
 @pytest.mark.slow
