@@ -357,6 +357,49 @@ def kernels(clock: Clock, expect_interpret: bool = False,
           jax.jit(lambda *a: fa.flash_block_update_biased(*a)),
           (q, k, v, m0, num0, den0, bias))
 
+    # attention's backward: the fused block pair against its jnp twin, q
+    # and k as wide as v (128 / 128) and half as wide again (192 / 128),
+    # a plain pair and the diagonal one through one compiled kernel
+    from ompi_tpu.parallel import model
+
+    block, f32 = min(sq, 1024), jnp.float32
+    cut = lambda x, n: x[:, :, n * block:(n + 1) * block]
+    for wide in (d, d * 3 // 2):
+        name = f"attn_block_backward {wide}/{d}"
+        keys = jax.random.split(jax.random.PRNGKey(wide), 7)
+        draw = lambda key, w, t=dt: jax.random.normal(
+            key, (b, h, 2 * block, w), t)
+        qb, kb, vb, dob = (draw(key, w) for key, w in zip(
+            keys, (wide, wide, d, d)))
+        acc = tuple(draw(key, w, f32) for key, w in zip(
+            keys[4:], (wide, wide, d)))
+        up = tuple(x.astype(f32) for x in (qb, kb, vb, dob))
+        o, lse = model._causal_fwd_blocks(*up[:3], block, True)
+        delta = jnp.sum(up[3] * o, axis=-1)
+        args = (qb, kb, vb, dob, lse, delta) + acc
+        compiled = compile_checked(name, jax.jit(
+            lambda ij, *a: fa.attn_block_backward(ij, *a, block=block)),
+            jnp.zeros(2, jnp.int32), *args)
+        for i, j in ((1, 0), (1, 1)):
+            got = clock.call(compiled, jnp.asarray((i, j), jnp.int32),
+                             *args, first=False)
+            want = model._bwd_pair(
+                cut(up[0], i), cut(up[1], j), cut(up[2], j), cut(up[3], i),
+                cut(lse, i), cut(delta, i),
+                model._tri_bias(block) if i == j else None,
+                1.0 / wide ** 0.5, f32)
+            for part, g, a0, w, n in zip(("dq", "dk", "dv"), got, acc,
+                                         want, (i, j, j)):
+                g = np.asarray(cut(g, n) - cut(a0, n), np.float32)
+                w = np.asarray(w, np.float32)
+                _require(np.all(np.isfinite(g)), f"{name}.{part}: non-finite")
+                err = float(np.max(np.abs(g - w)) / max(1.0,
+                                                        np.max(np.abs(w))))
+                _require(err <= tol, f"{name}.{part} pair {(i, j)}: error "
+                                     f"{err:.3e} of max|ref| exceeds {tol:g}")
+        print(f"  {name} (block {block}) {dtype} matches its jnp twin",
+              flush=True)
+
     a = jax.random.normal(kq, (reduce_elems,), jnp.float32)
     bb = jax.random.normal(kk, (reduce_elems,), jnp.float32)
     stack = jax.random.normal(kv, (8, reduce_elems // 8), jnp.float32)

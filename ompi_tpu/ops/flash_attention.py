@@ -16,6 +16,11 @@ op kernels (``ompi/mca/op/avx``).
 Grid: (batch*heads, q row tiles).  K/V blocks ride whole in VMEM (s_kv up
 to a few thousand at 128-lane alignment); scores compute at f32 on the
 MXU via ``preferred_element_type``.
+
+The causal train step's backward pass (``model.causal_flash_attention``)
+has its block pair here too: ``attn_block_backward``, the five matmuls of
+one (q block, kv block) pair fused the same way, with the float32
+gradient accumulators passed through the call in place.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ompi_tpu.base.jaxenv import pallas_interpret
 
@@ -204,3 +210,137 @@ def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
     return (mo[..., 0].reshape(b, h, sq).astype(m.dtype),
             numo.reshape(num.shape),
             deno[..., 0].reshape(b, h, sq).astype(den.dtype))
+
+
+#: the backward kernel's tile: so many q positions a grid step, against
+#: so many kv positions at a time (a block of 1,024 is one tile)
+BWD_TILE = 1024
+#: the tile on the diagonal goes by strips of so many kv positions, each
+#: against the q positions from its own first on: the rest see none of it
+BWD_STRIP = 256
+#: a tile of 1,024 at 192 / 128 holds k, v, q, do, the three float32
+#: accumulators twice (in, out) and a few (1024, 1024) float32 score
+#: arrays: over Mosaic's default 16 MiB of the v5e's 128 (offline compile)
+BWD_VMEM_LIMIT = 64 << 20
+
+
+def _bwd_block_kernel(scale, strip, ij_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                      dqo_ref, dko_ref, dvo_ref):
+    """One q tile of block i against kv block j, a tile of kv positions
+    at a time, **transposed**: scores are held (kv, q), so that the
+    logsumexp and delta of a q row are row vectors that broadcast down
+    the sublanes, and of the five matmuls only dq's contracts over the
+    first axis of both operands."""
+    t = pl.program_id(1)
+    diagonal = ij_ref[0] == ij_ref[1]
+    tile = q_ref.shape[1]
+    nt_dims = (((1,), (1,)), ((), ()))
+    nn_dims = (((1,), (0,)), ((), ()))
+    tn_dims = (((0,), (0,)), ((), ()))
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(t == 0)
+    def _():
+        dko_ref[...] = dk_ref[...]
+        dvo_ref[...] = dv_ref[...]
+
+    dqo_ref[...] = dq_ref[...]
+
+    def part(c, lo, n, masked):
+        """kv positions lo .. lo + n of tile c against the q tile's
+        positions from lo on."""
+        rows = pl.ds(c * tile + lo, n)
+        k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+        q, do = q_ref[0, lo:, :], do_ref[0, lo:, :]
+        s = dot(k, q, nt_dims) * scale                      # (kv, q)
+        if masked:
+            at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                       axis)
+            s = jnp.where(at(0) <= at(1), s, -jnp.inf)
+        p = jnp.exp(s - lse_ref[0, :, lo:])
+        dvo_ref[0, rows, :] += dot(p.astype(do.dtype), do, nn_dims)
+        ds = (p * (dot(v, do, nt_dims) - delta_ref[0, :, lo:])
+              * scale).astype(q.dtype)
+        dko_ref[0, rows, :] += dot(ds, q, nn_dims)
+        dqo_ref[0, lo:, :] += dot(ds, k, tn_dims)
+
+    # by position: a kv tile of the diagonal pair lies wholly under the
+    # diagonal (c < t), on it (c == t: masked, by strips) or wholly above
+    # it (p is 0 there: skipped); every tile of another pair lies under
+    for c in range(k_ref.shape[1] // tile):
+        pl.when(jnp.logical_or(jnp.logical_not(diagonal), c < t))(
+            functools.partial(part, c, 0, tile, False))
+
+        @pl.when(jnp.logical_and(diagonal, c == t))
+        def _():
+            for lo in range(0, tile, strip):
+                part(c, lo, strip, True)
+
+
+def _tile(length, most):
+    """A tile of ``length``: ``most`` where that divides it, else whole."""
+    most = min(length, most)
+    return most if length % most == 0 else length
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
+                        block: int, interpret=None):
+    """One block pair of causal attention's flash backward, fused: q
+    block ``ij[0]`` against kv block ``ij[1]`` (``block`` positions
+    each; the pair whose two are equal is masked by position), the
+    pair's terms added into the float32 accumulators and those handed
+    back: ``dq`` at block i, ``dk`` and ``dv`` at block j, every other
+    block as it came (the three alias their inputs).
+
+    q, k: (b, h, s, d); v, do: (b, h, s, dv), all of q's dtype; lse,
+    delta: (b, h, s) float32 (the forward's logsumexp; the row sums of
+    do * o); dq, dk: (b, h, s, d) and dv: (b, h, s, dv) float32.  The
+    arrays come whole and ``ij`` picks the blocks in the index maps (a
+    scalar-prefetch operand), so a walk over the pairs, unrolled or by
+    ``lax.scan``, slices nothing.  Five matmuls a pair with float32
+    accumulation, ``p`` and ``ds`` cast to q's dtype for theirs; no
+    (block, block) array leaves VMEM.  The ``jnp`` twin is
+    ``parallel/model._bwd_pair``.
+    """
+    if interpret is None:
+        interpret = pallas_interpret()
+    b, h, s, d = q.shape
+    hv = v.shape[-1]
+    bh = b * h
+    tq = _tile(block, BWD_TILE)
+    nt = block // tq
+
+    flat = lambda a: a.reshape(bh, s, a.shape[-1])
+    row = lambda a: a.reshape(bh, 1, s).astype(jnp.float32)
+    q_map = lambda g, t, ij: (g, ij[0] * nt + t, 0)
+    kv_map = lambda g, t, ij: (g, ij[1], 0)
+    row_map = lambda g, t, ij: (g, 0, ij[0] * nt + t)
+    q_spec = lambda width: pl.BlockSpec((1, tq, width), q_map)
+    kv_spec = lambda width: pl.BlockSpec((1, block, width), kv_map)
+    row_spec = pl.BlockSpec((1, 1, tq), row_map)
+
+    operands = [ij.astype(jnp.int32), flat(q), flat(k), flat(v), flat(do),
+                row(lse), row(delta), flat(dq), flat(dk), flat(dv)]
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    acc_specs = [q_spec(d), kv_spec(d), kv_spec(hv)]
+    out = pl.pallas_call(
+        functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
+                          _tile(tq, BWD_STRIP)),
+        out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
+                        for o in operands[7:]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, nt),
+            in_specs=[q_spec(d), kv_spec(d), kv_spec(hv), q_spec(hv),
+                      row_spec, row_spec] + acc_specs,
+            out_specs=acc_specs),
+        input_output_aliases={7: 0, 8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name="otpu_attn_block_backward",
+    )(*operands)
+    return tuple(o.reshape(a.shape) for o, a in zip(out, (dq, dk, dv)))

@@ -400,14 +400,16 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
 
 def _causal_bwd(block, interpret, res, do):
     q, k, v, o, lse = res
-    dt = q.dtype
     nb = q.shape[2] // block
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    bias = _tri_bias(block)
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
+    if not interpret:
+        return _causal_bwd_fused(q, k, v, do, lse, delta, block)
     if nb > UNROLLED_BLOCKS:
         return _causal_bwd_scanned(q, k, v, do, lse, delta, block)
+    dt = q.dtype
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    bias = _tri_bias(block)
     cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
     dq = [0.0] * nb
     dk = [0.0] * nb
@@ -455,6 +457,36 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
     whole = lambda a: jnp.moveaxis(a, 0, 2).reshape(
         b, h, s, a.shape[-1]).astype(dt)
     return whole(dq), whole(dk), whole(dv)
+
+
+def _causal_bwd_fused(q, k, v, do, lse, delta, block):
+    """The same pairs in the same order, each one call of the fused
+    Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
+    ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
+    and the float32 accumulators pass through every call in place.
+    Both walks: unrolled up to ``UNROLLED_BLOCKS`` blocks, one
+    ``lax.scan`` beyond; the arrays go in whole and the pair is an
+    operand, so neither slices."""
+    from ompi_tpu.ops.flash_attention import attn_block_backward
+
+    dt = q.dtype
+    nb = q.shape[2] // block
+    do = do.astype(dt)                  # what ``_contract`` makes of it
+    pair = lambda acc, ij: attn_block_backward(
+        ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False)
+    pairs = [(i, j) for i in range(nb) for j in range(i + 1)]
+    acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
+    vma = tuple(frozenset().union(*(jax.typeof(a).vma
+                                    for a in (q, k, v, do))))
+    if vma:                 # the carry varies as the kernel's results do
+        acc = jax.lax.pcast(acc, vma, to="varying")
+    if nb > UNROLLED_BLOCKS:
+        acc, _ = jax.lax.scan(lambda acc, ij: (pair(acc, ij), None), acc,
+                              jnp.asarray(pairs, jnp.int32))
+    else:
+        for ij in pairs:
+            acc = pair(acc, jnp.asarray(ij, jnp.int32))
+    return tuple(a.astype(dt) for a in acc)
 
 
 causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)

@@ -271,6 +271,42 @@ def cases(mesh1d, mesh2d):
         fa._update_pallas, mla_args(False), {"interpret": False}))
     case("joyai_flash_block_1k_biased", lambda: (
         fa._update_pallas, mla_args(True), {"interpret": False}))
+    # attention's backward (``parallel/model._causal_bwd``): the fused
+    # block-pair kernel at the two cells' shapes (the arrays come whole
+    # and a scalar-prefetch operand picks the pair, so the plain and the
+    # diagonal pair are one compiled kernel), and the two walks over the
+    # pairs, OLMoE's 10 unrolled and JoyAI's 36 by ``lax.scan``: the
+    # accumulators must alias through every call (no ``copy``, no
+    # ``dynamic-update-slice`` of their size beside it)
+    def attn_bwd_args(b, h, s, d, hv):
+        wide = lambda w, dt: _sds((b, h, s, w), dt, one, P())
+        row = _sds((b, h, s), jnp.float32, one, P())
+        return (wide(d, bf16), wide(d, bf16), wide(hv, bf16),
+                wide(hv, bf16), row, row)
+
+    def attn_block_backward(b, h, s, d, hv):
+        wide = lambda w: _sds((b, h, s, w), jnp.float32, one, P())
+        return fa.attn_block_backward, (
+            (_sds((2,), jnp.int32, one, P()),) + attn_bwd_args(b, h, s, d, hv)
+            + (wide(d), wide(d), wide(hv))), {"block": 1024,
+                                              "interpret": False}
+
+    def attn_backward_walk(b, h, s, d, hv):
+        from ompi_tpu.parallel import model
+
+        q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv)
+        o = _sds(v.shape, jnp.float32, one, P())     # and its cotangent
+        return jax.jit(lambda q, k, v, o, lse, do: model._causal_bwd(
+            1024, False, (q, k, v, o, lse), do)), (q, k, v, o, lse, o)
+
+    case("olmoe_attn_block_backward_1k",
+         lambda: attn_block_backward(2, 16, 4096, 128, 128))
+    case("joyai_attn_block_backward_1k",
+         lambda: attn_block_backward(1, 32, 8192, 192, 128))
+    case("olmoe_attn_backward_walk_4k",
+         lambda: attn_backward_walk(2, 16, 4096, 128, 128))
+    case("joyai_attn_backward_walk_8k",
+         lambda: attn_backward_walk(1, 32, 8192, 192, 128))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),

@@ -150,14 +150,30 @@ def test_olmoe_attention_aot_compiles_at_the_cells_shape(olmoe_rows):
         "custom-call"] >= 10
 
 
+def test_olmoe_attention_backward_aot_compiles_at_the_cells_shape(
+        olmoe_rows):
+    """The fused block pair of attention's backward at the OLMoE step's
+    shape (2 x 16 heads x 4,096 x 128 in bfloat16, blocks of 1,024): the
+    kernel alone, the pair a scalar operand, so the plain and the
+    diagonal pair are one compiled kernel; and the ten pairs unrolled."""
+    row = olmoe_rows["olmoe_attn_block_backward_1k"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
+    walk = olmoe_rows["olmoe_attn_backward_walk_4k"]
+    assert walk.get("compiled"), json.dumps(walk, indent=1)
+    assert walk["entry_ops"]["custom-call"] >= 10
+    assert "while" not in walk["entry_ops"], walk["entry_ops"]
+
+
 def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
         olmoe_rows):
     """The whole step of ``benchmark/configs/olmoe-1b-7b-train-1chip
-    .json`` (published widths, one layer): the flash kernel ten times,
-    nine grouped expert matmuls, one loop over the head's row blocks."""
+    .json`` (published widths, one layer): the flash kernel ten times
+    forward and the backward's block pair ten times, nine grouped
+    expert matmuls, one loop over the head's row blocks."""
     row = olmoe_rows["olmoe_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
-    assert row["entry_ops"]["custom-call"] >= 19
+    assert row["entry_ops"]["custom-call"] >= 29
     assert row["entry_ops"]["while"] == 1
     assert row["compile_s"] < 120
 
@@ -181,6 +197,18 @@ def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
         row = joyai_rows[name]
         assert row.get("compiled"), json.dumps(row, indent=1)
         assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
+
+
+def test_attention_backward_aot_compiles_at_192_and_128(joyai_rows):
+    """The backward's block pair with q and k 192 wide and v 128 (1 x 32
+    heads x 8,192, blocks of 1,024), alone and as the 36 pairs of one
+    ``lax.scan``: the kernel is in the loop's body, one loop."""
+    row = joyai_rows["joyai_attn_block_backward_1k"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
+    walk = joyai_rows["joyai_attn_backward_walk_8k"]
+    assert walk.get("compiled"), json.dumps(walk, indent=1)
+    assert walk["entry_ops"].get("while") == 1, walk["entry_ops"]
 
 
 def test_joyai_train_step_aot_compiles_from_the_cells_configuration(
