@@ -268,16 +268,28 @@ def transformer_block(p, x, *, sp, tp, n_heads_local, n_experts, capacity,
 
 
 # -- a public model's block (OLMoE): parallel/train.py's model path --------
-def matmul(a, w, compute_dtype):
+def cast_param(w, dtype):
+    """A parameter leaf in the matmuls' ``dtype``, under the scope
+    ``otpu_cast``: XLA makes a pass of its own of a large leaf's cast
+    (and of its transposition), which a trace then tells from the
+    sublayer's other work."""
+    with jax.named_scope("otpu_cast"):
+        return w.astype(dtype)
+
+
+def matmul(a, w, compute_dtype, weight: bool = True):
     """``a @ w`` with inputs in ``compute_dtype`` and a float32 result:
     bfloat16 inputs accumulate in float32 on the MXU; float32 inputs
     multiply at the highest precision (on a TPU the default would round
-    them to bfloat16 on the way in)."""
-    if jnp.dtype(compute_dtype) == jnp.float32:
-        return jnp.dot(a.astype(jnp.float32), w.astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)
-    return jnp.dot(a.astype(compute_dtype), w.astype(compute_dtype),
-                   preferred_element_type=jnp.float32)
+    them to bfloat16 on the way in).  ``w`` is a parameter leaf
+    (``cast_param``) unless ``weight`` is false."""
+    f32 = jnp.dtype(compute_dtype) == jnp.float32
+    dtype = jnp.float32 if f32 else compute_dtype
+    a = a.astype(dtype)
+    w = cast_param(w, dtype) if weight else w.astype(dtype)
+    if f32:
+        return jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
+    return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
 
 def rmsnorm_gain(x, gain, eps: float):
@@ -499,17 +511,20 @@ def olmoe_attention(p, x, cfg, *, interpret: bool):
     split (QK-norm); RoPE; causal attention; residual add."""
     b, s, d = x.shape
     nh, dt = cfg.num_attention_heads, cfg.compute_dtype
-    h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
-    q = rmsnorm_gain(matmul(h, p["wq"], dt), p["q_norm"], cfg.rms_norm_eps)
-    k = rmsnorm_gain(matmul(h, p["wk"], dt), p["k_norm"], cfg.rms_norm_eps)
-    v = matmul(h, p["wv"], dt)
-    heads = lambda t: t.reshape(b, s, nh, -1).transpose(0, 2, 1, 3)
-    q, k = rope(heads(q), cfg.rope_theta), rope(heads(k), cfg.rope_theta)
-    o = causal_flash_attention(q.astype(dt), k.astype(dt),
-                               heads(v).astype(dt),
-                               min(cfg.attn_block, s), interpret)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-    return x + matmul(o, p["wo"], dt)
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+        q = rmsnorm_gain(matmul(h, p["wq"], dt), p["q_norm"],
+                         cfg.rms_norm_eps)
+        k = rmsnorm_gain(matmul(h, p["wk"], dt), p["k_norm"],
+                         cfg.rms_norm_eps)
+        v = matmul(h, p["wv"], dt)
+        heads = lambda t: t.reshape(b, s, nh, -1).transpose(0, 2, 1, 3)
+        q, k = rope(heads(q), cfg.rope_theta), rope(heads(k), cfg.rope_theta)
+        q, k, v = q.astype(dt), k.astype(dt), heads(v).astype(dt)
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    with jax.named_scope("otpu_attn_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+        return x + matmul(o, p["wo"], dt)
 
 
 def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
@@ -549,22 +564,24 @@ def mla_attention(p, x, cfg, *, interpret: bool):
     b, s, _ = x.shape
     nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
     nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    h = rmsnorm_gain(x, p["ln1"], eps)
-    cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
-    q = matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
-    q = rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
-    kv = matmul(h, p["wkv_a"], dt)                       # (b, s, rank + rot)
-    ckv = rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
-    kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
-    k_rot = rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
-    k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
-        k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
-    heads = lambda t: t.transpose(0, 2, 1, 3)            # (b, nh, s, .)
-    o = causal_flash_attention(heads(q.astype(dt)), heads(k),
-                               heads(kvb[..., nope:].astype(dt)),
-                               min(cfg.attn_block, s), interpret)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
-    return x + matmul(o, p["wo"], dt)
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], eps)
+        cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
+        q = matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
+        q = rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
+        kv = matmul(h, p["wkv_a"], dt)                   # (b, s, rank + rot)
+        ckv = rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+        kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
+        k_rot = rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
+        k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
+            k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
+        heads = lambda t: t.transpose(0, 2, 1, 3)        # (b, nh, s, .)
+        q, k, v = (heads(q.astype(dt)), heads(k),
+                   heads(kvb[..., nope:].astype(dt)))
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    with jax.named_scope("otpu_attn_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
+        return x + matmul(o, p["wo"], dt)
 
 
 def swiglu(h, gate, up, down, compute_dtype):

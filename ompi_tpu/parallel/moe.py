@@ -846,12 +846,14 @@ def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
     import jax
     import jax.numpy as jnp
 
+    from ompi_tpu.parallel.model import cast_param
+
     f32 = jnp.dtype(compute_dtype) == jnp.float32
     prec = jax.lax.Precision.HIGHEST if f32 else None
 
     def gmm(a, w):
         return jax.lax.ragged_dot(
-            a.astype(compute_dtype), w.astype(compute_dtype), sizes,
+            a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
             precision=prec, preferred_element_type=jnp.float32)
 
     hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
@@ -880,6 +882,7 @@ def moe_sorted_block(p, x, cfg):
         logits = jnp.dot(h, p["router"],
                          precision=jax.lax.Precision.HIGHEST)
         probs, weights, experts = route_topk(logits, k, cfg.norm_topk_prob)
+    with jax.named_scope("otpu_dispatch"):
         token, place, sizes = sorted_dispatch(experts, cfg.num_experts)
     with jax.named_scope("otpu_experts"):
         y = grouped_expert_ffn(h.astype(cfg.compute_dtype)[token],
@@ -957,19 +960,21 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
 
     def chunk(lo, order, sizes, h, flat_w, gate, up, down):
         """Chunk ``lo``'s (token of each row, its weighted output)."""
-        slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-        token = slot // k
-        ends = jnp.cumsum(sizes)
-        live = lo + jnp.arange(rows) < ends[-1]
-        here = jnp.clip(jnp.minimum(ends, lo + rows)
-                        - jnp.maximum(ends - sizes, lo), 0, rows)
+        with jax.named_scope("otpu_dispatch"):
+            slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+            token = slot // k
+            ends = jnp.cumsum(sizes)
+            live = lo + jnp.arange(rows) < ends[-1]
+            here = jnp.clip(jnp.minimum(ends, lo + rows)
+                            - jnp.maximum(ends - sizes, lo), 0, rows)
         # rows past the last held slot belong to no group: a grouped
         # matmul leaves them as they were in memory (seen on the v5e:
         # NaN), in its transposes too, so they are cut off on both sides
         xs = jnp.where(live[:, None], h[token], 0.0)
         y = grouped_expert_ffn(xs, gate, up, down, here, cfg.compute_dtype)
-        w = jnp.where(live, flat_w[slot], 0.0)
-        return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
+        with jax.named_scope("otpu_combine"):
+            w = jnp.where(live, flat_w[slot], 0.0)
+            return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
 
     def trips(sizes):
         return (jnp.sum(sizes) + rows - 1) // rows
@@ -979,7 +984,8 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
         def body(c, out):
             token, y = chunk(c * rows, order, sizes, h, flat_w, gate, up,
                              down)
-            return out.at[token].add(y)
+            with jax.named_scope("otpu_combine"):
+                return out.at[token].add(y)
         return jax.lax.fori_loop(0, trips(sizes), body, h * 0)
 
     def fwd(*args):
@@ -1026,6 +1032,7 @@ def moe_shared_local_block(p, x, cfg, bias):
                          precision=jax.lax.Precision.HIGHEST)
         scores, weights, experts = route_sigmoid_bias(
             logits, bias, k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
+    with jax.named_scope("otpu_dispatch"):
         order, sizes = local_dispatch(experts, cfg.first_expert_here,
                                       cfg.n_experts_here)
         slots = jnp.zeros((cfg.num_experts,), jnp.int32).at[

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import time
+import weakref
 import zlib
 
 import numpy as np
@@ -690,7 +691,7 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
             dlogits = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
                 lb, logits.shape[-1], dtype=jnp.float32)
             dh = matmul(dlogits, w.T, compute_dtype)
-            dw = dw + matmul(hb.T, dlogits, compute_dtype)
+            dw = dw + matmul(hb.T, dlogits, compute_dtype, weight=False)
             return (total + jnp.sum(lse - picked), dw), (
                 dh, jnp.stack([lse, picked], axis=-1))
 
@@ -760,8 +761,10 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         x, st, routed = decoder_layer(layer, x, cfg, interpret=interpret,
                                       bias=bias_row)
         experts = routed.pop("experts", None)
-        return x, (jax.tree.map(psum, st), experts,
+        with jax.named_scope("otpu_stats"):
+            out = (jax.tree.map(psum, st), experts,
                    {"router_" + k: v[at] for k, v in routed.items()})
+        return x, out
 
     if cfg.n_dense_here + cfg.n_routers > 1:
         # a layer's activations are recomputed in its backward pass, so
@@ -770,10 +773,12 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         run = jax.checkpoint(run)
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32
-    if cfg.n_dense_here:
-        x, _ = _walk_layers(run, params["dense"], x, None, cfg.n_dense_here)
-    x, (st, chosen, sample) = _walk_layers(
-        run, params["layers"], x, bias.get("layers"), cfg.n_sparse_here)
+    with jax.named_scope("otpu_layers"):
+        if cfg.n_dense_here:
+            x, _ = _walk_layers(run, params["dense"], x, None,
+                                cfg.n_dense_here)
+        x, (st, chosen, sample) = _walk_layers(
+            run, params["layers"], x, bias.get("layers"), cfg.n_sparse_here)
     head_rows = min(cfg.loss_block_rows, b * s)
     with jax.named_scope("otpu_head"):
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
@@ -781,18 +786,22 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             h.reshape(b * s, -1), params["head"],
             labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
     routed = cfg.n_sparse_here * n_global   # rows of all routers' logits
-    ce = psum(ce_sum) / n_global
-    lb = z = jnp.zeros((), jnp.float32)
-    if "prob_sum" in st:
-        # HF's load_balancing_loss_func: every layer's rows in one mean
-        slots, prob_sum = jnp.sum(st["slots"], 0), jnp.sum(st["prob_sum"], 0)
-        lb = cfg.num_experts * jnp.sum((slots / routed)
-                                       * (prob_sum / routed))
-        z = jnp.sum(st["z_sum"], 0) / routed
-    lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
-    total = ce + lb + z
+    with jax.named_scope("otpu_loss"):
+        ce = psum(ce_sum) / n_global
+        lb = z = jnp.zeros((), jnp.float32)
+        if "prob_sum" in st:
+            # HF's load_balancing_loss_func: every layer's rows in one
+            # mean
+            slots, prob_sum = (jnp.sum(st["slots"], 0),
+                               jnp.sum(st["prob_sum"], 0))
+            lb = cfg.num_experts * jnp.sum((slots / routed)
+                                           * (prob_sum / routed))
+            z = jnp.sum(st["z_sum"], 0) / routed
+        lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
+        total = ce + lb + z
     losses, loads = [ce, lb, z], st["slots"]
-    sample["head_in"] = h.reshape(b * s, -1)[at]
+    with jax.named_scope("otpu_stats"):
+        sample["head_in"] = h.reshape(b * s, -1)[at]
     aux = {}
     if cfg.num_nextn_predict_layers:
         # DeepSeek-V3's multi-token prediction, depth one: the last
@@ -807,29 +816,37 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             joined = jnp.concatenate([nxt, prev], -1).reshape(b * s, -1)
             x2 = matmul(joined, mtp["proj"], cfg.compute_dtype
                         ).reshape(b, s, -1)
-            x2, (st2, chosen2, sample2) = _walk_layers(
-                run, jax.tree.map(lambda a: a[None], {
-                    k: v for k, v in mtp.items()
-                    if k not in ("enorm", "hnorm", "proj", "norm")}),
-                x2, bias.get("mtp"), 1)
-            h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
-            ce2_sum, aux["mtp_rows"] = head_cross_entropy(
-                h2.reshape(b * s, -1), params["head"],
-                labels[:, 1:].reshape(b * s), head_rows, cfg.compute_dtype)
-        losses.append(cfg.mtp_loss_coef * psum(ce2_sum) / n_global)
-        total = total + losses[-1]
-        loads = jnp.concatenate([loads, st2["slots"]])
-        chosen = jnp.concatenate([chosen, chosen2])
-        sample = {**{k: jnp.concatenate([sample[k], sample2[k]])
-                     for k in sample2},
-                  "head_in": sample["head_in"],
-                  "mtp_head_in": h2.reshape(b * s, -1)[at]}
+            with jax.named_scope("otpu_layers"):
+                x2, (st2, chosen2, sample2) = _walk_layers(
+                    run, jax.tree.map(lambda a: a[None], {
+                        k: v for k, v in mtp.items()
+                        if k not in ("enorm", "hnorm", "proj", "norm")}),
+                    x2, bias.get("mtp"), 1)
+            with jax.named_scope("otpu_head"):
+                h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
+                ce2_sum, aux["mtp_rows"] = head_cross_entropy(
+                    h2.reshape(b * s, -1), params["head"],
+                    labels[:, 1:].reshape(b * s), head_rows,
+                    cfg.compute_dtype)
+        with jax.named_scope("otpu_loss"):
+            losses.append(cfg.mtp_loss_coef * psum(ce2_sum) / n_global)
+            total = total + losses[-1]
+        with jax.named_scope("otpu_stats"):
+            loads = jnp.concatenate([loads, st2["slots"]])
+            chosen = jnp.concatenate([chosen, chosen2])
+            sample = {**{k: jnp.concatenate([sample[k], sample2[k]])
+                         for k in sample2},
+                      "head_in": sample["head_in"],
+                      "mtp_head_in": h2.reshape(b * s, -1)[at]}
     if cfg.n_experts_here < cfg.num_experts:
         first = cfg.first_expert_here
-        aux["local_slots"] = jnp.sum(
-            loads[:, first:first + cfg.n_experts_here])
-    return total, {"losses": jnp.stack([total] + losses), "loads": loads,
-                   "rows": rows, "experts": chosen, "sample": sample, **aux}
+        with jax.named_scope("otpu_stats"):
+            aux["local_slots"] = jnp.sum(
+                loads[:, first:first + cfg.n_experts_here])
+    with jax.named_scope("otpu_stats"):
+        losses = jnp.stack([total] + losses)
+    return total, {"losses": losses, "loads": loads, "rows": rows,
+                   "experts": chosen, "sample": sample, **aux}
 
 
 def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
@@ -858,6 +875,9 @@ def bias_update(cfg: ModelConfig, bias, loads):
 
     mean = jnp.mean(loads, axis=-1, keepdims=True)
     return bias + cfg.bias_update_gamma * jnp.sign(mean - loads)
+
+
+_ran_steps = weakref.WeakSet()      # the model steps that ran, while held
 
 
 def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
@@ -899,23 +919,27 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
         local = jax.tree.map(
             lambda p: jax.lax.pcast(p, ("dp",), to="varying"), params)
         (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(local)
-        grads = jax.tree.map(lambda g: jax.lax.psum(g, "dp"), grads)
-        t = t + 1
-        tf = t.astype(jnp.float32)
+        with jax.named_scope("otpu_grad_sync"):
+            grads = jax.tree.map(lambda g: jax.lax.psum(g, "dp"), grads)
+        with jax.named_scope("otpu_adamw"):
+            t = t + 1
+            tf = t.astype(jnp.float32)
         new_p, new_m, new_v = {}, {}, {}
         sq, g_probe, p_probe = [], [], []
-        with jax.named_scope("otpu_adamw"):
-            for name, path in names:
-                g = _leaf(grads, path)
+        for name, path in names:
+            g = _leaf(grads, path)
+            with jax.named_scope("otpu_adamw"):
                 p, m, v = adamw(cfg, name, _leaf(params, path), g,
                                 _leaf(mom, path), _leaf(var, path), tf)
-                for tree, leaf in ((new_p, p), (new_m, m), (new_v, v)):
-                    _set_leaf(tree, path, leaf)
+            for tree, leaf in ((new_p, p), (new_m, m), (new_v, v)):
+                _set_leaf(tree, path, leaf)
+            with jax.named_scope("otpu_stats"):
                 sq.append(jnp.sum(g * g))
                 g_probe.append(g[probes[name]])
                 p_probe.append(p[probes[name]])
-        aux.update(grad_sq=jnp.stack(sq), grad_probe=jnp.stack(g_probe),
-                   param_probe=jnp.stack(p_probe))
+        with jax.named_scope("otpu_stats"):
+            aux.update(grad_sq=jnp.stack(sq), grad_probe=jnp.stack(g_probe),
+                       param_probe=jnp.stack(p_probe))
         if biased:
             # DeepSeek-V3's auxiliary-loss-free balancing: after the
             # step an expert that took more than the mean of the whole
@@ -951,6 +975,7 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
     slots = n_global * cfg.num_experts_per_tok * cfg.n_routers
     trace.bind_profiler()
     count = [0]
+    avals = []          # the first call's arguments, as shapes: scopes()
 
     def step(state, tokens, labels):
         """One optimiser step: ``(state, aux)``; ``state`` is donated.
@@ -968,6 +993,11 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
             # the first call traces, lowers and compiles (or loads the
             # cached program): counted as every device program's is
             t0 = time.perf_counter()
+            avals.append(jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding),
+                (state, tokens, labels)))
+            _ran_steps.add(step)
             out = jitted(state, tokens, labels)
             spc.record("device_program_builds")
             spc.record("device_program_first_call_us",
@@ -979,7 +1009,24 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
                 return jitted(state, tokens, labels)
         return jitted(state, tokens, labels)
 
+    def scopes():
+        """Which instruction of the step's compiled program belongs to
+        which ``otpu_*`` scope and pass (``trace.scope_map`` of the
+        optimised HLO text; ``trace.STEP_SCOPES`` is the vocabulary).
+        Lowers and compiles the step again from the shapes of its first
+        call, which holds no buffer; JAX answers both from what the
+        first call left in memory, so no second program is loaded (on
+        the v5e 0.1 s for OLMoE's step and 0.8 s for JoyAI's 8,563
+        instructions, the device's bytes in use unchanged: PR 37).  For
+        a reader of a profiler's trace, after the steps it traced:
+        ``step()`` never calls it."""
+        if not avals:
+            raise RuntimeError("scopes(): the step has not run yet, so "
+                               "its arguments' shapes are not known")
+        return trace.scope_map(jitted.lower(*avals[0]).compile().as_text())
+
     step.jitted = jitted
+    step.scopes = scopes
 
     def place(params, tokens, labels):
         """``(state, tokens, labels)`` on the mesh: the state is the
@@ -1001,6 +1048,14 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
         return state, put(tokens, batch), put(labels, batch)
 
     return step, place
+
+
+def scopes_of_built_steps() -> list:
+    """``step.scopes()`` of every model step this process built, ran
+    and still holds: what a trace reader joins a device op to by the
+    program's name and the instruction's.  It reads a whole program's
+    text a step, so it is for after the measurement."""
+    return [step.scopes() for step in list(_ran_steps)]
 
 
 def record_step_stats(aux) -> int:

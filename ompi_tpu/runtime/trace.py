@@ -44,6 +44,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import re
 import threading
 import time
 from typing import Any, Optional
@@ -846,6 +847,236 @@ def skew_report(payloads: list) -> str:
             f"{name:<18}  {label:>5}  {len(durs):>5}  "
             f"{_percentile(durs, 0.50):>9.1f}  {_percentile(durs, 0.99):>9.1f}")
     return "\n".join(lines) + "\n"
+
+
+# -- a train step's device time by the program's own scopes --------------
+
+#: Every ``jax.named_scope`` the model train step (``parallel/train``,
+#: ``model``, ``moe``) wraps a part of itself in, outermost first as
+#: they nest.  JAX writes the open scopes into each HLO instruction's
+#: ``metadata={op_name=...}`` and XLA keeps the root's on the fusions it
+#: forms, so an op of a profiler's trace is joined to its scopes by the
+#: instruction's name (:func:`scope_map`).  A scope new to the step goes
+#: here too (``tests/test_train_scopes.py`` holds the sources to it);
+#: the benchmark's ``harness/scopes.json`` repeats the tuple.
+STEP_SCOPES = (
+    "otpu_embed",           # the token embedding's gather (and scatter)
+    "otpu_layers",          # the walk over the decoder layers: the scan,
+                            # its carries, the residual adds
+    "otpu_mla",             # a layer's latent attention sublayer
+    "otpu_attention",       # a layer's OLMoE attention sublayer
+    "otpu_attn_proj",       # inside either: norms, projections, RoPE,
+                            # the head split; what is left is the kernels
+    "otpu_dense_mlp",       # a dense layer's SwiGLU
+    "otpu_moe",             # a sparse layer's expert block, whole
+    "otpu_router",          # inside it: logits, scores, the choice
+    "otpu_dispatch",        # sorting the slots, counting them, gathering
+    "otpu_experts",         # the grouped matmuls
+    "otpu_combine",         # weighting the slots' outputs, adding them up
+    "otpu_shared_expert",   # the shared expert's SwiGLU
+    "otpu_cast",            # a parameter leaf cast (or transposed) for a
+                            # matmul, inside whichever part uses it
+    "otpu_head",            # final norm, blocked cross-entropy
+    "otpu_mtp",             # the next-next-token module, whole
+    "otpu_loss",            # the auxiliary losses, the loss's sums
+    "otpu_stats",           # what a step reports: sampled rows, gradient
+                            # sums of squares, probes
+    "otpu_grad_sync",       # the gradients' sum over the data axis
+    "otpu_adamw",           # the update of every trained leaf
+    "otpu_bias_update",     # the routers' balancing biases
+)
+#: the scopes whose ops are the optimiser's, whatever else their path says
+UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")
+#: an instruction's pass, by its path: see :func:`scope_of_path`
+PASSES = ("forward", "remat", "backward", "update")
+#: instructions that run nothing, and so are no op of a trace
+TRIVIAL_OPCODES = ("parameter", "constant", "tuple", "get-tuple-element",
+                   "bitcast")
+
+_SCOPE_NAME_RE = re.compile(r"otpu_\w+")
+_HLO_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION_RE = re.compile(
+    r"^\s+(ROOT )?%?([\w.\-]+) = .*? ([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS_RE = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_OPERAND_RE = re.compile(r"(?<![=\w])%([\w.\-]+)")
+#: what hands no scope on to a neighbour: it holds every scope's values
+_NO_INHERITANCE = ("tuple", "get-tuple-element", "parameter", "while",
+                   "call", "conditional")
+
+
+def scope_of_path(path: str, vocabulary=STEP_SCOPES) -> tuple:
+    """``(chain, pass, unknown)`` of one ``op_name`` path, such as
+    ``jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/
+    rematted_computation/otpu_mla/dot_general``: the ``vocabulary``
+    names among its components in order, also inside ``jvp(...)`` and
+    ``transpose(jvp(...))`` (not a ``jit(...)``'s own name, nor a Pallas
+    kernel's before its ``pallas_call``);
+    ``update`` under one of ``UPDATE_SCOPES``, else ``remat`` where the
+    path holds ``rematted_computation`` (a checkpointed layer's forward
+    pass run again in its backward pass), else ``backward`` where it
+    holds ``transpose(``, else ``forward`` (which so means "neither of
+    the three": the first forward pass, and what a step computes beside
+    its gradients); and the ``otpu_*`` components that are no
+    vocabulary name."""
+    chain, unknown = [], []
+    parts = path.split("/")
+    for part, after in zip(parts, parts[1:] + [""]):
+        # a jit's own name, and a Pallas kernel's (the component before
+        # its pallas_call), are no scopes
+        if part.startswith(("jit(", "pjit(")) \
+                or after.startswith("pallas_call"):
+            continue
+        for name in _SCOPE_NAME_RE.findall(part):
+            # a checkpointed layer's recomputation repeats the scopes it
+            # was traced under: once is enough
+            if name not in chain:
+                (chain if name in vocabulary else unknown).append(name)
+    if any(name in UPDATE_SCOPES for name in chain):
+        which = "update"
+    elif "rematted_computation" in path:
+        which = "remat"
+    elif "transpose(" in path:
+        which = "backward"
+    else:
+        which = "forward"
+    return chain, which, unknown
+
+
+def scope_map(compiled_text: str, vocabulary=STEP_SCOPES) -> dict:
+    """Which instruction of a compiled program belongs to which scope:
+    ``{"module": name, "ops": {instruction: {"chain": [...], "pass":
+    ..., "mixed": bool, "inherited": bool, "opcode": ..., "kinds":
+    [...] where mixed}}, "unknown": [...]}`` from the optimised HLO text
+    (``jitted.lower(...).compile().as_text()``).
+
+    ``chain`` and ``pass`` are :func:`scope_of_path` of the
+    instruction's ``op_name``.  A fusion carries its root's, and is
+    ``mixed`` where the instructions of its fused computation (those
+    that run something and have an ``op_name``; a fusion inside it
+    counts by its own) carry more than one innermost scope or more than
+    one pass (``kinds`` lists them as ``scope:pass``): its seconds are
+    booked to the root's scope and partly belong elsewhere.  The instructions inside fused computations are
+    not listed: a trace has one op a fusion.
+
+    The compiler makes instructions of its own, with no ``op_name`` or
+    one that has lost its scopes: copies for a layout, a parameter's
+    cast hoisted out of a loop, the TPU's ``ragged-dot`` custom call
+    (its ``op_name`` is its own name).  Such an instruction is
+    ``inherited``: it takes the chain most of its neighbours have
+    (operands and users that have one, reached through other such
+    instructions but not through a tuple, a loop or a call; an
+    ``otpu_cast`` at a neighbour's end left off) and, having no path at
+    all, the pass of its latest operand, or of its earliest user where
+    no operand has one.  One that finds no neighbour keeps an empty
+    chain, and ``pass`` None if it has no path.  ``unknown``: ``otpu_*`` path components outside the
+    vocabulary.  A pure function of the text."""
+    module = _HLO_MODULE_RE.search(compiled_text)
+    computations: dict = {}     # name -> [(instruction, opcode, op_name,
+    current = None              #           called, is root, operands)]
+    for line in compiled_text.splitlines():
+        if current is None:
+            m = _HLO_COMPUTATION_RE.match(line)
+            if m:
+                current = computations.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _HLO_INSTRUCTION_RE.match(line)
+        if not m:
+            continue
+        path = _HLO_OP_NAME_RE.search(line)
+        called = _HLO_CALLS_RE.search(line)
+        # a path of the program's starts at its jit: "jit(f)/..."; the
+        # compiler's own ("ragged-dot-none") counts as none
+        current.append((m.group(2), m.group(3),
+                        path.group(1) if path and "/" in path.group(1)
+                        else "",
+                        called.group(1) if called else None,
+                        bool(m.group(1)),
+                        _HLO_OPERAND_RE.findall(line[m.end():])))
+
+    def leaves(name):
+        """(opcode, path, is root) of what a fused computation runs, a
+        fusion nested in it replaced by its own."""
+        for _, opcode, path, called, root, _ in computations.get(name, ()):
+            if opcode == "fusion" and not path:
+                yield from ((o, p, root and r) for o, p, r in leaves(called))
+            else:
+                yield opcode, path, root
+
+    # a fusion's computation, and one applied element by element (a
+    # reduction's, a sort's comparison), run inside their caller's op
+    inside = {called for rows in computations.values()
+              for _, opcode, _, called, _, _ in rows
+              if called and opcode != "call"}
+    ops: dict = {}
+    unknown: set = set()
+    operands: dict = {}
+    users: dict = {}
+    for name, rows in computations.items():
+        if name in inside:
+            continue
+        for instruction, opcode, path, called, _, reads in rows:
+            inner = list(leaves(called)) if opcode == "fusion" else ()
+            if not path:        # a fusion without metadata: its root's
+                path = next((p for _, p, root in inner if root), "")
+            chain, which, other = scope_of_path(path, vocabulary)
+            unknown.update(other)
+            kinds = set()
+            for o, p, _ in inner:
+                if p and o not in TRIVIAL_OPCODES:
+                    c, w, _ = scope_of_path(p, vocabulary)
+                    kinds.add((c[-1] if c else None, w))
+            ops[instruction] = {"chain": chain,
+                                "pass": which if path else None,
+                                "mixed": len(kinds) > 1, "inherited": False,
+                                "opcode": opcode}
+            if len(kinds) > 1:      # what it mixes: "scope:pass", sorted
+                ops[instruction]["kinds"] = sorted(
+                    f"{c or '-'}:{w}" for c, w in kinds)
+            operands[instruction] = reads
+            for read in reads:
+                users.setdefault(read, []).append(instruction)
+    # the compiler's own instructions take their neighbours' scopes, a
+    # ring of neighbours a round
+    def named(names):
+        return [ops[n] for n in names if n in ops and ops[n]["chain"]
+                and ops[n]["opcode"] not in _NO_INHERITANCE]
+
+    def without_cast(chain):    # what reads a cast parameter is no cast
+        return tuple(chain[:-1] if chain[-1] == "otpu_cast" else chain)
+
+    in_order = (None,) + PASSES
+    lost = [i for i, e in ops.items()
+            if not e["chain"] and e["opcode"] not in _NO_INHERITANCE]
+    while lost:
+        found = {}
+        for i in lost:
+            reads, read_by = named(operands[i]), named(users.get(i, ()))
+            if not reads and not read_by:
+                continue
+            # the chain most of its neighbours have (the first of equals,
+            # operands before users); the pass of its latest operand, an
+            # instruction running no earlier, or of its earliest user
+            votes: dict = {}
+            for near in reads + read_by:
+                key = without_cast(near["chain"])
+                votes[key] = votes.get(key, 0) + 1
+            passes = [in_order.index(near["pass"])
+                      for near in reads or read_by]
+            found[i] = (list(max(votes, key=votes.get)),
+                        in_order[max(passes) if reads else min(passes)])
+        if not found:
+            break
+        for i, (chain, which) in found.items():
+            ops[i].update(chain=chain, inherited=True,
+                          **({} if ops[i]["pass"] else {"pass": which}))
+        lost = [i for i in lost if i not in found]
+    return {"module": module.group(1) if module else "", "ops": ops,
+            "unknown": sorted(unknown)}
 
 
 def reset_for_testing() -> None:

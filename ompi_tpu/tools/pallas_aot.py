@@ -20,6 +20,7 @@ Run: ``python -m ompi_tpu.tools.pallas_aot --out PALLAS_AOT.json``
 from __future__ import annotations
 
 import json
+import os
 import sys
 import time
 
@@ -403,8 +404,6 @@ def cases(mesh1d, mesh2d):
     # matmuls, backward and AdamW (OLMoE-1B-7B, one layer of 16;
     # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment)
     def model_step(devices, config):
-        import os
-
         from ompi_tpu.parallel.mesh import MeshSpec
 
         mesh, spec = make_mesh(devices, MeshSpec())
@@ -469,7 +468,12 @@ def entry_ops(compiled) -> dict:
 
 
 def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
-        verbose: bool = True) -> dict:
+        verbose: bool = True, dump: str | None = None) -> dict:
+    """Compile every case (those whose name holds ``only``); with
+    ``dump`` also write each compiled module's optimised HLO text to
+    ``<dump>/<case>.hlo.txt`` (what ``runtime/trace.scope_map`` reads;
+    two trees' texts with ``metadata={...}`` stripped say whether a
+    change moved any instruction)."""
     t0 = time.time()
     try:
         mesh1d, mesh2d = build_meshes(topology)
@@ -494,6 +498,11 @@ def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
             row["compiled"] = True
             row["compile_s"] = round(time.time() - ts, 2)
             row["entry_ops"] = entry_ops(compiled)
+            if dump:
+                os.makedirs(dump, exist_ok=True)
+                with open(os.path.join(dump, name + ".hlo.txt"), "w",
+                          encoding="utf-8") as f:
+                    f.write(compiled.as_text())
             try:
                 mem = compiled.memory_analysis()
                 row["peak_vmem_bytes"] = int(
@@ -526,8 +535,10 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="write JSON here")
     ap.add_argument("--only", default=None,
                     help="substring filter on kernel names")
+    ap.add_argument("--dump", default=None,
+                    help="write each compiled module's HLO text here")
     args = ap.parse_args(argv)
-    res = run(args.topology, args.only)
+    res = run(args.topology, args.only, dump=args.dump)
     text = json.dumps(res, indent=1)
     if args.out:
         with open(args.out, "w") as f:

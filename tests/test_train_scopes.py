@@ -1,0 +1,331 @@
+"""A train step's instructions by the program's own scopes
+(``runtime/trace.scope_map``, ``step.scopes()``): the classification of an
+``op_name`` path, the parse of an optimised HLO text (fusions, nested
+fusions, the compiler's own instructions and what they inherit), and the
+two model steps at the small widths of ``test_joyai_train`` and
+``test_olmoe_train`` compiled on the CPU: only vocabulary scopes, every
+instruction the program wrote lies under one, a checkpointed layer's
+recomputation is told from its first forward pass, the update is the
+update; and the scopes move no computation."""
+import contextlib
+import dataclasses
+import json
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+from test_joyai_train import F32 as JOYAI
+from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
+
+from ompi_tpu.parallel import train
+from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+from ompi_tpu.runtime import trace
+from ompi_tpu.tools import hlo_same
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OLMOE = dataclasses.replace(OLMOE_TWO_LAYERS, layers_here=1)  # as the cell
+
+# the paths ISSUE 37 read off a compiled step, and what each is
+PATHS = [
+    ("jit(step)/jvp()/while/body/closed_call/otpu_moe/otpu_experts/"
+     "dot_general", ["otpu_moe", "otpu_experts"], "forward"),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/otpu_mla/dot_general", ["otpu_mla"], "remat"),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "otpu_moe/otpu_experts/transpose", ["otpu_moe", "otpu_experts"],
+     "backward"),
+    ("jit(step)/jvp(otpu_head)/reduce_sum", ["otpu_head"], "forward"),
+    ("jit(step)/transpose(jvp(otpu_head))/mul", ["otpu_head"], "backward"),
+    ("jit(step)/otpu_adamw/sub", ["otpu_adamw"], "update"),
+    # a scope repeated by a checkpoint's recomputation counts once; the
+    # jit's own name is no scope; an update scope wins over transpose(
+    ("jit(otpu_train_step)/transpose(jvp(otpu_layers))/while/body/"
+     "checkpoint/rematted_computation/otpu_layers/otpu_mla/otpu_attn_proj/"
+     "mul", ["otpu_layers", "otpu_mla", "otpu_attn_proj"], "remat"),
+    ("jit(f)/transpose(jvp(otpu_bias_update))/sign", ["otpu_bias_update"],
+     "update"),
+    # a Pallas kernel's own name, before its pallas_call, is no scope
+    ("jit(otpu_train_step)/jvp(otpu_layers)/while/body/closed_call/otpu_mla/"
+     "jit(_update_pallas)/otpu_flash_block_update/pallas_call",
+     ["otpu_layers", "otpu_mla"], "forward"),
+]
+
+
+@pytest.mark.parametrize("path,chain,which", PATHS,
+                         ids=[p[0].split("/", 1)[1][:60] for p in PATHS])
+def test_a_path_gives_its_scopes_and_its_pass(path, chain, which):
+    assert trace.scope_of_path(path) == (chain, which, [])
+    text = ("HloModule jit_step, is_scheduled=true\n\n"
+            "ENTRY %main.1 (p: f32[4]) -> f32[4] {\n"
+            "  %p = f32[4]{0} parameter(0)\n"
+            f"  ROOT %neg.1 = f32[4]{{0}} negate(%p), metadata={{op_name="
+            f"\"{path}\" source_file=\"x.py\" source_line=1}}\n}}\n")
+    got = trace.scope_map(text)
+    assert got["module"] == "jit_step"
+    assert got["ops"]["neg.1"] == {
+        "chain": chain, "pass": which, "mixed": False, "inherited": False,
+        "opcode": "negate"}
+
+
+def test_a_name_outside_the_vocabulary_is_reported_not_kept():
+    chain, which, unknown = trace.scope_of_path(
+        "jit(f)/jvp(otpu_head)/otpu_new_part/add")
+    assert (chain, which, unknown) == (["otpu_head"], "forward",
+                                       ["otpu_new_part"])
+
+
+# what the TPU compiler makes of a step, in small: a fusion that carries
+# its root's path, one that mixes two scopes, a fusion of fusions without
+# metadata, a reduction's computation (no op of its own), the compiler's
+# own convert and ragged-dot (which inherit), a copy that feeds a tuple
+# only (which does not)
+M = 'metadata={op_name="jit(f)/%s" source_file="x.py" source_line=1}'
+TEXT = f"""HloModule jit_f, is_scheduled=true, entry_computation_layout={{()->f32[]}}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {{
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.9 = f32[] add(%a, %b), {M % "jvp(otpu_head)/reduce_sum"}
+}}
+
+%fused_computation.1 (param_0: f32[8]) -> f32[8] {{
+  %param_0 = f32[8]{{0}} parameter(0)
+  %mul.1 = f32[8]{{0}} multiply(%param_0, %param_0), {M % "jvp(otpu_layers)/otpu_mla/otpu_attn_proj/mul"}
+  ROOT %exp.1 = f32[8]{{0}} exponential(%mul.1), {M % "jvp(otpu_layers)/otpu_mla/otpu_attn_proj/exp"}
+}}
+
+%fused_computation.2 (param_0.1: f32[8]) -> f32[8] {{
+  %param_0.1 = f32[8]{{0}} parameter(0)
+  %sub.1 = f32[8]{{0}} subtract(%param_0.1, %param_0.1), {M % "otpu_stats/sub"}
+  ROOT %add.1 = f32[8]{{0}} add(%sub.1, %param_0.1), {M % "otpu_adamw/add"}
+}}
+
+%fused_computation.4 (param_0.3: f32[8]) -> f32[8] {{
+  %param_0.3 = f32[8]{{0}} parameter(0)
+  ROOT %neg.4 = f32[8]{{0}} negate(%param_0.3), {M % "transpose(jvp(otpu_layers))/otpu_moe/otpu_router/neg"}
+}}
+
+%fused_computation.3 (param_0.2: f32[8]) -> f32[8] {{
+  %param_0.2 = f32[8]{{0}} parameter(0)
+  %fusion.5 = f32[8]{{0}} fusion(%param_0.2), kind=kLoop, calls=%fused_computation.4
+  ROOT %fusion.6 = f32[8]{{0:T(8)S(1)}} fusion(%fusion.5), kind=kCustom, calls=%fused_computation.4
+}}
+
+ENTRY %main.2 (w: f32[8], x: f32[8]) -> (f32[8], f32[], f32[8]) {{
+  %w = f32[8]{{0}} parameter(0)
+  %x = f32[8]{{0:T(8)S(1)}} parameter(1)
+  %convert.7 = bf16[8]{{0}} convert(%w)
+  %ragged-dot-none.3 = f32[8]{{0}} custom-call(%x, %convert.7), custom_call_target="ragged-dot", metadata={{op_name="ragged-dot-none"}}
+  %fusion.1 = f32[8]{{0}} fusion(%ragged-dot-none.3), kind=kLoop, calls=%fused_computation.1, {M % "jvp(otpu_layers)/otpu_mla/otpu_attn_proj/exp"}
+  %fusion.2 = f32[8]{{0}} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2, {M % "otpu_adamw/add"}
+  %fusion.3 = f32[8]{{0:T(8)S(1)}} fusion(%fusion.2), kind=kCustom, calls=%fused_computation.3
+  %reduce.1 = f32[] reduce(%fusion.3, %x), dimensions={{0}}, to_apply=%region_0.1, {M % "jvp(otpu_head)/reduce_sum"}
+  %copy.8 = f32[8]{{0}} copy(%w)
+  ROOT %tuple.1 = (f32[8]{{0}}, f32[], f32[8]{{0}}) tuple(%fusion.2, %reduce.1, %copy.8)
+}}
+"""
+
+
+@pytest.fixture(scope="module")
+def parsed():
+    return trace.scope_map(TEXT)
+
+
+def test_only_what_runs_as_an_op_is_listed(parsed):
+    assert parsed["module"] == "jit_f" and parsed["unknown"] == []
+    assert sorted(parsed["ops"]) == [
+        "convert.7", "copy.8", "fusion.1", "fusion.2", "fusion.3",
+        "ragged-dot-none.3", "reduce.1", "tuple.1", "w", "x"]
+
+
+def test_a_fusion_carries_its_roots_scopes(parsed):
+    one = parsed["ops"]["fusion.1"]
+    assert one["chain"] == ["otpu_layers", "otpu_mla", "otpu_attn_proj"]
+    assert (one["pass"], one["mixed"], one["opcode"]) == (
+        "forward", False, "fusion")
+
+
+def test_a_fusion_of_two_scopes_is_mixed_and_says_of_which(parsed):
+    two = parsed["ops"]["fusion.2"]
+    assert (two["chain"], two["pass"], two["mixed"]) == (
+        ["otpu_adamw"], "update", True)
+    assert two["kinds"] == ["otpu_adamw:update", "otpu_stats:forward"]
+
+
+def test_a_fusion_of_fusions_without_metadata_takes_its_inner_roots(parsed):
+    three = parsed["ops"]["fusion.3"]
+    assert three["chain"] == ["otpu_layers", "otpu_moe", "otpu_router"]
+    assert (three["pass"], three["mixed"], three["inherited"]) == (
+        "backward", False, False)
+
+
+def test_the_compilers_own_instructions_inherit_from_their_users(parsed):
+    dot, cast = parsed["ops"]["ragged-dot-none.3"], parsed["ops"]["convert.7"]
+    # its own op_name is not a path of the program's: chain and pass come
+    # from its user, fusion.1; the cast's from the dot it feeds
+    for op in (dot, cast):
+        assert op["chain"] == ["otpu_layers", "otpu_mla", "otpu_attn_proj"]
+        assert (op["pass"], op["inherited"]) == ("forward", True)
+
+
+def test_nothing_is_inherited_through_a_tuple(parsed):
+    copy = parsed["ops"]["copy.8"]
+    assert (copy["chain"], copy["pass"], copy["inherited"]) == (
+        [], None, False)
+
+
+# -- the two model steps, compiled on the CPU at small widths ---------------
+def built(cfg):
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=cfg)
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_rows, (cfg.micro_batch, cfg.seq_len + 2)).astype(
+            np.int32)
+    n = cfg.seq_len + cfg.num_nextn_predict_layers
+    return step, place(train.init_model_params(cfg, 0),
+                       ids[:, :cfg.seq_len], ids[:, 1:1 + n])
+
+
+@pytest.fixture(scope="module")
+def joyai():
+    step, args = built(JOYAI)
+    with pytest.raises(RuntimeError, match="has not run"):
+        step.scopes()
+    before = len(train.scopes_of_built_steps())
+    step(*args)
+    del args            # the donated state: scopes() holds shapes only
+    return step, step.scopes(), before
+
+
+@pytest.fixture(scope="module")
+def olmoe():
+    step, args = built(OLMOE)
+    step(*args)
+    return step, step.scopes()
+
+
+def ran(scopes):
+    return {k: v for k, v in scopes["ops"].items()
+            if v["opcode"] not in trace.TRIVIAL_OPCODES}
+
+
+@pytest.mark.parametrize("which", ["joyai", "olmoe"])
+def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
+    scopes = request.getfixturevalue(which)[1]
+    assert scopes["module"] == "jit_otpu_train_step"
+    assert scopes["unknown"] == []
+    named = {s for v in scopes["ops"].values() for s in v["chain"]}
+    assert named <= set(trace.STEP_SCOPES)
+    assert {"otpu_embed", "otpu_layers", "otpu_attn_proj", "otpu_moe",
+            "otpu_router", "otpu_dispatch", "otpu_experts",
+            "otpu_head", "otpu_stats", "otpu_adamw"} <= named
+    assert {v["pass"] for v in scopes["ops"].values()} <= {
+        None, *trace.PASSES}
+
+
+@pytest.mark.parametrize("which", ["joyai", "olmoe"])
+def test_every_instruction_the_program_wrote_has_a_chain(which, request):
+    """Not a parameter, constant, tuple or bitcast, and with a path of
+    the program's (``pass`` None: the compiler's own, which on the CPU
+    are copies and rewritten reductions)."""
+    ops = ran(request.getfixturevalue(which)[1])
+    bare = [k for k, v in ops.items() if v["pass"] and not v["chain"]]
+    assert bare == []
+    assert sum(v["pass"] is not None for v in ops.values()) > len(ops) * 0.8
+
+
+def test_a_checkpointed_layers_recomputation_is_told_apart(joyai, olmoe):
+    """JoyAI's shape has more than one layer, so each is recomputed in
+    its backward pass: ops under ``rematted_computation``, in the layers'
+    own scopes.  One layer (the OLMoE cell's cut) checkpoints nothing."""
+    remat = [v for v in ran(joyai[1]).values() if v["pass"] == "remat"]
+    assert len(remat) > 50
+    assert all("otpu_layers" in v["chain"] for v in remat)
+    assert {"otpu_mla", "otpu_attn_proj", "otpu_moe", "otpu_dense_mlp"} <= {
+        s for v in remat for s in v["chain"]}
+    assert not [v for v in olmoe[1]["ops"].values() if v["pass"] == "remat"]
+    for _, scopes in (joyai[:2], olmoe):
+        passes = {v["pass"] for v in ran(scopes).values()}
+        assert {"forward", "backward", "update"} <= passes
+
+
+@pytest.mark.parametrize("which", ["joyai", "olmoe"])
+def test_the_updates_ops_are_the_update(which, request):
+    ops = ran(request.getfixturevalue(which)[1])
+    update = {k: v for k, v in ops.items() if v["pass"] == "update"}
+    assert len(update) >= 10
+    for v in ops.values():
+        under = bool({"otpu_adamw", "otpu_bias_update"} & set(v["chain"]))
+        assert under == (v["pass"] == "update") or v["inherited"]
+    if which == "joyai":
+        assert any("otpu_bias_update" in v["chain"] for v in update.values())
+
+
+def test_the_process_gives_the_maps_of_the_steps_it_ran(joyai):
+    step, scopes, before = joyai
+    maps = train.scopes_of_built_steps()
+    assert len(maps) > before       # the step that had not run was left out
+    assert scopes in maps
+    assert step.scopes() == scopes          # a pure function of the text
+
+
+def test_the_vocabulary_is_the_sources_and_the_benchmarks():
+    """Every ``named_scope`` the step's three files open is in
+    ``STEP_SCOPES``, every name of it is opened somewhere, and the
+    benchmark's data file repeats it."""
+    opened = set()
+    for name in ("train", "model", "moe"):
+        with open(os.path.join(ROOT, "ompi_tpu", "parallel", name + ".py"),
+                  encoding="utf-8") as f:
+            opened |= set(re.findall(r'named_scope\("(otpu_\w+)"\)',
+                                     f.read()))
+    assert opened == set(trace.STEP_SCOPES)
+    assert set(trace.UPDATE_SCOPES) <= opened
+    with open(os.path.join(ROOT, "benchmark", "harness", "scopes.json"),
+              encoding="utf-8") as f:
+        data = json.load(f)
+    assert data["scopes"] == list(trace.STEP_SCOPES)
+    assert data["passes"] == list(trace.PASSES)
+    assert data["update_scopes"] == list(trace.UPDATE_SCOPES)
+
+
+@pytest.mark.parametrize("cfg", [JOYAI, OLMOE], ids=["joyai", "olmoe"])
+def test_the_scopes_move_no_computation(cfg, monkeypatch):
+    """The step compiled with every ``named_scope`` a no-op is the same
+    program, instruction for instruction, once the metadata is gone
+    (``tools/hlo_same``: what PR 37 showed of both cells' steps offline,
+    for a v5e)."""
+    def text():
+        step, args = built(cfg)
+        return step.jitted.lower(*args).compile().as_text()
+
+    # the compile cache's key leaves the metadata out, so the second
+    # text would be the first's, loaded: compile both (JAX decides once
+    # a process whether it uses the cache, hence the resets)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        scoped = text()
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "otpu_adamw" in scoped and "otpu_adamw" not in bare
+    assert hlo_same.compare(scoped, bare).startswith("EQUAL")
+
+
+def test_hlo_same_tells_a_moved_instruction_from_a_renamed_one():
+    renamed = TEXT.replace("otpu_stats/sub", "otpu_loss/sub").replace(
+        "%mul.1", "%mul.77")
+    assert hlo_same.compare(TEXT, renamed) == "EQUAL but for instruction names"
+    assert hlo_same.compare(TEXT, TEXT.replace("x.py", "y.py")) == "EQUAL"
+    moved = TEXT.replace("subtract(%param_0.1, %param_0.1)",
+                         "multiply(%param_0.1, %param_0.1)")
+    assert moved != TEXT and hlo_same.compare(TEXT, moved) == "DIFFERENT"
