@@ -333,6 +333,14 @@ def kernels(clock: Clock, expect_interpret: bool = False,
     tol = 2e-2 if dt == jnp.bfloat16 else 1e-4
     twin = jax.jit(fa._update_jnp)
 
+    def close(label, g, w):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        _require(g.shape == w.shape and np.all(np.isfinite(g)),
+                 f"{label}: shape {g.shape} / non-finite")
+        err = float(np.max(np.abs(g - w)) / max(1.0, np.max(np.abs(w))))
+        _require(err <= tol, f"{label}: error {err:.3e} of max|ref| "
+                             f"exceeds {tol:g}")
+
     def check(name, kernel, args):
         up = tuple(a.astype(jnp.float32) for a in args)
         with jax.default_matmul_precision("highest"):
@@ -341,12 +349,7 @@ def kernels(clock: Clock, expect_interpret: bool = False,
         for _ in range(2):
             got = clock.call(compiled, *args, first=False)
         for part, g, w in zip(("m", "num", "den"), got, want):
-            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-            _require(g.shape == w.shape and np.all(np.isfinite(g)),
-                     f"{name}.{part}: shape {g.shape} / non-finite")
-            err = float(np.max(np.abs(g - w)) / max(1.0, np.max(np.abs(w))))
-            _require(err <= tol, f"{name}.{part}: error {err:.3e} of "
-                                 f"max|ref| exceeds {tol:g}")
+            close(f"{name}.{part}", g, w)
         print(f"  {name} {flash_shape} {dtype} matches its jnp twin",
               flush=True)
 
@@ -357,9 +360,10 @@ def kernels(clock: Clock, expect_interpret: bool = False,
           jax.jit(lambda *a: fa.flash_block_update_biased(*a)),
           (q, k, v, m0, num0, den0, bias))
 
-    # attention's backward: the fused block pair against its jnp twin, q
-    # and k as wide as v (128 / 128) and half as wide again (192 / 128),
-    # a plain pair and the diagonal one through one compiled kernel
+    # causal attention's two kernels against their jnp twins, q and k as
+    # wide as v (128 / 128) and half as wide again (192 / 128): the
+    # forward pass in one call; the backward's fused block pair, a plain
+    # pair and the diagonal one through one compiled kernel
     from ompi_tpu.parallel import model
 
     block, f32 = min(sq, 1024), jnp.float32
@@ -375,6 +379,14 @@ def kernels(clock: Clock, expect_interpret: bool = False,
             keys[4:], (wide, wide, d)))
         up = tuple(x.astype(f32) for x in (qb, kb, vb, dob))
         o, lse = model._causal_fwd_blocks(*up[:3], block, True)
+        fwd = f"flash_causal_forward {wide}/{d}"
+        got = clock.call(compile_checked(fwd, jax.jit(
+            lambda *a: fa.flash_causal_forward(*a, block=block)),
+            qb, kb, vb), qb, kb, vb, first=False)
+        for part, g, w in zip(("o", "lse"), got, (o, lse)):
+            close(f"{fwd}.{part}", g, w)
+        print(f"  {fwd} (block {block}) {dtype} matches its jnp twin",
+              flush=True)
         delta = jnp.sum(up[3] * o, axis=-1)
         args = (qb, kb, vb, dob, lse, delta) + acc
         compiled = compile_checked(name, jax.jit(
@@ -390,13 +402,8 @@ def kernels(clock: Clock, expect_interpret: bool = False,
                 1.0 / wide ** 0.5, f32)
             for part, g, a0, w, n in zip(("dq", "dk", "dv"), got, acc,
                                          want, (i, j, j)):
-                g = np.asarray(cut(g, n) - cut(a0, n), np.float32)
-                w = np.asarray(w, np.float32)
-                _require(np.all(np.isfinite(g)), f"{name}.{part}: non-finite")
-                err = float(np.max(np.abs(g - w)) / max(1.0,
-                                                        np.max(np.abs(w))))
-                _require(err <= tol, f"{name}.{part} pair {(i, j)}: error "
-                                     f"{err:.3e} of max|ref| exceeds {tol:g}")
+                close(f"{name}.{part} pair {(i, j)}",
+                      cut(g, n) - cut(a0, n), w)
         print(f"  {name} (block {block}) {dtype} matches its jnp twin",
               flush=True)
 

@@ -1,26 +1,35 @@
-"""Pallas flash-attention block kernel for ring attention.
+"""Pallas flash-attention kernels: ring attention's block update, and
+causal attention's forward pass and backward block pair.
 
 Ring attention (``ompi_tpu/parallel/model.py``) rotates K/V shards around
 the sequence-parallel mesh axis with ``ppermute`` and, per step, combines
 one K/V block into a running (max, numerator, denominator) softmax state.
 That per-step block combine is the FLOPs hot spot — two MXU matmuls plus
-the online-softmax rescale — and is what this kernel fuses: one VMEM
-round-trip instead of the five separate HBM-materialised intermediates
-(scores, max, probs, weighted-V, rescales) the jnp version produces.
+the online-softmax rescale — and is what ``flash_block_update[_biased]``
+fuses: one VMEM round-trip instead of the five separate HBM-materialised
+intermediates (scores, max, probs, weighted-V, rescales) the jnp version
+produces.  ``ring_attention`` is their only caller.
 
 The ring/communication structure stays at the JAX level (XLA schedules the
 ICI ppermute); only the local block math drops into Pallas — the same
 split the reference makes between its coll algorithms (schedules) and its
 op kernels (``ompi/mca/op/avx``).
 
-Grid: (batch*heads, q row tiles).  K/V blocks ride whole in VMEM (s_kv up
-to a few thousand at 128-lane alignment); scores compute at f32 on the
-MXU via ``preferred_element_type``.
+Block update, grid: (batch*heads, q row tiles).  K/V blocks ride whole in
+VMEM (s_kv up to a few thousand at 128-lane alignment); scores compute at
+f32 on the MXU via ``preferred_element_type``; the state passes through
+HBM between two calls, which the ring's ``ppermute`` between them needs.
 
-The causal train step's backward pass (``model.causal_flash_attention``)
-has its block pair here too: ``attn_block_backward``, the five matmuls of
-one (q block, kv block) pair fused the same way, with the float32
-gradient accumulators passed through the call in place.
+The causal train step (``model.causal_flash_attention``), which has all
+of K and V on the chip, runs these where Mosaic compiles (a TPU; the CPU
+runs the ``jnp`` twins in ``parallel/model``):
+
+- forward, ``flash_causal_forward``: one call a layer, q, k, v whole,
+  the blocks chosen by the index maps, the softmax state in VMEM scratch
+  from a q tile's first kv tile to its last;
+- backward, ``attn_block_backward``: the five matmuls of one (q block,
+  kv block) pair, with the float32 gradient accumulators passed through
+  the call in place.
 """
 from __future__ import annotations
 
@@ -344,3 +353,114 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
         name="otpu_attn_block_backward",
     )(*operands)
     return tuple(o.reshape(a.shape) for o, a in zip(out, (dq, dk, dv)))
+
+
+#: the causal forward kernel's tile: so many q positions a grid step
+#: against so many kv positions (a block of 1,024 is one tile).  On the
+#: chip 1,024 squared beats every other pair from 512 to 4,096 at both
+#: cells' shapes (PERF.md section 6, PR 38)
+FWD_TILE = 1024
+#: q, k, v and o tiles twice (the pipeline's two buffers), the float32
+#: numerator and a few (1024, 1024) float32 score arrays: as the backward
+FWD_VMEM_LIMIT = 64 << 20
+
+
+def _causal_fwd_kernel(scale, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_ref, den_ref, num_ref):
+    """q tile i against kv tile j of one (batch, head): one online-
+    softmax update of the running max, denominator and float32
+    numerator, which live in VMEM scratch from the row's first kv tile
+    (j = 0) to the diagonal one (j = i), where ``o`` and the logsumexp
+    are written.  A kv tile above the diagonal (j > i) does nothing.
+    Scores are held (q, kv): a row's statistics are columns, and the two
+    matmuls are the MXU's plain forms."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    tile = q_ref.shape[1]
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        den_ref[...] = jnp.zeros(den_ref.shape, jnp.float32)
+        num_ref[...] = jnp.zeros(num_ref.shape, jnp.float32)
+
+    def update(diagonal):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = dot(q, k, (((1,), (1,)), ((), ()))) * scale     # (q, kv)
+        if diagonal:
+            at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                       axis)
+            s = jnp.where(at(0) >= at(1), s, -jnp.inf)
+        # every row sees its tile's first kv position at least, so its
+        # running max is finite from the first update on
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        c = jnp.exp(m - new_m)
+        p = jnp.exp(s - new_m)
+        den_ref[...] = den_ref[...] * c + jnp.sum(p, axis=1, keepdims=True)
+        num_ref[...] = num_ref[...] * c + dot(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+        m_ref[...] = new_m
+
+    pl.when(j < i)(functools.partial(update, False))
+
+    @pl.when(j == i)
+    def _():
+        update(True)
+        o_ref[0] = num_ref[...] / den_ref[...]
+        # a row's logsumexp is a column here and a row where the backward
+        # reads it: one (tile, 128) transposition a q tile, no pass of
+        # o's size
+        lse = m_ref[...] + jnp.log(den_ref[...])
+        lse_ref[0] = jnp.broadcast_to(lse, (tile, 128)).T[:1]
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def flash_causal_forward(q, k, v, *, block: int, interpret=None):
+    """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
+    float32 and the logsumexp (b, h, s) float32 of q, k (b, h, s, d)
+    and v (b, h, s, dv), ``s`` a multiple of ``block``.
+
+    The arrays come whole; the grid is (b x h, q tiles, kv tiles), the
+    tile ``block`` or 1,024 positions, and the index maps pick the
+    tiles, a kv tile above the diagonal clamped to the diagonal's (the
+    one already there: not fetched again).  Scores, softmax state and
+    ``o`` in float32, ``p`` cast to v's dtype for ``p v``, scale
+    ``1 / sqrt(d)``; the diagonal tile is masked by position.  A width
+    that is no multiple of 128 lanes (192) is Mosaic's to lay out.  The
+    logsumexp leaves as (b x h, 1, s), the shape ``attn_block_backward``
+    reads.  The ``jnp`` twin is ``parallel/model._causal_fwd_blocks``.
+    """
+    if interpret is None:
+        interpret = pallas_interpret()
+    b, h, s, d = q.shape
+    hv = v.shape[-1]
+    bh = b * h
+    tile = _tile(block, FWD_TILE)
+    nt = s // tile
+
+    flat = lambda a: a.reshape(bh, s, a.shape[-1])
+    q_map = lambda g, i, j: (g, i, 0)
+    kv_map = lambda g, i, j: (g, jnp.minimum(i, j), 0)
+    kv_spec = lambda width: pl.BlockSpec((1, tile, width), kv_map)
+    operands = [flat(q), flat(k), flat(v)]
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    f32 = jnp.float32
+    o, lse = pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, 1.0 / math.sqrt(d)),
+        out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
+                   jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
+        grid=(bh, nt, nt),
+        in_specs=[pl.BlockSpec((1, tile, d), q_map), kv_spec(d), kv_spec(hv)],
+        out_specs=(pl.BlockSpec((1, tile, hv), q_map),
+                   pl.BlockSpec((1, 1, tile), lambda g, i, j: (g, 0, i))),
+        scratch_shapes=[pltpu.VMEM((tile, 1), f32), pltpu.VMEM((tile, 1), f32),
+                        pltpu.VMEM((tile, hv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=FWD_VMEM_LIMIT),
+        interpret=interpret,
+        name="otpu_flash_causal_forward",
+    )(*operands)
+    return o.reshape(b, h, s, hv), lse.reshape(b, h, s)

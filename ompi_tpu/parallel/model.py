@@ -326,17 +326,20 @@ def _contract(eq, a, b, compute_dtype):
 
 
 def _causal_fwd_blocks(q, k, v, block, interpret):
-    """Causal attention by blocks of ``block`` positions: q block i meets
-    kv blocks 0..i, the diagonal one under a triangular bias, each
-    through one online-softmax update (the fused Pallas kernel where
-    Mosaic compiles it, its jnp twin with float32 scores elsewhere).
-    The running max, numerator and denominator are float32 whatever
-    q, k, v are.  v, and so the numerator and o, may be of another width
-    than q and k (latent attention: 192 and 128).  Returns (o float32,
-    logsumexp float32)."""
-    from ompi_tpu.ops.flash_attention import (flash_block_update,
-                                              flash_block_update_biased)
+    """Causal attention's forward pass: (o float32, logsumexp float32)
+    of q, k (b, h, s, hd) and v (b, h, s, hv); v, and so the numerator
+    and o, may be of another width than q and k (latent attention: 192
+    and 128).  Where Mosaic compiles (``interpret`` false: a TPU) it is
+    one call of ``ops/flash_attention.flash_causal_forward``, which
+    takes the three whole.  Elsewhere (the CPU) it is the loop below,
+    that kernel's ``jnp`` twin: q block i meets kv blocks 0..i of
+    ``block`` positions, the diagonal one under a triangular bias, each
+    through one online-softmax update with float32 scores; the running
+    max, numerator and denominator are float32 whatever q, k, v are."""
+    if not interpret:
+        from ompi_tpu.ops.flash_attention import flash_causal_forward
 
+        return flash_causal_forward(q, k, v, block=block, interpret=False)
     b, h, s, hd = q.shape
     nb = s // block
     scale = 1.0 / math.sqrt(hd)
@@ -350,14 +353,6 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
         for j in range(i + 1):
             kj = k[:, :, j * block:(j + 1) * block]
             vj = v[:, :, j * block:(j + 1) * block]
-            if not interpret:
-                if j == i:
-                    m, num, den = flash_block_update_biased(
-                        qi, kj, vj, m, num, den, bias, False)
-                else:
-                    m, num, den = flash_block_update(qi, kj, vj, m, num,
-                                                     den, False)
-                continue
             sc = _contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
             if j == i:
                 sc = sc + bias
@@ -376,10 +371,14 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def causal_flash_attention(q, k, v, block: int, interpret: bool):
     """Causal self-attention of (b, h, s, hd) q, k and (b, h, s, hv) v
-    whose length is a multiple of ``block``.  Forward: ``_causal_fwd_blocks``.  Backward:
-    the flash backward by the same blocks (scores recomputed from q, k
-    and the saved logsumexp in float32; no (s, s) array is ever held),
-    its matmul inputs in q's dtype."""
+    whose length is a multiple of ``block``.  Forward:
+    ``_causal_fwd_blocks`` (on a TPU one kernel call, the blocks chosen
+    in its index maps; on the CPU a ``jnp`` loop over the blocks).
+    Backward: the flash backward by the same blocks (scores recomputed
+    from q, k and the saved logsumexp in float32; no (s, s) array is
+    ever held), its matmul inputs in q's dtype; on a TPU each block pair
+    one call of the fused kernel (``_causal_bwd_fused``), on the CPU
+    ``_bwd_pair``'s einsums."""
     return _causal_fwd_blocks(q, k, v, block, interpret)[0]
 
 

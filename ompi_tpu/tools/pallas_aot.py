@@ -235,9 +235,11 @@ def cases(mesh1d, mesh2d):
         {"interpret": False}))
     # the OLMoE train step's attention (``parallel/model
     # .causal_flash_attention``) at the benchmark cell's shape (2 x 16
-    # heads x 4,096 x 128, bfloat16, causal): ten block updates of 1,024
-    # positions, the four diagonal ones under their bias, and the two
-    # kernels alone.  The whole sequence as ONE biased update does not
+    # heads x 4,096 x 128, bfloat16, causal) through the model's own
+    # entry, which where Mosaic compiles is one call of
+    # ``flash_causal_forward`` (its own cases are below); and ring
+    # attention's block update alone at a pair of blocks of 1,024, plain
+    # and biased.  The whole sequence as ONE biased update does not
     # compile: K and V ride whole in VMEM beside a (256, 4096) float32
     # tile of scores and one of bias, and Mosaic refuses it
     # (RESOURCE_EXHAUSTED in vmem, offline for a v5e, PR 33)
@@ -300,6 +302,18 @@ def cases(mesh1d, mesh2d):
         return jax.jit(lambda q, k, v, o, lse, do: model._causal_bwd(
             1024, False, (q, k, v, o, lse), do)), (q, k, v, o, lse, o)
 
+    # attention's forward (``model._causal_fwd_blocks`` where Mosaic
+    # compiles): one call a layer, q, k and v whole, the blocks through
+    # the index maps, the softmax state in VMEM scratch
+    def flash_causal_forward(b, h, s, d, hv):
+        q, k, v = attn_bwd_args(b, h, s, d, hv)[:3]
+        return fa.flash_causal_forward, (q, k, v), {"block": 1024,
+                                                    "interpret": False}
+
+    case("olmoe_flash_causal_forward",
+         lambda: flash_causal_forward(2, 16, 4096, 128, 128))
+    case("joyai_flash_causal_forward",
+         lambda: flash_causal_forward(1, 32, 8192, 192, 128))
     case("olmoe_attn_block_backward_1k",
          lambda: attn_block_backward(2, 16, 4096, 128, 128))
     case("joyai_attn_block_backward_1k",

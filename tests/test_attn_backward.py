@@ -84,14 +84,10 @@ def kernels_interpreted(monkeypatch):
     """``causal_flash_attention(..., interpret=False)`` takes the kernels
     and asks Mosaic for them; here the same calls run the same kernels
     under the interpreter."""
-    for name in ("flash_block_update", "flash_block_update_biased"):
+    for name in ("flash_causal_forward", "attn_block_backward"):
         monkeypatch.setattr(
-            fa, name, lambda *a, _real=getattr(fa, name): _real(
-                *a[:-1], True))
-    monkeypatch.setattr(
-        fa, "attn_block_backward",
-        lambda *a, _real=fa.attn_block_backward, **kw: _real(
-            *a, **dict(kw, interpret=True)))
+            fa, name, lambda *a, _real=getattr(fa, name), **kw: _real(
+                *a, **dict(kw, interpret=True)))
 
 
 @pytest.mark.parametrize("nb", [2, 8], ids=["unrolled", "scanned"])

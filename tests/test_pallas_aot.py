@@ -138,16 +138,19 @@ def olmoe_rows():
 
 
 def test_olmoe_attention_aot_compiles_at_the_cells_shape(olmoe_rows):
-    """``flash_block_update[_biased]`` as the OLMoE step calls it: causal
-    attention of 2 x 16 heads x 4,096 x 128 in bfloat16 by blocks of
-    1,024 (ten updates, four of them under the triangular bias), and
-    each kernel alone at one pair of blocks."""
-    for name in ("olmoe_causal_attention_4k", "olmoe_flash_block_1k",
-                 "olmoe_flash_block_1k_biased"):
+    """Causal attention's forward pass as the OLMoE step calls it, 2 x
+    16 heads x 4,096 x 128 in bfloat16: through the model's entry and
+    alone it is one kernel call that takes q, k and v whole (no slice,
+    no concatenate beside it); and ``flash_block_update[_biased]``, ring
+    attention's, each alone at one pair of blocks of 1,024."""
+    for name in ("olmoe_causal_attention_4k", "olmoe_flash_causal_forward",
+                 "olmoe_flash_block_1k", "olmoe_flash_block_1k_biased"):
         assert olmoe_rows[name].get("compiled"), json.dumps(
             olmoe_rows[name], indent=1)
-    assert olmoe_rows["olmoe_causal_attention_4k"]["entry_ops"][
-        "custom-call"] >= 10
+    for name in ("olmoe_causal_attention_4k", "olmoe_flash_causal_forward"):
+        ops = olmoe_rows[name]["entry_ops"]
+        assert ops["custom-call"] == 1, ops
+        assert not {"slice", "concatenate", "fusion"} & set(ops), ops
 
 
 def test_olmoe_attention_backward_aot_compiles_at_the_cells_shape(
@@ -168,9 +171,9 @@ def test_olmoe_attention_backward_aot_compiles_at_the_cells_shape(
 def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
         olmoe_rows):
     """The whole step of ``benchmark/configs/olmoe-1b-7b-train-1chip
-    .json`` (published widths, one layer): the flash kernel ten times
-    forward and the backward's block pair ten times, nine grouped
-    expert matmuls, one loop over the head's row blocks."""
+    .json`` (published widths, one layer): attention's forward kernel
+    once and the backward's block pair ten times, nine grouped expert
+    matmuls, one loop over the head's row blocks."""
     row = olmoe_rows["olmoe_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["custom-call"] >= 29
@@ -197,6 +200,15 @@ def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
         row = joyai_rows[name]
         assert row.get("compiled"), json.dumps(row, indent=1)
         assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
+
+
+def test_attention_forward_aot_compiles_at_192_and_128(joyai_rows):
+    """The forward pass in one call with q and k 192 wide and v 128 (1 x
+    32 heads x 8,192): one kernel, the 192 lanes Mosaic's to lay out."""
+    row = joyai_rows["joyai_flash_causal_forward"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
+    assert not {"slice", "concatenate"} & set(row["entry_ops"])
 
 
 def test_attention_backward_aot_compiles_at_192_and_128(joyai_rows):

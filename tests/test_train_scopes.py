@@ -52,6 +52,10 @@ PATHS = [
     ("jit(otpu_train_step)/jvp(otpu_layers)/while/body/closed_call/otpu_mla/"
      "jit(_update_pallas)/otpu_flash_block_update/pallas_call",
      ["otpu_layers", "otpu_mla"], "forward"),
+    ("jit(otpu_train_step)/transpose(jvp(otpu_layers))/while/body/"
+     "checkpoint/rematted_computation/otpu_layers/otpu_mla/"
+     "jit(flash_causal_forward)/otpu_flash_causal_forward/pallas_call",
+     ["otpu_layers", "otpu_mla"], "remat"),
 ]
 
 
