@@ -34,6 +34,7 @@ MODEL_SCALE = 64            # d 512, head dim 256
 PRIMARY_BYTES = 16 << 20    # BASELINE.json's headline: f32 allreduce a rank
 SPOT_BYTES = 4 << 20
 FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bf16
+ROPE_SHAPE = (1, 8192, 32, 1536)        # b, s, heads, the latent's rank
 # each loss is taken BEFORE its update: four losses observe three
 # updates, and every one of them must have lowered the loss
 TRAIN_STEPS = 4
@@ -295,7 +296,7 @@ def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
 # -- 5. the kernels that path selects, against their XLA twins -------------
 def kernels(clock: Clock, expect_interpret: bool = False,
             flash_shape=FLASH_SHAPE, dtype: str = "bfloat16",
-            reduce_elems: int = 1 << 20) -> None:
+            reduce_elems: int = 1 << 20, rope_shape=ROPE_SHAPE) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -406,6 +407,28 @@ def kernels(clock: Clock, expect_interpret: bool = False,
                       cut(g, n) - cut(a0, n), w)
         print(f"  {name} (block {block}) {dtype} matches its jnp twin",
               flush=True)
+
+    # latent attention's q from its projection with RoPE on, the partner
+    # a product of its own and no rolled copy (``model.project_rope``; no
+    # kernel: XLA's fusions), against ``rope_interleaved`` of the same
+    # product in float32 at full precision, heads 192 wide, 128 unrotated
+    rb, rs, rh, rank = rope_shape
+    wide, theta = d * 3 // 2, 32e6
+    a = jax.random.normal(kq, (rb, rs, rank), dt)
+    w = jax.random.normal(kk, (rank, rh * wide), f32) / rank ** 0.5
+    got = clock.call(jax.jit(lambda a, w: model.project_rope(
+        a, w, rh, d, theta, dt)), a, w, first=True)
+    _require(got.dtype == f32, f"project_rope gives {got.dtype}")
+    want = jax.jit(lambda a, w: model.rope_interleaved(
+        model.matmul(a, w.astype(dt), f32, weight=False).reshape(
+            rb, rs, rh, wide), theta, d, 1))(a, w)
+    g, want = np.asarray(got), np.asarray(want)
+    err = float(np.max(np.abs(g - want)) / np.max(np.abs(want)))
+    _require(g.shape == want.shape and err <= 1e-5,
+             f"project_rope {g.shape}: error {err:.3e} of max|ref| "
+             "exceeds 1e-05")
+    print(f"  project_rope {g.shape} {dtype} matches rope_interleaved in "
+          f"float32 ({err:.2e} of max|ref|)", flush=True)
 
     a = jax.random.normal(kq, (reduce_elems,), jnp.float32)
     bb = jax.random.normal(kk, (reduce_elems,), jnp.float32)

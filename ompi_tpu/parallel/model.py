@@ -526,15 +526,10 @@ def olmoe_attention(p, x, cfg, *, interpret: bool):
         return x + matmul(o, p["wo"], dt)
 
 
-def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
-    """Rotary position embedding on interleaved pairs (x[2i], x[2i+1])
-    (DeepSeek-V3's ``rope_interleave``) of the entries from ``first`` on
-    of ``x``'s last axis, at positions 0..s-1 along ``seq_axis``; the
-    entries before ``first`` pass unchanged.  One elementwise pass over
-    the whole width (the pair's partner comes by a lane rotation), so a
-    head's rotary tail is neither cut off nor put back: the strided
-    halves of a 64-wide tail each pad to 128 lanes, four times their
-    size (2 GiB of them in the JoyAI step, offline compile, PR 35)."""
+def _rope_tables(x, theta: float, first: int, seq_axis: int):
+    """(cos, sin) of ``rope_interleaved``, shaped to broadcast against
+    ``x``: a pair's angle on both its entries, 1 and 0 on the entries
+    before ``first``."""
     width, s = x.shape[-1], x.shape[seq_axis]
     hd = width - first
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -543,11 +538,71 @@ def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
         [jnp.full((s, first), fill, jnp.float32), jnp.repeat(a, 2, -1)], -1)
     shape = [1] * x.ndim
     shape[seq_axis], shape[-1] = s, width
-    cos = pad(jnp.cos(ang), 1.0).reshape(shape)
-    sin = pad(jnp.sin(ang), 0.0).reshape(shape)
-    is_first = (jnp.arange(width) - first) % 2 == 0
+    return (pad(jnp.cos(ang), 1.0).reshape(shape),
+            pad(jnp.sin(ang), 0.0).reshape(shape))
+
+
+def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
+    """Rotary position embedding on interleaved pairs (x[2i], x[2i+1])
+    (DeepSeek-V3's ``rope_interleave``) of the entries from ``first`` on
+    of ``x``'s last axis, at positions 0..s-1 along ``seq_axis``; the
+    entries before ``first`` pass unchanged.  The pair's partner comes
+    by ``jnp.roll``, which XLA for a TPU writes to HBM as shifted copies
+    (a 191-wide and a one-lane slice each way, the lane padded to 128:
+    2.6 GB a layer and pass of the JoyAI step for q's 268 MB, offline
+    compile, PR 41), so the model no longer takes this way
+    (``project_rope``): it is the ``jnp`` twin the tests compare that
+    with."""
+    cos, sin = _rope_tables(x, theta, first, seq_axis)
+    is_first = (jnp.arange(x.shape[-1]) - first) % 2 == 0
     partner = jnp.where(is_first, -jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
     return x * cos + partner * sin
+
+
+def rotary_partner_columns(w, compute_dtype):
+    """The columns ``wp`` of interleaved rotary columns ``w`` (.., rot)
+    with ``a @ wp`` the rotary partner of ``a @ w``: ``wp[:, 2i] =
+    -w[:, 2i+1]``, ``wp[:, 2i+1] = w[:, 2i]``.  A product with a signed
+    permutation (each result one input times 1 or -1: exact in any
+    dtype), because a swap of neighbouring columns any other way is a
+    lane rotation or an array two lanes wide."""
+    rot = w.shape[-1]
+    i = jnp.arange(0, rot, 2)
+    swap = jnp.zeros((rot, rot), jnp.float32) \
+        .at[i, i + 1].set(1.0).at[i + 1, i].set(-1.0)
+    return matmul(w, swap, compute_dtype, weight=False).astype(w.dtype)
+
+
+def rope_partnered(x, partner, theta: float, seq_axis: int = -2):
+    """``rope_interleaved`` of ``x`` on its trailing ``partner.shape[-1]``
+    entries, given their partners (``partner[2i] = -x[2i+1]``,
+    ``partner[2i+1] = x[2i]``, counted from the first rotary entry): one
+    elementwise pass, the partner set behind the leading entries by a pad
+    (where those are a multiple of 128 lanes, as a latent head's are, it
+    starts a tile of its own)."""
+    first = x.shape[-1] - partner.shape[-1]
+    cos, sin = _rope_tables(x, theta, first, seq_axis)
+    partner = jnp.pad(partner, ((0, 0),) * (x.ndim - 1) + ((first, 0),))
+    return x * cos + partner * sin
+
+
+def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
+    """``a @ w`` (b, s, heads x width) split into ``heads`` with
+    ``rope_interleaved(.., first=first, seq_axis=1)`` on each, float32
+    (b, s, heads, width), with no shifted copy of the product: the
+    partner of column j of ``a @ w`` is, sign apart, column j^1 of the
+    same product, so ``a @ rotary_partner_columns(w's rotary columns)``
+    **is** the partner, the same dot products of the same inputs
+    accumulated the same way (for JoyAI's q 51 GFLOP a layer and pass in
+    place of the 2.6 GB the rolled copies moved, PR 41).  Its gradient
+    is autodiff's: elementwise passes and matmuls."""
+    b, s, _ = a.shape
+    w = cast_param(w, compute_dtype).reshape(w.shape[0], heads, -1)
+    wp = rotary_partner_columns(w[..., first:], compute_dtype)
+    dot = lambda cols: matmul(a, cols.reshape(cols.shape[0], -1),
+                              compute_dtype, weight=False) \
+        .reshape(b, s, heads, -1)
+    return rope_partnered(dot(w), dot(wp), theta, seq_axis=1)
 
 
 def mla_attention(p, x, cfg, *, interpret: bool):
@@ -559,21 +614,22 @@ def mla_attention(p, x, cfg, *, interpret: bool):
     / sqrt(nope + rope)) v`` with q, k of one width and v of another;
     residual add.  The two inner norms, RoPE and the softmax in float32;
     matmul inputs in ``compute_dtype``.  Training holds no cache, so the
-    latents are expanded to full keys and values."""
+    latents are expanded to full keys and values.  q and the shared
+    rotary key leave their projections with RoPE on (``project_rope``)."""
     b, s, _ = x.shape
     nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
     nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rank, theta = cfg.kv_lora_rank, cfg.rope_theta
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], eps)
         cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
-        q = matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
-        q = rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
-        kv = matmul(h, p["wkv_a"], dt)                   # (b, s, rank + rot)
-        ckv = rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+        q = project_rope(cq, p["wq_b"], nh, nope, theta, dt)
+        # (b, s, rank + rot), the rotary key behind the latent
+        kv = project_rope(h, p["wkv_a"], 1, rank, theta, dt)[:, :, 0]
+        ckv = rmsnorm_gain(kv[..., :rank], p["kv_a_norm"], eps)
         kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
-        k_rot = rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
         k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
-            k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
+            kv[:, :, None, rank:].astype(dt), (b, s, nh, rot))], -1)
         heads = lambda t: t.transpose(0, 2, 1, 3)        # (b, nh, s, .)
         q, k, v = (heads(q.astype(dt)), heads(k),
                    heads(kvb[..., nope:].astype(dt)))

@@ -417,14 +417,17 @@ def cases(mesh1d, mesh2d):
     # file, on one device: forward, the flash kernel, the grouped expert
     # matmuls, backward and AdamW (OLMoE-1B-7B, one layer of 16;
     # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment)
+    def model_config(config):
+        return train.load_model_config(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))),
+            "benchmark", "configs", config + ".json"))
+
     def model_step(devices, config):
         from ompi_tpu.parallel.mesh import MeshSpec
 
         mesh, spec = make_mesh(devices, MeshSpec())
-        cfg = train.load_model_config(os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))),
-            "benchmark", "configs", config + ".json"))
+        cfg = model_config(config)
         step, _ = train.build_train_step(mesh, spec, model=cfg)
         rep = lambda s, dt=f32: _sds(s, dt, mesh, P())
         tree = jax.tree.map(rep, train.model_param_shapes(cfg),
@@ -439,6 +442,24 @@ def cases(mesh1d, mesh2d):
             (tree, tree, tree, rep((), jnp.int32), bias),
             ids(cfg.seq_len),
             ids(cfg.seq_len + cfg.num_nextn_predict_layers))
+
+    # -- one latent-attention sublayer (``model.mla_attention``) of
+    # JoyAI's step, forward and gradient, at the cell's shapes: what
+    # stands between the projections and the two kernels.  Its compiled
+    # text must hold no ``_roll_static`` in any ``op_name`` and no array
+    # 191 wide: ``jnp.roll`` on q's (1, 8192, 32, 192) float32 array was
+    # 2.6 GB of shifted copies a layer and pass (PR 41)
+    def mla_operands(devices):
+        from ompi_tpu.parallel import model
+
+        one_dev = _Mesh(_np.asarray(devices), ("one",))
+        cfg = model_config("joyai-flash-train-1chip")
+        rep = lambda s: _sds(s, f32, one_dev, P())
+        leaves = {k: rep(v) for k, v in train.attention_shapes(cfg).items()}
+        loss = lambda p, x: jnp.sum(model.mla_attention(
+            p, x, cfg, interpret=False) ** 2)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))), (
+            leaves, rep((cfg.micro_batch, cfg.seq_len, cfg.hidden_size)))
 
     # -- a partitioned allreduce's group program (coll/xla _group_fn) over
     # four chips, at the sizes ``rank1-partitioned`` releases: ``entry_ops``
@@ -458,6 +479,7 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "olmoe-1b-7b-train-1chip"))
     case("joyai_step_1chip", lambda: model_step(
         topo_devs[:1], "joyai-flash-train-1chip"))
+    case("joyai_mla_operands", lambda: mla_operands(topo_devs[:1]))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

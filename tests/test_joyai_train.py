@@ -311,6 +311,123 @@ def test_the_attention_backward_by_scan_is_the_unrolled_one():
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+def _ulps(got, want, scale):
+    """|got - want| in float32 ulps of ``scale`` (an array of the terms'
+    sizes: a sum that cancels is judged by what was summed)."""
+    return float(np.max(np.abs(np.float32(got) - np.float32(want))
+                        / np.spacing(np.float32(scale))))
+
+
+def _swapped(x, first):
+    """The rotary partner of ``x``'s entries from ``first`` on, by index
+    arithmetic of its own (neither a roll nor a product)."""
+    pairs = x[..., first:].reshape(*x.shape[:-1], -1, 2)
+    return jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(
+        *x.shape[:-1], -1)
+
+
+def _mla_attention_rolled(p, x, cfg):
+    """``model.mla_attention`` as it was before PR 41: RoPE by
+    ``rope_interleaved`` (two rolls) on the projections' results."""
+    b, s, _ = x.shape
+    nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
+    nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    h = model.rmsnorm_gain(x, p["ln1"], eps)
+    cq = model.rmsnorm_gain(model.matmul(h, p["wq_a"], dt), p["q_a_norm"],
+                            eps)
+    q = model.matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
+    q = model.rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
+    kv = model.matmul(h, p["wkv_a"], dt)
+    ckv = model.rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+    kvb = model.matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
+    k_rot = model.rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
+    k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
+        k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
+    heads = lambda t: t.transpose(0, 2, 1, 3)
+    o = model.causal_flash_attention(
+        heads(q.astype(dt)), heads(k), heads(kvb[..., nope:].astype(dt)),
+        min(cfg.attn_block, s), True)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
+    return x + model.matmul(o, p["wo"], dt)
+
+
+ROPE_CASES = [
+    # the elementwise pass given the partner, at ISSUE 41's two shapes
+    ("pass", (2, 256, 4, 192), 128, 1), ("pass", (2, 256, 64), 0, -2),
+    # the projection with RoPE on: q's shape, the shared rotary key's
+    # behind its latent, and both in the matmuls' other dtype
+    ("project", (4, 192), 128, "float32"), ("project", (1, 64), 0, "float32"),
+    ("project", (1, 80), 16, "float32"), ("project", (4, 192), 128,
+                                          "bfloat16"),
+    # the sublayer whole, at this file's small widths
+    ("sublayer", None, None, "float32"), ("sublayer", None, None, "bfloat16")]
+
+
+@pytest.mark.parametrize("what,shape,first,arg", ROPE_CASES, ids=[
+    "-".join(str(x) for x in c if x is not None).replace(" ", "")
+    for c in ROPE_CASES])
+def test_rope_without_the_rolled_copies_is_rope_interleaved(
+        what, shape, first, arg, params):
+    """``model.project_rope`` / ``rope_partnered`` (the partner a product
+    of its own; no ``jnp.roll``) against the twin ``rope_interleaved``:
+    float32 values before any cast and the gradient with respect to the
+    input within one float32 ulp of the terms summed; the projection's
+    gradients with respect to its input and weights (other sums: the
+    partner's part goes through its own matmul) within a few ulps of the
+    largest entry; and ``mla_attention``'s output and parameter
+    gradients against the sublayer as it was."""
+    rng = np.random.default_rng(41)
+    normal = lambda *s: jnp.asarray(rng.normal(0, 1, s), jnp.float32)
+    theta = 32e6
+    if what == "pass":
+        x, g = normal(*shape), normal(*shape)
+        new = lambda x: model.rope_partnered(x, _swapped(x, first), theta,
+                                             seq_axis=arg)
+        old = lambda x: model.rope_interleaved(x, theta, first, arg)
+        # the two terms of an entry's sum: itself and its partner
+        terms = lambda t: jnp.abs(t) + jnp.abs(jnp.pad(
+            _swapped(t, first), ((0, 0),) * (t.ndim - 1) + ((first, 0),)))
+        assert _ulps(new(x), old(x), terms(x)) <= 1
+        dnew, dold = (jax.grad(lambda x: jnp.sum(f(x) * g))(x)
+                      for f in (new, old))
+        assert _ulps(dnew, dold, terms(g)) <= 1
+        return
+    if what == "project":
+        heads, width = shape
+        a, w = normal(2, 256, 32), normal(32, heads * width)
+        g = normal(2, 256, heads, width)
+        new = lambda a, w: model.project_rope(a, w, heads, first, theta, arg)
+        old = lambda a, w: model.rope_interleaved(
+            model.matmul(a, w, arg).reshape(2, 256, heads, width), theta,
+            first, 1)
+        assert new(a, w).dtype == jnp.float32
+        # the same dot products of the same inputs (on the CPU a product
+        # of another width may sum them in another order)
+        assert _ulps(new(a, w), old(a, w), jnp.max(jnp.abs(old(a, w)))) <= 4
+        for got, want in zip(*(jax.grad(lambda a, w: jnp.sum(f(a, w) * g),
+                                        argnums=(0, 1))(a, w)
+                               for f in (new, old))):
+            assert got.dtype == want.dtype == jnp.float32
+            loose = 16 if arg == "float32" else 2 ** 17  # bfloat16 operands
+            assert _ulps(got, want, jnp.max(jnp.abs(want))) <= loose
+        return
+    cfg = train.ModelConfig(compute_dtype=arg, num_experts=16, **PUBLISHED,
+                            **SHARE, **TRAIN)
+    leaves = {k: params["mtp"][k] for k in train.attention_shapes(cfg)}
+    x, g = normal(2, 32, cfg.hidden_size), normal(2, 32, cfg.hidden_size)
+    new = lambda p, x: model.mla_attention(p, x, cfg, interpret=True)
+    old = lambda p, x: _mla_attention_rolled(p, x, cfg)
+    tol = CLOSE if arg == "float32" else dict(rtol=2e-2, atol=2e-3)
+    np.testing.assert_allclose(new(leaves, x), old(leaves, x), **tol)
+    (pn, xn), (po, xo) = (jax.grad(lambda p, x: jnp.sum(f(p, x) * g),
+                                   argnums=(0, 1))(leaves, x)
+                          for f in (new, old))
+    np.testing.assert_allclose(xn, xo, **tol)
+    for k in leaves:
+        assert pn[k].dtype == po[k].dtype == jnp.float32
+        np.testing.assert_allclose(pn[k], po[k], **tol, err_msg=k)
+
+
 def test_the_configuration_file_gives_the_published_widths():
     cfg = train.load_model_config(os.path.join(
         BENCH, "configs", "joyai-flash-train-1chip.json"))

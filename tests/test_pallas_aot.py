@@ -187,10 +187,12 @@ def joyai_rows():
     128 alone, plain and biased, and the whole step of the cell's own
     configuration file for one v5e device (about 65 s of the 600)."""
     pytest.importorskip("libtpu")
+    dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
     res = _run_aot_subprocess("--only", "joyai", "--topology", "v5e:2x2",
-                              limit=600)
+                              "--dump", dump, limit=600)
     assert res.get("rows"), res.get("error")
-    return {r["kernel"]: r for r in res["rows"]}
+    return {r["kernel"]: dict(r, hlo=os.path.join(
+        dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
 
 
 def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
@@ -234,6 +236,23 @@ def test_joyai_train_step_aot_compiles_from_the_cells_configuration(
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["while"] >= 3
     assert row["compile_s"] < 400
+
+
+@pytest.mark.parametrize("case", ["joyai_mla_operands", "joyai_step_1chip"])
+def test_latent_attentions_operands_aot_hold_no_rolled_copy(case, joyai_rows):
+    """One latent-attention sublayer, forward and gradient, at the cell's
+    shapes, and the whole step: q's rotary partner is a product of its
+    own (``model.project_rope``), so the compiled text holds no
+    ``jnp.roll`` (``_roll_static`` in an ``op_name``) and no 191-wide
+    slice of q's (1, 8192, 32, 192) float32 array, which XLA wrote to HBM
+    as 2.6 GB of shifted copies a layer and pass (PR 41)."""
+    row = joyai_rows[case]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert "otpu_attn_proj" in text
+    assert "_roll_static" not in text
+    assert "[1,8192,32,191]" not in text
 
 
 @pytest.mark.slow
