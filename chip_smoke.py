@@ -35,6 +35,8 @@ PRIMARY_BYTES = 16 << 20    # BASELINE.json's headline: f32 allreduce a rank
 SPOT_BYTES = 4 << 20
 FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bf16
 ROPE_SHAPE = (1, 8192, 32, 1536)        # b, s, heads, the latent's rank
+# b, s, heads, head width, groups, state, chunk: the hybrid cell's mixer
+SSM_SHAPE = (1, 8192, 16, 64, 1, 128, 128)
 # each loss is taken BEFORE its update: four losses observe three
 # updates, and every one of them must have lowered the loss
 TRAIN_STEPS = 4
@@ -296,7 +298,8 @@ def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
 # -- 5. the kernels that path selects, against their XLA twins -------------
 def kernels(clock: Clock, expect_interpret: bool = False,
             flash_shape=FLASH_SHAPE, dtype: str = "bfloat16",
-            reduce_elems: int = 1 << 20, rope_shape=ROPE_SHAPE) -> None:
+            reduce_elems: int = 1 << 20, rope_shape=ROPE_SHAPE,
+            ssm_shape=SSM_SHAPE) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -429,6 +432,34 @@ def kernels(clock: Clock, expect_interpret: bool = False,
              "exceeds 1e-05")
     print(f"  project_rope {g.shape} {dtype} matches rope_interleaved in "
           f"float32 ({err:.2e} of max|ref|)", flush=True)
+
+    # Mamba-2's state-space scan in chunks (``model.ssd_chunked``; no
+    # kernel: XLA's batched matmuls and one loop over the chunks) against
+    # the recurrence one position at a time, at the hybrid cell's widths:
+    # steps between 0.001 and 0.1 and decays of 1 to 16 a unit step, as
+    # the mixer's leaves start
+    sb, ss, sh, sp_, sg, sn, chunk = ssm_shape
+    from ompi_tpu.parallel import nemotron_reference
+
+    xs = jax.random.normal(kq, (sb, ss, sh, sp_), f32)
+    step = jnp.exp(jax.random.uniform(kk, (sb, ss, sh), f32,
+                                      np.log(0.001), np.log(0.1)))
+    decay = -jax.random.uniform(kv, (sh,), f32, 1.0, 16.0)
+    bs, cs = (jax.random.normal(k, (sb, ss, sg, sn), f32) for k in (kk, kv))
+    got = clock.call(jax.jit(lambda *args: model.ssd_chunked(*args, chunk)),
+                     xs, step, decay, bs, cs, first=True)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(nemotron_reference.recurrence)(xs, step, decay, bs, cs)
+    g, want = np.asarray(got), np.asarray(want)
+    err = float(np.max(np.abs(g - want)) / np.max(np.abs(want)))
+    # both sides are float32 sums over thousands of positions (1.4e-5
+    # apart on the v5e at 8,192); a wrong scan is wrong by its whole size
+    _require(g.shape == want.shape and err <= 1e-4,
+             f"ssd_chunked {g.shape}: error {err:.3e} of max|ref| exceeds "
+             "1e-04")
+    print(f"  ssd_chunked {g.shape} in chunks of {chunk} matches the "
+          f"recurrence over {ss} positions ({err:.2e} of max|ref|)",
+          flush=True)
 
     a = jax.random.normal(kq, (reduce_elems,), jnp.float32)
     bb = jax.random.normal(kk, (reduce_elems,), jnp.float32)

@@ -838,9 +838,9 @@ def sorted_dispatch(experts, n_experts: int):
     return order // k, place.reshape(t, k), sizes
 
 
-def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
-    """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
-    up x)`` as three grouped matmuls (``lax.ragged_dot``: group e is the
+def _grouped_matmul(sizes, compute_dtype):
+    """``gmm(a, w)``: rows of ``a`` sorted by expert times the experts'
+    stacked matrices ``w`` (``lax.ragged_dot``: group e is the
     ``sizes[e]`` rows that expert e received), inputs in
     ``compute_dtype``, float32 results."""
     import jax
@@ -856,8 +856,28 @@ def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
             a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
             precision=prec, preferred_element_type=jnp.float32)
 
+    return gmm
+
+
+def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
+    """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
+    up x)`` as three grouped matmuls (``_grouped_matmul``)."""
+    import jax
+
+    gmm = _grouped_matmul(sizes, compute_dtype)
     hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
     return gmm(hidden, down)
+
+
+def grouped_relu2_ffn(xs, up, down, sizes, compute_dtype):
+    """relu2 experts (nemotron_h: two matrices, no gate) on slots sorted
+    by expert: ``down(relu(up x)^2)`` as two grouped matmuls
+    (``_grouped_matmul``)."""
+    import jax
+    import jax.numpy as jnp
+
+    gmm = _grouped_matmul(sizes, compute_dtype)
+    return gmm(jnp.square(jax.nn.relu(gmm(xs, up))), down)
 
 
 def moe_sorted_block(p, x, cfg):
@@ -936,9 +956,12 @@ def local_dispatch(experts, first: int, n_here: int):
     return order, sizes
 
 
-def local_expert_ffn(h, order, weights, sizes, p, cfg):
+def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
+                     ffn=grouped_expert_ffn):
     """The held experts' weighted part of the layer's output (T, d):
-    gather the held slots' rows, grouped matmuls, scatter-add by token.
+    gather the held slots' rows, grouped matmuls (``ffn`` over the held
+    experts' stacked matrices ``mats``: SwiGLU's three, or relu2's two
+    with ``grouped_relu2_ffn``), scatter-add by token.
     The slots held vary from step to step (0 to every slot a token can
     send here) and none is dropped.  They are walked in chunks of twice
     the mean load's rows by a loop that runs as many times as the held
@@ -958,7 +981,7 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
     padded = -(-t * k // rows) * rows
     order = jnp.pad(order, (0, padded - t * k))
 
-    def chunk(lo, order, sizes, h, flat_w, gate, up, down):
+    def chunk(lo, order, sizes, h, flat_w, *mats):
         """Chunk ``lo``'s (token of each row, its weighted output)."""
         with jax.named_scope("otpu_dispatch"):
             slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
@@ -971,7 +994,7 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
         # matmul leaves them as they were in memory (seen on the v5e:
         # NaN), in its transposes too, so they are cut off on both sides
         xs = jnp.where(live[:, None], h[token], 0.0)
-        y = grouped_expert_ffn(xs, gate, up, down, here, cfg.compute_dtype)
+        y = ffn(xs, *mats, here, cfg.compute_dtype)
         with jax.named_scope("otpu_combine"):
             w = jnp.where(live, flat_w[slot], 0.0)
             return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
@@ -980,10 +1003,9 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
         return (jnp.sum(sizes) + rows - 1) // rows
 
     @jax.custom_vjp
-    def run(order, sizes, h, flat_w, gate, up, down):
+    def run(order, sizes, h, flat_w, *mats):
         def body(c, out):
-            token, y = chunk(c * rows, order, sizes, h, flat_w, gate, up,
-                             down)
+            token, y = chunk(c * rows, order, sizes, h, flat_w, *mats)
             with jax.named_scope("otpu_combine"):
                 return out.at[token].add(y)
         return jax.lax.fori_loop(0, trips(sizes), body, h * 0)
@@ -1004,25 +1026,22 @@ def local_expert_ffn(h, order, weights, sizes, p, cfg):
             0, trips(sizes), body, tuple(a * 0 for a in diff)))
 
     run.defvjp(fwd, bwd)
-    return run(order, sizes, h, weights.reshape(t * k), p["gate"], p["up"],
-               p["down"])
+    return run(order, sizes, h, weights.reshape(t * k), *mats)
 
 
-def moe_shared_local_block(p, x, cfg, bias):
-    """DeepSeek-V3's sparse MLP sublayer (arXiv:2412.19437 section
-    2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
-    ``experts_here`` of the routed experts: pre-norm; the router's
-    sigmoid scores over **all** the experts in float32; the top k of
-    score + ``bias`` (E,); the shared expert on every token; the held
-    experts on the slots routed to them (``local_expert_ffn``).  What
-    the absent experts would add is left out.  Returns (the sublayer's
-    output before the residual add; ``slots`` an expert of all of them
-    received; by token row what the router read and made: ``in``,
-    ``logits``, ``scores`` (T, E), ``weights`` and ``experts`` (T, k))."""
+def _route_to_held(p, x, cfg, bias):
+    """What both expert blocks of a rank that holds a share do first, on
+    the residual stream ``x`` (b, s, d): pre-norm; the router's sigmoid
+    scores over **all** the experts in float32; the top k of score +
+    ``bias`` (E,); the held slots sorted by expert.  Returns (the normed
+    rows (T, d), the held slots' order and sizes, the slots an expert of
+    all of them received, by token row what the router read and made:
+    ``in``, ``logits``, ``scores`` (T, E), ``weights`` and ``experts``
+    (T, k))."""
     import jax
     import jax.numpy as jnp
 
-    from ompi_tpu.parallel.model import rmsnorm_gain, swiglu
+    from ompi_tpu.parallel.model import rmsnorm_gain
 
     b, s, d = x.shape
     t, k = b * s, cfg.num_experts_per_tok
@@ -1037,11 +1056,62 @@ def moe_shared_local_block(p, x, cfg, bias):
                                       cfg.n_experts_here)
         slots = jnp.zeros((cfg.num_experts,), jnp.int32).at[
             experts.reshape(t * k)].add(1)
+    return h, order, sizes, {"slots": slots.astype(jnp.float32)}, {
+        "in": h, "logits": logits, "scores": scores, "weights": weights,
+        "experts": experts}
+
+
+def moe_shared_local_block(p, x, cfg, bias):
+    """DeepSeek-V3's sparse MLP sublayer (arXiv:2412.19437 section
+    2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
+    ``experts_here`` of the routed experts: pre-norm; the router's
+    sigmoid scores over **all** the experts in float32; the top k of
+    score + ``bias`` (E,); the shared expert on every token; the held
+    experts on the slots routed to them (``local_expert_ffn``).  What
+    the absent experts would add is left out.  Returns (the sublayer's
+    output before the residual add; ``slots`` an expert of all of them
+    received; by token row what the router read and made: ``in``,
+    ``logits``, ``scores`` (T, E), ``weights`` and ``experts`` (T, k))."""
+    import jax
+
+    from ompi_tpu.parallel.model import swiglu
+
+    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
     with jax.named_scope("otpu_shared_expert"):
         out = swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"],
                      cfg.compute_dtype)
     with jax.named_scope("otpu_experts"):
-        out = out + local_expert_ffn(h, order, weights, sizes, p, cfg)
-    return out.reshape(b, s, d), {"slots": slots.astype(jnp.float32)}, {
-        "in": h, "logits": logits, "scores": scores, "weights": weights,
-        "experts": experts}
+        out = out + local_expert_ffn(h, order, seen["weights"], sizes,
+                                     (p["gate"], p["up"], p["down"]), cfg)
+    return out.reshape(x.shape), stats, seen
+
+
+def moe_latent_block(p, x, cfg, bias):
+    """nemotron_h's expert sublayer (its LatentMoE) on the residual
+    stream ``x`` (b, s, d), on a rank that holds ``experts_here`` of the
+    routed experts: pre-norm; the router's sigmoid scores over **all**
+    the experts in float32; the top k of score + ``bias`` (E,)
+    (``route_sigmoid_bias``: DeepSeek-V3's rule); a relu2 shared expert
+    on the hidden width on every token; the routed experts in a latent
+    of ``moe_latent_size``: ``l = h W_lat_down``, the held experts'
+    ``relu(l W_up)^2 W_down`` on the slots routed to them, weighted and
+    added up by token (``local_expert_ffn``), and that sum through
+    ``W_lat_up``.  What the absent experts would add is left out.
+    Returns what ``moe_shared_local_block`` does."""
+    import jax
+
+    from ompi_tpu.parallel.model import matmul, relu2
+
+    dt = cfg.compute_dtype
+    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
+    with jax.named_scope("otpu_shared_expert"):
+        out = relu2(h, p["shared_up"], p["shared_down"], dt)
+    with jax.named_scope("otpu_latent"):
+        latent = matmul(h, p["lat_down"], dt)
+    with jax.named_scope("otpu_experts"):
+        latent = local_expert_ffn(latent, order, seen["weights"], sizes,
+                                  (p["up"], p["down"]), cfg,
+                                  grouped_relu2_ffn)
+    with jax.named_scope("otpu_latent"):
+        out = out + matmul(latent, p["lat_up"], dt)
+    return out.reshape(x.shape), stats, seen

@@ -381,14 +381,20 @@ def build_train_step(mesh, spec: MeshSpec, lr: float = 2.0,
     return step, place
 
 
-# -- a public model's training step (OLMoE, JoyAI-LLM-Flash): widths from
-# a configuration file, not from the mesh; the kinds of sublayer from its
-# published keys ---------------------------------------------------------
+# -- a public model's training step (OLMoE, JoyAI-LLM-Flash,
+# Nemotron-3-Super): widths from a configuration file, not from the mesh;
+# the kinds of sublayer from its published keys ---------------------------
 #: OLMoE's layer leaves, stacked over the layers this rank holds
 LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
                 "router", "gate", "up", "down")
 GAINS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "q_a_norm",
-         "kv_a_norm", "enorm", "hnorm", "norm")
+         "kv_a_norm", "enorm", "hnorm", "norm", "gate_norm")
+#: a Mamba-2 mixer's leaves that are no matrices: like the gains they are
+#: not decayed, and each starts as ``init_model_params`` says
+UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b")
+#: a hybrid pattern's letters (nemotron_h) and the group a layer of each
+#: kind goes by in the parameter tree
+PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
 PROBE = 64              # entries of each leaf that a step reports
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
@@ -408,8 +414,11 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: ``sample_rows`` of each shard: ``router_in`` (L, R, d),
 #: ``router_logits`` (L, R, E), ``router_lse`` (L, R) or, of a sigmoid
 #: router, ``router_scores`` (L, R, E), ``router_weights`` (L, R, k),
-#: ``head_in`` (R, d) (``mtp_head_in``), so that their precision can be
-#: read from one step alone
+#: ``head_in`` (R, d) (``mtp_head_in``), and of every Mamba-2 layer's
+#: first held head what its scan read, whole (``ssm_dt_seq`` (M, T),
+#: ``ssm_x_seq`` (M, T, p), ``ssm_b_seq``, ``ssm_c_seq`` (M, T, n)), and
+#: made (``ssm_y`` (M, R, p)), so that their precision can be read from
+#: one step alone
 
 
 @dataclasses.dataclass(frozen=True)
@@ -423,7 +432,18 @@ class ModelConfig:
     has follows from the published keys: ``kv_lora_rank`` set is latent
     attention; layers before ``first_k_dense_replace`` are dense;
     ``scoring_func`` and ``topk_method`` say how a router scores and
-    chooses; ``n_shared_experts``; ``num_nextn_predict_layers``."""
+    chooses; ``n_shared_experts``; ``num_nextn_predict_layers``.
+
+    nemotron_h's keys: ``hybrid_override_pattern`` set makes every layer
+    **one** sublayer, by its letter (``M`` a Mamba-2 mixer, ``*``
+    grouped-query attention without RoPE, ``E`` experts in a latent of
+    ``moe_latent_size`` with relu2, beside a shared one); the rank holds
+    the ``layers_here`` layers from ``first_layer_here`` on, and of each
+    mixer the chip's share of its heads (``heads_here`` query heads with
+    the key-value heads they read; ``mamba_heads_here`` Mamba heads with
+    their B/C groups; 0 for all), as one member of a tensor-parallel
+    group holds them.  ``mtp_here`` says how many of the published
+    next-n modules are held (-1: all of them)."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -468,6 +488,54 @@ class ModelConfig:
     vocab_here: int = 0
     mtp_loss_coef: float = 0.0
     bias_update_gamma: float = 0.0
+    n_group: int = 1
+    topk_group: int = 1
+    mtp_here: int = -1
+    # nemotron_h's keys (Nemotron-3-Super)
+    hybrid_override_pattern: str = ""
+    first_layer_here: int = 0
+    heads_here: int = 0
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_heads_here: int = 0
+    n_groups: int = 1               # a mixer's B/C groups (n_group: routers')
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    mlp_hidden_act: str = "silu"
+    moe_latent_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+
+    @property
+    def pattern_here(self) -> str:
+        """The letters of the layers held here ("" without a pattern)."""
+        first = self.first_layer_here
+        return self.hybrid_override_pattern[first:first + self.layers_here]
+
+    @property
+    def segments(self) -> tuple:
+        """The held pattern as runs of like layers, ``(unit, repeats,
+        first layer)`` each: a unit is one letter or two different ones
+        (``ME`` four times over, then ``M``, ``*``, ``E``), and a run of
+        more than one repeat is walked by one ``lax.scan``."""
+        pattern, out, i = self.pattern_here, [], 0
+        while i < len(pattern):
+            best = (pattern[i], 1)
+            for width in (1, 2):
+                unit = pattern[i:i + width]
+                if len(set(unit)) != width:
+                    continue
+                n = 1
+                while pattern[i + n * width:i + (n + 1) * width] == unit:
+                    n += 1
+                if n > 1 and n * width > len(best[0]) * best[1]:
+                    best = (unit, n)
+            out.append(best + (i,))
+            i += len(best[0]) * best[1]
+        return tuple(out)
 
     @property
     def n_dense_here(self) -> int:
@@ -475,12 +543,38 @@ class ModelConfig:
 
     @property
     def n_sparse_here(self) -> int:
+        if self.hybrid_override_pattern:
+            return self.pattern_here.count("E")
         return self.layers_here - self.n_dense_here
+
+    @property
+    def n_mtp_here(self) -> int:
+        return self.num_nextn_predict_layers if self.mtp_here < 0 \
+            else self.mtp_here
 
     @property
     def n_routers(self) -> int:
         """Sparse layers in the walk, the next-next-token module's too."""
-        return self.n_sparse_here + self.num_nextn_predict_layers
+        return self.n_sparse_here + self.n_mtp_here
+
+    @property
+    def n_heads_here(self) -> int:
+        return self.heads_here or self.num_attention_heads
+
+    @property
+    def n_kv_heads_here(self) -> int:
+        """The key-value heads the held query heads read."""
+        per_kv = self.num_attention_heads // self.num_key_value_heads
+        return max(1, self.n_heads_here // per_kv)
+
+    @property
+    def n_mamba_heads_here(self) -> int:
+        return self.mamba_heads_here or self.mamba_num_heads
+
+    @property
+    def n_groups_here(self) -> int:
+        """The B/C groups of the held Mamba heads."""
+        return self.n_mamba_heads_here * self.n_groups // self.mamba_num_heads
 
     @property
     def n_experts_here(self) -> int:
@@ -499,12 +593,52 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     def __post_init__(self):
-        if self.num_key_value_heads != self.num_attention_heads:
-            raise NotImplementedError("grouped-query attention: "
-                                      "num_key_value_heads != heads")
+        hybrid = bool(self.hybrid_override_pattern)
+        per_kv = self.num_attention_heads // max(1, self.num_key_value_heads)
+        if not hybrid and self.num_key_value_heads \
+                != self.num_attention_heads:
+            raise NotImplementedError(
+                "num_key_value_heads: grouped-query attention is a "
+                "hybrid_override_pattern model's; this model's attention "
+                "has a key-value head a query head")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is not a multiple of the heads")
-        if self.num_nextn_predict_layers > 1:
+        if hybrid and (self.num_attention_heads % self.num_key_value_heads
+                       or (self.n_heads_here % per_kv
+                           and per_kv % self.n_heads_here)):
+            raise NotImplementedError(
+                f"heads_here {self.n_heads_here}: the held query heads "
+                f"are neither whole key-value heads' ({per_kv} each) nor "
+                "a whole part of one's; a key-value head split across "
+                "chips is not run")
+        if hybrid and (set(self.pattern_here) - set(PATTERN_KINDS)
+                       or len(self.pattern_here) != self.layers_here):
+            raise ValueError(
+                f"layers_here {self.layers_here} from first_layer_here "
+                f"{self.first_layer_here}: not layers of "
+                f"hybrid_override_pattern's letters {sorted(PATTERN_KINDS)}")
+        if "M" in self.pattern_here and (
+                self.n_mamba_heads_here * self.n_groups
+                % self.mamba_num_heads):
+            raise NotImplementedError(
+                f"mamba_heads_here {self.n_mamba_heads_here}: not whole "
+                f"B/C groups of {self.mamba_num_heads // self.n_groups} "
+                "heads; a group split across chips is not run")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise NotImplementedError(
+                f"n_group {self.n_group} / topk_group {self.topk_group}: "
+                "the routers choose among one group of experts")
+        if hybrid and self.n_mtp_here:
+            raise NotImplementedError(
+                f"mtp_here {self.n_mtp_here}: the next-n module of a "
+                "hybrid_override_pattern model (mtp_hybrid_override_"
+                "pattern) is not run; hold 0 of them")
+        if (self.mlp_hidden_act == "relu2") != bool(self.moe_latent_size):
+            raise NotImplementedError(
+                f"mlp_hidden_act {self.mlp_hidden_act} with moe_latent_size "
+                f"{self.moe_latent_size}: relu2 experts are run in a "
+                "latent, silu experts on the hidden width")
+        if self.n_mtp_here > 1:
             raise NotImplementedError("more than one next-n module")
         if (self.scoring_func, self.topk_method) not in (
                 ("softmax", "greedy"), ("sigmoid", "noaux_tc")):
@@ -522,21 +656,39 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
     left alone.  A model this path cannot run raises."""
     with open(path, encoding="utf-8") as f:
         body = json.load(f)
-    if body.get("hidden_act") != "silu" or body.get("attention_bias") \
+    hybrid = "hybrid_override_pattern" in body
+    act = body.get("mlp_hidden_act") if hybrid else body.get("hidden_act")
+    if act != ("relu2" if hybrid else "silu") or body.get("attention_bias") \
             or body.get("clip_qkv") or body.get("tie_word_embeddings") \
-            or body.get("rope_scaling") or body.get("n_group", 1) != 1 \
-            or body.get("topk_group", 1) != 1 \
+            or body.get("rope_scaling") \
             or body.get("moe_layer_freq", 1) != 1 \
             or ("kv_lora_rank" in body and not body.get("rope_interleave")):
         raise NotImplementedError(
-            f"{path}: the model path runs silu experts, no biases, no "
-            "clipping, an untied head, plain RoPE (on interleaved pairs "
-            "under latent attention), one group of experts and every "
-            "layer past the dense ones sparse")
+            f"{path}: the model path runs silu experts (relu2 in a "
+            "hybrid_override_pattern model), no biases, no clipping, an "
+            "untied head, plain RoPE (on interleaved pairs under latent "
+            "attention) and every layer past the dense ones sparse")
+    if hybrid and (
+            body.get("mamba_hidden_act") != "silu"
+            or not body.get("use_conv_bias") or body.get("mamba_proj_bias")
+            or body.get("use_bias") or body.get("mlp_bias")
+            or body.get("moe_shared_expert_overlap")
+            or body.get("sliding_window")
+            or body.get("head_dim", 0) * body["num_attention_heads"]
+            != body["hidden_size"]
+            or body.get("expand", 0) * body["hidden_size"]
+            != body["mamba_num_heads"] * body["mamba_head_dim"]):
+        raise NotImplementedError(
+            f"{path}: a hybrid_override_pattern model is run with silu in "
+            "the mixer, a convolution bias and no other, no window, "
+            "head_dim = hidden_size / heads and expand x hidden_size = "
+            "mamba_num_heads x mamba_head_dim")
     known = {f.name for f in dataclasses.fields(ModelConfig)}
     merged = {**body, **body.get("train", {}), **overrides}
     if "n_routed_experts" in merged:        # DeepSeek-V3's name for it
         merged.setdefault("num_experts", merged["n_routed_experts"])
+    if "layer_norm_epsilon" in merged:      # nemotron_h's
+        merged.setdefault("rms_norm_eps", merged["layer_norm_epsilon"])
     return ModelConfig(**{k: v for k, v in merged.items() if k in known})
 
 
@@ -569,22 +721,64 @@ def sparse_layer_shapes(cfg: ModelConfig) -> dict:
     return {**attention_shapes(cfg), **mlp}
 
 
+def pattern_layer_shapes(cfg: ModelConfig) -> dict:
+    """One layer's leaves by kind (``PATTERN_KINDS``'s) of a
+    ``hybrid_override_pattern`` model, on this rank's share of the heads
+    and of the experts.  ``mamba``: the pre-norm's gain, ``in_proj`` (d,
+    z + x + B + C + dt), the convolution's taps (kernel, x + B + C) and
+    bias, ``dt_bias``, ``A_log`` and ``D`` a head, the gated norm's gain,
+    ``out_proj``.  ``attn``: the gain, q and o over the held query
+    heads, k and v over the key-value heads they read.  ``moe``: the
+    gain, the router over all the experts, the latent's two projections,
+    the held experts' two matrices in the latent, the shared expert's two
+    on the hidden width."""
+    d, e = cfg.hidden_size, cfg.n_experts_here
+    nh, g = cfg.n_mamba_heads_here, cfg.n_groups_here
+    inner, bc = nh * cfg.mamba_head_dim, 2 * g * cfg.ssm_state_size
+    hd = d // cfg.num_attention_heads
+    q, kv = cfg.n_heads_here * hd, cfg.n_kv_heads_here * hd
+    lat, f = cfg.moe_latent_size, cfg.expert_width
+    fs = cfg.moe_shared_expert_intermediate_size * cfg.n_shared_experts
+    return {
+        "mamba": {"norm": (d,), "in_proj": (d, 2 * inner + bc + nh),
+                  "conv_w": (cfg.conv_kernel, inner + bc),
+                  "conv_b": (inner + bc,), "dt_bias": (nh,), "A_log": (nh,),
+                  "D": (nh,), "gate_norm": (inner,), "out_proj": (inner, d)},
+        "attn": {"ln1": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
+                 "wo": (q, d)},
+        "moe": {"ln2": (d,), "router": (d, cfg.num_experts),
+                "lat_down": (d, lat), "lat_up": (lat, d),
+                "up": (e, lat, f), "down": (e, f, lat),
+                "shared_up": (d, fs), "shared_down": (fs, d)}}
+
+
 def model_param_shapes(cfg: ModelConfig) -> dict:
     """The parameter tree's shapes: ``embed``, ``dense`` (the leading
     dense layers, stacked; absent where there are none), ``layers`` (the
     sparse layers, stacked), ``mtp`` (the next-next-token module: two
     norms, the projection of their joined outputs, one sparse layer, a
     last norm; absent where the model has none), ``final_norm``,
-    ``head``."""
+    ``head``.  Under a ``hybrid_override_pattern`` ``layers`` holds a
+    group a run of like layers (``cfg.segments``), ``l<first layer>``,
+    and in it a group a letter of the run's unit (``mamba``, ``attn``,
+    ``moe``) whose leaves are stacked over the run's repeats."""
     d, ff, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_rows
     stack = lambda n, shapes: {k: (n,) + s for k, s in shapes.items()}
     tree = {"embed": (v, d)}
+    if cfg.hybrid_override_pattern:
+        kinds = pattern_layer_shapes(cfg)
+        tree["layers"] = {
+            f"l{first}": {PATTERN_KINDS[c]: stack(n, kinds[PATTERN_KINDS[c]])
+                          for c in unit}
+            for unit, n, first in cfg.segments}
+        tree.update(final_norm=(d,), head=(d, v))
+        return tree
     if cfg.n_dense_here:
         tree["dense"] = stack(cfg.n_dense_here, {
             **attention_shapes(cfg), "ln2": (d,), "gate": (d, ff),
             "up": (d, ff), "down": (ff, d)})
     tree["layers"] = stack(cfg.n_sparse_here, sparse_layer_shapes(cfg))
-    if cfg.num_nextn_predict_layers:
+    if cfg.n_mtp_here:
         tree["mtp"] = {"enorm": (d,), "hnorm": (d,), "proj": (2 * d, d),
                        **sparse_layer_shapes(cfg), "norm": (d,)}
     tree.update(final_norm=(d,), head=(d, v))
@@ -596,22 +790,32 @@ def is_gain(name: str) -> bool:
     return name.rsplit(".", 1)[-1] in GAINS
 
 
+def is_decayed(name: str) -> bool:
+    """Whether AdamW decays the leaf: every matrix, and no gain, bias or
+    per-head scalar of a mixer (``UNDECAYED``)."""
+    return name.rsplit(".", 1)[-1] not in UNDECAYED
+
+
 def leaf_names(cfg: ModelConfig = None) -> list:
     """(name, path) of every trained leaf in a fixed order.  A sparse
     layer's leaves go by their own names, a dense layer's and the
-    module's by ``dense.<leaf>`` and ``mtp.<leaf>``; without ``cfg``,
-    OLMoE's."""
+    module's by ``dense.<leaf>`` and ``mtp.<leaf>``, a pattern's by
+    ``l<first layer>.<kind>.<leaf>``; without ``cfg``, OLMoE's."""
     if cfg is None:
         return [("embed", ("embed",))] + [
             (k, ("layers", k)) for k in LAYER_LEAVES] + [
             ("final_norm", ("final_norm",)), ("head", ("head",))]
     out = []
-    for key, sub in model_param_shapes(cfg).items():
-        if isinstance(sub, tuple):
-            out.append((key, (key,)))
-        else:
-            out += [(k if key == "layers" else f"{key}.{k}", (key, k))
-                    for k in sub]
+
+    def walk(sub, path):
+        for key, below in sub.items():
+            if isinstance(below, tuple):
+                out.append((".".join(k for k in path + (key,)
+                                     if k != "layers"), path + (key,)))
+            else:
+                walk(below, path + (key,))
+
+    walk(model_param_shapes(cfg), ())
     return out
 
 
@@ -646,16 +850,35 @@ def sample_rows(rows: int) -> np.ndarray:
 
 def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
     """Float32 master parameters drawn on the default device from
-    ``seed``: normal(0, ``init_std``) matrices, gains of one."""
+    ``seed``: normal(0, ``init_std``) matrices, gains of one.  A Mamba-2
+    mixer's other leaves as its authors start them (arXiv:2405.21060's
+    code): ``D`` one; ``A_log`` the logarithm of a uniform draw from 1
+    to 16; ``dt_bias`` the inverse softplus of a step drawn
+    log-uniformly between ``time_step_min`` and ``time_step_max`` (at
+    least ``time_step_floor``); the convolution's taps and bias uniform
+    within 1 / sqrt(``conv_kernel``), a depthwise convolution's usual
+    start."""
     import jax
     import jax.numpy as jnp
 
     key = jax.random.PRNGKey(seed)
 
     def draw(name, shape):
-        if is_gain(name):
+        last = name.rsplit(".", 1)[-1]
+        if is_gain(name) or last == "D":
             return jnp.ones(shape, jnp.float32)
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+        if last == "A_log":
+            return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1., 16.))
+        if last == "dt_bias":
+            step = jnp.maximum(cfg.time_step_floor, jnp.exp(
+                jax.random.uniform(k, shape, jnp.float32,
+                                   np.log(cfg.time_step_min),
+                                   np.log(cfg.time_step_max))))
+            return step + jnp.log(-jnp.expm1(-step))
+        if last in ("conv_w", "conv_b"):
+            bound = cfg.conv_kernel ** -0.5
+            return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
         return cfg.init_std * jax.random.normal(k, shape, jnp.float32)
 
     shapes, tree = model_param_shapes(cfg), {}
@@ -736,6 +959,37 @@ def _walk_layers(run, stacked, x, bias, n: int):
         lambda x, xs: run(xs[0], x, xs[1]), x, (stacked, bias))
 
 
+def _walk_pattern(run, layers, x, bias, cfg: ModelConfig):
+    """The held layers of a ``hybrid_override_pattern`` in turn, a run of
+    like layers at a time (``cfg.segments``; ``layers`` holds a group a
+    run): a run's unit is called once, or scanned over its repeats
+    (``_walk_layers``), each of its layers through ``run``.  ``bias``
+    (the held expert layers, E) gives each expert layer its row.
+    Returns (x, {letter: the outs of that letter's layers stacked in
+    the layers' order})."""
+    import jax
+    import jax.numpy as jnp
+
+    outs, done = {}, 0          # done: the expert layers walked so far
+    for unit, n, first in cfg.segments:
+        def unit_run(group, x, bias_row, unit=unit):
+            out = {}
+            for letter in unit:
+                x, out[letter] = run(group[PATTERN_KINDS[letter]], x,
+                                     bias_row if letter == "E" else None)
+            return x, out
+
+        rows = None
+        if "E" in unit:
+            rows, done = bias[done:done + n], done + n
+        x, out = _walk_layers(unit_run, layers[f"l{first}"], x, rows, n)
+        for letter in unit:
+            outs.setdefault(letter, []).append(out[letter])
+    with jax.named_scope("otpu_stats"):
+        return x, {letter: jax.tree.map(lambda *a: jnp.concatenate(a), *of)
+                   for letter, of in outs.items()}
+
+
 def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                n_global: int, axes: tuple = (), bias=None):
     """The training loss of one micro-batch shard and what a step
@@ -758,15 +1012,18 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     bias = bias or {}
 
     def run(layer, x, bias_row):
-        x, st, routed = decoder_layer(layer, x, cfg, interpret=interpret,
-                                      bias=bias_row)
-        experts = routed.pop("experts", None)
+        x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
+                                    bias=bias_row)
+        experts = seen.pop("experts", None)
         with jax.named_scope("otpu_stats"):
-            out = (jax.tree.map(psum, st), experts,
-                   {"router_" + k: v[at] for k, v in routed.items()})
+            # a router's rows at the sampled ones; of a mixer's scan the
+            # sequences whole (``_seq``) and its result at the sampled
+            out = (jax.tree.map(psum, st), experts, {
+                k if k.startswith("ssm_") else "router_" + k:
+                v if k.endswith("_seq") else v[at] for k, v in seen.items()})
         return x, out
 
-    if cfg.n_dense_here + cfg.n_routers > 1:
+    if cfg.layers_here + cfg.n_mtp_here > 1:
         # a layer's activations are recomputed in its backward pass, so
         # that one layer's are held at a time and not every layer's;
         # with one layer there is nothing to save
@@ -777,8 +1034,15 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         if cfg.n_dense_here:
             x, _ = _walk_layers(run, params["dense"], x, None,
                                 cfg.n_dense_here)
-        x, (st, chosen, sample) = _walk_layers(
-            run, params["layers"], x, bias.get("layers"), cfg.n_sparse_here)
+        if cfg.hybrid_override_pattern:
+            x, outs = _walk_pattern(run, params["layers"], x,
+                                    bias.get("layers"), cfg)
+            st, chosen, sample = outs["E"]
+            sample.update(outs.get("M", ({}, None, {}))[2])
+        else:
+            x, (st, chosen, sample) = _walk_layers(
+                run, params["layers"], x, bias.get("layers"),
+                cfg.n_sparse_here)
     head_rows = min(cfg.loss_block_rows, b * s)
     with jax.named_scope("otpu_head"):
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
@@ -803,7 +1067,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     with jax.named_scope("otpu_stats"):
         sample["head_in"] = h.reshape(b * s, -1)[at]
     aux = {}
-    if cfg.num_nextn_predict_layers:
+    if cfg.n_mtp_here:
         # DeepSeek-V3's multi-token prediction, depth one: the last
         # layer's output (before the final norm) joined with the next
         # token's embedding, one more sparse layer, the same embedding
@@ -851,8 +1115,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
 
 def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
     """One AdamW update of one leaf in float32 (decoupled weight decay
-    on everything but the norms' gains; ``t`` counts from 1; the
-    learning rate rises linearly over the first ``warmup_steps``)."""
+    on every matrix: ``is_decayed``; ``t`` counts from 1; the learning
+    rate rises linearly over the first ``warmup_steps``)."""
     import jax.numpy as jnp
 
     m = cfg.adam_b1 * m + (1.0 - cfg.adam_b1) * g
@@ -860,7 +1124,7 @@ def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
     mhat = m / (1.0 - cfg.adam_b1 ** t)
     vhat = v / (1.0 - cfg.adam_b2 ** t)
     step = mhat / (jnp.sqrt(vhat) + cfg.adam_eps)
-    if not is_gain(name):
+    if is_decayed(name):
         step = step + cfg.weight_decay * p
     lr = cfg.lr * jnp.minimum(1.0, t / cfg.warmup_steps)
     return p - lr * step, m, v
@@ -961,7 +1225,11 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
     aux_specs = {"losses": rep, "loads": rep, "rows": batch,
                  "experts": rows, "grad_sq": rep, "grad_probe": rep,
                  "param_probe": rep, "sample": {**sample, "head_in": batch}}
-    if cfg.num_nextn_predict_layers:
+    if "M" in cfg.pattern_here:
+        aux_specs["sample"].update(
+            {"ssm_" + k: rows for k in ("x_seq", "b_seq", "c_seq", "y")},
+            ssm_dt_seq=P(None, "dp"))
+    if cfg.n_mtp_here:
         aux_specs["mtp_rows"] = aux_specs["sample"]["mtp_head_in"] = batch
     if cfg.n_experts_here < cfg.num_experts:
         aux_specs["local_slots"] = rep
@@ -973,6 +1241,7 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
 
     jitted = jax.jit(otpu_train_step, donate_argnums=(0,))
     slots = n_global * cfg.num_experts_per_tok * cfg.n_routers
+    ssm_tokens = n_global * cfg.pattern_here.count("M")
     trace.bind_profiler()
     count = [0]
     avals = []          # the first call's arguments, as shapes: scopes()
@@ -985,8 +1254,10 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
         spc.record("train_steps")
         spc.record("train_tokens", n_global)
         spc.record("moe_token_slots", slots)
-        if cfg.num_nextn_predict_layers:
+        if cfg.n_mtp_here:
             spc.record("train_mtp_tokens", n_global)
+        if ssm_tokens:
+            spc.record("train_ssm_layer_tokens", ssm_tokens)
         if biased:
             spc.record("moe_bias_updates", cfg.n_routers)
         if count[0] == 1:
@@ -1041,7 +1312,7 @@ def _build_model_step(mesh, spec: MeshSpec, cfg: ModelConfig):
             row = lambda n: put(jnp.zeros((n, cfg.num_experts),
                                           jnp.float32), rep)
             bias = {"layers": row(cfg.n_sparse_here)}
-            if cfg.num_nextn_predict_layers:
+            if cfg.n_mtp_here:
                 bias["mtp"] = row(1)
         state = (params, zeros(), zeros(), put(jnp.zeros((), jnp.int32), rep),
                  bias)

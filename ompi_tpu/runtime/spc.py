@@ -118,6 +118,9 @@ _COUNTERS = (
     # here and to absent ones
     "train_mtp_tokens", "moe_bias_updates", "train_steps_read",
     "moe_local_slots", "moe_absent_slots",
+    # a model with state-space layers: the tokens that went through one,
+    # a layer each (tokens x Mamba layers held), in the steps issued
+    "train_ssm_layer_tokens",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

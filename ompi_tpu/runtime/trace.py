@@ -884,6 +884,15 @@ STEP_SCOPES = (
     "otpu_grad_sync",       # the gradients' sum over the data axis
     "otpu_adamw",           # the update of every trained leaf
     "otpu_bias_update",     # the routers' balancing biases
+    # behind this line: names the benchmark's harness/scopes.json does
+    # not repeat (it is the leading part of this tuple); a metric file
+    # that reads one lists it under its own ``vocabulary``
+    "otpu_mamba",           # a layer's Mamba-2 mixer, whole
+    "otpu_ssm_proj",        # inside it: the pre-norm, in_proj, out_proj
+    "otpu_ssm_conv",        # the causal depthwise convolution and its silu
+    "otpu_ssm_scan",        # softplus, the chunked state-space scan, D x
+    "otpu_ssm_norm",        # the gate and the grouped norm
+    "otpu_latent",          # inside otpu_moe: the latent's two projections
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

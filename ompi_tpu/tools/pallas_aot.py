@@ -416,7 +416,9 @@ def cases(mesh1d, mesh2d):
     # -- a public model's step at a benchmark cell's own configuration
     # file, on one device: forward, the flash kernel, the grouped expert
     # matmuls, backward and AdamW (OLMoE-1B-7B, one layer of 16;
-    # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment)
+    # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment;
+    # Nemotron-3-Super, one chip's share of a 64-chip deployment: the
+    # chunked state-space scan, grouped-query attention, latent experts)
     def model_config(config):
         return train.load_model_config(os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(
@@ -434,14 +436,14 @@ def cases(mesh1d, mesh2d):
                             is_leaf=lambda x: isinstance(x, tuple))
         bias = {}
         if cfg.topk_method == "noaux_tc":
-            bias = {"layers": rep((cfg.n_sparse_here, cfg.num_experts)),
-                    "mtp": rep((1, cfg.num_experts))}
+            bias = {"layers": rep((cfg.n_sparse_here, cfg.num_experts))}
+            if cfg.n_mtp_here:
+                bias["mtp"] = rep((1, cfg.num_experts))
         ids = lambda n: _sds((cfg.micro_batch, n), jnp.int32, mesh,
                              P("dp", None))
         return step.jitted, (
             (tree, tree, tree, rep((), jnp.int32), bias),
-            ids(cfg.seq_len),
-            ids(cfg.seq_len + cfg.num_nextn_predict_layers))
+            ids(cfg.seq_len), ids(cfg.seq_len + cfg.n_mtp_here))
 
     # -- one latent-attention sublayer (``model.mla_attention``) of
     # JoyAI's step, forward and gradient, at the cell's shapes: what
@@ -480,6 +482,8 @@ def cases(mesh1d, mesh2d):
     case("joyai_step_1chip", lambda: model_step(
         topo_devs[:1], "joyai-flash-train-1chip"))
     case("joyai_mla_operands", lambda: mla_operands(topo_devs[:1]))
+    case("nemotron3_step_1chip", lambda: model_step(
+        topo_devs[:1], "nemotron3-super-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

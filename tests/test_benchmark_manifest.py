@@ -90,6 +90,28 @@ def _cut_batch_share(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# nemotron3-train-1chip: the same, at the widths of
+# tests/test_nemotron_train.py (the seven layers MEMEM*E of the published
+# pattern: a scanned run of two ME and one layer of each kind, as the
+# period's four and three; 4 of 16 Mamba heads, 4 of 16 query heads, 8 of
+# 32 experts, 64 of 512 ids)
+TINY_HYBRID = dict(hidden_size=64, intermediate_size=24, head_dim=4,
+                   num_attention_heads=16, num_key_value_heads=2,
+                   mamba_num_heads=16, mamba_head_dim=8, n_groups=8,
+                   ssm_state_size=8, chunk_size=8, moe_latent_size=16,
+                   moe_intermediate_size=24,
+                   moe_shared_expert_intermediate_size=48,
+                   n_routed_experts=32, num_experts_per_tok=3,
+                   vocab_size=512, vocab_here=64, experts_here=8,
+                   heads_here=4, mamba_heads_here=4, first_layer_here=31,
+                   layers_here=7)
+
+
+def _tiny_hybrid(config):
+    config.update(TINY_HYBRID)
+    config["train"].update(TINY_SHARE_TRAIN)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -142,10 +164,16 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("joyai-flash-train-1chip", _tiny_share),
         cut={"packed-8k-steps": _cut_batch_share}),
+    "nemotron3-train-1chip": dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("nemotron3-super-train-1chip", _tiny_hybrid),
+        cut={"packed-8k-hybrid-steps": _cut_batch_share}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
-STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip")
+STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
+              "nemotron3-train-1chip")
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the child: run_cell as the command calls it, but for the three
@@ -462,6 +490,31 @@ def test_a_share_step_counts_its_slots_here_and_elsewhere(rehearsal):
     assert rehearsal["builds"] == [1, 1]
 
 
+@of_cells("nemotron3-train-1chip")
+def test_a_hybrid_step_counts_its_state_space_tokens(rehearsal):
+    """The trainer's counters on one chip's share of a hybrid model, by
+    the kind that reads everything from the kit: the state-space
+    layers' tokens and the routers' bias updates follow from the steps
+    issued (3 Mamba-2 and 3 expert layers at the rehearsal's widths, no
+    next-n module); over the steps read back every slot went to a held
+    expert or to an absent one; the step's program is the one program
+    built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_SHARE_TRAIN["micro_batch"] * TINY_SHARE_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["train_tokens"] == c["train_steps"] * tokens
+    assert c["train_ssm_layer_tokens"] == c["train_tokens"] * 3
+    assert "train_mtp_tokens" not in c or c["train_mtp_tokens"] == 0
+    assert c["moe_token_slots"] == c["train_tokens"] * 3 * 3
+    assert c["moe_bias_updates"] == c["train_steps"] * 3
+    assert c["train_steps_read"] >= 3
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 3 * 3
+    assert rehearsal["builds"] == [1, 1]
+
+
 def test_train_check_tells_the_program_from_its_control(tmp_path):
     """``benchmark/tools/train_check.py`` at the rehearsal's widths: one
     step of the program lies within the kind's tolerance of the
@@ -520,6 +573,42 @@ def test_share_check_tells_the_program_from_its_controls(tmp_path):
         assert row["parts_softmax"]["widest_units"] > 1
         assert row["parts_bias_in_weights"]["widest_units"] \
             > 10 * row["program"]["units_by_group"]["router_weights"]
+
+
+def test_kit_check_tells_the_hybrid_program_from_its_controls(tmp_path):
+    """``benchmark/tools/kit_check.py`` on the hybrid cell at the
+    rehearsal's widths: one step of the program, after a few steps have
+    moved the balancing biases, lies within the kind's tolerance of the
+    kit's reference (the state-space layer one position at a time); the
+    reference computed in bfloat16 lies far outside the program's, and a
+    bfloat16 router, a scan whose decay and state are held in bfloat16
+    and a softmax in the sigmoid's place outside the tolerance (the bias
+    in the weights only as far as four steps of gamma move a weight)."""
+    env = _stage("nemotron3-train-1chip", str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", "nemotron3-train-1chip", "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "2", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    (summary,) = [json.loads(ln[8:]) for ln in done.stdout.splitlines()
+                  if ln.startswith("summary ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["widest_units"] < 0.05
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        assert row["parts_bf16"]["widest_units"] > 1
+        assert row["parts_scan_bf16"]["units_by_group"]["ssm_y"] > 1
+        assert row["parts_softmax"]["widest_units"] > 1
+        assert row["parts_bias_in_weights"]["widest_units"] \
+            > 10 * row["program"]["units_by_group"]["router_weights"]
+    assert summary["program"] == max(r["program"]["widest_units"]
+                                     for r in rows)
+    assert summary["parts_scan_bf16"] == min(
+        r["parts_scan_bf16"]["widest_units"] for r in rows)
 
 
 def test_a_share_of_the_busy_seconds_counts_no_loop_twice(tmp_path,

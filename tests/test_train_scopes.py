@@ -19,6 +19,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from test_joyai_train import F32 as JOYAI
+from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 
 from ompi_tpu.parallel import train
@@ -189,7 +190,7 @@ def built(cfg):
     ids = np.random.default_rng(0).integers(
         0, cfg.vocab_rows, (cfg.micro_batch, cfg.seq_len + 2)).astype(
             np.int32)
-    n = cfg.seq_len + cfg.num_nextn_predict_layers
+    n = cfg.seq_len + cfg.n_mtp_here
     return step, place(train.init_model_params(cfg, 0),
                        ids[:, :cfg.seq_len], ids[:, 1:1 + n])
 
@@ -212,12 +213,19 @@ def olmoe():
     return step, step.scopes()
 
 
+@pytest.fixture(scope="module")
+def nemotron():
+    step, args = built(NEMOTRON)
+    step(*args)
+    return step, step.scopes()
+
+
 def ran(scopes):
     return {k: v for k, v in scopes["ops"].items()
             if v["opcode"] not in trace.TRIVIAL_OPCODES}
 
 
-@pytest.mark.parametrize("which", ["joyai", "olmoe"])
+@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron"])
 def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     scopes = request.getfixturevalue(which)[1]
     assert scopes["module"] == "jit_otpu_train_step"
@@ -227,11 +235,39 @@ def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     assert {"otpu_embed", "otpu_layers", "otpu_attn_proj", "otpu_moe",
             "otpu_router", "otpu_dispatch", "otpu_experts",
             "otpu_head", "otpu_stats", "otpu_adamw"} <= named
+    assert ({"otpu_mamba", "otpu_ssm_proj", "otpu_ssm_conv", "otpu_ssm_scan",
+             "otpu_ssm_norm", "otpu_latent"} <= named) \
+        == (which == "nemotron")
     assert {v["pass"] for v in scopes["ops"].values()} <= {
         None, *trace.PASSES}
 
 
-@pytest.mark.parametrize("which", ["joyai", "olmoe"])
+def test_every_op_of_a_mixer_lands_under_its_scope(nemotron):
+    """Every instruction whose path passes through ``mamba_mixer``'s
+    parts has ``otpu_mamba`` and one of the four parts in its chain, in
+    the forward pass, the recomputed one and the backward one; the
+    chunks' recurrence (a ``while``) is under the scan's scope; and what
+    is under the scan's scope is under the mixer's."""
+    ops = ran(nemotron[1])
+    parts = {"otpu_ssm_proj", "otpu_ssm_conv", "otpu_ssm_scan",
+             "otpu_ssm_norm"}
+    mixer = [v for v in ops.values() if "otpu_mamba" in v["chain"]]
+    assert len(mixer) > 100
+    loose = [v for v in mixer if not parts & set(v["chain"])
+             and not v["inherited"]]
+    assert loose == []
+    for part in parts:
+        under = [v for v in ops.values() if part in v["chain"]]
+        assert all("otpu_mamba" in v["chain"] for v in under), part
+        assert {"forward", "remat", "backward"} <= {v["pass"]
+                                                    for v in under}, part
+    assert any(v["opcode"] == "while" and "otpu_ssm_scan" in v["chain"]
+               for v in ops.values())
+    latent = [v for v in ops.values() if "otpu_latent" in v["chain"]]
+    assert latent and all("otpu_moe" in v["chain"] for v in latent)
+
+
+@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron"])
 def test_every_instruction_the_program_wrote_has_a_chain(which, request):
     """Not a parameter, constant, tuple or bitcast, and with a path of
     the program's (``pass`` None: the compiler's own, which on the CPU
@@ -292,7 +328,19 @@ def test_the_vocabulary_is_the_sources_and_the_benchmarks():
     with open(os.path.join(ROOT, "benchmark", "harness", "scopes.json"),
               encoding="utf-8") as f:
         data = json.load(f)
-    assert data["scopes"] == list(trace.STEP_SCOPES)
+    # the benchmark's file is the leading part of the tuple (a PR that
+    # adds a cell may not edit it), and every name behind it is listed
+    # by a metric file that reads it (``vocabulary``, for
+    # readers/trace_scope_share_wide.py)
+    held = len(data["scopes"])
+    assert data["scopes"] == list(trace.STEP_SCOPES[:held])
+    listed = set()
+    metrics = os.path.join(ROOT, "benchmark", "metrics")
+    for name in os.listdir(metrics):
+        with open(os.path.join(metrics, name), encoding="utf-8") as f:
+            listed |= set(json.load(f).get("params", {}).get(
+                "vocabulary", ()))
+    assert set(trace.STEP_SCOPES[held:]) <= listed <= set(trace.STEP_SCOPES)
     assert data["passes"] == list(trace.PASSES)
     assert data["update_scopes"] == list(trace.UPDATE_SCOPES)
 
