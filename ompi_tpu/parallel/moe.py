@@ -921,6 +921,25 @@ def moe_sorted_block(p, x, cfg):
 
 # -- a share of the routed experts beside a shared one (DeepSeek-V3's
 # expert layer on one rank of an expert-parallel deployment) -------------
+
+# what a layer's ``jax.checkpoint`` keeps of an expert block
+# (``train.model_loss``'s policy saves these names and nothing else): the
+# router's float32 product, the integers chosen and sorted from it, the
+# chosen experts' scores, and the held experts' sum in the latent.  Small
+# beside a layer's activations, and dear to make again: the six-pass
+# product, the top-k (a whole sort on a TPU), the gather entry by entry,
+# the argsort, the loop.
+ROUTER_LOGITS = "otpu_router_logits"
+CHOSEN_EXPERTS = "otpu_chosen_experts"
+CHOSEN_SCORES = "otpu_chosen_scores"
+DISPATCH_ORDER = "otpu_dispatch_order"
+DISPATCH_SIZES = "otpu_dispatch_sizes"
+EXPERT_SLOTS = "otpu_expert_slots"
+LATENT_SUM = "otpu_latent_sum"
+CHECKPOINT_KEEPS = (ROUTER_LOGITS, CHOSEN_EXPERTS, CHOSEN_SCORES,
+                    DISPATCH_ORDER, DISPATCH_SIZES, EXPERT_SLOTS, LATENT_SUM)
+
+
 def route_sigmoid_bias(logits, bias, top_k: int, normalize: bool,
                        scale: float):
     """DeepSeek-V3's ``noaux_tc`` routing with one group: ``sigmoid``
@@ -928,13 +947,19 @@ def route_sigmoid_bias(logits, bias, top_k: int, normalize: bool,
     (the balancing bias enters the choice and nothing else), weights the
     chosen scores themselves, normalised to sum to one if ``normalize``
     and times ``scale``.  Returns (scores (T, E), weights (T, k),
-    experts (T, k))."""
+    experts (T, k)).  The experts are named (``CHOSEN_EXPERTS``) before
+    anything reads them, and their scores as gathered (``CHOSEN_SCORES``),
+    so what a checkpoint recomputes of the weights is the normalisation:
+    no second top-k, and no second gather of T k single entries."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
     scores = jax.nn.sigmoid(logits)
     _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    experts = checkpoint_name(experts, CHOSEN_EXPERTS)
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, experts, axis=-1), CHOSEN_SCORES)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                              + 1e-20)
@@ -1037,9 +1062,12 @@ def _route_to_held(p, x, cfg, bias):
     rows (T, d), the held slots' order and sizes, the slots an expert of
     all of them received, by token row what the router read and made:
     ``in``, ``logits``, ``scores`` (T, E), ``weights`` and ``experts``
-    (T, k))."""
+    (T, k)).  The product, the chosen experts and the dispatch's integers
+    carry their ``CHECKPOINT_KEEPS`` names, so a checkpointed layer's
+    backward pass reads the forward pass's and routes as it did."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
     from ompi_tpu.parallel.model import rmsnorm_gain
 
@@ -1047,15 +1075,19 @@ def _route_to_held(p, x, cfg, bias):
     t, k = b * s, cfg.num_experts_per_tok
     h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
     with jax.named_scope("otpu_router"):
-        logits = jnp.dot(h, p["router"],
-                         precision=jax.lax.Precision.HIGHEST)
+        logits = checkpoint_name(
+            jnp.dot(h, p["router"], precision=jax.lax.Precision.HIGHEST),
+            ROUTER_LOGITS)
         scores, weights, experts = route_sigmoid_bias(
             logits, bias, k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
     with jax.named_scope("otpu_dispatch"):
         order, sizes = local_dispatch(experts, cfg.first_expert_here,
                                       cfg.n_experts_here)
-        slots = jnp.zeros((cfg.num_experts,), jnp.int32).at[
-            experts.reshape(t * k)].add(1)
+        order = checkpoint_name(order, DISPATCH_ORDER)
+        sizes = checkpoint_name(sizes, DISPATCH_SIZES)
+        slots = checkpoint_name(
+            jnp.zeros((cfg.num_experts,), jnp.int32).at[
+                experts.reshape(t * k)].add(1), EXPERT_SLOTS)
     return h, order, sizes, {"slots": slots.astype(jnp.float32)}, {
         "in": h, "logits": logits, "scores": scores, "weights": weights,
         "experts": experts}
@@ -1099,6 +1131,7 @@ def moe_latent_block(p, x, cfg, bias):
     ``W_lat_up``.  What the absent experts would add is left out.
     Returns what ``moe_shared_local_block`` does."""
     import jax
+    from jax.ad_checkpoint import checkpoint_name
 
     from ompi_tpu.parallel.model import matmul, relu2
 
@@ -1109,9 +1142,12 @@ def moe_latent_block(p, x, cfg, bias):
     with jax.named_scope("otpu_latent"):
         latent = matmul(h, p["lat_down"], dt)
     with jax.named_scope("otpu_experts"):
-        latent = local_expert_ffn(latent, order, seen["weights"], sizes,
-                                  (p["up"], p["down"]), cfg,
-                                  grouped_relu2_ffn)
+        # ``lat_up``'s weight gradient reads this sum: kept, or a layer's
+        # backward pass runs the held experts' loop once more to make it
+        latent = checkpoint_name(
+            local_expert_ffn(latent, order, seen["weights"], sizes,
+                             (p["up"], p["down"]), cfg, grouped_relu2_ffn),
+            LATENT_SUM)
     with jax.named_scope("otpu_latent"):
         out = out + matmul(latent, p["lat_up"], dt)
     return out.reshape(x.shape), stats, seen

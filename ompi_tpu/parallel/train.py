@@ -944,6 +944,17 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
     return ce(h, w)
 
 
+def layer_checkpoint_policy():
+    """What a walked layer's ``jax.checkpoint`` keeps for its backward
+    pass: the results an expert block names (``moe.CHECKPOINT_KEEPS``)
+    and nothing else, so a layer that names nothing is recomputed whole."""
+    import jax
+
+    from ompi_tpu.parallel.moe import CHECKPOINT_KEEPS
+
+    return jax.checkpoint_policies.save_only_these_names(*CHECKPOINT_KEEPS)
+
+
 def _walk_layers(run, stacked, x, bias, n: int):
     """``n`` like layers in turn: ``run(layer, x, bias row) -> (x,
     out)``; returns (x, the outs stacked).  More than one is a
@@ -1026,8 +1037,10 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     if cfg.layers_here + cfg.n_mtp_here > 1:
         # a layer's activations are recomputed in its backward pass, so
         # that one layer's are held at a time and not every layer's;
-        # with one layer there is nothing to save
-        run = jax.checkpoint(run)
+        # with one layer there is nothing to save.  Kept from the forward
+        # pass are only an expert block's named routing results
+        # (``moe.CHECKPOINT_KEEPS``)
+        run = jax.checkpoint(run, policy=layer_checkpoint_policy())
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32
     with jax.named_scope("otpu_layers"):

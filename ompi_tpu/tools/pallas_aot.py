@@ -547,6 +547,8 @@ def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
                 mem = compiled.memory_analysis()
                 row["peak_vmem_bytes"] = int(
                     getattr(mem, "temp_size_in_bytes", 0) or 0)
+                row["argument_bytes"] = int(
+                    getattr(mem, "argument_size_in_bytes", 0) or 0)
             except Exception:
                 pass
         except Exception as e:

@@ -162,6 +162,28 @@ def test_parameters_and_bias_after_three_steps(dp, params):
     assert moved.max() <= 3 * F32.bias_update_gamma + 1e-9 and moved.any()
 
 
+def test_what_the_checkpoint_keeps_changes_no_number(params, system,
+                                                     monkeypatch):
+    """A layer's checkpoint keeps the routing results an expert block
+    names (``moe.CHECKPOINT_KEEPS``): every gradient entry, and what a
+    step reports, is bit for bit what the bare checkpoint gives."""
+    _, (stepped,) = run_steps(F32, params, (0,))
+    monkeypatch.setattr(train, "layer_checkpoint_policy",
+                        lambda: jax.checkpoint_policies.nothing_saveable)
+    (total, aux), bare = jax.value_and_grad(
+        lambda p: system_loss(p, F32, batch_of(0), some_bias()),
+        has_aux=True)(params)
+    _, (bare_stepped,) = run_steps(F32, params, (0,))
+    assert total == system["total"]
+    for name, path in train.leaf_names(F32):
+        np.testing.assert_array_equal(train._leaf(system["grads"], path),
+                                      train._leaf(bare, path), name)
+    for key in ("losses", "loads", "experts"):
+        np.testing.assert_array_equal(system["aux"][key], aux[key], key)
+        np.testing.assert_array_equal(stepped[key], bare_stepped[key], key)
+    np.testing.assert_array_equal(stepped["grad_sq"], bare_stepped["grad_sq"])
+
+
 def test_a_step_reports_what_it_counted(params):
     if "train_steps" not in spc.counters():
         spc.init()

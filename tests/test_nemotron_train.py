@@ -345,6 +345,45 @@ def test_every_leafs_gradient_is_the_references():
         near(train._leaf(got, path), train._leaf(want, path), err_msg=name)
 
 
+def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
+    """A layer's checkpoint keeps an expert block's routing results
+    (``moe.CHECKPOINT_KEEPS``): the backward pass reads them and does not
+    make them again, so every gradient entry, and what a step reports, is
+    bit for bit what the bare checkpoint (nothing kept) gives."""
+    tokens, labels = batch_of(4)
+    params, bias = train.init_model_params(F32, 11), some_bias()
+
+    def grads():
+        return jax.value_and_grad(
+            lambda ps: train.model_loss(ps, tokens, labels, F32,
+                                        interpret=True, n_global=64,
+                                        bias=bias), has_aux=True)(params)
+
+    def one_step():
+        mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+        step, place = train.build_train_step(mesh, spec, model=F32)
+        state, _, _ = place(jax.tree.map(jnp.copy, params), tokens, labels)
+        return jax.device_get(step(state, tokens, labels)[1])
+
+    (loss, aux), got = grads()
+    stepped = one_step()
+    monkeypatch.setattr(train, "layer_checkpoint_policy",
+                        lambda: jax.checkpoint_policies.nothing_saveable)
+    (bare_loss, bare_aux), bare = grads()
+    bare_stepped = one_step()
+    assert loss == bare_loss
+    entries = 0
+    for name, path in NAMES:
+        leaf = np.asarray(train._leaf(got, path))
+        np.testing.assert_array_equal(leaf, train._leaf(bare, path), name)
+        entries += leaf.size
+    assert entries > 50_000
+    for key in ("losses", "loads", "experts"):
+        np.testing.assert_array_equal(aux[key], bare_aux[key], key)
+        np.testing.assert_array_equal(stepped[key], bare_stepped[key], key)
+    np.testing.assert_array_equal(stepped["grad_sq"], bare_stepped["grad_sq"])
+
+
 def test_no_gain_and_no_mixer_scalar_is_decayed():
     undecayed = {n for n, _ in NAMES if not train.is_decayed(n)}
     assert undecayed == {n for n, _ in NAMES if n.rsplit(".", 1)[-1] in (

@@ -181,18 +181,24 @@ def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
     assert row["compile_s"] < 120
 
 
+def _rows_with_texts(only: str) -> dict:
+    """{case: its row, with ``hlo`` the file of its compiled text} of one
+    child that compiles the cases named ``only`` for one v5e device."""
+    pytest.importorskip("libtpu")
+    dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
+    res = _run_aot_subprocess("--only", only, "--topology", "v5e:2x2",
+                              "--dump", dump, limit=600)
+    assert res.get("rows"), res.get("error")
+    return {r["kernel"]: dict(r, hlo=os.path.join(
+        dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
+
+
 @pytest.fixture(scope="module")
 def joyai_rows():
     """One child for the JoyAI-LLM-Flash cases: the block update at 192 /
     128 alone, plain and biased, and the whole step of the cell's own
     configuration file for one v5e device (about 65 s of the 600)."""
-    pytest.importorskip("libtpu")
-    dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
-    res = _run_aot_subprocess("--only", "joyai", "--topology", "v5e:2x2",
-                              "--dump", dump, limit=600)
-    assert res.get("rows"), res.get("error")
-    return {r["kernel"]: dict(r, hlo=os.path.join(
-        dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
+    return _rows_with_texts("joyai")
 
 
 def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
@@ -253,6 +259,48 @@ def test_latent_attentions_operands_aot_hold_no_rolled_copy(case, joyai_rows):
     assert "otpu_attn_proj" in text
     assert "_roll_static" not in text
     assert "[1,8192,32,191]" not in text
+
+
+@pytest.fixture(scope="module")
+def nemotron_rows():
+    """One child for Nemotron-3-Super's whole step of the cell's own
+    configuration file, for one v5e device (about 60 s of the 600)."""
+    return _rows_with_texts("nemotron3")
+
+
+@pytest.mark.parametrize("rows,case", [
+    ("joyai_rows", "joyai_step_1chip"),
+    ("nemotron_rows", "nemotron3_step_1chip")])
+def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
+                                                            request):
+    """A walked layer's checkpoint keeps what the expert block names
+    (``moe.CHECKPOINT_KEEPS``, PR 43), so in the step compiled for a v5e
+    no instruction under ``rematted_computation`` is a ``sort`` (the
+    dispatch's argsort, and the top-k, which the TPU's compiler writes as
+    a whole sort of (8192, E)), any other part of the top-k, the gather
+    of the chosen scores (T k single entries: 1.8 ms a layer on the
+    chip), the router's float32 product or the held experts' loop
+    (``test_train_scopes.ROUTING``); they run in the forward pass, and a
+    layer's other work is still recomputed.  The step fits the chip:
+    arguments and temporaries under its 15.75 GiB."""
+    from test_train_scopes import ROUTING
+
+    row = request.getfixturevalue(rows)[case]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    kinds = {"forward": set(), "remat": set()}
+    recomputed = 0
+    with open(row["hlo"], encoding="utf-8") as f:
+        for line in f:
+            if " = " not in line or 'op_name="' not in line:
+                continue
+            path = line.split('op_name="', 1)[1].split('"', 1)[0]
+            remat = "rematted_computation" in path
+            recomputed += remat and "otpu_attn_proj" in path
+            kinds["remat" if remat else "forward"].update(
+                k for k, is_it in ROUTING.items() if is_it(line, path))
+    assert recomputed > 20
+    assert kinds == {"forward": set(ROUTING), "remat": set()}
+    assert row["argument_bytes"] + row["peak_vmem_bytes"] < 15.75 * 2 ** 30
 
 
 @pytest.mark.slow

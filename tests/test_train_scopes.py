@@ -293,6 +293,66 @@ def test_a_checkpointed_layers_recomputation_is_told_apart(joyai, olmoe):
         assert {"forward", "backward", "update"} <= passes
 
 
+# what an expert block's routing runs as, by an instruction's own line
+# (fused ones too): the dispatch's argsort, the router's top-k (a custom
+# call on the CPU, a whole sort on the TPU), the gather of the chosen
+# scores, the router's (T, E) product, the held experts' loop
+ROUTING = {
+    "sort": lambda line, path: " sort(" in line,
+    "top-k": lambda line, path: path.endswith("/top_k"),
+    "scores' gather": lambda line, path:
+        path.endswith("otpu_router/jit(take_along_axis)/gather"),
+    "router product": lambda line, path:
+        path.endswith("otpu_router/dot_general"),
+    "experts' loop": lambda line, path:
+        " while(" in line and "otpu_experts" in path,
+}
+
+
+def routing_by_pass(cfg):
+    """{pass: the ``ROUTING`` kinds among its instructions} and {pass: its
+    scopes} of ``cfg``'s step compiled here."""
+    step, args = built(cfg)
+    kinds, scopes = {}, {}
+    for line in step.jitted.lower(*args).compile().as_text().splitlines():
+        path = re.search(r'op_name="([^"]*)"', line)
+        if " = " not in line or not path:
+            continue
+        chain, which, _ = trace.scope_of_path(path.group(1))
+        scopes.setdefault(which, set()).update(chain)
+        kinds.setdefault(which, set()).update(
+            k for k, is_it in ROUTING.items() if is_it(line, path.group(1)))
+    return kinds, scopes
+
+
+@pytest.mark.parametrize("cfg,loop_is_read", [(JOYAI, False),
+                                              (NEMOTRON, True)],
+                         ids=["joyai", "nemotron"])
+def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
+        cfg, loop_is_read, monkeypatch):
+    """``model_loss``'s checkpoint keeps what an expert block names
+    (``moe.CHECKPOINT_KEEPS``): no recomputed instruction is the
+    dispatch's sort, the router's top-k, the gather of the chosen scores
+    or the (T, E) product, nor, where the loop's sum is read by a weight
+    gradient (Nemotron's ``lat_up``; JoyAI's XLA drops by itself), the
+    held experts' loop; what is not named (attention's projections, a
+    mixer) is recomputed as before.  The bare checkpoint recomputes
+    every one of them: the patterns see what they are meant to."""
+    kinds, scopes = routing_by_pass(cfg)
+    assert kinds["forward"] == set(ROUTING)
+    assert kinds["remat"] == set()
+    assert "otpu_attn_proj" in scopes["remat"]
+    assert ("otpu_mamba" in scopes["remat"]) == (cfg is NEMOTRON)
+    # the elementwise rest of the router is recomputed: scores, weights
+    assert "otpu_router" in scopes["remat"]
+    monkeypatch.setattr(train, "layer_checkpoint_policy",
+                        lambda: jax.checkpoint_policies.nothing_saveable)
+    bare, _ = routing_by_pass(cfg)
+    assert bare["forward"] == set(ROUTING)
+    assert bare["remat"] == set(ROUTING) - (
+        set() if loop_is_read else {"experts' loop"})
+
+
 @pytest.mark.parametrize("which", ["joyai", "olmoe"])
 def test_the_updates_ops_are_the_update(which, request):
     ops = ran(request.getfixturevalue(which)[1])
