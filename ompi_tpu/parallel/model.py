@@ -13,6 +13,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.base.jaxenv import pallas_interpret
 
@@ -369,6 +370,17 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
     return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
 
+# what a layer's ``jax.checkpoint`` keeps of causal attention
+# (``train.layer_checkpoint_policy`` saves these beside an expert block's):
+# the forward kernel's two results, float32 as it writes them, which are
+# all its backward pass reads beside q, k and v.  Named in the forward
+# rule before anything reads them, so that a checkpointed layer's
+# backward pass holds no second run of the kernel.
+ATTN_OUT = "otpu_attn_out"
+ATTN_LSE = "otpu_attn_lse"
+CHECKPOINT_KEEPS = (ATTN_OUT, ATTN_LSE)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def causal_flash_attention(q, k, v, block: int, interpret: bool):
     """Causal self-attention of (b, h, s, hd) q, k and (b, h, s, hv) v
@@ -385,6 +397,8 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool):
 
 def _causal_fwd(q, k, v, block, interpret):
     o, lse = _causal_fwd_blocks(q, k, v, block, interpret)
+    o = checkpoint_name(o, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
     return o, (q, k, v, o, lse)
 
 

@@ -946,13 +946,16 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
 
 def layer_checkpoint_policy():
     """What a walked layer's ``jax.checkpoint`` keeps for its backward
-    pass: the results an expert block names (``moe.CHECKPOINT_KEEPS``)
-    and nothing else, so a layer that names nothing is recomputed whole."""
+    pass: the results an expert block names (``moe.CHECKPOINT_KEEPS``),
+    causal attention's forward results (``model.CHECKPOINT_KEEPS``: o and
+    the logsumexp) and nothing else, so a layer that names nothing is
+    recomputed whole."""
     import jax
 
-    from ompi_tpu.parallel.moe import CHECKPOINT_KEEPS
+    from ompi_tpu.parallel import model, moe
 
-    return jax.checkpoint_policies.save_only_these_names(*CHECKPOINT_KEEPS)
+    return jax.checkpoint_policies.save_only_these_names(
+        *moe.CHECKPOINT_KEEPS, *model.CHECKPOINT_KEEPS)
 
 
 def _walk_layers(run, stacked, x, bias, n: int):
@@ -1039,7 +1042,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         # that one layer's are held at a time and not every layer's;
         # with one layer there is nothing to save.  Kept from the forward
         # pass are only an expert block's named routing results
-        # (``moe.CHECKPOINT_KEEPS``)
+        # (``moe.CHECKPOINT_KEEPS``) and causal attention's o and
+        # logsumexp (``model.CHECKPOINT_KEEPS``)
         run = jax.checkpoint(run, policy=layer_checkpoint_policy())
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32

@@ -549,6 +549,11 @@ def run(topology: str = DEFAULT_TOPOLOGY, only: str | None = None,
                     getattr(mem, "temp_size_in_bytes", 0) or 0)
                 row["argument_bytes"] = int(
                     getattr(mem, "argument_size_in_bytes", 0) or 0)
+                # the most the program holds at once, arguments
+                # included: what has to fit the chip (the two above sum
+                # to more: JoyAI's step ran on a v5e at 17.86 GB of them)
+                row["peak_bytes"] = int(
+                    getattr(mem, "peak_memory_in_bytes", 0) or 0)
             except Exception:
                 pass
         except Exception as e:

@@ -162,26 +162,85 @@ def test_parameters_and_bias_after_three_steps(dp, params):
     assert moved.max() <= 3 * F32.bias_update_gamma + 1e-9 and moved.any()
 
 
-def test_what_the_checkpoint_keeps_changes_no_number(params, system,
-                                                     monkeypatch):
+def test_what_the_checkpoint_keeps_changes_no_number(params, monkeypatch):
     """A layer's checkpoint keeps the routing results an expert block
-    names (``moe.CHECKPOINT_KEEPS``): every gradient entry, and what a
-    step reports, is bit for bit what the bare checkpoint gives."""
+    names (``moe.CHECKPOINT_KEEPS``) and attention's o and logsumexp
+    (``model.CHECKPOINT_KEEPS``): every gradient entry, and what a step
+    reports, is bit for bit what the bare checkpoint gives.  The
+    gradients are compared one primitive at a time (``disable_jit``),
+    where a number depends on the program alone: run eagerly, each
+    checkpointed layer's backward pass is a compiled unit of its own, in
+    which XLA's CPU backend fuses a recomputed o into ``delta``'s sum and
+    adds that loop up in another order than over a stored o, so the bare
+    checkpoint's dq and dk move in their last bit.  The step jitted whole
+    (``run_steps``) is compared as compiled."""
+    def grads():
+        with jax.disable_jit():
+            return jax.value_and_grad(
+                lambda p: system_loss(p, F32, batch_of(0), some_bias()),
+                has_aux=True)(params)
+
+    (total, kept_aux), kept = grads()
     _, (stepped,) = run_steps(F32, params, (0,))
     monkeypatch.setattr(train, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
-    (total, aux), bare = jax.value_and_grad(
-        lambda p: system_loss(p, F32, batch_of(0), some_bias()),
-        has_aux=True)(params)
+    (bare_total, aux), bare = grads()
     _, (bare_stepped,) = run_steps(F32, params, (0,))
-    assert total == system["total"]
+    assert total == bare_total
     for name, path in train.leaf_names(F32):
-        np.testing.assert_array_equal(train._leaf(system["grads"], path),
+        np.testing.assert_array_equal(train._leaf(kept, path),
                                       train._leaf(bare, path), name)
     for key in ("losses", "loads", "experts"):
-        np.testing.assert_array_equal(system["aux"][key], aux[key], key)
+        np.testing.assert_array_equal(kept_aux[key], aux[key], key)
         np.testing.assert_array_equal(stepped[key], bare_stepped[key], key)
-    np.testing.assert_array_equal(stepped["grad_sq"], bare_stepped["grad_sq"])
+    for key in ("grad_sq", "grad_probe", "param_probe"):
+        np.testing.assert_array_equal(stepped[key], bare_stepped[key], key)
+
+
+# causal attention alone, at the widths its two callers under a
+# checkpoint give it: latent attention's (q, k 192 wide, v 128) and
+# grouped-query attention's (4 query heads on one key-value head of 128)
+SEAMS = {"latent": (4, 4, 192, 128), "grouped_query": (4, 1, 128, 128)}
+
+
+@pytest.mark.parametrize("seam", SEAMS)
+def test_attention_alone_keeps_o_and_the_logsumexp(seam):
+    """``causal_flash_attention`` under ``jax.checkpoint`` with the
+    layers' policy and with none: o, dq, dk and dv are bit for bit the
+    same (one primitive at a time, as above), and the backward pass the
+    policy leaves holds no second forward pass (no ``exp`` but the block
+    pairs' own)."""
+    nh, nkv, hd, hv = SEAMS[seam]
+    rng = np.random.default_rng(44)
+    draw = lambda n, w: jnp.asarray(rng.normal(size=(2, n, 64, w)),
+                                    jnp.float32)
+    q, k, v, w = draw(nh, hd), draw(nkv, hd), draw(nkv, hv), draw(nh, hv)
+
+    def attend(q, k, v):
+        k, v = (jnp.repeat(a, nh // nkv, 1) for a in (k, v))
+        o = model.causal_flash_attention(jnp.tanh(q), k, v, 16, True)
+        return jnp.sum(o * w), o
+
+    def exps(jaxpr):
+        inner = [getattr(sub, "jaxpr", sub) for eqn in jaxpr.eqns
+                 for val in eqn.params.values()
+                 for sub in (val if isinstance(val, (tuple, list)) else [val])]
+        return sum(eqn.primitive.name == "exp" for eqn in jaxpr.eqns) + sum(
+            exps(sub) for sub in inner if hasattr(sub, "eqns"))
+
+    got = {}
+    for name, policy in (("kept", train.layer_checkpoint_policy()),
+                         ("bare", None)):
+        run = jax.value_and_grad(jax.checkpoint(attend, policy=policy),
+                                 (0, 1, 2), has_aux=True)
+        with jax.disable_jit():
+            (_, o), grads = run(q, k, v)
+        got[name] = (o, *grads), exps(jax.make_jaxpr(run)(q, k, v).jaxpr)
+    for kept, bare in zip(got["kept"][0], got["bare"][0]):
+        np.testing.assert_array_equal(kept, bare)
+    # 4 blocks: 10 block pairs, each an exp of its scores and, forward,
+    # one of the running maximum's step
+    assert (got["kept"][1], got["bare"][1]) == (30, 50)
 
 
 def test_a_step_reports_what_it_counted(params):

@@ -268,6 +268,26 @@ def nemotron_rows():
     return _rows_with_texts("nemotron3")
 
 
+def op_paths(row):
+    """(line, ``op_name`` path) of every instruction of a row's compiled
+    text that has one."""
+    with open(row["hlo"], encoding="utf-8") as f:
+        for line in f:
+            if " = " in line and 'op_name="' in line:
+                yield line, line.split('op_name="', 1)[1].split('"', 1)[0]
+
+
+def fits_a_v5e(row) -> bool:
+    """The most a compiled step holds at once (``memory_analysis()``'s
+    ``peak_memory_in_bytes``: the arguments, which the donated state's
+    results alias, and the temporaries alive at the worst moment) lies
+    under a v5e's 15.75 GiB.  The sum of the arguments and
+    ``temp_size_in_bytes`` bounds nothing the chip needs: JoyAI's step
+    with o and the logsumexp kept reads 17.86 GB by it, compiles for the
+    v5e and runs on one (peak 14.46 GB)."""
+    return 0 < row["peak_bytes"] < 15.75 * 2 ** 30
+
+
 @pytest.mark.parametrize("rows,case", [
     ("joyai_rows", "joyai_step_1chip"),
     ("nemotron_rows", "nemotron3_step_1chip")])
@@ -281,26 +301,53 @@ def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
     of the chosen scores (T k single entries: 1.8 ms a layer on the
     chip), the router's float32 product or the held experts' loop
     (``test_train_scopes.ROUTING``); they run in the forward pass, and a
-    layer's other work is still recomputed.  The step fits the chip:
-    arguments and temporaries under its 15.75 GiB."""
+    layer's other work is still recomputed.  The step fits the chip
+    (``fits_a_v5e``)."""
     from test_train_scopes import ROUTING
 
     row = request.getfixturevalue(rows)[case]
     assert row.get("compiled"), json.dumps(row, indent=1)
     kinds = {"forward": set(), "remat": set()}
     recomputed = 0
-    with open(row["hlo"], encoding="utf-8") as f:
-        for line in f:
-            if " = " not in line or 'op_name="' not in line:
-                continue
-            path = line.split('op_name="', 1)[1].split('"', 1)[0]
-            remat = "rematted_computation" in path
-            recomputed += remat and "otpu_attn_proj" in path
-            kinds["remat" if remat else "forward"].update(
-                k for k, is_it in ROUTING.items() if is_it(line, path))
+    for line, path in op_paths(row):
+        remat = "rematted_computation" in path
+        recomputed += remat and "otpu_attn_proj" in path
+        kinds["remat" if remat else "forward"].update(
+            k for k, is_it in ROUTING.items() if is_it(line, path))
     assert recomputed > 20
     assert kinds == {"forward": set(ROUTING), "remat": set()}
-    assert row["argument_bytes"] + row["peak_vmem_bytes"] < 15.75 * 2 ** 30
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+
+
+@pytest.mark.parametrize("rows,case,calls", [
+    ("joyai_rows", "joyai_step_1chip", ["jvp(otpu_layers)/otpu_mla",
+                                        "jvp(otpu_layers)/while/body",
+                                        "jvp(otpu_mtp)/otpu_layers/otpu_mla"]),
+    ("nemotron_rows", "nemotron3_step_1chip",
+     ["jvp(otpu_layers)/otpu_attention"])])
+def test_a_checkpoints_recomputed_pass_aot_holds_no_attention_forward(
+        rows, case, calls, request):
+    """A walked layer's checkpoint keeps causal attention's o and
+    logsumexp (``model.CHECKPOINT_KEEPS``, PR 44), so in the step compiled
+    for a v5e the forward kernel (``otpu_flash_causal_forward``) stands
+    once a layer, in the forward pass, and nowhere under
+    ``rematted_computation``: JoyAI's in the dense layer, in the body
+    that the four sparse layers scan and in the module (six calls a
+    step, twelve before), Nemotron's in its one attention layer; the
+    backward kernel is where it was, and the step fits the chip
+    (``fits_a_v5e``)."""
+    row = request.getfixturevalue(rows)[case]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    kernels = [path for line, path in op_paths(row)
+               if " custom-call(" in line]
+    forward = sorted(p for p in kernels if "/otpu_flash_causal_forward/" in p)
+    assert not [p for p in forward if "rematted_computation" in p]
+    assert len(forward) == len(calls), forward
+    for path, where in zip(forward, calls):
+        assert path.startswith("jit(otpu_train_step)/" + where), path
+    assert sum("/otpu_attn_block_backward/" in p
+               for p in kernels) >= len(calls)
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
 
 
 @pytest.mark.slow

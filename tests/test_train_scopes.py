@@ -309,9 +309,19 @@ ROUTING = {
 }
 
 
-def routing_by_pass(cfg):
-    """{pass: the ``ROUTING`` kinds among its instructions} and {pass: its
-    scopes} of ``cfg``'s step compiled here."""
+# causal attention's own products (on the CPU the kernels' ``jnp`` twins:
+# scores, numerator, and the backward pairs'), which lie in an attention
+# sublayer outside its projections
+ATTENTION = {
+    "attention's products": lambda line, path:
+        path.endswith("/dot_general") and trace.scope_of_path(path)[0][-1:]
+        in (["otpu_mla"], ["otpu_attention"]),
+}
+
+
+def routing_by_pass(cfg, kinds_of=ROUTING):
+    """{pass: the kinds of ``kinds_of`` among its instructions} and
+    {pass: its scopes} of ``cfg``'s step compiled here."""
     step, args = built(cfg)
     kinds, scopes = {}, {}
     for line in step.jitted.lower(*args).compile().as_text().splitlines():
@@ -321,7 +331,7 @@ def routing_by_pass(cfg):
         chain, which, _ = trace.scope_of_path(path.group(1))
         scopes.setdefault(which, set()).update(chain)
         kinds.setdefault(which, set()).update(
-            k for k, is_it in ROUTING.items() if is_it(line, path.group(1)))
+            k for k, is_it in kinds_of.items() if is_it(line, path.group(1)))
     return kinds, scopes
 
 
@@ -351,6 +361,25 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
     assert bare["forward"] == set(ROUTING)
     assert bare["remat"] == set(ROUTING) - (
         set() if loop_is_read else {"experts' loop"})
+
+
+@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON], ids=["joyai", "nemotron"])
+def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
+                                                              monkeypatch):
+    """``model_loss``'s checkpoint keeps causal attention's o and
+    logsumexp (``model.CHECKPOINT_KEEPS``): attention's own products are
+    in the forward and the backward pass and in no recomputed one, while
+    the projections that make q, k and v (which the backward pass reads
+    and nothing keeps) are recomputed as before.  The bare checkpoint
+    recomputes the products too: the pattern sees what it is meant to."""
+    kinds, scopes = routing_by_pass(cfg, ATTENTION)
+    assert kinds["forward"] == kinds["backward"] == set(ATTENTION)
+    assert kinds.get("remat", set()) == set()
+    assert "otpu_attn_proj" in scopes["remat"]
+    monkeypatch.setattr(train, "layer_checkpoint_policy",
+                        lambda: jax.checkpoint_policies.nothing_saveable)
+    bare, _ = routing_by_pass(cfg, ATTENTION)
+    assert bare["forward"] == bare["remat"] == set(ATTENTION)
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe"])
