@@ -8,9 +8,10 @@ Drives, once, what a user gets with no ``--mca`` overrides, through the
 entry points a user calls: ``ompi_tpu.init()`` -> the single-process
 device world -> ``COMM_WORLD.*_array`` collectives owned by ``coll/xla``;
 then the flagship train step at ``OTPU_MODEL_SCALE=64`` (the repo's
-widest configuration) with the flash block kernel a TPU selects; then
-the Pallas kernels that path selects, standalone against their XLA
-twins.  Every result is checked against numpy / the jnp twin.
+widest configuration; float32, ring attention in plain ``jnp``) on its
+meshes; then the Pallas kernels a public model's step selects on a TPU,
+standalone against their XLA twins.  Every result is checked against
+numpy / the jnp twin.
 
 It is a smoke, not a benchmark: it reports seconds per phase (first call,
 which compiles, apart from later calls) and no rate.  Any exception ends
@@ -33,7 +34,7 @@ WATCHDOG_S = 1100           # the contract allows 1200 s, compile included
 MODEL_SCALE = 64            # d 512, head dim 256
 PRIMARY_BYTES = 16 << 20    # BASELINE.json's headline: f32 allreduce a rank
 SPOT_BYTES = 4 << 20
-FLASH_SHAPE = (4, 8, 2048, 2048, 128)   # bf16
+FLASH_SHAPE = (4, 8, 2048, 128)         # b, h, s, head width; bf16
 ROPE_SHAPE = (1, 8192, 32, 1536)        # b, s, heads, the latent's rank
 # b, s, heads, head width, groups, state, chunk: the hybrid cell's mixer
 SSM_SHAPE = (1, 8192, 16, 64, 1, 128, 128)
@@ -238,36 +239,25 @@ def collectives(world, clock: Clock, platform: str = "tpu",
 
 
 # -- 4. the flagship trainer -----------------------------------------------
-def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
-            expect_mosaic: bool = True) -> None:
+def trainer(devs, clock: Clock, scale: int = MODEL_SCALE) -> None:
     import jax
 
     import __graft_entry__
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel import train  # noqa: F401  (registers the var)
     from ompi_tpu.parallel.dryrun import make_step_and_args
     from ompi_tpu.parallel.mesh import MeshSpec
 
-    configs = [("float32", None), ("bfloat16", None)]
+    specs = [None]
     if len(devs) == 4:
         # the pipeline-active mesh run_training_step adds on four chips
-        configs.append(("float32", MeshSpec(dp=1, pp=2, sp=1, tp=2)))
-    dtype_var = registry.lookup("otpu_parallel_compute_dtype")
-    old_dtype = dtype_var.value
+        specs.append(MeshSpec(dp=1, pp=2, sp=1, tp=2))
     old_scale = os.environ.get("OTPU_MODEL_SCALE")
     os.environ["OTPU_MODEL_SCALE"] = str(scale)
     try:
-        for dtype, spec in configs:
-            dtype_var.set(dtype)
+        for spec in specs:
             step, (params, xd), mspec = make_step_and_args(devs, spec)
             t0 = time.perf_counter()
             compiled = step.lower(params, xd).compile()
             clock.cold += time.perf_counter() - t0
-            # a silent drop to the jnp attention branch must not pass
-            _require(_has_mosaic(compiled) == expect_mosaic,
-                     f"train step {mspec.sizes()} {dtype}: compiled HLO "
-                     f"{'lacks' if expect_mosaic else 'has'} the Mosaic "
-                     f"custom call ({MOSAIC_CALL})")
             losses = []
             for _ in range(TRAIN_STEPS):
                 params, loss = clock.call(compiled, params, xd,
@@ -277,25 +267,23 @@ def trainer(devs, clock: Clock, scale: int = MODEL_SCALE,
                      f"non-finite loss in {losses}")
             _require(all(b < a for a, b in zip(losses, losses[1:])),
                      f"loss not falling at every step: {losses}")
-            print(f"  train mesh={mspec.sizes()} {dtype} scale {scale} "
-                  f"mosaic={expect_mosaic} losses "
-                  + " -> ".join(f"{v:.6f}" for v in losses), flush=True)
+            print(f"  train mesh={mspec.sizes()} float32 scale {scale} "
+                  "losses " + " -> ".join(f"{v:.6f}" for v in losses),
+                  flush=True)
         # the driver's own entry, jitted the way the driver jits it
-        dtype_var.set(old_dtype)
         fn, args = __graft_entry__.entry()
         _, loss = clock.call(jax.jit(fn), *args, first=True)
         _require(np.isfinite(float(loss)), "entry() loss not finite")
         print(f"  __graft_entry__.entry() jitted: loss {float(loss):.6f}",
               flush=True)
     finally:
-        dtype_var.set(old_dtype)
         if old_scale is None:
             os.environ.pop("OTPU_MODEL_SCALE", None)
         else:
             os.environ["OTPU_MODEL_SCALE"] = old_scale
 
 
-# -- 5. the kernels that path selects, against their XLA twins -------------
+# -- 5. the kernels a model's step selects, against their XLA twins -------
 def kernels(clock: Clock, expect_interpret: bool = False,
             flash_shape=FLASH_SHAPE, dtype: str = "bfloat16",
             reduce_elems: int = 1 << 20, rope_shape=ROPE_SHAPE,
@@ -321,21 +309,12 @@ def kernels(clock: Clock, expect_interpret: bool = False,
                  f" the Mosaic custom call ({MOSAIC_CALL})")
         return compiled
 
-    b, h, sq, skv, d = flash_shape
+    b, h, sq, d = flash_shape
     dt = jnp.dtype(dtype)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(kq, (b, h, sq, d), dt)
-    k = jax.random.normal(kk, (b, h, skv, d), dt)
-    v = jax.random.normal(kv, (b, h, skv, d), dt)
-    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
-    num0 = jnp.zeros((b, h, sq, d), jnp.float32)
-    den0 = jnp.zeros((b, h, sq), jnp.float32)
-    bias = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(skv)[None, :],
-                     0.0, -jnp.inf).astype(jnp.float32)
     # the twin, in float32 at full matmul precision, is the reference;
     # the band is the input dtype's (the kernel rounds p to it for p@v)
     tol = 2e-2 if dt == jnp.bfloat16 else 1e-4
-    twin = jax.jit(fa._update_jnp)
 
     def close(label, g, w):
         g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
@@ -345,30 +324,11 @@ def kernels(clock: Clock, expect_interpret: bool = False,
         _require(err <= tol, f"{label}: error {err:.3e} of max|ref| "
                              f"exceeds {tol:g}")
 
-    def check(name, kernel, args):
-        up = tuple(a.astype(jnp.float32) for a in args)
-        with jax.default_matmul_precision("highest"):
-            want = jax.block_until_ready(twin(*up))
-        compiled = compile_checked(name, kernel, *args)
-        for _ in range(2):
-            got = clock.call(compiled, *args, first=False)
-        for part, g, w in zip(("m", "num", "den"), got, want):
-            close(f"{name}.{part}", g, w)
-        print(f"  {name} {flash_shape} {dtype} matches its jnp twin",
-              flush=True)
-
-    check("flash_block_update",
-          jax.jit(lambda *a: fa.flash_block_update(*a)),
-          (q, k, v, m0, num0, den0))
-    check("flash_block_update_biased",
-          jax.jit(lambda *a: fa.flash_block_update_biased(*a)),
-          (q, k, v, m0, num0, den0, bias))
-
     # causal attention's two kernels against their jnp twins, q and k as
     # wide as v (128 / 128) and half as wide again (192 / 128): the
     # forward pass in one call; the backward's fused block pair, a plain
     # pair and the diagonal one through one compiled kernel
-    from ompi_tpu.parallel import model
+    from ompi_tpu.parallel import layers, model
 
     block, f32 = min(sq, 1024), jnp.float32
     cut = lambda x, n: x[:, :, n * block:(n + 1) * block]
@@ -412,18 +372,18 @@ def kernels(clock: Clock, expect_interpret: bool = False,
               flush=True)
 
     # latent attention's q from its projection with RoPE on, the partner
-    # a product of its own and no rolled copy (``model.project_rope``; no
+    # a product of its own and no rolled copy (``layers.project_rope``; no
     # kernel: XLA's fusions), against ``rope_interleaved`` of the same
     # product in float32 at full precision, heads 192 wide, 128 unrotated
     rb, rs, rh, rank = rope_shape
     wide, theta = d * 3 // 2, 32e6
     a = jax.random.normal(kq, (rb, rs, rank), dt)
     w = jax.random.normal(kk, (rank, rh * wide), f32) / rank ** 0.5
-    got = clock.call(jax.jit(lambda a, w: model.project_rope(
+    got = clock.call(jax.jit(lambda a, w: layers.project_rope(
         a, w, rh, d, theta, dt)), a, w, first=True)
     _require(got.dtype == f32, f"project_rope gives {got.dtype}")
-    want = jax.jit(lambda a, w: model.rope_interleaved(
-        model.matmul(a, w.astype(dt), f32, weight=False).reshape(
+    want = jax.jit(lambda a, w: layers.rope_interleaved(
+        layers.matmul(a, w.astype(dt), f32, weight=False).reshape(
             rb, rs, rh, wide), theta, d, 1))(a, w)
     g, want = np.asarray(got), np.asarray(want)
     err = float(np.max(np.abs(g - want)) / np.max(np.abs(want)))

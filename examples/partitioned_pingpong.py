@@ -6,7 +6,7 @@ Rank 0 "produces" a large buffer one partition at a time (simulated
 compute per partition) and releases each slice with ``Pready`` the
 moment it is final — transfer of finished partitions overlaps the
 computation of the rest, which is the contract behind bucketed gradient
-overlap (``parallel_bucket_overlap``).  Rank 1 polls ``Parrived`` and
+overlap.  Rank 1 polls ``Parrived`` and
 consumes partitions as they land instead of waiting for the whole
 message.  Try ``--mca part_persist_min_partitions 4`` to watch N app
 partitions travel as fewer wire messages (``otpu_info --pvars`` shows

@@ -13,8 +13,7 @@ over the planner's bar is a group of one and is dispatched at its own
 ``pready``, as every bucket once was.  XLA's async dispatch means a
 group's reduction runs while the application is still producing the
 next group's buckets, which is exactly the bucketed-gradient-overlap
-pattern (``parallel_bucket_overlap`` expresses the same schedule in-jit
-for the trainer).  Any release order is correct; a group whose members
+pattern.  Any release order is correct; a group whose members
 are released one after another (last to first, or first to last)
 launches with its last member, so every launch of a step is issued
 before the last ``pready`` returns.  ``parrived(i)`` and ``test()`` are

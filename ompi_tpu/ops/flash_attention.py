@@ -1,28 +1,9 @@
-"""Pallas flash-attention kernels: ring attention's block update, and
-causal attention's forward pass and backward block pair.
+"""Pallas flash-attention kernels: causal attention's forward pass and
+its backward block pair.
 
-Ring attention (``ompi_tpu/parallel/model.py``) rotates K/V shards around
-the sequence-parallel mesh axis with ``ppermute`` and, per step, combines
-one K/V block into a running (max, numerator, denominator) softmax state.
-That per-step block combine is the FLOPs hot spot — two MXU matmuls plus
-the online-softmax rescale — and is what ``flash_block_update[_biased]``
-fuses: one VMEM round-trip instead of the five separate HBM-materialised
-intermediates (scores, max, probs, weighted-V, rescales) the jnp version
-produces.  ``ring_attention`` is their only caller.
-
-The ring/communication structure stays at the JAX level (XLA schedules the
-ICI ppermute); only the local block math drops into Pallas — the same
-split the reference makes between its coll algorithms (schedules) and its
-op kernels (``ompi/mca/op/avx``).
-
-Block update, grid: (batch*heads, q row tiles).  K/V blocks ride whole in
-VMEM (s_kv up to a few thousand at 128-lane alignment); scores compute at
-f32 on the MXU via ``preferred_element_type``; the state passes through
-HBM between two calls, which the ring's ``ppermute`` between them needs.
-
-The causal train step (``model.causal_flash_attention``), which has all
-of K and V on the chip, runs these where Mosaic compiles (a TPU; the CPU
-runs the ``jnp`` twins in ``parallel/model``):
+The causal train step (``parallel/model.causal_flash_attention``), which
+has all of K and V on the chip, runs these where Mosaic compiles (a TPU;
+the CPU runs the ``jnp`` twins in ``parallel/model``):
 
 - forward, ``flash_causal_forward``: one call a layer, q, k, v whole,
   the blocks chosen by the index maps, the softmax state in VMEM scratch
@@ -30,6 +11,10 @@ runs the ``jnp`` twins in ``parallel/model``):
 - backward, ``attn_block_backward``: the five matmuls of one (q block,
   kv block) pair, with the float32 gradient accumulators passed through
   the call in place.
+
+Scores compute in float32 on the MXU via ``preferred_element_type``.
+Ring attention's block update (``parallel/flagship.ring_attention``) is
+plain ``jnp`` on every platform and no kernel of this module.
 """
 from __future__ import annotations
 
@@ -42,184 +27,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ompi_tpu.base.jaxenv import pallas_interpret
-
-Q_TILE = 256
-
-
-def _block_kernel(scale, biased, *refs):
-    if biased:
-        bias_ref, q_ref, k_ref, v_ref, m_ref, num_ref, den_ref, \
-            mo_ref, numo_ref, deno_ref = refs
-    else:
-        q_ref, k_ref, v_ref, m_ref, num_ref, den_ref, \
-            mo_ref, numo_ref, deno_ref = refs
-        bias_ref = None
-    q = q_ref[0]            # (tq, d)
-    k = k_ref[0]            # (skv, d)
-    v = v_ref[0]            # (skv, dv)
-    m = m_ref[0]            # (tq, LANES) broadcast copies, col 0 is live
-    num = num_ref[0]        # (tq, dv)
-    den = den_ref[0]        # (tq, LANES)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (tq, skv)
-    if bias_ref is not None:
-        # additive bias per (q row, kv col): -inf entries mask (causal,
-        # padding), finite entries shift (ALiBi) — fused into the same
-        # VMEM pass
-        s = s + bias_ref[...]
-    blk_max = jnp.max(s, axis=-1, keepdims=True)         # (tq, 1)
-    new_m = jnp.maximum(m[:, :1], blk_max)               # (tq, 1)
-    c = jnp.exp(m[:, :1] - new_m)                        # (tq, 1)
-    p = jnp.exp(s - new_m)                               # (tq, skv)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (tq, dv)
-    numo_ref[0] = (num * c + pv).astype(num.dtype)
-    deno_ref[0] = (den[:, :1] * c + jnp.sum(p, axis=-1, keepdims=True)
-                   ) * jnp.ones_like(den)
-    mo_ref[0] = new_m * jnp.ones_like(m)
-
-
-def _update_jnp(q, k_blk, v_blk, m, num, den, bias=None):
-    """The same block update in plain jnp — autodiff reference and the
-    source of the custom-VJP backward (recompute, flash-style: nothing
-    beyond the step inputs is saved).  ``bias`` (sq, skv) is added to
-    the scores (broadcast over batch/heads)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk) * scale
-    if bias is not None:
-        s = s + bias
-    new_m = jnp.maximum(m, s.max(axis=-1))
-    c = jnp.exp(m - new_m)
-    p = jnp.exp(s - new_m[..., None])
-    new_num = num * c[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, v_blk)
-    new_den = den * c + p.sum(axis=-1)
-    return new_m, new_num, new_den
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def flash_block_update(q, k_blk, v_blk, m, num, den, interpret=None):
-    """One online-softmax accumulation step against a K/V block.
-
-    q: (b, h, sq, d); k_blk: (b, h, skv, d); v_blk: (b, h, skv, dv);
-    m/den: (b, h, sq); num: (b, h, sq, dv).  ``dv`` may differ from ``d``
-    (latent attention: q and k 192 wide, v and the numerator 128): the
-    scores contract over ``d``, whatever it is, and only ``dv`` shapes
-    the numerator.  Returns updated (m, num, den).  Forward runs the
-    fused Pallas kernel; reverse-mode recomputes through the jnp block
-    math (the Pallas custom-VJP pattern — kernels have no autodiff rule).
-    ``interpret``: None resolves from the process's default devices; a
-    caller tracing for other devices (a mesh) passes their mode.
-    """
-    return _update_pallas(q, k_blk, v_blk, m, num, den,
-                          interpret=interpret)
-
-
-def _flash_fwd(q, k_blk, v_blk, m, num, den, interpret):
-    return (_update_pallas(q, k_blk, v_blk, m, num, den,
-                           interpret=interpret),
-            (q, k_blk, v_blk, m, num, den))
-
-
-def _flash_bwd(interpret, res, ct):
-    _, vjp = jax.vjp(_update_jnp, *res)
-    return vjp(ct)
-
-
-flash_block_update.defvjp(_flash_fwd, _flash_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def flash_block_update_biased(q, k_blk, v_blk, m, num, den, bias,
-                              interpret=None):
-    """Block update with an additive score bias (sq, skv): -inf masks
-    (causal ring attention, padding), finite shifts (ALiBi).  Same
-    fused Pallas forward; reverse recomputes through the jnp twin."""
-    return _update_pallas(q, k_blk, v_blk, m, num, den, bias=bias,
-                          interpret=interpret)
-
-
-def _flash_biased_fwd(q, k_blk, v_blk, m, num, den, bias, interpret):
-    return (_update_pallas(q, k_blk, v_blk, m, num, den, bias=bias,
-                           interpret=interpret),
-            (q, k_blk, v_blk, m, num, den, bias))
-
-
-# _flash_bwd handles both residual arities: jax.vjp adapts to the
-# 6- (unbiased) vs 7-element (biased) tuple
-flash_block_update_biased.defvjp(_flash_biased_fwd, _flash_bwd)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _update_pallas(q, k_blk, v_blk, m, num, den, bias=None, *,
-                   interpret=None):
-    # ``interpret`` is part of the jit cache key: an explicit False (the
-    # AOT Mosaic gate) can never be served a cached interpreter trace,
-    # and vice versa.  None = the process's default devices, at trace
-    # time.
-    if interpret is None:
-        interpret = pallas_interpret()
-    b, h, sq, d = q.shape
-    skv, dv = k_blk.shape[2], v_blk.shape[-1]
-    scale = 1.0 / math.sqrt(d)
-    bh = b * h
-    tq = min(Q_TILE, sq)
-    if sq % tq:
-        tq = sq  # ragged seq tiles: fall back to one tile per (b, h)
-
-    lanes = 128
-    qf = q.reshape(bh, sq, d)
-    kf = k_blk.reshape(bh, skv, d)
-    vf = v_blk.reshape(bh, skv, dv)
-    # carry scalars per row are lane-broadcast so refs stay (…, 128)-tiled
-    mf = jnp.broadcast_to(m.reshape(bh, sq)[..., None], (bh, sq, lanes))
-    nf = num.reshape(bh, sq, dv)
-    df = jnp.broadcast_to(den.reshape(bh, sq)[..., None], (bh, sq, lanes))
-
-    grid = (bh, sq // tq)
-    row = lambda i, j: (i, j, 0)
-    blk = lambda i, j: (i, 0, 0)
-    # a block's last dimension is the array's own, so a width that is no
-    # multiple of the 128 lanes (192) is Mosaic's to lay out: it pads the
-    # tile in VMEM and the contraction takes the MXU passes of 256
-    q_spec = pl.BlockSpec((1, tq, d), row)
-    k_spec = pl.BlockSpec((1, skv, d), blk)
-    v_spec = pl.BlockSpec((1, skv, dv), blk)
-    n_spec = pl.BlockSpec((1, tq, dv), row)
-    s_spec = pl.BlockSpec((1, tq, lanes), row)
-
-    biased = bias is not None
-    in_specs = [q_spec, k_spec, v_spec, s_spec, n_spec, s_spec]
-    operands = [qf, kf, vf, mf.astype(jnp.float32), nf,
-                df.astype(jnp.float32)]
-    if biased:
-        # (sq, skv) shared across (b, h): one q-tile row slice per step
-        in_specs.insert(0, pl.BlockSpec((tq, skv), lambda i, j: (j, 0)))
-        operands.insert(0, bias.astype(jnp.float32))
-
-    # inside shard_map(check_vma=True) — the train step — every output
-    # must say which mesh axes it varies over: those of its operands
-    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
-    mo, numo, deno = pl.pallas_call(
-        functools.partial(_block_kernel, scale, biased),
-        out_shape=(
-            jax.ShapeDtypeStruct(mf.shape, jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct(nf.shape, nf.dtype, vma=vma),
-            jax.ShapeDtypeStruct(df.shape, jnp.float32, vma=vma),
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(s_spec, n_spec, s_spec),
-        interpret=interpret,
-        name="otpu_flash_block_update",
-    )(*operands)
-
-    return (mo[..., 0].reshape(b, h, sq).astype(m.dtype),
-            numo.reshape(num.shape),
-            deno[..., 0].reshape(b, h, sq).astype(den.dtype))
-
 
 #: the backward kernel's tile: so many q positions a grid step, against
 #: so many kv positions at a time (a block of 1,024 is one tile)

@@ -14,11 +14,13 @@ are first-class: a 4-axis ``Mesh`` (dp, pp, sp, tp) with
 - **tp**  — tensor parallel matmuls with ``psum`` combine; the same axis
   carries **ep** (MoE expert parallel) via ``all_to_all`` dispatch
   (≅ ``coll_base_alltoall.c`` pairwise exchange)
+
+All four axes run in the invented step of ``flagship.py``; a public
+model's step (``train.build_train_step``, exported here) shards over dp.
 """
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh, default_axis_sizes
-from ompi_tpu.parallel.train import build_train_step, init_params, model_dims
+from ompi_tpu.parallel.train import build_train_step
 
 __all__ = [
-    "MeshSpec", "make_mesh", "default_axis_sizes",
-    "build_train_step", "init_params", "model_dims",
+    "MeshSpec", "make_mesh", "default_axis_sizes", "build_train_step",
 ]

@@ -8,13 +8,13 @@ import numpy as np
 
 def make_step_and_args(devices, spec=None, layers=None):
     """Shared flagship-path setup: (jitted step, (params, x)) on a mesh."""
+    from ompi_tpu.parallel.flagship import (build_flagship_step, init_params,
+                                            model_dims)
     from ompi_tpu.parallel.mesh import make_mesh
-    from ompi_tpu.parallel.train import (build_train_step, init_params,
-                                         model_dims)
 
     mesh, mspec = make_mesh(devices, spec)
     dims = model_dims(mspec, layers)
-    step, place = build_train_step(mesh, mspec, layers=layers)
+    step, place = build_flagship_step(mesh, mspec, layers=layers)
     rng = np.random.RandomState(1)
     x = rng.normal(0, 1, (dims["batch"], dims["seq"], dims["d"]))
     params, xd = place(init_params(mspec, layers=layers), x)
@@ -54,45 +54,6 @@ def run_training_step(devices, spec=None) -> float:
         sizes["pp"] = 2
         _one_descending_step(devices[:2 * (n // 2)], MeshSpec(**sizes))
     return loss
-
-
-def run_bucket_overlap_check(devices, spec=None) -> None:
-    """Tier-1 coverage of ``parallel_bucket_overlap`` without TPU
-    access: one step with the single-psum dp sync and one with the
-    bucketed (late-layer-first Pready order) sync must produce
-    BIT-IDENTICAL parameters and loss — psum per bucket is elementwise
-    the same reduction, so any drift is a real bug."""
-    import jax
-
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel import train as _train  # registers the var
-
-    var = registry.lookup("otpu_parallel_bucket_overlap")
-    old = bool(var.value)
-    var.set(False)
-    try:
-        step, (params, xd), mspec = make_step_and_args(devices, spec)
-        base_params, base_loss = step(params, xd)
-        jax.block_until_ready(base_params)
-        var.set(True)
-        step2, (params2, xd2), _ = make_step_and_args(devices, spec)
-        new_params, new_loss = step2(params2, xd2)
-        jax.block_until_ready(new_params)
-    finally:
-        var.set(old)
-    if float(base_loss) != float(new_loss):
-        raise RuntimeError(
-            f"bucket-overlap loss diverged: {float(base_loss)!r} vs "
-            f"{float(new_loss)!r}")
-    for k in base_params:
-        a = np.asarray(base_params[k])
-        b = np.asarray(new_params[k])
-        if a.tobytes() != b.tobytes():
-            raise RuntimeError(
-                f"bucket-overlap param {k!r} not bit-identical "
-                f"(max abs diff {np.max(np.abs(a - b))})")
-    print(f"bucket-overlap dryrun ok: mesh={mspec.sizes()} params "
-          "bit-identical")
 
 
 def run_pallas_ring_check(devs, mesh, interp: bool) -> dict:
@@ -197,7 +158,6 @@ def run_pallas_ring_check(devs, mesh, interp: bool) -> dict:
                                          interpret=interp),
         want.reshape(n, m // n, n_out), tol=1e-3)
     return checks
-
 
 
 def run_tolerance_check(coll, approx_fn, exact_fn=None,

@@ -1,0 +1,145 @@
+"""What every sublayer of a public model is made of: a parameter's cast,
+the matmul with float32 results, RMSNorm with a gain, the rotary
+embeddings and the two feed-forwards.  ``parallel/experts.py`` and
+``parallel/model.py`` build on these; nothing here imports either.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def cast_param(w, dtype):
+    """A parameter leaf in the matmuls' ``dtype``, under the scope
+    ``otpu_cast``: XLA makes a pass of its own of a large leaf's cast
+    (and of its transposition), which a trace then tells from the
+    sublayer's other work."""
+    with jax.named_scope("otpu_cast"):
+        return w.astype(dtype)
+
+
+def matmul(a, w, compute_dtype, weight: bool = True):
+    """``a @ w`` with inputs in ``compute_dtype`` and a float32 result:
+    bfloat16 inputs accumulate in float32 on the MXU; float32 inputs
+    multiply at the highest precision (on a TPU the default would round
+    them to bfloat16 on the way in).  ``w`` is a parameter leaf
+    (``cast_param``) unless ``weight`` is false."""
+    f32 = jnp.dtype(compute_dtype) == jnp.float32
+    dtype = jnp.float32 if f32 else compute_dtype
+    a = a.astype(dtype)
+    w = cast_param(w, dtype) if weight else w.astype(dtype)
+    if f32:
+        return jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
+    return jnp.dot(a, w, preferred_element_type=jnp.float32)
+
+
+def rmsnorm_gain(x, gain, eps: float):
+    """RMSNorm with a learned gain, in float32 whatever ``x`` is."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * gain
+
+
+def rope(x, theta: float):
+    """Rotary position embedding on ``x`` (b, h, s, hd) at positions
+    0..s-1, the half-split form of the HF models (``rotate_half``)."""
+    hd, s = x.shape[-1], x.shape[-2]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)           # (s, hd)
+    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+
+
+def _rope_tables(x, theta: float, first: int, seq_axis: int):
+    """(cos, sin) of ``rope_interleaved``, shaped to broadcast against
+    ``x``: a pair's angle on both its entries, 1 and 0 on the entries
+    before ``first``."""
+    width, s = x.shape[-1], x.shape[seq_axis]
+    hd = width - first
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    pad = lambda a, fill: jnp.concatenate(
+        [jnp.full((s, first), fill, jnp.float32), jnp.repeat(a, 2, -1)], -1)
+    shape = [1] * x.ndim
+    shape[seq_axis], shape[-1] = s, width
+    return (pad(jnp.cos(ang), 1.0).reshape(shape),
+            pad(jnp.sin(ang), 0.0).reshape(shape))
+
+
+def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
+    """Rotary position embedding on interleaved pairs (x[2i], x[2i+1])
+    (DeepSeek-V3's ``rope_interleave``) of the entries from ``first`` on
+    of ``x``'s last axis, at positions 0..s-1 along ``seq_axis``; the
+    entries before ``first`` pass unchanged.  The pair's partner comes
+    by ``jnp.roll``, which XLA for a TPU writes to HBM as shifted copies
+    (a 191-wide and a one-lane slice each way, the lane padded to 128:
+    2.6 GB a layer and pass of the JoyAI step for q's 268 MB, offline
+    compile, PR 41), so the model no longer takes this way
+    (``project_rope``): it is the ``jnp`` twin the tests compare that
+    with."""
+    cos, sin = _rope_tables(x, theta, first, seq_axis)
+    is_first = (jnp.arange(x.shape[-1]) - first) % 2 == 0
+    partner = jnp.where(is_first, -jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
+    return x * cos + partner * sin
+
+
+def rotary_partner_columns(w, compute_dtype):
+    """The columns ``wp`` of interleaved rotary columns ``w`` (.., rot)
+    with ``a @ wp`` the rotary partner of ``a @ w``: ``wp[:, 2i] =
+    -w[:, 2i+1]``, ``wp[:, 2i+1] = w[:, 2i]``.  A product with a signed
+    permutation (each result one input times 1 or -1: exact in any
+    dtype), because a swap of neighbouring columns any other way is a
+    lane rotation or an array two lanes wide."""
+    rot = w.shape[-1]
+    i = jnp.arange(0, rot, 2)
+    swap = jnp.zeros((rot, rot), jnp.float32) \
+        .at[i, i + 1].set(1.0).at[i + 1, i].set(-1.0)
+    return matmul(w, swap, compute_dtype, weight=False).astype(w.dtype)
+
+
+def rope_partnered(x, partner, theta: float, seq_axis: int = -2):
+    """``rope_interleaved`` of ``x`` on its trailing ``partner.shape[-1]``
+    entries, given their partners (``partner[2i] = -x[2i+1]``,
+    ``partner[2i+1] = x[2i]``, counted from the first rotary entry): one
+    elementwise pass, the partner set behind the leading entries by a pad
+    (where those are a multiple of 128 lanes, as a latent head's are, it
+    starts a tile of its own)."""
+    first = x.shape[-1] - partner.shape[-1]
+    cos, sin = _rope_tables(x, theta, first, seq_axis)
+    partner = jnp.pad(partner, ((0, 0),) * (x.ndim - 1) + ((first, 0),))
+    return x * cos + partner * sin
+
+
+def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
+    """``a @ w`` (b, s, heads x width) split into ``heads`` with
+    ``rope_interleaved(.., first=first, seq_axis=1)`` on each, float32
+    (b, s, heads, width), with no shifted copy of the product: the
+    partner of column j of ``a @ w`` is, sign apart, column j^1 of the
+    same product, so ``a @ rotary_partner_columns(w's rotary columns)``
+    **is** the partner, the same dot products of the same inputs
+    accumulated the same way (for JoyAI's q 51 GFLOP a layer and pass in
+    place of the 2.6 GB the rolled copies moved, PR 41).  Its gradient
+    is autodiff's: elementwise passes and matmuls."""
+    b, s, _ = a.shape
+    w = cast_param(w, compute_dtype).reshape(w.shape[0], heads, -1)
+    wp = rotary_partner_columns(w[..., first:], compute_dtype)
+    dot = lambda cols: matmul(a, cols.reshape(cols.shape[0], -1),
+                              compute_dtype, weight=False) \
+        .reshape(b, s, heads, -1)
+    return rope_partnered(dot(w), dot(wp), theta, seq_axis=1)
+
+
+def swiglu(h, gate, up, down, compute_dtype):
+    """``down(silu(gate h) * up h)``: a dense feed-forward, or a shared
+    expert, on rows ``h`` (T, d)."""
+    act = jax.nn.silu(matmul(h, gate, compute_dtype)) \
+        * matmul(h, up, compute_dtype)
+    return matmul(act, down, compute_dtype)
+
+
+def relu2(h, up, down, compute_dtype):
+    """``down(relu(up h)^2)``: nemotron_h's feed-forward (no gate), a
+    shared expert on rows ``h`` (T, d)."""
+    act = jnp.square(jax.nn.relu(matmul(h, up, compute_dtype)))
+    return matmul(act, down, compute_dtype)

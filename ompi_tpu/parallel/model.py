@@ -1,10 +1,10 @@
-"""Per-device transformer block bodies with explicit mesh collectives.
-
-These run *inside* ``shard_map`` — the MPI-flavoured explicit-SPMD style:
-every cross-device exchange is a named collective on a mesh axis, the
-device-side mirror of the reference's coll algorithms (ring allreduce
-``coll_base_allreduce.c:341``, pairwise alltoall ``coll_base_alltoall.c``,
-binomial pipelines) rather than GSPMD auto-propagation.
+"""A public model's sublayers (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super):
+causal flash attention with its two walks of the block pairs, the three
+attention sublayers, Mamba-2's chunked scan and mixer, and
+``decoder_layer``, which chooses a layer's sublayers by what it holds.
+The primitives come from ``parallel/layers.py`` and the expert blocks
+from ``parallel/experts.py``; ``parallel/train.py`` builds the step on
+``decoder_layer``.
 """
 from __future__ import annotations
 
@@ -15,301 +15,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ompi_tpu.base.jaxenv import pallas_interpret
-
-
-def rmsnorm(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-
-
-def ring_attention(q, k, v, axis: str, n_shards: int, use_flash=None,
-                   causal: bool = False, interpret=None):
-    """Flash-style ring attention over the sequence-parallel axis.
-
-    q/k/v local: (b, h_local, s_local, hd).  K/V blocks rotate around the
-    ``axis`` ring via ``ppermute`` (the CP/ring-attention neighbor exchange,
-    SURVEY.md §2.6) while the numerator/denominator accumulate with the
-    running-max rescaling, so memory stays O(s_local) regardless of the
-    global sequence length — long context is a first-class mesh axis.
-
-    ``causal=True`` applies the autoregressive mask at GLOBAL positions:
-    shard i's queries own rows [i*s_local, (i+1)*s_local); the block
-    visiting at ring step t originated at shard (i-t) mod n, so an
-    additive 0/-inf bias built from the two shard offsets masks exactly
-    the future positions.  Step 0 is the diagonal block (every query
-    row sees at least its own position), which keeps the running max
-    finite before any fully-masked later block arrives.
-
-    The per-step block combine (two MXU matmuls + online-softmax rescale)
-    is the hot op: on TPU it drops into the fused Pallas kernel
-    (``ompi_tpu/ops/flash_attention.py``); the ring structure itself stays
-    at the XLA level so the compiler schedules the ICI ppermute.
-
-    ``interpret`` is the Pallas mode of the devices this is traced for
-    (None: the process's default devices); the fused kernel is the
-    default exactly where it compiles through Mosaic.
-    """
-    hd = q.shape[-1]
-    s_local = q.shape[-2]
-    scale = 1.0 / math.sqrt(hd)
-    if interpret is None:
-        interpret = pallas_interpret()
-    if use_flash is None:
-        use_flash = not interpret
-    # derive the accumulator inits FROM q (0*q + const) so they inherit
-    # q's varying-manifest axes: fresh jnp.zeros/full would be unvarying
-    # and the scan carry would trip the vma checker under check_vma=True
-    m0 = q[..., 0] * 0 - jnp.inf
-    num0 = q * 0
-    den0 = q[..., 0] * 0
-    perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-    my = jax.lax.axis_index(axis) if n_shards > 1 else 0
-
-    def step_bias(t):
-        # kv block at step t came from shard (my - t) mod n
-        src = jax.lax.rem(my - t + n_shards, n_shards)
-        qpos = my * s_local + jnp.arange(s_local)[:, None]
-        kpos = src * s_local + jnp.arange(s_local)[None, :]
-        # q.dtype (not f32): a wider bias would promote the scan
-        # carry under bfloat16 compute and break lax.scan's
-        # carry-type invariant; the flash kernel upcasts internally
-        return jnp.where(qpos >= kpos, 0.0, -jnp.inf).astype(q.dtype)
-
-    def body(carry, t):
-        k_blk, v_blk, m, num, den = carry
-        bias = step_bias(t) if causal else None
-        if use_flash:
-            from ompi_tpu.ops.flash_attention import (
-                flash_block_update, flash_block_update_biased)
-
-            if causal:
-                new_m, num, den = flash_block_update_biased(
-                    q, k_blk, v_blk, m, num, den, bias, interpret)
-            else:
-                new_m, num, den = flash_block_update(q, k_blk, v_blk, m,
-                                                     num, den, interpret)
-        else:
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk) * scale
-            if bias is not None:
-                s = s + bias
-            new_m = jnp.maximum(m, s.max(axis=-1))
-            c = jnp.exp(m - new_m)
-            p = jnp.exp(s - new_m[..., None])
-            num = num * c[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, v_blk)
-            den = den * c + p.sum(axis=-1)
-        if n_shards > 1:
-            k_blk = jax.lax.ppermute(k_blk, axis, perm)
-            v_blk = jax.lax.ppermute(v_blk, axis, perm)
-        return (k_blk, v_blk, new_m, num, den), None
-
-    (_, _, _, num, den), _ = jax.lax.scan(
-        body, (k, v, m0, num0, den0), jnp.arange(n_shards))
-    return num / den[..., None]
-
-
-def ulysses_attention(q, k, v, axis: str, n_shards: int,
-                      causal: bool = False):
-    """DeepSpeed-Ulysses sequence parallelism: all-to-all head↔sequence
-    reshard instead of the ring's K/V rotation.
-
-    q/k/v local: (b, h_local, s_local, hd) with h_local % n_shards == 0.
-    One ``all_to_all`` turns the sequence axis local-complete (each shard
-    keeps h_local/n_shards heads over the FULL sequence), attention runs
-    locally with no inter-step dependency, and the inverse all_to_all
-    restores sequence sharding.  Two reshard phases (four ``all_to_all``
-    calls: q/k/v scatter + the output inverse) vs the ring's
-    n_shards ppermute steps — better for short-ish sequences on fast ICI;
-    the ring wins at very long context (O(s_local) memory).  The MoE-
-    dispatch-shaped exchange of SURVEY.md §2.6's alltoall row.
-    """
-    if n_shards == 1:
-        return _full_attention(q, k, v, causal)
-
-    def scatter_heads(t):   # (b, h_l, s_l, hd) -> (b, h_l/n, s, hd)
-        return jax.lax.all_to_all(t, axis, split_axis=1, concat_axis=2,
-                                  tiled=True)
-
-    # after the reshard each shard holds the FULL sequence, so the
-    # causal mask is the plain global lower-triangle
-    o = _full_attention(scatter_heads(q), scatter_heads(k),
-                        scatter_heads(v), causal)  # (b, h_l/n, s, hd)
-    # inverse reshard: full-sequence heads -> my seq block, all heads
-    return jax.lax.all_to_all(o, axis, split_axis=2, concat_axis=1,
-                              tiled=True)
-
-
-def _full_attention(q, k, v, causal: bool = False):
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
-    if causal:
-        sq, skv = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(skv)[None, :]
-        s = jnp.where(mask, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", w, v)
-
-
-def attention_block(p, x, *, sp: int, tp: int, n_heads_local: int,
-                    interpret: bool, sp_impl: str = "ring",
-                    causal: bool = False):
-    """Sequence-parallel attention with tp-sharded heads; psum output proj.
-
-    ``interpret`` is the Pallas mode of the mesh this is traced for,
-    resolved once by the caller (``build_train_step``): it decides both
-    whether ring attention takes the fused kernel and how that compiles.
-
-    x local: (b, s_local, d) replicated over tp.  Head projections are
-    column-sharded over tp (h_local = H/tp); the output projection is
-    row-sharded, so its partial products combine with a ``psum`` over tp —
-    the tensor-parallel allreduce (DP/TP table row, SURVEY.md §2.6).
-
-    ``sp_impl`` picks the context-parallel scheme: "ring" (ppermute K/V
-    rotation, O(s_local) memory — long context) or "ulysses" (all-to-all
-    head↔seq reshard, 2 collectives — short/medium context on fast ICI).
-    """
-    b, s_l, d = x.shape
-    h = rmsnorm(x)
-
-    def heads(w):
-        y = h @ w  # (b, s_l, h_local*hd)
-        return y.reshape(b, s_l, n_heads_local, -1).transpose(0, 2, 1, 3)
-
-    q, k, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
-    if sp_impl == "ulysses" and sp > 1:
-        if n_heads_local % sp:
-            # silent ring fallback would invalidate any collective-count
-            # comparison the user is running — fail loudly instead
-            raise ValueError(
-                f"ulysses needs local heads divisible by sp "
-                f"({n_heads_local} % {sp}); use sp_impl='ring'")
-        o = ulysses_attention(q, k, v, "sp", sp,
-                              causal=causal)        # (b, h_l, s_l, hd)
-    else:
-        o = ring_attention(q, k, v, "sp", sp, causal=causal,
-                           interpret=interpret)     # (b, h_l, s_l, hd)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s_l, -1)  # (b, s_l, h_l*hd)
-    o = o @ p["wo"]
-    if tp > 1:
-        o = jax.lax.psum(o, "tp")
-    return x + o
-
-
-def mlp_block(p, x, *, tp: int):
-    """Megatron-style tp MLP: column-shard w1, row-shard w2, psum combine."""
-    h = rmsnorm(x)
-    y = jax.nn.gelu(h @ p["w1"]) @ p["w2"]
-    if tp > 1:
-        y = jax.lax.psum(y, "tp")
-    return x + y
-
-
-def moe_block(p, x, *, tp: int, n_experts: int, capacity: int):
-    """Top-1 MoE with experts sharded over tp (the ep axis) via all_to_all.
-
-    Local tokens are chunked over tp (each tp shard routes its slice),
-    dispatched to expert-home shards with ``all_to_all`` (the MoE dispatch
-    ≅ pairwise alltoall, SURVEY.md §2.6 EP row), processed by the local
-    expert FFNs, returned by the inverse all_to_all, and the chunks
-    re-replicated with ``all_gather``.  Static capacity per (expert,
-    source-shard); overflow tokens fall through on the residual path.
-    """
-    b, s_l, d = x.shape
-    xf = rmsnorm(x).reshape(b * s_l, d)
-    t = xf.shape[0]
-    tc = t // tp
-    e_l = n_experts // tp
-    r = jax.lax.axis_index("tp") if tp > 1 else 0
-    chunk = jax.lax.dynamic_slice_in_dim(xf, r * tc, tc, 0)  # (tc, d)
-
-    logits = chunk @ p["wr"]                        # (tc, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    eid = jnp.argmax(probs, axis=-1)                # (tc,)
-    # routing bookkeeping in f32 ALWAYS: bf16 cumsum cannot count
-    # past 256 exactly, silently colliding capacity slots at
-    # production token counts (compute_dtype must not leak here)
-    oh = jax.nn.one_hot(eid, n_experts, dtype=jnp.float32)       # (tc, E)
-    pos = (jnp.cumsum(oh, axis=0) - 1.0) * oh                    # (tc, E)
-    keep = oh * (pos < capacity)
-    pos_oh = jax.nn.one_hot(
-        jnp.clip(pos.astype(jnp.int32), 0, capacity - 1), capacity,
-        dtype=xf.dtype)                                          # (tc, E, cap)
-    # mask back to compute dtype (exact 0/1): the expert einsums
-    # and the residual must stay in compute precision
-    disp = (keep[..., None] * pos_oh).astype(xf.dtype)           # (tc, E, cap)
-
-    ex_in = jnp.einsum("tec,td->ecd", disp, chunk)   # (E, cap, d)
-    ex_in = ex_in.reshape(tp, e_l, capacity, d)
-    if tp > 1:
-        ex_in = jax.lax.all_to_all(ex_in, "tp", split_axis=0, concat_axis=0)
-    # (tp, e_l, cap, d): leading dim is now source shard
-    ex_in = ex_in.transpose(1, 0, 2, 3).reshape(e_l, tp * capacity, d)
-    hid = jax.nn.gelu(jnp.einsum("etd,edf->etf", ex_in, p["we1"]))
-    ex_out = jnp.einsum("etf,efd->etd", hid, p["we2"])
-    ex_out = ex_out.reshape(e_l, tp, capacity, d).transpose(1, 0, 2, 3)
-    if tp > 1:
-        ex_out = jax.lax.all_to_all(ex_out, "tp", split_axis=0, concat_axis=0)
-    ex_out = ex_out.reshape(n_experts, capacity, d)
-
-    gate = jnp.einsum("tec,te->t", disp, probs)      # kept-assignment prob
-    out_chunk = jnp.einsum("tec,ecd->td", disp, ex_out) * gate[:, None]
-    if tp > 1:
-        out = jax.lax.all_gather(out_chunk, "tp", axis=0, tiled=True)  # (t, d)
-    else:
-        out = out_chunk
-    return x + out.reshape(b, s_l, d)
-
-
-def transformer_block(p, x, *, sp, tp, n_heads_local, n_experts, capacity,
-                      interpret: bool, sp_impl: str = "ring",
-                      causal: bool = False):
-    x = attention_block(p, x, sp=sp, tp=tp, n_heads_local=n_heads_local,
-                        sp_impl=sp_impl, causal=causal, interpret=interpret)
-    x = mlp_block(p, x, tp=tp)
-    x = moe_block(p, x, tp=tp, n_experts=n_experts, capacity=capacity)
-    return x
-
-
-# -- a public model's blocks (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super):
-# parallel/train.py's model path ---------------------------------------------
-def cast_param(w, dtype):
-    """A parameter leaf in the matmuls' ``dtype``, under the scope
-    ``otpu_cast``: XLA makes a pass of its own of a large leaf's cast
-    (and of its transposition), which a trace then tells from the
-    sublayer's other work."""
-    with jax.named_scope("otpu_cast"):
-        return w.astype(dtype)
-
-
-def matmul(a, w, compute_dtype, weight: bool = True):
-    """``a @ w`` with inputs in ``compute_dtype`` and a float32 result:
-    bfloat16 inputs accumulate in float32 on the MXU; float32 inputs
-    multiply at the highest precision (on a TPU the default would round
-    them to bfloat16 on the way in).  ``w`` is a parameter leaf
-    (``cast_param``) unless ``weight`` is false."""
-    f32 = jnp.dtype(compute_dtype) == jnp.float32
-    dtype = jnp.float32 if f32 else compute_dtype
-    a = a.astype(dtype)
-    w = cast_param(w, dtype) if weight else w.astype(dtype)
-    if f32:
-        return jnp.dot(a, w, precision=jax.lax.Precision.HIGHEST)
-    return jnp.dot(a, w, preferred_element_type=jnp.float32)
-
-
-def rmsnorm_gain(x, gain, eps: float):
-    """RMSNorm with a learned gain, in float32 whatever ``x`` is."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                             + eps) * gain
-
-
-def rope(x, theta: float):
-    """Rotary position embedding on ``x`` (b, h, s, hd) at positions
-    0..s-1, the half-split form of the HF models (``rotate_half``)."""
-    hd, s = x.shape[-1], x.shape[-2]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)           # (s, hd)
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+from ompi_tpu.parallel import experts
+from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
+                                      rope, swiglu)
 
 
 def _tri_bias(block: int):
@@ -541,85 +249,6 @@ def olmoe_attention(p, x, cfg, *, interpret: bool):
         return x + matmul(o, p["wo"], dt)
 
 
-def _rope_tables(x, theta: float, first: int, seq_axis: int):
-    """(cos, sin) of ``rope_interleaved``, shaped to broadcast against
-    ``x``: a pair's angle on both its entries, 1 and 0 on the entries
-    before ``first``."""
-    width, s = x.shape[-1], x.shape[seq_axis]
-    hd = width - first
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    pad = lambda a, fill: jnp.concatenate(
-        [jnp.full((s, first), fill, jnp.float32), jnp.repeat(a, 2, -1)], -1)
-    shape = [1] * x.ndim
-    shape[seq_axis], shape[-1] = s, width
-    return (pad(jnp.cos(ang), 1.0).reshape(shape),
-            pad(jnp.sin(ang), 0.0).reshape(shape))
-
-
-def rope_interleaved(x, theta: float, first: int = 0, seq_axis: int = -2):
-    """Rotary position embedding on interleaved pairs (x[2i], x[2i+1])
-    (DeepSeek-V3's ``rope_interleave``) of the entries from ``first`` on
-    of ``x``'s last axis, at positions 0..s-1 along ``seq_axis``; the
-    entries before ``first`` pass unchanged.  The pair's partner comes
-    by ``jnp.roll``, which XLA for a TPU writes to HBM as shifted copies
-    (a 191-wide and a one-lane slice each way, the lane padded to 128:
-    2.6 GB a layer and pass of the JoyAI step for q's 268 MB, offline
-    compile, PR 41), so the model no longer takes this way
-    (``project_rope``): it is the ``jnp`` twin the tests compare that
-    with."""
-    cos, sin = _rope_tables(x, theta, first, seq_axis)
-    is_first = (jnp.arange(x.shape[-1]) - first) % 2 == 0
-    partner = jnp.where(is_first, -jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
-    return x * cos + partner * sin
-
-
-def rotary_partner_columns(w, compute_dtype):
-    """The columns ``wp`` of interleaved rotary columns ``w`` (.., rot)
-    with ``a @ wp`` the rotary partner of ``a @ w``: ``wp[:, 2i] =
-    -w[:, 2i+1]``, ``wp[:, 2i+1] = w[:, 2i]``.  A product with a signed
-    permutation (each result one input times 1 or -1: exact in any
-    dtype), because a swap of neighbouring columns any other way is a
-    lane rotation or an array two lanes wide."""
-    rot = w.shape[-1]
-    i = jnp.arange(0, rot, 2)
-    swap = jnp.zeros((rot, rot), jnp.float32) \
-        .at[i, i + 1].set(1.0).at[i + 1, i].set(-1.0)
-    return matmul(w, swap, compute_dtype, weight=False).astype(w.dtype)
-
-
-def rope_partnered(x, partner, theta: float, seq_axis: int = -2):
-    """``rope_interleaved`` of ``x`` on its trailing ``partner.shape[-1]``
-    entries, given their partners (``partner[2i] = -x[2i+1]``,
-    ``partner[2i+1] = x[2i]``, counted from the first rotary entry): one
-    elementwise pass, the partner set behind the leading entries by a pad
-    (where those are a multiple of 128 lanes, as a latent head's are, it
-    starts a tile of its own)."""
-    first = x.shape[-1] - partner.shape[-1]
-    cos, sin = _rope_tables(x, theta, first, seq_axis)
-    partner = jnp.pad(partner, ((0, 0),) * (x.ndim - 1) + ((first, 0),))
-    return x * cos + partner * sin
-
-
-def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
-    """``a @ w`` (b, s, heads x width) split into ``heads`` with
-    ``rope_interleaved(.., first=first, seq_axis=1)`` on each, float32
-    (b, s, heads, width), with no shifted copy of the product: the
-    partner of column j of ``a @ w`` is, sign apart, column j^1 of the
-    same product, so ``a @ rotary_partner_columns(w's rotary columns)``
-    **is** the partner, the same dot products of the same inputs
-    accumulated the same way (for JoyAI's q 51 GFLOP a layer and pass in
-    place of the 2.6 GB the rolled copies moved, PR 41).  Its gradient
-    is autodiff's: elementwise passes and matmuls."""
-    b, s, _ = a.shape
-    w = cast_param(w, compute_dtype).reshape(w.shape[0], heads, -1)
-    wp = rotary_partner_columns(w[..., first:], compute_dtype)
-    dot = lambda cols: matmul(a, cols.reshape(cols.shape[0], -1),
-                              compute_dtype, weight=False) \
-        .reshape(b, s, heads, -1)
-    return rope_partnered(dot(w), dot(wp), theta, seq_axis=1)
-
-
 def mla_attention(p, x, cfg, *, interpret: bool):
     """DeepSeek-V3's latent attention sublayer (arXiv:2412.19437 section
     2.1.1) on the residual stream ``x`` (b, s, d) float32: pre-norm; q
@@ -652,21 +281,6 @@ def mla_attention(p, x, cfg, *, interpret: bool):
     with jax.named_scope("otpu_attn_proj"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
         return x + matmul(o, p["wo"], dt)
-
-
-def swiglu(h, gate, up, down, compute_dtype):
-    """``down(silu(gate h) * up h)``: a dense feed-forward, or a shared
-    expert, on rows ``h`` (T, d)."""
-    act = jax.nn.silu(matmul(h, gate, compute_dtype)) \
-        * matmul(h, up, compute_dtype)
-    return matmul(act, down, compute_dtype)
-
-
-def relu2(h, up, down, compute_dtype):
-    """``down(relu(up h)^2)``: nemotron_h's feed-forward (no gate), a
-    shared expert on rows ``h`` (T, d)."""
-    act = jnp.square(jax.nn.relu(matmul(h, up, compute_dtype)))
-    return matmul(act, down, compute_dtype)
 
 
 def gqa_attention(p, x, cfg, *, interpret: bool):
@@ -811,18 +425,16 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
     the layer holds and the configuration's published keys say.  A layer
     of a ``hybrid_override_pattern`` has **one** sublayer: the Mamba-2
     mixer where it holds ``in_proj``, the latent relu2 expert block
-    (``moe.moe_latent_block``) where it holds a router, else
+    (``experts.moe_latent_block``) where it holds a router, else
     grouped-query attention without RoPE.  Any other model's layer has
     attention (latent where ``kv_lora_rank`` is set, else OLMoE's) and
     then a dense SwiGLU where the layer has no router, else the sparse
-    MLP (``moe.moe_sorted_block``: every expert here, softmax scores; or
-    ``moe.moe_shared_local_block``: a share of the experts beside a
+    MLP (``experts.moe_sorted_block``: every expert here, softmax scores; or
+    ``experts.moe_shared_local_block``: a share of the experts beside a
     shared one, sigmoid scores chosen under ``bias``).  Returns (x, the
     router's statistics, what the router, or a mixer's scan, read and
     made by token row); the last two are empty for a layer with
     neither."""
-    from ompi_tpu.parallel import moe
-
     if cfg.hybrid_override_pattern:
         if "in_proj" in p:
             with jax.named_scope("otpu_mamba"):
@@ -833,7 +445,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
                 return x + gqa_attention(p, x, cfg, interpret=interpret), \
                     {}, {}
         with jax.named_scope("otpu_moe"):
-            y, stats, routed = moe.moe_latent_block(p, x, cfg, bias)
+            y, stats, routed = experts.moe_latent_block(p, x, cfg, bias)
         return x + y, stats, routed
     if cfg.kv_lora_rank:
         with jax.named_scope("otpu_mla"):
@@ -849,7 +461,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
         return x + y.reshape(x.shape), {}, {}
     with jax.named_scope("otpu_moe"):
         if cfg.scoring_func == "sigmoid":
-            y, stats, routed = moe.moe_shared_local_block(p, x, cfg, bias)
+            y, stats, routed = experts.moe_shared_local_block(p, x, cfg, bias)
         else:
-            y, stats, routed = moe.moe_sorted_block(p, x, cfg)
+            y, stats, routed = experts.moe_sorted_block(p, x, cfg)
     return x + y, stats, routed

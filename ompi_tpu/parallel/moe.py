@@ -803,351 +803,3 @@ def main(argv: Optional[list] = None) -> int:
 
 if __name__ == "__main__":
     raise SystemExit(main())
-
-
-# -- a learned top-k router with no dropped token (parallel/train.py's
-# model path; the hash-gated host trainer above is untouched) -----------
-def route_topk(logits, top_k: int, normalize: bool = False):
-    """``softmax`` over every expert, then the ``top_k`` largest:
-    returns (probs (T, E), weights (T, k)) and experts (T, k).
-    The weights are the chosen probabilities as they stand unless
-    ``normalize`` (OLMoE's ``norm_topk_prob`` is false)."""
-    import jax
-    import jax.numpy as jnp
-
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
-    if normalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return probs, weights, experts
-
-
-def sorted_dispatch(experts, n_experts: int):
-    """Sort-and-gather dispatch of the T x k token-slots: returns
-    (token of each sorted slot, where each (token, k) slot went, the
-    slots each expert received).  Every slot is kept: a group is as
-    long as its expert is popular, so no token is ever dropped."""
-    import jax.numpy as jnp
-
-    t, k = experts.shape
-    flat = experts.reshape(t * k)
-    order = jnp.argsort(flat, stable=True)
-    place = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
-    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
-    return order // k, place.reshape(t, k), sizes
-
-
-def _grouped_matmul(sizes, compute_dtype):
-    """``gmm(a, w)``: rows of ``a`` sorted by expert times the experts'
-    stacked matrices ``w`` (``lax.ragged_dot``: group e is the
-    ``sizes[e]`` rows that expert e received), inputs in
-    ``compute_dtype``, float32 results."""
-    import jax
-    import jax.numpy as jnp
-
-    from ompi_tpu.parallel.model import cast_param
-
-    f32 = jnp.dtype(compute_dtype) == jnp.float32
-    prec = jax.lax.Precision.HIGHEST if f32 else None
-
-    def gmm(a, w):
-        return jax.lax.ragged_dot(
-            a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
-            precision=prec, preferred_element_type=jnp.float32)
-
-    return gmm
-
-
-def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
-    """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
-    up x)`` as three grouped matmuls (``_grouped_matmul``)."""
-    import jax
-
-    gmm = _grouped_matmul(sizes, compute_dtype)
-    hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
-    return gmm(hidden, down)
-
-
-def grouped_relu2_ffn(xs, up, down, sizes, compute_dtype):
-    """relu2 experts (nemotron_h: two matrices, no gate) on slots sorted
-    by expert: ``down(relu(up x)^2)`` as two grouped matmuls
-    (``_grouped_matmul``)."""
-    import jax
-    import jax.numpy as jnp
-
-    gmm = _grouped_matmul(sizes, compute_dtype)
-    return gmm(jnp.square(jax.nn.relu(gmm(xs, up))), down)
-
-
-def moe_sorted_block(p, x, cfg):
-    """OLMoE's sparse MLP sublayer on the residual stream ``x`` (b, s,
-    d): pre-norm, a learned router (logits and softmax in float32), the
-    top k of all experts with no capacity, sort-and-gather dispatch,
-    grouped expert matmuls, weighted combine.  Returns (the sublayer's
-    output, before the residual add; the router's statistics: slots an
-    expert received, summed probabilities an expert, summed squared
-    logsumexp of the logits; and by token row what the router read and
-    made: ``in`` (T, d), ``logits`` (T, E), ``lse`` (T,), ``weights`` and
-    ``experts`` (T, k))."""
-    import jax
-    import jax.numpy as jnp
-
-    from ompi_tpu.parallel.model import rmsnorm_gain
-
-    b, s, d = x.shape
-    t, k = b * s, cfg.num_experts_per_tok
-    h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
-    with jax.named_scope("otpu_router"):
-        logits = jnp.dot(h, p["router"],
-                         precision=jax.lax.Precision.HIGHEST)
-        probs, weights, experts = route_topk(logits, k, cfg.norm_topk_prob)
-    with jax.named_scope("otpu_dispatch"):
-        token, place, sizes = sorted_dispatch(experts, cfg.num_experts)
-    with jax.named_scope("otpu_experts"):
-        y = grouped_expert_ffn(h.astype(cfg.compute_dtype)[token],
-                               p["gate"], p["up"], p["down"], sizes,
-                               cfg.compute_dtype)
-    with jax.named_scope("otpu_combine"):
-        out = jnp.sum(y[place] * weights[..., None], axis=1)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    stats = {"slots": sizes.astype(jnp.float32),
-             "prob_sum": jnp.sum(probs, axis=0),
-             "z_sum": jnp.sum(lse * lse)}
-    return out.reshape(b, s, d), stats, {
-        "in": h, "logits": logits, "lse": lse, "weights": weights,
-        "experts": experts}
-
-
-# -- a share of the routed experts beside a shared one (DeepSeek-V3's
-# expert layer on one rank of an expert-parallel deployment) -------------
-
-# what a layer's ``jax.checkpoint`` keeps of an expert block
-# (``train.model_loss``'s policy saves these names and nothing else): the
-# router's float32 product, the integers chosen and sorted from it, the
-# chosen experts' scores, and the held experts' sum in the latent.  Small
-# beside a layer's activations, and dear to make again: the six-pass
-# product, the top-k (a whole sort on a TPU), the gather entry by entry,
-# the argsort, the loop.
-ROUTER_LOGITS = "otpu_router_logits"
-CHOSEN_EXPERTS = "otpu_chosen_experts"
-CHOSEN_SCORES = "otpu_chosen_scores"
-DISPATCH_ORDER = "otpu_dispatch_order"
-DISPATCH_SIZES = "otpu_dispatch_sizes"
-EXPERT_SLOTS = "otpu_expert_slots"
-LATENT_SUM = "otpu_latent_sum"
-CHECKPOINT_KEEPS = (ROUTER_LOGITS, CHOSEN_EXPERTS, CHOSEN_SCORES,
-                    DISPATCH_ORDER, DISPATCH_SIZES, EXPERT_SLOTS, LATENT_SUM)
-
-
-def route_sigmoid_bias(logits, bias, top_k: int, normalize: bool,
-                       scale: float):
-    """DeepSeek-V3's ``noaux_tc`` routing with one group: ``sigmoid``
-    scores over every expert, the ``top_k`` largest of score + ``bias``
-    (the balancing bias enters the choice and nothing else), weights the
-    chosen scores themselves, normalised to sum to one if ``normalize``
-    and times ``scale``.  Returns (scores (T, E), weights (T, k),
-    experts (T, k)).  The experts are named (``CHOSEN_EXPERTS``) before
-    anything reads them, and their scores as gathered (``CHOSEN_SCORES``),
-    so what a checkpoint recomputes of the weights is the normalisation:
-    no second top-k, and no second gather of T k single entries."""
-    import jax
-    import jax.numpy as jnp
-    from jax.ad_checkpoint import checkpoint_name
-
-    scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-    experts = checkpoint_name(experts, CHOSEN_EXPERTS)
-    weights = checkpoint_name(
-        jnp.take_along_axis(scores, experts, axis=-1), CHOSEN_SCORES)
-    if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20)
-    return scores, weights * scale, experts
-
-
-def local_dispatch(experts, first: int, n_here: int):
-    """The T x k token-slots with those of the ``n_here`` experts held
-    here (``first`` and up) in front, sorted by expert: returns (the
-    slots in that order, the slots each held expert received).  Only
-    integers are sorted; no row of activations moves here."""
-    import jax.numpy as jnp
-
-    t, k = experts.shape
-    here = experts.reshape(t * k) - first
-    key = jnp.where((here >= 0) & (here < n_here), here, n_here)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((n_here + 1,), jnp.int32).at[key].add(1)[:n_here]
-    return order, sizes
-
-
-def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
-                     ffn=grouped_expert_ffn):
-    """The held experts' weighted part of the layer's output (T, d):
-    gather the held slots' rows, grouped matmuls (``ffn`` over the held
-    experts' stacked matrices ``mats``: SwiGLU's three, or relu2's two
-    with ``grouped_relu2_ffn``), scatter-add by token.
-    The slots held vary from step to step (0 to every slot a token can
-    send here) and none is dropped.  They are walked in chunks of twice
-    the mean load's rows by a loop that runs as many times as the held
-    slots need (``lax.fori_loop`` to a count read on the device): gather,
-    matmuls and scatter cost by the slots that are here, to a chunk, and
-    not by T x k, and one chunk's buffers are held at a time.  Such a
-    loop has no transpose, so the backward pass is written out: the same
-    loop over the same chunks, each chunk's forward recomputed and its
-    cotangents added up."""
-    import jax
-    import jax.numpy as jnp
-
-    t, d = h.shape
-    k, n_here = cfg.num_experts_per_tok, sizes.shape[0]
-    mean = max(1, t * k * n_here // cfg.num_experts)
-    rows = 2 * max(4, 1 << (mean - 1).bit_length())
-    padded = -(-t * k // rows) * rows
-    order = jnp.pad(order, (0, padded - t * k))
-
-    def chunk(lo, order, sizes, h, flat_w, *mats):
-        """Chunk ``lo``'s (token of each row, its weighted output)."""
-        with jax.named_scope("otpu_dispatch"):
-            slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-            token = slot // k
-            ends = jnp.cumsum(sizes)
-            live = lo + jnp.arange(rows) < ends[-1]
-            here = jnp.clip(jnp.minimum(ends, lo + rows)
-                            - jnp.maximum(ends - sizes, lo), 0, rows)
-        # rows past the last held slot belong to no group: a grouped
-        # matmul leaves them as they were in memory (seen on the v5e:
-        # NaN), in its transposes too, so they are cut off on both sides
-        xs = jnp.where(live[:, None], h[token], 0.0)
-        y = ffn(xs, *mats, here, cfg.compute_dtype)
-        with jax.named_scope("otpu_combine"):
-            w = jnp.where(live, flat_w[slot], 0.0)
-            return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
-
-    def trips(sizes):
-        return (jnp.sum(sizes) + rows - 1) // rows
-
-    @jax.custom_vjp
-    def run(order, sizes, h, flat_w, *mats):
-        def body(c, out):
-            token, y = chunk(c * rows, order, sizes, h, flat_w, *mats)
-            with jax.named_scope("otpu_combine"):
-                return out.at[token].add(y)
-        return jax.lax.fori_loop(0, trips(sizes), body, h * 0)
-
-    def fwd(*args):
-        return run(*args), args
-
-    def bwd(args, ct):
-        order, sizes, *diff = args
-
-        def body(c, acc):
-            token = jax.lax.dynamic_slice_in_dim(order, c * rows, rows) // k
-            _, vjp = jax.vjp(lambda *diff: chunk(
-                c * rows, order, sizes, *diff)[1], *diff)
-            return jax.tree.map(jnp.add, acc, vjp(ct[token]))
-
-        return (None, None) + tuple(jax.lax.fori_loop(
-            0, trips(sizes), body, tuple(a * 0 for a in diff)))
-
-    run.defvjp(fwd, bwd)
-    return run(order, sizes, h, weights.reshape(t * k), *mats)
-
-
-def _route_to_held(p, x, cfg, bias):
-    """What both expert blocks of a rank that holds a share do first, on
-    the residual stream ``x`` (b, s, d): pre-norm; the router's sigmoid
-    scores over **all** the experts in float32; the top k of score +
-    ``bias`` (E,); the held slots sorted by expert.  Returns (the normed
-    rows (T, d), the held slots' order and sizes, the slots an expert of
-    all of them received, by token row what the router read and made:
-    ``in``, ``logits``, ``scores`` (T, E), ``weights`` and ``experts``
-    (T, k)).  The product, the chosen experts and the dispatch's integers
-    carry their ``CHECKPOINT_KEEPS`` names, so a checkpointed layer's
-    backward pass reads the forward pass's and routes as it did."""
-    import jax
-    import jax.numpy as jnp
-    from jax.ad_checkpoint import checkpoint_name
-
-    from ompi_tpu.parallel.model import rmsnorm_gain
-
-    b, s, d = x.shape
-    t, k = b * s, cfg.num_experts_per_tok
-    h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
-    with jax.named_scope("otpu_router"):
-        logits = checkpoint_name(
-            jnp.dot(h, p["router"], precision=jax.lax.Precision.HIGHEST),
-            ROUTER_LOGITS)
-        scores, weights, experts = route_sigmoid_bias(
-            logits, bias, k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
-    with jax.named_scope("otpu_dispatch"):
-        order, sizes = local_dispatch(experts, cfg.first_expert_here,
-                                      cfg.n_experts_here)
-        order = checkpoint_name(order, DISPATCH_ORDER)
-        sizes = checkpoint_name(sizes, DISPATCH_SIZES)
-        slots = checkpoint_name(
-            jnp.zeros((cfg.num_experts,), jnp.int32).at[
-                experts.reshape(t * k)].add(1), EXPERT_SLOTS)
-    return h, order, sizes, {"slots": slots.astype(jnp.float32)}, {
-        "in": h, "logits": logits, "scores": scores, "weights": weights,
-        "experts": experts}
-
-
-def moe_shared_local_block(p, x, cfg, bias):
-    """DeepSeek-V3's sparse MLP sublayer (arXiv:2412.19437 section
-    2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
-    ``experts_here`` of the routed experts: pre-norm; the router's
-    sigmoid scores over **all** the experts in float32; the top k of
-    score + ``bias`` (E,); the shared expert on every token; the held
-    experts on the slots routed to them (``local_expert_ffn``).  What
-    the absent experts would add is left out.  Returns (the sublayer's
-    output before the residual add; ``slots`` an expert of all of them
-    received; by token row what the router read and made: ``in``,
-    ``logits``, ``scores`` (T, E), ``weights`` and ``experts`` (T, k))."""
-    import jax
-
-    from ompi_tpu.parallel.model import swiglu
-
-    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
-    with jax.named_scope("otpu_shared_expert"):
-        out = swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"],
-                     cfg.compute_dtype)
-    with jax.named_scope("otpu_experts"):
-        out = out + local_expert_ffn(h, order, seen["weights"], sizes,
-                                     (p["gate"], p["up"], p["down"]), cfg)
-    return out.reshape(x.shape), stats, seen
-
-
-def moe_latent_block(p, x, cfg, bias):
-    """nemotron_h's expert sublayer (its LatentMoE) on the residual
-    stream ``x`` (b, s, d), on a rank that holds ``experts_here`` of the
-    routed experts: pre-norm; the router's sigmoid scores over **all**
-    the experts in float32; the top k of score + ``bias`` (E,)
-    (``route_sigmoid_bias``: DeepSeek-V3's rule); a relu2 shared expert
-    on the hidden width on every token; the routed experts in a latent
-    of ``moe_latent_size``: ``l = h W_lat_down``, the held experts'
-    ``relu(l W_up)^2 W_down`` on the slots routed to them, weighted and
-    added up by token (``local_expert_ffn``), and that sum through
-    ``W_lat_up``.  What the absent experts would add is left out.
-    Returns what ``moe_shared_local_block`` does."""
-    import jax
-    from jax.ad_checkpoint import checkpoint_name
-
-    from ompi_tpu.parallel.model import matmul, relu2
-
-    dt = cfg.compute_dtype
-    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
-    with jax.named_scope("otpu_shared_expert"):
-        out = relu2(h, p["shared_up"], p["shared_down"], dt)
-    with jax.named_scope("otpu_latent"):
-        latent = matmul(h, p["lat_down"], dt)
-    with jax.named_scope("otpu_experts"):
-        # ``lat_up``'s weight gradient reads this sum: kept, or a layer's
-        # backward pass runs the held experts' loop once more to make it
-        latent = checkpoint_name(
-            local_expert_ffn(latent, order, seen["weights"], sizes,
-                             (p["up"], p["down"]), cfg, grouped_relu2_ffn),
-            LATENT_SUM)
-    with jax.named_scope("otpu_latent"):
-        out = out + matmul(latent, p["lat_up"], dt)
-    return out.reshape(x.shape), stats, seen

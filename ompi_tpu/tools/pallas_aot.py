@@ -14,8 +14,9 @@ This is the compile-contract analog of the reference's hardware-proven
 transport layer (``opal/mca/btl/btl.h:878-1078``): a kernel that fails
 here would fail on a real v5e slice.
 
-Run: ``python -m ompi_tpu.tools.pallas_aot --out PALLAS_AOT.json``
-(CPU client; no TPU needed).
+Run: ``python -m ompi_tpu.tools.pallas_aot`` (CPU client; no TPU needed;
+``--out FILE`` also writes the JSON it prints; no record of a run is
+committed, ``tests/test_pallas_aot.py`` compiles its own rows).
 """
 from __future__ import annotations
 
@@ -202,47 +203,23 @@ def cases(mesh1d, mesh2d):
          _sds((n, n, 2048, 1024), f32, mesh1d, P("x")))))
 
     # -- single-chip hot kernels: the MFU path must be Mosaic-proven
-    # too (flash-attention block update at chip_smoke's scale + the VPU
-    # reduction kernels behind mca/op).  They take no mesh to read the
-    # platform from, so interpret=False is passed EXPLICITLY (a static
-    # jit-cache-key ingredient: no cached interpreter trace is served).
+    # too (causal attention's two kernels + the VPU reduction kernels
+    # behind mca/op).  They take no mesh to read the platform from, so
+    # interpret=False is passed EXPLICITLY (a static jit-cache-key
+    # ingredient: no cached interpreter trace is served).
     import numpy as _np
     from jax.sharding import Mesh as _Mesh
 
     one = _Mesh(_np.asarray(mesh1d.devices).reshape(-1)[:1], ("one",))
 
-    def flash_args(b, h, sq, skv, d, dt):
-        return (_sds((b, h, sq, d), dt, one, P()),
-                _sds((b, h, skv, d), dt, one, P()),
-                _sds((b, h, skv, d), dt, one, P()),
-                _sds((b, h, sq), jnp.float32, one, P()),
-                _sds((b, h, sq, d), jnp.float32, one, P()),
-                _sds((b, h, sq), jnp.float32, one, P()))
-
     from ompi_tpu.ops import flash_attention as fa
     from ompi_tpu.ops import pallas_reduce as pr
 
-    case("flash_attention_bf16_2k", lambda: (
-        fa._update_pallas, flash_args(4, 8, 2048, 2048, 128, bf16),
-        {"interpret": False}))
-    case("flash_attention_f32_small", lambda: (
-        fa._update_pallas, flash_args(1, 2, 256, 512, 128, f32),
-        {"interpret": False}))
-    case("flash_attention_causal_bias", lambda: (
-        fa._update_pallas,
-        flash_args(4, 8, 2048, 2048, 128, bf16)
-        + (_sds((2048, 2048), jnp.float32, one, P()),),
-        {"interpret": False}))
     # the OLMoE train step's attention (``parallel/model
     # .causal_flash_attention``) at the benchmark cell's shape (2 x 16
     # heads x 4,096 x 128, bfloat16, causal) through the model's own
     # entry, which where Mosaic compiles is one call of
-    # ``flash_causal_forward`` (its own cases are below); and ring
-    # attention's block update alone at a pair of blocks of 1,024, plain
-    # and biased.  The whole sequence as ONE biased update does not
-    # compile: K and V ride whole in VMEM beside a (256, 4096) float32
-    # tile of scores and one of bias, and Mosaic refuses it
-    # (RESOURCE_EXHAUSTED in vmem, offline for a v5e, PR 33)
+    # ``flash_causal_forward`` (its own cases are below)
     def olmoe_attention():
         from ompi_tpu.parallel import model
 
@@ -251,29 +228,6 @@ def cases(mesh1d, mesh2d):
             q, k, v, 1024, False)), (qkv, qkv, qkv)
 
     case("olmoe_causal_attention_4k", olmoe_attention)
-    case("olmoe_flash_block_1k", lambda: (
-        fa._update_pallas, flash_args(2, 16, 1024, 1024, 128, bf16),
-        {"interpret": False}))
-    case("olmoe_flash_block_1k_biased", lambda: (
-        fa._update_pallas,
-        flash_args(2, 16, 1024, 1024, 128, bf16)
-        + (_sds((1024, 1024), jnp.float32, one, P()),),
-        {"interpret": False}))
-    # latent attention's block update (JoyAI-LLM-Flash's step): q and k
-    # 192 wide, v and the numerator 128; 192 is no multiple of the 128
-    # lanes, and Mosaic lays the tile out itself
-    def mla_args(biased):
-        q = _sds((1, 32, 1024, 192), bf16, one, P())
-        v = _sds((1, 32, 1024, 128), bf16, one, P())
-        row = _sds((1, 32, 1024), jnp.float32, one, P())
-        num = _sds((1, 32, 1024, 128), jnp.float32, one, P())
-        return (q, q, v, row, num, row) + (
-            (_sds((1024, 1024), jnp.float32, one, P()),) if biased else ())
-
-    case("joyai_flash_block_1k", lambda: (
-        fa._update_pallas, mla_args(False), {"interpret": False}))
-    case("joyai_flash_block_1k_biased", lambda: (
-        fa._update_pallas, mla_args(True), {"interpret": False}))
     # attention's backward (``parallel/model._causal_bwd``): the fused
     # block-pair kernel at the two cells' shapes (the arrays come whole
     # and a scalar-prefetch operand picks the pair, so the plain and the
@@ -393,22 +347,20 @@ def cases(mesh1d, mesh2d):
          _sds((QROWS, 1), f32, one, P())),
         {"interpret": False}))
 
-    # -- the composed flagship train step (forward + backward, flash
-    # attention chosen from the MESH's platform, shard_map under
-    # check_vma=True) on one device and on the 2x2 (sp, tp) mesh the
-    # default factorisation gives four chips: what a kernel compiled
-    # standalone cannot show — e.g. a pallas_call whose out_shape
-    # carries no vma only fails inside the step
-    from ompi_tpu.parallel import train
+    # -- the composed flagship train step (forward + backward, ring
+    # attention, shard_map under check_vma=True) on one device and on
+    # the 2x2 (sp, tp) mesh the default factorisation gives four chips:
+    # the only place the sp / tp collectives are compiled for the chip
+    from ompi_tpu.parallel import flagship, train
     from ompi_tpu.parallel.mesh import make_mesh
 
     def train_step(devices):
         mesh, spec = make_mesh(devices)
-        dims = train.model_dims(spec)
-        step, _ = train.build_train_step(mesh, spec)
-        pspecs = train.param_specs(P)
+        dims = flagship.model_dims(spec)
+        step, _ = flagship.build_flagship_step(mesh, spec)
+        pspecs = flagship.param_specs()
         params = {k: _sds(v.shape, f32, mesh, pspecs[k])
-                  for k, v in train.init_params(spec).items()}
+                  for k, v in flagship.init_params(spec).items()}
         x = _sds((dims["batch"], dims["seq"], dims["d"]), f32, mesh,
                  P("dp", "sp", None))
         return step, (params, x)

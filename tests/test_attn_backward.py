@@ -12,6 +12,7 @@ import pytest
 
 from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.parallel import model
+from ompi_tpu.parallel.flagship import _full_attention
 
 
 def _case(d, hv, dt, block, nb, seed=0, b=1, h=2):
@@ -105,7 +106,7 @@ def test_attention_gradients_through_the_kernels(kernels_interpreted, d, hv,
     q, k, v, w = draw(d), draw(d), draw(hv), draw(hv)
     got = jax.grad(lambda q, k, v: jnp.sum(model.causal_flash_attention(
         q, k, v, block, False) * w), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda q, k, v: jnp.sum(model._full_attention(
+    want = jax.grad(lambda q, k, v: jnp.sum(_full_attention(
         q, k, v, True) * w), argnums=(0, 1, 2))(q, k, v)
     for g, x in zip(got, want):
         assert g.dtype == x.dtype

@@ -621,8 +621,8 @@ def test_a_share_of_the_busy_seconds_counts_no_loop_twice(tmp_path,
         from harness import hostspans, protocol
         reader = protocol.load_module("readers", "trace_op_busy_share", BENCH)
         monkeypatch.setattr(hostspans, "write_table", lambda *a: None)
-        ops = {"while.3 s32[]": 6.0, "otpu_flash_block_update.2": 1.0,
-               "otpu_flash_block_update.5": 0.5, "fusion.9 f32[8]": 4.5}
+        ops = {"while.3 s32[]": 6.0, "otpu_flash_causal_forward.2": 1.0,
+               "otpu_flash_causal_forward.5": 0.5, "fusion.9 f32[8]": 4.5}
         ctx = {"points": [{"name": "p", "kind": "train_step_share"}],
                "trace": {"points": {"p": {"ops": ops, "busy_s": 7.5}}}}
         params = {"pattern": "^otpu_flash", "table": "t",

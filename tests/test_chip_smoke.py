@@ -45,15 +45,11 @@ def test_phases_pass_small_on_cpu_mesh(world):
     assert chip_smoke.boot(devs) is world
     chip_smoke.collectives(world, clock, platform="cpu",
                            primary_bytes=1 << 16, spot_bytes=1 << 14)
-    # no TPU in the mesh: the jnp attention branch, so no Mosaic call —
-    # and the phase must notice when the expectation is the other way
-    chip_smoke.trainer(devs, clock, scale=1, expect_mosaic=False)
-    with pytest.raises(RuntimeError, match="lacks the Mosaic"):
-        chip_smoke.trainer(devs[:1], clock, scale=1, expect_mosaic=True)
+    chip_smoke.trainer(devs, clock, scale=1)
     # four devices add the pipeline-active mesh
-    chip_smoke.trainer(devs[:4], clock, scale=1, expect_mosaic=False)
+    chip_smoke.trainer(devs[:4], clock, scale=1)
     chip_smoke.kernels(clock, expect_interpret=True,
-                       flash_shape=(1, 2, 128, 128, 128), dtype="float32",
+                       flash_shape=(1, 2, 128, 128), dtype="float32",
                        reduce_elems=1 << 14, rope_shape=(1, 64, 2, 32),
                        ssm_shape=(1, 44, 4, 8, 2, 8, 16))
     with pytest.raises(RuntimeError, match="interpret resolved to True"):

@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import joyai_reference as ref
-from ompi_tpu.parallel import model, moe, train
+from ompi_tpu.parallel import experts, layers, model, train
+from ompi_tpu.parallel.flagship import _full_attention
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
 
@@ -164,7 +165,7 @@ def test_parameters_and_bias_after_three_steps(dp, params):
 
 def test_what_the_checkpoint_keeps_changes_no_number(params, monkeypatch):
     """A layer's checkpoint keeps the routing results an expert block
-    names (``moe.CHECKPOINT_KEEPS``) and attention's o and logsumexp
+    names (``experts.CHECKPOINT_KEEPS``) and attention's o and logsumexp
     (``model.CHECKPOINT_KEEPS``): every gradient entry, and what a step
     reports, is bit for bit what the bare checkpoint gives.  The
     gradients are compared one primitive at a time (``disable_jit``),
@@ -298,7 +299,7 @@ def test_a_hot_held_expert_drops_no_slot():
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.uniform(0.5, 1.5, (2, 32, 64)), jnp.float32)
     bias = jnp.zeros((16,))
-    out, stats, routed = moe.moe_shared_local_block(p, x, F32, bias)
+    out, stats, routed = experts.moe_shared_local_block(p, x, F32, bias)
     assert (routed["experts"][:, 0] == 5).all()
     assert stats["slots"][5] == 64 and stats["slots"].sum() == 256
     held = int(stats["slots"][4:8].sum())
@@ -319,7 +320,7 @@ def test_no_held_slot_is_an_empty_part():
     p = share_of(p, F32)
     x = jnp.asarray(np.random.default_rng(2).uniform(0.5, 1.5, (2, 32, 64)),
                     jnp.float32)
-    out, stats, _ = moe.moe_shared_local_block(p, x, F32, jnp.zeros((16,)))
+    out, stats, _ = experts.moe_shared_local_block(p, x, F32, jnp.zeros((16,)))
     assert stats["slots"][4:8].sum() == 0
     h = ref._norm(x, p["ln2"], F32.rms_norm_eps).reshape(64, 64)
     with jax.default_matmul_precision("highest"):
@@ -345,7 +346,7 @@ def test_the_shares_add_up():
     total, slots = shared, 0
     for share in range(4):
         cfg = dataclasses.replace(F32, expert_share=share)
-        out, stats, _ = moe.moe_shared_local_block(share_of(p, cfg), x, cfg,
+        out, stats, _ = experts.moe_shared_local_block(share_of(p, cfg), x, cfg,
                                                    bias)
         total = total + (out.reshape(64, 64) - shared)
         lo = cfg.first_expert_here
@@ -386,7 +387,7 @@ def test_the_attention_backward_by_scan_is_the_unrolled_one():
     for got, want in zip(grads(4), grads(16)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     full = jax.grad(lambda q, k, v: jnp.sum(
-        model._full_attention(q, k, v, True) * w), argnums=(0, 1, 2))(
+        _full_attention(q, k, v, True) * w), argnums=(0, 1, 2))(
         q, k, v)
     for got, want in zip(grads(4), full):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
@@ -413,15 +414,15 @@ def _mla_attention_rolled(p, x, cfg):
     b, s, _ = x.shape
     nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
     nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    h = model.rmsnorm_gain(x, p["ln1"], eps)
-    cq = model.rmsnorm_gain(model.matmul(h, p["wq_a"], dt), p["q_a_norm"],
+    h = layers.rmsnorm_gain(x, p["ln1"], eps)
+    cq = layers.rmsnorm_gain(layers.matmul(h, p["wq_a"], dt), p["q_a_norm"],
                             eps)
-    q = model.matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
-    q = model.rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
-    kv = model.matmul(h, p["wkv_a"], dt)
-    ckv = model.rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
-    kvb = model.matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
-    k_rot = model.rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
+    q = layers.matmul(cq, p["wq_b"], dt).reshape(b, s, nh, nope + rot)
+    q = layers.rope_interleaved(q, cfg.rope_theta, first=nope, seq_axis=1)
+    kv = layers.matmul(h, p["wkv_a"], dt)
+    ckv = layers.rmsnorm_gain(kv[..., :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+    kvb = layers.matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
+    k_rot = layers.rope_interleaved(kv[..., cfg.kv_lora_rank:], cfg.rope_theta)
     k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
         k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
     heads = lambda t: t.transpose(0, 2, 1, 3)
@@ -429,7 +430,7 @@ def _mla_attention_rolled(p, x, cfg):
         heads(q.astype(dt)), heads(k), heads(kvb[..., nope:].astype(dt)),
         min(cfg.attn_block, s), True)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
-    return x + model.matmul(o, p["wo"], dt)
+    return x + layers.matmul(o, p["wo"], dt)
 
 
 ROPE_CASES = [
@@ -449,7 +450,7 @@ ROPE_CASES = [
     for c in ROPE_CASES])
 def test_rope_without_the_rolled_copies_is_rope_interleaved(
         what, shape, first, arg, params):
-    """``model.project_rope`` / ``rope_partnered`` (the partner a product
+    """``layers.project_rope`` / ``rope_partnered`` (the partner a product
     of its own; no ``jnp.roll``) against the twin ``rope_interleaved``:
     float32 values before any cast and the gradient with respect to the
     input within one float32 ulp of the terms summed; the projection's
@@ -462,9 +463,9 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
     theta = 32e6
     if what == "pass":
         x, g = normal(*shape), normal(*shape)
-        new = lambda x: model.rope_partnered(x, _swapped(x, first), theta,
+        new = lambda x: layers.rope_partnered(x, _swapped(x, first), theta,
                                              seq_axis=arg)
-        old = lambda x: model.rope_interleaved(x, theta, first, arg)
+        old = lambda x: layers.rope_interleaved(x, theta, first, arg)
         # the two terms of an entry's sum: itself and its partner
         terms = lambda t: jnp.abs(t) + jnp.abs(jnp.pad(
             _swapped(t, first), ((0, 0),) * (t.ndim - 1) + ((first, 0),)))
@@ -477,9 +478,9 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
         heads, width = shape
         a, w = normal(2, 256, 32), normal(32, heads * width)
         g = normal(2, 256, heads, width)
-        new = lambda a, w: model.project_rope(a, w, heads, first, theta, arg)
-        old = lambda a, w: model.rope_interleaved(
-            model.matmul(a, w, arg).reshape(2, 256, heads, width), theta,
+        new = lambda a, w: layers.project_rope(a, w, heads, first, theta, arg)
+        old = lambda a, w: layers.rope_interleaved(
+            layers.matmul(a, w, arg).reshape(2, 256, heads, width), theta,
             first, 1)
         assert new(a, w).dtype == jnp.float32
         # the same dot products of the same inputs (on the CPU a product

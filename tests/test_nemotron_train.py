@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import model, moe, train
+from ompi_tpu.parallel import experts, model, train
 from ompi_tpu.parallel import nemotron_reference as ref
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
@@ -187,7 +187,7 @@ def test_the_latent_block_under_uneven_routing():
             argnums=(0, 1), has_aux=True)(p, x)
     (got, stats), got_g = jax.value_and_grad(
         lambda p, x: (lambda y, st, _: (jnp.sum(y * probe), st))(
-            *moe.moe_latent_block(p, x, F32, bias)),
+            *experts.moe_latent_block(p, x, F32, bias)),
         argnums=(0, 1), has_aux=True)(p, x)
     assert load[9] == 64 and load[10] == 0
     close(stats["slots"], load)
@@ -256,7 +256,7 @@ def test_the_expert_shares_add_up_to_the_uncut_layer():
         part = dataclasses.replace(F32, experts_here=2, expert_share=j)
         mine = {**p, "up": p["up"][2 * j:2 * j + 2],
                 "down": p["down"][2 * j:2 * j + 2]}
-        total = total + moe.moe_latent_block(mine, x, part, bias)[0] - shared
+        total = total + experts.moe_latent_block(mine, x, part, bias)[0] - shared
     close(total, want, rtol=1e-4, atol=1e-5)
 
 
@@ -347,7 +347,7 @@ def test_every_leafs_gradient_is_the_references():
 
 def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
     """A layer's checkpoint keeps an expert block's routing results
-    (``moe.CHECKPOINT_KEEPS``): the backward pass reads them and does not
+    (``experts.CHECKPOINT_KEEPS``): the backward pass reads them and does not
     make them again, so every gradient entry, and what a step reports, is
     bit for bit what the bare checkpoint (nothing kept) gives."""
     tokens, labels = batch_of(4)

@@ -16,8 +16,8 @@ import pytest
 
 from ompi_tpu.parallel import olmoe_reference as ref
 from ompi_tpu.parallel import train
+from ompi_tpu.parallel.experts import moe_sorted_block
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
-from ompi_tpu.parallel.moe import moe_sorted_block
 from ompi_tpu.runtime import spc
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(

@@ -1,6 +1,6 @@
 """AOT compile-contract test: every coll/pallas kernel must lower
 through the real Mosaic TPU compiler (offline, against a v5e-8
-topology) — the CI teeth behind PALLAS_AOT.json.
+topology) — the CI teeth behind ``tools/pallas_aot``.
 
 The interpreter suite (test_pallas_coll.py) proves the *schedules*;
 this proves the *lowering*: semaphore allocation, VMEM/HBM placement,
@@ -44,21 +44,20 @@ def _run_aot_subprocess(*extra, limit: int = 240, **env_extra) -> dict:
         return json.load(f)
 
 
-def test_train_step_aot_compiles_with_flash():
-    """The composed flagship step at full width — flash attention chosen
-    from the TOPOLOGY's platform, forward and backward, inside
-    shard_map(check_vma=True) — must compile for one v5e device and
-    for the 2x2 mesh.  Tier-1 (seconds): this is where a kernel that
-    only fails inside the step (an out_shape without vma) breaks,
-    instead of on the first chip run."""
-    pytest.importorskip("libtpu")
-    res = _run_aot_subprocess("--only", "train_step", "--topology",
-                              "v5e:2x2", OTPU_MODEL_SCALE="64")
-    assert res.get("rows"), res.get("error")
-    assert {r["kernel"] for r in res["rows"]} == {
-        "train_step_1dev", "train_step_2x2"}
-    bad = [r for r in res["rows"] if not r.get("compiled")]
-    assert not bad, json.dumps(bad, indent=1)
+def test_flagship_step_aot_compiles_with_no_custom_call():
+    """The composed flagship step at full width — ring attention,
+    forward and backward, inside shard_map(check_vma=True) — must
+    compile for one v5e device and for the 2x2 mesh (the only place the
+    sp / tp collectives are compiled for the chip), and hold no Mosaic
+    ``custom-call`` anywhere in its text, loops included (XLA's own
+    ``AllocateBuffer`` stay): ring attention has one block update, plain
+    ``jnp``, on every platform."""
+    rows = _rows_with_texts("train_step", OTPU_MODEL_SCALE="64")
+    assert set(rows) == {"train_step_1dev", "train_step_2x2"}
+    for name, row in rows.items():
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        with open(row["hlo"], encoding="utf-8") as f:
+            assert "tpu_custom_call" not in f.read(), name
 
 
 def test_reduce_stack_aot_holds_the_kernel_alone():
@@ -141,13 +140,10 @@ def test_olmoe_attention_aot_compiles_at_the_cells_shape(olmoe_rows):
     """Causal attention's forward pass as the OLMoE step calls it, 2 x
     16 heads x 4,096 x 128 in bfloat16: through the model's entry and
     alone it is one kernel call that takes q, k and v whole (no slice,
-    no concatenate beside it); and ``flash_block_update[_biased]``, ring
-    attention's, each alone at one pair of blocks of 1,024."""
-    for name in ("olmoe_causal_attention_4k", "olmoe_flash_causal_forward",
-                 "olmoe_flash_block_1k", "olmoe_flash_block_1k_biased"):
+    no concatenate beside it)."""
+    for name in ("olmoe_causal_attention_4k", "olmoe_flash_causal_forward"):
         assert olmoe_rows[name].get("compiled"), json.dumps(
             olmoe_rows[name], indent=1)
-    for name in ("olmoe_causal_attention_4k", "olmoe_flash_causal_forward"):
         ops = olmoe_rows[name]["entry_ops"]
         assert ops["custom-call"] == 1, ops
         assert not {"slice", "concatenate", "fusion"} & set(ops), ops
@@ -181,13 +177,13 @@ def test_olmoe_train_step_aot_compiles_from_the_cells_configuration(
     assert row["compile_s"] < 120
 
 
-def _rows_with_texts(only: str) -> dict:
+def _rows_with_texts(only: str, **env_extra) -> dict:
     """{case: its row, with ``hlo`` the file of its compiled text} of one
-    child that compiles the cases named ``only`` for one v5e device."""
+    child that compiles the cases named ``only`` for a v5e 2x2."""
     pytest.importorskip("libtpu")
     dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
     res = _run_aot_subprocess("--only", only, "--topology", "v5e:2x2",
-                              "--dump", dump, limit=600)
+                              "--dump", dump, limit=600, **env_extra)
     assert res.get("rows"), res.get("error")
     return {r["kernel"]: dict(r, hlo=os.path.join(
         dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
@@ -195,19 +191,11 @@ def _rows_with_texts(only: str) -> dict:
 
 @pytest.fixture(scope="module")
 def joyai_rows():
-    """One child for the JoyAI-LLM-Flash cases: the block update at 192 /
-    128 alone, plain and biased, and the whole step of the cell's own
-    configuration file for one v5e device (about 65 s of the 600)."""
+    """One child for the JoyAI-LLM-Flash cases: attention's two kernels
+    at 192 / 128 alone, the latent sublayer's operands and the whole
+    step of the cell's own configuration file for one v5e device (about
+    65 s of the 600)."""
     return _rows_with_texts("joyai")
-
-
-def test_the_block_update_aot_compiles_at_192_and_128(joyai_rows):
-    """q and k 192 wide, v and the numerator 128: one kernel, no
-    padding outside it."""
-    for name in ("joyai_flash_block_1k", "joyai_flash_block_1k_biased"):
-        row = joyai_rows[name]
-        assert row.get("compiled"), json.dumps(row, indent=1)
-        assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
 
 
 def test_attention_forward_aot_compiles_at_192_and_128(joyai_rows):
@@ -248,7 +236,7 @@ def test_joyai_train_step_aot_compiles_from_the_cells_configuration(
 def test_latent_attentions_operands_aot_hold_no_rolled_copy(case, joyai_rows):
     """One latent-attention sublayer, forward and gradient, at the cell's
     shapes, and the whole step: q's rotary partner is a product of its
-    own (``model.project_rope``), so the compiled text holds no
+    own (``layers.project_rope``), so the compiled text holds no
     ``jnp.roll`` (``_roll_static`` in an ``op_name``) and no 191-wide
     slice of q's (1, 8192, 32, 192) float32 array, which XLA wrote to HBM
     as 2.6 GB of shifted copies a layer and pass (PR 41)."""
@@ -294,7 +282,7 @@ def fits_a_v5e(row) -> bool:
 def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
                                                             request):
     """A walked layer's checkpoint keeps what the expert block names
-    (``moe.CHECKPOINT_KEEPS``, PR 43), so in the step compiled for a v5e
+    (``experts.CHECKPOINT_KEEPS``, PR 43), so in the step compiled for a v5e
     no instruction under ``rematted_computation`` is a ``sort`` (the
     dispatch's argsort, and the top-k, which the TPU's compiler writes as
     a whole sort of (8192, E)), any other part of the top-k, the gather
@@ -373,7 +361,8 @@ def test_all_kernels_aot_compile():
                    "all_gather_bidi", "all_reduce_torus", "matmul_allreduce",
                    "matmul_reduce_scatter",
                    # single-chip hot kernels (the MFU path)
-                   "flash_attention_bf16_2k", "vpu_combine2_sum",
+                   "olmoe_flash_causal_forward",
+                   "joyai_attn_block_backward_1k", "vpu_combine2_sum",
                    "vpu_reduce_stack_max",
                    "vpu_reduce_stack_rows_prod_f32",
                    "vpu_reduce_stack_rows_band_i32",

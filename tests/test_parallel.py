@@ -1,20 +1,29 @@
-"""ompi_tpu.parallel: mesh factoring, ring attention, MoE, pipeline, train.
+"""ompi_tpu.parallel: mesh factoring, ring attention, MoE, pipeline, the
+invented ("flagship") step, and the shape of the package's imports.
 
 Numerical references are single-device jnp computations; the parallel
 versions must match them exactly (same math, different schedule) — the
 analog of the reference's coll algorithm-vs-basic cross-checks.
 """
+import ast
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
+from ompi_tpu.parallel.flagship import (_full_attention, build_flagship_step,
+                                        init_params, model_dims,
+                                        ring_attention)
 from ompi_tpu.parallel.mesh import MeshSpec, default_axis_sizes, make_mesh
-from ompi_tpu.parallel.model import ring_attention
 from ompi_tpu.parallel.pipeline import pipeline_apply
-from ompi_tpu.parallel.train import build_train_step, init_params, model_dims
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_default_axis_sizes():
@@ -23,13 +32,6 @@ def test_default_axis_sizes():
     assert default_axis_sizes(1) == MeshSpec()
     assert default_axis_sizes(4).n == 4
     assert default_axis_sizes(12).n == 12
-
-
-def _ref_attention(q, k, v):
-    # q,k,v: (b, h, s, hd) global — plain softmax attention
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 def test_ring_attention_matches_dense():
@@ -45,7 +47,7 @@ def test_ring_attention_matches_dense():
         mesh=mesh, in_specs=P(None, None, "sp", None),
         out_specs=P(None, None, "sp", None), check_vma=False))
     out = fn(q, k, v)
-    np.testing.assert_allclose(out, _ref_attention(q, k, v),
+    np.testing.assert_allclose(out, _full_attention(q, k, v),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -78,7 +80,7 @@ def test_pipeline_matches_sequential():
 def test_train_step_descends(n):
     mesh, spec = make_mesh(jax.devices()[:n])
     dims = model_dims(spec)
-    step, place = build_train_step(mesh, spec)
+    step, place = build_flagship_step(mesh, spec)
     rng = np.random.RandomState(2)
     x = rng.normal(0, 1, (dims["batch"], dims["seq"], dims["d"]))
     params, xd = place(init_params(spec), x)
@@ -104,7 +106,7 @@ def test_update_is_one_gradient_step(spec_text):
     mesh, spec = make_mesh(
         jax.devices()[:spec.dp * spec.pp * spec.sp * spec.tp], spec)
     dims = model_dims(spec)
-    step, place = build_train_step(mesh, spec, lr=lr)
+    step, place = build_flagship_step(mesh, spec, lr=lr)
     x = np.random.RandomState(2).normal(
         0, 1, (dims["batch"], dims["seq"], dims["d"]))
     p0 = init_params(spec)
@@ -136,41 +138,6 @@ def test_widest_config_descends_on_the_four_device_mesh(monkeypatch):
     assert all(b < a for a, b in zip(losses, losses[1:])), losses
 
 
-def test_ulysses_matches_ring_and_full():
-    """Ulysses (all-to-all SP) == ring attention == unsharded reference."""
-    import jax
-    import jax.numpy as jnp
-    from jax import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from ompi_tpu.parallel.model import (_full_attention, ring_attention,
-                                         ulysses_attention)
-
-    ndev = len(jax.devices())
-    mesh = Mesh(np.array(jax.devices()), ("sp",))
-    b, h, s, hd = 2, 2 * ndev, 4 * ndev, 8
-    key = jax.random.PRNGKey(3)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (b, h, s, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, h, s, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, h, s, hd), jnp.float32)
-
-    spec = P(None, None, "sp", None)
-
-    def run(fn):
-        body = lambda qq, kk, vv: fn(qq, kk, vv, "sp", ndev)
-        return jax.jit(shard_map(body, mesh=mesh,
-                                 in_specs=(spec, spec, spec),
-                                 out_specs=spec, check_vma=False))(q, k, v)
-
-    ref = _full_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(run(ulysses_attention)),
-                               np.asarray(ref), rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(run(lambda *a: ring_attention(*a, use_flash=False))),
-        np.asarray(ref), rtol=2e-4, atol=2e-5)
-
-
 def test_composed_step_with_active_pipeline_axis():
     """The 4-axis step with pp>=2 ACTIVE: loss descends on the
     {dp:1,pp:2,sp:2,tp:2} mesh (round-2 gap: the composed dp x pp x sp
@@ -193,10 +160,6 @@ def test_pp2_matches_pp1_same_model():
     devices, both layers local) must produce the same loss and the same
     updated parameters — pipelining is an execution schedule, not a
     different function."""
-    from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
-    from ompi_tpu.parallel.train import (build_train_step, init_params,
-                                         model_dims)
-
     rng = np.random.RandomState(7)
     spec2 = MeshSpec(dp=1, pp=2, sp=2, tp=2)
     spec1 = MeshSpec(dp=1, pp=1, sp=2, tp=2)
@@ -207,7 +170,7 @@ def test_pp2_matches_pp1_same_model():
     results = {}
     for name, spec, ndev in (("pp2", spec2, 8), ("pp1", spec1, 4)):
         mesh, _ = make_mesh(jax.devices()[:ndev], spec)
-        step, place = build_train_step(mesh, spec, layers=2)
+        step, place = build_flagship_step(mesh, spec, layers=2)
         pd, xd = place(params, x)
         p1, l1 = step(pd, xd)
         results[name] = (float(l1), {k: np.asarray(v)
@@ -231,18 +194,10 @@ def test_dryrun_spec_override_and_16dev():
     g.dryrun_multichip(16)   # default_axis_sizes(16) -> all 4 axes active
 
 
-def test_causal_ring_and_ulysses_match_masked_reference():
-    """causal=True on both SP schemes == unsharded lower-triangle
-    attention — the mask composes from GLOBAL positions across ring
-    steps (shard-offset block bias), not local ones."""
-    import jax
-    import jax.numpy as jnp
-    from jax import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from ompi_tpu.parallel.model import (_full_attention, ring_attention,
-                                         ulysses_attention)
-
+def test_causal_ring_matches_masked_reference():
+    """causal=True on the ring == unsharded lower-triangle attention —
+    the mask composes from GLOBAL positions across ring steps
+    (shard-offset block bias), not local ones."""
     ndev = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()), ("sp",))
     b, h, s, hd = 2, 2 * ndev, 4 * ndev, 8
@@ -251,181 +206,108 @@ def test_causal_ring_and_ulysses_match_masked_reference():
     k = jax.random.normal(ks[1], (b, h, s, hd), jnp.float32)
     v = jax.random.normal(ks[2], (b, h, s, hd), jnp.float32)
     spec = P(None, None, "sp", None)
-
-    def run(fn):
-        body = lambda qq, kk, vv: fn(qq, kk, vv, "sp", ndev)
-        return jax.jit(shard_map(body, mesh=mesh,
-                                 in_specs=(spec, spec, spec),
-                                 out_specs=spec, check_vma=False))(q, k, v)
-
-    ref = np.asarray(_full_attention(q, k, v, causal=True))
-    got_ring = run(lambda *a: ring_attention(*a, use_flash=False,
-                                             causal=True))
-    np.testing.assert_allclose(np.asarray(got_ring), ref, rtol=2e-4,
-                               atol=2e-5)
-    got_ul = run(lambda *a: ulysses_attention(*a, causal=True))
-    np.testing.assert_allclose(np.asarray(got_ul), ref, rtol=2e-4,
-                               atol=2e-5)
-    # flash path (interpreter off-TPU) agrees too
-    got_flash = run(lambda *a: ring_attention(*a, use_flash=True,
-                                              causal=True))
-    np.testing.assert_allclose(np.asarray(got_flash), ref, rtol=2e-4,
-                               atol=2e-5)
+    body = lambda qq, kk, vv: ring_attention(qq, kk, vv, "sp", ndev,
+                                             causal=True)
+    got = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_full_attention(q, k, v, causal=True)),
+        rtol=2e-4, atol=2e-5)
 
 
 def test_causal_single_shard_and_gradients():
-    """n_shards=1 causal == plain masked attention; gradients flow
-    through the biased flash custom-VJP (recompute via the jnp twin)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ompi_tpu.parallel.model import _full_attention, ring_attention
-
+    """n_shards=1 causal == plain masked attention, values and the
+    gradient with respect to q."""
     b, h, s, hd = 1, 2, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (b, h, s, hd), jnp.float32)
     k = jax.random.normal(ks[1], (b, h, s, hd), jnp.float32)
     v = jax.random.normal(ks[2], (b, h, s, hd), jnp.float32)
-    ref = np.asarray(_full_attention(q, k, v, causal=True))
-    for flash in (False, True):
-        got = ring_attention(q, k, v, "sp", 1, use_flash=flash,
-                             causal=True)
-        np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5,
-                                   atol=2e-5)
-
-    def loss(fn, flash):
-        return lambda qq: jnp.sum(
-            fn(qq, k, v, "sp", 1, use_flash=flash, causal=True) ** 2)
-
-    g_flash = jax.grad(loss(ring_attention, True))(q)
-    g_jnp = jax.grad(loss(ring_attention, False))(q)
-    np.testing.assert_allclose(np.asarray(g_flash), np.asarray(g_jnp),
+    ring = lambda qq: ring_attention(qq, k, v, "sp", 1, causal=True)
+    full = lambda qq: _full_attention(qq, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(ring(q)), np.asarray(full(q)),
+                               rtol=2e-5, atol=2e-5)
+    grad = lambda fn: jax.grad(lambda qq: jnp.sum(fn(qq) ** 2))(q)
+    np.testing.assert_allclose(np.asarray(grad(ring)), np.asarray(grad(full)),
                                rtol=2e-4, atol=2e-5)
 
 
-def test_causal_train_step_var():
-    """--mca parallel_causal 1 flows into the composed train step and
-    changes the loss trajectory (masked attention is a different
-    program), while still descending."""
-    import jax
-
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel.dryrun import parse_spec, run_training_step
-
-    var = registry.lookup("otpu_parallel_causal")
-    assert var is not None
-    old = var.value
-    try:
-        devs = jax.devices()[:4]
-        spec = parse_spec("dp=2,pp=1,sp=2,tp=1")
-        var.set(False)
-        base = run_training_step(devs, spec)
-        var.set(True)
-        causal = run_training_step(devs, spec)
-        assert np.isfinite(causal)
-        # masked attention is a genuinely different program: same init,
-        # same data, different loss
-        assert abs(causal - base) > 1e-6, (causal, base)
-    finally:
-        var.set(old)
+# -- the package's shape: one model path whose imports point one way ------
+_FRESH = (
+    "import sys; {imports}; "
+    "from ompi_tpu.base.var import registry; "
+    "print(sorted(m.rsplit('.', 1)[1] for m in sys.modules "
+    "if m.startswith('ompi_tpu.parallel.'))); "
+    "print(sorted(v.name for v in registry.all_vars() "
+    "if v.name.startswith(('otpu_parallel_', 'otpu_moe_'))))")
 
 
-def test_remat_var_matches_baseline_loss():
-    """--mca parallel_remat 1 must change only WHERE activations come
-    from (recompute vs store): the loss trajectory is bit-comparable."""
-    import jax
-
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel.dryrun import parse_spec, run_training_step
-
-    var = registry.lookup("otpu_parallel_remat")
-    assert var is not None
-    devs = jax.devices()[:4]
-    spec = parse_spec("dp=2,pp=1,sp=2,tp=1")
-    old = var.value
-    try:
-        var.set(False)
-        base = run_training_step(devs, spec)
-        var.set(True)
-        remat = run_training_step(devs, spec)
-        np.testing.assert_allclose(remat, base, rtol=1e-6)
-    finally:
-        var.set(old)
+def _fresh(imports: str) -> tuple:
+    """(the ``ompi_tpu.parallel`` modules loaded, the ``parallel`` and
+    ``moe`` options registered) after ``imports`` in a new interpreter."""
+    out = subprocess.run(
+        [sys.executable, "-c", _FRESH.format(imports=imports)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO),
+        cwd=REPO, capture_output=True, text=True, timeout=300, check=True)
+    loaded, options = out.stdout.strip().splitlines()[-2:]
+    return ast.literal_eval(loaded), ast.literal_eval(options)
 
 
-def test_compute_dtype_bf16_descends():
-    """--mca parallel_compute_dtype bfloat16: the composed step still
-    trains (finite loss, close to the f32 program) with half-width
-    activations and per-block param casts — including combined with
-    causal masking and remat (the production stack)."""
-    import jax
-
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel.dryrun import parse_spec, run_training_step
-
-    var = registry.lookup("otpu_parallel_compute_dtype")
-    assert var is not None
-    devs = jax.devices()[:4]
-    spec = parse_spec("dp=2,pp=1,sp=2,tp=1")
-    old = var.value
-    causal = registry.lookup("otpu_parallel_causal")
-    remat = registry.lookup("otpu_parallel_remat")
-    old_c, old_r = causal.value, remat.value
-    try:
-        var.set("float32")
-        base = run_training_step(devs, spec)
-        var.set("bfloat16")
-        lo = run_training_step(devs, spec)
-        assert np.isfinite(lo)
-        # bf16 rounding makes a different (but close) program
-        np.testing.assert_allclose(lo, base, rtol=0.1)
-        # the production combination: bf16 + causal + remat must
-        # compose (regression: the f32 causal bias once promoted the
-        # bf16 scan carry and broke lax.scan's type invariant)
-        causal.set(True)
-        remat.set(True)
-        combo = run_training_step(devs, spec)
-        assert np.isfinite(combo)
-    finally:
-        var.set(old)
-        causal.set(old_c)
-        remat.set(old_r)
+def test_importing_the_model_path_loads_nothing_beside_it():
+    """``import ompi_tpu.parallel.train`` is all the benchmark's train
+    kinds do: it loads the model path (mesh, layers, experts, model,
+    train) and neither the invented step, the pipeline, the host
+    trainers nor any option of theirs."""
+    loaded, options = _fresh("import ompi_tpu.parallel.train")
+    assert not {"flagship", "pipeline", "moe", "elastic",
+                "checkpoint"} & set(loaded), loaded
+    assert {"layers", "experts", "model", "train"} <= set(loaded)
+    assert options == []
 
 
-def test_zero1_matches_baseline_and_shards_state():
-    """--mca parallel_zero1 1: reduce-scatter grads, dp-sharded
-    momentum, masked-psum param rebuild — loss parity with the
-    allreduce baseline at momentum 0, and the state really is one
-    (chunk,) block per (dp, pp, tp) shard."""
-    import jax
+def test_the_invented_step_registers_no_option():
+    """The flagship step has one variant: no ``otpu_parallel_*`` option
+    exists after its module is imported either."""
+    loaded, options = _fresh("import ompi_tpu.parallel.flagship")
+    assert "flagship" in loaded and "pipeline" in loaded
+    assert [o for o in options if o.startswith("otpu_parallel_")] == []
 
-    from ompi_tpu.base.var import registry
-    from ompi_tpu.parallel.dryrun import (make_step_and_args, parse_spec,
-                                          run_training_step)
 
-    z = registry.lookup("otpu_parallel_zero1")
-    mvar = registry.lookup("otpu_parallel_momentum")
-    old_z, old_m = z.value, mvar.value
-    devs = jax.devices()[:8]
-    try:
-        for s in ("dp=2,pp=2,sp=2,tp=1", "dp=2,pp=1,sp=2,tp=2"):
-            spec = parse_spec(s)
-            z.set(False)
-            mvar.set(0.0)
-            base = run_training_step(devs, spec)
-            z.set(True)
-            np.testing.assert_allclose(run_training_step(devs, spec),
-                                       base, rtol=1e-6)
-            mvar.set(0.9)
-            assert np.isfinite(run_training_step(devs, spec))
-        # structural: carried state is (params, m) with the sharded spec
-        z.set(True)
-        step, args, _ = make_step_and_args(
-            devs, parse_spec("dp=2,pp=1,sp=2,tp=2"))
-        (params, m), x = args
-        assert tuple(m.sharding.spec) == (("dp", "pp", "tp"),)
-        txt = step.lower(*args).as_text()
-        assert "reduce-scatter" in txt or "reduce_scatter" in txt
-    finally:
-        z.set(old_z)
-        mvar.set(old_m)
+def test_build_train_step_needs_a_model():
+    """``train.build_train_step`` builds a public model's step and no
+    second program: without a configuration it is a ``TypeError``."""
+    from ompi_tpu.parallel import build_train_step
+
+    mesh, spec = make_mesh(jax.devices()[:1])
+    with pytest.raises(TypeError, match="model"):
+        build_train_step(mesh, spec)
+
+
+#: the model path top down: a module imports only those before it
+MODEL_PATH = ("mesh", "layers", "experts", "model", "train")
+
+
+def test_model_path_imports_point_one_way_at_module_top():
+    """No ``ompi_tpu.parallel`` import below module level in the model
+    path's files, and none of a module further down ``MODEL_PATH`` (so
+    no cycle, and nothing of the package beside the path)."""
+    for at, name in enumerate(MODEL_PATH[1:], 1):
+        path = os.path.join(REPO, "ompi_tpu", "parallel", name + ".py")
+        with open(path, encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                # ``from ompi_tpu.parallel import x`` names modules
+                names = [node.module] if node.module != "ompi_tpu.parallel" \
+                    else [f"{node.module}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            for mod in names:
+                if not (mod or "").startswith("ompi_tpu.parallel"):
+                    continue
+                assert node in tree.body, \
+                    f"{name}.py:{node.lineno}: {mod} imported below the top"
+                assert mod.rsplit(".", 1)[1] in MODEL_PATH[:at], \
+                    f"{name}.py:{node.lineno}: imports {mod}"

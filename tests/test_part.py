@@ -2,8 +2,8 @@
 analog): Psend_init/Precv_init with Pready/Pready_range/Pready_list and
 Parrived, aggregation onto fewer wire messages, mismatched send/recv
 partition counts, mixed Startall, loud error paths, a seeded Pready-order
-fuzz vs a numpy reference, the partitioned device collective (pcoll),
-and the parallel_bucket_overlap trainer dryrun."""
+fuzz vs a numpy reference and the partitioned device collective
+(pcoll)."""
 import os
 import subprocess
 import sys
@@ -659,41 +659,6 @@ def test_partitioned_coll_is_the_allreduce_only(world):
     with pytest.raises(MpiError, match="no partitioned device collective"):
         _xla(world).partitioned_coll(world, "allgather", [np.ones(
             (world.size, 4), np.float32)])
-
-
-def test_bucket_overlap_dryrun_bit_identical():
-    """The acceptance pin: parallel_bucket_overlap produces bit-identical
-    parameters to the non-overlapped trainer step (8-device virtual
-    mesh, default and pp-active specs)."""
-    import jax
-
-    from ompi_tpu.parallel.dryrun import (parse_spec,
-                                          run_bucket_overlap_check)
-
-    run_bucket_overlap_check(jax.devices())
-    run_bucket_overlap_check(jax.devices(),
-                             parse_spec("dp=2,pp=2,sp=1,tp=2"))
-
-
-def test_bucket_overlap_rejects_zero1():
-    from ompi_tpu.base.var import registry
-
-    import jax
-
-    from ompi_tpu.parallel import train
-    from ompi_tpu.parallel.dryrun import make_step_and_args
-
-    bvar = registry.lookup("otpu_parallel_bucket_overlap")
-    zvar = registry.lookup("otpu_parallel_zero1")
-    old_b, old_z = bvar.value, zvar.value
-    bvar.set(True)
-    zvar.set(True)
-    try:
-        with pytest.raises(ValueError):
-            make_step_and_args(jax.devices())
-    finally:
-        bvar.set(old_b)
-        zvar.set(old_z)
 
 
 def test_part_framework_discovered_by_otpu_info():

@@ -51,7 +51,7 @@ PATHS = [
      "update"),
     # a Pallas kernel's own name, before its pallas_call, is no scope
     ("jit(otpu_train_step)/jvp(otpu_layers)/while/body/closed_call/otpu_mla/"
-     "jit(_update_pallas)/otpu_flash_block_update/pallas_call",
+     "jit(flash_causal_forward)/otpu_flash_causal_forward/pallas_call",
      ["otpu_layers", "otpu_mla"], "forward"),
     ("jit(otpu_train_step)/transpose(jvp(otpu_layers))/while/body/"
      "checkpoint/rematted_computation/otpu_layers/otpu_mla/"
@@ -341,7 +341,7 @@ def routing_by_pass(cfg, kinds_of=ROUTING):
 def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         cfg, loop_is_read, monkeypatch):
     """``model_loss``'s checkpoint keeps what an expert block names
-    (``moe.CHECKPOINT_KEEPS``): no recomputed instruction is the
+    (``experts.CHECKPOINT_KEEPS``): no recomputed instruction is the
     dispatch's sort, the router's top-k, the gather of the chosen scores
     or the (T, E) product, nor, where the loop's sum is read by a weight
     gradient (Nemotron's ``lat_up``; JoyAI's XLA drops by itself), the
@@ -403,11 +403,11 @@ def test_the_process_gives_the_maps_of_the_steps_it_ran(joyai):
 
 
 def test_the_vocabulary_is_the_sources_and_the_benchmarks():
-    """Every ``named_scope`` the step's three files open is in
+    """Every ``named_scope`` the step's four files open is in
     ``STEP_SCOPES``, every name of it is opened somewhere, and the
     benchmark's data file repeats it."""
     opened = set()
-    for name in ("train", "model", "moe"):
+    for name in ("train", "model", "experts", "layers"):
         with open(os.path.join(ROOT, "ompi_tpu", "parallel", name + ".py"),
                   encoding="utf-8") as f:
             opened |= set(re.findall(r'named_scope\("(otpu_\w+)"\)',
