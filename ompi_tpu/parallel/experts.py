@@ -1,9 +1,10 @@
 """The expert blocks of a public model's sparse layers: a learned top-k
 router with no dropped token, sort-and-gather dispatch, grouped expert
 matmuls and a weighted combine.  ``moe_sorted_block`` holds every expert
-(OLMoE); ``moe_shared_local_block`` (DeepSeek-V3's layer: JoyAI-LLM-Flash)
-and ``moe_latent_block`` (nemotron_h's LatentMoE) hold a share of the
-routed experts beside a shared one.  ``parallel/model.decoder_layer``
+(OLMoE); ``moe_shared_local_block`` (DeepSeek-V3's layer: JoyAI-LLM-Flash;
+without a shared expert lfm2_moe's: LFM2-8B-A1B) and ``moe_latent_block``
+(nemotron_h's LatentMoE) hold a share of the routed experts, beside a
+shared one where the model has it.  ``parallel/model.decoder_layer``
 chooses among them; the primitives come from ``parallel/layers.py``.
 """
 from __future__ import annotations
@@ -272,19 +273,25 @@ def moe_shared_local_block(p, x, cfg, bias):
     2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
     ``experts_here`` of the routed experts: pre-norm; the router's
     sigmoid scores over **all** the experts in float32; the top k of
-    score + ``bias`` (E,); the shared expert on every token; the held
-    experts on the slots routed to them (``local_expert_ffn``).  What
-    the absent experts would add is left out.  Returns (the sublayer's
+    score + ``bias`` (E,); the shared expert on every token, where the
+    model has one (``n_shared_experts``; lfm2_moe's has none, and its
+    result is the held experts' part alone); the held experts on the
+    slots routed to them (``local_expert_ffn``).  What the absent
+    experts would add is left out.  Returns (the sublayer's
     output before the residual add; ``slots`` an expert of all of them
     received; by token row what the router read and made: ``in``,
     ``logits``, ``scores`` (T, E), ``weights`` and ``experts`` (T, k))."""
     h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
-    with jax.named_scope("otpu_shared_expert"):
-        out = swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"],
-                     cfg.compute_dtype)
+    shared = None
+    if cfg.n_shared_experts:
+        with jax.named_scope("otpu_shared_expert"):
+            shared = swiglu(h, p["shared_gate"], p["shared_up"],
+                            p["shared_down"], cfg.compute_dtype)
     with jax.named_scope("otpu_experts"):
-        out = out + local_expert_ffn(h, order, seen["weights"], sizes,
-                                     (p["gate"], p["up"], p["down"]), cfg)
+        out = local_expert_ffn(h, order, seen["weights"], sizes,
+                               (p["gate"], p["up"], p["down"]), cfg)
+        if shared is not None:
+            out = shared + out
     return out.reshape(x.shape), stats, seen
 
 

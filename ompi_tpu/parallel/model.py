@@ -1,7 +1,8 @@
-"""A public model's sublayers (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super):
-causal flash attention with its two walks of the block pairs, the three
-attention sublayers, Mamba-2's chunked scan and mixer, and
-``decoder_layer``, which chooses a layer's sublayers by what it holds.
+"""A public model's sublayers (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super,
+LFM2-8B-A1B): causal flash attention with its two walks of the block
+pairs, the three attention sublayers, Mamba-2's chunked scan and mixer,
+LFM2's gated short convolution, and ``decoder_layer``, which chooses a
+layer's sublayers by what it holds.
 The primitives come from ``parallel/layers.py`` and the expert blocks
 from ``parallel/experts.py``; ``parallel/train.py`` builds the step on
 ``decoder_layer``.
@@ -284,29 +285,55 @@ def mla_attention(p, x, cfg, *, interpret: bool):
 
 
 def gqa_attention(p, x, cfg, *, interpret: bool):
-    """nemotron_h's attention sublayer, **without** the residual add, on
-    the residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
+    """Grouped-query attention, **without** the residual add, on the
+    residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
     projections without bias; the ``n_heads_here`` query heads held here
     and the ``n_kv_heads_here`` key-value heads they read (each read by
     ``num_attention_heads / num_key_value_heads`` query heads of the
-    model, by as many of those as are held here); no rotary embedding
-    (the positions come from the state-space layers); causal softmax
+    model, by as many of those as are held here); causal softmax
     attention.  The flash kernels take one k and v a query head, so the
     key-value heads are repeated into that layout (the repeat's
-    transpose adds the query heads' gradients up)."""
+    transpose adds the query heads' gradients up).
+
+    Two models' sublayer, told apart by what the layer holds.
+    nemotron_h's (Nemotron-3-Super) holds no ``q_norm``: no rotary
+    embedding (the positions come from the state-space layers), q, k
+    and v cast as they leave their projections.  lfm2's (LFM2-8B-A1B)
+    holds ``q_norm`` and ``k_norm`` (head width,): RMSNorm with a gain
+    over **each head's** width of q and of k, then RoPE in the
+    half-split form, both in float32 and on the key-value heads before
+    their repeat.  Returns (the sublayer's output, by token row what
+    the norm and RoPE read and made of the first query head and the
+    first key-value head side by side, ``attn_qk_in`` and ``attn_qk``
+    (T, 2 hd); empty for nemotron_h's)."""
     b, s, _ = x.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
+    seen = {}
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
-        heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
-            0, 2, 1, 3).astype(dt)
-        q = heads(matmul(h, p["wq"], dt), nh)
-        k, v = (jnp.repeat(heads(matmul(h, p[w], dt), nkv), nh // nkv, 1)
-                for w in ("wk", "wv"))
+        if "q_norm" in p:
+            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+            q_in, k_in = (split(matmul(h, p[w], dt), n)
+                          for w, n in (("wq", nh), ("wk", nkv)))
+            q, k = (rope(rmsnorm_gain(t, p[g], cfg.rms_norm_eps),
+                         cfg.rope_theta)
+                    for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+            first = lambda a, c: jnp.concatenate(
+                [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
+            seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+            q = q.astype(dt)
+            k, v = (jnp.repeat(t.astype(dt), nh // nkv, 1)
+                    for t in (k, split(matmul(h, p["wv"], dt), nkv)))
+        else:
+            heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
+                0, 2, 1, 3).astype(dt)
+            q = heads(matmul(h, p["wq"], dt), nh)
+            k, v = (jnp.repeat(heads(matmul(h, p[w], dt), nkv), nh // nkv, 1)
+                    for w in ("wk", "wv"))
     o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
     with jax.named_scope("otpu_attn_proj"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
-        return matmul(o, p["wo"], dt)
+        return matmul(o, p["wo"], dt), seen
 
 
 def ssd_chunked(x, dt, a, b, c, chunk: int):
@@ -420,21 +447,70 @@ def mamba_mixer(p, x, cfg):
                       ).reshape(b, s, d), seen
 
 
+#: the leading channels of a short convolution whose gate path a step
+#: reports (``short_conv``): one tile's lanes of the hidden width
+CONV_SAMPLE = 128
+
+
+def short_conv(p, x, cfg):
+    """lfm2's gated short convolution (LFM2-8B-A1B's ``conv`` operator),
+    **without** the residual add, on the residual stream ``x`` (b, s, d)
+    float32: pre-norm; ``[B | C | u] = n W_in`` (d, 3 d; matmul inputs
+    in ``compute_dtype``); ``z_t = sum_j w_j (B * u)_{t - (taps - 1) +
+    j}``, a causal depthwise convolution of ``conv_kernel`` taps a
+    channel (``conv_w`` (taps, d), the last tap on the position itself)
+    with zeros before the sequence's start, no bias and no activation;
+    ``(C * z) W_out``.  The two gates and the taps, everything between
+    the two projections, are float32.  The sequence is never reset
+    inside a packed row.  Returns (the sublayer's output, of the first
+    ``CONV_SAMPLE`` channels by token row what the gate path read,
+    ``conv_bcu_seq`` (T, B | C | u) whole, because a position's result
+    holds the ``taps - 1`` before it, and made, ``conv_y`` (T, .): C *
+    z)."""
+    b, s, d = x.shape
+    dt, taps = cfg.compute_dtype, p["conv_w"].shape[0]
+    with jax.named_scope("otpu_conv_proj"):
+        n = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+        bcu = matmul(n.reshape(b * s, d), p["in_proj"], dt).reshape(
+            b, s, 3, d)
+    with jax.named_scope("otpu_conv_gate"):
+        gated = jnp.pad(bcu[:, :, 0] * bcu[:, :, 2],
+                        ((0, 0), (taps - 1, 0), (0, 0)))
+        y = bcu[:, :, 1] * sum(gated[:, k:k + s] * p["conv_w"][k]
+                               for k in range(taps))
+        c = min(CONV_SAMPLE, d)
+        seen = {"conv_bcu_seq": bcu[..., :c].reshape(b * s, 3 * c),
+                "conv_y": y[..., :c].reshape(b * s, c)}
+    with jax.named_scope("otpu_conv_proj"):
+        return matmul(y.reshape(b * s, d), p["out_proj"], dt
+                      ).reshape(b, s, d), seen
+
+
 def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
     """One decoder layer of a public model, its sublayers chosen by what
-    the layer holds and the configuration's published keys say.  A layer
-    of a ``hybrid_override_pattern`` has **one** sublayer: the Mamba-2
-    mixer where it holds ``in_proj``, the latent relu2 expert block
-    (``experts.moe_latent_block``) where it holds a router, else
-    grouped-query attention without RoPE.  Any other model's layer has
-    attention (latent where ``kv_lora_rank`` is set, else OLMoE's) and
-    then a dense SwiGLU where the layer has no router, else the sparse
-    MLP (``experts.moe_sorted_block``: every expert here, softmax scores; or
-    ``experts.moe_shared_local_block``: a share of the experts beside a
-    shared one, sigmoid scores chosen under ``bias``).  Returns (x, the
-    router's statistics, what the router, or a mixer's scan, read and
-    made by token row); the last two are empty for a layer with
-    neither."""
+    the layer holds and the configuration's published keys say.
+
+    A layer of a ``hybrid_override_pattern`` (Nemotron-3-Super) has
+    **one** sublayer: the Mamba-2 mixer where it holds ``in_proj``, the
+    latent relu2 expert block (``experts.moe_latent_block``) where it
+    holds a router, else grouped-query attention without RoPE.
+
+    Any other model's layer has an operator and then a feed-forward,
+    each behind its own norm and with its own residual add.  The
+    operator: of a ``layer_types`` model (LFM2-8B-A1B) the gated short
+    convolution where the layer holds ``in_proj``, else grouped-query
+    attention with a per-head QK-norm and RoPE; latent attention where
+    ``kv_lora_rank`` is set (JoyAI-LLM-Flash); else OLMoE's attention.
+    The feed-forward: a dense SwiGLU where the layer has no router
+    (JoyAI's and LFM2's leading layers), else the sparse MLP
+    (``experts.moe_sorted_block``: every expert here, softmax scores,
+    OLMoE; or ``experts.moe_shared_local_block``: a share of the
+    experts, beside a shared one if the model has it, sigmoid scores
+    chosen under ``bias``, JoyAI and LFM2).
+
+    Returns (x, the router's statistics, what the router, a mixer's
+    scan, a short convolution's gate path or RoPE read and made by token
+    row); the last two hold nothing of a sublayer the layer has not."""
     if cfg.hybrid_override_pattern:
         if "in_proj" in p:
             with jax.named_scope("otpu_mamba"):
@@ -442,12 +518,21 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
             return x + y, {}, seen
         if "router" not in p:
             with jax.named_scope("otpu_attention"):
-                return x + gqa_attention(p, x, cfg, interpret=interpret), \
+                return x + gqa_attention(p, x, cfg, interpret=interpret)[0], \
                     {}, {}
         with jax.named_scope("otpu_moe"):
             y, stats, routed = experts.moe_latent_block(p, x, cfg, bias)
         return x + y, stats, routed
-    if cfg.kv_lora_rank:
+    seen = {}
+    if cfg.layer_types and "in_proj" in p:
+        with jax.named_scope("otpu_conv"):
+            y, seen = short_conv(p, x, cfg)
+        x = x + y
+    elif cfg.layer_types:
+        with jax.named_scope("otpu_attention"):
+            y, seen = gqa_attention(p, x, cfg, interpret=interpret)
+        x = x + y
+    elif cfg.kv_lora_rank:
         with jax.named_scope("otpu_mla"):
             x = mla_attention(p, x, cfg, interpret=interpret)
     else:
@@ -458,10 +543,10 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
             h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps)
             y = swiglu(h.reshape(-1, h.shape[-1]), p["gate"], p["up"],
                        p["down"], cfg.compute_dtype)
-        return x + y.reshape(x.shape), {}, {}
+        return x + y.reshape(x.shape), {}, seen
     with jax.named_scope("otpu_moe"):
         if cfg.scoring_func == "sigmoid":
             y, stats, routed = experts.moe_shared_local_block(p, x, cfg, bias)
         else:
             y, stats, routed = experts.moe_sorted_block(p, x, cfg)
-    return x + y, stats, routed
+    return x + y, stats, {**routed, **seen}

@@ -1,6 +1,7 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
-Nemotron-3-Super): widths from a configuration file, not from the mesh;
-the kinds of sublayer from its published keys (``ModelConfig``).  The
+Nemotron-3-Super, LFM2-8B-A1B): widths from a configuration file, not
+from the mesh; the kinds of sublayer from its published keys
+(``ModelConfig``).  The
 parameter tree and its initialisation, the loss over the walked layers
 (``parallel/model.decoder_layer``), AdamW, the routers' bias update,
 ``build_train_step`` and what reads a finished step's ``aux``.  The batch
@@ -38,8 +39,20 @@ GAINS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "q_a_norm",
 #: not decayed, and each starts as ``init_model_params`` says
 UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b")
 #: a hybrid pattern's letters (nemotron_h) and the group a layer of each
-#: kind goes by in the parameter tree
-PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
+#: kind goes by in the parameter tree; behind them the letters a
+#: ``layer_types`` model's layers are walked by (lfm2_moe: an operator,
+#: then a feed-forward): a gated short convolution or grouped-query
+#: attention, before a dense SwiGLU (small letter) or the experts (capital)
+PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe",
+                 "c": "conv_dense", "a": "attn_dense",
+                 "C": "conv_moe", "A": "attn_moe"}
+#: the letters whose layer holds a router
+EXPERT_LETTERS = "ECA"
+#: ``layer_types``' names and the letter's lower case
+OPERATOR_LETTERS = {"conv": "c", "full_attention": "a"}
+#: what an operator's sublayer reports by token row goes by its own name
+#: into a step's ``sample``; what a router does, behind ``router_``
+OPERATOR_SAMPLES = ("ssm_", "conv_", "attn_")
 PROBE = 64              # entries of each leaf that a step reports
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
@@ -62,8 +75,16 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: ``head_in`` (R, d) (``mtp_head_in``), and of every Mamba-2 layer's
 #: first held head what its scan read, whole (``ssm_dt_seq`` (M, T),
 #: ``ssm_x_seq`` (M, T, p), ``ssm_b_seq``, ``ssm_c_seq`` (M, T, n)), and
-#: made (``ssm_y`` (M, R, p)), so that their precision can be read from
-#: one step alone
+#: made (``ssm_y`` (M, R, p)); of every gated short convolution's first
+#: ``model.CONV_SAMPLE`` channels what its gates and taps read, whole
+#: (``conv_bcu_seq`` (C, T, B | C | u)), and made (``conv_y`` (C, R, .));
+#: of every RoPE attention layer's first query and first key-value head
+#: the two side by side before the QK-norm (``attn_qk_in`` (A, R, 2 hd))
+#: and behind RoPE (``attn_qk``), so that their precision can be read
+#: from one step alone; under ``tie_word_embeddings``
+#: ``embed_probe_read`` (PROBE,), whether a probed entry of ``embed``
+#: lies in a row the step's tokens read (the others' gradient is the
+#: head's alone)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +109,18 @@ class ModelConfig:
     the key-value heads they read; ``mamba_heads_here`` Mamba heads with
     their B/C groups; 0 for all), as one member of a tensor-parallel
     group holds them.  ``mtp_here`` says how many of the published
-    next-n modules are held (-1: all of them)."""
+    next-n modules are held (-1: all of them).
+
+    lfm2_moe's keys: ``layer_types`` set gives every layer an operator by
+    its name (``conv`` the gated short convolution of ``conv_kernel``
+    taps, the file's ``conv_L_cache``; ``full_attention`` grouped-query
+    attention with a per-head QK-norm and RoPE) and then a feed-forward:
+    a dense SwiGLU in the model's first ``first_k_dense_replace`` layers
+    (the file's ``num_dense_layers``), the routed experts with no shared
+    one after them.  The rank holds the ``layers_here`` layers from
+    ``first_layer_here`` on, whole but for the experts.
+    ``tie_word_embeddings``: the head reads the embedding matrix, which
+    is then the one leaf of both (any model's)."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -153,11 +185,22 @@ class ModelConfig:
     mlp_hidden_act: str = "silu"
     moe_latent_size: int = 0
     moe_shared_expert_intermediate_size: int = 0
+    # lfm2_moe's keys (LFM2-8B-A1B)
+    layer_types: tuple = ()
+    tie_word_embeddings: bool = False
 
     @property
     def pattern_here(self) -> str:
-        """The letters of the layers held here ("" without a pattern)."""
+        """The letters of the layers held here ("" without a pattern): a
+        ``hybrid_override_pattern``'s own, or a ``layer_types`` model's
+        (``PATTERN_KINDS``)."""
         first = self.first_layer_here
+        if self.layer_types:
+            return "".join(
+                OPERATOR_LETTERS[kind] if i < self.first_k_dense_replace
+                else OPERATOR_LETTERS[kind].upper()
+                for i, kind in enumerate(self.layer_types)
+            )[first:first + self.layers_here]
         return self.hybrid_override_pattern[first:first + self.layers_here]
 
     @property
@@ -165,11 +208,13 @@ class ModelConfig:
         """The held pattern as runs of like layers, ``(unit, repeats,
         first layer)`` each: a unit is one letter or two different ones
         (``ME`` four times over, then ``M``, ``*``, ``E``), and a run of
-        more than one repeat is walked by one ``lax.scan``."""
+        more than one repeat is walked by one ``lax.scan``.  A
+        ``layer_types`` model's unit is one letter: its layer holds two
+        sublayers already."""
         pattern, out, i = self.pattern_here, [], 0
         while i < len(pattern):
             best = (pattern[i], 1)
-            for width in (1, 2):
+            for width in ((1,) if self.layer_types else (1, 2)):
                 unit = pattern[i:i + width]
                 if len(set(unit)) != width:
                     continue
@@ -188,8 +233,8 @@ class ModelConfig:
 
     @property
     def n_sparse_here(self) -> int:
-        if self.hybrid_override_pattern:
-            return self.pattern_here.count("E")
+        if self.pattern_here:
+            return sum(c in EXPERT_LETTERS for c in self.pattern_here)
         return self.layers_here - self.n_dense_here
 
     @property
@@ -218,8 +263,10 @@ class ModelConfig:
 
     @property
     def n_groups_here(self) -> int:
-        """The B/C groups of the held Mamba heads."""
-        return self.n_mamba_heads_here * self.n_groups // self.mamba_num_heads
+        """The B/C groups of the held Mamba heads (0 where the model has
+        no mixer)."""
+        return self.n_mamba_heads_here * self.n_groups \
+            // max(1, self.mamba_num_heads)
 
     @property
     def n_experts_here(self) -> int:
@@ -239,29 +286,52 @@ class ModelConfig:
 
     def __post_init__(self):
         hybrid = bool(self.hybrid_override_pattern)
+        # a file's list; a tuple so that the configuration stays hashable
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        typed = bool(self.layer_types)
         per_kv = self.num_attention_heads // max(1, self.num_key_value_heads)
-        if not hybrid and self.num_key_value_heads \
+        if not (hybrid or typed) and self.num_key_value_heads \
                 != self.num_attention_heads:
             raise NotImplementedError(
                 "num_key_value_heads: grouped-query attention is a "
-                "hybrid_override_pattern model's; this model's attention "
-                "has a key-value head a query head")
+                "hybrid_override_pattern or layer_types model's; this "
+                "model's attention has a key-value head a query head")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is not a multiple of the heads")
-        if hybrid and (self.num_attention_heads % self.num_key_value_heads
-                       or (self.n_heads_here % per_kv
-                           and per_kv % self.n_heads_here)):
+        if typed and (hybrid or set(self.layer_types)
+                      - set(OPERATOR_LETTERS)):
+            raise NotImplementedError(
+                f"layer_types {sorted(set(self.layer_types))}: a layer's "
+                f"operator is one of {sorted(OPERATOR_LETTERS)}, and the "
+                "model has no hybrid_override_pattern beside them")
+        if typed and (self.heads_here or self.kv_lora_rank
+                      or self.n_shared_experts or self.scoring_func
+                      != "sigmoid"):
+            raise NotImplementedError(
+                f"heads_here {self.heads_here} / kv_lora_rank "
+                f"{self.kv_lora_rank} / n_shared_experts "
+                f"{self.n_shared_experts} / scoring_func "
+                f"{self.scoring_func}: a layer_types model holds its "
+                "operators whole (no head is split), attends by grouped "
+                "key-value heads, and routes by sigmoid scores under a "
+                "bias to experts with no shared one beside them")
+        if (hybrid or typed) and (
+                self.num_attention_heads % self.num_key_value_heads
+                or (self.n_heads_here % per_kv
+                    and per_kv % self.n_heads_here)):
             raise NotImplementedError(
                 f"heads_here {self.n_heads_here}: the held query heads "
                 f"are neither whole key-value heads' ({per_kv} each) nor "
                 "a whole part of one's; a key-value head split across "
                 "chips is not run")
-        if hybrid and (set(self.pattern_here) - set(PATTERN_KINDS)
-                       or len(self.pattern_here) != self.layers_here):
+        if (hybrid or typed) and (
+                set(self.pattern_here) - set("M*E" if hybrid else "caCA")
+                or len(self.pattern_here) != self.layers_here):
             raise ValueError(
                 f"layers_here {self.layers_here} from first_layer_here "
                 f"{self.first_layer_here}: not layers of "
-                f"hybrid_override_pattern's letters {sorted(PATTERN_KINDS)}")
+                "hybrid_override_pattern's letters ['*', 'E', 'M'] or of "
+                "layer_types")
         if "M" in self.pattern_here and (
                 self.n_mamba_heads_here * self.n_groups
                 % self.mamba_num_heads):
@@ -273,11 +343,12 @@ class ModelConfig:
             raise NotImplementedError(
                 f"n_group {self.n_group} / topk_group {self.topk_group}: "
                 "the routers choose among one group of experts")
-        if hybrid and self.n_mtp_here:
+        if (hybrid or typed) and self.n_mtp_here:
             raise NotImplementedError(
                 f"mtp_here {self.n_mtp_here}: the next-n module of a "
                 "hybrid_override_pattern model (mtp_hybrid_override_"
-                "pattern) is not run; hold 0 of them")
+                "pattern) or of a layer_types model is not run; hold 0 "
+                "of them")
         if (self.mlp_hidden_act == "relu2") != bool(self.moe_latent_size):
             raise NotImplementedError(
                 f"mlp_hidden_act {self.mlp_hidden_act} with moe_latent_size "
@@ -302,17 +373,28 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
     with open(path, encoding="utf-8") as f:
         body = json.load(f)
     hybrid = "hybrid_override_pattern" in body
-    act = body.get("mlp_hidden_act") if hybrid else body.get("hidden_act")
+    typed = "layer_types" in body       # lfm2_moe: its file names no
+    #                                     activation, its code runs silu
+    act = body.get("mlp_hidden_act") if hybrid else body.get(
+        "hidden_act", "silu" if typed else None)
     if act != ("relu2" if hybrid else "silu") or body.get("attention_bias") \
-            or body.get("clip_qkv") or body.get("tie_word_embeddings") \
-            or body.get("rope_scaling") \
+            or body.get("clip_qkv") or body.get("rope_scaling") \
             or body.get("moe_layer_freq", 1) != 1 \
             or ("kv_lora_rank" in body and not body.get("rope_interleave")):
         raise NotImplementedError(
             f"{path}: the model path runs silu experts (relu2 in a "
-            "hybrid_override_pattern model), no biases, no clipping, an "
-            "untied head, plain RoPE (on interleaved pairs under latent "
-            "attention) and every layer past the dense ones sparse")
+            "hybrid_override_pattern model), no biases, no clipping, "
+            "plain RoPE (on interleaved pairs under latent attention) "
+            "and every layer past the dense ones sparse")
+    if typed and body.get("conv_bias"):
+        raise NotImplementedError(
+            f"{path}: conv_bias: the gated short convolution is run "
+            "without a bias")
+    if typed and not body.get("use_expert_bias"):
+        raise NotImplementedError(
+            f"{path}: use_expert_bias: a layer_types model's routers "
+            "choose under a balancing bias (scoring_func sigmoid, "
+            "topk_method noaux_tc)")
     if hybrid and (
             body.get("mamba_hidden_act") != "silu"
             or not body.get("use_conv_bias") or body.get("mamba_proj_bias")
@@ -334,6 +416,12 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
         merged.setdefault("num_experts", merged["n_routed_experts"])
     if "layer_norm_epsilon" in merged:      # nemotron_h's
         merged.setdefault("rms_norm_eps", merged["layer_norm_epsilon"])
+    if typed:                               # lfm2_moe's
+        for theirs, ours in (("norm_eps", "rms_norm_eps"),
+                             ("num_dense_layers", "first_k_dense_replace"),
+                             ("conv_L_cache", "conv_kernel")):
+            if theirs in merged:
+                merged.setdefault(ours, merged[theirs])
     return ModelConfig(**{k: v for k, v in merged.items() if k in known})
 
 
@@ -376,8 +464,32 @@ def pattern_layer_shapes(cfg: ModelConfig) -> dict:
     heads, k and v over the key-value heads they read.  ``moe``: the
     gain, the router over all the experts, the latent's two projections,
     the held experts' two matrices in the latent, the shared expert's two
-    on the hidden width."""
+    on the hidden width.
+
+    A ``layer_types`` model's kinds are an operator's leaves and a
+    feed-forward's together.  ``conv_*``: the operator norm's gain,
+    ``in_proj`` (d, B | C | u), the taps (kernel, d), ``out_proj``;
+    ``attn_*``: the gain, q and o over the query heads, k and v over the
+    key-value heads, the per-head QK-norm's two gains; ``*_dense``: the
+    feed-forward norm's gain and SwiGLU's three matrices; ``*_moe``: the
+    gain, the router over all the experts, the held experts' three."""
     d, e = cfg.hidden_size, cfg.n_experts_here
+    if cfg.layer_types:
+        hd = d // cfg.num_attention_heads
+        kv, ff, f = cfg.num_key_value_heads * hd, cfg.intermediate_size, \
+            cfg.expert_width
+        ops = {"conv": {"ln1": (d,), "in_proj": (d, 3 * d),
+                        "conv_w": (cfg.conv_kernel, d), "out_proj": (d, d)},
+               "attn": {"ln1": (d,), "wq": (d, d), "wk": (d, kv),
+                        "wv": (d, kv), "wo": (d, d), "q_norm": (hd,),
+                        "k_norm": (hd,)}}
+        ffns = {"dense": {"ln2": (d,), "gate": (d, ff), "up": (d, ff),
+                          "down": (ff, d)},
+                "moe": {"ln2": (d,), "router": (d, cfg.num_experts),
+                        "gate": (e, d, f), "up": (e, d, f),
+                        "down": (e, f, d)}}
+        return {f"{op}_{ffn}": {**ops[op], **ffns[ffn]}
+                for op in ops for ffn in ffns}
     nh, g = cfg.n_mamba_heads_here, cfg.n_groups_here
     inner, bc = nh * cfg.mamba_head_dim, 2 * g * cfg.ssm_state_size
     hd = d // cfg.num_attention_heads
@@ -403,21 +515,23 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
     sparse layers, stacked), ``mtp`` (the next-next-token module: two
     norms, the projection of their joined outputs, one sparse layer, a
     last norm; absent where the model has none), ``final_norm``,
-    ``head``.  Under a ``hybrid_override_pattern`` ``layers`` holds a
-    group a run of like layers (``cfg.segments``), ``l<first layer>``,
-    and in it a group a letter of the run's unit (``mamba``, ``attn``,
-    ``moe``) whose leaves are stacked over the run's repeats."""
+    ``head`` (absent under ``tie_word_embeddings``: the head reads
+    ``embed``).  Under a ``hybrid_override_pattern`` or ``layer_types``
+    ``layers`` holds a group a run of like layers (``cfg.segments``),
+    ``l<first layer>``, and in it a group a letter of the run's unit
+    (``PATTERN_KINDS``) whose leaves are stacked over the run's repeats."""
     d, ff, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_rows
     stack = lambda n, shapes: {k: (n,) + s for k, s in shapes.items()}
     tree = {"embed": (v, d)}
-    if cfg.hybrid_override_pattern:
+    last = {"final_norm": (d,)} if cfg.tie_word_embeddings \
+        else {"final_norm": (d,), "head": (d, v)}
+    if cfg.pattern_here:
         kinds = pattern_layer_shapes(cfg)
         tree["layers"] = {
             f"l{first}": {PATTERN_KINDS[c]: stack(n, kinds[PATTERN_KINDS[c]])
                           for c in unit}
             for unit, n, first in cfg.segments}
-        tree.update(final_norm=(d,), head=(d, v))
-        return tree
+        return {**tree, **last}
     if cfg.n_dense_here:
         tree["dense"] = stack(cfg.n_dense_here, {
             **attention_shapes(cfg), "ln2": (d,), "gate": (d, ff),
@@ -426,8 +540,7 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
     if cfg.n_mtp_here:
         tree["mtp"] = {"enorm": (d,), "hnorm": (d,), "proj": (2 * d, d),
                        **sparse_layer_shapes(cfg), "norm": (d,)}
-    tree.update(final_norm=(d,), head=(d, v))
-    return tree
+    return {**tree, **last}
 
 
 def is_gain(name: str) -> bool:
@@ -605,31 +718,39 @@ def _walk_layers(run, stacked, x, bias, n: int):
 
 
 def _walk_pattern(run, layers, x, bias, cfg: ModelConfig):
-    """The held layers of a ``hybrid_override_pattern`` in turn, a run of
-    like layers at a time (``cfg.segments``; ``layers`` holds a group a
-    run): a run's unit is called once, or scanned over its repeats
-    (``_walk_layers``), each of its layers through ``run``.  ``bias``
-    (the held expert layers, E) gives each expert layer its row.
-    Returns (x, {letter: the outs of that letter's layers stacked in
-    the layers' order})."""
-    outs, done = {}, 0          # done: the expert layers walked so far
+    """The held layers of a ``hybrid_override_pattern`` or ``layer_types``
+    model in turn, a run of like layers at a time (``cfg.segments``;
+    ``layers`` holds a group a run): a run's unit is called once, or
+    scanned over its repeats (``_walk_layers``), each of its layers
+    through ``run``.  ``bias`` (the held expert layers, E) gives each
+    layer with a router its row.  Returns (x, what the layers' ``run``
+    gave: the routers' statistics and chosen experts and the sampled
+    rows, each stacked in the layers' order over the layers that have
+    it; a unit of two letters has no key in both)."""
+    stats, chosen, sample, done = {}, [], {}, 0   # done: routers walked
     for unit, n, first in cfg.segments:
         def unit_run(group, x, bias_row, unit=unit):
             out = {}
             for letter in unit:
-                x, out[letter] = run(group[PATTERN_KINDS[letter]], x,
-                                     bias_row if letter == "E" else None)
+                x, out[letter] = run(
+                    group[PATTERN_KINDS[letter]], x,
+                    bias_row if letter in EXPERT_LETTERS else None)
             return x, out
 
         rows = None
-        if "E" in unit:
+        if set(unit) & set(EXPERT_LETTERS):
             rows, done = bias[done:done + n], done + n
         x, out = _walk_layers(unit_run, layers[f"l{first}"], x, rows, n)
         for letter in unit:
-            outs.setdefault(letter, []).append(out[letter])
+            st, experts, seen = out[letter]
+            for into, part in ((stats, st), (sample, seen)):
+                for k, v in part.items():
+                    into.setdefault(k, []).append(v)
+            if experts is not None:
+                chosen.append(experts)
     with jax.named_scope("otpu_stats"):
-        return x, {letter: jax.tree.map(lambda *a: jnp.concatenate(a), *of)
-                   for letter, of in outs.items()}
+        cat = lambda of: {k: jnp.concatenate(v) for k, v in of.items()}
+        return x, (cat(stats), jnp.concatenate(chosen), cat(sample))
 
 
 def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
@@ -653,10 +774,12 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                                     bias=bias_row)
         experts = seen.pop("experts", None)
         with jax.named_scope("otpu_stats"):
-            # a router's rows at the sampled ones; of a mixer's scan the
-            # sequences whole (``_seq``) and its result at the sampled
+            # a router's rows at the sampled ones; of a mixer's scan, or
+            # a short convolution's input, the sequences whole (``_seq``)
+            # and its result at the sampled; q and k around their norm
+            # and RoPE at the sampled
             out = (jax.tree.map(psum, st), experts, {
-                k if k.startswith("ssm_") else "router_" + k:
+                k if k.startswith(OPERATOR_SAMPLES) else "router_" + k:
                 v if k.endswith("_seq") else v[at] for k, v in seen.items()})
         return x, out
 
@@ -671,23 +794,24 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32
     with jax.named_scope("otpu_layers"):
-        if cfg.n_dense_here:
-            x, _ = _walk_layers(run, params["dense"], x, None,
-                                cfg.n_dense_here)
-        if cfg.hybrid_override_pattern:
-            x, outs = _walk_pattern(run, params["layers"], x,
-                                    bias.get("layers"), cfg)
-            st, chosen, sample = outs["E"]
-            sample.update(outs.get("M", ({}, None, {}))[2])
+        if cfg.pattern_here:
+            x, (st, chosen, sample) = _walk_pattern(
+                run, params["layers"], x, bias.get("layers"), cfg)
         else:
+            if cfg.n_dense_here:
+                x, _ = _walk_layers(run, params["dense"], x, None,
+                                    cfg.n_dense_here)
             x, (st, chosen, sample) = _walk_layers(
                 run, params["layers"], x, bias.get("layers"),
                 cfg.n_sparse_here)
     head_rows = min(cfg.loss_block_rows, b * s)
+    # a tied head reads the embedding matrix itself: one leaf, whose
+    # gradient is the sum of the gather's and the cross-entropy's
+    head = params["embed"].T if cfg.tie_word_embeddings else params["head"]
     with jax.named_scope("otpu_head"):
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
-            h.reshape(b * s, -1), params["head"],
+            h.reshape(b * s, -1), head,
             labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
     routed = cfg.n_sparse_here * n_global   # rows of all routers' logits
     with jax.named_scope("otpu_loss"):
@@ -729,7 +853,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             with jax.named_scope("otpu_head"):
                 h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
                 ce2_sum, aux["mtp_rows"] = head_cross_entropy(
-                    h2.reshape(b * s, -1), params["head"],
+                    h2.reshape(b * s, -1), head,
                     labels[:, 1:].reshape(b * s), head_rows,
                     cfg.compute_dtype)
         with jax.named_scope("otpu_loss"):
@@ -839,6 +963,14 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         with jax.named_scope("otpu_stats"):
             aux.update(grad_sq=jnp.stack(sq), grad_probe=jnp.stack(g_probe),
                        param_probe=jnp.stack(p_probe))
+            if cfg.tie_word_embeddings:
+                # which probed entries of the tied matrix lie in a row
+                # the gather read this step: the others' gradient is the
+                # head's alone
+                read = jnp.any(tokens[..., None] == probes["embed"][0],
+                               axis=(0, 1))
+                aux["embed_probe_read"] = jax.lax.psum(
+                    read.astype(jnp.float32), "dp") > 0
         if biased:
             # DeepSeek-V3's auxiliary-loss-free balancing: after the
             # step an expert that took more than the mean of the whole
@@ -864,6 +996,15 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         aux_specs["sample"].update(
             {"ssm_" + k: rows for k in ("x_seq", "b_seq", "c_seq", "y")},
             ssm_dt_seq=P(None, "dp"))
+    if cfg.layer_types:
+        held = set(cfg.pattern_here.lower())
+        aux_specs["sample"].update(
+            {k: rows for letter, keys in (
+                ("c", ("conv_bcu_seq", "conv_y")),
+                ("a", ("attn_qk_in", "attn_qk")))
+             if letter in held for k in keys})
+    if cfg.tie_word_embeddings:
+        aux_specs["embed_probe_read"] = rep
     if cfg.n_mtp_here:
         aux_specs["mtp_rows"] = aux_specs["sample"]["mtp_head_in"] = batch
     if cfg.n_experts_here < cfg.num_experts:

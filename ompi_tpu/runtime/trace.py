@@ -893,6 +893,9 @@ STEP_SCOPES = (
     "otpu_ssm_scan",        # softplus, the chunked state-space scan, D x
     "otpu_ssm_norm",        # the gate and the grouped norm
     "otpu_latent",          # inside otpu_moe: the latent's two projections
+    "otpu_conv",            # a layer's gated short convolution, whole
+    "otpu_conv_proj",       # inside it: the pre-norm, in_proj, out_proj
+    "otpu_conv_gate",       # B * u, the taps, C * z
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

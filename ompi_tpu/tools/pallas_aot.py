@@ -268,6 +268,10 @@ def cases(mesh1d, mesh2d):
          lambda: flash_causal_forward(2, 16, 4096, 128, 128))
     case("joyai_flash_causal_forward",
          lambda: flash_causal_forward(1, 32, 8192, 192, 128))
+    # LFM2's: 2 x 32 query heads x 8,192 at a head width of 64 (half the
+    # MXU's contraction depth, half a tile's lanes)
+    case("lfm2_flash_causal_forward",
+         lambda: flash_causal_forward(2, 32, 8192, 64, 64))
     case("olmoe_attn_block_backward_1k",
          lambda: attn_block_backward(2, 16, 4096, 128, 128))
     case("joyai_attn_block_backward_1k",
@@ -276,6 +280,10 @@ def cases(mesh1d, mesh2d):
          lambda: attn_backward_walk(2, 16, 4096, 128, 128))
     case("joyai_attn_backward_walk_8k",
          lambda: attn_backward_walk(1, 32, 8192, 192, 128))
+    case("lfm2_attn_block_backward_1k",
+         lambda: attn_block_backward(2, 32, 8192, 64, 64))
+    case("lfm2_attn_backward_walk_8k",
+         lambda: attn_backward_walk(2, 32, 8192, 64, 64))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),
@@ -370,7 +378,9 @@ def cases(mesh1d, mesh2d):
     # matmuls, backward and AdamW (OLMoE-1B-7B, one layer of 16;
     # JoyAI-LLM-Flash, one chip's share of a 16-chip deployment;
     # Nemotron-3-Super, one chip's share of a 64-chip deployment: the
-    # chunked state-space scan, grouped-query attention, latent experts)
+    # chunked state-space scan, grouped-query attention, latent experts;
+    # LFM2-8B-A1B, one chip's share of a 4-chip deployment: gated short
+    # convolutions, attention at a head width of 64, a tied head)
     def model_config(config):
         return train.load_model_config(os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(
@@ -436,6 +446,8 @@ def cases(mesh1d, mesh2d):
     case("joyai_mla_operands", lambda: mla_operands(topo_devs[:1]))
     case("nemotron3_step_1chip", lambda: model_step(
         topo_devs[:1], "nemotron3-super-train-1chip"))
+    case("lfm2_step_1chip", lambda: model_step(
+        topo_devs[:1], "lfm2-8b-a1b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

@@ -112,6 +112,31 @@ def _tiny_hybrid(config):
     config["train"].update(TINY_SHARE_TRAIN)
 
 
+# lfm2-train-1chip: the same, at the widths of tests/test_lfm2_train.py
+# (the published layer_types' layers 1 to 6: a dense convolution layer, an
+# attention layer, a scanned run of three convolution layers, an attention
+# layer; 4 query heads of 16 reading 2 key-value heads, 2 of 8 experts, 64
+# of 256 ids)
+TINY_TYPED = dict(hidden_size=64, intermediate_size=96,
+                  num_attention_heads=4, num_key_value_heads=2,
+                  moe_intermediate_size=24, num_experts=8,
+                  num_experts_per_tok=2, vocab_size=256, vocab_here=64,
+                  experts_here=2)
+TINY_TYPED_TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16,
+                        loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_typed(config):
+    config.update(TINY_TYPED)
+    config["train"].update(TINY_TYPED_TRAIN)
+
+
+def _cut_batch_typed(p):
+    p.update(sequences=TINY_TYPED_TRAIN["micro_batch"],
+             seq_len=TINY_TYPED_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -169,11 +194,16 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("nemotron3-super-train-1chip", _tiny_hybrid),
         cut={"packed-8k-hybrid-steps": _cut_batch_share}),
+    "lfm2-train-1chip": dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("lfm2-8b-a1b-train-1chip", _tiny_typed),
+        cut={"packed-8k-conv-steps": _cut_batch_typed}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
-              "nemotron3-train-1chip")
+              "nemotron3-train-1chip", "lfm2-train-1chip")
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the child: run_cell as the command calls it, but for the three
@@ -515,6 +545,31 @@ def test_a_hybrid_step_counts_its_state_space_tokens(rehearsal):
     assert rehearsal["builds"] == [1, 1]
 
 
+@of_cells("lfm2-train-1chip")
+def test_a_typed_step_counts_its_routers_and_its_slots(rehearsal):
+    """The trainer's counters on one chip's share of a ``layer_types``
+    model, by the kind that reads everything from the kit: the routers'
+    bias updates follow from the steps issued (5 sparse layers of the 6
+    held, no next-n module, no state-space layer); over the steps read
+    back every slot went to a held expert or to an absent one; the
+    step's program is the one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_TYPED_TRAIN["micro_batch"] * TINY_TYPED_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.lfm2.bf16.2x8192"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["train_tokens"] == c["train_steps"] * tokens
+    assert not c.get("train_ssm_layer_tokens") \
+        and not c.get("train_mtp_tokens")
+    assert c["moe_token_slots"] == c["train_tokens"] * 2 * 5
+    assert c["moe_bias_updates"] == c["train_steps"] * 5
+    assert c["train_steps_read"] >= 3
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 2 * 5
+    assert rehearsal["builds"] == [1, 1]
+
+
 def test_train_check_tells_the_program_from_its_control(tmp_path):
     """``benchmark/tools/train_check.py`` at the rehearsal's widths: one
     step of the program lies within the kind's tolerance of the
@@ -609,6 +664,46 @@ def test_kit_check_tells_the_hybrid_program_from_its_controls(tmp_path):
                                      for r in rows)
     assert summary["parts_scan_bf16"] == min(
         r["parts_scan_bf16"]["widest_units"] for r in rows)
+
+
+def test_kit_check_tells_the_typed_program_from_its_controls(tmp_path):
+    """``benchmark/tools/kit_check.py`` on LFM2's cell at the rehearsal's
+    widths, with no file of the harness edited for it: one step of the
+    program lies within the kind's tolerance of ``lfm2kit``'s reference;
+    the reference computed in bfloat16 lies far outside the program's,
+    and each of the kit's six controls of a part outside the tolerance
+    at that part: a bfloat16 router and head, gates and taps in
+    bfloat16, the bias in the weights, a softmax in the sigmoid's place,
+    an untied head, RoPE left out."""
+    env = _stage("lfm2-train-1chip", str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", "lfm2-train-1chip", "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "2", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    (summary,) = [json.loads(ln[8:]) for ln in done.stdout.splitlines()
+                  if ln.startswith("summary ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["widest_units"] < 0.05
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        for variant, part in (("bf16", "router_logits"),
+                              ("conv_bf16", "conv_y"),
+                              ("softmax", "router_scores"),
+                              ("untied", "head_rows"),
+                              ("no_rope", "rope_qk")):
+            assert row["parts_" + variant]["units_by_group"][part] > 1, \
+                variant
+        assert row["parts_bias_in_weights"]["widest_units"] \
+            > 10 * row["program"]["units_by_group"]["router_weights"]
+    assert summary["program"] == max(r["program"]["widest_units"]
+                                     for r in rows)
+    assert summary["parts_conv_bf16"] == min(
+        r["parts_conv_bf16"]["widest_units"] for r in rows)
 
 
 def test_a_share_of_the_busy_seconds_counts_no_loop_twice(tmp_path,

@@ -165,7 +165,7 @@ def test_grouped_query_attention_is_the_references(heads, kv, here):
             argnums=(0, 1))(p, x)
     got, got_g = jax.value_and_grad(
         lambda p, x: jnp.sum(model.gqa_attention(
-            p, x, cfg, interpret=True) * probe), argnums=(0, 1))(p, x)
+            p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1))(p, x)
     close(got, want, rtol=1e-4)
     for k in p:
         near(got_g[0][k], want_g[0][k], err_msg=k)
@@ -234,7 +234,8 @@ def test_the_head_shares_add_up_to_the_uncut_layers():
         q, kv = cols(j, 0, 8), cols(j // 4, 0, 4)
         mine = {"ln1": p["ln1"], "wq": p["wq"][:, q], "wk": p["wk"][:, kv],
                 "wv": p["wv"][:, kv], "wo": p["wo"][q]}
-        total = total + model.gqa_attention(mine, x, part, interpret=True)
+        total = total + model.gqa_attention(mine, x, part,
+                                            interpret=True)[0]
     with jax.default_matmul_precision("highest"):
         close(total, ref.attention(p, x, whole), rtol=1e-4, atol=1e-5)
 

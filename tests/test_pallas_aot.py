@@ -256,6 +256,54 @@ def nemotron_rows():
     return _rows_with_texts("nemotron3")
 
 
+@pytest.fixture(scope="module")
+def lfm2_rows():
+    """One child for the LFM2-8B-A1B cases: attention's two kernels at a
+    head width of 64 alone and the whole step of the cell's own
+    configuration file, for one v5e device (about 45 s of the 600)."""
+    return _rows_with_texts("lfm2_")
+
+
+def test_attention_forward_aot_compiles_at_a_head_width_of_64(lfm2_rows):
+    """The forward pass in one call with q, k and v 64 wide (2 x 32
+    heads x 8,192): the kernel compiles as it is, half a tile's lanes
+    Mosaic's to lay out.  XLA itself keeps such an array with the 8,192
+    positions minor (64 is half a lane tile) and copies it into the
+    kernel's layout, here and in the step (PERF.md 5 has what the copies
+    cost on the chip)."""
+    row = lfm2_rows["lfm2_flash_causal_forward"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
+    assert not {"concatenate", "fusion"} & set(row["entry_ops"])
+
+
+def test_attention_backward_aot_compiles_at_a_head_width_of_64(lfm2_rows):
+    """The backward's block pair with q, k and v 64 wide (2 x 32 heads x
+    8,192, blocks of 1,024), alone and as the 36 pairs of one
+    ``lax.scan``: the kernel is in the loop's body, one loop."""
+    row = lfm2_rows["lfm2_attn_block_backward_1k"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
+    walk = lfm2_rows["lfm2_attn_backward_walk_8k"]
+    assert walk.get("compiled"), json.dumps(walk, indent=1)
+    assert walk["entry_ops"].get("while") == 1, walk["entry_ops"]
+
+
+def test_lfm2_train_step_aot_compiles_from_the_cells_configuration(
+        lfm2_rows):
+    """The whole step of ``benchmark/configs/lfm2-8b-a1b-train-1chip
+    .json`` (published widths; layers 1-6 of 24, 8 of 32 experts, 2 x
+    8,192 tokens): it fits the chip beside its 7.3 GB of state, the
+    three like convolution layers are one loop, and the tied matrix is
+    one argument of the state's three trees (no ``head`` beside it)."""
+    row = lfm2_rows["lfm2_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 606_456_064 + (1 << 20)
+
+
 def op_paths(row):
     """(line, ``op_name`` path) of every instruction of a row's compiled
     text that has one."""
@@ -278,7 +326,8 @@ def fits_a_v5e(row) -> bool:
 
 @pytest.mark.parametrize("rows,case", [
     ("joyai_rows", "joyai_step_1chip"),
-    ("nemotron_rows", "nemotron3_step_1chip")])
+    ("nemotron_rows", "nemotron3_step_1chip"),
+    ("lfm2_rows", "lfm2_step_1chip")])
 def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
                                                             request):
     """A walked layer's checkpoint keeps what the expert block names
@@ -312,7 +361,9 @@ def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
                                         "jvp(otpu_layers)/while/body",
                                         "jvp(otpu_mtp)/otpu_layers/otpu_mla"]),
     ("nemotron_rows", "nemotron3_step_1chip",
-     ["jvp(otpu_layers)/otpu_attention"])])
+     ["jvp(otpu_layers)/otpu_attention"]),
+    ("lfm2_rows", "lfm2_step_1chip",
+     ["jvp(otpu_layers)/otpu_attention"] * 2)])
 def test_a_checkpoints_recomputed_pass_aot_holds_no_attention_forward(
         rows, case, calls, request):
     """A walked layer's checkpoint keeps causal attention's o and

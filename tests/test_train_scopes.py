@@ -19,6 +19,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from test_joyai_train import F32 as JOYAI
+from test_lfm2_train import F32 as LFM2
 from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 
@@ -220,12 +221,19 @@ def nemotron():
     return step, step.scopes()
 
 
+@pytest.fixture(scope="module")
+def lfm2():
+    step, args = built(LFM2)
+    step(*args)
+    return step, step.scopes()
+
+
 def ran(scopes):
     return {k: v for k, v in scopes["ops"].items()
             if v["opcode"] not in trace.TRIVIAL_OPCODES}
 
 
-@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron"])
+@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2"])
 def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     scopes = request.getfixturevalue(which)[1]
     assert scopes["module"] == "jit_otpu_train_step"
@@ -238,6 +246,8 @@ def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     assert ({"otpu_mamba", "otpu_ssm_proj", "otpu_ssm_conv", "otpu_ssm_scan",
              "otpu_ssm_norm", "otpu_latent"} <= named) \
         == (which == "nemotron")
+    assert ({"otpu_conv", "otpu_conv_proj", "otpu_conv_gate"} <= named) \
+        == (which == "lfm2")
     assert {v["pass"] for v in scopes["ops"].values()} <= {
         None, *trace.PASSES}
 
@@ -267,7 +277,31 @@ def test_every_op_of_a_mixer_lands_under_its_scope(nemotron):
     assert latent and all("otpu_moe" in v["chain"] for v in latent)
 
 
-@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron"])
+def test_every_op_of_a_short_convolution_lands_under_its_scope(lfm2):
+    """Every instruction whose path passes through ``short_conv`` has
+    ``otpu_conv`` and one of its two parts in its chain, in the forward
+    pass, the recomputed one and the backward one; the new names stand
+    behind the vocabulary's earlier ones; and the layer's other sublayers
+    keep the scopes they have in the other models."""
+    assert trace.STEP_SCOPES[-3:] == ("otpu_conv", "otpu_conv_proj",
+                                      "otpu_conv_gate")
+    ops = ran(lfm2[1])
+    parts = {"otpu_conv_proj", "otpu_conv_gate"}
+    conv = [v for v in ops.values() if "otpu_conv" in v["chain"]]
+    assert len(conv) > 30
+    assert [v for v in conv if not parts & set(v["chain"])
+            and not v["inherited"]] == []
+    for part in parts:
+        under = [v for v in ops.values() if part in v["chain"]]
+        assert all("otpu_conv" in v["chain"] for v in under), part
+        assert {"forward", "remat", "backward"} <= {v["pass"]
+                                                    for v in under}, part
+    named = {s for v in ops.values() for s in v["chain"]}
+    assert {"otpu_attention", "otpu_dense_mlp", "otpu_bias_update"} <= named
+    assert "otpu_shared_expert" not in named and "otpu_mla" not in named
+
+
+@pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2"])
 def test_every_instruction_the_program_wrote_has_a_chain(which, request):
     """Not a parameter, constant, tuple or bitcast, and with a path of
     the program's (``pass`` None: the compiler's own, which on the CPU
@@ -336,8 +370,9 @@ def routing_by_pass(cfg, kinds_of=ROUTING):
 
 
 @pytest.mark.parametrize("cfg,loop_is_read", [(JOYAI, False),
-                                              (NEMOTRON, True)],
-                         ids=["joyai", "nemotron"])
+                                              (NEMOTRON, True),
+                                              (LFM2, False)],
+                         ids=["joyai", "nemotron", "lfm2"])
 def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         cfg, loop_is_read, monkeypatch):
     """``model_loss``'s checkpoint keeps what an expert block names
@@ -353,6 +388,7 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
     assert kinds["remat"] == set()
     assert "otpu_attn_proj" in scopes["remat"]
     assert ("otpu_mamba" in scopes["remat"]) == (cfg is NEMOTRON)
+    assert ("otpu_conv_gate" in scopes["remat"]) == (cfg is LFM2)
     # the elementwise rest of the router is recomputed: scores, weights
     assert "otpu_router" in scopes["remat"]
     monkeypatch.setattr(train, "layer_checkpoint_policy",
@@ -363,7 +399,8 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         set() if loop_is_read else {"experts' loop"})
 
 
-@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON], ids=["joyai", "nemotron"])
+@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2],
+                         ids=["joyai", "nemotron", "lfm2"])
 def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
                                                               monkeypatch):
     """``model_loss``'s checkpoint keeps causal attention's o and
