@@ -9,12 +9,15 @@ chooses among them; the primitives come from ``parallel/layers.py``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.parallel.layers import (cast_param, matmul, relu2,
                                       rmsnorm_gain, swiglu)
+from ompi_tpu.runtime import spc
 
 
 def route_topk(logits, top_k: int, normalize: bool = False):
@@ -43,15 +46,73 @@ def sorted_dispatch(experts, n_experts: int):
     return order // k, place.reshape(t, k), sizes
 
 
-def _grouped_matmul(sizes, compute_dtype):
+def _count_built(products: int, on_kernel: bool) -> None:
+    """SPC ``moe_gmm_built``: the grouped matmuls ``_grouped_matmul``
+    made, forward or transposed, while steps were traced;
+    ``moe_gmm_kernel_built``: those of them made on the Pallas kernel.
+    JAX traces a product more than once and makes a ``ragged_dot``'s
+    transposes itself: what reads is the second over the first, 0 where
+    no shape took the kernel and 1 where every one did."""
+    spc.record("moe_gmm_built", products)
+    if on_kernel:
+        spc.record("moe_gmm_kernel_built", products)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kernel_matmul(a, w, sizes, dtype):
+    """``a`` (m, k) times the stacked ``w`` (g, k, n) by group on the
+    Pallas kernel (``ops/grouped_matmul.gmm``), both cast to ``dtype`` on
+    the way in, with its gradient written out: the rows' is ``gmm``
+    against the transposed matrices, the matrices' ``tgmm``, both of the
+    cotangent cast to ``dtype`` (what the MXU takes of a float32 one:
+    ``lax.ragged_dot``'s transposes on a TPU hand it over in float32 and
+    the kernel rounds it, ``contract_precision<bf16>``) and both float32
+    until they are cast to ``a``'s and ``w``'s dtypes."""
+    return _kernel_matmul_fwd(a, w, sizes, dtype)[0]
+
+
+def _kernel_matmul_fwd(a, w, sizes, dtype):
+    from ompi_tpu.ops import grouped_matmul as kernel
+
+    _count_built(1, True)
+    a16, w16 = a.astype(dtype), cast_param(w, dtype)
+    # the empty arrays carry the primals' dtypes to the backward pass
+    return kernel.gmm(a16, w16, sizes), (
+        a16, w16, sizes, jnp.zeros((0,), a.dtype), jnp.zeros((0,), w.dtype))
+
+
+def _kernel_matmul_bwd(dtype, res, ct):
+    from ompi_tpu.ops import grouped_matmul as kernel
+
+    _count_built(2, True)
+    a16, w16, sizes, a0, w0 = res
+    ct = ct.astype(dtype)
+    da = kernel.gmm(ct, w16, sizes, transpose_rhs=True)
+    dw = kernel.tgmm(a16, ct, sizes)
+    return da.astype(a0.dtype), dw.astype(w0.dtype), None
+
+
+_kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
+
+
+def _grouped_matmul(sizes, compute_dtype, interpret: bool):
     """``gmm(a, w)``: rows of ``a`` sorted by expert times the experts'
-    stacked matrices ``w`` (``lax.ragged_dot``: group e is the
-    ``sizes[e]`` rows that expert e received), inputs in
-    ``compute_dtype``, float32 results."""
+    stacked matrices ``w`` (group e is the ``sizes[e]`` rows that expert
+    e received), inputs in ``compute_dtype``, float32 results.  Where
+    Mosaic compiles (``interpret`` false: a TPU), the inputs are bfloat16
+    and the shape has tiles (``ops/grouped_matmul.supported``) it is the
+    Pallas kernel (``_kernel_matmul``); everywhere else
+    ``lax.ragged_dot``."""
     f32 = jnp.dtype(compute_dtype) == jnp.float32
     prec = jax.lax.Precision.HIGHEST if f32 else None
+    on_mosaic = not interpret and jnp.dtype(compute_dtype) == jnp.bfloat16
+    if on_mosaic:
+        from ompi_tpu.ops import grouped_matmul as kernel
 
     def gmm(a, w):
+        if on_mosaic and kernel.supported(*a.shape, w.shape[2]):
+            return _kernel_matmul(a, w, sizes, compute_dtype)
+        _count_built(1, False)
         return jax.lax.ragged_dot(
             a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
             precision=prec, preferred_element_type=jnp.float32)
@@ -59,23 +120,25 @@ def _grouped_matmul(sizes, compute_dtype):
     return gmm
 
 
-def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype):
+def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype,
+                       interpret: bool = True):
     """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
     up x)`` as three grouped matmuls (``_grouped_matmul``)."""
-    gmm = _grouped_matmul(sizes, compute_dtype)
+    gmm = _grouped_matmul(sizes, compute_dtype, interpret)
     hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
     return gmm(hidden, down)
 
 
-def grouped_relu2_ffn(xs, up, down, sizes, compute_dtype):
+def grouped_relu2_ffn(xs, up, down, sizes, compute_dtype,
+                      interpret: bool = True):
     """relu2 experts (nemotron_h: two matrices, no gate) on slots sorted
     by expert: ``down(relu(up x)^2)`` as two grouped matmuls
     (``_grouped_matmul``)."""
-    gmm = _grouped_matmul(sizes, compute_dtype)
+    gmm = _grouped_matmul(sizes, compute_dtype, interpret)
     return gmm(jnp.square(jax.nn.relu(gmm(xs, up))), down)
 
 
-def moe_sorted_block(p, x, cfg):
+def moe_sorted_block(p, x, cfg, *, interpret: bool = True):
     """OLMoE's sparse MLP sublayer on the residual stream ``x`` (b, s,
     d): pre-norm, a learned router (logits and softmax in float32), the
     top k of all experts with no capacity, sort-and-gather dispatch,
@@ -97,7 +160,7 @@ def moe_sorted_block(p, x, cfg):
     with jax.named_scope("otpu_experts"):
         y = grouped_expert_ffn(h.astype(cfg.compute_dtype)[token],
                                p["gate"], p["up"], p["down"], sizes,
-                               cfg.compute_dtype)
+                               cfg.compute_dtype, interpret)
     with jax.named_scope("otpu_combine"):
         out = jnp.sum(y[place] * weights[..., None], axis=1)
     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -166,7 +229,7 @@ def local_dispatch(experts, first: int, n_here: int):
 
 
 def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
-                     ffn=grouped_expert_ffn):
+                     ffn=grouped_expert_ffn, interpret: bool = True):
     """The held experts' weighted part of the layer's output (T, d):
     gather the held slots' rows, grouped matmuls (``ffn`` over the held
     experts' stacked matrices ``mats``: SwiGLU's three, or relu2's two
@@ -200,7 +263,7 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         # matmul leaves them as they were in memory (seen on the v5e:
         # NaN), in its transposes too, so they are cut off on both sides
         xs = jnp.where(live[:, None], h[token], 0.0)
-        y = ffn(xs, *mats, here, cfg.compute_dtype)
+        y = ffn(xs, *mats, here, cfg.compute_dtype, interpret)
         with jax.named_scope("otpu_combine"):
             w = jnp.where(live, flat_w[slot], 0.0)
             return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
@@ -268,7 +331,7 @@ def _route_to_held(p, x, cfg, bias):
         "experts": experts}
 
 
-def moe_shared_local_block(p, x, cfg, bias):
+def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True):
     """DeepSeek-V3's sparse MLP sublayer (arXiv:2412.19437 section
     2.1.2) on the residual stream ``x`` (b, s, d), on a rank that holds
     ``experts_here`` of the routed experts: pre-norm; the router's
@@ -289,13 +352,14 @@ def moe_shared_local_block(p, x, cfg, bias):
                             p["shared_down"], cfg.compute_dtype)
     with jax.named_scope("otpu_experts"):
         out = local_expert_ffn(h, order, seen["weights"], sizes,
-                               (p["gate"], p["up"], p["down"]), cfg)
+                               (p["gate"], p["up"], p["down"]), cfg,
+                               interpret=interpret)
         if shared is not None:
             out = shared + out
     return out.reshape(x.shape), stats, seen
 
 
-def moe_latent_block(p, x, cfg, bias):
+def moe_latent_block(p, x, cfg, bias, *, interpret: bool = True):
     """nemotron_h's expert sublayer (its LatentMoE) on the residual
     stream ``x`` (b, s, d), on a rank that holds ``experts_here`` of the
     routed experts: pre-norm; the router's sigmoid scores over **all**
@@ -318,7 +382,8 @@ def moe_latent_block(p, x, cfg, bias):
         # backward pass runs the held experts' loop once more to make it
         latent = checkpoint_name(
             local_expert_ffn(latent, order, seen["weights"], sizes,
-                             (p["up"], p["down"]), cfg, grouped_relu2_ffn),
+                             (p["up"], p["down"]), cfg, grouped_relu2_ffn,
+                             interpret),
             LATENT_SUM)
     with jax.named_scope("otpu_latent"):
         out = out + matmul(latent, p["lat_up"], dt)

@@ -521,7 +521,8 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
                 return x + gqa_attention(p, x, cfg, interpret=interpret)[0], \
                     {}, {}
         with jax.named_scope("otpu_moe"):
-            y, stats, routed = experts.moe_latent_block(p, x, cfg, bias)
+            y, stats, routed = experts.moe_latent_block(
+                p, x, cfg, bias, interpret=interpret)
         return x + y, stats, routed
     seen = {}
     if cfg.layer_types and "in_proj" in p:
@@ -546,7 +547,9 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
         return x + y.reshape(x.shape), {}, seen
     with jax.named_scope("otpu_moe"):
         if cfg.scoring_func == "sigmoid":
-            y, stats, routed = experts.moe_shared_local_block(p, x, cfg, bias)
+            y, stats, routed = experts.moe_shared_local_block(
+                p, x, cfg, bias, interpret=interpret)
         else:
-            y, stats, routed = experts.moe_sorted_block(p, x, cfg)
+            y, stats, routed = experts.moe_sorted_block(
+                p, x, cfg, interpret=interpret)
     return x + y, stats, {**routed, **seen}

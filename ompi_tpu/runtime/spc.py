@@ -118,6 +118,11 @@ _COUNTERS = (
     # here and to absent ones
     "train_mtp_tokens", "moe_bias_updates", "train_steps_read",
     "moe_local_slots", "moe_absent_slots",
+    # the experts' grouped matmuls made while steps were traced
+    # (parallel/experts._grouped_matmul), forward or transposed, and those
+    # of them made on the Pallas kernel (ops/grouped_matmul): the second
+    # over the first says which share of a run's engaged the kernel
+    "moe_gmm_built", "moe_gmm_kernel_built",
     # a model with state-space layers: the tokens that went through one,
     # a layer each (tokens x Mamba layers held), in the steps issued
     "train_ssm_layer_tokens",

@@ -284,6 +284,25 @@ def cases(mesh1d, mesh2d):
          lambda: attn_block_backward(2, 32, 8192, 64, 64))
     case("lfm2_attn_backward_walk_8k",
          lambda: attn_backward_walk(2, 32, 8192, 64, 64))
+    # the experts' grouped matmul (``experts._kernel_matmul``: ``ops/
+    # grouped_matmul``'s three kernels, forward and both transposed) at
+    # a cell's rows a call, held experts and both expert matrices
+    def gmm_forms(m, g, d, f):
+        from ompi_tpu.parallel import experts
+
+        def loss(a, b, up, down, sizes):
+            return (jnp.sum(experts._kernel_matmul(a, up, sizes, bf16))
+                    + jnp.sum(experts._kernel_matmul(b, down, sizes, bf16)))
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3))), (
+            rep(m, d), rep(m, f), rep(g, d, f), rep(g, f, d),
+            _sds((g,), jnp.int32, one, P()))
+
+    case("gmm_lfm2", lambda: gmm_forms(32768, 8, 2048, 1792))
+    case("gmm_olmoe", lambda: gmm_forms(65536, 64, 2048, 1024))
+    case("gmm_joyai", lambda: gmm_forms(8192, 16, 2048, 768))
+    case("gmm_nemotron", lambda: gmm_forms(8192, 8, 1024, 2688))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),

@@ -304,6 +304,31 @@ def test_lfm2_train_step_aot_compiles_from_the_cells_configuration(
     assert row["argument_bytes"] < 3 * 4 * 606_456_064 + (1 << 20)
 
 
+@pytest.fixture(scope="module")
+def gmm_rows():
+    """One child for the experts' grouped matmul at the four model
+    cells' shapes, forward and both transposed products of both expert
+    matrices, for one v5e device (about 25 s of the 600)."""
+    return _rows_with_texts("gmm_")
+
+
+@pytest.mark.parametrize("cell", ["lfm2", "olmoe", "joyai", "nemotron"])
+def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
+    """``ops/grouped_matmul``'s three kernels at the tiles the module
+    chooses for a cell's rows a call, held experts and both expert
+    matrices (PR 47): Mosaic takes the whole contraction and the widest
+    column tile in the VMEM the module asks for, and each of the six
+    products is one custom call whose ``op_name`` carries its kernel's
+    name, which is how a trace finds it."""
+    row = gmm_rows["gmm_" + cell]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    kernels = [path for line, path in op_paths(row)
+               if " custom-call(" in line]
+    for name in ("otpu_gmm", "otpu_gmm_nt", "otpu_gmm_t"):
+        assert sum(f"({name})" in p or f"/{name}/" in p
+                   for p in kernels) == 2, kernels
+
+
 def op_paths(row):
     """(line, ``op_name`` path) of every instruction of a row's compiled
     text that has one."""
