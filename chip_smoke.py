@@ -325,25 +325,28 @@ def kernels(clock: Clock, expect_interpret: bool = False,
                              f"exceeds {tol:g}")
 
     # causal attention's two kernels against their jnp twins, q and k as
-    # wide as v (128 / 128) and half as wide again (192 / 128): the
-    # forward pass in one call; the backward's fused block pair, a plain
-    # pair and the diagonal one through one compiled kernel
+    # wide as v (128 / 128) and half as wide again (192 / 128), a
+    # key-value head a query head, and at half the width (64 / 64) with
+    # every query head on one key-value head, read through the index
+    # maps: the forward pass in one call; the backward's fused block
+    # pair, a plain pair and the diagonal one through one compiled kernel
     from ompi_tpu.parallel import layers, model
 
     block, f32 = min(sq, 1024), jnp.float32
     cut = lambda x, n: x[:, :, n * block:(n + 1) * block]
-    for wide in (d, d * 3 // 2):
-        name = f"attn_block_backward {wide}/{d}"
+    for wide, hv, n_kv in ((d, d, h), (d * 3 // 2, d, h),
+                           (d // 2, d // 2, 1)):
+        name = f"attn_block_backward {wide}/{hv} {h} on {n_kv}"
         keys = jax.random.split(jax.random.PRNGKey(wide), 7)
-        draw = lambda key, w, t=dt: jax.random.normal(
-            key, (b, h, 2 * block, w), t)
-        qb, kb, vb, dob = (draw(key, w) for key, w in zip(
-            keys, (wide, wide, d, d)))
-        acc = tuple(draw(key, w, f32) for key, w in zip(
-            keys[4:], (wide, wide, d)))
+        draw = lambda key, w, n, t=dt: jax.random.normal(
+            key, (b, n, 2 * block, w), t)
+        qb, kb, vb, dob = (draw(key, w, n) for key, w, n in zip(
+            keys, (wide, wide, hv, hv), (h, n_kv, n_kv, h)))
+        acc = tuple(draw(key, w, n, f32) for key, w, n in zip(
+            keys[4:], (wide, wide, hv), (h, n_kv, n_kv)))
         up = tuple(x.astype(f32) for x in (qb, kb, vb, dob))
         o, lse = model._causal_fwd_blocks(*up[:3], block, True)
-        fwd = f"flash_causal_forward {wide}/{d}"
+        fwd = f"flash_causal_forward {wide}/{hv} {h} on {n_kv}"
         got = clock.call(compile_checked(fwd, jax.jit(
             lambda *a: fa.flash_causal_forward(*a, block=block)),
             qb, kb, vb), qb, kb, vb, first=False)
@@ -356,16 +359,18 @@ def kernels(clock: Clock, expect_interpret: bool = False,
         compiled = compile_checked(name, jax.jit(
             lambda ij, *a: fa.attn_block_backward(ij, *a, block=block)),
             jnp.zeros(2, jnp.int32), *args)
+        fold = lambda x, n: model._group_blocks(x, n_kv, block)[n]
         for i, j in ((1, 0), (1, 1)):
             got = clock.call(compiled, jnp.asarray((i, j), jnp.int32),
                              *args, first=False)
-            want = model._bwd_pair(
-                cut(up[0], i), cut(up[1], j), cut(up[2], j), cut(up[3], i),
-                cut(lse, i), cut(delta, i),
-                model._tri_bias(block) if i == j else None,
+            dq, dk, dv = model._bwd_pair(
+                fold(up[0], i), cut(up[1], j), cut(up[2], j),
+                fold(up[3], i), fold(lse, i), fold(delta, i),
+                model._group_bias(block, h // n_kv) if i == j else None,
                 1.0 / wide ** 0.5, f32)
-            for part, g, a0, w, n in zip(("dq", "dk", "dv"), got, acc,
-                                         want, (i, j, j)):
+            for part, g, a0, w, n in zip(
+                    ("dq", "dk", "dv"), got, acc,
+                    (dq.reshape(b, h, block, wide), dk, dv), (i, j, j)):
                 close(f"{name}.{part} pair {(i, j)}",
                       cut(g, n) - cut(a0, n), w)
         print(f"  {name} (block {block}) {dtype} matches its jnp twin",

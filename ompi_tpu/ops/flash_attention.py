@@ -12,6 +12,13 @@ the CPU runs the ``jnp`` twins in ``parallel/model``):
   kv block) pair, with the float32 gradient accumulators passed through
   the call in place.
 
+k and v come with the model's own number of key-value heads, (b, n_kv,
+s, .) beside q (b, h, s, .): a grid step is one query head, and the
+index maps hand it the block of the key-value head its group shares
+(``h // n_kv`` consecutive query heads a group, read from the shapes).
+Nothing is repeated in HBM; with as many key-value heads as query heads
+the maps are a head's own.
+
 Scores compute in float32 on the MXU via ``preferred_element_type``.
 Ring attention's block update (``parallel/flagship.ring_attention``) is
 plain ``jnp`` on every platform and no kernel of this module.
@@ -40,14 +47,18 @@ BWD_STRIP = 256
 BWD_VMEM_LIMIT = 64 << 20
 
 
-def _bwd_block_kernel(scale, strip, ij_ref, q_ref, k_ref, v_ref, do_ref,
-                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+def _bwd_block_kernel(scale, strip, rep, ij_ref, q_ref, k_ref, v_ref,
+                      do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
                       dqo_ref, dko_ref, dvo_ref):
-    """One q tile of block i against kv block j, a tile of kv positions
-    at a time, **transposed**: scores are held (kv, q), so that the
-    logsumexp and delta of a q row are row vectors that broadcast down
-    the sublanes, and of the five matmuls only dq's contracts over the
-    first axis of both operands."""
+    """One q tile of block i of one query head against kv block j of the
+    key-value head its group shares, a tile of kv positions at a time,
+    **transposed**: scores are held (kv, q), so that the logsumexp and
+    delta of a q row are row vectors that broadcast down the sublanes,
+    and of the five matmuls only dq's contracts over the first axis of
+    both operands.  The ``rep`` query heads of a group are consecutive
+    grid steps on one ``dk`` / ``dv`` block, which stays in VMEM from
+    the group's first tile, where it takes the incoming accumulator, to
+    its last: the group's sum is made in float32, here."""
     t = pl.program_id(1)
     diagonal = ij_ref[0] == ij_ref[1]
     tile = q_ref.shape[1]
@@ -57,7 +68,11 @@ def _bwd_block_kernel(scale, strip, ij_ref, q_ref, k_ref, v_ref, do_ref,
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=jnp.float32)
 
-    @pl.when(t == 0)
+    first = t == 0          # of the group's: the block's first visit
+    if rep > 1:
+        first = jnp.logical_and(first, pl.program_id(0) % rep == 0)
+
+    @pl.when(first)
     def _():
         dko_ref[...] = dk_ref[...]
         dvo_ref[...] = dv_ref[...]
@@ -101,6 +116,21 @@ def _tile(length, most):
     return most if length % most == 0 else length
 
 
+def _heads_a_group(q, k):
+    """The query heads that share one key-value head, from the shapes of
+    q (b, h, s, d) and k (b, n_kv, s, d)."""
+    h, n_kv = q.shape[1], k.shape[1]
+    if h % n_kv:
+        raise ValueError(f"{h} query heads on {n_kv} key-value heads")
+    return h // n_kv
+
+
+def _group(g, rep):
+    """Row ``b_i * n_kv + h_i // rep`` of the flattened k and v for grid
+    step ``g = b_i * h + h_i``."""
+    return g if rep == 1 else g // rep
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
                         block: int, interpret=None):
@@ -111,9 +141,13 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     back: ``dq`` at block i, ``dk`` and ``dv`` at block j, every other
     block as it came (the three alias their inputs).
 
-    q, k: (b, h, s, d); v, do: (b, h, s, dv), all of q's dtype; lse,
-    delta: (b, h, s) float32 (the forward's logsumexp; the row sums of
-    do * o); dq, dk: (b, h, s, d) and dv: (b, h, s, dv) float32.  The
+    q: (b, h, s, d) and do: (b, h, s, dv); k: (b, n_kv, s, d) and v:
+    (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``, all of q's dtype;
+    lse, delta: (b, h, s) float32 (the forward's logsumexp; the row
+    sums of do * o); dq: (b, h, s, d), dk: (b, n_kv, s, d) and dv: (b,
+    n_kv, s, dv) float32.  A key-value head's ``dk`` and ``dv`` gather
+    its ``h // n_kv`` query heads' terms in float32 (the head axis of
+    the grid is sequential where heads share one).  The
     arrays come whole and ``ij`` picks the blocks in the index maps (a
     scalar-prefetch operand), so a walk over the pairs, unrolled or by
     ``lax.scan``, slices nothing.  Five matmuls a pair with float32
@@ -126,13 +160,14 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     b, h, s, d = q.shape
     hv = v.shape[-1]
     bh = b * h
+    rep = _heads_a_group(q, k)
     tq = _tile(block, BWD_TILE)
     nt = block // tq
 
-    flat = lambda a: a.reshape(bh, s, a.shape[-1])
+    flat = lambda a: a.reshape(-1, s, a.shape[-1])
     row = lambda a: a.reshape(bh, 1, s).astype(jnp.float32)
     q_map = lambda g, t, ij: (g, ij[0] * nt + t, 0)
-    kv_map = lambda g, t, ij: (g, ij[1], 0)
+    kv_map = lambda g, t, ij: (_group(g, rep), ij[1], 0)
     row_map = lambda g, t, ij: (g, 0, ij[0] * nt + t)
     q_spec = lambda width: pl.BlockSpec((1, tq, width), q_map)
     kv_spec = lambda width: pl.BlockSpec((1, block, width), kv_map)
@@ -144,7 +179,7 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     acc_specs = [q_spec(d), kv_spec(d), kv_spec(hv)]
     out = pl.pallas_call(
         functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
-                          _tile(tq, BWD_STRIP)),
+                          _tile(tq, BWD_STRIP), rep),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
                         for o in operands[7:]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -154,7 +189,9 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
             out_specs=acc_specs),
         input_output_aliases={7: 0, 8: 1, 9: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a group's heads revisit its dk and dv block: in turn
+            dimension_semantics=("parallel" if rep == 1 else "arbitrary",
+                                 "arbitrary"),
             vmem_limit_bytes=BWD_VMEM_LIMIT),
         interpret=interpret,
         name="otpu_attn_block_backward",
@@ -226,13 +263,15 @@ def _causal_fwd_kernel(scale, q_ref, k_ref, v_ref, o_ref, lse_ref,
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def flash_causal_forward(q, k, v, *, block: int, interpret=None):
     """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
-    float32 and the logsumexp (b, h, s) float32 of q, k (b, h, s, d)
-    and v (b, h, s, dv), ``s`` a multiple of ``block``.
+    float32 and the logsumexp (b, h, s) float32 of q (b, h, s, d), k
+    (b, n_kv, s, d) and v (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``
+    and ``s`` of ``block``.
 
     The arrays come whole; the grid is (b x h, q tiles, kv tiles), the
     tile ``block`` or 1,024 positions, and the index maps pick the
-    tiles, a kv tile above the diagonal clamped to the diagonal's (the
-    one already there: not fetched again).  Scores, softmax state and
+    tiles, of the key-value head the query head's group shares, a kv
+    tile above the diagonal clamped to the diagonal's (the one already
+    there: not fetched again).  Scores, softmax state and
     ``o`` in float32, ``p`` cast to v's dtype for ``p v``, scale
     ``1 / sqrt(d)``; the diagonal tile is masked by position.  A width
     that is no multiple of 128 lanes (192) is Mosaic's to lay out.  The
@@ -244,12 +283,13 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None):
     b, h, s, d = q.shape
     hv = v.shape[-1]
     bh = b * h
+    rep = _heads_a_group(q, k)
     tile = _tile(block, FWD_TILE)
     nt = s // tile
 
-    flat = lambda a: a.reshape(bh, s, a.shape[-1])
+    flat = lambda a: a.reshape(-1, s, a.shape[-1])
     q_map = lambda g, i, j: (g, i, 0)
-    kv_map = lambda g, i, j: (g, jnp.minimum(i, j), 0)
+    kv_map = lambda g, i, j: (_group(g, rep), jnp.minimum(i, j), 0)
     kv_spec = lambda width: pl.BlockSpec((1, tile, width), kv_map)
     operands = [flat(q), flat(k), flat(v)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))

@@ -19,6 +19,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ompi_tpu.parallel import experts
 from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
                                       rope, swiglu)
+from ompi_tpu.runtime import spc
 
 
 def _tri_bias(block: int):
@@ -36,28 +37,58 @@ def _contract(eq, a, b, compute_dtype):
                       preferred_element_type=jnp.float32)
 
 
+def _group_blocks(a, n_kv: int, block: int):
+    """A query-side array (b, h, s, ...) by blocks of ``block``
+    positions, the ``h / n_kv`` query heads that share a key-value head
+    folded into a block's rows: (blocks, b, n_kv, h / n_kv * block,
+    ...).  The ``jnp`` twins' layout (the kernels group through their
+    index maps): a group's rows meet its one k and v block in one
+    contraction, and the sum over them is dk's and dv's own."""
+    b, h, s = a.shape[:3]
+    nb, rep = s // block, h // n_kv
+    a = jnp.moveaxis(a.reshape(b, n_kv, rep, nb, block, *a.shape[3:]), 3, 0)
+    return a.reshape(nb, b, n_kv, rep * block, *a.shape[5:])
+
+
+def _ungroup_blocks(a, h: int):
+    """``_group_blocks``'s inverse: (b, h, s, ...) again."""
+    nb, b, n_kv, rows = a.shape[:4]
+    block = rows * n_kv // h
+    a = a.reshape(nb, b, n_kv, h // n_kv, block, *a.shape[4:])
+    return jnp.moveaxis(a, 0, 3).reshape(b, h, nb * block, *a.shape[5:])
+
+
+def _group_bias(block: int, rep: int):
+    """The diagonal block's triangular bias for a group's folded rows."""
+    return jnp.tile(_tri_bias(block), (rep, 1))
+
+
 def _causal_fwd_blocks(q, k, v, block, interpret):
     """Causal attention's forward pass: (o float32, logsumexp float32)
-    of q, k (b, h, s, hd) and v (b, h, s, hv); v, and so the numerator
-    and o, may be of another width than q and k (latent attention: 192
-    and 128).  Where Mosaic compiles (``interpret`` false: a TPU) it is
-    one call of ``ops/flash_attention.flash_causal_forward``, which
-    takes the three whole.  Elsewhere (the CPU) it is the loop below,
-    that kernel's ``jnp`` twin: q block i meets kv blocks 0..i of
-    ``block`` positions, the diagonal one under a triangular bias, each
-    through one online-softmax update with float32 scores; the running
-    max, numerator and denominator are float32 whatever q, k, v are."""
+    of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
+    key-value head is read by ``h / n_kv`` consecutive query heads, and
+    v, and so the numerator and o, may be of another width than q and k
+    (latent attention: 192 and 128).  Where Mosaic compiles
+    (``interpret`` false: a TPU) it is one call of
+    ``ops/flash_attention.flash_causal_forward``, which takes the three
+    whole.  Elsewhere (the CPU) it is the loop below, that kernel's
+    ``jnp`` twin: q block i of a group's query heads meets kv blocks
+    0..i of ``block`` positions, the diagonal one under a triangular
+    bias, each through one online-softmax update with float32 scores;
+    the running max, numerator and denominator are float32 whatever q,
+    k, v are."""
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
         return flash_causal_forward(q, k, v, block=block, interpret=False)
-    b, h, s, hd = q.shape
+    h, s, hd = q.shape[1:]
     nb = s // block
     scale = 1.0 / math.sqrt(hd)
-    bias = _tri_bias(block)
+    bias = _group_bias(block, h // k.shape[1])
+    qb = _group_blocks(q, k.shape[1], block)
     outs, lses = [], []
     for i in range(nb):
-        qi = q[:, :, i * block:(i + 1) * block]
+        qi = qb[i]
         zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
         m, den = zero - jnp.inf, zero
         num = jnp.zeros(v.shape[-1:], jnp.float32) + zero[..., None]
@@ -76,7 +107,8 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
             m = new_m
         outs.append(num / den[..., None])
         lses.append(m + jnp.log(den))
-    return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
+    return (_ungroup_blocks(jnp.stack(outs), h),
+            _ungroup_blocks(jnp.stack(lses), h))
 
 
 # what a layer's ``jax.checkpoint`` keeps of causal attention
@@ -92,8 +124,11 @@ CHECKPOINT_KEEPS = (ATTN_OUT, ATTN_LSE)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def causal_flash_attention(q, k, v, block: int, interpret: bool):
-    """Causal self-attention of (b, h, s, hd) q, k and (b, h, s, hv) v
-    whose length is a multiple of ``block``.  Forward:
+    """Causal self-attention of q (b, h, s, hd), k (b, n_kv, s, hd) and
+    v (b, n_kv, s, hv) whose length is a multiple of ``block``: k and v
+    come with the model's own key-value heads, each shared by ``h /
+    n_kv`` consecutive query heads, and are repeated nowhere; their
+    gradients are the group's sums, made in float32.  Forward:
     ``_causal_fwd_blocks`` (on a TPU one kernel call, the blocks chosen
     in its index maps; on the CPU a ``jnp`` loop over the blocks).
     Backward: the flash backward by the same blocks (scores recomputed
@@ -104,7 +139,19 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool):
     return _causal_fwd_blocks(q, k, v, block, interpret)[0]
 
 
+def _count_built(q, k) -> None:
+    """SPC ``attn_built``: the causal attention passes made, forward
+    rule or backward rule, while steps were traced (JAX traces a pass
+    more than once); ``attn_shared_kv_built``: those of them whose k and
+    v came with fewer heads than q and went to the kernels, or their
+    twins, that way."""
+    spc.record("attn_built", 1)
+    if k.shape[1] < q.shape[1]:
+        spc.record("attn_shared_kv_built", 1)
+
+
 def _causal_fwd(q, k, v, block, interpret):
+    _count_built(q, k)
     o, lse = _causal_fwd_blocks(q, k, v, block, interpret)
     o = checkpoint_name(o, ATTN_OUT)
     lse = checkpoint_name(lse, ATTN_LSE)
@@ -121,7 +168,9 @@ UNROLLED_BLOCKS = 4
 
 
 def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
-    """One block pair of the flash backward: (dq, dk, dv) parts."""
+    """One block pair of the flash backward: (dq, dk, dv) parts.  A
+    head of the query side is a key-value head's, its rows the group's
+    (``_group_blocks``), so dk and dv sum the group in float32."""
     sc = _contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
     if bias is not None:
         sc = sc + bias
@@ -135,6 +184,8 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
 
 def _causal_bwd(block, interpret, res, do):
     q, k, v, o, lse = res
+    _count_built(q, k)
+    h, n_kv = q.shape[1], k.shape[1]
     nb = q.shape[2] // block
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
@@ -144,35 +195,34 @@ def _causal_bwd(block, interpret, res, do):
         return _causal_bwd_scanned(q, k, v, do, lse, delta, block)
     dt = q.dtype
     scale = 1.0 / math.sqrt(q.shape[-1])
-    bias = _tri_bias(block)
+    bias = _group_bias(block, h // n_kv)
+    qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
+                             for a in (q, do, lse, delta))
     cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
     dq = [0.0] * nb
     dk = [0.0] * nb
     dv = [0.0] * nb
     for i in range(nb):
-        qi, doi = cut(q, i), cut(do, i)
-        lse_i, delta_i = cut(lse, i), cut(delta, i)
         for j in range(i + 1):
             dq_c, dk_c, dv_c = _bwd_pair(
-                qi, cut(k, j), cut(v, j), doi, lse_i, delta_i,
+                qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
                 bias if j == i else None, scale, dt)
             dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
     cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
-    return cat(dq), cat(dk), cat(dv)
+    return _ungroup_blocks(jnp.stack(dq), h).astype(dt), cat(dk), cat(dv)
 
 
 def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
     """The same pairs in the same order (q block by q block, kv blocks
     ascending), one a step of a ``lax.scan`` over float32 accumulators."""
     dt = q.dtype
-    b, h, s, _ = q.shape
-    nb = s // block
+    h, n_kv = q.shape[1], k.shape[1]
+    nb = q.shape[2] // block
     scale = 1.0 / math.sqrt(q.shape[-1])
-    tri = _tri_bias(block)
-    blocks = lambda a: jnp.moveaxis(
-        a.reshape(b, h, nb, block, *a.shape[3:]), 2, 0)
-    qb, kb, vb, dob = blocks(q), blocks(k), blocks(v), blocks(do)
-    lseb, deltab = blocks(lse), blocks(delta)
+    tri = _group_bias(block, h // n_kv)
+    qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
+                             for a in (q, do, lse, delta))
+    kb, vb = (_group_blocks(a, n_kv, block) for a in (k, v))
     pairs = [(i, j) for i in range(nb) for j in range(i + 1)]
     zero = lambda a: (a * 0).astype(jnp.float32)         # carries a's vma
 
@@ -189,19 +239,19 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
         step, (zero(qb), zero(kb), zero(vb)),
         (jnp.asarray([p[0] for p in pairs]),
          jnp.asarray([p[1] for p in pairs])))
-    whole = lambda a: jnp.moveaxis(a, 0, 2).reshape(
-        b, h, s, a.shape[-1]).astype(dt)
-    return whole(dq), whole(dk), whole(dv)
+    return (_ungroup_blocks(dq, h).astype(dt),
+            _ungroup_blocks(dk, n_kv).astype(dt),
+            _ungroup_blocks(dv, n_kv).astype(dt))
 
 
 def _causal_bwd_fused(q, k, v, do, lse, delta, block):
     """The same pairs in the same order, each one call of the fused
     Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
     ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
-    and the float32 accumulators pass through every call in place.
-    Both walks: unrolled up to ``UNROLLED_BLOCKS`` blocks, one
-    ``lax.scan`` beyond; the arrays go in whole and the pair is an
-    operand, so neither slices."""
+    and the float32 accumulators pass through every call in place, dk's
+    and dv's with k's and v's own heads.  Both walks: unrolled up to
+    ``UNROLLED_BLOCKS`` blocks, one ``lax.scan`` beyond; the arrays go
+    in whole and the pair is an operand, so neither slices."""
     from ompi_tpu.ops.flash_attention import attn_block_backward
 
     dt = q.dtype
@@ -291,9 +341,10 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
     and the ``n_kv_heads_here`` key-value heads they read (each read by
     ``num_attention_heads / num_key_value_heads`` query heads of the
     model, by as many of those as are held here); causal softmax
-    attention.  The flash kernels take one k and v a query head, so the
-    key-value heads are repeated into that layout (the repeat's
-    transpose adds the query heads' gradients up).
+    attention.  k and v go to ``causal_flash_attention`` as they leave
+    their projections, with their own heads: the flash kernels read a
+    group's shared head through their index maps and sum its query
+    heads' gradients in float32.
 
     Two models' sublayer, told apart by what the layer holds.
     nemotron_h's (Nemotron-3-Super) holds no ``q_norm``: no rotary
@@ -301,11 +352,10 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
     and v cast as they leave their projections.  lfm2's (LFM2-8B-A1B)
     holds ``q_norm`` and ``k_norm`` (head width,): RMSNorm with a gain
     over **each head's** width of q and of k, then RoPE in the
-    half-split form, both in float32 and on the key-value heads before
-    their repeat.  Returns (the sublayer's output, by token row what
-    the norm and RoPE read and made of the first query head and the
-    first key-value head side by side, ``attn_qk_in`` and ``attn_qk``
-    (T, 2 hd); empty for nemotron_h's)."""
+    half-split form, both in float32.  Returns (the sublayer's output,
+    by token row what the norm and RoPE read and made of the first query
+    head and the first key-value head side by side, ``attn_qk_in`` and
+    ``attn_qk`` (T, 2 hd); empty for nemotron_h's)."""
     b, s, _ = x.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     seen = {}
@@ -321,15 +371,13 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
             first = lambda a, c: jnp.concatenate(
                 [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
             seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
-            q = q.astype(dt)
-            k, v = (jnp.repeat(t.astype(dt), nh // nkv, 1)
-                    for t in (k, split(matmul(h, p["wv"], dt), nkv)))
+            q, k = q.astype(dt), k.astype(dt)
+            v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
         else:
             heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
                 0, 2, 1, 3).astype(dt)
-            q = heads(matmul(h, p["wq"], dt), nh)
-            k, v = (jnp.repeat(heads(matmul(h, p[w], dt), nkv), nh // nkv, 1)
-                    for w in ("wk", "wv"))
+            q, k, v = (heads(matmul(h, p[w], dt), n)
+                       for w, n in (("wq", nh), ("wk", nkv), ("wv", nkv)))
     o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
     with jax.named_scope("otpu_attn_proj"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)

@@ -123,6 +123,12 @@ _COUNTERS = (
     # of them made on the Pallas kernel (ops/grouped_matmul): the second
     # over the first says which share of a run's engaged the kernel
     "moe_gmm_built", "moe_gmm_kernel_built",
+    # the causal attention passes made while steps were traced
+    # (parallel/model.causal_flash_attention's forward and backward
+    # rules), and those of them whose k and v came with fewer heads than q
+    # and went to the flash kernels, or their twins, unrepeated: the
+    # second over the first is 1 for a grouped-query model, 0 for the rest
+    "attn_built", "attn_shared_kv_built",
     # a model with state-space layers: the tokens that went through one,
     # a layer each (tokens x Mamba layers held), in the steps issued
     "train_ssm_layer_tokens",

@@ -931,8 +931,10 @@ def scope_of_path(path: str, vocabulary=STEP_SCOPES) -> tuple:
     holds ``transpose(``, else ``forward`` (which so means "neither of
     the three": the first forward pass, and what a step computes beside
     its gradients); and the ``otpu_*`` components that are no
-    vocabulary name."""
+    vocabulary name.  Where the compiler folded one instruction into
+    another it joins their paths with ``;``: the first is read."""
     chain, unknown = [], []
+    path = path.split(";")[0]
     parts = path.split("/")
     for part, after in zip(parts, parts[1:] + [""]):
         # a jit's own name, and a Pallas kernel's (the component before

@@ -235,32 +235,38 @@ def cases(mesh1d, mesh2d):
     # pairs, OLMoE's 10 unrolled and JoyAI's 36 by ``lax.scan``: the
     # accumulators must alias through every call (no ``copy``, no
     # ``dynamic-update-slice`` of their size beside it)
-    def attn_bwd_args(b, h, s, d, hv):
-        wide = lambda w, dt: _sds((b, h, s, w), dt, one, P())
+    # ``n_kv`` key-value heads under the ``h`` query heads: k, v and the
+    # accumulators dk, dv come with the model's own (the kernels' index
+    # maps read a group's shared head; nothing is repeated)
+    def attn_bwd_args(b, h, s, d, hv, n_kv=None):
+        n_kv = n_kv or h
+        wide = lambda w, dt, n=h: _sds((b, n, s, w), dt, one, P())
         row = _sds((b, h, s), jnp.float32, one, P())
-        return (wide(d, bf16), wide(d, bf16), wide(hv, bf16),
+        return (wide(d, bf16), wide(d, bf16, n_kv), wide(hv, bf16, n_kv),
                 wide(hv, bf16), row, row)
 
-    def attn_block_backward(b, h, s, d, hv):
-        wide = lambda w: _sds((b, h, s, w), jnp.float32, one, P())
+    def attn_block_backward(b, h, s, d, hv, n_kv=None):
+        n_kv = n_kv or h
+        wide = lambda w, n=h: _sds((b, n, s, w), jnp.float32, one, P())
         return fa.attn_block_backward, (
-            (_sds((2,), jnp.int32, one, P()),) + attn_bwd_args(b, h, s, d, hv)
-            + (wide(d), wide(d), wide(hv))), {"block": 1024,
-                                              "interpret": False}
+            (_sds((2,), jnp.int32, one, P()),)
+            + attn_bwd_args(b, h, s, d, hv, n_kv)
+            + (wide(d), wide(d, n_kv), wide(hv, n_kv))), {
+                "block": 1024, "interpret": False}
 
-    def attn_backward_walk(b, h, s, d, hv):
+    def attn_backward_walk(b, h, s, d, hv, n_kv=None):
         from ompi_tpu.parallel import model
 
-        q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv)
-        o = _sds(v.shape, jnp.float32, one, P())     # and its cotangent
+        q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv, n_kv)
+        o = _sds((b, h, s, hv), jnp.float32, one, P())  # and its cotangent
         return jax.jit(lambda q, k, v, o, lse, do: model._causal_bwd(
             1024, False, (q, k, v, o, lse), do)), (q, k, v, o, lse, o)
 
     # attention's forward (``model._causal_fwd_blocks`` where Mosaic
     # compiles): one call a layer, q, k and v whole, the blocks through
     # the index maps, the softmax state in VMEM scratch
-    def flash_causal_forward(b, h, s, d, hv):
-        q, k, v = attn_bwd_args(b, h, s, d, hv)[:3]
+    def flash_causal_forward(b, h, s, d, hv, n_kv=None):
+        q, k, v = attn_bwd_args(b, h, s, d, hv, n_kv)[:3]
         return fa.flash_causal_forward, (q, k, v), {"block": 1024,
                                                     "interpret": False}
 
@@ -268,10 +274,10 @@ def cases(mesh1d, mesh2d):
          lambda: flash_causal_forward(2, 16, 4096, 128, 128))
     case("joyai_flash_causal_forward",
          lambda: flash_causal_forward(1, 32, 8192, 192, 128))
-    # LFM2's: 2 x 32 query heads x 8,192 at a head width of 64 (half the
-    # MXU's contraction depth, half a tile's lanes)
+    # LFM2's: 2 x 32 query heads on 8 key-value heads x 8,192 at a head
+    # width of 64 (half the MXU's contraction depth, half a tile's lanes)
     case("lfm2_flash_causal_forward",
-         lambda: flash_causal_forward(2, 32, 8192, 64, 64))
+         lambda: flash_causal_forward(2, 32, 8192, 64, 64, 8))
     case("olmoe_attn_block_backward_1k",
          lambda: attn_block_backward(2, 16, 4096, 128, 128))
     case("joyai_attn_block_backward_1k",
@@ -281,9 +287,15 @@ def cases(mesh1d, mesh2d):
     case("joyai_attn_backward_walk_8k",
          lambda: attn_backward_walk(1, 32, 8192, 192, 128))
     case("lfm2_attn_block_backward_1k",
-         lambda: attn_block_backward(2, 32, 8192, 64, 64))
+         lambda: attn_block_backward(2, 32, 8192, 64, 64, 8))
     case("lfm2_attn_backward_walk_8k",
-         lambda: attn_backward_walk(2, 32, 8192, 64, 64))
+         lambda: attn_backward_walk(2, 32, 8192, 64, 64, 8))
+    # Nemotron-3-Super's share: 4 query heads on 1 key-value head x
+    # 8,192 at a head width of 128, the whole head axis one group
+    case("nemotron3_flash_causal_forward",
+         lambda: flash_causal_forward(1, 4, 8192, 128, 128, 1))
+    case("nemotron3_attn_backward_walk_8k",
+         lambda: attn_backward_walk(1, 4, 8192, 128, 128, 1))
     # the experts' grouped matmul (``experts._kernel_matmul``: ``ops/
     # grouped_matmul``'s three kernels, forward and both transposed) at
     # a cell's rows a call, held experts and both expert matrices

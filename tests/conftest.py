@@ -61,3 +61,78 @@ def fresh_registry():
     var.registry._cli.clear()
     var.registry._deprecation_warned.clear()
     output._help_seen.clear()
+
+
+@pytest.fixture
+def traced_step(monkeypatch):
+    """``traced_step(cfg, tokens, labels, on_tpu)``: a public model's
+    train step (``parallel/train.build_train_step`` on one device)
+    traced to a jaxpr and nothing more, as the CPU runs it (the ``jnp``
+    twins) or, ``on_tpu``, as a TPU does (the Pallas kernels as
+    ``pallas_call`` equations; tracing lowers nothing).  Returns
+    ``eqns`` (every equation, the sub-programs' included), ``built`` and
+    ``shared`` (what the trace added to the SPC counters ``attn_built``
+    and ``attn_shared_kv_built``), ``kernels(name)`` (the operands' and
+    the results' shapes of each ``pallas_call`` of that name) and
+    ``holds_no_repeat(b, nh, nkv, s, hd)``, which asserts that ``nh``
+    query heads read ``nkv`` key-value heads through the kernels' index
+    maps and nothing else."""
+    import types
+
+    import jax
+
+    from ompi_tpu.parallel import train
+    from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+    from ompi_tpu.runtime import spc
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    def trace(cfg, tokens, labels, on_tpu):
+        if on_tpu:
+            monkeypatch.setattr(train, "pallas_interpret",
+                                lambda devices=None: False)
+        if "attn_built" not in spc.counters():
+            spc.init()
+        names = ("attn_built", "attn_shared_kv_built")
+        before = [spc.read(n) for n in names]
+        mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+        step, place = train.build_train_step(mesh, spec, model=cfg)
+        args = place(train.init_model_params(cfg, 3), tokens, labels)
+        eqns = list(walk(jax.make_jaxpr(step.jitted)(*args).jaxpr))
+        built, shared = (spc.read(n) - b for n, b in zip(names, before))
+        shapes = lambda vs: [v.aval.shape for v in vs]
+        kernels = lambda name: [
+            (shapes(e.invars), shapes(e.outvars)) for e in eqns
+            if e.primitive.name == "pallas_call"
+            and e.params["name"] == name]
+
+        def holds_no_repeat(b, nh, nkv, s, hd):
+            """k and v reach the kernels ``nkv`` heads a batch entry
+            and dk and dv leave them so (the scalar-prefetch pair is the
+            backward's first operand), no ``jnp.repeat``'s broadcast (b,
+            nkv, nh / nkv, s, hd) is anywhere in the step, and every
+            pass counts as one whose k and v are shared."""
+            assert built == shared > 0
+            assert not [e for e in eqns
+                        if e.primitive.name == "broadcast_in_dim"
+                        and e.outvars[0].aval.shape
+                        == (b, nkv, nh // nkv, s, hd)]
+            forward = kernels("otpu_flash_causal_forward")
+            backward = kernels("otpu_attn_block_backward")
+            assert bool(forward) == bool(backward) == on_tpu
+            per_q, per_kv = (b * nh, s, hd), (b * nkv, s, hd)
+            for ins, outs in forward:
+                assert ins == [per_q, per_kv, per_kv] and outs[0] == per_q
+            for ins, outs in backward:
+                assert ins[1:5] == [per_q, per_kv, per_kv, per_q]
+                assert ins[7:] == outs == [per_q, per_kv, per_kv]
+
+        return types.SimpleNamespace(
+            eqns=eqns, built=built, shared=shared, kernels=kernels,
+            holds_no_repeat=holds_no_repeat)
+
+    return trace

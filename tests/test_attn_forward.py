@@ -10,10 +10,10 @@ from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.parallel import model
 
 
-def _qkv(d, hv, dt, s, seed=0, b=2, h=2):
+def _qkv(d, hv, dt, s, seed=0, b=2, h=2, n_kv=None):
     rng = np.random.default_rng(seed)
-    draw = lambda w: jnp.asarray(rng.normal(0, 1, (b, h, s, w)), dt)
-    return draw(d), draw(d), draw(hv)
+    draw = lambda w, n=h: jnp.asarray(rng.normal(0, 1, (b, n, s, w)), dt)
+    return draw(d), draw(d, n_kv or h), draw(hv, n_kv or h)
 
 
 def _agree(got, want, dt):
@@ -27,15 +27,28 @@ def _agree(got, want, dt):
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("nb", [1, 2, 8])
-@pytest.mark.parametrize("d,hv", [(128, 128), (192, 128)],
-                         ids=["128-128", "192-128"])
-def test_the_forward_kernel_is_its_twin(d, hv, nb, dt):
-    """One tile a block, 1, 2 and 8 of them, two batch entries of two
-    heads, q and k as wide as v and wider: ``o`` and the logsumexp."""
+@pytest.mark.parametrize("h,n_kv,d,hv", [
+    (2, 2, 128, 128), (2, 2, 192, 128), (8, 2, 64, 64), (4, 1, 128, 128)],
+    ids=["128-128", "192-128", "8on2-64", "4on1-128"])
+def test_the_forward_kernel_is_its_twin(h, n_kv, d, hv, nb, dt):
+    """One tile a block, 1, 2 and 8 of them, two batch entries, q and k
+    as wide as v and wider; a key-value head a query head, and query
+    heads on fewer key-value heads (LFM2's 4 a group at a width of 64,
+    Nemotron's 4 on 1 at 128): ``o`` and the logsumexp of the kernel on
+    k and v as they are, against the twin on them as they are and on k
+    and v repeated a query head."""
     block = 128
-    q, k, v = _qkv(d, hv, dt, nb * block)
+    q, k, v = _qkv(d, hv, dt, nb * block, h=h, n_kv=n_kv)
     got = fa.flash_causal_forward(q, k, v, block=block, interpret=True)
     _agree(got, model._causal_fwd_blocks(q, k, v, block, True), dt)
+    repeated = (jnp.repeat(t, h // n_kv, 1) for t in (k, v))
+    _agree(got, model._causal_fwd_blocks(q, *repeated, block, True), dt)
+
+
+def test_query_heads_that_no_group_divides_are_refused():
+    q, k, v = _qkv(64, 64, jnp.float32, 128, h=3, n_kv=2)
+    with pytest.raises(ValueError, match="3 query heads on 2"):
+        fa.flash_causal_forward(q, k, v, block=128, interpret=True)
 
 
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],

@@ -345,6 +345,15 @@ def test_one_step_reports_the_references_loads_and_gradients(stepped):
         aux["embed_probe_read"], np.isin(rows, np.asarray(tokens)))
 
 
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["twins", "kernels"])
+def test_the_step_holds_no_k_or_v_a_query_head(traced_step, on_tpu):
+    """4 query heads on 2 key-value heads, two attention layers, 2 x 32
+    positions at a head width of 16 (``traced_step``'s
+    ``holds_no_repeat`` says what is held)."""
+    assert (F32.n_heads_here, F32.n_kv_heads_here) == (4, 2)
+    traced_step(F32, *batch_of(0), on_tpu).holds_no_repeat(2, 4, 2, 32, 16)
+
+
 def test_every_leafs_gradient_is_the_references():
     tokens, labels = batch_of(4)
     params, bias = train.init_model_params(F32, 11), some_bias()

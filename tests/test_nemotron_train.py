@@ -284,6 +284,15 @@ def stepped():
                 want=want, counted=counted)
 
 
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["twins", "kernels"])
+def test_the_step_holds_no_k_or_v_a_query_head(traced_step, on_tpu):
+    """The 4 query heads held here read 1 key-value head, one attention
+    layer, 2 x 32 positions at a head width of 4 (``traced_step``'s
+    ``holds_no_repeat`` says what is held)."""
+    assert (F32.n_heads_here, F32.n_kv_heads_here) == (4, 1)
+    traced_step(F32, *batch_of(0), on_tpu).holds_no_repeat(2, 4, 1, 32, 4)
+
+
 def test_the_pattern_is_walked_in_runs_of_like_layers():
     assert F32.pattern_here == "MEMEM*"
     assert F32.segments == (("ME", 2, 0), ("M", 1, 4), ("*", 1, 5))

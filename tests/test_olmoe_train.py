@@ -63,6 +63,19 @@ def system(params):
     return dict(total=total, aux=aux, grads=grads)
 
 
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["twins", "kernels"])
+def test_a_key_value_head_a_query_head_counts_as_no_shared_one(traced_step,
+                                                               on_tpu):
+    """Every query head reads a key-value head of its own: the step's
+    attention passes are counted, none as one whose k and v are shared,
+    and the kernels take k and v as wide as q."""
+    got = traced_step(F32, *batch_of(0), on_tpu)
+    assert got.built > 0 and got.shared == 0
+    forward = got.kernels("otpu_flash_causal_forward")
+    assert bool(forward) == on_tpu
+    assert all(ins[0][0] == ins[1][0] == ins[2][0] for ins, _ in forward)
+
+
 def run_steps(cfg, params, seeds, dp=1):
     """Parameters and each step's ``aux`` after one optimiser step a
     seed's batch, through ``build_train_step`` on ``dp`` CPU devices."""

@@ -11,6 +11,7 @@ reference's hardware-proven transport contract
 """
 import json
 import os
+import re
 import subprocess
 import sys
 import tempfile
@@ -251,9 +252,41 @@ def test_latent_attentions_operands_aot_hold_no_rolled_copy(case, joyai_rows):
 
 @pytest.fixture(scope="module")
 def nemotron_rows():
-    """One child for Nemotron-3-Super's whole step of the cell's own
-    configuration file, for one v5e device (about 60 s of the 600)."""
+    """One child for the Nemotron-3-Super cases: attention's two kernels
+    with the share's 4 query heads on its 1 key-value head alone and the
+    whole step of the cell's own configuration file, for one v5e device
+    (about 60 s of the 600)."""
     return _rows_with_texts("nemotron3")
+
+
+# the (b, n_kv, rep, s, hd) broadcast that ``jnp.repeat`` of k or v to the
+# query heads made, until PR 48, in the two cells that share key-value
+# heads, as the compiled texts of that time held it
+REPEATED = {"nemotron_rows": r"= bf16\[4,8192,128\]\S* broadcast\(",
+            "lfm2_rows": r"bf16\[2,8,4,8192,64\]"}
+
+
+@pytest.mark.parametrize("rows,prefix", [("nemotron_rows", "nemotron3"),
+                                         ("lfm2_rows", "lfm2")])
+def test_shared_key_value_heads_aot_compile_and_repeat_nothing(rows, prefix,
+                                                               request):
+    """Query heads on fewer key-value heads (Nemotron's 4 on 1 at a head
+    width of 128, LFM2's 32 on 8 at 64; 8,192 positions): the forward
+    kernel and the backward's 36 pairs by one ``lax.scan`` compile with
+    k, v, dk and dv at the key-value heads' count, a group's dk and dv
+    block revisited by its query heads in turn; and neither they nor the
+    whole step hold an array of k or v repeated a query head."""
+    repeated = REPEATED[rows]
+    rows = request.getfixturevalue(rows)
+    cases = [prefix + "_flash_causal_forward",
+             prefix + "_attn_backward_walk_8k", prefix + "_step_1chip"]
+    for case in cases:
+        row = rows[case]
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        with open(row["hlo"], encoding="utf-8") as f:
+            assert not re.search(repeated, f.read()), case
+    assert rows[cases[0]]["entry_ops"].get("custom-call", 0) >= 1
+    assert rows[cases[1]]["entry_ops"].get("while") == 1
 
 
 @pytest.fixture(scope="module")
@@ -266,11 +299,11 @@ def lfm2_rows():
 
 def test_attention_forward_aot_compiles_at_a_head_width_of_64(lfm2_rows):
     """The forward pass in one call with q, k and v 64 wide (2 x 32
-    heads x 8,192): the kernel compiles as it is, half a tile's lanes
-    Mosaic's to lay out.  XLA itself keeps such an array with the 8,192
-    positions minor (64 is half a lane tile) and copies it into the
-    kernel's layout, here and in the step (PERF.md 5 has what the copies
-    cost on the chip)."""
+    query heads on 8 key-value heads x 8,192): the kernel compiles as it
+    is, half a tile's lanes Mosaic's to lay out.  XLA itself keeps such
+    an array with the 8,192 positions minor (64 is half a lane tile) and
+    copies it into the kernel's layout, here and in the step (PERF.md 5
+    has what the copies cost on the chip)."""
     row = lfm2_rows["lfm2_flash_causal_forward"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
@@ -278,8 +311,9 @@ def test_attention_forward_aot_compiles_at_a_head_width_of_64(lfm2_rows):
 
 
 def test_attention_backward_aot_compiles_at_a_head_width_of_64(lfm2_rows):
-    """The backward's block pair with q, k and v 64 wide (2 x 32 heads x
-    8,192, blocks of 1,024), alone and as the 36 pairs of one
+    """The backward's block pair with q, k and v 64 wide (2 x 32 query
+    heads on 8 key-value heads x 8,192, blocks of 1,024), alone and as
+    the 36 pairs of one
     ``lax.scan``: the kernel is in the loop's body, one loop."""
     row = lfm2_rows["lfm2_attn_block_backward_1k"]
     assert row.get("compiled"), json.dumps(row, indent=1)

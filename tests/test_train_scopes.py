@@ -58,11 +58,18 @@ PATHS = [
      "checkpoint/rematted_computation/otpu_layers/otpu_mla/"
      "jit(flash_causal_forward)/otpu_flash_causal_forward/pallas_call",
      ["otpu_layers", "otpu_mla"], "remat"),
+    # two instructions the compiler folded into one (a reshape and a
+    # transpose that move nothing): their paths joined, the first read
+    ("jit(otpu_train_step)/jvp(otpu_layers)/otpu_attention/otpu_attn_proj/"
+     "transpose;jit(otpu_train_step)/jvp(otpu_layers)/otpu_attention/"
+     "otpu_attn_proj/reshape",
+     ["otpu_layers", "otpu_attention", "otpu_attn_proj"], "forward"),
 ]
 
 
-@pytest.mark.parametrize("path,chain,which", PATHS,
-                         ids=[p[0].split("/", 1)[1][:60] for p in PATHS])
+@pytest.mark.parametrize(
+    "path,chain,which", PATHS,
+    ids=[p[0].split("/", 1)[1][:60].replace(";", "+") for p in PATHS])
 def test_a_path_gives_its_scopes_and_its_pass(path, chain, which):
     assert trace.scope_of_path(path) == (chain, which, [])
     text = ("HloModule jit_step, is_scheduled=true\n\n"
