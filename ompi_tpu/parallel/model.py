@@ -585,7 +585,85 @@ def _unit_lower_inverse_bwd(t, ct):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def gated_delta_chunked(q, k, v, g, beta, chunk: int):
+def _count_rule(on_kernel: bool) -> None:
+    """SPC ``gdn_rule_built``: the delta rule's passes made while steps
+    were traced (``gated_delta_chunked``'s XLA form, whose backward pass
+    is autodiff's and not seen here, or the kernel path's forward and
+    backward rules: JAX traces a pass more than once);
+    ``gdn_rule_kernel_built``: those of them made on the Pallas kernels.
+    What reads is the second over the first."""
+    spc.record("gdn_rule_built", 1)
+    if on_kernel:
+        spc.record("gdn_rule_kernel_built", 1)
+
+
+def _kernel_views(arrays, hk, hv):
+    """(q, k, v, their lane blocks) as ``ops/gated_delta`` reads them: of
+    three arrays (bt, s, heads x 128) each from its first block; of one,
+    the convolution's [q | k | v], key head h's q at block h, its k at
+    ``hk + h`` and its value heads at ``2 hk / r + h`` blocks of r heads."""
+    if len(arrays) == 3:
+        return (*arrays, (0, 0, 0))
+    (qkv,) = arrays
+    return qkv, qkv, qkv, (0, hk, 2 * hk * hk // hv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _kernel_rule(arrays, g, beta, chunk, hk, unit):
+    """The chunked rule on the Pallas kernels (``ops/gated_delta``) for
+    ``hk`` key heads 128 wide: o (bt, s, hv x 128) of ``arrays``, either
+    (q, k, v) as the rule reads them, heads side by side, or with
+    ``unit`` = (eps, scale) the convolution's one [q | k | v], whose q
+    and k rows the kernels put at unit length themselves.  The forward
+    kernel also writes, for a backward pass, the state that entered each
+    chunk and the chunk's ``T``; the backward kernel makes a chunk's
+    other parts again from those.  Nothing a chunk is kept or recomputed
+    by XLA."""
+    from ompi_tpu.ops import gated_delta as rule_kernel
+
+    _count_rule(True)
+    *views, at = _kernel_views(arrays, hk, g.shape[2])
+    return rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
+                                    at=at, unit=unit)
+
+
+def _kernel_rule_fwd(arrays, g, beta, chunk, hk, unit):
+    from ompi_tpu.ops import gated_delta as rule_kernel
+
+    _count_rule(True)
+    *views, at = _kernel_views(arrays, hk, g.shape[2])
+    o, kept = rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
+                                       at=at, unit=unit, states=True)
+    return o, (arrays, g, beta, kept)
+
+
+def _kernel_rule_bwd(chunk, hk, unit, res, do):
+    from ompi_tpu.ops import gated_delta as rule_kernel
+
+    _count_rule(True)
+    arrays, g, beta, kept = res
+    *views, at = _kernel_views(arrays, hk, g.shape[2])
+    *d_qkv, dg, dbeta = rule_kernel.rule_backward(
+        *views, g, beta, kept, do, chunk=chunk, hk=hk, at=at, unit=unit)
+    if len(arrays) == 1:
+        d_qkv = [jnp.concatenate(d_qkv, axis=-1)]
+    return tuple(d_qkv), dg, dbeta
+
+
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
+
+
+def _rule_on_kernels(interpret, chunk, dk, dv, r, s) -> bool:
+    """Whether the rule runs on the Pallas kernels: where Mosaic compiles
+    (``interpret`` false: a TPU) and the shape has tiles."""
+    if interpret:
+        return False
+    from ompi_tpu.ops import gated_delta as rule_kernel
+
+    return rule_kernel.supported(chunk, dk, dv, r, s)
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk: int, interpret: bool = True):
     """The gated delta rule (arXiv:2412.06464, the chunked form of its
     section 3.3 and of qwen3_next's modelling code) in float32: per
     value head, from a zero state S (dk x dv) that is never reset, ``S
@@ -610,10 +688,20 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int):
     on the state it reads, so they lie inside the scan).  Running sums,
     exponentials, the solve, the states and every product are float32
     at the highest precision.  The backward pass is autodiff's through
-    the same chunks."""
+    the same chunks.
+
+    Where Mosaic compiles (``interpret`` false: a TPU) and the shape has
+    tiles (``ops/gated_delta.supported``) the same chunks run in Pallas
+    kernels that keep the state in VMEM (``_kernel_rule``), forward and
+    backward; everywhere else this XLA form, which is their oracle."""
     bt, s, hk, dk = k.shape
     hv, dv = v.shape[2:]
     r = hv // hk
+    if _rule_on_kernels(interpret, chunk, dk, dv, r, s):
+        flat = lambda t: t.reshape(bt, s, -1)
+        return _kernel_rule((flat(q), flat(k), flat(v)), g, beta, chunk, hk,
+                            None).reshape(v.shape)
+    _count_rule(False)
     _f32 = lambda eq, one, two: _contract(eq, one, two, jnp.float32)
     pad = -s % chunk
     if pad:
@@ -666,7 +754,11 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int):
     return o.transpose(1, 0, 4, 2, 3, 5).reshape(bt, s + pad, hv, dv)[:, :s]
 
 
-def gated_delta_net(p, x, cfg):
+#: what the delta rule's L2 norms add under the root (``layers.l2norm``'s)
+L2NORM_EPS = 1e-6
+
+
+def gated_delta_net(p, x, cfg, *, interpret: bool = True):
     """qwen3_next's Gated DeltaNet operator (Qwen3-Next-80B-A3B's
     ``linear_attention`` layers; arXiv:2412.06464), **without** the
     residual add, on the residual stream ``x`` (b, s, d) float32, whole
@@ -682,7 +774,11 @@ def gated_delta_net(p, x, cfg):
     ``rmsnorm over each head of o * gain * silu(z)`` (the gate behind
     the gain); ``y W_out``.  Everything between the two large
     projections is float32.  The sequence is never reset inside a packed
-    row.  Returns (the sublayer's output, what the rule read and made of
+    row.  Where the rule runs on its Pallas kernels (``_rule_on_kernels``)
+    they read q, k and v where the convolution left them, one array, and
+    put q's and k's rows at unit length themselves (``_kernel_rule``):
+    a 4D view of q, k or v costs XLA two relayouts of it a pass.
+    Returns (the sublayer's output, what the rule read and made of
     the first value head, by token row: ``gdn_q_seq``, ``gdn_k_seq`` (T,
     dk), ``gdn_v_seq`` (T, dv), ``gdn_g_seq``, ``gdn_beta_seq`` (T,)
     whole, because a position's state holds every earlier one, and the
@@ -707,14 +803,27 @@ def gated_delta_net(p, x, cfg):
         qkv = jax.nn.silu(sum(padded[:, j:j + s] * p["conv_w"][j]
                               for j in range(taps)))
     with jax.named_scope("otpu_gdn_rule"):
-        q, k = (l2norm(qkv[..., j * key:(j + 1) * key]
-                       .reshape(b, s, hk, dk)) for j in (0, 1))
-        q = q * dk ** -0.5
-        v = qkv[..., 2 * key:].reshape(b, s, hv, dv)
         beta = jax.nn.sigmoid(ba[:, :, 0])
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, :, 1]
                                                    + p["dt_bias"])
-        o = gated_delta_chunked(q, k, v, g, beta, cfg.chunk_size)
+        heads = lambda t, n, width: t.reshape(b, s, n, width)
+        if _rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk, s) \
+                and 2 * hk % (hv // hk) == 0:
+            # the kernels read q, k and v where the convolution left them
+            # and norm q and k themselves: only the first head, which the
+            # step reports, is cut out and normed here
+            o = heads(_kernel_rule((qkv,), g, beta, cfg.chunk_size, hk,
+                                   (L2NORM_EPS, dk ** -0.5)), hv, dv)
+            q, k, v = (heads(qkv[..., first:first + width], 1, width)
+                       for first, width in ((0, dk), (key, dk), (2 * key, dv)))
+            q, k = l2norm(q, L2NORM_EPS) * dk ** -0.5, l2norm(k, L2NORM_EPS)
+        else:
+            q, k = (l2norm(heads(qkv[..., j * key:(j + 1) * key], hk, dk),
+                           L2NORM_EPS) for j in (0, 1))
+            q = q * dk ** -0.5
+            v = heads(qkv[..., 2 * key:], hv, dv)
+            o = gated_delta_chunked(q, k, v, g, beta, cfg.chunk_size,
+                                    interpret)
         rows = lambda t: t.reshape((b * s,) + t.shape[2:])
         seen = {"gdn_q_seq": rows(q[:, :, 0]), "gdn_k_seq": rows(k[:, :, 0]),
                 "gdn_v_seq": rows(v[:, :, 0]), "gdn_g_seq": rows(g[:, :, 0]),
@@ -774,7 +883,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
     seen = {}
     if cfg.layer_types and "ba_proj" in p:
         with jax.named_scope("otpu_gdn"):
-            y, seen = gated_delta_net(p, x, cfg)
+            y, seen = gated_delta_net(p, x, cfg, interpret=interpret)
         x = x + y
     elif cfg.layer_types and "in_proj" in p:
         with jax.named_scope("otpu_conv"):

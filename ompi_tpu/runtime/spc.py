@@ -123,6 +123,11 @@ _COUNTERS = (
     # of them made on the Pallas kernel (ops/grouped_matmul): the second
     # over the first says which share of a run's engaged the kernel
     "moe_gmm_built", "moe_gmm_kernel_built",
+    # the chunked delta rule's passes made while steps were traced
+    # (parallel/model.gated_delta_chunked: the XLA form's forward, or the
+    # kernel path's forward and backward rules), and those of them made
+    # on the Pallas kernels (ops/gated_delta): the second over the first
+    "gdn_rule_built", "gdn_rule_kernel_built",
     # the causal attention passes made while steps were traced
     # (parallel/model.causal_flash_attention's forward and backward
     # rules), and those of them whose k and v came with fewer heads than q

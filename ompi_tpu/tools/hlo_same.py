@@ -32,19 +32,24 @@ _NAME_RE = re.compile(r"%[\w.\-]+")
 _bodies: dict = {}
 
 
+def kernel_text(b64: str) -> str:
+    """A Mosaic kernel's MLIR, without its locations, from the ``body``
+    of its ``custom-call``'s backend configuration."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return str(ir.Module.parse(base64.b64decode(b64)))
+
+
 def _kernel(b64: str) -> str:
     """A digest of a Mosaic kernel's MLIR without its locations."""
     if b64 not in _bodies:
-        from jax._src.interpreters import mlir
-        from jax._src.lib import tpu
-        from jaxlib.mlir import ir
-
-        ctx = mlir.make_ir_context()
-        tpu.register_dialect(ctx)
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            text = str(ir.Module.parse(base64.b64decode(b64)))
-        _bodies[b64] = hashlib.sha256(text.encode()).hexdigest()
+        _bodies[b64] = hashlib.sha256(kernel_text(b64).encode()).hexdigest()
     return _bodies[b64]
 
 

@@ -325,6 +325,25 @@ def cases(mesh1d, mesh2d):
     case("gmm_joyai", lambda: gmm_forms(8192, 16, 2048, 768))
     case("gmm_nemotron", lambda: gmm_forms(8192, 8, 1024, 2688))
     case("gmm_qwen3next", lambda: gmm_forms(32768, 32, 2048, 512))
+    # the chunked delta rule (``model._kernel_rule``: ``ops/gated_delta``'s
+    # two kernels) as the Qwen3-Next cell's step builds it: 16 key heads,
+    # 32 value heads, 128 / 128, 16,384 positions in chunks of 64, q, k
+    # and v read from the convolution's one array and normed in the
+    # kernels, every product float32 at the highest precision
+    def gdn_rule(backward):
+        from ompi_tpu.parallel import model
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        rule = lambda qkv, g, beta: model._kernel_rule(
+            (qkv,), g, beta, 64, 16, (model.L2NORM_EPS, 128 ** -0.5))
+        if backward:
+            rule = jax.grad(lambda *a, rule=rule: jnp.sum(rule(*a)),
+                            (0, 1, 2))
+        return jax.jit(rule), (rep(1, 16384, 8192), rep(1, 16384, 32),
+                               rep(1, 16384, 32))
+
+    case("qwen3next_gdn_rule_forward", lambda: gdn_rule(False))
+    case("qwen3next_gdn_rule_backward", lambda: gdn_rule(True))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),

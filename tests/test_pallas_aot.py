@@ -190,6 +190,20 @@ def _rows_with_texts(only: str, **env_extra) -> dict:
         dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
 
 
+def _kernel_bodies(hlo_text: str, prefix: str) -> dict:
+    """{kernel name: its Mosaic module as MLIR text} of the compiled
+    text's ``custom-call`` lines whose kernel is named ``prefix``..."""
+    from ompi_tpu.tools import hlo_same
+
+    out = {}
+    for line in hlo_text.split("\n"):
+        name = re.search(r"/(%s\w*)/pallas_call" % prefix, line)
+        body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line)
+        if " custom-call(" in line and name and body:
+            out[name.group(1)] = hlo_same.kernel_text(body.group(1))
+    return out
+
+
 @pytest.fixture(scope="module")
 def joyai_rows():
     """One child for the JoyAI-LLM-Flash cases: attention's two kernels
@@ -342,8 +356,9 @@ def test_lfm2_train_step_aot_compiles_from_the_cells_configuration(
 def qwen3next_rows():
     """One child for the Qwen3-Next-80B-A3B cases: attention's two kernels
     at a head width of 256 alone and the whole step of the cell's own
-    configuration file, for one v5e device (about 2.5 min of the 600: the
-    step's 16,384 positions)."""
+    configuration file, and the delta rule's two kernels at the cell's
+    shape, for one v5e device (about 2 min of the 600: the step's 16,384
+    positions)."""
     return _rows_with_texts("qwen3next_")
 
 
@@ -374,9 +389,11 @@ def test_qwen3next_train_step_aot_compiles_from_the_cells_configuration(
     """The whole step of ``benchmark/configs/qwen3-next-80b-a3b-train-1chip
     .json`` (published widths; layers 0-3 of 48, 32 of 512 experts, 1 x
     16,384 tokens): it fits the chip beside its 7.5 GB of state with the
-    rule's scan steps recomputed one by one, the three like DeltaNet
-    layers are one loop, and the state's fifth slot is rows of no
-    entries."""
+    delta rule on its kernels and no step-wise checkpoint (PR 52: the
+    forward kernel in the forward and the recomputed pass, the backward
+    kernel once, all under ``otpu_gdn_rule``, and no loop of XLA's
+    there), the three like DeltaNet layers are one loop, and the state's
+    fifth slot is rows of no entries."""
     row = qwen3next_rows["qwen3next_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["while"] >= 3
@@ -386,7 +403,46 @@ def test_qwen3next_train_step_aot_compiles_from_the_cells_configuration(
     scopes = {name for _, path in op_paths(row) for name in
               re.findall(r"otpu_gdn\w*", path)}
     assert scopes == {"otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv",
-                      "otpu_gdn_rule", "otpu_gdn_norm"}
+                      "otpu_gdn_rule", "otpu_gdn_norm",
+                      "otpu_gdn_rule_fwd", "otpu_gdn_rule_bwd"}
+    rule = [(line, path) for line, path in op_paths(row)
+            if "/otpu_gdn_rule/" in path]
+    kernels = sorted(path.split("jit(otpu_train_step)/")[1]
+                     for line, path in rule if " custom-call(" in line)
+    assert [(k.split("/")[0], "rematted_computation" in k,
+             k.split("/")[-2]) for k in kernels] == [
+        ("jvp(otpu_layers)", False, "otpu_gdn_rule_fwd"),
+        ("transpose(jvp(otpu_layers))", False, "otpu_gdn_rule_bwd"),
+        ("transpose(jvp(otpu_layers))", True, "otpu_gdn_rule_fwd")], kernels
+    assert not [line for line, _ in rule if " while(" in line]
+
+
+def test_the_delta_rule_aot_compiles_at_the_cells_shape(qwen3next_rows):
+    """``gated_delta_chunked`` where Mosaic compiles, at 16 key heads, 32
+    value heads, 128 / 128 and 16,384 positions in chunks of 64: the
+    forward alone is one kernel call and no loop; its gradient is the
+    forward kernel, which also writes the entering states (0.54 GB) and
+    the inverses (0.13 GB), and the backward kernel."""
+    fwd = qwen3next_rows["qwen3next_gdn_rule_forward"]
+    assert fwd.get("compiled"), json.dumps(fwd, indent=1)
+    assert fwd["entry_ops"].get("custom-call") == 1, fwd["entry_ops"]
+    bwd = qwen3next_rows["qwen3next_gdn_rule_backward"]
+    assert bwd.get("compiled"), json.dumps(bwd, indent=1)
+    assert bwd["entry_ops"].get("custom-call") == 2, bwd["entry_ops"]
+    for row in (fwd, bwd):
+        assert "while" not in row["entry_ops"], row["entry_ops"]
+    # every product of the kernels is float32 at the highest precision
+    with open(bwd["hlo"], encoding="utf-8") as f:
+        bodies = _kernel_bodies(f.read(), "otpu_gdn_rule_")
+    assert sorted(bodies) == ["otpu_gdn_rule_bwd", "otpu_gdn_rule_fwd"]
+    for name, text in bodies.items():
+        products = [ln for ln in text.split("\n") if "tpu.matmul" in ln]
+        assert len(products) > 40, (name, len(products))
+        assert all("contract_precision<fp32>" in ln
+                   and "xf32>" in ln and "bf16" not in ln
+                   for ln in products), name
+    # operands and results, the states, the inverses: under 3 GB
+    assert bwd["peak_bytes"] < 3 << 30
 
 
 @pytest.fixture(scope="module")
