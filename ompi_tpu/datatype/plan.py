@@ -528,6 +528,7 @@ def plan_for(datatype, count: int = 1) -> Plan:
     if plan is None:
         spc.record("device_ddt_plan_builds")
         trace.bind_profiler()
+        trace.bind_builds()
         if trace.profiler_on():
             with trace.profiler_span("otpu.ddt.plan", count=count,
                                      nseg=datatype.nseg):

@@ -362,7 +362,7 @@ class XlaHierarchicalColl:
             P, up, low = self._P, self.up_axis, self.low_axis
             divisible = (x.shape[1:] and x.shape[1] % self.n_low == 0)
 
-            def body(t):  # t: (1, *S) block per device
+            def otpu_han_allreduce(t):  # t: (1, *S) block per device
                 v = t[0]
                 if divisible:
                     s = jax.lax.psum_scatter(
@@ -372,7 +372,8 @@ class XlaHierarchicalColl:
                 return jax.lax.psum(jax.lax.psum(v, low), up)
 
             fn = jax.jit(shard_map(
-                body, mesh=self.mesh, in_specs=P((up, low)), out_specs=P(),
+                otpu_han_allreduce,
+                mesh=self.mesh, in_specs=P((up, low)), out_specs=P(),
                 check_vma=False))
             self._cache[key] = fn
         return fn(x)
@@ -388,7 +389,7 @@ class XlaHierarchicalColl:
         if fn is None:
             P, up, low = self._P, self.up_axis, self.low_axis
 
-            def body(t):  # (1, n, *S)
+            def otpu_han_reduce_scatter(t):  # (1, n, *S)
                 # scatter across the local ici group first, then finish
                 # the reduction across dcn and scatter the remainder
                 v = jax.lax.psum(t[0], low)       # (n, *S) node-reduced
@@ -398,7 +399,7 @@ class XlaHierarchicalColl:
                 return jax.lax.dynamic_index_in_dim(v, i, 0)
 
             fn = jax.jit(shard_map(
-                body, mesh=self.mesh, in_specs=P((up, low)),
+                otpu_han_reduce_scatter, mesh=self.mesh, in_specs=P((up, low)),
                 out_specs=P((up, low)), check_vma=False))
             self._cache[key] = fn
         return fn(x)

@@ -118,6 +118,7 @@ class PersistentColl:
         self._nbytes = nbytes
         self._bump = spc.bump_device   # pre-bound: ~sub-µs steady state
         trace.bind_profiler()          # fn is a jitted program: jax is in
+        trace.bind_builds()
         self._profiling = trace.profiler_on
         self._span = f"otpu.coll.{coll}_init"
 
@@ -151,6 +152,7 @@ class GroupedColl:
         self.count = count
         self.nbytes = nbytes
         trace.bind_profiler()
+        trace.bind_builds()
         self._profiling = trace.profiler_on
         self._span = f"otpu.coll.{coll}_pgroup"
 
@@ -213,6 +215,7 @@ class XlaCollModule:
         self._replicated = NamedSharding(self.mesh, P())
         self._jax_array = jax.Array   # fast isinstance gate for _fast
         trace.bind_profiler()
+        trace.bind_builds()
 
     # -- helpers ---------------------------------------------------------
     def _check(self, comm, x, inner_n: bool = False):

@@ -1402,8 +1402,13 @@ def _jit_right_permute(mesh, axis: str, payload_shape, dtype_str: str,
     n = mesh.shape[axis]
     fn = _build_right_permute(n, axis, (1,) + payload_shape, dtype_str,
                               interpret)
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis), check_vma=False))
+
+    def otpu_pallas_right_permute(t):
+        return fn(t)
+
+    return jax.jit(shard_map(
+        otpu_pallas_right_permute, mesh=mesh, in_specs=P(axis),
+        out_specs=P(axis), check_vma=False))
 
 
 def right_permute(x, mesh, axis: str, interpret: Optional[bool] = None):
@@ -1429,11 +1434,12 @@ def _jit_all_gather(mesh, axis: str, blk_shape, dtype_str: str,
              else _build_all_gather)
     inner = build(n, axis, blk_shape, dtype_str, interpret)
 
-    def body(t):                       # t: (1, *S)
+    def otpu_pallas_all_gather(t):  # t: (1, *S)
         return inner(t[0])             # (n, *S)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_gather, mesh=mesh, in_specs=P(axis),
+        out_specs=P(), check_vma=False))
 
 
 def all_gather(x, mesh, axis: str, interpret: Optional[bool] = None,
@@ -1550,7 +1556,7 @@ def _jit_reduce_scatter(mesh, axis: str, payload_shape, dtype_str: str,
         shape_in = (n, rows, 128)
     padded = rows * 128
 
-    def body(t):                       # t: (1, n, *S)
+    def otpu_pallas_reduce_scatter(t):  # t: (1, n, *S)
         r2 = t[0].reshape(n, blk)
         if padded != blk:
             r2 = jnp.pad(r2, ((0, 0), (0, padded - blk)),
@@ -1558,8 +1564,9 @@ def _jit_reduce_scatter(mesh, axis: str, payload_shape, dtype_str: str,
         out = inner(r2.reshape(shape_in))
         return out.reshape(-1)[:blk].reshape((1,) + payload_shape)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_reduce_scatter, mesh=mesh, in_specs=P(axis),
+        out_specs=P(axis), check_vma=False))
 
 
 def reduce_scatter(x, mesh, axis: str, op: str = "sum",
@@ -1627,7 +1634,7 @@ def _jit_all_reduce(mesh, axis: str, payload_shape, dtype_str: str,
         shape_in = (n, rows, 128)
     padded = rows * 128 * n
 
-    def body(t):                       # t: (1, *S)
+    def otpu_pallas_all_reduce(t):  # t: (1, *S)
         flat = t.reshape(-1)
         if padded != size:
             flat = jnp.pad(flat, (0, padded - size),
@@ -1635,8 +1642,9 @@ def _jit_all_reduce(mesh, axis: str, payload_shape, dtype_str: str,
         out = inner(flat.reshape(shape_in))
         return out.reshape(-1)[:size].reshape(payload_shape)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_reduce, mesh=mesh, in_specs=P(axis),
+        out_specs=P(), check_vma=False))
 
 
 def all_reduce(x, mesh, axis: str, op: str = "sum",
@@ -1687,11 +1695,12 @@ def _jit_all_to_all(mesh, axis: str, blk_shape, dtype_str: str,
     n = mesh.shape[axis]
     inner = _build_all_to_all(n, axis, blk_shape, dtype_str, interpret)
 
-    def body(t):                       # t: (1, n, *S)
+    def otpu_pallas_all_to_all(t):  # t: (1, n, *S)
         return inner(t[0])[None]       # (1, n, *S): row = my received
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_to_all, mesh=mesh, in_specs=P(axis),
+        out_specs=P(axis), check_vma=False))
 
 
 def all_to_all(x, mesh, axis: str, interpret: Optional[bool] = None):
@@ -1724,11 +1733,12 @@ def _jit_all_gather_v(mesh, axis: str, max_rows: int, width: int,
     inner = _build_all_gather_v(n, axis, max_rows, width, chunk,
                                 dtype_str, interpret)
 
-    def body(c, t):                    # c: (n,) replicated; t: (1, R, W)
+    def otpu_pallas_all_gather_v(c, t):  # c: (n,) replicated; t: (1, R, W)
         return inner(c, t[0])          # (n, R, W) replicated
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_gather_v, mesh=mesh, in_specs=(P(), P(axis)),
+        out_specs=P(), check_vma=False))
 
 
 def all_gather_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
@@ -1790,11 +1800,13 @@ def _jit_all_to_all_v(mesh, axis: str, max_rows: int, width: int,
     inner = _build_all_to_all_v(n, axis, max_rows, width, chunk,
                                 dtype_str, interpret)
 
-    def body(c, t):                    # c: (n, n) replicated; t: (1, n, R, W)
+    # c: (n, n) replicated; t: (1, n, R, W)
+    def otpu_pallas_all_to_all_v(c, t):
         return inner(c, t[0])[None]
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
-                             out_specs=P(axis), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_to_all_v, mesh=mesh, in_specs=(P(), P(axis)),
+        out_specs=P(axis), check_vma=False))
 
 
 def all_to_all_v(x, counts, mesh, axis: str, chunk_rows: int = 8,
@@ -1883,7 +1895,7 @@ def _jit_all_reduce_torus(mesh, axes, payload_shape, dtype_str: str,
                             interpret, sub=(n0, n1, 0))
     pad = _pad_value(op, dtype_str)
 
-    def body(t):                       # t: (1, *S)
+    def otpu_pallas_all_reduce_torus(t):  # t: (1, *S)
         flat = t.reshape(-1)
         if rows0 * 128 * n0 != size:
             flat = jnp.pad(flat, (0, rows0 * 128 * n0 - size),
@@ -1898,8 +1910,9 @@ def _jit_all_reduce_torus(mesh, axes, payload_shape, dtype_str: str,
         full = ag0(red)                           # (n0, rows0, 128)
         return full.reshape(-1)[:size].reshape(payload_shape)
 
-    return jax.jit(shard_map(body, mesh=flat_mesh, in_specs=P("_t"),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_reduce_torus, mesh=flat_mesh, in_specs=P("_t"),
+        out_specs=P(), check_vma=False))
 
 
 def all_reduce_torus(x, mesh, axes=("x", "y"), op: str = "sum",
@@ -1972,7 +1985,7 @@ def _jit_reduce_scatter_torus(mesh, axes, payload_shape, dtype_str: str,
                                 sub=(n0, n1, 1), cid=17)
     padded = rb * 128
 
-    def body(t):                       # t: (1, N, *S)
+    def otpu_pallas_reduce_scatter_torus(t):  # t: (1, N, *S)
         r2 = t[0].reshape(N, blk)
         if padded != blk:
             r2 = jnp.pad(r2, ((0, 0), (0, padded - blk)),
@@ -1981,8 +1994,9 @@ def _jit_reduce_scatter_torus(mesh, axes, payload_shape, dtype_str: str,
         p2 = rs1(p1.reshape(n1, rb, 128))        # (rb, 128)
         return p2.reshape(-1)[:blk].reshape((1,) + payload_shape)
 
-    return jax.jit(shard_map(body, mesh=flat_mesh, in_specs=P("_t"),
-                             out_specs=P("_t"), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_reduce_scatter_torus, mesh=flat_mesh, in_specs=P("_t"),
+        out_specs=P("_t"), check_vma=False))
 
 
 def reduce_scatter_torus(x, mesh, axes=("x", "y"), op: str = "sum",
@@ -2029,7 +2043,7 @@ def _jit_all_gather_torus(mesh, axes, blk_shape, dtype_str: str,
     ag0 = _build_all_gather(n0, "_t", (n1 * rb, 128), dtype_str,
                             interpret, sub=(n0, n1, 0), cid=18)
 
-    def body(t):                       # t: (1, *S)
+    def otpu_pallas_all_gather_torus(t):  # t: (1, *S)
         flat = t[0].reshape(-1)
         if rb * 128 != blk:
             flat = jnp.pad(flat, (0, rb * 128 - blk))
@@ -2038,8 +2052,9 @@ def _jit_all_gather_torus(mesh, axes, blk_shape, dtype_str: str,
         return full.reshape(N, rb * 128)[:, :blk].reshape(
             (N,) + blk_shape)
 
-    return jax.jit(shard_map(body, mesh=flat_mesh, in_specs=P("_t"),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_all_gather_torus, mesh=flat_mesh, in_specs=P("_t"),
+        out_specs=P(), check_vma=False))
 
 
 def all_gather_torus(x, mesh, axes=("x", "y"),
@@ -2074,15 +2089,16 @@ def _jit_bcast(mesh, axis: str, payload_shape, dtype_str: str,
     padded = nseg * srows * 128
     inner = _build_bcast(n, axis, nseg, srows, dtype_str, interpret)
 
-    def body(r, t):                    # r: (1,) int32; t: (1, *S)
+    def otpu_pallas_bcast(r, t):  # r: (1,) int32; t: (1, *S)
         flat = t.reshape(-1)
         if padded != size:
             flat = jnp.pad(flat, (0, padded - size))
         out = inner(r, flat.reshape(nseg, srows, 128))  # root's rows
         return out.reshape(-1)[:size].reshape((1,) + payload_shape)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
-                             out_specs=P(axis), check_vma=False))
+    return jax.jit(shard_map(
+        otpu_pallas_bcast, mesh=mesh, in_specs=(P(), P(axis)),
+        out_specs=P(axis), check_vma=False))
 
 
 def bcast(x, mesh, axis: str, root: int = 0, interpret: Optional[bool] = None,

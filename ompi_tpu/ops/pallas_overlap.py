@@ -174,13 +174,14 @@ def _jit_matmul_reduce_scatter(mesh, axis: str, m: int, k_loc: int,
                                 dtype_str, interpret, align=-1,
                                 with_ag=False, cid=11)
 
-    def body(a, b):   # a: (1, m, k_loc), b: (1, k_loc, n_out)
+    # a: (1, m, k_loc), b: (1, k_loc, n_out)
+    def otpu_pallas_matmul_reduce_scatter(a, b):
         a2 = a[0]
         if m_pad != m:
             a2 = jnp.pad(a2, ((0, m_pad - m), (0, 0)))
         return inner(a2, b[0])[None]     # (1, m_blk, n_out)
 
-    return jax.jit(shard_map(body, mesh=mesh,
+    return jax.jit(shard_map(otpu_pallas_matmul_reduce_scatter, mesh=mesh,
                              in_specs=(P(axis), P(axis)),
                              out_specs=P(axis), check_vma=False))
 
@@ -215,14 +216,15 @@ def _jit_matmul_allreduce(mesh, axis: str, m: int, k_loc: int,
                                 dtype_str, interpret, align=0,
                                 with_ag=True, cid=10)
 
-    def body(a, b):   # a: (1, m, k_loc), b: (1, k_loc, n_out)
+    # a: (1, m, k_loc), b: (1, k_loc, n_out)
+    def otpu_pallas_matmul_allreduce(a, b):
         a2 = a[0]
         if m_pad != m:
             a2 = jnp.pad(a2, ((0, m_pad - m), (0, 0)))
         out = inner(a2, b[0])            # (n, m_blk, n_out)
         return out.reshape(m_pad, n_out)[:m]
 
-    return jax.jit(shard_map(body, mesh=mesh,
+    return jax.jit(shard_map(otpu_pallas_matmul_allreduce, mesh=mesh,
                              in_specs=(P(axis), P(axis)),
                              out_specs=P(), check_vma=False))
 

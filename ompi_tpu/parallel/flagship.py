@@ -312,7 +312,7 @@ def build_flagship_step(mesh, spec: MeshSpec, lr: float = 2.0,
                 n_experts=dims["n_experts"], capacity=dims["capacity"])
         return x_mb
 
-    def body(params, x):
+    def otpu_flagship_step(params, x):
         def loss_fn(ps):
             y = pipeline_apply(stage_fn, ps, x.reshape(M, mb, s_l, d), pp=pp,
                                vary_axes=("pp", "tp"))
@@ -351,7 +351,7 @@ def build_flagship_step(mesh, spec: MeshSpec, lr: float = 2.0,
     # composed step compiles and descends — with silently wrong
     # pipeline gradients (caught by test_pp2_matches_pp1_same_model).
     step = jax.jit(shard_map(
-        body, mesh=mesh,
+        otpu_flagship_step, mesh=mesh,
         in_specs=(pspecs, P("dp", "sp", None)),
         out_specs=(pspecs, P()),
         check_vma=True))

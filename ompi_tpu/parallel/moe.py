@@ -546,7 +546,7 @@ def build_moe_train_step(mesh, spec: MeshSpec, lr: float = 0.02):
     pspecs = moe_param_specs(P, spec)
     x_spec = P("dp", None)
 
-    def body(params, x):
+    def otpu_moe_train_step(params, x):
         def loss_fn(ps):
             y = moe_ep_block(ps, x, ep=ep,
                              n_experts=dims["n_experts"],
@@ -571,7 +571,8 @@ def build_moe_train_step(mesh, spec: MeshSpec, lr: float = 0.02):
         new = jax.tree.map(lambda p_, g: p_ - lr * g, params, grads)
         return new, loss
 
-    step = jax.jit(shard_map(body, mesh=mesh, in_specs=(pspecs, x_spec),
+    step = jax.jit(shard_map(
+        otpu_moe_train_step, mesh=mesh, in_specs=(pspecs, x_spec),
                              out_specs=(pspecs, P()), check_vma=True))
 
     def place(params, x_np):

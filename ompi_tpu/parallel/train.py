@@ -1039,6 +1039,10 @@ def bias_update(cfg: ModelConfig, bias, loads):
 
 
 _ran_steps = weakref.WeakSet()      # the model steps that ran, while held
+#: what ``step.memory()`` reads of a compiled step's ``memory_analysis()``
+MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
+                 "alias_size_in_bytes", "temp_size_in_bytes",
+                 "peak_memory_in_bytes")
 
 
 def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
@@ -1162,36 +1166,34 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
             state, tokens, labels)
 
     jitted = jax.jit(otpu_train_step, donate_argnums=(0,))
-    slots = n_global * cfg.num_experts_per_tok * cfg.n_routers
-    ssm_tokens = n_global * cfg.pattern_here.count("M")
     trace.bind_profiler()
+    trace.bind_builds()
     count = [0]
     avals = []          # the first call's arguments, as shapes: scopes()
 
     def step(state, tokens, labels):
         """One optimiser step: ``(state, aux)``; ``state`` is donated.
         Nothing here reads the device: what it computed comes back in
-        ``aux`` (``record_step_stats`` reads it, outside any timing)."""
+        ``aux`` (``record_step_stats`` reads it, outside any timing).
+        SPC ``train_steps`` counts the calls; the tokens, routed slots and
+        bias updates in them are constants of ``cfg`` times it."""
         count[0] += 1
         spc.record("train_steps")
-        spc.record("train_tokens", n_global)
-        spc.record("moe_token_slots", slots)
-        if cfg.n_mtp_here:
-            spc.record("train_mtp_tokens", n_global)
-        if ssm_tokens:
-            spc.record("train_ssm_layer_tokens", ssm_tokens)
-        if biased:
-            spc.record("moe_bias_updates", cfg.n_routers)
         if count[0] == 1:
             # the first call traces, lowers and compiles (or loads the
-            # cached program): counted as every device program's is
+            # cached program): counted as every device program's is, its
+            # phases by the build record (``trace.bind_builds``)
             t0 = time.perf_counter()
             avals.append(jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=a.sharding),
                 (state, tokens, labels)))
             _ran_steps.add(step)
-            out = jitted(state, tokens, labels)
+            if trace.profiler_on():
+                with trace.profiler_span("otpu.train.first_call"):
+                    out = jitted(state, tokens, labels)
+            else:
+                out = jitted(state, tokens, labels)
             spc.record("device_program_builds")
             spc.record("device_program_first_call_us",
                        (time.perf_counter() - t0) * 1e6)
@@ -1202,24 +1204,40 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
                 return jitted(state, tokens, labels)
         return jitted(state, tokens, labels)
 
+    def compiled():
+        """The step as compiled, from the shapes of its first call, which
+        holds no buffer; JAX answers the lowering and the compile from
+        what the first call left in memory, so no second program is
+        loaded, and the build record books nothing of it."""
+        if not avals:
+            raise RuntimeError("the step has not run yet, so its "
+                               "arguments' shapes are not known")
+        with trace.builds_suspended():
+            return jitted.lower(*avals[0]).compile()
+
     def scopes():
         """Which instruction of the step's compiled program belongs to
         which ``otpu_*`` scope and pass (``trace.scope_map`` of the
         optimised HLO text; ``trace.STEP_SCOPES`` is the vocabulary).
-        Lowers and compiles the step again from the shapes of its first
-        call, which holds no buffer; JAX answers both from what the
-        first call left in memory, so no second program is loaded (on
-        the v5e 0.1 s for OLMoE's step and 0.8 s for JoyAI's 8,563
-        instructions, the device's bytes in use unchanged: PR 37).  For
-        a reader of a profiler's trace, after the steps it traced:
-        ``step()`` never calls it."""
-        if not avals:
-            raise RuntimeError("scopes(): the step has not run yet, so "
-                               "its arguments' shapes are not known")
-        return trace.scope_map(jitted.lower(*avals[0]).compile().as_text())
+        Reads ``compiled()`` (on the v5e 0.1 s for OLMoE's step and 0.8 s
+        for JoyAI's 8,563 instructions, the device's bytes in use
+        unchanged: PR 37).  For a reader of a profiler's trace, after the
+        steps it traced: ``step()`` never calls it."""
+        return trace.scope_map(compiled().as_text())
+
+    def memory():
+        """What the step as compiled holds on a device, in bytes
+        (``memory_analysis()`` of ``compiled()``): its arguments, its
+        outputs, the outputs that alias a donated argument, the
+        temporaries, and the peak, which is what has to fit the chip
+        beside whatever else the process keeps there.  Asked for after
+        the measurement, like ``scopes()``: ``step()`` never calls it."""
+        m = compiled().memory_analysis()
+        return {k: int(getattr(m, k)) for k in MEMORY_FIELDS}
 
     step.jitted = jitted
     step.scopes = scopes
+    step.memory = memory
 
     def place(params, tokens, labels):
         """``(state, tokens, labels)`` on the mesh: the state is the
@@ -1255,6 +1273,14 @@ def scopes_of_built_steps() -> list:
     program's name and the instruction's.  It reads a whole program's
     text a step, so it is for after the measurement."""
     return [step.scopes() for step in list(_ran_steps)]
+
+
+def memory_of_built_steps() -> list:
+    """``step.memory()`` of every model step this process built, ran and
+    still holds, each with its program's name (``module``, as
+    ``scopes_of_built_steps()`` names it): for after the measurement."""
+    return [{"module": "jit_" + step.jitted.__name__, **step.memory()}
+            for step in list(_ran_steps)]
 
 
 def record_step_stats(aux) -> int:

@@ -26,6 +26,20 @@ _COUNTERS = (
     # otpu.coll.get/build/first_call spans cannot
     "device_slow_path", "device_program_builds",
     "device_program_first_call_us",
+    # the build record (runtime/trace.bind_builds), from JAX's own compile
+    # events and recorded by its listener alone, so only while something
+    # is built: the host microseconds the device path's own programs
+    # (``trace.own_program``: otpu_* and trace.OWN_PROGRAMS) spent being
+    # traced, lowered, and in the backend (a compile on a miss of the
+    # persistent cache, a load on a hit), each program's outermost phase
+    # once; their requests to the persistent cache and its hits; the
+    # backend events themselves (every program of every build site, a
+    # rebuild for new shapes too); and all three phases of everything
+    # else the process built (a caller's programs, eager operations)
+    "device_program_trace_us", "device_program_lower_us",
+    "device_program_backend_us", "device_program_cache_requests",
+    "device_program_cache_hits", "device_programs_compiled",
+    "device_other_build_us",
     # the datatype engine's device path (datatype/plan behind
     # mca/accelerator): pack_array / unpack_array calls, the bytes of
     # their packed streams, plans built (one a datatype and count, so
@@ -105,19 +119,15 @@ _COUNTERS = (
     # monotonic high-water so the counter plane stays append-only)
     "moe_dispatch_tokens", "moe_dropped_tokens", "moe_imbalance_max",
     # a public model's train step (parallel/train.py's model path):
-    # optimiser steps issued, the tokens and the routed token-slots
-    # (tokens x experts a token x layers) in them, and the fullest
+    # optimiser steps issued (tokens, routed slots, bias updates are each
+    # a constant of the configuration times this), and the fullest
     # expert's slots in any step read back so far (a high-water gauge,
     # read outside the step: ``train.record_step_stats``)
-    "train_steps", "train_tokens", "moe_token_slots",
-    "moe_max_expert_load",
-    # a model with a next-next-token module and a rank that holds a share
-    # of the routed experts: the module's tokens and the routers'
-    # balancing-bias updates in the steps issued; over the steps read
-    # back (``train_steps_read``), the slots that went to experts held
-    # here and to absent ones
-    "train_mtp_tokens", "moe_bias_updates", "train_steps_read",
-    "moe_local_slots", "moe_absent_slots",
+    "train_steps", "moe_max_expert_load",
+    # a rank that holds a share of the routed experts: over the steps
+    # read back (``train_steps_read``), the slots that went to experts
+    # held here and to absent ones
+    "train_steps_read", "moe_local_slots", "moe_absent_slots",
     # the experts' grouped matmuls made while steps were traced
     # (parallel/experts._grouped_matmul), forward or transposed, and those
     # of them made on the Pallas kernel (ops/grouped_matmul): the second
@@ -134,9 +144,6 @@ _COUNTERS = (
     # and went to the flash kernels, or their twins, unrepeated: the
     # second over the first is 1 for a grouped-query model, 0 for the rest
     "attn_built", "attn_shared_kv_built",
-    # a model with state-space layers: the tokens that went through one,
-    # a layer each (tokens x Mamba layers held), in the steps issued
-    "train_ssm_layer_tokens",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

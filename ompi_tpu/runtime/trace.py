@@ -41,6 +41,7 @@ timeline renders real cross-rank message arrows and
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
@@ -74,6 +75,9 @@ requests_enabled = False
 #: enumerates; every ``trace.span``/``instant`` call site uses one).
 CATEGORIES = {
     "boot": "instance boot path (coord connect, modex fence)",
+    "build": "a device program's build, a span a phase (build.trace, "
+             "build.lower, build.backend: a compile on a cache miss, a "
+             "load on a hit), from JAX's own compile events",
     "btl": "transport-layer wire operations (sendmsg, ring push)",
     "chaos": "injected-fault instants (ft/chaos)",
     "coll": "collective invocations (c_coll interposition)",
@@ -558,6 +562,168 @@ def bind_profiler() -> None:
 
         profiler_span = TraceAnnotation
         profiler_on = TraceAnnotation.is_enabled
+
+
+# -- a device program's own build -----------------------------------------
+
+#: The device path's jitted functions that are not named ``otpu_*``: the
+#: build record tells the program's own programs from a caller's by the
+#: name JAX's compile events carry, and a reader of a profiler's trace
+#: knows these by the names they have.  ``tests/test_build_record.py``
+#: walks the device path's sources and holds every ``jax.jit`` to the
+#: rule (:func:`own_program`).
+OWN_PROGRAMS = (
+    "reduce_stack", "combine2",                     # ops/pallas_reduce
+    "transpose_blocks",                             # ops/pallas_ddt
+    "gmm", "tgmm",                                  # ops/grouped_matmul
+    "flash_causal_forward", "attn_block_backward",  # ops/flash_attention
+    "rule_forward", "rule_backward",                # ops/gated_delta
+    "encode_int8", "decode_int8", "dequant_accumulate",  # ops/pallas_quant
+)
+
+#: JAX's events of one build, in order: (phase, the counter of the
+#: program's own).  Each comes as a scalar at entry and a duration at
+#: exit, with the function's name (``f`` while tracing, ``jit(f)`` after).
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("trace", "device_program_trace_us"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower", "device_program_lower_us"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend", "device_program_backend_us"),
+}
+#: the persistent cache's events carry no name: they belong to the
+#: event open on their thread
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_builds = threading.local()     # .open: this thread's open events,
+                                # outermost first; .suspended: a depth
+_bind_lock = threading.Lock()
+_spc_record = None              # spc.record once bound
+
+
+def own_program(fun_name: str) -> bool:
+    """Whether a compile event's ``fun_name`` names one of the device
+    path's programs: ``otpu_*`` or one of :data:`OWN_PROGRAMS`, bare (the
+    trace event) or as ``jit(<name>)`` (the other two)."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]
+    return fun_name.startswith("otpu_") or fun_name in OWN_PROGRAMS
+
+
+def bind_builds() -> None:
+    """Register the build record's listeners with ``jax.monitoring``,
+    once a process; bound where :func:`bind_profiler` is, so the base
+    layer stays jax-free.  JAX calls them only while it builds
+    something: a cached call reaches none."""
+    global _spc_record
+    if _spc_record is not None:
+        return
+    with _bind_lock:
+        if _spc_record is not None:
+            return
+        import jax.monitoring as mon
+
+        from ompi_tpu.runtime import spc
+
+        _spc_record = spc.record
+        mon.register_scalar_listener(_build_enter)
+        mon.register_event_listener(_build_event)
+        mon.register_event_duration_secs_listener(_build_exit)
+
+
+@contextlib.contextmanager
+def builds_suspended():
+    """``with trace.builds_suspended():`` books nothing of what this
+    thread builds inside: for a program compiled again only to be read
+    (``step.scopes()``, ``step.memory()``)."""
+    _builds.suspended = getattr(_builds, "suspended", 0) + 1
+    try:
+        yield
+    finally:
+        _builds.suspended -= 1
+
+
+def _build_enter(event, _value=None, **kw) -> None:
+    """Scalar listener: a phase opens on this thread."""
+    try:
+        if event in _BUILD_PHASES and not getattr(_builds, "suspended", 0):
+            # [event, name, cache requests, hits, load us]
+            _builds.__dict__.setdefault("open", []).append(
+                [event, str(kw.get("fun_name", "")), 0, 0, 0.0])
+    except Exception:       # never into JAX's compile path
+        pass
+
+
+def _build_event(event, **_kw) -> None:
+    """Plain-event listener: a request to the persistent cache, or a hit,
+    by the build open on this thread."""
+    try:
+        if event == _CACHE_REQUEST or event == _CACHE_HIT:
+            stack = getattr(_builds, "open", None)
+            if stack:
+                stack[-1][2 if event == _CACHE_REQUEST else 3] += 1
+    except Exception:
+        pass
+
+
+def _build_exit(event, secs=0.0, **_kw) -> None:
+    """Duration listener: a phase closes.  Only the outermost event of a
+    thread is booked: a ``jax.jit`` traced inside another's trace, or an
+    eager operation compiled there, lies inside the outer phase's seconds
+    already and hands it its cache requests."""
+    try:
+        stack = getattr(_builds, "open", None)
+        if not stack:
+            return
+        if event == _CACHE_LOAD:
+            stack[-1][4] += secs * 1e6
+            return
+        if event not in _BUILD_PHASES:
+            return
+        at = len(stack) - 1
+        while at >= 0 and stack[at][0] != event:
+            at -= 1
+        if at < 0:
+            return              # an exit whose entry was never seen
+        entry = stack[at]
+        del stack[at:]
+        if stack:
+            for i in (2, 3, 4):
+                stack[0][i] += entry[i]
+            return
+        _book_build(entry, secs)
+    except Exception:
+        pass
+
+
+def _book_build(entry: list, secs: float) -> None:
+    """One outermost phase into the counters and, while the ring is on,
+    one ``build`` span on the ring's clock: JAX's events come on
+    ``time.time()``, so the span ends now and starts ``secs`` earlier."""
+    event, name, requests, hits, load_us = entry
+    phase, counter = _BUILD_PHASES[event]
+    own = own_program(name)
+    if own:
+        _spc_record(counter, secs * 1e6)
+        if phase == "backend":
+            _spc_record("device_programs_compiled")
+        if requests:
+            _spc_record("device_program_cache_requests", requests)
+        if hits:
+            _spc_record("device_program_cache_hits", hits)
+    else:
+        _spc_record("device_other_build_us", secs * 1e6)
+    if enabled:
+        t_end = time.perf_counter_ns()
+        args = {"program": name, "own": own}
+        if phase == "backend" or requests:
+            args["cache"] = "hit" if hits and hits >= requests else "miss"
+            args["load_us"] = load_us
+        span("build." + phase, "build", t_end - int(secs * 1e9), t_end,
+             args=args)
 
 
 def wrap_coll_table(comm) -> None:

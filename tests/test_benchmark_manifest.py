@@ -235,25 +235,40 @@ CELLS = {
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
+# what a step no longer counts: each a constant of the configuration
+# times SPC train_steps (the slots read back hold the constants)
+PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
+                      "train_ssm_layer_tokens", "moe_bias_updates")
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
               "qwen3next-train-1chip")
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
+# the per-layer metrics of the build record (PR 53): read by their own
+# readers in the child, since a rehearsal is a ``--trace 0`` run
+BUILD_METRICS = ("compile.trace_s", "compile.lower_s",
+                 "compile.own_backend_s", "compile.cache_hit_share",
+                 "compile.own_programs", "compile.other_s")
+PEAK_METRIC = "step.hbm_peak_share"
+
 # the child: run_cell as the command calls it, but for the three
 # arguments it keeps for rehearsals.  What the program counts is read
 # around it: the programs coll/xla names as it builds them, and SPC
-# ``device_program_builds`` when the measured time starts and at the end.
+# ``device_program_builds`` when the measured time starts and at the end;
+# after the measurement, while the run still holds its steps, the build
+# record's metrics as their files and readers give them (against a v5e's
+# memory: the CPU has no row in the table of peaks).
 REHEARSAL = """
-import json, sys
+import json, os, sys
 sys.path[:0] = [{bench!r}, {repo!r}]
 import run
-from harness import protocol
+from harness import manifest, protocol
 from ompi_tpu.mca.coll import xla
 from ompi_tpu.runtime import spc
 
-programs, builds = [], []
+programs, builds, layer = [], [], {{}}
 name_of, measure = xla._program_name, protocol.measure
+bench_dir = os.path.join({root!r}, "benchmark")
 
 def named(coll, variant=None):
     programs.append(name_of(coll, variant))
@@ -261,7 +276,15 @@ def named(coll, variant=None):
 
 def measured(*args, **kw):
     builds.append(spc.read("device_program_builds"))
-    return measure(*args, **kw)
+    out = measure(*args, **kw)
+    ctx = {{"points": [], "run": {{"workload": {cell!r}}}, "trace": None,
+           "device_kind": "TPU v5 lite"}}
+    for name in {metrics!r}:
+        spec = manifest.metric_spec(name, bench_dir)
+        layer[name] = protocol.load_module(
+            "readers", spec["reader"], bench_dir).read(
+                ctx, spec.get("params", {{}}))
+    return out
 
 xla._program_name, protocol.measure = named, measured
 result = run.run_cell({cell!r}, seed=2147483999, seconds=0.3, trace=False,
@@ -272,6 +295,7 @@ print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                                   "moe_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
+print("layer " + json.dumps(layer))
 print("result " + json.dumps(result))
 """
 
@@ -368,7 +392,8 @@ def _rehearse(cell, root):
     env = _stage(cell, root)
     done = subprocess.run(
         [sys.executable, "-c", REHEARSAL.format(
-            bench=BENCH, repo=REPO, cell=cell, root=root)],
+            bench=BENCH, repo=REPO, cell=cell, root=root,
+            metrics=BUILD_METRICS + (PEAK_METRIC,))],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
     lines = done.stdout.splitlines()
@@ -380,6 +405,7 @@ def _rehearse(cell, root):
             "points": {p["name"]: p for p in tagged("point")},
             "run": tagged("run")[0], "counters": tagged("counters")[0],
             "programs": tagged("programs")[0], "builds": tagged("builds")[0],
+            "layer": tagged("layer")[0],
             "result": tagged("result")[0]}
 
 
@@ -423,6 +449,60 @@ def test_the_rehearsal_reports_the_cells_end_to_end_metrics(rehearsal):
     metrics = rehearsal["result"]["metrics"]
     assert set(metrics) == rehearsal["spec"]["metrics"]
     assert all(m["value"] > 0 for m in metrics.values())
+
+
+@every_cell
+def test_the_rehearsal_reads_the_build_record(rehearsal):
+    """The six ``compile.*`` metrics of the build record read something
+    in every cell: the own programs' three phases and the rest's took
+    time, no phase twice (together under the set-up they lie in), at
+    least one own program went through the backend, and the share of
+    cache hits is one.  A step cell reads its step's compiled peak as a
+    share of a chip's memory; a cell that builds no step reads none."""
+    layer, run = rehearsal["layer"], rehearsal["run"]
+    assert all(layer[m] is not None for m in BUILD_METRICS), layer
+    phases = [layer["compile." + m] for m in
+              ("trace_s", "lower_s", "own_backend_s", "other_s")]
+    assert all(s > 0 for s in phases) and sum(phases) < run["setup_s"]
+    assert layer["compile.own_programs"] >= 1
+    assert 0 <= layer["compile.cache_hit_share"] <= 100
+    # the harness's own count is of the whole process
+    assert layer["compile.own_backend_s"] <= run["compile_s"]
+    if rehearsal["cell"].endswith("-train-1chip"):
+        assert 0 < layer[PEAK_METRIC] < 100
+        # one step, so the first call's seconds hold its three phases
+        first = rehearsal["counters"]["device_program_first_call_us"] / 1e6
+        assert sum(phases[:3]) < first
+    else:
+        assert layer[PEAK_METRIC] is None
+
+
+@of_cells("rank1-blocking-xl", "rank1-ddt")
+def test_programs_no_first_call_counts_are_counted_as_built(rehearsal):
+    """The cells whose programs are built by module-level jits and the
+    datatype engine, which ``device_program_builds`` never saw."""
+    assert rehearsal["layer"]["compile.own_programs"] > 0
+    if rehearsal["cell"] == "rank1-blocking-xl":
+        assert rehearsal["counters"]["device_program_builds"] == 0
+
+
+def test_the_build_metrics_are_entries_of_the_manifest(mf, real):
+    """Appended, every cell under the six, the step cells under the
+    seventh, each moving what the issue says."""
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    cells = [w["name"] for w in real["workloads"]]
+    for name in BUILD_METRICS:
+        m = by_name[name]
+        assert (m["workloads"], m["moves"], m["source"]) == (
+            cells, "setup_s", "program_counter")
+        assert m["layer"] == by_name["compile.backend_s"]["layer"]
+    peak = by_name[PEAK_METRIC]
+    assert peak["workloads"] == [c for c in cells
+                                 if c.endswith("-train-1chip")]
+    assert (peak["moves"], peak["better"]) == ("small_msg_us", "lower")
+    assert peak["layer"] == by_name["train.mfu"]["layer"]
+    assert [m["name"] for m in real["per_layer"][-7:]] \
+        == list(BUILD_METRICS) + [PEAK_METRIC]
 
 
 @of_cells(*CALL_CELLS)
@@ -517,26 +597,26 @@ def test_a_step_counts_a_collective_a_bucket(rehearsal):
 @of_cells("olmoe-train-1chip")
 def test_a_train_step_counts_its_tokens_and_moves_no_collective(rehearsal):
     """The step's ``psum`` passes no ``world.*_array`` slot; the
-    trainer's own counters follow from the steps issued (tokens, routed
-    slots: tokens x 2 experts x 2 layers at the rehearsal's widths), the
-    fullest expert holds at least the mean load, and the step's program
-    is the one program built, in set-up."""
+    trainer counts the steps issued and nothing that is a constant times
+    them (tokens; routed slots: tokens x 2 experts x 2 layers at the
+    rehearsal's widths), the fullest expert holds at least the mean
+    load, and the step's program is the one program built, in set-up."""
     (row,), c = rehearsal["points"].values(), rehearsal["counters"]
     tokens = TINY_TRAIN["micro_batch"] * TINY_TRAIN["seq_len"]
     assert rehearsal["run"]["spc_device_collectives"] == 0
     assert row["collectives_per_call"] == 0 and row["tolerance"]["why"]
     assert c["train_steps"] > row["k"] * row["windows"]
-    assert c["train_tokens"] == c["train_steps"] * tokens
-    assert c["moe_token_slots"] == c["train_tokens"] * 2 * 2
+    assert not set(PER_STEP_CONSTANTS) & set(c)
     assert c["moe_max_expert_load"] >= tokens * 2 / 8
     assert rehearsal["builds"] == [1, 1]
 
 
 @of_cells("joyai-train-1chip")
 def test_a_share_step_counts_its_slots_here_and_elsewhere(rehearsal):
-    """The trainer's counters on one chip's share: the module's tokens
-    and the routers' bias updates follow from the steps issued (2 sparse
-    layers and the module at the rehearsal's widths); over the steps read
+    """The trainer's counters on one chip's share: the steps issued,
+    and no constant times them (the module's tokens, the routers' bias
+    updates: 2 sparse layers and the module at the rehearsal's widths,
+    which the slots read back hold); over the steps read
     back every slot went to a held expert or to an absent one; the
     step's program is the one program built, in set-up."""
     (row,), c = rehearsal["points"].values(), rehearsal["counters"]
@@ -544,10 +624,7 @@ def test_a_share_step_counts_its_slots_here_and_elsewhere(rehearsal):
     assert row["kind"] == "train_step_share" and row["tolerance"]["why"]
     assert rehearsal["run"]["spc_device_collectives"] == 0
     assert c["train_steps"] > row["k"] * row["windows"]
-    assert c["train_tokens"] == c["train_steps"] * tokens
-    assert c["train_mtp_tokens"] == c["train_tokens"]
-    assert c["moe_token_slots"] == c["train_tokens"] * 4 * 3
-    assert c["moe_bias_updates"] == c["train_steps"] * 3
+    assert not set(PER_STEP_CONSTANTS) & set(c)
     assert c["train_steps_read"] >= 3
     assert c["moe_local_slots"] + c["moe_absent_slots"] \
         == c["train_steps_read"] * tokens * 4 * 3
@@ -557,10 +634,11 @@ def test_a_share_step_counts_its_slots_here_and_elsewhere(rehearsal):
 @of_cells("nemotron3-train-1chip")
 def test_a_hybrid_step_counts_its_state_space_tokens(rehearsal):
     """The trainer's counters on one chip's share of a hybrid model, by
-    the kind that reads everything from the kit: the state-space
-    layers' tokens and the routers' bias updates follow from the steps
-    issued (3 Mamba-2 and 3 expert layers at the rehearsal's widths, no
-    next-n module); over the steps read back every slot went to a held
+    the kind that reads everything from the kit: the steps issued, and
+    no constant times them (the state-space layers' tokens, the routers'
+    bias updates: 3 Mamba-2 and 3 expert layers at the rehearsal's
+    widths, no next-n module); over the steps read back every slot went
+    to a held
     expert or to an absent one; the step's program is the one program
     built, in set-up."""
     (row,), c = rehearsal["points"].values(), rehearsal["counters"]
@@ -568,11 +646,7 @@ def test_a_hybrid_step_counts_its_state_space_tokens(rehearsal):
     assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
     assert rehearsal["run"]["spc_device_collectives"] == 0
     assert c["train_steps"] > row["k"] * row["windows"]
-    assert c["train_tokens"] == c["train_steps"] * tokens
-    assert c["train_ssm_layer_tokens"] == c["train_tokens"] * 3
-    assert "train_mtp_tokens" not in c or c["train_mtp_tokens"] == 0
-    assert c["moe_token_slots"] == c["train_tokens"] * 3 * 3
-    assert c["moe_bias_updates"] == c["train_steps"] * 3
+    assert not set(PER_STEP_CONSTANTS) & set(c)
     assert c["train_steps_read"] >= 3
     assert c["moe_local_slots"] + c["moe_absent_slots"] \
         == c["train_steps_read"] * tokens * 3 * 3
@@ -582,9 +656,10 @@ def test_a_hybrid_step_counts_its_state_space_tokens(rehearsal):
 @of_cells("lfm2-train-1chip")
 def test_a_typed_step_counts_its_routers_and_its_slots(rehearsal):
     """The trainer's counters on one chip's share of a ``layer_types``
-    model, by the kind that reads everything from the kit: the routers'
-    bias updates follow from the steps issued (5 sparse layers of the 6
-    held, no next-n module, no state-space layer); over the steps read
+    model, by the kind that reads everything from the kit: the steps
+    issued, and no constant times them (the routers' bias updates: 5
+    sparse layers of the 6 held, no next-n module, no state-space
+    layer); over the steps read
     back every slot went to a held expert or to an absent one; the
     step's program is the one program built, in set-up."""
     (row,), c = rehearsal["points"].values(), rehearsal["counters"]
@@ -593,11 +668,7 @@ def test_a_typed_step_counts_its_routers_and_its_slots(rehearsal):
     assert row["name"] == "train_step.lfm2.bf16.2x8192"
     assert rehearsal["run"]["spc_device_collectives"] == 0
     assert c["train_steps"] > row["k"] * row["windows"]
-    assert c["train_tokens"] == c["train_steps"] * tokens
-    assert not c.get("train_ssm_layer_tokens") \
-        and not c.get("train_mtp_tokens")
-    assert c["moe_token_slots"] == c["train_tokens"] * 2 * 5
-    assert c["moe_bias_updates"] == c["train_steps"] * 5
+    assert not set(PER_STEP_CONSTANTS) & set(c)
     assert c["train_steps_read"] >= 3
     assert c["moe_local_slots"] + c["moe_absent_slots"] \
         == c["train_steps_read"] * tokens * 2 * 5
@@ -618,10 +689,7 @@ def test_a_delta_rule_step_counts_its_routers_and_its_slots(rehearsal):
     assert row["name"] == "train_step.qwen3next.bf16.1x16384"
     assert rehearsal["run"]["spc_device_collectives"] == 0
     assert c["train_steps"] > row["k"] * row["windows"]
-    assert c["train_tokens"] == c["train_steps"] * tokens
-    assert not c.get("train_ssm_layer_tokens") \
-        and not c.get("train_mtp_tokens") and not c.get("moe_bias_updates")
-    assert c["moe_token_slots"] == c["train_tokens"] * 4 * 4
+    assert not set(PER_STEP_CONSTANTS) & set(c)
     assert c["train_steps_read"] >= 3
     assert c["moe_local_slots"] + c["moe_absent_slots"] \
         == c["train_steps_read"] * tokens * 4 * 4

@@ -289,13 +289,13 @@ def stepped():
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     auxes = []
-    if "moe_bias_updates" not in spc.counters():
+    if "train_steps" not in spc.counters():
         spc.init()
-    before = spc.read("moe_bias_updates")
+    before = spc.read("train_steps")
     for tokens, labels in batches:
         state, aux = step(state, tokens, labels)
         auxes.append(jax.device_get(aux))
-    counted = spc.read("moe_bias_updates") - before
+    counted = spc.read("train_steps") - before
     with jax.default_matmul_precision("highest"):
         want = ref.train_steps(params, batches, F32)
     return dict(params=params, batches=batches, state=state, auxes=auxes,
@@ -315,7 +315,9 @@ def test_three_steps_are_the_references(stepped):
                      - np.asarray(train._leaf(params, path)))
         assert off.max() <= 3 * F32.lr, name
         assert np.mean(off > 0.01 * 3 * F32.lr) <= 1e-3, name
-    assert stepped["counted"] == 3 * 5          # steps x routers
+    # the steps issued; the bias updates in them are the configuration's
+    # constant (a router each) times it
+    assert stepped["counted"] == 3 and F32.n_routers == 5
 
 
 def test_one_step_reports_the_references_loads_and_gradients(stepped):

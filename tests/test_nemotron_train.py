@@ -271,13 +271,13 @@ def stepped():
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     auxes = []
-    if "train_ssm_layer_tokens" not in spc.counters():
+    if "train_steps" not in spc.counters():
         spc.init()
-    before = spc.read("train_ssm_layer_tokens")
+    before = spc.read("train_steps")
     for tokens, labels in batches:
         state, aux = step(state, tokens, labels)
         auxes.append(jax.device_get(aux))
-    counted = spc.read("train_ssm_layer_tokens") - before
+    counted = spc.read("train_steps") - before
     with jax.default_matmul_precision("highest"):
         want = ref.train_steps(params, batches, F32)
     return dict(params=params, batches=batches, state=state, auxes=auxes,
@@ -320,7 +320,11 @@ def test_three_steps_are_the_references(stepped):
                      - np.asarray(train._leaf(params, path)))
         assert off.max() <= 3 * F32.lr, name
         assert np.mean(off > 0.01 * 3 * F32.lr) <= 1e-3, name
-    assert stepped["counted"] == 3 * 64 * 3        # steps x tokens x M layers
+    # the steps issued; the state-space layers' tokens in them are the
+    # configuration's constant (tokens x M layers held) times it
+    assert stepped["counted"] == 3
+    assert F32.micro_batch * F32.seq_len * F32.pattern_here.count("M") \
+        == 64 * 3
 
 
 def test_one_step_reports_the_references_loads_and_gradients(stepped):
