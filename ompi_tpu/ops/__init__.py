@@ -7,6 +7,14 @@ and MXU (attention blocks).  The MCA ``op`` framework
 (``ompi_tpu/mca/op/``) selects these when running on a TPU backend and
 falls back to plain XLA (jnp) elsewhere, mirroring the reference's
 runtime CPU-capability dispatch (``op_avx_component.c``).
+
+A public model's train step (``parallel/``) chooses its own kernels the
+same way, from what it can see (``interpret`` false: a TPU, and a shape
+with tiles), each beside the XLA form that is its oracle:
+``flash_attention`` (causal attention, forward and backward),
+``grouped_matmul`` (the experts' products), ``gated_delta`` (the chunked
+gated delta rule) and ``causal_conv`` (the DeltaNet convolution and its
+silu).
 """
 from ompi_tpu.ops.pallas_reduce import (  # noqa: F401
     combine2,

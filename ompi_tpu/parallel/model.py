@@ -585,16 +585,16 @@ def _unit_lower_inverse_bwd(t, ct):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _count_rule(on_kernel: bool) -> None:
-    """SPC ``gdn_rule_built``: the delta rule's passes made while steps
-    were traced (``gated_delta_chunked``'s XLA form, whose backward pass
-    is autodiff's and not seen here, or the kernel path's forward and
-    backward rules: JAX traces a pass more than once);
-    ``gdn_rule_kernel_built``: those of them made on the Pallas kernels.
-    What reads is the second over the first."""
-    spc.record("gdn_rule_built", 1)
+def _count_gdn(part: str, on_kernel: bool) -> None:
+    """SPC ``gdn_<part>_built``: the passes of a Gated DeltaNet layer's
+    ``part`` (``rule``, ``conv``) made while steps were traced (the XLA
+    form, whose backward pass is autodiff's and not seen here, or the
+    kernel path's forward and backward rules: JAX traces a pass more
+    than once); ``gdn_<part>_kernel_built``: those of them made on the
+    Pallas kernels.  What reads is the second over the first."""
+    spc.record(f"gdn_{part}_built", 1)
     if on_kernel:
-        spc.record("gdn_rule_kernel_built", 1)
+        spc.record(f"gdn_{part}_kernel_built", 1)
 
 
 def _kernel_views(arrays, hk, hv):
@@ -621,7 +621,7 @@ def _kernel_rule(arrays, g, beta, chunk, hk, unit):
     by XLA."""
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_rule(True)
+    _count_gdn("rule", True)
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     return rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
                                     at=at, unit=unit)
@@ -630,7 +630,7 @@ def _kernel_rule(arrays, g, beta, chunk, hk, unit):
 def _kernel_rule_fwd(arrays, g, beta, chunk, hk, unit):
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_rule(True)
+    _count_gdn("rule", True)
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     o, kept = rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
                                        at=at, unit=unit, states=True)
@@ -640,7 +640,7 @@ def _kernel_rule_fwd(arrays, g, beta, chunk, hk, unit):
 def _kernel_rule_bwd(chunk, hk, unit, res, do):
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_rule(True)
+    _count_gdn("rule", True)
     arrays, g, beta, kept = res
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     *d_qkv, dg, dbeta = rule_kernel.rule_backward(
@@ -701,7 +701,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int, interpret: bool = True):
         flat = lambda t: t.reshape(bt, s, -1)
         return _kernel_rule((flat(q), flat(k), flat(v)), g, beta, chunk, hk,
                             None).reshape(v.shape)
-    _count_rule(False)
+    _count_gdn("rule", False)
     _f32 = lambda eq, one, two: _contract(eq, one, two, jnp.float32)
     pad = -s % chunk
     if pad:
@@ -754,6 +754,94 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int, interpret: bool = True):
     return o.transpose(1, 0, 4, 2, 3, 5).reshape(bt, s + pad, hv, dv)[:, :s]
 
 
+@jax.custom_vjp
+def _kernel_conv(x, w):
+    """The causal depthwise convolution and its silu on the Pallas
+    kernels (``ops/causal_conv``): ``silu(sum_j w[j] x[t - (taps - 1) +
+    j])`` (b, s, c) of x (b, s, c) and the taps w (taps, c), float32.
+    Only x and w are kept for the backward kernel, which makes the
+    pre-activation again, writes dx and sums dw in one pass over x and
+    the cotangent."""
+    from ompi_tpu.ops import causal_conv
+
+    _count_gdn("conv", True)
+    return causal_conv.conv_forward(x, w)
+
+
+def _kernel_conv_fwd(x, w):
+    from ompi_tpu.ops import causal_conv
+
+    _count_gdn("conv", True)
+    return causal_conv.conv_forward(x, w), (x, w)
+
+
+def _kernel_conv_bwd(res, dy):
+    from ompi_tpu.ops import causal_conv
+
+    _count_gdn("conv", True)
+    return causal_conv.conv_backward(*res, dy)
+
+
+_kernel_conv.defvjp(_kernel_conv_fwd, _kernel_conv_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _kernel_conv_rule(x, w, g, beta, chunk, hk, unit):
+    """The convolution and the rule behind it, both on their kernels, as
+    one rule of autodiff: (the convolution's [q | k | v] (b, s, c), the
+    rule's o) as ``_kernel_conv(x, w)`` and ``_kernel_rule`` of that one
+    array make them.  What differs is what a backward pass keeps: x, w,
+    g, beta and the rule's states and inverses, not [q | k | v], which
+    the backward rule makes again by a second ``conv_forward`` in front
+    of the rule's backward kernel.  Kept, as ``_kernel_rule`` after
+    ``_kernel_conv`` keeps it, it lives from a checkpointed layer's
+    recomputed pass through the rule's backward kernel, 0.54 GB at
+    16,384 positions: Qwen3-Next's step then compiles to a peak of 15.91
+    GB of a v5e's 16 and this way to 15.64, for 1.6 ms a layer on the
+    chip (PR 54)."""
+    with jax.named_scope("otpu_gdn_conv"):
+        qkv = _kernel_conv(x, w)
+    with jax.named_scope("otpu_gdn_rule"):
+        return qkv, _kernel_rule((qkv,), g, beta, chunk, hk, unit)
+
+
+def _kernel_conv_rule_fwd(x, w, g, beta, chunk, hk, unit):
+    with jax.named_scope("otpu_gdn_conv"):
+        qkv = _kernel_conv(x, w)
+    with jax.named_scope("otpu_gdn_rule"):
+        o, (_, _, _, kept) = _kernel_rule_fwd((qkv,), g, beta, chunk, hk,
+                                              unit)
+    return (qkv, o), (x, w, g, beta, kept)
+
+
+def _kernel_conv_rule_bwd(chunk, hk, unit, res, cts):
+    x, w, g, beta, kept = res
+    d_seen, do = cts
+    with jax.named_scope("otpu_gdn_conv"):
+        # behind the cotangent: the compiler would else take the
+        # recomputed pass's call for this one and keep its result
+        x, do = jax.lax.optimization_barrier((x, do))
+        qkv = _kernel_conv(x, w)
+    with jax.named_scope("otpu_gdn_rule"):
+        (d_qkv,), dg, dbeta = _kernel_rule_bwd(
+            chunk, hk, unit, ((qkv,), g, beta, kept), do)
+    with jax.named_scope("otpu_gdn_conv"):
+        return (*_kernel_conv_bwd((x, w), d_qkv + d_seen), dg, dbeta)
+
+
+_kernel_conv_rule.defvjp(_kernel_conv_rule_fwd, _kernel_conv_rule_bwd)
+
+
+def _conv_on_kernels(interpret, taps, c, s) -> bool:
+    """Whether the convolution runs on the Pallas kernels: where Mosaic
+    compiles (``interpret`` false: a TPU) and the shape has tiles."""
+    if interpret:
+        return False
+    from ompi_tpu.ops import causal_conv
+
+    return causal_conv.supported(taps, c, s)
+
+
 #: what the delta rule's L2 norms add under the root (``layers.l2norm``'s)
 L2NORM_EPS = 1e-6
 
@@ -774,7 +862,12 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True):
     ``rmsnorm over each head of o * gain * silu(z)`` (the gate behind
     the gain); ``y W_out``.  Everything between the two large
     projections is float32.  The sequence is never reset inside a packed
-    row.  Where the rule runs on its Pallas kernels (``_rule_on_kernels``)
+    row.  Where Mosaic compiles (``interpret`` false: a TPU) and the
+    width is whole tiles of lanes (``_conv_on_kernels``) the convolution
+    and its silu run in Pallas kernels that read and write each array
+    once a pass (``_kernel_conv``); everywhere else the lines here, which
+    are the kernels' oracle.  Where the rule runs on its Pallas kernels
+    (``_rule_on_kernels``)
     they read q, k and v where the convolution left them, one array, and
     put q's and k's rows at unit length themselves (``_kernel_rule``):
     a 4D view of q, k or v costs XLA two relayouts of it a pass.
@@ -797,23 +890,35 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True):
                   for cols in (w[:, :2 * key + val], w[:, 2 * key + val:]))
         ba = jnp.dot(n, p["ba_proj"], precision=jax.lax.Precision.HIGHEST
                      ).reshape(b, s, 2, hv)
-    with jax.named_scope("otpu_gdn_conv"):
-        taps = p["conv_w"].shape[0]
-        padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
-        qkv = jax.nn.silu(sum(padded[:, j:j + s] * p["conv_w"][j]
-                              for j in range(taps)))
     with jax.named_scope("otpu_gdn_rule"):
         beta = jax.nn.sigmoid(ba[:, :, 0])
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, :, 1]
                                                    + p["dt_bias"])
+    taps, unit = p["conv_w"].shape[0], (L2NORM_EPS, dk ** -0.5)
+    conv_on = _conv_on_kernels(interpret, taps, qkv.shape[2], s)
+    rule_on = _rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk,
+                               s) and 2 * hk % (hv // hk) == 0
+    if conv_on and rule_on:
+        qkv, o = _kernel_conv_rule(qkv, p["conv_w"], g, beta,
+                                   cfg.chunk_size, hk, unit)
+    else:
+        with jax.named_scope("otpu_gdn_conv"):
+            if conv_on:
+                qkv = _kernel_conv(qkv, p["conv_w"])
+            else:
+                _count_gdn("conv", False)
+                padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
+                qkv = jax.nn.silu(sum(padded[:, j:j + s] * p["conv_w"][j]
+                                      for j in range(taps)))
+    with jax.named_scope("otpu_gdn_rule"):
         heads = lambda t, n, width: t.reshape(b, s, n, width)
-        if _rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk, s) \
-                and 2 * hk % (hv // hk) == 0:
+        if rule_on:
             # the kernels read q, k and v where the convolution left them
             # and norm q and k themselves: only the first head, which the
             # step reports, is cut out and normed here
-            o = heads(_kernel_rule((qkv,), g, beta, cfg.chunk_size, hk,
-                                   (L2NORM_EPS, dk ** -0.5)), hv, dv)
+            if not conv_on:
+                o = _kernel_rule((qkv,), g, beta, cfg.chunk_size, hk, unit)
+            o = heads(o, hv, dv)
             q, k, v = (heads(qkv[..., first:first + width], 1, width)
                        for first, width in ((0, dk), (key, dk), (2 * key, dv)))
             q, k = l2norm(q, L2NORM_EPS) * dk ** -0.5, l2norm(k, L2NORM_EPS)

@@ -138,6 +138,11 @@ _COUNTERS = (
     # kernel path's forward and backward rules), and those of them made
     # on the Pallas kernels (ops/gated_delta): the second over the first
     "gdn_rule_built", "gdn_rule_kernel_built",
+    # the DeltaNet convolution's passes made while steps were traced
+    # (parallel/model.gated_delta_net: the XLA lines' forward, or the
+    # kernel path's forward and backward rules), and those of them made
+    # on the Pallas kernels (ops/causal_conv): the second over the first
+    "gdn_conv_built", "gdn_conv_kernel_built",
     # the causal attention passes made while steps were traced
     # (parallel/model.causal_flash_attention's forward and backward
     # rules), and those of them whose k and v came with fewer heads than q

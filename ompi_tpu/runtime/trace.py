@@ -578,6 +578,7 @@ OWN_PROGRAMS = (
     "gmm", "tgmm",                                  # ops/grouped_matmul
     "flash_causal_forward", "attn_block_backward",  # ops/flash_attention
     "rule_forward", "rule_backward",                # ops/gated_delta
+    "conv_forward", "conv_backward",                # ops/causal_conv
     "encode_int8", "decode_int8", "dequant_accumulate",  # ops/pallas_quant
 )
 

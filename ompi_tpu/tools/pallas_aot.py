@@ -344,6 +344,21 @@ def cases(mesh1d, mesh2d):
 
     case("qwen3next_gdn_rule_forward", lambda: gdn_rule(False))
     case("qwen3next_gdn_rule_backward", lambda: gdn_rule(True))
+    # the DeltaNet convolution and its silu (``model._kernel_conv``:
+    # ``ops/causal_conv``'s two kernels) at the same cell's shape: 4 taps
+    # over the (1, 16384, 8192) float32 [q | k | v]
+    def gdn_conv(backward):
+        from ompi_tpu.parallel import model
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        conv = model._kernel_conv
+        if backward:
+            conv = jax.grad(lambda *a: jnp.sum(model._kernel_conv(*a)),
+                            (0, 1))
+        return jax.jit(conv), (rep(1, 16384, 8192), rep(4, 8192))
+
+    case("qwen3next_gdn_conv_forward", lambda: gdn_conv(False))
+    case("qwen3next_gdn_conv_backward", lambda: gdn_conv(True))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),

@@ -501,8 +501,26 @@ def test_the_build_metrics_are_entries_of_the_manifest(mf, real):
                                  if c.endswith("-train-1chip")]
     assert (peak["moves"], peak["better"]) == ("small_msg_us", "lower")
     assert peak["layer"] == by_name["train.mfu"]["layer"]
-    assert [m["name"] for m in real["per_layer"][-7:]] \
+    assert [m["name"] for m in real["per_layer"][-8:-1]] \
         == list(BUILD_METRICS) + [PEAK_METRIC]
+
+
+def test_the_convolutions_kernel_share_is_an_entry_of_the_manifest(real):
+    """Appended behind them (PR 54): a data file on ``program_counter``
+    in the one cell whose model has a DeltaNet convolution, under the
+    layer and the end-to-end metric of the rule's ``gdn.kernel_share``."""
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    conv, rule = by_name["gdn.conv_kernel_share"], by_name["gdn.kernel_share"]
+    assert real["per_layer"][-1] is conv
+    assert {k: v for k, v in conv.items() if k != "name"} \
+        == {k: v for k, v in rule.items() if k != "name"}
+    assert conv["workloads"] == ["qwen3next-train-1chip"]
+    with open(os.path.join(BENCH, "metrics", "gdn.conv_kernel_share.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["params"]) == ("program_counter", {
+        "name": "gdn_conv_kernel_built", "over": "gdn_conv_built",
+        "scale": 100})
 
 
 @of_cells(*CALL_CELLS)

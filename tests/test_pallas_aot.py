@@ -356,9 +356,9 @@ def test_lfm2_train_step_aot_compiles_from_the_cells_configuration(
 def qwen3next_rows():
     """One child for the Qwen3-Next-80B-A3B cases: attention's two kernels
     at a head width of 256 alone and the whole step of the cell's own
-    configuration file, and the delta rule's two kernels at the cell's
-    shape, for one v5e device (about 2 min of the 600: the step's 16,384
-    positions)."""
+    configuration file, and the delta rule's and the DeltaNet
+    convolution's two kernels each at the cell's shape, for one v5e
+    device (about 2 min of the 600: the step's 16,384 positions)."""
     return _rows_with_texts("qwen3next_")
 
 
@@ -404,7 +404,8 @@ def test_qwen3next_train_step_aot_compiles_from_the_cells_configuration(
               re.findall(r"otpu_gdn\w*", path)}
     assert scopes == {"otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv",
                       "otpu_gdn_rule", "otpu_gdn_norm",
-                      "otpu_gdn_rule_fwd", "otpu_gdn_rule_bwd"}
+                      "otpu_gdn_rule_fwd", "otpu_gdn_rule_bwd",
+                      "otpu_gdn_conv_fwd", "otpu_gdn_conv_bwd"}
     rule = [(line, path) for line, path in op_paths(row)
             if "/otpu_gdn_rule/" in path]
     kernels = sorted(path.split("jit(otpu_train_step)/")[1]
@@ -415,6 +416,44 @@ def test_qwen3next_train_step_aot_compiles_from_the_cells_configuration(
         ("transpose(jvp(otpu_layers))", False, "otpu_gdn_rule_bwd"),
         ("transpose(jvp(otpu_layers))", True, "otpu_gdn_rule_fwd")], kernels
     assert not [line for line, _ in rule if " while(" in line]
+    # the convolution's kernels (PR 54) under ``otpu_gdn_conv``: forward in
+    # the forward and the recomputed pass, and once more in front of the
+    # rule's backward kernel, where [q | k | v] is made again and not kept
+    # (the compiler may not take the recomputed pass's call for it)
+    conv = sorted(path.split("jit(otpu_train_step)/")[1]
+                  for line, path in op_paths(row)
+                  if "/otpu_gdn_conv/" in path and " custom-call(" in line)
+    assert [(k.split("/")[0], "rematted_computation" in k,
+             k.split("/")[-2]) for k in conv] == [
+        ("jvp(otpu_layers)", False, "otpu_gdn_conv_fwd"),
+        ("transpose(jvp(otpu_layers))", False, "otpu_gdn_conv_bwd"),
+        ("transpose(jvp(otpu_layers))", False, "otpu_gdn_conv_fwd"),
+        ("transpose(jvp(otpu_layers))", True, "otpu_gdn_conv_fwd")], conv
+
+
+def test_the_deltanet_convolution_aot_compiles_at_the_cells_shape(
+        qwen3next_rows):
+    """``model._kernel_conv`` where Mosaic compiles, 4 taps over the (1,
+    16384, 8192) float32 [q | k | v]: the forward is one kernel call and
+    nothing beside it (no padded copy, no relayout: a ``fusion`` or a
+    ``copy`` would be one), its gradient the backward kernel alone, and
+    neither holds more than its operands and results (x and y, 1.07 GB;
+    x, dy and dx, 1.61 GB).  All arithmetic is float32."""
+    fwd = qwen3next_rows["qwen3next_gdn_conv_forward"]
+    bwd = qwen3next_rows["qwen3next_gdn_conv_backward"]
+    for row, arrays in ((fwd, 2), (bwd, 3)):
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        ops = row["entry_ops"]
+        assert ops.get("custom-call") == 1, ops
+        assert not {"fusion", "copy", "pad", "while"} & set(ops), ops
+        assert row["peak_bytes"] < arrays * 4 * 16384 * 8192 + (1 << 20)
+    with open(fwd["hlo"], encoding="utf-8") as f:
+        bodies = _kernel_bodies(f.read(), "otpu_gdn_conv_")
+    with open(bwd["hlo"], encoding="utf-8") as f:
+        bodies.update(_kernel_bodies(f.read(), "otpu_gdn_conv_"))
+    assert sorted(bodies) == ["otpu_gdn_conv_bwd", "otpu_gdn_conv_fwd"]
+    for name, text in bodies.items():
+        assert "xf32>" in text and "bf16" not in text, name
 
 
 def test_the_delta_rule_aot_compiles_at_the_cells_shape(qwen3next_rows):
