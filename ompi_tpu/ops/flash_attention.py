@@ -19,6 +19,18 @@ index maps hand it the block of the key-value head its group shares
 Nothing is repeated in HBM; with as many key-value heads as query heads
 the maps are a head's own.
 
+A static ``window`` (None: plain causal attention, and then every grid,
+index map and kernel body is what it was without the argument) makes
+both kernels sliding-window attention: key j is visible to query i iff 0
+<= i - j < ``window``, a whole number w of blocks.  q block i then meets
+kv blocks max(0, i - w) .. i and no other: the diagonal one under the
+triangular mask as ever, the far one (i - w) under its mirror (key
+column c visible to query row r iff c > r), those between under none.
+The forward's grid holds the w + 1 kv tiles a q tile can reach, its index
+map starting at the far tile; the backward's walk
+(``parallel/model._window_pairs``) leaves out the pairs out of reach and
+the kernel tells the far pair from ``ij`` and w.
+
 Scores compute in float32 on the MXU via ``preferred_element_type``.
 Ring attention's block update (``parallel/flagship.ring_attention``) is
 plain ``jnp`` on every platform and no kernel of this module.
@@ -47,7 +59,7 @@ BWD_STRIP = 256
 BWD_VMEM_LIMIT = 64 << 20
 
 
-def _bwd_block_kernel(scale, strip, rep, ij_ref, q_ref, k_ref, v_ref,
+def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
                       dqo_ref, dko_ref, dvo_ref):
     """One q tile of block i of one query head against kv block j of the
@@ -58,7 +70,9 @@ def _bwd_block_kernel(scale, strip, rep, ij_ref, q_ref, k_ref, v_ref,
     both operands.  The ``rep`` query heads of a group are consecutive
     grid steps on one ``dk`` / ``dv`` block, which stays in VMEM from
     the group's first tile, where it takes the incoming accumulator, to
-    its last: the group's sum is made in float32, here."""
+    its last: the group's sum is made in float32, here.  ``far_by``
+    (None without a window) is the window in blocks: the pair whose
+    blocks lie that far apart is the far one, masked the other way."""
     t = pl.program_id(1)
     diagonal = ij_ref[0] == ij_ref[1]
     tile = q_ref.shape[1]
@@ -79,35 +93,52 @@ def _bwd_block_kernel(scale, strip, rep, ij_ref, q_ref, k_ref, v_ref,
 
     dqo_ref[...] = dq_ref[...]
 
-    def part(c, lo, n, masked):
+    def part(c, lo, n, masked, far=False):
         """kv positions lo .. lo + n of tile c against the q tile's
-        positions from lo on."""
+        positions from lo on; of the far pair's tile, against those
+        before lo + n (the diagonal strip's mirror: the rest see none of
+        it), kv position lo + a visible to q position b iff lo + a > b."""
         rows = pl.ds(c * tile + lo, n)
+        qs = slice(0, lo + n) if far else slice(lo, None)
         k, v = k_ref[0, rows, :], v_ref[0, rows, :]
-        q, do = q_ref[0, lo:, :], do_ref[0, lo:, :]
+        q, do = q_ref[0, qs, :], do_ref[0, qs, :]
         s = dot(k, q, nt_dims) * scale                      # (kv, q)
         if masked:
             at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                        axis)
-            s = jnp.where(at(0) <= at(1), s, -jnp.inf)
-        p = jnp.exp(s - lse_ref[0, :, lo:])
+            s = jnp.where(at(0) + lo > at(1) if far else at(0) <= at(1), s,
+                          -jnp.inf)
+        p = jnp.exp(s - lse_ref[0, :, qs])
         dvo_ref[0, rows, :] += dot(p.astype(do.dtype), do, nn_dims)
-        ds = (p * (dot(v, do, nt_dims) - delta_ref[0, :, lo:])
+        ds = (p * (dot(v, do, nt_dims) - delta_ref[0, :, qs])
               * scale).astype(q.dtype)
         dko_ref[0, rows, :] += dot(ds, q, nn_dims)
-        dqo_ref[0, lo:, :] += dot(ds, k, tn_dims)
+        dqo_ref[0, qs, :] += dot(ds, k, tn_dims)
 
     # by position: a kv tile of the diagonal pair lies wholly under the
     # diagonal (c < t), on it (c == t: masked, by strips) or wholly above
     # it (p is 0 there: skipped); every tile of another pair lies under
+    if far_by is None:
+        whole = lambda c: jnp.logical_or(jnp.logical_not(diagonal), c < t)
+    else:
+        # the far pair mirrors the diagonal one: a kv tile lies wholly in
+        # reach (c > t), on the window's edge (c == t) or out of reach
+        far = ij_ref[0] - ij_ref[1] == far_by
+        whole = lambda c: jnp.where(diagonal, c < t,
+                                    jnp.where(far, c > t, True))
     for c in range(k_ref.shape[1] // tile):
-        pl.when(jnp.logical_or(jnp.logical_not(diagonal), c < t))(
-            functools.partial(part, c, 0, tile, False))
+        pl.when(whole(c))(functools.partial(part, c, 0, tile, False))
 
         @pl.when(jnp.logical_and(diagonal, c == t))
         def _():
             for lo in range(0, tile, strip):
                 part(c, lo, strip, True)
+
+        if far_by is not None:
+            @pl.when(jnp.logical_and(far, c == t))
+            def _():
+                for lo in range(0, tile, strip):
+                    part(c, lo, strip, True, far=True)
 
 
 def _tile(length, most):
@@ -131,9 +162,20 @@ def _group(g, rep):
     return g if rep == 1 else g // rep
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _window_blocks(window, block: int, length: int):
+    """A window in blocks (None where there is none): a whole number of
+    them, fewer than the sequence has."""
+    if window is None:
+        return None
+    if window % block or not 0 < window < length:
+        raise ValueError(f"a window of {window} positions is no whole number "
+                         f"of blocks of {block} inside {length} positions")
+    return window // block
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
 def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
-                        block: int, interpret=None):
+                        block: int, interpret=None, window=None):
     """One block pair of causal attention's flash backward, fused: q
     block ``ij[0]`` against kv block ``ij[1]`` (``block`` positions
     each; the pair whose two are equal is masked by position), the
@@ -153,7 +195,9 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     ``lax.scan``, slices nothing.  Five matmuls a pair with float32
     accumulation, ``p`` and ``ds`` cast to q's dtype for theirs; no
     (block, block) array leaves VMEM.  The ``jnp`` twin is
-    ``parallel/model._bwd_pair``.
+    ``parallel/model._bwd_pair``.  With ``window`` (positions, whole
+    blocks) the pair whose blocks lie the window apart is the far one
+    and masked as such; the caller walks no pair beyond it.
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -161,6 +205,7 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     hv = v.shape[-1]
     bh = b * h
     rep = _heads_a_group(q, k)
+    far_by = _window_blocks(window, block, s)
     tq = _tile(block, BWD_TILE)
     nt = block // tq
 
@@ -179,7 +224,7 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     acc_specs = [q_spec(d), kv_spec(d), kv_spec(hv)]
     out = pl.pallas_call(
         functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
-                          _tile(tq, BWD_STRIP), rep),
+                          _tile(tq, BWD_STRIP), rep, far_by),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
                         for o in operands[7:]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -209,59 +254,111 @@ FWD_TILE = 1024
 FWD_VMEM_LIMIT = 64 << 20
 
 
+def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask):
+    """One online-softmax update of a q tile's running max, denominator
+    and float32 numerator (VMEM scratch) by one kv tile.  ``mask``: None,
+    ``"diagonal"`` (key column c visible to query row r iff c <= r) or
+    ``"far"`` (a window's far tile: iff c > r).  Scores are held (q, kv):
+    a row's statistics are columns, and the two matmuls are the MXU's
+    plain forms."""
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    s = dot(q, k, (((1,), (1,)), ((), ()))) * scale     # (q, kv)
+    if mask is not None:
+        at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   axis)
+        s = jnp.where(at(0) < at(1) if mask == "far" else at(0) >= at(1), s,
+                      -jnp.inf)
+    # without a window every row sees its tile's first kv position at
+    # least, so its running max is finite from the first update on
+    m = m_ref[...]
+    new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    at_m = new_m
+    if mask == "far":
+        # a window's far tile comes first and its last query row sees
+        # nothing of it: that row's max stays -inf, and exp(-inf - -inf)
+        # must not arise; against 0 its c and p are exp(-inf) = 0
+        at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
+    c = jnp.exp(m - at_m)
+    p = jnp.exp(s - at_m)
+    den_ref[...] = den_ref[...] * c + jnp.sum(p, axis=1, keepdims=True)
+    num_ref[...] = num_ref[...] * c + dot(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+    m_ref[...] = new_m
+
+
+def _init_state(m_ref, den_ref, num_ref):
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+    den_ref[...] = jnp.zeros(den_ref.shape, jnp.float32)
+    num_ref[...] = jnp.zeros(num_ref.shape, jnp.float32)
+
+
+def _write_out(o_ref, lse_ref, m_ref, den_ref, num_ref):
+    o_ref[0] = num_ref[...] / den_ref[...]
+    # a row's logsumexp is a column here and a row where the backward
+    # reads it: one (tile, 128) transposition a q tile, no pass of
+    # o's size
+    lse = m_ref[...] + jnp.log(den_ref[...])
+    lse_ref[0] = jnp.broadcast_to(lse, (o_ref.shape[1], 128)).T[:1]
+
+
 def _causal_fwd_kernel(scale, q_ref, k_ref, v_ref, o_ref, lse_ref,
                        m_ref, den_ref, num_ref):
     """q tile i against kv tile j of one (batch, head): one online-
-    softmax update of the running max, denominator and float32
-    numerator, which live in VMEM scratch from the row's first kv tile
-    (j = 0) to the diagonal one (j = i), where ``o`` and the logsumexp
-    are written.  A kv tile above the diagonal (j > i) does nothing.
-    Scores are held (q, kv): a row's statistics are columns, and the two
-    matmuls are the MXU's plain forms."""
+    softmax update (``_online_update``) of the running max, denominator
+    and float32 numerator, which live in VMEM scratch from the row's
+    first kv tile (j = 0) to the diagonal one (j = i), where ``o`` and
+    the logsumexp are written.  A kv tile above the diagonal (j > i)
+    does nothing."""
     i, j = pl.program_id(1), pl.program_id(2)
-    tile = q_ref.shape[1]
-    dot = functools.partial(jax.lax.dot_general,
-                            preferred_element_type=jnp.float32)
+    state = (m_ref, den_ref, num_ref)
+    update = functools.partial(_online_update, scale, q_ref, k_ref, v_ref,
+                               *state)
 
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
-        den_ref[...] = jnp.zeros(den_ref.shape, jnp.float32)
-        num_ref[...] = jnp.zeros(num_ref.shape, jnp.float32)
+        _init_state(*state)
 
-    def update(diagonal):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = dot(q, k, (((1,), (1,)), ((), ()))) * scale     # (q, kv)
-        if diagonal:
-            at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                                       axis)
-            s = jnp.where(at(0) >= at(1), s, -jnp.inf)
-        # every row sees its tile's first kv position at least, so its
-        # running max is finite from the first update on
-        m = m_ref[...]
-        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        c = jnp.exp(m - new_m)
-        p = jnp.exp(s - new_m)
-        den_ref[...] = den_ref[...] * c + jnp.sum(p, axis=1, keepdims=True)
-        num_ref[...] = num_ref[...] * c + dot(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
-        m_ref[...] = new_m
-
-    pl.when(j < i)(functools.partial(update, False))
+    pl.when(j < i)(functools.partial(update, None))
 
     @pl.when(j == i)
     def _():
-        update(True)
-        o_ref[0] = num_ref[...] / den_ref[...]
-        # a row's logsumexp is a column here and a row where the backward
-        # reads it: one (tile, 128) transposition a q tile, no pass of
-        # o's size
-        lse = m_ref[...] + jnp.log(den_ref[...])
-        lse_ref[0] = jnp.broadcast_to(lse, (tile, 128)).T[:1]
+        update("diagonal")
+        _write_out(o_ref, lse_ref, *state)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def flash_causal_forward(q, k, v, *, block: int, interpret=None):
+def _window_fwd_kernel(scale, w, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_ref, den_ref, num_ref):
+    """``_causal_fwd_kernel`` under a window of ``w`` tiles: the grid's
+    third axis is the w + 1 kv tiles q tile i can reach, step n being kv
+    tile j = i - w + n: the far one first (n = 0, masked the far way),
+    the diagonal one last (n = w: masked, then ``o`` and the logsumexp
+    are written), those between under no mask.  A step before the
+    sequence's start (j < 0: the first w q tiles have them) does
+    nothing."""
+    i, n = pl.program_id(1), pl.program_id(2)
+    j = i - w + n
+    state = (m_ref, den_ref, num_ref)
+    update = functools.partial(_online_update, scale, q_ref, k_ref, v_ref,
+                               *state)
+
+    @pl.when(n == 0)
+    def _():
+        _init_state(*state)
+
+    pl.when(jnp.logical_and(n == 0, j >= 0))(functools.partial(update, "far"))
+    pl.when(jnp.logical_and(jnp.logical_and(n > 0, n < w), j >= 0))(
+        functools.partial(update, None))
+
+    @pl.when(n == w)
+    def _():
+        update("diagonal")
+        _write_out(o_ref, lse_ref, *state)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
+def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None):
     """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
     float32 and the logsumexp (b, h, s) float32 of q (b, h, s, d), k
     (b, n_kv, s, d) and v (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``
@@ -277,6 +374,9 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None):
     that is no multiple of 128 lanes (192) is Mosaic's to lay out.  The
     logsumexp leaves as (b x h, 1, s), the shape ``attn_block_backward``
     reads.  The ``jnp`` twin is ``parallel/model._causal_fwd_blocks``.
+    With ``window`` (positions, whole tiles) the grid's third axis is
+    the window's tiles and the diagonal one (``_window_fwd_kernel``), the
+    index map starting at the far tile (clamped to the sequence's first).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -290,15 +390,23 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None):
     flat = lambda a: a.reshape(-1, s, a.shape[-1])
     q_map = lambda g, i, j: (g, i, 0)
     kv_map = lambda g, i, j: (_group(g, rep), jnp.minimum(i, j), 0)
+    kernel, reach = functools.partial(_causal_fwd_kernel,
+                                      1.0 / math.sqrt(d)), nt
+    w = _window_blocks(window, tile, s)
+    if w is not None:
+        kv_map = lambda g, i, n: (_group(g, rep), jnp.maximum(i - w + n, 0),
+                                  0)
+        kernel, reach = functools.partial(_window_fwd_kernel,
+                                          1.0 / math.sqrt(d), w), w + 1
     kv_spec = lambda width: pl.BlockSpec((1, tile, width), kv_map)
     operands = [flat(q), flat(k), flat(v)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
     f32 = jnp.float32
     o, lse = pl.pallas_call(
-        functools.partial(_causal_fwd_kernel, 1.0 / math.sqrt(d)),
+        kernel,
         out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
                    jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
-        grid=(bh, nt, nt),
+        grid=(bh, nt, reach),
         in_specs=[pl.BlockSpec((1, tile, d), q_map), kv_spec(d), kv_spec(hv)],
         out_specs=(pl.BlockSpec((1, tile, hv), q_map),
                    pl.BlockSpec((1, 1, tile), lambda g, i, j: (g, 0, i))),

@@ -4,9 +4,10 @@ matmuls and a weighted combine.  ``moe_sorted_block`` holds every expert
 (OLMoE); ``moe_shared_local_block`` (DeepSeek-V3's layer: JoyAI-LLM-Flash;
 without a shared expert lfm2_moe's: LFM2-8B-A1B; by softmax scores with
 no bias beside a sigmoid-gated shared expert qwen3_next's:
-Qwen3-Next-80B-A3B) and ``moe_latent_block`` (nemotron_h's LatentMoE)
-hold a share of the routed experts, beside a shared one where the model
-has it.  ``parallel/model.decoder_layer``
+Qwen3-Next-80B-A3B; by a router that read the layer's input, with
+relu-gated experts, SmallThinker-21BA3B's) and ``moe_latent_block``
+(nemotron_h's LatentMoE) hold a share of the routed experts, beside a
+shared one where the model has it.  ``parallel/model.decoder_layer``
 chooses among them; the primitives come from ``parallel/layers.py``.
 """
 from __future__ import annotations
@@ -122,12 +123,18 @@ def _grouped_matmul(sizes, compute_dtype, interpret: bool):
     return gmm
 
 
+#: a gated expert's activation by the configuration's name for it:
+#: SwiGLU's silu, ReGLU's relu
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype,
-                       interpret: bool = True):
-    """SwiGLU experts on slots sorted by expert: ``down(silu(gate x) *
-    up x)`` as three grouped matmuls (``_grouped_matmul``)."""
+                       interpret: bool = True, act: str = "silu"):
+    """Gated experts on slots sorted by expert: ``down(act(gate x) * up
+    x)`` as three grouped matmuls (``_grouped_matmul``); ``act`` is
+    SwiGLU's ``silu`` or ReGLU's ``relu`` (``GATE_ACTS``)."""
     gmm = _grouped_matmul(sizes, compute_dtype, interpret)
-    hidden = jax.nn.silu(gmm(xs, gate)) * gmm(xs, up)
+    hidden = GATE_ACTS[act](gmm(xs, gate)) * gmm(xs, up)
     return gmm(hidden, down)
 
 
@@ -311,11 +318,24 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
     return run(order, sizes, h, weights.reshape(t * k), *mats)
 
 
-def _route_to_held(p, x, cfg, bias):
+def router_logits(p, rows):
+    """The router's product of ``rows`` (T, d): float32 at the highest
+    precision, under the checkpoint's name (``ROUTER_LOGITS``)."""
+    with jax.named_scope("otpu_router"):
+        return checkpoint_name(
+            jnp.dot(rows, p["router"], precision=jax.lax.Precision.HIGHEST),
+            ROUTER_LOGITS)
+
+
+def _route_to_held(p, x, cfg, bias, routed=None):
     """What both expert blocks of a rank that holds a share do first, on
     the residual stream ``x`` (b, s, d): pre-norm; the router's scores
     over **all** the experts in float32; the top k; the held slots sorted
-    by expert.  Two routers, told apart by ``scoring_func``: ``sigmoid``
+    by expert.  ``routed``: None, or where the router stands before
+    attention (``decoder_layer``) the rows it read and the logits it made
+    of them (``router_logits``), which are then not made here: the
+    experts still read the normed ``x``, and ``in`` is what the router
+    read.  Two routers, told apart by ``scoring_func``: ``sigmoid``
     scores chosen under ``bias`` (E,) (DeepSeek-V3's ``noaux_tc``: JoyAI,
     Nemotron-3-Super, LFM2), or ``softmax`` probabilities with no bias
     (qwen3_next's: Qwen3-Next, the weights OLMoE's ``route_topk`` gives,
@@ -332,10 +352,8 @@ def _route_to_held(p, x, cfg, bias):
     t, k = b * s, cfg.num_experts_per_tok
     h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps).reshape(t, d)
     stats = {}
+    read, logits = (h, router_logits(p, h)) if routed is None else routed
     with jax.named_scope("otpu_router"):
-        logits = checkpoint_name(
-            jnp.dot(h, p["router"], precision=jax.lax.Precision.HIGHEST),
-            ROUTER_LOGITS)
         if cfg.scoring_func == "softmax":
             scores = jax.nn.softmax(logits, axis=-1)
             weights, experts = route_chosen(
@@ -356,11 +374,12 @@ def _route_to_held(p, x, cfg, bias):
                 experts.reshape(t * k)].add(1), EXPERT_SLOTS)
     stats["slots"] = slots.astype(jnp.float32)
     return h, order, sizes, stats, {
-        "in": h, "logits": logits, "scores": scores, "weights": weights,
+        "in": read, "logits": logits, "scores": scores, "weights": weights,
         "experts": experts}
 
 
-def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True):
+def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True,
+                           routed=None):
     """The sparse MLP sublayer of a rank that holds ``experts_here`` of
     the routed experts, on the residual stream ``x`` (b, s, d): pre-norm;
     the router's scores over **all** the experts in float32 and the top
@@ -374,12 +393,17 @@ def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True):
     the result is the held experts' part alone.  qwen3_next's
     (Qwen3-Next-80B-A3B): softmax probabilities with no bias, and the
     shared expert times ``sigmoid(h w_g)`` a token where the layer holds
-    ``shared_w_g`` (d, 1), the gate in float32.  Returns (the sublayer's
+    ``shared_w_g`` (d, 1), the gate in float32.  SmallThinker-21BA3B's:
+    qwen3_next's router over logits made from the layer's input
+    (``routed``: ``_route_to_held``), no shared expert, and experts gated
+    by ``cfg.mlp_hidden_act`` (``relu``: ReGLU).  Returns (the sublayer's
     output before the residual add; the router's statistics
     (``_route_to_held``); by token row what the router read and made:
     ``in``, ``logits``, ``scores`` (T, E), ``weights`` and ``experts``
-    (T, k), and a gated shared expert's ``shared_gate`` (T, 1))."""
-    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias)
+    (T, k), a gated shared expert's ``shared_gate`` (T, 1), and where
+    ``routed`` is given the normed rows the held experts read and their
+    weighted sum, ``expert_in`` and ``expert_out`` (T, d))."""
+    h, order, sizes, stats, seen = _route_to_held(p, x, cfg, bias, routed)
     shared = None
     if cfg.n_shared_experts:
         with jax.named_scope("otpu_shared_expert"):
@@ -391,9 +415,14 @@ def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True):
                     precision=jax.lax.Precision.HIGHEST))
                 shared, seen = shared * mix, {**seen, "shared_gate": mix}
     with jax.named_scope("otpu_experts"):
-        out = local_expert_ffn(h, order, seen["weights"], sizes,
-                               (p["gate"], p["up"], p["down"]), cfg,
-                               interpret=interpret)
+        out = local_expert_ffn(
+            h, order, seen["weights"], sizes, (p["gate"], p["up"], p["down"]),
+            cfg, functools.partial(grouped_expert_ffn,
+                                   act=cfg.mlp_hidden_act), interpret)
+        if routed is not None:
+            # the router read other rows than the experts: what these read
+            # and made goes beside what it read
+            seen = {**seen, "expert_in": h, "expert_out": out}
         if shared is not None:
             out = shared + out
     return out.reshape(x.shape), stats, seen

@@ -1,6 +1,7 @@
 """A public model's sublayers (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super,
-LFM2-8B-A1B, Qwen3-Next-80B-A3B): causal flash attention with its two
-walks of the block pairs, the three attention sublayers, Mamba-2's
+LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B): causal flash
+attention, in full or under a sliding window, with its two walks of the
+block pairs, the three attention sublayers, Mamba-2's
 chunked scan and mixer, LFM2's gated short convolution, Gated DeltaNet's
 chunked rule and operator, and ``decoder_layer``, which chooses a layer's
 sublayers by what it holds.
@@ -65,7 +66,36 @@ def _group_bias(block: int, rep: int):
     return jnp.tile(_tri_bias(block), (rep, 1))
 
 
-def _causal_fwd_blocks(q, k, v, block, interpret):
+def _far_bias(block: int, rep: int):
+    """A window's far block's bias for a group's folded rows: key column
+    c visible to query row r iff c > r (the diagonal block's mirror)."""
+    i = jnp.arange(block)
+    return jnp.tile(jnp.where(i[:, None] < i[None, :], 0.0,
+                              -jnp.inf).astype(jnp.float32), (rep, 1))
+
+
+def _window_pairs(nb: int, w):
+    """The (q block, kv block) pairs causal attention walks over ``nb``
+    blocks, q block by q block, kv blocks ascending: kv blocks 0 .. i, or
+    under a window of ``w`` blocks max(0, i - w) .. i.  The kernels' grid
+    and the ``jnp`` twins walk these and no other."""
+    return [(i, j) for i in range(nb)
+            for j in range(0 if w is None else max(0, i - w), i + 1)]
+
+
+def _window_in_blocks(window, block: int, length: int):
+    """A static window in blocks: None where there is none or it covers
+    the sequence (plain causal attention, bit for bit); else a whole
+    number of blocks."""
+    if window is None or window >= length:
+        return None
+    if window % block:
+        raise ValueError(f"a window of {window} positions is no whole "
+                         f"number of blocks of {block}")
+    return window // block
+
+
+def _causal_fwd_blocks(q, k, v, block, interpret, window=None):
     """Causal attention's forward pass: (o float32, logsumexp float32)
     of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
     key-value head is read by ``h / n_kv`` consecutive query heads, and
@@ -78,11 +108,16 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
     0..i of ``block`` positions, the diagonal one under a triangular
     bias, each through one online-softmax update with float32 scores;
     the running max, numerator and denominator are float32 whatever q,
-    k, v are."""
+    k, v are.  Under a static ``window`` (positions; a whole number w of
+    blocks) q block i meets kv blocks max(0, i - w) .. i, the far one (i
+    - w) under ``_far_bias``; its last query row sees nothing of it, and
+    that row's running max stays -inf through it."""
+    w = _window_in_blocks(window, block, q.shape[2])
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
-        return flash_causal_forward(q, k, v, block=block, interpret=False)
+        return flash_causal_forward(q, k, v, block=block, interpret=False,
+                                    window=None if w is None else window)
     h, s, hd = q.shape[1:]
     nb = s // block
     scale = 1.0 / math.sqrt(hd)
@@ -94,15 +129,20 @@ def _causal_fwd_blocks(q, k, v, block, interpret):
         zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
         m, den = zero - jnp.inf, zero
         num = jnp.zeros(v.shape[-1:], jnp.float32) + zero[..., None]
-        for j in range(i + 1):
+        for j in range(0 if w is None else max(0, i - w), i + 1):
             kj = k[:, :, j * block:(j + 1) * block]
             vj = v[:, :, j * block:(j + 1) * block]
             sc = _contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
             if j == i:
                 sc = sc + bias
-            new_m = jnp.maximum(m, sc.max(axis=-1))
-            c = jnp.exp(m - new_m)
-            p = jnp.exp(sc - new_m[..., None])
+            far = w is not None and j == i - w
+            if far:
+                sc = sc + _far_bias(block, h // k.shape[1])
+            new_m = at_m = jnp.maximum(m, sc.max(axis=-1))
+            if far:     # a row that sees nothing yet: exp(-inf - 0) = 0
+                at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
+            c = jnp.exp(m - at_m)
+            p = jnp.exp(sc - at_m[..., None])
             num = num * c[..., None] + _contract("bhqk,bhkd->bhqd", p, vj,
                                                  q.dtype)
             den = den * c + p.sum(axis=-1)
@@ -124,8 +164,9 @@ ATTN_LSE = "otpu_attn_lse"
 CHECKPOINT_KEEPS = (ATTN_OUT, ATTN_LSE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def causal_flash_attention(q, k, v, block: int, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_flash_attention(q, k, v, block: int, interpret: bool,
+                           window=None):
     """Causal self-attention of q (b, h, s, hd), k (b, n_kv, s, hd) and
     v (b, n_kv, s, hv) whose length is a multiple of ``block``: k and v
     come with the model's own key-value heads, each shared by ``h /
@@ -137,24 +178,41 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool):
     from q, k and the saved logsumexp in float32; no (s, s) array is
     ever held), its matmul inputs in q's dtype; on a TPU each block pair
     one call of the fused kernel (``_causal_bwd_fused``), on the CPU
-    ``_bwd_pair``'s einsums."""
-    return _causal_fwd_blocks(q, k, v, block, interpret)[0]
+    ``_bwd_pair``'s einsums.
+
+    ``window`` (static; None: every earlier key) makes it sliding-window
+    attention: key j is visible to query i iff 0 <= i - j < ``window``,
+    a whole number of blocks.  Both passes then walk the block pairs a
+    window can reach and no other (``_window_pairs``), the far pair under
+    its own mask; a window that covers the sequence is None, bit for
+    bit.  With None every branch, grid and kernel is what it was before
+    the argument."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, window)[0]
 
 
-def _count_built(q, k) -> None:
+def _count_built(q, k, block, window) -> None:
     """SPC ``attn_built``: the causal attention passes made, forward
     rule or backward rule, while steps were traced (JAX traces a pass
     more than once); ``attn_shared_kv_built``: those of them whose k and
     v came with fewer heads than q and went to the kernels, or their
-    twins, that way."""
+    twins, that way; ``attn_window_built``: those made under a window;
+    ``attn_pairs_walked`` the block pairs the passes walk and
+    ``attn_pairs_causal`` those full causal passes of their lengths
+    would."""
+    nb = q.shape[2] // block
+    w = _window_in_blocks(window, block, q.shape[2])
     spc.record("attn_built", 1)
     if k.shape[1] < q.shape[1]:
         spc.record("attn_shared_kv_built", 1)
+    if w is not None:
+        spc.record("attn_window_built", 1)
+    spc.record("attn_pairs_walked", len(_window_pairs(nb, w)))
+    spc.record("attn_pairs_causal", nb * (nb + 1) // 2)
 
 
-def _causal_fwd(q, k, v, block, interpret):
-    _count_built(q, k)
-    o, lse = _causal_fwd_blocks(q, k, v, block, interpret)
+def _causal_fwd(q, k, v, block, interpret, window=None):
+    _count_built(q, k, block, window)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, window)
     o = checkpoint_name(o, ATTN_OUT)
     lse = checkpoint_name(lse, ATTN_LSE)
     return o, (q, k, v, o, lse)
@@ -172,7 +230,8 @@ UNROLLED_BLOCKS = 4
 def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
     """One block pair of the flash backward: (dq, dk, dv) parts.  A
     head of the query side is a key-value head's, its rows the group's
-    (``_group_blocks``), so dk and dv sum the group in float32."""
+    (``_group_blocks``), so dk and dv sum the group in float32.
+    ``bias``: the diagonal pair's, a window's far pair's, or None."""
     sc = _contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
     if bias is not None:
         sc = sc + bias
@@ -184,37 +243,38 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
             _contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
 
 
-def _causal_bwd(block, interpret, res, do):
+def _causal_bwd(block, interpret, window, res, do):
     q, k, v, o, lse = res
-    _count_built(q, k)
+    _count_built(q, k, block, window)
     h, n_kv = q.shape[1], k.shape[1]
     nb = q.shape[2] // block
+    w = _window_in_blocks(window, block, q.shape[2])
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
     if not interpret:
-        return _causal_bwd_fused(q, k, v, do, lse, delta, block)
+        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w)
     if nb > UNROLLED_BLOCKS:
-        return _causal_bwd_scanned(q, k, v, do, lse, delta, block)
+        return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w)
     dt = q.dtype
     scale = 1.0 / math.sqrt(q.shape[-1])
     bias = _group_bias(block, h // n_kv)
+    far = None if w is None else _far_bias(block, h // n_kv)
     qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
                              for a in (q, do, lse, delta))
     cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
     dq = [0.0] * nb
     dk = [0.0] * nb
     dv = [0.0] * nb
-    for i in range(nb):
-        for j in range(i + 1):
-            dq_c, dk_c, dv_c = _bwd_pair(
-                qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
-                bias if j == i else None, scale, dt)
-            dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
+    for i, j in _window_pairs(nb, w):
+        dq_c, dk_c, dv_c = _bwd_pair(
+            qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
+            bias if j == i else far if i - j == w else None, scale, dt)
+        dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
     cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
     return _ungroup_blocks(jnp.stack(dq), h).astype(dt), cat(dk), cat(dv)
 
 
-def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
+def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None):
     """The same pairs in the same order (q block by q block, kv blocks
     ascending), one a step of a ``lax.scan`` over float32 accumulators."""
     dt = q.dtype
@@ -225,14 +285,17 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
     qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
                              for a in (q, do, lse, delta))
     kb, vb = (_group_blocks(a, n_kv, block) for a in (k, v))
-    pairs = [(i, j) for i in range(nb) for j in range(i + 1)]
+    pairs = _window_pairs(nb, w)
     zero = lambda a: (a * 0).astype(jnp.float32)         # carries a's vma
 
     def step(acc, ij):
         i, j = ij
+        bias = jnp.where(i == j, tri, 0.0)
+        if w is not None:
+            bias = jnp.where(i - j == w, _far_bias(block, h // n_kv), bias)
         dq_c, dk_c, dv_c = _bwd_pair(
-            qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i],
-            jnp.where(i == j, tri, 0.0), scale, dt)
+            qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i], bias, scale,
+            dt)
         dq, dk, dv = acc
         return (dq.at[i].add(dq_c), dk.at[j].add(dk_c),
                 dv.at[j].add(dv_c)), None
@@ -246,7 +309,7 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block):
             _ungroup_blocks(dv, n_kv).astype(dt))
 
 
-def _causal_bwd_fused(q, k, v, do, lse, delta, block):
+def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None):
     """The same pairs in the same order, each one call of the fused
     Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
     ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
@@ -260,8 +323,9 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block):
     nb = q.shape[2] // block
     do = do.astype(dt)                  # what ``_contract`` makes of it
     pair = lambda acc, ij: attn_block_backward(
-        ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False)
-    pairs = [(i, j) for i in range(nb) for j in range(i + 1)]
+        ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
+        window=None if w is None else w * block)
+    pairs = _window_pairs(nb, w)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     vma = tuple(frozenset().union(*(jax.typeof(a).vma
                                     for a in (q, k, v, do))))
@@ -336,7 +400,8 @@ def mla_attention(p, x, cfg, *, interpret: bool):
         return x + matmul(o, p["wo"], dt)
 
 
-def gqa_attention(p, x, cfg, *, interpret: bool):
+def gqa_attention(p, x, cfg, *, interpret: bool,
+                  kind: str = "full_attention"):
     """Grouped-query attention, **without** the residual add, on the
     residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
     projections without bias; the ``n_heads_here`` query heads held here
@@ -348,7 +413,9 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
     group's shared head through their index maps and sum its query
     heads' gradients in float32.
 
-    Three models' sublayer, told apart by what the layer holds.
+    Four models' sublayer, told apart by what the layer holds and, where
+    the leaves cannot say, by the layer's ``kind`` (its ``layer_types``
+    name; ``full_attention`` where nobody says) under the configuration.
     nemotron_h's (Nemotron-3-Super) holds no ``q_norm``: no rotary
     embedding (the positions come from the state-space layers), q, k
     and v cast as they leave their projections.  lfm2's (LFM2-8B-A1B)
@@ -361,15 +428,38 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
     head only (``partial_rotary_factor``), and ``o * sigmoid(gate)``, in
     float32, before ``W_o``.  The head's width is the configuration's
     ``head_width`` (``head_dim`` where the file gives one), whatever the
-    hidden width.  Returns (the sublayer's output, by token row what the
+    hidden width.  smallthinker's (SmallThinker-21BA3B) holds no
+    ``q_norm`` either, and is a ``layer_types`` model: RoPE over the whole
+    head, in float32, on the kinds of layer the configuration's
+    ``rope_kinds`` names (its ``sliding_attention`` layers) and none on
+    the others (its ``full_attention`` layers), and a
+    ``sliding_attention`` layer attends to the last ``sliding_window``
+    keys (``causal_flash_attention``'s ``window``) where the sequence is
+    longer than that.  Whether a kind is turned is the configuration's to
+    say in every form but nemotron_h's: lfm2's and qwen3_next's
+    ``rope_kinds`` name their one kind of attention.
+
+    Returns (the sublayer's output, by token row what the
     norm and RoPE read and made of the first query head and the first
     key-value head side by side, ``attn_qk_in`` and ``attn_qk`` (T, 2
-    hd); empty for nemotron_h's; of a gated layer also the first head's
-    o and gate side by side, ``attn_og_in`` (T, 2 hd), and what the gate
-    made of them, ``attn_og`` (T, hd))."""
+    hd): of a QK-normed layer that is turned, and of every layer of a
+    model that goes by ``rope_kinds`` without a norm, the two alike where
+    the layer is not turned; of a gated layer also the first
+    head's o and gate side by side, ``attn_og_in`` (T, 2 hd), and what
+    the gate made of them, ``attn_og`` (T, hd); of a layer under a window
+    what the kernels read and made of the first query head and its
+    key-value head: ``attn_win_q`` (T, hd), ``attn_win_k_seq`` and
+    ``attn_win_v_seq`` (T, hd) whole, because a row reads a window of
+    them, and ``attn_win_o`` (T, hd))."""
     b, s, _ = x.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     seen, gate = {}, None
+    turned = kind in cfg.rope_kinds
+    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width)) if turned \
+        else (lambda t: t)
+    window = cfg.sliding_window if kind == "sliding_attention" else None
+    first = lambda a, c: jnp.concatenate(
+        [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
         if "q_norm" in p:
@@ -378,20 +468,33 @@ def gqa_attention(p, x, cfg, *, interpret: bool):
                           for w, n in (("wq", nh), ("wk", nkv)))
             if p["wq"].shape[-1] == 2 * p["wo"].shape[0]:
                 q_in, gate = jnp.split(q_in, 2, axis=-1)
-            q, k = (rope(rmsnorm_gain(t, p[g], cfg.rms_norm_eps),
-                         cfg.rope_theta, cfg.rotary_width)
+            q, k = (turn(rmsnorm_gain(t, p[g], cfg.rms_norm_eps))
                     for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
-            first = lambda a, c: jnp.concatenate(
-                [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
-            seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+            if turned:
+                seen = {"attn_qk_in": first(q_in, k_in),
+                        "attn_qk": first(q, k)}
             q, k = q.astype(dt), k.astype(dt)
             v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
+        elif cfg.layer_types:
+            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+            q_in, k_in, v = (split(matmul(h, p[w], dt), n) for w, n in (
+                ("wq", nh), ("wk", nkv), ("wv", nkv)))
+            q, k = turn(q_in), turn(k_in)
+            # reported of a layer that is not turned too: that it was left
+            # alone is what a check reads
+            seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+            q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
         else:
             heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
                 0, 2, 1, 3).astype(dt)
             q, k, v = (heads(matmul(h, p[w], dt), n)
                        for w, n in (("wq", nh), ("wk", nkv), ("wv", nkv)))
-    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret,
+                               window)
+    if window is not None:
+        rows = lambda t: t[:, 0].reshape(b * s, -1).astype(jnp.float32)
+        seen.update(attn_win_q=rows(q), attn_win_k_seq=rows(k),
+                    attn_win_v_seq=rows(v), attn_win_o=rows(o))
     with jax.named_scope("otpu_attn_proj"):
         if gate is not None:
             gated = o * jax.nn.sigmoid(gate)
@@ -942,7 +1045,8 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True):
                       ).reshape(b, s, d), seen
 
 
-def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
+def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
+                  kind: str = "full_attention"):
     """One decoder layer of a public model, its sublayers chosen by what
     the layer holds and the configuration's published keys say.
 
@@ -958,7 +1062,12 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
     ``linear_attention``), the gated short convolution where it holds
     ``in_proj`` (LFM2-8B-A1B's ``conv``), else grouped-query attention
     with a per-head QK-norm and RoPE (LFM2's, and with an output gate
-    and RoPE on part of the head Qwen3-Next's); latent attention where
+    and RoPE on part of the head Qwen3-Next's; with neither norm nor
+    gate SmallThinker-21BA3B's, whose ``full_attention`` and
+    ``sliding_attention`` layers hold the same leaves: ``kind``, the
+    layer's ``layer_types`` name, a static argument, says which this is,
+    and ``gqa_attention`` reads RoPE and the window off it; a window
+    layer's sublayer goes under ``otpu_swa``); latent attention where
     ``kv_lora_rank`` is set (JoyAI-LLM-Flash); else OLMoE's attention.
     The feed-forward: a dense SwiGLU where the layer has no router
     (JoyAI's and LFM2's leading layers), else the sparse MLP
@@ -985,7 +1094,11 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
             y, stats, routed = experts.moe_latent_block(
                 p, x, cfg, bias, interpret=interpret)
         return x + y, stats, routed
-    seen = {}
+    seen, routed = {}, None
+    if cfg.router_before_attention and "router" in p:
+        with jax.named_scope("otpu_moe"):
+            rows = x.reshape(-1, x.shape[-1])
+            routed = (rows, experts.router_logits(p, rows))
     if cfg.layer_types and "ba_proj" in p:
         with jax.named_scope("otpu_gdn"):
             y, seen = gated_delta_net(p, x, cfg, interpret=interpret)
@@ -995,8 +1108,10 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
             y, seen = short_conv(p, x, cfg)
         x = x + y
     elif cfg.layer_types:
-        with jax.named_scope("otpu_attention"):
-            y, seen = gqa_attention(p, x, cfg, interpret=interpret)
+        with jax.named_scope("otpu_swa") if kind == "sliding_attention" \
+                else jax.named_scope("otpu_attention"):
+            y, seen = gqa_attention(p, x, cfg, interpret=interpret,
+                                    kind=kind)
         x = x + y
     elif cfg.kv_lora_rank:
         with jax.named_scope("otpu_mla"):
@@ -1012,9 +1127,9 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None):
         return x + y.reshape(x.shape), {}, seen
     with jax.named_scope("otpu_moe"):
         if cfg.routes_to_held:
-            y, stats, routed = experts.moe_shared_local_block(
-                p, x, cfg, bias, interpret=interpret)
+            y, stats, made = experts.moe_shared_local_block(
+                p, x, cfg, bias, interpret=interpret, routed=routed)
         else:
-            y, stats, routed = experts.moe_sorted_block(
+            y, stats, made = experts.moe_sorted_block(
                 p, x, cfg, interpret=interpret)
-    return x + y, stats, {**routed, **seen}
+    return x + y, stats, {**made, **seen}

@@ -192,15 +192,17 @@ def experts(p, x, cfg: ModelConfig, shared: bool = True):
         jnp.sum(probs, axis=0)
 
 
-def layers_of(params, cfg: ModelConfig):
+def layers_of(params, cfg: ModelConfig, kinds: dict = KINDS):
     """(letter, the layer's leaves) of the held layers in their order,
-    from the tree's runs of like layers (``cfg.segments``)."""
+    from the tree's runs of like layers (``cfg.segments``); ``kinds``
+    names a letter's group in the tree (another ``layer_types`` model's
+    reference brings its own)."""
     for unit, n, first in cfg.segments:
         group = params["layers"][f"l{first}"]
         for i in range(n):
             for letter in unit:
                 yield letter, jax.tree.map(lambda a: a[i],
-                                           group[KINDS[letter]])
+                                           group[kinds[letter]])
 
 
 def forward(params, tokens, cfg: ModelConfig):

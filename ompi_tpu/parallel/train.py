@@ -1,6 +1,6 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
-Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B): widths from a
-configuration file, not
+Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B):
+widths from a configuration file, not
 from the mesh; the kinds of sublayer from its published keys
 (``ModelConfig``).  The
 parameter tree and its initialisation, the loss over the walked layers
@@ -13,6 +13,7 @@ imports.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 import weakref
@@ -44,15 +45,21 @@ UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b")
 #: ``layer_types`` model's layers are walked by (lfm2_moe: an operator,
 #: then a feed-forward): a gated short convolution or grouped-query
 #: attention (qwen3_next: a Gated DeltaNet operator or output-gated
-#: attention), before a dense SwiGLU (small letter) or the experts (capital)
+#: attention; smallthinker: attention in full or under a sliding window,
+#: two kinds of the same leaves), before a dense SwiGLU (small letter) or
+#: the experts (capital)
 PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe",
                  "c": "conv_dense", "a": "attn_dense", "l": "gdn_dense",
-                 "C": "conv_moe", "A": "attn_moe", "L": "gdn_moe"}
+                 "w": "swa_dense",
+                 "C": "conv_moe", "A": "attn_moe", "L": "gdn_moe",
+                 "W": "swa_moe"}
 #: the letters whose layer holds a router
-EXPERT_LETTERS = "ECAL"
+EXPERT_LETTERS = "ECALW"
 #: ``layer_types``' names and the letter's lower case
 OPERATOR_LETTERS = {"conv": "c", "full_attention": "a",
-                    "linear_attention": "l"}
+                    "linear_attention": "l", "sliding_attention": "w"}
+#: a ``layer_types`` letter's name, which ``decoder_layer`` is told
+LETTER_OPERATORS = {v: k for k, v in OPERATOR_LETTERS.items()}
 #: what an operator's sublayer reports by token row goes by its own name
 #: into a step's ``sample``; what a router does, behind ``router_``
 OPERATOR_SAMPLES = ("ssm_", "conv_", "attn_", "gdn_")
@@ -89,8 +96,15 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: ``gdn_v_seq`` (G, T, dv), ``gdn_g_seq``, ``gdn_beta_seq`` (G, T)), and
 #: made (``gdn_o`` (G, R, dv)); of every output-gated attention layer's
 #: first head o and its gate side by side (``attn_og_in`` (A, R, 2 hd))
-#: and gated (``attn_og`` (A, R, hd)); a gated shared expert's gate
-#: (``router_shared_gate`` (L, R, 1)); under ``tie_word_embeddings``
+#: and gated (``attn_og`` (A, R, hd)); of every sliding-window layer's
+#: first query head and its key-value head what the kernels read
+#: (``attn_win_q`` (W, R, hd); ``attn_win_k_seq``, ``attn_win_v_seq`` (W,
+#: T, hd) whole) and made (``attn_win_o`` (W, R, hd)); a gated shared
+#: expert's gate
+#: (``router_shared_gate`` (L, R, 1)); where the router stands before
+#: attention the normed rows the held experts read and their weighted sum
+#: (``router_expert_in``, ``router_expert_out`` (L, R, d)); under
+#: ``tie_word_embeddings``
 #: ``embed_probe_read`` (PROBE,), whether a probed entry of ``embed``
 #: lies in a row the step's tokens read (the others' gradient is the
 #: head's alone)
@@ -145,7 +159,23 @@ class ModelConfig:
     ``moe_shared_expert_intermediate_size`` (the file's
     ``shared_expert_intermediate_size``) times a sigmoid gate
     (``shared_expert_gate``).  The two gates are the family's modelling
-    code's, no published key: the loader sets them by ``model_type``."""
+    code's, no published key: the loader sets them by ``model_type``.
+
+    smallthinker's keys: a ``layer_types`` model too (``load_model_config``
+    derives the names from ``sliding_window_layout``: ``sliding_attention``
+    where it is 1, else ``full_attention``), both kinds grouped-query
+    attention on heads of ``head_dim`` with no QK-norm (``qk_norm``
+    false) and the same leaves; a ``sliding_attention`` layer attends to
+    the last ``sliding_window`` keys (the file's ``sliding_window_size``,
+    whole ``attn_block``s); ``rope_kinds`` names the kinds of layer whose
+    q and k RoPE turns (from the file's ``rope_layout``: its
+    ``sliding_attention`` layers; lfm2's and qwen3_next's one kind of
+    attention is turned, the default); the router reads the layer's
+    input, before the attention sublayer (``router_before_attention``),
+    scores by ``softmax`` under no bias; the experts are relu-gated
+    (``mlp_hidden_act`` ``relu``: ReGLU) with no shared one.  The
+    router's place and the activation are the model's report's, no
+    published key: the loader sets them by ``model_type``."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -222,6 +252,11 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     attn_output_gate: bool = False
     shared_expert_gate: bool = False
+    # smallthinker's keys (SmallThinker-21BA3B)
+    sliding_window: int = 0         # a sliding_attention layer's window
+    rope_kinds: tuple = ("full_attention", "sliding_attention")
+    qk_norm: bool = True            # a layer_types model's attention
+    router_before_attention: bool = False
 
     @property
     def pattern_here(self) -> str:
@@ -348,6 +383,7 @@ class ModelConfig:
         hybrid = bool(self.hybrid_override_pattern)
         # a file's list; a tuple so that the configuration stays hashable
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_kinds", tuple(self.rope_kinds))
         typed = bool(self.layer_types)
         per_kv = self.num_attention_heads // max(1, self.num_key_value_heads)
         if not (hybrid or typed) and self.num_key_value_heads \
@@ -356,7 +392,8 @@ class ModelConfig:
                 "num_key_value_heads: grouped-query attention is a "
                 "hybrid_override_pattern or layer_types model's; this "
                 "model's attention has a key-value head a query head")
-        if self.hidden_size % self.num_attention_heads:
+        if not (typed and self.head_dim) \
+                and self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is not a multiple of the heads")
         if typed and (hybrid or set(self.layer_types)
                       - set(OPERATOR_LETTERS)):
@@ -406,8 +443,21 @@ class ModelConfig:
                 f"are neither whole key-value heads' ({per_kv} each) nor "
                 "a whole part of one's; a key-value head split across "
                 "chips is not run")
+        windowed = "sliding_attention" in self.layer_types
+        if windowed != bool(self.sliding_window) or (
+                windowed and self.sliding_window % self.attn_block):
+            raise NotImplementedError(
+                f"sliding_window {self.sliding_window}: a window is a "
+                "layer_types model's sliding_attention layers', and a whole "
+                f"number of attn_block {self.attn_block} positions")
+        if not typed and (not self.qk_norm or self.router_before_attention):
+            raise NotImplementedError(
+                f"qk_norm {self.qk_norm} / router_before_attention "
+                f"{self.router_before_attention}: only a layer_types "
+                "model's attention goes without a QK-norm, and only its "
+                "router reads the layer's input")
         if (hybrid or typed) and (
-                set(self.pattern_here) - set("M*E" if hybrid else "calCAL")
+                set(self.pattern_here) - set("M*E" if hybrid else "calwCALW")
                 or len(self.pattern_here) != self.layers_here):
             raise ValueError(
                 f"layers_here {self.layers_here} from first_layer_here "
@@ -431,11 +481,15 @@ class ModelConfig:
                 "hybrid_override_pattern model (mtp_hybrid_override_"
                 "pattern) or of a layer_types model is not run; hold 0 "
                 "of them")
-        if (self.mlp_hidden_act == "relu2") != bool(self.moe_latent_size):
+        if (self.mlp_hidden_act == "relu2") != bool(self.moe_latent_size) \
+                or self.mlp_hidden_act not in ("relu2", "silu", "relu") \
+                or (self.mlp_hidden_act == "relu"
+                    and (not typed or self.first_k_dense_replace)):
             raise NotImplementedError(
                 f"mlp_hidden_act {self.mlp_hidden_act} with moe_latent_size "
                 f"{self.moe_latent_size}: relu2 experts are run in a "
-                "latent, silu experts on the hidden width")
+                "latent, silu experts on the hidden width, relu-gated ones "
+                "in a layer_types model with no dense layer")
         if self.n_mtp_here > 1:
             raise NotImplementedError("more than one next-n module")
         if (self.scoring_func, self.topk_method) not in (
@@ -469,6 +523,29 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
         body.setdefault("layer_types", [
             "linear_attention" if (i + 1) % every else "full_attention"
             for i in range(body["num_hidden_layers"])])
+    thinker = body.get("model_type") == "smallthinker"
+    if thinker:
+        windows, turned = body["sliding_window_layout"], body["rope_layout"]
+        if not body.get("moe_primary_router_apply_softmax"):
+            raise NotImplementedError(
+                f"{path}: moe_primary_router_apply_softmax false: a sigmoid "
+                "over the chosen logits is not run; the router scores by a "
+                "softmax")
+        if list(windows) != list(turned):
+            raise NotImplementedError(
+                f"{path}: rope_layout differs from sliding_window_layout: "
+                "RoPE goes by a layer's kind, so a window layer without it "
+                "or a full layer with it is a kind that is not run")
+        body.setdefault("layer_types", [
+            "sliding_attention" if on else "full_attention"
+            for on in windows])
+    elif "sliding_window_layout" in body or "sliding_window_size" in body \
+            or body.get("sliding_window") or "rope_layout" in body:
+        raise NotImplementedError(
+            f"{path}: sliding_window / sliding_window_layout / rope_layout: "
+            "an attention window and RoPE by layer are a smallthinker "
+            "model's; a window in a hybrid_override_pattern, latent-"
+            "attention or other layer_types model is not run")
     typed = "layer_types" in body       # lfm2_moe: its file names no
     #                                     activation, its code runs silu
     act = body.get("mlp_hidden_act") if hybrid else body.get(
@@ -524,6 +601,18 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
                               "moe_shared_expert_intermediate_size")):
             if theirs in merged:
                 merged.setdefault(ours, merged[theirs])
+    if thinker:
+        for theirs, ours in (("moe_num_primary_experts", "num_experts"),
+                             ("moe_num_active_primary_experts",
+                              "num_experts_per_tok"),
+                             ("moe_ffn_hidden_size", "moe_intermediate_size"),
+                             ("sliding_window_size", "sliding_window")):
+            merged.setdefault(ours, merged[theirs])
+        merged.setdefault("intermediate_size", merged["moe_intermediate_size"])
+        merged.setdefault("rope_kinds", sorted(
+            {t for t, on in zip(merged["layer_types"], turned) if on}))
+        merged.update(qk_norm=False, router_before_attention=True,
+                      mlp_hidden_act="relu")
     if next_:       # its modelling code's, on which config.json is silent
         merged.setdefault("attn_output_gate", True)
         shared = bool(merged.get("moe_shared_expert_intermediate_size"))
@@ -577,7 +666,9 @@ def pattern_layer_shapes(cfg: ModelConfig) -> dict:
     feed-forward's together.  ``conv_*``: the operator norm's gain,
     ``in_proj`` (d, B | C | u), the taps (kernel, d), ``out_proj``;
     ``attn_*``: the gain, q and o over the query heads, k and v over the
-    key-value heads, the per-head QK-norm's two gains; ``*_dense``: the
+    key-value heads, the per-head QK-norm's two gains (none where
+    ``qk_norm`` is false); ``swa_*`` (a ``sliding_attention`` layer): the
+    same leaves; ``*_dense``: the
     feed-forward norm's gain and SwiGLU's three matrices; ``*_moe``: the
     gain, the router over all the experts, the held experts' three, and
     where the model has a shared expert its three and, where that is
@@ -601,7 +692,8 @@ def pattern_layer_shapes(cfg: ModelConfig) -> dict:
                "attn": {"ln1": (d,),
                         "wq": (d, q * (2 if cfg.attn_output_gate else 1)),
                         "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-                        "q_norm": (hd,), "k_norm": (hd,)},
+                        **({"q_norm": (hd,), "k_norm": (hd,)}
+                           if cfg.qk_norm else {})},
                "gdn": {"ln1": (d,), "in_proj": (d, 2 * key + 2 * val),
                        "ba_proj": (d, 2 * cfg.linear_num_value_heads),
                        "conv_w": (cfg.conv_kernel, 2 * key + val),
@@ -609,6 +701,7 @@ def pattern_layer_shapes(cfg: ModelConfig) -> dict:
                        "dt_bias": (cfg.linear_num_value_heads,),
                        "gate_norm": (cfg.linear_value_head_dim,),
                        "out_proj": (val, d)}}
+        ops["swa"] = ops["attn"]    # a window layer holds what a full one does
         moe = {"ln2": (d,), "router": (d, cfg.num_experts),
                "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
         if fs:
@@ -852,12 +945,13 @@ def _walk_layers(run, stacked, x, bias, n: int):
         lambda x, xs: run(xs[0], x, xs[1]), x, (stacked, bias))
 
 
-def _walk_pattern(run, layers, x, bias, cfg: ModelConfig):
+def _walk_pattern(run_of, layers, x, bias, cfg: ModelConfig):
     """The held layers of a ``hybrid_override_pattern`` or ``layer_types``
     model in turn, a run of like layers at a time (``cfg.segments``;
     ``layers`` holds a group a run): a run's unit is called once, or
     scanned over its repeats (``_walk_layers``), each of its layers
-    through ``run``.  ``bias`` (the held expert layers, E) gives each
+    through ``run_of(kind)``, ``kind`` its ``layer_types`` name
+    (``full_attention`` for a pattern's letter, which has no other).  ``bias`` (the held expert layers, E) gives each
     layer with a router its row (None: the routers choose under none).
     Returns (x, what the layers' ``run``
     gave: the routers' statistics and chosen experts and the sampled
@@ -868,7 +962,8 @@ def _walk_pattern(run, layers, x, bias, cfg: ModelConfig):
         def unit_run(group, x, bias_row, unit=unit):
             out = {}
             for letter in unit:
-                x, out[letter] = run(
+                x, out[letter] = run_of(
+                    LETTER_OPERATORS.get(letter.lower(), "full_attention"))(
                     group[PATTERN_KINDS[letter]], x,
                     bias_row if letter in EXPERT_LETTERS else None)
             return x, out
@@ -905,34 +1000,44 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     at = sample_rows(b * s)
     bias = bias or {}
 
-    def run(layer, x, bias_row):
-        x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
-                                    bias=bias_row)
-        experts = seen.pop("experts", None)
-        with jax.named_scope("otpu_stats"):
-            # a router's rows at the sampled ones; of a mixer's scan, or
-            # a short convolution's input, the sequences whole (``_seq``)
-            # and its result at the sampled; q and k around their norm
-            # and RoPE at the sampled
-            out = (jax.tree.map(psum, st), experts, {
-                k if k.startswith(OPERATOR_SAMPLES) else "router_" + k:
-                v if k.endswith("_seq") else v[at] for k, v in seen.items()})
-        return x, out
+    @functools.cache    # one function a kind, so that JAX traces it once
+    def run_of(kind: str = "full_attention"):
+        """A layer's run, ``kind`` its ``layer_types`` name: static,
+        because two kinds of attention layer hold the same leaves."""
+        def run(layer, x, bias_row):
+            x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
+                                        bias=bias_row, kind=kind)
+            experts = seen.pop("experts", None)
+            with jax.named_scope("otpu_stats"):
+                # a router's rows at the sampled ones; of a mixer's scan,
+                # or a short convolution's input, the sequences whole
+                # (``_seq``) and its result at the sampled; q and k around
+                # their norm and RoPE at the sampled
+                out = (jax.tree.map(psum, st), experts, {
+                    k if k.startswith(OPERATOR_SAMPLES) else "router_" + k:
+                    v if k.endswith("_seq") else v[at]
+                    for k, v in seen.items()})
+            return x, out
 
-    if cfg.layers_here + cfg.n_mtp_here > 1:
-        # a layer's activations are recomputed in its backward pass, so
-        # that one layer's are held at a time and not every layer's;
-        # with one layer there is nothing to save.  Kept from the forward
-        # pass are only an expert block's named routing results
-        # (``experts.CHECKPOINT_KEEPS``) and causal attention's o and
-        # logsumexp (``model.CHECKPOINT_KEEPS``)
-        run = jax.checkpoint(run, policy=layer_checkpoint_policy())
+        if cfg.layers_here + cfg.n_mtp_here > 1:
+            # a layer's activations are recomputed in its backward pass,
+            # so that one layer's are held at a time and not every
+            # layer's; with one layer there is nothing to save.  Kept from
+            # the forward pass are only an expert block's named routing
+            # results (``experts.CHECKPOINT_KEEPS``) and causal
+            # attention's o and logsumexp (``model.CHECKPOINT_KEEPS``)
+            return jax.checkpoint(run, policy=layer_checkpoint_policy())
+        return run
+
+    run = run_of()
     with jax.named_scope("otpu_embed"):
         x = params["embed"][tokens]                          # (b, s, d) f32
     with jax.named_scope("otpu_layers"):
         if cfg.pattern_here:
+            # a layer's kind is static: two kinds of attention layer hold
+            # the same leaves, and nothing in them says which one this is
             x, (st, chosen, sample) = _walk_pattern(
-                run, params["layers"], x, bias.get("layers"), cfg)
+                run_of, params["layers"], x, bias.get("layers"), cfg)
         else:
             if cfg.n_dense_here:
                 x, _ = _walk_layers(run, params["dense"], x, None,
@@ -1140,12 +1245,19 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
             ssm_dt_seq=P(None, "dp"))
     if cfg.layer_types:
         held = set(cfg.pattern_here.lower())
+        # an attention layer of a kind RoPE turns reports q and k around
+        # it; every one of a model without a QK-norm
+        turned = {OPERATOR_LETTERS[k] for k in cfg.rope_kinds} & held \
+            if cfg.qk_norm else {"a", "w"} & held
         aux_specs["sample"].update(
-            {k: rows for letter, keys in (
-                ("c", ("conv_bcu_seq", "conv_y")),
-                ("a", ("attn_qk_in", "attn_qk")),
-                ("l", ("gdn_q_seq", "gdn_k_seq", "gdn_v_seq", "gdn_o")))
-             if letter in held for k in keys})
+            {k: rows for on, keys in (
+                ("c" in held, ("conv_bcu_seq", "conv_y")),
+                (turned, ("attn_qk_in", "attn_qk")),
+                ("l" in held, ("gdn_q_seq", "gdn_k_seq", "gdn_v_seq",
+                               "gdn_o")),
+                ("w" in held, ("attn_win_q", "attn_win_k_seq",
+                               "attn_win_v_seq", "attn_win_o")))
+             if on for k in keys})
         if "l" in held:
             aux_specs["sample"].update(gdn_g_seq=P(None, "dp"),
                                        gdn_beta_seq=P(None, "dp"))
@@ -1153,6 +1265,9 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
             aux_specs["sample"].update(attn_og_in=rows, attn_og=rows)
     if cfg.shared_expert_gate:
         aux_specs["sample"]["router_shared_gate"] = rows
+    if cfg.router_before_attention:
+        aux_specs["sample"].update(router_expert_in=rows,
+                                   router_expert_out=rows)
     if cfg.tie_word_embeddings:
         aux_specs["embed_probe_read"] = rep
     if cfg.n_mtp_here:

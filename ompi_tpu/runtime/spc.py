@@ -149,6 +149,10 @@ _COUNTERS = (
     # and went to the flash kernels, or their twins, unrepeated: the
     # second over the first is 1 for a grouped-query model, 0 for the rest
     "attn_built", "attn_shared_kv_built",
+    # those of the passes made under a sliding window, the block pairs
+    # the passes walk, and those full causal passes of their lengths
+    # would: walked over causal is what the windows spare
+    "attn_window_built", "attn_pairs_walked", "attn_pairs_causal",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

@@ -1068,6 +1068,8 @@ STEP_SCOPES = (
     "otpu_gdn_conv",        # the causal depthwise convolution and its silu
     "otpu_gdn_rule",        # L2 norms, g, beta, the chunks, the recurrence
     "otpu_gdn_norm",        # the gated norm: RMSNorm a head, silu(z)
+    "otpu_swa",             # a sliding-window layer's attention sublayer
+                            # (a full layer's keeps otpu_attention)
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

@@ -245,30 +245,31 @@ def cases(mesh1d, mesh2d):
         return (wide(d, bf16), wide(d, bf16, n_kv), wide(hv, bf16, n_kv),
                 wide(hv, bf16), row, row)
 
-    def attn_block_backward(b, h, s, d, hv, n_kv=None):
+    def attn_block_backward(b, h, s, d, hv, n_kv=None, **window):
         n_kv = n_kv or h
         wide = lambda w, n=h: _sds((b, n, s, w), jnp.float32, one, P())
         return fa.attn_block_backward, (
             (_sds((2,), jnp.int32, one, P()),)
             + attn_bwd_args(b, h, s, d, hv, n_kv)
             + (wide(d), wide(d, n_kv), wide(hv, n_kv))), {
-                "block": 1024, "interpret": False}
+                "block": 1024, "interpret": False, **window}
 
-    def attn_backward_walk(b, h, s, d, hv, n_kv=None):
+    def attn_backward_walk(b, h, s, d, hv, n_kv=None, window=None):
         from ompi_tpu.parallel import model
 
         q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv, n_kv)
         o = _sds((b, h, s, hv), jnp.float32, one, P())  # and its cotangent
         return jax.jit(lambda q, k, v, o, lse, do: model._causal_bwd(
-            1024, False, (q, k, v, o, lse), do)), (q, k, v, o, lse, o)
+            1024, False, window, (q, k, v, o, lse), do)), (q, k, v, o, lse,
+                                                          o)
 
     # attention's forward (``model._causal_fwd_blocks`` where Mosaic
     # compiles): one call a layer, q, k and v whole, the blocks through
     # the index maps, the softmax state in VMEM scratch
-    def flash_causal_forward(b, h, s, d, hv, n_kv=None):
+    def flash_causal_forward(b, h, s, d, hv, n_kv=None, **window):
         q, k, v = attn_bwd_args(b, h, s, d, hv, n_kv)[:3]
-        return fa.flash_causal_forward, (q, k, v), {"block": 1024,
-                                                    "interpret": False}
+        return fa.flash_causal_forward, (q, k, v), {
+            "block": 1024, "interpret": False, **window}
 
     case("olmoe_flash_causal_forward",
          lambda: flash_causal_forward(2, 16, 4096, 128, 128))
@@ -299,6 +300,15 @@ def cases(mesh1d, mesh2d):
          lambda: attn_block_backward(1, 16, 16384, 256, 256, 2))
     case("qwen3next_attn_backward_walk_16k",
          lambda: attn_backward_walk(1, 16, 16384, 256, 256, 2))
+    # SmallThinker's window layers: 28 query heads on 4 key-value heads
+    # (7 a group) x 16,384 at a head width of 128 under a window of 4,096:
+    # the forward's grid holds 5 kv tiles a q tile where a full layer's
+    # holds 16, the backward's one loop walks 70 pairs where it walks 136
+    case("smallthinker_flash_window_forward",
+         lambda: flash_causal_forward(1, 28, 16384, 128, 128, 4,
+                                      window=4096))
+    case("smallthinker_attn_window_backward",
+         lambda: attn_backward_walk(1, 28, 16384, 128, 128, 4, window=4096))
     # Nemotron-3-Super's share: 4 query heads on 1 key-value head x
     # 8,192 at a head width of 128, the whole head axis one group
     case("nemotron3_flash_causal_forward",
@@ -529,6 +539,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "lfm2-8b-a1b-train-1chip"))
     case("qwen3next_step_1chip", lambda: model_step(
         topo_devs[:1], "qwen3-next-80b-a3b-train-1chip"))
+    case("smallthinker_step_1chip", lambda: model_step(
+        topo_devs[:1], "smallthinker-21b-a3b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

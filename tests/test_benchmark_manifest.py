@@ -165,6 +165,31 @@ def _cut_batch_next(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# smallthinker-train-1chip: the same, at the widths of
+# tests/test_smallthinker_train.py (one period: a full layer without RoPE
+# and a scanned run of three window layers with it; 8 query heads of 16 on
+# 2 key-value heads, a window of 16 in blocks of 8 over 40 positions, 4 of
+# 16 relu-gated experts, 64 of 256 ids)
+TINY_THINKER = dict(hidden_size=64, head_dim=16, num_attention_heads=8,
+                    num_key_value_heads=2, moe_ffn_hidden_size=24,
+                    moe_num_primary_experts=16,
+                    moe_num_active_primary_experts=3, sliding_window_size=16,
+                    vocab_size=256, vocab_here=64, experts_here=4)
+TINY_THINKER_TRAIN = dict(seq_len=40, micro_batch=1, attn_block=8,
+                          loss_block_rows=8, compute_dtype="float32")
+
+
+def _tiny_thinker(config):
+    config.update(TINY_THINKER)
+    config["train"].update(TINY_THINKER_TRAIN)
+
+
+def _cut_batch_thinker(p):
+    p.update(sequences=TINY_THINKER_TRAIN["micro_batch"],
+             seq_len=TINY_THINKER_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -232,6 +257,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("qwen3-next-80b-a3b-train-1chip", _tiny_next),
         cut={"packed-16k-deltanet-steps": _cut_batch_next}),
+    "smallthinker-train-1chip": dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("smallthinker-21b-a3b-train-1chip", _tiny_thinker),
+        cut={"packed-16k-window-steps": _cut_batch_thinker}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -241,7 +271,7 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
                       "train_ssm_layer_tokens", "moe_bias_updates")
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
-              "qwen3next-train-1chip")
+              "qwen3next-train-1chip", "smallthinker-train-1chip")
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -501,8 +531,9 @@ def test_the_build_metrics_are_entries_of_the_manifest(mf, real):
                                  if c.endswith("-train-1chip")]
     assert (peak["moves"], peak["better"]) == ("small_msg_us", "lower")
     assert peak["layer"] == by_name["train.mfu"]["layer"]
-    assert [m["name"] for m in real["per_layer"][-8:-1]] \
-        == list(BUILD_METRICS) + [PEAK_METRIC]
+    names = [m["name"] for m in real["per_layer"]]
+    at = names.index(BUILD_METRICS[0])
+    assert names[at:at + 7] == list(BUILD_METRICS) + [PEAK_METRIC]
 
 
 def test_the_convolutions_kernel_share_is_an_entry_of_the_manifest(real):
@@ -511,7 +542,8 @@ def test_the_convolutions_kernel_share_is_an_entry_of_the_manifest(real):
     layer and the end-to-end metric of the rule's ``gdn.kernel_share``."""
     by_name = {m["name"]: m for m in real["per_layer"]}
     conv, rule = by_name["gdn.conv_kernel_share"], by_name["gdn.kernel_share"]
-    assert real["per_layer"][-1] is conv
+    assert real["per_layer"][real["per_layer"].index(
+        by_name[PEAK_METRIC]) + 1] is conv
     assert {k: v for k, v in conv.items() if k != "name"} \
         == {k: v for k, v in rule.items() if k != "name"}
     assert conv["workloads"] == ["qwen3next-train-1chip"]
@@ -714,6 +746,70 @@ def test_a_delta_rule_step_counts_its_routers_and_its_slots(rehearsal):
     assert rehearsal["builds"] == [1, 1]
 
 
+@of_cells("smallthinker-train-1chip")
+def test_a_window_step_counts_its_routers_and_its_slots(rehearsal):
+    """The trainer's counters on one chip's share of smallthinker's
+    period, by the kind that reads everything from the kit and with no
+    file of the harness edited for it: 4 routers before attention that
+    choose by softmax under no bias; over the steps read back every slot
+    went to a held expert or to an absent one; the step's program is the
+    one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_THINKER_TRAIN["micro_batch"] * TINY_THINKER_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.smallthinker.bf16.1x16384"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert not set(PER_STEP_CONSTANTS) & set(c)
+    assert c["train_steps_read"] >= 3
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 3 * 4
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
+    """Appended behind everything that was there (PR 56): data files on
+    readers that are there, in the one cell whose model has a window, each
+    moving ``small_msg_us``; the window's three under a layer of their
+    own; the cell's name at the end of the lists every step cell is in."""
+    cell = "smallthinker-train-1chip"
+    names = [m["name"] for m in real["per_layer"]]
+    new = ["smallthinker.mfu", "smallthinker.tokens_per_s",
+           "smallthinker.local_load", "smallthinker.remat_share",
+           "smallthinker.unnamed_share", "smallthinker.flash_mfu",
+           "smallthinker.attn_bwd_mfu", "swa.operator_share",
+           "attn.window_share", "attn.pairs_walked_share"]
+    assert names[-10:] == new
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    for name in new:
+        m = by_name[name]
+        assert (m["workloads"], m["moves"]) == ([cell], "small_msg_us")
+        twin = by_name.get(name.replace("smallthinker.", "qwen3next."))
+        if twin and twin is not m:
+            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
+                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+    assert by_name["smallthinker.attn_bwd_mfu"]["layer"] \
+        == by_name["kernel.flash_mfu"]["layer"]
+    assert len({by_name[n]["layer"] for n in new[-3:]}) == 1
+    assert real["workloads"][-1]["name"] == cell \
+        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
+    for m in real["end_to_end"] + real["per_layer"]:
+        if "qwen3next-train-1chip" in m.get("workloads", ()) \
+                and len(m["workloads"]) > 1:
+            assert m["workloads"][-1] == cell, m["name"]
+    for name, params in (
+            ("attn.window_share", {"name": "attn_window_built",
+                                   "over": "attn_built", "scale": 100}),
+            ("attn.pairs_walked_share", {"name": "attn_pairs_walked",
+                                         "over": "attn_pairs_causal",
+                                         "scale": 100})):
+        with open(os.path.join(BENCH, "metrics", name + ".json"),
+                  encoding="utf-8") as f:
+            spec = json.load(f)
+        assert (spec["reader"], spec["params"]) == ("program_counter",
+                                                    params)
+
+
 def test_train_check_tells_the_program_from_its_control(tmp_path):
     """``benchmark/tools/train_check.py`` at the rehearsal's widths: one
     step of the program lies within the kind's tolerance of the
@@ -890,6 +986,53 @@ def test_kit_check_tells_the_delta_rule_program_from_its_controls(tmp_path):
                                      for r in rows)
     assert summary["parts_rule_bf16"] == min(
         r["parts_rule_bf16"]["widest_units"] for r in rows)
+
+
+def test_kit_check_tells_the_window_program_from_its_controls(tmp_path):
+    """``benchmark/tools/kit_check.py`` on SmallThinker's cell at the
+    rehearsal's widths, with no file of the harness edited for it: one
+    step of the program lies within the kind's tolerance of
+    ``smallthinkerkit``'s reference (dense masked softmax, the window as
+    its inequality); the reference computed in bfloat16 lies far outside
+    the program's, and each of the kit's seven controls of a part outside
+    the tolerance at that part: a bfloat16 router and head, the window
+    left out, RoPE on the full layer, RoPE left off a window layer, the
+    router reading the post-attention stream, silu in relu's place, the
+    chosen weights left unnormalised."""
+    env = _stage("smallthinker-train-1chip", str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", "smallthinker-train-1chip", "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "2", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    (summary,) = [json.loads(ln[8:]) for ln in done.stdout.splitlines()
+                  if ln.startswith("summary ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["widest_units"] < 0.05
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        for variant, part in (("bf16", "head_rows"),
+                              ("no_window", "window_o"),
+                              ("rope_full", "rope_qk"),
+                              ("no_rope_window", "rope_qk"),
+                              ("router_post", "router_logits"),
+                              ("unnormalised", "router_weights")):
+            assert row["parts_" + variant]["units_by_group"][part] > 1, \
+                variant
+        # an expert 24 wide from matrices of 0.02 makes a hundredth of what
+        # one 768 wide does, and the part's unit is set at the published
+        # widths (silu reads 25 there): here it is told from the program's
+        silu = row["parts_silu"]["units_by_group"]["expert_out"]
+        assert silu > 0.2 and silu > 100 * row["program"][
+            "units_by_group"]["expert_out"]
+    assert summary["program"] == max(r["program"]["widest_units"]
+                                     for r in rows)
+    assert summary["parts_no_window"] == min(
+        r["parts_no_window"]["widest_units"] for r in rows)
 
 
 def test_a_share_of_the_busy_seconds_counts_no_loop_twice(tmp_path,

@@ -485,6 +485,66 @@ def test_the_delta_rule_aot_compiles_at_the_cells_shape(qwen3next_rows):
 
 
 @pytest.fixture(scope="module")
+def smallthinker_rows():
+    """One child for the SmallThinker-21BA3B cases: attention's two
+    kernels under a window of 4,096 at the cell's shape and the whole step
+    of the cell's own configuration file, for one v5e device (about a
+    minute of the 600)."""
+    return _rows_with_texts("smallthinker_")
+
+
+def test_the_window_kernels_aot_compile_at_the_cells_shape(
+        smallthinker_rows):
+    """28 query heads on 4 key-value heads (7 a group) x 16,384 positions
+    at a head width of 128 under a window of 4,096: the forward kernel in
+    one call whose grid holds 5 kv tiles a q tile, the backward's 70 block
+    pairs one ``lax.scan`` with the far pair's masked strips in the
+    kernel; both under the kernels' own VMEM limits as they are, and k
+    and v repeated a query head nowhere."""
+    row = smallthinker_rows["smallthinker_flash_window_forward"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call", 0) >= 1, row["entry_ops"]
+    walk = smallthinker_rows["smallthinker_attn_window_backward"]
+    assert walk.get("compiled"), json.dumps(walk, indent=1)
+    assert walk["entry_ops"].get("while") == 1, walk["entry_ops"]
+    with open(walk["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert re.search(r"s32\[70,2\]", text) \
+        and not re.search(r"s32\[136,2\]", text)
+    for case in ("smallthinker_flash_window_forward",
+                 "smallthinker_attn_window_backward",
+                 "smallthinker_step_1chip"):
+        with open(smallthinker_rows[case]["hlo"], encoding="utf-8") as f:
+            assert not re.search(r"bf16\[1,4,7,16384,128\]", f.read()), case
+
+
+def test_smallthinker_train_step_aot_compiles_from_the_cells_configuration(
+        smallthinker_rows):
+    """The whole step of ``benchmark/configs/smallthinker-21b-a3b-train-
+    1chip.json`` (published widths; layers 0-3 of 52, 16 of 64 experts, 1
+    x 16,384 tokens): it fits the chip beside its 7.9 GB of state, the
+    three like window layers are one loop, the forward kernel stands once
+    in the full layer (under ``otpu_attention``) and once in the window
+    run's body (under ``otpu_swa``) and nowhere in a recomputed pass, and
+    the routers' float32 products stand before their layers'
+    attention."""
+    row = smallthinker_rows["smallthinker_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 656_529_920 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    forward = sorted(p for p in kernels if "/otpu_flash_causal_forward/" in p)
+    assert not [p for p in forward if "rematted_computation" in p]
+    assert [("otpu_swa" in p, "otpu_attention" in p) for p in forward] \
+        == [(False, True), (True, False)], forward
+    backward = [p for p in kernels if "/otpu_attn_block_backward/" in p]
+    assert {"otpu_swa" in p for p in backward} == {True, False}
+
+
+@pytest.fixture(scope="module")
 def gmm_rows():
     """One child for the experts' grouped matmul at the four model
     cells' shapes, forward and both transposed products of both expert
@@ -534,7 +594,8 @@ def fits_a_v5e(row) -> bool:
     ("joyai_rows", "joyai_step_1chip"),
     ("nemotron_rows", "nemotron3_step_1chip"),
     ("lfm2_rows", "lfm2_step_1chip"),
-    ("qwen3next_rows", "qwen3next_step_1chip")])
+    ("qwen3next_rows", "qwen3next_step_1chip"),
+    ("smallthinker_rows", "smallthinker_step_1chip")])
 def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
                                                             request):
     """A walked layer's checkpoint keeps what the expert block names

@@ -23,6 +23,7 @@ from test_lfm2_train import F32 as LFM2
 from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 from test_qwen3next_train import F32 as QWEN3NEXT
+from test_smallthinker_train import F32 as SMALLTHINKER
 
 from ompi_tpu.parallel import train
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -243,13 +244,20 @@ def qwen3next():
     return step, step.scopes()
 
 
+@pytest.fixture(scope="module")
+def smallthinker():
+    step, args = built(SMALLTHINKER)
+    step(*args)
+    return step, step.scopes()
+
+
 def ran(scopes):
     return {k: v for k, v in scopes["ops"].items()
             if v["opcode"] not in trace.TRIVIAL_OPCODES}
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next"])
+                                   "qwen3next", "smallthinker"])
 def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     scopes = request.getfixturevalue(which)[1]
     assert scopes["module"] == "jit_otpu_train_step"
@@ -266,6 +274,7 @@ def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
         == (which == "lfm2")
     assert ({"otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
              "otpu_gdn_norm"} <= named) == (which == "qwen3next")
+    assert ("otpu_swa" in named) == (which == "smallthinker")
     assert {v["pass"] for v in scopes["ops"].values()} <= {
         None, *trace.PASSES}
 
@@ -301,7 +310,7 @@ def test_every_op_of_a_short_convolution_lands_under_its_scope(lfm2):
     pass, the recomputed one and the backward one; the new names stand
     behind the vocabulary's earlier ones; and the layer's other sublayers
     keep the scopes they have in the other models."""
-    assert trace.STEP_SCOPES[-8:-5] == ("otpu_conv", "otpu_conv_proj",
+    assert trace.STEP_SCOPES[-9:-6] == ("otpu_conv", "otpu_conv_proj",
                                         "otpu_conv_gate")
     ops = ran(lfm2[1])
     parts = {"otpu_conv_proj", "otpu_conv_gate"}
@@ -327,7 +336,7 @@ def test_every_op_of_a_delta_net_operator_lands_under_its_scope(qwen3next):
     behind the vocabulary's earlier ones; the attention gate lies under
     ``otpu_attn_proj`` and the shared expert's gate under
     ``otpu_shared_expert``; and no bias update is in the step."""
-    assert trace.STEP_SCOPES[-5:] == (
+    assert trace.STEP_SCOPES[-6:-1] == (
         "otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
         "otpu_gdn_norm")
     ops = ran(qwen3next[1])
@@ -350,8 +359,26 @@ def test_every_op_of_a_delta_net_operator_lands_under_its_scope(qwen3next):
                 "otpu_conv"} & named
 
 
+def test_a_window_layers_attention_lands_under_its_own_scope(smallthinker):
+    """The three window layers' attention sublayers (one scanned run) are
+    under ``otpu_swa`` in every pass, their projections under
+    ``otpu_attn_proj`` inside it; the full layer's keeps
+    ``otpu_attention``; no instruction is under both; and the routers'
+    products, made before attention, are under ``otpu_moe`` /
+    ``otpu_router`` and in the forward pass alone."""
+    ops = ran(smallthinker[1])
+    swa = [v for v in ops.values() if "otpu_swa" in v["chain"]]
+    full = [v for v in ops.values() if "otpu_attention" in v["chain"]]
+    assert len(swa) > 50 and len(full) > 50
+    assert not [v for v in swa if "otpu_attention" in v["chain"]]
+    for under in (swa, full):
+        assert {"forward", "remat", "backward"} <= {v["pass"] for v in under}
+        assert any("otpu_attn_proj" in v["chain"] for v in under)
+    assert trace.STEP_SCOPES[-1] == "otpu_swa"
+
+
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next"])
+                                   "qwen3next", "smallthinker"])
 def test_every_instruction_the_program_wrote_has_a_chain(which, request):
     """Not a parameter, constant, tuple or bitcast, and with a path of
     the program's (``pass`` None: the compiler's own, which on the CPU
@@ -399,7 +426,7 @@ ROUTING = {
 ATTENTION = {
     "attention's products": lambda line, path:
         path.endswith("/dot_general") and trace.scope_of_path(path)[0][-1:]
-        in (["otpu_mla"], ["otpu_attention"]),
+        in (["otpu_mla"], ["otpu_attention"], ["otpu_swa"]),
 }
 
 
@@ -422,8 +449,10 @@ def routing_by_pass(cfg, kinds_of=ROUTING):
 @pytest.mark.parametrize("cfg,loop_is_read", [(JOYAI, False),
                                               (NEMOTRON, True),
                                               (LFM2, False),
-                                              (QWEN3NEXT, False)],
-                         ids=["joyai", "nemotron", "lfm2", "qwen3next"])
+                                              (QWEN3NEXT, False),
+                                              (SMALLTHINKER, False)],
+                         ids=["joyai", "nemotron", "lfm2", "qwen3next",
+                              "smallthinker"])
 def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         cfg, loop_is_read, monkeypatch):
     """``model_loss``'s checkpoint keeps what an expert block names
@@ -451,8 +480,10 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         set() if loop_is_read else {"experts' loop"})
 
 
-@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2, QWEN3NEXT],
-                         ids=["joyai", "nemotron", "lfm2", "qwen3next"])
+@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2, QWEN3NEXT,
+                                 SMALLTHINKER],
+                         ids=["joyai", "nemotron", "lfm2", "qwen3next",
+                              "smallthinker"])
 def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
                                                               monkeypatch):
     """``model_loss``'s checkpoint keeps causal attention's o and
