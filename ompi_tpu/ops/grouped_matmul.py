@@ -18,7 +18,9 @@ no accumulator stands between the MXU and the result.
 - ``gmm``: ``(m, k) x (g, k, n) -> (m, n)``, or with ``transpose_rhs``
   ``(m, k) x (g, n, k) -> (m, n)`` (the cotangent of the rows);
 - ``tgmm``: ``(m, k), (m, n) -> (g, k, n)``, a group's weight gradient,
-  zeros for an empty group.
+  zeros for an empty group; or, given a running sum ``acc`` (g, k, n),
+  that sum with the gradient added in place: a group with no row is not
+  visited and keeps what it held (megablox's ``existing_out``).
 
 bfloat16 operands, float32 accumulation on the MXU, float32 results.
 Rows past the last group belong to no tile: ``gmm`` leaves them as they
@@ -72,19 +74,21 @@ def gmm_tiles(m: int, k: int, n: int):
     return None
 
 
-def tgmm_tiles(m: int, k: int, n: int):
+def tgmm_tiles(m: int, k: int, n: int, acc: bool = False):
     """(row tile, ``k`` tile, ``n`` tile) of ``tgmm``, or None: the rows
     are the contraction here, the result's (k, n) tile stays in VMEM
     while a group's row tiles add to it, and the tiles that fit and read
     the rows again least often are taken (whole matrices at the cells'
-    shapes: 2,048 x 1,792 float32 twice is 29 MiB)."""
+    shapes: 2,048 x 1,792 float32 twice is 29 MiB).  With ``acc`` the
+    running sum's tile comes in beside the result's."""
     tm = min(m, ROW_TILE)
     if m % tm or tm % 16:
         return None
     best = None
     for tk in _widths(k):
         for tn in _widths(n):
-            if 2 * (tm * tk * 2 + tm * tn * 2 + tk * tn * 4) > VMEM_TILES:
+            if 2 * (tm * tk * 2 + tm * tn * 2
+                    + (1 + acc) * tk * tn * 4) > VMEM_TILES:
                 continue
             reread = k * (n // tn) + n * (k // tk)
             if best is None or reread < best[0]:
@@ -199,7 +203,8 @@ def gmm(lhs, rhs, sizes, *, transpose_rhs: bool = False, tiles=None,
     )(*table, lhs, rhs)
 
 
-def _tgmm_kernel(tm, offsets, group_ids, tile_ids, lhs, rhs, out):
+def _tgmm_kernel(tm, offsets, group_ids, tile_ids, lhs, rhs, *acc_out):
+    *acc, out = acc_out
     i = pl.program_id(2)
     group = group_ids[i]
     whole, mine = _rows_of_group(offsets, group, tile_ids[i], tm)
@@ -209,7 +214,7 @@ def _tgmm_kernel(tm, offsets, group_ids, tile_ids, lhs, rhs, out):
 
     @pl.when((i == 0) | (group_ids[jnp.maximum(i - 1, 0)] != group))
     def _():
-        out[...] = jnp.zeros_like(out)
+        out[...] = acc[0][...] if acc else jnp.zeros_like(out)
 
     @pl.when(whole)
     def _():
@@ -225,13 +230,20 @@ def _tgmm_kernel(tm, offsets, group_ids, tile_ids, lhs, rhs, out):
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
-def tgmm(lhs, rhs, sizes, *, tiles=None, interpret: bool = False):
+def tgmm(lhs, rhs, sizes, acc=None, *, tiles=None, interpret: bool = False):
     """A group's ``lhs`` rows (m, k) transposed times its ``rhs`` rows
-    (m, n): (g, k, n) float32, zeros where a group has no row."""
+    (m, n): (g, k, n) float32, zeros where a group has no row.  With
+    ``acc`` (g, k, n) float32 the result is ``acc`` plus that, written
+    over ``acc``'s own buffer: a group's first visit starts from what is
+    there, and a group with no row is not visited at all, so a call costs
+    by the groups that have rows and not by ``g``."""
     (m, k), n, g = lhs.shape, rhs.shape[1], sizes.shape[0]
-    tm, tk, tn = tiles or tgmm_tiles(m, k, n)
-    table, steps = group_tiles(sizes, m, tm, visit_empty=True)
+    tm, tk, tn = tiles or tgmm_tiles(m, k, n, acc is not None)
+    table, steps = group_tiles(sizes, m, tm, visit_empty=acc is None)
     vma = jax.typeof(lhs).vma | jax.typeof(rhs).vma | jax.typeof(sizes).vma
+    out_spec = pl.BlockSpec(
+        (None, tk, tn), lambda j, c, i, off, gid, tid: (gid[i], c, j))
+    sums = () if acc is None else (acc,)
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm),
         out_shape=jax.ShapeDtypeStruct((g, k, n), jnp.float32, vma=vma),
@@ -241,11 +253,12 @@ def tgmm(lhs, rhs, sizes, *, tiles=None, interpret: bool = False):
                 pl.BlockSpec((tm, tk),
                              lambda j, c, i, off, gid, tid: (tid[i], c)),
                 pl.BlockSpec((tm, tn),
-                             lambda j, c, i, off, gid, tid: (tid[i], j))],
-            out_specs=pl.BlockSpec(
-                (None, tk, tn),
-                lambda j, c, i, off, gid, tid: (gid[i], c, j)),
+                             lambda j, c, i, off, gid, tid: (tid[i], j)),
+                *[out_spec] * len(sums)],
+            out_specs=out_spec,
             grid=(n // tn, k // tk, steps)),
+        # the three table operands count: ``acc`` is the sixth
+        input_output_aliases={5: 0} if sums else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -255,4 +268,4 @@ def tgmm(lhs, rhs, sizes, *, tiles=None, interpret: bool = False):
                             + 4 * g * k * n)),
         interpret=interpret,
         name="otpu_gmm_t",
-    )(*table, lhs, rhs)
+    )(*table, lhs, rhs, *sums)

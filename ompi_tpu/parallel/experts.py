@@ -105,22 +105,41 @@ def _grouped_matmul(sizes, compute_dtype, interpret: bool):
     Mosaic compiles (``interpret`` false: a TPU), the inputs are bfloat16
     and the shape has tiles (``ops/grouped_matmul.supported``) it is the
     Pallas kernel (``_kernel_matmul``); everywhere else
-    ``lax.ragged_dot``."""
+    ``lax.ragged_dot``.  And ``transposes(a, w, ct, acc)``, what a
+    backward pass that is written out calls (``local_expert_ffn``'s):
+    (the cotangent of ``a`` under ``ct``, the running float32 sum ``acc``
+    (g, k, n) with ``w``'s gradient added), by the same two kernels as
+    ``_kernel_matmul_bwd`` with ``acc`` updated in place, or by
+    ``ragged_dot``'s own transposes and an add."""
     f32 = jnp.dtype(compute_dtype) == jnp.float32
     prec = jax.lax.Precision.HIGHEST if f32 else None
     on_mosaic = not interpret and jnp.dtype(compute_dtype) == jnp.bfloat16
     if on_mosaic:
         from ompi_tpu.ops import grouped_matmul as kernel
 
+    def on_kernel(a, w):
+        return on_mosaic and kernel.supported(*a.shape, w.shape[2])
+
     def gmm(a, w):
-        if on_mosaic and kernel.supported(*a.shape, w.shape[2]):
+        if on_kernel(a, w):
             return _kernel_matmul(a, w, sizes, compute_dtype)
         _count_built(1, False)
         return jax.lax.ragged_dot(
             a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
             precision=prec, preferred_element_type=jnp.float32)
 
-    return gmm
+    def transposes(a, w, ct, acc):
+        if not on_kernel(a, w):
+            da, dw = jax.vjp(gmm, a, w)[1](ct)
+            return da, acc + dw
+        _count_built(2, True)
+        ct = ct.astype(compute_dtype)
+        da = kernel.gmm(ct, cast_param(w, compute_dtype), sizes,
+                        transpose_rhs=True)
+        return da.astype(a.dtype), kernel.tgmm(
+            a.astype(compute_dtype), ct, sizes, acc)
+
+    return gmm, transposes
 
 
 #: a gated expert's activation by the configuration's name for it:
@@ -133,18 +152,48 @@ def grouped_expert_ffn(xs, gate, up, down, sizes, compute_dtype,
     """Gated experts on slots sorted by expert: ``down(act(gate x) * up
     x)`` as three grouped matmuls (``_grouped_matmul``); ``act`` is
     SwiGLU's ``silu`` or ReGLU's ``relu`` (``GATE_ACTS``)."""
-    gmm = _grouped_matmul(sizes, compute_dtype, interpret)
+    gmm, _ = _grouped_matmul(sizes, compute_dtype, interpret)
     hidden = GATE_ACTS[act](gmm(xs, gate)) * gmm(xs, up)
     return gmm(hidden, down)
 
 
-def grouped_relu2_ffn(xs, up, down, sizes, compute_dtype,
-                      interpret: bool = True):
+def grouped_expert_ffn_vjp(xs, gate, up, down, sizes, compute_dtype,
+                           interpret: bool = True, act: str = "silu"):
+    """``grouped_expert_ffn``'s value and ``back(dy, sums)``: under the
+    value's cotangent ``dy``, (``xs``'s cotangent, the running float32
+    sums of ``gate``'s, ``up``'s and ``down``'s gradients with this
+    call's added: ``_grouped_matmul``'s ``transposes``)."""
+    gmm, transposes = _grouped_matmul(sizes, compute_dtype, interpret)
+    hidden, act_back = jax.vjp(lambda g, u: GATE_ACTS[act](g) * u,
+                               gmm(xs, gate), gmm(xs, up))
+
+    def back(dy, sums):
+        dhidden, sum_down = transposes(hidden, down, dy, sums[2])
+        dg, du = act_back(dhidden)
+        by_gate, sum_gate = transposes(xs, gate, dg, sums[0])
+        by_up, sum_up = transposes(xs, up, du, sums[1])
+        return by_gate + by_up, (sum_gate, sum_up, sum_down)
+
+    return gmm(hidden, down), back
+
+
+def grouped_relu2_ffn_vjp(xs, up, down, sizes, compute_dtype,
+                          interpret: bool = True):
     """relu2 experts (nemotron_h: two matrices, no gate) on slots sorted
-    by expert: ``down(relu(up x)^2)`` as two grouped matmuls
-    (``_grouped_matmul``)."""
-    gmm = _grouped_matmul(sizes, compute_dtype, interpret)
-    return gmm(jnp.square(jax.nn.relu(gmm(xs, up))), down)
+    by expert, ``down(relu(up x)^2)`` as two grouped matmuls
+    (``_grouped_matmul``): the value and ``back(dy, sums)``, as
+    ``grouped_expert_ffn_vjp``'s: (``xs``'s cotangent, the running sums
+    of ``up``'s and ``down``'s gradients)."""
+    gmm, transposes = _grouped_matmul(sizes, compute_dtype, interpret)
+    hidden, act_back = jax.vjp(lambda a: jnp.square(jax.nn.relu(a)),
+                               gmm(xs, up))
+
+    def back(dy, sums):
+        dhidden, sum_down = transposes(hidden, down, dy, sums[1])
+        dxs, sum_up = transposes(xs, up, *act_back(dhidden), sums[0])
+        return dxs, (sum_up, sum_down)
+
+    return gmm(hidden, down), back
 
 
 def moe_sorted_block(p, x, cfg, *, interpret: bool = True):
@@ -248,30 +297,61 @@ def local_dispatch(experts, first: int, n_here: int):
     return order, sizes
 
 
+#: the most row tiles (``ops/grouped_matmul.ROW_TILE``) a trip of the held
+#: experts' loop walks.  On the v5e 2,048 rows were the fastest or within
+#: 0.3% of it in all five cells that hold a share (200 to 2,300 rows a
+#: group, rows of 1,024 to 2,560 floats): at 1,024 the trips' own cost
+#: shows, at 4,096 and up the rows past the last slot do, and XLA's
+#: scatter-add of 4,096 to 16,384 rows of 2,560 floats runs at a third of
+#: its rate (my chip runs, PR 57: ``PERF.md`` section 6)
+CHUNK_TILES = 8
+
+
+def chunk_rows(t: int, k: int, held: int, total: int) -> int:
+    """The rows a trip of ``local_expert_ffn``'s loop walks, from the
+    shapes alone (``t`` tokens of ``k`` slots each, ``held`` of ``total``
+    experts here): half the mean load rounded up to a power of two, so
+    that small shapes run several trips too, and at most ``CHUNK_TILES``
+    row tiles, which is what every cell's shapes give.  Whole row tiles,
+    or whole sublane tiles of 16 under one: every trip's products have
+    tiles (``ops/grouped_matmul.supported``)."""
+    from ompi_tpu.ops.grouped_matmul import ROW_TILE
+
+    mean = max(1, t * k * held // total)
+    return min(CHUNK_TILES * ROW_TILE,
+               max(16, (1 << (mean - 1).bit_length()) // 2))
+
+
 def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
-                     ffn=grouped_expert_ffn, interpret: bool = True):
+                     ffn=grouped_expert_ffn_vjp, interpret: bool = True):
     """The held experts' weighted part of the layer's output (T, d):
     gather the held slots' rows, grouped matmuls (``ffn`` over the held
-    experts' stacked matrices ``mats``: SwiGLU's three, or relu2's two
-    with ``grouped_relu2_ffn``), scatter-add by token.
+    experts' stacked matrices ``mats``: ``grouped_expert_ffn_vjp`` over
+    a gated expert's three, ``grouped_relu2_ffn_vjp`` over relu2's two),
+    scatter-add by token.
     The slots held vary from step to step (0 to every slot a token can
-    send here) and none is dropped.  They are walked in chunks of twice
-    the mean load's rows by a loop that runs as many times as the held
-    slots need (``lax.fori_loop`` to a count read on the device): gather,
-    matmuls and scatter cost by the slots that are here, to a chunk, and
-    not by T x k, and one chunk's buffers are held at a time.  Such a
+    send here) and none is dropped.  They are walked in chunks of
+    ``chunk_rows`` rows by a loop that runs as many times as the held
+    slots need (``lax.fori_loop`` to a count read on the device), so a
+    hot rank runs more trips and one chunk's buffers are held at a time.
+    A trip costs by the rows of its chunk and by the experts that have
+    rows in it, and by nothing else: the matrices are cast once, before
+    the loop, and every sum the loop makes is added to in place.  Such a
     loop has no transpose, so the backward pass is written out: the same
     loop over the same chunks, each chunk's forward recomputed and its
-    cotangents added up."""
+    cotangents added where they belong, the rows' and the weights' by
+    scatter-add into the carry, the matrices' by the kernel into their
+    running float32 sums (``_grouped_matmul``'s ``transposes``: an
+    expert with no row in the chunk is not touched)."""
     t, d = h.shape
     k, n_here = cfg.num_experts_per_tok, sizes.shape[0]
-    mean = max(1, t * k * n_here // cfg.num_experts)
-    rows = 2 * max(4, 1 << (mean - 1).bit_length())
+    rows = chunk_rows(t, k, n_here, cfg.num_experts)
     padded = -(-t * k // rows) * rows
     order = jnp.pad(order, (0, padded - t * k))
 
     def chunk(lo, order, sizes, h, flat_w, *mats):
-        """Chunk ``lo``'s (token of each row, its weighted output)."""
+        """Chunk ``lo``'s (slot and token of each row, which rows hold a
+        slot, their weights, their experts' output, its ``back``)."""
         with jax.named_scope("otpu_dispatch"):
             slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
             token = slot // k
@@ -283,36 +363,63 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         # matmul leaves them as they were in memory (seen on the v5e:
         # NaN), in its transposes too, so they are cut off on both sides
         xs = jnp.where(live[:, None], h[token], 0.0)
-        y = ffn(xs, *mats, here, cfg.compute_dtype, interpret)
+        y, back = ffn(xs, *mats, here, cfg.compute_dtype, interpret)
         with jax.named_scope("otpu_combine"):
             w = jnp.where(live, flat_w[slot], 0.0)
-            return token, jnp.where(live[:, None], y, 0.0) * w[:, None]
+            return slot, token, live, w, jnp.where(live[:, None], y, 0.0), back
 
     def trips(sizes):
         return (jnp.sum(sizes) + rows - 1) // rows
 
+    def cast(mats):
+        return tuple(cast_param(m, cfg.compute_dtype) for m in mats)
+
+    def zeros(like, *args):
+        """A loop's starting sums, shaped as ``like`` (array, dtype)
+        pairs and varying as ``args`` do, as the body's results will."""
+        zero = [jnp.zeros(a.shape, dtype) for a, dtype in like]
+        vma = tuple(frozenset().union(*(jax.typeof(a).vma for a in args)))
+        return jax.lax.pcast(zero, vma, to="varying") if vma else zero
+
     @jax.custom_vjp
     def run(order, sizes, h, flat_w, *mats):
+        mats = cast(mats)
+
         def body(c, out):
-            token, y = chunk(c * rows, order, sizes, h, flat_w, *mats)
+            _, token, _, w, y, _ = chunk(c * rows, order, sizes, h, flat_w,
+                                         *mats)
             with jax.named_scope("otpu_combine"):
-                return out.at[token].add(y)
-        return jax.lax.fori_loop(0, trips(sizes), body, h * 0)
+                return out.at[token].add(y * w[:, None])
+        (out,) = zeros([(h, h.dtype)], order, sizes, h, flat_w, *mats)
+        return jax.lax.fori_loop(0, trips(sizes), body, out)
 
     def fwd(*args):
         return run(*args), args
 
     def bwd(args, ct):
-        order, sizes, *diff = args
+        order, sizes, h, flat_w, *mats = args
+        mats16 = cast(mats)
 
-        def body(c, acc):
-            token = jax.lax.dynamic_slice_in_dim(order, c * rows, rows) // k
-            _, vjp = jax.vjp(lambda *diff: chunk(
-                c * rows, order, sizes, *diff)[1], *diff)
-            return jax.tree.map(jnp.add, acc, vjp(ct[token]))
+        def body(c, sums):
+            dh, dw, dmats = sums
+            slot, token, live, w, y, back = chunk(
+                c * rows, order, sizes, h, flat_w, *mats16)
+            with jax.named_scope("otpu_combine"):
+                dout = ct[token]
+                dw = dw.at[slot].add(
+                    jnp.where(live, jnp.sum(y * dout, axis=1), 0.0))
+                dy = jnp.where(live[:, None], dout * w[:, None], 0.0)
+            dxs, dmats = back(dy, dmats)
+            return (dh.at[token].add(jnp.where(live[:, None], dxs, 0.0)),
+                    dw, dmats)
 
-        return (None, None) + tuple(jax.lax.fori_loop(
-            0, trips(sizes), body, tuple(a * 0 for a in diff)))
+        dh, dw, *dmats = zeros(
+            [(h, h.dtype), (flat_w, flat_w.dtype)]
+            + [(m, jnp.float32) for m in mats], ct, *args)
+        zero = (dh, dw, tuple(dmats))
+        dh, dw, dmats = jax.lax.fori_loop(0, trips(sizes), body, zero)
+        return (None, None, dh, dw) + tuple(
+            s.astype(m.dtype) for s, m in zip(dmats, mats))
 
     run.defvjp(fwd, bwd)
     return run(order, sizes, h, weights.reshape(t * k), *mats)
@@ -417,7 +524,7 @@ def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True,
     with jax.named_scope("otpu_experts"):
         out = local_expert_ffn(
             h, order, seen["weights"], sizes, (p["gate"], p["up"], p["down"]),
-            cfg, functools.partial(grouped_expert_ffn,
+            cfg, functools.partial(grouped_expert_ffn_vjp,
                                    act=cfg.mlp_hidden_act), interpret)
         if routed is not None:
             # the router read other rows than the experts: what these read
@@ -451,8 +558,8 @@ def moe_latent_block(p, x, cfg, bias, *, interpret: bool = True):
         # backward pass runs the held experts' loop once more to make it
         latent = checkpoint_name(
             local_expert_ffn(latent, order, seen["weights"], sizes,
-                             (p["up"], p["down"]), cfg, grouped_relu2_ffn,
-                             interpret),
+                             (p["up"], p["down"]), cfg,
+                             grouped_relu2_ffn_vjp, interpret),
             LATENT_SUM)
     with jax.named_scope("otpu_latent"):
         out = out + matmul(latent, p["lat_up"], dt)

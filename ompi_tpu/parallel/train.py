@@ -74,7 +74,8 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: last); ``rows`` (T, 2) every row's logsumexp over the vocabulary and
 #: its label's logit (``mtp_rows``: the module's head);
 #: ``experts`` (L, T, k) the experts every token chose; ``local_slots``
-#: the slots that went to experts held here, where the rank holds a
+#: the slots that went to experts held here and ``chunk_rows`` the rows
+#: the held experts' loops walked for them, where the rank holds a
 #: share of them; by leaf in ``leaf_names(cfg)``'s order ``grad_sq`` (the
 #: gradient's sum of squares), ``grad_probe`` and ``param_probe`` (the
 #: gradient and the updated parameter at ``probe_positions``); and
@@ -1110,9 +1111,16 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                       "mtp_head_in": h2.reshape(b * s, -1)[at]}
     if cfg.n_experts_here < cfg.num_experts:
         first = cfg.first_expert_here
+        chunk = experts.chunk_rows(tokens.size, cfg.num_experts_per_tok,
+                                   cfg.n_experts_here, cfg.num_experts)
         with jax.named_scope("otpu_stats"):
-            aux["local_slots"] = jnp.sum(
-                loads[:, first:first + cfg.n_experts_here])
+            held = jnp.sum(loads[:, first:first + cfg.n_experts_here],
+                           axis=1)
+            aux["local_slots"] = jnp.sum(held)
+            # what the held experts' loops walked: a layer's held slots
+            # in whole chunks (``experts.local_expert_ffn``)
+            aux["chunk_rows"] = jnp.sum(
+                (held.astype(jnp.int32) + chunk - 1) // chunk * chunk)
     with jax.named_scope("otpu_stats"):
         losses = jnp.stack([total] + losses)
     return total, {"losses": losses, "loads": loads, "rows": rows,
@@ -1273,7 +1281,7 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
     if cfg.n_mtp_here:
         aux_specs["mtp_rows"] = aux_specs["sample"]["mtp_head_in"] = batch
     if cfg.n_experts_here < cfg.num_experts:
-        aux_specs["local_slots"] = rep
+        aux_specs["local_slots"] = aux_specs["chunk_rows"] = rep
 
     def otpu_train_step(state, tokens, labels):
         return shard_map(body, mesh=mesh, in_specs=(rep, batch, batch),
@@ -1404,13 +1412,16 @@ def record_step_stats(aux) -> int:
     at the fullest expert's slots of any step read so far.  Where the
     rank holds a share of the experts, the slots that went to held
     experts and to absent ones add to ``moe_local_slots`` and
-    ``moe_absent_slots``; ``train_steps_read`` counts the steps read."""
+    ``moe_absent_slots``, and the rows the held experts' loops walked
+    for them (whole chunks) to ``moe_chunk_rows``; ``train_steps_read``
+    counts the steps read."""
     loads = np.asarray(aux["loads"])
     spc.record("train_steps_read")
     if "local_slots" in aux:
         here = int(np.asarray(aux["local_slots"]))
         spc.record("moe_local_slots", here)
         spc.record("moe_absent_slots", int(loads.sum()) - here)
+        spc.record("moe_chunk_rows", int(np.asarray(aux["chunk_rows"])))
     fullest = int(loads.max())
     seen = spc.read("moe_max_expert_load")
     if fullest > seen:

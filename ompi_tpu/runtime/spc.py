@@ -126,8 +126,12 @@ _COUNTERS = (
     "train_steps", "moe_max_expert_load",
     # a rank that holds a share of the routed experts: over the steps
     # read back (``train_steps_read``), the slots that went to experts
-    # held here and to absent ones
+    # held here and to absent ones, and the rows the held experts' loops
+    # walked for the held ones (a layer's held slots in whole chunks of
+    # ``experts.chunk_rows``): the first over the last is the share of
+    # the rows walked that held a slot
     "train_steps_read", "moe_local_slots", "moe_absent_slots",
+    "moe_chunk_rows",
     # the experts' grouped matmuls made while steps were traced
     # (parallel/experts._grouped_matmul), forward or transposed, and those
     # of them made on the Pallas kernel (ops/grouped_matmul): the second

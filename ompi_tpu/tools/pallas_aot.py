@@ -317,7 +317,8 @@ def cases(mesh1d, mesh2d):
          lambda: attn_backward_walk(1, 4, 8192, 128, 128, 1))
     # the experts' grouped matmul (``experts._kernel_matmul``: ``ops/
     # grouped_matmul``'s three kernels, forward and both transposed) at
-    # a cell's rows a call, held experts and both expert matrices
+    # a cell's rows a call, held experts and both expert matrices:
+    # OLMoE's every slot at once, under autodiff
     def gmm_forms(m, g, d, f):
         from ompi_tpu.parallel import experts
 
@@ -330,11 +331,38 @@ def cases(mesh1d, mesh2d):
             rep(m, d), rep(m, f), rep(g, d, f), rep(g, f, d),
             _sds((g,), jnp.int32, one, P()))
 
-    case("gmm_lfm2", lambda: gmm_forms(32768, 8, 2048, 1792))
+    # and as a trip of the held experts' loop makes them
+    # (``experts._grouped_matmul``: the forward product, and the
+    # transposes with the matrices' gradients added to running sums in
+    # place) at the chunk the loop walks at a share cell's shapes
+    # (``experts.chunk_rows``): ``t`` tokens of ``k`` slots, ``g`` of
+    # ``total`` experts held
+    def gmm_trip_forms(t, k, g, total, d, f):
+        from ompi_tpu.parallel import experts
+
+        m = experts.chunk_rows(t, k, g, total)
+
+        def trip(a, b, up, down, sizes, ct_d, ct_f, sum_up, sum_down):
+            gmm, transposes = experts._grouped_matmul(sizes, bf16, False)
+            return (gmm(a, up), gmm(b, down),
+                    transposes(a, up, ct_f, sum_up),
+                    transposes(b, down, ct_d, sum_down))
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        return jax.jit(trip, donate_argnums=(7, 8)), (
+            rep(m, d), rep(m, f), rep(g, d, f), rep(g, f, d),
+            _sds((g,), jnp.int32, one, P()), rep(m, d), rep(m, f),
+            rep(g, d, f), rep(g, f, d))
+
     case("gmm_olmoe", lambda: gmm_forms(65536, 64, 2048, 1024))
-    case("gmm_joyai", lambda: gmm_forms(8192, 16, 2048, 768))
-    case("gmm_nemotron", lambda: gmm_forms(8192, 8, 1024, 2688))
-    case("gmm_qwen3next", lambda: gmm_forms(32768, 32, 2048, 512))
+    case("gmm_lfm2", lambda: gmm_trip_forms(16384, 4, 8, 32, 2048, 1792))
+    case("gmm_joyai", lambda: gmm_trip_forms(8192, 8, 16, 256, 2048, 768))
+    case("gmm_nemotron",
+         lambda: gmm_trip_forms(8192, 22, 8, 512, 1024, 2688))
+    case("gmm_qwen3next",
+         lambda: gmm_trip_forms(16384, 10, 32, 512, 2048, 512))
+    case("gmm_smallthinker",
+         lambda: gmm_trip_forms(16384, 6, 16, 64, 2560, 768))
     # the chunked delta rule (``model._kernel_rule``: ``ops/gated_delta``'s
     # two kernels) as the Qwen3-Next cell's step builds it: 16 key heads,
     # 32 value heads, 128 / 128, 16,384 positions in chunks of 64, q, k

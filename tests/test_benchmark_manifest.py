@@ -280,6 +280,12 @@ BUILD_METRICS = ("compile.trace_s", "compile.lower_s",
                  "compile.own_backend_s", "compile.cache_hit_share",
                  "compile.own_programs", "compile.other_s")
 PEAK_METRIC = "step.hbm_peak_share"
+# the share of the held experts' loops' rows that held a slot (PR 57),
+# and the cells whose rank holds a share of the experts
+LIVE_ROWS = "moe.live_row_share"
+SHARE_CELLS = ("joyai-train-1chip", "nemotron3-train-1chip",
+               "lfm2-train-1chip", "qwen3next-train-1chip",
+               "smallthinker-train-1chip")
 
 # the child: run_cell as the command calls it, but for the three
 # arguments it keeps for rehearsals.  What the program counts is read
@@ -423,7 +429,7 @@ def _rehearse(cell, root):
     done = subprocess.run(
         [sys.executable, "-c", REHEARSAL.format(
             bench=BENCH, repo=REPO, cell=cell, root=root,
-            metrics=BUILD_METRICS + (PEAK_METRIC,))],
+            metrics=BUILD_METRICS + (PEAK_METRIC, LIVE_ROWS))],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-3000:]
     lines = done.stdout.splitlines()
@@ -779,7 +785,7 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
            "smallthinker.unnamed_share", "smallthinker.flash_mfu",
            "smallthinker.attn_bwd_mfu", "swa.operator_share",
            "attn.window_share", "attn.pairs_walked_share"]
-    assert names[-10:] == new
+    assert names[-11:-1] == new
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -808,6 +814,44 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
             spec = json.load(f)
         assert (spec["reader"], spec["params"]) == ("program_counter",
                                                     params)
+
+
+@of_cells("olmoe-train-1chip", *SHARE_CELLS)
+def test_the_loops_rows_are_counted_where_a_share_is_held(rehearsal):
+    """SPC ``moe_chunk_rows``: over the steps read back, every layer's
+    held slots in whole chunks, so never less than the slots and less
+    than a chunk a loop more; ``moe.live_row_share`` reads the one over
+    the other through its file and ``program_counter``.  OLMoE's step
+    holds every expert and has no loop: nothing is counted and the
+    reader finds nothing."""
+    c, share = rehearsal["counters"], rehearsal["layer"][LIVE_ROWS]
+    if rehearsal["cell"] not in SHARE_CELLS:
+        assert c["moe_chunk_rows"] == c["moe_local_slots"] == 0
+        assert share is None
+        return
+    assert c["moe_chunk_rows"] >= c["moe_local_slots"] > 0
+    assert c["moe_chunk_rows"] % 16 == 0
+    # the reader read while the run still held its steps, the counters
+    # are the run's last: both are a share of whole chunks
+    assert 0 < share <= 100
+    assert 0 < 100 * c["moe_local_slots"] / c["moe_chunk_rows"] <= 100
+
+
+def test_the_live_row_share_is_an_entry_of_the_manifest(real):
+    """Appended behind everything that was there (PR 57): a data file on
+    ``program_counter`` under the expert block's layer, in the five
+    cells whose rank holds a share of the experts and not in OLMoE's."""
+    m = real["per_layer"][-1]
+    twin = {x["name"]: x for x in real["per_layer"]}["moe.gmm_kernel_share"]
+    assert m == {"name": LIVE_ROWS, "unit": "%", "better": "higher",
+                 "source": "program_counter", "layer": twin["layer"],
+                 "moves": "small_msg_us", "workloads": list(SHARE_CELLS)}
+    assert "olmoe-train-1chip" in twin["workloads"]
+    with open(os.path.join(BENCH, "metrics", LIVE_ROWS + ".json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["params"]) == ("program_counter", {
+        "name": "moe_local_slots", "over": "moe_chunk_rows", "scale": 100})
 
 
 def test_train_check_tells_the_program_from_its_control(tmp_path):

@@ -89,6 +89,56 @@ def test_kernel_agrees_with_ragged_dot(groups, widths, form):
     _close(jnp.where(live, got, 0), jnp.where(live, want, 0), form)
 
 
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_tgmm_adds_to_a_running_sum(groups):
+    """``tgmm`` with a starting value is that value plus ``ragged_dot``'s
+    transpose, for groups that are empty, whole tiles, that straddle a
+    tile, with NaN in the rows past the last group; and the sum of a
+    group with no row comes out bit for bit as it went in (the kernel
+    does not visit it)."""
+    (sizes, m, tm), (k, n, tn) = GROUPS[groups], WIDTHS["square"]
+    a, w, ct, sz, live = _operands(sizes, m, k, n)
+    nan = lambda x: jnp.where(live, x, jnp.nan)
+    acc = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (len(sizes), k, n)), F32)
+    got = gm.tgmm(nan(a), nan(ct), sz, acc, tiles=(tm, k, tn),
+                  interpret=True)
+    want = acc + jax.vjp(lambda w: _ragged(a, w, sz), w.astype(F32))[1](
+        jnp.where(live, ct, 0).astype(F32))[0]
+    _close(got, want, groups)
+    empty = np.asarray(sizes) == 0
+    np.testing.assert_array_equal(np.asarray(got)[empty],
+                                  np.asarray(acc)[empty])
+    assert not np.array_equal(np.asarray(got)[~empty],
+                              np.asarray(acc)[~empty]) or empty.all()
+
+
+@pytest.mark.parametrize("rows", [64, 128])
+@pytest.mark.parametrize("groups", ["uneven", "rows_past_the_last_group",
+                                    "tile_straddles_three_groups"])
+def test_a_group_that_straddles_two_calls_sums_over_both(groups, rows):
+    """The loop's use: the rows walked ``rows`` a call, each call handed
+    the rows every group has in it and the sum so far.  A group whose
+    rows lie in two calls is summed over both, and the whole is
+    ``ragged_dot``'s transpose over all the rows at once."""
+    (sizes, m, tm), (k, n, tn) = GROUPS[groups], WIDTHS["square"]
+    a, w, ct, sz, live = _operands(sizes, m, k, n)
+    nan = lambda x: jnp.where(live, x, jnp.nan)
+    ends = jnp.cumsum(sz)
+    acc = jnp.zeros((len(sizes), k, n), F32)
+    calls = 0
+    for lo in range(0, m, rows):
+        here = jnp.clip(jnp.minimum(ends, lo + rows)
+                        - jnp.maximum(ends - sz, lo), 0, rows)
+        calls += int((here > 0).sum())
+        acc = gm.tgmm(nan(a)[lo:lo + rows], nan(ct)[lo:lo + rows], here,
+                      acc, tiles=(min(tm, rows), k, tn), interpret=True)
+    assert calls > len(sizes) - sizes.count(0)      # a group in two calls
+    want = jax.vjp(lambda w: _ragged(a, w, sz), w.astype(F32))[1](
+        jnp.where(live, ct, 0).astype(F32))[0]
+    _close(acc, want, groups)
+
+
 @pytest.mark.parametrize("m,k,n", [
     (32768, 2048, 1792), (32768, 1792, 2048),      # LFM2
     (65536, 2048, 1024), (65536, 1024, 2048),      # OLMoE
@@ -139,8 +189,12 @@ def _ffn_operands(ffn, sizes, m, d, f, seed=1):
     return normal(m, d), mats, jnp.asarray(sizes, jnp.int32)
 
 
-@pytest.mark.parametrize("ffn", [experts.grouped_expert_ffn,
-                                 experts.grouped_relu2_ffn])
+def _relu2_value(*args):
+    return experts.grouped_relu2_ffn_vjp(*args)[0]
+
+
+@pytest.mark.parametrize("ffn", [experts.grouped_expert_ffn, _relu2_value],
+                         ids=["grouped_expert_ffn", "grouped_relu2_ffn"])
 def test_gradients_through_the_experts_ffn(ffn, interpreted):
     """``jax.grad`` through the SwiGLU and the relu2 experts on the
     kernel against the same on ``lax.ragged_dot``: values and the
@@ -166,28 +220,35 @@ def test_gradients_through_the_experts_ffn(ffn, interpreted):
         assert not np.asarray(g)[1].any()
 
 
+@pytest.mark.parametrize("form", ["gated", "relu2"])
 @pytest.mark.parametrize("held", ["some_slots", "no_slot"])
-def test_local_expert_ffn_whole_on_the_kernel(held, interpreted):
-    """``local_expert_ffn``'s result and gradients compared whole: its
-    chunks hold rows past the last held slot, which the kernel leaves
-    unwritten and the function's masks cut off on both sides."""
+def test_local_expert_ffn_whole_on_the_kernel(held, form, interpreted):
+    """``local_expert_ffn``'s result and gradients compared whole, for
+    both expert forms: its chunks hold rows past the last held slot,
+    which the kernel leaves unwritten and the function's masks cut off
+    on both sides, and the held slots are several chunks, so the
+    matrices' gradients are summed by the kernel over several trips."""
     import types
 
     t, d, f, k, e, here = 64, 128, 128, 2, 8, 2
+    ffn, n_mats = FORMS[form]
     cfg = types.SimpleNamespace(num_experts_per_tok=k, num_experts=e,
                                 compute_dtype=BF16)
     rng = np.random.default_rng(2)
     normal = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.3, F32)
     h, weights = normal(t, d), jnp.abs(normal(t, k))
-    mats = (normal(here, d, f), normal(here, d, f), normal(here, f, d))
+    mats = tuple(normal(here, d, f) for _ in range(n_mats - 1)) + (
+        normal(here, f, d),)
     chosen = rng.integers(0 if held == "some_slots" else here, e, (t, k))
     order, sizes = experts.local_dispatch(jnp.asarray(chosen), 0, here)
+    if held == "some_slots":
+        assert int(sizes.sum()) > 2 * experts.chunk_rows(t, k, here, e)
 
     def loss(interpret, h, weights, *mats):
         return jnp.sum(experts.local_expert_ffn(
-            h, order, weights, sizes, mats, cfg, interpret=interpret) ** 2)
+            h, order, weights, sizes, mats, cfg, ffn, interpret) ** 2)
 
-    args = (0, 1, 2, 3, 4)
+    args = tuple(range(2 + n_mats))
     got = jax.value_and_grad(functools.partial(loss, False), args)(
         h, weights, *mats)
     want = jax.value_and_grad(functools.partial(loss, True), args)(
@@ -199,6 +260,131 @@ def test_local_expert_ffn_whole_on_the_kernel(held, interpreted):
         scale = float(jnp.max(jnp.abs(w))) + 1e-30
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
                                    atol=scale * 2.0 ** -6)
+
+
+#: the loop's shapes in the cases below: 64 tokens of 2 slots, 2 of 8
+#: experts held
+LOOP = dict(t=64, k=2, e=8, here=2, d=128, f=128)
+FORMS = {"gated": (experts.grouped_expert_ffn_vjp, 3),
+         "relu2": (experts.grouped_relu2_ffn_vjp, 2)}
+
+
+def _loop_rows():
+    return experts.chunk_rows(LOOP["t"], LOOP["k"], LOOP["here"], LOOP["e"])
+
+
+def _chosen(held: str):
+    """(T, k) experts with the held slots the case names: the first so
+    many slots go to the held experts in turn, the others to absent
+    ones; ``hot``: every token's first choice is held expert 1."""
+    t, k, e, here = (LOOP[x] for x in ("t", "k", "e", "here"))
+    rows = _loop_rows()
+    flat = here + np.arange(t * k) % (e - here)
+    if held == "hot":
+        chosen = flat.reshape(t, k)
+        chosen[:, 0] = 1
+        chosen[:3, 1] = 0
+        return chosen, t + 3
+    n = {"none": 0, "under_one_chunk": rows - 3, "two_chunks": 2 * rows,
+         "two_chunks_and_a_row": 2 * rows + 1}[held]
+    flat[:n] = np.arange(n) % here
+    return flat.reshape(t, k), n
+
+
+def _dense(form, h, weights, chosen, *mats):
+    """The held experts' part by masks: every held expert on every row,
+    weighted by what the row sent it."""
+    out = 0.0
+    for e in range(LOOP["here"]):
+        if form == "gated":
+            gate, up, down = (m[e] for m in mats)
+            y = jnp.dot(jax.nn.silu(jnp.dot(h, gate, precision=HIGHEST))
+                        * jnp.dot(h, up, precision=HIGHEST), down,
+                        precision=HIGHEST)
+        else:
+            up, down = (m[e] for m in mats)
+            y = jnp.dot(jnp.square(jax.nn.relu(
+                jnp.dot(h, up, precision=HIGHEST))), down, precision=HIGHEST)
+        out = out + y * jnp.sum(weights * (chosen == e), axis=1)[:, None]
+    return out
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("held", ["none", "under_one_chunk", "two_chunks",
+                                  "two_chunks_and_a_row", "hot"])
+def test_the_loop_is_the_dense_masked_sum(held, form):
+    """``local_expert_ffn``'s value and every gradient (the rows', the
+    weights' and each matrix's) in float32 against every held expert
+    run on every row under a mask, for both expert forms: at no held
+    slot (no trip), under one chunk, at whole chunks, at whole chunks and
+    one row, and with one hot expert whose group is longer than the
+    batch is wide, which no chunk holds."""
+    import types
+
+    t, k, e, here, d, f = (LOOP[x] for x in "t k e here d f".split())
+    ffn, n_mats = FORMS[form]
+    cfg = types.SimpleNamespace(num_experts_per_tok=k, num_experts=e,
+                                compute_dtype=F32)
+    rng = np.random.default_rng(4)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.3, F32)
+    h, weights = normal(t, d), jnp.abs(normal(t, k))
+    mats = (normal(here, d, f),) * (n_mats - 1) + (normal(here, f, d),)
+    mats = tuple(m + 0.01 * i for i, m in enumerate(mats))
+    chosen, n_held = _chosen(held)
+    chosen = jnp.asarray(chosen)
+    order, sizes = experts.local_dispatch(chosen, 0, here)
+    assert int(sizes.sum()) == n_held
+    if held == "hot":
+        assert int(sizes.max()) == t > 2 * _loop_rows()
+
+    def loop(h, weights, *mats):
+        return jnp.sum(jnp.sin(experts.local_expert_ffn(
+            h, order, weights, sizes, mats, cfg, ffn)))
+
+    def dense(h, weights, *mats):
+        return jnp.sum(jnp.sin(_dense(form, h, weights, chosen, *mats)))
+
+    args = tuple(range(2 + n_mats))
+    got = jax.value_and_grad(loop, args)(h, weights, *mats)
+    want = jax.value_and_grad(dense, args)(h, weights, *mats)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        scale = float(jnp.max(jnp.abs(w))) + 1e-30
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=scale * 1e-5)
+    if held == "none":
+        assert not any(np.asarray(g).any() for g in got[1])
+
+
+def _model_configs():
+    import glob
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return sorted(glob.glob(os.path.join(
+        here, "benchmark", "configs", "*-train-1chip.json")))
+
+
+@pytest.mark.parametrize("path", _model_configs(),
+                         ids=lambda p: p.rsplit("/", 1)[1][:-5])
+def test_a_chunk_is_whole_row_tiles_at_every_cells_shapes(path):
+    """``chunk_rows`` at a configuration's shapes is whole row tiles (or
+    whole sublane tiles of 16 under one), so every trip's three products
+    have tiles and take the kernel; it comes from the shapes alone."""
+    from ompi_tpu.parallel import train
+
+    cfg = train.load_model_config(path)
+    t, d = cfg.micro_batch * cfg.seq_len, cfg.moe_latent_size or \
+        cfg.hidden_size
+    rows = experts.chunk_rows(t, cfg.num_experts_per_tok, cfg.n_experts_here,
+                              cfg.num_experts)
+    assert rows % (gm.ROW_TILE if rows >= gm.ROW_TILE else 16) == 0
+    assert gm.supported(rows, d, cfg.expert_width)
+    assert rows <= experts.CHUNK_TILES * gm.ROW_TILE <= t
+    for tokens in (16, 64, 256, 1000, 4096):    # and at a test's shapes
+        small = experts.chunk_rows(tokens, 2, 2, 8)
+        assert small % 16 == 0 and small <= max(16, tokens // 2)
 
 
 def _primitives(jaxpr, found=None):

@@ -248,7 +248,7 @@ def test_a_step_reports_what_it_counted(params):
     if "train_steps" not in spc.counters():
         spc.init()
     names = ("train_steps", "train_steps_read", "moe_local_slots",
-             "moe_absent_slots")
+             "moe_absent_slots", "moe_chunk_rows")
     before = {k: spc.read(k) for k in names}
     _, (aux,) = run_steps(F32, params, (0,))
     fullest = train.record_step_stats(aux)
@@ -260,6 +260,11 @@ def test_a_step_reports_what_it_counted(params):
     assert F32.num_experts_per_tok * F32.n_routers == 4 * 3
     assert moved["moe_local_slots"] == int(aux["local_slots"])
     assert moved["moe_local_slots"] + moved["moe_absent_slots"] == 768
+    # the three loops' held slots (the loads' columns 4-7) in whole chunks
+    rows = experts.chunk_rows(64, 4, 4, 16)
+    held = np.asarray(aux["loads"])[:, 4:8].sum(axis=1).astype(int)
+    assert moved["moe_chunk_rows"] == int(aux["chunk_rows"]) \
+        == (-(-held // rows) * rows).sum() >= moved["moe_local_slots"]
     assert fullest == np.asarray(aux["loads"]).max() >= 16
     assert aux["grad_probe"].shape == (len(LEAVES), train.PROBE)
     assert aux["sample"]["router_scores"].shape == (3, train.SAMPLE_ROWS, 16)
@@ -292,9 +297,9 @@ def share_of(p, cfg):
 
 def test_a_hot_held_expert_drops_no_slot():
     """Every token's first choice is held expert 5: its group is as long
-    as the batch, the held slots are several chunks of the mean load,
-    and the output is what the dense reference computes, so nothing fell
-    through."""
+    as the batch, the held slots are several of the loop's chunks
+    (``experts.chunk_rows``), and the output is what the dense reference
+    computes, so nothing fell through."""
     p = share_of(sparse_leaves(F32, hot=5), F32)
     rng = np.random.default_rng(6)
     x = jnp.asarray(rng.uniform(0.5, 1.5, (2, 32, 64)), jnp.float32)
@@ -303,7 +308,8 @@ def test_a_hot_held_expert_drops_no_slot():
     assert (routed["experts"][:, 0] == 5).all()
     assert stats["slots"][5] == 64 and stats["slots"].sum() == 256
     held = int(stats["slots"][4:8].sum())
-    assert held > 64 + 16                   # more than one chunk of 64 rows
+    rows = experts.chunk_rows(64, 4, 4, 16)
+    assert stats["slots"][5] > rows and held > 2 * rows     # several trips
     h = ref._norm(x, p["ln2"], F32.rms_norm_eps).reshape(64, 64)
     with jax.default_matmul_precision("highest"):
         want, load = ref.sparse_mlp(p, h, bias, F32)

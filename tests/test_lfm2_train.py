@@ -232,6 +232,9 @@ def test_the_expert_block_without_a_shared_expert_under_uneven_routing():
             *experts.moe_shared_local_block(p, x, F32, bias)),
         argnums=(0, 1), has_aux=True)(p, x)
     assert load[2] == 64 and load[3] == 0
+    # the hot expert's group alone is more than one of the loop's chunks
+    assert 64 > experts.chunk_rows(
+        64, F32.num_experts_per_tok, F32.n_experts_here, F32.num_experts)
     close(stats["slots"], load)
     close(got, want, rtol=1e-4)
     for k in ("ln2", "router", "gate", "up", "down"):

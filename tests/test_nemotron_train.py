@@ -190,6 +190,9 @@ def test_the_latent_block_under_uneven_routing():
             *experts.moe_latent_block(p, x, F32, bias)),
         argnums=(0, 1), has_aux=True)(p, x)
     assert load[9] == 64 and load[10] == 0
+    # the hot expert's group alone is more than one of the loop's chunks
+    assert 64 > experts.chunk_rows(
+        64, F32.num_experts_per_tok, F32.n_experts_here, F32.num_experts)
     close(stats["slots"], load)
     close(got, want, rtol=1e-4)
     for k in p:

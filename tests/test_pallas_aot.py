@@ -546,21 +546,26 @@ def test_smallthinker_train_step_aot_compiles_from_the_cells_configuration(
 
 @pytest.fixture(scope="module")
 def gmm_rows():
-    """One child for the experts' grouped matmul at the four model
-    cells' shapes, forward and both transposed products of both expert
+    """One child for the experts' grouped matmul at the six model cells'
+    shapes, forward and both transposed products of both expert
     matrices, for one v5e device (about 25 s of the 600)."""
     return _rows_with_texts("gmm_")
 
 
 @pytest.mark.parametrize("cell", ["lfm2", "olmoe", "joyai", "nemotron",
-                                  "qwen3next"])
+                                  "qwen3next", "smallthinker"])
 def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
     """``ops/grouped_matmul``'s three kernels at the tiles the module
     chooses for a cell's rows a call, held experts and both expert
     matrices (PR 47): Mosaic takes the whole contraction and the widest
     column tile in the VMEM the module asks for, and each of the six
     products is one custom call whose ``op_name`` carries its kernel's
-    name, which is how a trace finds it."""
+    name, which is how a trace finds it.  OLMoE's are every slot at once
+    under autodiff; a share cell's are a trip of the held experts' loop
+    at ``experts.chunk_rows`` rows (PR 57): the matrices' gradients are
+    added to running float32 sums that come in and go out in one buffer
+    (the module's ``input_output_alias``), and no instruction copies a
+    sum."""
     row = gmm_rows["gmm_" + cell]
     assert row.get("compiled"), json.dumps(row, indent=1)
     kernels = [path for line, path in op_paths(row)
@@ -568,6 +573,16 @@ def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
     for name in ("otpu_gmm", "otpu_gmm_nt", "otpu_gmm_t"):
         assert sum(f"({name})" in p or f"/{name}/" in p
                    for p in kernels) == 2, kernels
+    if cell == "olmoe":
+        return
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    head = text[:text.index("\n")]
+    assert "{3}: (7, {}, may-alias)" in head \
+        and "{5}: (8, {}, may-alias)" in head, head[:300]
+    sums = [ln for ln in text.splitlines()
+            if re.search(r" = f32\[\d+,\d+,\d+\]\S* (copy|add)\(", ln)]
+    assert not sums, sums[:3]
 
 
 def op_paths(row):
