@@ -61,7 +61,7 @@ BWD_VMEM_LIMIT = 64 << 20
 
 def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                      dqo_ref, dko_ref, dvo_ref):
+                      dqo_ref, dko_ref, dvo_ref, selt_ref=None, live=None):
     """One q tile of block i of one query head against kv block j of the
     key-value head its group shares, a tile of kv positions at a time,
     **transposed**: scores are held (kv, q), so that the logsumexp and
@@ -72,7 +72,11 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
     the group's first tile, where it takes the incoming accumulator, to
     its last: the group's sum is made in float32, here.  ``far_by``
     (None without a window) is the window in blocks: the pair whose
-    blocks lie that far apart is the far one, masked the other way."""
+    blocks lie that far apart is the far one, masked the other way.
+    ``selt_ref`` (None without a selection: ``_bwd_select_kernel`` gives
+    it) is the pair's tile of a selection, key-major: every kv tile is
+    then masked by it and by nothing else, where ``live`` says the pair
+    selects anything at all."""
     t = pl.program_id(1)
     diagonal = ij_ref[0] == ij_ref[1]
     tile = q_ref.shape[1]
@@ -103,7 +107,10 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         k, v = k_ref[0, rows, :], v_ref[0, rows, :]
         q, do = q_ref[0, qs, :], do_ref[0, qs, :]
         s = dot(k, q, nt_dims) * scale                      # (kv, q)
-        if masked:
+        if selt_ref is not None:
+            s = jnp.where(selt_ref[0, rows, qs].astype(jnp.int32) != 0, s,
+                          -jnp.inf)
+        elif masked:
             at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                        axis)
             s = jnp.where(at(0) + lo > at(1) if far else at(0) <= at(1), s,
@@ -115,6 +122,11 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         dko_ref[0, rows, :] += dot(ds, q, nn_dims)
         dqo_ref[0, qs, :] += dot(ds, k, tn_dims)
 
+    if selt_ref is not None:
+        # the selection holds the diagonal too: no tile goes by position
+        for c in range(k_ref.shape[1] // tile):
+            pl.when(live)(functools.partial(part, c, 0, tile, True))
+        return
     # by position: a kv tile of the diagonal pair lies wholly under the
     # diagonal (c < t), on it (c == t: masked, by strips) or wholly above
     # it (p is 0 there: skipped); every tile of another pair lies under
@@ -139,6 +151,19 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
             def _():
                 for lo in range(0, tile, strip):
                     part(c, lo, strip, True, far=True)
+
+
+def _bwd_select_kernel(scale, rep, heads, nb, ij_ref, flags_ref, q_ref, k_ref,
+                       v_ref, do_ref, lse_ref, delta_ref, selt_ref, *accs):
+    """``_bwd_block_kernel`` under a selection: ``selt_ref`` (1, block, q
+    tile) int8 is the pair's tile of the mask, key-major as the scores are
+    held; ``flags_ref`` (b x blocks x blocks) says which pairs select
+    anything, and a pair that does not only hands its accumulators on."""
+    live = flags_ref[(pl.program_id(0) // heads) * nb * nb
+                     + ij_ref[0] * nb + ij_ref[1]] != 0
+    _bwd_block_kernel(scale, None, rep, None, ij_ref, q_ref, k_ref, v_ref,
+                      do_ref, lse_ref, delta_ref, *accs, selt_ref=selt_ref,
+                      live=live)
 
 
 def _tile(length, most):
@@ -173,9 +198,19 @@ def _window_blocks(window, block: int, length: int):
     return window // block
 
 
+def _tile_flags(select, tile: int):
+    """Which (q tile, kv tile) pairs of a selection (b, s, s) select
+    anything: int32 (b x tiles x tiles), 1 or 0."""
+    b, s, _ = select.shape
+    nt = s // tile
+    return jnp.any(select.reshape(b, nt, tile, nt, tile) != 0,
+                   axis=(2, 4)).astype(jnp.int32).reshape(-1)
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
 def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
-                        block: int, interpret=None, window=None):
+                        block: int, interpret=None, window=None,
+                        select=None):
     """One block pair of causal attention's flash backward, fused: q
     block ``ij[0]`` against kv block ``ij[1]`` (``block`` positions
     each; the pair whose two are equal is masked by position), the
@@ -197,7 +232,11 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     (block, block) array leaves VMEM.  The ``jnp`` twin is
     ``parallel/model._bwd_pair``.  With ``window`` (positions, whole
     blocks) the pair whose blocks lie the window apart is the far one
-    and masked as such; the caller walks no pair beyond it.
+    and masked as such; the caller walks no pair beyond it.  With
+    ``select`` = (the selection key-major (b, s kv, s q) int8, its pairs'
+    flags as ``_tile_flags(.., block)`` gives them) a pair is masked by
+    its tile of the selection and by nothing else, and a pair that selects
+    nothing hands the accumulators on (``_bwd_select_kernel``).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -222,6 +261,11 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
                 row(lse), row(delta), flat(dq), flat(dk), flat(dv)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
     acc_specs = [q_spec(d), kv_spec(d), kv_spec(hv)]
+    if select is not None:
+        return _select_block_backward(
+            operands, select, (q_spec, kv_spec, row_spec, acc_specs),
+            (b, h, s, d, hv, rep, tq, nt, block), vma, interpret,
+            (dq, dk, dv))
     out = pl.pallas_call(
         functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
                           _tile(tq, BWD_STRIP), rep, far_by),
@@ -244,6 +288,39 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     return tuple(o.reshape(a.shape) for o, a in zip(out, (dq, dk, dv)))
 
 
+def _select_block_backward(operands, select, specs, dims, vma, interpret,
+                           like):
+    """``attn_block_backward``'s call under a selection: one more scalar
+    operand (the pairs' flags) and one more input (the mask's tile)."""
+    q_spec, kv_spec, row_spec, acc_specs = specs
+    b, h, s, d, hv, rep, tq, nt, block = dims
+    selt, flags = select
+    lift = lambda spec: pl.BlockSpec(
+        spec.block_shape, lambda g, t, ij, fl: spec.index_map(g, t, ij))
+    sel_spec = pl.BlockSpec((1, block, tq), lambda g, t, ij, fl: (
+        g // h, ij[1], ij[0] * nt + t))
+    ins = [q_spec(d), kv_spec(d), kv_spec(hv), q_spec(hv), row_spec, row_spec]
+    out = pl.pallas_call(
+        functools.partial(_bwd_select_kernel, 1.0 / math.sqrt(d), rep, h,
+                          s // block),
+        out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
+                        for o in operands[7:]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * h, nt),
+            in_specs=[lift(sp) for sp in ins] + [sel_spec]
+            + [lift(sp) for sp in acc_specs],
+            out_specs=[lift(sp) for sp in acc_specs]),
+        input_output_aliases={9: 0, 10: 1, 11: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel" if rep == 1 else "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name="otpu_attn_select_backward",
+    )(operands[0], flags, *operands[1:7], selt, *operands[7:])
+    return tuple(o.reshape(a.shape) for o, a in zip(out, like))
+
+
 #: the causal forward kernel's tile: so many q positions a grid step
 #: against so many kv positions (a block of 1,024 is one tile).  On the
 #: chip 1,024 squared beats every other pair from 512 to 4,096 at both
@@ -254,18 +331,23 @@ FWD_TILE = 1024
 FWD_VMEM_LIMIT = 64 << 20
 
 
-def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask):
+def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask,
+                   sel_ref=None):
     """One online-softmax update of a q tile's running max, denominator
     and float32 numerator (VMEM scratch) by one kv tile.  ``mask``: None,
-    ``"diagonal"`` (key column c visible to query row r iff c <= r) or
-    ``"far"`` (a window's far tile: iff c > r).  Scores are held (q, kv):
+    ``"diagonal"`` (key column c visible to query row r iff c <= r),
+    ``"far"`` (a window's far tile: iff c > r) or ``"select"`` (iff
+    ``sel_ref``'s tile of a selection (1, q, kv) int8 says so).  Scores
+    are held (q, kv):
     a row's statistics are columns, and the two matmuls are the MXU's
     plain forms."""
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=jnp.float32)
     q, k, v = q_ref[0], k_ref[0], v_ref[0]
     s = dot(q, k, (((1,), (1,)), ((), ()))) * scale     # (q, kv)
-    if mask is not None:
+    if mask == "select":
+        s = jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, -jnp.inf)
+    elif mask is not None:
         at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                    axis)
         s = jnp.where(at(0) < at(1) if mask == "far" else at(0) >= at(1), s,
@@ -275,9 +357,10 @@ def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask):
     m = m_ref[...]
     new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     at_m = new_m
-    if mask == "far":
+    if mask in ("far", "select"):
         # a window's far tile comes first and its last query row sees
-        # nothing of it: that row's max stays -inf, and exp(-inf - -inf)
+        # nothing of it (under a selection any row may see nothing of
+        # any tile): that row's max stays -inf, and exp(-inf - -inf)
         # must not arise; against 0 its c and p are exp(-inf) = 0
         at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
     c = jnp.exp(m - at_m)
@@ -357,8 +440,33 @@ def _window_fwd_kernel(scale, w, q_ref, k_ref, v_ref, o_ref, lse_ref,
         _write_out(o_ref, lse_ref, *state)
 
 
+def _select_fwd_kernel(scale, heads, flags_ref, q_ref, k_ref, v_ref, sel_ref,
+                       o_ref, lse_ref, m_ref, den_ref, num_ref):
+    """``_causal_fwd_kernel`` under a selection: every kv tile up to the
+    diagonal one is masked by ``sel_ref``'s tile (which holds the diagonal
+    too) and by nothing else; a tile pair that selects nothing
+    (``flags_ref`` (b x tiles x tiles)) is passed over."""
+    g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nt = pl.num_programs(1)
+    state = (m_ref, den_ref, num_ref)
+
+    @pl.when(j == 0)
+    def _():
+        _init_state(*state)
+
+    live = flags_ref[(g // heads) * nt * nt + i * nt + jnp.minimum(i, j)] != 0
+    pl.when(jnp.logical_and(j <= i, live))(functools.partial(
+        _online_update, scale, q_ref, k_ref, v_ref, *state, "select",
+        sel_ref))
+
+    @pl.when(j == i)
+    def _():
+        _write_out(o_ref, lse_ref, *state)
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
-def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None):
+def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
+                         select=None):
     """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
     float32 and the logsumexp (b, h, s) float32 of q (b, h, s, d), k
     (b, n_kv, s, d) and v (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``
@@ -377,6 +485,10 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None):
     With ``window`` (positions, whole tiles) the grid's third axis is
     the window's tiles and the diagonal one (``_window_fwd_kernel``), the
     index map starting at the far tile (clamped to the sequence's first).
+    With ``select`` (b, s, s) int8, query-major, key u is visible to query
+    t iff ``select[b, t, u]`` is not 0 (the selection holds causality:
+    nothing above the diagonal), every row selects a key, and a tile pair
+    that selects nothing is passed over (``_select_fwd_kernel``).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -402,6 +514,33 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None):
     operands = [flat(q), flat(k), flat(v)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
     f32 = jnp.float32
+    if select is not None:
+        lift = lambda fn: (lambda g, i, j, fl: fn(g, i, j))
+        vma = vma | jax.typeof(select).vma
+        o, lse = pl.pallas_call(
+            functools.partial(_select_fwd_kernel, 1.0 / math.sqrt(d), h),
+            out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
+                       jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(bh, nt, nt),
+                in_specs=[pl.BlockSpec((1, tile, d), lift(q_map)),
+                          pl.BlockSpec((1, tile, d), lift(kv_map)),
+                          pl.BlockSpec((1, tile, hv), lift(kv_map)),
+                          pl.BlockSpec((1, tile, tile), lambda g, i, j, fl: (
+                              g // h, i, jnp.minimum(i, j)))],
+                out_specs=(pl.BlockSpec((1, tile, hv), lift(q_map)),
+                           pl.BlockSpec((1, 1, tile),
+                                        lambda g, i, j, fl: (g, 0, i))),
+                scratch_shapes=[pltpu.VMEM((tile, 1), f32),
+                                pltpu.VMEM((tile, 1), f32),
+                                pltpu.VMEM((tile, hv), f32)]),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=FWD_VMEM_LIMIT),
+            interpret=interpret,
+            name="otpu_flash_select_forward",
+        )(_tile_flags(select, tile), *operands, select)
+        return o.reshape(b, h, s, hv), lse.reshape(b, h, s)
     o, lse = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),

@@ -1,5 +1,5 @@
 """What every sublayer of a public model is made of: a parameter's cast,
-the matmul with float32 results, RMSNorm with a gain, an L2 norm, the rotary
+the matmul with float32 results, RMSNorm with a gain, LayerNorm, an L2 norm, the rotary
 embeddings and the two feed-forwards.  ``parallel/experts.py`` and
 ``parallel/model.py`` build on these; nothing here imports either.
 """
@@ -38,6 +38,15 @@ def rmsnorm_gain(x, gain, eps: float):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                              + eps) * gain
+
+
+def layernorm(x, gain, bias, eps: float):
+    """LayerNorm with a learned gain and bias over the last axis, in
+    float32: the norm a lightning indexer puts on its one key."""
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * gain + bias
 
 
 def l2norm(x, eps: float = 1e-6):

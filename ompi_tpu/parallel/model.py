@@ -1,7 +1,10 @@
 """A public model's sublayers (OLMoE, JoyAI-LLM-Flash, Nemotron-3-Super,
-LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B): causal flash
-attention, in full or under a sliding window, with its two walks of the
-block pairs, the three attention sublayers, Mamba-2's
+LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B, Keye-VL-2.0-30B-A3B's
+language model): causal flash
+attention, in full, under a sliding window or under a learned selection,
+with its two walks of the
+block pairs, the three attention sublayers, learned sparse attention (a
+lightning indexer, its exact top-k and its alignment loss), Mamba-2's
 chunked scan and mixer, LFM2's gated short convolution, Gated DeltaNet's
 chunked rule and operator, and ``decoder_layer``, which chooses a layer's
 sublayers by what it holds.
@@ -19,7 +22,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.parallel import experts
-from ompi_tpu.parallel.layers import (cast_param, l2norm, matmul,
+from ompi_tpu.parallel.layers import (cast_param, l2norm, layernorm, matmul,
                                       project_rope, rmsnorm_gain, rope,
                                       swiglu)
 from ompi_tpu.runtime import spc
@@ -95,7 +98,19 @@ def _window_in_blocks(window, block: int, length: int):
     return window // block
 
 
-def _causal_fwd_blocks(q, k, v, block, interpret, window=None):
+def _select_bias(select, i, j, block: int, rep: int):
+    """A selection's (q block i, kv block j) as a bias for a group's
+    folded rows, (b, 1, rep x block, block): 0 where ``select`` (b, s, s)
+    says a key is visible, -inf elsewhere.  ``i`` and ``j`` may be
+    traced."""
+    b, s, _ = select.shape
+    nb = s // block
+    tile = select.reshape(b, nb, block, nb, block)[:, i, :, j]
+    bias = jnp.where(tile != 0, 0.0, -jnp.inf).astype(jnp.float32)
+    return jnp.tile(bias, (1, rep, 1))[:, None]
+
+
+def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
     """Causal attention's forward pass: (o float32, logsumexp float32)
     of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
     key-value head is read by ``h / n_kv`` consecutive query heads, and
@@ -111,11 +126,18 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None):
     k, v are.  Under a static ``window`` (positions; a whole number w of
     blocks) q block i meets kv blocks max(0, i - w) .. i, the far one (i
     - w) under ``_far_bias``; its last query row sees nothing of it, and
-    that row's running max stays -inf through it."""
+    that row's running max stays -inf through it.  Under ``select`` (b,
+    s, s) int8 (a data-dependent selection that holds causality; None:
+    everything here is what it was) every block pair goes under its tile
+    of the selection (``_select_bias``) and under no mask by position,
+    and any row may see nothing of any block."""
     w = _window_in_blocks(window, block, q.shape[2])
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
+        if select is not None:
+            return flash_causal_forward(q, k, v, block=block,
+                                        interpret=False, select=select)
         return flash_causal_forward(q, k, v, block=block, interpret=False,
                                     window=None if w is None else window)
     h, s, hd = q.shape[1:]
@@ -133,13 +155,17 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None):
             kj = k[:, :, j * block:(j + 1) * block]
             vj = v[:, :, j * block:(j + 1) * block]
             sc = _contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
-            if j == i:
+            if select is not None:
+                sc = sc + _select_bias(select, i, j, block, h // k.shape[1])
+            elif j == i:
                 sc = sc + bias
-            far = w is not None and j == i - w
+            far = select is None and w is not None and j == i - w
             if far:
                 sc = sc + _far_bias(block, h // k.shape[1])
             new_m = at_m = jnp.maximum(m, sc.max(axis=-1))
-            if far:     # a row that sees nothing yet: exp(-inf - 0) = 0
+            # a row that sees nothing yet (of a window's far block, or of
+            # any block under a selection): exp(-inf - 0) = 0
+            if far or select is not None:
                 at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
             c = jnp.exp(m - at_m)
             p = jnp.exp(sc - at_m[..., None])
@@ -161,7 +187,16 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None):
 # backward pass holds no second run of the kernel.
 ATTN_OUT = "otpu_attn_out"
 ATTN_LSE = "otpu_attn_lse"
-CHECKPOINT_KEEPS = (ATTN_OUT, ATTN_LSE)
+# and of a learned sparse attention sublayer (``dsa_attention``): the
+# selection (an int8 mask; made again it costs the index scores and the
+# counting passes, and a second choice need not be the first), its rows'
+# logsumexp, and the alignment loss's rows and gradients, which its one
+# pass makes together
+DSA_SELECTION = "otpu_dsa_selection"
+DSA_INDEX_LSE = "otpu_dsa_index_lse"
+DSA_LOSS = "otpu_dsa_loss"
+CHECKPOINT_KEEPS = (ATTN_OUT, ATTN_LSE, DSA_SELECTION, DSA_INDEX_LSE,
+                    DSA_LOSS)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -243,7 +278,7 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
             _contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
 
 
-def _causal_bwd(block, interpret, window, res, do):
+def _causal_bwd(block, interpret, window, res, do, select=None):
     q, k, v, o, lse = res
     _count_built(q, k, block, window)
     h, n_kv = q.shape[1], k.shape[1]
@@ -252,9 +287,10 @@ def _causal_bwd(block, interpret, window, res, do):
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
     if not interpret:
-        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w)
+        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select)
     if nb > UNROLLED_BLOCKS:
-        return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w)
+        return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w,
+                                   select)
     dt = q.dtype
     scale = 1.0 / math.sqrt(q.shape[-1])
     bias = _group_bias(block, h // n_kv)
@@ -268,13 +304,15 @@ def _causal_bwd(block, interpret, window, res, do):
     for i, j in _window_pairs(nb, w):
         dq_c, dk_c, dv_c = _bwd_pair(
             qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
-            bias if j == i else far if i - j == w else None, scale, dt)
+            _select_bias(select, i, j, block, h // n_kv)
+            if select is not None
+            else bias if j == i else far if i - j == w else None, scale, dt)
         dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
     cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
     return _ungroup_blocks(jnp.stack(dq), h).astype(dt), cat(dk), cat(dv)
 
 
-def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None):
+def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
     """The same pairs in the same order (q block by q block, kv blocks
     ascending), one a step of a ``lax.scan`` over float32 accumulators."""
     dt = q.dtype
@@ -293,6 +331,8 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None):
         bias = jnp.where(i == j, tri, 0.0)
         if w is not None:
             bias = jnp.where(i - j == w, _far_bias(block, h // n_kv), bias)
+        if select is not None:
+            bias = _select_bias(select, i, j, block, h // n_kv)
         dq_c, dk_c, dv_c = _bwd_pair(
             qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i], bias, scale,
             dt)
@@ -309,22 +349,31 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None):
             _ungroup_blocks(dv, n_kv).astype(dt))
 
 
-def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None):
+def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
     """The same pairs in the same order, each one call of the fused
     Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
     ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
     and the float32 accumulators pass through every call in place, dk's
     and dv's with k's and v's own heads.  Both walks: unrolled up to
     ``UNROLLED_BLOCKS`` blocks, one ``lax.scan`` beyond; the arrays go
-    in whole and the pair is an operand, so neither slices."""
-    from ompi_tpu.ops.flash_attention import attn_block_backward
+    in whole and the pair is an operand, so neither slices.  Under a
+    selection the kernel reads the mask key-major, as it holds the
+    scores: transposed once here, beside the pairs' flags."""
+    from ompi_tpu.ops.flash_attention import (_tile_flags,
+                                              attn_block_backward)
 
     dt = q.dtype
     nb = q.shape[2] // block
     do = do.astype(dt)                  # what ``_contract`` makes of it
-    pair = lambda acc, ij: attn_block_backward(
-        ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
-        window=None if w is None else w * block)
+    if select is not None:
+        select = (jnp.swapaxes(select, 1, 2), _tile_flags(select, block))
+        pair = lambda acc, ij: attn_block_backward(
+            ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
+            select=select)
+    else:
+        pair = lambda acc, ij: attn_block_backward(
+            ij, q, k, v, do, lse, delta, *acc, block=block,
+            interpret=False, window=None if w is None else w * block)
     pairs = _window_pairs(nb, w)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     vma = tuple(frozenset().union(*(jax.typeof(a).vma
@@ -341,6 +390,315 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None):
 
 
 causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+# -- learned sparse attention (DeepSeek-V3.2's DSA) ---------------------------
+def _count_dsa(q, topk: int) -> None:
+    """SPC ``dsa_built``: the attention passes made under a selection,
+    forward rule or backward rule, while steps were traced (as
+    ``attn_window_built``); ``dsa_keys_selected`` the (query, key) pairs
+    those passes attend to, ``min(t + 1, topk)`` a query, and
+    ``dsa_keys_causal`` those full causal passes of their lengths would,
+    both from the shapes."""
+    b, _, s, _ = q.shape
+    full = min(s, topk)
+    spc.record("dsa_built", 1)
+    spc.record("dsa_keys_selected",
+               b * (full * (full + 1) // 2 + (s - full) * topk))
+    spc.record("dsa_keys_causal", b * s * (s + 1) // 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
+                             topk: int):
+    """``causal_flash_attention`` under a data-dependent selection:
+    ``select`` (b, s, s) int8, query-major, says which keys u <= t query
+    t attends to (every row selects a key).  Returns (o (b, h, s, hv)
+    float32, the logsumexp (b, h, s) float32 over the selected keys);
+    ``topk``, the most keys a row selects, is read by the counters alone.
+    No gradient passes through the selection, and none through the
+    logsumexp handed out (what reads it reads a constant).  Both passes
+    walk every causal block pair under its tile of the selection
+    (``_causal_fwd_blocks``, ``_causal_bwd``: the same kernels and twins),
+    a pair that selects nothing passed over by the kernels."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+
+
+def _selected_fwd(q, k, v, select, block, interpret, topk):
+    _count_built(q, k, block, None)
+    _count_dsa(q, topk)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+    o = checkpoint_name(o, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
+    return (o, lse), (q, k, v, o, lse, select)
+
+
+def _selected_bwd(block, interpret, topk, res, cts):
+    *res, select = res
+    _count_dsa(res[0], topk)
+    return (*_causal_bwd(block, interpret, None, tuple(res), cts[0],
+                         select=select), None)
+
+
+selected_flash_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
+def index_scores(qi, ki, w):
+    """The lightning indexer's scores of query rows ``qi`` (b, J, r, di)
+    against every key ``ki`` (b, s, di) under the heads' weights ``w`` (b,
+    r, J) float32 (the scale in them): ``I[t, u] = sum_j w[t, j] relu(qi[t,
+    j] . ki[u])`` (b, r, s) float32; the products' inputs in ``qi``'s type
+    with float32 results, relu, the weights and the sum over the heads,
+    head by head in their order, in float32 (the kernels' order)."""
+    out = 0.0
+    for j in range(qi.shape[1]):
+        z = _contract("brd,bsd->brs", qi[:, j], ki, qi.dtype)
+        out = out + w[:, :, j, None] * jnp.maximum(z, 0.0)
+    return out
+
+
+def select_topk(scores, first: int, topk: int):
+    """The exact selection of query rows ``first`` .. of ``scores`` (b, r,
+    s) float32: a boolean (b, r, s), true at the ``min(t + 1, topk)`` keys
+    u <= t of largest score, a tie at the bar going to the earlier key.
+    ``ops/sparse_attention.index_select``'s ``jnp`` twin, pass for pass:
+    the bar is found by counting (the k-th largest of a row, bit by bit of
+    the scores' ordered bits; then the last position among those that tie
+    with it), which costs a row ``32 + log2(s)`` passes and never sorts."""
+    from ompi_tpu.ops.sparse_attention import INT_MIN, ordered_bits
+
+    b, r, s = scores.shape
+    i32 = jnp.int32
+    t = first + jnp.arange(r, dtype=i32)[:, None]
+    col = jnp.arange(s, dtype=i32)[None, :]
+    seen = col <= t
+    key = jnp.where(seen, ordered_bits(scores), INT_MIN)
+    want = jnp.minimum(t + 1, topk)
+    count = lambda pred: jnp.sum(pred, axis=-1, keepdims=True, dtype=i32)
+    u = jnp.zeros((b, r, 1), i32)
+    for bit in range(31, -1, -1):
+        cand = u | i32(INT_MIN if bit == 31 else 1 << bit)
+        u = jnp.where(count(key >= (cand ^ i32(INT_MIN))) >= want, cand, u)
+    tau = u ^ i32(INT_MIN)
+    need = want - count(key > tau)
+    last = jnp.zeros((b, r, 1), i32)
+    for bit in range((s - 1).bit_length() - 1, -1, -1):
+        cand = last | i32(1 << bit)
+        last = jnp.where(count((key == tau) & (col < cand)) < need, cand,
+                         last)
+    return seen & ((key > tau) | ((key == tau) & (col <= last)))
+
+
+def _index_select_blocks(qi, ki, w, topk: int, rows: int, interpret: bool):
+    """(the selection (b, s, s) int8, each row's logsumexp over its
+    selected scores (b, s) float32) of the indexer's ``qi`` (b, J, s, di),
+    ``ki`` (b, s, di) and ``w`` (b, s, J).  Where Mosaic compiles one call
+    of ``ops/sparse_attention.index_select``, which keeps a tile's scores
+    in VMEM; elsewhere (the CPU) ``rows`` query rows at a time
+    (``index_scores``, ``select_topk``), so that no (s, s, J) array and
+    only one block's (rows, s) scores are ever held."""
+    if not interpret:
+        from ompi_tpu.ops.sparse_attention import index_select
+
+        return index_select(qi, ki, w, topk=topk, interpret=False)
+    b, heads, s, di = qi.shape
+    rows = rows if s % rows == 0 else s
+    nb = s // rows
+
+    def block(xs):
+        qb, wb, first = xs
+        sc = index_scores(qb, ki, wb)
+        chosen = select_topk(sc, first, topk)
+        lse = jax.nn.logsumexp(jnp.where(chosen, sc, -jnp.inf), axis=-1)
+        return chosen.astype(jnp.int8), lse
+
+    sel, lse = jax.lax.map(block, (
+        jnp.moveaxis(qi.reshape(b, heads, nb, rows, di), 2, 0),
+        jnp.moveaxis(w.reshape(b, nb, rows, heads), 1, 0),
+        jnp.arange(nb, dtype=jnp.int32) * rows))
+    return (jnp.moveaxis(sel, 0, 1).reshape(b, s, s),
+            jnp.moveaxis(lse, 0, 1).reshape(b, s))
+
+
+def mean_attention_rows(qb, k, lse_b, chosen):
+    """``pbar`` (b, r, s) float32 of query rows ``qb`` (b, h, r, d): the
+    mean over the query heads of ``exp(q . k / sqrt(d) - lse)`` at the
+    ``chosen`` keys (b, r, s), 0 elsewhere; ``k`` (b, n_kv, s, d),
+    ``lse_b`` (b, h, r) the attention's own logsumexp."""
+    b, h, r, d = qb.shape
+    n_kv = k.shape[1]
+    qg = qb.reshape(b, n_kv, h // n_kv, r, d)
+    sc = _contract("bgerd,bgsd->bgers", qg, k, qb.dtype) / math.sqrt(d)
+    p = jnp.exp(sc - lse_b.reshape(b, n_kv, h // n_kv, r)[..., None])
+    return jnp.where(chosen, jnp.sum(p, axis=(1, 2)) / h, 0.0)
+
+
+def _index_loss_rows(qi, ki, w, q, k, lse, select, rows: int):
+    """The alignment loss by row (b, s), differentiable in ``qi``, ``ki``
+    and ``w`` (``ops/sparse_attention.index_loss``'s ``jnp`` twin):
+    ``KL(pbar[t, .] || softmax_S(I[t, .]))`` over the selected keys, a
+    block of ``rows`` query rows at a time."""
+    b, heads, s, di = qi.shape
+    rows = rows if s % rows == 0 else s
+    nb = s // rows
+    by_rows = lambda a, axis: jnp.moveaxis(a.reshape(
+        a.shape[:axis] + (nb, rows) + a.shape[axis + 1:]), axis, 0)
+
+    def block(xs):
+        qib, wb, qb, lse_b, sel_b = xs
+        chosen = sel_b != 0
+        sc = index_scores(qib, ki, wb)
+        logq = sc - jax.nn.logsumexp(jnp.where(chosen, sc, -jnp.inf),
+                                     axis=-1, keepdims=True)
+        pbar = mean_attention_rows(qb, k, lse_b, chosen)
+        live = pbar > 0.0
+        return jnp.sum(jnp.where(live, pbar * (jnp.log(jnp.where(
+            live, pbar, 1.0)) - jnp.where(chosen, logq, 0.0)), 0.0), axis=-1)
+
+    kl = jax.lax.map(block, (by_rows(qi, 2), by_rows(w, 1), by_rows(q, 2),
+                             by_rows(lse, 2), by_rows(select, 1)))
+    return jnp.moveaxis(kl, 0, 1).reshape(b, s)
+
+
+def _index_loss_blocks(qi, ki, w, q, k, lse, ilse, select, rows, interpret):
+    """(the alignment loss by row (b, s), its sum's gradients with respect
+    to ``qi``, ``ki`` and ``w``): where Mosaic compiles one call of
+    ``ops/sparse_attention.index_loss``, which makes the four in one pass
+    over the causal tile pairs; elsewhere ``_index_loss_rows`` and its
+    autodiff."""
+    if not interpret:
+        from ompi_tpu.ops.sparse_attention import index_loss
+
+        return index_loss(q, k, lse, qi, ki, w, ilse, select,
+                          interpret=False)
+    kl, back = jax.vjp(lambda *a: _index_loss_rows(*a, q, k, lse, select,
+                                                   rows), qi, ki, w)
+    return (kl, *back(jnp.ones_like(kl)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def index_alignment_loss(qi, ki, w, q, k, lse, ilse, select, rows: int,
+                         interpret: bool):
+    """DSA's alignment loss of one layer: (``sum_t KL(pbar[t, .] ||
+    softmax_{S_t}(I[t, .]))``, the same by row (b, s), which is reported
+    and carries no gradient).  ``pbar`` is made from q, k and the
+    attention's logsumexp, all three read as constants (the published
+    loss detaches the attention's distribution); the gradient reaches
+    ``qi``, ``ki`` and ``w`` alone.  The forward rule makes the loss and
+    its gradients in one pass and names them (``DSA_LOSS``), so a
+    checkpointed layer's backward pass only scales what its forward pass
+    kept."""
+    kl = _index_loss_blocks(qi, ki, w, q, k, lse, ilse, select, rows,
+                            interpret)[0]
+    return jnp.sum(kl), kl
+
+
+def _index_loss_fwd(qi, ki, w, q, k, lse, ilse, select, rows, interpret):
+    kl, dqi, dki, dw = (checkpoint_name(a, DSA_LOSS) for a in
+                        _index_loss_blocks(qi, ki, w, q, k, lse, ilse,
+                                           select, rows, interpret))
+    return (jnp.sum(kl), kl), (dqi.astype(qi.dtype), dki.astype(ki.dtype),
+                               dw)
+
+
+def _index_loss_bwd(rows, interpret, res, cts):
+    scale = cts[0]
+    return tuple((g * scale).astype(g.dtype) for g in res) + (None,) * 5
+
+
+index_alignment_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+
+
+def dsa_attention(p, x, cfg, *, interpret: bool, at=None):
+    """Grouped-query attention under DeepSeek-V3.2's learned sparse
+    attention (DSA), **without** the residual add, on the residual stream
+    ``x`` (b, s, d) float32.  q, k and v are ``gqa_attention``'s QK-normed
+    form with RoPE over the whole head (lfm2's).  Beside them a **lightning
+    indexer** reads the normed input with its gradient stopped: ``qI = hI
+    W_qI`` (``index_heads`` heads of ``index_head_dim``), one key a
+    position ``kI = LayerNorm(hI W_kI)``, RoPE on both, the heads' weights
+    ``w = hI W_wI`` in float32; ``I[t, u] = sum_j w[t, j] relu(qI[t, j] .
+    kI[u]) / sqrt(heads x width)`` for u <= t.  Query t attends to ``S_t``,
+    the ``min(t + 1, index_topk)`` keys of largest ``I[t, .]``, chosen
+    exactly (``_index_select_blocks``) and a constant of the step: softmax
+    over ``S_t`` through the flash kernels under the selection's tiles
+    (``selected_flash_attention``).  The indexer learns from
+    ``index_alignment_loss`` alone, whose ``pbar`` is read from q, k and
+    the kernels' logsumexp as constants; nothing else of the step reaches
+    its leaves.
+
+    Returns (the sublayer's output, {``index_kl_sum``: the alignment loss
+    summed over the rows}, what a check reads: ``attn_qk_in`` / ``attn_qk``
+    as ``gqa_attention``; the selection packed eight keys a byte
+    (``dsa_selection_seq`` (b, s, s / 8) uint8, key u in bit u % 8 of byte
+    u // 8); the index key ``dsa_ki_seq`` (T, di), the first key-value
+    head's ``dsa_k_seq`` and ``dsa_v_seq`` (T, hd) and every key-value
+    head's ``dsa_kall_seq`` (T, n_kv hd) whole; and at the rows ``at``
+    (flat token rows of this shard) ``dsa_qi_at`` (R, J di), ``dsa_w_at``
+    (R, J), the scores made again from those ``dsa_index_at`` (R, s),
+    every head's q ``dsa_q_at`` (R, h hd) and logsumexp ``dsa_lse_at`` (R,
+    h), the first head's ``dsa_o_at`` (R, hd), the row's loss
+    ``dsa_kl_at`` (R,))."""
+    b, s, _ = x.shape
+    nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
+    eps, theta = cfg.rms_norm_eps, cfg.rope_theta
+    heads, di, topk = cfg.index_heads, cfg.index_head_dim, cfg.index_topk
+    split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], eps)
+        q_in, k_in = (split(matmul(h, p[m], dt), n)
+                      for m, n in (("wq", nh), ("wk", nkv)))
+        q, k = (rope(rmsnorm_gain(t, p[g], eps), theta)
+                for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+        first = lambda a, c: jnp.concatenate(
+            [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
+        seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+        q, k = q.astype(dt), k.astype(dt)
+        v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
+    with jax.named_scope("otpu_dsa_index"):
+        hi = jax.lax.stop_gradient(h)
+        qi = rope(split(matmul(hi, p["index_wq"], dt), heads), theta
+                  ).astype(dt)
+        ki = layernorm(matmul(hi, p["index_wk"], dt), p["index_k_norm"],
+                       p["index_k_bias"], eps)
+        ki = rope(ki[:, None], theta)[:, 0].astype(dt)
+        w = jnp.dot(hi, p["index_ww"], precision=jax.lax.Precision.HIGHEST
+                    ) * (heads * di) ** -0.5
+    with jax.named_scope("otpu_dsa_select"):
+        sel, ilse = _index_select_blocks(
+            *(jax.lax.stop_gradient(a) for a in (qi, ki, w)), topk,
+            cfg.index_q_chunk, interpret)
+        sel = checkpoint_name(sel, DSA_SELECTION)
+        ilse = checkpoint_name(ilse, DSA_INDEX_LSE)
+    o, lse = selected_flash_attention(q, k, v, sel, min(cfg.attn_block, s),
+                                      interpret, topk)
+    with jax.named_scope("otpu_dsa_loss"):
+        kl_sum, kl = index_alignment_loss(
+            qi, ki, w, *(jax.lax.stop_gradient(a) for a in (q, k, lse)),
+            ilse, sel, cfg.index_q_chunk, interpret)
+    with jax.named_scope("otpu_stats"):
+        rows = lambda t: t.reshape(b * s, -1).astype(jnp.float32)
+        seen.update(
+            dsa_selection_seq=jnp.packbits(sel.astype(jnp.uint8), axis=-1,
+                                           bitorder="little"),
+            dsa_ki_seq=rows(ki), dsa_k_seq=rows(k[:, 0]),
+            dsa_v_seq=rows(v[:, 0]),
+            dsa_kall_seq=rows(k.transpose(0, 2, 1, 3)))
+        if at is not None:
+            bi, ti = at // s, at % s
+            qi_at, w_at = qi[bi, :, ti], w[bi, ti]          # (R, J, di)
+            seen.update(
+                dsa_qi_at=qi_at.reshape(len(at), -1).astype(jnp.float32),
+                dsa_w_at=w_at,
+                dsa_index_at=index_scores(
+                    qi_at[:, :, None], ki[bi], w_at[:, None])[:, 0],
+                dsa_q_at=q[bi, :, ti].reshape(len(at), -1).astype(
+                    jnp.float32),
+                dsa_lse_at=lse[bi, :, ti], dsa_o_at=o[bi, 0, ti],
+                dsa_kl_at=kl[bi, ti])
+    with jax.named_scope("otpu_attn_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return matmul(o, p["wo"], dt), {"index_kl_sum": kl_sum}, seen
 
 
 def olmoe_attention(p, x, cfg, *, interpret: bool):
@@ -412,6 +770,11 @@ def gqa_attention(p, x, cfg, *, interpret: bool,
     their projections, with their own heads: the flash kernels read a
     group's shared head through their index maps and sum its query
     heads' gradients in float32.
+
+    (A fifth model's q, k and v, Keye-VL-2.0's, share lfm2's form below,
+    the per-head QK-norm and RoPE over the whole head, but its sublayer is
+    ``dsa_attention``, which puts a learned selection between them and the
+    kernels: there is no fifth branch here.)
 
     Four models' sublayer, told apart by what the layer holds and, where
     the leaves cannot say, by the layer's ``kind`` (its ``layer_types``
@@ -1046,7 +1409,7 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True):
 
 
 def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
-                  kind: str = "full_attention"):
+                  kind: str = "full_attention", at=None):
     """One decoder layer of a public model, its sublayers chosen by what
     the layer holds and the configuration's published keys say.
 
@@ -1067,7 +1430,10 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
     ``sliding_attention`` layers hold the same leaves: ``kind``, the
     layer's ``layer_types`` name, a static argument, says which this is,
     and ``gqa_attention`` reads RoPE and the window off it; a window
-    layer's sublayer goes under ``otpu_swa``); latent attention where
+    layer's sublayer goes under ``otpu_swa``; a ``sparse_attention``
+    layer's is ``dsa_attention``, under ``otpu_dsa``, which also reads
+    ``at``, the token rows a step samples, and whose alignment loss goes
+    out beside the router's statistics); latent attention where
     ``kv_lora_rank`` is set (JoyAI-LLM-Flash); else OLMoE's attention.
     The feed-forward: a dense SwiGLU where the layer has no router
     (JoyAI's and LFM2's leading layers), else the sparse MLP
@@ -1094,7 +1460,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
             y, stats, routed = experts.moe_latent_block(
                 p, x, cfg, bias, interpret=interpret)
         return x + y, stats, routed
-    seen, routed = {}, None
+    seen, routed, index_stats = {}, None, {}
     if cfg.router_before_attention and "router" in p:
         with jax.named_scope("otpu_moe"):
             rows = x.reshape(-1, x.shape[-1])
@@ -1106,6 +1472,11 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
     elif cfg.layer_types and "in_proj" in p:
         with jax.named_scope("otpu_conv"):
             y, seen = short_conv(p, x, cfg)
+        x = x + y
+    elif cfg.layer_types and kind == "sparse_attention":
+        with jax.named_scope("otpu_dsa"):
+            y, index_stats, seen = dsa_attention(p, x, cfg,
+                                                 interpret=interpret, at=at)
         x = x + y
     elif cfg.layer_types:
         with jax.named_scope("otpu_swa") if kind == "sliding_attention" \
@@ -1124,7 +1495,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
             h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps)
             y = swiglu(h.reshape(-1, h.shape[-1]), p["gate"], p["up"],
                        p["down"], cfg.compute_dtype)
-        return x + y.reshape(x.shape), {}, seen
+        return x + y.reshape(x.shape), index_stats, seen
     with jax.named_scope("otpu_moe"):
         if cfg.routes_to_held:
             y, stats, made = experts.moe_shared_local_block(
@@ -1132,4 +1503,4 @@ def decoder_layer(p, x, cfg, *, interpret: bool, bias=None,
         else:
             y, stats, made = experts.moe_sorted_block(
                 p, x, cfg, interpret=interpret)
-    return x + y, stats, {**made, **seen}
+    return x + y, {**stats, **index_stats}, {**made, **seen}

@@ -1,5 +1,6 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
-Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B):
+Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
+Keye-VL-2.0-30B-A3B's language model):
 widths from a configuration file, not
 from the mesh; the kinds of sublayer from its published keys
 (``ModelConfig``).  The
@@ -36,33 +37,36 @@ from ompi_tpu.runtime import spc, trace
 LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
                 "router", "gate", "up", "down")
 GAINS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "q_a_norm",
-         "kv_a_norm", "enorm", "hnorm", "norm", "gate_norm")
+         "kv_a_norm", "enorm", "hnorm", "norm", "gate_norm", "index_k_norm")
 #: a Mamba-2 mixer's leaves that are no matrices: like the gains they are
-#: not decayed, and each starts as ``init_model_params`` says
-UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b")
+#: not decayed, and each starts as ``init_model_params`` says (an indexer's
+#: LayerNorm bias at zero)
+UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b", "index_k_bias")
 #: a hybrid pattern's letters (nemotron_h) and the group a layer of each
 #: kind goes by in the parameter tree; behind them the letters a
 #: ``layer_types`` model's layers are walked by (lfm2_moe: an operator,
 #: then a feed-forward): a gated short convolution or grouped-query
 #: attention (qwen3_next: a Gated DeltaNet operator or output-gated
 #: attention; smallthinker: attention in full or under a sliding window,
-#: two kinds of the same leaves), before a dense SwiGLU (small letter) or
-#: the experts (capital)
+#: two kinds of the same leaves; Keye-VL-2.0: attention under a learned
+#: selection, which holds an indexer's leaves beside attention's), before a
+#: dense SwiGLU (small letter) or the experts (capital)
 PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe",
                  "c": "conv_dense", "a": "attn_dense", "l": "gdn_dense",
-                 "w": "swa_dense",
+                 "w": "swa_dense", "s": "dsa_dense",
                  "C": "conv_moe", "A": "attn_moe", "L": "gdn_moe",
-                 "W": "swa_moe"}
+                 "W": "swa_moe", "S": "dsa_moe"}
 #: the letters whose layer holds a router
-EXPERT_LETTERS = "ECALW"
+EXPERT_LETTERS = "ECALWS"
 #: ``layer_types``' names and the letter's lower case
 OPERATOR_LETTERS = {"conv": "c", "full_attention": "a",
-                    "linear_attention": "l", "sliding_attention": "w"}
+                    "linear_attention": "l", "sliding_attention": "w",
+                    "sparse_attention": "s"}
 #: a ``layer_types`` letter's name, which ``decoder_layer`` is told
 LETTER_OPERATORS = {v: k for k, v in OPERATOR_LETTERS.items()}
 #: what an operator's sublayer reports by token row goes by its own name
 #: into a step's ``sample``; what a router does, behind ``router_``
-OPERATOR_SAMPLES = ("ssm_", "conv_", "attn_", "gdn_")
+OPERATOR_SAMPLES = ("ssm_", "conv_", "attn_", "gdn_", "dsa_")
 PROBE = 64              # entries of each leaf that a step reports
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
@@ -100,8 +104,11 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: and gated (``attn_og`` (A, R, hd)); of every sliding-window layer's
 #: first query head and its key-value head what the kernels read
 #: (``attn_win_q`` (W, R, hd); ``attn_win_k_seq``, ``attn_win_v_seq`` (W,
-#: T, hd) whole) and made (``attn_win_o`` (W, R, hd)); a gated shared
-#: expert's gate
+#: T, hd) whole) and made (``attn_win_o`` (W, R, hd)); of every
+#: ``sparse_attention`` layer what ``model.dsa_attention`` lists (``dsa_*``:
+#: the selection packed, the indexer's and attention's inputs whole or at
+#: the sampled rows, the row's scores, output and alignment loss); a gated
+#: shared expert's gate
 #: (``router_shared_gate`` (L, R, 1)); where the router stands before
 #: attention the normed rows the held experts read and their weighted sum
 #: (``router_expert_in``, ``router_expert_out`` (L, R, d)); under
@@ -176,7 +183,18 @@ class ModelConfig:
     scores by ``softmax`` under no bias; the experts are relu-gated
     (``mlp_hidden_act`` ``relu``: ReGLU) with no shared one.  The
     router's place and the activation are the model's report's, no
-    published key: the loader sets them by ``model_type``."""
+    published key: the loader sets them by ``model_type``.
+
+    KeyeVL2's keys (Keye-VL-2.0-30B-A3B's language model): a
+    ``layer_types`` model too, every layer ``sparse_attention``
+    (``load_model_config`` derives the names from ``sa_config``): lfm2's
+    QK-normed attention on heads of ``head_dim`` under DeepSeek-V3.2's
+    learned selection (``model.dsa_attention``): an indexer of
+    ``index_heads`` heads of ``index_head_dim`` and one key a position, the
+    ``index_topk`` best keys a query (``sa_config``'s ``indexer_num_heads``,
+    ``indexer_head_dim``, ``topk``; its two chunk sizes are the sizes of
+    the blocks the scores are made by), an alignment loss times
+    ``index_loss_coef``; qwen3_next's softmax router with no shared expert."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -199,6 +217,8 @@ class ModelConfig:
     adam_eps: float = 1e-8
     weight_decay: float = 0.1
     init_std: float = 0.02
+    embed_init_std: float | None = None     # the embedding's rows, where
+    #                                         they start wider than init_std
     compute_dtype: str = "bfloat16"
     attn_block: int = 1024
     loss_block_rows: int = 1024
@@ -258,6 +278,13 @@ class ModelConfig:
     rope_kinds: tuple = ("full_attention", "sliding_attention")
     qk_norm: bool = True            # a layer_types model's attention
     router_before_attention: bool = False
+    # KeyeVL2's keys (Keye-VL-2.0-30B-A3B): its ``sa_config``
+    index_heads: int = 0            # the indexer's query heads
+    index_head_dim: int = 0
+    index_topk: int = 0             # the keys a query attends to
+    index_q_chunk: int = 512        # the score blocks' sizes: they change
+    index_kv_chunk: int = 512       # no number
+    index_loss_coef: float = 1.0
 
     @property
     def pattern_here(self) -> str:
@@ -451,6 +478,18 @@ class ModelConfig:
                 f"sliding_window {self.sliding_window}: a window is a "
                 "layer_types model's sliding_attention layers', and a whole "
                 f"number of attn_block {self.attn_block} positions")
+        sparse = "sparse_attention" in self.layer_types
+        if sparse != bool(self.index_topk) or (sparse and (
+                min(self.index_heads, self.index_head_dim) < 1
+                or self.index_head_dim % 2 or not self.qk_norm
+                or self.attn_output_gate or windowed
+                or self.partial_rotary_factor != 1.0)):
+            raise NotImplementedError(
+                f"index_topk {self.index_topk} (sa_config): a learned "
+                "selection is a layer_types model's sparse_attention "
+                "layers', with an indexer of index_heads heads of an even "
+                "index_head_dim, on QK-normed attention with RoPE over the "
+                "whole head, no output gate and no sliding window beside it")
         if not typed and (not self.qk_norm or self.router_before_attention):
             raise NotImplementedError(
                 f"qk_norm {self.qk_norm} / router_before_attention "
@@ -458,7 +497,7 @@ class ModelConfig:
                 "model's attention goes without a QK-norm, and only its "
                 "router reads the layer's input")
         if (hybrid or typed) and (
-                set(self.pattern_here) - set("M*E" if hybrid else "calwCALW")
+                set(self.pattern_here) - set("M*E" if hybrid else "calwsCALWS")
                 or len(self.pattern_here) != self.layers_here):
             raise ValueError(
                 f"layers_here {self.layers_here} from first_layer_here "
@@ -510,6 +549,16 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
     with open(path, encoding="utf-8") as f:
         body = json.load(f)
     hybrid = "hybrid_override_pattern" in body
+    keye = body.get("model_type") == "KeyeVL2"
+    sparse = body.get("sa_config")
+    if sparse and (not keye or hybrid or "kv_lora_rank" in body
+                   or "layer_types" in body or body.get("sliding_window")
+                   or body.get("use_sliding_window")):
+        raise NotImplementedError(
+            f"{path}: sa_config: a learned selection is a KeyeVL2 model's, "
+            "on grouped-query attention in every layer; sa_config beside a "
+            "sliding_window, or in a latent-attention (kv_lora_rank), "
+            "hybrid_override_pattern or other layer_types model is not run")
     next_ = body.get("model_type") == "qwen3_next"
     if next_:
         for key, runs in (("mlp_only_layers", []), ("decoder_sparse_step", 1),
@@ -547,12 +596,40 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             "an attention window and RoPE by layer are a smallthinker "
             "model's; a window in a hybrid_override_pattern, latent-"
             "attention or other layer_types model is not run")
+    if keye:
+        for key, runs in (("mlp_only_layers", []), ("decoder_sparse_step", 1)):
+            if body.get(key, runs) != runs:
+                raise NotImplementedError(
+                    f"{path}: {key} {body[key]}: a KeyeVL2 model is run "
+                    "with every layer sparse")
+        if not sparse or sparse.get("indexer_num_kv_heads") != 1:
+            raise NotImplementedError(
+                f"{path}: sa_config {sparse}: a KeyeVL2 model is run under "
+                "its learned selection, the indexer with one key a position "
+                "(indexer_num_kv_heads 1)")
+        body.setdefault("layer_types",
+                        ["sparse_attention"] * body["num_hidden_layers"])
+    scaling = body.get("rope_scaling")
+    if scaling:
+        # M-RoPE's three position components are equal on a text token, so
+        # on text ids ``default`` scaling with sections is plain RoPE
+        kinds = {scaling.get("rope_type", "default"),
+                 scaling.get("type", "default")}
+        section = scaling.get("mrope_section")
+        if kinds != {"default"} or set(scaling) - {
+                "rope_type", "type", "mrope_section"} or not section \
+                or 2 * sum(section) != body.get("head_dim"):
+            raise NotImplementedError(
+                f"{path}: rope_scaling {scaling}: only rope_type default "
+                "with an mrope_section that sums to half of head_dim is "
+                "run (plain RoPE on text ids); every other scaling of the "
+                "rotary frequencies is not")
     typed = "layer_types" in body       # lfm2_moe: its file names no
     #                                     activation, its code runs silu
     act = body.get("mlp_hidden_act") if hybrid else body.get(
         "hidden_act", "silu" if typed else None)
     if act != ("relu2" if hybrid else "silu") or body.get("attention_bias") \
-            or body.get("clip_qkv") or body.get("rope_scaling") \
+            or body.get("clip_qkv") \
             or body.get("moe_layer_freq", 1) != 1 \
             or ("kv_lora_rank" in body and not body.get("rope_interleave")):
         raise NotImplementedError(
@@ -614,6 +691,14 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             {t for t, on in zip(merged["layer_types"], turned) if on}))
         merged.update(qk_norm=False, router_before_attention=True,
                       mlp_hidden_act="relu")
+    if keye:
+        for theirs, ours in (("indexer_num_heads", "index_heads"),
+                             ("indexer_head_dim", "index_head_dim"),
+                             ("topk", "index_topk"),
+                             ("q_chunk_size", "index_q_chunk"),
+                             ("kv_chunk_size", "index_kv_chunk")):
+            if theirs in sparse:
+                merged.setdefault(ours, sparse[theirs])
     if next_:       # its modelling code's, on which config.json is silent
         merged.setdefault("attn_output_gate", True)
         shared = bool(merged.get("moe_shared_expert_intermediate_size"))
@@ -703,6 +788,15 @@ def pattern_layer_shapes(cfg: ModelConfig) -> dict:
                        "gate_norm": (cfg.linear_value_head_dim,),
                        "out_proj": (val, d)}}
         ops["swa"] = ops["attn"]    # a window layer holds what a full one does
+        # a sparse_attention layer: attention's leaves and the indexer's
+        # (its queries, its one key and that key's LayerNorm, its heads'
+        # weights)
+        index = cfg.index_heads * cfg.index_head_dim
+        ops["dsa"] = {**ops["attn"], "index_wq": (d, index),
+                      "index_wk": (d, cfg.index_head_dim),
+                      "index_k_norm": (cfg.index_head_dim,),
+                      "index_k_bias": (cfg.index_head_dim,),
+                      "index_ww": (d, cfg.index_heads)}
         moe = {"ln2": (d,), "router": (d, cfg.num_experts),
                "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
         if fs:
@@ -834,7 +928,11 @@ def sample_rows(rows: int) -> np.ndarray:
 
 def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
     """Float32 master parameters drawn on the default device from
-    ``seed``: normal(0, ``init_std``) matrices, gains of one (and a Gated
+    ``seed``: normal(0, ``init_std``) matrices (the embedding's rows
+    normal(0, ``embed_init_std``) where the file gives one: at 0.02 a
+    token's own row is a seventh of what attention's mean of values adds
+    to every position alike, and each layer's router then sends nearly
+    every token to the same eight experts), gains of one (and a Gated
     DeltaNet operator's ``dt_bias``; its ``A_log`` and taps start as a
     mixer's).  A Mamba-2
     mixer's other leaves as its authors start them (arXiv:2405.21060's
@@ -851,6 +949,8 @@ def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
         if is_gain(name) or last == "D" or (
                 last == "dt_bias" and cfg.layer_types):
             return jnp.ones(shape, jnp.float32)
+        if last == "index_k_bias":
+            return jnp.zeros(shape, jnp.float32)
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         if last == "A_log":
             return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1., 16.))
@@ -863,7 +963,10 @@ def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
         if last in ("conv_w", "conv_b"):
             bound = cfg.conv_kernel ** -0.5
             return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
-        return cfg.init_std * jax.random.normal(k, shape, jnp.float32)
+        std = cfg.init_std
+        if name == "embed" and cfg.embed_init_std is not None:
+            std = cfg.embed_init_std
+        return std * jax.random.normal(k, shape, jnp.float32)
 
     shapes, tree = model_param_shapes(cfg), {}
     for name, path in leaf_names(cfg):
@@ -1007,7 +1110,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         because two kinds of attention layer hold the same leaves."""
         def run(layer, x, bias_row):
             x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
-                                        bias=bias_row, kind=kind)
+                                        bias=bias_row, kind=kind, at=at)
             experts = seen.pop("experts", None)
             with jax.named_scope("otpu_stats"):
                 # a router's rows at the sampled ones; of a mixer's scan,
@@ -1016,7 +1119,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                 # their norm and RoPE at the sampled
                 out = (jax.tree.map(psum, st), experts, {
                     k if k.startswith(OPERATOR_SAMPLES) else "router_" + k:
-                    v if k.endswith("_seq") else v[at]
+                    v if k.endswith(("_seq", "_at")) else v[at]
                     for k, v in seen.items()})
             return x, out
 
@@ -1070,6 +1173,13 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                 z = jnp.sum(st["z_sum"], 0) / routed
         lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
         total = ce + lb + z
+        # a learned selection's alignment loss, every layer's rows in one
+        # mean a token: its gradient reaches the indexers' leaves alone
+        index = None
+        if "index_kl_sum" in st:
+            index = cfg.index_loss_coef * jnp.sum(st["index_kl_sum"]) \
+                / n_global
+            total = total + index
     losses, loads = [ce, lb, z], st["slots"]
     with jax.named_scope("otpu_stats"):
         sample["head_in"] = h.reshape(b * s, -1)[at]
@@ -1121,6 +1231,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             # in whole chunks (``experts.local_expert_ffn``)
             aux["chunk_rows"] = jnp.sum(
                 (held.astype(jnp.int32) + chunk - 1) // chunk * chunk)
+    if index is not None:
+        losses.append(index)
     with jax.named_scope("otpu_stats"):
         losses = jnp.stack([total] + losses)
     return total, {"losses": losses, "loads": loads, "rows": rows,
@@ -1255,8 +1367,8 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         held = set(cfg.pattern_here.lower())
         # an attention layer of a kind RoPE turns reports q and k around
         # it; every one of a model without a QK-norm
-        turned = {OPERATOR_LETTERS[k] for k in cfg.rope_kinds} & held \
-            if cfg.qk_norm else {"a", "w"} & held
+        turned = ({OPERATOR_LETTERS[k] for k in cfg.rope_kinds} | {"s"}) \
+            & held if cfg.qk_norm else {"a", "w"} & held
         aux_specs["sample"].update(
             {k: rows for on, keys in (
                 ("c" in held, ("conv_bcu_seq", "conv_y")),
@@ -1271,6 +1383,13 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
                                        gdn_beta_seq=P(None, "dp"))
         if "a" in held and cfg.attn_output_gate:
             aux_specs["sample"].update(attn_og_in=rows, attn_og=rows)
+        if "s" in held:
+            aux_specs["sample"].update(
+                {"dsa_" + k: rows for k in (
+                    "ki_seq", "k_seq", "v_seq", "kall_seq", "qi_at", "w_at",
+                    "index_at", "q_at", "lse_at", "o_at")},
+                dsa_kl_at=P(None, "dp"),
+                dsa_selection_seq=P(None, "dp", None, None))
     if cfg.shared_expert_gate:
         aux_specs["sample"]["router_shared_gate"] = rows
     if cfg.router_before_attention:

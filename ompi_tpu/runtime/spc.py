@@ -157,6 +157,12 @@ _COUNTERS = (
     # the passes walk, and those full causal passes of their lengths
     # would: walked over causal is what the windows spare
     "attn_window_built", "attn_pairs_walked", "attn_pairs_causal",
+    # learned sparse attention (parallel/model.selected_flash_attention):
+    # the attention passes made under a selection while steps were traced,
+    # the (query, key) pairs they attend to and those full causal passes
+    # of their lengths would, both from the shapes: selected over causal
+    # is what the selection leaves of the triangle
+    "dsa_built", "dsa_keys_selected", "dsa_keys_causal",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

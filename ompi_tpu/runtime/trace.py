@@ -577,6 +577,7 @@ OWN_PROGRAMS = (
     "transpose_blocks",                             # ops/pallas_ddt
     "gmm", "tgmm",                                  # ops/grouped_matmul
     "flash_causal_forward", "attn_block_backward",  # ops/flash_attention
+    "index_select", "index_loss",                   # ops/sparse_attention
     "rule_forward", "rule_backward",                # ops/gated_delta
     "conv_forward", "conv_backward",                # ops/causal_conv
     "encode_int8", "decode_int8", "dequant_accumulate",  # ops/pallas_quant
@@ -1070,6 +1071,12 @@ STEP_SCOPES = (
     "otpu_gdn_norm",        # the gated norm: RMSNorm a head, silu(z)
     "otpu_swa",             # a sliding-window layer's attention sublayer
                             # (a full layer's keeps otpu_attention)
+    "otpu_dsa",             # a learned sparse attention sublayer, whole
+                            # (in otpu_attention's place)
+    "otpu_dsa_index",       # inside it: the indexer's projections, norm,
+                            # RoPE and score blocks
+    "otpu_dsa_select",      # the exact top-k and whatever builds the mask
+    "otpu_dsa_loss",        # pbar, the KL, the indexer's backward
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

@@ -412,6 +412,52 @@ def cases(mesh1d, mesh2d):
     case("vpu_reduce_stack_rows_prod_f32", lambda: (
         pr.reduce_stack, ("PROD", _sds((4, ROW), f32, one, P())),
         {"interpret": False}))
+    # Keye-VL-2.0's learned sparse attention at the cell's shape (1 x 32
+    # query heads on 4 key-value heads x 16,384 at 128; an indexer of 16
+    # heads of 64, top 2,048): both flash kernels under a selection's int8
+    # tiles, and the two kernels of ``ops/sparse_attention``
+    def select_args(b, s):
+        return _sds((b, s, s), jnp.int8, one, P())
+
+    def flash_select_forward(b, h, s, d, n_kv):
+        q, k, v = attn_bwd_args(b, h, s, d, d, n_kv)[:3]
+        return fa.flash_causal_forward, (q, k, v), {
+            "block": 1024, "interpret": False, "select": select_args(b, s)}
+
+    def attn_select_backward(b, h, s, d, n_kv):
+        fn, args, kw = attn_block_backward(b, h, s, d, d, n_kv)
+        flags = _sds((b * (s // 1024) ** 2,), jnp.int32, one, P())
+        return fn, args, {**kw, "select": (select_args(b, s), flags)}
+
+    def dsa_index_args(b, s, heads, di):
+        return (_sds((b, heads, s, di), bf16, one, P()),
+                _sds((b, s, di), bf16, one, P()),
+                _sds((b, s, heads), f32, one, P()))
+
+    def dsa_index_select(b, s, heads, di, topk):
+        from ompi_tpu.ops import sparse_attention as sa
+
+        return sa.index_select, dsa_index_args(b, s, heads, di), {
+            "topk": topk, "interpret": False}
+
+    def dsa_index_loss(b, h, s, d, n_kv, heads, di):
+        from ompi_tpu.ops import sparse_attention as sa
+
+        q, k = attn_bwd_args(b, h, s, d, d, n_kv)[:2]
+        row = _sds((b, s), f32, one, P())
+        return sa.index_loss, (
+            q, k, _sds((b, h, s), f32, one, P()),
+            *dsa_index_args(b, s, heads, di), row, select_args(b, s)), {
+                "interpret": False}
+
+    case("keye_flash_select_forward",
+         lambda: flash_select_forward(1, 32, 16384, 128, 4))
+    case("keye_attn_select_backward",
+         lambda: attn_select_backward(1, 32, 16384, 128, 4))
+    case("keye_dsa_index_select",
+         lambda: dsa_index_select(1, 16384, 16, 64, 2048))
+    case("keye_dsa_index_loss",
+         lambda: dsa_index_loss(1, 32, 16384, 128, 4, 16, 64))
     case("vpu_reduce_stack_rows_band_i32", lambda: (
         pr.reduce_stack, ("BAND", _sds((4, ROW), jnp.int32, one, P())),
         {"interpret": False}))
@@ -569,6 +615,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "qwen3-next-80b-a3b-train-1chip"))
     case("smallthinker_step_1chip", lambda: model_step(
         topo_devs[:1], "smallthinker-21b-a3b-train-1chip"))
+    case("keye_step_1chip", lambda: model_step(
+        topo_devs[:1], "keye-vl2-30b-a3b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

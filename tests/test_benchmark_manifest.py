@@ -190,6 +190,35 @@ def _cut_batch_thinker(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# keye-train-1chip: the same, at the widths of tests/test_keye_train.py (a
+# scanned run of four sparse-attention layers: 8 query heads of 16 on 2
+# key-value heads, an indexer of 4 heads of 8, top 24 of 64 positions in
+# blocks of 16, 4 of 16 experts, 64 of 256 ids)
+KEYE = "keye-train-1chip"
+TINY_KEYE = dict(hidden_size=64, head_dim=16, num_attention_heads=8,
+                 num_key_value_heads=2, moe_intermediate_size=24,
+                 num_experts=16, num_experts_per_tok=3, vocab_size=256,
+                 vocab_here=64, experts_here=4,
+                 rope_scaling={"rope_type": "default", "type": "default",
+                               "mrope_section": [2, 3, 3]},
+                 sa_config={"indexer_head_dim": 8, "indexer_num_heads": 4,
+                            "indexer_num_kv_heads": 1, "kv_chunk_size": 16,
+                            "q_chunk_size": 16, "topk": 24})
+TINY_KEYE_TRAIN = dict(seq_len=64, micro_batch=1, attn_block=16,
+                       loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_keye(config):
+    config.update(TINY_KEYE)
+    config["train"].update(TINY_KEYE_TRAIN)
+
+
+def _cut_batch_keye(p):
+    p.update(sequences=TINY_KEYE_TRAIN["micro_batch"],
+             seq_len=TINY_KEYE_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -262,6 +291,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("smallthinker-21b-a3b-train-1chip", _tiny_thinker),
         cut={"packed-16k-window-steps": _cut_batch_thinker}),
+    KEYE: dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("keye-vl2-30b-a3b-train-1chip", _tiny_keye),
+        cut={"packed-16k-sparse-steps": _cut_batch_keye}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -271,7 +305,7 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
                       "train_ssm_layer_tokens", "moe_bias_updates")
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
-              "qwen3next-train-1chip", "smallthinker-train-1chip")
+              "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE)
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -285,7 +319,7 @@ PEAK_METRIC = "step.hbm_peak_share"
 LIVE_ROWS = "moe.live_row_share"
 SHARE_CELLS = ("joyai-train-1chip", "nemotron3-train-1chip",
                "lfm2-train-1chip", "qwen3next-train-1chip",
-               "smallthinker-train-1chip")
+               "smallthinker-train-1chip", KEYE)
 
 # the child: run_cell as the command calls it, but for the three
 # arguments it keeps for rehearsals.  What the program counts is read
@@ -328,7 +362,8 @@ result = run.run_cell({cell!r}, seed=2147483999, seconds=0.3, trace=False,
 builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                  if k.startswith(("device_", "train_",
-                                                  "moe_"))}}))
+                                                  "moe_", "attn_",
+                                                  "dsa_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
 print("layer " + json.dumps(layer))
@@ -773,6 +808,78 @@ def test_a_window_step_counts_its_routers_and_its_slots(rehearsal):
     assert rehearsal["builds"] == [1, 1]
 
 
+@of_cells(KEYE)
+def test_a_sparse_attention_step_counts_its_routers_and_its_selection(
+        rehearsal):
+    """The trainer's counters on one chip's share of Keye-VL-2.0's language
+    model, by the kind that reads everything from the kit and with no file
+    of the harness edited for it: 4 softmax routers under no bias; every
+    attention pass made under a selection, of 24 of up to 64 keys a query;
+    the step's program is the one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_KEYE_TRAIN["micro_batch"] * TINY_KEYE_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.keye.bf16.1x16384"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 3 * 4
+    assert c["dsa_built"] == c["attn_built"] > 0
+    assert c["dsa_keys_selected"] * (64 * 65 // 2) \
+        == c["dsa_keys_causal"] * (24 * 25 // 2 + 40 * 24)
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
+    """Appended behind everything that was there (PR 58): data files on
+    readers that are there, in the one cell whose model selects its keys,
+    each moving ``small_msg_us``; the selection's seven under a layer of
+    their own; the cell's name at the end of the lists every share cell is
+    in."""
+    names = [m["name"] for m in real["per_layer"]]
+    new = ["keye.mfu", "keye.tokens_per_s", "keye.local_load",
+           "keye.remat_share", "keye.unnamed_share", "keye.flash_mfu",
+           "keye.attn_bwd_mfu", "dsa.operator_share", "dsa.index_share",
+           "dsa.select_share", "dsa.loss_share", "dsa.selected_share",
+           "dsa.index_mfu", "dsa.loss_mfu"]
+    assert names[-14:] == new
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    for name in new:
+        m = by_name[name]
+        assert (m["workloads"], m["moves"]) == ([KEYE], "small_msg_us")
+        twin = by_name.get(name.replace("keye.", "smallthinker."))
+        if twin and twin is not m:
+            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
+                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+        with open(os.path.join(BENCH, "metrics", name + ".json"),
+                  encoding="utf-8") as f:
+            assert json.load(f)["reader"] in (
+                "trace_kit_flops", "point_rate", "program_counter",
+                "trace_scope_share_wide")
+    assert len({by_name[n]["layer"] for n in new[-7:]}) == 1
+    assert real["workloads"][-1]["name"] == KEYE \
+        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
+    assert real["workloads"][-1]["chips"] == 1
+    for m in real["end_to_end"] + real["per_layer"]:
+        if "qwen3next-train-1chip" in m.get("workloads", ()) \
+                and len(m["workloads"]) > 1:
+            assert m["workloads"][-1] == KEYE, m["name"]
+    with open(os.path.join(BENCH, "metrics", "dsa.selected_share.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["params"]) == ("program_counter", {
+        "name": "dsa_keys_selected", "over": "dsa_keys_causal",
+        "scale": 100})
+    with open(os.path.join(BENCH, "metrics", "dsa.loss_share.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    from ompi_tpu.runtime import trace
+
+    assert spec["params"]["scopes"] == ["otpu_dsa_loss"] \
+        and tuple(spec["params"]["vocabulary"]) == trace.STEP_SCOPES[
+            -len(spec["params"]["vocabulary"]):]
+
+
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     """Appended behind everything that was there (PR 56): data files on
     readers that are there, in the one cell whose model has a window, each
@@ -785,7 +892,8 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
            "smallthinker.unnamed_share", "smallthinker.flash_mfu",
            "smallthinker.attn_bwd_mfu", "swa.operator_share",
            "attn.window_share", "attn.pairs_walked_share"]
-    assert names[-11:-1] == new
+    at = names.index(new[0])            # PR 57 and PR 58 appended behind
+    assert names[at:at + 10] == new and names[at + 10] == LIVE_ROWS
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -797,12 +905,13 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     assert by_name["smallthinker.attn_bwd_mfu"]["layer"] \
         == by_name["kernel.flash_mfu"]["layer"]
     assert len({by_name[n]["layer"] for n in new[-3:]}) == 1
-    assert real["workloads"][-1]["name"] == cell \
-        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
+    assert real["workloads"][10]["name"] == cell \
+        and real["configs"][9]["name"] == real["workloads"][10]["config"]
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
-            assert m["workloads"][-1] == cell, m["name"]
+            assert [c for c in m["workloads"] if c != KEYE][-1] == cell, \
+                m["name"]
     for name, params in (
             ("attn.window_share", {"name": "attn_window_built",
                                    "over": "attn_built", "scale": 100}),
@@ -841,7 +950,7 @@ def test_the_live_row_share_is_an_entry_of_the_manifest(real):
     """Appended behind everything that was there (PR 57): a data file on
     ``program_counter`` under the expert block's layer, in the five
     cells whose rank holds a share of the experts and not in OLMoE's."""
-    m = real["per_layer"][-1]
+    (m,) = [x for x in real["per_layer"] if x["name"] == LIVE_ROWS]
     twin = {x["name"]: x for x in real["per_layer"]}["moe.gmm_kernel_share"]
     assert m == {"name": LIVE_ROWS, "unit": "%", "better": "higher",
                  "source": "program_counter", "layer": twin["layer"],
@@ -1030,6 +1139,46 @@ def test_kit_check_tells_the_delta_rule_program_from_its_controls(tmp_path):
                                      for r in rows)
     assert summary["parts_rule_bf16"] == min(
         r["parts_rule_bf16"]["widest_units"] for r in rows)
+
+
+def test_kit_check_tells_the_sparse_program_from_its_controls(tmp_path):
+    """``benchmark/tools/kit_check.py`` on Keye's cell at the rehearsal's
+    widths, with no file of the harness edited for it: one step of the
+    program lies within the kind's tolerance of ``keyekit``'s reference
+    under the step's own routing and selection; the reference in bfloat16
+    lies far outside the program's, and each of the kit's eight controls
+    outside the tolerance where it bites: a bfloat16 router and head, the
+    selection left out, its better half alone, the indexer without relu, q
+    and k without their head norms, the alignment loss left out, the
+    indexer's input attached, pbar attached."""
+    env = _stage(KEYE, str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", KEYE, "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "1", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    (row,) = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+              if ln.startswith("seed ")]
+    assert "selection: least share" in done.stdout
+    assert row["program"]["widest_units"] < 0.05
+    assert row["control_bf16"]["widest_units"] \
+        > 100 * row["program"]["widest_units"]
+    for variant, part in (("bf16", "head_rows"),
+                          ("no_selection", "select_o"),
+                          ("top_half", "select_o"),
+                          ("no_relu", "index_rows"),
+                          ("no_head_norm", "rope_qk"),
+                          ("no_index_loss", "losses"),
+                          ("pbar_attached", "grad_probe")):
+        assert row["parts_" + variant]["units_by_group"][part] > 1, variant
+    # the indexer's input attached sends the alignment loss's gradient into
+    # the stream: from matrices of 0.02 at these widths a tenth of what it
+    # is at the published ones, where the unit is set; told from the
+    # program's here
+    attached = row["parts_hi_attached"]["units_by_group"]["grad_probe"]
+    assert attached > 0.1 and attached > 50 * row["program"][
+        "units_by_group"]["grad_probe"]
 
 
 def test_kit_check_tells_the_window_program_from_its_controls(tmp_path):

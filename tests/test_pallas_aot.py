@@ -545,6 +545,70 @@ def test_smallthinker_train_step_aot_compiles_from_the_cells_configuration(
 
 
 @pytest.fixture(scope="module")
+def keye_rows():
+    """One child for the Keye-VL-2.0-30B-A3B cases: both flash kernels
+    under a selection's tiles, the two kernels of ``ops/sparse_attention``
+    and the whole step of the cell's own configuration file, for one v5e
+    device (about a minute of the 600)."""
+    return _rows_with_texts("keye_")
+
+
+def test_the_sparse_attention_kernels_aot_compile_at_the_cells_shape(
+        keye_rows):
+    """32 query heads on 4 key-value heads x 16,384 positions at a head
+    width of 128 under an int8 selection (1, 16384, 16384); an indexer of
+    16 heads of 64 and top 2,048: the forward kernel under the mask's
+    tiles, the backward pair under the mask key-major, the index / select
+    kernel (a tile's scores in 16 MiB of VMEM scratch, 46 counting passes)
+    and the alignment loss's one pass, each one Mosaic call under its own
+    VMEM limit."""
+    for case in ("keye_flash_select_forward", "keye_attn_select_backward",
+                 "keye_dsa_index_select", "keye_dsa_index_loss"):
+        row = keye_rows[case]
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        assert row["entry_ops"].get("custom-call") == 1, (case,
+                                                          row["entry_ops"])
+        with open(row["hlo"], encoding="utf-8") as f:
+            text = f.read()
+        assert "s8[1,16384,16384]" in text, case
+    # the index scores never leave the kernel: no (s, s) float32 array, and
+    # no (s, s, heads) one, in or around it
+    with open(keye_rows["keye_dsa_index_select"]["hlo"],
+              encoding="utf-8") as f:
+        assert not re.search(r"f32\[1,(16,)?16384,16384", f.read())
+
+
+def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
+    """The whole step of ``benchmark/configs/keye-vl2-30b-a3b-train-
+    1chip.json`` (published widths; layers 0-3 of 48, 16 of 128 experts, 1
+    x 16,384 tokens): it fits the chip beside its 5.6 GB of state, the four
+    like layers are one loop, and each of the sublayer's four kernels
+    stands under ``otpu_dsa`` in the pass it belongs to and in no
+    recomputed one: the selection and the alignment loss in the forward
+    pass alone (the checkpoint keeps the mask and the loss's gradients),
+    the flash forward too, the backward pairs in the backward pass."""
+    row = keye_rows["keye_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 465_391_104 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    for name, scope in (("otpu_dsa_index_select", "otpu_dsa_select"),
+                        ("otpu_dsa_index_loss", "otpu_dsa_loss"),
+                        ("otpu_flash_select_forward", "otpu_dsa"),
+                        ("otpu_attn_select_backward", "otpu_dsa")):
+        found = [p for p in kernels if f"/{name}/" in p]
+        assert found and all(scope in p for p in found), (name, found)
+        assert not [p for p in found if "rematted_computation" in p], name
+        assert all(("transpose(" in p) == (name == "otpu_attn_select_backward")
+                   for p in found), (name, found)
+    assert not [p for p in kernels if "/otpu_flash_causal_forward/" in p
+                or "/otpu_attn_block_backward/" in p]
+
+
+@pytest.fixture(scope="module")
 def gmm_rows():
     """One child for the experts' grouped matmul at the six model cells'
     shapes, forward and both transposed products of both expert
@@ -610,7 +674,8 @@ def fits_a_v5e(row) -> bool:
     ("nemotron_rows", "nemotron3_step_1chip"),
     ("lfm2_rows", "lfm2_step_1chip"),
     ("qwen3next_rows", "qwen3next_step_1chip"),
-    ("smallthinker_rows", "smallthinker_step_1chip")])
+    ("smallthinker_rows", "smallthinker_step_1chip"),
+    ("keye_rows", "keye_step_1chip")])
 def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(rows, case,
                                                             request):
     """A walked layer's checkpoint keeps what the expert block names

@@ -23,6 +23,7 @@ from test_lfm2_train import F32 as LFM2
 from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 from test_qwen3next_train import F32 as QWEN3NEXT
+from test_keye_train import F32 as KEYE
 from test_smallthinker_train import F32 as SMALLTHINKER
 
 from ompi_tpu.parallel import train
@@ -251,13 +252,20 @@ def smallthinker():
     return step, step.scopes()
 
 
+@pytest.fixture(scope="module")
+def keye():
+    step, args = built(KEYE)
+    step(*args)
+    return step, step.scopes()
+
+
 def ran(scopes):
     return {k: v for k, v in scopes["ops"].items()
             if v["opcode"] not in trace.TRIVIAL_OPCODES}
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next", "smallthinker"])
+                                   "qwen3next", "smallthinker", "keye"])
 def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     scopes = request.getfixturevalue(which)[1]
     assert scopes["module"] == "jit_otpu_train_step"
@@ -275,6 +283,8 @@ def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     assert ({"otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
              "otpu_gdn_norm"} <= named) == (which == "qwen3next")
     assert ("otpu_swa" in named) == (which == "smallthinker")
+    assert ({"otpu_dsa", "otpu_dsa_index", "otpu_dsa_select",
+             "otpu_dsa_loss"} <= named) == (which == "keye")
     assert {v["pass"] for v in scopes["ops"].values()} <= {
         None, *trace.PASSES}
 
@@ -310,7 +320,7 @@ def test_every_op_of_a_short_convolution_lands_under_its_scope(lfm2):
     pass, the recomputed one and the backward one; the new names stand
     behind the vocabulary's earlier ones; and the layer's other sublayers
     keep the scopes they have in the other models."""
-    assert trace.STEP_SCOPES[-9:-6] == ("otpu_conv", "otpu_conv_proj",
+    assert trace.STEP_SCOPES[-13:-10] == ("otpu_conv", "otpu_conv_proj",
                                         "otpu_conv_gate")
     ops = ran(lfm2[1])
     parts = {"otpu_conv_proj", "otpu_conv_gate"}
@@ -336,7 +346,7 @@ def test_every_op_of_a_delta_net_operator_lands_under_its_scope(qwen3next):
     behind the vocabulary's earlier ones; the attention gate lies under
     ``otpu_attn_proj`` and the shared expert's gate under
     ``otpu_shared_expert``; and no bias update is in the step."""
-    assert trace.STEP_SCOPES[-6:-1] == (
+    assert trace.STEP_SCOPES[-10:-5] == (
         "otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
         "otpu_gdn_norm")
     ops = ran(qwen3next[1])
@@ -374,11 +384,34 @@ def test_a_window_layers_attention_lands_under_its_own_scope(smallthinker):
     for under in (swa, full):
         assert {"forward", "remat", "backward"} <= {v["pass"] for v in under}
         assert any("otpu_attn_proj" in v["chain"] for v in under)
-    assert trace.STEP_SCOPES[-1] == "otpu_swa"
+    assert trace.STEP_SCOPES[-5] == "otpu_swa"
+
+
+def test_a_sparse_attention_sublayer_lands_under_its_own_scopes(keye):
+    """The four sparse-attention sublayers (one scanned run) are under
+    ``otpu_dsa`` in every pass and never under ``otpu_attention``; the
+    indexer, the selection and the alignment loss each under its own scope
+    inside it, beside ``otpu_attn_proj``; the selection in the forward pass
+    alone (it is kept, and nothing differentiates it)."""
+    ops = ran(keye[1])
+    dsa = [v for v in ops.values() if "otpu_dsa" in v["chain"]]
+    assert len(dsa) > 100
+    assert not [v for v in ops.values() if "otpu_attention" in v["chain"]]
+    assert {"forward", "remat", "backward"} <= {v["pass"] for v in dsa}
+    for part in ("otpu_dsa_index", "otpu_dsa_select", "otpu_dsa_loss",
+                 "otpu_attn_proj"):
+        under = [v for v in ops.values() if part in v["chain"]]
+        assert under and all("otpu_dsa" in v["chain"] for v in under), part
+    assert {v["pass"] for v in ops.values()
+            if "otpu_dsa_select" in v["chain"]} == {"forward"}
+    assert {"forward", "remat", "backward"} <= {
+        v["pass"] for v in ops.values() if "otpu_dsa_index" in v["chain"]}
+    assert trace.STEP_SCOPES[-4:] == ("otpu_dsa", "otpu_dsa_index",
+                                      "otpu_dsa_select", "otpu_dsa_loss")
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next", "smallthinker"])
+                                   "qwen3next", "smallthinker", "keye"])
 def test_every_instruction_the_program_wrote_has_a_chain(which, request):
     """Not a parameter, constant, tuple or bitcast, and with a path of
     the program's (``pass`` None: the compiler's own, which on the CPU
@@ -426,7 +459,15 @@ ROUTING = {
 ATTENTION = {
     "attention's products": lambda line, path:
         path.endswith("/dot_general") and trace.scope_of_path(path)[0][-1:]
-        in (["otpu_mla"], ["otpu_attention"], ["otpu_swa"]),
+        in (["otpu_mla"], ["otpu_attention"], ["otpu_swa"], ["otpu_dsa"]),
+}
+# a learned selection's two dear parts: the counting passes that find a
+# row's bar (comparisons summed a row) and the alignment loss's products
+SELECTION = {
+    "the selection's counting": lambda line, path:
+        "otpu_dsa_select" in path and path.endswith("/reduce_sum"),
+    "the alignment loss's products": lambda line, path:
+        "otpu_dsa_loss" in path and path.endswith("/dot_general"),
 }
 
 
@@ -480,10 +521,32 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         set() if loop_is_read else {"experts' loop"})
 
 
+def test_a_layers_checkpoint_keeps_the_selection_and_the_losss_gradients(
+        monkeypatch):
+    """``model_loss``'s checkpoint keeps a sparse-attention sublayer's
+    selection and its alignment loss's rows and gradients
+    (``model.CHECKPOINT_KEEPS``): the recomputed pass does not select
+    again, and the loss's one pass runs in the forward pass alone (its
+    backward rule scales what was kept).  The bare checkpoint recomputes
+    both: the patterns see what they are meant to."""
+    kinds, scopes = routing_by_pass(KEYE, SELECTION)
+    assert kinds["forward"] == set(SELECTION)
+    # (the twin's loss makes its gradients by autodiff inside its forward
+    # rule, so those products' paths read ``transpose(jvp(..))``: they are
+    # no recomputation, and on a TPU they are the one kernel's)
+    assert kinds.get("remat", set()) == set()
+    assert "the selection's counting" not in kinds.get("backward", set())
+    assert {"otpu_attn_proj", "otpu_dsa_index"} <= scopes["remat"]
+    monkeypatch.setattr(train, "layer_checkpoint_policy",
+                        lambda: jax.checkpoint_policies.nothing_saveable)
+    bare, _ = routing_by_pass(KEYE, SELECTION)
+    assert bare["forward"] == bare["remat"] == set(SELECTION)
+
+
 @pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2, QWEN3NEXT,
-                                 SMALLTHINKER],
+                                 SMALLTHINKER, KEYE],
                          ids=["joyai", "nemotron", "lfm2", "qwen3next",
-                              "smallthinker"])
+                              "smallthinker", "keye"])
 def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
                                                               monkeypatch):
     """``model_loss``'s checkpoint keeps causal attention's o and
