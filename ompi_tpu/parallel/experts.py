@@ -238,8 +238,7 @@ def moe_sorted_block(p, x, cfg, *, interpret: bool = True):
 # router's float32 product, the integers chosen and sorted from it, the
 # chosen experts' scores, and the held experts' sum in the latent.  Small
 # beside a layer's activations, and dear to make again: the six-pass
-# product, the top-k (a whole sort on a TPU), the gather entry by entry,
-# the argsort, the loop.
+# product, the top-k (a whole sort on a TPU), the argsort, the loop.
 ROUTER_LOGITS = "otpu_router_logits"
 CHOSEN_EXPERTS = "otpu_chosen_experts"
 CHOSEN_SCORES = "otpu_chosen_scores"
@@ -251,22 +250,74 @@ CHECKPOINT_KEEPS = (ROUTER_LOGITS, CHOSEN_EXPERTS, CHOSEN_SCORES,
                     DISPATCH_ORDER, DISPATCH_SIZES, EXPERT_SLOTS, LATENT_SUM)
 
 
+@jax.custom_vjp
+def chosen_scores(scores, experts):
+    """``scores`` (T, E) at the chosen ``experts`` (T, k), bit for bit
+    what ``jnp.take_along_axis(scores, experts, axis=-1)`` gathers, as k
+    compares of an expert's column against the lane's number and a sum
+    over the lanes that holds one entry and zeros.  A v5e walks a gather's
+    single entries at about 10 ns each (1.84 ms a layer for Nemotron's
+    8,192 x 22 of 512) and compares a lane in a thousandth of that (0.05
+    ms a layer in the same step; PR 59, ``PERF.md`` section 5), and XLA
+    fuses each compare into its sum, so no (T, k, E) array is written.
+    The transpose is written out the same way: a token's experts are
+    distinct, so the k terms added are the scatter-add's result bit for
+    bit (0.1 ms a layer where the scatter took 1.56), and what is kept
+    for it is ``experts``."""
+    lane = jnp.arange(scores.shape[-1], dtype=experts.dtype)
+    return jnp.stack(
+        [jnp.sum(jnp.where(experts[:, j:j + 1] == lane, scores, 0), axis=-1)
+         for j in range(experts.shape[1])], axis=-1)
+
+
+def _chosen_scores_fwd(scores, experts):
+    # the empty array carries the scores' width to the transpose
+    return chosen_scores(scores, experts), (
+        experts, jnp.zeros((0, scores.shape[-1]), scores.dtype))
+
+
+def _chosen_scores_bwd(res, ct):
+    experts, width = res
+    lane = jnp.arange(width.shape[-1], dtype=experts.dtype)
+    dscores = sum(
+        jnp.where(experts[:, j:j + 1] == lane, ct[:, j:j + 1], 0)
+        for j in range(experts.shape[1]))
+    # written once, as the scatter's result was: fused into what reads it
+    # (the router's two float32 products) the k terms are made again in
+    # every pass of both (seen on the v5e: 3.5 ms of Nemotron's step)
+    return jax.lax.optimization_barrier(dscores), None
+
+
+chosen_scores.defvjp(_chosen_scores_fwd, _chosen_scores_bwd)
+
+
+def count_keys(keys, bins: int):
+    """How many of the integer ``keys`` (any shape) equal each of 0 ..
+    ``bins`` - 1: int32 (bins,), exactly ``zeros(bins).at[keys].add(1)``
+    of keys in range, as a compare of every key against every bin and a
+    sum over the keys.  A v5e adds a scatter's indices one at a time
+    (1.57 ms a layer for Nemotron's 180,224, into 9 bins as into 512);
+    the compares cost T k ``bins`` lanes and fuse into the sum (0.003 and
+    0.1 ms a layer in the same step; PR 59, ``PERF.md`` section 5)."""
+    hit = keys.reshape(-1, 1) == jnp.arange(bins, dtype=keys.dtype)
+    return jnp.sum(hit, axis=0, dtype=jnp.int32)
+
+
 def route_chosen(scores, bias, top_k: int, normalize: bool, scale: float):
     """The ``top_k`` largest of ``scores`` (T, E) + ``bias`` (the
     balancing bias enters the choice and nothing else; None where the
-    router has none), weights the chosen scores themselves, normalised to
-    sum to one if ``normalize`` and times ``scale``.  Returns (weights
-    (T, k), experts (T, k)).  The experts are named (``CHOSEN_EXPERTS``)
-    before anything reads them, and their scores as gathered
-    (``CHOSEN_SCORES``), so what a checkpoint recomputes of the weights
-    is the normalisation: no second top-k, and no second gather of T k
-    single entries."""
+    router has none), weights the chosen scores themselves
+    (``chosen_scores``: dense compares, no gather and no scatter of T k
+    single entries), normalised to sum to one if ``normalize`` and times
+    ``scale``.  Returns (weights (T, k), experts (T, k)).  The experts
+    are named (``CHOSEN_EXPERTS``) before anything reads them, and their
+    scores as read (``CHOSEN_SCORES``), so what a checkpoint recomputes
+    of the weights is the normalisation: no second top-k."""
     _, experts = jax.lax.top_k(
         scores if bias is None else scores + jax.lax.stop_gradient(bias),
         top_k)
     experts = checkpoint_name(experts, CHOSEN_EXPERTS)
-    weights = checkpoint_name(
-        jnp.take_along_axis(scores, experts, axis=-1), CHOSEN_SCORES)
+    weights = checkpoint_name(chosen_scores(scores, experts), CHOSEN_SCORES)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                              + 1e-20)
@@ -287,14 +338,16 @@ def route_sigmoid_bias(logits, bias, top_k: int, normalize: bool,
 def local_dispatch(experts, first: int, n_here: int):
     """The T x k token-slots with those of the ``n_here`` experts held
     here (``first`` and up) in front, sorted by expert: returns (the
-    slots in that order, the slots each held expert received).  Only
-    integers are sorted; no row of activations moves here."""
+    slots in that order, the slots each held expert received:
+    ``count_keys``, dense compares in place of a scatter-add of T k
+    indices; a slot held nowhere has a key past the last bin and counts
+    in none).  Only integers are sorted; no row of activations moves
+    here."""
     t, k = experts.shape
     here = experts.reshape(t * k) - first
     key = jnp.where((here >= 0) & (here < n_here), here, n_here)
     order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((n_here + 1,), jnp.int32).at[key].add(1)[:n_here]
-    return order, sizes
+    return order, count_keys(key, n_here)
 
 
 #: the most row tiles (``ops/grouped_matmul.ROW_TILE``) a trip of the held
@@ -476,9 +529,8 @@ def _route_to_held(p, x, cfg, bias, routed=None):
                                       cfg.n_experts_here)
         order = checkpoint_name(order, DISPATCH_ORDER)
         sizes = checkpoint_name(sizes, DISPATCH_SIZES)
-        slots = checkpoint_name(
-            jnp.zeros((cfg.num_experts,), jnp.int32).at[
-                experts.reshape(t * k)].add(1), EXPERT_SLOTS)
+        slots = checkpoint_name(count_keys(experts, cfg.num_experts),
+                                EXPERT_SLOTS)
     stats["slots"] = slots.astype(jnp.float32)
     return h, order, sizes, stats, {
         "in": read, "logits": logits, "scores": scores, "weights": weights,

@@ -320,6 +320,8 @@ LIVE_ROWS = "moe.live_row_share"
 SHARE_CELLS = ("joyai-train-1chip", "nemotron3-train-1chip",
                "lfm2-train-1chip", "qwen3next-train-1chip",
                "smallthinker-train-1chip", KEYE)
+# what routing and dispatch cost such a cell's step (PR 59)
+ROUTE_SHARE = "moe.route_share"
 
 # the child: run_cell as the command calls it, but for the three
 # arguments it keeps for rehearsals.  What the program counts is read
@@ -842,7 +844,8 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
            "keye.attn_bwd_mfu", "dsa.operator_share", "dsa.index_share",
            "dsa.select_share", "dsa.loss_share", "dsa.selected_share",
            "dsa.index_mfu", "dsa.loss_mfu"]
-    assert names[-14:] == new
+    at = names.index(new[0])            # PR 59 appended behind
+    assert names[at:at + 14] == new and names[at + 14:] == [ROUTE_SHARE]
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -961,6 +964,50 @@ def test_the_live_row_share_is_an_entry_of_the_manifest(real):
         spec = json.load(f)
     assert (spec["reader"], spec["params"]) == ("program_counter", {
         "name": "moe_local_slots", "over": "moe_chunk_rows", "scale": 100})
+
+
+def test_the_route_share_is_an_entry_of_the_manifest(real):
+    """Appended behind everything that was there (PR 59): a data file on
+    ``trace_scope_share_wide`` under the expert block's layer, in the six
+    cells whose rank holds a share of the experts and not in OLMoE's; it
+    selects both kinds of such a cell's point through its file, reads
+    the router's and the dispatch's scopes, and shares the run's one wide
+    reduction with ``dsa.select_share`` (one vocabulary: the program's
+    whole ``STEP_SCOPES`` behind ``scopes.json``'s)."""
+    from ompi_tpu.runtime import trace
+
+    assert real["per_layer"][-1] == {
+        "name": ROUTE_SHARE, "unit": "%", "better": "lower",
+        "source": "device_trace",
+        "layer": {x["name"]: x for x in real["per_layer"]}[LIVE_ROWS][
+            "layer"],
+        "moves": "small_msg_us", "workloads": list(SHARE_CELLS)}
+
+    def spec_of(name):
+        with open(os.path.join(BENCH, "metrics", name + ".json"),
+                  encoding="utf-8") as f:
+            return json.load(f)
+
+    spec = spec_of(ROUTE_SHARE)
+    assert spec["reader"] == "trace_scope_share_wide"
+    assert spec["params"]["scopes"] == ["otpu_router", "otpu_dispatch"]
+    assert spec["params"]["vocabulary"] == spec_of("dsa.select_share")[
+        "params"]["vocabulary"]
+    with open(os.path.join(BENCH, "harness", "scopes.json"),
+              encoding="utf-8") as f:
+        base = json.load(f)["scopes"]
+    assert tuple(base + spec["params"]["vocabulary"]) == trace.STEP_SCOPES
+    kinds = set()
+    for cell in real["workloads"]:
+        with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"),
+                  encoding="utf-8") as f:
+            points = json.load(f).get("points", [])
+        if cell["name"] in SHARE_CELLS:
+            kinds |= {p["kind"] for p in points}
+        else:
+            assert not {p["kind"] for p in points} & set(
+                spec["params"]["select"]["kind"]), cell["name"]
+    assert kinds == set(spec["params"]["select"]["kind"])
 
 
 def test_train_check_tells_the_program_from_its_control(tmp_path):

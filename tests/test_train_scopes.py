@@ -439,13 +439,14 @@ def test_a_checkpointed_layers_recomputation_is_told_apart(joyai, olmoe):
 
 # what an expert block's routing runs as, by an instruction's own line
 # (fused ones too): the dispatch's argsort, the router's top-k (a custom
-# call on the CPU, a whole sort on the TPU), the gather of the chosen
-# scores, the router's (T, E) product, the held experts' loop
+# call on the CPU, a whole sort on the TPU), the chosen scores (since PR
+# 59 compares of an expert's column against the lane's number, summed:
+# the router's only ``eq``), the router's (T, E) product, the held
+# experts' loop
 ROUTING = {
     "sort": lambda line, path: " sort(" in line,
     "top-k": lambda line, path: path.endswith("/top_k"),
-    "scores' gather": lambda line, path:
-        path.endswith("otpu_router/jit(take_along_axis)/gather"),
+    "chosen scores": lambda line, path: path.endswith("otpu_router/eq"),
     "router product": lambda line, path:
         path.endswith("otpu_router/dot_general"),
     "experts' loop": lambda line, path:
@@ -498,7 +499,7 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
         cfg, loop_is_read, monkeypatch):
     """``model_loss``'s checkpoint keeps what an expert block names
     (``experts.CHECKPOINT_KEEPS``): no recomputed instruction is the
-    dispatch's sort, the router's top-k, the gather of the chosen scores
+    dispatch's sort, the router's top-k, the chosen scores' compares
     or the (T, E) product, nor, where the loop's sum is read by a weight
     gradient (Nemotron's ``lat_up``; JoyAI's XLA drops by itself), the
     held experts' loop; what is not named (attention's projections, a
