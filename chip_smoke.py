@@ -330,7 +330,7 @@ def kernels(clock: Clock, expect_interpret: bool = False,
     # every query head on one key-value head, read through the index
     # maps: the forward pass in one call; the backward's fused block
     # pair, a plain pair and the diagonal one through one compiled kernel
-    from ompi_tpu.parallel import layers, model
+    from ompi_tpu.parallel import causal, layers, mamba
 
     block, f32 = min(sq, 1024), jnp.float32
     cut = lambda x, n: x[:, :, n * block:(n + 1) * block]
@@ -345,7 +345,7 @@ def kernels(clock: Clock, expect_interpret: bool = False,
         acc = tuple(draw(key, w, n, f32) for key, w, n in zip(
             keys[4:], (wide, wide, hv), (h, n_kv, n_kv)))
         up = tuple(x.astype(f32) for x in (qb, kb, vb, dob))
-        o, lse = model._causal_fwd_blocks(*up[:3], block, True)
+        o, lse = causal._causal_fwd_blocks(*up[:3], block, True)
         fwd = f"flash_causal_forward {wide}/{hv} {h} on {n_kv}"
         got = clock.call(compile_checked(fwd, jax.jit(
             lambda *a: fa.flash_causal_forward(*a, block=block)),
@@ -359,14 +359,14 @@ def kernels(clock: Clock, expect_interpret: bool = False,
         compiled = compile_checked(name, jax.jit(
             lambda ij, *a: fa.attn_block_backward(ij, *a, block=block)),
             jnp.zeros(2, jnp.int32), *args)
-        fold = lambda x, n: model._group_blocks(x, n_kv, block)[n]
+        fold = lambda x, n: causal._group_blocks(x, n_kv, block)[n]
         for i, j in ((1, 0), (1, 1)):
             got = clock.call(compiled, jnp.asarray((i, j), jnp.int32),
                              *args, first=False)
-            dq, dk, dv = model._bwd_pair(
+            dq, dk, dv = causal._bwd_pair(
                 fold(up[0], i), cut(up[1], j), cut(up[2], j),
                 fold(up[3], i), fold(lse, i), fold(delta, i),
-                model._group_bias(block, h // n_kv) if i == j else None,
+                causal._group_bias(block, h // n_kv) if i == j else None,
                 1.0 / wide ** 0.5, f32)
             for part, g, a0, w, n in zip(
                     ("dq", "dk", "dv"), got, acc,
@@ -398,7 +398,7 @@ def kernels(clock: Clock, expect_interpret: bool = False,
     print(f"  project_rope {g.shape} {dtype} matches rope_interleaved in "
           f"float32 ({err:.2e} of max|ref|)", flush=True)
 
-    # Mamba-2's state-space scan in chunks (``model.ssd_chunked``; no
+    # Mamba-2's state-space scan in chunks (``mamba.ssd_chunked``; no
     # kernel: XLA's batched matmuls and one loop over the chunks) against
     # the recurrence one position at a time, at the hybrid cell's widths:
     # steps between 0.001 and 0.1 and decays of 1 to 16 a unit step, as
@@ -411,7 +411,7 @@ def kernels(clock: Clock, expect_interpret: bool = False,
                                       np.log(0.001), np.log(0.1)))
     decay = -jax.random.uniform(kv, (sh,), f32, 1.0, 16.0)
     bs, cs = (jax.random.normal(k, (sb, ss, sg, sn), f32) for k in (kk, kv))
-    got = clock.call(jax.jit(lambda *args: model.ssd_chunked(*args, chunk)),
+    got = clock.call(jax.jit(lambda *args: mamba.ssd_chunked(*args, chunk)),
                      xs, step, decay, bs, cs, first=True)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(nemotron_reference.recurrence)(xs, step, decay, bs, cs)

@@ -1,7 +1,7 @@
 """Pallas kernels for a causal depthwise convolution and its silu: one
 pass over the array forward and one backward.
 
-A Gated DeltaNet layer's convolution (``parallel/model._kernel_conv``,
+A Gated DeltaNet layer's convolution (``parallel/gdn._kernel_conv``,
 which ``gated_delta_net`` calls under ``otpu_gdn_conv``) runs here where
 Mosaic compiles (a TPU) and the shape has tiles (``supported``);
 everywhere else it stays the lines in ``gated_delta_net``, which are

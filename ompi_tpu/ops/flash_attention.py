@@ -1,7 +1,7 @@
 """Pallas flash-attention kernels: causal attention's forward pass and
 its backward block pair.
 
-The causal train step (``parallel/model.causal_flash_attention``), which
+The causal train step (``parallel/causal.causal_flash_attention``), which
 has all of K and V on the chip, runs these where Mosaic compiles (a TPU;
 the CPU runs the ``jnp`` twins in ``parallel/model``):
 
@@ -28,7 +28,7 @@ triangular mask as ever, the far one (i - w) under its mirror (key
 column c visible to query row r iff c > r), those between under none.
 The forward's grid holds the w + 1 kv tiles a q tile can reach, its index
 map starting at the far tile; the backward's walk
-(``parallel/model._window_pairs``) leaves out the pairs out of reach and
+(``parallel/causal._window_pairs``) leaves out the pairs out of reach and
 the kernel tells the far pair from ``ij`` and w.
 
 Scores compute in float32 on the MXU via ``preferred_element_type``.
@@ -230,7 +230,7 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     ``lax.scan``, slices nothing.  Five matmuls a pair with float32
     accumulation, ``p`` and ``ds`` cast to q's dtype for theirs; no
     (block, block) array leaves VMEM.  The ``jnp`` twin is
-    ``parallel/model._bwd_pair``.  With ``window`` (positions, whole
+    ``parallel/causal._bwd_pair``.  With ``window`` (positions, whole
     blocks) the pair whose blocks lie the window apart is the far one
     and masked as such; the caller walks no pair beyond it.  With
     ``select`` = (the selection key-major (b, s kv, s q) int8, its pairs'
@@ -481,7 +481,7 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     ``1 / sqrt(d)``; the diagonal tile is masked by position.  A width
     that is no multiple of 128 lanes (192) is Mosaic's to lay out.  The
     logsumexp leaves as (b x h, 1, s), the shape ``attn_block_backward``
-    reads.  The ``jnp`` twin is ``parallel/model._causal_fwd_blocks``.
+    reads.  The ``jnp`` twin is ``parallel/causal._causal_fwd_blocks``.
     With ``window`` (positions, whole tiles) the grid's third axis is
     the window's tiles and the diagonal one (``_window_fwd_kernel``), the
     index map starting at the far tile (clamped to the sequence's first).

@@ -1,7 +1,7 @@
 """Pallas kernels for the chunked gated delta rule: a pass's per-chunk
 work and its chunk-to-chunk recurrence in one call, forward and backward.
 
-A Gated DeltaNet layer's rule (``parallel/model._kernel_rule``, which
+A Gated DeltaNet layer's rule (``parallel/gdn._kernel_rule``, which
 ``gated_delta_net`` and ``gated_delta_chunked`` call) runs here where
 Mosaic compiles (a TPU) and the shape has tiles (``supported``);
 everywhere else it stays ``gated_delta_chunked``'s XLA form, which is
@@ -38,7 +38,7 @@ against the level below's exact inverse, as blocked forward substitution
 does, so no power of L larger than a block's own is ever formed (the
 plain series ``sum (-L)^k`` cancels terms many orders above its sum
 where keys are alike).  Its gradient is ``-T^T ct T^T``, as
-``model.unit_lower_inverse`` writes it out.
+``gdn.unit_lower_inverse`` writes it out.
 
 The L2 norms of q and k can run inside the kernels (``unit``), on rows
 read where the convolution left them in its one [q | k | v] array: then

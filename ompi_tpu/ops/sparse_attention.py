@@ -1,7 +1,7 @@
 """Pallas kernels of learned sparse attention (DeepSeek-V3.2's DSA: a
 lightning indexer scores every earlier key, a query attends to its
 ``topk`` best): what stands beside the two flash kernels in
-``parallel/model.dsa_attention`` where Mosaic compiles (a TPU; the CPU
+``parallel/dsa.dsa_attention`` where Mosaic compiles (a TPU; the CPU
 runs the ``jnp`` twins in ``parallel/model``).
 
 - ``index_select``: a tile of query rows against every earlier key.  The
@@ -163,7 +163,7 @@ def index_select(qi, ki, w, *, topk: int, interpret=None):
     a tie at the bar going to the earlier key; the logsumexp (b, s)
     float32 of each row's selected scores).  Exact: the bar is the row's
     k-th largest score, found by counting.  The ``jnp`` twin is
-    ``parallel/model._index_select_blocks``."""
+    ``parallel/dsa._index_select_blocks``."""
     if interpret is None:
         interpret = pallas_interpret()
     b, heads, s, di = qi.shape
@@ -266,7 +266,7 @@ def index_loss(q, k, lse, qi, ki, w, ilse, sel, *, interpret=None):
     heads of ``exp(q[t, h] . k[u, g(h)] / sqrt(d) - lse[t, h])`` (q (b, H,
     s, d), k (b, G, s, d), ``lse`` (b, H, s): the flash forward's).  The
     gradients are ``sum_t kl[t]``'s with ``pbar`` a constant.  The ``jnp``
-    twin is ``parallel/model._index_loss_blocks``."""
+    twin is ``parallel/dsa._index_loss_blocks``."""
     if interpret is None:
         interpret = pallas_interpret()
     b, heads, s, d = q.shape

@@ -1,0 +1,248 @@
+"""The attention sublayers on ``causal_flash_attention``: OLMoE's,
+DeepSeek-V3's latent attention (JoyAI-LLM-Flash) and grouped-query
+attention in the forms Nemotron-3-Super, LFM2, Qwen3-Next and
+SmallThinker publish, each with its entry in ``parallel/model.py``'s
+table.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ompi_tpu.parallel.causal import (ATTN_KEEPS,
+                                                causal_flash_attention)
+from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
+                                      rope)
+from ompi_tpu.parallel.sublayer import Sublayer
+
+
+def olmoe_attention(p, x, cfg, *, interpret: bool, at=None):
+    """OLMoE's attention sublayer, **without** the residual add, on the
+    residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
+    projections without bias; RMSNorm with a gain over the whole width of
+    q and of k **before** the heads are split (QK-norm); RoPE; causal
+    attention.  Reports nothing."""
+    b, s, d = x.shape
+    nh, dt = cfg.num_attention_heads, cfg.compute_dtype
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+        q = rmsnorm_gain(matmul(h, p["wq"], dt), p["q_norm"],
+                         cfg.rms_norm_eps)
+        k = rmsnorm_gain(matmul(h, p["wk"], dt), p["k_norm"],
+                         cfg.rms_norm_eps)
+        v = matmul(h, p["wv"], dt)
+        heads = lambda t: t.reshape(b, s, nh, -1).transpose(0, 2, 1, 3)
+        q, k = rope(heads(q), cfg.rope_theta), rope(heads(k), cfg.rope_theta)
+        q, k, v = q.astype(dt), k.astype(dt), heads(v).astype(dt)
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    with jax.named_scope("otpu_attn_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+        return matmul(o, p["wo"], dt), {}, {}
+
+
+def mla_attention(p, x, cfg, *, interpret: bool, at=None):
+    """DeepSeek-V3's latent attention sublayer (arXiv:2412.19437 section
+    2.1.1), **without** the residual add, on the residual stream ``x`` (b,
+    s, d) float32: pre-norm; q
+    through a normed latent of ``q_lora_rank``; k's no-position part and
+    v through a normed latent of ``kv_lora_rank``; one rotary key of
+    ``qk_rope_head_dim`` that every head shares; causal ``softmax(q k^T
+    / sqrt(nope + rope)) v`` with q, k of one width and v of another.
+    The two inner norms, RoPE and the softmax in float32;
+    matmul inputs in ``compute_dtype``.  Training holds no cache, so the
+    latents are expanded to full keys and values.  q and the shared
+    rotary key leave their projections with RoPE on (``project_rope``)."""
+    b, s, _ = x.shape
+    nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
+    nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rank, theta = cfg.kv_lora_rank, cfg.rope_theta
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], eps)
+        cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
+        q = project_rope(cq, p["wq_b"], nh, nope, theta, dt)
+        # (b, s, rank + rot), the rotary key behind the latent
+        kv = project_rope(h, p["wkv_a"], 1, rank, theta, dt)[:, :, 0]
+        ckv = rmsnorm_gain(kv[..., :rank], p["kv_a_norm"], eps)
+        kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
+        k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
+            kv[:, :, None, rank:].astype(dt), (b, s, nh, rot))], -1)
+        heads = lambda t: t.transpose(0, 2, 1, 3)        # (b, nh, s, .)
+        q, k, v = (heads(q.astype(dt)), heads(k),
+                   heads(kvb[..., nope:].astype(dt)))
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    with jax.named_scope("otpu_attn_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
+        return matmul(o, p["wo"], dt), {}, {}
+
+
+def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
+                  windowed: bool = False):
+    """Grouped-query attention, **without** the residual add, on the
+    residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
+    projections without bias; the ``n_heads_here`` query heads held here
+    and the ``n_kv_heads_here`` key-value heads they read; causal softmax
+    attention.  k and v go to ``causal_flash_attention`` as they leave
+    their projections, with their own heads: the flash kernels read a
+    group's shared head through their index maps and sum its query
+    heads' gradients in float32.
+
+    Four models' sublayer, told apart by what the layer holds and, where
+    the leaves cannot say, by the entry that runs it (``kind``, its
+    ``layer_types`` name, and ``windowed``).  Without ``q_norm`` and
+    outside ``layer_types`` (nemotron_h): no rotary embedding, q, k and v
+    cast as they leave their projections.  With ``q_norm`` and ``k_norm``
+    (head width,) (lfm2): RMSNorm with a gain over **each head's** width
+    of q and of k, then RoPE in the half-split form where ``kind`` is one
+    of ``cfg.rope_kinds``, both in float32; a ``wq`` twice as wide as
+    ``wo`` is long (qwen3_next) holds a **gate** behind every head's
+    query, RoPE turns the leading ``rotary_width`` entries only, and ``o *
+    sigmoid(gate)``, in float32, goes to ``W_o``.  A ``layer_types`` layer
+    without ``q_norm`` (smallthinker): RoPE over the whole head by
+    ``rope_kinds``, and under ``windowed`` the last ``sliding_window`` keys
+    (``causal_flash_attention``'s ``window``).  Heads are ``head_width``
+    wide whatever the hidden width.  (Keye-VL-2.0's q, k and v share
+    lfm2's form, but its sublayer is ``dsa.dsa_attention``.)
+
+    Returns (the sublayer's output, no statistics, by token row what
+    ``_gqa_reports`` lists: the first query head and the first key-value
+    head side by side before the norm and behind RoPE, ``attn_qk_in`` and
+    ``attn_qk`` (T, 2 hd), the two alike where the layer is not turned; a
+    gated layer's first head's o and gate side by side, ``attn_og_in`` (T,
+    2 hd), and gated, ``attn_og`` (T, hd); under a window what the kernels
+    read and made of the first query head and its key-value head,
+    ``attn_win_q``, ``attn_win_o`` (T, hd) and ``attn_win_k_seq``,
+    ``attn_win_v_seq`` (T, hd) whole, because a row reads a window of
+    them)."""
+    b, s, _ = x.shape
+    nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
+    seen, gate = {}, None
+    turned = kind in cfg.rope_kinds
+    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width)) if turned \
+        else (lambda t: t)
+    window = cfg.sliding_window if windowed else None
+    first = lambda a, c: jnp.concatenate(
+        [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
+    with jax.named_scope("otpu_attn_proj"):
+        h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+        if "q_norm" in p:
+            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+            q_in, k_in = (split(matmul(h, p[w], dt), n)
+                          for w, n in (("wq", nh), ("wk", nkv)))
+            if p["wq"].shape[-1] == 2 * p["wo"].shape[0]:
+                q_in, gate = jnp.split(q_in, 2, axis=-1)
+            q, k = (turn(rmsnorm_gain(t, p[g], cfg.rms_norm_eps))
+                    for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+            if turned:
+                seen = {"attn_qk_in": first(q_in, k_in),
+                        "attn_qk": first(q, k)}
+            q, k = q.astype(dt), k.astype(dt)
+            v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
+        elif cfg.layer_types:
+            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+            q_in, k_in, v = (split(matmul(h, p[w], dt), n) for w, n in (
+                ("wq", nh), ("wk", nkv), ("wv", nkv)))
+            q, k = turn(q_in), turn(k_in)
+            # reported of a layer that is not turned too: that it was left
+            # alone is what a check reads
+            seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+            q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+        else:
+            heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
+                0, 2, 1, 3).astype(dt)
+            q, k, v = (heads(matmul(h, p[w], dt), n)
+                       for w, n in (("wq", nh), ("wk", nkv), ("wv", nkv)))
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret,
+                               window)
+    if window is not None:
+        rows = lambda t: t[:, 0].reshape(b * s, -1).astype(jnp.float32)
+        seen.update(attn_win_q=rows(q), attn_win_k_seq=rows(k),
+                    attn_win_v_seq=rows(v), attn_win_o=rows(o))
+    with jax.named_scope("otpu_attn_proj"):
+        if gate is not None:
+            gated = o * jax.nn.sigmoid(gate)
+            seen["attn_og_in"] = jnp.concatenate(
+                [o[:, 0], gate[:, 0]], -1).reshape(b * s, -1)
+            seen["attn_og"] = gated[:, 0].reshape(b * s, -1)
+            o = gated
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return matmul(o, p["wo"], dt), {}, seen
+
+
+def _olmoe_shapes(cfg) -> dict:
+    d = cfg.hidden_size
+    return {"ln1": (d,), "wq": (d, d), "wk": (d, d), "wv": (d, d),
+            "wo": (d, d), "q_norm": (d,), "k_norm": (d,)}
+
+
+def _mla_shapes(cfg) -> dict:
+    d, nh = cfg.hidden_size, cfg.num_attention_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {"ln1": (d,), "wq_a": (d, cfg.q_lora_rank),
+            "q_a_norm": (cfg.q_lora_rank,),
+            "wq_b": (cfg.q_lora_rank, nh * qk),
+            "wkv_a": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            "kv_a_norm": (cfg.kv_lora_rank,),
+            "wkv_b": (cfg.kv_lora_rank,
+                      nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": (nh * cfg.v_head_dim, d)}
+
+
+def gqa_shapes(cfg, qk_norm=None) -> dict:
+    """The gain, q and o over the held query heads, k and v over the
+    key-value heads they read, heads of ``head_width``; ``wq`` holds a
+    gate beside every query head under ``attn_output_gate``; the per-head
+    QK-norm's two gains where the model has one (``qk_norm``: the
+    configuration's unless given)."""
+    d, hd = cfg.hidden_size, cfg.head_width
+    q, kv = cfg.n_heads_here * hd, cfg.n_kv_heads_here * hd
+    norms = cfg.qk_norm if qk_norm is None else qk_norm
+    return {"ln1": (d,), "wq": (d, q * (2 if cfg.attn_output_gate else 1)),
+            "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
+            **({"q_norm": (hd,), "k_norm": (hd,)} if norms else {})}
+
+
+def _gqa_reports(cfg, kind: str, windowed: bool) -> dict:
+    """What ``gqa_attention`` reports of a ``layer_types`` layer of
+    ``kind``: q and k around the norm and RoPE of a kind RoPE turns, and of
+    every kind of a model without a QK-norm; o around its gate; of a window
+    layer what the kernels read and made."""
+    keys = ("attn_qk_in", "attn_qk") \
+        if kind in cfg.rope_kinds or not cfg.qk_norm else ()
+    if cfg.attn_output_gate:
+        keys += ("attn_og_in", "attn_og")
+    if windowed:
+        keys += ("attn_win_q", "attn_win_k_seq", "attn_win_v_seq",
+                 "attn_win_o")
+    return dict.fromkeys(keys, 1)
+
+
+def _gqa(name: str, group: str, scope: str,
+         windowed: bool = False) -> Sublayer:
+    """A ``layer_types`` model's grouped-query attention by its name."""
+    bound = dict(kind=name, windowed=windowed)
+    return Sublayer(
+        name=name, group=group, scope=scope,
+        run=functools.partial(gqa_attention, **bound), shapes=gqa_shapes,
+        undecayed=("ln1", "q_norm", "k_norm"),
+        reports=functools.partial(_gqa_reports, **bound), keeps=ATTN_KEEPS)
+
+
+#: lfm2's and qwen3_next's attention, smallthinker's in full
+FULL = _gqa("full_attention", "attn", "otpu_attention")
+#: smallthinker's under its window (``sliding_window``): a full layer's
+#: leaves
+WINDOW = _gqa("sliding_attention", "swa", "otpu_swa", windowed=True)
+#: nemotron_h's ``*``: no QK-norm, no RoPE, a chip's share of the heads
+SHARED_KV = Sublayer(
+    name="*", group="attn", scope="otpu_attention", run=gqa_attention,
+    shapes=functools.partial(gqa_shapes, qk_norm=False), undecayed=("ln1",),
+    keeps=ATTN_KEEPS)
+#: the stacked tree's two (OLMoE's; JoyAI's where ``kv_lora_rank`` is set)
+OLMOE = Sublayer(
+    scope="otpu_attention", run=olmoe_attention, shapes=_olmoe_shapes,
+    undecayed=("ln1", "q_norm", "k_norm"), keeps=ATTN_KEEPS)
+MLA = Sublayer(
+    scope="otpu_mla", run=mla_attention, shapes=_mla_shapes,
+    undecayed=("ln1", "q_a_norm", "kv_a_norm"), keeps=ATTN_KEEPS)

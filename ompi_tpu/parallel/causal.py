@@ -1,0 +1,415 @@
+"""Causal flash attention on the held heads: in full, under a sliding
+window (``causal_flash_attention``) or under a learned selection
+(``selected_flash_attention``), each a ``custom_vjp`` over
+``ops/flash_attention``'s kernels with a ``jnp`` twin that the CPU and
+the tests' references run, the backward pass's two walks of the block
+pairs, and the SPC counters of what was built.  The attention sublayers
+(``parallel/attention.py``, ``parallel/dsa.py``) stand on it.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ompi_tpu.parallel.layers import contract
+from ompi_tpu.runtime import spc
+
+
+def _tri_bias(block: int):
+    i = jnp.arange(block)
+    return jnp.where(i[:, None] >= i[None, :], 0.0,
+                     -jnp.inf).astype(jnp.float32)
+
+
+def _group_blocks(a, n_kv: int, block: int):
+    """A query-side array (b, h, s, ...) by blocks of ``block``
+    positions, the ``h / n_kv`` query heads that share a key-value head
+    folded into a block's rows: (blocks, b, n_kv, h / n_kv * block,
+    ...).  The ``jnp`` twins' layout (the kernels group through their
+    index maps): a group's rows meet its one k and v block in one
+    contraction, and the sum over them is dk's and dv's own."""
+    b, h, s = a.shape[:3]
+    nb, rep = s // block, h // n_kv
+    a = jnp.moveaxis(a.reshape(b, n_kv, rep, nb, block, *a.shape[3:]), 3, 0)
+    return a.reshape(nb, b, n_kv, rep * block, *a.shape[5:])
+
+
+def _ungroup_blocks(a, h: int):
+    """``_group_blocks``'s inverse: (b, h, s, ...) again."""
+    nb, b, n_kv, rows = a.shape[:4]
+    block = rows * n_kv // h
+    a = a.reshape(nb, b, n_kv, h // n_kv, block, *a.shape[4:])
+    return jnp.moveaxis(a, 0, 3).reshape(b, h, nb * block, *a.shape[5:])
+
+
+def _group_bias(block: int, rep: int):
+    """The diagonal block's triangular bias for a group's folded rows."""
+    return jnp.tile(_tri_bias(block), (rep, 1))
+
+
+def _far_bias(block: int, rep: int):
+    """A window's far block's bias for a group's folded rows: key column
+    c visible to query row r iff c > r (the diagonal block's mirror)."""
+    i = jnp.arange(block)
+    return jnp.tile(jnp.where(i[:, None] < i[None, :], 0.0,
+                              -jnp.inf).astype(jnp.float32), (rep, 1))
+
+
+def _window_pairs(nb: int, w):
+    """The (q block, kv block) pairs causal attention walks over ``nb``
+    blocks, q block by q block, kv blocks ascending: kv blocks 0 .. i, or
+    under a window of ``w`` blocks max(0, i - w) .. i.  The kernels' grid
+    and the ``jnp`` twins walk these and no other."""
+    return [(i, j) for i in range(nb)
+            for j in range(0 if w is None else max(0, i - w), i + 1)]
+
+
+def _window_in_blocks(window, block: int, length: int):
+    """A static window in blocks: None where there is none or it covers
+    the sequence (plain causal attention, bit for bit); else a whole
+    number of blocks."""
+    if window is None or window >= length:
+        return None
+    if window % block:
+        raise ValueError(f"a window of {window} positions is no whole "
+                         f"number of blocks of {block}")
+    return window // block
+
+
+def _select_bias(select, i, j, block: int, rep: int):
+    """A selection's (q block i, kv block j) as a bias for a group's
+    folded rows, (b, 1, rep x block, block): 0 where ``select`` (b, s, s)
+    says a key is visible, -inf elsewhere.  ``i`` and ``j`` may be
+    traced."""
+    b, s, _ = select.shape
+    nb = s // block
+    tile = select.reshape(b, nb, block, nb, block)[:, i, :, j]
+    bias = jnp.where(tile != 0, 0.0, -jnp.inf).astype(jnp.float32)
+    return jnp.tile(bias, (1, rep, 1))[:, None]
+
+
+def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
+    """Causal attention's forward pass: (o float32, logsumexp float32)
+    of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
+    key-value head is read by ``h / n_kv`` consecutive query heads, and
+    v, and so the numerator and o, may be of another width than q and k
+    (latent attention: 192 and 128).  Where Mosaic compiles
+    (``interpret`` false: a TPU) it is one call of
+    ``ops/flash_attention.flash_causal_forward``, which takes the three
+    whole.  Elsewhere (the CPU) it is the loop below, that kernel's
+    ``jnp`` twin: q block i of a group's query heads meets kv blocks
+    0..i of ``block`` positions, the diagonal one under a triangular
+    bias, each through one online-softmax update with float32 scores;
+    the running max, numerator and denominator are float32 whatever q,
+    k, v are.  Under a static ``window`` (positions; a whole number w of
+    blocks) q block i meets kv blocks max(0, i - w) .. i, the far one (i
+    - w) under ``_far_bias``; its last query row sees nothing of it, and
+    that row's running max stays -inf through it.  Under ``select`` (b,
+    s, s) int8 (a data-dependent selection that holds causality; None:
+    everything here is what it was) every block pair goes under its tile
+    of the selection (``_select_bias``) and under no mask by position,
+    and any row may see nothing of any block."""
+    w = _window_in_blocks(window, block, q.shape[2])
+    if not interpret:
+        from ompi_tpu.ops.flash_attention import flash_causal_forward
+
+        if select is not None:
+            return flash_causal_forward(q, k, v, block=block,
+                                        interpret=False, select=select)
+        return flash_causal_forward(q, k, v, block=block, interpret=False,
+                                    window=None if w is None else window)
+    h, s, hd = q.shape[1:]
+    nb = s // block
+    scale = 1.0 / math.sqrt(hd)
+    bias = _group_bias(block, h // k.shape[1])
+    qb = _group_blocks(q, k.shape[1], block)
+    outs, lses = [], []
+    for i in range(nb):
+        qi = qb[i]
+        zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
+        m, den = zero - jnp.inf, zero
+        num = jnp.zeros(v.shape[-1:], jnp.float32) + zero[..., None]
+        for j in range(0 if w is None else max(0, i - w), i + 1):
+            kj = k[:, :, j * block:(j + 1) * block]
+            vj = v[:, :, j * block:(j + 1) * block]
+            sc = contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
+            if select is not None:
+                sc = sc + _select_bias(select, i, j, block, h // k.shape[1])
+            elif j == i:
+                sc = sc + bias
+            far = select is None and w is not None and j == i - w
+            if far:
+                sc = sc + _far_bias(block, h // k.shape[1])
+            new_m = at_m = jnp.maximum(m, sc.max(axis=-1))
+            # a row that sees nothing yet (of a window's far block, or of
+            # any block under a selection): exp(-inf - 0) = 0
+            if far or select is not None:
+                at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
+            c = jnp.exp(m - at_m)
+            p = jnp.exp(sc - at_m[..., None])
+            num = num * c[..., None] + contract("bhqk,bhkd->bhqd", p, vj,
+                                                 q.dtype)
+            den = den * c + p.sum(axis=-1)
+            m = new_m
+        outs.append(num / den[..., None])
+        lses.append(m + jnp.log(den))
+    return (_ungroup_blocks(jnp.stack(outs), h),
+            _ungroup_blocks(jnp.stack(lses), h))
+
+
+# what a layer's ``jax.checkpoint`` keeps of causal attention (an attention
+# sublayer's ``keeps``): the forward kernel's two results, float32 as it
+# writes them, which are all its backward pass reads beside q, k and v.  Named in the forward
+# rule before anything reads them, so that a checkpointed layer's
+# backward pass holds no second run of the kernel.
+ATTN_OUT = "otpu_attn_out"
+ATTN_LSE = "otpu_attn_lse"
+ATTN_KEEPS = (ATTN_OUT, ATTN_LSE)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_flash_attention(q, k, v, block: int, interpret: bool,
+                           window=None):
+    """Causal self-attention of q (b, h, s, hd), k (b, n_kv, s, hd) and
+    v (b, n_kv, s, hv) whose length is a multiple of ``block``: k and v
+    come with the model's own key-value heads, each shared by ``h /
+    n_kv`` consecutive query heads, and are repeated nowhere; their
+    gradients are the group's sums, made in float32.  Forward:
+    ``_causal_fwd_blocks`` (on a TPU one kernel call, the blocks chosen
+    in its index maps; on the CPU a ``jnp`` loop over the blocks).
+    Backward: the flash backward by the same blocks (scores recomputed
+    from q, k and the saved logsumexp in float32; no (s, s) array is
+    ever held), its matmul inputs in q's dtype; on a TPU each block pair
+    one call of the fused kernel (``_causal_bwd_fused``), on the CPU
+    ``_bwd_pair``'s einsums.
+
+    ``window`` (static; None: every earlier key) makes it sliding-window
+    attention: key j is visible to query i iff 0 <= i - j < ``window``,
+    a whole number of blocks.  Both passes then walk the block pairs a
+    window can reach and no other (``_window_pairs``), the far pair under
+    its own mask; a window that covers the sequence is None, bit for
+    bit.  With None every branch, grid and kernel is what it was before
+    the argument."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, window)[0]
+
+
+def _count_built(q, k, block, window) -> None:
+    """SPC ``attn_built``: the causal attention passes made, forward
+    rule or backward rule, while steps were traced (JAX traces a pass
+    more than once); ``attn_shared_kv_built``: those of them whose k and
+    v came with fewer heads than q and went to the kernels, or their
+    twins, that way; ``attn_window_built``: those made under a window;
+    ``attn_pairs_walked`` the block pairs the passes walk and
+    ``attn_pairs_causal`` those full causal passes of their lengths
+    would."""
+    nb = q.shape[2] // block
+    w = _window_in_blocks(window, block, q.shape[2])
+    spc.record("attn_built", 1)
+    if k.shape[1] < q.shape[1]:
+        spc.record("attn_shared_kv_built", 1)
+    if w is not None:
+        spc.record("attn_window_built", 1)
+    spc.record("attn_pairs_walked", len(_window_pairs(nb, w)))
+    spc.record("attn_pairs_causal", nb * (nb + 1) // 2)
+
+
+def _causal_fwd(q, k, v, block, interpret, window=None):
+    _count_built(q, k, block, window)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, window)
+    o = checkpoint_name(o, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
+    return o, (q, k, v, o, lse)
+
+
+#: up to this many blocks the backward pass's block pairs are unrolled
+#: (10 pairs at OLMoE's 4 blocks: what that step has always compiled
+#: to); beyond it they are walked by one ``lax.scan``, a pair's scores
+#: held at a time.  Unrolled, the 36 pairs of 8 blocks let XLA hold 15
+#: and more (h, block, block) float32 score blocks at once: 19.6 GB for
+#: the JoyAI step (offline compile for a v5e, PR 35)
+UNROLLED_BLOCKS = 4
+
+
+def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
+    """One block pair of the flash backward: (dq, dk, dv) parts.  A
+    head of the query side is a key-value head's, its rows the group's
+    (``_group_blocks``), so dk and dv sum the group in float32.
+    ``bias``: the diagonal pair's, a window's far pair's, or None."""
+    sc = contract("bhqd,bhkd->bhqk", qi, kj, dt) * scale
+    if bias is not None:
+        sc = sc + bias
+    p = jnp.exp(sc - lse_i[..., None])
+    dv = contract("bhqk,bhqd->bhkd", p, doi, dt)
+    dp = contract("bhqd,bhkd->bhqk", doi, vj, dt)
+    ds = p * (dp - delta_i[..., None]) * scale
+    return (contract("bhqk,bhkd->bhqd", ds, kj, dt),
+            contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
+
+
+def _causal_bwd(block, interpret, window, res, do, select=None):
+    q, k, v, o, lse = res
+    _count_built(q, k, block, window)
+    h, n_kv = q.shape[1], k.shape[1]
+    nb = q.shape[2] // block
+    w = _window_in_blocks(window, block, q.shape[2])
+    do = do.astype(jnp.float32)
+    delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
+    if not interpret:
+        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select)
+    if nb > UNROLLED_BLOCKS:
+        return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w,
+                                   select)
+    dt = q.dtype
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    bias = _group_bias(block, h // n_kv)
+    far = None if w is None else _far_bias(block, h // n_kv)
+    qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
+                             for a in (q, do, lse, delta))
+    cut = lambda a, i: a[:, :, i * block:(i + 1) * block]
+    dq = [0.0] * nb
+    dk = [0.0] * nb
+    dv = [0.0] * nb
+    for i, j in _window_pairs(nb, w):
+        dq_c, dk_c, dv_c = _bwd_pair(
+            qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
+            _select_bias(select, i, j, block, h // n_kv)
+            if select is not None
+            else bias if j == i else far if i - j == w else None, scale, dt)
+        dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
+    cat = lambda parts: jnp.concatenate(parts, axis=2).astype(dt)
+    return _ungroup_blocks(jnp.stack(dq), h).astype(dt), cat(dk), cat(dv)
+
+
+def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
+    """The same pairs in the same order (q block by q block, kv blocks
+    ascending), one a step of a ``lax.scan`` over float32 accumulators."""
+    dt = q.dtype
+    h, n_kv = q.shape[1], k.shape[1]
+    nb = q.shape[2] // block
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    tri = _group_bias(block, h // n_kv)
+    qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
+                             for a in (q, do, lse, delta))
+    kb, vb = (_group_blocks(a, n_kv, block) for a in (k, v))
+    pairs = _window_pairs(nb, w)
+    zero = lambda a: (a * 0).astype(jnp.float32)         # carries a's vma
+
+    def step(acc, ij):
+        i, j = ij
+        bias = jnp.where(i == j, tri, 0.0)
+        if w is not None:
+            bias = jnp.where(i - j == w, _far_bias(block, h // n_kv), bias)
+        if select is not None:
+            bias = _select_bias(select, i, j, block, h // n_kv)
+        dq_c, dk_c, dv_c = _bwd_pair(
+            qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i], bias, scale,
+            dt)
+        dq, dk, dv = acc
+        return (dq.at[i].add(dq_c), dk.at[j].add(dk_c),
+                dv.at[j].add(dv_c)), None
+
+    (dq, dk, dv), _ = jax.lax.scan(
+        step, (zero(qb), zero(kb), zero(vb)),
+        (jnp.asarray([p[0] for p in pairs]),
+         jnp.asarray([p[1] for p in pairs])))
+    return (_ungroup_blocks(dq, h).astype(dt),
+            _ungroup_blocks(dk, n_kv).astype(dt),
+            _ungroup_blocks(dv, n_kv).astype(dt))
+
+
+def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
+    """The same pairs in the same order, each one call of the fused
+    Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
+    ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
+    and the float32 accumulators pass through every call in place, dk's
+    and dv's with k's and v's own heads.  Both walks: unrolled up to
+    ``UNROLLED_BLOCKS`` blocks, one ``lax.scan`` beyond; the arrays go
+    in whole and the pair is an operand, so neither slices.  Under a
+    selection the kernel reads the mask key-major, as it holds the
+    scores: transposed once here, beside the pairs' flags."""
+    from ompi_tpu.ops.flash_attention import (_tile_flags,
+                                              attn_block_backward)
+
+    dt = q.dtype
+    nb = q.shape[2] // block
+    do = do.astype(dt)                  # what ``contract`` makes of it
+    if select is not None:
+        select = (jnp.swapaxes(select, 1, 2), _tile_flags(select, block))
+        pair = lambda acc, ij: attn_block_backward(
+            ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
+            select=select)
+    else:
+        pair = lambda acc, ij: attn_block_backward(
+            ij, q, k, v, do, lse, delta, *acc, block=block,
+            interpret=False, window=None if w is None else w * block)
+    pairs = _window_pairs(nb, w)
+    acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
+    vma = tuple(frozenset().union(*(jax.typeof(a).vma
+                                    for a in (q, k, v, do))))
+    if vma:                 # the carry varies as the kernel's results do
+        acc = jax.lax.pcast(acc, vma, to="varying")
+    if nb > UNROLLED_BLOCKS:
+        acc, _ = jax.lax.scan(lambda acc, ij: (pair(acc, ij), None), acc,
+                              jnp.asarray(pairs, jnp.int32))
+    else:
+        for ij in pairs:
+            acc = pair(acc, jnp.asarray(ij, jnp.int32))
+    return tuple(a.astype(dt) for a in acc)
+
+
+causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+# -- learned sparse attention (DeepSeek-V3.2's DSA) ---------------------------
+def _count_dsa(q, topk: int) -> None:
+    """SPC ``dsa_built``: the attention passes made under a selection,
+    forward rule or backward rule, while steps were traced (as
+    ``attn_window_built``); ``dsa_keys_selected`` the (query, key) pairs
+    those passes attend to, ``min(t + 1, topk)`` a query, and
+    ``dsa_keys_causal`` those full causal passes of their lengths would,
+    both from the shapes."""
+    b, _, s, _ = q.shape
+    full = min(s, topk)
+    spc.record("dsa_built", 1)
+    spc.record("dsa_keys_selected",
+               b * (full * (full + 1) // 2 + (s - full) * topk))
+    spc.record("dsa_keys_causal", b * s * (s + 1) // 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
+                             topk: int):
+    """``causal_flash_attention`` under a data-dependent selection:
+    ``select`` (b, s, s) int8, query-major, says which keys u <= t query
+    t attends to (every row selects a key).  Returns (o (b, h, s, hv)
+    float32, the logsumexp (b, h, s) float32 over the selected keys);
+    ``topk``, the most keys a row selects, is read by the counters alone.
+    No gradient passes through the selection, and none through the
+    logsumexp handed out (what reads it reads a constant).  Both passes
+    walk every causal block pair under its tile of the selection
+    (``_causal_fwd_blocks``, ``_causal_bwd``: the same kernels and twins),
+    a pair that selects nothing passed over by the kernels."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+
+
+def _selected_fwd(q, k, v, select, block, interpret, topk):
+    _count_built(q, k, block, None)
+    _count_dsa(q, topk)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+    o = checkpoint_name(o, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
+    return (o, lse), (q, k, v, o, lse, select)
+
+
+def _selected_bwd(block, interpret, topk, res, cts):
+    *res, select = res
+    _count_dsa(res[0], topk)
+    return (*_causal_bwd(block, interpret, None, tuple(res), cts[0],
+                         select=select), None)
+
+
+selected_flash_attention.defvjp(_selected_fwd, _selected_bwd)

@@ -20,6 +20,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.parallel.layers import (cast_param, matmul, relu2,
                                       rmsnorm_gain, swiglu)
+from ompi_tpu.parallel.sublayer import Sublayer
 from ompi_tpu.runtime import spc
 
 
@@ -196,7 +197,8 @@ def grouped_relu2_ffn_vjp(xs, up, down, sizes, compute_dtype,
     return gmm(hidden, down), back
 
 
-def moe_sorted_block(p, x, cfg, *, interpret: bool = True):
+def moe_sorted_block(p, x, cfg, bias=None, *, interpret: bool = True,
+                     routed=None):
     """OLMoE's sparse MLP sublayer on the residual stream ``x`` (b, s,
     d): pre-norm, a learned router (logits and softmax in float32), the
     top k of all experts with no capacity, sort-and-gather dispatch,
@@ -234,7 +236,7 @@ def moe_sorted_block(p, x, cfg, *, interpret: bool = True):
 # expert layer on one rank of an expert-parallel deployment) -------------
 
 # what a layer's ``jax.checkpoint`` keeps of an expert block
-# (``train.model_loss``'s policy saves these names and nothing else): the
+# (``objective.model_loss``'s policy saves these names and nothing else): the
 # router's float32 product, the integers chosen and sorted from it, the
 # chosen experts' scores, and the held experts' sum in the latent.  Small
 # beside a layer's activations, and dear to make again: the six-pass
@@ -587,7 +589,8 @@ def moe_shared_local_block(p, x, cfg, bias, *, interpret: bool = True,
     return out.reshape(x.shape), stats, seen
 
 
-def moe_latent_block(p, x, cfg, bias, *, interpret: bool = True):
+def moe_latent_block(p, x, cfg, bias, *, interpret: bool = True,
+                     routed=None):
     """nemotron_h's expert sublayer (its LatentMoE) on the residual
     stream ``x`` (b, s, d), on a rank that holds ``experts_here`` of the
     routed experts: pre-norm; the router's sigmoid scores over **all**
@@ -616,3 +619,74 @@ def moe_latent_block(p, x, cfg, bias, *, interpret: bool = True):
     with jax.named_scope("otpu_latent"):
         out = out + matmul(latent, p["lat_up"], dt)
     return out.reshape(x.shape), stats, seen
+
+
+def dense_mlp(p, x, cfg, bias=None, *, interpret: bool = True, routed=None):
+    """A dense layer's feed-forward on the residual stream ``x`` (b, s,
+    d): pre-norm, SwiGLU.  No router: no statistics, nothing reported."""
+    h = rmsnorm_gain(x, p["ln2"], cfg.rms_norm_eps)
+    y = swiglu(h.reshape(-1, h.shape[-1]), p["gate"], p["up"], p["down"],
+               cfg.compute_dtype)
+    return y.reshape(x.shape), {}, {}
+
+
+# -- the feed-forwards a layer may end in (``parallel/model.py``'s table) ----
+def _dense_shapes(cfg) -> dict:
+    d, ff = cfg.hidden_size, cfg.intermediate_size
+    return {"ln2": (d,), "gate": (d, ff), "up": (d, ff), "down": (ff, d)}
+
+
+def _routed_shapes(cfg) -> dict:
+    """The gain, the router over all the experts, the held experts' three,
+    and where the model has a shared expert its three and, where that is
+    gated, ``shared_w_g`` (d, 1)."""
+    d, f, e, fs = (cfg.hidden_size, cfg.expert_width, cfg.n_experts_here,
+                   cfg.shared_width)
+    out = {"ln2": (d,), "router": (d, cfg.num_experts),
+           "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
+    if fs:
+        out.update(shared_gate=(d, fs), shared_up=(d, fs),
+                   shared_down=(fs, d))
+    if cfg.shared_expert_gate:
+        out["shared_w_g"] = (d, 1)
+    return out
+
+
+def _latent_shapes(cfg) -> dict:
+    """The gain, the router over all the experts, the latent's two
+    projections, the held experts' two matrices in the latent, the shared
+    expert's two on the hidden width."""
+    d, e, lat, f = (cfg.hidden_size, cfg.n_experts_here, cfg.moe_latent_size,
+                    cfg.expert_width)
+    fs = cfg.moe_shared_expert_intermediate_size * cfg.n_shared_experts
+    return {"ln2": (d,), "router": (d, cfg.num_experts),
+            "lat_down": (d, lat), "lat_up": (lat, d), "up": (e, lat, f),
+            "down": (e, f, lat), "shared_up": (d, fs), "shared_down": (fs, d)}
+
+
+def _held_reports(cfg) -> dict:
+    keys = ("in", "logits", "scores", "weights")
+    if cfg.shared_expert_gate:
+        keys += ("shared_gate",)
+    if cfg.router_before_attention:
+        keys += ("expert_in", "expert_out")
+    return dict.fromkeys(keys, 1)
+
+
+DENSE = Sublayer(group="dense", scope="otpu_dense_mlp", run=dense_mlp,
+                 shapes=_dense_shapes, undecayed=("ln2",))
+#: OLMoE's: every expert here
+SORTED = Sublayer(
+    group="moe", scope="otpu_moe", run=moe_sorted_block,
+    shapes=_routed_shapes, undecayed=("ln2",),
+    reports=lambda cfg: {"in": 1, "logits": 1, "lse": 0, "weights": 1})
+#: a share of the experts (``cfg.routes_to_held``)
+SHARED_LOCAL = Sublayer(
+    group="moe", scope="otpu_moe", run=moe_shared_local_block,
+    shapes=_routed_shapes, undecayed=("ln2",), reports=_held_reports,
+    keeps=CHECKPOINT_KEEPS)
+#: nemotron_h's ``E``
+LATENT = Sublayer(
+    name="E", group="moe", scope="otpu_moe", run=moe_latent_block,
+    shapes=_latent_shapes, undecayed=("ln2",), reports=_held_reports,
+    keeps=CHECKPOINT_KEEPS)

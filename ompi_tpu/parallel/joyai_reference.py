@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ompi_tpu.parallel.olmoe_reference import _norm, adamw_step
-from ompi_tpu.parallel.train import ModelConfig
+from ompi_tpu.parallel.config import ModelConfig
 
 
 def _rope(x, theta):

@@ -73,7 +73,7 @@ import jax.numpy as jnp
 from ompi_tpu.parallel.olmoe_reference import _norm, _rope
 from ompi_tpu.parallel.qwen3next_reference import (adamw_step,  # noqa: F401
                                                    layers_of as _layers_of)
-from ompi_tpu.parallel.train import ModelConfig
+from ompi_tpu.parallel.config import ModelConfig
 
 KINDS = {"S": "dsa_moe"}
 

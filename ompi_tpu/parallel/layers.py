@@ -33,6 +33,15 @@ def matmul(a, w, compute_dtype, weight: bool = True):
     return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
 
+def contract(eq, a, b, compute_dtype):
+    """A float32 einsum of blocked attention, its inputs in
+    ``compute_dtype``."""
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return jnp.einsum(eq, a, b, precision=jax.lax.Precision.HIGHEST)
+    return jnp.einsum(eq, a.astype(compute_dtype), b.astype(compute_dtype),
+                      preferred_element_type=jnp.float32)
+
+
 def rmsnorm_gain(x, gain, eps: float):
     """RMSNorm with a learned gain, in float32 whatever ``x`` is."""
     x = x.astype(jnp.float32)

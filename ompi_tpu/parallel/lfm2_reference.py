@@ -66,7 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from ompi_tpu.parallel.olmoe_reference import _norm, _rope, adamw_step
-from ompi_tpu.parallel.train import ModelConfig
+from ompi_tpu.parallel.config import ModelConfig
 
 KINDS = {"c": "conv_dense", "a": "attn_dense", "C": "conv_moe",
          "A": "attn_moe"}

@@ -57,7 +57,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ompi_tpu.parallel.train import ModelConfig, _leaf, _set_leaf, leaf_names
+from ompi_tpu.parallel.config import ModelConfig
+from ompi_tpu.parallel.train import _leaf, _set_leaf, leaf_names
 
 UNDECAYED = ("norm", "gate_norm", "ln1", "ln2", "final_norm", "conv_b",
              "A_log", "D", "dt_bias")

@@ -1,0 +1,293 @@
+"""A public model's training objective: the embedding, the walked layers
+(``parallel/model.decoder_layer``, a run of like layers under one
+``lax.scan``, each layer under ``jax.checkpoint``), the head's
+cross-entropy in blocks of rows, the routers' losses and the next-n
+module's, with what a step reports of them (its ``aux``, listed in
+``parallel/train.py``).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ompi_tpu.parallel import experts
+from ompi_tpu.parallel.config import ModelConfig
+from ompi_tpu.parallel.layers import matmul, rmsnorm_gain
+from ompi_tpu.parallel.model import (CHECKPOINT_KEEPS, decoder_layer,
+                                     kind_of_letter, layer_kinds)
+
+SAMPLE_ROWS = 16        # token rows whose activations a step reports
+
+
+def sample_rows(rows: int) -> np.ndarray:
+    """The ``SAMPLE_ROWS`` rows of a shard of ``rows`` token rows that a
+    step reports activations at: evenly spaced, the last row among
+    them."""
+    n = min(SAMPLE_ROWS, rows)
+    return (np.arange(1, n + 1) * rows) // n - 1
+
+
+def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
+    """Summed cross-entropy of ``softmax(h @ w)`` against ``labels``, by
+    blocks of ``block_rows`` rows so that no (T, V) array is ever held.
+    The forward pass also makes the two gradients (``softmax - onehot``
+    is at hand in each block), so the backward pass only scales them:
+    the head's logits are computed once a step, not twice.  Returns
+    (the sum over rows, per row (logsumexp, the label's logit))."""
+    t, d = h.shape
+    nblk = t // block_rows
+    if nblk * block_rows != t:
+        raise ValueError(f"{t} rows are not whole blocks of {block_rows}")
+
+    def run(h, w, labels):
+        def block(carry, xs):
+            total, dw = carry
+            hb, lb = xs
+            logits = matmul(hb, w, compute_dtype)            # (rows, V) f32
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
+            dlogits = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
+                lb, logits.shape[-1], dtype=jnp.float32)
+            dh = matmul(dlogits, w.T, compute_dtype)
+            dw = dw + matmul(hb.T, dlogits, compute_dtype, weight=False)
+            return (total + jnp.sum(lse - picked), dw), (
+                dh, jnp.stack([lse, picked], axis=-1))
+
+        vma = tuple(jax.typeof(h).vma | jax.typeof(labels).vma)
+        zero = (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32))
+        if vma:         # the scan's carry varies as its inputs do
+            zero = jax.lax.pcast(zero, vma, to="varying")
+        (total, dw), (dh, rows) = jax.lax.scan(
+            block, zero, (h.reshape(nblk, block_rows, d),
+                          labels.reshape(nblk, block_rows)))
+        return total, rows.reshape(t, 2), dh.reshape(t, d), dw
+
+    @jax.custom_vjp
+    def ce(h, w):
+        total, rows, _, _ = run(h, w, labels)
+        return total, rows
+
+    def fwd(h, w):
+        total, rows, dh, dw = run(h, w, labels)
+        return (total, rows), (dh, dw)
+
+    def bwd(res, ct):
+        dh, dw = res
+        return ct[0] * dh, ct[0] * dw
+
+    ce.defvjp(fwd, bwd)
+    return ce(h, w)
+
+
+def layer_checkpoint_policy():
+    """What a walked layer's ``jax.checkpoint`` keeps for its backward
+    pass: what its sublayers name (their ``keeps``: an expert block's
+    routing results, causal attention's o and logsumexp) and nothing else,
+    so a layer that names nothing is recomputed whole."""
+    return jax.checkpoint_policies.save_only_these_names(
+        *CHECKPOINT_KEEPS)
+
+
+def _walk_layers(run, stacked, x, bias, n: int):
+    """``n`` like layers in turn: ``run(layer, x, bias row) -> (x,
+    out)``; returns (x, the outs stacked).  More than one is a
+    ``lax.scan`` over the stacked leaves, so the layer is traced and
+    compiled once however many there are."""
+    if n == 1:
+        x, out = run(jax.tree.map(lambda a: a[0], stacked), x,
+                     None if bias is None else bias[0])
+        return x, jax.tree.map(lambda a: a[None], out)
+    return jax.lax.scan(
+        lambda x, xs: run(xs[0], x, xs[1]), x, (stacked, bias))
+
+
+def _walk_pattern(run_of, layers, x, bias, cfg: ModelConfig):
+    """The held layers of a ``hybrid_override_pattern`` or ``layer_types``
+    model in turn, a run of like layers at a time (``cfg.segments``;
+    ``layers`` holds a group a run): a run's unit is called once, or
+    scanned over its repeats (``_walk_layers``), each of its layers
+    through ``run_of(kind)``, ``kind`` its kind's name.  ``bias`` (the held
+    expert layers, E) gives each layer with a router its row (None: the
+    routers choose under none).  Returns (x, what the layers' ``run``
+    gave: the routers' statistics and chosen experts and the sampled
+    rows, each stacked in the layers' order over the layers that have
+    it; a unit of two letters has no key in both)."""
+    by_letter = kind_of_letter(cfg)
+    stats, chosen, sample, done = {}, [], {}, 0   # done: routers walked
+    for unit, n, first in cfg.segments:
+        kinds = [by_letter[letter] for letter in unit]
+
+        def unit_run(group, x, bias_row, kinds=kinds):
+            out = {}
+            for kind in kinds:
+                x, out[kind.letter] = run_of(kind.name)(
+                    group[kind.name], x, bias_row if kind.routes else None)
+            return x, out
+
+        rows = None
+        if bias is not None and any(kind.routes for kind in kinds):
+            rows, done = bias[done:done + n], done + n
+        x, out = _walk_layers(unit_run, layers[f"l{first}"], x, rows, n)
+        for letter in unit:
+            st, experts, seen = out[letter]
+            for into, part in ((stats, st), (sample, seen)):
+                for k, v in part.items():
+                    into.setdefault(k, []).append(v)
+            if experts is not None:
+                chosen.append(experts)
+    with jax.named_scope("otpu_stats"):
+        cat = lambda of: {k: jnp.concatenate(v) for k, v in of.items()}
+        return x, (cat(stats), jnp.concatenate(chosen), cat(sample))
+
+
+def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
+               n_global: int, axes: tuple = (), bias=None):
+    """The training loss of one micro-batch shard and what a step
+    reports of it.  ``n_global`` is the tokens of the whole batch and
+    ``axes`` the mesh axes it is sharded over: sums cross them by
+    ``psum``, so every shard returns the whole batch's loss.  ``bias``
+    holds the routers' balancing biases where they choose under one
+    (``layers`` (L, E) and ``mtp`` (1, E)); nothing is differentiated
+    with respect to it.  Where the model has a next-next-token module,
+    ``labels`` is one position longer than ``tokens``: ``labels[:, i]``
+    follows ``tokens[:, i]`` and ``labels[:, i + 1]`` follows that."""
+    psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
+    b, s = tokens.shape
+    at = sample_rows(b * s)
+    bias = bias or {}
+
+    @functools.cache    # one function a kind, so that JAX traces it once
+    def run_of(kind: str):
+        """A layer's run, ``kind`` its kind's name (``model.layer_kinds``):
+        static, because two kinds of attention layer hold the same
+        leaves."""
+        operator = layer_kinds(cfg)[kind].operator
+        own = set(operator.reports(cfg)) if operator else ()
+
+        def run(layer, x, bias_row):
+            x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
+                                        bias=bias_row, kind=kind, at=at)
+            experts = seen.pop("experts", None)
+            with jax.named_scope("otpu_stats"):
+                # an operator's rows under their own names, a router's
+                # behind ``router_``: at the sampled rows, but for what
+                # was cut there already (``_at``) and the sequences a
+                # position's result holds every earlier row of (``_seq``)
+                out = (jax.tree.map(psum, st), experts, {
+                    k if k in own else "router_" + k:
+                    v if k.endswith(("_seq", "_at")) else v[at]
+                    for k, v in seen.items()})
+            return x, out
+
+        if cfg.layers_here + cfg.n_mtp_here > 1:
+            # a layer's activations are recomputed in its backward pass,
+            # so that one layer's are held at a time and not every
+            # layer's; with one layer there is nothing to save.  Kept from
+            # the forward pass is only what its sublayers name
+            return jax.checkpoint(run, policy=layer_checkpoint_policy())
+        return run
+
+    with jax.named_scope("otpu_embed"):
+        x = params["embed"][tokens]                          # (b, s, d) f32
+    with jax.named_scope("otpu_layers"):
+        if cfg.pattern_here:
+            x, (st, chosen, sample) = _walk_pattern(
+                run_of, params["layers"], x, bias.get("layers"), cfg)
+        else:
+            if cfg.n_dense_here:
+                x, _ = _walk_layers(run_of("dense"), params["dense"], x,
+                                    None, cfg.n_dense_here)
+            x, (st, chosen, sample) = _walk_layers(
+                run_of("layers"), params["layers"], x, bias.get("layers"),
+                cfg.n_sparse_here)
+    head_rows = min(cfg.loss_block_rows, b * s)
+    # a tied head reads the embedding matrix itself: one leaf, whose
+    # gradient is the sum of the gather's and the cross-entropy's
+    head = params["embed"].T if cfg.tie_word_embeddings else params["head"]
+    with jax.named_scope("otpu_head"):
+        h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
+        ce_sum, rows = head_cross_entropy(
+            h.reshape(b * s, -1), head,
+            labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
+    routed = cfg.n_sparse_here * n_global  # rows of all routers' logits
+    with jax.named_scope("otpu_loss"):
+        ce = psum(ce_sum) / n_global
+        lb = z = jnp.zeros((), jnp.float32)
+        if "prob_sum" in st:
+            # HF's load_balancing_loss_func: every layer's rows in one
+            # mean
+            slots, prob_sum = (jnp.sum(st["slots"], 0),
+                               jnp.sum(st["prob_sum"], 0))
+            lb = cfg.num_experts * jnp.sum((slots / routed)
+                                           * (prob_sum / routed))
+            if "z_sum" in st:
+                z = jnp.sum(st["z_sum"], 0) / routed
+        lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
+        total = ce + lb + z
+        # a learned selection's alignment loss, every layer's rows in one
+        # mean a token: its gradient reaches the indexers' leaves alone
+        index = None
+        if "index_kl_sum" in st:
+            index = cfg.index_loss_coef * jnp.sum(st["index_kl_sum"]) \
+                / n_global
+            total = total + index
+    losses, loads = [ce, lb, z], st["slots"]
+    with jax.named_scope("otpu_stats"):
+        sample["head_in"] = h.reshape(b * s, -1)[at]
+    aux = {}
+    if cfg.n_mtp_here:
+        # DeepSeek-V3's multi-token prediction, depth one: the last
+        # layer's output (before the final norm) joined with the next
+        # token's embedding, one more sparse layer, the same embedding
+        # and head, a cross-entropy against the token after the next
+        mtp = params["mtp"]
+        with jax.named_scope("otpu_mtp"):
+            nxt = rmsnorm_gain(params["embed"][labels[:, :s]], mtp["enorm"],
+                               cfg.rms_norm_eps)
+            prev = rmsnorm_gain(x, mtp["hnorm"], cfg.rms_norm_eps)
+            joined = jnp.concatenate([nxt, prev], -1).reshape(b * s, -1)
+            x2 = matmul(joined, mtp["proj"], cfg.compute_dtype
+                        ).reshape(b, s, -1)
+            with jax.named_scope("otpu_layers"):
+                x2, (st2, chosen2, sample2) = _walk_layers(
+                    run_of("layers"), jax.tree.map(lambda a: a[None], {
+                        k: v for k, v in mtp.items()
+                        if k not in ("enorm", "hnorm", "proj", "norm")}),
+                    x2, bias.get("mtp"), 1)
+            with jax.named_scope("otpu_head"):
+                h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
+                ce2_sum, aux["mtp_rows"] = head_cross_entropy(
+                    h2.reshape(b * s, -1), head,
+                    labels[:, 1:].reshape(b * s), head_rows,
+                    cfg.compute_dtype)
+        with jax.named_scope("otpu_loss"):
+            losses.append(cfg.mtp_loss_coef * psum(ce2_sum) / n_global)
+            total = total + losses[-1]
+        with jax.named_scope("otpu_stats"):
+            loads = jnp.concatenate([loads, st2["slots"]])
+            chosen = jnp.concatenate([chosen, chosen2])
+            sample = {**{k: jnp.concatenate([sample[k], sample2[k]])
+                         for k in sample2},
+                      "head_in": sample["head_in"],
+                      "mtp_head_in": h2.reshape(b * s, -1)[at]}
+    if cfg.n_experts_here < cfg.num_experts:
+        first = cfg.first_expert_here
+        chunk = experts.chunk_rows(tokens.size, cfg.num_experts_per_tok,
+                                   cfg.n_experts_here, cfg.num_experts)
+        with jax.named_scope("otpu_stats"):
+            held = jnp.sum(loads[:, first:first + cfg.n_experts_here],
+                           axis=1)
+            aux["local_slots"] = jnp.sum(held)
+            # what the held experts' loops walked: a layer's held slots
+            # in whole chunks (``experts.local_expert_ffn``)
+            aux["chunk_rows"] = jnp.sum(
+                (held.astype(jnp.int32) + chunk - 1) // chunk * chunk)
+    if index is not None:
+        losses.append(index)
+    with jax.named_scope("otpu_stats"):
+        losses = jnp.stack([total] + losses)
+    return total, {"losses": losses, "loads": loads, "rows": rows,
+                   "experts": chosen, "sample": sample, **aux}

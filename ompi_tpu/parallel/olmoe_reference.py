@@ -29,8 +29,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ompi_tpu.parallel.train import (ModelConfig, is_gain, leaf_names,
-                                     _leaf, _set_leaf)
+from ompi_tpu.parallel.config import ModelConfig
+from ompi_tpu.parallel.train import (is_decayed, leaf_names, _leaf,
+                                     _set_leaf)
 
 
 def _norm(x, gain, eps):
@@ -121,7 +122,7 @@ def adamw_step(params, mom, var, t: int, g, cfg: ModelConfig):
         v = cfg.adam_b2 * v + (1 - cfg.adam_b2) * gi * gi
         upd = (m / (1 - cfg.adam_b1 ** t)) / (
             jnp.sqrt(v / (1 - cfg.adam_b2 ** t)) + cfg.adam_eps)
-        if not is_gain(name):
+        if is_decayed(name):
             upd = upd + cfg.weight_decay * p
         lr = cfg.lr * min(1.0, t / cfg.warmup_steps)
         for tree, leaf in zip(out, (p - lr * upd, m, v)):

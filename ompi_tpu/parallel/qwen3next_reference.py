@@ -69,7 +69,7 @@ Departures, each for a stated reason:
 * the rule's state and the convolution are not reset and attention not
   masked between packed documents;
 * AdamW decays every matrix and the taps, no gain, no ``A_log`` and no
-  ``dt_bias`` (``train.UNDECAYED``).
+  ``dt_bias`` (``model.UNDECAYED``).
 """
 from __future__ import annotations
 
@@ -78,8 +78,8 @@ import jax.numpy as jnp
 
 from ompi_tpu.parallel.lfm2_reference import conv_taps, swiglu
 from ompi_tpu.parallel.olmoe_reference import _norm, _rope
-from ompi_tpu.parallel.train import (ModelConfig, _leaf, _set_leaf,
-                                     is_decayed, leaf_names)
+from ompi_tpu.parallel.config import ModelConfig
+from ompi_tpu.parallel.train import _leaf, _set_leaf, is_decayed, leaf_names
 
 KINDS = {"L": "gdn_moe", "A": "attn_moe"}
 
@@ -106,7 +106,7 @@ def l2norm(x):
     return x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
-def delta_net(p, x, cfg: ModelConfig):
+def gdn(p, x, cfg: ModelConfig):
     """``Op`` of a ``linear_attention`` layer, without the residual add."""
     b, s, _ = x.shape
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
@@ -211,7 +211,7 @@ def forward(params, tokens, cfg: ModelConfig):
     x = params["embed"][tokens]
     loads, prob_sums = [], []
     for letter, p in layers_of(params, cfg):
-        x = x + (delta_net if letter == "L" else attention)(p, x, cfg)
+        x = x + (gdn if letter == "L" else attention)(p, x, cfg)
         y, load, prob_sum = experts(p, x, cfg)
         x = x + y
         loads.append(load)

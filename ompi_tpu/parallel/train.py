@@ -1,21 +1,16 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
 Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
-Keye-VL-2.0-30B-A3B's language model):
-widths from a configuration file, not
-from the mesh; the kinds of sublayer from its published keys
-(``ModelConfig``).  The
-parameter tree and its initialisation, the loss over the walked layers
-(``parallel/model.decoder_layer``), AdamW, the routers' bias update,
-``build_train_step`` and what reads a finished step's ``aux``.  The batch
-is sharded over ``dp`` alone; the ``pp`` / ``sp`` / ``tp`` shardings run
-in the invented step of ``parallel/flagship.py``, which nothing here
-imports.
+Keye-VL-2.0-30B-A3B's language model): widths from a configuration file
+(``parallel/config.py``), not from the mesh; the kinds of layer from
+``parallel/model.py``'s table.  The parameter tree and its initialisation,
+AdamW, the routers' bias update, ``build_train_step`` around
+``parallel/objective.model_loss`` and what reads a finished step's ``aux``.
+The batch is sharded over ``dp`` alone; the ``pp`` / ``sp`` / ``tp``
+shardings run in the invented step of ``parallel/flagship.py``, which
+nothing here imports.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
-import json
 import time
 import weakref
 import zlib
@@ -27,48 +22,20 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ompi_tpu.base.jaxenv import pallas_interpret
-from ompi_tpu.parallel import experts, model
-from ompi_tpu.parallel.layers import matmul, rmsnorm_gain
+# ``load_model_config`` is imported for the benchmark's sake alone, which
+# takes it from here; everything else names ``parallel/config.py``
+from ompi_tpu.parallel.config import (ModelConfig,
+                                      load_model_config)  # noqa: F401
+from ompi_tpu.parallel.objective import model_loss
 from ompi_tpu.parallel.mesh import MeshSpec
-from ompi_tpu.parallel.model import decoder_layer
+from ompi_tpu.parallel.model import (UNDECAYED, kind_of_letter, layer_kinds,
+                                     leaf_starts, sample_axes)
 from ompi_tpu.runtime import spc, trace
 
-#: OLMoE's layer leaves, stacked over the layers this rank holds
-LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
-                "router", "gate", "up", "down")
-GAINS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm", "q_a_norm",
-         "kv_a_norm", "enorm", "hnorm", "norm", "gate_norm", "index_k_norm")
-#: a Mamba-2 mixer's leaves that are no matrices: like the gains they are
-#: not decayed, and each starts as ``init_model_params`` says (an indexer's
-#: LayerNorm bias at zero)
-UNDECAYED = GAINS + ("A_log", "D", "dt_bias", "conv_b", "index_k_bias")
-#: a hybrid pattern's letters (nemotron_h) and the group a layer of each
-#: kind goes by in the parameter tree; behind them the letters a
-#: ``layer_types`` model's layers are walked by (lfm2_moe: an operator,
-#: then a feed-forward): a gated short convolution or grouped-query
-#: attention (qwen3_next: a Gated DeltaNet operator or output-gated
-#: attention; smallthinker: attention in full or under a sliding window,
-#: two kinds of the same leaves; Keye-VL-2.0: attention under a learned
-#: selection, which holds an indexer's leaves beside attention's), before a
-#: dense SwiGLU (small letter) or the experts (capital)
-PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "moe",
-                 "c": "conv_dense", "a": "attn_dense", "l": "gdn_dense",
-                 "w": "swa_dense", "s": "dsa_dense",
-                 "C": "conv_moe", "A": "attn_moe", "L": "gdn_moe",
-                 "W": "swa_moe", "S": "dsa_moe"}
-#: the letters whose layer holds a router
-EXPERT_LETTERS = "ECALWS"
-#: ``layer_types``' names and the letter's lower case
-OPERATOR_LETTERS = {"conv": "c", "full_attention": "a",
-                    "linear_attention": "l", "sliding_attention": "w",
-                    "sparse_attention": "s"}
-#: a ``layer_types`` letter's name, which ``decoder_layer`` is told
-LETTER_OPERATORS = {v: k for k, v in OPERATOR_LETTERS.items()}
-#: what an operator's sublayer reports by token row goes by its own name
-#: into a step's ``sample``; what a router does, behind ``router_``
-OPERATOR_SAMPLES = ("ssm_", "conv_", "attn_", "gdn_", "dsa_")
+#: the tree's own gains, beside its layers' (``model.UNDECAYED``): they
+#: start at one and are not decayed
+GAINS = ("final_norm", "enorm", "hnorm", "norm")
 PROBE = 64              # entries of each leaf that a step reports
-SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
 #: them outside the step (no unit, no scaling).  ``losses`` (the total,
 #: cross-entropy, and the load-balancing and router z losses as weighted
@@ -84,749 +51,20 @@ SAMPLE_ROWS = 16        # token rows whose activations a step reports
 #: gradient's sum of squares), ``grad_probe`` and ``param_probe`` (the
 #: gradient and the updated parameter at ``probe_positions``); and
 #: ``sample``, what went into and came out of the float32 parts at
-#: ``sample_rows`` of each shard: ``router_in`` (L, R, d),
-#: ``router_logits`` (L, R, E), ``router_lse`` (L, R) or, of a sigmoid
-#: router, ``router_scores`` (L, R, E), ``router_weights`` (L, R, k),
-#: ``head_in`` (R, d) (``mtp_head_in``), and of every Mamba-2 layer's
-#: first held head what its scan read, whole (``ssm_dt_seq`` (M, T),
-#: ``ssm_x_seq`` (M, T, p), ``ssm_b_seq``, ``ssm_c_seq`` (M, T, n)), and
-#: made (``ssm_y`` (M, R, p)); of every gated short convolution's first
-#: ``model.CONV_SAMPLE`` channels what its gates and taps read, whole
-#: (``conv_bcu_seq`` (C, T, B | C | u)), and made (``conv_y`` (C, R, .));
-#: of every RoPE attention layer's first query and first key-value head
-#: the two side by side before the QK-norm (``attn_qk_in`` (A, R, 2 hd))
-#: and behind RoPE (``attn_qk``), so that their precision can be read
-#: from one step alone; of every Gated DeltaNet layer's first value head
-#: what its rule read, whole (``gdn_q_seq``, ``gdn_k_seq`` (G, T, dk),
-#: ``gdn_v_seq`` (G, T, dv), ``gdn_g_seq``, ``gdn_beta_seq`` (G, T)), and
-#: made (``gdn_o`` (G, R, dv)); of every output-gated attention layer's
-#: first head o and its gate side by side (``attn_og_in`` (A, R, 2 hd))
-#: and gated (``attn_og`` (A, R, hd)); of every sliding-window layer's
-#: first query head and its key-value head what the kernels read
-#: (``attn_win_q`` (W, R, hd); ``attn_win_k_seq``, ``attn_win_v_seq`` (W,
-#: T, hd) whole) and made (``attn_win_o`` (W, R, hd)); of every
-#: ``sparse_attention`` layer what ``model.dsa_attention`` lists (``dsa_*``:
-#: the selection packed, the indexer's and attention's inputs whole or at
-#: the sampled rows, the row's scores, output and alignment loss); a gated
-#: shared expert's gate
-#: (``router_shared_gate`` (L, R, 1)); where the router stands before
-#: attention the normed rows the held experts read and their weighted sum
-#: (``router_expert_in``, ``router_expert_out`` (L, R, d)); under
-#: ``tie_word_embeddings``
-#: ``embed_probe_read`` (PROBE,), whether a probed entry of ``embed``
-#: lies in a row the step's tokens read (the others' gradient is the
-#: head's alone)
-
-
-@dataclasses.dataclass(frozen=True)
-class ModelConfig:
-    """A public model's widths (the keys of its published
-    ``config.json``), how much of it this rank holds (``layers_here``:
-    the leading dense layers, then sparse ones; ``experts_here`` routed
-    experts from ``expert_share`` x ``experts_here`` on, 0 for all;
-    ``vocab_here`` rows of the vocabulary, 0 for all) and how it is
-    trained (the ``train`` group of the file).  Which sublayers a layer
-    has follows from the published keys: ``kv_lora_rank`` set is latent
-    attention; layers before ``first_k_dense_replace`` are dense;
-    ``scoring_func`` and ``topk_method`` say how a router scores and
-    chooses; ``n_shared_experts``; ``num_nextn_predict_layers``.
-
-    nemotron_h's keys: ``hybrid_override_pattern`` set makes every layer
-    **one** sublayer, by its letter (``M`` a Mamba-2 mixer, ``*``
-    grouped-query attention without RoPE, ``E`` experts in a latent of
-    ``moe_latent_size`` with relu2, beside a shared one); the rank holds
-    the ``layers_here`` layers from ``first_layer_here`` on, and of each
-    mixer the chip's share of its heads (``heads_here`` query heads with
-    the key-value heads they read; ``mamba_heads_here`` Mamba heads with
-    their B/C groups; 0 for all), as one member of a tensor-parallel
-    group holds them.  ``mtp_here`` says how many of the published
-    next-n modules are held (-1: all of them).
-
-    lfm2_moe's keys: ``layer_types`` set gives every layer an operator by
-    its name (``conv`` the gated short convolution of ``conv_kernel``
-    taps, the file's ``conv_L_cache``; ``full_attention`` grouped-query
-    attention with a per-head QK-norm and RoPE) and then a feed-forward:
-    a dense SwiGLU in the model's first ``first_k_dense_replace`` layers
-    (the file's ``num_dense_layers``), the routed experts with no shared
-    one after them.  The rank holds the ``layers_here`` layers from
-    ``first_layer_here`` on, whole but for the experts.
-    ``tie_word_embeddings``: the head reads the embedding matrix, which
-    is then the one leaf of both (any model's).
-
-    qwen3_next's keys: a ``layer_types`` model too (``load_model_config``
-    derives the names from ``full_attention_interval``), whose third
-    operator ``linear_attention`` is the Gated DeltaNet of
-    ``linear_num_key_heads`` key and ``linear_num_value_heads`` value
-    heads, ``linear_key_head_dim`` and ``linear_value_head_dim`` wide,
-    behind a convolution of ``conv_kernel`` taps (the file's
-    ``linear_conv_kernel_dim``) and in chunks of ``chunk_size``; whose
-    attention heads are ``head_dim`` wide whatever the hidden width, turn
-    the leading ``partial_rotary_factor`` of it and carry an output gate
-    (``attn_output_gate``); and whose routers score by ``softmax`` with
-    no bias, beside a shared expert of
-    ``moe_shared_expert_intermediate_size`` (the file's
-    ``shared_expert_intermediate_size``) times a sigmoid gate
-    (``shared_expert_gate``).  The two gates are the family's modelling
-    code's, no published key: the loader sets them by ``model_type``.
-
-    smallthinker's keys: a ``layer_types`` model too (``load_model_config``
-    derives the names from ``sliding_window_layout``: ``sliding_attention``
-    where it is 1, else ``full_attention``), both kinds grouped-query
-    attention on heads of ``head_dim`` with no QK-norm (``qk_norm``
-    false) and the same leaves; a ``sliding_attention`` layer attends to
-    the last ``sliding_window`` keys (the file's ``sliding_window_size``,
-    whole ``attn_block``s); ``rope_kinds`` names the kinds of layer whose
-    q and k RoPE turns (from the file's ``rope_layout``: its
-    ``sliding_attention`` layers; lfm2's and qwen3_next's one kind of
-    attention is turned, the default); the router reads the layer's
-    input, before the attention sublayer (``router_before_attention``),
-    scores by ``softmax`` under no bias; the experts are relu-gated
-    (``mlp_hidden_act`` ``relu``: ReGLU) with no shared one.  The
-    router's place and the activation are the model's report's, no
-    published key: the loader sets them by ``model_type``.
-
-    KeyeVL2's keys (Keye-VL-2.0-30B-A3B's language model): a
-    ``layer_types`` model too, every layer ``sparse_attention``
-    (``load_model_config`` derives the names from ``sa_config``): lfm2's
-    QK-normed attention on heads of ``head_dim`` under DeepSeek-V3.2's
-    learned selection (``model.dsa_attention``): an indexer of
-    ``index_heads`` heads of ``index_head_dim`` and one key a position, the
-    ``index_topk`` best keys a query (``sa_config``'s ``indexer_num_heads``,
-    ``indexer_head_dim``, ``topk``; its two chunk sizes are the sizes of
-    the blocks the scores are made by), an alignment loss times
-    ``index_loss_coef``; qwen3_next's softmax router with no shared expert."""
-    hidden_size: int
-    intermediate_size: int
-    num_attention_heads: int
-    num_key_value_heads: int
-    num_experts: int
-    num_experts_per_tok: int
-    vocab_size: int
-    layers_here: int
-    rms_norm_eps: float = 1e-5
-    rope_theta: float = 10000.0
-    norm_topk_prob: bool = False
-    seq_len: int = 4096
-    micro_batch: int = 2
-    aux_loss_coef: float = 0.01
-    z_loss_coef: float = 0.001
-    lr: float = 4e-4
-    warmup_steps: int = 1           # lr rises linearly over these steps
-    adam_b1: float = 0.9
-    adam_b2: float = 0.95
-    adam_eps: float = 1e-8
-    weight_decay: float = 0.1
-    init_std: float = 0.02
-    embed_init_std: float | None = None     # the embedding's rows, where
-    #                                         they start wider than init_std
-    compute_dtype: str = "bfloat16"
-    attn_block: int = 1024
-    loss_block_rows: int = 1024
-    # DeepSeek-V3's keys (JoyAI-LLM-Flash); OLMoE's file has none of them
-    q_lora_rank: int = 0
-    kv_lora_rank: int = 0
-    qk_nope_head_dim: int = 0
-    qk_rope_head_dim: int = 0
-    v_head_dim: int = 0
-    first_k_dense_replace: int = 0
-    moe_intermediate_size: int = 0      # an expert's width, where the
-    #                                     dense one is intermediate_size
-    n_shared_experts: int = 0
-    scoring_func: str = "softmax"
-    topk_method: str = "greedy"
-    routed_scaling_factor: float = 1.0
-    num_nextn_predict_layers: int = 0
-    experts_here: int = 0
-    expert_share: int = 0
-    vocab_here: int = 0
-    mtp_loss_coef: float = 0.0
-    bias_update_gamma: float = 0.0
-    n_group: int = 1
-    topk_group: int = 1
-    mtp_here: int = -1
-    # nemotron_h's keys (Nemotron-3-Super)
-    hybrid_override_pattern: str = ""
-    first_layer_here: int = 0
-    heads_here: int = 0
-    mamba_num_heads: int = 0
-    mamba_head_dim: int = 0
-    mamba_heads_here: int = 0
-    n_groups: int = 1               # a mixer's B/C groups (n_group: routers')
-    ssm_state_size: int = 0
-    conv_kernel: int = 4
-    chunk_size: int = 128
-    time_step_min: float = 0.001
-    time_step_max: float = 0.1
-    time_step_floor: float = 1e-4
-    mlp_hidden_act: str = "silu"
-    moe_latent_size: int = 0
-    moe_shared_expert_intermediate_size: int = 0
-    # lfm2_moe's keys (LFM2-8B-A1B)
-    layer_types: tuple = ()
-    tie_word_embeddings: bool = False
-    # qwen3_next's keys (Qwen3-Next-80B-A3B)
-    head_dim: int = 0               # 0: hidden_size / heads
-    partial_rotary_factor: float = 1.0
-    linear_num_key_heads: int = 0
-    linear_num_value_heads: int = 0
-    linear_key_head_dim: int = 0
-    linear_value_head_dim: int = 0
-    attn_output_gate: bool = False
-    shared_expert_gate: bool = False
-    # smallthinker's keys (SmallThinker-21BA3B)
-    sliding_window: int = 0         # a sliding_attention layer's window
-    rope_kinds: tuple = ("full_attention", "sliding_attention")
-    qk_norm: bool = True            # a layer_types model's attention
-    router_before_attention: bool = False
-    # KeyeVL2's keys (Keye-VL-2.0-30B-A3B): its ``sa_config``
-    index_heads: int = 0            # the indexer's query heads
-    index_head_dim: int = 0
-    index_topk: int = 0             # the keys a query attends to
-    index_q_chunk: int = 512        # the score blocks' sizes: they change
-    index_kv_chunk: int = 512       # no number
-    index_loss_coef: float = 1.0
-
-    @property
-    def pattern_here(self) -> str:
-        """The letters of the layers held here ("" without a pattern): a
-        ``hybrid_override_pattern``'s own, or a ``layer_types`` model's
-        (``PATTERN_KINDS``)."""
-        first = self.first_layer_here
-        if self.layer_types:
-            return "".join(
-                OPERATOR_LETTERS[kind] if i < self.first_k_dense_replace
-                else OPERATOR_LETTERS[kind].upper()
-                for i, kind in enumerate(self.layer_types)
-            )[first:first + self.layers_here]
-        return self.hybrid_override_pattern[first:first + self.layers_here]
-
-    @property
-    def segments(self) -> tuple:
-        """The held pattern as runs of like layers, ``(unit, repeats,
-        first layer)`` each: a unit is one letter or two different ones
-        (``ME`` four times over, then ``M``, ``*``, ``E``), and a run of
-        more than one repeat is walked by one ``lax.scan``.  A
-        ``layer_types`` model's unit is one letter: its layer holds two
-        sublayers already."""
-        pattern, out, i = self.pattern_here, [], 0
-        while i < len(pattern):
-            best = (pattern[i], 1)
-            for width in ((1,) if self.layer_types else (1, 2)):
-                unit = pattern[i:i + width]
-                if len(set(unit)) != width:
-                    continue
-                n = 1
-                while pattern[i + n * width:i + (n + 1) * width] == unit:
-                    n += 1
-                if n > 1 and n * width > len(best[0]) * best[1]:
-                    best = (unit, n)
-            out.append(best + (i,))
-            i += len(best[0]) * best[1]
-        return tuple(out)
-
-    @property
-    def n_dense_here(self) -> int:
-        return min(self.first_k_dense_replace, self.layers_here)
-
-    @property
-    def n_sparse_here(self) -> int:
-        if self.pattern_here:
-            return sum(c in EXPERT_LETTERS for c in self.pattern_here)
-        return self.layers_here - self.n_dense_here
-
-    @property
-    def n_mtp_here(self) -> int:
-        return self.num_nextn_predict_layers if self.mtp_here < 0 \
-            else self.mtp_here
-
-    @property
-    def n_routers(self) -> int:
-        """Sparse layers in the walk, the next-next-token module's too."""
-        return self.n_sparse_here + self.n_mtp_here
-
-    @property
-    def n_heads_here(self) -> int:
-        return self.heads_here or self.num_attention_heads
-
-    @property
-    def n_kv_heads_here(self) -> int:
-        """The key-value heads the held query heads read."""
-        per_kv = self.num_attention_heads // self.num_key_value_heads
-        return max(1, self.n_heads_here // per_kv)
-
-    @property
-    def n_mamba_heads_here(self) -> int:
-        return self.mamba_heads_here or self.mamba_num_heads
-
-    @property
-    def n_groups_here(self) -> int:
-        """The B/C groups of the held Mamba heads (0 where the model has
-        no mixer)."""
-        return self.n_mamba_heads_here * self.n_groups \
-            // max(1, self.mamba_num_heads)
-
-    @property
-    def n_experts_here(self) -> int:
-        return self.experts_here or self.num_experts
-
-    @property
-    def first_expert_here(self) -> int:
-        return self.expert_share * self.n_experts_here
-
-    @property
-    def vocab_rows(self) -> int:
-        return self.vocab_here or self.vocab_size
-
-    @property
-    def expert_width(self) -> int:
-        return self.moe_intermediate_size or self.intermediate_size
-
-    @property
-    def head_width(self) -> int:
-        """An attention head's width: the file's ``head_dim``, else the
-        hidden width over the heads."""
-        return self.head_dim or self.hidden_size // self.num_attention_heads
-
-    @property
-    def rotary_width(self):
-        """The leading entries of a head that RoPE turns (None: all)."""
-        if self.partial_rotary_factor == 1.0:
-            return None
-        return int(self.head_width * self.partial_rotary_factor)
-
-    @property
-    def shared_width(self) -> int:
-        """The shared experts' width together (0: none)."""
-        return self.n_shared_experts * (
-            self.moe_shared_expert_intermediate_size or self.expert_width)
-
-    @property
-    def routes_to_held(self) -> bool:
-        """Whether a sparse layer is ``experts.moe_shared_local_block``
-        (a share of the experts, the router's ``scores`` reported), not
-        OLMoE's ``moe_sorted_block``."""
-        return bool(self.layer_types) or self.scoring_func == "sigmoid"
-
-    def __post_init__(self):
-        hybrid = bool(self.hybrid_override_pattern)
-        # a file's list; a tuple so that the configuration stays hashable
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        object.__setattr__(self, "rope_kinds", tuple(self.rope_kinds))
-        typed = bool(self.layer_types)
-        per_kv = self.num_attention_heads // max(1, self.num_key_value_heads)
-        if not (hybrid or typed) and self.num_key_value_heads \
-                != self.num_attention_heads:
-            raise NotImplementedError(
-                "num_key_value_heads: grouped-query attention is a "
-                "hybrid_override_pattern or layer_types model's; this "
-                "model's attention has a key-value head a query head")
-        if not (typed and self.head_dim) \
-                and self.hidden_size % self.num_attention_heads:
-            raise ValueError("hidden_size is not a multiple of the heads")
-        if typed and (hybrid or set(self.layer_types)
-                      - set(OPERATOR_LETTERS)):
-            raise NotImplementedError(
-                f"layer_types {sorted(set(self.layer_types))}: a layer's "
-                f"operator is one of {sorted(OPERATOR_LETTERS)}, and the "
-                "model has no hybrid_override_pattern beside them")
-        if typed and (self.heads_here or self.kv_lora_rank):
-            raise NotImplementedError(
-                f"heads_here {self.heads_here} / kv_lora_rank "
-                f"{self.kv_lora_rank}: a layer_types model holds its "
-                "operators whole (no head is split) and attends by "
-                "grouped key-value heads")
-        if self.head_dim and not (typed or self.kv_lora_rank) \
-                and self.head_dim * self.num_attention_heads \
-                != self.hidden_size:
-            raise NotImplementedError(
-                f"head_dim {self.head_dim}: only a layer_types model's "
-                "attention heads have a width that is not hidden_size / "
-                "heads (under kv_lora_rank the key names the rotary "
-                "part and is not read)")
-        if (self.attn_output_gate or self.shared_expert_gate
-                or self.partial_rotary_factor != 1.0) and not typed:
-            raise NotImplementedError(
-                f"attn_output_gate {self.attn_output_gate} / "
-                f"shared_expert_gate {self.shared_expert_gate} / "
-                f"partial_rotary_factor {self.partial_rotary_factor}: "
-                "only a layer_types model's attention and shared expert "
-                "are gated, and only its RoPE turns a part of the head")
-        if self.shared_expert_gate and not self.n_shared_experts:
-            raise ValueError("shared_expert_gate without a shared expert")
-        if "linear_attention" in self.layer_types and (
-                min(self.linear_num_key_heads, self.linear_key_head_dim,
-                    self.linear_value_head_dim) < 1
-                or self.linear_num_value_heads
-                % max(1, self.linear_num_key_heads)):
-            raise ValueError(
-                f"linear_num_value_heads {self.linear_num_value_heads}: "
-                "not whole groups of linear_num_key_heads "
-                f"{self.linear_num_key_heads} heads of a stated width")
-        if (hybrid or typed) and (
-                self.num_attention_heads % self.num_key_value_heads
-                or (self.n_heads_here % per_kv
-                    and per_kv % self.n_heads_here)):
-            raise NotImplementedError(
-                f"heads_here {self.n_heads_here}: the held query heads "
-                f"are neither whole key-value heads' ({per_kv} each) nor "
-                "a whole part of one's; a key-value head split across "
-                "chips is not run")
-        windowed = "sliding_attention" in self.layer_types
-        if windowed != bool(self.sliding_window) or (
-                windowed and self.sliding_window % self.attn_block):
-            raise NotImplementedError(
-                f"sliding_window {self.sliding_window}: a window is a "
-                "layer_types model's sliding_attention layers', and a whole "
-                f"number of attn_block {self.attn_block} positions")
-        sparse = "sparse_attention" in self.layer_types
-        if sparse != bool(self.index_topk) or (sparse and (
-                min(self.index_heads, self.index_head_dim) < 1
-                or self.index_head_dim % 2 or not self.qk_norm
-                or self.attn_output_gate or windowed
-                or self.partial_rotary_factor != 1.0)):
-            raise NotImplementedError(
-                f"index_topk {self.index_topk} (sa_config): a learned "
-                "selection is a layer_types model's sparse_attention "
-                "layers', with an indexer of index_heads heads of an even "
-                "index_head_dim, on QK-normed attention with RoPE over the "
-                "whole head, no output gate and no sliding window beside it")
-        if not typed and (not self.qk_norm or self.router_before_attention):
-            raise NotImplementedError(
-                f"qk_norm {self.qk_norm} / router_before_attention "
-                f"{self.router_before_attention}: only a layer_types "
-                "model's attention goes without a QK-norm, and only its "
-                "router reads the layer's input")
-        if (hybrid or typed) and (
-                set(self.pattern_here) - set("M*E" if hybrid else "calwsCALWS")
-                or len(self.pattern_here) != self.layers_here):
-            raise ValueError(
-                f"layers_here {self.layers_here} from first_layer_here "
-                f"{self.first_layer_here}: not layers of "
-                "hybrid_override_pattern's letters ['*', 'E', 'M'] or of "
-                "layer_types")
-        if "M" in self.pattern_here and (
-                self.n_mamba_heads_here * self.n_groups
-                % self.mamba_num_heads):
-            raise NotImplementedError(
-                f"mamba_heads_here {self.n_mamba_heads_here}: not whole "
-                f"B/C groups of {self.mamba_num_heads // self.n_groups} "
-                "heads; a group split across chips is not run")
-        if self.n_group != 1 or self.topk_group != 1:
-            raise NotImplementedError(
-                f"n_group {self.n_group} / topk_group {self.topk_group}: "
-                "the routers choose among one group of experts")
-        if (hybrid or typed) and self.n_mtp_here:
-            raise NotImplementedError(
-                f"mtp_here {self.n_mtp_here}: the next-n module of a "
-                "hybrid_override_pattern model (mtp_hybrid_override_"
-                "pattern) or of a layer_types model is not run; hold 0 "
-                "of them")
-        if (self.mlp_hidden_act == "relu2") != bool(self.moe_latent_size) \
-                or self.mlp_hidden_act not in ("relu2", "silu", "relu") \
-                or (self.mlp_hidden_act == "relu"
-                    and (not typed or self.first_k_dense_replace)):
-            raise NotImplementedError(
-                f"mlp_hidden_act {self.mlp_hidden_act} with moe_latent_size "
-                f"{self.moe_latent_size}: relu2 experts are run in a "
-                "latent, silu experts on the hidden width, relu-gated ones "
-                "in a layer_types model with no dense layer")
-        if self.n_mtp_here > 1:
-            raise NotImplementedError("more than one next-n module")
-        if (self.scoring_func, self.topk_method) not in (
-                ("softmax", "greedy"), ("sigmoid", "noaux_tc")):
-            raise NotImplementedError(
-                f"router {self.scoring_func} / {self.topk_method}")
-        if self.first_expert_here + self.n_experts_here > self.num_experts:
-            raise ValueError("the experts held here are not among the "
-                             "router's")
-
-
-def load_model_config(path: str, **overrides) -> ModelConfig:
-    """The configuration file of a public model: the keys of its
-    ``config.json`` at the top level, ``layers_here``, and a ``train``
-    group; keys this dataclass does not know (the file's prose) are
-    left alone.  A model this path cannot run raises."""
-    with open(path, encoding="utf-8") as f:
-        body = json.load(f)
-    hybrid = "hybrid_override_pattern" in body
-    keye = body.get("model_type") == "KeyeVL2"
-    sparse = body.get("sa_config")
-    if sparse and (not keye or hybrid or "kv_lora_rank" in body
-                   or "layer_types" in body or body.get("sliding_window")
-                   or body.get("use_sliding_window")):
-        raise NotImplementedError(
-            f"{path}: sa_config: a learned selection is a KeyeVL2 model's, "
-            "on grouped-query attention in every layer; sa_config beside a "
-            "sliding_window, or in a latent-attention (kv_lora_rank), "
-            "hybrid_override_pattern or other layer_types model is not run")
-    next_ = body.get("model_type") == "qwen3_next"
-    if next_:
-        for key, runs in (("mlp_only_layers", []), ("decoder_sparse_step", 1),
-                          ("use_sliding_window", False)):
-            if body.get(key, runs) != runs:
-                raise NotImplementedError(
-                    f"{path}: {key} {body[key]}: a qwen3_next model is run "
-                    "with every layer sparse and no attention window")
-        # the family's configuration class: every
-        # ``full_attention_interval``-th layer attends in full
-        every = body["full_attention_interval"]
-        body.setdefault("layer_types", [
-            "linear_attention" if (i + 1) % every else "full_attention"
-            for i in range(body["num_hidden_layers"])])
-    thinker = body.get("model_type") == "smallthinker"
-    if thinker:
-        windows, turned = body["sliding_window_layout"], body["rope_layout"]
-        if not body.get("moe_primary_router_apply_softmax"):
-            raise NotImplementedError(
-                f"{path}: moe_primary_router_apply_softmax false: a sigmoid "
-                "over the chosen logits is not run; the router scores by a "
-                "softmax")
-        if list(windows) != list(turned):
-            raise NotImplementedError(
-                f"{path}: rope_layout differs from sliding_window_layout: "
-                "RoPE goes by a layer's kind, so a window layer without it "
-                "or a full layer with it is a kind that is not run")
-        body.setdefault("layer_types", [
-            "sliding_attention" if on else "full_attention"
-            for on in windows])
-    elif "sliding_window_layout" in body or "sliding_window_size" in body \
-            or body.get("sliding_window") or "rope_layout" in body:
-        raise NotImplementedError(
-            f"{path}: sliding_window / sliding_window_layout / rope_layout: "
-            "an attention window and RoPE by layer are a smallthinker "
-            "model's; a window in a hybrid_override_pattern, latent-"
-            "attention or other layer_types model is not run")
-    if keye:
-        for key, runs in (("mlp_only_layers", []), ("decoder_sparse_step", 1)):
-            if body.get(key, runs) != runs:
-                raise NotImplementedError(
-                    f"{path}: {key} {body[key]}: a KeyeVL2 model is run "
-                    "with every layer sparse")
-        if not sparse or sparse.get("indexer_num_kv_heads") != 1:
-            raise NotImplementedError(
-                f"{path}: sa_config {sparse}: a KeyeVL2 model is run under "
-                "its learned selection, the indexer with one key a position "
-                "(indexer_num_kv_heads 1)")
-        body.setdefault("layer_types",
-                        ["sparse_attention"] * body["num_hidden_layers"])
-    scaling = body.get("rope_scaling")
-    if scaling:
-        # M-RoPE's three position components are equal on a text token, so
-        # on text ids ``default`` scaling with sections is plain RoPE
-        kinds = {scaling.get("rope_type", "default"),
-                 scaling.get("type", "default")}
-        section = scaling.get("mrope_section")
-        if kinds != {"default"} or set(scaling) - {
-                "rope_type", "type", "mrope_section"} or not section \
-                or 2 * sum(section) != body.get("head_dim"):
-            raise NotImplementedError(
-                f"{path}: rope_scaling {scaling}: only rope_type default "
-                "with an mrope_section that sums to half of head_dim is "
-                "run (plain RoPE on text ids); every other scaling of the "
-                "rotary frequencies is not")
-    typed = "layer_types" in body       # lfm2_moe: its file names no
-    #                                     activation, its code runs silu
-    act = body.get("mlp_hidden_act") if hybrid else body.get(
-        "hidden_act", "silu" if typed else None)
-    if act != ("relu2" if hybrid else "silu") or body.get("attention_bias") \
-            or body.get("clip_qkv") \
-            or body.get("moe_layer_freq", 1) != 1 \
-            or ("kv_lora_rank" in body and not body.get("rope_interleave")):
-        raise NotImplementedError(
-            f"{path}: the model path runs silu experts (relu2 in a "
-            "hybrid_override_pattern model), no biases, no clipping, "
-            "plain RoPE (on interleaved pairs under latent attention) "
-            "and every layer past the dense ones sparse")
-    if typed and body.get("conv_bias"):
-        raise NotImplementedError(
-            f"{path}: conv_bias: the gated short convolution is run "
-            "without a bias")
-    if typed and bool(body.get("use_expert_bias")) != (
-            body.get("scoring_func", "softmax") == "sigmoid"):
-        raise NotImplementedError(
-            f"{path}: use_expert_bias {body.get('use_expert_bias')} with "
-            f"scoring_func {body.get('scoring_func', 'softmax')}: a "
-            "layer_types model's routers choose by sigmoid scores under a "
-            "balancing bias (topk_method noaux_tc) or by softmax scores "
-            "under none")
-    if hybrid and (
-            body.get("mamba_hidden_act") != "silu"
-            or not body.get("use_conv_bias") or body.get("mamba_proj_bias")
-            or body.get("use_bias") or body.get("mlp_bias")
-            or body.get("moe_shared_expert_overlap")
-            or body.get("sliding_window")
-            or body.get("head_dim", 0) * body["num_attention_heads"]
-            != body["hidden_size"]
-            or body.get("expand", 0) * body["hidden_size"]
-            != body["mamba_num_heads"] * body["mamba_head_dim"]):
-        raise NotImplementedError(
-            f"{path}: a hybrid_override_pattern model is run with silu in "
-            "the mixer, a convolution bias and no other, no window, "
-            "head_dim = hidden_size / heads and expand x hidden_size = "
-            "mamba_num_heads x mamba_head_dim")
-    known = {f.name for f in dataclasses.fields(ModelConfig)}
-    merged = {**body, **body.get("train", {}), **overrides}
-    if "n_routed_experts" in merged:        # DeepSeek-V3's name for it
-        merged.setdefault("num_experts", merged["n_routed_experts"])
-    if "layer_norm_epsilon" in merged:      # nemotron_h's
-        merged.setdefault("rms_norm_eps", merged["layer_norm_epsilon"])
-    if typed:                               # lfm2_moe's, qwen3_next's
-        for theirs, ours in (("norm_eps", "rms_norm_eps"),
-                             ("num_dense_layers", "first_k_dense_replace"),
-                             ("conv_L_cache", "conv_kernel"),
-                             ("linear_conv_kernel_dim", "conv_kernel"),
-                             ("shared_expert_intermediate_size",
-                              "moe_shared_expert_intermediate_size")):
-            if theirs in merged:
-                merged.setdefault(ours, merged[theirs])
-    if thinker:
-        for theirs, ours in (("moe_num_primary_experts", "num_experts"),
-                             ("moe_num_active_primary_experts",
-                              "num_experts_per_tok"),
-                             ("moe_ffn_hidden_size", "moe_intermediate_size"),
-                             ("sliding_window_size", "sliding_window")):
-            merged.setdefault(ours, merged[theirs])
-        merged.setdefault("intermediate_size", merged["moe_intermediate_size"])
-        merged.setdefault("rope_kinds", sorted(
-            {t for t, on in zip(merged["layer_types"], turned) if on}))
-        merged.update(qk_norm=False, router_before_attention=True,
-                      mlp_hidden_act="relu")
-    if keye:
-        for theirs, ours in (("indexer_num_heads", "index_heads"),
-                             ("indexer_head_dim", "index_head_dim"),
-                             ("topk", "index_topk"),
-                             ("q_chunk_size", "index_q_chunk"),
-                             ("kv_chunk_size", "index_kv_chunk")):
-            if theirs in sparse:
-                merged.setdefault(ours, sparse[theirs])
-    if next_:       # its modelling code's, on which config.json is silent
-        merged.setdefault("attn_output_gate", True)
-        shared = bool(merged.get("moe_shared_expert_intermediate_size"))
-        merged.setdefault("n_shared_experts", int(shared))
-        merged.setdefault("shared_expert_gate", shared)
-    return ModelConfig(**{k: v for k, v in merged.items() if k in known})
-
-
-def attention_shapes(cfg: ModelConfig) -> dict:
-    d, nh = cfg.hidden_size, cfg.num_attention_heads
-    if not cfg.kv_lora_rank:
-        return {"ln1": (d,), "wq": (d, d), "wk": (d, d), "wv": (d, d),
-                "wo": (d, d), "q_norm": (d,), "k_norm": (d,)}
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    return {"ln1": (d,), "wq_a": (d, cfg.q_lora_rank),
-            "q_a_norm": (cfg.q_lora_rank,),
-            "wq_b": (cfg.q_lora_rank, nh * qk),
-            "wkv_a": (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-            "kv_a_norm": (cfg.kv_lora_rank,),
-            "wkv_b": (cfg.kv_lora_rank,
-                      nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-            "wo": (nh * cfg.v_head_dim, d)}
-
-
-def sparse_layer_shapes(cfg: ModelConfig) -> dict:
-    """One sparse layer's leaves: attention, the router over all the
-    experts, the experts held here, the shared expert if any."""
-    d, f, e = cfg.hidden_size, cfg.expert_width, cfg.n_experts_here
-    mlp = {"ln2": (d,), "router": (d, cfg.num_experts),
-           "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
-    if cfg.n_shared_experts:
-        fs = f * cfg.n_shared_experts
-        mlp.update(shared_gate=(d, fs), shared_up=(d, fs),
-                   shared_down=(fs, d))
-    return {**attention_shapes(cfg), **mlp}
+#: ``objective.sample_rows`` of each shard, stacked over the layers that report
+#: it: ``head_in`` (R, d) (``mtp_head_in``) and what each kind of
+#: sublayer's ``reports`` names and its docstring describes
+#: (``model.sample_axes``), a router's behind ``router_``, so that the
+#: float32 parts' precision can be read from one step alone; under
+#: ``tie_word_embeddings`` ``embed_probe_read`` (PROBE,), whether a probed
+#: entry of ``embed`` lies in a row the step's tokens read (the others'
+#: gradient is the head's alone)
 
 
 def pattern_layer_shapes(cfg: ModelConfig) -> dict:
-    """One layer's leaves by kind (``PATTERN_KINDS``'s) of a
-    ``hybrid_override_pattern`` model, on this rank's share of the heads
-    and of the experts.  ``mamba``: the pre-norm's gain, ``in_proj`` (d,
-    z + x + B + C + dt), the convolution's taps (kernel, x + B + C) and
-    bias, ``dt_bias``, ``A_log`` and ``D`` a head, the gated norm's gain,
-    ``out_proj``.  ``attn``: the gain, q and o over the held query
-    heads, k and v over the key-value heads they read.  ``moe``: the
-    gain, the router over all the experts, the latent's two projections,
-    the held experts' two matrices in the latent, the shared expert's two
-    on the hidden width.
-
-    A ``layer_types`` model's kinds are an operator's leaves and a
-    feed-forward's together.  ``conv_*``: the operator norm's gain,
-    ``in_proj`` (d, B | C | u), the taps (kernel, d), ``out_proj``;
-    ``attn_*``: the gain, q and o over the query heads, k and v over the
-    key-value heads, the per-head QK-norm's two gains (none where
-    ``qk_norm`` is false); ``swa_*`` (a ``sliding_attention`` layer): the
-    same leaves; ``*_dense``: the
-    feed-forward norm's gain and SwiGLU's three matrices; ``*_moe``: the
-    gain, the router over all the experts, the held experts' three, and
-    where the model has a shared expert its three and, where that is
-    gated, ``shared_w_g`` (d, 1).  ``gdn_*`` (qwen3_next's
-    ``linear_attention``): the gain, ``in_proj`` (d, q | k | v | z),
-    ``ba_proj`` (d, b | a: a column a value head each), the taps (kernel,
-    q | k | v), ``A_log`` and ``dt_bias`` a value head, the gated norm's
-    gain over a head, ``out_proj``.  Attention's heads are ``head_width``
-    wide and ``wq`` holds a gate beside every query head under
-    ``attn_output_gate``.  Only the operators ``layer_types`` names have
-    kinds."""
-    d, e = cfg.hidden_size, cfg.n_experts_here
-    if cfg.layer_types:
-        hd = cfg.head_width
-        q, kv = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
-        ff, f, fs = cfg.intermediate_size, cfg.expert_width, cfg.shared_width
-        key = cfg.linear_num_key_heads * cfg.linear_key_head_dim
-        val = cfg.linear_num_value_heads * cfg.linear_value_head_dim
-        ops = {"conv": {"ln1": (d,), "in_proj": (d, 3 * d),
-                        "conv_w": (cfg.conv_kernel, d), "out_proj": (d, d)},
-               "attn": {"ln1": (d,),
-                        "wq": (d, q * (2 if cfg.attn_output_gate else 1)),
-                        "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-                        **({"q_norm": (hd,), "k_norm": (hd,)}
-                           if cfg.qk_norm else {})},
-               "gdn": {"ln1": (d,), "in_proj": (d, 2 * key + 2 * val),
-                       "ba_proj": (d, 2 * cfg.linear_num_value_heads),
-                       "conv_w": (cfg.conv_kernel, 2 * key + val),
-                       "A_log": (cfg.linear_num_value_heads,),
-                       "dt_bias": (cfg.linear_num_value_heads,),
-                       "gate_norm": (cfg.linear_value_head_dim,),
-                       "out_proj": (val, d)}}
-        ops["swa"] = ops["attn"]    # a window layer holds what a full one does
-        # a sparse_attention layer: attention's leaves and the indexer's
-        # (its queries, its one key and that key's LayerNorm, its heads'
-        # weights)
-        index = cfg.index_heads * cfg.index_head_dim
-        ops["dsa"] = {**ops["attn"], "index_wq": (d, index),
-                      "index_wk": (d, cfg.index_head_dim),
-                      "index_k_norm": (cfg.index_head_dim,),
-                      "index_k_bias": (cfg.index_head_dim,),
-                      "index_ww": (d, cfg.index_heads)}
-        moe = {"ln2": (d,), "router": (d, cfg.num_experts),
-               "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
-        if fs:
-            moe.update(shared_gate=(d, fs), shared_up=(d, fs),
-                       shared_down=(fs, d))
-        if cfg.shared_expert_gate:
-            moe["shared_w_g"] = (d, 1)
-        ffns = {"dense": {"ln2": (d,), "gate": (d, ff), "up": (d, ff),
-                          "down": (ff, d)}, "moe": moe}
-        held = {PATTERN_KINDS[OPERATOR_LETTERS[t]].split("_")[0]
-                for t in cfg.layer_types}
-        return {f"{op}_{ffn}": {**ops[op], **ffns[ffn]}
-                for op in sorted(held) for ffn in ffns}
-    nh, g = cfg.n_mamba_heads_here, cfg.n_groups_here
-    inner, bc = nh * cfg.mamba_head_dim, 2 * g * cfg.ssm_state_size
-    hd = d // cfg.num_attention_heads
-    q, kv = cfg.n_heads_here * hd, cfg.n_kv_heads_here * hd
-    lat, f = cfg.moe_latent_size, cfg.expert_width
-    fs = cfg.moe_shared_expert_intermediate_size * cfg.n_shared_experts
-    return {
-        "mamba": {"norm": (d,), "in_proj": (d, 2 * inner + bc + nh),
-                  "conv_w": (cfg.conv_kernel, inner + bc),
-                  "conv_b": (inner + bc,), "dt_bias": (nh,), "A_log": (nh,),
-                  "D": (nh,), "gate_norm": (inner,), "out_proj": (inner, d)},
-        "attn": {"ln1": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
-                 "wo": (q, d)},
-        "moe": {"ln2": (d,), "router": (d, cfg.num_experts),
-                "lat_down": (d, lat), "lat_up": (lat, d),
-                "up": (e, lat, f), "down": (e, f, lat),
-                "shared_up": (d, fs), "shared_down": (fs, d)}}
+    """One layer's leaves by kind (``model.layer_kinds``), on this rank's
+    share of the heads and of the experts."""
+    return {name: kind.shapes(cfg) for name, kind in layer_kinds(cfg).items()}
 
 
 def model_param_shapes(cfg: ModelConfig) -> dict:
@@ -838,51 +76,41 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
     ``head`` (absent under ``tie_word_embeddings``: the head reads
     ``embed``).  Under a ``hybrid_override_pattern`` or ``layer_types``
     ``layers`` holds a group a run of like layers (``cfg.segments``),
-    ``l<first layer>``, and in it a group a letter of the run's unit
-    (``PATTERN_KINDS``) whose leaves are stacked over the run's repeats."""
-    d, ff, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_rows
+    ``l<first layer>``, and in it a group a letter of the run's unit (its
+    kind's name) whose leaves are stacked over the run's repeats."""
+    d, v = cfg.hidden_size, cfg.vocab_rows
     stack = lambda n, shapes: {k: (n,) + s for k, s in shapes.items()}
     tree = {"embed": (v, d)}
     last = {"final_norm": (d,)} if cfg.tie_word_embeddings \
         else {"final_norm": (d,), "head": (d, v)}
+    kinds = pattern_layer_shapes(cfg)
     if cfg.pattern_here:
-        kinds = pattern_layer_shapes(cfg)
+        group = {c: kind.name for c, kind in kind_of_letter(cfg).items()}
         tree["layers"] = {
-            f"l{first}": {PATTERN_KINDS[c]: stack(n, kinds[PATTERN_KINDS[c]])
-                          for c in unit}
+            f"l{first}": {group[c]: stack(n, kinds[group[c]]) for c in unit}
             for unit, n, first in cfg.segments}
         return {**tree, **last}
     if cfg.n_dense_here:
-        tree["dense"] = stack(cfg.n_dense_here, {
-            **attention_shapes(cfg), "ln2": (d,), "gate": (d, ff),
-            "up": (d, ff), "down": (ff, d)})
-    tree["layers"] = stack(cfg.n_sparse_here, sparse_layer_shapes(cfg))
+        tree["dense"] = stack(cfg.n_dense_here, kinds["dense"])
+    tree["layers"] = stack(cfg.n_sparse_here, kinds["layers"])
     if cfg.n_mtp_here:
         tree["mtp"] = {"enorm": (d,), "hnorm": (d,), "proj": (2 * d, d),
-                       **sparse_layer_shapes(cfg), "norm": (d,)}
+                       **kinds["layers"], "norm": (d,)}
     return {**tree, **last}
-
-
-def is_gain(name: str) -> bool:
-    """A norm's gain: starts at one, and is not decayed."""
-    return name.rsplit(".", 1)[-1] in GAINS
 
 
 def is_decayed(name: str) -> bool:
     """Whether AdamW decays the leaf: every matrix, and no gain, bias or
-    per-head scalar of a mixer (``UNDECAYED``)."""
-    return name.rsplit(".", 1)[-1] not in UNDECAYED
+    per-head scalar (``GAINS``; a sublayer's ``undecayed``)."""
+    last = name.rsplit(".", 1)[-1]
+    return last not in GAINS and last not in UNDECAYED
 
 
-def leaf_names(cfg: ModelConfig = None) -> list:
+def leaf_names(cfg: ModelConfig) -> list:
     """(name, path) of every trained leaf in a fixed order.  A sparse
     layer's leaves go by their own names, a dense layer's and the
     module's by ``dense.<leaf>`` and ``mtp.<leaf>``, a pattern's by
-    ``l<first layer>.<kind>.<leaf>``; without ``cfg``, OLMoE's."""
-    if cfg is None:
-        return [("embed", ("embed",))] + [
-            (k, ("layers", k)) for k in LAYER_LEAVES] + [
-            ("final_norm", ("final_norm",)), ("head", ("head",))]
+    ``l<first layer>.<kind>.<leaf>``."""
     out = []
 
     def walk(sub, path):
@@ -918,325 +146,37 @@ def probe_positions(name: str, size: int) -> np.ndarray:
     return np.sort(rng.integers(0, size, PROBE)).astype(np.int64)
 
 
-def sample_rows(rows: int) -> np.ndarray:
-    """The ``SAMPLE_ROWS`` rows of a shard of ``rows`` token rows that a
-    step reports activations at: evenly spaced, the last row among
-    them."""
-    n = min(SAMPLE_ROWS, rows)
-    return (np.arange(1, n + 1) * rows) // n - 1
+def _embed_start(key, shape, cfg):
+    """The embedding's rows, normal(0, ``embed_init_std``) where the file
+    gives one: at 0.02 a token's own row is a seventh of what attention's
+    mean of values adds to every position alike, and each layer's router
+    then sends nearly every token to the same eight experts."""
+    std = cfg.init_std if cfg.embed_init_std is None else cfg.embed_init_std
+    return std * jax.random.normal(key, shape, jnp.float32)
 
 
 def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
     """Float32 master parameters drawn on the default device from
-    ``seed``: normal(0, ``init_std``) matrices (the embedding's rows
-    normal(0, ``embed_init_std``) where the file gives one: at 0.02 a
-    token's own row is a seventh of what attention's mean of values adds
-    to every position alike, and each layer's router then sends nearly
-    every token to the same eight experts), gains of one (and a Gated
-    DeltaNet operator's ``dt_bias``; its ``A_log`` and taps start as a
-    mixer's).  A Mamba-2
-    mixer's other leaves as its authors start them (arXiv:2405.21060's
-    code): ``D`` one; ``A_log`` the logarithm of a uniform draw from 1
-    to 16; ``dt_bias`` the inverse softplus of a step drawn
-    log-uniformly between ``time_step_min`` and ``time_step_max`` (at
-    least ``time_step_floor``); the convolution's taps and bias uniform
-    within 1 / sqrt(``conv_kernel``), a depthwise convolution's usual
-    start."""
+    ``seed``, each leaf from a key folded from its name: a leaf AdamW does
+    not decay (``is_decayed``) at one, a matrix as normal(0, ``init_std``),
+    and what a sublayer's ``starts`` names as that says
+    (``model.leaf_starts``)."""
     key = jax.random.PRNGKey(seed)
+    starts = {**leaf_starts(cfg), "embed": _embed_start}
 
     def draw(name, shape):
-        last = name.rsplit(".", 1)[-1]
-        if is_gain(name) or last == "D" or (
-                last == "dt_bias" and cfg.layer_types):
-            return jnp.ones(shape, jnp.float32)
-        if last == "index_k_bias":
-            return jnp.zeros(shape, jnp.float32)
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-        if last == "A_log":
-            return jnp.log(jax.random.uniform(k, shape, jnp.float32, 1., 16.))
-        if last == "dt_bias":
-            step = jnp.maximum(cfg.time_step_floor, jnp.exp(
-                jax.random.uniform(k, shape, jnp.float32,
-                                   np.log(cfg.time_step_min),
-                                   np.log(cfg.time_step_max))))
-            return step + jnp.log(-jnp.expm1(-step))
-        if last in ("conv_w", "conv_b"):
-            bound = cfg.conv_kernel ** -0.5
-            return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
-        std = cfg.init_std
-        if name == "embed" and cfg.embed_init_std is not None:
-            std = cfg.embed_init_std
-        return std * jax.random.normal(k, shape, jnp.float32)
+        start = starts.get(name.rsplit(".", 1)[-1])
+        if start is not None:
+            return start(k, shape, cfg)
+        if not is_decayed(name):
+            return jnp.ones(shape, jnp.float32)
+        return cfg.init_std * jax.random.normal(k, shape, jnp.float32)
 
     shapes, tree = model_param_shapes(cfg), {}
     for name, path in leaf_names(cfg):
         _set_leaf(tree, path, draw(name, _leaf(shapes, path)))
     return tree
-
-
-def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
-    """Summed cross-entropy of ``softmax(h @ w)`` against ``labels``, by
-    blocks of ``block_rows`` rows so that no (T, V) array is ever held.
-    The forward pass also makes the two gradients (``softmax - onehot``
-    is at hand in each block), so the backward pass only scales them:
-    the head's logits are computed once a step, not twice.  Returns
-    (the sum over rows, per row (logsumexp, the label's logit))."""
-    t, d = h.shape
-    nblk = t // block_rows
-    if nblk * block_rows != t:
-        raise ValueError(f"{t} rows are not whole blocks of {block_rows}")
-
-    def run(h, w, labels):
-        def block(carry, xs):
-            total, dw = carry
-            hb, lb = xs
-            logits = matmul(hb, w, compute_dtype)            # (rows, V) f32
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
-            dlogits = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
-                lb, logits.shape[-1], dtype=jnp.float32)
-            dh = matmul(dlogits, w.T, compute_dtype)
-            dw = dw + matmul(hb.T, dlogits, compute_dtype, weight=False)
-            return (total + jnp.sum(lse - picked), dw), (
-                dh, jnp.stack([lse, picked], axis=-1))
-
-        vma = tuple(jax.typeof(h).vma | jax.typeof(labels).vma)
-        zero = (jnp.zeros((), jnp.float32), jnp.zeros(w.shape, jnp.float32))
-        if vma:         # the scan's carry varies as its inputs do
-            zero = jax.lax.pcast(zero, vma, to="varying")
-        (total, dw), (dh, rows) = jax.lax.scan(
-            block, zero, (h.reshape(nblk, block_rows, d),
-                          labels.reshape(nblk, block_rows)))
-        return total, rows.reshape(t, 2), dh.reshape(t, d), dw
-
-    @jax.custom_vjp
-    def ce(h, w):
-        total, rows, _, _ = run(h, w, labels)
-        return total, rows
-
-    def fwd(h, w):
-        total, rows, dh, dw = run(h, w, labels)
-        return (total, rows), (dh, dw)
-
-    def bwd(res, ct):
-        dh, dw = res
-        return ct[0] * dh, ct[0] * dw
-
-    ce.defvjp(fwd, bwd)
-    return ce(h, w)
-
-
-def layer_checkpoint_policy():
-    """What a walked layer's ``jax.checkpoint`` keeps for its backward
-    pass: the results an expert block names (``experts.CHECKPOINT_KEEPS``),
-    causal attention's forward results (``model.CHECKPOINT_KEEPS``: o and
-    the logsumexp) and nothing else, so a layer that names nothing is
-    recomputed whole."""
-    return jax.checkpoint_policies.save_only_these_names(
-        *experts.CHECKPOINT_KEEPS, *model.CHECKPOINT_KEEPS)
-
-
-def _walk_layers(run, stacked, x, bias, n: int):
-    """``n`` like layers in turn: ``run(layer, x, bias row) -> (x,
-    out)``; returns (x, the outs stacked).  More than one is a
-    ``lax.scan`` over the stacked leaves, so the layer is traced and
-    compiled once however many there are."""
-    if n == 1:
-        x, out = run(jax.tree.map(lambda a: a[0], stacked), x,
-                     None if bias is None else bias[0])
-        return x, jax.tree.map(lambda a: a[None], out)
-    return jax.lax.scan(
-        lambda x, xs: run(xs[0], x, xs[1]), x, (stacked, bias))
-
-
-def _walk_pattern(run_of, layers, x, bias, cfg: ModelConfig):
-    """The held layers of a ``hybrid_override_pattern`` or ``layer_types``
-    model in turn, a run of like layers at a time (``cfg.segments``;
-    ``layers`` holds a group a run): a run's unit is called once, or
-    scanned over its repeats (``_walk_layers``), each of its layers
-    through ``run_of(kind)``, ``kind`` its ``layer_types`` name
-    (``full_attention`` for a pattern's letter, which has no other).  ``bias`` (the held expert layers, E) gives each
-    layer with a router its row (None: the routers choose under none).
-    Returns (x, what the layers' ``run``
-    gave: the routers' statistics and chosen experts and the sampled
-    rows, each stacked in the layers' order over the layers that have
-    it; a unit of two letters has no key in both)."""
-    stats, chosen, sample, done = {}, [], {}, 0   # done: routers walked
-    for unit, n, first in cfg.segments:
-        def unit_run(group, x, bias_row, unit=unit):
-            out = {}
-            for letter in unit:
-                x, out[letter] = run_of(
-                    LETTER_OPERATORS.get(letter.lower(), "full_attention"))(
-                    group[PATTERN_KINDS[letter]], x,
-                    bias_row if letter in EXPERT_LETTERS else None)
-            return x, out
-
-        rows = None
-        if bias is not None and set(unit) & set(EXPERT_LETTERS):
-            rows, done = bias[done:done + n], done + n
-        x, out = _walk_layers(unit_run, layers[f"l{first}"], x, rows, n)
-        for letter in unit:
-            st, experts, seen = out[letter]
-            for into, part in ((stats, st), (sample, seen)):
-                for k, v in part.items():
-                    into.setdefault(k, []).append(v)
-            if experts is not None:
-                chosen.append(experts)
-    with jax.named_scope("otpu_stats"):
-        cat = lambda of: {k: jnp.concatenate(v) for k, v in of.items()}
-        return x, (cat(stats), jnp.concatenate(chosen), cat(sample))
-
-
-def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
-               n_global: int, axes: tuple = (), bias=None):
-    """The training loss of one micro-batch shard and what a step
-    reports of it.  ``n_global`` is the tokens of the whole batch and
-    ``axes`` the mesh axes it is sharded over: sums cross them by
-    ``psum``, so every shard returns the whole batch's loss.  ``bias``
-    holds the routers' balancing biases where they choose under one
-    (``layers`` (L, E) and ``mtp`` (1, E)); nothing is differentiated
-    with respect to it.  Where the model has a next-next-token module,
-    ``labels`` is one position longer than ``tokens``: ``labels[:, i]``
-    follows ``tokens[:, i]`` and ``labels[:, i + 1]`` follows that."""
-    psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
-    b, s = tokens.shape
-    at = sample_rows(b * s)
-    bias = bias or {}
-
-    @functools.cache    # one function a kind, so that JAX traces it once
-    def run_of(kind: str = "full_attention"):
-        """A layer's run, ``kind`` its ``layer_types`` name: static,
-        because two kinds of attention layer hold the same leaves."""
-        def run(layer, x, bias_row):
-            x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
-                                        bias=bias_row, kind=kind, at=at)
-            experts = seen.pop("experts", None)
-            with jax.named_scope("otpu_stats"):
-                # a router's rows at the sampled ones; of a mixer's scan,
-                # or a short convolution's input, the sequences whole
-                # (``_seq``) and its result at the sampled; q and k around
-                # their norm and RoPE at the sampled
-                out = (jax.tree.map(psum, st), experts, {
-                    k if k.startswith(OPERATOR_SAMPLES) else "router_" + k:
-                    v if k.endswith(("_seq", "_at")) else v[at]
-                    for k, v in seen.items()})
-            return x, out
-
-        if cfg.layers_here + cfg.n_mtp_here > 1:
-            # a layer's activations are recomputed in its backward pass,
-            # so that one layer's are held at a time and not every
-            # layer's; with one layer there is nothing to save.  Kept from
-            # the forward pass are only an expert block's named routing
-            # results (``experts.CHECKPOINT_KEEPS``) and causal
-            # attention's o and logsumexp (``model.CHECKPOINT_KEEPS``)
-            return jax.checkpoint(run, policy=layer_checkpoint_policy())
-        return run
-
-    run = run_of()
-    with jax.named_scope("otpu_embed"):
-        x = params["embed"][tokens]                          # (b, s, d) f32
-    with jax.named_scope("otpu_layers"):
-        if cfg.pattern_here:
-            # a layer's kind is static: two kinds of attention layer hold
-            # the same leaves, and nothing in them says which one this is
-            x, (st, chosen, sample) = _walk_pattern(
-                run_of, params["layers"], x, bias.get("layers"), cfg)
-        else:
-            if cfg.n_dense_here:
-                x, _ = _walk_layers(run, params["dense"], x, None,
-                                    cfg.n_dense_here)
-            x, (st, chosen, sample) = _walk_layers(
-                run, params["layers"], x, bias.get("layers"),
-                cfg.n_sparse_here)
-    head_rows = min(cfg.loss_block_rows, b * s)
-    # a tied head reads the embedding matrix itself: one leaf, whose
-    # gradient is the sum of the gather's and the cross-entropy's
-    head = params["embed"].T if cfg.tie_word_embeddings else params["head"]
-    with jax.named_scope("otpu_head"):
-        h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
-        ce_sum, rows = head_cross_entropy(
-            h.reshape(b * s, -1), head,
-            labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
-    routed = cfg.n_sparse_here * n_global   # rows of all routers' logits
-    with jax.named_scope("otpu_loss"):
-        ce = psum(ce_sum) / n_global
-        lb = z = jnp.zeros((), jnp.float32)
-        if "prob_sum" in st:
-            # HF's load_balancing_loss_func: every layer's rows in one
-            # mean
-            slots, prob_sum = (jnp.sum(st["slots"], 0),
-                               jnp.sum(st["prob_sum"], 0))
-            lb = cfg.num_experts * jnp.sum((slots / routed)
-                                           * (prob_sum / routed))
-            if "z_sum" in st:
-                z = jnp.sum(st["z_sum"], 0) / routed
-        lb, z = cfg.aux_loss_coef * lb, cfg.z_loss_coef * z
-        total = ce + lb + z
-        # a learned selection's alignment loss, every layer's rows in one
-        # mean a token: its gradient reaches the indexers' leaves alone
-        index = None
-        if "index_kl_sum" in st:
-            index = cfg.index_loss_coef * jnp.sum(st["index_kl_sum"]) \
-                / n_global
-            total = total + index
-    losses, loads = [ce, lb, z], st["slots"]
-    with jax.named_scope("otpu_stats"):
-        sample["head_in"] = h.reshape(b * s, -1)[at]
-    aux = {}
-    if cfg.n_mtp_here:
-        # DeepSeek-V3's multi-token prediction, depth one: the last
-        # layer's output (before the final norm) joined with the next
-        # token's embedding, one more sparse layer, the same embedding
-        # and head, a cross-entropy against the token after the next
-        mtp = params["mtp"]
-        with jax.named_scope("otpu_mtp"):
-            nxt = rmsnorm_gain(params["embed"][labels[:, :s]], mtp["enorm"],
-                               cfg.rms_norm_eps)
-            prev = rmsnorm_gain(x, mtp["hnorm"], cfg.rms_norm_eps)
-            joined = jnp.concatenate([nxt, prev], -1).reshape(b * s, -1)
-            x2 = matmul(joined, mtp["proj"], cfg.compute_dtype
-                        ).reshape(b, s, -1)
-            with jax.named_scope("otpu_layers"):
-                x2, (st2, chosen2, sample2) = _walk_layers(
-                    run, jax.tree.map(lambda a: a[None], {
-                        k: v for k, v in mtp.items()
-                        if k not in ("enorm", "hnorm", "proj", "norm")}),
-                    x2, bias.get("mtp"), 1)
-            with jax.named_scope("otpu_head"):
-                h2 = rmsnorm_gain(x2, mtp["norm"], cfg.rms_norm_eps)
-                ce2_sum, aux["mtp_rows"] = head_cross_entropy(
-                    h2.reshape(b * s, -1), head,
-                    labels[:, 1:].reshape(b * s), head_rows,
-                    cfg.compute_dtype)
-        with jax.named_scope("otpu_loss"):
-            losses.append(cfg.mtp_loss_coef * psum(ce2_sum) / n_global)
-            total = total + losses[-1]
-        with jax.named_scope("otpu_stats"):
-            loads = jnp.concatenate([loads, st2["slots"]])
-            chosen = jnp.concatenate([chosen, chosen2])
-            sample = {**{k: jnp.concatenate([sample[k], sample2[k]])
-                         for k in sample2},
-                      "head_in": sample["head_in"],
-                      "mtp_head_in": h2.reshape(b * s, -1)[at]}
-    if cfg.n_experts_here < cfg.num_experts:
-        first = cfg.first_expert_here
-        chunk = experts.chunk_rows(tokens.size, cfg.num_experts_per_tok,
-                                   cfg.n_experts_here, cfg.num_experts)
-        with jax.named_scope("otpu_stats"):
-            held = jnp.sum(loads[:, first:first + cfg.n_experts_here],
-                           axis=1)
-            aux["local_slots"] = jnp.sum(held)
-            # what the held experts' loops walked: a layer's held slots
-            # in whole chunks (``experts.local_expert_ffn``)
-            aux["chunk_rows"] = jnp.sum(
-                (held.astype(jnp.int32) + chunk - 1) // chunk * chunk)
-    if index is not None:
-        losses.append(index)
-    with jax.named_scope("otpu_stats"):
-        losses = jnp.stack([total] + losses)
-    return total, {"losses": losses, "loads": loads, "rows": rows,
-                   "experts": chosen, "sample": sample, **aux}
 
 
 def adamw(cfg: ModelConfig, name: str, p, g, m, v, t):
@@ -1343,7 +283,7 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
             # batch's slots is chosen a little less readily, one that
             # took fewer a little more; a sign rule, not AdamW's
             with jax.named_scope("otpu_bias_update"):
-                n = cfg.n_sparse_here       # the module's row is the last
+                n = cfg.n_sparse_here      # the module's row is the last
                 rows = {"layers": aux["loads"][:n], "mtp": aux["loads"][n:]}
                 bias = {k: bias_update(cfg, b, rows[k])
                         for k, b in bias.items()}
@@ -1352,49 +292,12 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
     rep = P()
     batch = P("dp", None)
     rows = P(None, "dp", None)
-    sample = {"router_" + k: P(None, "dp") if k == "lse" else rows
-              for k in (("in", "logits", "scores", "weights")
-                        if cfg.routes_to_held
-                        else ("in", "logits", "lse", "weights"))}
+    # what the layers report, stacked over them: the token rows over dp
+    sample = {k: P(None, "dp", *(None,) * axes)
+              for k, axes in sample_axes(cfg).items()}
     aux_specs = {"losses": rep, "loads": rep, "rows": batch,
                  "experts": rows, "grad_sq": rep, "grad_probe": rep,
                  "param_probe": rep, "sample": {**sample, "head_in": batch}}
-    if "M" in cfg.pattern_here:
-        aux_specs["sample"].update(
-            {"ssm_" + k: rows for k in ("x_seq", "b_seq", "c_seq", "y")},
-            ssm_dt_seq=P(None, "dp"))
-    if cfg.layer_types:
-        held = set(cfg.pattern_here.lower())
-        # an attention layer of a kind RoPE turns reports q and k around
-        # it; every one of a model without a QK-norm
-        turned = ({OPERATOR_LETTERS[k] for k in cfg.rope_kinds} | {"s"}) \
-            & held if cfg.qk_norm else {"a", "w"} & held
-        aux_specs["sample"].update(
-            {k: rows for on, keys in (
-                ("c" in held, ("conv_bcu_seq", "conv_y")),
-                (turned, ("attn_qk_in", "attn_qk")),
-                ("l" in held, ("gdn_q_seq", "gdn_k_seq", "gdn_v_seq",
-                               "gdn_o")),
-                ("w" in held, ("attn_win_q", "attn_win_k_seq",
-                               "attn_win_v_seq", "attn_win_o")))
-             if on for k in keys})
-        if "l" in held:
-            aux_specs["sample"].update(gdn_g_seq=P(None, "dp"),
-                                       gdn_beta_seq=P(None, "dp"))
-        if "a" in held and cfg.attn_output_gate:
-            aux_specs["sample"].update(attn_og_in=rows, attn_og=rows)
-        if "s" in held:
-            aux_specs["sample"].update(
-                {"dsa_" + k: rows for k in (
-                    "ki_seq", "k_seq", "v_seq", "kall_seq", "qi_at", "w_at",
-                    "index_at", "q_at", "lse_at", "o_at")},
-                dsa_kl_at=P(None, "dp"),
-                dsa_selection_seq=P(None, "dp", None, None))
-    if cfg.shared_expert_gate:
-        aux_specs["sample"]["router_shared_gate"] = rows
-    if cfg.router_before_attention:
-        aux_specs["sample"].update(router_expert_in=rows,
-                                   router_expert_out=rows)
     if cfg.tie_word_embeddings:
         aux_specs["embed_probe_read"] = rep
     if cfg.n_mtp_here:

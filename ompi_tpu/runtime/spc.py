@@ -138,17 +138,17 @@ _COUNTERS = (
     # over the first says which share of a run's engaged the kernel
     "moe_gmm_built", "moe_gmm_kernel_built",
     # the chunked delta rule's passes made while steps were traced
-    # (parallel/model.gated_delta_chunked: the XLA form's forward, or the
+    # (parallel/gdn.gated_delta_chunked: the XLA form's forward, or the
     # kernel path's forward and backward rules), and those of them made
     # on the Pallas kernels (ops/gated_delta): the second over the first
     "gdn_rule_built", "gdn_rule_kernel_built",
     # the DeltaNet convolution's passes made while steps were traced
-    # (parallel/model.gated_delta_net: the XLA lines' forward, or the
+    # (parallel/gdn.gated_delta_net: the XLA lines' forward, or the
     # kernel path's forward and backward rules), and those of them made
     # on the Pallas kernels (ops/causal_conv): the second over the first
     "gdn_conv_built", "gdn_conv_kernel_built",
     # the causal attention passes made while steps were traced
-    # (parallel/model.causal_flash_attention's forward and backward
+    # (parallel/causal.causal_flash_attention's forward and backward
     # rules), and those of them whose k and v came with fewer heads than q
     # and went to the flash kernels, or their twins, unrepeated: the
     # second over the first is 1 for a grouped-query model, 0 for the rest
@@ -157,7 +157,7 @@ _COUNTERS = (
     # the passes walk, and those full causal passes of their lengths
     # would: walked over causal is what the windows spare
     "attn_window_built", "attn_pairs_walked", "attn_pairs_causal",
-    # learned sparse attention (parallel/model.selected_flash_attention):
+    # learned sparse attention (parallel/causal.selected_flash_attention):
     # the attention passes made under a selection while steps were traced,
     # the (query, key) pairs they attend to and those full causal passes
     # of their lengths would, both from the shapes: selected over causal

@@ -221,14 +221,14 @@ def cases(mesh1d, mesh2d):
     # entry, which where Mosaic compiles is one call of
     # ``flash_causal_forward`` (its own cases are below)
     def olmoe_attention():
-        from ompi_tpu.parallel import model
+        from ompi_tpu.parallel import causal
 
         qkv = _sds((2, 16, 4096, 128), bf16, one, P())
-        return jax.jit(lambda q, k, v: model._causal_fwd_blocks(
+        return jax.jit(lambda q, k, v: causal._causal_fwd_blocks(
             q, k, v, 1024, False)), (qkv, qkv, qkv)
 
     case("olmoe_causal_attention_4k", olmoe_attention)
-    # attention's backward (``parallel/model._causal_bwd``): the fused
+    # attention's backward (``causal._causal_bwd``): the fused
     # block-pair kernel at the two cells' shapes (the arrays come whole
     # and a scalar-prefetch operand picks the pair, so the plain and the
     # diagonal pair are one compiled kernel), and the two walks over the
@@ -255,15 +255,16 @@ def cases(mesh1d, mesh2d):
                 "block": 1024, "interpret": False, **window}
 
     def attn_backward_walk(b, h, s, d, hv, n_kv=None, window=None):
-        from ompi_tpu.parallel import model
+        from ompi_tpu.parallel import causal
 
         q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv, n_kv)
         o = _sds((b, h, s, hv), jnp.float32, one, P())  # and its cotangent
-        return jax.jit(lambda q, k, v, o, lse, do: model._causal_bwd(
+        bwd = causal._causal_bwd
+        return jax.jit(lambda q, k, v, o, lse, do: bwd(
             1024, False, window, (q, k, v, o, lse), do)), (q, k, v, o, lse,
                                                           o)
 
-    # attention's forward (``model._causal_fwd_blocks`` where Mosaic
+    # attention's forward (``causal._causal_fwd_blocks`` where Mosaic
     # compiles): one call a layer, q, k and v whole, the blocks through
     # the index maps, the softmax state in VMEM scratch
     def flash_causal_forward(b, h, s, d, hv, n_kv=None, **window):
@@ -363,17 +364,17 @@ def cases(mesh1d, mesh2d):
          lambda: gmm_trip_forms(16384, 10, 32, 512, 2048, 512))
     case("gmm_smallthinker",
          lambda: gmm_trip_forms(16384, 6, 16, 64, 2560, 768))
-    # the chunked delta rule (``model._kernel_rule``: ``ops/gated_delta``'s
+    # the chunked delta rule (``gdn._kernel_rule``: ``ops/gated_delta``'s
     # two kernels) as the Qwen3-Next cell's step builds it: 16 key heads,
     # 32 value heads, 128 / 128, 16,384 positions in chunks of 64, q, k
     # and v read from the convolution's one array and normed in the
     # kernels, every product float32 at the highest precision
     def gdn_rule(backward):
-        from ompi_tpu.parallel import model
+        from ompi_tpu.parallel import gdn
 
         rep = lambda *s: _sds(s, f32, one, P())
-        rule = lambda qkv, g, beta: model._kernel_rule(
-            (qkv,), g, beta, 64, 16, (model.L2NORM_EPS, 128 ** -0.5))
+        rule = lambda qkv, g, beta: gdn._kernel_rule(
+            (qkv,), g, beta, 64, 16, (gdn.L2NORM_EPS, 128 ** -0.5))
         if backward:
             rule = jax.grad(lambda *a, rule=rule: jnp.sum(rule(*a)),
                             (0, 1, 2))
@@ -382,16 +383,16 @@ def cases(mesh1d, mesh2d):
 
     case("qwen3next_gdn_rule_forward", lambda: gdn_rule(False))
     case("qwen3next_gdn_rule_backward", lambda: gdn_rule(True))
-    # the DeltaNet convolution and its silu (``model._kernel_conv``:
+    # the DeltaNet convolution and its silu (``gdn._kernel_conv``:
     # ``ops/causal_conv``'s two kernels) at the same cell's shape: 4 taps
     # over the (1, 16384, 8192) float32 [q | k | v]
     def gdn_conv(backward):
-        from ompi_tpu.parallel import model
+        from ompi_tpu.parallel import gdn
 
         rep = lambda *s: _sds(s, f32, one, P())
-        conv = model._kernel_conv
+        conv = gdn._kernel_conv
         if backward:
-            conv = jax.grad(lambda *a: jnp.sum(model._kernel_conv(*a)),
+            conv = jax.grad(lambda *a: jnp.sum(gdn._kernel_conv(*a)),
                             (0, 1))
         return jax.jit(conv), (rep(1, 16384, 8192), rep(4, 8192))
 
@@ -519,6 +520,7 @@ def cases(mesh1d, mesh2d):
     # the 2x2 (sp, tp) mesh the default factorisation gives four chips:
     # the only place the sp / tp collectives are compiled for the chip
     from ompi_tpu.parallel import flagship, train
+    from ompi_tpu.parallel.config import load_model_config
     from ompi_tpu.parallel.mesh import make_mesh
 
     def train_step(devices):
@@ -543,7 +545,7 @@ def cases(mesh1d, mesh2d):
     # Qwen3-Next-80B-A3B, one chip's share of a 16-chip deployment: the
     # chunked gated delta rule, output-gated attention at 256 wide)
     def model_config(config):
-        return train.load_model_config(os.path.join(
+        return load_model_config(os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__)))),
             "benchmark", "configs", config + ".json"))
@@ -570,21 +572,21 @@ def cases(mesh1d, mesh2d):
             (tree, tree, tree, rep((), jnp.int32), bias),
             ids(cfg.seq_len), ids(cfg.seq_len + cfg.n_mtp_here))
 
-    # -- one latent-attention sublayer (``model.mla_attention``) of
+    # -- one latent-attention sublayer (``attention.mla_attention``) of
     # JoyAI's step, forward and gradient, at the cell's shapes: what
     # stands between the projections and the two kernels.  Its compiled
     # text must hold no ``_roll_static`` in any ``op_name`` and no array
     # 191 wide: ``jnp.roll`` on q's (1, 8192, 32, 192) float32 array was
     # 2.6 GB of shifted copies a layer and pass (PR 41)
     def mla_operands(devices):
-        from ompi_tpu.parallel import model
+        from ompi_tpu.parallel import attention
 
         one_dev = _Mesh(_np.asarray(devices), ("one",))
         cfg = model_config("joyai-flash-train-1chip")
         rep = lambda s: _sds(s, f32, one_dev, P())
-        leaves = {k: rep(v) for k, v in train.attention_shapes(cfg).items()}
-        loss = lambda p, x: jnp.sum(model.mla_attention(
-            p, x, cfg, interpret=False) ** 2)
+        leaves = {k: rep(v) for k, v in attention.MLA.shapes(cfg).items()}
+        loss = lambda p, x: jnp.sum((x + attention.mla_attention(
+            p, x, cfg, interpret=False)[0]) ** 2)
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))), (
             leaves, rep((cfg.micro_batch, cfg.seq_len, cfg.hidden_size)))
 

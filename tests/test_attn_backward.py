@@ -1,6 +1,6 @@
 """The fused block pair of attention's backward pass
 (``ops/flash_attention.attn_block_backward``) against its ``jnp`` twin
-(``parallel/model._bwd_pair``), the kernel itself under the Pallas
+(``parallel/causal._bwd_pair``), the kernel itself under the Pallas
 interpreter; and ``causal_flash_attention``'s gradient with both kernels
 in place against full attention's, by both walks over the pairs."""
 import math
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ops import flash_attention as fa
-from ompi_tpu.parallel import model
+from ompi_tpu.parallel import causal
 from ompi_tpu.parallel.flagship import _full_attention
 
 
@@ -27,7 +27,7 @@ def _case(d, hv, dt, block, nb, seed=0, b=1, h=2, n_kv=None):
     draw = lambda w, t=dt, n=h: jnp.asarray(
         rng.normal(0, 1, (b, n, nb * block, w)), t)
     q, k, v, do = draw(d), draw(d, n=n_kv), draw(hv, n=n_kv), draw(hv)
-    o, lse = model._causal_fwd_blocks(q, k, v, block, True)
+    o, lse = causal._causal_fwd_blocks(q, k, v, block, True)
     delta = jnp.sum(do.astype(jnp.float32) * o, -1)
     acc = [draw(d, jnp.float32), draw(d, jnp.float32, n_kv),
            draw(hv, jnp.float32, n_kv)]           # not zero: it accumulates
@@ -40,11 +40,11 @@ def _twin(q, k, v, do, lse, delta, acc, block, i, j):
     accumulators' blocks."""
     h, n_kv = q.shape[1], k.shape[1]
     cut = lambda a, n: a[:, :, n * block:(n + 1) * block]
-    dq, dk, dv = model._bwd_pair(
+    dq, dk, dv = causal._bwd_pair(
         cut(q, i), cut(jnp.repeat(k, h // n_kv, 1), j),
         cut(jnp.repeat(v, h // n_kv, 1), j), cut(do, i).astype(jnp.float32),
         cut(lse, i), cut(delta, i),
-        model._tri_bias(block) if i == j else None,
+        causal._tri_bias(block) if i == j else None,
         1.0 / math.sqrt(q.shape[-1]), q.dtype)
     groups = lambda a: a.reshape(a.shape[0], n_kv, h // n_kv,
                                  *a.shape[2:]).sum(2)
@@ -97,13 +97,13 @@ def test_a_groups_folded_rows_are_its_heads_side_by_side(pair):
     block, (i, j) = 128, pair
     q, k, v, do, lse, delta, acc = _case(64, 64, jnp.float32, block, 2,
                                          h=8, n_kv=2, b=2)
-    fold = lambda a, n=2: model._group_blocks(a, n, block)
-    np.testing.assert_array_equal(model._ungroup_blocks(fold(q), 8), q)
-    np.testing.assert_array_equal(model._ungroup_blocks(fold(lse), 8), lse)
-    parts = model._bwd_pair(
+    fold = lambda a, n=2: causal._group_blocks(a, n, block)
+    np.testing.assert_array_equal(causal._ungroup_blocks(fold(q), 8), q)
+    np.testing.assert_array_equal(causal._ungroup_blocks(fold(lse), 8), lse)
+    parts = causal._bwd_pair(
         fold(q)[i], fold(k)[j], fold(v)[j], fold(do.astype(jnp.float32))[i],
         fold(lse)[i], fold(delta)[i],
-        model._group_bias(block, 4) if i == j else None, 0.125, q.dtype)
+        causal._group_bias(block, 4) if i == j else None, 0.125, q.dtype)
     zero = [jnp.zeros_like(a) for a in acc]
     want = _twin(q, k, v, do, lse, delta, zero, block, i, j)
     for g, w, n, heads in zip(parts, want, (i, j, j), (8, 2, 2)):
@@ -157,7 +157,7 @@ def _gradients_agree(h, n_kv, d, hv, nb, dt, block, interpret, seed):
     draw = lambda w, n=h: jnp.asarray(
         rng.normal(0, 1, (2, n, nb * block, w)), jnp.float32)
     q, k, v, w = draw(d), draw(d, n_kv), draw(hv, n_kv), draw(hv)
-    got = jax.grad(lambda q, k, v: jnp.sum(model.causal_flash_attention(
+    got = jax.grad(lambda q, k, v: jnp.sum(causal.causal_flash_attention(
         q, k, v, block, interpret) * w), argnums=(0, 1, 2))(
             q.astype(dt), k.astype(dt), v.astype(dt))
     rtol, atol = (1e-4, 2e-5) if dt == jnp.float32 else (0.06, 0.06)
@@ -175,7 +175,7 @@ def test_attention_gradients_through_the_kernels(kernels_interpreted, h,
                                                  n_kv, d, hv, nb, dt):
     """Forward and backward kernel in ``causal_flash_attention``'s
     gradient, at 2 blocks (the unrolled walk) and at 8 (the scan)."""
-    assert 2 <= model.UNROLLED_BLOCKS < 8
+    assert 2 <= causal.UNROLLED_BLOCKS < 8
     _gradients_agree(h, n_kv, d, hv, nb, dt, 128, False, 1)
 
 

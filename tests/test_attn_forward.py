@@ -1,13 +1,13 @@
 """Causal attention's forward pass as one kernel
 (``ops/flash_attention.flash_causal_forward``) against its ``jnp`` twin
-(``parallel/model._causal_fwd_blocks``), the kernel itself under the
+(``parallel/causal._causal_fwd_blocks``), the kernel itself under the
 Pallas interpreter: ``o`` and the logsumexp."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ompi_tpu.ops import flash_attention as fa
-from ompi_tpu.parallel import model
+from ompi_tpu.parallel import causal
 
 
 def _qkv(d, hv, dt, s, seed=0, b=2, h=2, n_kv=None):
@@ -40,9 +40,9 @@ def test_the_forward_kernel_is_its_twin(h, n_kv, d, hv, nb, dt):
     block = 128
     q, k, v = _qkv(d, hv, dt, nb * block, h=h, n_kv=n_kv)
     got = fa.flash_causal_forward(q, k, v, block=block, interpret=True)
-    _agree(got, model._causal_fwd_blocks(q, k, v, block, True), dt)
+    _agree(got, causal._causal_fwd_blocks(q, k, v, block, True), dt)
     repeated = (jnp.repeat(t, h // n_kv, 1) for t in (k, v))
-    _agree(got, model._causal_fwd_blocks(q, *repeated, block, True), dt)
+    _agree(got, causal._causal_fwd_blocks(q, *repeated, block, True), dt)
 
 
 def test_query_heads_that_no_group_divides_are_refused():
@@ -60,7 +60,7 @@ def test_a_block_longer_than_a_tile_goes_by_tiles(dt):
     block = 2 * fa.FWD_TILE
     q, k, v = _qkv(192, 128, dt, block, seed=1, b=1, h=1)
     got = fa.flash_causal_forward(q, k, v, block=block, interpret=True)
-    _agree(got, model._causal_fwd_blocks(q, k, v, block, True), dt)
+    _agree(got, causal._causal_fwd_blocks(q, k, v, block, True), dt)
 
 
 def test_a_kv_tile_above_the_diagonal_changes_nothing():

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.base.var import registry
-from ompi_tpu.parallel import train
+from ompi_tpu.parallel import config, train
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc, trace
 
@@ -34,7 +34,7 @@ TRACE_EVENT, LOWER_EVENT, BACKEND_EVENT = trace._BUILD_PHASES
 # the device path's sources, whose every jax.jit the rule is held to
 WALKED = ("ops", "mca/coll", "mca/accelerator", "datatype", "parallel")
 # OLMoE at the widths of tests/test_olmoe_train.py: the toy model
-TOY = train.ModelConfig(
+TOY = config.ModelConfig(
     compute_dtype="float32", hidden_size=64, intermediate_size=32,
     num_attention_heads=4, num_key_value_heads=4, num_experts=8,
     num_experts_per_tok=2, vocab_size=256, layers_here=2, seq_len=32,

@@ -12,7 +12,7 @@ import pytest
 
 from ompi_tpu.ops import causal_conv as cc
 from ompi_tpu.ops import gated_delta as gd
-from ompi_tpu.parallel import model, train
+from ompi_tpu.parallel import gdn, train
 from ompi_tpu.runtime import spc
 
 #: tiles of 16 positions by 128 channels: 27, 40 and 200 positions end
@@ -190,7 +190,7 @@ def test_which_convolution_is_built_and_counted(widths, interpret, conv_on,
     spc.init()
     cfg, p, x = operator(**widths)
     before = (spc.read("gdn_conv_built"), spc.read("gdn_conv_kernel_built"))
-    layer = functools.partial(model.gated_delta_net, cfg=cfg,
+    layer = functools.partial(gdn.gated_delta_net, cfg=cfg,
                               interpret=interpret)
     fwd = jax.make_jaxpr(layer)(p, x)
     both = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(layer(p, x)[0]),
@@ -238,7 +238,7 @@ def test_the_operator_on_the_kernels_is_the_operator(widths, monkeypatch):
     weight = jax.random.normal(jax.random.PRNGKey(3), x.shape)
 
     def loss(p, x, interpret):
-        y, seen = model.gated_delta_net(p, x, cfg, interpret=interpret)
+        y, _, seen = gdn.gated_delta_net(p, x, cfg, interpret=interpret)
         return jnp.sum(y * weight), (y, seen)
 
     (_, (y, seen)), grads = jax.value_and_grad(

@@ -1,7 +1,7 @@
 """Sliding-window attention through both flash kernels
 (``ops/flash_attention.flash_causal_forward`` and ``attn_block_backward``
 with a static ``window``, under the Pallas interpreter) and through their
-``jnp`` twins (``parallel/model.causal_flash_attention``) against a dense
+``jnp`` twins (``parallel/causal.causal_flash_attention``) against a dense
 masked softmax and its autodiff: key j is visible to query i iff 0 <= i -
 j < window.  Windows of 1, 2 and all blocks, grouped key-value heads (7
 query heads a key-value head, SmallThinker's), head widths of 64 and 128;
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ops import flash_attention as fa
-from ompi_tpu.parallel import model
+from ompi_tpu.parallel import causal
 from ompi_tpu.runtime import spc
 
 BLOCK = 128
@@ -51,7 +51,7 @@ def walk_backward(q, k, v, do, o, lse, window, pairs=None):
     w = None if window is None else window // BLOCK
     delta = jnp.sum(do * o, axis=-1)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
-    for ij in (pairs or model._window_pairs(nb, w)):
+    for ij in (pairs or causal._window_pairs(nb, w)):
         acc = fa.attn_block_backward(
             jnp.asarray(ij, jnp.int32), q, k, v, do, lse, delta, *acc,
             block=BLOCK, interpret=True, window=window)
@@ -73,7 +73,7 @@ def test_the_forward_kernel_under_a_window_is_the_dense_softmax(h, n_kv, d,
     want = dense(q, k, v, w * BLOCK)
     got = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                   window=w * BLOCK)
-    twin = model._causal_fwd_blocks(q, k, v, BLOCK, True, w * BLOCK)
+    twin = causal._causal_fwd_blocks(q, k, v, BLOCK, True, w * BLOCK)
     for g, t, x in zip(got, twin, want):
         np.testing.assert_allclose(g, x, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(t, x, rtol=2e-5, atol=2e-5)
@@ -94,7 +94,7 @@ def test_the_backward_kernel_under_a_window_is_autodiff(h, n_kv, d, w):
     o, lse = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                      window=window)
     got = walk_backward(q, k, v, do, o, lse, window)
-    twin = jax.grad(lambda *a: jnp.sum(model.causal_flash_attention(
+    twin = jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
         *a, BLOCK, True, window) * do), (0, 1, 2))(q, k, v)
     for name, g, t, x in zip("qkv", got, twin, want):
         scale = float(jnp.abs(x).max())
@@ -115,7 +115,7 @@ def test_the_twins_backward_walks_agree_beyond_the_unrolled_blocks(blocks):
                      jnp.float32)
     want = jax.grad(lambda *a: jnp.sum(dense(*a, window)[0] * do),
                     (0, 1, 2))(q, k, v)
-    got = jax.grad(lambda *a: jnp.sum(model.causal_flash_attention(
+    got = jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
         *a, block, True, window) * do), (0, 1, 2))(q, k, v)
     for g, x in zip(got, want):
         np.testing.assert_allclose(g, x, rtol=1e-4,
@@ -130,7 +130,7 @@ def test_a_window_of_all_blocks_is_full_attention_bit_for_bit():
     q, k, v = _qkv(64, 4 * BLOCK, 4, 2, seed=5)
     before = spc.read("attn_window_built")
     run = lambda *window: jax.value_and_grad(
-        lambda *a: jnp.sum(model.causal_flash_attention(
+        lambda *a: jnp.sum(causal.causal_flash_attention(
             *a, BLOCK, True, *window) ** 2), (0, 1, 2))(q, k, v)
     plain, covered, longer = run(), run(4 * BLOCK), run(4096)
     for other in (covered, longer):
@@ -149,7 +149,7 @@ def test_the_far_tiles_all_masked_rows_stay_finite():
     window = BLOCK
     o, lse = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                      window=window)
-    twin = model._causal_fwd_blocks(q, k, v, BLOCK, True, window)
+    twin = causal._causal_fwd_blocks(q, k, v, BLOCK, True, window)
     want = dense(q, k, v, window)
     for g, t, x in zip((o, lse), twin, want):
         assert np.all(np.isfinite(g)) and np.all(np.isfinite(t))
@@ -173,7 +173,7 @@ def test_the_far_tiles_all_masked_rows_stay_finite():
 def test_the_walk_holds_the_pairs_a_window_reaches(nb, w, pairs):
     """At 16 blocks and a window of 4 a pass walks 70 pairs where a full
     one walks 136 (the cell's shape); at 8 blocks 30 of 36."""
-    walk = model._window_pairs(nb, w)
+    walk = causal._window_pairs(nb, w)
     assert len(walk) == len(set(walk)) == pairs
     reach = nb if w is None else w
     assert set(walk) == {(i, j) for i in range(nb) for j in range(nb)
@@ -191,7 +191,7 @@ def test_the_kernels_walk_the_twins_pairs():
     do = jnp.ones(q.shape, jnp.float32)
     o, lse = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                      window=window)
-    pairs = model._window_pairs(4, 2)
+    pairs = causal._window_pairs(4, 2)
     whole = walk_backward(q, k, v, do, o, lse, window)
     fewer = walk_backward(q, k, v, do, o, lse, window, pairs[:-1])
     assert float(jnp.abs(whole[0] - fewer[0]).max()) > 1e-3
@@ -214,7 +214,7 @@ def test_without_a_window_the_callers_programs_are_what_they_were():
     q, k, v = _qkv(64, 2 * BLOCK, 4, 2, seed=8, dt=jnp.bfloat16)
     for interpret in (True, False):
         loss = lambda *window: lambda *a: jnp.sum(
-            model.causal_flash_attention(*a, BLOCK, interpret, *window))
+            causal.causal_flash_attention(*a, BLOCK, interpret, *window))
         assert _text(jax.grad(loss(), (0, 1, 2)), q, k, v) \
             == _text(jax.grad(loss(None), (0, 1, 2)), q, k, v)
     fwd = lambda **kw: lambda *a: fa.flash_causal_forward(
@@ -244,7 +244,7 @@ def test_a_window_the_kernels_cannot_walk_is_refused(window):
                                 window=window)
     if window == 100:
         with pytest.raises(ValueError, match="window"):
-            model.causal_flash_attention(q, k, v, BLOCK, True, window)
+            causal.causal_flash_attention(q, k, v, BLOCK, True, window)
 
 
 def test_the_counters_count_what_was_built():
@@ -260,7 +260,7 @@ def test_the_counters_count_what_was_built():
     q, k, v = _qkv(32, 64, 4, 2, seed=9)
     for window in (16, None):
         jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
-            model.causal_flash_attention(*a, 16, True, window)),
+            causal.causal_flash_attention(*a, 16, True, window)),
             (0, 1, 2)))(q, k, v)
     assert [spc.read(n) - b for n, b in zip(names, before)] \
         == [4, 2, 2 * 7 + 2 * 10, 4 * 10, 4]

@@ -1,5 +1,5 @@
 """The Pallas kernels of the chunked gated delta rule (``ops/gated_delta``)
-in interpret mode against ``parallel/model.gated_delta_chunked``'s XLA
+in interpret mode against ``parallel/gdn.gated_delta_chunked``'s XLA
 form and the recurrence one position at a time, and which of the two
 ``gated_delta_chunked`` builds where."""
 import functools
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ops import gated_delta as gd
-from ompi_tpu.parallel import layers, model
+from ompi_tpu.parallel import gdn, layers
 from ompi_tpu.parallel import qwen3next_reference as ref
 from ompi_tpu.runtime import spc
 from test_grouped_matmul import _primitives
@@ -76,7 +76,7 @@ def test_the_forward_kernel_is_the_xla_form_and_the_recurrence(length, r):
     args = rule_inputs(length + r, length, r, alike=1.0)
     got = forward(*args)
     assert got.shape == args[2].shape and got.dtype == jnp.float32
-    near(got, model.gated_delta_chunked(*args, CHUNK), 2e-6, "XLA form")
+    near(got, gdn.gated_delta_chunked(*args, CHUNK), 2e-6, "XLA form")
     with jax.default_matmul_precision("highest"):
         near(got, by_positions(*args), 2e-5, "recurrence")
 
@@ -88,7 +88,7 @@ def test_the_backward_kernel_is_autodiff_of_the_xla_form(length, r):
     args = rule_inputs(3 * length + r, length, r, alike=1.0)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     want = jax.grad(lambda *a: jnp.sum(
-        model.gated_delta_chunked(*a, CHUNK) * weight), range(5))(*args)
+        gdn.gated_delta_chunked(*a, CHUNK) * weight), range(5))(*args)
     o, kept = forward(*args, states=True)
     near(o, forward(*args), 0, "the output with and without what is kept")
     got = backward(*args, kept, weight)
@@ -103,7 +103,7 @@ def test_a_batch_and_a_chunk_of_a_tile_go_through_the_same_maps():
     XLA form in chunks of 8: the rule is the same whatever the chunk."""
     args = rule_inputs(4, 128, 2, bt=2, hk=1, alike=2.0)
     weight = jax.random.normal(jax.random.PRNGKey(5), args[2].shape)
-    want, pull = jax.vjp(lambda *a: model.gated_delta_chunked(*a, 8), *args)
+    want, pull = jax.vjp(lambda *a: gdn.gated_delta_chunked(*a, 8), *args)
     o, kept = forward(*args, chunk=64, group=1, states=True)
     near(o, want, 2e-6)
     got = backward(*args, kept, weight, chunk=64, group=1)
@@ -129,7 +129,7 @@ def test_the_kernels_norm_q_and_k_read_where_the_convolution_left_them(
         heads = lambda t, n: t.reshape(1, length, n, w)
         q = layers.l2norm(heads(qkv[..., :hk * w], hk), unit[0]) * unit[1]
         k = layers.l2norm(heads(qkv[..., hk * w:2 * hk * w], hk), unit[0])
-        return model.gated_delta_chunked(
+        return gdn.gated_delta_chunked(
             q, k, heads(qkv[..., 2 * hk * w:], hv), g, beta, CHUNK
         ).reshape(1, length, -1)
 
@@ -170,7 +170,7 @@ def test_the_inverse_by_blocks_is_the_inverse():
     """``(I + L)^-1`` by block widths 1, 4, 16, 64 against float64's, for
     rows that are much alike (the plain series ``sum (-L)^k`` would
     cancel terms far above its sum), one matrix and two down one
-    diagonal; at 8 rows against ``model.unit_lower_inverse``'s forward
+    diagonal; at 8 rows against ``gdn.unit_lower_inverse``'s forward
     substitution too."""
     rng = np.random.default_rng(3)
     k = rng.standard_normal((2, 64, 32)) + 3.0 * rng.standard_normal((2, 1, 32))
@@ -181,7 +181,7 @@ def test_the_inverse_by_blocks_is_the_inverse():
     near(jnp.stack([gd._unit_lower_inverse(m) for m in low]), want, 2e-6)
     near(jnp.stack(gd._unit_lower_inverses(list(low))), want, 2e-6)
     near(gd._unit_lower_inverse(low[0, :8, :8]),
-         model.unit_lower_inverse(low[0, :8, :8]), 1e-6)
+         gdn.unit_lower_inverse(low[0, :8, :8]), 1e-6)
 
 
 def test_which_shapes_have_tiles():
@@ -207,7 +207,7 @@ def test_which_rule_is_built_and_counted(width, interpret, on_kernel):
     spc.init()
     args = rule_inputs(0, 40, 2, dk=width, dv=width)
     before = (spc.read("gdn_rule_built"), spc.read("gdn_rule_kernel_built"))
-    rule = lambda *a: model.gated_delta_chunked(*a, 8, interpret)
+    rule = lambda *a: gdn.gated_delta_chunked(*a, 8, interpret)
     names = _primitives(jax.make_jaxpr(rule)(*args).jaxpr)
     names |= _primitives(jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(rule(*a)), range(5)))(*args).jaxpr)
@@ -238,5 +238,5 @@ def test_the_operator_hands_the_choice_down():
     x = jnp.zeros((1, 16, 64), jnp.float32)
     for interpret in (True, False):
         jaxpr = jax.make_jaxpr(functools.partial(
-            model.gated_delta_net, cfg=cfg, interpret=interpret))(p, x)
+            gdn.gated_delta_net, cfg=cfg, interpret=interpret))(p, x)
         assert ("pallas_call" in _primitives(jaxpr.jaxpr)) == (not interpret)

@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import joyai_reference as ref
-from ompi_tpu.parallel import experts, layers, model, train
+from ompi_tpu.parallel import (attention, causal, config, experts, layers,
+                               objective, train)
 from ompi_tpu.parallel.flagship import _full_attention
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
@@ -39,7 +40,7 @@ SHARE = dict(layers_here=3, experts_here=4, expert_share=1, vocab_here=64)
 TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16, loss_block_rows=16,
              lr=1e-2, aux_loss_coef=0.0, z_loss_coef=0.0, mtp_loss_coef=0.3,
              bias_update_gamma=0.001)
-F32 = train.ModelConfig(compute_dtype="float32", num_experts=16,
+F32 = config.ModelConfig(compute_dtype="float32", num_experts=16,
                         **PUBLISHED, **SHARE, **TRAIN)
 LEAVES = [name for name, _ in train.leaf_names(F32)]
 CLOSE = dict(rtol=1e-5, atol=1e-6)
@@ -77,7 +78,7 @@ def reference(params):
 
 def system_loss(params, cfg, batch, bias):
     tokens, labels = batch
-    return train.model_loss(params, tokens, labels, cfg, interpret=True,
+    return objective.model_loss(params, tokens, labels, cfg, interpret=True,
                             n_global=tokens.size, bias=bias)
 
 
@@ -183,7 +184,7 @@ def test_what_the_checkpoint_keeps_changes_no_number(params, monkeypatch):
 
     (total, kept_aux), kept = grads()
     _, (stepped,) = run_steps(F32, params, (0,))
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     (bare_total, aux), bare = grads()
     _, (bare_stepped,) = run_steps(F32, params, (0,))
@@ -219,7 +220,7 @@ def test_attention_alone_keeps_o_and_the_logsumexp(seam):
 
     def attend(q, k, v):
         k, v = (jnp.repeat(a, nh // nkv, 1) for a in (k, v))
-        o = model.causal_flash_attention(jnp.tanh(q), k, v, 16, True)
+        o = causal.causal_flash_attention(jnp.tanh(q), k, v, 16, True)
         return jnp.sum(o * w), o
 
     def exps(jaxpr):
@@ -230,7 +231,7 @@ def test_attention_alone_keeps_o_and_the_logsumexp(seam):
             exps(sub) for sub in inner if hasattr(sub, "eqns"))
 
     got = {}
-    for name, policy in (("kept", train.layer_checkpoint_policy()),
+    for name, policy in (("kept", objective.layer_checkpoint_policy()),
                          ("bare", None)):
         run = jax.value_and_grad(jax.checkpoint(attend, policy=policy),
                                  (0, 1, 2), has_aux=True)
@@ -267,8 +268,8 @@ def test_a_step_reports_what_it_counted(params):
         == (-(-held // rows) * rows).sum() >= moved["moe_local_slots"]
     assert fullest == np.asarray(aux["loads"]).max() >= 16
     assert aux["grad_probe"].shape == (len(LEAVES), train.PROBE)
-    assert aux["sample"]["router_scores"].shape == (3, train.SAMPLE_ROWS, 16)
-    assert aux["sample"]["mtp_head_in"].shape == (train.SAMPLE_ROWS, 64)
+    assert aux["sample"]["router_scores"].shape == (3, objective.SAMPLE_ROWS, 16)
+    assert aux["sample"]["mtp_head_in"].shape == (objective.SAMPLE_ROWS, 64)
 
 
 def sparse_leaves(cfg, seed=5, hot=None):
@@ -386,10 +387,10 @@ def test_the_attention_backward_by_scan_is_the_unrolled_one():
 
     def grads(block):
         return jax.grad(lambda q, k, v: jnp.sum(
-            model.causal_flash_attention(q, k, v, block, True) * w),
+            causal.causal_flash_attention(q, k, v, block, True) * w),
             argnums=(0, 1, 2))(q, k, v)
 
-    assert 32 // 4 > model.UNROLLED_BLOCKS >= 32 // 16
+    assert 32 // 4 > causal.UNROLLED_BLOCKS >= 32 // 16
     for got, want in zip(grads(4), grads(16)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     full = jax.grad(lambda q, k, v: jnp.sum(
@@ -415,7 +416,7 @@ def _swapped(x, first):
 
 
 def _mla_attention_rolled(p, x, cfg):
-    """``model.mla_attention`` as it was before PR 41: RoPE by
+    """``attention.mla_attention`` as it was before PR 41: RoPE by
     ``rope_interleaved`` (two rolls) on the projections' results."""
     b, s, _ = x.shape
     nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
@@ -432,7 +433,7 @@ def _mla_attention_rolled(p, x, cfg):
     k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
         k_rot[:, :, None].astype(dt), (b, s, nh, rot))], -1)
     heads = lambda t: t.transpose(0, 2, 1, 3)
-    o = model.causal_flash_attention(
+    o = causal.causal_flash_attention(
         heads(q.astype(dt)), heads(k), heads(kvb[..., nope:].astype(dt)),
         min(cfg.attn_block, s), True)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
@@ -499,11 +500,12 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
             loose = 16 if arg == "float32" else 2 ** 17  # bfloat16 operands
             assert _ulps(got, want, jnp.max(jnp.abs(want))) <= loose
         return
-    cfg = train.ModelConfig(compute_dtype=arg, num_experts=16, **PUBLISHED,
+    cfg = config.ModelConfig(compute_dtype=arg, num_experts=16, **PUBLISHED,
                             **SHARE, **TRAIN)
-    leaves = {k: params["mtp"][k] for k in train.attention_shapes(cfg)}
+    leaves = {k: params["mtp"][k] for k in attention.MLA.shapes(cfg)}
     x, g = normal(2, 32, cfg.hidden_size), normal(2, 32, cfg.hidden_size)
-    new = lambda p, x: model.mla_attention(p, x, cfg, interpret=True)
+    new = lambda p, x: x + attention.mla_attention(
+        p, x, cfg, interpret=True)[0]
     old = lambda p, x: _mla_attention_rolled(p, x, cfg)
     tol = CLOSE if arg == "float32" else dict(rtol=2e-2, atol=2e-3)
     np.testing.assert_allclose(new(leaves, x), old(leaves, x), **tol)
@@ -570,7 +572,7 @@ def test_olmoes_losses_are_bit_for_bit_the_parents(dtype):
     with its one layer nothing is rematerialised and nothing scanned, so
     the program is the parent's (with two layers the layers are scanned
     and recomputed, and one loss of twelve differs in its last bit)."""
-    cfg = train.ModelConfig(
+    cfg = config.ModelConfig(
         hidden_size=64, intermediate_size=32, num_attention_heads=4,
         num_key_value_heads=4, num_experts=8, num_experts_per_tok=2,
         vocab_size=256, layers_here=1, seq_len=32, micro_batch=2,

@@ -7,7 +7,7 @@ import jax
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import train
+from ompi_tpu.parallel import objective, train
 
 from test_keye_train import (F32, INDEX, NAMES, batch_of, near, ref_grads,
                              spread_params)
@@ -23,7 +23,7 @@ def _grads_of(term, params, tokens, labels):
     cross-entropy and the load-balancing loss, ``index`` the alignment
     loss."""
     def loss(p):
-        total, aux = train.model_loss(p, tokens, labels, F32,
+        total, aux = objective.model_loss(p, tokens, labels, F32,
                                       interpret=True, n_global=tokens.size)
         index = aux["losses"][4]
         return index if term == "index" else total - index

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import keye_reference as ref
-from ompi_tpu.parallel import model, train
+from ompi_tpu.parallel import (attention, config, dsa, model, objective,
+                               train)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -36,7 +37,7 @@ SHARE = dict(layers_here=4, first_layer_here=0, experts_here=4,
 TRAIN = dict(seq_len=64, micro_batch=2, attn_block=16, loss_block_rows=16,
              lr=1e-2, aux_loss_coef=0.001, z_loss_coef=0.0,
              index_loss_coef=1.0)
-F32 = train.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
+F32 = config.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
                         **TRAIN)
 NAMES = train.leaf_names(F32)
 INDEX = ("index_wq", "index_wk", "index_k_norm", "index_k_bias", "index_ww")
@@ -92,8 +93,8 @@ def test_the_sparse_attention_sublayer_is_the_references():
     of both through every leaf of the sublayer."""
     p = layer_of(F32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64))
-    at = train.sample_rows(128)
-    sublayer = jax.jit(lambda p, x: model.dsa_attention(
+    at = objective.sample_rows(128)
+    sublayer = jax.jit(lambda p, x: dsa.dsa_attention(
         p, x, F32, interpret=True, at=at))
     y, stats, seen = sublayer(p, x)
     with jax.default_matmul_precision("highest"):
@@ -110,7 +111,7 @@ def test_the_sparse_attention_sublayer_is_the_references():
         and seen["dsa_kl_at"].shape == (16,)
     assert float(np.asarray(seen["dsa_kl_at"]).min()) > 0
     ours = lambda p, x: (lambda y, st, _: jnp.sum(y * y)
-                         + st["index_kl_sum"])(*model.dsa_attention(
+                         + st["index_kl_sum"])(*dsa.dsa_attention(
                              p, x, F32, interpret=True))
     theirs = lambda p, x: (lambda y, kl, _: jnp.sum(y * y) + kl)(
         *ref.attention(p, x, F32, chosen))
@@ -128,7 +129,7 @@ def test_the_sparse_attention_sublayer_is_the_references():
 def test_a_wrong_selection_or_indexer_differs(control):
     p = layer_of(F32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64))
-    y = jax.jit(lambda p, x: model.dsa_attention(
+    y = jax.jit(lambda p, x: dsa.dsa_attention(
         p, x, F32, interpret=True)[0])(p, x)
     if control == "no_relu":
         import unittest.mock
@@ -148,13 +149,13 @@ def test_a_sequence_no_longer_than_topk_attends_to_every_earlier_key():
     cfg = dataclasses.replace(F32, seq_len=16)
     p = layer_of(cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 64))
-    y, _, seen = jax.jit(lambda p, x: model.dsa_attention(
+    y, _, seen = jax.jit(lambda p, x: dsa.dsa_attention(
         p, x, cfg, interpret=True))(p, x)
     assert unpacked(seen["dsa_selection_seq"], 16).sum() == 2 * 16 * 17 // 2
     plain = dataclasses.replace(
         cfg, layer_types=("full_attention",) * 8, index_topk=0,
         index_heads=0, index_head_dim=0)
-    want = model.gqa_attention(p, x, plain, interpret=True)[0]
+    want = attention.FULL.run(p, x, plain, interpret=True)[0]
     close(y, want, rtol=1e-5, atol=1e-6)
 
 
@@ -176,7 +177,7 @@ def test_the_shares_layer_outputs_add_up_to_the_uncut_layer():
         mine = {**p, **{k: p[k][4 * j:4 * j + 4]
                         for k in ("gate", "up", "down")}}
         out, stats, _ = jax.jit(lambda p, x, part=part: model.decoder_layer(
-            p, x, part, interpret=True, kind="sparse_attention"))(mine, x)
+            p, x, part, interpret=True, kind="dsa_moe"))(mine, x)
         total = total + (out - alike)       # a share's routed part
         kls.append(float(stats["index_kl_sum"]))
     close(total + alike, want, rtol=1e-4, atol=1e-5)

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import experts, model, train
+from ompi_tpu.parallel import (attention, causal, config, experts, layers,
+                               objective, short_conv, train)
 from ompi_tpu.parallel import lfm2_reference as ref
 from ompi_tpu.parallel import nemotron_reference
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -41,7 +42,7 @@ SHARE = dict(layers_here=6, first_layer_here=1, experts_here=2,
 TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16, loss_block_rows=16,
              lr=1e-2, aux_loss_coef=0.0, z_loss_coef=0.0,
              bias_update_gamma=0.001)
-F32 = train.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
+F32 = config.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
                         **TRAIN)
 NAMES = train.leaf_names(F32)
 CLOSE = dict(rtol=1e-5, atol=1e-6)
@@ -65,11 +66,10 @@ def layer_of(cfg, kind, seed=5):
     """One layer's leaves of ``kind`` (``conv_dense``, ``attn_moe``, ...)
     drawn as ``init_model_params`` would, the matrices wide enough (0.3)
     that every part matters."""
-    letter = {v: k for k, v in train.PATTERN_KINDS.items()}[kind]
-    types = ("conv" if letter in "cC" else "full_attention",) * 2
+    types = ("conv" if kind.startswith("conv") else "full_attention",) * 2
     one = dataclasses.replace(
         cfg, init_std=0.3, layer_types=types, layers_here=1,
-        first_layer_here=0 if letter.islower() else 1,
+        first_layer_here=0 if kind.endswith("dense") else 1,
         first_k_dense_replace=1)
     (group,) = train.init_model_params(one, seed)["layers"].values()
     assert {k: v.shape[1:] for k, v in group[kind].items()} \
@@ -123,9 +123,9 @@ def test_the_short_convolution_is_the_loop_over_positions(length):
     x = jax.random.normal(jax.random.PRNGKey(length), (2, length, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     got, got_g = jax.value_and_grad(
-        lambda p, x: jnp.sum(model.short_conv(p, x, F32)[0] * probe),
+        lambda p, x: jnp.sum(short_conv.short_conv(p, x, F32)[0] * probe),
         argnums=(0, 1))(p, x)
-    close(model.short_conv(p, x, F32)[0], conv_by_positions(p, x, F32),
+    close(short_conv.short_conv(p, x, F32)[0], conv_by_positions(p, x, F32),
           rtol=1e-4, atol=1e-5)
     with jax.default_matmul_precision("highest"):
         close(ref.short_conv(p, x, F32), conv_by_positions(p, x, F32),
@@ -142,8 +142,8 @@ def test_the_short_convolution_is_the_loop_over_positions(length):
 def test_the_short_convolution_reports_what_its_gate_path_read_and_made():
     p = layer_of(F32, "conv_moe")
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 64))
-    _, seen = model.short_conv(p, x, F32)
-    c = min(model.CONV_SAMPLE, 64)
+    _, _, seen = short_conv.short_conv(p, x, F32)
+    c = min(short_conv.CONV_SAMPLE, 64)
     assert seen["conv_bcu_seq"].shape == (18, 3 * c)
     bcu = np.asarray(seen["conv_bcu_seq"], np.float64).reshape(2, 9, 3, c)
     gated = bcu[:, :, 0] * bcu[:, :, 2]
@@ -170,13 +170,13 @@ def test_attention_with_qk_norm_and_rope_is_the_references(heads, kv):
             lambda p, x: jnp.sum(ref.attention(p, x, cfg) * probe),
             argnums=(0, 1))(p, x)
     got, got_g = jax.value_and_grad(
-        lambda p, x: jnp.sum(model.gqa_attention(
+        lambda p, x: jnp.sum(attention.FULL.run(
             p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1))(p, x)
     close(got, want, rtol=1e-4)
     for k in ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm"):
         near(got_g[0][k], want_g[0][k], err_msg=k)
     near(got_g[1], want_g[1])
-    _, seen = model.gqa_attention(p, x, cfg, interpret=True)
+    _, _, seen = attention.FULL.run(p, x, cfg, interpret=True)
     hd = 64 // heads
     assert seen["attn_qk"].shape == seen["attn_qk_in"].shape == (64, 2 * hd)
     # row 0 is position 0: RoPE turns nothing there, so what is left is
@@ -196,18 +196,18 @@ def test_without_a_qk_norm_the_sublayer_is_nemotrons_bit_for_bit():
     cfg = dataclasses.replace(NEMOTRON, heads_here=8, num_key_value_heads=4)
     p = their_layer(cfg, "attn")
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
-    got, seen = model.gqa_attention(p, x, cfg, interpret=True)
+    got, _, seen = attention.SHARED_KV.run(p, x, cfg, interpret=True)
     assert seen == {}
     with jax.default_matmul_precision("highest"):
         close(got, nemotron_reference.attention(p, x, cfg), rtol=1e-4)
     b, s, dt = 2, 32, cfg.compute_dtype
-    h = model.rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
+    h = layers.rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
     heads = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
-    q = heads(model.matmul(h, p["wq"], dt), 8)
-    k, v = (jnp.repeat(heads(model.matmul(h, p[w], dt), 2), 4, 1)
+    q = heads(layers.matmul(h, p["wq"], dt), 8)
+    k, v = (jnp.repeat(heads(layers.matmul(h, p[w], dt), 2), 4, 1)
             for w in ("wk", "wv"))
-    o = model.causal_flash_attention(q, k, v, 16, True)
-    want = model.matmul(o.transpose(0, 2, 1, 3).reshape(b, s, -1), p["wo"],
+    o = causal.causal_flash_attention(q, k, v, 16, True)
+    want = layers.matmul(o.transpose(0, 2, 1, 3).reshape(b, s, -1), p["wo"],
                         dt)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -363,7 +363,7 @@ def test_every_leafs_gradient_is_the_references():
     tokens, labels = batch_of(4)
     params, bias = train.init_model_params(F32, 11), some_bias()
     (_, aux), got = jax.value_and_grad(
-        lambda ps: train.model_loss(ps, tokens, labels, F32, interpret=True,
+        lambda ps: objective.model_loss(ps, tokens, labels, F32, interpret=True,
                                     n_global=64, bias=bias),
         has_aux=True)(params)
     (_, loads), want = ref.grads(params, tokens, labels, F32, bias)
@@ -380,7 +380,7 @@ def test_the_tied_matrixs_gradient_is_the_sum_of_both_uses():
     gradient."""
     tokens, labels = batch_of(4)
     params, bias = train.init_model_params(F32, 11), some_bias()
-    got = jax.grad(lambda ps: train.model_loss(
+    got = jax.grad(lambda ps: objective.model_loss(
         ps, tokens, labels, F32, interpret=True, n_global=64,
         bias=bias)[0])(params)["embed"]
     embed = params["embed"]
@@ -412,12 +412,12 @@ def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
 
     def grads():
         return jax.value_and_grad(
-            lambda ps: train.model_loss(ps, tokens, labels, F32,
+            lambda ps: objective.model_loss(ps, tokens, labels, F32,
                                         interpret=True, n_global=64,
                                         bias=bias), has_aux=True)(params)
 
     (loss, aux), got = grads()
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     (bare_loss, bare_aux), bare = grads()
     assert loss == bare_loss
@@ -574,7 +574,7 @@ def test_a_tied_head_is_any_models(tmp_path):
     assert "head" not in train.model_param_shapes(cfg)
     tokens, labels = batch_of(1)
     params = train.init_model_params(cfg, 0)
-    loss, aux = train.model_loss(params, tokens, labels[:, :32], cfg,
+    loss, aux = objective.model_loss(params, tokens, labels[:, :32], cfg,
                                  interpret=True, n_global=64)
     assert np.isfinite(float(loss)) and aux["rows"].shape == (64, 2)
 

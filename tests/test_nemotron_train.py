@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import experts, model, train
+from ompi_tpu.parallel import (attention, config, experts, mamba, objective,
+                               train)
 from ompi_tpu.parallel import nemotron_reference as ref
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
@@ -42,7 +43,7 @@ SHARE = dict(layers_here=6, first_layer_here=1, heads_here=4,
 TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16, loss_block_rows=16,
              lr=1e-2, aux_loss_coef=0.0, z_loss_coef=0.0,
              bias_update_gamma=0.001)
-F32 = train.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
+F32 = config.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
                         **TRAIN)
 NAMES = train.leaf_names(F32)
 CLOSE = dict(rtol=1e-5, atol=1e-6)
@@ -106,12 +107,12 @@ def test_the_chunked_scan_is_the_recurrence(length, groups):
             lambda *args: jnp.sum(ref.recurrence(*args) * probe),
             argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
     got, got_g = jax.value_and_grad(
-        lambda *args: jnp.sum(model.ssd_chunked(*args, 8) * probe),
+        lambda *args: jnp.sum(mamba.ssd_chunked(*args, 8) * probe),
         argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
     close(got, want, rtol=1e-4)
     for g, w in zip(got_g, want_g):
         near(g, w)
-    close(model.ssd_chunked(x, dt, a, b, c, 8),
+    close(mamba.ssd_chunked(x, dt, a, b, c, 8),
           ref.recurrence(x, dt, a, b, c), rtol=1e-4, atol=1e-5)
 
 
@@ -123,9 +124,9 @@ def test_a_long_decay_does_not_overflow_the_chunk():
     a = -jnp.array([3.0, 5.0])
     b = c = jnp.ones((1, 16, 1, 4))
     y, g = jax.value_and_grad(
-        lambda dt: jnp.sum(model.ssd_chunked(x, dt, a, b, c, 8)))(dt)
+        lambda dt: jnp.sum(mamba.ssd_chunked(x, dt, a, b, c, 8)))(dt)
     assert np.isfinite(np.asarray(y)) and np.all(np.isfinite(np.asarray(g)))
-    close(model.ssd_chunked(x, dt, a, b, c, 8),
+    close(mamba.ssd_chunked(x, dt, a, b, c, 8),
           ref.recurrence(x, dt, a, b, c))
 
 
@@ -140,7 +141,7 @@ def test_the_mixer_is_the_references(groups_here):
             lambda p, x: jnp.sum(ref.mixer(p, x, cfg) * probe),
             argnums=(0, 1))(p, x)
     got, got_g = jax.value_and_grad(
-        lambda p, x: jnp.sum(model.mamba_mixer(p, x, cfg)[0] * probe),
+        lambda p, x: jnp.sum(mamba.mamba_mixer(p, x, cfg)[0] * probe),
         argnums=(0, 1))(p, x)
     close(got, want, rtol=1e-4)
     for k in p:
@@ -164,7 +165,7 @@ def test_grouped_query_attention_is_the_references(heads, kv, here):
             lambda p, x: jnp.sum(ref.attention(p, x, cfg) * probe),
             argnums=(0, 1))(p, x)
     got, got_g = jax.value_and_grad(
-        lambda p, x: jnp.sum(model.gqa_attention(
+        lambda p, x: jnp.sum(attention.gqa_attention(
             p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1))(p, x)
     close(got, want, rtol=1e-4)
     for k in p:
@@ -228,7 +229,7 @@ def test_the_head_shares_add_up_to_the_uncut_layers():
                 "out_proj": p["out_proj"][cols(j, 0, 16)],
                 **{k: p[k][cols(j, 0, 2)]
                    for k in ("dt_bias", "A_log", "D")}}
-        total = total + model.mamba_mixer(mine, x, part)[0]
+        total = total + mamba.mamba_mixer(mine, x, part)[0]
     with jax.default_matmul_precision("highest"):
         close(total, ref.mixer(p, x, whole), rtol=1e-4, atol=1e-5)
     p = layer_of(whole, "attn")
@@ -237,7 +238,7 @@ def test_the_head_shares_add_up_to_the_uncut_layers():
         q, kv = cols(j, 0, 8), cols(j // 4, 0, 4)
         mine = {"ln1": p["ln1"], "wq": p["wq"][:, q], "wk": p["wk"][:, kv],
                 "wv": p["wv"][:, kv], "wo": p["wo"][q]}
-        total = total + model.gqa_attention(mine, x, part,
+        total = total + attention.gqa_attention(mine, x, part,
                                             interpret=True)[0]
     with jax.default_matmul_precision("highest"):
         close(total, ref.attention(p, x, whole), rtol=1e-4, atol=1e-5)
@@ -353,7 +354,7 @@ def test_every_leafs_gradient_is_the_references():
     tokens, labels = batch_of(4)
     params, bias = train.init_model_params(F32, 11), some_bias()
     (_, aux), got = jax.value_and_grad(
-        lambda ps: train.model_loss(ps, tokens, labels, F32, interpret=True,
+        lambda ps: objective.model_loss(ps, tokens, labels, F32, interpret=True,
                                     n_global=64, bias=bias),
         has_aux=True)(params)
     (_, loads), want = ref.grads(params, tokens, labels, F32, bias)
@@ -372,7 +373,7 @@ def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
 
     def grads():
         return jax.value_and_grad(
-            lambda ps: train.model_loss(ps, tokens, labels, F32,
+            lambda ps: objective.model_loss(ps, tokens, labels, F32,
                                         interpret=True, n_global=64,
                                         bias=bias), has_aux=True)(params)
 
@@ -384,7 +385,7 @@ def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
 
     (loss, aux), got = grads()
     stepped = one_step()
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     (bare_loss, bare_aux), bare = grads()
     bare_stepped = one_step()

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import olmoe_reference as ref
-from ompi_tpu.parallel import train
+from ompi_tpu.parallel import config, objective, train
 from ompi_tpu.parallel.experts import moe_sorted_block
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
@@ -26,9 +26,9 @@ WIDTHS = dict(hidden_size=64, intermediate_size=32, num_attention_heads=4,
               num_key_value_heads=4, num_experts=8, num_experts_per_tok=2,
               vocab_size=256, layers_here=2, seq_len=32, micro_batch=2,
               attn_block=16, loss_block_rows=16, lr=1e-2)
-F32 = train.ModelConfig(compute_dtype="float32", **WIDTHS)
-BF16 = train.ModelConfig(compute_dtype="bfloat16", **WIDTHS)
-LEAVES = [name for name, _ in train.leaf_names()]
+F32 = config.ModelConfig(compute_dtype="float32", **WIDTHS)
+BF16 = config.ModelConfig(compute_dtype="bfloat16", **WIDTHS)
+LEAVES = [name for name, _ in train.leaf_names(F32)]
 CLOSE = dict(rtol=1e-5, atol=1e-6)
 
 
@@ -52,7 +52,7 @@ def reference(params):
 
 def system_loss(params, cfg, batch):
     tokens, labels = batch
-    return train.model_loss(params, tokens, labels, cfg, interpret=True,
+    return objective.model_loss(params, tokens, labels, cfg, interpret=True,
                             n_global=tokens.size)
 
 
@@ -114,7 +114,7 @@ def test_loss_and_its_three_parts(system, reference):
 
 @pytest.mark.parametrize("leaf", LEAVES)
 def test_gradient_of_every_leaf(leaf, system, reference):
-    path = dict(train.leaf_names())[leaf]
+    path = dict(train.leaf_names(F32))[leaf]
     got = train._leaf(system["grads"], path)
     want = train._leaf(reference["grads"], path)
     assert float(jnp.abs(want).max()) > 0
@@ -134,7 +134,7 @@ def test_parameters_after_three_adamw_steps(dp, params):
     want, losses = ref.train_steps(params, [batch_of(s) for s in seeds], F32)
     np.testing.assert_allclose([a["losses"][0] for a in auxes], losses,
                                rtol=1e-5)
-    for name, path in train.leaf_names():
+    for name, path in train.leaf_names(F32):
         np.testing.assert_allclose(
             train._leaf(got, path), train._leaf(want, path), rtol=1e-5,
             atol=0.01 * 3 * F32.lr, err_msg=name)
@@ -156,8 +156,8 @@ def test_a_step_reports_what_it_counted(params):
     assert spc.read("moe_max_expert_load") >= fullest
     assert aux["grad_probe"].shape == (len(LEAVES), train.PROBE)
     assert aux["grad_sq"].shape == (len(LEAVES),)
-    assert aux["sample"]["router_in"].shape == (2, train.SAMPLE_ROWS, 64)
-    assert aux["sample"]["head_in"].shape == (train.SAMPLE_ROWS, 64)
+    assert aux["sample"]["router_in"].shape == (2, objective.SAMPLE_ROWS, 64)
+    assert aux["sample"]["head_in"].shape == (objective.SAMPLE_ROWS, 64)
 
 
 def test_no_token_is_dropped_when_one_expert_takes_a_whole_batch():
@@ -206,7 +206,7 @@ def test_bfloat16_compute_meets_the_reference_within_its_tolerance(
                                rtol=1e-2)
     assert not np.allclose(aux["losses"], reference["parts"], **CLOSE)
     sparse = ("ln2", "router", "gate", "up", "down")
-    for name, path in train.leaf_names():
+    for name, path in train.leaf_names(F32):
         got, want = train._leaf(grads, path), train._leaf(
             reference["grads"], path)
         off = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
@@ -256,7 +256,7 @@ def test_the_benchmarks_reference_is_the_repos(kit, params, reference):
     out = kit_step(kit, params)
     np.testing.assert_allclose(out["losses"], reference["parts"], **CLOSE)
     np.testing.assert_array_equal(out["loads"], reference["loads"])
-    for name, path in train.leaf_names():
+    for name, path in train.leaf_names(F32):
         want = train._leaf(reference["grads"], path)
         np.testing.assert_allclose(
             out["grads"][name], want, rtol=1e-5,
@@ -325,7 +325,7 @@ def test_a_wrong_variant_fails_the_comparison(wrong, kit, params, system):
     assert not np.allclose(system["aux"]["losses"], out["losses"], **CLOSE)
     assert abs(float(system["aux"]["losses"][1] - out["losses"][1])) > 1e-4
     leaf = {"renorm": "down", "qknorm_per_head": "wq"}[wrong]
-    got = train._leaf(system["grads"], dict(train.leaf_names())[leaf])
+    got = train._leaf(system["grads"], dict(train.leaf_names(F32))[leaf])
     want = out["grads"][leaf]
     assert float(jnp.abs(got - want).max()) > 0.05 * float(
         jnp.abs(want).max())

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import experts, layers, model, train
+from ompi_tpu.parallel import (attention, causal, config, experts, gdn,
+                               layers, train)
 from ompi_tpu.parallel import qwen3next_reference as ref
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 
@@ -41,7 +42,7 @@ SHARE = dict(layers_here=4, first_layer_here=0, experts_here=4,
              expert_share=1, vocab_here=64, mtp_here=0)
 TRAIN = dict(seq_len=40, micro_batch=2, attn_block=8, loss_block_rows=8,
              chunk_size=8, lr=1e-2, aux_loss_coef=0.001, z_loss_coef=0.0)
-F32 = train.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
+F32 = config.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
                         **TRAIN)
 NAMES = train.leaf_names(F32)
 CLOSE = dict(rtol=1e-5, atol=1e-6)
@@ -115,12 +116,12 @@ def test_the_chunked_rule_is_the_recurrence_over_positions(length):
     args = rule_inputs(length, length)
     with jax.default_matmul_precision("highest"):
         want = by_positions(*args)
-        got = model.gated_delta_chunked(*args, 8)
+        got = gdn.gated_delta_chunked(*args, 8)
         close(got, want, rtol=1e-4, atol=1e-6)
         weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
         loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weight)
         g_want = jax.grad(loss(by_positions), range(5))(*args)
-        g_got = jax.grad(loss(lambda *a: model.gated_delta_chunked(*a, 8)),
+        g_got = jax.grad(loss(lambda *a: gdn.gated_delta_chunked(*a, 8)),
                          range(5))(*args)
     for name, a, b in zip("qkvgb", g_got, g_want):
         near(a, b, rel=1e-4, err_msg=name)
@@ -130,11 +131,11 @@ def test_without_beta_nothing_is_written_and_the_state_only_decays():
     """beta = 0 writes nothing: from the zero state every output is zero,
     whatever g is; and a chunk's result does not depend on the chunk."""
     q, k, v, g, beta = rule_inputs(3, 24)
-    got = model.gated_delta_chunked(q, k, v, g, beta * 0, 8)
+    got = gdn.gated_delta_chunked(q, k, v, g, beta * 0, 8)
     np.testing.assert_array_equal(np.asarray(got), 0.0)
     with jax.default_matmul_precision("highest"):
-        close(model.gated_delta_chunked(q, k, v, g, beta, 8),
-              model.gated_delta_chunked(q, k, v, g, beta, 4),
+        close(gdn.gated_delta_chunked(q, k, v, g, beta, 8),
+              gdn.gated_delta_chunked(q, k, v, g, beta, 4),
               rtol=1e-4, atol=1e-6)
 
 
@@ -142,7 +143,7 @@ def test_without_decay_and_with_beta_one_it_is_the_plain_delta_rule():
     """g = 0 and beta = 1: ``S <- S + k (v - S^T k)^T``, in numpy
     float64."""
     q, k, v, g, beta = rule_inputs(4, 19, bt=1, hk=1, hv=1)
-    got = model.gated_delta_chunked(q, k, v, g * 0, beta * 0 + 1, 8)
+    got = gdn.gated_delta_chunked(q, k, v, g * 0, beta * 0 + 1, 8)
     qn, kn, vn = (np.asarray(t, np.float64)[0, :, 0] for t in (q, k, v))
     state, want = np.zeros((16, 16)), []
     for t in range(19):
@@ -154,12 +155,12 @@ def test_without_decay_and_with_beta_one_it_is_the_plain_delta_rule():
 def test_the_unit_lower_inverse_and_its_gradient():
     low = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (3, 8, 8)), -1)
     want = jnp.linalg.inv(jnp.eye(8) + low)
-    close(model.unit_lower_inverse(low), want, rtol=1e-4, atol=1e-5)
+    close(gdn.unit_lower_inverse(low), want, rtol=1e-4, atol=1e-5)
     weight = jax.random.normal(jax.random.PRNGKey(1), low.shape)
     g_want = jax.grad(lambda a: jnp.sum(
         jnp.linalg.inv(jnp.eye(8) + jnp.tril(a, -1)) * weight))(low)
     g_got = jax.grad(lambda a: jnp.sum(
-        model.unit_lower_inverse(jnp.tril(a, -1)) * weight))(low)
+        gdn.unit_lower_inverse(jnp.tril(a, -1)) * weight))(low)
     near(g_got, g_want, rel=1e-4)
 
 
@@ -167,9 +168,9 @@ def test_the_unit_lower_inverse_and_its_gradient():
 def test_the_delta_net_operator_is_the_references_and_reports_its_rule():
     p = layer_of(F32, "gdn_moe")
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64))
-    got, seen = model.gated_delta_net(p, x, F32)
+    got, _, seen = gdn.gated_delta_net(p, x, F32)
     with jax.default_matmul_precision("highest"):
-        close(got, ref.delta_net(p, x, F32), rtol=1e-4, atol=5e-5)
+        close(got, ref.gdn(p, x, F32), rtol=1e-4, atol=5e-5)
     assert {k: v.shape for k, v in seen.items()} == {
         "gdn_q_seq": (80, 16), "gdn_k_seq": (80, 16), "gdn_v_seq": (80, 16),
         "gdn_g_seq": (80,), "gdn_beta_seq": (80,), "gdn_o": (80, 16)}
@@ -194,7 +195,7 @@ def test_gated_attention_with_partial_rope_is_the_references(heads, kv):
         and p["wo"].shape == (heads * 32, 64) and p["wk"].shape \
         == (64, kv * 32) and cfg.rotary_width == 8
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64))
-    got, seen = model.gqa_attention(p, x, cfg, interpret=True)
+    got, _, seen = attention.FULL.run(p, x, cfg, interpret=True)
     with jax.default_matmul_precision("highest"):
         close(got, ref.attention(p, x, cfg), rtol=1e-4, atol=1e-5)
     assert seen["attn_qk"].shape == seen["attn_qk_in"].shape == (80, 64) \
@@ -235,19 +236,19 @@ def test_without_a_gate_the_sublayer_is_lfm2s_bit_for_bit():
 
     p = their_layer(LFM2, "attn_moe")
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
-    got, seen = model.gqa_attention(p, x, LFM2, interpret=True)
+    got, _, seen = attention.FULL.run(p, x, LFM2, interpret=True)
     assert set(seen) == {"attn_qk_in", "attn_qk"} \
         and LFM2.rotary_width is None and LFM2.head_width == 16
     b, s, dt = 2, 32, LFM2.compute_dtype
-    h = model.rmsnorm_gain(x, p["ln1"], LFM2.rms_norm_eps)
+    h = layers.rmsnorm_gain(x, p["ln1"], LFM2.rms_norm_eps)
     heads = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
-    q, k = (layers.rope(model.rmsnorm_gain(
-        heads(model.matmul(h, p[w], dt), n), p[g], LFM2.rms_norm_eps),
+    q, k = (layers.rope(layers.rmsnorm_gain(
+        heads(layers.matmul(h, p[w], dt), n), p[g], LFM2.rms_norm_eps),
         LFM2.rope_theta) for w, n, g in (("wq", 4, "q_norm"),
                                          ("wk", 2, "k_norm")))
-    o = model.causal_flash_attention(
-        q, k, heads(model.matmul(h, p["wv"], dt), 2), 16, True)
-    want = model.matmul(o.transpose(0, 2, 1, 3).reshape(b, s, -1), p["wo"],
+    o = causal.causal_flash_attention(
+        q, k, heads(layers.matmul(h, p["wv"], dt), 2), 16, True)
+    want = layers.matmul(o.transpose(0, 2, 1, 3).reshape(b, s, -1), p["wo"],
                         dt)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

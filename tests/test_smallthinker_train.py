@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import experts, layers, model, train
+from ompi_tpu.parallel import (attention, config, experts, layers, model,
+                               objective, train)
 from ompi_tpu.parallel import smallthinker_reference as ref
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 
@@ -38,7 +39,7 @@ SHARE = dict(layers_here=4, first_layer_here=0, experts_here=4,
              expert_share=1, vocab_here=64, mtp_here=0)
 TRAIN = dict(seq_len=40, micro_batch=2, attn_block=8, loss_block_rows=8,
              lr=1e-2, aux_loss_coef=0.001, z_loss_coef=0.0)
-F32 = train.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
+F32 = config.ModelConfig(compute_dtype="float32", **PUBLISHED, **SHARE,
                         **TRAIN)
 NAMES = train.leaf_names(F32)
 CLOSE = dict(rtol=1e-5, atol=1e-6)
@@ -87,8 +88,8 @@ def test_an_attention_sublayer_is_the_references(kind, letter):
     p = layer_of(F32, kind)
     assert "q_norm" not in p and set(p) >= {"wq", "wk", "wv", "wo", "router"}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64))
-    name = {"A": "full_attention", "W": "sliding_attention"}[letter]
-    got, seen = model.gqa_attention(p, x, F32, interpret=True, kind=name)
+    entry = {"A": attention.FULL, "W": attention.WINDOW}[letter]
+    got, _, seen = entry.run(p, x, F32, interpret=True)
     with jax.default_matmul_precision("highest"):
         want = ref.attention(p, x, F32, letter)
     close(got, want, rtol=1e-4, atol=1e-5)
@@ -116,8 +117,8 @@ def test_the_wrong_kind_of_layer_differs(control):
         "no_rope_on_window": dict(kind="sliding_attention", cfg=change(
             F32, rope_kinds=()))}[control]
     letter = "A" if control == "rope_on_full" else "W"
-    got = model.gqa_attention(p, x, wrong["cfg"], interpret=True,
-                              kind=wrong["kind"])[0]
+    got = model.NAMED[wrong["kind"]].run(p, x, wrong["cfg"],
+                                         interpret=True)[0]
     with jax.default_matmul_precision("highest"):
         want = ref.attention(p, x, F32, letter)
     assert float(jnp.abs(got - want).max()) > 1e-2
@@ -130,11 +131,9 @@ def test_a_sequence_shorter_than_the_window_is_full_attention_bit_for_bit():
     wide = dataclasses.replace(F32, sliding_window=4096, attn_block=8)
     p = layer_of(F32, "swa_moe")
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 64))
-    got = model.gqa_attention(p, x, wide, interpret=True,
-                              kind="sliding_attention")[0]
+    got = attention.WINDOW.run(p, x, wide, interpret=True)[0]
     turned = dataclasses.replace(F32, rope_kinds=("full_attention",))
-    full = model.gqa_attention(p, x, turned, interpret=True,
-                               kind="full_attention")[0]
+    full = attention.FULL.run(p, x, turned, interpret=True)[0]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
 
 
@@ -144,8 +143,7 @@ def test_rope_turns_window_layers_only():
     were projected: the test above)."""
     p = layer_of(F32, "swa_moe")
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 40, 64))
-    _, seen = model.gqa_attention(p, x, F32, interpret=True,
-                                  kind="sliding_attention")
+    _, _, seen = attention.WINDOW.run(p, x, F32, interpret=True)
     q_in = seen["attn_qk_in"][:, :16].reshape(2, 1, 40, 16)
     close(seen["attn_qk"][:, :16].reshape(2, 1, 40, 16),
           layers.rope(q_in, F32.rope_theta), rtol=1e-6)
@@ -159,7 +157,7 @@ def test_the_router_reads_the_layers_input():
     p = layer_of(F32, "swa_moe")
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 40, 64))
     out, stats, seen = model.decoder_layer(p, x, F32, interpret=True,
-                                           kind="sliding_attention")
+                                           kind="swa_moe")
     rows = x.reshape(80, 64)
     close(seen["in"], rows)
     close(seen["logits"], jnp.dot(rows, p["router"],
@@ -172,7 +170,7 @@ def test_the_router_reads_the_layers_input():
     close(jnp.sum(seen["weights"], -1), np.ones(80), rtol=1e-5)
     after = dataclasses.replace(F32, router_before_attention=False)
     late, _, seen_late = model.decoder_layer(p, x, after, interpret=True,
-                                             kind="sliding_attention")
+                                             kind="swa_moe")
     assert float(jnp.abs(late - want).max()) > 1e-2
     assert np.mean(np.asarray(seen_late["experts"])
                    != np.asarray(seen["experts"])) > 0.3
@@ -219,7 +217,7 @@ def test_the_four_shares_layer_outputs_add_up_to_the_uncut_layer():
         mine = {**p, **{k: p[k][4 * j:4 * j + 4]
                         for k in ("gate", "up", "down")}}
         out = model.decoder_layer(mine, x, part, interpret=True,
-                                  kind="sliding_attention")[0]
+                                  kind="swa_moe")[0]
         total = total + (out - alike)       # a share's routed part
     close(total + alike, want, rtol=1e-4, atol=1e-5)
     assert float(jnp.abs(want - alike).max()) > 1e-3
@@ -317,7 +315,7 @@ def test_the_loss_and_its_gradients_one_primitive_at_a_time():
     tokens, labels = batch_of(0, cfg=cfg)
     with jax.disable_jit():
         (_, aux), got = jax.value_and_grad(
-            lambda p: train.model_loss(p, tokens, labels, cfg,
+            lambda p: objective.model_loss(p, tokens, labels, cfg,
                                        interpret=True, n_global=24),
             has_aux=True)(params)
     (total, (ce, lb, loads)), g = ref.grads(params, tokens, labels, cfg)

@@ -1,4 +1,4 @@
-"""Learned sparse attention's pieces (``parallel/model.dsa_attention``'s):
+"""Learned sparse attention's pieces (``parallel/dsa.dsa_attention``'s):
 the exact selection by counting passes against ``jnp.sort``, ties and rows
 before ``topk`` among them; the index/select kernel
 (``ops/sparse_attention.index_select``) under the Pallas interpreter
@@ -18,7 +18,7 @@ import pytest
 
 from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.ops import sparse_attention as sa
-from ompi_tpu.parallel import model
+from ompi_tpu.parallel import causal, dsa
 from ompi_tpu.runtime import spc
 
 BLOCK = 128
@@ -57,8 +57,8 @@ def test_the_counting_selection_is_the_sorts(topk):
     """Rows before ``topk`` take every earlier key; later rows exactly
     ``topk``, none scored below a key left out, ties to the earlier."""
     qi, ki, w = _indexer(256)
-    scores = model.index_scores(qi, ki, w)
-    got = np.asarray(model.select_topk(scores, 0, topk))
+    scores = dsa.index_scores(qi, ki, w)
+    got = np.asarray(dsa.select_topk(scores, 0, topk))
     np.testing.assert_array_equal(got, sorted_selection(scores, topk))
     t = np.arange(256)
     np.testing.assert_array_equal(got.sum(-1)[0], np.minimum(t + 1, topk))
@@ -72,13 +72,13 @@ def test_the_counting_selection_is_the_sorts(topk):
 def test_the_selection_breaks_a_tie_at_the_bar_by_position():
     """Scores that are all alike: the first ``topk`` keys win, exactly."""
     scores = jnp.zeros((1, 64, 64), jnp.float32)
-    got = np.asarray(model.select_topk(scores, 0, 5))[0]
+    got = np.asarray(dsa.select_topk(scores, 0, 5))[0]
     for t in range(64):
         assert got[t].nonzero()[0].tolist() == list(range(min(t + 1, 5)))
     # and of signed zeros the positive ones stand above the negative
     signed = jnp.where(jnp.arange(64) % 2 == 0, -0.0, 0.0)[None, None] \
         * jnp.ones((1, 64, 1))
-    got = np.asarray(model.select_topk(signed, 0, 3))[0]
+    got = np.asarray(dsa.select_topk(signed, 0, 3))[0]
     assert got[63].nonzero()[0].tolist() == [1, 3, 5]
 
 
@@ -89,7 +89,7 @@ def test_the_select_kernel_is_its_twin(s, topk):
     same mask bit for bit (their scores are the same sums in the same
     order) and the same logsumexp over the selected scores."""
     qi, ki, w = _indexer(s, b=2)
-    twin_sel, twin_lse = model._index_select_blocks(qi, ki, w, topk, 64,
+    twin_sel, twin_lse = dsa._index_select_blocks(qi, ki, w, topk, 64,
                                                     True)
     sel, lse = sa.index_select(qi, ki, w, topk=topk, interpret=True)
     assert sel.dtype == jnp.int8 and sel.shape == (2, s, s)
@@ -97,7 +97,7 @@ def test_the_select_kernel_is_its_twin(s, topk):
     np.testing.assert_allclose(lse, twin_lse, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(
         np.asarray(sel) != 0,
-        sorted_selection(model.index_scores(qi, ki, w), topk))
+        sorted_selection(dsa.index_scores(qi, ki, w), topk))
 
 
 def dense(q, k, v, sel):
@@ -120,7 +120,7 @@ def _selection(s, topk, b=1, empty=True):
     the rows of the last two blocks select nothing of block 1 (a whole
     tile pair, (2, 1) and (3, 1), is empty) and rows of block 2 nothing of
     their own block but the diagonal key."""
-    sel = model._index_select_blocks(*_indexer(s, b=b), topk, 64, True)[0]
+    sel = dsa._index_select_blocks(*_indexer(s, b=b), topk, 64, True)[0]
     if empty:
         sel = sel.at[:, 2 * BLOCK:, BLOCK:2 * BLOCK].set(0)
         sel = sel.at[:, 2 * BLOCK:3 * BLOCK, 2 * BLOCK:3 * BLOCK].set(0)
@@ -141,7 +141,7 @@ def test_the_forward_kernel_under_a_selection_is_the_dense_softmax(
     want = dense(q, k, v, sel)
     got = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                   select=sel)
-    twin = model._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
+    twin = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
     for g, t, x in zip(got, twin, want):
         np.testing.assert_allclose(g, x, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(t, x, rtol=2e-5, atol=2e-5)
@@ -154,7 +154,7 @@ def walk_backward(q, k, v, do, o, lse, sel):
     delta = jnp.sum(do * o, axis=-1)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     select = (jnp.swapaxes(sel, 1, 2), fa._tile_flags(sel, BLOCK))
-    for ij in model._window_pairs(q.shape[2] // BLOCK, None):
+    for ij in causal._window_pairs(q.shape[2] // BLOCK, None):
         acc = fa.attn_block_backward(
             jnp.asarray(ij, jnp.int32), q, k, v, do, lse, delta, *acc,
             block=BLOCK, interpret=True, select=select)
@@ -176,7 +176,7 @@ def test_the_backward_under_a_selection_is_autodiff(h, n_kv, d, blocks):
     want = jax.grad(lambda *a: jnp.sum(dense(*a, sel)[0] * do),
                     argnums=(0, 1, 2))(q, k, v)
     o, lse = dense(q, k, v, sel)
-    twin = model._causal_bwd(BLOCK, True, None, (q, k, v, o, lse), do,
+    twin = causal._causal_bwd(BLOCK, True, None, (q, k, v, o, lse), do,
                              select=sel)
     got = walk_backward(q, k, v, do, o, lse, sel)
     for g, t, x in zip(got, twin, want):
@@ -191,7 +191,7 @@ def test_the_selected_attention_hands_no_gradient_to_its_logsumexp():
     s = 2 * BLOCK
     q, k, v = _qkv(s, dt=jnp.float32)
     sel = _selection(s, 50, empty=False)
-    fn = lambda q, k, v: jnp.sum(model.selected_flash_attention(
+    fn = lambda q, k, v: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, 50)[1])
     for g in jax.grad(fn, argnums=(0, 1, 2))(q, k, v):
         assert not np.any(np.asarray(g))
@@ -206,7 +206,7 @@ def loss_by_definition(qi, ki, w, q, k, sel):
             precision=jax.lax.Precision.HIGHEST) / math.sqrt(q.shape[-1]),
         -jnp.inf), -1)
     pbar = jnp.mean(a, axis=1)
-    scores = model.index_scores(qi, ki, w)
+    scores = dsa.index_scores(qi, ki, w)
     logq = jax.nn.log_softmax(jnp.where(sel != 0, scores, -jnp.inf), -1)
     live = pbar > 0
     return jnp.sum(jnp.where(live, pbar * (jnp.log(jnp.where(
@@ -220,13 +220,13 @@ def test_the_alignment_loss_kernel_is_the_definition_and_its_autodiff():
     s, topk = 2 * sa.LOSS_TILE // 2, 60
     qi, ki, w = _indexer(s, b=2, dt=jnp.float32, ties=False)
     q, k, v = _qkv(s, 4, 2, 128, b=2, dt=jnp.float32)
-    sel, ilse = model._index_select_blocks(qi, ki, w, topk, 64, True)
-    _, lse = model._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
+    sel, ilse = dsa._index_select_blocks(qi, ki, w, topk, 64, True)
+    _, lse = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
     want = loss_by_definition(qi, ki, w, q, k, sel)
     grads = jax.grad(lambda *a: jnp.sum(loss_by_definition(*a, q, k, sel)),
                      argnums=(0, 1, 2))(qi, ki, w)
     got = sa.index_loss(q, k, lse, qi, ki, w, ilse, sel, interpret=True)
-    twin = model._index_loss_blocks(qi, ki, w, q, k, lse, ilse, sel, 64,
+    twin = dsa._index_loss_blocks(qi, ki, w, q, k, lse, ilse, sel, 64,
                                     True)
     for g, t, x in zip(got, twin, (want, *grads)):
         np.testing.assert_allclose(g, x, rtol=2e-4, atol=2e-5)
@@ -239,14 +239,14 @@ def test_the_alignment_loss_reaches_the_indexer_alone():
     s = 2 * BLOCK
     qi, ki, w = _indexer(s, dt=jnp.float32, ties=False)
     q, k, v = _qkv(s, dt=jnp.float32)
-    sel, ilse = model._index_select_blocks(qi, ki, w, 40, 64, True)
-    _, lse = model._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
-    total = lambda *a: model.index_alignment_loss(*a, ilse, sel, 64, True)[0]
+    sel, ilse = dsa._index_select_blocks(qi, ki, w, 40, 64, True)
+    _, lse = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
+    total = lambda *a: dsa.index_alignment_loss(*a, ilse, sel, 64, True)[0]
     grads = jax.grad(total, argnums=(0, 1, 2, 3, 4, 5))(qi, ki, w, q, k, lse)
     assert all(np.any(np.asarray(g)) for g in grads[:3])
     assert not any(np.any(np.asarray(g)) for g in grads[3:])
     rows = lambda *a: jnp.sum(
-        model.index_alignment_loss(*a, ilse, sel, 64, True)[1])
+        dsa.index_alignment_loss(*a, ilse, sel, 64, True)[1])
     assert not any(np.any(np.asarray(g)) for g in jax.grad(
         rows, argnums=(0, 1, 2))(qi, ki, w, q, k, lse))
 
@@ -259,7 +259,7 @@ def test_without_a_selection_the_callers_programs_are_what_they_were():
     fwd = lambda q, k, v, **kw: fa.flash_causal_forward(
         q, k, v, block=BLOCK, interpret=True, **kw)
     assert text(fwd, q, k, v) == text(fwd, q, k, v, select=None)
-    twin = lambda q, k, v, **kw: model._causal_fwd_blocks(
+    twin = lambda q, k, v, **kw: causal._causal_fwd_blocks(
         q, k, v, BLOCK, True, **kw)
     assert text(twin, q, k, v) == text(twin, q, k, v, select=None)
 
@@ -272,7 +272,7 @@ def test_the_counters_count_what_was_built():
     sel = _selection(s, topk, empty=False)
     before = {n: spc.read(n) for n in ("dsa_built", "dsa_keys_selected",
                                        "dsa_keys_causal", "attn_built")}
-    jax.grad(lambda q: jnp.sum(model.selected_flash_attention(
+    jax.grad(lambda q: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, topk)[0]))(q)
     moved = {n: spc.read(n) - v for n, v in before.items()}
     assert moved["dsa_built"] == moved["attn_built"] >= 2

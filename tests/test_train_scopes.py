@@ -24,9 +24,10 @@ from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 from test_qwen3next_train import F32 as QWEN3NEXT
 from test_keye_train import F32 as KEYE
+from test_parallel import MODEL_PATH
 from test_smallthinker_train import F32 as SMALLTHINKER
 
-from ompi_tpu.parallel import train
+from ompi_tpu.parallel import model, objective, train
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import trace
 from ompi_tpu.tools import hlo_same
@@ -317,11 +318,8 @@ def test_every_op_of_a_mixer_lands_under_its_scope(nemotron):
 def test_every_op_of_a_short_convolution_lands_under_its_scope(lfm2):
     """Every instruction whose path passes through ``short_conv`` has
     ``otpu_conv`` and one of its two parts in its chain, in the forward
-    pass, the recomputed one and the backward one; the new names stand
-    behind the vocabulary's earlier ones; and the layer's other sublayers
-    keep the scopes they have in the other models."""
-    assert trace.STEP_SCOPES[-13:-10] == ("otpu_conv", "otpu_conv_proj",
-                                        "otpu_conv_gate")
+    pass, the recomputed one and the backward one; and the layer's other
+    sublayers keep the scopes they have in the other models."""
     ops = ran(lfm2[1])
     parts = {"otpu_conv_proj", "otpu_conv_gate"}
     conv = [v for v in ops.values() if "otpu_conv" in v["chain"]]
@@ -342,13 +340,9 @@ def test_every_op_of_a_delta_net_operator_lands_under_its_scope(qwen3next):
     """Every instruction whose path passes through ``gated_delta_net`` has
     ``otpu_gdn`` and one of its four parts in its chain, in the forward
     pass, the recomputed one and the backward one; the chunks' recurrence
-    (a ``while``) is under the rule's scope; the five new names stand
-    behind the vocabulary's earlier ones; the attention gate lies under
+    (a ``while``) is under the rule's scope; the attention gate lies under
     ``otpu_attn_proj`` and the shared expert's gate under
     ``otpu_shared_expert``; and no bias update is in the step."""
-    assert trace.STEP_SCOPES[-10:-5] == (
-        "otpu_gdn", "otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
-        "otpu_gdn_norm")
     ops = ran(qwen3next[1])
     parts = {"otpu_gdn_proj", "otpu_gdn_conv", "otpu_gdn_rule",
              "otpu_gdn_norm"}
@@ -384,7 +378,6 @@ def test_a_window_layers_attention_lands_under_its_own_scope(smallthinker):
     for under in (swa, full):
         assert {"forward", "remat", "backward"} <= {v["pass"] for v in under}
         assert any("otpu_attn_proj" in v["chain"] for v in under)
-    assert trace.STEP_SCOPES[-5] == "otpu_swa"
 
 
 def test_a_sparse_attention_sublayer_lands_under_its_own_scopes(keye):
@@ -406,8 +399,6 @@ def test_a_sparse_attention_sublayer_lands_under_its_own_scopes(keye):
             if "otpu_dsa_select" in v["chain"]} == {"forward"}
     assert {"forward", "remat", "backward"} <= {
         v["pass"] for v in ops.values() if "otpu_dsa_index" in v["chain"]}
-    assert trace.STEP_SCOPES[-4:] == ("otpu_dsa", "otpu_dsa_index",
-                                      "otpu_dsa_select", "otpu_dsa_loss")
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
@@ -514,7 +505,7 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
     assert ("otpu_gdn_rule" in scopes["remat"]) == (cfg is QWEN3NEXT)
     # the elementwise rest of the router is recomputed: scores, weights
     assert "otpu_router" in scopes["remat"]
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     bare, _ = routing_by_pass(cfg)
     assert bare["forward"] == set(ROUTING)
@@ -538,7 +529,7 @@ def test_a_layers_checkpoint_keeps_the_selection_and_the_losss_gradients(
     assert kinds.get("remat", set()) == set()
     assert "the selection's counting" not in kinds.get("backward", set())
     assert {"otpu_attn_proj", "otpu_dsa_index"} <= scopes["remat"]
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     bare, _ = routing_by_pass(KEYE, SELECTION)
     assert bare["forward"] == bare["remat"] == set(SELECTION)
@@ -560,7 +551,7 @@ def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
     assert kinds["forward"] == kinds["backward"] == set(ATTENTION)
     assert kinds.get("remat", set()) == set()
     assert "otpu_attn_proj" in scopes["remat"]
-    monkeypatch.setattr(train, "layer_checkpoint_policy",
+    monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     bare, _ = routing_by_pass(cfg, ATTENTION)
     assert bare["forward"] == bare["remat"] == set(ATTENTION)
@@ -587,11 +578,11 @@ def test_the_process_gives_the_maps_of_the_steps_it_ran(joyai):
 
 
 def test_the_vocabulary_is_the_sources_and_the_benchmarks():
-    """Every ``named_scope`` the step's four files open is in
-    ``STEP_SCOPES``, every name of it is opened somewhere, and the
-    benchmark's data file repeats it."""
-    opened = set()
-    for name in ("train", "model", "experts", "layers"):
+    """Every ``named_scope`` the model path's files open (a sublayer's
+    own is its entry's ``scope``) is in ``STEP_SCOPES``, every name of it
+    is opened somewhere, and the benchmark's data file repeats it."""
+    opened = {entry.scope for entry in model.SUBLAYERS}
+    for name in MODEL_PATH:
         with open(os.path.join(ROOT, "ompi_tpu", "parallel", name + ".py"),
                   encoding="utf-8") as f:
             opened |= set(re.findall(r'named_scope\("(otpu_\w+)"\)',
