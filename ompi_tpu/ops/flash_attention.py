@@ -31,6 +31,18 @@ map starting at the far tile; the backward's walk
 (``parallel/causal._window_pairs``) leaves out the pairs out of reach and
 the kernel tells the far pair from ``ij`` and w.
 
+A ``select`` makes both kernels attention under a data-dependent
+selection, which travels **packed, eight keys a byte**: (b, s, s / 8)
+int8, query-major.  The keys go by groups of ``8 x lanes`` (1,024 where
+that divides s: ``select_lanes``), and within a group byte c (lane c of
+128) holds key ``lanes x m + c`` in bit m: bit plane m of a (rows, lanes)
+byte tile is the mask of keys ``lanes x m ..`` of the group, and of the
+transposed byte tile (lanes, q) it is those rows of the key-major mask, so
+a reader unpacks its tile in VMEM with shifts, ands, compares and
+tile-aligned concatenation (``_selected``) and nothing crosses lanes.
+``ops/sparse_attention.pack_selection`` / ``unpack_selection`` are the
+format's two ends outside a kernel.
+
 Scores compute in float32 on the MXU via ``preferred_element_type``.
 Ring attention's block update (``parallel/flagship.ring_attention``) is
 plain ``jnp`` on every platform and no kernel of this module.
@@ -61,7 +73,7 @@ BWD_VMEM_LIMIT = 64 << 20
 
 def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                      dqo_ref, dko_ref, dvo_ref, selt_ref=None, live=None):
+                      dqo_ref, dko_ref, dvo_ref, chosen=None, live=None):
     """One q tile of block i of one query head against kv block j of the
     key-value head its group shares, a tile of kv positions at a time,
     **transposed**: scores are held (kv, q), so that the logsumexp and
@@ -73,8 +85,8 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
     its last: the group's sum is made in float32, here.  ``far_by``
     (None without a window) is the window in blocks: the pair whose
     blocks lie that far apart is the far one, masked the other way.
-    ``selt_ref`` (None without a selection: ``_bwd_select_kernel`` gives
-    it) is the pair's tile of a selection, key-major: every kv tile is
+    ``chosen`` (None without a selection: ``_bwd_select_kernel`` gives
+    it) makes kv tile c's mask (kv, q) of a selection: every kv tile is
     then masked by it and by nothing else, where ``live`` says the pair
     selects anything at all."""
     t = pl.program_id(1)
@@ -107,9 +119,8 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         k, v = k_ref[0, rows, :], v_ref[0, rows, :]
         q, do = q_ref[0, qs, :], do_ref[0, qs, :]
         s = dot(k, q, nt_dims) * scale                      # (kv, q)
-        if selt_ref is not None:
-            s = jnp.where(selt_ref[0, rows, qs].astype(jnp.int32) != 0, s,
-                          -jnp.inf)
+        if chosen is not None:
+            s = jnp.where(chosen(c), s, -jnp.inf)
         elif masked:
             at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                        axis)
@@ -122,7 +133,7 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         dko_ref[0, rows, :] += dot(ds, q, nn_dims)
         dqo_ref[0, qs, :] += dot(ds, k, tn_dims)
 
-    if selt_ref is not None:
+    if chosen is not None:
         # the selection holds the diagonal too: no tile goes by position
         for c in range(k_ref.shape[1] // tile):
             pl.when(live)(functools.partial(part, c, 0, tile, True))
@@ -153,16 +164,27 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
                     part(c, lo, strip, True, far=True)
 
 
-def _bwd_select_kernel(scale, rep, heads, nb, ij_ref, flags_ref, q_ref, k_ref,
-                       v_ref, do_ref, lse_ref, delta_ref, selt_ref, *accs):
-    """``_bwd_block_kernel`` under a selection: ``selt_ref`` (1, block, q
-    tile) int8 is the pair's tile of the mask, key-major as the scores are
-    held; ``flags_ref`` (b x blocks x blocks) says which pairs select
-    anything, and a pair that does not only hands its accumulators on."""
+def _bwd_select_kernel(scale, rep, heads, nb, lanes, ij_ref, flags_ref, q_ref,
+                       k_ref, v_ref, do_ref, lse_ref, delta_ref, selt_ref,
+                       *accs):
+    """``_bwd_block_kernel`` under a selection: ``selt_ref`` (1, bytes, q
+    tile) int8 is the pair's block of the packed selection, key-major as
+    the scores are held (``_select_blocks`` says which bytes; a kv tile's
+    mask is unpacked in VMEM); ``flags_ref`` (b x blocks x blocks) says
+    which pairs select anything, and a pair that does not only hands its
+    accumulators on."""
+    block, tile = k_ref.shape[1], q_ref.shape[1]
     live = flags_ref[(pl.program_id(0) // heads) * nb * nb
                      + ij_ref[0] * nb + ij_ref[1]] != 0
+
+    def chosen(c):
+        if tile == block:
+            return _selected(selt_ref[0], 0, lanes, tile, ij_ref[1])
+        return _selected(selt_ref[0, pl.ds(c * tile // 8, tile // 8), :], 0,
+                         lanes, tile)
+
     _bwd_block_kernel(scale, None, rep, None, ij_ref, q_ref, k_ref, v_ref,
-                      do_ref, lse_ref, delta_ref, *accs, selt_ref=selt_ref,
+                      do_ref, lse_ref, delta_ref, *accs, chosen=chosen,
                       live=live)
 
 
@@ -198,13 +220,68 @@ def _window_blocks(window, block: int, length: int):
     return window // block
 
 
+#: keys a group of a packed selection where that divides the length: eight
+#: bit planes of 128 lanes
+SELECT_GROUP = 1024
+
+
+def select_lanes(length: int) -> int:
+    """The bytes a group of a packed selection of ``length`` keys: an
+    eighth of the largest power of two up to ``SELECT_GROUP`` that divides
+    it (128 at a multiple of 1,024)."""
+    if length % 8:
+        raise ValueError(f"a selection of {length} keys packs into no whole "
+                         "number of bytes")
+    group = SELECT_GROUP
+    while length % group:
+        group //= 2
+    return group // 8
+
+
+def _select_blocks(length: int, tile: int):
+    """How a reader's kv tiles of ``tile`` keys lie in a packed selection
+    of ``length`` keys: (``select_lanes``, the bytes a block of the packed
+    axis, the kv tiles that share one block).  A tile is whole groups, or
+    some of one group's bit planes (then the block is the group's bytes,
+    and ``_selected`` finds a tile's planes in them by the tile's number)."""
+    lanes = select_lanes(length)
+    group = 8 * lanes
+    if tile % group and (group % tile or tile % lanes):
+        raise ValueError(f"a tile of {tile} keys is no whole number of "
+                         f"groups of {group} or of their {lanes}-key planes")
+    return lanes, max(tile, group) // 8, max(1, group // tile)
+
+
+def _selected(packed, axis: int, lanes: int, keys: int, j=0):
+    """The boolean mask of ``keys`` keys along ``axis``, unpacked from the
+    int8 bytes of a packed selection along it (a block as
+    ``_select_blocks`` cuts them): whole groups of ``lanes`` bytes (every
+    plane of each, in order), or one group's bytes, of which kv tile ``j``
+    (may be traced) is ``keys / lanes`` planes.  Shifts, ands, compares
+    and a concatenation of whole planes: in a kernel nothing crosses lanes
+    or sublane tiles."""
+    x = packed.astype(jnp.int32)        # sign-extended: bit 7 stays bit 7
+    planes = min(keys // lanes, 8)
+    first = 0 if planes == 8 else (j % (8 // planes)) * planes
+    parts = [jax.lax.slice_in_dim(x, lo, lo + lanes, axis=axis) >> (first + m)
+             for lo in range(0, x.shape[axis], lanes) for m in range(planes)]
+    bits = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+    return (bits & 1) != 0
+
+
 def _tile_flags(select, tile: int):
-    """Which (q tile, kv tile) pairs of a selection (b, s, s) select
-    anything: int32 (b x tiles x tiles), 1 or 0."""
+    """Which (q tile, kv tile) pairs of a packed selection (b, s, s / 8)
+    select anything: int32 (b x tiles x tiles), 1 or 0.  One bitwise-or
+    reduction of the bytes by (q tile, group) says which planes of the
+    group hold a bit; a kv tile is some of those planes."""
     b, s, _ = select.shape
     nt = s // tile
-    return jnp.any(select.reshape(b, nt, tile, nt, tile) != 0,
-                   axis=(2, 4)).astype(jnp.int32).reshape(-1)
+    lanes = _select_blocks(s, tile)[0]
+    ors = jax.lax.reduce(select.reshape(b, nt, tile, -1, lanes), jnp.int8(0),
+                         jax.lax.bitwise_or, (2, 4)).astype(jnp.int32)
+    planes = (ors[..., None] >> jnp.arange(8, dtype=jnp.int32)) & 1
+    return jnp.any(planes.reshape(b, nt, nt, tile // lanes) != 0,
+                   axis=-1).astype(jnp.int32).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
@@ -233,10 +310,12 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     ``parallel/causal._bwd_pair``.  With ``window`` (positions, whole
     blocks) the pair whose blocks lie the window apart is the far one
     and masked as such; the caller walks no pair beyond it.  With
-    ``select`` = (the selection key-major (b, s kv, s q) int8, its pairs'
-    flags as ``_tile_flags(.., block)`` gives them) a pair is masked by
-    its tile of the selection and by nothing else, and a pair that selects
-    nothing hands the accumulators on (``_bwd_select_kernel``).
+    ``select`` = (the packed selection key-major (b, s / 8, s q) int8:
+    ``flash_causal_forward``'s, its last two axes exchanged; its pairs'
+    flags as ``_tile_flags(.., block)`` gives them of the query-major one)
+    a pair is masked by its tile of the selection and by nothing else, and
+    a pair that selects nothing hands the accumulators on
+    (``_bwd_select_kernel``).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -291,18 +370,20 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
 def _select_block_backward(operands, select, specs, dims, vma, interpret,
                            like):
     """``attn_block_backward``'s call under a selection: one more scalar
-    operand (the pairs' flags) and one more input (the mask's tile)."""
+    operand (the pairs' flags) and one more input (the pair's block of the
+    packed selection)."""
     q_spec, kv_spec, row_spec, acc_specs = specs
     b, h, s, d, hv, rep, tq, nt, block = dims
     selt, flags = select
+    lanes, nbytes, share = _select_blocks(s, block)
     lift = lambda spec: pl.BlockSpec(
         spec.block_shape, lambda g, t, ij, fl: spec.index_map(g, t, ij))
-    sel_spec = pl.BlockSpec((1, block, tq), lambda g, t, ij, fl: (
-        g // h, ij[1], ij[0] * nt + t))
+    sel_spec = pl.BlockSpec((1, nbytes, tq), lambda g, t, ij, fl: (
+        g // h, ij[1] // share, ij[0] * nt + t))
     ins = [q_spec(d), kv_spec(d), kv_spec(hv), q_spec(hv), row_spec, row_spec]
     out = pl.pallas_call(
         functools.partial(_bwd_select_kernel, 1.0 / math.sqrt(d), rep, h,
-                          s // block),
+                          s // block, lanes),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
                         for o in operands[7:]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -332,12 +413,12 @@ FWD_VMEM_LIMIT = 64 << 20
 
 
 def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask,
-                   sel_ref=None):
+                   chosen=None):
     """One online-softmax update of a q tile's running max, denominator
     and float32 numerator (VMEM scratch) by one kv tile.  ``mask``: None,
     ``"diagonal"`` (key column c visible to query row r iff c <= r),
     ``"far"`` (a window's far tile: iff c > r) or ``"select"`` (iff
-    ``sel_ref``'s tile of a selection (1, q, kv) int8 says so).  Scores
+    ``chosen()``, the tile (q, kv) of a selection, says so).  Scores
     are held (q, kv):
     a row's statistics are columns, and the two matmuls are the MXU's
     plain forms."""
@@ -346,7 +427,7 @@ def _online_update(scale, q_ref, k_ref, v_ref, m_ref, den_ref, num_ref, mask,
     q, k, v = q_ref[0], k_ref[0], v_ref[0]
     s = dot(q, k, (((1,), (1,)), ((), ()))) * scale     # (q, kv)
     if mask == "select":
-        s = jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, -jnp.inf)
+        s = jnp.where(chosen(), s, -jnp.inf)
     elif mask is not None:
         at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                    axis)
@@ -440,15 +521,18 @@ def _window_fwd_kernel(scale, w, q_ref, k_ref, v_ref, o_ref, lse_ref,
         _write_out(o_ref, lse_ref, *state)
 
 
-def _select_fwd_kernel(scale, heads, flags_ref, q_ref, k_ref, v_ref, sel_ref,
-                       o_ref, lse_ref, m_ref, den_ref, num_ref):
+def _select_fwd_kernel(scale, heads, lanes, flags_ref, q_ref, k_ref, v_ref,
+                       sel_ref, o_ref, lse_ref, m_ref, den_ref, num_ref):
     """``_causal_fwd_kernel`` under a selection: every kv tile up to the
-    diagonal one is masked by ``sel_ref``'s tile (which holds the diagonal
-    too) and by nothing else; a tile pair that selects nothing
-    (``flags_ref`` (b x tiles x tiles)) is passed over."""
+    diagonal one is masked by its bits of ``sel_ref`` (1, q tile, bytes)
+    int8, the tile's block of the packed selection (``_select_blocks``;
+    unpacked in VMEM; it holds the diagonal too) and by nothing else; a
+    tile pair that selects nothing (``flags_ref`` (b x tiles x tiles)) is
+    passed over."""
     g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nt = pl.num_programs(1)
     state = (m_ref, den_ref, num_ref)
+    chosen = lambda: _selected(sel_ref[0], 1, lanes, k_ref.shape[1], j)
 
     @pl.when(j == 0)
     def _():
@@ -457,7 +541,7 @@ def _select_fwd_kernel(scale, heads, flags_ref, q_ref, k_ref, v_ref, sel_ref,
     live = flags_ref[(g // heads) * nt * nt + i * nt + jnp.minimum(i, j)] != 0
     pl.when(jnp.logical_and(j <= i, live))(functools.partial(
         _online_update, scale, q_ref, k_ref, v_ref, *state, "select",
-        sel_ref))
+        chosen))
 
     @pl.when(j == i)
     def _():
@@ -485,10 +569,13 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     With ``window`` (positions, whole tiles) the grid's third axis is
     the window's tiles and the diagonal one (``_window_fwd_kernel``), the
     index map starting at the far tile (clamped to the sequence's first).
-    With ``select`` (b, s, s) int8, query-major, key u is visible to query
-    t iff ``select[b, t, u]`` is not 0 (the selection holds causality:
-    nothing above the diagonal), every row selects a key, and a tile pair
-    that selects nothing is passed over (``_select_fwd_kernel``).
+    With ``select`` (b, s, s / 8) int8, a selection packed eight keys a
+    byte, query-major (the module's head has the layout;
+    ``ops/sparse_attention.pack_selection`` makes it of a mask), key u is
+    visible to query t iff its bit of row t is set (the selection holds
+    causality: nothing above the diagonal), every row selects a key, and a
+    tile pair that selects nothing is passed over
+    (``_select_fwd_kernel``).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -517,8 +604,10 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     if select is not None:
         lift = lambda fn: (lambda g, i, j, fl: fn(g, i, j))
         vma = vma | jax.typeof(select).vma
+        lanes, nbytes, share = _select_blocks(s, tile)
         o, lse = pl.pallas_call(
-            functools.partial(_select_fwd_kernel, 1.0 / math.sqrt(d), h),
+            functools.partial(_select_fwd_kernel, 1.0 / math.sqrt(d), h,
+                              lanes),
             out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
                        jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -526,8 +615,8 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
                 in_specs=[pl.BlockSpec((1, tile, d), lift(q_map)),
                           pl.BlockSpec((1, tile, d), lift(kv_map)),
                           pl.BlockSpec((1, tile, hv), lift(kv_map)),
-                          pl.BlockSpec((1, tile, tile), lambda g, i, j, fl: (
-                              g // h, i, jnp.minimum(i, j)))],
+                          pl.BlockSpec((1, tile, nbytes), lambda g, i, j, fl: (
+                              g // h, i, jnp.minimum(i, j) // share))],
                 out_specs=(pl.BlockSpec((1, tile, hv), lift(q_map)),
                            pl.BlockSpec((1, 1, tile),
                                         lambda g, i, j, fl: (g, 0, i))),

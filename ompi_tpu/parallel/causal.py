@@ -82,13 +82,14 @@ def _window_in_blocks(window, block: int, length: int):
 
 def _select_bias(select, i, j, block: int, rep: int):
     """A selection's (q block i, kv block j) as a bias for a group's
-    folded rows, (b, 1, rep x block, block): 0 where ``select`` (b, s, s)
-    says a key is visible, -inf elsewhere.  ``i`` and ``j`` may be
-    traced."""
-    b, s, _ = select.shape
-    nb = s // block
-    tile = select.reshape(b, nb, block, nb, block)[:, i, :, j]
-    bias = jnp.where(tile != 0, 0.0, -jnp.inf).astype(jnp.float32)
+    folded rows, (b, 1, rep x block, block): 0 where the packed ``select``
+    (b, s, s / 8) says a key is visible, -inf elsewhere; the block's bits
+    alone are unpacked.  ``i`` and ``j`` may be traced."""
+    from ompi_tpu.ops.sparse_attention import unpack_selection
+
+    rows = jax.lax.dynamic_slice_in_dim(select, i * block, block, axis=1)
+    tile = unpack_selection(rows, j * block, block)
+    bias = jnp.where(tile, 0.0, -jnp.inf).astype(jnp.float32)
     return jnp.tile(bias, (1, rep, 1))[:, None]
 
 
@@ -109,10 +110,11 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
     blocks) q block i meets kv blocks max(0, i - w) .. i, the far one (i
     - w) under ``_far_bias``; its last query row sees nothing of it, and
     that row's running max stays -inf through it.  Under ``select`` (b,
-    s, s) int8 (a data-dependent selection that holds causality; None:
-    everything here is what it was) every block pair goes under its tile
-    of the selection (``_select_bias``) and under no mask by position,
-    and any row may see nothing of any block."""
+    s, s / 8) int8 (a data-dependent selection that holds causality,
+    packed eight keys a byte as ``ops/sparse_attention.pack_selection``
+    packs a mask; None: everything here is what it was) every block pair
+    goes under its tile of the selection (``_select_bias``) and under no
+    mask by position, and any row may see nothing of any block."""
     w = _window_in_blocks(window, block, q.shape[2])
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
@@ -329,8 +331,9 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
     and dv's with k's and v's own heads.  Both walks: unrolled up to
     ``UNROLLED_BLOCKS`` blocks, one ``lax.scan`` beyond; the arrays go
     in whole and the pair is an operand, so neither slices.  Under a
-    selection the kernel reads the mask key-major, as it holds the
-    scores: transposed once here, beside the pairs' flags."""
+    selection the kernel reads the packed bytes key-major, as it holds
+    the scores: (b, s / 8, s), transposed once here, beside the pairs'
+    flags."""
     from ompi_tpu.ops.flash_attention import (_tile_flags,
                                               attn_block_backward)
 
@@ -369,12 +372,14 @@ def _count_dsa(q, topk: int) -> None:
     """SPC ``dsa_built``: the attention passes made under a selection,
     forward rule or backward rule, while steps were traced (as
     ``attn_window_built``); ``dsa_keys_selected`` the (query, key) pairs
-    those passes attend to, ``min(t + 1, topk)`` a query, and
+    those passes attend to, ``min(t + 1, topk)`` a query,
     ``dsa_keys_causal`` those full causal passes of their lengths would,
-    both from the shapes."""
+    and ``dsa_mask_bytes`` the bytes of the selection a pass reads, packed
+    eight keys a byte, all from the shapes."""
     b, _, s, _ = q.shape
     full = min(s, topk)
     spc.record("dsa_built", 1)
+    spc.record("dsa_mask_bytes", b * s * s // 8)
     spc.record("dsa_keys_selected",
                b * (full * (full + 1) // 2 + (s - full) * topk))
     spc.record("dsa_keys_causal", b * s * (s + 1) // 2)
@@ -384,7 +389,9 @@ def _count_dsa(q, topk: int) -> None:
 def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
                              topk: int):
     """``causal_flash_attention`` under a data-dependent selection:
-    ``select`` (b, s, s) int8, query-major, says which keys u <= t query
+    ``select`` (b, s, s / 8) int8, query-major and packed eight keys a
+    byte (``ops/sparse_attention.index_select`` writes it so;
+    ``pack_selection`` packs a given mask), says which keys u <= t query
     t attends to (every row selects a key).  Returns (o (b, h, s, hv)
     float32, the logsumexp (b, h, s) float32 over the selected keys);
     ``topk``, the most keys a row selects, is read by the counters alone.

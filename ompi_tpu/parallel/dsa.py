@@ -23,10 +23,11 @@ from ompi_tpu.parallel.sublayer import Sublayer, zeros
 
 
 # what a layer's ``jax.checkpoint`` keeps of a learned sparse attention
-# sublayer beside attention's own: the selection (an int8 mask; made again
-# it costs the index scores and the counting passes, and a second choice
-# need not be the first), its rows' logsumexp, and the alignment loss's rows
-# and gradients, which its one pass makes together
+# sublayer beside attention's own: the selection (packed eight keys a byte,
+# (b, s, s / 8) int8; made again it costs the index scores and the counting
+# passes, and a second choice need not be the first), its rows' logsumexp,
+# and the alignment loss's rows and gradients, which its one pass makes
+# together
 DSA_SELECTION = "otpu_dsa_selection"
 DSA_INDEX_LSE = "otpu_dsa_index_lse"
 DSA_LOSS = "otpu_dsa_loss"
@@ -79,16 +80,17 @@ def select_topk(scores, first: int, topk: int):
 
 
 def _index_select_blocks(qi, ki, w, topk: int, rows: int, interpret: bool):
-    """(the selection (b, s, s) int8, each row's logsumexp over its
-    selected scores (b, s) float32) of the indexer's ``qi`` (b, J, s, di),
+    """(the selection (b, s, s / 8) int8, packed eight keys a byte as the
+    kernel packs it, each row's logsumexp over its selected scores (b, s)
+    float32) of the indexer's ``qi`` (b, J, s, di),
     ``ki`` (b, s, di) and ``w`` (b, s, J).  Where Mosaic compiles one call
     of ``ops/sparse_attention.index_select``, which keeps a tile's scores
     in VMEM; elsewhere (the CPU) ``rows`` query rows at a time
     (``index_scores``, ``select_topk``), so that no (s, s, J) array and
     only one block's (rows, s) scores are ever held."""
-    if not interpret:
-        from ompi_tpu.ops.sparse_attention import index_select
+    from ompi_tpu.ops.sparse_attention import index_select, pack_selection
 
+    if not interpret:
         return index_select(qi, ki, w, topk=topk, interpret=False)
     b, heads, s, di = qi.shape
     rows = rows if s % rows == 0 else s
@@ -99,13 +101,13 @@ def _index_select_blocks(qi, ki, w, topk: int, rows: int, interpret: bool):
         sc = index_scores(qb, ki, wb)
         chosen = select_topk(sc, first, topk)
         lse = jax.nn.logsumexp(jnp.where(chosen, sc, -jnp.inf), axis=-1)
-        return chosen.astype(jnp.int8), lse
+        return pack_selection(chosen), lse
 
     sel, lse = jax.lax.map(block, (
         jnp.moveaxis(qi.reshape(b, heads, nb, rows, di), 2, 0),
         jnp.moveaxis(w.reshape(b, nb, rows, heads), 1, 0),
         jnp.arange(nb, dtype=jnp.int32) * rows))
-    return (jnp.moveaxis(sel, 0, 1).reshape(b, s, s),
+    return (jnp.moveaxis(sel, 0, 1).reshape(b, s, s // 8),
             jnp.moveaxis(lse, 0, 1).reshape(b, s))
 
 
@@ -126,7 +128,10 @@ def _index_loss_rows(qi, ki, w, q, k, lse, select, rows: int):
     """The alignment loss by row (b, s), differentiable in ``qi``, ``ki``
     and ``w`` (``ops/sparse_attention.index_loss``'s ``jnp`` twin):
     ``KL(pbar[t, .] || softmax_S(I[t, .]))`` over the selected keys, a
-    block of ``rows`` query rows at a time."""
+    block of ``rows`` query rows at a time, whose rows of the packed
+    ``select`` (b, s, s / 8) are unpacked with it."""
+    from ompi_tpu.ops.sparse_attention import unpack_selection
+
     b, heads, s, di = qi.shape
     rows = rows if s % rows == 0 else s
     nb = s // rows
@@ -135,7 +140,7 @@ def _index_loss_rows(qi, ki, w, q, k, lse, select, rows: int):
 
     def block(xs):
         qib, wb, qb, lse_b, sel_b = xs
-        chosen = sel_b != 0
+        chosen = unpack_selection(sel_b)
         sc = index_scores(qib, ki, wb)
         logq = sc - jax.nn.logsumexp(jnp.where(chosen, sc, -jnp.inf),
                                      axis=-1, keepdims=True)
@@ -218,9 +223,12 @@ def dsa_attention(p, x, cfg, *, interpret: bool, at=None):
 
     Returns (the sublayer's output, {``index_kl_sum``: the alignment loss
     summed over the rows}, what a check reads: ``attn_qk_in`` / ``attn_qk``
-    as ``gqa_attention``; the selection packed eight keys a byte
-    (``dsa_selection_seq`` (b, s, s / 8) uint8, key u in bit u % 8 of byte
-    u // 8); the index key ``dsa_ki_seq`` (T, di), the first key-value
+    as ``gqa_attention``; the selection packed eight keys a byte in key
+    order (``dsa_selection_seq`` (b, s, s / 8) uint8, key u in bit u % 8
+    of byte u // 8: ``selection_bytes`` permutes into it the bits of the
+    kernels' packing, (b, s, s / 8) int8 by groups of 1,024 keys, which is
+    what travels between the kernels and what ``DSA_SELECTION`` keeps);
+    the index key ``dsa_ki_seq`` (T, di), the first key-value
     head's ``dsa_k_seq`` and ``dsa_v_seq`` (T, hd) and every key-value
     head's ``dsa_kall_seq`` (T, n_kv hd) whole; and at the rows ``at``
     (flat token rows of this shard) ``dsa_qi_at`` (R, J di), ``dsa_w_at``
@@ -228,6 +236,8 @@ def dsa_attention(p, x, cfg, *, interpret: bool, at=None):
     every head's q ``dsa_q_at`` (R, h hd) and logsumexp ``dsa_lse_at`` (R,
     h), the first head's ``dsa_o_at`` (R, hd), the row's loss
     ``dsa_kl_at`` (R,))."""
+    from ompi_tpu.ops.sparse_attention import selection_bytes
+
     b, s, _ = x.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     eps, theta = cfg.rms_norm_eps, cfg.rope_theta
@@ -268,8 +278,7 @@ def dsa_attention(p, x, cfg, *, interpret: bool, at=None):
     with jax.named_scope("otpu_stats"):
         rows = lambda t: t.reshape(b * s, -1).astype(jnp.float32)
         seen.update(
-            dsa_selection_seq=jnp.packbits(sel.astype(jnp.uint8), axis=-1,
-                                           bitorder="little"),
+            dsa_selection_seq=selection_bytes(sel),
             dsa_ki_seq=rows(ki), dsa_k_seq=rows(k[:, 0]),
             dsa_v_seq=rows(v[:, 0]),
             dsa_kall_seq=rows(k.transpose(0, 2, 1, 3)))

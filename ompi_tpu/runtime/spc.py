@@ -161,8 +161,9 @@ _COUNTERS = (
     # the attention passes made under a selection while steps were traced,
     # the (query, key) pairs they attend to and those full causal passes
     # of their lengths would, both from the shapes: selected over causal
-    # is what the selection leaves of the triangle
-    "dsa_built", "dsa_keys_selected", "dsa_keys_causal",
+    # is what the selection leaves of the triangle; and the bytes of the
+    # selection a pass reads, packed eight keys a byte: b x s x s / 8
+    "dsa_built", "dsa_keys_selected", "dsa_keys_causal", "dsa_mask_bytes",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

@@ -415,10 +415,13 @@ def cases(mesh1d, mesh2d):
         {"interpret": False}))
     # Keye-VL-2.0's learned sparse attention at the cell's shape (1 x 32
     # query heads on 4 key-value heads x 16,384 at 128; an indexer of 16
-    # heads of 64, top 2,048): both flash kernels under a selection's int8
-    # tiles, and the two kernels of ``ops/sparse_attention``
-    def select_args(b, s):
-        return _sds((b, s, s), jnp.int8, one, P())
+    # heads of 64, top 2,048): both flash kernels under a selection packed
+    # eight keys a byte (query-major for the forward and the loss, which
+    # transposes it; key-major for the backward pair), and the two kernels
+    # of ``ops/sparse_attention``
+    def select_args(b, s, key_major=False):
+        return _sds((b, s // 8, s) if key_major else (b, s, s // 8),
+                    jnp.int8, one, P())
 
     def flash_select_forward(b, h, s, d, n_kv):
         q, k, v = attn_bwd_args(b, h, s, d, d, n_kv)[:3]
@@ -428,7 +431,7 @@ def cases(mesh1d, mesh2d):
     def attn_select_backward(b, h, s, d, n_kv):
         fn, args, kw = attn_block_backward(b, h, s, d, d, n_kv)
         flags = _sds((b * (s // 1024) ** 2,), jnp.int32, one, P())
-        return fn, args, {**kw, "select": (select_args(b, s), flags)}
+        return fn, args, {**kw, "select": (select_args(b, s, True), flags)}
 
     def dsa_index_args(b, s, heads, di):
         return (_sds((b, heads, s, di), bf16, one, P()),

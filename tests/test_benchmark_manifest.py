@@ -829,6 +829,8 @@ def test_a_sparse_attention_step_counts_its_routers_and_its_selection(
     assert c["dsa_built"] == c["attn_built"] > 0
     assert c["dsa_keys_selected"] * (64 * 65 // 2) \
         == c["dsa_keys_causal"] * (24 * 25 // 2 + 40 * 24)
+    # the selection a pass reads, eight keys a byte: 64 x 64 / 8
+    assert c["dsa_mask_bytes"] == c["dsa_built"] * 64 * 64 // 8
     assert rehearsal["builds"] == [1, 1]
 
 

@@ -273,12 +273,13 @@ def keye_rows():
 def test_the_sparse_attention_kernels_aot_compile_at_the_cells_shape(
         keye_rows):
     """32 query heads on 4 key-value heads x 16,384 positions at a head
-    width of 128 under an int8 selection (1, 16384, 16384); an indexer of
-    16 heads of 64 and top 2,048: the forward kernel under the mask's
-    tiles, the backward pair under the mask key-major, the index / select
-    kernel (a tile's scores in 16 MiB of VMEM scratch, 46 counting passes)
-    and the alignment loss's one pass, each one Mosaic call under its own
-    VMEM limit."""
+    width of 128 under a selection packed eight keys a byte, int8 (1,
+    16384, 2048); an indexer of 16 heads of 64 and top 2,048: the forward
+    kernel under the selection's tiles, the backward pair under its bytes
+    key-major (1, 2048, 16384), the index / select kernel (a tile's scores
+    in 16 MiB of VMEM scratch, 46 counting passes) and the alignment
+    loss's one pass, each one Mosaic call under its own VMEM limit; no
+    array of (16384, 16384) is in or around any of them."""
     for case in ("keye_flash_select_forward", "keye_attn_select_backward",
                  "keye_dsa_index_select", "keye_dsa_index_loss"):
         row = keye_rows[case]
@@ -287,7 +288,9 @@ def test_the_sparse_attention_kernels_aot_compile_at_the_cells_shape(
                                                           row["entry_ops"])
         with open(row["hlo"], encoding="utf-8") as f:
             text = f.read()
-        assert "s8[1,16384,16384]" in text, case
+        assert ("s8[1,2048,16384]" if case == "keye_attn_select_backward"
+                else "s8[1,16384,2048]") in text, case
+        assert not re.search(r"\[(\d+,)*16384,16384[\],]", text), case
     # the index scores never leave the kernel: no (s, s) float32 array, and
     # no (s, s, heads) one, in or around it
     with open(keye_rows["keye_dsa_index_select"]["hlo"],
@@ -303,7 +306,11 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
     stands under ``otpu_dsa`` in the pass it belongs to and in no
     recomputed one: the selection and the alignment loss in the forward
     pass alone (the checkpoint keeps the mask and the loss's gradients),
-    the flash forward too, the backward pairs in the backward pass."""
+    the flash forward too, the backward pairs in the backward pass.  The
+    selection is packed wherever it goes: the scan's stack of kept
+    residuals holds s8[4,1,16384,2048], and an array of (16384, 16384) is
+    made under ``otpu_stats`` if anywhere (the check's layout of the bits:
+    the compiler fuses it away today)."""
     row = keye_rows["keye_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["while"] >= 3
@@ -323,6 +330,13 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
                    for p in found), (name, found)
     assert not [p for p in kernels if "/otpu_flash_causal_forward/" in p
                 or "/otpu_attn_block_backward/" in p]
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert "s8[4,1,16384,2048]" in text
+    square = [line for line in text.splitlines()
+              if re.search(r"= \w+\[(\d+,)*16384,16384[\],]", line)]
+    assert all("otpu_stats" in line for line in square), [
+        line[:300] for line in square if "otpu_stats" not in line][:5]
 
 
 @pytest.fixture(scope="module")

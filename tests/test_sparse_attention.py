@@ -8,7 +8,10 @@ selection's tiles (``flash_causal_forward`` / ``attn_block_backward`` with
 selection that empties whole tiles; the alignment loss's kernel
 (``index_loss``) and twin against the loss written as its definition; that
 without a selection the callers' programs are what they were; the
-counters."""
+counters.  The selection travels packed, eight keys a byte
+(``pack_selection`` / ``unpack_selection``; ``selection_bytes`` is what a
+check reads): the packing against ``jnp.packbits`` at lengths that are and
+are not whole groups of 1,024 keys."""
 import math
 
 import jax
@@ -86,18 +89,89 @@ def test_the_selection_breaks_a_tie_at_the_bar_by_position():
                          ids=["2tiles", "2chunks"])
 def test_the_select_kernel_is_its_twin(s, topk):
     """The interpreted kernel against the twin's blocks and the sort: the
-    same mask bit for bit (their scores are the same sums in the same
-    order) and the same logsumexp over the selected scores."""
+    same packed selection byte for byte (their scores are the same sums in
+    the same order) and the same logsumexp over the selected scores."""
     qi, ki, w = _indexer(s, b=2)
     twin_sel, twin_lse = dsa._index_select_blocks(qi, ki, w, topk, 64,
                                                     True)
     sel, lse = sa.index_select(qi, ki, w, topk=topk, interpret=True)
-    assert sel.dtype == jnp.int8 and sel.shape == (2, s, s)
+    assert sel.dtype == jnp.int8 and sel.shape == (2, s, s // 8)
     np.testing.assert_array_equal(sel, twin_sel)
     np.testing.assert_allclose(lse, twin_lse, rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(
-        np.asarray(sel) != 0,
+        sa.unpack_selection(sel),
         sorted_selection(dsa.index_scores(qi, ki, w), topk))
+
+
+def _mask(s, rows=24, b=2, seed=5):
+    """A boolean mask (b, rows, s) with every bit plane of every group
+    set somewhere and cleared somewhere."""
+    return np.random.default_rng(seed).random((b, rows, s)) < 0.3
+
+
+@pytest.mark.parametrize("s,what", [
+    (s, what) for s in (16, 768, 2048, 2560)
+    for what in ("inverse", "blocks", "bytes", "flags")],
+    ids=lambda v: str(v))
+def test_a_packed_selection_is_its_mask(s, what):
+    """``pack_selection`` and ``unpack_selection`` are inverses, whole and
+    by blocks (a traced one among them), at lengths that are whole groups
+    of 1,024 keys (2,048), that are not (768 and 2,560: groups of 256 and
+    512) and that are less than a byte's lanes (16);
+    ``selection_bytes`` of the packed selection is ``jnp.packbits`` of the
+    mask; the tiles' flags are the mask's."""
+    mask = _mask(s)
+    packed = jax.jit(sa.pack_selection)(mask)
+    assert packed.dtype == jnp.int8 and packed.shape == (2, 24, s // 8)
+    group = 8 * fa.select_lanes(s)
+    assert s % group == 0 and (group == 1024 or s % (2 * group))
+    if what == "inverse":
+        np.testing.assert_array_equal(jax.jit(sa.unpack_selection)(packed),
+                                      mask)
+        # the layout: byte c of a group holds key lanes x m + c in bit m
+        lanes = group // 8
+        u = s - 3
+        g, m, c = u // group, u % group // lanes, u % lanes
+        one = jax.jit(sa.pack_selection)(np.arange(s)[None, None] == u)
+        want = np.zeros(s // 8, np.uint8)
+        want[g * lanes + c] = 1 << m
+        np.testing.assert_array_equal(np.asarray(one)[0, 0].view(np.uint8),
+                                      want)
+    elif what == "blocks":
+        # a block of whole groups, and (below a group) of whole planes
+        for keys in {s, group, max(group // 4, 8)}:
+            block = jax.jit(lambda p, f: sa.unpack_selection(p, f, keys))
+            for first in range(0, s, keys):
+                np.testing.assert_array_equal(block(packed, first),
+                                              mask[..., first:first + keys])
+        np.testing.assert_array_equal(      # and untraced, as the kernels'
+            sa.unpack_selection(packed, s - keys, keys),    # callers do
+            mask[..., s - keys:])
+    elif what == "bytes":
+        got = jax.jit(sa.selection_bytes)(packed)
+        assert got.dtype == jnp.uint8
+        np.testing.assert_array_equal(got, jnp.packbits(
+            jnp.asarray(mask), axis=-1, bitorder="little"))
+    else:
+        # single keys here and there: some tile pairs hold none
+        tile = max(group // 4, 8)
+        nt = s // tile
+        rng = np.random.default_rng(s)
+        square = np.zeros((1, s, s), bool)
+        square[0, rng.integers(0, s, nt * nt), rng.integers(0, s, nt * nt)] \
+            = True
+        got = jax.jit(lambda m: fa._tile_flags(sa.pack_selection(m), tile))(
+            square)
+        want = square.reshape(nt, tile, nt, tile).any(axis=(1, 3))
+        np.testing.assert_array_equal(np.asarray(got).reshape(nt, nt), want)
+        assert want.any() and not want.all()
+
+
+def test_a_length_or_a_tile_the_packing_cannot_hold_is_refused():
+    with pytest.raises(ValueError, match="whole number of bytes"):
+        sa.pack_selection(jnp.zeros((1, 4, 12), bool))
+    with pytest.raises(ValueError, match="groups of 256"):
+        sa.unpack_selection(jnp.zeros((1, 4, 96), jnp.int8), 0, 96)
 
 
 def dense(q, k, v, sel):
@@ -116,15 +190,17 @@ def dense(q, k, v, sel):
 
 
 def _selection(s, topk, b=1, empty=True):
-    """A selection of ``s`` positions: the indexer's own; with ``empty``
+    """A selection of ``s`` positions as a mask (``pack_selection`` hands
+    it to what is tested): the indexer's own; with ``empty``
     the rows of the last two blocks select nothing of block 1 (a whole
     tile pair, (2, 1) and (3, 1), is empty) and rows of block 2 nothing of
     their own block but the diagonal key."""
-    sel = dsa._index_select_blocks(*_indexer(s, b=b), topk, 64, True)[0]
+    sel = sa.unpack_selection(dsa._index_select_blocks(
+        *_indexer(s, b=b), topk, 64, True)[0])
     if empty:
-        sel = sel.at[:, 2 * BLOCK:, BLOCK:2 * BLOCK].set(0)
-        sel = sel.at[:, 2 * BLOCK:3 * BLOCK, 2 * BLOCK:3 * BLOCK].set(0)
-        sel = jnp.maximum(sel, jnp.eye(s, dtype=jnp.int8)[None])
+        sel = sel.at[:, 2 * BLOCK:, BLOCK:2 * BLOCK].set(False)
+        sel = sel.at[:, 2 * BLOCK:3 * BLOCK, 2 * BLOCK:3 * BLOCK].set(False)
+        sel = sel | jnp.eye(s, dtype=bool)[None]
     return sel
 
 
@@ -136,12 +212,13 @@ def test_the_forward_kernel_under_a_selection_is_the_dense_softmax(
     s = 4 * BLOCK
     q, k, v = _qkv(s, h, n_kv, d, dt=jnp.float32)
     sel = _selection(s, 100, empty=empty)
-    assert not empty or int(fa._tile_flags(sel, BLOCK).reshape(4, 4)[3, 1]) \
-        == 0
+    packed = sa.pack_selection(sel)
+    assert not empty or int(fa._tile_flags(packed, BLOCK).reshape(4, 4)[3, 1]
+                            ) == 0
     want = dense(q, k, v, sel)
     got = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
-                                  select=sel)
-    twin = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
+                                  select=packed)
+    twin = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=packed)
     for g, t, x in zip(got, twin, want):
         np.testing.assert_allclose(g, x, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(t, x, rtol=2e-5, atol=2e-5)
@@ -150,7 +227,7 @@ def test_the_forward_kernel_under_a_selection_is_the_dense_softmax(
 
 def walk_backward(q, k, v, do, o, lse, sel):
     """(dq, dk, dv) by ``attn_block_backward`` over the causal pairs under
-    the selection, interpreted."""
+    the packed selection, interpreted."""
     delta = jnp.sum(do * o, axis=-1)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     select = (jnp.swapaxes(sel, 1, 2), fa._tile_flags(sel, BLOCK))
@@ -176,9 +253,10 @@ def test_the_backward_under_a_selection_is_autodiff(h, n_kv, d, blocks):
     want = jax.grad(lambda *a: jnp.sum(dense(*a, sel)[0] * do),
                     argnums=(0, 1, 2))(q, k, v)
     o, lse = dense(q, k, v, sel)
+    packed = sa.pack_selection(sel)
     twin = causal._causal_bwd(BLOCK, True, None, (q, k, v, o, lse), do,
-                             select=sel)
-    got = walk_backward(q, k, v, do, o, lse, sel)
+                             select=packed)
+    got = walk_backward(q, k, v, do, o, lse, packed)
     for g, t, x in zip(got, twin, want):
         np.testing.assert_allclose(g, x, rtol=3e-4, atol=3e-4)
         np.testing.assert_allclose(t, x, rtol=3e-4, atol=3e-4)
@@ -190,7 +268,7 @@ def test_the_selected_attention_hands_no_gradient_to_its_logsumexp():
     constant."""
     s = 2 * BLOCK
     q, k, v = _qkv(s, dt=jnp.float32)
-    sel = _selection(s, 50, empty=False)
+    sel = sa.pack_selection(_selection(s, 50, empty=False))
     fn = lambda q, k, v: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, 50)[1])
     for g in jax.grad(fn, argnums=(0, 1, 2))(q, k, v):
@@ -222,8 +300,9 @@ def test_the_alignment_loss_kernel_is_the_definition_and_its_autodiff():
     q, k, v = _qkv(s, 4, 2, 128, b=2, dt=jnp.float32)
     sel, ilse = dsa._index_select_blocks(qi, ki, w, topk, 64, True)
     _, lse = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
-    want = loss_by_definition(qi, ki, w, q, k, sel)
-    grads = jax.grad(lambda *a: jnp.sum(loss_by_definition(*a, q, k, sel)),
+    mask = sa.unpack_selection(sel)
+    want = loss_by_definition(qi, ki, w, q, k, mask)
+    grads = jax.grad(lambda *a: jnp.sum(loss_by_definition(*a, q, k, mask)),
                      argnums=(0, 1, 2))(qi, ki, w)
     got = sa.index_loss(q, k, lse, qi, ki, w, ilse, sel, interpret=True)
     twin = dsa._index_loss_blocks(qi, ki, w, q, k, lse, ilse, sel, 64,
@@ -269,9 +348,11 @@ def test_the_counters_count_what_was_built():
         spc.init()
     s, topk = 2 * BLOCK, 100
     q, k, v = _qkv(s, dt=jnp.float32)
-    sel = _selection(s, topk, empty=False)
+    mask = _selection(s, topk, empty=False)
+    sel = sa.pack_selection(mask)
     before = {n: spc.read(n) for n in ("dsa_built", "dsa_keys_selected",
-                                       "dsa_keys_causal", "attn_built")}
+                                       "dsa_keys_causal", "dsa_mask_bytes",
+                                       "attn_built")}
     jax.grad(lambda q: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, topk)[0]))(q)
     moved = {n: spc.read(n) - v for n, v in before.items()}
@@ -279,4 +360,6 @@ def test_the_counters_count_what_was_built():
     selected = topk * (topk + 1) // 2 + (s - topk) * topk
     assert moved["dsa_keys_selected"] == moved["dsa_built"] * selected
     assert moved["dsa_keys_causal"] == moved["dsa_built"] * s * (s + 1) // 2
-    assert int(np.asarray(sel, np.int64).sum()) == selected
+    assert moved["dsa_mask_bytes"] == moved["dsa_built"] * s * s // 8 \
+        == moved["dsa_built"] * sel.size
+    assert int(np.asarray(mask, np.int64).sum()) == selected
