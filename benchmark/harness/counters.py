@@ -40,6 +40,20 @@ class CompileCounters:
                 "writes": self.writes}
 
 
+#: the program's SPC counters as they stood when set-up ended: what a
+#: metric that moves ``setup_s`` may count (``run.py`` marks it once, before
+#: the harness's own check builds its reference programs; a run is a
+#: process, so one mark a process)
+AT_SETUP: dict = {}
+
+
+def mark_setup() -> None:
+    from ompi_tpu.runtime import spc
+
+    AT_SETUP.clear()
+    AT_SETUP.update(spc.counters())
+
+
 def device_collectives() -> int:
     """The program's count of device collectives issued (two integer
     adds per call in ``runtime/spc.bump_device``)."""

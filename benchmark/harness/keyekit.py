@@ -94,8 +94,8 @@ PRECISION = ("router_logits", "router_scores", "router_weights",
 PART_CONTROLS = ("bf16", "no_selection", "top_half", "no_relu",
                  "no_head_norm") + WRONG
 RMS_ONLY = ("embed",)
-# a gradient's RMS as log10 over this (``smallthinkerkit.RMS_UNIT``: a limit
-# of 9.6% of an RMS at 8): the indexer's leaves learn from a loss whose
+# a gradient's RMS as log10 over this (a limit of 9.6% of an RMS at 8, where
+# ``olmoekit``'s 4 gives 4.7%): the indexer's leaves learn from a loss whose
 # ``pbar`` rests on bfloat16 attention probabilities, and a collapsed
 # router's gradient is a sum over a few experts' tokens
 RMS_UNIT = 8.0
@@ -123,10 +123,13 @@ REGRET_UNIT = 32.0
 # units of the tolerance (atol 5e-3): 1e-4 reads 0.02, a tenth 20
 SELECT_REGRET_UNIT = 1.0
 # the total, the cross-entropy and the head's mean logsumexp in units of the
-# tolerance over this (``smallthinkerkit.LOSS_SCALE``)
+# tolerance over this (``smallthinkerkit.LOSS_SCALE``'s value; that kit takes
+# ln V off them first since PR 63, this one compares them as they stand)
 LOSS_SCALE = 8.0
-# the auxiliary loss over its coefficient over this (``smallthinkerkit``'s
-# reasoning: it rests on a few experts' probabilities at initialisation)
+# the auxiliary loss over its coefficient over this: held loosely, as
+# ``smallthinkerkit`` held its own until PR 63 (a sum that rests on a few
+# experts' probabilities where the routers collapse); not read again here
+# since this file's rows went to 2.0 (PERF.md 7)
 AUX_SCALE = 0.1
 # the alignment loss (mean_t sum_layers KL, a few tenths: the tolerance's
 # atol does the work) as it stands: its ``pbar`` is made of bfloat16

@@ -73,58 +73,69 @@ PART_CONTROLS = ("bf16", "no_window", "rope_full", "no_rope_window",
 # the embedding's gradient is compared by its RMS alone (``nemotronkit``
 # says why)
 RMS_ONLY = ("embed",)
-# a gradient's RMS as log10 over this (``olmoekit.RMS_UNIT`` is 4: a limit
-# of 4.7% of an RMS).  The collapsed routers' gradients are sums over a few
-# experts' tokens and move more: at 4 they read up to 0.56
-# (``l0.attn_moe.router``) in 50 checks on the chip where every other leaf
-# stays under 0.13, the reference in bfloat16 0.08-0.42; at 8 (9.6%) 0.28
-RMS_UNIT = 8.0
+# Every unit below was read on the chip at the published widths with the
+# embedding's rows at the file's ``start.embed_init_std`` 2.0 (PERF.md 2; my
+# chip runs, PR 63: ``tools/kit_check.py --dump``, 6 + 12 seeds).  Until
+# then the rows were drawn at 0.02, the routers of layers 1 to 3 collapsed
+# (the fullest expert 7 to 10 times the mean) and several units stood wide
+# for that; with rows of 2.0 the fullest expert takes 1.9 to 2.8 times the
+# mean in every layer (a Zipf law's head routes as one), and they do not.
+#
+# a gradient's RMS as log10 over this: ``olmoekit.RMS_UNIT``'s 4, a limit
+# of 4.7% of an RMS.  The program's RMS lies within 0.24% of the
+# reference's on every leaf (0.05 at the widest, a router), the reference
+# in bfloat16 within 0.26-1.2%: an RMS tells a wrong gradient, no precision
+RMS_UNIT = 4.0
 # a leaf whose largest probed entry is over this many RMS is probed in
-# units of that entry (``qwen3nextkit.HOT_ENTRY``, 8 there).  Here the
-# routers collapse at initialisation, so a held expert's gradient is hot or
-# next to nothing with the slots it got, and ``gate``'s passes relu's kink,
+# units of that entry (``qwen3nextkit.HOT_ENTRY``, 8 there): most checked
+# leaves have one at 4 to 12 RMS.  ``gate``'s gradient passes relu's kink,
 # where bfloat16 and float32 take a pre-activation near zero for different
-# signs: at 8 the program's probes read up to 0.71 (``l0.attn_moe.gate``)
-# and 0.53 (a router) in 26 checks on the chip, the reference in bfloat16
-# 0.15-0.29; at 4 a leaf with a hot entry reads half that
+# signs: the full layer's ``gate`` reads 0.23 and 0.50 on two of six
+# seeds, every other leaf at most 0.07 on all, the reference in bfloat16
+# 0.04-0.22.  The probes tell a wrong gradient, no precision
 HOT_ENTRY = 4.0
-# a routing regret in units of this many k-th probabilities: the routers
-# read the un-normed stream (entries of a few hundredths at initialisation),
-# so the 64 probabilities lie within a few per cent of 1/64 and the sixth
-# and seventh nearer than anywhere else in the benchmark
-REGRET_UNIT = 64.0
-# the total, the cross-entropy and the head's mean logsumexp (all of order
-# eleven, where the tolerance's rtol, 4.1e-3 of them, does the work) in
-# units of the tolerance over this.  Read on the chip raw (PERF.md 2, six
-# seeds): the program lies at most 1.05e-3 from the reference in its total
-# and cross-entropy and 6.4e-5 in a quarter's mean logsumexp; the reference
-# in bfloat16 at least 6.0e-3 in its total or 9.3e-3 in a mean logsumexp on
-# every seed.  At 8 (a limit of 4.7e-3) they read 0.22 and 2.0
+# a routing regret in units of this many k-th probabilities
+# (``olmoekit``'s).  The routers read the un-normed stream, whose entries
+# are the token's own row's (of order 2): a logit is of order 2 and the
+# sixth and seventh probabilities part as in any other model of the
+# benchmark.  The step's choice costs at most 0.94% of the sixth
+# probability under the reference's own scores (0.06 here), the reference
+# in bfloat16 2.9-4.0% more than that (0.18-0.25)
+REGRET_UNIT = 32.0
+# the total, the cross-entropy and the head's mean logsumexp, each **less
+# the loss of a uniform guess**, ln(``vocab_here``) = 10.545, in units of
+# the tolerance over this.  All three are of order eleven, where the
+# tolerance's rtol alone is 4.1e-3 of them whatever the scale; what a run
+# at initialisation has to get right is the half by which they exceed
+# ln V.  Read raw: the program lies at most 1.1e-4 from the reference in
+# its total and cross-entropy and 1.3e-5 in a quarter's mean logsumexp; the
+# reference in bfloat16 2.2e-3 to 3.7e-3 in a mean logsumexp on every seed
+# (its own total is rounded to a bfloat16 step of 0.0625 and lies 1e-3 to
+# 3e-2 off by chance: with ln V left in, at a limit of 4.7e-3, one seed in
+# six read 1.05 and one in about fifteen would pass).  At 8 the limit is
+# 8.1e-4: the program reads 0.14, the control 2.7 at the narrowest
 LOSS_SCALE = 8.0
-# the auxiliary loss over its coefficient (E sum_e f_e P_e, 7.8 to 9.8
-# here where an even router gives 6) over this.  This model's routers read
-# the un-normed stream, and at initialisation the later layers' collapse
-# (the fullest expert of layers 1 to 3 takes 7 to 10 times the mean), so
-# the sum rests on a few experts' probabilities, which follow the bfloat16
-# matmuls' noise in the stream with nothing to average it: the program
-# lies up to 1.2e-2 from the reference (0.15% of it; 22 checks), which the
-# tolerance's rtol alone (3e-3 of it) refuses at any scale from 1 up, and
-# the reference in bfloat16 lies no farther (1.3e-3 to 3.2e-2).  So it is
-# held loosely, for what it can still tell: a wrong router or wrong
-# weights (the controls move it by tenths).  At 0.1 the program reads 0.22
-AUX_SCALE = 0.1
-# the label's logit averaged over a quarter of the rows (of order a tenth,
+# the auxiliary loss over its coefficient (E sum_e f_e P_e, 6.13 to 6.21
+# here where an even router gives 6) over this: ``qwen3nextkit``'s 3 for
+# the same sum.  It no longer rests on a few experts' probabilities: the
+# program lies at most 4.7e-5 from the reference (0.012), the reference in
+# bfloat16 4.5e-3 to 2.0e-2 (1.1-5.0) on five seeds of six and 2.6e-5 on
+# one (its sum is a bfloat16 number too); a wrong router or wrong weights
+# move it by tenths
+AUX_SCALE = 3.0
+# the label's logit averaged over a quarter of the rows (a few hundredths,
 # either sign: the tolerance's atol does the work) over this: the program
-# lies up to 2.5e-3 from the reference (50 checks) and the reference in
-# bfloat16 no farther (1.4e-3 on six seeds), so it tells no precision and
-# is held at 0.5 (a limit of 1e-2: 0.25)
-LABEL_SCALE = 0.5
+# lies at most 1.4e-4 from the reference and the reference in bfloat16 no
+# farther (5e-5 to 2.5e-4), so it tells no precision; it tells a head read
+# at the wrong rows.  At 4 the limit is 1.25e-3: the program reads 0.12
+LABEL_SCALE = 4.0
 # a head's q and k as attention reads them in units of SAMPLE_UNIT over
 # this (``qwen3nextkit.ROPE_SCALE``: positions to 16,383, where one more
 # bit of a float32 inverse frequency is 8e-4 rad, and an entry that no norm
 # has bounded is up to 5).  Read on the chip at 0.0125: the program
 # 0.41-0.64 in four checks, RoPE on the full layer or off a window layer
-# 1,312 at the narrowest: at 0.005 they read 0.26 and 525
+# 1,312 at the narrowest: at 0.005 they read 0.26 and 525; with the rows at
+# 2.0 (PR 63) 0.21-0.31 and 525 in six checks: a normed entry is what it was
 ROPE_SCALE = 0.005
 # the first head's window output in units of SAMPLE_UNIT over this: o is a
 # mean of up to 4,096 rows of v, of order a few hundredths, and the kernel
@@ -132,7 +143,8 @@ ROPE_SCALE = 0.005
 # carry a row's weight that is 3e-5 of an entry, 1e-4 at the widest of a
 # check's 6,144 entries).  Read on the chip at 1: the program 1.53-2.38 in
 # four checks, a window layer attending in full 5,024 at the narrowest; at
-# 0.1 0.24-0.42 in 50 more and 607: at 0.05 they read 0.21 and 300
+# 0.1 0.24-0.42 in 50 more and 607: at 0.05 they read 0.21 and 300; with the
+# rows at 2.0 (PR 63) 0.20-0.32 and 151 in six checks
 WINDOW_SCALE = 0.05
 # the held experts' weighted sum at the sampled rows in units of
 # SAMPLE_UNIT over this: of order a tenth to one.  The want rounds where
@@ -145,7 +157,8 @@ WINDOW_SCALE = 0.05
 # want that keeps the hidden product exact has no such tail and lies 1.5e-3
 # to 2.6e-3 away in every check, more with every step taken: worse.  silu
 # in relu's place lies 0.15 away at the narrowest.  At 0.01 the limit is
-# 5e-3 of the sum: the tail reads 0.24, silu 25
+# 5e-3 of the sum: the tail reads 0.24, silu 25; with the rows at 2.0 (PR 63)
+# 0.000-0.005 in five checks, 0.11 in one, silu 50 at the narrowest
 EXPERT_SCALE = 0.01
 #: query rows of one head that attention scores at once
 ATTN_ROWS = 2048
@@ -473,7 +486,7 @@ def compared(stats: dict, cfg: dict, wrt: tuple) -> dict:
     """What a check compares of one step's statistics, each in its unit
     (``qwen3nextkit.compared``'s, but for the scales: the total, the
     cross-entropy and the head's logsumexp averaged over quarters of the
-    rows times ``LOSS_SCALE``, the auxiliary loss over its coefficient
+    rows, each less ln(``vocab_here``), times ``LOSS_SCALE``, the auxiliary loss over its coefficient
     times ``AUX_SCALE``, the label's logit averaged likewise times
     ``LABEL_SCALE``; the share of a layer's slots every one of all the
     experts received, and
@@ -496,12 +509,14 @@ def compared(stats: dict, cfg: dict, wrt: tuple) -> dict:
     here = held(cfg)
     first = here["first_expert"]
     losses = np.asarray(stats["losses"], np.float64)
+    uniform = np.log(cfg["vocab_here"] or cfg["vocab_size"])
     return {k: np.asarray(v, np.float32) for k, v in {
-        "losses": np.append(LOSS_SCALE * losses[:2],
+        "losses": np.append(LOSS_SCALE * (losses[:2] - uniform),
                             AUX_SCALE * losses[2] / cfg["aux_loss_coef"]),
         "load_share": share,
         "local_share": share[:, first:first + here["experts"]].sum(-1),
-        "row_means": rows.reshape(ROW_BLOCKS, -1, 2).mean(axis=1)
+        "row_means": (rows.reshape(ROW_BLOCKS, -1, 2).astype(np.float64)
+                      .mean(axis=1) - (uniform, 0.0))
         * (LOSS_SCALE, LABEL_SCALE),
         "route_regret": stats["regret"],
         "grad_log_rms": np.log10(rms) / RMS_UNIT,

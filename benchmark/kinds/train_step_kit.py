@@ -62,6 +62,16 @@ config_path, input_sharding = base.config_path, base.input_sharding
 bus_bytes, moved_bytes = base.bus_bytes, base.moved_bytes
 
 
+def start_of(point) -> dict:
+    """The configuration file's ``start`` group: how a run's parameters
+    are drawn where that is not the program's default for the file
+    (``embed_init_std``: the embedding's rows alone drawn wide, so that
+    the routers choose by the token; the file's ``assumed`` says why).
+    Handed to the program's loader as overrides, so it moves initial
+    values and nothing of the compiled step."""
+    return manifest.load_json(config_path(point)).get("start", {})
+
+
 def kit_of(point):
     """(the kit's module, the configuration as the kit reads it)."""
     path = config_path(point)
@@ -96,7 +106,7 @@ def prepare(env, point, bits):
 
 
 def bind(env, point, first):
-    cfg = load_model_config(config_path(point))
+    cfg = load_model_config(config_path(point), **start_of(point))
     if (cfg.micro_batch, cfg.seq_len) != (point["sequences"],
                                           point["seq_len"]):
         raise ValueError(f"{point['name']}: the point's batch is not the "
@@ -200,7 +210,7 @@ def reference(point, n, xs):
                      bias_now)
     if _RUN.get("keep_last"):       # tools/kit_check.py reads controls
         held["last"] = dict(params=params, tokens=tokens, labels=labels,
-                            bias=bias, by_name=by_name, want=want)
+                            bias=bias, by_name=by_name, want=want, out=out)
     print(f"check {point['name']}: widest deviation in units of the "
           f"tolerance: {json.dumps(units_of(got, want))}", flush=True)
     return [want[k] for k in _RUN["names"]]

@@ -34,15 +34,16 @@ def points(real):
 
 
 def test_the_cell_and_its_configuration_are_the_entries_after_pr_26s(real):
-    """The fourth cell, the third configuration and the per-layer
-    entries 18 to 21 (later PRs append after them)."""
+    """The fourth cell, the third configuration and four per-layer
+    entries in a row (later PRs append after them)."""
     assert real["workloads"][3]["name"] == CELL
     assert real["workloads"][3]["chips"] == 1
     assert real["configs"][2]["name"] == "ddt-device-1chip"
     assert real["configs"][2]["reduced"] == ["ranks", "patterns"]
-    assert [m["name"] for m in real["per_layer"][17:21]] == [
+    at = [m["name"] for m in real["per_layer"]].index("ddt.roofline")
+    assert [m["name"] for m in real["per_layer"][at:at + 4]] == [
         "ddt.roofline", "ddt.vs_manual", "ddt.fw_self_us", "ddt.plan_builds"]
-    assert {m["layer"] for m in real["per_layer"][17:21]} == {LAYER}
+    assert {m["layer"] for m in real["per_layer"][at:at + 4]} == {LAYER}
 
 
 def test_the_thirteen_points_letter_for_letter(points):

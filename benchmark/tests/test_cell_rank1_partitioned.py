@@ -59,21 +59,28 @@ def test_no_step_us_and_why(real):
 
 
 def test_the_per_layer_entries(real):
-    mine = real["per_layer"][21:25]
+    at = [m["name"] for m in real["per_layer"]].index(PART_METRICS[0])
+    mine = real["per_layer"][at:at + 4]
     assert [m["name"] for m in mine] == PART_METRICS
     assert all(m["moves"] == "small_msg_us" and m["workloads"] == [CELL]
                and m["layer"] == PART for m in mine)
     by_name = {m["name"]: m for m in real["per_layer"]}
-    for name in JOINED:             # appended to, never first
-        assert by_name[name]["workloads"][-1] == CELL
-        assert len(by_name[name]["workloads"]) > 1
+    for name in JOINED:             # appended to, never first; the step
+        #                             cells of later PRs came behind it
+        cells = by_name[name]["workloads"]
+        assert cells.index(CELL) >= 1
+        assert all(c.endswith("-train-1chip")
+                   for c in cells[cells.index(CELL) + 1:])
     # a launch's parts are one a launch: k x the programs a call observed
     for name in ("launch.pjit_us", "launch.pjrt_us", "launch.alloc_us"):
         assert mf.metric_spec(name)["params"]["per"] == "launch"
     assert "per" not in mf.metric_spec("dispatch.fw_self_us")["params"]
     reported = {m["name"] for m in mf.metrics_of(real, "per_layer", CELL)}
-    assert reported == set(PART_METRICS) | set(JOINED) | {
-        "boot.init_s", "compile.backend_s"}
+    build = {"compile.trace_s", "compile.lower_s", "compile.own_backend_s",
+             "compile.cache_hit_share", "compile.own_programs",
+             "compile.other_s"}          # PR 53's, of every cell
+    assert reported == set(PART_METRICS) | set(JOINED) | build | {
+        "boot.init_s", "compile.backend_s", "harness.check_s"}
 
 
 def test_the_four_points_letter_for_letter(points):

@@ -89,8 +89,9 @@ def _break(real, fn):
         bound=0.11)),
     ("a bound under 1%", lambda m: m["end_to_end"][0].update(bound=0.005)),
     ("an absolute bound", lambda m: m["end_to_end"][0].update(bound=3)),
-    ("a second four-chip cell", lambda m: m["workloads"][1].update(
-        chips=4)),
+    # one in four cells may ask for four chips: of twelve, three
+    ("a fourth four-chip cell", lambda m: [w.update(chips=4)
+                                           for w in m["workloads"][1:4]]),
     ("chips 2", lambda m: m["workloads"][1].update(chips=2)),
     ("an extra key on a metric", lambda m: m["end_to_end"][0].update(
         why="because")),

@@ -66,6 +66,108 @@ def test_rehearsal_of_every_cell(tiny_root, cell, metrics, capsys):
     assert json.load(open(saved))["result"] == result
 
 
+def test_setup_s_stops_before_the_harness_checks(tiny_root, monkeypatch,
+                                                 capsys):
+    """``setup_s`` runs from the open device to the first call that could
+    be timed: the harness's own check of every point against the
+    reference comes after it, as ``check_s`` (PR 63).  A check that sleeps
+    shows in ``check_s`` and in nothing of ``setup_s``; it still runs
+    before the measured windows and still decides ``correct``."""
+    import time
+
+    naps, real_check = [], pt.check
+
+    def slow_check(pr, rng):
+        naps.append((time.perf_counter(), len(pr.windows)))
+        time.sleep(0.4)
+        return real_check(pr, rng)
+
+    monkeypatch.setattr(pt, "check", slow_check)
+    result = _rehearse(tiny_root, "rank1-partitioned")
+    facts = [json.loads(line[4:])
+             for line in capsys.readouterr().out.splitlines()
+             if line.startswith("run ")][0]
+    points = len(naps) // 2
+    assert result["correct"] is True and points >= 2
+    # the first round of checks saw no timed window, the second all
+    assert all(w == 0 for _, w in naps[:points])
+    assert all(w >= 1 for _, w in naps[points:])
+    phases = facts["setup_phases"]
+    assert facts["check_s"] == phases["check_s"] >= 0.4 * points
+    made = (phases["program_import_s"] + facts["init_s"]
+            + phases["inputs_s"] + phases["warm_s"])
+    assert made <= facts["setup_s"] < made + 0.3
+    assert result["metrics"]["setup_s"]["value"] == facts["setup_s"]
+
+
+def test_a_build_metric_counts_to_the_end_of_set_up(monkeypatch):
+    """The six ``compile.*`` metrics of the program's build record move
+    ``setup_s``, so they read the counters as ``run.py`` marked them when
+    set-up ended (``until: setup``): the reference programs that the
+    harness's check builds after it are no part of them.  A metric
+    without the key reads the counters as they stand."""
+    from harness import counters
+    from ompi_tpu.runtime import spc
+
+    reader = pt.load_module("readers", "program_counter", mf.BENCH_DIR)
+    for name in ("trace_s", "lower_s", "own_backend_s", "cache_hit_share",
+                 "own_programs", "other_s"):
+        assert mf.metric_spec("compile." + name)["params"]["until"] \
+            == "setup"
+    assert "until" not in mf.metric_spec("compile.first_call_s")["params"]
+    other = mf.metric_spec("compile.other_s")["params"]
+    live = {"device_other_build_us": 9e6}
+    monkeypatch.setattr(spc, "counters", lambda: dict(live))
+    monkeypatch.setattr(counters, "AT_SETUP", {})
+    assert reader.read({}, other) == 9.0        # before the mark: live
+    counters.mark_setup()
+    live["device_other_build_us"] = 14e6        # the check built more
+    assert reader.read({}, other) == 9.0
+    assert reader.read({}, {k: v for k, v in other.items()
+                            if k != "until"}) == 14.0
+
+
+def test_the_checks_time_is_a_per_layer_metric_of_every_cell():
+    """``harness.check_s`` keeps what ``setup_s`` held until PR 63: read
+    by ``run_value`` from the run's own ``check_s``, listed without
+    ``workloads`` (every cell), under a layer of the benchmark's own."""
+    real = mf.load()
+    (m,) = [x for x in real["per_layer"] if x["name"] == "harness.check_s"]
+    assert m == {"name": "harness.check_s", "unit": "s", "better": "lower",
+                 "source": "host_clock", "moves": "setup_s",
+                 "layer": "harness (benchmark's own work)"}
+    spec = mf.metric_spec("harness.check_s")
+    assert (spec["reader"], spec["params"]) == ("run_value",
+                                                {"key": "check_s"})
+    reader = pt.load_module("readers", "run_value", mf.BENCH_DIR)
+    assert reader.read({"run": {"check_s": 1.25}}, spec["params"]) == 1.25
+    assert reader.read({"run": {}}, spec["params"]) is None
+    for cell in real["workloads"]:
+        assert m in mf.metrics_of(real, "per_layer", cell["name"])
+
+
+@pytest.mark.parametrize("gone", ["moe." + "expert_share",
+                                  "moe." + "local_expert_share"])
+def test_a_metric_that_read_nothing_is_in_no_file(gone):
+    """Both matched XLA's ``ragged-dot`` alone and have read nothing since
+    the grouped matmuls run on a Pallas kernel (PR 47); ``moe.gmm_share``
+    matches either and lists their cells."""
+    real = mf.load()
+    assert gone not in [m["name"] for m in real["per_layer"]]
+    for folder, _, files in os.walk(mf.BENCH_DIR):
+        if "__pycache__" in folder:
+            continue
+        for fn in files:
+            with open(os.path.join(folder, fn), encoding="utf-8",
+                      errors="replace") as f:
+                assert gone not in f.read(), os.path.join(folder, fn)
+    gmm = mf.by_name(real["per_layer"], "moe.gmm_share", "metric")
+    assert {"olmoe-train-1chip", "joyai-train-1chip"} <= set(
+        gmm["workloads"])
+    assert mf.metric_spec("moe.gmm_share")["params"]["pattern"] \
+        == "^(otpu_gmm|ragged-dot)"
+
+
 def test_a_wrong_result_is_counted(tiny_root, monkeypatch):
     kind = pt.load_module("kinds", "bcast",
                           os.path.join(tiny_root, "benchmark"))

@@ -181,9 +181,11 @@ def test_every_step_metric_names_the_reader_and_the_two_kinds():
     names = [n[:-5] for n in os.listdir(os.path.join(BENCH, "metrics"))
              if n.startswith("step.")]
     assert sorted(names) == [
-        "step.attention_share", "step.expert_block_share", "step.head_share",
+        "step.attention_share", "step.attn_proj_share",
+        "step.expert_block_share", "step.hbm_peak_share", "step.head_share",
         "step.mixed_share", "step.optimizer_share", "step.remat_share",
         "step.unnamed_share"]
+    names.remove("step.hbm_peak_share")     # PR 53's, reader step_memory
     for name in names:
         with open(os.path.join(BENCH, "metrics", name + ".json"),
                   encoding="utf-8") as f:

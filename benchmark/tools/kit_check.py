@@ -36,6 +36,7 @@ import argparse
 import gc
 import json
 import os
+import pickle
 import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -47,7 +48,8 @@ for _p in (os.path.dirname(os.path.abspath(__file__)), BENCH_DIR,
 import run  # noqa: E402
 
 
-def read(seeds, warm, workload, platform="tpu", root=run.CHECKOUT):
+def read(seeds, warm, workload, platform="tpu", root=run.CHECKOUT,
+         dump=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -75,6 +77,7 @@ def read(seeds, warm, workload, platform="tpu", root=run.CHECKOUT):
                 "held", "kit", "cfg", "checked"))
             got, last, aux = held["got"], held["last"], held["aux"]
             want = last["want"]
+            bias_after = jax.device_get(held["state"][4]) if dump else None
             # the trainer's state has done its step: the bfloat16
             # reference does not fit beside it and the float32 copy
             for a in jax.tree.leaves(held.pop("state")):
@@ -96,6 +99,17 @@ def read(seeds, warm, workload, platform="tpu", root=run.CHECKOUT):
                 sides["parts_" + variant] = kit.precision_want(
                     aux, last["by_name"], last["bias"]["layers"],
                     last["params"]["head"], xs[1], cfg, variant=variant)
+            if dump:        # the raw statistics, for units set offline
+                os.makedirs(dump, exist_ok=True)
+                with open(os.path.join(dump, f"{workload}.{seed}.pkl"),
+                          "wb") as f:
+                    pickle.dump(jax.tree.map(np.asarray, {
+                        "program": kit.step_stats(aux, bias_after, cfg),
+                        "reference": last["out"],
+                        "control_bf16": low, "wrt": list(wrt),
+                        "parts_program": kit.precision_got(aux, cfg),
+                        "parts_want": {k: want[k] for k in kit.PRECISION},
+                    }), f)      # a part control's units scale as printed
             for name, side in sides.items():
                 units = {k: float(np.max(np.abs(
                     np.float64(side[k]) - want[k]) / (
@@ -124,9 +138,12 @@ def main(argv=None) -> int:
     ap.add_argument("--warm", type=int, default=4)
     ap.add_argument("--platform", default="tpu")
     ap.add_argument("--root", default=run.CHECKOUT)
+    ap.add_argument("--dump", help="a directory for each seed's raw "
+                    "statistics (program, reference, controls), from "
+                    "which a kit's units are set")
     args = ap.parse_args(argv)
     rows = read([args.base + i for i in range(args.seeds)], args.warm,
-                args.workload, args.platform, args.root)
+                args.workload, args.platform, args.root, args.dump)
     sides = [n for n in rows[0] if n.startswith(("program", "control",
                                                  "parts"))]
     # the program's widest over the seeds, each control's narrowest
