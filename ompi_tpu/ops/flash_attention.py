@@ -43,6 +43,21 @@ tile-aligned concatenation (``_selected``) and nothing crosses lanes.
 ``ops/sparse_attention.pack_selection`` / ``unpack_selection`` are the
 format's two ends outside a kernel.
 
+A static ``bd`` (a block length B; None: nothing here is other than it
+was) makes both kernels attention under **block diffusion's mask**, which
+is not under the diagonal.  The rows are two halves of equal length, a
+noisy copy of a sequence before its clean copy, both at positions 0 .. L -
+1; with ``blk = position // B``, query row i sees key row j iff both are
+noisy and ``blk(j) == blk(i)``, or i is noisy, j clean and ``blk(j) <
+blk(i)``, or both are clean and ``blk(j) <= blk(i)``: a clean row sees no
+noisy one.  ``blk(i) - blk(j)`` then lies within bounds that the pair's
+two halves give, so a tile's mask is two compares against scalars
+(``_bd_mask``) and never an array.  Which tile pairs hold a visible entry
+is static (``bd_pairs``): the forward's grid walks a table of them through
+its index maps (``_bd_fwd_kernel``), the backward's caller walks the same
+list; a pair wholly visible runs the unmasked body, a pair with no visible
+entry is walked by neither.
+
 Scores compute in float32 on the MXU via ``preferred_element_type``.
 Ring attention's block update (``parallel/flagship.ring_attention``) is
 plain ``jnp`` on every platform and no kernel of this module.
@@ -71,9 +86,75 @@ BWD_STRIP = 256
 BWD_VMEM_LIMIT = 64 << 20
 
 
+#: a tile pair under block diffusion's mask: wholly visible, or masked
+#: inside the tile (one with no visible entry is in no list)
+BD_WHOLE, BD_MASKED = 1, 2
+_BD_FAR = 1 << 30       # no bound on blk(i) - blk(j) from above
+
+
+def _bd_bounds(q_noisy, kv_noisy):
+    """(lo, hi): query row i sees key row j iff ``lo <= blk(i) - blk(j) <=
+    hi``, by the halves the two lie in (Python or traced booleans; a clean
+    query and a noisy key are never paired): both noisy ``==``, a noisy
+    query and a clean key ``<``, both clean ``<=``."""
+    if isinstance(q_noisy, bool):
+        return (1 if q_noisy and not kv_noisy else 0,
+                0 if q_noisy and kv_noisy else _BD_FAR)
+    return (jnp.where(jnp.logical_and(q_noisy, jnp.logical_not(kv_noisy)),
+                      1, 0),
+            jnp.where(jnp.logical_and(q_noisy, kv_noisy), 0, _BD_FAR))
+
+
+def bd_pairs(nb: int, block: int, bl: int) -> list:
+    """The (q block, kv block, kind) triples attention under block
+    diffusion's mask walks over ``nb`` blocks of ``block`` rows, the first
+    half of them the noisy copy: q block by q block, a noisy one's noisy
+    kv blocks before its clean ones, each ascending; ``kind`` is
+    ``BD_WHOLE`` or ``BD_MASKED``, and a pair with no visible entry is left
+    out.  Static: the forward's grid, the backward's walk, the ``jnp``
+    twins and the counters all read this list."""
+    half = nb // 2
+    if nb % 2 or bl < 1:
+        raise ValueError(f"{nb} blocks of {block} rows are no two halves of "
+                         f"whole blocks, or the block length {bl} is none")
+    blocks = lambda t: ((t % half) * block // bl,
+                        ((t % half + 1) * block - 1) // bl)
+    out = []
+    for i in range(nb):
+        for j in range(nb):
+            if j < half <= i:
+                continue
+            lo, hi = _bd_bounds(i < half, j < half)
+            (q_lo, q_hi), (k_lo, k_hi) = blocks(i), blocks(j)
+            least, most = q_lo - k_hi, q_hi - k_lo
+            if most < lo or least > hi:
+                continue
+            out.append((i, j, BD_WHOLE if lo <= least and most <= hi
+                        else BD_MASKED))
+    return out
+
+
+def _bd_mask(shape, q_axis: int, q_first, kv_first, q_noisy, kv_noisy,
+             bl: int):
+    """A tile's mask under block diffusion: ``shape``'s axis ``q_axis``
+    runs over query positions from ``q_first``, the other over key
+    positions from ``kv_first`` (positions inside a half; scalars, maybe
+    traced, as the two halves' flags are)."""
+    def blk(first, axis):
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        if bl & (bl - 1):
+            return jax.lax.div(pos, jnp.int32(bl))
+        return pos >> (bl.bit_length() - 1)
+
+    lo, hi = _bd_bounds(q_noisy, kv_noisy)
+    d = blk(q_first, q_axis) - blk(kv_first, 1 - q_axis)
+    return jnp.logical_and(d >= lo, d <= hi)
+
+
 def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                      dqo_ref, dko_ref, dvo_ref, chosen=None, live=None):
+                      dqo_ref, dko_ref, dvo_ref, chosen=None, live=None,
+                      bd=None):
     """One q tile of block i of one query head against kv block j of the
     key-value head its group shares, a tile of kv positions at a time,
     **transposed**: scores are held (kv, q), so that the logsumexp and
@@ -88,7 +169,10 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
     ``chosen`` (None without a selection: ``_bwd_select_kernel`` gives
     it) makes kv tile c's mask (kv, q) of a selection: every kv tile is
     then masked by it and by nothing else, where ``live`` says the pair
-    selects anything at all."""
+    selects anything at all.  ``bd`` (None without block diffusion's mask:
+    (the block length, the blocks a half)) reads the pair's kind from
+    ``ij_ref[2]``: every kv tile of a ``BD_WHOLE`` pair goes unmasked, of
+    a ``BD_MASKED`` one under ``_bd_mask`` of its positions."""
     t = pl.program_id(1)
     diagonal = ij_ref[0] == ij_ref[1]
     tile = q_ref.shape[1]
@@ -121,6 +205,12 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         s = dot(k, q, nt_dims) * scale                      # (kv, q)
         if chosen is not None:
             s = jnp.where(chosen(c), s, -jnp.inf)
+        elif masked and bd is not None:
+            block = k_ref.shape[1]
+            s = jnp.where(_bd_mask(
+                s.shape, 1, (ij_ref[0] % bd[1]) * block + t * tile,
+                (ij_ref[1] % bd[1]) * block + c * tile,
+                ij_ref[0] < bd[1], ij_ref[1] < bd[1], bd[0]), s, -jnp.inf)
         elif masked:
             at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                        axis)
@@ -137,6 +227,14 @@ def _bwd_block_kernel(scale, strip, rep, far_by, ij_ref, q_ref, k_ref, v_ref,
         # the selection holds the diagonal too: no tile goes by position
         for c in range(k_ref.shape[1] // tile):
             pl.when(live)(functools.partial(part, c, 0, tile, True))
+        return
+    if bd is not None:
+        # by the pair's kind alone: no tile goes by strips
+        inside = ij_ref[2] == BD_MASKED
+        for c in range(k_ref.shape[1] // tile):
+            pl.when(jnp.logical_not(inside))(
+                functools.partial(part, c, 0, tile, False))
+            pl.when(inside)(functools.partial(part, c, 0, tile, True))
         return
     # by position: a kv tile of the diagonal pair lies wholly under the
     # diagonal (c < t), on it (c == t: masked, by strips) or wholly above
@@ -284,10 +382,11 @@ def _tile_flags(select, tile: int):
                    axis=-1).astype(jnp.int32).reshape(-1)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "window",
+                                             "bd"))
 def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
                         block: int, interpret=None, window=None,
-                        select=None):
+                        select=None, bd=None):
     """One block pair of causal attention's flash backward, fused: q
     block ``ij[0]`` against kv block ``ij[1]`` (``block`` positions
     each; the pair whose two are equal is masked by position), the
@@ -315,7 +414,9 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     flags as ``_tile_flags(.., block)`` gives them of the query-major one)
     a pair is masked by its tile of the selection and by nothing else, and
     a pair that selects nothing hands the accumulators on
-    (``_bwd_select_kernel``).
+    (``_bwd_select_kernel``).  With ``bd`` (block diffusion's block
+    length; static) ``ij`` holds three entries, one of ``bd_pairs``'
+    triples, and the pair is masked as its kind says.
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -347,7 +448,8 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
             (dq, dk, dv))
     out = pl.pallas_call(
         functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
-                          _tile(tq, BWD_STRIP), rep, far_by),
+                          _tile(tq, BWD_STRIP), rep, far_by,
+                          bd=None if bd is None else (bd, s // block // 2)),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
                         for o in operands[7:]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -362,7 +464,8 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
                                  "arbitrary"),
             vmem_limit_bytes=BWD_VMEM_LIMIT),
         interpret=interpret,
-        name="otpu_attn_block_backward",
+        name="otpu_attn_block_backward" if bd is None
+        else "otpu_attn_bd_backward",
     )(*operands)
     return tuple(o.reshape(a.shape) for o, a in zip(out, (dq, dk, dv)))
 
@@ -548,9 +651,57 @@ def _select_fwd_kernel(scale, heads, lanes, flags_ref, q_ref, k_ref, v_ref,
         _write_out(o_ref, lse_ref, *state)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret", "window"))
+def _bd_fwd_kernel(scale, bl, half, kv_ref, kind_ref, q_ref, k_ref, v_ref,
+                   o_ref, lse_ref, m_ref, den_ref, num_ref):
+    """``_causal_fwd_kernel`` under block diffusion's mask: the grid's
+    third axis is the most kv tiles a q tile meets, and step n of q tile i
+    reads the table (``_bd_table``: flat, ``i x steps + n``): kv tile
+    ``kv_ref[..]`` (the index maps read the same entry) under kind
+    ``kind_ref[..]``: ``BD_WHOLE`` no mask, ``BD_MASKED`` ``_bd_mask`` of
+    the two tiles' positions and halves, 0 a step past the row's last pair,
+    which does nothing.  ``o`` and the logsumexp are written at the last
+    step.  A masked tile may show a row nothing (a noisy row of a half's
+    first block sees no clean key): the update guards its running max as
+    under a selection."""
+    i, n = pl.program_id(1), pl.program_id(2)
+    steps = pl.num_programs(2)
+    tile = q_ref.shape[1]
+    state = (m_ref, den_ref, num_ref)
+    j, kind = kv_ref[i * steps + n], kind_ref[i * steps + n]
+
+    @pl.when(n == 0)
+    def _():
+        _init_state(*state)
+
+    pl.when(kind == BD_WHOLE)(functools.partial(
+        _online_update, scale, q_ref, k_ref, v_ref, *state, None))
+    pl.when(kind == BD_MASKED)(functools.partial(
+        _online_update, scale, q_ref, k_ref, v_ref, *state, "select",
+        lambda: _bd_mask((tile, k_ref.shape[1]), 0, (i % half) * tile,
+                         (j % half) * tile, i < half, j < half, bl)))
+
+    @pl.when(n == steps - 1)
+    def _():
+        _write_out(o_ref, lse_ref, *state)
+
+
+def _bd_table(nt: int, tile: int, bl: int):
+    """``bd_pairs`` as the forward's grid reads it: (steps, kv tiles, kinds),
+    the two flat int32 (nt x steps), a row padded with its last kv tile
+    (fetched already) under kind 0."""
+    rows = [[] for _ in range(nt)]
+    for i, j, kind in bd_pairs(nt, tile, bl):
+        rows[i].append((j, kind))
+    steps = max(len(r) for r in rows)
+    rows = [r + [(r[-1][0], 0)] * (steps - len(r)) for r in rows]
+    flat = lambda at: jnp.asarray([e[at] for r in rows for e in r], jnp.int32)
+    return steps, flat(0), flat(1)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "window",
+                                             "bd"))
 def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
-                         select=None):
+                         select=None, bd=None):
     """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
     float32 and the logsumexp (b, h, s) float32 of q (b, h, s, d), k
     (b, n_kv, s, d) and v (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``
@@ -575,7 +726,9 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     visible to query t iff its bit of row t is set (the selection holds
     causality: nothing above the diagonal), every row selects a key, and a
     tile pair that selects nothing is passed over
-    (``_select_fwd_kernel``).
+    (``_select_fwd_kernel``).  With ``bd`` (block diffusion's block length;
+    static) the rows are a noisy and a clean half and the grid's third axis
+    walks ``bd_pairs``' tile pairs of a q tile (``_bd_fwd_kernel``).
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -601,6 +754,34 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     operands = [flat(q), flat(k), flat(v)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
     f32 = jnp.float32
+    if bd is not None:
+        steps, kv_of, kinds = _bd_table(nt, tile, bd)
+        kv_map = lambda g, i, n, kv, kd: (_group(g, rep), kv[i * steps + n],
+                                          0)
+        q_map = lambda g, i, n, kv, kd: (g, i, 0)
+        o, lse = pl.pallas_call(
+            functools.partial(_bd_fwd_kernel, 1.0 / math.sqrt(d), bd,
+                              nt // 2),
+            out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
+                       jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(bh, nt, steps),
+                in_specs=[pl.BlockSpec((1, tile, d), q_map),
+                          pl.BlockSpec((1, tile, d), kv_map),
+                          pl.BlockSpec((1, tile, hv), kv_map)],
+                out_specs=(pl.BlockSpec((1, tile, hv), q_map),
+                           pl.BlockSpec((1, 1, tile),
+                                        lambda g, i, n, kv, kd: (g, 0, i))),
+                scratch_shapes=[pltpu.VMEM((tile, 1), f32),
+                                pltpu.VMEM((tile, 1), f32),
+                                pltpu.VMEM((tile, hv), f32)]),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=FWD_VMEM_LIMIT),
+            interpret=interpret,
+            name="otpu_flash_bd_forward",
+        )(kv_of, kinds, *operands)
+        return o.reshape(b, h, s, hv), lse.reshape(b, h, s)
     if select is not None:
         lift = lambda fn: (lambda g, i, j, fl: fn(g, i, j))
         vma = vma | jax.typeof(select).vma

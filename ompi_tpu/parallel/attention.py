@@ -1,8 +1,9 @@
 """The attention sublayers on ``causal_flash_attention``: OLMoE's,
 DeepSeek-V3's latent attention (JoyAI-LLM-Flash) and grouped-query
-attention in the forms Nemotron-3-Super, LFM2, Qwen3-Next and
-SmallThinker publish, each with its entry in ``parallel/model.py``'s
-table.
+attention in the forms Nemotron-3-Super, LFM2, Qwen3-Next, SmallThinker
+and SDAR (over a noisy and a clean copy of every sequence, on
+``block_diffusion_flash_attention``) publish, each with its entry in
+``parallel/model.py``'s table.
 """
 from __future__ import annotations
 
@@ -12,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from ompi_tpu.parallel.causal import (ATTN_KEEPS,
-                                                causal_flash_attention)
+                                      block_diffusion_flash_attention,
+                                      causal_flash_attention)
 from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
                                       rope)
 from ompi_tpu.parallel.sublayer import Sublayer
@@ -78,7 +80,7 @@ def mla_attention(p, x, cfg, *, interpret: bool, at=None):
 
 
 def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
-                  windowed: bool = False):
+                  windowed: bool = False, diffused: bool = False):
     """Grouped-query attention, **without** the residual add, on the
     residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
     projections without bias; the ``n_heads_here`` query heads held here
@@ -88,11 +90,11 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     group's shared head through their index maps and sum its query
     heads' gradients in float32.
 
-    Four models' sublayer, told apart by what the layer holds and, where
+    Five models' sublayer, told apart by what the layer holds and, where
     the leaves cannot say, by the entry that runs it (``kind``, its
-    ``layer_types`` name, and ``windowed``).  Without ``q_norm`` and
-    outside ``layer_types`` (nemotron_h): no rotary embedding, q, k and v
-    cast as they leave their projections.  With ``q_norm`` and ``k_norm``
+    ``layer_types`` name, ``windowed`` and ``diffused``).  Without
+    ``q_norm`` and outside ``layer_types`` (nemotron_h): no rotary
+    embedding, q, k and v cast as they leave their projections.  With ``q_norm`` and ``k_norm``
     (head width,) (lfm2): RMSNorm with a gain over **each head's** width
     of q and of k, then RoPE in the half-split form where ``kind`` is one
     of ``cfg.rope_kinds``, both in float32; a ``wq`` twice as wide as
@@ -103,7 +105,13 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     ``rope_kinds``, and under ``windowed`` the last ``sliding_window`` keys
     (``causal_flash_attention``'s ``window``).  Heads are ``head_width``
     wide whatever the hidden width.  (Keye-VL-2.0's q, k and v share
-    lfm2's form, but its sublayer is ``dsa.dsa_attention``.)
+    lfm2's form, but its sublayer is ``dsa.dsa_attention``.)  Under
+    ``diffused`` (sdar_moe; lfm2's form) the ``s`` rows are a noisy copy
+    of every sequence before its clean copy: RoPE turns both halves at
+    positions ``0 .. s / 2 - 1`` and attention goes under block diffusion's
+    mask in blocks of ``block_length``
+    (``block_diffusion_flash_attention``: a noisy row sees its own block's
+    noisy rows and every earlier block's clean ones).
 
     Returns (the sublayer's output, no statistics, by token row what
     ``_gqa_reports`` lists: the first query head and the first key-value
@@ -114,13 +122,16 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     read and made of the first query head and its key-value head,
     ``attn_win_q``, ``attn_win_o`` (T, hd) and ``attn_win_k_seq``,
     ``attn_win_v_seq`` (T, hd) whole, because a row reads a window of
-    them)."""
+    them; under ``diffused`` the same four as ``bd_q``, ``bd_o``,
+    ``bd_k_seq`` and ``bd_v_seq``)."""
     b, s, _ = x.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     seen, gate = {}, None
     turned = kind in cfg.rope_kinds
-    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width)) if turned \
-        else (lambda t: t)
+    # both copies of a sequence stand at its positions
+    positions = jnp.tile(jnp.arange(s // 2), 2) if diffused else None
+    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width, positions)) \
+        if turned else (lambda t: t)
     window = cfg.sliding_window if windowed else None
     first = lambda a, c: jnp.concatenate(
         [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
@@ -153,12 +164,18 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
                 0, 2, 1, 3).astype(dt)
             q, k, v = (heads(matmul(h, p[w], dt), n)
                        for w, n in (("wq", nh), ("wk", nkv), ("wv", nkv)))
-    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret,
-                               window)
-    if window is not None:
+    if diffused:
+        o = block_diffusion_flash_attention(
+            q, k, v, min(cfg.attn_block, s // 2), interpret,
+            cfg.block_length)
+    else:
+        o = causal_flash_attention(q, k, v, min(cfg.attn_block, s),
+                                   interpret, window)
+    if window is not None or diffused:
         rows = lambda t: t[:, 0].reshape(b * s, -1).astype(jnp.float32)
-        seen.update(attn_win_q=rows(q), attn_win_k_seq=rows(k),
-                    attn_win_v_seq=rows(v), attn_win_o=rows(o))
+        names = ("bd_q", "bd_k_seq", "bd_v_seq", "bd_o") if diffused else (
+            "attn_win_q", "attn_win_k_seq", "attn_win_v_seq", "attn_win_o")
+        seen.update(zip(names, map(rows, (q, k, v, o))))
     with jax.named_scope("otpu_attn_proj"):
         if gate is not None:
             gated = o * jax.nn.sigmoid(gate)
@@ -203,11 +220,13 @@ def gqa_shapes(cfg, qk_norm=None) -> dict:
             **({"q_norm": (hd,), "k_norm": (hd,)} if norms else {})}
 
 
-def _gqa_reports(cfg, kind: str, windowed: bool) -> dict:
+def _gqa_reports(cfg, kind: str, windowed: bool,
+                 diffused: bool = False) -> dict:
     """What ``gqa_attention`` reports of a ``layer_types`` layer of
     ``kind``: q and k around the norm and RoPE of a kind RoPE turns, and of
     every kind of a model without a QK-norm; o around its gate; of a window
-    layer what the kernels read and made."""
+    layer, and of one under block diffusion's mask, what the kernels read
+    and made."""
     keys = ("attn_qk_in", "attn_qk") \
         if kind in cfg.rope_kinds or not cfg.qk_norm else ()
     if cfg.attn_output_gate:
@@ -215,13 +234,15 @@ def _gqa_reports(cfg, kind: str, windowed: bool) -> dict:
     if windowed:
         keys += ("attn_win_q", "attn_win_k_seq", "attn_win_v_seq",
                  "attn_win_o")
+    if diffused:
+        keys += ("bd_q", "bd_k_seq", "bd_v_seq", "bd_o")
     return dict.fromkeys(keys, 1)
 
 
-def _gqa(name: str, group: str, scope: str,
-         windowed: bool = False) -> Sublayer:
+def _gqa(name: str, group: str, scope: str, windowed: bool = False,
+         diffused: bool = False) -> Sublayer:
     """A ``layer_types`` model's grouped-query attention by its name."""
-    bound = dict(kind=name, windowed=windowed)
+    bound = dict(kind=name, windowed=windowed, diffused=diffused)
     return Sublayer(
         name=name, group=group, scope=scope,
         run=functools.partial(gqa_attention, **bound), shapes=gqa_shapes,
@@ -234,6 +255,9 @@ FULL = _gqa("full_attention", "attn", "otpu_attention")
 #: smallthinker's under its window (``sliding_window``): a full layer's
 #: leaves
 WINDOW = _gqa("sliding_attention", "swa", "otpu_swa", windowed=True)
+#: sdar_moe's: a full layer's leaves, over a noisy and a clean copy of
+#: every sequence under block diffusion's mask (``block_length``)
+DIFFUSED = _gqa("block_diffusion_attention", "bd", "otpu_bd", diffused=True)
 #: nemotron_h's ``*``: no QK-norm, no RoPE, a chip's share of the heads
 SHARED_KV = Sublayer(
     name="*", group="attn", scope="otpu_attention", run=gqa_attention,

@@ -1,6 +1,8 @@
 """Causal flash attention on the held heads: in full, under a sliding
-window (``causal_flash_attention``) or under a learned selection
-(``selected_flash_attention``), each a ``custom_vjp`` over
+window (``causal_flash_attention``), under a learned selection
+(``selected_flash_attention``) or under block diffusion's mask over a
+noisy and a clean copy of a sequence (``block_diffusion_flash_attention``:
+the one mask here that is not under the diagonal), each a ``custom_vjp`` over
 ``ops/flash_attention``'s kernels with a ``jnp`` twin that the CPU and
 the tests' references run, the backward pass's two walks of the block
 pairs, and the SPC counters of what was built.  The attention sublayers
@@ -93,7 +95,22 @@ def _select_bias(select, i, j, block: int, rep: int):
     return jnp.tile(bias, (1, rep, 1))[:, None]
 
 
-def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
+def _bd_bias(i, j, block: int, rep: int, bl: int, half: int):
+    """Block diffusion's (q block i, kv block j) as a bias for a group's
+    folded rows, (rep x block, block): 0 where the key is visible
+    (``ops/flash_attention._bd_mask``: the kernels' own rule), -inf
+    elsewhere.  ``i`` and ``j`` may be traced; ``half`` is the blocks a
+    half."""
+    from ompi_tpu.ops.flash_attention import _bd_mask
+
+    seen = _bd_mask((block, block), 0, (i % half) * block,
+                    (j % half) * block, i < half, j < half, bl)
+    return jnp.tile(jnp.where(seen, 0.0, -jnp.inf).astype(jnp.float32),
+                    (rep, 1))
+
+
+def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None,
+                       bd=None):
     """Causal attention's forward pass: (o float32, logsumexp float32)
     of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
     key-value head is read by ``h / n_kv`` consecutive query heads, and
@@ -114,11 +131,17 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
     packed eight keys a byte as ``ops/sparse_attention.pack_selection``
     packs a mask; None: everything here is what it was) every block pair
     goes under its tile of the selection (``_select_bias``) and under no
-    mask by position, and any row may see nothing of any block."""
+    mask by position, and any row may see nothing of any block.  Under
+    ``bd`` (block diffusion's block length: the rows are a noisy half
+    before a clean one) q block i meets the kv blocks ``bd_pairs`` lists
+    for it, each under ``_bd_bias``."""
     w = _window_in_blocks(window, block, q.shape[2])
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
+        if bd is not None:
+            return flash_causal_forward(q, k, v, block=block,
+                                        interpret=False, bd=bd)
         if select is not None:
             return flash_causal_forward(q, k, v, block=block,
                                         interpret=False, select=select)
@@ -130,26 +153,30 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None):
     bias = _group_bias(block, h // k.shape[1])
     qb = _group_blocks(q, k.shape[1], block)
     outs, lses = [], []
+    pairs = _walked_pairs(nb, block, w, bd)
     for i in range(nb):
         qi = qb[i]
         zero = (qi[..., 0] * 0).astype(jnp.float32)    # carries q's vma
         m, den = zero - jnp.inf, zero
         num = jnp.zeros(v.shape[-1:], jnp.float32) + zero[..., None]
-        for j in range(0 if w is None else max(0, i - w), i + 1):
+        for j in (j for at, j in pairs if at == i):
             kj = k[:, :, j * block:(j + 1) * block]
             vj = v[:, :, j * block:(j + 1) * block]
             sc = contract("bhqd,bhkd->bhqk", qi, kj, q.dtype) * scale
-            if select is not None:
+            if bd is not None:
+                sc = sc + _bd_bias(i, j, block, h // k.shape[1], bd, nb // 2)
+            elif select is not None:
                 sc = sc + _select_bias(select, i, j, block, h // k.shape[1])
             elif j == i:
                 sc = sc + bias
-            far = select is None and w is not None and j == i - w
+            far = select is None and bd is None and w is not None \
+                and j == i - w
             if far:
                 sc = sc + _far_bias(block, h // k.shape[1])
             new_m = at_m = jnp.maximum(m, sc.max(axis=-1))
             # a row that sees nothing yet (of a window's far block, or of
             # any block under a selection): exp(-inf - 0) = 0
-            if far or select is not None:
+            if far or select is not None or bd is not None:
                 at_m = jnp.where(new_m == -jnp.inf, 0.0, new_m)
             c = jnp.exp(m - at_m)
             p = jnp.exp(sc - at_m[..., None])
@@ -199,7 +226,7 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool,
     return _causal_fwd_blocks(q, k, v, block, interpret, window)[0]
 
 
-def _count_built(q, k, block, window) -> None:
+def _count_built(q, k, block, window, bd=None) -> None:
     """SPC ``attn_built``: the causal attention passes made, forward
     rule or backward rule, while steps were traced (JAX traces a pass
     more than once); ``attn_shared_kv_built``: those of them whose k and
@@ -207,7 +234,10 @@ def _count_built(q, k, block, window) -> None:
     twins, that way; ``attn_window_built``: those made under a window;
     ``attn_pairs_walked`` the block pairs the passes walk and
     ``attn_pairs_causal`` those full causal passes of their lengths
-    would."""
+    would.  Under block diffusion's mask (``bd``) also ``bd_built``, the
+    passes made under it, ``bd_pairs_visible`` the (query, key) pairs a
+    pass attends to (``bd_visible_pairs``) and ``bd_pairs_causal`` those
+    a causal pass over its rows would, both from the shapes."""
     nb = q.shape[2] // block
     w = _window_in_blocks(window, block, q.shape[2])
     spc.record("attn_built", 1)
@@ -215,8 +245,13 @@ def _count_built(q, k, block, window) -> None:
         spc.record("attn_shared_kv_built", 1)
     if w is not None:
         spc.record("attn_window_built", 1)
-    spc.record("attn_pairs_walked", len(_window_pairs(nb, w)))
+    spc.record("attn_pairs_walked", len(_walked_pairs(nb, block, w, bd)))
     spc.record("attn_pairs_causal", nb * (nb + 1) // 2)
+    if bd is not None:
+        b, rows = q.shape[0], q.shape[2]
+        spc.record("bd_built", 1)
+        spc.record("bd_pairs_visible", b * bd_visible_pairs(rows // 2, bd))
+        spc.record("bd_pairs_causal", b * rows * (rows + 1) // 2)
 
 
 def _causal_fwd(q, k, v, block, interpret, window=None):
@@ -252,19 +287,20 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
             contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
 
 
-def _causal_bwd(block, interpret, window, res, do, select=None):
+def _causal_bwd(block, interpret, window, res, do, select=None, bd=None):
     q, k, v, o, lse = res
-    _count_built(q, k, block, window)
+    _count_built(q, k, block, window, bd)
     h, n_kv = q.shape[1], k.shape[1]
     nb = q.shape[2] // block
     w = _window_in_blocks(window, block, q.shape[2])
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
     if not interpret:
-        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select)
+        return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select,
+                                 bd)
     if nb > UNROLLED_BLOCKS:
         return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w,
-                                   select)
+                                   select, bd)
     dt = q.dtype
     scale = 1.0 / math.sqrt(q.shape[-1])
     bias = _group_bias(block, h // n_kv)
@@ -275,10 +311,11 @@ def _causal_bwd(block, interpret, window, res, do, select=None):
     dq = [0.0] * nb
     dk = [0.0] * nb
     dv = [0.0] * nb
-    for i, j in _window_pairs(nb, w):
+    for i, j in _walked_pairs(nb, block, w, bd):
         dq_c, dk_c, dv_c = _bwd_pair(
             qb[i], cut(k, j), cut(v, j), dob[i], lseb[i], deltab[i],
-            _select_bias(select, i, j, block, h // n_kv)
+            _bd_bias(i, j, block, h // n_kv, bd, nb // 2) if bd is not None
+            else _select_bias(select, i, j, block, h // n_kv)
             if select is not None
             else bias if j == i else far if i - j == w else None, scale, dt)
         dq[i], dk[j], dv[j] = dq[i] + dq_c, dk[j] + dk_c, dv[j] + dv_c
@@ -286,7 +323,18 @@ def _causal_bwd(block, interpret, window, res, do, select=None):
     return _ungroup_blocks(jnp.stack(dq), h).astype(dt), cat(dk), cat(dv)
 
 
-def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
+def _walked_pairs(nb: int, block: int, w, bd) -> list:
+    """The (q block, kv block) pairs a pass walks: ``_window_pairs``, or
+    under block diffusion's mask those of ``bd_pairs``."""
+    if bd is None:
+        return _window_pairs(nb, w)
+    from ompi_tpu.ops.flash_attention import bd_pairs
+
+    return [(i, j) for i, j, _ in bd_pairs(nb, block, bd)]
+
+
+def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None,
+                        bd=None):
     """The same pairs in the same order (q block by q block, kv blocks
     ascending), one a step of a ``lax.scan`` over float32 accumulators."""
     dt = q.dtype
@@ -297,7 +345,7 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
     qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
                              for a in (q, do, lse, delta))
     kb, vb = (_group_blocks(a, n_kv, block) for a in (k, v))
-    pairs = _window_pairs(nb, w)
+    pairs = _walked_pairs(nb, block, w, bd)
     zero = lambda a: (a * 0).astype(jnp.float32)         # carries a's vma
 
     def step(acc, ij):
@@ -307,6 +355,8 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
             bias = jnp.where(i - j == w, _far_bias(block, h // n_kv), bias)
         if select is not None:
             bias = _select_bias(select, i, j, block, h // n_kv)
+        if bd is not None:
+            bias = _bd_bias(i, j, block, h // n_kv, bd, nb // 2)
         dq_c, dk_c, dv_c = _bwd_pair(
             qb[i], kb[j], vb[j], dob[i], lseb[i], deltab[i], bias, scale,
             dt)
@@ -323,7 +373,8 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None):
             _ungroup_blocks(dv, n_kv).astype(dt))
 
 
-def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
+def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None,
+                      bd=None):
     """The same pairs in the same order, each one call of the fused
     Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
     ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
@@ -333,9 +384,10 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
     in whole and the pair is an operand, so neither slices.  Under a
     selection the kernel reads the packed bytes key-major, as it holds
     the scores: (b, s / 8, s), transposed once here, beside the pairs'
-    flags."""
+    flags.  Under block diffusion's mask the operand is one of
+    ``bd_pairs``' triples, the pair's kind behind it."""
     from ompi_tpu.ops.flash_attention import (_tile_flags,
-                                              attn_block_backward)
+                                              attn_block_backward, bd_pairs)
 
     dt = q.dtype
     nb = q.shape[2] // block
@@ -345,11 +397,15 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None):
         pair = lambda acc, ij: attn_block_backward(
             ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
             select=select)
+    elif bd is not None:
+        pair = lambda acc, ij: attn_block_backward(
+            ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
+            bd=bd)
     else:
         pair = lambda acc, ij: attn_block_backward(
             ij, q, k, v, do, lse, delta, *acc, block=block,
             interpret=False, window=None if w is None else w * block)
-    pairs = _window_pairs(nb, w)
+    pairs = _window_pairs(nb, w) if bd is None else bd_pairs(nb, block, bd)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     vma = tuple(frozenset().union(*(jax.typeof(a).vma
                                     for a in (q, k, v, do))))
@@ -420,3 +476,55 @@ def _selected_bwd(block, interpret, topk, res, cts):
 
 
 selected_flash_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
+# -- block diffusion (BD3-LM's training pass; SDAR) ---------------------------
+def bd_visible_pairs(length: int, bl: int) -> int:
+    """The (query, key) pairs one sequence of ``length`` tokens in blocks
+    of ``bl`` attends to under block diffusion's mask over its ``2 x
+    length`` rows: the noisy half against itself by blocks, against the
+    clean half strictly before its block, the clean half against itself up
+    to its block."""
+    n = length // bl
+    return length * bl + bl * bl * (n * (n - 1) // 2 + n * (n + 1) // 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def block_diffusion_flash_attention(q, k, v, block: int, interpret: bool,
+                                    bl: int):
+    """``causal_flash_attention`` under **block diffusion's mask**: the
+    rows of q (b, h, 2L, hd), k (b, n_kv, 2L, hd) and v (b, n_kv, 2L, hv)
+    are a noisy copy of a sequence of L tokens before its clean copy, both
+    at positions 0 .. L - 1 (the caller's RoPE says so; nothing here reads
+    a position but through the mask), L a multiple of ``block``, and with
+    ``blk = position // bl`` query row i sees key row j iff both are noisy
+    and ``blk(j) == blk(i)`` (its own block, both ways: a **later** key
+    too), or i is noisy, j clean and ``blk(j) < blk(i)``, or both are clean
+    and ``blk(j) <= blk(i)``; a clean row sees no noisy one.  Both passes
+    walk the block pairs that hold a visible entry and no other
+    (``ops/flash_attention.bd_pairs``: static, 80 of a causal walk's 136
+    at 16 blocks), a pair wholly visible under no mask, the others under
+    ``_bd_mask`` of their positions, never an array.  Returns o (b, h, 2L,
+    hv) float32; the forward keeps o and the logsumexp (``ATTN_KEEPS``)
+    and the backward recomputes the scores under the same mask.  A
+    sibling of ``selected_flash_attention``: ``causal_flash_attention``
+    and everything it compiles to are untouched."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, bd=bl)[0]
+
+
+def _bd_fwd(q, k, v, block, interpret, bl):
+    if (q.shape[2] // 2) % block:
+        raise ValueError(f"a half of {q.shape[2] // 2} rows is no whole "
+                         f"number of blocks of {block}")
+    _count_built(q, k, block, None, bl)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, bd=bl)
+    o = checkpoint_name(o, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
+    return o, (q, k, v, o, lse)
+
+
+def _bd_bwd(block, interpret, bl, res, do):
+    return _causal_bwd(block, interpret, None, res, do, bd=bl)
+
+
+block_diffusion_flash_attention.defvjp(_bd_fwd, _bd_bwd)

@@ -17,7 +17,8 @@ import json
 #: walked by before a dense SwiGLU (``first_k_dense_replace``); before the
 #: experts it is the capital
 LAYER_TYPES = {"conv": "c", "full_attention": "a", "linear_attention": "l",
-               "sliding_attention": "w", "sparse_attention": "s"}
+               "sliding_attention": "w", "sparse_attention": "s",
+               "block_diffusion_attention": "b"}
 #: a ``hybrid_override_pattern``'s letters (nemotron_h): a layer is one
 #: sublayer, a Mamba-2 mixer, attention or the experts
 HYBRID_LETTERS = "M*E"
@@ -134,7 +135,8 @@ class ModelConfig:
     # smallthinker's keys (SmallThinker-21BA3B)
     sliding_window: int = 0         # a sliding_attention layer's window
     # the kinds of layer whose q and k RoPE turns (the file's rope_layout)
-    rope_kinds: tuple = ("full_attention", "sliding_attention")
+    rope_kinds: tuple = ("full_attention", "sliding_attention",
+                         "block_diffusion_attention")
     qk_norm: bool = True            # a layer_types model's attention
     router_before_attention: bool = False
     # KeyeVL2's keys (Keye-VL-2.0-30B-A3B): its ``sa_config``
@@ -144,6 +146,11 @@ class ModelConfig:
     index_q_chunk: int = 512        # the score blocks' sizes: they change
     index_kv_chunk: int = 512       # no number
     index_loss_coef: float = 1.0
+    # sdar_moe's keys (SDAR-30B-A3B): block diffusion's training pass
+    block_length: int = 0           # tokens a block (0: next-token training)
+    mask_token_here: int = -1       # the mask token's row of ``vocab_rows``
+    noise_seed: int = 0             # the step's noise is keyed by it
+    t_min: float = 0.001            # a block's noise level: from here to 1
 
     @property
     def pattern_here(self) -> str:
@@ -349,6 +356,24 @@ class ModelConfig:
                 "layers', with an indexer of index_heads heads of an even "
                 "index_head_dim, on QK-normed attention with RoPE over the "
                 "whole head, no output gate and no sliding window beside it")
+        diffuses = "block_diffusion_attention" in self.layer_types
+        if diffuses != bool(self.block_length) or (diffuses and (
+                set(self.layer_types) != {"block_diffusion_attention"}
+                or not self.qk_norm or self.attn_output_gate or windowed
+                or sparse or self.partial_rotary_factor != 1.0
+                or self.tie_word_embeddings
+                or self.seq_len % self.block_length
+                or self.seq_len % min(self.attn_block, self.seq_len)
+                or not 0 <= self.mask_token_here < self.vocab_rows
+                or not 0.0 < self.t_min <= 1.0)):
+            raise NotImplementedError(
+                f"block_length {self.block_length}: block diffusion is a "
+                "layer_types model's whose every layer is "
+                "block_diffusion_attention (QK-normed attention with RoPE "
+                "over the whole head, no gate, no window, no selection, an "
+                "untied head), on sequences of whole blocks and whole "
+                "attn_block tiles, with mask_token_here a row of the held "
+                "vocabulary and t_min in (0, 1]")
         if not typed and (not self.qk_norm or self.router_before_attention):
             raise NotImplementedError(
                 f"qk_norm {self.qk_norm} / router_before_attention "
@@ -468,6 +493,29 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
                 "(indexer_num_kv_heads 1)")
         body.setdefault("layer_types",
                         ["sparse_attention"] * body["num_hidden_layers"])
+    sdar = body.get("model_type") == "sdar_moe"
+    if sdar:
+        for key, runs in (("mlp_only_layers", []), ("decoder_sparse_step", 1),
+                          ("use_sliding_window", False)):
+            if body.get(key, runs) != runs:
+                raise NotImplementedError(
+                    f"{path}: {key} {body[key]}: an sdar_moe model is run "
+                    "with every layer sparse and no attention window")
+        if not body.get("block_length") or hybrid or "kv_lora_rank" in body \
+                or "layer_types" in body or body.get("sliding_window") \
+                or "mask_token_here" not in body:
+            raise NotImplementedError(
+                f"{path}: an sdar_moe model is trained by block diffusion: "
+                "the file gives block_length and mask_token_here, and no "
+                "layer_types, window, latent attention or hybrid pattern")
+        body.setdefault("layer_types", ["block_diffusion_attention"]
+                        * body["num_hidden_layers"])
+    elif any(key in body or key in body.get("train", {}) for key in (
+            "block_length", "mask_token_here", "noise_seed", "t_min")):
+        raise NotImplementedError(
+            f"{path}: block_length / mask_token_here / noise_seed / t_min: "
+            "block diffusion's training pass is an sdar_moe model's; a "
+            f"model_type {body.get('model_type')} model trains next-token")
     scaling = body.get("rope_scaling")
     if scaling:
         # M-RoPE's three position components are equal on a text token, so

@@ -66,16 +66,20 @@ def l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def rope(x, theta: float, rotary: int | None = None):
+def rope(x, theta: float, rotary: int | None = None, positions=None):
     """Rotary position embedding on ``x`` (b, h, s, hd) at positions
     0..s-1, the half-split form of the HF models (``rotate_half``), over
     the leading ``rotary`` entries of the head (all of it where None:
     OLMoE's and lfm2's; qwen3_next's ``partial_rotary_factor`` turns the
-    first quarter and passes the rest as it is)."""
+    first quarter and passes the rest as it is).  ``positions`` (s,)
+    gives the rows others (block diffusion's two copies of a sequence
+    repeat 0..L-1); None is the call without it, bit for bit."""
     hd, s = x.shape[-1], x.shape[-2]
     rot = hd if rotary is None else rotary
     inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    at = jnp.arange(s, dtype=jnp.float32) if positions is None \
+        else jnp.asarray(positions).astype(jnp.float32)
+    ang = at[:, None] * inv[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)           # (s, rot)
     x1, x2 = x[..., :rot // 2], x[..., rot // 2:rot]
     whole = rot == hd

@@ -16,7 +16,8 @@ from typing import NamedTuple
 import jax
 
 from ompi_tpu.parallel import experts
-from ompi_tpu.parallel.attention import FULL, MLA, OLMOE, SHARED_KV, WINDOW
+from ompi_tpu.parallel.attention import (DIFFUSED, FULL, MLA, OLMOE,
+                                         SHARED_KV, WINDOW)
 from ompi_tpu.parallel.config import HYBRID_LETTERS, LAYER_TYPES
 from ompi_tpu.parallel.gdn import GDN
 from ompi_tpu.parallel.mamba import MIXER
@@ -24,7 +25,8 @@ from ompi_tpu.parallel.short_conv import CONV
 from ompi_tpu.parallel.dsa import DSA
 from ompi_tpu.parallel.sublayer import Sublayer
 
-OPERATORS = (MIXER, SHARED_KV, CONV, FULL, GDN, WINDOW, DSA, OLMOE, MLA)
+OPERATORS = (MIXER, SHARED_KV, CONV, FULL, GDN, WINDOW, DSA, DIFFUSED, OLMOE,
+             MLA)
 FEED_FORWARDS = (experts.DENSE, experts.SORTED, experts.SHARED_LOCAL,
                  experts.LATENT)
 SUBLAYERS = OPERATORS + FEED_FORWARDS

@@ -3,7 +3,9 @@
 ``lax.scan``, each layer under ``jax.checkpoint``), the head's
 cross-entropy in blocks of rows, the routers' losses and the next-n
 module's, with what a step reports of them (its ``aux``, listed in
-``parallel/train.py``).
+``parallel/train.py``); and, for a model trained by block diffusion
+(``block_length``), the step's noise, the noisy and the clean copy of
+every sequence side by side, and the masked rows' weighted loss.
 """
 from __future__ import annotations
 
@@ -30,30 +32,41 @@ def sample_rows(rows: int) -> np.ndarray:
     return (np.arange(1, n + 1) * rows) // n - 1
 
 
-def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
+def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
+                       weights=None):
     """Summed cross-entropy of ``softmax(h @ w)`` against ``labels``, by
     blocks of ``block_rows`` rows so that no (T, V) array is ever held.
     The forward pass also makes the two gradients (``softmax - onehot``
     is at hand in each block), so the backward pass only scales them:
-    the head's logits are computed once a step, not twice.  Returns
-    (the sum over rows, per row (logsumexp, the label's logit))."""
+    the head's logits are computed once a step, not twice.  ``weights``
+    (T,) float32 (None: one a row, and the program is the call's without
+    it) gives each row's share of the sum, a constant of the step; a row
+    of weight zero adds nothing to either gradient.  Returns (the
+    weighted sum over rows, per row (logsumexp, the label's logit))."""
     t, d = h.shape
     nblk = t // block_rows
     if nblk * block_rows != t:
         raise ValueError(f"{t} rows are not whole blocks of {block_rows}")
 
+    by_block = () if weights is None else (
+        weights.reshape(nblk, block_rows),)
+
     def run(h, w, labels):
         def block(carry, xs):
             total, dw = carry
-            hb, lb = xs
+            hb, lb, *wb = xs
             logits = matmul(hb, w, compute_dtype)            # (rows, V) f32
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
             dlogits = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
                 lb, logits.shape[-1], dtype=jnp.float32)
+            lost = lse - picked
+            if wb:
+                with jax.named_scope("otpu_bd_loss"):
+                    dlogits, lost = dlogits * wb[0][:, None], lost * wb[0]
             dh = matmul(dlogits, w.T, compute_dtype)
             dw = dw + matmul(hb.T, dlogits, compute_dtype, weight=False)
-            return (total + jnp.sum(lse - picked), dw), (
+            return (total + jnp.sum(lost), dw), (
                 dh, jnp.stack([lse, picked], axis=-1))
 
         vma = tuple(jax.typeof(h).vma | jax.typeof(labels).vma)
@@ -62,7 +75,7 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
             zero = jax.lax.pcast(zero, vma, to="varying")
         (total, dw), (dh, rows) = jax.lax.scan(
             block, zero, (h.reshape(nblk, block_rows, d),
-                          labels.reshape(nblk, block_rows)))
+                          labels.reshape(nblk, block_rows), *by_block))
         return total, rows.reshape(t, 2), dh.reshape(t, d), dw
 
     @jax.custom_vjp
@@ -80,6 +93,45 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype):
 
     ce.defvjp(fwd, bwd)
     return ce(h, w)
+
+
+def block_diffusion_noise(tokens, labels, cfg: ModelConfig):
+    """A step's noise (BD3-LM's training pass, arXiv:2503.09573): (a noise
+    level a block (b, s / B) float32, which tokens are replaced by the mask
+    token (b, s) bool).  A sequence's draw is keyed by ``cfg.noise_seed``
+    and the sequence's **two spare ids**, the last two of its ``labels``
+    (a batch holds two ids more than the step reads): ``key =
+    fold_in(fold_in(PRNGKey(noise_seed), spare[0]), spare[1])``; ``k_c =
+    bits(fold_in(key, 0), (s / B,)) >> 8`` a block and ``k_i =
+    bits(fold_in(key, 1), (s,)) >> 8`` a token, uniform 24-bit integers.
+    The level is ``t_c = t_min + (1 - t_min) u_c`` on the grid of 2^-24,
+    **in integers**: ``q_c = m + floor((2^24 - m) k_c / 2^24)`` with ``m =
+    round(t_min 2^24)`` and ``t_c = q_c / 2^24``; token i of block c is
+    masked iff ``k_i < q_c``, with probability ``t_c`` exactly.  No float
+    is rounded and no compiler's fused multiply-add can turn a bit (the
+    float form differed in its last bit between a jitted and an op-by-op
+    run on one machine): one seed and one order of batches repeat their
+    noise, two batches draw different noise, and whoever holds the batch
+    draws it again bit for bit."""
+    b, s = tokens.shape
+    bl = cfg.block_length
+    base = jax.random.PRNGKey(cfg.noise_seed)
+    floor_ = round(cfg.t_min * (1 << 24))
+    a1, a0 = ((1 << 24) - floor_) >> 12, ((1 << 24) - floor_) & 0xFFF
+
+    def draw(spare):
+        key = jax.random.fold_in(jax.random.fold_in(base, spare[0]), spare[1])
+        return (jax.random.bits(jax.random.fold_in(key, 0), (s // bl,),
+                                jnp.uint32) >> 8,
+                jax.random.bits(jax.random.fold_in(key, 1), (s,),
+                                jnp.uint32) >> 8)
+
+    by_block, by_token = jax.vmap(draw)(labels[:, -2:].astype(jnp.uint32))
+    # (2^24 - m) k / 2^24 by limbs of 12 bits: no product passes 32 bits
+    k1, k0 = by_block >> 12, by_block & 0xFFF
+    grid = floor_ + a1 * k1 + ((a1 * k0 + a0 * k1 + ((a0 * k0) >> 12)) >> 12)
+    levels = grid.astype(jnp.float32) * (2.0 ** -24)
+    return levels, by_token < jnp.repeat(grid, bl, axis=1)
 
 
 def layer_checkpoint_policy():
@@ -153,10 +205,34 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     (``layers`` (L, E) and ``mtp`` (1, E)); nothing is differentiated
     with respect to it.  Where the model has a next-next-token module,
     ``labels`` is one position longer than ``tokens``: ``labels[:, i]``
-    follows ``tokens[:, i]`` and ``labels[:, i + 1]`` follows that."""
+    follows ``tokens[:, i]`` and ``labels[:, i + 1]`` follows that.
+
+    A model trained by block diffusion (``cfg.block_length``: SDAR, on
+    BD3-LM's objective) reads ``tokens`` as the clean sequences ``x0`` and
+    of ``labels`` the last two ids alone, which key the step's noise
+    (``block_diffusion_noise``).  The layers walk the ``2 s`` rows ``[xt ;
+    x0]`` of every sequence, the noisy copy (a masked token replaced by
+    ``mask_token_here``) before the clean one, and know nothing of the
+    halves but through their attention sublayer's mask and positions.  The
+    head reads the noisy half's rows, and the loss is ``(1 / (b s)) sum_i
+    m_i / t_blk(i) x (-log softmax(logits_i)[x0_i])``, ``m_i`` 1 where row
+    i is masked: no shift, MDLM's weight for the linear schedule
+    (arXiv:2406.07524), an unmasked row at weight zero; the load-balancing
+    loss is over all ``2 s`` rows' routing.  ``rows`` then holds the noisy
+    half's rows, and ``aux`` also the noise: ``bd_mask`` (b, s) uint8,
+    ``bd_levels`` (b, s / B), ``bd_masked`` the masked rows and
+    ``bd_weight_sum`` their weights' sum, over the whole batch."""
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
-    at = sample_rows(b * s)
+    ids, levels, masked = tokens, None, None
+    if cfg.block_length:
+        with jax.named_scope("otpu_bd_noise"):
+            levels, masked = block_diffusion_noise(tokens, labels, cfg)
+            ids = jnp.concatenate([jnp.where(
+                masked, jnp.asarray(cfg.mask_token_here, tokens.dtype),
+                tokens), tokens], axis=1)
+    at = sample_rows(ids.size)          # of the rows the layers walk
+    at_head = sample_rows(b * s)        # of the rows the head reads
     bias = bias or {}
 
     @functools.cache    # one function a kind, so that JAX traces it once
@@ -191,7 +267,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         return run
 
     with jax.named_scope("otpu_embed"):
-        x = params["embed"][tokens]                          # (b, s, d) f32
+        x = params["embed"][ids]                             # (b, s, d) f32
     with jax.named_scope("otpu_layers"):
         if cfg.pattern_here:
             x, (st, chosen, sample) = _walk_pattern(
@@ -208,11 +284,21 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     # gradient is the sum of the gather's and the cross-entropy's
     head = params["embed"].T if cfg.tie_word_embeddings else params["head"]
     with jax.named_scope("otpu_head"):
+        if masked is None:
+            targets, weighted = labels[:, :s], ()
+        else:
+            # the noisy half's rows against their own clean tokens
+            x, targets = x[:, :s], tokens
+            with jax.named_scope("otpu_bd_loss"):
+                weights = jnp.where(masked, 1.0 / jnp.repeat(
+                    levels, cfg.block_length, axis=1), 0.0)
+                weighted = (weights.reshape(b * s),)
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
-            h.reshape(b * s, -1), head,
-            labels[:, :s].reshape(b * s), head_rows, cfg.compute_dtype)
-    routed = cfg.n_sparse_here * n_global  # rows of all routers' logits
+            h.reshape(b * s, -1), head, targets.reshape(b * s), head_rows,
+            cfg.compute_dtype, *weighted)
+    # rows of all routers' logits
+    routed = cfg.n_sparse_here * n_global * ids.shape[1] // s
     with jax.named_scope("otpu_loss"):
         ce = psum(ce_sum) / n_global
         lb = z = jnp.zeros((), jnp.float32)
@@ -236,8 +322,14 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             total = total + index
     losses, loads = [ce, lb, z], st["slots"]
     with jax.named_scope("otpu_stats"):
-        sample["head_in"] = h.reshape(b * s, -1)[at]
+        sample["head_in"] = h.reshape(b * s, -1)[at_head]
     aux = {}
+    if masked is not None:
+        with jax.named_scope("otpu_stats"):
+            aux.update(
+                bd_mask=masked.astype(jnp.uint8), bd_levels=levels,
+                bd_masked=psum(jnp.sum(masked.astype(jnp.float32))),
+                bd_weight_sum=psum(jnp.sum(weights)))
     if cfg.n_mtp_here:
         # DeepSeek-V3's multi-token prediction, depth one: the last
         # layer's output (before the final norm) joined with the next
@@ -275,7 +367,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                       "mtp_head_in": h2.reshape(b * s, -1)[at]}
     if cfg.n_experts_here < cfg.num_experts:
         first = cfg.first_expert_here
-        chunk = experts.chunk_rows(tokens.size, cfg.num_experts_per_tok,
+        chunk = experts.chunk_rows(ids.size, cfg.num_experts_per_tok,
                                    cfg.n_experts_here, cfg.num_experts)
         with jax.named_scope("otpu_stats"):
             held = jnp.sum(loads[:, first:first + cfg.n_experts_here],

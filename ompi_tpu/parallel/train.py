@@ -1,6 +1,7 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
 Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
-Keye-VL-2.0-30B-A3B's language model): widths from a configuration file
+Keye-VL-2.0-30B-A3B's language model, SDAR-30B-A3B by block diffusion):
+widths from a configuration file
 (``parallel/config.py``), not from the mesh; the kinds of layer from
 ``parallel/model.py``'s table.  The parameter tree and its initialisation,
 AdamW, the routers' bias update, ``build_train_step`` around
@@ -58,7 +59,10 @@ PROBE = 64              # entries of each leaf that a step reports
 #: float32 parts' precision can be read from one step alone; under
 #: ``tie_word_embeddings`` ``embed_probe_read`` (PROBE,), whether a probed
 #: entry of ``embed`` lies in a row the step's tokens read (the others'
-#: gradient is the head's alone)
+#: gradient is the head's alone); of a model trained by block diffusion
+#: the step's noise, ``bd_mask`` (b, s) uint8, ``bd_levels`` (b, s / B),
+#: ``bd_masked`` and ``bd_weight_sum`` (``objective.model_loss``), and
+#: ``rows`` of the noisy half's rows alone
 
 
 def pattern_layer_shapes(cfg: ModelConfig) -> dict:
@@ -304,6 +308,9 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         aux_specs["mtp_rows"] = aux_specs["sample"]["mtp_head_in"] = batch
     if cfg.n_experts_here < cfg.num_experts:
         aux_specs["local_slots"] = aux_specs["chunk_rows"] = rep
+    if cfg.block_length:
+        aux_specs.update(bd_mask=batch, bd_levels=batch, bd_masked=rep,
+                         bd_weight_sum=rep)
 
     def otpu_train_step(state, tokens, labels):
         return shard_map(body, mesh=mesh, in_specs=(rep, batch, batch),
@@ -436,9 +443,12 @@ def record_step_stats(aux) -> int:
     experts and to absent ones add to ``moe_local_slots`` and
     ``moe_absent_slots``, and the rows the held experts' loops walked
     for them (whole chunks) to ``moe_chunk_rows``; ``train_steps_read``
-    counts the steps read."""
+    counts the steps read.  Of a step trained by block diffusion the rows
+    its noise masked add to ``bd_rows_masked``."""
     loads = np.asarray(aux["loads"])
     spc.record("train_steps_read")
+    if "bd_masked" in aux:
+        spc.record("bd_rows_masked", int(np.asarray(aux["bd_masked"])))
     if "local_slots" in aux:
         here = int(np.asarray(aux["local_slots"]))
         spc.record("moe_local_slots", here)

@@ -164,6 +164,15 @@ _COUNTERS = (
     # is what the selection leaves of the triangle; and the bytes of the
     # selection a pass reads, packed eight keys a byte: b x s x s / 8
     "dsa_built", "dsa_keys_selected", "dsa_keys_causal", "dsa_mask_bytes",
+    # block diffusion (parallel/causal.block_diffusion_flash_attention):
+    # the attention passes made under its mask while steps were traced,
+    # the (query, key) pairs they attend to over a noisy and a clean copy
+    # of every sequence and those causal passes over the same rows would,
+    # both from the shapes (visible over causal: 50.02% at 8,192 tokens in
+    # blocks of 4); and, read back a step outside every window
+    # (parallel/train.record_step_stats), the token rows the steps' noise
+    # replaced by the mask token
+    "bd_built", "bd_pairs_visible", "bd_pairs_causal", "bd_rows_masked",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

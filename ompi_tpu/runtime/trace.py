@@ -1077,6 +1077,12 @@ STEP_SCOPES = (
                             # RoPE and score blocks
     "otpu_dsa_select",      # the exact top-k and whatever builds the mask
     "otpu_dsa_loss",        # pbar, the KL, the indexer's backward
+    "otpu_bd",              # a block-diffusion attention sublayer, whole
+                            # (in otpu_attention's place)
+    "otpu_bd_noise",        # the step's noise: levels, the masked copy,
+                            # the two copies' ids side by side
+    "otpu_bd_loss",         # inside otpu_head: the masked rows' weights
+                            # and the weighted sum
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

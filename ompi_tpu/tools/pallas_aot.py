@@ -254,15 +254,15 @@ def cases(mesh1d, mesh2d):
             + (wide(d), wide(d, n_kv), wide(hv, n_kv))), {
                 "block": 1024, "interpret": False, **window}
 
-    def attn_backward_walk(b, h, s, d, hv, n_kv=None, window=None):
+    def attn_backward_walk(b, h, s, d, hv, n_kv=None, window=None, **bd):
         from ompi_tpu.parallel import causal
 
         q, k, v, _, lse, _ = attn_bwd_args(b, h, s, d, hv, n_kv)
         o = _sds((b, h, s, hv), jnp.float32, one, P())  # and its cotangent
         bwd = causal._causal_bwd
         return jax.jit(lambda q, k, v, o, lse, do: bwd(
-            1024, False, window, (q, k, v, o, lse), do)), (q, k, v, o, lse,
-                                                          o)
+            1024, False, window, (q, k, v, o, lse), do, **bd)), (
+                q, k, v, o, lse, o)
 
     # attention's forward (``causal._causal_fwd_blocks`` where Mosaic
     # compiles): one call a layer, q, k and v whole, the blocks through
@@ -310,6 +310,14 @@ def cases(mesh1d, mesh2d):
                                       window=4096))
     case("smallthinker_attn_window_backward",
          lambda: attn_backward_walk(1, 28, 16384, 128, 128, 4, window=4096))
+    # SDAR's layers: 32 query heads on 4 key-value heads x 16,384 rows (a
+    # noisy and a clean copy of 8,192 tokens) at a head width of 128 under
+    # block diffusion's mask in blocks of 4: the forward's grid holds 9 kv
+    # tiles a q tile, the backward's one loop walks 80 pairs of 136
+    case("sdar_flash_bd_forward",
+         lambda: flash_causal_forward(1, 32, 16384, 128, 128, 4, bd=4))
+    case("sdar_attn_bd_backward",
+         lambda: attn_backward_walk(1, 32, 16384, 128, 128, 4, bd=4))
     # Nemotron-3-Super's share: 4 query heads on 1 key-value head x
     # 8,192 at a head width of 128, the whole head axis one group
     case("nemotron3_flash_causal_forward",
@@ -622,6 +630,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "smallthinker-21b-a3b-train-1chip"))
     case("keye_step_1chip", lambda: model_step(
         topo_devs[:1], "keye-vl2-30b-a3b-train-1chip"))
+    case("sdar_step_1chip", lambda: model_step(
+        topo_devs[:1], "sdar-30b-a3b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

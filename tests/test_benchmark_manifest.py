@@ -219,6 +219,30 @@ def _cut_batch_keye(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# sdar-train-1chip: the same, at the widths of tests/test_sdar_train.py (a
+# scanned run of four block-diffusion layers: 8 query heads of 16 on 2
+# key-value heads over a noisy and a clean copy of 64 tokens in blocks of 4,
+# tiles of 16; 4 of 16 experts, 64 of 256 ids, the mask token the last)
+SDAR = "sdar-train-1chip"
+TINY_SDAR = dict(hidden_size=64, head_dim=16, num_attention_heads=8,
+                 num_key_value_heads=2, moe_intermediate_size=24,
+                 num_experts=16, num_experts_per_tok=3, vocab_size=256,
+                 vocab_here=64, experts_here=4, mask_token_here=63)
+TINY_SDAR_TRAIN = dict(seq_len=64, micro_batch=1, attn_block=16,
+                       loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_sdar(config):
+    config.update(TINY_SDAR)
+    config["train"].update(TINY_SDAR_TRAIN)
+
+
+def _cut_batch_sdar(p):
+    p.update(sequences=TINY_SDAR_TRAIN["micro_batch"],
+             seq_len=TINY_SDAR_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -296,6 +320,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("keye-vl2-30b-a3b-train-1chip", _tiny_keye),
         cut={"packed-16k-sparse-steps": _cut_batch_keye}),
+    SDAR: dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("sdar-30b-a3b-train-1chip", _tiny_sdar),
+        cut={"packed-8k-block-diffusion-steps": _cut_batch_sdar}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -305,7 +334,8 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
                       "train_ssm_layer_tokens", "moe_bias_updates")
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
-              "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE)
+              "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE,
+              SDAR)
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -319,7 +349,7 @@ PEAK_METRIC = "step.hbm_peak_share"
 LIVE_ROWS = "moe.live_row_share"
 SHARE_CELLS = ("joyai-train-1chip", "nemotron3-train-1chip",
                "lfm2-train-1chip", "qwen3next-train-1chip",
-               "smallthinker-train-1chip", KEYE)
+               "smallthinker-train-1chip", KEYE, SDAR)
 # what routing and dispatch cost such a cell's step (PR 59)
 ROUTE_SHARE = "moe.route_share"
 
@@ -365,7 +395,7 @@ builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                  if k.startswith(("device_", "train_",
                                                   "moe_", "attn_",
-                                                  "dsa_"))}}))
+                                                  "dsa_", "bd_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
 print("layer " + json.dumps(layer))
@@ -847,7 +877,7 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
            "dsa.select_share", "dsa.loss_share", "dsa.selected_share",
            "dsa.index_mfu", "dsa.loss_mfu"]
     at = names.index(new[0])            # PR 59 appended behind
-    assert names[at:at + 14] == new and names[at + 14:] == [ROUTE_SHARE]
+    assert names[at:at + 14] == new and names[at + 14] == ROUTE_SHARE
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -862,13 +892,14 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
                 "trace_kit_flops", "point_rate", "program_counter",
                 "trace_scope_share_wide")
     assert len({by_name[n]["layer"] for n in new[-7:]}) == 1
-    assert real["workloads"][-1]["name"] == KEYE \
-        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
-    assert real["workloads"][-1]["chips"] == 1
+    assert real["workloads"][11]["name"] == KEYE \
+        and real["configs"][10]["name"] == real["workloads"][11]["config"]
+    assert real["workloads"][11]["chips"] == 1
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
-            assert m["workloads"][-1] == KEYE, m["name"]
+            assert [c for c in m["workloads"] if c != SDAR][-1] == KEYE, \
+                m["name"]
     with open(os.path.join(BENCH, "metrics", "dsa.selected_share.json"),
               encoding="utf-8") as f:
         spec = json.load(f)
@@ -880,9 +911,99 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
         spec = json.load(f)
     from ompi_tpu.runtime import trace
 
+    # PR 64 put its three names behind these
     assert spec["params"]["scopes"] == ["otpu_dsa_loss"] \
         and tuple(spec["params"]["vocabulary"]) == trace.STEP_SCOPES[
-            -len(spec["params"]["vocabulary"]):]
+            -len(spec["params"]["vocabulary"]) - 3:-3]
+
+
+@of_cells(SDAR)
+def test_a_block_diffusion_step_counts_its_rows_and_its_visible_pairs(
+        rehearsal):
+    """The trainer's counters on one chip's share of SDAR, by the kind that
+    reads everything from the kit and with no file of the harness edited
+    for it: 4 softmax routers under no bias over the ``2 L`` rows of a
+    noisy and a clean copy; every attention pass made under block
+    diffusion's mask, 64 tokens in blocks of 4 and tiles of 16; the rows
+    the steps read back had masked; the step's program is the one program
+    built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_SDAR_TRAIN["micro_batch"] * TINY_SDAR_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.sdar.bf16.1x8192"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * 2 * tokens * 3 * 4
+    assert c["bd_built"] == c["attn_built"] > 0
+    # 16 blocks of 4: 16 x 17 / 2 + 16 x 15 / 2 blocks squared and 64 x 4
+    assert c["bd_pairs_visible"] * (128 * 129 // 2) \
+        == c["bd_pairs_causal"] * (16 * (136 + 120) + 64 * 4)
+    # 8 tiles of 16: 4 + 6 + 4 + 10 tile pairs of a causal walk's 36
+    assert c["attn_pairs_walked"] * 36 == c["attn_pairs_causal"] * 24
+    assert 0 < c["bd_rows_masked"] < c["train_steps_read"] * tokens
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
+    """Appended behind everything that was there (PR 64): data files on
+    readers that are there, in the one cell trained by block diffusion,
+    each moving ``small_msg_us``; the mechanism's five under a layer of
+    their own; the cell's name at the end of the lists every share cell is
+    in and of the walked pairs' list."""
+    names = [m["name"] for m in real["per_layer"]]
+    new = ["sdar.mfu", "sdar.tokens_per_s", "sdar.local_load",
+           "sdar.remat_share", "sdar.unnamed_share", "sdar.flash_mfu",
+           "sdar.attn_bwd_mfu", "bd.operator_share", "bd.noise_share",
+           "bd.loss_share", "bd.visible_share", "bd.masked_share"]
+    assert names[-12:] == new
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    readers = {}
+    for name in new:
+        m = by_name[name]
+        assert (m["workloads"], m["moves"]) == ([SDAR], "small_msg_us")
+        twin = by_name.get(name.replace("sdar.", "keye."))
+        if twin and twin is not m:
+            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
+                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+        with open(os.path.join(BENCH, "metrics", name + ".json"),
+                  encoding="utf-8") as f:
+            readers[name] = json.load(f)
+        assert readers[name]["reader"] in (
+            "trace_kit_flops", "point_rate", "program_counter",
+            "trace_scope_share_wide")
+    assert len({by_name[n]["layer"] for n in new[-5:]}) == 1
+    assert real["workloads"][-1]["name"] == SDAR \
+        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
+    assert real["workloads"][-1]["chips"] == 1
+    assert real["configs"][-1]["reduced"] == ["layers", "experts", "vocab",
+                                              "ranks"]
+    for m in real["end_to_end"] + real["per_layer"]:
+        if KEYE in m.get("workloads", ()) and len(m["workloads"]) > 1:
+            assert m["workloads"][-1] == SDAR, m["name"]
+    assert by_name["attn.pairs_walked_share"]["workloads"][-1] == SDAR
+    assert (readers["bd.visible_share"]["reader"],
+            readers["bd.visible_share"]["params"]) == ("program_counter", {
+                "name": "bd_pairs_visible", "over": "bd_pairs_causal",
+                "scale": 100})
+    assert readers["bd.masked_share"]["params"] == {
+        "name": "bd_rows_masked", "over": "train_steps_read",
+        "scale": 100 / 8192}
+    assert readers["sdar.tokens_per_s"]["params"]["amount"] == 8192
+    for name, count, pattern in (
+            ("sdar.flash_mfu", "flash_forward", "^otpu_flash"),
+            ("sdar.attn_bwd_mfu", "attn_backward", "^otpu_attn_.*backward")):
+        assert (readers[name]["params"]["count"],
+                readers[name]["params"]["pattern"]) == (count, pattern)
+    from ompi_tpu.runtime import trace
+
+    for name, scope in (("bd.operator_share", "otpu_bd"),
+                        ("bd.noise_share", "otpu_bd_noise"),
+                        ("bd.loss_share", "otpu_bd_loss")):
+        spec = readers[name]["params"]
+        assert spec["scopes"] == [scope] and tuple(
+            spec["vocabulary"]) == trace.STEP_SCOPES[-len(
+                spec["vocabulary"]):]
 
 
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
@@ -902,7 +1023,10 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
-        assert (m["workloads"], m["moves"]) == ([cell], "small_msg_us")
+        # the walked pairs are read where a mask spares some: PR 64's cell
+        assert (m["workloads"], m["moves"]) == (
+            [cell, SDAR] if name == "attn.pairs_walked_share" else [cell],
+            "small_msg_us")
         twin = by_name.get(name.replace("smallthinker.", "qwen3next."))
         if twin and twin is not m:
             assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
@@ -915,8 +1039,8 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
-            assert [c for c in m["workloads"] if c != KEYE][-1] == cell, \
-                m["name"]
+            assert [c for c in m["workloads"]
+                    if c not in (KEYE, SDAR)][-1] == cell, m["name"]
     for name, params in (
             ("attn.window_share", {"name": "attn_window_built",
                                    "over": "attn_built", "scale": 100}),
@@ -975,10 +1099,12 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
     selects both kinds of such a cell's point through its file, reads
     the router's and the dispatch's scopes, and shares the run's one wide
     reduction with ``dsa.select_share`` (one vocabulary: the program's
-    whole ``STEP_SCOPES`` behind ``scopes.json``'s)."""
+    ``STEP_SCOPES`` behind ``scopes.json``'s, as far as PR 58 brought it:
+    the three names of PR 64 stand behind it, in that PR's own files)."""
     from ompi_tpu.runtime import trace
 
-    assert real["per_layer"][-1] == {
+    # PR 64's twelve stand behind it
+    assert real["per_layer"][-13] == {
         "name": ROUTE_SHARE, "unit": "%", "better": "lower",
         "source": "device_trace",
         "layer": {x["name"]: x for x in real["per_layer"]}[LIVE_ROWS][
@@ -998,7 +1124,8 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
     with open(os.path.join(BENCH, "harness", "scopes.json"),
               encoding="utf-8") as f:
         base = json.load(f)["scopes"]
-    assert tuple(base + spec["params"]["vocabulary"]) == trace.STEP_SCOPES
+    assert tuple(base + spec["params"]["vocabulary"]) \
+        == trace.STEP_SCOPES[:-3]
     kinds = set()
     for cell in real["workloads"]:
         with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"),
@@ -1228,6 +1355,42 @@ def test_kit_check_tells_the_sparse_program_from_its_controls(tmp_path):
     attached = row["parts_hi_attached"]["units_by_group"]["grad_probe"]
     assert attached > 0.1 and attached > 50 * row["program"][
         "units_by_group"]["grad_probe"]
+
+
+def test_kit_check_tells_the_block_diffusion_program_from_its_controls(
+        tmp_path):
+    """``benchmark/tools/kit_check.py`` on SDAR's cell at the rehearsal's
+    widths, with no file of the harness edited for it: one step of the
+    program lies within the kind's tolerance of ``sdarkit``'s reference
+    under the step's own routing, **the noise drawn again by the kit equal
+    to the step's bit for bit**; the reference in bfloat16 lies far outside
+    the program's, and each of the kit's five controls outside the
+    tolerance where it bites: a bfloat16 router and head, a plain causal
+    mask over the 2L rows, a noisy row that sees its own block's clean
+    copy, masked rows drawn at a fixed rate of one half, a loss without the
+    weight."""
+    env = _stage(SDAR, str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", SDAR, "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "2", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    rows = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+            if ln.startswith("seed ")]
+    assert len(rows) == 2
+    for row in rows:
+        assert row["program"]["widest_units"] < 0.05
+        assert row["program"]["units_by_group"]["noise"] == 0.0
+        assert row["control_bf16"]["widest_units"] \
+            > 100 * row["program"]["widest_units"]
+        for variant, part in (("bf16", "head_rows"), ("causal", "bd_o"),
+                              ("leak", "bd_o"), ("half_rate", "noise"),
+                              ("half_rate", "bd_weights"),
+                              ("unweighted", "losses"),
+                              ("unweighted", "grad_probe")):
+            assert row["parts_" + variant]["units_by_group"][part] > 1, \
+                variant
 
 
 def test_kit_check_tells_the_window_program_from_its_controls(tmp_path):

@@ -1,10 +1,11 @@
 """The parameter tree where it is an interface: the benchmark's kits find
 a leaf's probe positions and its draw by its name, and a checkpoint's
-reader by its path.  For each of the seven cells' configuration files the
+reader by its path.  For each of the step cells' configuration files the
 names, their order and the shapes at the published widths, and the initial
 values of a tiny cut, are held to digests taken on the tree of PR 59
-(commit d0e779b, before the kinds of layer were declared in one table), so
-that a refactor of the tree's makers is checked here and not on the chip.
+(commit d0e779b, before the kinds of layer were declared in one table; a
+later model's on the tree of the PR that brought it), so that a refactor of
+the tree's makers is checked here and not on the chip.
 """
 import hashlib
 import json
@@ -74,6 +75,13 @@ TREES = {
              experts_here=4, index_heads=4, index_head_dim=8, index_topk=24,
              index_q_chunk=16, index_kv_chunk=16),
         "98ae55b966aecc18", "304bc0dbfb628c4f"),
+    # PR 64's own tree, pinned as it was brought
+    "sdar-30b-a3b-train-1chip.json": (
+        dict(hidden_size=64, head_dim=16, num_attention_heads=8,
+             num_key_value_heads=2, moe_intermediate_size=24, num_experts=16,
+             num_experts_per_tok=3, vocab_size=256, vocab_here=64,
+             experts_here=4, mask_token_here=63),
+        "f44cc625a1563a7e", "6690f312d2e32ef4"),
 }
 
 

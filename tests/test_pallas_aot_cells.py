@@ -1,8 +1,8 @@
 """AOT compile-contract tests, the second file (``test_pallas_aot.py``
 is the first; ``aot_rows.py`` holds what both share and says why there are
-two): OLMoE's, Qwen3-Next's, SmallThinker's and Keye's kernels and steps at
-the cells' shapes, the experts' grouped matmul at every cell's, and the whole
-inventory (slow).
+two): OLMoE's, Qwen3-Next's, SmallThinker's, Keye's and SDAR's kernels and
+steps at the cells' shapes, the experts' grouped matmul at every cell's, and
+the whole inventory (slow).
 """
 import json
 import re
@@ -340,6 +340,72 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
 
 
 @pytest.fixture(scope="module")
+def sdar_rows():
+    """One child for the SDAR-30B-A3B cases: both flash kernels under block
+    diffusion's mask and the whole step of the cell's own configuration
+    file, for one v5e device (about a minute of the 600)."""
+    return rows_with_texts("sdar_")
+
+
+def test_the_block_diffusion_kernels_aot_compile_at_the_cells_shape(
+        sdar_rows):
+    """32 query heads on 4 key-value heads x 16,384 rows (a noisy and a
+    clean copy of 8,192 tokens) at a head width of 128 in blocks of 4: the
+    forward kernel in one call whose grid holds the 9 kv tiles a q tile
+    meets at most, read from a table of 16 x 9 entries; the backward's 80
+    tile pairs one ``lax.scan`` over triples, a pair's kind an operand;
+    both under the kernels' own VMEM limits as they are, no mask an array,
+    k and v repeated a query head nowhere."""
+    row = sdar_rows["sdar_flash_bd_forward"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"].get("custom-call") == 1, row["entry_ops"]
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert "otpu_flash_bd_forward" in text and "s32[144]" in text
+    walk = sdar_rows["sdar_attn_bd_backward"]
+    assert walk.get("compiled"), json.dumps(walk, indent=1)
+    assert walk["entry_ops"].get("while") == 1, walk["entry_ops"]
+    with open(walk["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert re.search(r"s32\[80,3\]", text) \
+        and not re.search(r"s32\[136,[23]\]", text)
+    for case in ("sdar_flash_bd_forward", "sdar_attn_bd_backward",
+                 "sdar_step_1chip"):
+        with open(sdar_rows[case]["hlo"], encoding="utf-8") as f:
+            text = f.read()
+        assert not re.search(r"bf16\[1,4,8,16384,128\]", text), case
+        assert not re.search(r"\[(\d+,)*16384,16384[\],]", text), case
+
+
+def test_sdar_train_step_aot_compiles_from_the_cells_configuration(
+        sdar_rows):
+    """The whole step of ``benchmark/configs/sdar-30b-a3b-train-1chip.json``
+    (published widths; layers 0-3 of 48, 16 of 128 experts, 1 x 8,192
+    tokens, so 16,384 rows a layer): it fits the chip beside its 5.5 GB of
+    state, the four like layers are one loop, and both kernels stand under
+    ``otpu_bd`` in the pass they belong to and in no recomputed one (the
+    checkpoint keeps o and the logsumexp); no causal or selection kernel
+    is in it."""
+    row = sdar_rows["sdar_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 456_346_624 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    for name in ("otpu_flash_bd_forward", "otpu_attn_bd_backward"):
+        found = [p for p in kernels if f"/{name}/" in p]
+        assert found and all("otpu_bd" in p for p in found), (name, found)
+        assert not [p for p in found if "rematted_computation" in p], name
+        assert all(("transpose(" in p) == (name == "otpu_attn_bd_backward")
+                   for p in found), (name, found)
+    assert not [p for p in kernels if "/otpu_flash_causal_forward/" in p
+                or "/otpu_attn_block_backward/" in p
+                or "select" in p.rsplit("/", 2)[-2]]
+
+
+@pytest.fixture(scope="module")
 def gmm_rows():
     """One child for the experts' grouped matmul at the six model cells'
     shapes, forward and both transposed products of both expert
@@ -383,7 +449,8 @@ def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
 @pytest.mark.parametrize("rows,case", [
     ("qwen3next_rows", "qwen3next_step_1chip"),
     ("smallthinker_rows", "smallthinker_step_1chip"),
-    ("keye_rows", "keye_step_1chip")])
+    ("keye_rows", "keye_step_1chip"),
+    ("sdar_rows", "sdar_step_1chip")])
 def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(
         rows, case, request):
     aot_rows.recomputed_pass_holds_no_routing(

@@ -1,0 +1,163 @@
+"""The whole block-diffusion training step of SDAR-30B-A3B through
+``train.build_train_step`` against ``parallel/sdar_reference.py``: three
+steps' losses and parameters, one step's loads, noise and every leaf's
+gradient, the update, bit-for-bit repeats, bfloat16 compute, two
+data-parallel ranks; at ``tests/test_sdar_train.py``'s small widths."""
+import dataclasses
+import unittest.mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ompi_tpu.parallel import sdar_reference as ref
+from ompi_tpu.parallel import train
+from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+from ompi_tpu.runtime import spc
+
+from test_sdar_train import (F32, NAMES, batch_of, close, near, ref_grads,
+                             spread_params)
+
+
+@pytest.fixture(scope="module")
+def stepped():
+    """Three steps of the program from seed 3, and the reference's."""
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=F32)
+    params = spread_params(F32, 3)
+    batches = [batch_of(s) for s in range(3)]
+    state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
+    auxes = []
+    for tokens, labels in batches:
+        state, aux = step(state, tokens, labels)
+        auxes.append(jax.device_get(aux))
+    with unittest.mock.patch.object(ref, "grads", ref_grads):
+        want = ref.train_steps(params, batches, F32)
+    return dict(params=params, batches=batches, state=state, auxes=auxes,
+                want=want, step=step)
+
+
+def test_three_steps_are_the_references(stepped):
+    params, losses = stepped["want"]
+    # the first step's at float32's own width; the later ones start from
+    # parameters an entry of which Adam's sign rule may have turned
+    got = [a["losses"][:3] for a in stepped["auxes"]]
+    want = [[float(x) for x in row] for row in losses]
+    close(got[0], want[0], rtol=2e-5)
+    close(got, want, rtol=2e-4)
+    for name, path in NAMES:
+        off = np.abs(np.asarray(train._leaf(stepped["state"][0], path))
+                     - np.asarray(train._leaf(params, path)))
+        assert off.max() <= 3 * F32.lr, name
+        assert np.mean(off > 0.01 * 3 * F32.lr) <= 2e-3, name
+
+
+def test_one_step_reports_the_references_losses_noise_and_gradients(stepped):
+    """Loss parts, loads, the mask and the levels bit for bit, and the
+    gradient of every leaf, through the jitted step."""
+    tokens, labels = stepped["batches"][0]
+    aux = stepped["auxes"][0]
+    (total, (ce, lb, loads, levels, masked)), g = ref_grads(
+        stepped["params"], tokens, labels, F32)
+    close(aux["losses"], [total, ce, lb, 0.0])
+    assert float(lb) > 0
+    close(aux["loads"], loads)
+    np.testing.assert_array_equal(aux["bd_mask"] != 0, masked)
+    np.testing.assert_array_equal(aux["bd_levels"], levels)
+    assert aux["bd_mask"].dtype == np.uint8 \
+        and aux["bd_mask"].shape == (2, 64) \
+        and aux["bd_levels"].shape == (2, 16)
+    assert float(aux["bd_masked"]) == float(np.asarray(masked).sum())
+    sample = aux["sample"]
+    assert sample["bd_k_seq"].shape == (4, 256, 16) \
+        and sample["bd_o"].shape == (4, 16, 16) \
+        and sample["attn_qk"].shape == (4, 16, 32) \
+        and sample["router_scores"].shape == (4, 16, 16) \
+        and sample["head_in"].shape == (16, 64)
+    for (name, path), sq, probe in zip(NAMES, aux["grad_sq"],
+                                       aux["grad_probe"]):
+        leaf = np.asarray(train._leaf(g, path))
+        close(sq, np.sum(leaf * leaf), rtol=2e-4, err_msg=name)
+        near(probe, leaf.reshape(-1)[train.probe_positions(
+            name, leaf.size)], rel=1e-4, err_msg=name)
+
+
+def test_the_mask_tokens_row_learns_from_the_masked_rows(stepped):
+    """The embedding's gradient holds the noisy and the clean copy's
+    gathers: the mask token's row gets one (no datum draws it, every
+    masked row reads it)."""
+    tokens, labels = stepped["batches"][0]
+    _, g = ref_grads(stepped["params"], tokens, labels, F32)
+    assert np.any(np.asarray(g["embed"][F32.mask_token_here]))
+    assert F32.mask_token_here not in np.asarray(tokens)
+
+
+def test_the_parameters_after_one_update_are_the_references(stepped):
+    tokens, labels = stepped["batches"][0]
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=F32)
+    state, t, l = place(jax.tree.map(jnp.copy, stepped["params"]), tokens,
+                        labels)
+    state, _ = step(state, t, l)
+    with unittest.mock.patch.object(ref, "grads", ref_grads):
+        want, _ = ref.train_steps(stepped["params"], [(tokens, labels)], F32)
+    for name, path in NAMES:
+        got, ours = (np.asarray(train._leaf(tree, path))
+                     for tree in (state[0], want))
+        assert np.abs(got - ours).max() <= 2 * F32.lr, name
+        assert np.mean(np.abs(got - ours) > 1e-3 * F32.lr) <= 2e-3, name
+
+
+def test_the_losses_and_the_noise_repeat_bit_for_bit_from_one_seed(stepped):
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=F32)
+    state, _, _ = place(spread_params(F32, 3), *stepped["batches"][0])
+    for (tokens, labels), before in zip(stepped["batches"],
+                                        stepped["auxes"]):
+        state, aux = step(state, tokens, labels)
+        np.testing.assert_array_equal(np.asarray(aux["losses"]),
+                                      before["losses"])
+        np.testing.assert_array_equal(np.asarray(aux["bd_mask"]),
+                                      before["bd_mask"])
+    masks = [a["bd_mask"] for a in stepped["auxes"]]
+    assert not np.array_equal(masks[0], masks[1]) \
+        and not np.array_equal(masks[1], masks[2])
+
+
+def test_a_step_read_back_counts_its_masked_rows(stepped):
+    if "bd_rows_masked" not in spc.counters():
+        spc.init()
+    before = spc.read("bd_rows_masked"), spc.read("train_steps_read")
+    train.record_step_stats(stepped["auxes"][0])
+    assert spc.read("bd_rows_masked") - before[0] \
+        == int(stepped["auxes"][0]["bd_masked"]) > 0
+    assert spc.read("train_steps_read") - before[1] == 1
+
+
+def test_bfloat16_compute_stays_near_float32(stepped):
+    cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
+    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
+    step, place = train.build_train_step(mesh, spec, model=cfg)
+    state, t, l = place(spread_params(cfg, 3), *stepped["batches"][0])
+    _, aux = step(state, t, l)
+    close(aux["losses"][1], stepped["auxes"][0]["losses"][1], rtol=5e-3)
+    np.testing.assert_array_equal(np.asarray(aux["bd_mask"]),
+                                  stepped["auxes"][0]["bd_mask"])
+
+
+def test_two_data_parallel_ranks_are_one_model(stepped):
+    if len(jax.devices()) < 2:
+        pytest.skip("one device")
+    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
+    step, place = train.build_train_step(mesh, spec, model=F32)
+    state, t, l = place(spread_params(F32, 3), *stepped["batches"][0])
+    _, aux = step(state, t, l)
+    want = stepped["auxes"][0]
+    close(aux["losses"], want["losses"], rtol=1e-5)
+    close(aux["loads"], want["loads"])
+    close(aux["grad_sq"], want["grad_sq"], rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(aux["bd_mask"]),
+                                  want["bd_mask"])
+    np.testing.assert_array_equal(np.asarray(aux["bd_levels"]),
+                                  want["bd_levels"])

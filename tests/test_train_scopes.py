@@ -24,6 +24,7 @@ from test_nemotron_train import F32 as NEMOTRON
 from test_olmoe_train import F32 as OLMOE_TWO_LAYERS
 from test_qwen3next_train import F32 as QWEN3NEXT
 from test_keye_train import F32 as KEYE
+from test_sdar_train import F32 as SDAR
 from test_parallel import MODEL_PATH
 from test_smallthinker_train import F32 as SMALLTHINKER
 
@@ -260,13 +261,21 @@ def keye():
     return step, step.scopes()
 
 
+@pytest.fixture(scope="module")
+def sdar():
+    step, args = built(SDAR)
+    step(*args)
+    return step, step.scopes()
+
+
 def ran(scopes):
     return {k: v for k, v in scopes["ops"].items()
             if v["opcode"] not in trace.TRIVIAL_OPCODES}
 
 
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next", "smallthinker", "keye"])
+                                   "qwen3next", "smallthinker", "keye",
+                                   "sdar"])
 def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     scopes = request.getfixturevalue(which)[1]
     assert scopes["module"] == "jit_otpu_train_step"
@@ -286,6 +295,10 @@ def test_a_compiled_step_names_only_vocabulary_scopes(which, request):
     assert ("otpu_swa" in named) == (which == "smallthinker")
     assert ({"otpu_dsa", "otpu_dsa_index", "otpu_dsa_select",
              "otpu_dsa_loss"} <= named) == (which == "keye")
+    assert ({"otpu_bd", "otpu_bd_noise", "otpu_bd_loss"} <= named) \
+        == (which == "sdar")
+    assert not {"otpu_bd", "otpu_bd_noise", "otpu_bd_loss"} & named \
+        or which == "sdar"
     assert {v["pass"] for v in scopes["ops"].values()} <= {
         None, *trace.PASSES}
 
@@ -401,8 +414,30 @@ def test_a_sparse_attention_sublayer_lands_under_its_own_scopes(keye):
         v["pass"] for v in ops.values() if "otpu_dsa_index" in v["chain"]}
 
 
+def test_a_block_diffusion_step_lands_under_its_own_scopes(sdar):
+    """The four block-diffusion sublayers (one scanned run) are under
+    ``otpu_bd`` in every pass and never under ``otpu_attention``, their
+    projections beside the kernels' twins inside it; the noise is drawn
+    under ``otpu_bd_noise`` in the forward pass alone (nothing
+    differentiates it) and outside the layers; the weighted loss's own work
+    is under ``otpu_bd_loss`` inside ``otpu_head``."""
+    ops = ran(sdar[1])
+    bd = [v for v in ops.values() if "otpu_bd" in v["chain"]]
+    assert len(bd) > 50
+    assert not [v for v in ops.values() if "otpu_attention" in v["chain"]]
+    assert {"forward", "remat", "backward"} <= {v["pass"] for v in bd}
+    under = [v for v in ops.values() if "otpu_attn_proj" in v["chain"]]
+    assert under and all("otpu_bd" in v["chain"] for v in under)
+    noise = [v for v in ops.values() if "otpu_bd_noise" in v["chain"]]
+    assert noise and {v["pass"] for v in noise} == {"forward"}
+    assert not any("otpu_layers" in v["chain"] for v in noise)
+    loss = [v for v in ops.values() if "otpu_bd_loss" in v["chain"]]
+    assert loss and all("otpu_head" in v["chain"] for v in loss)
+
+
 @pytest.mark.parametrize("which", ["joyai", "olmoe", "nemotron", "lfm2",
-                                   "qwen3next", "smallthinker", "keye"])
+                                   "qwen3next", "smallthinker", "keye",
+                                   "sdar"])
 def test_every_instruction_the_program_wrote_has_a_chain(which, request):
     """Not a parameter, constant, tuple or bitcast, and with a path of
     the program's (``pass`` None: the compiler's own, which on the CPU
@@ -451,7 +486,8 @@ ROUTING = {
 ATTENTION = {
     "attention's products": lambda line, path:
         path.endswith("/dot_general") and trace.scope_of_path(path)[0][-1:]
-        in (["otpu_mla"], ["otpu_attention"], ["otpu_swa"], ["otpu_dsa"]),
+        in (["otpu_mla"], ["otpu_attention"], ["otpu_swa"], ["otpu_dsa"],
+            ["otpu_bd"]),
 }
 # a learned selection's two dear parts: the counting passes that find a
 # row's bar (comparisons summed a row) and the alignment loss's products
@@ -536,9 +572,9 @@ def test_a_layers_checkpoint_keeps_the_selection_and_the_losss_gradients(
 
 
 @pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2, QWEN3NEXT,
-                                 SMALLTHINKER, KEYE],
+                                 SMALLTHINKER, KEYE, SDAR],
                          ids=["joyai", "nemotron", "lfm2", "qwen3next",
-                              "smallthinker", "keye"])
+                              "smallthinker", "keye", "sdar"])
 def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
                                                               monkeypatch):
     """``model_loss``'s checkpoint keeps causal attention's o and
