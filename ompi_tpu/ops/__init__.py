@@ -13,8 +13,10 @@ same way, from what it can see (``interpret`` false: a TPU, and a shape
 with tiles), each beside the XLA form that is its oracle:
 ``flash_attention`` (causal attention, forward and backward),
 ``grouped_matmul`` (the experts' products), ``gated_delta`` (the chunked
-gated delta rule) and ``causal_conv`` (the DeltaNet convolution and its
-silu).
+gated delta rule), ``causal_conv`` (the DeltaNet convolution and its
+silu), ``sparse_attention`` (DSA's index selection and alignment loss) and
+``head_norm_rope`` (a QK-normed head's norm, RoPE, split and cast on its
+way to the flash kernels).
 """
 from ompi_tpu.ops.pallas_reduce import (  # noqa: F401
     combine2,

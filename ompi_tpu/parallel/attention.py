@@ -16,8 +16,9 @@ from ompi_tpu.parallel.causal import (ATTN_KEEPS,
                                       block_diffusion_flash_attention,
                                       causal_flash_attention)
 from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
-                                      rope)
+                                      rope, rope_tables)
 from ompi_tpu.parallel.sublayer import Sublayer
+from ompi_tpu.runtime import spc
 
 
 def olmoe_attention(p, x, cfg, *, interpret: bool, at=None):
@@ -79,6 +80,128 @@ def mla_attention(p, x, cfg, *, interpret: bool, at=None):
         return matmul(o, p["wo"], dt), {}, {}
 
 
+def normed_turned_heads(t, gain, cfg, turned: bool, positions=None):
+    """Heads ``t`` (b, n, s, hd) float32 under the per-head QK-norm
+    (RMSNorm with ``gain`` (hd,) over each head's width) and, where
+    ``turned``, RoPE in the half-split form over the leading
+    ``cfg.rotary_width`` entries at ``positions``: the ``jnp`` lines, which
+    are the twin of ``ops/head_norm_rope``'s kernels and every other
+    shape's path."""
+    t = rmsnorm_gain(t, gain, cfg.rms_norm_eps)
+    return rope(t, cfg.rope_theta, cfg.rotary_width, positions) \
+        if turned else t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _kernel_heads(prod, gain, cos, sin, heads, eps, dtype):
+    """``normed_turned_heads`` of the product ``prod`` (b, s, heads x hd)
+    float32 where it lies, split into heads and cast to ``dtype``, (b,
+    heads, s, hd), on the Pallas kernels (``ops/head_norm_rope``), one
+    pass each way; ``cos`` and ``sin`` (s, hd) are ``rope_tables``', the
+    second with its sign on (``signed_sin``).  Only the product, the gain
+    and the tables are kept for the backward kernel, which makes the norm
+    again and writes the product's cotangent in ``dtype``, which is what
+    its readers, the projections' transposes, cast it to."""
+    from ompi_tpu.ops import head_norm_rope
+
+    return head_norm_rope.heads_forward(prod, gain, cos, sin, heads=heads,
+                                  eps=eps, dtype=dtype)
+
+
+def _kernel_heads_fwd(prod, gain, cos, sin, heads, eps, dtype):
+    from ompi_tpu.ops import head_norm_rope
+
+    return head_norm_rope.heads_forward(
+        prod, gain, cos, sin, heads=heads, eps=eps, dtype=dtype), (
+            prod, gain, cos, sin)
+
+
+def _kernel_heads_bwd(heads, eps, dtype, res, do):
+    from ompi_tpu.ops import head_norm_rope
+
+    dprod, dgain = head_norm_rope.heads_backward(*res, do, eps=eps,
+                                                 dtype=dtype)
+    return dprod.astype(jnp.float32), dgain, None, None
+
+
+_kernel_heads.defvjp(_kernel_heads_fwd, _kernel_heads_bwd)
+
+
+def _qk_on_kernels(interpret, cfg, turned, gated) -> bool:
+    """Whether q's and k's way to the flash kernels runs on the Pallas
+    kernels: where Mosaic compiles (``interpret`` false: a TPU), RoPE
+    turns the layer and the head has tiles."""
+    if interpret or not turned:
+        return False
+    from ompi_tpu.ops import head_norm_rope
+
+    return head_norm_rope.supported(cfg.head_width, cfg.rotary_width, gated)
+
+
+def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
+              positions=None):
+    """q and k of a sublayer with a per-head QK-norm (lfm2's form: qwen3_moe's,
+    sdar_moe's, Keye's, qwen3_next's) from the normed input ``h`` (b, s,
+    d): ``h W_q`` and ``h W_k`` split into the ``n_heads_here`` and
+    ``n_kv_heads_here`` heads, a gate split off behind every query head
+    where ``wq`` is twice as wide as ``wo`` is long, RMSNorm with the gains
+    ``q_norm`` and ``k_norm`` over each head, RoPE where ``turned`` (at
+    ``positions``), all float32, then the cast to ``compute_dtype``.
+
+    Where Mosaic compiles and the head has tiles (``_qk_on_kernels``) each
+    of the two is one pass of ``ops/head_norm_rope``'s kernel over its
+    projection's product where it lies, and one back; elsewhere the
+    ``jnp`` lines (``normed_turned_heads`` between a transposition and a
+    cast).  SPC ``attn_qk_built`` counts both ways' q and k while steps
+    are traced, ``attn_qk_kernel_built`` the kernels'.
+
+    Returns (q (b, nh, s, hd), k (b, nkv, s, hd), the gate (b, nh, s, hd)
+    float32 or None, and of the first query head and the first key-value
+    head side by side, by token row, the product and what the norm and
+    RoPE made of it in float32: ``attn_qk_in``, ``attn_qk`` (T, 2 hd),
+    which on the kernels are those two heads' products made again from
+    their own columns and the forward kernel's float32 output over
+    them)."""
+    b, s, _ = h.shape
+    nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
+    gated = p["wq"].shape[-1] == 2 * p["wo"].shape[0]
+    on_kernels = _qk_on_kernels(interpret, cfg, turned, gated)
+    spc.record("attn_qk_built", 2)
+    side = lambda a, c: jnp.concatenate([a, c], -1).reshape(b * s, -1)
+    gate = None
+    if on_kernels:
+        from ompi_tpu.ops.head_norm_rope import signed_sin
+
+        spc.record("attn_qk_kernel_built", 2)
+        hd = cfg.head_width
+        cos, sin = rope_tables(s, hd, cfg.rope_theta, positions)
+        sin = signed_sin(sin)
+        prods = [matmul(h, p[w], dt) for w in ("wq", "wk")]
+        q, k = (_kernel_heads(t, p[g], cos, sin, n, cfg.rms_norm_eps, dt)
+                for t, g, n in zip(prods, ("q_norm", "k_norm"), (nh, nkv)))
+        # the first heads' products made again, from their own columns,
+        # and the same kernel over them in float32, so that what a check
+        # reads of the norm and RoPE is the kernel's arithmetic: a slice
+        # of the whole product as a second reader has XLA lay it out for
+        # the slice and copy it for the kernel (3.2 ms a step of SDAR's
+        # on the v5e, PR 65)
+        ins = [matmul(h, p[w][:, :hd], dt) for w in ("wq", "wk")]
+        outs = [_kernel_heads(t, p[g], cos, sin, 1, cfg.rms_norm_eps,
+                              jnp.float32)[:, 0]
+                for t, g in zip(ins, ("q_norm", "k_norm"))]
+    else:
+        split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+        q_in, k_in = (split(matmul(h, p[w], dt), n)
+                      for w, n in (("wq", nh), ("wk", nkv)))
+        if gated:
+            q_in, gate = jnp.split(q_in, 2, axis=-1)
+        q, k = (normed_turned_heads(t, p[g], cfg, turned, positions)
+                for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+        ins, outs = (q_in[:, 0], k_in[:, 0]), (q[:, 0], k[:, 0])
+    seen = {"attn_qk_in": side(*ins), "attn_qk": side(*outs)}
+    return q.astype(dt), k.astype(dt), gate, seen
+
+
 def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
                   windowed: bool = False, diffused: bool = False):
     """Grouped-query attention, **without** the residual add, on the
@@ -135,23 +258,17 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     window = cfg.sliding_window if windowed else None
     first = lambda a, c: jnp.concatenate(
         [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
+    split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
         if "q_norm" in p:
-            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
-            q_in, k_in = (split(matmul(h, p[w], dt), n)
-                          for w, n in (("wq", nh), ("wk", nkv)))
-            if p["wq"].shape[-1] == 2 * p["wo"].shape[0]:
-                q_in, gate = jnp.split(q_in, 2, axis=-1)
-            q, k = (turn(rmsnorm_gain(t, p[g], cfg.rms_norm_eps))
-                    for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+            q, k, gate, qk_seen = normed_qk(
+                p, h, cfg, interpret=interpret, turned=turned,
+                positions=positions)
             if turned:
-                seen = {"attn_qk_in": first(q_in, k_in),
-                        "attn_qk": first(q, k)}
-            q, k = q.astype(dt), k.astype(dt)
+                seen = qk_seen
             v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
         elif cfg.layer_types:
-            split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
             q_in, k_in, v = (split(matmul(h, p[w], dt), n) for w, n in (
                 ("wq", nh), ("wk", nkv), ("wv", nkv)))
             q, k = turn(q_in), turn(k_in)

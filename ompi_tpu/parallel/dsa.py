@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ompi_tpu.parallel.attention import gqa_shapes
+from ompi_tpu.parallel.attention import gqa_shapes, normed_qk
 from ompi_tpu.parallel.causal import (ATTN_KEEPS,
                                                 selected_flash_attention)
 from ompi_tpu.parallel.layers import (contract, layernorm, matmul,
@@ -245,14 +245,7 @@ def dsa_attention(p, x, cfg, *, interpret: bool, at=None):
     split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], eps)
-        q_in, k_in = (split(matmul(h, p[m], dt), n)
-                      for m, n in (("wq", nh), ("wk", nkv)))
-        q, k = (rope(rmsnorm_gain(t, p[g], eps), theta)
-                for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
-        first = lambda a, c: jnp.concatenate(
-            [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
-        seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
-        q, k = q.astype(dt), k.astype(dt)
+        q, k, _, seen = normed_qk(p, h, cfg, interpret=interpret)
         v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
     with jax.named_scope("otpu_dsa_index"):
         hi = jax.lax.stop_gradient(h)

@@ -66,6 +66,17 @@ def l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+def rope_tables(s: int, rot: int, theta: float, positions=None):
+    """(cos, sin) (s, rot) float32 of ``rope``'s angles: a pair's angle on
+    both its halves, at positions 0..s-1 or at ``positions`` (s,)."""
+    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    at = jnp.arange(s, dtype=jnp.float32) if positions is None \
+        else jnp.asarray(positions).astype(jnp.float32)
+    ang = at[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)           # (s, rot)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def rope(x, theta: float, rotary: int | None = None, positions=None):
     """Rotary position embedding on ``x`` (b, h, s, hd) at positions
     0..s-1, the half-split form of the HF models (``rotate_half``), over
@@ -76,15 +87,11 @@ def rope(x, theta: float, rotary: int | None = None, positions=None):
     repeat 0..L-1); None is the call without it, bit for bit."""
     hd, s = x.shape[-1], x.shape[-2]
     rot = hd if rotary is None else rotary
-    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
-    at = jnp.arange(s, dtype=jnp.float32) if positions is None \
-        else jnp.asarray(positions).astype(jnp.float32)
-    ang = at[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)           # (s, rot)
+    cos, sin = rope_tables(s, rot, theta, positions)
     x1, x2 = x[..., :rot // 2], x[..., rot // 2:rot]
     whole = rot == hd
-    turned = (x if whole else x[..., :rot]) * jnp.cos(ang) \
-        + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+    turned = (x if whole else x[..., :rot]) * cos \
+        + jnp.concatenate([-x2, x1], -1) * sin
     return turned if whole else jnp.concatenate([turned, x[..., rot:]], -1)
 
 

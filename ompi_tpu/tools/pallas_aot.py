@@ -318,6 +318,42 @@ def cases(mesh1d, mesh2d):
          lambda: flash_causal_forward(1, 32, 16384, 128, 128, 4, bd=4))
     case("sdar_attn_bd_backward",
          lambda: attn_backward_walk(1, 32, 16384, 128, 128, 4, bd=4))
+    # and q's and k's way to those kernels (``attention._kernel_heads``:
+    # ``ops/head_norm_rope``'s two kernels; Keye's layers have the same
+    # shape): per-head RMSNorm, RoPE, the head split and the cast over the
+    # float32 products (1, 16384, 4096) and (1, 16384, 512) where they lie;
+    # the first heads' own products through the forward kernel in float32
+    # (what ``attn_qk`` reads of a step); and the kernels' other tile, a
+    # head of two lane tiles (16 on 2 of 256), which no cell runs
+    def head_norm_rope(backward, heads=(32, 4), hd=128, dtype=bf16):
+        from ompi_tpu.ops.head_norm_rope import signed_sin
+        from ompi_tpu.parallel import attention
+        from ompi_tpu.parallel.layers import rope_tables
+
+        def made(q, k, gq, gk):
+            with jax.named_scope("otpu_attn_proj"):     # the sublayers'
+                cos, sin = rope_tables(16384, hd, 1e6)
+                return tuple(
+                    attention._kernel_heads(t, g, cos, signed_sin(sin), n,
+                                            1e-6, dtype)
+                    for t, g, n in zip((q, k), (gq, gk), heads))
+
+        fn = made
+        if backward:
+            fn = jax.grad(lambda *a: sum(
+                jnp.sum(t.astype(f32)) for t in made(*a)), (0, 1, 2, 3))
+        rep = lambda *s: _sds(s, f32, one, P())
+        return jax.jit(fn), (*(rep(1, 16384, n * hd) for n in heads),
+                             rep(hd), rep(hd))
+
+    case("sdar_head_norm_rope_forward", lambda: head_norm_rope(False))
+    case("sdar_head_norm_rope_backward", lambda: head_norm_rope(True))
+    case("sdar_head_norm_rope_first_head",
+         lambda: head_norm_rope(False, (1, 1), dtype=f32))
+    case("sdar_head_norm_rope_256_forward",
+         lambda: head_norm_rope(False, (16, 2), 256))
+    case("sdar_head_norm_rope_256_backward",
+         lambda: head_norm_rope(True, (16, 2), 256))
     # Nemotron-3-Super's share: 4 query heads on 1 key-value head x
     # 8,192 at a head width of 128, the whole head axis one group
     case("nemotron3_flash_causal_forward",

@@ -956,7 +956,9 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
            "sdar.remat_share", "sdar.unnamed_share", "sdar.flash_mfu",
            "sdar.attn_bwd_mfu", "bd.operator_share", "bd.noise_share",
            "bd.loss_share", "bd.visible_share", "bd.masked_share"]
-    assert names[-12:] == new
+    # found by name, in their order, whatever a later PR appends
+    at = names.index(new[0])
+    assert names[at:at + 12] == new and at > names.index(ROUTE_SHARE)
     by_name = {m["name"]: m for m in real["per_layer"]}
     readers = {}
     for name in new:
@@ -1103,8 +1105,8 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
     the three names of PR 64 stand behind it, in that PR's own files)."""
     from ompi_tpu.runtime import trace
 
-    # PR 64's twelve stand behind it
-    assert real["per_layer"][-13] == {
+    # found by name: later PRs' entries stand behind it
+    assert {x["name"]: x for x in real["per_layer"]}[ROUTE_SHARE] == {
         "name": ROUTE_SHARE, "unit": "%", "better": "lower",
         "source": "device_trace",
         "layer": {x["name"]: x for x in real["per_layer"]}[LIVE_ROWS][

@@ -1,0 +1,303 @@
+"""The Pallas kernels of a QK-normed head's way to the flash kernels
+(``ops/head_norm_rope``) in interpret mode against the ``jnp`` lines of
+``parallel/attention.normed_turned_heads`` and their autodiff, and which of
+the two ``attention.normed_qk`` builds where."""
+import functools
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ompi_tpu.ops import head_norm_rope as hnr
+from ompi_tpu.parallel import attention, train
+from ompi_tpu.parallel.layers import rmsnorm_gain, rope, rope_tables
+from ompi_tpu.runtime import spc
+
+#: tiles of 16 positions: 24 and 40 end inside a tile, 32 is two whole
+ROWS, HD, EPS, THETA = 16, 128, 1e-6, 1e6
+#: (query or key-value heads, positions, head width): SDAR's and Keye's 32
+#: on 4, one head, lengths a row tile does not divide, and a head of two
+#: lane tiles
+SHAPES = [(32, 24, HD), (4, 24, HD), (1, 40, HD), (4, 32, HD), (2, 24, 256)]
+SDAR = "benchmark/configs/sdar-30b-a3b-train-1chip.json"
+KEYE = "benchmark/configs/keye-vl2-30b-a3b-train-1chip.json"
+LFM2 = "benchmark/configs/lfm2-8b-a1b-train-1chip.json"
+QWEN3NEXT = "benchmark/configs/qwen3-next-80b-a3b-train-1chip.json"
+
+forward = functools.partial(hnr.heads_forward, eps=EPS, interpret=True)
+backward = functools.partial(hnr.heads_backward, eps=EPS, interpret=True)
+
+
+@pytest.fixture(autouse=True)
+def small_tiles(monkeypatch):
+    """The module's tile, which follows the length alone, at ``ROWS``
+    positions."""
+    monkeypatch.setattr(hnr, "ROWS", ROWS)
+
+
+def twin(x, gain, heads, dtype, positions=None):
+    """The lines the kernels replace: the transposed heads, the norm, RoPE,
+    the cast."""
+    b, s, _ = x.shape
+    cfg = types.SimpleNamespace(rms_norm_eps=EPS, rope_theta=THETA,
+                                rotary_width=None)
+    t = x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+    return attention.normed_turned_heads(t, gain, cfg, True,
+                                         positions).astype(dtype)
+
+
+def inputs(seed, heads, s, b=1, hd=HD, positions=None):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    cos, sin = rope_tables(s, hd, THETA, positions)
+    return (jax.random.normal(ks[0], (b, s, heads * hd)),
+            1 + 0.2 * jax.random.normal(ks[1], (hd,)), cos,
+            hnr.signed_sin(sin), jax.random.normal(ks[2], (b, heads, s, hd)))
+
+
+def near(got, want, rel, what=""):
+    """Within ``rel`` of the largest entry."""
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert got.shape == want.shape, what
+    np.testing.assert_allclose(got, want, rtol=0, err_msg=what,
+                               atol=rel * max(1e-30, np.abs(want).max()))
+
+
+@pytest.mark.parametrize("dtype,rel", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 8e-3)])
+@pytest.mark.parametrize("heads,s,hd", SHAPES)
+def test_the_forward_kernel_is_the_twin(heads, s, hd, dtype, rel):
+    """The product read where it lies, (b, s, heads x hd), leaves as (b,
+    heads, s, hd) in the operand's dtype, at lengths that are whole row
+    tiles and that end inside one."""
+    x, gain, cos, sin, _ = inputs(heads + s, heads, s, hd=hd)
+    got = forward(x, gain, cos, sin, heads=heads, dtype=dtype)
+    assert got.dtype == dtype and got.shape == (1, heads, s, hd)
+    near(got, twin(x, gain, heads, dtype), rel)
+
+
+@pytest.mark.parametrize("dtype,rel", [(jnp.float32, 4e-6),
+                                       (jnp.bfloat16, 8e-3)])
+@pytest.mark.parametrize("heads,s,hd", SHAPES)
+def test_the_backward_kernel_is_autodiff_of_the_twin(heads, s, hd, dtype,
+                                                     rel):
+    """The product's cotangent where the projection's transposes read it
+    and the gain's gradient summed over rows and heads in float32, from
+    the product, the gain and the cotangent alone; the cotangent comes in
+    the operand's dtype and the product's leaves in it."""
+    x, gain, cos, sin, do = inputs(3 * heads + s, heads, s, hd=hd)
+    do = do.astype(dtype)
+    want = jax.vjp(lambda x, g: twin(x, g, heads, dtype), x, gain)[1](do)
+    dx, dg = backward(x, gain, cos, sin, do, dtype=dtype)
+    assert dx.dtype == dtype and dg.dtype == jnp.float32
+    near(dx, want[0], rel, "dx")
+    near(dg, want[1], 1e-5 if dtype == jnp.float32 else rel, "dgain")
+
+
+def test_both_halves_of_a_diffused_row_turn_at_its_position():
+    """Under block diffusion the rows are a noisy copy of a sequence before
+    its clean copy, both at positions 0 .. s / 2 - 1: the tables carry the
+    positions, forward and through all three gradients' way back."""
+    heads, s = 4, 48
+    positions = jnp.tile(jnp.arange(s // 2), 2)
+    x, gain, cos, sin, do = inputs(11, heads, s, positions=positions)
+    want_fn = lambda x, g: twin(x, g, heads, jnp.float32, positions)
+    got = forward(x, gain, cos, sin, heads=heads, dtype=jnp.float32)
+    near(got, want_fn(x, gain), 2e-6)
+    # a clean row's head is its noisy row's where the products are equal
+    both = forward(jnp.tile(x[:, :s // 2], (1, 2, 1)), gain, cos, sin,
+                   heads=heads, dtype=jnp.float32)
+    near(both[:, :, s // 2:], both[:, :, :s // 2], 0)
+    assert np.abs(np.asarray(got - twin(x, gain, heads, jnp.float32))
+                  ).max() > 0.1
+    dx, dg = backward(x, gain, cos, sin, do)
+    want = jax.vjp(want_fn, x, gain)[1](do)
+    near(dx, want[0], 4e-6, "dx")
+    near(dg, want[1], 1e-5, "dgain")
+
+
+@pytest.mark.parametrize("rows,sub", [(2048, 512), (32, 16), (64, 32)])
+def test_the_modules_tile_and_others_give_the_same(rows, sub, monkeypatch):
+    """The module's own tile (here all of a short length in whole sublane
+    tiles, one piece), and tiles of 32 and 64 positions in pieces of 16
+    and 32."""
+    monkeypatch.setattr(hnr, "ROWS", rows)
+    monkeypatch.setattr(hnr, "SUB_ROWS", sub)
+    heads, s = 2, 72
+    x, gain, cos, sin, do = inputs(5, heads, s, b=2)
+    near(forward(x, gain, cos, sin, heads=heads, dtype=jnp.float32)[1:],
+         twin(x[1:], gain, heads, jnp.float32), 2e-6)
+    want = jax.vjp(lambda x, g: twin(x, g, heads, jnp.float32), x, gain)[1](do)
+    got = backward(x, gain, cos, sin, do)
+    for name, a, b in zip(("dx", "dgain"), got, want):
+        near(a, b, 1e-5, name)
+
+
+def test_the_tiles_are_what_the_module_says(monkeypatch):
+    """Heads in whole tiles of 128 lanes, turned whole, no gate; 2,048
+    positions a grid step, or all of a shorter length."""
+    assert hnr.supported(128, None, False) and hnr.supported(128, 128, False)
+    assert hnr.supported(256, None, False)
+    assert not hnr.supported(64, None, False)       # lfm2: two heads a tile
+    assert not hnr.supported(256, 64, False)        # qwen3_next: a quarter
+    assert not hnr.supported(256, None, True)       # and a gate behind it
+    monkeypatch.setattr(hnr, "ROWS", 2048)           # ``small_tiles``' back
+    assert (hnr.row_tile(16384), hnr.row_tile(24)) == (2048, 32)
+
+
+def layer(config, seed=0, **widths):
+    """(cfg, a QK-normed sublayer's projections and gains, its normed
+    input) at small widths."""
+    cfg = train.load_model_config(
+        config, hidden_size=64, seq_len=32, micro_batch=1, attn_block=16,
+        loss_block_rows=16, vocab_size=256, vocab_here=64,
+        compute_dtype="float32", **widths)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    p = {name: (1 + 0.2 * jax.random.normal(k, shape)
+                if name.endswith("norm") else
+                0.2 * jax.random.normal(k, shape))
+         for k, (name, shape) in zip(ks, attention.gqa_shapes(cfg).items())}
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, 32, 64))
+    return cfg, p, h
+
+
+def old_lines(p, h, cfg, turned=True, positions=None):
+    """q, k, the gate and what is seen as ``gqa_attention`` and
+    ``dsa_attention`` each wrote them until PR 65."""
+    b, s, _ = h.shape
+    nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
+    mm, gate = attention.matmul, None
+    split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
+    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width, positions)) \
+        if turned else (lambda t: t)
+    first = lambda a, c: jnp.concatenate(
+        [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
+    q_in, k_in = (split(mm(h, p[w], dt), n)
+                  for w, n in (("wq", nh), ("wk", nkv)))
+    if p["wq"].shape[-1] == 2 * p["wo"].shape[0]:
+        q_in, gate = jnp.split(q_in, 2, axis=-1)
+    q, k = (turn(rmsnorm_gain(t, p[g], cfg.rms_norm_eps))
+            for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+    seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
+    return q.astype(dt), k.astype(dt), gate, seen
+
+
+SMALL = dict(head_dim=16, num_attention_heads=4, num_key_value_heads=2)
+TILED = dict(head_dim=128, num_attention_heads=2, num_key_value_heads=1)
+#: (configuration file, widths, the step's interpret, on the kernels)
+WHERE = [
+    (SDAR, dict(TILED, mask_token_here=63), True, False),    # the CPU's
+    (SDAR, dict(SMALL, mask_token_here=63), False, False),   # no tile
+    (LFM2, dict(head_dim=64, num_attention_heads=4,
+                num_key_value_heads=2), False, False),       # two a tile
+    (QWEN3NEXT, dict(head_dim=256, num_attention_heads=2,
+                     num_key_value_heads=1), False, False),  # gate, quarter
+    (SDAR, dict(TILED, mask_token_here=63), False, True),
+    (KEYE, TILED, False, True)]
+IDS = ["tiles-cpu", "no-tiles-tpu", "lfm2-64-tpu", "qwen3next-gated-tpu",
+       "sdar-tpu", "keye-tpu"]
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("config,widths,interpret,on", WHERE, ids=IDS)
+def test_which_way_is_built_and_counted(config, widths, interpret, on):
+    """``normed_qk`` takes ``interpret`` from the step: on the CPU, at a
+    head that is no whole tile, at LFM2's heads of 64 and at Qwen3-Next's
+    gated, quarter-turned head of 256 the program holds the ``jnp`` lines;
+    where Mosaic compiles and a head of 128 is turned whole it holds one
+    kernel for q and one for k each way.  The two SPC counters read what
+    was built."""
+    spc.init()
+    cfg, p, h = layer(config, **widths)
+    before = (spc.read("attn_qk_built"), spc.read("attn_qk_kernel_built"))
+    way = functools.partial(attention.normed_qk, cfg=cfg,
+                            interpret=interpret)
+    fwd = jax.make_jaxpr(way)(p, h)
+    both = jax.make_jaxpr(jax.grad(
+        lambda p, h: sum(jnp.sum(a) for a in way(p, h)[:2]), (0, 1)))(p, h)
+    kernels = lambda jaxpr: sorted(
+        eqn.params["name"] for eqn in _equations(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call")
+    # forward q's, k's and, for ``attn_qk``, the first head's of each
+    assert kernels(fwd) == ["otpu_head_norm_rope_fwd"] * (4 * on)
+    assert kernels(both) == ["otpu_head_norm_rope_bwd"] * (2 * on) \
+        + ["otpu_head_norm_rope_fwd"] * (4 * on)
+    built = spc.read("attn_qk_built") - before[0]
+    assert built >= 4 and built % 2 == 0
+    assert spc.read("attn_qk_kernel_built") - before[1] == built * on
+
+
+@pytest.mark.parametrize("config,widths,interpret,on", WHERE[:4],
+                         ids=IDS[:4])
+def test_every_other_shape_takes_the_lines_bit_for_bit(config, widths,
+                                                       interpret, on):
+    """q, k, the gate and what a check reads are what the two sublayers'
+    own lines made until PR 65, to the bit, on the CPU and where a TPU's
+    step meets a shape the kernels have no tile for."""
+    cfg, p, h = layer(config, **widths)
+    got = attention.normed_qk(p, h, cfg, interpret=interpret)
+    want = old_lines(p, h, cfg)
+    assert (got[2] is None) == (want[2] is None) == (config != QWEN3NEXT)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("diffused", [False, True],
+                         ids=["keye", "sdar-diffused"])
+def test_q_and_k_on_the_kernels_are_q_and_k(diffused, monkeypatch):
+    """``normed_qk`` on the kernels (interpreted here, which takes the
+    kernels being told so) gives the lines' q and k, the same
+    ``attn_qk_in`` / ``attn_qk`` of the first query and key-value head
+    (their products made again and the forward kernel over them), and the
+    same gradient of both projections, both gains and the input."""
+    for name in ("heads_forward", "heads_backward"):
+        monkeypatch.setattr(hnr, name, functools.partial(
+            getattr(hnr, name), interpret=True))
+    config, widths = WHERE[4 if diffused else 5][:2]
+    cfg, p, h = layer(config, **widths)
+    positions = jnp.tile(jnp.arange(16), 2) if diffused else None
+    weights = [jax.random.normal(jax.random.PRNGKey(n), (1, heads, 32, HD))
+               for n, heads in ((3, 2), (4, 1))]
+
+    def loss(p, h, interpret):
+        q, k, gate, seen = attention.normed_qk(
+            p, h, cfg, interpret=interpret, positions=positions)
+        assert gate is None
+        return sum(jnp.sum(a * w) for a, w in zip((q, k), weights)), (
+            q, k, seen)
+
+    (_, got), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        p, h, False)
+    (_, want), want_grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        p, h, True)
+    for name, a, b in zip(("q", "k"), got, want):
+        near(a, b, 2e-6, name)
+    assert sorted(got[2]) == sorted(want[2]) == ["attn_qk", "attn_qk_in"]
+    for name in got[2]:
+        near(got[2][name], want[2][name], 2e-6, name)
+    for name in ("wq", "wk", "q_norm", "k_norm"):
+        near(grads[0][name], want_grads[0][name], 2e-5, name)
+    near(grads[1], want_grads[1], 2e-5, "dh")
+
+
+def test_what_a_check_reads_is_the_kernels_own(monkeypatch):
+    """``attn_qk`` on the kernels is the forward kernel's output, not the
+    lines': a kernel that made something else of a head shows there, next
+    to an ``attn_qk_in`` that stays the product."""
+    real = functools.partial(hnr.heads_forward, interpret=True)
+    cfg, p, h = layer(KEYE, **TILED)
+    seen = {}
+    for name, fn in (("right", real),
+                     ("wrong", lambda *a, **k: 2 * real(*a, **k))):
+        monkeypatch.setattr(hnr, "heads_forward", fn)
+        seen[name] = attention.normed_qk(p, h, cfg, interpret=False)[3]
+    near(seen["wrong"]["attn_qk"], 2 * seen["right"]["attn_qk"], 0)
+    near(seen["wrong"]["attn_qk_in"], seen["right"]["attn_qk_in"], 0)

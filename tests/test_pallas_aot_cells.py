@@ -330,6 +330,7 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
                    for p in found), (name, found)
     assert not [p for p in kernels if "/otpu_flash_causal_forward/" in p
                 or "/otpu_attn_block_backward/" in p]
+    _holds_the_head_norm_rope_kernels(kernels, "otpu_dsa")
     with open(row["hlo"], encoding="utf-8") as f:
         text = f.read()
     assert "s8[4,1,16384,2048]" in text
@@ -339,11 +340,34 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
         line[:300] for line in square if "otpu_stats" not in line][:5]
 
 
+def _holds_the_head_norm_rope_kernels(kernels, operator):
+    """Of a step's kernel paths: ``otpu_head_norm_rope_fwd`` for q, for k
+    and for the first head of each (``attn_qk``) in the forward pass, for
+    q and k again in the recomputed one, which no check reads, ``_bwd``
+    for q and k in the backward pass, all under ``operator``'s
+    ``otpu_attn_proj``."""
+    found = {name: [p for p in kernels if f"/{name}/" in p]
+             for name in ("otpu_head_norm_rope_fwd",
+                          "otpu_head_norm_rope_bwd")}
+    for name, paths in found.items():
+        assert all(f"{operator}/otpu_attn_proj" in p for p in paths), paths
+    fwd = found["otpu_head_norm_rope_fwd"]
+    assert sorted("rematted_computation" in p for p in fwd) == [
+        False, False, False, False, True, True], fwd
+    assert sorted(p.startswith("transpose(") for p in fwd) == [
+        False, False, False, False, True, True], fwd
+    bwd = found["otpu_head_norm_rope_bwd"]
+    assert len(bwd) == 2 and all(
+        p.startswith("transpose(") and "rematted_computation" not in p
+        for p in bwd), bwd
+
+
 @pytest.fixture(scope="module")
 def sdar_rows():
     """One child for the SDAR-30B-A3B cases: both flash kernels under block
-    diffusion's mask and the whole step of the cell's own configuration
-    file, for one v5e device (about a minute of the 600)."""
+    diffusion's mask, the two kernels of ``ops/head_norm_rope`` and the
+    whole step of the cell's own configuration file, for one v5e device
+    (about a minute of the 600)."""
     return rows_with_texts("sdar_")
 
 
@@ -377,6 +401,40 @@ def test_the_block_diffusion_kernels_aot_compile_at_the_cells_shape(
         assert not re.search(r"\[(\d+,)*16384,16384[\],]", text), case
 
 
+@pytest.mark.parametrize("way,kernel,heads,hd", [
+    ("forward", "otpu_head_norm_rope_fwd", "32|4", 128),
+    ("backward", "otpu_head_norm_rope_bwd", "32|4", 128),
+    ("first_head", "otpu_head_norm_rope_fwd", "1", 128),
+    ("256_forward", "otpu_head_norm_rope_fwd", "16|2", 256),
+    ("256_backward", "otpu_head_norm_rope_bwd", "16|2", 256)])
+def test_the_head_norm_rope_kernels_aot_compile_at_the_cells_shape(
+        sdar_rows, way, kernel, heads, hd):
+    """q's and k's way from their projections' float32 products, (1, 16384,
+    4096) and (1, 16384, 512), to the flash kernels' operands, bfloat16 (1,
+    32 | 4, 16384, 128), and back (``ops/head_norm_rope``, PR 65; Keye's
+    layers have the same shapes): one Mosaic call each for q and for k,
+    the products read and their cotangents written where they lie, so no
+    copy, transposition or array of (.., 16384, 32, 128) stands beside
+    them, and no float32 array of the heads' shape anywhere.  The same of
+    the first heads' own products with a float32 result (what a step's
+    ``attn_qk`` reads), and of the kernels' other tile, 16 heads on 2 of
+    256, which no cell runs."""
+    row = sdar_rows[f"sdar_head_norm_rope_{way}"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    ops = row["entry_ops"]
+    assert ops["custom-call"] == 2 and not {"copy", "transpose"} & set(ops)
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert sorted(kernel_bodies(text, "otpu_head_norm_rope_")) == [kernel]
+    if way == "first_head":
+        assert "f32[1,1,16384,128]" in text and "bf16[" not in text
+        return
+    assert not re.search(r"f32\[1,(%s),16384,%d\]" % (heads, hd), text)
+    assert not re.search(r"\[1,16384,(%s),%d\]" % (heads, hd), text)
+    if way.endswith("backward"):
+        assert "bf16[1,16384,4096]" in text and f"f32[8,{hd}]" in text
+
+
 def test_sdar_train_step_aot_compiles_from_the_cells_configuration(
         sdar_rows):
     """The whole step of ``benchmark/configs/sdar-30b-a3b-train-1chip.json``
@@ -403,6 +461,10 @@ def test_sdar_train_step_aot_compiles_from_the_cells_configuration(
     assert not [p for p in kernels if "/otpu_flash_causal_forward/" in p
                 or "/otpu_attn_block_backward/" in p
                 or "select" in p.rsplit("/", 2)[-2]]
+    # q and k reach them through ``ops/head_norm_rope`` (PR 65), under
+    # ``otpu_attn_proj``: one call each in the forward and the recomputed
+    # pass, one each back
+    _holds_the_head_norm_rope_kernels(kernels, "otpu_bd")
 
 
 @pytest.fixture(scope="module")
