@@ -14,9 +14,10 @@ with tiles), each beside the XLA form that is its oracle:
 ``flash_attention`` (causal attention, forward and backward),
 ``grouped_matmul`` (the experts' products), ``gated_delta`` (the chunked
 gated delta rule), ``causal_conv`` (the DeltaNet convolution and its
-silu), ``sparse_attention`` (DSA's index selection and alignment loss) and
+silu), ``sparse_attention`` (DSA's index selection and alignment loss),
 ``head_norm_rope`` (a QK-normed head's norm, RoPE, split and cast on its
-way to the flash kernels).
+way to the flash kernels) and ``row_scatter`` (the held experts' loop's
+rows added into the layer's sums by token).
 """
 from ompi_tpu.ops.pallas_reduce import (  # noqa: F401
     combine2,

@@ -397,16 +397,28 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
     cotangents added where they belong, the rows' and the weights' by
     scatter-add into the carry, the matrices' by the kernel into their
     running float32 sums (``_grouped_matmul``'s ``transposes``: an
-    expert with no row in the chunk is not touched)."""
+    expert with no row in the chunk is not touched).  The rows' sums of
+    both loops (the output, the cotangent of ``h``) are added to by the
+    Pallas row kernel where Mosaic compiles and a row is whole lane
+    tiles (``ops/row_scatter``: row DMAs in place, the live rows alone,
+    weighted inside), and are then carried a row as tiles of its own
+    (``row_scatter.as_tiles``) and turned to rows once, after the loop;
+    everywhere else by ``.at[].add``."""
     t, d = h.shape
     k, n_here = cfg.num_experts_per_tok, sizes.shape[0]
     rows = chunk_rows(t, k, n_here, cfg.num_experts)
+    on_kernel = False
+    if not interpret:
+        from ompi_tpu.ops import row_scatter
+
+        on_kernel = row_scatter.supported(rows, d, h.dtype)
     padded = -(-t * k // rows) * rows
     order = jnp.pad(order, (0, padded - t * k))
 
     def chunk(lo, order, sizes, h, flat_w, *mats):
         """Chunk ``lo``'s (slot and token of each row, which rows hold a
-        slot, their weights, their experts' output, its ``back``)."""
+        slot, how many of them each held expert has, their weights, their
+        experts' output, its ``back``)."""
         with jax.named_scope("otpu_dispatch"):
             slot = jax.lax.dynamic_slice_in_dim(order, lo, rows)
             token = slot // k
@@ -417,11 +429,38 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         # rows past the last held slot belong to no group: a grouped
         # matmul leaves them as they were in memory (seen on the v5e:
         # NaN), in its transposes too, so they are cut off on both sides
+        # (the row kernel does not read them)
         xs = jnp.where(live[:, None], h[token], 0.0)
         y, back = ffn(xs, *mats, here, cfg.compute_dtype, interpret)
         with jax.named_scope("otpu_combine"):
             w = jnp.where(live, flat_w[slot], 0.0)
-            return slot, token, live, w, jnp.where(live[:, None], y, 0.0), back
+            if not on_kernel:
+                y = jnp.where(live[:, None], y, 0.0)
+            return slot, token, live, here, w, y, back
+
+    def add_rows(acc, token, live, here, y, w=None):
+        """``acc`` with the chunk's live rows ``y``, times ``w`` where
+        given, added by token: on the row kernel (``acc`` as tiles a row)
+        or by XLA's scatter-add of every row, the dead ones as zeros.
+        SPC ``moe_scatter_built`` counts the loops' scatter-adds made
+        while steps were traced, ``moe_scatter_kernel_built`` those of
+        them on the kernel."""
+        spc.record("moe_scatter_built", 1)
+        if on_kernel:
+            spc.record("moe_scatter_kernel_built", 1)
+            offsets = jnp.concatenate(
+                [jnp.zeros((1,), here.dtype), jnp.cumsum(here)])
+            # one kernel for both loops (a step builds it once): the
+            # cotangents' rows go in under weights of one
+            ones = jnp.ones((rows,), y.dtype)
+            return row_scatter.row_scatter_add(
+                acc, token, offsets, y, ones if w is None else w)
+        if w is None:
+            return acc.at[token].add(jnp.where(live[:, None], y, 0.0))
+        return acc.at[token].add(y * w[:, None])
+
+    def as_rows(sums):
+        return row_scatter.as_rows(sums) if on_kernel else sums
 
     def trips(sizes):
         return (jnp.sum(sizes) + rows - 1) // rows
@@ -433,6 +472,8 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         """A loop's starting sums, shaped as ``like`` (array, dtype)
         pairs and varying as ``args`` do, as the body's results will."""
         zero = [jnp.zeros(a.shape, dtype) for a, dtype in like]
+        if on_kernel:     # the rows' sums: the first of either loop's
+            zero[0] = row_scatter.as_tiles(zero[0])
         vma = tuple(frozenset().union(*(jax.typeof(a).vma for a in args)))
         return jax.lax.pcast(zero, vma, to="varying") if vma else zero
 
@@ -441,12 +482,12 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         mats = cast(mats)
 
         def body(c, out):
-            _, token, _, w, y, _ = chunk(c * rows, order, sizes, h, flat_w,
-                                         *mats)
+            _, token, live, here, w, y, _ = chunk(
+                c * rows, order, sizes, h, flat_w, *mats)
             with jax.named_scope("otpu_combine"):
-                return out.at[token].add(y * w[:, None])
+                return add_rows(out, token, live, here, y, w)
         (out,) = zeros([(h, h.dtype)], order, sizes, h, flat_w, *mats)
-        return jax.lax.fori_loop(0, trips(sizes), body, out)
+        return as_rows(jax.lax.fori_loop(0, trips(sizes), body, out))
 
     def fwd(*args):
         return run(*args), args
@@ -457,7 +498,7 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
 
         def body(c, sums):
             dh, dw, dmats = sums
-            slot, token, live, w, y, back = chunk(
+            slot, token, live, here, w, y, back = chunk(
                 c * rows, order, sizes, h, flat_w, *mats16)
             with jax.named_scope("otpu_combine"):
                 dout = ct[token]
@@ -465,15 +506,14 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
                     jnp.where(live, jnp.sum(y * dout, axis=1), 0.0))
                 dy = jnp.where(live[:, None], dout * w[:, None], 0.0)
             dxs, dmats = back(dy, dmats)
-            return (dh.at[token].add(jnp.where(live[:, None], dxs, 0.0)),
-                    dw, dmats)
+            return add_rows(dh, token, live, here, dxs), dw, dmats
 
         dh, dw, *dmats = zeros(
             [(h, h.dtype), (flat_w, flat_w.dtype)]
             + [(m, jnp.float32) for m in mats], ct, *args)
         zero = (dh, dw, tuple(dmats))
         dh, dw, dmats = jax.lax.fori_loop(0, trips(sizes), body, zero)
-        return (None, None, dh, dw) + tuple(
+        return (None, None, as_rows(dh), dw) + tuple(
             s.astype(m.dtype) for s, m in zip(dmats, mats))
 
     run.defvjp(fwd, bwd)

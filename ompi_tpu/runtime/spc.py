@@ -137,6 +137,11 @@ _COUNTERS = (
     # of them made on the Pallas kernel (ops/grouped_matmul): the second
     # over the first says which share of a run's engaged the kernel
     "moe_gmm_built", "moe_gmm_kernel_built",
+    # the held experts' loops' row scatter-adds made while steps were
+    # traced (parallel/experts.local_expert_ffn: one a loop, forward and
+    # backward), and those of them made on the Pallas row kernel
+    # (ops/row_scatter): the second over the first
+    "moe_scatter_built", "moe_scatter_kernel_built",
     # the chunked delta rule's passes made while steps were traced
     # (parallel/gdn.gated_delta_chunked: the XLA form's forward, or the
     # kernel path's forward and backward rules), and those of them made

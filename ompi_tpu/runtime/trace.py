@@ -576,6 +576,7 @@ OWN_PROGRAMS = (
     "reduce_stack", "combine2",                     # ops/pallas_reduce
     "transpose_blocks",                             # ops/pallas_ddt
     "gmm", "tgmm",                                  # ops/grouped_matmul
+    "row_scatter_add",                              # ops/row_scatter
     "flash_causal_forward", "attn_block_backward",  # ops/flash_attention
     "index_select", "index_loss",                   # ops/sparse_attention
     "rule_forward", "rule_backward",                # ops/gated_delta

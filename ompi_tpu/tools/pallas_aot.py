@@ -408,6 +408,40 @@ def cases(mesh1d, mesh2d):
          lambda: gmm_trip_forms(16384, 10, 32, 512, 2048, 512))
     case("gmm_smallthinker",
          lambda: gmm_trip_forms(16384, 6, 16, 64, 2560, 768))
+    # the same loop's two row scatter-adds (``ops/row_scatter``: the
+    # trip's output rows times their weights into the layer's sums, the
+    # rows' cotangents times one into the cotangent of ``h``), sums and
+    # results in one buffer each, at the chunk and the width a share
+    # cell sends (Keye's are SDAR's; Nemotron's rows are its latent's)
+    def row_scatter_forms(t, k, g, total, d):
+        from ompi_tpu.ops import row_scatter
+        from ompi_tpu.parallel import experts
+
+        m = experts.chunk_rows(t, k, g, total)
+
+        def trip(out, dh, token, offsets, y, w, dxs):
+            return (row_scatter.row_scatter_add(out, token, offsets, y, w),
+                    row_scatter.row_scatter_add(dh, token, offsets, dxs,
+                                                jnp.ones_like(w)))
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        ints = lambda *s: _sds(s, jnp.int32, one, P())
+        sums = rep(t, *row_scatter.tile_shape(d))
+        return jax.jit(trip, donate_argnums=(0, 1)), (
+            sums, sums, ints(m), ints(g + 1), rep(m, d), rep(m), rep(m, d))
+
+    case("row_scatter_smallthinker",
+         lambda: row_scatter_forms(16384, 6, 16, 64, 2560))
+    case("row_scatter_sdar",
+         lambda: row_scatter_forms(16384, 8, 16, 128, 2048))
+    case("row_scatter_lfm2",
+         lambda: row_scatter_forms(16384, 4, 8, 32, 2048))
+    case("row_scatter_qwen3next",
+         lambda: row_scatter_forms(16384, 10, 32, 512, 2048))
+    case("row_scatter_joyai",
+         lambda: row_scatter_forms(8192, 8, 16, 256, 2048))
+    case("row_scatter_nemotron",
+         lambda: row_scatter_forms(8192, 22, 8, 512, 1024))
     # the chunked delta rule (``gdn._kernel_rule``: ``ops/gated_delta``'s
     # two kernels) as the Qwen3-Next cell's step builds it: 16 key heads,
     # 32 value heads, 128 / 128, 16,384 positions in chunks of 64, q, k

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ops import grouped_matmul as gm
+from ompi_tpu.ops import row_scatter
 from ompi_tpu.parallel import experts
 from ompi_tpu.runtime import spc
 
@@ -177,6 +178,8 @@ def interpreted(monkeypatch):
     for name in ("gmm", "tgmm"):
         monkeypatch.setattr(gm, name, functools.partial(
             getattr(gm, name), interpret=True))
+    monkeypatch.setattr(row_scatter, "row_scatter_add", functools.partial(
+        row_scatter.row_scatter_add, interpret=True))
 
 
 def _ffn_operands(ffn, sizes, m, d, f, seed=1):
@@ -239,7 +242,14 @@ def test_local_expert_ffn_whole_on_the_kernel(held, form, interpreted):
     h, weights = normal(t, d), jnp.abs(normal(t, k))
     mats = tuple(normal(here, d, f) for _ in range(n_mats - 1)) + (
         normal(here, f, d),)
-    chosen = rng.integers(0 if held == "some_slots" else here, e, (t, k))
+    # a token's two experts are distinct, as ``lax.top_k``'s are (the
+    # row kernel counts on it: ``ops/row_scatter``)
+    if held == "some_slots":
+        first = rng.integers(0, here, t)
+        chosen = np.stack([first, (first + rng.integers(1, e, t)) % e], 1)
+    else:
+        chosen = np.stack([rng.integers(here, e - 1, t),
+                           np.full(t, e - 1)], 1)
     order, sizes = experts.local_dispatch(jnp.asarray(chosen), 0, here)
     if held == "some_slots":
         assert int(sizes.sum()) > 2 * experts.chunk_rows(t, k, here, e)

@@ -508,6 +508,39 @@ def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
     assert not sums, sums[:3]
 
 
+@pytest.fixture(scope="module")
+def row_scatter_rows():
+    """One child for the held experts' loop's row scatter-add at the six
+    share cells' shapes, the output's (weighted) and the cotangent's, for
+    one v5e device (about 10 s of the 600)."""
+    return rows_with_texts("row_scatter_")
+
+
+@pytest.mark.parametrize("cell", ["smallthinker", "sdar", "lfm2",
+                                  "qwen3next", "joyai", "nemotron"])
+def test_row_scatter_aot_compiles_at_a_cells_shapes(cell, row_scatter_rows):
+    """``ops/row_scatter``'s kernel at the chunk (``experts.chunk_rows``)
+    and the width a share cell sends, under the rows' weights and under
+    ones (PR 66): Mosaic takes single-row DMAs between the sums in HBM, a row
+    as tiles of its own (``row_scatter.tile_shape``: SmallThinker's 20
+    lane tiles as (4, 640)), and the two VMEM buffers, each call is one
+    custom call whose ``op_name`` carries the kernel's name, the sums
+    come in and go out in one buffer (``input_output_alias``), and
+    nothing else stands in the program but the ones: no copy to another
+    layout."""
+    row = row_scatter_rows["row_scatter_" + cell]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    kernels = [path for line, path in op_paths(row)
+               if " custom-call(" in line]
+    assert sum("otpu_row_scatter_add" in p for p in kernels) == 2, kernels
+    with open(row["hlo"], encoding="utf-8") as f:
+        head = f.readline()
+    assert "{0}: (0, {}, may-alias)" in head \
+        and "{1}: (1, {}, may-alias)" in head, head[:300]
+    assert row["entry_ops"] == {"constant": 1, "broadcast": 1,
+                                "custom-call": 2, "tuple": 1}
+
+
 @pytest.mark.parametrize("rows,case", [
     ("qwen3next_rows", "qwen3next_step_1chip"),
     ("smallthinker_rows", "smallthinker_step_1chip"),
