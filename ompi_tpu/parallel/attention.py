@@ -357,18 +357,21 @@ def _gqa_reports(cfg, kind: str, windowed: bool,
 
 
 def _gqa(name: str, group: str, scope: str, windowed: bool = False,
-         diffused: bool = False) -> Sublayer:
+         diffused: bool = False, post_norm: str = "") -> Sublayer:
     """A ``layer_types`` model's grouped-query attention by its name."""
     bound = dict(kind=name, windowed=windowed, diffused=diffused)
     return Sublayer(
         name=name, group=group, scope=scope,
         run=functools.partial(gqa_attention, **bound), shapes=gqa_shapes,
         undecayed=("ln1", "q_norm", "k_norm"),
-        reports=functools.partial(_gqa_reports, **bound), keeps=ATTN_KEEPS)
+        reports=functools.partial(_gqa_reports, **bound), keeps=ATTN_KEEPS,
+        post_norm=post_norm)
 
 
-#: lfm2's and qwen3_next's attention, smallthinker's in full
-FULL = _gqa("full_attention", "attn", "otpu_attention")
+#: lfm2's and qwen3_next's attention, smallthinker's in full, ouro's (the
+#: one with a norm behind it: ``post_norm``)
+FULL = _gqa("full_attention", "attn", "otpu_attention",
+            post_norm="ln1_post")
 #: smallthinker's under its window (``sliding_window``): a full layer's
 #: leaves
 WINDOW = _gqa("sliding_attention", "swa", "otpu_swa", windowed=True)

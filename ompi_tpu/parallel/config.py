@@ -52,7 +52,11 @@ class ModelConfig:
     model's first ``first_k_dense_replace`` layers and the routed experts
     after them; any other model's layers are attention (latent where
     ``kv_lora_rank`` is set) before the same two.  ``scoring_func`` and
-    ``topk_method`` say how a router scores and chooses."""
+    ``topk_method`` say how a router scores and chooses.  Under
+    ``sandwich_norm`` every sublayer is normed behind as well as before,
+    ahead of its residual add; under ``total_ut_steps`` the held layers
+    are walked that many times over one set of leaves
+    (``objective.model_loss``)."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -151,6 +155,13 @@ class ModelConfig:
     mask_token_here: int = -1       # the mask token's row of ``vocab_rows``
     noise_seed: int = 0             # the step's noise is keyed by it
     t_min: float = 0.001            # a block's noise level: from here to 1
+    # ouro's keys (Ouro-2.6B): a looped model, no router anywhere
+    total_ut_steps: int = 0         # passes over the held layers, the final
+    #                                 norm, the head and an exit gate behind
+    #                                 each (0: one walk, no gate)
+    exit_beta: float = 0.0          # the exit distribution's entropy bonus
+    sandwich_norm: bool = False     # a norm behind a sublayer, ahead of its
+    #                                 residual add
 
     @property
     def pattern_here(self) -> str:
@@ -374,6 +385,19 @@ class ModelConfig:
                 "untied head), on sequences of whole blocks and whole "
                 "attn_block tiles, with mask_token_here a row of the held "
                 "vocabulary and t_min in (0, 1]")
+        looped = bool(self.total_ut_steps)
+        if (looped or self.sandwich_norm or self.exit_beta) and (
+                not looped or set(self.layer_types) != {"full_attention"}
+                or self.n_sparse_here or self.num_experts or self.qk_norm
+                or self.attn_output_gate or self.tie_word_embeddings
+                or self.total_ut_steps < 1 or self.exit_beta < 0.0):
+            raise NotImplementedError(
+                f"total_ut_steps {self.total_ut_steps} / exit_beta "
+                f"{self.exit_beta} / sandwich_norm {self.sandwich_norm}: "
+                "the looped walk, its exit gate and the norm behind a "
+                "sublayer are an ouro model's: every layer full_attention "
+                "without a QK-norm or a gate, then a dense SwiGLU, no "
+                "router, an untied head, one pass or more")
         if not typed and (not self.qk_norm or self.router_before_attention):
             raise NotImplementedError(
                 f"qk_norm {self.qk_norm} / router_before_attention "
@@ -516,6 +540,27 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             f"{path}: block_length / mask_token_here / noise_seed / t_min: "
             "block diffusion's training pass is an sdar_moe model's; a "
             f"model_type {body.get('model_type')} model trains next-token")
+    ouro = body.get("model_type") == "ouro"
+    if ouro:
+        layers = body["num_hidden_layers"]
+        if body.get("layer_types", ["full_attention"] * layers) \
+                != ["full_attention"] * layers or hybrid \
+                or "kv_lora_rank" in body or body.get("use_sliding_window") \
+                or body.get("total_ut_steps", 0) < 1 or any(
+                    key in body for key in ("num_experts", "n_routed_experts",
+                                            "num_local_experts")):
+            raise NotImplementedError(
+                f"{path}: an ouro model is run with every layer "
+                "full_attention then a dense SwiGLU, no expert, no window, "
+                "and total_ut_steps passes over its layers, one or more")
+        body.setdefault("layer_types", ["full_attention"] * layers)
+    elif any(key in body or key in body.get("train", {}) for key in (
+            "total_ut_steps", "exit_beta", "sandwich_norm")):
+        raise NotImplementedError(
+            f"{path}: total_ut_steps / exit_beta / sandwich_norm: the looped "
+            "walk, its exit gate and the norm behind a sublayer are an ouro "
+            f"model's; a model_type {body.get('model_type')} model walks its "
+            "layers once")
     scaling = body.get("rope_scaling")
     if scaling:
         # M-RoPE's three position components are equal on a text token, so
@@ -608,6 +653,13 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
                              ("kv_chunk_size", "index_kv_chunk")):
             if theirs in sparse:
                 merged.setdefault(ours, sparse[theirs])
+    if ouro:
+        # what the file's keys imply: every layer dense and none routed,
+        # no QK-norm, RoPE on every layer, and (the report's, no published
+        # key) a norm behind every sublayer as well as before it
+        merged.update(first_k_dense_replace=merged["num_hidden_layers"],
+                      num_experts=0, num_experts_per_tok=0, qk_norm=False,
+                      rope_kinds=("full_attention",), sandwich_norm=True)
     if next_:       # its modelling code's, on which config.json is silent
         merged.setdefault("attn_output_gate", True)
         shared = bool(merged.get("moe_shared_expert_intermediate_size"))

@@ -714,7 +714,8 @@ def _held_reports(cfg) -> dict:
 
 
 DENSE = Sublayer(group="dense", scope="otpu_dense_mlp", run=dense_mlp,
-                 shapes=_dense_shapes, undecayed=("ln2",))
+                 shapes=_dense_shapes, undecayed=("ln2",),
+                 post_norm="ln2_post")
 #: OLMoE's: every expert here
 SORTED = Sublayer(
     group="moe", scope="otpu_moe", run=moe_sorted_block,

@@ -20,6 +20,7 @@ from ompi_tpu.parallel.attention import (DIFFUSED, FULL, MLA, OLMOE,
                                          SHARED_KV, WINDOW)
 from ompi_tpu.parallel.config import HYBRID_LETTERS, LAYER_TYPES
 from ompi_tpu.parallel.gdn import GDN
+from ompi_tpu.parallel.layers import rmsnorm_gain
 from ompi_tpu.parallel.mamba import MIXER
 from ompi_tpu.parallel.short_conv import CONV
 from ompi_tpu.parallel.dsa import DSA
@@ -33,7 +34,9 @@ SUBLAYERS = OPERATORS + FEED_FORWARDS
 #: a sublayer by what a configuration file calls it
 NAMED = {e.name: e for e in SUBLAYERS if e.name}
 #: the leaves AdamW does not decay, by their last name
-UNDECAYED = frozenset(leaf for e in SUBLAYERS for leaf in e.undecayed)
+UNDECAYED = frozenset(
+    leaf for e in SUBLAYERS
+    for leaf in e.undecayed + ((e.post_norm,) if e.post_norm else ()))
 #: what a walked layer's ``jax.checkpoint`` keeps for its backward pass
 CHECKPOINT_KEEPS = tuple(dict.fromkeys(
     name for e in SUBLAYERS for name in e.keeps))
@@ -59,9 +62,14 @@ class LayerKind(NamedTuple):
         return self.feed_forward not in (None, experts.DENSE)
 
     def shapes(self, cfg) -> dict:
-        """One layer's leaves, in the tree's order."""
-        return {k: v for part in self.parts
-                for k, v in part.shapes(cfg).items()}
+        """One layer's leaves, in the tree's order: each sublayer's own,
+        and under ``cfg.sandwich_norm`` the gain of the norm behind it."""
+        out = {}
+        for part in self.parts:
+            out.update(part.shapes(cfg))
+            if cfg.sandwich_norm and part.post_norm:
+                out[part.post_norm] = (cfg.hidden_size,)
+        return out
 
 
 @functools.cache
@@ -130,8 +138,10 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
     the residual stream ``x`` (b, s, d): its operator's residual add, then
     its feed-forward's, as far as it has them; the router's product made
     from the layer's input, before the operator, where
-    ``cfg.router_before_attention``.  ``bias`` is the router's balancing
-    bias and ``at`` the token rows a step samples.
+    ``cfg.router_before_attention``.  Under ``cfg.sandwich_norm`` what a
+    sublayer returns goes through an RMSNorm of its own (the sublayer's
+    ``post_norm`` gain) before it is added.  ``bias`` is the router's
+    balancing bias and ``at`` the token rows a step samples.
 
     Returns (x, the sublayers' statistics, what they report by token row:
     a router's under its own keys, an operator's under its prefix)."""
@@ -145,11 +155,15 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
     if op is not None:
         with jax.named_scope(op.scope):
             y, stats, seen = op.run(p, x, cfg, interpret=interpret, at=at)
+            if cfg.sandwich_norm:
+                y = rmsnorm_gain(y, p[op.post_norm], cfg.rms_norm_eps)
         x = x + y
     if ffn is not None:
         with jax.named_scope(ffn.scope):
             y, routing, made = ffn.run(p, x, cfg, bias, interpret=interpret,
                                        routed=routed)
+            if cfg.sandwich_norm:
+                y = rmsnorm_gain(y, p[ffn.post_norm], cfg.rms_norm_eps)
         x = x + y
         stats, seen = {**routing, **stats}, {**made, **seen}
     return x, stats, seen

@@ -5,7 +5,10 @@ cross-entropy in blocks of rows, the routers' losses and the next-n
 module's, with what a step reports of them (its ``aux``, listed in
 ``parallel/train.py``); and, for a model trained by block diffusion
 (``block_length``), the step's noise, the noisy and the clean copy of
-every sequence side by side, and the masked rows' weighted loss.
+every sequence side by side, and the masked rows' weighted loss; for a
+looped model (``total_ut_steps``) the walk run that many times over one
+set of leaves, the exit gate behind every pass and the expected loss under
+the exit distribution (``looped_loss``).
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ from ompi_tpu.parallel.config import ModelConfig
 from ompi_tpu.parallel.layers import matmul, rmsnorm_gain
 from ompi_tpu.parallel.model import (CHECKPOINT_KEEPS, decoder_layer,
                                      kind_of_letter, layer_kinds)
+from ompi_tpu.runtime import spc
 
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 
@@ -33,16 +37,18 @@ def sample_rows(rows: int) -> np.ndarray:
 
 
 def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
-                       weights=None):
+                       weights=None, scope: str = "otpu_bd_loss"):
     """Summed cross-entropy of ``softmax(h @ w)`` against ``labels``, by
     blocks of ``block_rows`` rows so that no (T, V) array is ever held.
     The forward pass also makes the two gradients (``softmax - onehot``
     is at hand in each block), so the backward pass only scales them:
     the head's logits are computed once a step, not twice.  ``weights``
     (T,) float32 (None: one a row, and the program is the call's without
-    it) gives each row's share of the sum, a constant of the step; a row
+    it) gives each row's share of the sum, a constant of the step (no
+    gradient reaches it; its work is traced under ``scope``); a row
     of weight zero adds nothing to either gradient.  Returns (the
-    weighted sum over rows, per row (logsumexp, the label's logit))."""
+    weighted sum over rows, per row (logsumexp, the label's logit): no
+    gradient passes through the second)."""
     t, d = h.shape
     nblk = t // block_rows
     if nblk * block_rows != t:
@@ -62,7 +68,7 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
                 lb, logits.shape[-1], dtype=jnp.float32)
             lost = lse - picked
             if wb:
-                with jax.named_scope("otpu_bd_loss"):
+                with jax.named_scope(scope):
                     dlogits, lost = dlogits * wb[0][:, None], lost * wb[0]
             dh = matmul(dlogits, w.T, compute_dtype)
             dw = dw + matmul(hb.T, dlogits, compute_dtype, weight=False)
@@ -192,7 +198,109 @@ def _walk_pattern(run_of, layers, x, bias, cfg: ModelConfig):
                 chosen.append(experts)
     with jax.named_scope("otpu_stats"):
         cat = lambda of: {k: jnp.concatenate(v) for k, v in of.items()}
-        return x, (cat(stats), jnp.concatenate(chosen), cat(sample))
+        return x, (cat(stats), jnp.concatenate(chosen) if chosen else None,
+                   cat(sample))
+
+
+def exit_distribution(gate):
+    """A looped model's exit distribution from its gate's products ``gate``
+    (T, rows) float32, a pass a row: with ``lambda_t = sigmoid(gate_t)``,
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < T and the last
+    pass takes what is left, ``p_T = prod_{j<T} (1 - lambda_j)`` (its own
+    gate is not read).  Returns (p, log p), the logarithm summed from
+    ``log_sigmoid`` of the product and of its negative, so that no
+    ``log(0)`` arises however far the gate saturates; at T = 1 p is 1 and
+    log p 0 exactly."""
+    stay = jax.nn.log_sigmoid(-gate[:-1])           # log(1 - lambda_t)
+    none = jnp.zeros_like(gate[:1])
+    log_p = jnp.concatenate([none, jnp.cumsum(stay, axis=0)]) \
+        + jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]), none])
+    return jnp.exp(log_p), log_p
+
+
+def looped_loss(params, x, labels, cfg: ModelConfig, run_of, psum,
+                n_global: int, at_head):
+    """A looped model's loss (Ouro, arXiv:2510.25741: its Stage I
+    objective) from the embedded rows ``x`` (b, s, d), and what a step
+    reports of it.  ``h_0 = x``; for pass t = 1 .. ``total_ut_steps``
+    **``h_t = RMSNorm_f(Layers(h_{t-1}))``**: one ``lax.scan`` over the
+    passes whose body closes over ``params["layers"]``, so every pass reads
+    the same leaves, the tree holds each once and a leaf's gradient is the
+    sum over the passes; each layer application under the walk's
+    ``jax.checkpoint`` as in any model.  Behind every pass the one head and
+    the one exit gate read ``h_t``: ``lambda_t = sigmoid(h_t . w + b)``,
+    the exit distribution ``p`` a token row (``exit_distribution``), and
+
+    ``L = (1 / (b s)) sum_i [ sum_t p_t,i CE_t,i - exit_beta H(p_.,i) ]``
+
+    with ``CE_t,i`` the row's cross-entropy behind pass t and ``H`` the
+    entropy.  The head reads all ``T b s`` rows in **one** blocked
+    cross-entropy, the rows weighted by ``p`` as a constant (one (d, V)
+    gradient accumulated, no (rows, V) array held); the gate's gradient
+    comes through ``p_t,i`` times the row's own cross-entropy, which the
+    head returns a row, and through the entropy.
+
+    ``aux``: ``losses`` [total, the passes' mean cross-entropies, the
+    expected cross-entropy, ``exit_beta`` x the mean entropy]; ``rows`` (b
+    s, T, 2); ``sample`` the layers' rows stacked over the T x layers
+    applications, ``head_in`` (R, T, d), ``exit_logit`` (R, T) and
+    ``exit_entropy`` (R,); ``exit_p`` (R, T) at the sampled rows and
+    ``exit_mean`` (T,) over the whole batch; ``loads`` and ``experts`` with
+    no entry, since nothing routes."""
+    b, s, d = x.shape
+    n, t = b * s, cfg.total_ut_steps
+    for name, by in (("loop_built", 1), ("loop_passes", t),
+                     ("loop_layers_held", cfg.layers_here),
+                     ("loop_layer_applications", t * cfg.layers_here),
+                     ("loop_head_rows", t * n)):
+        spc.record(name, by)
+
+    def one_pass(x, _):
+        with jax.named_scope("otpu_loop_pass"):
+            with jax.named_scope("otpu_layers"):
+                x, (_, _, seen) = _walk_pattern(run_of, params["layers"], x,
+                                                None, cfg)
+            h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
+        return h, (h, seen)
+
+    _, (hs, seen) = jax.lax.scan(one_pass, x, None, length=t)
+    hs = hs.reshape(t, n, d)
+    with jax.named_scope("otpu_exit_gate"):
+        gate = jnp.sum(hs * params["exit_gate"]["w"], -1) \
+            + params["exit_gate"]["b"][0]
+        p, log_p = exit_distribution(gate)                   # (t, n) f32
+    with jax.named_scope("otpu_head"):
+        ce_sum, rows = head_cross_entropy(
+            hs.reshape(t * n, d), params["head"],
+            jnp.tile(labels[:, :s].reshape(n), t),
+            min(cfg.loss_block_rows, n), cfg.compute_dtype,
+            jax.lax.stop_gradient(p).reshape(t * n), scope="otpu_exit_loss")
+    with jax.named_scope("otpu_exit_loss"):
+        rows = jax.lax.stop_gradient(rows).reshape(t, n, 2)
+        lost = rows[..., 0] - rows[..., 1]
+        # the head weighted its rows by p as a constant; p's own gradient
+        # is the row's cross-entropy: a term of value zero that carries it
+        through_p = jnp.sum(p * lost)
+        expected = ce_sum + (through_p - jax.lax.stop_gradient(through_p))
+        entropy = -jnp.sum(p * log_p, axis=0)                # (n,)
+    with jax.named_scope("otpu_loss"):
+        ce = psum(expected) / n_global
+        bonus = cfg.exit_beta * psum(jnp.sum(entropy)) / n_global
+        total = ce - bonus
+    with jax.named_scope("otpu_stats"):
+        losses = jnp.concatenate([
+            jnp.stack([total]), psum(jnp.sum(lost, axis=1)) / n_global,
+            jnp.stack([ce, bonus])])
+        sample = {k: v.reshape((-1,) + v.shape[2:]) for k, v in seen.items()}
+        sample.update(head_in=hs[:, at_head].transpose(1, 0, 2),
+                      exit_logit=gate[:, at_head].T,
+                      exit_entropy=entropy[at_head])
+        return total, {
+            "losses": losses, "rows": rows.transpose(1, 0, 2),
+            "loads": jnp.zeros((0, cfg.num_experts), jnp.float32),
+            "experts": jnp.zeros((0, n, 0), jnp.int32), "sample": sample,
+            "exit_p": p[:, at_head].T,
+            "exit_mean": psum(jnp.sum(p, axis=1)) / n_global}
 
 
 def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
@@ -221,7 +329,10 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     loss is over all ``2 s`` rows' routing.  ``rows`` then holds the noisy
     half's rows, and ``aux`` also the noise: ``bd_mask`` (b, s) uint8,
     ``bd_levels`` (b, s / B), ``bd_masked`` the masked rows and
-    ``bd_weight_sum`` their weights' sum, over the whole batch."""
+    ``bd_weight_sum`` their weights' sum, over the whole batch.
+
+    A looped model (``cfg.total_ut_steps``: Ouro) walks its held layers
+    that many times and has its own loss and ``aux``: ``looped_loss``."""
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
     ids, levels, masked = tokens, None, None
@@ -258,7 +369,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                     for k, v in seen.items()})
             return x, out
 
-        if cfg.layers_here + cfg.n_mtp_here > 1:
+        if cfg.layers_here + cfg.n_mtp_here > 1 or cfg.total_ut_steps > 1:
             # a layer's activations are recomputed in its backward pass,
             # so that one layer's are held at a time and not every
             # layer's; with one layer there is nothing to save.  Kept from
@@ -268,6 +379,9 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
 
     with jax.named_scope("otpu_embed"):
         x = params["embed"][ids]                             # (b, s, d) f32
+    if cfg.total_ut_steps:
+        return looped_loss(params, x, labels, cfg, run_of, psum, n_global,
+                           at_head)
     with jax.named_scope("otpu_layers"):
         if cfg.pattern_here:
             x, (st, chosen, sample) = _walk_pattern(

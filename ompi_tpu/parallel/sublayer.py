@@ -50,6 +50,10 @@ class Sublayer:
     reports: Callable = lambda cfg: {}
     #: the ``checkpoint_name``s a walked layer's checkpoint keeps of it
     keeps: tuple = ()
+    #: the gain (d,) of the norm behind it, ahead of its residual add, which
+    #: a layer holds under ``cfg.sandwich_norm`` ("": the sublayer is in no
+    #: such model); undecayed, it starts at one
+    post_norm: str = ""
 
 
 def zeros(key, shape, cfg):

@@ -1,6 +1,7 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
 Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
-Keye-VL-2.0-30B-A3B's language model, SDAR-30B-A3B by block diffusion):
+Keye-VL-2.0-30B-A3B's language model, SDAR-30B-A3B by block diffusion,
+Ouro-2.6B's looped walk):
 widths from a configuration file
 (``parallel/config.py``), not from the mesh; the kinds of layer from
 ``parallel/model.py``'s table.  The parameter tree and its initialisation,
@@ -36,6 +37,9 @@ from ompi_tpu.runtime import spc, trace
 #: the tree's own gains, beside its layers' (``model.UNDECAYED``): they
 #: start at one and are not decayed
 GAINS = ("final_norm", "enorm", "hnorm", "norm")
+#: a looped model's exit gate (``objective.looped_loss``): its leaves
+#: start at zero (every pass's lambda one half) and are not decayed
+EXIT_GATE = ("exit_gate.w", "exit_gate.b")
 PROBE = 64              # entries of each leaf that a step reports
 #: what a step's ``aux`` holds: small raw statistics, for whoever reads
 #: them outside the step (no unit, no scaling).  ``losses`` (the total,
@@ -62,7 +66,12 @@ PROBE = 64              # entries of each leaf that a step reports
 #: gradient is the head's alone); of a model trained by block diffusion
 #: the step's noise, ``bd_mask`` (b, s) uint8, ``bd_levels`` (b, s / B),
 #: ``bd_masked`` and ``bd_weight_sum`` (``objective.model_loss``), and
-#: ``rows`` of the noisy half's rows alone
+#: ``rows`` of the noisy half's rows alone; of a looped model
+#: (``objective.looped_loss``) ``losses`` (the total, every pass's mean
+#: cross-entropy, the expected one, the weighted entropy bonus), ``rows``
+#: (T, passes, 2), ``exit_p`` (R, passes) and ``exit_mean`` (passes,),
+#: ``sample`` stacked over the passes' layer applications, ``loads`` and
+#: ``experts`` with no entry
 
 
 def pattern_layer_shapes(cfg: ModelConfig) -> dict:
@@ -78,7 +87,8 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
     norms, the projection of their joined outputs, one sparse layer, a
     last norm; absent where the model has none), ``final_norm``,
     ``head`` (absent under ``tie_word_embeddings``: the head reads
-    ``embed``).  Under a ``hybrid_override_pattern`` or ``layer_types``
+    ``embed``), ``exit_gate`` (a looped model's: ``w`` (d,), ``b`` (1,)).
+    Under a ``hybrid_override_pattern`` or ``layer_types``
     ``layers`` holds a group a run of like layers (``cfg.segments``),
     ``l<first layer>``, and in it a group a letter of the run's unit (its
     kind's name) whose leaves are stacked over the run's repeats."""
@@ -87,6 +97,8 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
     tree = {"embed": (v, d)}
     last = {"final_norm": (d,)} if cfg.tie_word_embeddings \
         else {"final_norm": (d,), "head": (d, v)}
+    if cfg.total_ut_steps:
+        last["exit_gate"] = {"w": (d,), "b": (1,)}
     kinds = pattern_layer_shapes(cfg)
     if cfg.pattern_here:
         group = {c: kind.name for c, kind in kind_of_letter(cfg).items()}
@@ -105,9 +117,10 @@ def model_param_shapes(cfg: ModelConfig) -> dict:
 
 def is_decayed(name: str) -> bool:
     """Whether AdamW decays the leaf: every matrix, and no gain, bias or
-    per-head scalar (``GAINS``; a sublayer's ``undecayed``)."""
+    per-head scalar (``GAINS``; a sublayer's ``undecayed``; ``EXIT_GATE``)."""
     last = name.rsplit(".", 1)[-1]
-    return last not in GAINS and last not in UNDECAYED
+    return last not in GAINS and last not in UNDECAYED \
+        and name not in EXIT_GATE
 
 
 def leaf_names(cfg: ModelConfig) -> list:
@@ -163,8 +176,8 @@ def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
     """Float32 master parameters drawn on the default device from
     ``seed``, each leaf from a key folded from its name: a leaf AdamW does
     not decay (``is_decayed``) at one, a matrix as normal(0, ``init_std``),
-    and what a sublayer's ``starts`` names as that says
-    (``model.leaf_starts``)."""
+    what a sublayer's ``starts`` names as that says
+    (``model.leaf_starts``), and the exit gate at zero."""
     key = jax.random.PRNGKey(seed)
     starts = {**leaf_starts(cfg), "embed": _embed_start}
 
@@ -173,6 +186,8 @@ def init_model_params(cfg: ModelConfig, seed: int = 0) -> dict:
         start = starts.get(name.rsplit(".", 1)[-1])
         if start is not None:
             return start(k, shape, cfg)
+        if name in EXIT_GATE:
+            return jnp.zeros(shape, jnp.float32)
         if not is_decayed(name):
             return jnp.ones(shape, jnp.float32)
         return cfg.init_std * jax.random.normal(k, shape, jnp.float32)
@@ -311,6 +326,9 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
     if cfg.block_length:
         aux_specs.update(bd_mask=batch, bd_levels=batch, bd_masked=rep,
                          bd_weight_sum=rep)
+    if cfg.total_ut_steps:
+        aux_specs.update(exit_p=batch, exit_mean=rep)
+        aux_specs["sample"].update(exit_logit=batch, exit_entropy=P("dp"))
 
     def otpu_train_step(state, tokens, labels):
         return shard_map(body, mesh=mesh, in_specs=(rep, batch, batch),
@@ -444,9 +462,17 @@ def record_step_stats(aux) -> int:
     ``moe_absent_slots``, and the rows the held experts' loops walked
     for them (whole chunks) to ``moe_chunk_rows``; ``train_steps_read``
     counts the steps read.  Of a step trained by block diffusion the rows
-    its noise masked add to ``bd_rows_masked``."""
+    its noise masked add to ``bd_rows_masked``; of a looped model's the
+    batch's mean exit pass, ``sum_t t p_t`` in thousandths, to
+    ``loop_exit_depth``, and nothing routes, so no slot is counted."""
     loads = np.asarray(aux["loads"])
     spc.record("train_steps_read")
+    if "exit_mean" in aux:
+        mean = np.asarray(aux["exit_mean"], np.float64)
+        spc.record("loop_exit_depth", int(round(
+            1000.0 * float(mean @ np.arange(1, mean.size + 1)))))
+    if not loads.size:
+        return 0
     if "bd_masked" in aux:
         spc.record("bd_rows_masked", int(np.asarray(aux["bd_masked"])))
     if "local_slots" in aux:

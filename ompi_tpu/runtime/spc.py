@@ -184,6 +184,15 @@ _COUNTERS = (
     # (parallel/train.record_step_stats), the token rows the steps' noise
     # replaced by the mask token
     "bd_built", "bd_pairs_visible", "bd_pairs_causal", "bd_rows_masked",
+    # a looped model's walk (parallel/objective.looped_loss), from the
+    # shapes while steps were traced: the losses built, the passes, the
+    # layers held, the layer applications (passes x layers: over the
+    # layers held, the times a leaf is read a pass of the step) and the
+    # rows the head reads (passes x tokens); and, read back a step outside
+    # every window (parallel/train.record_step_stats), the batch's mean
+    # exit pass sum_t t p_t in thousandths (1,875 at a gate of zero)
+    "loop_built", "loop_passes", "loop_layers_held",
+    "loop_layer_applications", "loop_head_rows", "loop_exit_depth",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

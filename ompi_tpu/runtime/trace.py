@@ -1084,6 +1084,11 @@ STEP_SCOPES = (
                             # the two copies' ids side by side
     "otpu_bd_loss",         # inside otpu_head: the masked rows' weights
                             # and the weighted sum
+    "otpu_loop_pass",       # a looped model's pass, whole: the layers'
+                            # walk (otpu_layers inside) and the pass's norm
+    "otpu_exit_gate",       # the exit gate's product, lambda, p, log p
+    "otpu_exit_loss",       # the expected loss's and the entropy's own
+                            # work, inside otpu_head and beside it
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

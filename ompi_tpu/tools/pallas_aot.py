@@ -702,6 +702,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "keye-vl2-30b-a3b-train-1chip"))
     case("sdar_step_1chip", lambda: model_step(
         topo_devs[:1], "sdar-30b-a3b-train-1chip"))
+    case("ouro_step_1chip", lambda: model_step(
+        topo_devs[:1], "ouro-2.6b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

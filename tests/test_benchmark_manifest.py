@@ -243,6 +243,28 @@ def _cut_batch_sdar(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# ouro-train-1chip: the same, at the widths of tests/test_ouro_train.py (a
+# scanned run of two sandwich layers walked four times: 4 heads of 16, a
+# feed-forward of 96, all 256 ids, 2 rows of 64 tokens, tiles of 16)
+OURO = "ouro-train-1chip"
+TINY_OURO = dict(hidden_size=64, head_dim=16, num_attention_heads=4,
+                 num_key_value_heads=4, intermediate_size=96, vocab_size=256,
+                 layers_here=2)
+TINY_OURO_TRAIN = dict(seq_len=64, micro_batch=2, attn_block=16,
+                       loss_block_rows=32, compute_dtype="float32")
+
+
+def _tiny_ouro(config):
+    config.update(TINY_OURO)
+    config["train"].update(TINY_OURO_TRAIN)
+
+
+def _cut_batch_ouro(p):
+    p.update(sequences=TINY_OURO_TRAIN["micro_batch"],
+             seq_len=TINY_OURO_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -325,6 +347,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("sdar-30b-a3b-train-1chip", _tiny_sdar),
         cut={"packed-8k-block-diffusion-steps": _cut_batch_sdar}),
+    OURO: dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("ouro-2.6b-train-1chip", _tiny_ouro),
+        cut={"packed-4k-looped-steps": _cut_batch_ouro}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -335,7 +362,7 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
               "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE,
-              SDAR)
+              SDAR, OURO)
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -395,7 +422,7 @@ builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                  if k.startswith(("device_", "train_",
                                                   "moe_", "attn_",
-                                                  "dsa_", "bd_"))}}))
+                                                  "dsa_", "bd_", "loop_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
 print("layer " + json.dumps(layer))
@@ -898,8 +925,8 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
-            assert [c for c in m["workloads"] if c != SDAR][-1] == KEYE, \
-                m["name"]
+            assert [c for c in m["workloads"]
+                    if c not in (SDAR, OURO)][-1] == KEYE, m["name"]
     with open(os.path.join(BENCH, "metrics", "dsa.selected_share.json"),
               encoding="utf-8") as f:
         spec = json.load(f)
@@ -911,10 +938,10 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
         spec = json.load(f)
     from ompi_tpu.runtime import trace
 
-    # PR 64 put its three names behind these
+    # PR 64 and PR 67 put three names each behind these
     assert spec["params"]["scopes"] == ["otpu_dsa_loss"] \
         and tuple(spec["params"]["vocabulary"]) == trace.STEP_SCOPES[
-            -len(spec["params"]["vocabulary"]) - 3:-3]
+            -len(spec["params"]["vocabulary"]) - 6:-6]
 
 
 @of_cells(SDAR)
@@ -975,15 +1002,16 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
             "trace_kit_flops", "point_rate", "program_counter",
             "trace_scope_share_wide")
     assert len({by_name[n]["layer"] for n in new[-5:]}) == 1
-    assert real["workloads"][-1]["name"] == SDAR \
-        and real["configs"][-1]["name"] == real["workloads"][-1]["config"]
-    assert real["workloads"][-1]["chips"] == 1
-    assert real["configs"][-1]["reduced"] == ["layers", "experts", "vocab",
+    assert real["workloads"][12]["name"] == SDAR \
+        and real["configs"][11]["name"] == real["workloads"][12]["config"]
+    assert real["workloads"][12]["chips"] == 1
+    assert real["configs"][11]["reduced"] == ["layers", "experts", "vocab",
                                               "ranks"]
     for m in real["end_to_end"] + real["per_layer"]:
         if KEYE in m.get("workloads", ()) and len(m["workloads"]) > 1:
-            assert m["workloads"][-1] == SDAR, m["name"]
-    assert by_name["attn.pairs_walked_share"]["workloads"][-1] == SDAR
+            assert [c for c in m["workloads"] if c != OURO][-1] == SDAR, \
+                m["name"]
+    assert SDAR in by_name["attn.pairs_walked_share"]["workloads"]
     assert (readers["bd.visible_share"]["reader"],
             readers["bd.visible_share"]["params"]) == ("program_counter", {
                 "name": "bd_pairs_visible", "over": "bd_pairs_causal",
@@ -1002,10 +1030,110 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
     for name, scope in (("bd.operator_share", "otpu_bd"),
                         ("bd.noise_share", "otpu_bd_noise"),
                         ("bd.loss_share", "otpu_bd_loss")):
-        spec = readers[name]["params"]
+        spec = readers[name]["params"]      # PR 67 put three behind
         assert spec["scopes"] == [scope] and tuple(
             spec["vocabulary"]) == trace.STEP_SCOPES[-len(
+                spec["vocabulary"]) - 3:-3]
+
+
+@of_cells(OURO)
+def test_a_looped_step_counts_its_passes_and_routes_nothing(rehearsal):
+    """The trainer's counters on one chip's four layers of Ouro (two here),
+    by the kind that reads everything from the kit and with no file of the
+    harness edited for it: the loop's shape from what was traced, the mean
+    exit pass of the steps read back (a gate that has hardly moved: 1.875),
+    no slot and no expert's load anywhere, every attention pass a plain
+    causal walk; the step's program is the one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_OURO_TRAIN["micro_batch"] * TINY_OURO_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.ouro.bf16.2x4096"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert c["loop_built"] > 0 \
+        and c["loop_passes"] == 4 * c["loop_built"] \
+        and c["loop_layers_held"] == 2 * c["loop_built"] \
+        and c["loop_layer_applications"] == 8 * c["loop_built"] \
+        and c["loop_head_rows"] == 4 * tokens * c["loop_built"]
+    assert abs(c["loop_exit_depth"] / c["train_steps_read"] - 1875) < 2
+    for name in ("moe_local_slots", "moe_absent_slots", "moe_chunk_rows",
+                 "moe_max_expert_load", "moe_gmm_built", "bd_built",
+                 "attn_window_built", "attn_shared_kv_built"):
+        assert c.get(name, 0) == 0, name
+    assert c["attn_built"] > 0 \
+        and c["attn_pairs_walked"] == c["attn_pairs_causal"] > 0
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
+    """Appended behind everything that was there (PR 67): data files on
+    readers that are there, in the one looped cell, each moving
+    ``small_msg_us``; the loop's five under a layer of their own; the
+    cell's name at the end of the lists SmallThinker's cell is in that
+    this step has, and of none of the experts'.  Eight and not the twelve
+    the issue lists: ``per_layer`` holds 128 entries at most."""
+    names = [m["name"] for m in real["per_layer"]]
+    new = ["ouro.mfu", "ouro.flash_mfu", "ouro.attn_bwd_mfu",
+           "loop.pass_share", "loop.head_share", "loop.exit_share",
+           "loop.cast_share", "loop.applications_per_layer"]
+    at = names.index(new[0])    # by name, whatever a later PR appends
+    assert names[at:at + 8] == new and at > names.index("bd.masked_share")
+    assert len(names) <= 128
+    by_name = {m["name"]: m for m in real["per_layer"]}
+    readers = {}
+    for name in new:
+        m = by_name[name]
+        assert (m["workloads"], m["moves"]) == ([OURO], "small_msg_us")
+        twin = by_name.get(name.replace("ouro.", "sdar."))
+        if twin and twin is not m:
+            assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
+                == {k: twin[k] for k in ("unit", "better", "source", "layer")}
+        with open(os.path.join(BENCH, "metrics", name + ".json"),
+                  encoding="utf-8") as f:
+            readers[name] = json.load(f)
+        assert readers[name]["reader"] in (
+            "trace_kit_flops", "program_counter", "trace_scope_share_wide")
+    assert len({by_name[n]["layer"] for n in new[-5:]}) == 1
+    at = [w["name"] for w in real["workloads"]].index(OURO)
+    cell = real["workloads"][at]
+    (config,) = [c for c in real["configs"] if c["name"] == cell["config"]]
+    assert (cell["traffic"], cell["chips"], config["reduced"]) == (
+        "packed-4k-looped-steps", 1, ["layers"])
+    for m in real["end_to_end"] + real["per_layer"]:
+        lists = m.get("workloads", ())
+        if m["name"].startswith("moe.") or "local_load" in m["name"]:
+            assert OURO not in lists, m["name"]
+        elif m["name"].startswith(("compile.", "launch.", "device.")) \
+                and "smallthinker-train-1chip" in lists:
+            assert OURO in lists, m["name"]
+    for name in ("small_msg_us", "step.hbm_peak_share",
+                 "attn.pairs_walked_share"):
+        (m,) = [m for m in real["end_to_end"] + real["per_layer"]
+                if m["name"] == name]
+        assert OURO in m["workloads"], name
+    assert (readers["loop.applications_per_layer"]["reader"],
+            readers["loop.applications_per_layer"]["params"]) == (
+        "program_counter", {"name": "loop_layer_applications",
+                            "over": "loop_layers_held"})
+    assert (readers["ouro.mfu"]["params"]["count"],
+            "pattern" in readers["ouro.mfu"]["params"]) == ("step", False)
+    for name, count, pattern in (
+            ("ouro.flash_mfu", "flash_forward", "^otpu_flash"),
+            ("ouro.attn_bwd_mfu", "attn_backward", "^otpu_attn_.*backward")):
+        assert (readers[name]["params"]["count"],
+                readers[name]["params"]["pattern"]) == (count, pattern)
+    from ompi_tpu.runtime import trace
+
+    for name, scopes in (
+            ("loop.pass_share", ["otpu_loop_pass"]),
+            ("loop.head_share", ["otpu_head"]),
+            ("loop.exit_share", ["otpu_exit_gate", "otpu_exit_loss"]),
+            ("loop.cast_share", ["otpu_cast"])):
+        spec = readers[name]["params"]
+        assert spec["scopes"] == scopes and tuple(
+            spec["vocabulary"]) == trace.STEP_SCOPES[-len(
                 spec["vocabulary"]):]
+        assert set(scopes) <= set(trace.STEP_SCOPES)
 
 
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
@@ -1025,10 +1153,11 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
-        # the walked pairs are read where a mask spares some: PR 64's cell
+        # the walked pairs are read where a mask spares some, PR 64's cell,
+        # and where every pair of the triangle is walked, PR 67's
         assert (m["workloads"], m["moves"]) == (
-            [cell, SDAR] if name == "attn.pairs_walked_share" else [cell],
-            "small_msg_us")
+            [cell, SDAR, OURO] if name == "attn.pairs_walked_share"
+            else [cell], "small_msg_us")
         twin = by_name.get(name.replace("smallthinker.", "qwen3next."))
         if twin and twin is not m:
             assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
@@ -1042,7 +1171,7 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
             assert [c for c in m["workloads"]
-                    if c not in (KEYE, SDAR)][-1] == cell, m["name"]
+                    if c not in (KEYE, SDAR, OURO)][-1] == cell, m["name"]
     for name, params in (
             ("attn.window_share", {"name": "attn_window_built",
                                    "over": "attn_built", "scale": 100}),
@@ -1127,7 +1256,7 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
               encoding="utf-8") as f:
         base = json.load(f)["scopes"]
     assert tuple(base + spec["params"]["vocabulary"]) \
-        == trace.STEP_SCOPES[:-3]
+        == trace.STEP_SCOPES[:-6]
     kinds = set()
     for cell in real["workloads"]:
         with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"),
@@ -1135,7 +1264,8 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
             points = json.load(f).get("points", [])
         if cell["name"] in SHARE_CELLS:
             kinds |= {p["kind"] for p in points}
-        else:
+        elif cell["name"] != OURO:      # a kit cell in which nothing routes:
+            #                             the metric does not list it
             assert not {p["kind"] for p in points} & set(
                 spec["params"]["select"]["kind"]), cell["name"]
     assert kinds == set(spec["params"]["select"]["kind"])
@@ -1393,6 +1523,39 @@ def test_kit_check_tells_the_block_diffusion_program_from_its_controls(
                               ("unweighted", "grad_probe")):
             assert row["parts_" + variant]["units_by_group"][part] > 1, \
                 variant
+
+
+def test_kit_check_tells_the_looped_program_from_its_controls(tmp_path):
+    """``benchmark/tools/kit_check.py`` on Ouro's cell at the rehearsal's
+    widths, with no file of the harness edited for it (a step that routes
+    nothing gives its row's load as not a number): one step of the program
+    lies within the kind's tolerance of ``ourokit``'s reference; the
+    reference in bfloat16 lies far outside the program's, and each of the
+    kit's controls outside the tolerance where it bites: a bfloat16 head,
+    one pass in place of four, the pass's norm left out between the passes,
+    the last pass's loss alone, the exit distribution held at a quarter
+    each (the gate's gradient then zero), a layer without its second
+    norms."""
+    env = _stage(OURO, str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", OURO, "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "1", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    (row,) = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+              if ln.startswith("seed ")]
+    assert row["program"]["widest_units"] < 0.05
+    assert row["local_load"] != row["local_load"]       # nothing routes
+    assert row["control_bf16"]["widest_units"] \
+        > 100 * row["program"]["widest_units"]
+    for variant, part in (("bf16", "head_rows"), ("one_pass", "lse_means"),
+                          ("no_pass_norm", "grad_probe"),
+                          ("last_pass_loss", "losses"),
+                          ("uniform_exit", "exit_mean"),
+                          ("uniform_exit", "grad_log_rms"),
+                          ("no_post_norm", "grad_probe")):
+        assert row["parts_" + variant]["units_by_group"][part] > 1, variant
 
 
 def test_kit_check_tells_the_window_program_from_its_controls(tmp_path):

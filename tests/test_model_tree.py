@@ -82,6 +82,13 @@ TREES = {
              num_experts_per_tok=3, vocab_size=256, vocab_here=64,
              experts_here=4, mask_token_here=63),
         "f44cc625a1563a7e", "6690f312d2e32ef4"),
+    # PR 67's own tree, pinned as it was brought: each layer's leaves once
+    # (a second gain behind each sublayer), the exit gate behind the head
+    "ouro-2.6b-train-1chip.json": (
+        dict(hidden_size=64, head_dim=16, num_attention_heads=4,
+             num_key_value_heads=4, intermediate_size=96, vocab_size=256,
+             layers_here=2),
+        "6bf59e92c7fae8cb", "934f087bce1c92b6"),
 }
 
 

@@ -363,6 +363,48 @@ def _holds_the_head_norm_rope_kernels(kernels, operator):
 
 
 @pytest.fixture(scope="module")
+def ouro_rows():
+    """One child for Ouro-2.6B's case: the whole looped step of the cell's
+    own configuration file, for one v5e device (about 15 s of the 600)."""
+    return rows_with_texts("ouro_")
+
+
+def test_ouro_train_step_aot_compiles_from_the_cells_configuration(
+        ouro_rows):
+    """The whole step of ``benchmark/configs/ouro-2.6b-train-1chip.json``
+    (published widths; layers 0-3 of 48 walked four times, the whole
+    vocabulary, 2 x 4,096 tokens): it fits the chip beside its 4.9 GB of
+    state, which holds every leaf once (406,884,353 parameters and AdamW's
+    two moments); the passes and the layers are loops, forward and
+    backward; both flash kernels stand under ``otpu_loop_pass`` in the pass
+    they belong to and in no recomputed one (the checkpoint keeps o and the
+    logsumexp); no router, no grouped matmul and no other model's kernel
+    is in it."""
+    row = ouro_rows["ouro_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 406_884_353 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    for name in ("otpu_flash_causal_forward", "otpu_attn_block_backward"):
+        found = [p for p in kernels if f"/{name}/" in p]
+        assert found and all(
+            "otpu_loop_pass/otpu_layers" in p and "otpu_attention" in p
+            for p in found), (name, found)
+        assert not [p for p in found if "rematted_computation" in p], name
+        assert all(("transpose(" in p) == (name == "otpu_attn_block_backward")
+                   for p in found), (name, found)
+    assert not [p for p in kernels if "otpu_gmm" in p or "otpu_moe" in p
+                or "otpu_row_scatter" in p or "otpu_head_norm_rope" in p
+                or "_bd_" in p]
+    paths = [path for _, path in op_paths(row)]
+    for scope in ("otpu_exit_gate", "otpu_exit_loss", "otpu_head"):
+        assert any(scope in p for p in paths), scope
+
+
+@pytest.fixture(scope="module")
 def sdar_rows():
     """One child for the SDAR-30B-A3B cases: both flash kernels under block
     diffusion's mask, the two kernels of ``ops/head_norm_rope`` and the
