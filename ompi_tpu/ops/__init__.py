@@ -15,8 +15,8 @@ with tiles), each beside the XLA form that is its oracle:
 ``grouped_matmul`` (the experts' products), ``gated_delta`` (the chunked
 gated delta rule), ``causal_conv`` (the DeltaNet convolution and its
 silu), ``sparse_attention`` (DSA's index selection and alignment loss),
-``head_norm_rope`` (a QK-normed head's norm, RoPE, split and cast on its
-way to the flash kernels) and ``row_scatter`` (the held experts' loop's
+``head_norm_rope`` (a head's norm where it has one, RoPE, split and cast
+on its way to the flash kernels) and ``row_scatter`` (the held experts' loop's
 rows added into the layer's sums by token).
 """
 from ompi_tpu.ops.pallas_reduce import (  # noqa: F401

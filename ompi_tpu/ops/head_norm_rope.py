@@ -1,20 +1,22 @@
 """Pallas kernels for a head's way from its projection's product to the
-flash kernels' operand: per-head RMSNorm with a gain, RoPE in the
-half-split form, the head split and the cast, one pass over the array
-forward and one backward.
+flash kernels' operand: per-head RMSNorm with a gain where the head has
+one, RoPE in the half-split form, the head split and the cast, one pass
+over the array forward and one backward.
 
-The Qwen3-MoE form of grouped-query attention (``parallel/attention
-.normed_qk``, which ``gqa_attention``'s QK-norm branch and
-``dsa.dsa_attention`` call under ``otpu_attn_proj``) runs here where
-Mosaic compiles (a TPU) and a head is whole tiles of 128 lanes, turned
-whole, with no gate behind it (``supported``); everywhere else it stays
-``attention.normed_turned_heads``, the ``jnp`` lines that are these
-kernels' oracle.  XLA makes of those lines five fusions with HBM between
-them (the transposed float32 heads, the normed heads, RoPE's ``[-x2,
-x1]``, the turned heads, the cast: 43 ms of SDAR's 420 ms step on the
-v5e, every one a pass over a (32, 16384, 128) array; PR 64's scope
-table); a kernel that holds a tile of one head's rows in VMEM reads the
-product once and writes the operand once.
+Grouped-query attention's q and k (``parallel/attention.normed_qk``,
+which ``gqa_attention``'s ``layer_types`` branch and ``dsa.dsa_attention``
+call under ``otpu_attn_proj``) run here where Mosaic compiles (a TPU),
+RoPE turns the layer and a head is whole tiles of 128 lanes, turned
+whole, with no gate behind it (``supported``): the Qwen3-MoE form with
+its per-head QK-norm (SDAR, Keye) and the form that is turned and not
+normed (``gain`` None: Ouro, SmallThinker's window layers).  Everywhere
+else they stay ``attention.normed_turned_heads``, the ``jnp`` lines that
+are these kernels' oracle.  XLA makes of the normed lines five fusions
+with HBM between them (the transposed float32 heads, the normed heads,
+RoPE's ``[-x2, x1]``, the turned heads, the cast: 43 ms of SDAR's 420 ms
+step on the v5e, every one a pass over a (32, 16384, 128) array; PR 64's
+scope table); a kernel that holds a tile of one head's rows in VMEM
+reads the product once and writes the operand once.
 
 - ``heads_forward``: grid (batch, row tile, head), the heads innermost so
   that a row tile's cos / sin block is fetched once for all of them.  A step
@@ -35,6 +37,16 @@ product once and writes the operand once.
   cotangent (b, s, n x hd) where the matmuls' transposes read it, and
   adds ``sum_rows dy x rsqrt(.)`` to the gain's sums.  Nothing but (the
   product, the gain, the tables) is kept from the forward pass.
+- A head without a gain (``gain`` None) is the same algorithm with the
+  norm's step absent, on the same grid, tiles and pieces
+  (``otpu_head_rope_fwd`` / ``_bwd``): forward ``x cos + partner(x) sin``;
+  backward ``dx = do cos + partner'(do sin)`` from the cotangent and the
+  tables **alone** (the turn is linear: nothing of the product is kept or
+  read), and with no gain's sums there is no shared block, so every axis
+  is ``parallel``.  XLA makes of these lines four fusions (the transposed
+  float32 heads, ``[-x2, x1]``, the turned heads, the cast): 3.5 ms a
+  layer and pass over SmallThinker's 67 M entries of q and k on the v5e
+  (``PERF.md`` section 5, PR 56's scope table).
 
 Inside a step the tile is walked in pieces of ``SUB_ROWS`` rows, written
 out one after the other.  On the v5e, at (1, 16384, 32 x 128) with the
@@ -44,7 +56,7 @@ where the lines take 4.19 and 5.49 and the HBM's rate allows 0.49 and
 
 All arithmetic is float32, in the order of ``layers.rmsnorm_gain`` and
 ``layers.rope``: ``((x * rsqrt(mean(x x) + eps)) * gain) * cos +
-partner * sin``.
+partner * sin``, and ``x * cos + partner * sin`` without a gain.
 """
 from __future__ import annotations
 
@@ -140,6 +152,28 @@ def _bwd_kernel(eps, sub, x_ref, g_ref, cos_ref, sin_ref, do_ref, dx_ref,
     dg_ref[...] += sums
 
 
+def _turn_fwd_kernel(sub, x_ref, cos_ref, sin_ref, o_ref):
+    """One tile of rows of one head that has no gain: ``_fwd_kernel``
+    without the norm."""
+    rows, hd = x_ref.shape
+    for r0 in range(0, rows, sub):
+        at = slice(r0, r0 + sub)
+        x = x_ref[at, :]
+        o_ref[at, :] = (x * cos_ref[at, :] + pltpu.roll(x, hd // 2, 1)
+                        * sin_ref[at, :]).astype(o_ref.dtype)
+
+
+def _turn_bwd_kernel(sub, cos_ref, sin_ref, do_ref, dx_ref):
+    """One tile of rows of one head that has no gain: the un-turn of
+    ``_bwd_kernel`` alone, from the cotangent and the tables."""
+    rows, hd = do_ref.shape
+    for r0 in range(0, rows, sub):
+        at = slice(r0, r0 + sub)
+        do = do_ref[at, :].astype(jnp.float32)
+        dx_ref[at, :] = (do * cos_ref[at, :] + pltpu.roll(
+            do * sin_ref[at, :], hd // 2, 1)).astype(dx_ref.dtype)
+
+
 def _padded(a, axis, rows):
     """``a`` with ``axis`` padded with zeros to whole row tiles (rows whose
     norm is of nothing and whose gradient is nothing)."""
@@ -177,7 +211,8 @@ def heads_forward(x, gain, cos, sin, *, heads: int, eps: float, dtype,
                   interpret: bool = False):
     """``rope(rmsnorm_gain(heads of x, gain, eps))`` (b, heads, s, hd) in
     ``dtype`` of the product x (b, s, heads x hd) float32, the gain (hd,)
-    and the angles' tables cos and ``signed_sin`` (s, hd) float32."""
+    and the angles' tables cos and ``signed_sin`` (s, hd) float32; with
+    ``gain`` None, ``rope(heads of x)``."""
     b, s, width = x.shape
     hd = width // heads
     rows = row_tile(s)
@@ -185,11 +220,18 @@ def heads_forward(x, gain, cos, sin, *, heads: int, eps: float, dtype,
         _padded(sin, 0, rows)
     sp = x.shape[1]
     lying, split, one, table = _specs(rows, hd)
+    grid, out = (b, sp // rows, heads), [((b, heads, sp, hd), dtype)]
+    if gain is None:
+        (o,) = _call(
+            functools.partial(_turn_fwd_kernel, _sub_rows(rows)),
+            "otpu_head_rope_fwd", (x, cos, sin), grid,
+            [lying, table, table], [split], out,
+            ("parallel", "parallel", "arbitrary"), interpret)
+        return o[:, :, :s]
     (o,) = _call(
         functools.partial(_fwd_kernel, eps, _sub_rows(rows)),
         "otpu_head_norm_rope_fwd", (x, gain.reshape(1, hd), cos, sin),
-        (b, sp // rows, heads), [lying, one, table, table], [split],
-        [((b, heads, sp, hd), dtype)],
+        grid, [lying, one, table, table], [split], out,
         ("parallel", "parallel", "arbitrary"), interpret)
     return o[:, :, :s]
 
@@ -199,20 +241,28 @@ def heads_backward(x, gain, cos, sin, do, *, eps: float,
     """(dx (b, s, heads x hd) in ``dtype``, dgain (hd,) float32) of
     ``heads_forward``'s result for its cotangent ``do`` (b, heads, s, hd),
     from x, the gain and the tables as ``heads_forward`` took them; dgain is
-    summed over the batch, the positions and the heads in float32."""
-    b, s, width = x.shape
-    heads = do.shape[1]
-    hd = width // heads
+    summed over the batch, the positions and the heads in float32.  With
+    ``gain`` None x is not read (None will do) and dgain is None."""
+    b, heads, s, hd = do.shape
     rows = row_tile(s)
-    x, do = _padded(x, 1, rows), _padded(do, 2, rows)
+    do = _padded(do, 2, rows)
     cos, sin = _padded(cos, 0, rows), _padded(sin, 0, rows)
-    sp = x.shape[1]
+    sp = do.shape[2]
+    grid, dx_shape = (b, sp // rows, heads), ((b, sp, heads * hd), dtype)
     lying, split, one, table = _specs(rows, hd)
+    if gain is None:
+        (dx,) = _call(
+            functools.partial(_turn_bwd_kernel, _sub_rows(rows)),
+            "otpu_head_rope_bwd", (cos, sin, do), grid,
+            [table, table, split], [lying], [dx_shape],
+            ("parallel", "parallel", "parallel"), interpret)
+        return dx[:, :s], None
+    x = _padded(x, 1, rows)
     dx, dg = _call(
         functools.partial(_bwd_kernel, eps, _sub_rows(rows)),
         "otpu_head_norm_rope_bwd", (x, gain.reshape(1, hd), cos, sin, do),
-        (b, sp // rows, heads), [lying, one, table, table, split],
+        grid, [lying, one, table, table, split],
         [lying, pl.BlockSpec((8, hd), lambda z, i, h: (0, 0))],
-        [((b, sp, width), dtype), ((8, hd), jnp.float32)],
+        [dx_shape, ((8, hd), jnp.float32)],
         ("arbitrary", "arbitrary", "arbitrary"), interpret)
     return dx[:, :s], jnp.sum(dg, axis=0)

@@ -82,12 +82,13 @@ def mla_attention(p, x, cfg, *, interpret: bool, at=None):
 
 def normed_turned_heads(t, gain, cfg, turned: bool, positions=None):
     """Heads ``t`` (b, n, s, hd) float32 under the per-head QK-norm
-    (RMSNorm with ``gain`` (hd,) over each head's width) and, where
-    ``turned``, RoPE in the half-split form over the leading
-    ``cfg.rotary_width`` entries at ``positions``: the ``jnp`` lines, which
-    are the twin of ``ops/head_norm_rope``'s kernels and every other
-    shape's path."""
-    t = rmsnorm_gain(t, gain, cfg.rms_norm_eps)
+    (RMSNorm with ``gain`` (hd,) over each head's width; none where
+    ``gain`` is None) and, where ``turned``, RoPE in the half-split form
+    over the leading ``cfg.rotary_width`` entries at ``positions``: the
+    ``jnp`` lines, which are the twin of ``ops/head_norm_rope``'s kernels
+    and every other shape's path."""
+    if gain is not None:
+        t = rmsnorm_gain(t, gain, cfg.rms_norm_eps)
     return rope(t, cfg.rope_theta, cfg.rotary_width, positions) \
         if turned else t
 
@@ -101,7 +102,9 @@ def _kernel_heads(prod, gain, cos, sin, heads, eps, dtype):
     second with its sign on (``signed_sin``).  Only the product, the gain
     and the tables are kept for the backward kernel, which makes the norm
     again and writes the product's cotangent in ``dtype``, which is what
-    its readers, the projections' transposes, cast it to."""
+    its readers, the projections' transposes, cast it to.  A head without
+    a norm has ``gain`` None: the tables alone are kept, the turn being
+    linear."""
     from ompi_tpu.ops import head_norm_rope
 
     return head_norm_rope.heads_forward(prod, gain, cos, sin, heads=heads,
@@ -113,7 +116,7 @@ def _kernel_heads_fwd(prod, gain, cos, sin, heads, eps, dtype):
 
     return head_norm_rope.heads_forward(
         prod, gain, cos, sin, heads=heads, eps=eps, dtype=dtype), (
-            prod, gain, cos, sin)
+            None if gain is None else prod, gain, cos, sin)
 
 
 def _kernel_heads_bwd(heads, eps, dtype, res, do):
@@ -140,17 +143,19 @@ def _qk_on_kernels(interpret, cfg, turned, gated) -> bool:
 
 def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
               positions=None):
-    """q and k of a sublayer with a per-head QK-norm (lfm2's form: qwen3_moe's,
-    sdar_moe's, Keye's, qwen3_next's) from the normed input ``h`` (b, s,
-    d): ``h W_q`` and ``h W_k`` split into the ``n_heads_here`` and
+    """q and k of a ``layer_types`` sublayer from the normed input ``h``
+    (b, s, d): ``h W_q`` and ``h W_k`` split into the ``n_heads_here`` and
     ``n_kv_heads_here`` heads, a gate split off behind every query head
-    where ``wq`` is twice as wide as ``wo`` is long, RMSNorm with the gains
-    ``q_norm`` and ``k_norm`` over each head, RoPE where ``turned`` (at
-    ``positions``), all float32, then the cast to ``compute_dtype``.
+    where ``wq`` is twice as wide as ``wo`` is long, where the layer holds
+    ``q_norm`` and ``k_norm`` (lfm2's form: qwen3_moe's, sdar_moe's,
+    Keye's, qwen3_next's) RMSNorm with those gains over each head, where it
+    holds none (smallthinker's form, ouro's) no norm, RoPE where ``turned``
+    (at ``positions``), all float32, then the cast to ``compute_dtype``.
 
-    Where Mosaic compiles and the head has tiles (``_qk_on_kernels``) each
-    of the two is one pass of ``ops/head_norm_rope``'s kernel over its
-    projection's product where it lies, and one back; elsewhere the
+    Where Mosaic compiles, RoPE turns the layer and the head has tiles
+    (``_qk_on_kernels``) each of the two is one pass of
+    ``ops/head_norm_rope``'s kernel over its projection's product where it
+    lies, and one back, with the norm's step or without; elsewhere the
     ``jnp`` lines (``normed_turned_heads`` between a transposition and a
     cast).  SPC ``attn_qk_built`` counts both ways' q and k while steps
     are traced, ``attn_qk_kernel_built`` the kernels'.
@@ -165,6 +170,7 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
     b, s, _ = h.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     gated = p["wq"].shape[-1] == 2 * p["wo"].shape[0]
+    gains = [p.get(g) for g in ("q_norm", "k_norm")]
     on_kernels = _qk_on_kernels(interpret, cfg, turned, gated)
     spc.record("attn_qk_built", 2)
     side = lambda a, c: jnp.concatenate([a, c], -1).reshape(b * s, -1)
@@ -177,8 +183,8 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
         cos, sin = rope_tables(s, hd, cfg.rope_theta, positions)
         sin = signed_sin(sin)
         prods = [matmul(h, p[w], dt) for w in ("wq", "wk")]
-        q, k = (_kernel_heads(t, p[g], cos, sin, n, cfg.rms_norm_eps, dt)
-                for t, g, n in zip(prods, ("q_norm", "k_norm"), (nh, nkv)))
+        q, k = (_kernel_heads(t, g, cos, sin, n, cfg.rms_norm_eps, dt)
+                for t, g, n in zip(prods, gains, (nh, nkv)))
         # the first heads' products made again, from their own columns,
         # and the same kernel over them in float32, so that what a check
         # reads of the norm and RoPE is the kernel's arithmetic: a slice
@@ -186,17 +192,17 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
         # the slice and copy it for the kernel (3.2 ms a step of SDAR's
         # on the v5e, PR 65)
         ins = [matmul(h, p[w][:, :hd], dt) for w in ("wq", "wk")]
-        outs = [_kernel_heads(t, p[g], cos, sin, 1, cfg.rms_norm_eps,
+        outs = [_kernel_heads(t, g, cos, sin, 1, cfg.rms_norm_eps,
                               jnp.float32)[:, 0]
-                for t, g in zip(ins, ("q_norm", "k_norm"))]
+                for t, g in zip(ins, gains)]
     else:
         split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
         q_in, k_in = (split(matmul(h, p[w], dt), n)
                       for w, n in (("wq", nh), ("wk", nkv)))
         if gated:
             q_in, gate = jnp.split(q_in, 2, axis=-1)
-        q, k = (normed_turned_heads(t, p[g], cfg, turned, positions)
-                for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
+        q, k = (normed_turned_heads(t, g, cfg, turned, positions)
+                for t, g in zip((q_in, k_in), gains))
         ins, outs = (q_in[:, 0], k_in[:, 0]), (q[:, 0], k[:, 0])
     seen = {"attn_qk_in": side(*ins), "attn_qk": side(*outs)}
     return q.astype(dt), k.astype(dt), gate, seen
@@ -213,7 +219,7 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     group's shared head through their index maps and sum its query
     heads' gradients in float32.
 
-    Five models' sublayer, told apart by what the layer holds and, where
+    Six models' sublayer, told apart by what the layer holds and, where
     the leaves cannot say, by the entry that runs it (``kind``, its
     ``layer_types`` name, ``windowed`` and ``diffused``).  Without
     ``q_norm`` and outside ``layer_types`` (nemotron_h): no rotary
@@ -224,10 +230,13 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     ``wo`` is long (qwen3_next) holds a **gate** behind every head's
     query, RoPE turns the leading ``rotary_width`` entries only, and ``o *
     sigmoid(gate)``, in float32, goes to ``W_o``.  A ``layer_types`` layer
-    without ``q_norm`` (smallthinker): RoPE over the whole head by
-    ``rope_kinds``, and under ``windowed`` the last ``sliding_window`` keys
-    (``causal_flash_attention``'s ``window``).  Heads are ``head_width``
-    wide whatever the hidden width.  (Keye-VL-2.0's q, k and v share
+    without ``q_norm`` (smallthinker, ouro): RoPE over the whole head by
+    ``rope_kinds`` and no norm, and under ``windowed`` the last
+    ``sliding_window`` keys (``causal_flash_attention``'s ``window``).
+    With a norm or without, q and k of a ``layer_types`` layer are
+    ``normed_qk``'s: one Pallas pass each way where Mosaic compiles, the
+    layer is turned and a head has tiles, the ``jnp`` lines elsewhere.
+    Heads are ``head_width`` wide whatever the hidden width.  (Keye-VL-2.0's q, k and v share
     lfm2's form, but its sublayer is ``dsa.dsa_attention``.)  Under
     ``diffused`` (sdar_moe; lfm2's form) the ``s`` rows are a noisy copy
     of every sequence before its clean copy: RoPE turns both halves at
@@ -253,29 +262,19 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     turned = kind in cfg.rope_kinds
     # both copies of a sequence stand at its positions
     positions = jnp.tile(jnp.arange(s // 2), 2) if diffused else None
-    turn = (lambda t: rope(t, cfg.rope_theta, cfg.rotary_width, positions)) \
-        if turned else (lambda t: t)
     window = cfg.sliding_window if windowed else None
-    first = lambda a, c: jnp.concatenate(
-        [a[:, 0], c[:, 0]], -1).reshape(b * s, -1)
     split = lambda t, n: t.reshape(b, s, n, -1).transpose(0, 2, 1, 3)
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], cfg.rms_norm_eps)
-        if "q_norm" in p:
+        if "q_norm" in p or cfg.layer_types:
             q, k, gate, qk_seen = normed_qk(
                 p, h, cfg, interpret=interpret, turned=turned,
                 positions=positions)
-            if turned:
+            # a model without a QK-norm reports a layer that is not turned
+            # too: that it was left alone is what a check reads
+            if turned or "q_norm" not in p:
                 seen = qk_seen
             v = split(matmul(h, p["wv"], dt), nkv).astype(dt)
-        elif cfg.layer_types:
-            q_in, k_in, v = (split(matmul(h, p[w], dt), n) for w, n in (
-                ("wq", nh), ("wk", nkv), ("wv", nkv)))
-            q, k = turn(q_in), turn(k_in)
-            # reported of a layer that is not turned too: that it was left
-            # alone is what a check reads
-            seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
-            q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
         else:
             heads = lambda t, n: t.reshape(b, s, n, -1).transpose(
                 0, 2, 1, 3).astype(dt)

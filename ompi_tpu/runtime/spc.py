@@ -158,11 +158,11 @@ _COUNTERS = (
     # and went to the flash kernels, or their twins, unrepeated: the
     # second over the first is 1 for a grouped-query model, 0 for the rest
     "attn_built", "attn_shared_kv_built",
-    # the q and k arrays a per-head QK-norm's sublayer made for the flash
-    # kernels while steps were traced (parallel/attention.normed_qk: two a
-    # call), and those of them made on the Pallas kernels
-    # (ops/head_norm_rope: norm, RoPE, head split and cast in one pass):
-    # the second over the first
+    # the q and k arrays a ``layer_types`` model's attention sublayer made
+    # for the flash kernels while steps were traced, with a per-head
+    # QK-norm or without (parallel/attention.normed_qk: two a call), and
+    # those of them made on the Pallas kernels (ops/head_norm_rope: norm,
+    # RoPE, head split and cast in one pass): the second over the first
     "attn_qk_built", "attn_qk_kernel_built",
     # those of the passes made under a sliding window, the block pairs
     # the passes walk, and those full causal passes of their lengths

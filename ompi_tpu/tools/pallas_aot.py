@@ -325,26 +325,34 @@ def cases(mesh1d, mesh2d):
     # the first heads' own products through the forward kernel in float32
     # (what ``attn_qk`` reads of a step); and the kernels' other tile, a
     # head of two lane tiles (16 on 2 of 256), which no cell runs
-    def head_norm_rope(backward, heads=(32, 4), hd=128, dtype=bf16):
+    def head_norm_rope(backward, heads=(32, 4), hd=128, dtype=bf16,
+                       rows=(1, 16384), normed=True):
         from ompi_tpu.ops.head_norm_rope import signed_sin
         from ompi_tpu.parallel import attention
         from ompi_tpu.parallel.layers import rope_tables
 
-        def made(q, k, gq, gk):
+        def made(q, k, *gains):
             with jax.named_scope("otpu_attn_proj"):     # the sublayers'
-                cos, sin = rope_tables(16384, hd, 1e6)
+                cos, sin = rope_tables(rows[1], hd, 1e6)
                 return tuple(
                     attention._kernel_heads(t, g, cos, signed_sin(sin), n,
                                             1e-6, dtype)
-                    for t, g, n in zip((q, k), (gq, gk), heads))
+                    for t, g, n in zip((q, k), gains or (None, None), heads))
 
-        fn = made
-        if backward:
+        rep = lambda *s, dt=f32: _sds(s, dt, one, P())
+        fn, prods = made, tuple(rep(*rows, n * hd) for n in heads)
+        if backward and normed:
             fn = jax.grad(lambda *a: sum(
                 jnp.sum(t.astype(f32)) for t in made(*a)), (0, 1, 2, 3))
-        rep = lambda *s: _sds(s, f32, one, P())
-        return jax.jit(fn), (*(rep(1, 16384, n * hd) for n in heads),
-                             rep(hd), rep(hd))
+        elif backward:
+            # the turn's transpose reads nothing of q and k: the cotangents
+            # are the arguments the program is placed by
+            fn = lambda dq, dk: jax.vjp(made, *(
+                jnp.zeros(t.shape, f32) for t in prods))[1]((dq, dk))
+            return jax.jit(fn), tuple(
+                rep(rows[0], n, rows[1], hd, dt=dtype) for n in heads)
+        return jax.jit(fn), (*prods,
+                             *((rep(hd), rep(hd)) if normed else ()))
 
     case("sdar_head_norm_rope_forward", lambda: head_norm_rope(False))
     case("sdar_head_norm_rope_backward", lambda: head_norm_rope(True))
@@ -354,6 +362,22 @@ def cases(mesh1d, mesh2d):
          lambda: head_norm_rope(False, (16, 2), 256))
     case("sdar_head_norm_rope_256_backward",
          lambda: head_norm_rope(True, (16, 2), 256))
+    # the same way for a head that is turned and not normed (no gain: the
+    # backward reads the cotangent and the tables alone): SmallThinker's
+    # window layers, 28 on 4 x 16,384, and Ouro's sixteen applications, 16
+    # on 16 x (2, 4096); and the first heads' products in float32
+    small = dict(heads=(28, 4), normed=False)
+    ouro = dict(heads=(16, 16), rows=(2, 4096), normed=False)
+    case("smallthinker_head_rope_forward",
+         lambda: head_norm_rope(False, **small))
+    case("smallthinker_head_rope_backward",
+         lambda: head_norm_rope(True, **small))
+    case("smallthinker_head_rope_first_head", lambda: head_norm_rope(
+        False, **dict(small, heads=(1, 1), dtype=f32)))
+    case("ouro_head_rope_forward", lambda: head_norm_rope(False, **ouro))
+    case("ouro_head_rope_backward", lambda: head_norm_rope(True, **ouro))
+    case("ouro_head_rope_first_head", lambda: head_norm_rope(
+        False, **dict(ouro, heads=(1, 1), dtype=f32)))
     # Nemotron-3-Super's share: 4 query heads on 1 key-value head x
     # 8,192 at a head width of 128, the whole head axis one group
     case("nemotron3_flash_causal_forward",

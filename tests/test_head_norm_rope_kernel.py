@@ -1,7 +1,7 @@
-"""The Pallas kernels of a QK-normed head's way to the flash kernels
-(``ops/head_norm_rope``) in interpret mode against the ``jnp`` lines of
-``parallel/attention.normed_turned_heads`` and their autodiff, and which of
-the two ``attention.normed_qk`` builds where."""
+"""The Pallas kernels of a head's way to the flash kernels, with a per-head
+QK-norm and without (``ops/head_norm_rope``), in interpret mode against
+the ``jnp`` lines of ``parallel/attention.normed_turned_heads`` and their
+autodiff, and which of the two ``attention.normed_qk`` builds where."""
 import functools
 import types
 
@@ -21,10 +21,19 @@ ROWS, HD, EPS, THETA = 16, 128, 1e-6, 1e6
 #: on 4, one head, lengths a row tile does not divide, and a head of two
 #: lane tiles
 SHAPES = [(32, 24, HD), (4, 24, HD), (1, 40, HD), (4, 32, HD), (2, 24, 256)]
+#: (heads, positions, head width, batch, whether the head has a gain): the
+#: normed shapes, and a head that is turned and not normed: Ouro's 16 on
+#: 16 over two sequences, SmallThinker's 28 on 4, one head (what a check
+#: reads), whole tiles and lengths that end inside one
+CASES = [(*shape, 1, True) for shape in SHAPES] + [
+    (16, 32, HD, 2, False), (28, 24, HD, 1, False), (4, 40, HD, 1, False),
+    (1, 40, HD, 1, False)]
 SDAR = "benchmark/configs/sdar-30b-a3b-train-1chip.json"
 KEYE = "benchmark/configs/keye-vl2-30b-a3b-train-1chip.json"
 LFM2 = "benchmark/configs/lfm2-8b-a1b-train-1chip.json"
 QWEN3NEXT = "benchmark/configs/qwen3-next-80b-a3b-train-1chip.json"
+OURO = "benchmark/configs/ouro-2.6b-train-1chip.json"
+SMALLTHINKER = "benchmark/configs/smallthinker-21b-a3b-train-1chip.json"
 
 forward = functools.partial(hnr.heads_forward, eps=EPS, interpret=True)
 backward = functools.partial(hnr.heads_backward, eps=EPS, interpret=True)
@@ -39,21 +48,25 @@ def small_tiles(monkeypatch):
 
 def twin(x, gain, heads, dtype, positions=None):
     """The lines the kernels replace: the transposed heads, the norm, RoPE,
-    the cast."""
+    the cast; of a head without a gain ``layers.rope`` between the split
+    and the cast."""
     b, s, _ = x.shape
     cfg = types.SimpleNamespace(rms_norm_eps=EPS, rope_theta=THETA,
                                 rotary_width=None)
     t = x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+    if gain is None:
+        return rope(t, THETA, None, positions).astype(dtype)
     return attention.normed_turned_heads(t, gain, cfg, True,
                                          positions).astype(dtype)
 
 
-def inputs(seed, heads, s, b=1, hd=HD, positions=None):
+def inputs(seed, heads, s, b=1, hd=HD, positions=None, normed=True):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     cos, sin = rope_tables(s, hd, THETA, positions)
     return (jax.random.normal(ks[0], (b, s, heads * hd)),
-            1 + 0.2 * jax.random.normal(ks[1], (hd,)), cos,
-            hnr.signed_sin(sin), jax.random.normal(ks[2], (b, heads, s, hd)))
+            1 + 0.2 * jax.random.normal(ks[1], (hd,)) if normed else None,
+            cos, hnr.signed_sin(sin),
+            jax.random.normal(ks[2], (b, heads, s, hd)))
 
 
 def near(got, want, rel, what=""):
@@ -66,32 +79,39 @@ def near(got, want, rel, what=""):
 
 @pytest.mark.parametrize("dtype,rel", [(jnp.float32, 2e-6),
                                        (jnp.bfloat16, 8e-3)])
-@pytest.mark.parametrize("heads,s,hd", SHAPES)
-def test_the_forward_kernel_is_the_twin(heads, s, hd, dtype, rel):
+@pytest.mark.parametrize("heads,s,hd,b,normed", CASES)
+def test_the_forward_kernel_is_the_twin(heads, s, hd, b, normed, dtype, rel):
     """The product read where it lies, (b, s, heads x hd), leaves as (b,
     heads, s, hd) in the operand's dtype, at lengths that are whole row
-    tiles and that end inside one."""
-    x, gain, cos, sin, _ = inputs(heads + s, heads, s, hd=hd)
+    tiles and that end inside one, under the norm and without one."""
+    x, gain, cos, sin, _ = inputs(heads + s, heads, s, b, hd, normed=normed)
     got = forward(x, gain, cos, sin, heads=heads, dtype=dtype)
-    assert got.dtype == dtype and got.shape == (1, heads, s, hd)
+    assert got.dtype == dtype and got.shape == (b, heads, s, hd)
     near(got, twin(x, gain, heads, dtype), rel)
 
 
 @pytest.mark.parametrize("dtype,rel", [(jnp.float32, 4e-6),
                                        (jnp.bfloat16, 8e-3)])
-@pytest.mark.parametrize("heads,s,hd", SHAPES)
-def test_the_backward_kernel_is_autodiff_of_the_twin(heads, s, hd, dtype,
-                                                     rel):
+@pytest.mark.parametrize("heads,s,hd,b,normed", CASES)
+def test_the_backward_kernel_is_autodiff_of_the_twin(heads, s, hd, b,
+                                                     normed, dtype, rel):
     """The product's cotangent where the projection's transposes read it
     and the gain's gradient summed over rows and heads in float32, from
     the product, the gain and the cotangent alone; the cotangent comes in
-    the operand's dtype and the product's leaves in it."""
-    x, gain, cos, sin, do = inputs(3 * heads + s, heads, s, hd=hd)
+    the operand's dtype and the product's leaves in it.  Of a head without
+    a gain the cotangent and the tables are all that is read: no product
+    is handed in."""
+    x, gain, cos, sin, do = inputs(3 * heads + s, heads, s, b, hd,
+                                   normed=normed)
     do = do.astype(dtype)
     want = jax.vjp(lambda x, g: twin(x, g, heads, dtype), x, gain)[1](do)
-    dx, dg = backward(x, gain, cos, sin, do, dtype=dtype)
-    assert dx.dtype == dtype and dg.dtype == jnp.float32
+    dx, dg = backward(x if normed else None, gain, cos, sin, do, dtype=dtype)
+    assert dx.dtype == dtype
     near(dx, want[0], rel, "dx")
+    if not normed:
+        assert dg is None
+        return
+    assert dg.dtype == jnp.float32
     near(dg, want[1], 1e-5 if dtype == jnp.float32 else rel, "dgain")
 
 
@@ -147,8 +167,8 @@ def test_the_tiles_are_what_the_module_says(monkeypatch):
 
 
 def layer(config, seed=0, **widths):
-    """(cfg, a QK-normed sublayer's projections and gains, its normed
-    input) at small widths."""
+    """(cfg, a sublayer's projections and, where its model has a QK-norm,
+    gains, its normed input) at small widths."""
     cfg = train.load_model_config(
         config, hidden_size=64, seq_len=32, micro_batch=1, attn_block=16,
         loss_block_rows=16, vocab_size=256, vocab_here=64,
@@ -164,7 +184,9 @@ def layer(config, seed=0, **widths):
 
 def old_lines(p, h, cfg, turned=True, positions=None):
     """q, k, the gate and what is seen as ``gqa_attention`` and
-    ``dsa_attention`` each wrote them until PR 65."""
+    ``dsa_attention`` each wrote them until PR 65, and as
+    ``gqa_attention``'s branch for a ``layer_types`` model without a
+    QK-norm wrote them until PR 68 (the same lines less the norm)."""
     b, s, _ = h.shape
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     mm, gate = attention.matmul, None
@@ -177,7 +199,9 @@ def old_lines(p, h, cfg, turned=True, positions=None):
                   for w, n in (("wq", nh), ("wk", nkv)))
     if p["wq"].shape[-1] == 2 * p["wo"].shape[0]:
         q_in, gate = jnp.split(q_in, 2, axis=-1)
-    q, k = (turn(rmsnorm_gain(t, p[g], cfg.rms_norm_eps))
+    norm = (lambda t, g: rmsnorm_gain(t, p[g], cfg.rms_norm_eps)) \
+        if "q_norm" in p else (lambda t, g: t)
+    q, k = (turn(norm(t, g))
             for t, g in ((q_in, "q_norm"), (k_in, "k_norm")))
     seen = {"attn_qk_in": first(q_in, k_in), "attn_qk": first(q, k)}
     return q.astype(dt), k.astype(dt), gate, seen
@@ -185,18 +209,32 @@ def old_lines(p, h, cfg, turned=True, positions=None):
 
 SMALL = dict(head_dim=16, num_attention_heads=4, num_key_value_heads=2)
 TILED = dict(head_dim=128, num_attention_heads=2, num_key_value_heads=1)
-#: (configuration file, widths, the step's interpret, on the kernels)
+#: (configuration file, widths, the step's interpret, whether RoPE turns
+#: the layer, on the kernels)
 WHERE = [
-    (SDAR, dict(TILED, mask_token_here=63), True, False),    # the CPU's
-    (SDAR, dict(SMALL, mask_token_here=63), False, False),   # no tile
+    (SDAR, dict(TILED, mask_token_here=63), True, True, False),  # the CPU's
+    (SDAR, dict(SMALL, mask_token_here=63), False, True, False),  # no tile
     (LFM2, dict(head_dim=64, num_attention_heads=4,
-                num_key_value_heads=2), False, False),       # two a tile
+                num_key_value_heads=2), False, True, False),  # two a tile
     (QWEN3NEXT, dict(head_dim=256, num_attention_heads=2,
-                     num_key_value_heads=1), False, False),  # gate, quarter
-    (SDAR, dict(TILED, mask_token_here=63), False, True),
-    (KEYE, TILED, False, True)]
+                     num_key_value_heads=1), False, True, False),  # gated
+    (SDAR, dict(TILED, mask_token_here=63), False, True, True),
+    (KEYE, TILED, False, True, True),
+    # a head that is turned and not normed: Ouro's every layer,
+    # SmallThinker's window layers; its full layer is not turned
+    (OURO, TILED, True, True, False),
+    (OURO, SMALL, False, True, False),
+    (SMALLTHINKER, TILED, True, True, False),
+    (SMALLTHINKER, TILED, True, False, False),
+    (SMALLTHINKER, TILED, False, False, False),
+    (OURO, TILED, False, True, True),
+    (SMALLTHINKER, TILED, False, True, True)]
 IDS = ["tiles-cpu", "no-tiles-tpu", "lfm2-64-tpu", "qwen3next-gated-tpu",
-       "sdar-tpu", "keye-tpu"]
+       "sdar-tpu", "keye-tpu", "ouro-cpu", "ouro-no-tiles-tpu",
+       "smallthinker-window-cpu", "smallthinker-full-cpu",
+       "smallthinker-full-tpu", "ouro-tpu", "smallthinker-window-tpu"]
+#: the rows of ``WHERE`` that take the lines
+LINES = [n for n, where in enumerate(WHERE) if not where[-1]]
 
 
 def _equations(jaxpr):
@@ -207,61 +245,76 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-@pytest.mark.parametrize("config,widths,interpret,on", WHERE, ids=IDS)
-def test_which_way_is_built_and_counted(config, widths, interpret, on):
+@pytest.mark.parametrize("config,widths,interpret,turned,on", WHERE,
+                         ids=IDS)
+def test_which_way_is_built_and_counted(config, widths, interpret, turned,
+                                        on):
     """``normed_qk`` takes ``interpret`` from the step: on the CPU, at a
-    head that is no whole tile, at LFM2's heads of 64 and at Qwen3-Next's
-    gated, quarter-turned head of 256 the program holds the ``jnp`` lines;
-    where Mosaic compiles and a head of 128 is turned whole it holds one
-    kernel for q and one for k each way.  The two SPC counters read what
+    head that is no whole tile, at LFM2's heads of 64, at Qwen3-Next's
+    gated, quarter-turned head of 256 and in a layer RoPE does not turn
+    (SmallThinker's full layer) the program holds the ``jnp`` lines; where
+    Mosaic compiles and a head of 128 is turned whole it holds one kernel
+    for q and one for k each way, the normed pair where the layer holds
+    gains (SDAR, Keye) and the pair without a norm where it holds none
+    (Ouro, SmallThinker's window layers).  The two SPC counters read what
     was built."""
     spc.init()
     cfg, p, h = layer(config, **widths)
     before = (spc.read("attn_qk_built"), spc.read("attn_qk_kernel_built"))
     way = functools.partial(attention.normed_qk, cfg=cfg,
-                            interpret=interpret)
+                            interpret=interpret, turned=turned)
     fwd = jax.make_jaxpr(way)(p, h)
     both = jax.make_jaxpr(jax.grad(
         lambda p, h: sum(jnp.sum(a) for a in way(p, h)[:2]), (0, 1)))(p, h)
     kernels = lambda jaxpr: sorted(
         eqn.params["name"] for eqn in _equations(jaxpr.jaxpr)
         if eqn.primitive.name == "pallas_call")
+    name = "otpu_head_norm_rope" if "q_norm" in p else "otpu_head_rope"
     # forward q's, k's and, for ``attn_qk``, the first head's of each
-    assert kernels(fwd) == ["otpu_head_norm_rope_fwd"] * (4 * on)
-    assert kernels(both) == ["otpu_head_norm_rope_bwd"] * (2 * on) \
-        + ["otpu_head_norm_rope_fwd"] * (4 * on)
+    assert kernels(fwd) == [name + "_fwd"] * (4 * on)
+    assert kernels(both) == [name + "_bwd"] * (2 * on) \
+        + [name + "_fwd"] * (4 * on)
     built = spc.read("attn_qk_built") - before[0]
     assert built >= 4 and built % 2 == 0
     assert spc.read("attn_qk_kernel_built") - before[1] == built * on
 
 
-@pytest.mark.parametrize("config,widths,interpret,on", WHERE[:4],
-                         ids=IDS[:4])
+@pytest.mark.parametrize("config,widths,interpret,turned,on",
+                         [WHERE[n] for n in LINES],
+                         ids=[IDS[n] for n in LINES])
 def test_every_other_shape_takes_the_lines_bit_for_bit(config, widths,
-                                                       interpret, on):
-    """q, k, the gate and what a check reads are what the two sublayers'
-    own lines made until PR 65, to the bit, on the CPU and where a TPU's
-    step meets a shape the kernels have no tile for."""
+                                                       interpret, turned,
+                                                       on):
+    """q, k, the gate and what a check reads are what the sublayers' own
+    lines made until PR 65, and until PR 68 of a model without a QK-norm,
+    to the bit: on the CPU, where a TPU's step meets a shape the kernels
+    have no tile for, and in a layer that is not turned."""
     cfg, p, h = layer(config, **widths)
-    got = attention.normed_qk(p, h, cfg, interpret=interpret)
-    want = old_lines(p, h, cfg)
+    got = attention.normed_qk(p, h, cfg, interpret=interpret, turned=turned)
+    want = old_lines(p, h, cfg, turned)
     assert (got[2] is None) == (want[2] is None) == (config != QWEN3NEXT)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if not turned:      # left alone: what a check reads of such a layer
+        np.testing.assert_array_equal(
+            np.asarray(got[3]["attn_qk"]), np.asarray(got[3]["attn_qk_in"]))
 
 
-@pytest.mark.parametrize("diffused", [False, True],
-                         ids=["keye", "sdar-diffused"])
-def test_q_and_k_on_the_kernels_are_q_and_k(diffused, monkeypatch):
+@pytest.mark.parametrize("where,diffused", [
+    ("keye-tpu", False), ("sdar-tpu", True), ("ouro-tpu", False),
+    ("smallthinker-window-tpu", False)],
+    ids=["keye", "sdar-diffused", "ouro", "smallthinker-window"])
+def test_q_and_k_on_the_kernels_are_q_and_k(where, diffused, monkeypatch):
     """``normed_qk`` on the kernels (interpreted here, which takes the
     kernels being told so) gives the lines' q and k, the same
     ``attn_qk_in`` / ``attn_qk`` of the first query and key-value head
     (their products made again and the forward kernel over them), and the
-    same gradient of both projections, both gains and the input."""
+    same gradient of both projections, both gains where the layer holds
+    them, and the input."""
     for name in ("heads_forward", "heads_backward"):
         monkeypatch.setattr(hnr, name, functools.partial(
             getattr(hnr, name), interpret=True))
-    config, widths = WHERE[4 if diffused else 5][:2]
+    config, widths = WHERE[IDS.index(where)][:2]
     cfg, p, h = layer(config, **widths)
     positions = jnp.tile(jnp.arange(16), 2) if diffused else None
     weights = [jax.random.normal(jax.random.PRNGKey(n), (1, heads, 32, HD))
@@ -283,17 +336,20 @@ def test_q_and_k_on_the_kernels_are_q_and_k(diffused, monkeypatch):
     assert sorted(got[2]) == sorted(want[2]) == ["attn_qk", "attn_qk_in"]
     for name in got[2]:
         near(got[2][name], want[2][name], 2e-6, name)
-    for name in ("wq", "wk", "q_norm", "k_norm"):
+    assert ("q_norm" in p) == (where in ("keye-tpu", "sdar-tpu"))
+    for name in sorted(set(p) & {"wq", "wk", "q_norm", "k_norm"}):
         near(grads[0][name], want_grads[0][name], 2e-5, name)
     near(grads[1], want_grads[1], 2e-5, "dh")
 
 
-def test_what_a_check_reads_is_the_kernels_own(monkeypatch):
+@pytest.mark.parametrize("config", [KEYE, OURO, SMALLTHINKER],
+                         ids=["keye", "ouro", "smallthinker-window"])
+def test_what_a_check_reads_is_the_kernels_own(config, monkeypatch):
     """``attn_qk`` on the kernels is the forward kernel's output, not the
     lines': a kernel that made something else of a head shows there, next
     to an ``attn_qk_in`` that stays the product."""
     real = functools.partial(hnr.heads_forward, interpret=True)
-    cfg, p, h = layer(KEYE, **TILED)
+    cfg, p, h = layer(config, **TILED)
     seen = {}
     for name, fn in (("right", real),
                      ("wrong", lambda *a, **k: 2 * real(*a, **k))):

@@ -259,6 +259,12 @@ def test_smallthinker_train_step_aot_compiles_from_the_cells_configuration(
         == [(False, True), (True, False)], forward
     backward = [p for p in kernels if "/otpu_attn_block_backward/" in p]
     assert {"otpu_swa" in p for p in backward} == {True, False}
+    # the window layers' q and k, turned and not normed, reach the flash
+    # kernels through ``ops/head_norm_rope``'s pair without a gain (PR 68),
+    # in the window run's body alone: the full layer is not turned, and
+    # the lines are its way
+    _holds_the_head_norm_rope_kernels(kernels, "otpu_swa", "otpu_head_rope")
+    assert not [p for p in kernels if "otpu_head_norm_rope" in p]
 
 
 @pytest.fixture(scope="module")
@@ -340,23 +346,24 @@ def test_keye_train_step_aot_compiles_from_the_cells_configuration(keye_rows):
         line[:300] for line in square if "otpu_stats" not in line][:5]
 
 
-def _holds_the_head_norm_rope_kernels(kernels, operator):
-    """Of a step's kernel paths: ``otpu_head_norm_rope_fwd`` for q, for k
-    and for the first head of each (``attn_qk``) in the forward pass, for
-    q and k again in the recomputed one, which no check reads, ``_bwd``
-    for q and k in the backward pass, all under ``operator``'s
-    ``otpu_attn_proj``."""
-    found = {name: [p for p in kernels if f"/{name}/" in p]
-             for name in ("otpu_head_norm_rope_fwd",
-                          "otpu_head_norm_rope_bwd")}
-    for name, paths in found.items():
+def _holds_the_head_norm_rope_kernels(kernels, operator,
+                                      name="otpu_head_norm_rope"):
+    """Of a step's kernel paths: ``<name>_fwd`` for q, for k and for the
+    first head of each (``attn_qk``) in the forward pass, for q and k
+    again in the recomputed one, which no check reads, ``_bwd`` for q and
+    k in the backward pass, all under ``operator``'s ``otpu_attn_proj``;
+    ``name`` is the normed pair's, or ``otpu_head_rope`` for a head that
+    is turned and not normed."""
+    found = {kernel: [p for p in kernels if f"/{kernel}/" in p]
+             for kernel in (name + "_fwd", name + "_bwd")}
+    for paths in found.values():
         assert all(f"{operator}/otpu_attn_proj" in p for p in paths), paths
-    fwd = found["otpu_head_norm_rope_fwd"]
+    fwd = found[name + "_fwd"]
     assert sorted("rematted_computation" in p for p in fwd) == [
         False, False, False, False, True, True], fwd
     assert sorted(p.startswith("transpose(") for p in fwd) == [
         False, False, False, False, True, True], fwd
-    bwd = found["otpu_head_norm_rope_bwd"]
+    bwd = found[name + "_bwd"]
     assert len(bwd) == 2 and all(
         p.startswith("transpose(") and "rematted_computation" not in p
         for p in bwd), bwd
@@ -364,8 +371,9 @@ def _holds_the_head_norm_rope_kernels(kernels, operator):
 
 @pytest.fixture(scope="module")
 def ouro_rows():
-    """One child for Ouro-2.6B's case: the whole looped step of the cell's
-    own configuration file, for one v5e device (about 15 s of the 600)."""
+    """One child for Ouro-2.6B's cases: the whole looped step of the cell's
+    own configuration file and q's and k's way to the flash kernels at
+    its shape, for one v5e device (about 20 s of the 600)."""
     return rows_with_texts("ouro_")
 
 
@@ -378,8 +386,10 @@ def test_ouro_train_step_aot_compiles_from_the_cells_configuration(
     two moments); the passes and the layers are loops, forward and
     backward; both flash kernels stand under ``otpu_loop_pass`` in the pass
     they belong to and in no recomputed one (the checkpoint keeps o and the
-    logsumexp); no router, no grouped matmul and no other model's kernel
-    is in it."""
+    logsumexp); q and k reach them through ``ops/head_norm_rope``'s pair
+    without a gain (PR 68: one call each in the forward and the recomputed
+    pass, one each back, and the first heads' in the forward pass); no
+    router, no grouped matmul and no other model's kernel is in it."""
     row = ouro_rows["ouro_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["while"] >= 3
@@ -399,6 +409,10 @@ def test_ouro_train_step_aot_compiles_from_the_cells_configuration(
     assert not [p for p in kernels if "otpu_gmm" in p or "otpu_moe" in p
                 or "otpu_row_scatter" in p or "otpu_head_norm_rope" in p
                 or "_bd_" in p]
+    _holds_the_head_norm_rope_kernels(kernels, "otpu_attention",
+                                      "otpu_head_rope")
+    assert all("otpu_loop_pass/otpu_layers" in p for p in kernels
+               if "otpu_head_rope" in p)
     paths = [path for _, path in op_paths(row)]
     for scope in ("otpu_exit_gate", "otpu_exit_loss", "otpu_head"):
         assert any(scope in p for p in paths), scope
@@ -475,6 +489,42 @@ def test_the_head_norm_rope_kernels_aot_compile_at_the_cells_shape(
     assert not re.search(r"\[1,16384,(%s),%d\]" % (heads, hd), text)
     if way.endswith("backward"):
         assert "bf16[1,16384,4096]" in text and f"f32[8,{hd}]" in text
+
+
+@pytest.mark.parametrize("way,kernel", [
+    ("forward", "otpu_head_rope_fwd"), ("backward", "otpu_head_rope_bwd"),
+    ("first_head", "otpu_head_rope_fwd")])
+@pytest.mark.parametrize("rows,cell,b,s,heads", [
+    ("smallthinker_rows", "smallthinker", 1, 16384, (28, 4)),
+    ("ouro_rows", "ouro", 2, 4096, (16, 16))])
+def test_the_head_rope_kernels_aot_compile_at_the_cells_shape(
+        rows, cell, b, s, heads, way, kernel, request):
+    """The same way for a head that is turned and not normed (PR 68):
+    SmallThinker's window layers, 28 on 4 x 16,384, and Ouro's sixteen
+    applications, 16 on 16 x (2, 4096).  One Mosaic call each for q and
+    for k, no copy, transposition or array of (b, s, n, 128) beside them
+    and no float32 array of the heads' shape; the backward's arguments are
+    the cotangents alone (the turn is linear: no product is kept), it
+    writes the products' cotangents in bfloat16 where they lie and holds
+    no gain's sums; the first heads' own products leave in float32."""
+    row = request.getfixturevalue(rows)[f"{cell}_head_rope_{way}"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert not {"copy", "transpose"} & set(row["entry_ops"])
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert sorted(kernel_bodies(text, "otpu_head_")) == [kernel]
+    if way == "first_head":
+        assert f"f32[{b},1,{s},128]" in text and "bf16[" not in text
+        return
+    either = "|".join(map(str, sorted(set(heads))))
+    assert not re.search(r"f32\[%d,(%s),%d,128\]" % (b, either, s), text)
+    assert not re.search(r"\[%d,%d,(%s),128\]" % (b, s, either), text)
+    if way == "backward":
+        entry = text[text.index("ENTRY"):].split("\n", 1)[0]
+        assert re.findall(r"(\w+)\[", entry.split("->")[0]) == ["bf16"] * 2
+        assert f"bf16[{b},{s},{heads[0] * 128}]" in text
+        assert "f32[8,128]" not in text
 
 
 def test_sdar_train_step_aot_compiles_from_the_cells_configuration(
