@@ -383,10 +383,10 @@ def _tile_flags(select, tile: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "window",
-                                             "bd"))
+                                             "bd", "scale"))
 def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
                         block: int, interpret=None, window=None,
-                        select=None, bd=None):
+                        select=None, bd=None, scale=None):
     """One block pair of causal attention's flash backward, fused: q
     block ``ij[0]`` against kv block ``ij[1]`` (``block`` positions
     each; the pair whose two are equal is masked by position), the
@@ -416,13 +416,15 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
     a pair that selects nothing hands the accumulators on
     (``_bwd_select_kernel``).  With ``bd`` (block diffusion's block
     length; static) ``ij`` holds three entries, one of ``bd_pairs``'
-    triples, and the pair is masked as its kind says.
+    triples, and the pair is masked as its kind says.  ``scale`` (static;
+    None: ``1 / sqrt(d)``) is the scores' own.
     """
     if interpret is None:
         interpret = pallas_interpret()
     b, h, s, d = q.shape
     hv = v.shape[-1]
     bh = b * h
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     rep = _heads_a_group(q, k)
     far_by = _window_blocks(window, block, s)
     tq = _tile(block, BWD_TILE)
@@ -445,9 +447,9 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
         return _select_block_backward(
             operands, select, (q_spec, kv_spec, row_spec, acc_specs),
             (b, h, s, d, hv, rep, tq, nt, block), vma, interpret,
-            (dq, dk, dv))
+            (dq, dk, dv), scale)
     out = pl.pallas_call(
-        functools.partial(_bwd_block_kernel, 1.0 / math.sqrt(d),
+        functools.partial(_bwd_block_kernel, scale,
                           _tile(tq, BWD_STRIP), rep, far_by,
                           bd=None if bd is None else (bd, s // block // 2)),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
@@ -471,7 +473,7 @@ def attn_block_backward(ij, q, k, v, do, lse, delta, dq, dk, dv, *,
 
 
 def _select_block_backward(operands, select, specs, dims, vma, interpret,
-                           like):
+                           like, scale):
     """``attn_block_backward``'s call under a selection: one more scalar
     operand (the pairs' flags) and one more input (the pair's block of the
     packed selection)."""
@@ -485,7 +487,7 @@ def _select_block_backward(operands, select, specs, dims, vma, interpret,
         g // h, ij[1] // share, ij[0] * nt + t))
     ins = [q_spec(d), kv_spec(d), kv_spec(hv), q_spec(hv), row_spec, row_spec]
     out = pl.pallas_call(
-        functools.partial(_bwd_select_kernel, 1.0 / math.sqrt(d), rep, h,
+        functools.partial(_bwd_select_kernel, scale, rep, h,
                           s // block, lanes),
         out_shape=tuple(jax.ShapeDtypeStruct(o.shape, jnp.float32, vma=vma)
                         for o in operands[7:]),
@@ -699,9 +701,9 @@ def _bd_table(nt: int, tile: int, bl: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "window",
-                                             "bd"))
+                                             "bd", "scale"))
 def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
-                         select=None, bd=None):
+                         select=None, bd=None, scale=None):
     """Causal attention's forward pass in one call: ``o`` (b, h, s, dv)
     float32 and the logsumexp (b, h, s) float32 of q (b, h, s, d), k
     (b, n_kv, s, d) and v (b, n_kv, s, dv), ``h`` a multiple of ``n_kv``
@@ -712,8 +714,9 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     tiles, of the key-value head the query head's group shares, a kv
     tile above the diagonal clamped to the diagonal's (the one already
     there: not fetched again).  Scores, softmax state and
-    ``o`` in float32, ``p`` cast to v's dtype for ``p v``, scale
-    ``1 / sqrt(d)``; the diagonal tile is masked by position.  A width
+    ``o`` in float32, ``p`` cast to v's dtype for ``p v``, the scores'
+    scale ``1 / sqrt(d)`` unless ``scale`` (static) gives one; the diagonal
+    tile is masked by position.  A width
     that is no multiple of 128 lanes (192) is Mosaic's to lay out.  The
     logsumexp leaves as (b x h, 1, s), the shape ``attn_block_backward``
     reads.  The ``jnp`` twin is ``parallel/causal._causal_fwd_blocks``.
@@ -738,18 +741,18 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
     rep = _heads_a_group(q, k)
     tile = _tile(block, FWD_TILE)
     nt = s // tile
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
 
     flat = lambda a: a.reshape(-1, s, a.shape[-1])
     q_map = lambda g, i, j: (g, i, 0)
     kv_map = lambda g, i, j: (_group(g, rep), jnp.minimum(i, j), 0)
-    kernel, reach = functools.partial(_causal_fwd_kernel,
-                                      1.0 / math.sqrt(d)), nt
+    kernel, reach = functools.partial(_causal_fwd_kernel, scale), nt
     w = _window_blocks(window, tile, s)
     if w is not None:
         kv_map = lambda g, i, n: (_group(g, rep), jnp.maximum(i - w + n, 0),
                                   0)
-        kernel, reach = functools.partial(_window_fwd_kernel,
-                                          1.0 / math.sqrt(d), w), w + 1
+        kernel, reach = functools.partial(_window_fwd_kernel, scale,
+                                          w), w + 1
     kv_spec = lambda width: pl.BlockSpec((1, tile, width), kv_map)
     operands = [flat(q), flat(k), flat(v)]
     vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
@@ -760,8 +763,7 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
                                           0)
         q_map = lambda g, i, n, kv, kd: (g, i, 0)
         o, lse = pl.pallas_call(
-            functools.partial(_bd_fwd_kernel, 1.0 / math.sqrt(d), bd,
-                              nt // 2),
+            functools.partial(_bd_fwd_kernel, scale, bd, nt // 2),
             out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
                        jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -787,8 +789,7 @@ def flash_causal_forward(q, k, v, *, block: int, interpret=None, window=None,
         vma = vma | jax.typeof(select).vma
         lanes, nbytes, share = _select_blocks(s, tile)
         o, lse = pl.pallas_call(
-            functools.partial(_select_fwd_kernel, 1.0 / math.sqrt(d), h,
-                              lanes),
+            functools.partial(_select_fwd_kernel, scale, h, lanes),
             out_shape=(jax.ShapeDtypeStruct((bh, s, hv), f32, vma=vma),
                        jax.ShapeDtypeStruct((bh, 1, s), f32, vma=vma)),
             grid_spec=pltpu.PrefetchScalarGridSpec(

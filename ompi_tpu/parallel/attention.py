@@ -14,7 +14,9 @@ import jax.numpy as jnp
 
 from ompi_tpu.parallel.causal import (ATTN_KEEPS,
                                       block_diffusion_flash_attention,
-                                      causal_flash_attention)
+                                      causal_flash_attention,
+                                      document_selection,
+                                      selected_flash_attention)
 from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
                                       rope, rope_tables)
 from ompi_tpu.parallel.sublayer import Sublayer
@@ -209,7 +211,7 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
 
 
 def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
-                  windowed: bool = False, diffused: bool = False):
+                  windowed: bool = False, diffused: bool = False, doc=None):
     """Grouped-query attention, **without** the residual add, on the
     residual stream ``x`` (b, s, d) float32: pre-norm; q, k, v, o
     projections without bias; the ``n_heads_here`` query heads held here
@@ -243,7 +245,14 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
     positions ``0 .. s / 2 - 1`` and attention goes under block diffusion's
     mask in blocks of ``block_length``
     (``block_diffusion_flash_attention``: a noisy row sees its own block's
-    noisy rows and every earlier block's clean ones).
+    noisy rows and every earlier block's clean ones).  The scores' scale
+    is ``cfg.attention_scale`` where the file gives one (granitemoehybrid's
+    ``attention_multiplier``: 1 / 64 at a head of 64, not 1 / 8), handed
+    to the kernels and their twins; under ``doc`` (b, s) int32, a packed
+    row's documents (granitemoehybrid's ``cu_seq_lens``), a query sees the
+    earlier keys of its own document alone: the mask goes to
+    ``selected_flash_attention`` packed eight keys a byte
+    (``document_selection``, made here under the sublayer's scope).
 
     Returns (the sublayer's output, no statistics, by token row what
     ``_gqa_reports`` lists: the first query head and the first key-value
@@ -284,9 +293,13 @@ def gqa_attention(p, x, cfg, *, interpret: bool, at=None, kind: str = "",
         o = block_diffusion_flash_attention(
             q, k, v, min(cfg.attn_block, s // 2), interpret,
             cfg.block_length)
+    elif doc is not None:
+        o, _ = selected_flash_attention(
+            q, k, v, document_selection(doc), min(cfg.attn_block, s),
+            interpret, None, cfg.attention_scale)
     else:
         o = causal_flash_attention(q, k, v, min(cfg.attn_block, s),
-                                   interpret, window)
+                                   interpret, window, cfg.attention_scale)
     if window is not None or diffused:
         rows = lambda t: t[:, 0].reshape(b * s, -1).astype(jnp.float32)
         names = ("bd_q", "bd_k_seq", "bd_v_seq", "bd_o") if diffused else (

@@ -110,7 +110,7 @@ def _bd_bias(i, j, block: int, rep: int, bl: int, half: int):
 
 
 def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None,
-                       bd=None):
+                       bd=None, scale=None):
     """Causal attention's forward pass: (o float32, logsumexp float32)
     of q (b, h, s, hd), k (b, n_kv, s, hd) and v (b, n_kv, s, hv): each
     key-value head is read by ``h / n_kv`` consecutive query heads, and
@@ -134,22 +134,26 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None,
     mask by position, and any row may see nothing of any block.  Under
     ``bd`` (block diffusion's block length: the rows are a noisy half
     before a clean one) q block i meets the kv blocks ``bd_pairs`` lists
-    for it, each under ``_bd_bias``."""
+    for it, each under ``_bd_bias``.  ``scale`` (static; None: ``1 /
+    sqrt(hd)``, and every call is the one without the argument) is the
+    scores' own, handed to the kernel or multiplied in here."""
     w = _window_in_blocks(window, block, q.shape[2])
     if not interpret:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
         if bd is not None:
             return flash_causal_forward(q, k, v, block=block,
-                                        interpret=False, bd=bd)
+                                        interpret=False, bd=bd, scale=scale)
         if select is not None:
             return flash_causal_forward(q, k, v, block=block,
-                                        interpret=False, select=select)
+                                        interpret=False, select=select,
+                                        scale=scale)
         return flash_causal_forward(q, k, v, block=block, interpret=False,
-                                    window=None if w is None else window)
+                                    window=None if w is None else window,
+                                    scale=scale)
     h, s, hd = q.shape[1:]
     nb = s // block
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     bias = _group_bias(block, h // k.shape[1])
     qb = _group_blocks(q, k.shape[1], block)
     outs, lses = [], []
@@ -200,9 +204,9 @@ ATTN_LSE = "otpu_attn_lse"
 ATTN_KEEPS = (ATTN_OUT, ATTN_LSE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def causal_flash_attention(q, k, v, block: int, interpret: bool,
-                           window=None):
+                           window=None, scale=None):
     """Causal self-attention of q (b, h, s, hd), k (b, n_kv, s, hd) and
     v (b, n_kv, s, hv) whose length is a multiple of ``block``: k and v
     come with the model's own key-value heads, each shared by ``h /
@@ -222,8 +226,11 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool,
     window can reach and no other (``_window_pairs``), the far pair under
     its own mask; a window that covers the sequence is None, bit for
     bit.  With None every branch, grid and kernel is what it was before
-    the argument."""
-    return _causal_fwd_blocks(q, k, v, block, interpret, window)[0]
+    the argument.  ``scale`` (static; None: ``1 / sqrt(hd)``) is the
+    scores' scale where a model gives its own (granitemoehybrid's
+    ``attention_multiplier``), in both passes."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, window,
+                              scale=scale)[0]
 
 
 def _count_built(q, k, block, window, bd=None) -> None:
@@ -254,9 +261,10 @@ def _count_built(q, k, block, window, bd=None) -> None:
         spc.record("bd_pairs_causal", b * rows * (rows + 1) // 2)
 
 
-def _causal_fwd(q, k, v, block, interpret, window=None):
+def _causal_fwd(q, k, v, block, interpret, window=None, scale=None):
     _count_built(q, k, block, window)
-    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, window)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, window,
+                                scale=scale)
     o = checkpoint_name(o, ATTN_OUT)
     lse = checkpoint_name(lse, ATTN_LSE)
     return o, (q, k, v, o, lse)
@@ -287,7 +295,8 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
             contract("bhqk,bhqd->bhkd", ds, qi, dt), dv)
 
 
-def _causal_bwd(block, interpret, window, res, do, select=None, bd=None):
+def _causal_bwd(block, interpret, window, res, do, select=None, bd=None,
+                scale=None):
     q, k, v, o, lse = res
     _count_built(q, k, block, window, bd)
     h, n_kv = q.shape[1], k.shape[1]
@@ -297,12 +306,12 @@ def _causal_bwd(block, interpret, window, res, do, select=None, bd=None):
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
     if not interpret:
         return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select,
-                                 bd)
+                                 bd, scale)
     if nb > UNROLLED_BLOCKS:
         return _causal_bwd_scanned(q, k, v, do, lse, delta, block, w,
-                                   select, bd)
+                                   select, bd, scale)
     dt = q.dtype
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     bias = _group_bias(block, h // n_kv)
     far = None if w is None else _far_bias(block, h // n_kv)
     qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
@@ -334,13 +343,13 @@ def _walked_pairs(nb: int, block: int, w, bd) -> list:
 
 
 def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None,
-                        bd=None):
+                        bd=None, scale=None):
     """The same pairs in the same order (q block by q block, kv blocks
     ascending), one a step of a ``lax.scan`` over float32 accumulators."""
     dt = q.dtype
     h, n_kv = q.shape[1], k.shape[1]
     nb = q.shape[2] // block
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     tri = _group_bias(block, h // n_kv)
     qb, dob, lseb, deltab = (_group_blocks(a, n_kv, block)
                              for a in (q, do, lse, delta))
@@ -374,7 +383,7 @@ def _causal_bwd_scanned(q, k, v, do, lse, delta, block, w=None, select=None,
 
 
 def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None,
-                      bd=None):
+                      bd=None, scale=None):
     """The same pairs in the same order, each one call of the fused
     Pallas kernel (``ops/flash_attention.attn_block_backward``, whose
     ``jnp`` twin is ``_bwd_pair``): a pair's scores never leave VMEM,
@@ -396,15 +405,16 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None,
         select = (jnp.swapaxes(select, 1, 2), _tile_flags(select, block))
         pair = lambda acc, ij: attn_block_backward(
             ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
-            select=select)
+            select=select, scale=scale)
     elif bd is not None:
         pair = lambda acc, ij: attn_block_backward(
             ij, q, k, v, do, lse, delta, *acc, block=block, interpret=False,
-            bd=bd)
+            bd=bd, scale=scale)
     else:
         pair = lambda acc, ij: attn_block_backward(
             ij, q, k, v, do, lse, delta, *acc, block=block,
-            interpret=False, window=None if w is None else w * block)
+            interpret=False, window=None if w is None else w * block,
+            scale=scale)
     pairs = _window_pairs(nb, w) if bd is None else bd_pairs(nb, block, bd)
     acc = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
     vma = tuple(frozenset().union(*(jax.typeof(a).vma
@@ -420,7 +430,11 @@ def _causal_bwd_fused(q, k, v, do, lse, delta, block, w=None, select=None,
     return tuple(a.astype(dt) for a in acc)
 
 
-causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
+def _causal_bwd_rule(block, interpret, window, scale, res, do):
+    return _causal_bwd(block, interpret, window, res, do, scale=scale)
+
+
+causal_flash_attention.defvjp(_causal_fwd, _causal_bwd_rule)
 
 
 # -- learned sparse attention (DeepSeek-V3.2's DSA) ---------------------------
@@ -431,7 +445,11 @@ def _count_dsa(q, topk: int) -> None:
     those passes attend to, ``min(t + 1, topk)`` a query,
     ``dsa_keys_causal`` those full causal passes of their lengths would,
     and ``dsa_mask_bytes`` the bytes of the selection a pass reads, packed
-    eight keys a byte, all from the shapes."""
+    eight keys a byte, all from the shapes.  Under a document mask
+    (``topk`` None) nothing was chosen and nothing is counted here
+    (``document_selection`` counts ``doc_built``)."""
+    if topk is None:
+        return
     b, _, s, _ = q.shape
     full = min(s, topk)
     spc.record("dsa_built", 1)
@@ -441,9 +459,9 @@ def _count_dsa(q, topk: int) -> None:
     spc.record("dsa_keys_causal", b * s * (s + 1) // 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
-                             topk: int):
+                             topk: int, scale=None):
     """``causal_flash_attention`` under a data-dependent selection:
     ``select`` (b, s, s / 8) int8, query-major and packed eight keys a
     byte (``ops/sparse_attention.index_select`` writes it so;
@@ -455,24 +473,44 @@ def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
     logsumexp handed out (what reads it reads a constant).  Both passes
     walk every causal block pair under its tile of the selection
     (``_causal_fwd_blocks``, ``_causal_bwd``: the same kernels and twins),
-    a pair that selects nothing passed over by the kernels."""
-    return _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+    a pair that selects nothing passed over by the kernels.  A **document
+    mask** of a packed row is such a selection (``document_selection``:
+    key u <= t of query t's document), ``topk`` then None.
+    ``scale`` as ``causal_flash_attention``'s."""
+    return _causal_fwd_blocks(q, k, v, block, interpret, select=select,
+                              scale=scale)
 
 
-def _selected_fwd(q, k, v, select, block, interpret, topk):
+def _selected_fwd(q, k, v, select, block, interpret, topk, scale=None):
     _count_built(q, k, block, None)
     _count_dsa(q, topk)
-    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, select=select)
+    o, lse = _causal_fwd_blocks(q, k, v, block, interpret, select=select,
+                                scale=scale)
     o = checkpoint_name(o, ATTN_OUT)
     lse = checkpoint_name(lse, ATTN_LSE)
     return (o, lse), (q, k, v, o, lse, select)
 
 
-def _selected_bwd(block, interpret, topk, res, cts):
+def _selected_bwd(block, interpret, topk, scale, res, cts):
     *res, select = res
     _count_dsa(res[0], topk)
     return (*_causal_bwd(block, interpret, None, tuple(res), cts[0],
-                         select=select), None)
+                         select=select, scale=scale), None)
+
+
+def document_selection(doc):
+    """A packed row's document mask as a selection for
+    ``selected_flash_attention``: (b, s, s / 8) int8, key u visible to
+    query t iff ``u <= t`` and ``doc_u == doc_t`` (``doc`` (b, s) int32, a
+    position's document).  Every row selects its own key; a tile pair
+    wholly across a boundary selects nothing and is passed over by the
+    kernels' flags.  32 MB of bits a row of 16,384."""
+    from ompi_tpu.ops.sparse_attention import pack_selection
+
+    spc.record("doc_built", 1)
+    t = jnp.arange(doc.shape[1])
+    return pack_selection((t[:, None] >= t[None, :])
+                          & (doc[:, :, None] == doc[:, None, :]))
 
 
 selected_flash_attention.defvjp(_selected_fwd, _selected_bwd)

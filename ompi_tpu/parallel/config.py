@@ -18,12 +18,21 @@ import json
 #: experts it is the capital
 LAYER_TYPES = {"conv": "c", "full_attention": "a", "linear_attention": "l",
                "sliding_attention": "w", "sparse_attention": "s",
-               "block_diffusion_attention": "b"}
+               "block_diffusion_attention": "b", "mamba": "m"}
+#: the ``layer_types`` operators that may hold a chip's share of their
+#: heads (``heads_here``, ``mamba_heads_here``): each declares how it is
+#: split (``attention.gqa_attention``: query heads with the key-value heads
+#: they read; ``mamba.mamba_mixer``: heads with their B/C groups, a group
+#: some of whose heads are held held whole).  A file's ``attention``
+#: (granitemoehybrid's name) is ``full_attention`` (the loader's alias)
+SHARE_TYPES = frozenset({"full_attention", "mamba"})
 #: a ``hybrid_override_pattern``'s letters (nemotron_h): a layer is one
 #: sublayer, a Mamba-2 mixer, attention or the experts
 HYBRID_LETTERS = "M*E"
-#: the letters whose layer holds a router
-EXPERT_LETTERS = "E" + "".join(LAYER_TYPES.values()).upper()
+#: the published keys that only a granitemoehybrid file may give: scalars
+#: on the embedding, the attention scores, the residual adds, the logits
+MULTIPLIERS = ("embedding_multiplier", "attention_multiplier",
+               "residual_multiplier", "logits_scaling")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,7 +52,13 @@ class ModelConfig:
     them (``heads_here`` query heads with the key-value heads they read,
     ``mamba_heads_here`` Mamba heads with their B/C groups; 0: all);
     ``mtp_here`` of the published next-n modules (-1: all).  A
-    ``layer_types`` model's layers are held whole but for the experts.
+    ``layer_types`` model holds the same shares of its ``full_attention``
+    and ``mamba`` layers (``SHARE_TYPES``: granitemoehybrid's, a
+    tensor-parallel pair's member); every other ``layer_types`` operator
+    (``conv``, ``linear_attention``, the window, the selection, block
+    diffusion) is held whole, and a share beside one is refused.  A Mamba
+    B/C group some of whose heads are held is held whole
+    (``n_groups_here``).
 
     Which sublayers a layer has: ``hybrid_override_pattern`` makes every
     layer **one** sublayer, by its letter (``HYBRID_LETTERS``);
@@ -162,6 +177,19 @@ class ModelConfig:
     exit_beta: float = 0.0          # the exit distribution's entropy bonus
     sandwich_norm: bool = False     # a norm behind a sublayer, ahead of its
     #                                 residual add
+    # granitemoehybrid's keys (granite-4.0-h-micro): four scalars, and the
+    # documents of a packed row
+    embedding_multiplier: float = 1.0   # on the embedding's rows
+    attention_multiplier: float = 0.0   # the scores' scale (0: 1 / sqrt of
+    #                                     the head's width)
+    residual_multiplier: float = 1.0    # on what a sublayer returns, ahead
+    #                                     of its residual add
+    logits_scaling: float = 1.0         # the logits are divided by it
+    eos_token_here: int = -1        # the end-of-document id's row of
+    #                                 ``vocab_rows``: the position behind one
+    #                                 starts a document, across whose start
+    #                                 no scan, convolution or attention reads
+    #                                 (-1: a row is one document)
 
     @property
     def pattern_here(self) -> str:
@@ -207,8 +235,12 @@ class ModelConfig:
 
     @property
     def n_sparse_here(self) -> int:
+        """The held layers with a router: a ``layer_types`` pattern's
+        capitals, a ``hybrid_override_pattern``'s ``E``."""
+        if self.layer_types:
+            return sum(c.isupper() for c in self.pattern_here)
         if self.pattern_here:
-            return sum(c in EXPERT_LETTERS for c in self.pattern_here)
+            return self.pattern_here.count("E")
         return self.layers_here - self.n_dense_here
 
     @property
@@ -238,9 +270,18 @@ class ModelConfig:
     @property
     def n_groups_here(self) -> int:
         """The B/C groups of the held Mamba heads (0 where the model has
-        no mixer)."""
-        return self.n_mamba_heads_here * self.n_groups \
-            // max(1, self.mamba_num_heads)
+        no mixer): a group some of whose heads are held is held whole, as
+        every holder of its heads holds it."""
+        if not self.mamba_num_heads:
+            return 0
+        return max(1, self.n_mamba_heads_here * self.n_groups
+                   // self.mamba_num_heads)
+
+    @property
+    def attention_scale(self):
+        """The scale of an attention score where the file gives one
+        (``attention_multiplier``); None: 1 / sqrt of the head's width."""
+        return self.attention_multiplier or None
 
     @property
     def n_experts_here(self) -> int:
@@ -306,12 +347,15 @@ class ModelConfig:
                 f"layer_types {sorted(set(self.layer_types))}: a layer's "
                 f"operator is one of {sorted(LAYER_TYPES)}, and the "
                 "model has no hybrid_override_pattern beside them")
-        if typed and (self.heads_here or self.kv_lora_rank):
+        whole = sorted(set(self.layer_types) - SHARE_TYPES)
+        if typed and (self.kv_lora_rank or (
+                (self.heads_here or self.mamba_heads_here) and whole)):
             raise NotImplementedError(
-                f"heads_here {self.heads_here} / kv_lora_rank "
-                f"{self.kv_lora_rank}: a layer_types model holds its "
-                "operators whole (no head is split) and attends by "
-                "grouped key-value heads")
+                f"heads_here {self.heads_here} / mamba_heads_here "
+                f"{self.mamba_heads_here} / kv_lora_rank "
+                f"{self.kv_lora_rank}: a layer_types model attends by "
+                f"grouped key-value heads, and holds {whole} whole: only "
+                f"{sorted(SHARE_TYPES)} declare how their heads are split")
         if self.head_dim and not (typed or self.kv_lora_rank) \
                 and self.head_dim * self.num_attention_heads \
                 != self.hidden_size:
@@ -412,13 +456,38 @@ class ModelConfig:
                 f"{self.first_layer_here}: not layers of "
                 "hybrid_override_pattern's letters ['*', 'E', 'M'] or of "
                 "layer_types")
-        if "M" in self.pattern_here and (
-                self.n_mamba_heads_here * self.n_groups
-                % self.mamba_num_heads):
+        mixes = "M" in self.pattern_here or "mamba" in self.layer_types
+        per_group = self.mamba_num_heads // max(1, self.n_groups)
+        if mixes and (
+                min(self.mamba_num_heads, self.mamba_head_dim,
+                    self.ssm_state_size, per_group) < 1
+                or self.mamba_num_heads % self.n_groups
+                or (self.n_mamba_heads_here % per_group
+                    and (per_group % self.n_mamba_heads_here
+                         or hybrid))):
             raise NotImplementedError(
-                f"mamba_heads_here {self.n_mamba_heads_here}: not whole "
-                f"B/C groups of {self.mamba_num_heads // self.n_groups} "
-                "heads; a group split across chips is not run")
+                f"mamba_heads_here {self.n_mamba_heads_here}: neither whole "
+                f"B/C groups of {per_group} heads nor (in a layer_types "
+                "model) a whole part of one group's, which every holder of "
+                "its heads then holds whole")
+        scalars = (self.embedding_multiplier, self.attention_multiplier,
+                   self.residual_multiplier, self.logits_scaling)
+        if scalars != (1.0, 0.0, 1.0, 1.0) and (
+                not typed or self.attention_multiplier < 0.0 or min(
+                    self.embedding_multiplier, self.residual_multiplier,
+                    self.logits_scaling) <= 0.0):
+            raise NotImplementedError(
+                f"{' / '.join(MULTIPLIERS)} {scalars}: the four scalars are "
+                "a layer_types model's (granitemoehybrid's), each above 0")
+        if self.eos_token_here != -1 and (
+                whole or not typed or self.total_ut_steps
+                or not 0 <= self.eos_token_here < self.vocab_rows):
+            raise NotImplementedError(
+                f"eos_token_here {self.eos_token_here}: the documents of a "
+                "packed row are reset in a layer_types model's "
+                f"{sorted(SHARE_TYPES)} layers alone ({whole} read across "
+                "a document's start), in one walk of them, and the id is a "
+                f"row of the held vocabulary's {self.vocab_rows}")
         if self.n_group != 1 or self.topk_group != 1:
             raise NotImplementedError(
                 f"n_group {self.n_group} / topk_group {self.topk_group}: "
@@ -561,6 +630,35 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             "walk, its exit gate and the norm behind a sublayer are an ouro "
             f"model's; a model_type {body.get('model_type')} model walks its "
             "layers once")
+    granite = body.get("model_type") == "granitemoehybrid"
+    if granite:
+        # the family's file names its operators ``mamba`` and ``attention``;
+        # the second is the path's ``full_attention``
+        body["layer_types"] = [
+            "full_attention" if kind == "attention" else kind
+            for kind in body.get("layer_types", [])]
+        if not body["layer_types"] or hybrid or "kv_lora_rank" in body \
+                or body.get("num_local_experts") \
+                or body.get("num_experts_per_tok") \
+                or body.get("position_embedding_type") != "nope" \
+                or body.get("normalization_function", "rmsnorm") \
+                != "rmsnorm" or not body.get("mamba_conv_bias") \
+                or body.get("mamba_proj_bias") \
+                or body.get("mamba_expand", 0) * body["hidden_size"] \
+                != body["mamba_n_heads"] * body["mamba_d_head"]:
+            raise NotImplementedError(
+                f"{path}: a granitemoehybrid model is run with its layers "
+                "named by layer_types, a dense SwiGLU behind each (no "
+                "local expert), no rotary embedding (nope), RMSNorm, a "
+                "bias on the mixer's convolution and on no projection, and "
+                "mamba_expand x hidden_size = mamba_n_heads x mamba_d_head")
+    elif any(key in body or key in body.get("train", {})
+             for key in MULTIPLIERS + ("eos_token_here",)):
+        raise NotImplementedError(
+            f"{path}: {' / '.join(MULTIPLIERS)} / eos_token_here: the four "
+            "scalars and the documents of a packed row are a "
+            f"granitemoehybrid model's; a model_type {body.get('model_type')}"
+            " model has none")
     scaling = body.get("rope_scaling")
     if scaling:
         # M-RoPE's three position components are equal on a text token, so
@@ -653,6 +751,22 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
                              ("kv_chunk_size", "index_kv_chunk")):
             if theirs in sparse:
                 merged.setdefault(ours, sparse[theirs])
+    if granite:
+        for theirs, ours in (("mamba_n_heads", "mamba_num_heads"),
+                             ("mamba_d_head", "mamba_head_dim"),
+                             ("mamba_d_state", "ssm_state_size"),
+                             ("mamba_n_groups", "n_groups"),
+                             ("mamba_d_conv", "conv_kernel"),
+                             ("mamba_chunk_size", "chunk_size"),
+                             ("shared_intermediate_size",
+                              "intermediate_size")):
+            if ours not in overrides:   # a caller's cut goes by either name
+                merged[ours] = merged[theirs]
+        # what the file's keys imply: every layer's feed-forward the dense
+        # SwiGLU and none routed, no QK-norm, and (nope) no layer turned
+        merged.update(first_k_dense_replace=merged["num_hidden_layers"],
+                      num_experts=0, num_experts_per_tok=0, qk_norm=False,
+                      rope_kinds=())
     if ouro:
         # what the file's keys imply: every layer dense and none routed,
         # no QK-norm, RoPE on every layer, and (the report's, no published

@@ -21,13 +21,13 @@ from ompi_tpu.parallel.attention import (DIFFUSED, FULL, MLA, OLMOE,
 from ompi_tpu.parallel.config import HYBRID_LETTERS, LAYER_TYPES
 from ompi_tpu.parallel.gdn import GDN
 from ompi_tpu.parallel.layers import rmsnorm_gain
-from ompi_tpu.parallel.mamba import MIXER
+from ompi_tpu.parallel.mamba import MIXER, TYPED_MIXER
 from ompi_tpu.parallel.short_conv import CONV
 from ompi_tpu.parallel.dsa import DSA
 from ompi_tpu.parallel.sublayer import Sublayer
 
-OPERATORS = (MIXER, SHARED_KV, CONV, FULL, GDN, WINDOW, DSA, DIFFUSED, OLMOE,
-             MLA)
+OPERATORS = (MIXER, TYPED_MIXER, SHARED_KV, CONV, FULL, GDN, WINDOW, DSA,
+             DIFFUSED, OLMOE, MLA)
 FEED_FORWARDS = (experts.DENSE, experts.SORTED, experts.SHARED_LOCAL,
                  experts.LATENT)
 SUBLAYERS = OPERATORS + FEED_FORWARDS
@@ -133,30 +133,39 @@ def sample_axes(cfg) -> dict:
 
 
 def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
-                  at=None):
+                  at=None, doc=None):
     """One decoder layer of ``kind`` (``layer_kinds(cfg)``'s name of it) on
     the residual stream ``x`` (b, s, d): its operator's residual add, then
     its feed-forward's, as far as it has them; the router's product made
     from the layer's input, before the operator, where
     ``cfg.router_before_attention``.  Under ``cfg.sandwich_norm`` what a
     sublayer returns goes through an RMSNorm of its own (the sublayer's
-    ``post_norm`` gain) before it is added.  ``bias`` is the router's
-    balancing bias and ``at`` the token rows a step samples.
+    ``post_norm`` gain) before it is added, and what is added is
+    ``cfg.residual_multiplier`` times it (granitemoehybrid's; at 1.0 the
+    plain add).  ``bias`` is the router's balancing bias, ``at`` the token
+    rows a step samples and ``doc`` (b, s) a packed row's documents, handed
+    to the operator where the model resets at their starts (None: it is
+    not handed on, and an operator that knows no documents is as it was).
 
     Returns (x, the sublayers' statistics, what they report by token row:
     a router's under its own keys, an operator's under its prefix)."""
     layer = layer_kinds(cfg)[kind]
     op, ffn = layer.operator, layer.feed_forward
     stats, seen, routed = {}, {}, None
+    scaled = (lambda y: y) if cfg.residual_multiplier == 1.0 \
+        else (lambda y: cfg.residual_multiplier * y)
+    docs = {} if doc is None else {"doc": doc}
     if cfg.router_before_attention and layer.routes:
         with jax.named_scope(ffn.scope):
             rows = x.reshape(-1, x.shape[-1])
             routed = (rows, experts.router_logits(p, rows))
     if op is not None:
         with jax.named_scope(op.scope):
-            y, stats, seen = op.run(p, x, cfg, interpret=interpret, at=at)
+            y, stats, seen = op.run(p, x, cfg, interpret=interpret, at=at,
+                                    **docs)
             if cfg.sandwich_norm:
                 y = rmsnorm_gain(y, p[op.post_norm], cfg.rms_norm_eps)
+            y = scaled(y)
         x = x + y
     if ffn is not None:
         with jax.named_scope(ffn.scope):
@@ -164,6 +173,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
                                        routed=routed)
             if cfg.sandwich_norm:
                 y = rmsnorm_gain(y, p[ffn.post_norm], cfg.rms_norm_eps)
+            y = scaled(y)
         x = x + y
         stats, seen = {**routing, **stats}, {**made, **seen}
     return x, stats, seen

@@ -8,7 +8,9 @@ module's, with what a step reports of them (its ``aux``, listed in
 every sequence side by side, and the masked rows' weighted loss; for a
 looped model (``total_ut_steps``) the walk run that many times over one
 set of leaves, the exit gate behind every pass and the expected loss under
-the exit distribution (``looped_loss``).
+the exit distribution (``looped_loss``); for a model trained padding-free
+(``eos_token_here``) the documents of a packed row, made from its ids
+(``documents``) and handed down the walk.
 """
 from __future__ import annotations
 
@@ -36,8 +38,19 @@ def sample_rows(rows: int) -> np.ndarray:
     return (np.arange(1, n + 1) * rows) // n - 1
 
 
+def documents(ids, eos: int):
+    """A packed row's documents from its ids (b, s): ``doc`` (b, s) int32, a
+    position's document, counted from 0 along its row: the positions before
+    it that hold the end-of-document id ``eos``, so the position behind one
+    starts the next.  Integers alone: whoever holds the ids makes the same
+    ``doc``."""
+    ends = (ids == eos).astype(jnp.int32)
+    return jnp.cumsum(ends, axis=1) - ends
+
+
 def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
-                       weights=None, scope: str = "otpu_bd_loss"):
+                       weights=None, scope: str = "otpu_bd_loss",
+                       logits_scaling: float = 1.0):
     """Summed cross-entropy of ``softmax(h @ w)`` against ``labels``, by
     blocks of ``block_rows`` rows so that no (T, V) array is ever held.
     The forward pass also makes the two gradients (``softmax - onehot``
@@ -46,9 +59,12 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
     (T,) float32 (None: one a row, and the program is the call's without
     it) gives each row's share of the sum, a constant of the step (no
     gradient reaches it; its work is traced under ``scope``); a row
-    of weight zero adds nothing to either gradient.  Returns (the
-    weighted sum over rows, per row (logsumexp, the label's logit): no
-    gradient passes through the second)."""
+    of weight zero adds nothing to either gradient.  The logits are ``h @
+    w / logits_scaling`` (granitemoehybrid's; at 1.0 the program is the
+    call's without it), divided in each block as they leave the product,
+    and so are both gradients.  Returns (the weighted sum over rows, per
+    row (logsumexp, the label's logit) of the scaled logits: no gradient
+    passes through the second)."""
     t, d = h.shape
     nblk = t // block_rows
     if nblk * block_rows != t:
@@ -62,11 +78,15 @@ def head_cross_entropy(h, w, labels, block_rows: int, compute_dtype,
             total, dw = carry
             hb, lb, *wb = xs
             logits = matmul(hb, w, compute_dtype)            # (rows, V) f32
+            if logits_scaling != 1.0:
+                logits = logits / logits_scaling
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
             dlogits = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
                 lb, logits.shape[-1], dtype=jnp.float32)
             lost = lse - picked
+            if logits_scaling != 1.0:       # d loss / d (h @ w)
+                dlogits = dlogits / logits_scaling
             if wb:
                 with jax.named_scope(scope):
                     dlogits, lost = dlogits * wb[0][:, None], lost * wb[0]
@@ -332,7 +352,19 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     ``bd_weight_sum`` their weights' sum, over the whole batch.
 
     A looped model (``cfg.total_ut_steps``: Ouro) walks its held layers
-    that many times and has its own loss and ``aux``: ``looped_loss``."""
+    that many times and has its own loss and ``aux``: ``looped_loss``.
+
+    A model trained padding-free (``cfg.eos_token_here`` a row of the
+    vocabulary: granite-4.0-h-micro) reads each row of ``tokens`` as
+    documents laid end to end, each ending in that id: ``documents`` makes
+    a position's document once, under ``otpu_embed``, and the walk hands
+    it to every layer, whose operator reads nothing across a document's
+    start.  Every row's loss counts, an end-of-document row's too (its
+    label is the next document's first token).  ``aux`` then holds
+    ``doc`` (b, s) int32, every position's document.
+    The embedding's rows are times ``cfg.embedding_multiplier`` and the
+    logits over ``cfg.logits_scaling``; a model without a router reports
+    ``loads`` and ``experts`` with no entry."""
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
     ids, levels, masked = tokens, None, None
@@ -345,6 +377,10 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     at = sample_rows(ids.size)          # of the rows the layers walk
     at_head = sample_rows(b * s)        # of the rows the head reads
     bias = bias or {}
+    doc = None
+    if cfg.eos_token_here >= 0:
+        with jax.named_scope("otpu_embed"):
+            doc = documents(ids, cfg.eos_token_here)
 
     @functools.cache    # one function a kind, so that JAX traces it once
     def run_of(kind: str):
@@ -356,7 +392,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
 
         def run(layer, x, bias_row):
             x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
-                                        bias=bias_row, kind=kind, at=at)
+                                        bias=bias_row, kind=kind, at=at,
+                                        doc=doc)
             experts = seen.pop("experts", None)
             with jax.named_scope("otpu_stats"):
                 # an operator's rows under their own names, a router's
@@ -379,6 +416,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
 
     with jax.named_scope("otpu_embed"):
         x = params["embed"][ids]                             # (b, s, d) f32
+        if cfg.embedding_multiplier != 1.0:
+            x = cfg.embedding_multiplier * x
     if cfg.total_ut_steps:
         return looped_loss(params, x, labels, cfg, run_of, psum, n_global,
                            at_head)
@@ -410,7 +449,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
             h.reshape(b * s, -1), head, targets.reshape(b * s), head_rows,
-            cfg.compute_dtype, *weighted)
+            cfg.compute_dtype, *weighted,
+            logits_scaling=cfg.logits_scaling)
     # rows of all routers' logits
     routed = cfg.n_sparse_here * n_global * ids.shape[1] // s
     with jax.named_scope("otpu_loss"):
@@ -434,10 +474,15 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             index = cfg.index_loss_coef * jnp.sum(st["index_kl_sum"]) \
                 / n_global
             total = total + index
-    losses, loads = [ce, lb, z], st["slots"]
+    losses = [ce, lb, z]
     with jax.named_scope("otpu_stats"):
         sample["head_in"] = h.reshape(b * s, -1)[at_head]
-    aux = {}
+        if "slots" in st:
+            loads = st["slots"]
+        else:       # no layer routes (``looped_loss``'s form)
+            loads = jnp.zeros((0, cfg.num_experts), jnp.float32)
+            chosen = jnp.zeros((0, b * s, 0), jnp.int32)
+    aux = {} if doc is None else {"doc": doc}
     if masked is not None:
         with jax.named_scope("otpu_stats"):
             aux.update(

@@ -1,7 +1,8 @@
 """A public model's training step (OLMoE, JoyAI-LLM-Flash,
 Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
 Keye-VL-2.0-30B-A3B's language model, SDAR-30B-A3B by block diffusion,
-Ouro-2.6B's looped walk):
+Ouro-2.6B's looped walk, granite-4.0-h-micro padding-free over the
+documents of a packed row):
 widths from a configuration file
 (``parallel/config.py``), not from the mesh; the kinds of layer from
 ``parallel/model.py``'s table.  The parameter tree and its initialisation,
@@ -71,6 +72,9 @@ PROBE = 64              # entries of each leaf that a step reports
 #: cross-entropy, the expected one, the weighted entropy bonus), ``rows``
 #: (T, passes, 2), ``exit_p`` (R, passes) and ``exit_mean`` (passes,),
 #: ``sample`` stacked over the passes' layer applications, ``loads`` and
+#: ``experts`` with no entry; of a model trained padding-free
+#: (``eos_token_here``) ``doc`` (b, s) int32, every position's document
+#: (``objective.documents``), and where it has no router ``loads`` and
 #: ``experts`` with no entry
 
 
@@ -326,6 +330,8 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
     if cfg.block_length:
         aux_specs.update(bd_mask=batch, bd_levels=batch, bd_masked=rep,
                          bd_weight_sum=rep)
+    if cfg.eos_token_here >= 0:
+        aux_specs["doc"] = batch
     if cfg.total_ut_steps:
         aux_specs.update(exit_p=batch, exit_mean=rep)
         aux_specs["sample"].update(exit_logit=batch, exit_entropy=P("dp"))
@@ -464,9 +470,23 @@ def record_step_stats(aux) -> int:
     counts the steps read.  Of a step trained by block diffusion the rows
     its noise masked add to ``bd_rows_masked``; of a looped model's the
     batch's mean exit pass, ``sum_t t p_t`` in thousandths, to
-    ``loop_exit_depth``, and nothing routes, so no slot is counted."""
+    ``loop_exit_depth``, and nothing routes, so no slot is counted; of a
+    step over the documents of packed rows the documents begun add to
+    ``doc_starts``, the (query, key) pairs one attention layer sees under
+    their mask to ``doc_pairs_visible`` and those it would see under the
+    triangle alone to ``doc_pairs_causal``."""
     loads = np.asarray(aux["loads"])
     spc.record("train_steps_read")
+    if "doc" in aux:
+        doc = np.asarray(aux["doc"], np.int64)
+        b, s = doc.shape
+        # a row's documents by their lengths: each sees n (n + 1) / 2 pairs
+        lengths = np.bincount((doc + np.arange(b)[:, None]
+                               * (doc.max() + 1)).ravel())
+        spc.record("doc_starts", int(np.count_nonzero(lengths)))
+        spc.record("doc_pairs_visible",
+                   int(np.sum(lengths * (lengths + 1) // 2)))
+        spc.record("doc_pairs_causal", b * s * (s + 1) // 2)
     if "exit_mean" in aux:
         mean = np.asarray(aux["exit_mean"], np.float64)
         spc.record("loop_exit_depth", int(round(

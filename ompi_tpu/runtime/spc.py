@@ -193,6 +193,15 @@ _COUNTERS = (
     # exit pass sum_t t p_t in thousandths (1,875 at a gate of zero)
     "loop_built", "loop_passes", "loop_layers_held",
     "loop_layer_applications", "loop_head_rows", "loop_exit_depth",
+    # the documents of a packed row (parallel/objective.documents): the
+    # scans (parallel/mamba.ssd_chunked), convolutions (mamba.causal_taps)
+    # and attention masks (parallel/causal.document_selection) made under a
+    # row's documents while steps were traced; and, read back a step
+    # outside every window (parallel/train.record_step_stats), the
+    # documents begun, the (query, key) pairs one attention layer sees
+    # under the document mask and those it would under the triangle alone:
+    # visible over causal is what the boundaries leave of attention's work
+    "doc_built", "doc_starts", "doc_pairs_visible", "doc_pairs_causal",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

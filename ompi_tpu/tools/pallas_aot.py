@@ -525,15 +525,17 @@ def cases(mesh1d, mesh2d):
         return _sds((b, s // 8, s) if key_major else (b, s, s // 8),
                     jnp.int8, one, P())
 
-    def flash_select_forward(b, h, s, d, n_kv):
+    def flash_select_forward(b, h, s, d, n_kv, **scale):
         q, k, v = attn_bwd_args(b, h, s, d, d, n_kv)[:3]
         return fa.flash_causal_forward, (q, k, v), {
-            "block": 1024, "interpret": False, "select": select_args(b, s)}
+            "block": 1024, "interpret": False, "select": select_args(b, s),
+            **scale}
 
-    def attn_select_backward(b, h, s, d, n_kv):
+    def attn_select_backward(b, h, s, d, n_kv, **scale):
         fn, args, kw = attn_block_backward(b, h, s, d, d, n_kv)
         flags = _sds((b * (s // 1024) ** 2,), jnp.int32, one, P())
-        return fn, args, {**kw, "select": (select_args(b, s, True), flags)}
+        return fn, args, {**kw, "select": (select_args(b, s, True), flags),
+                          **scale}
 
     def dsa_index_args(b, s, heads, di):
         return (_sds((b, heads, s, di), bf16, one, P()),
@@ -560,6 +562,13 @@ def cases(mesh1d, mesh2d):
          lambda: flash_select_forward(1, 32, 16384, 128, 4))
     case("keye_attn_select_backward",
          lambda: attn_select_backward(1, 32, 16384, 128, 4))
+    # granite-4.0-h-micro's attention layer under a packed row's document
+    # mask, which is such a selection (1 x 16 query heads on 4 key-value
+    # heads x 16,384 at 64, the scores' scale the file's 1 / 64)
+    case("granite_flash_select_forward",
+         lambda: flash_select_forward(1, 16, 16384, 64, 4, scale=1 / 64))
+    case("granite_attn_select_backward",
+         lambda: attn_select_backward(1, 16, 16384, 64, 4, scale=1 / 64))
     case("keye_dsa_index_select",
          lambda: dsa_index_select(1, 16384, 16, 64, 2048))
     case("keye_dsa_index_loss",
@@ -728,6 +737,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "sdar-30b-a3b-train-1chip"))
     case("ouro_step_1chip", lambda: model_step(
         topo_devs[:1], "ouro-2.6b-train-1chip"))
+    case("granite_step_1chip", lambda: model_step(
+        topo_devs[:1], "granite-4.0-h-micro-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

@@ -265,6 +265,40 @@ def _cut_batch_ouro(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# granite-train-1chip: the same, at the widths of tests/test_granite_train.py
+# (layers 4 to 6, a Mamba-2 layer each side of the attention layer: half of
+# 4 Mamba heads of 32 beside the one B/C group, half of 4 query heads on 2
+# key-value heads, 64 of 256 ids whose last ends a document, chunks of 8, 2
+# packed rows of 64 tokens in documents of about 4, tiles of 16)
+GRANITE = "granite-train-1chip"
+TINY_GRANITE = dict(hidden_size=64, num_attention_heads=4,
+                    num_key_value_heads=2, heads_here=2, mamba_n_heads=4,
+                    mamba_d_head=32, mamba_heads_here=2, mamba_d_state=16,
+                    mamba_chunk_size=8, intermediate_size=96,
+                    shared_intermediate_size=96, vocab_size=256,
+                    vocab_here=64, eos_token_here=63, layers_here=3,
+                    first_layer_here=4)
+TINY_GRANITE_TRAIN = dict(seq_len=64, micro_batch=2, attn_block=16,
+                          loss_block_rows=32, compute_dtype="float32")
+
+
+def _tiny_granite(config):
+    config.update(TINY_GRANITE)
+    config["train"].update(TINY_GRANITE_TRAIN)
+
+
+def _cut_batch_granite(p):
+    p.update(sequences=TINY_GRANITE_TRAIN["micro_batch"],
+             seq_len=TINY_GRANITE_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
+#: the cells later PRs brought, in the order they were appended: a test of
+#: an earlier cell's place at the end of a list leaves out the ones behind
+#: it (``LATER[LATER.index(cell) + 1:]``)
+LATER = (KEYE, SDAR, OURO, GRANITE)
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -352,6 +386,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("ouro-2.6b-train-1chip", _tiny_ouro),
         cut={"packed-4k-looped-steps": _cut_batch_ouro}),
+    GRANITE: dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("granite-4.0-h-micro-train-1chip", _tiny_granite),
+        cut={"packed-16k-docs-steps": _cut_batch_granite}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -362,7 +401,7 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
               "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE,
-              SDAR, OURO)
+              SDAR, OURO, GRANITE)
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -421,7 +460,7 @@ result = run.run_cell({cell!r}, seed=2147483999, seconds=0.3, trace=False,
 builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                  if k.startswith(("device_", "train_",
-                                                  "moe_", "attn_",
+                                                  "moe_", "attn_", "doc_",
                                                   "dsa_", "bd_", "loop_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
@@ -926,7 +965,7 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
             assert [c for c in m["workloads"]
-                    if c not in (SDAR, OURO)][-1] == KEYE, m["name"]
+                    if c not in LATER[1:]][-1] == KEYE, m["name"]
     with open(os.path.join(BENCH, "metrics", "dsa.selected_share.json"),
               encoding="utf-8") as f:
         spec = json.load(f)
@@ -1009,8 +1048,8 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
                                               "ranks"]
     for m in real["end_to_end"] + real["per_layer"]:
         if KEYE in m.get("workloads", ()) and len(m["workloads"]) > 1:
-            assert [c for c in m["workloads"] if c != OURO][-1] == SDAR, \
-                m["name"]
+            assert [c for c in m["workloads"]
+                    if c not in LATER[2:]][-1] == SDAR, m["name"]
     assert SDAR in by_name["attn.pairs_walked_share"]["workloads"]
     assert (readers["bd.visible_share"]["reader"],
             readers["bd.visible_share"]["params"]) == ("program_counter", {
@@ -1136,6 +1175,91 @@ def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
         assert set(scopes) <= set(trace.STEP_SCOPES)
 
 
+@of_cells(GRANITE)
+def test_a_padding_free_step_counts_its_documents_and_routes_nothing(
+        rehearsal):
+    """The trainer's counters on one chip's share of granite-4.0-h-micro
+    (layers 4 to 6 here), by the kind that reads everything from the kit
+    and with no file of the harness edited for it: the scans, convolutions
+    and masks built under a row's documents, the documents and the pairs
+    their masks leave of the steps read back (rows of 64 tokens in documents
+    of about 4), no slot and no expert's load anywhere, every attention
+    pass over the model's own key-value heads; the step's program is the
+    one program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    rows, s = (TINY_GRANITE_TRAIN[k] for k in ("micro_batch", "seq_len"))
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.granite.bf16.1x16384"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    read = c["train_steps_read"]
+    assert c["doc_built"] >= 3 and read > 0
+    assert c["doc_pairs_causal"] == read * rows * s * (s + 1) // 2
+    assert 4 * rows * read < c["doc_starts"] < 40 * rows * read
+    assert s * rows * read <= c["doc_pairs_visible"] \
+        < 0.5 * c["doc_pairs_causal"]
+    for name in ("moe_local_slots", "moe_absent_slots", "moe_chunk_rows",
+                 "moe_max_expert_load", "moe_gmm_built", "bd_built",
+                 "dsa_built", "attn_window_built", "loop_built"):
+        assert c.get(name, 0) == 0, name
+    assert c["attn_built"] == c["attn_shared_kv_built"] > 0 \
+        and c["attn_pairs_walked"] == c["attn_pairs_causal"] > 0
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_padding_free_cells_entries_are_the_manifests(real):
+    """PR 69 brought a configuration, a cell and list entries, and no metric
+    (``per_layer`` holds its 128): everything found by name.  The cell's
+    name stands at the end of every list it is in: the ones every model
+    cell is in, and Nemotron's state-space and whole-step shares, LFM2's
+    forward kernel at a head of 64 and the grouped-query counter, whose
+    readers take everything from the point's kind and kit."""
+    assert len(real["per_layer"]) == 128
+    at = [w["name"] for w in real["workloads"]].index(GRANITE)
+    cell = real["workloads"][at]
+    (config,) = [c for c in real["configs"] if c["name"] == cell["config"]]
+    assert (cell["traffic"], cell["chips"], config["reduced"]) == (
+        "packed-16k-docs-steps", 1, ["layers", "heads", "vocab"])
+    assert config["source"] == "https://huggingface.co/ibm-granite/" \
+        "granite-4.0-h-micro/blob/main/config.json"
+    assert LATER[-1] == GRANITE and at == len(real["workloads"]) - 1
+    listed = {m["name"]: m["workloads"]
+              for m in real["end_to_end"] + real["per_layer"]
+              if GRANITE in m.get("workloads", ())}
+    assert all(cells[-1] == GRANITE and len(cells) > 1
+               for cells in listed.values())
+    every = {m["name"] for m in real["end_to_end"] + real["per_layer"]
+             if {"nemotron3-train-1chip", "lfm2-train-1chip", OURO}
+             <= set(m.get("workloads", ()))}
+    assert every < set(listed) and "small_msg_us" in every \
+        and "step.hbm_peak_share" in every \
+        and {n for n in every if n.startswith("compile.")} >= set(
+            BUILD_METRICS)
+    assert {n: cells[:-1] for n, cells in listed.items()
+            if n not in every} == {
+        "ssm.mixer_share": ["nemotron3-train-1chip"],
+        "ssm.scan_share": ["nemotron3-train-1chip"],
+        "nemo.mfu": ["nemotron3-train-1chip"],
+        "nemo.remat_share": ["nemotron3-train-1chip"],
+        "nemo.unnamed_share": ["nemotron3-train-1chip"],
+        "lfm2.flash_mfu": ["lfm2-train-1chip"],
+        "attn.shared_kv_share": [
+            "nemotron3-train-1chip", "lfm2-train-1chip",
+            "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE, SDAR]}
+    for name in listed:
+        if name in {m["name"] for m in real["per_layer"]}:
+            with open(os.path.join(BENCH, "metrics", name + ".json"),
+                      encoding="utf-8") as f:
+                spec = json.load(f)
+            select = spec.get("params", {}).get("select", {})
+            assert select.get("kind", "train_step_kit") in (
+                "train_step_kit", ["train_step_kit"]) \
+                or "train_step_kit" in select["kind"], name
+    assert not [m["name"] for m in real["per_layer"]
+                if m["name"].startswith(("moe.", "doc.", "granite."))
+                and GRANITE in m.get("workloads", ())]
+
+
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     """Appended behind everything that was there (PR 56): data files on
     readers that are there, in the one cell whose model has a window, each
@@ -1171,7 +1295,7 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
             assert [c for c in m["workloads"]
-                    if c not in (KEYE, SDAR, OURO)][-1] == cell, m["name"]
+                    if c not in LATER][-1] == cell, m["name"]
     for name, params in (
             ("attn.window_share", {"name": "attn_window_built",
                                    "over": "attn_built", "scale": 100}),
@@ -1264,7 +1388,8 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
             points = json.load(f).get("points", [])
         if cell["name"] in SHARE_CELLS:
             kinds |= {p["kind"] for p in points}
-        elif cell["name"] != OURO:      # a kit cell in which nothing routes:
+        elif cell["name"] not in (OURO, GRANITE):   # a kit cell in which
+            #                             nothing routes:
             #                             the metric does not list it
             assert not {p["kind"] for p in points} & set(
                 spec["params"]["select"]["kind"]), cell["name"]
@@ -1555,6 +1680,40 @@ def test_kit_check_tells_the_looped_program_from_its_controls(tmp_path):
                           ("uniform_exit", "exit_mean"),
                           ("uniform_exit", "grad_log_rms"),
                           ("no_post_norm", "grad_probe")):
+        assert row["parts_" + variant]["units_by_group"][part] > 1, variant
+
+
+def test_kit_check_tells_the_padding_free_program_from_its_controls(
+        tmp_path):
+    """``benchmark/tools/kit_check.py`` on granite-4.0-h-micro's cell at the
+    rehearsal's widths, with no file of the harness edited for it: one step
+    of the program lies within the kind's tolerance of ``granitekit``'s
+    reference; the reference in bfloat16 lies far outside the program's, and
+    each of the kit's controls outside the tolerance where it bites: a
+    bfloat16 head, the scan's state in bfloat16, the scan and the whole
+    model without the scan's resets, the convolution read across a
+    document's start, attention under the triangle alone, the scale 1 /
+    sqrt(head), a residual multiplier of one."""
+    env = _stage(GRANITE, str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "tools", "kit_check.py"),
+         "--workload", GRANITE, "--platform", "cpu",
+         "--root", str(tmp_path), "--seeds", "1", "--base", "2147483990"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    (row,) = [json.loads(ln[5:]) for ln in done.stdout.splitlines()
+              if ln.startswith("seed ")]
+    assert row["program"]["widest_units"] < 0.05
+    assert row["local_load"] != row["local_load"]       # nothing routes
+    assert row["control_bf16"]["widest_units"] \
+        > 100 * row["program"]["widest_units"]
+    for variant, part in (("bf16", "head_rows"), ("scan_bf16", "ssm_y"),
+                          ("scan_no_reset", "ssm_y"),
+                          ("no_scan_reset", "grad_log_rms"),
+                          ("no_conv_reset", "conv_x"),
+                          ("no_doc_mask", "grad_probe"),
+                          ("sqrt_scale", "grad_log_rms"),
+                          ("residual_one", "grad_log_rms")):
         assert row["parts_" + variant]["units_by_group"][part] > 1, variant
 
 

@@ -252,7 +252,8 @@ def _text(fn, *args):
 
 def test_without_a_description_the_callers_programs_are_what_they_were():
     """``causal_flash_attention`` takes no description (the mask has an
-    entry of its own beside ``selected_flash_attention``); with ``bd``
+    entry of its own beside ``selected_flash_attention``; PR 69 gave it
+    the scores' ``scale``, None for every model but one); with ``bd``
     None the jaxpr of both kernels' callers is the text of the call
     without the argument; under the mask the kernels carry it in their
     names, as ``_select_`` is carried."""
@@ -260,7 +261,7 @@ def test_without_a_description_the_callers_programs_are_what_they_were():
 
     assert list(inspect.signature(
         causal.causal_flash_attention.__wrapped__).parameters) == [
-        "q", "k", "v", "block", "interpret", "window"]
+        "q", "k", "v", "block", "interpret", "window", "scale"]
     q, k, v = _qkv(64, 2 * BLOCK, 4, 2, seed=8, dt=jnp.bfloat16)
     fwd = lambda **kw: lambda *a: fa.flash_causal_forward(
         *a, block=BLOCK, interpret=True, **kw)

@@ -89,6 +89,16 @@ TREES = {
              num_key_value_heads=4, intermediate_size=96, vocab_size=256,
              layers_here=2),
         "6bf59e92c7fae8cb", "934f087bce1c92b6"),
+    # PR 69's own tree, pinned as it was brought: a Mamba-2 mixer or
+    # attention, then a dense SwiGLU, on a share of each mixer's heads; the
+    # tied matrix once
+    "granite-4.0-h-micro-train-1chip.json": (
+        dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+             heads_here=2, mamba_n_heads=4, mamba_d_head=32,
+             mamba_heads_here=2, mamba_d_state=16, mamba_chunk_size=8,
+             shared_intermediate_size=96, vocab_size=256, vocab_here=64,
+             eos_token_here=63, layers_here=3, first_layer_here=4),
+        "7bb1f947f7dfc79b", "8250c5af4151712d"),
 }
 
 

@@ -419,6 +419,74 @@ def test_ouro_train_step_aot_compiles_from_the_cells_configuration(
 
 
 @pytest.fixture(scope="module")
+def granite_rows():
+    """One child for granite-4.0-h-micro's cases: both flash kernels under a
+    packed row's document mask at a head of 64 with the file's scale, and
+    the whole padding-free step of the cell's own configuration file, for
+    one v5e device (about 40 s of the 600)."""
+    return rows_with_texts("granite_")
+
+
+def test_the_document_mask_kernels_aot_compile_at_the_cells_shape(
+        granite_rows):
+    """16 query heads on 4 key-value heads x 16,384 positions at a head
+    width of 64 under a document mask packed eight keys a byte, int8 (1,
+    16384, 2048), the scores' scale 1 / 64 a static argument: one Mosaic
+    call each; no array of (16384, 16384) is in or around either."""
+    for case in ("granite_flash_select_forward",
+                 "granite_attn_select_backward"):
+        row = granite_rows[case]
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        assert row["entry_ops"].get("custom-call") == 1, (case,
+                                                          row["entry_ops"])
+        with open(row["hlo"], encoding="utf-8") as f:
+            text = f.read()
+        assert ("s8[1,2048,16384]" if case == "granite_attn_select_backward"
+                else "s8[1,16384,2048]") in text, case
+        assert not re.search(r"\[(\d+,)*16384,16384[\],]", text), case
+
+
+def test_granite_train_step_aot_compiles_from_the_cells_configuration(
+        granite_rows):
+    """The whole step of ``benchmark/configs/granite-4.0-h-micro-train-
+    1chip.json`` (published widths; layers 0-9 of 40, half the heads of each
+    mixer, an eighth of the vocabulary, 1 x 16,384 tokens): it fits the chip
+    beside its 7.8 GB of state (652,970,080 parameters and AdamW's two
+    moments); the two runs of Mamba layers are loops; the attention layer's
+    two kernels are the selection's, under ``otpu_attention`` in the pass
+    they belong to and in no recomputed one; the document mask is packed
+    wherever it goes and no (16384, 16384) array of floats is made; no
+    router, no grouped matmul and no other model's kernel is in it."""
+    row = granite_rows["granite_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 3
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 652_970_080 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    for name in ("otpu_flash_select_forward", "otpu_attn_select_backward"):
+        found = [p for p in kernels if f"/{name}/" in p]
+        assert found and all("otpu_attention" in p for p in found), (name,
+                                                                     found)
+        assert not [p for p in found if "rematted_computation" in p], name
+        assert all(("transpose(" in p) == (name == "otpu_attn_select_backward")
+                   for p in found), (name, found)
+    assert not [p for p in kernels if "otpu_gmm" in p or "otpu_moe" in p
+                or "otpu_row_scatter" in p or "otpu_head_" in p
+                or "_bd_" in p or "/otpu_flash_causal_forward/" in p
+                or "/otpu_attn_block_backward/" in p or "otpu_dsa" in p]
+    paths = [path for _, path in op_paths(row)]
+    for scope in ("otpu_mamba/otpu_ssm_scan", "otpu_mamba/otpu_ssm_conv",
+                  "otpu_dense_mlp", "otpu_embed", "otpu_head"):
+        assert any(scope in p for p in paths), scope
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert "s8[1,16384,2048]" in text
+    assert not re.search(r"= (f32|bf16)\[(\d+,)*16384,16384[\],]", text)
+
+
+@pytest.fixture(scope="module")
 def sdar_rows():
     """One child for the SDAR-30B-A3B cases: both flash kernels under block
     diffusion's mask, the two kernels of ``ops/head_norm_rope`` and the
