@@ -23,6 +23,7 @@ chip (a chip belongs to one process).  The last line of stdout is
 """
 import contextlib
 import faulthandler
+import functools
 import json
 import os
 import sys
@@ -38,6 +39,9 @@ FLASH_SHAPE = (4, 8, 2048, 128)         # b, h, s, head width; bf16
 ROPE_SHAPE = (1, 8192, 32, 1536)        # b, s, heads, the latent's rank
 # b, s, heads, head width, groups, state, chunk: the hybrid cell's mixer
 SSM_SHAPE = (1, 8192, 16, 64, 1, 128, 128)
+# the two Mamba cells' scans for the Pallas kernels, in the same order:
+# Granite's under a packed row's documents and Nemotron's
+SSM_KERNEL_SHAPES = ((1, 16384, 32, 64, 1, 128, 256), SSM_SHAPE)
 # each loss is taken BEFORE its update: four losses observe three
 # updates, and every one of them must have lowered the loss
 TRAIN_STEPS = 4
@@ -287,7 +291,8 @@ def trainer(devs, clock: Clock, scale: int = MODEL_SCALE) -> None:
 def kernels(clock: Clock, expect_interpret: bool = False,
             flash_shape=FLASH_SHAPE, dtype: str = "bfloat16",
             reduce_elems: int = 1 << 20, rope_shape=ROPE_SHAPE,
-            ssm_shape=SSM_SHAPE) -> None:
+            ssm_shape=SSM_SHAPE,
+            ssm_kernel_shapes=SSM_KERNEL_SHAPES) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -398,8 +403,8 @@ def kernels(clock: Clock, expect_interpret: bool = False,
     print(f"  project_rope {g.shape} {dtype} matches rope_interleaved in "
           f"float32 ({err:.2e} of max|ref|)", flush=True)
 
-    # Mamba-2's state-space scan in chunks (``mamba.ssd_chunked``; no
-    # kernel: XLA's batched matmuls and one loop over the chunks) against
+    # Mamba-2's state-space scan in chunks (``mamba.ssd_chunked``, the
+    # XLA form: batched matmuls and one loop over the chunks) against
     # the recurrence one position at a time, at the hybrid cell's widths:
     # steps between 0.001 and 0.1 and decays of 1 to 16 a unit step, as
     # the mixer's leaves start
@@ -425,6 +430,53 @@ def kernels(clock: Clock, expect_interpret: bool = False,
     print(f"  ssd_chunked {g.shape} in chunks of {chunk} matches the "
           f"recurrence over {ss} positions ({err:.2e} of max|ref|)",
           flush=True)
+
+    # the same scan on its Pallas kernels (``ops/ssd_scan``: the chunks'
+    # matrices and the states in VMEM, forward and backward) against the
+    # XLA form and autodiff through it, with and without a packed row's
+    # documents (a dozen, one starting on a chunk's first position and one
+    # on a chunk's last), at both Mamba cells' shapes: the largest error
+    # over the largest entry of y and of the five gradients
+    from ompi_tpu.ops import ssd_scan
+
+    interpret = pallas_interpret()
+    flat = lambda t: t.reshape(t.shape[:2] + (-1,))
+    for sb, ss, sh, sp_, sg, sn, chunk in ssm_kernel_shapes:
+        xs, weigh = (jax.random.normal(k, (sb, ss, sh, sp_), f32)
+                     for k in (kq, kv))
+        step = jnp.exp(jax.random.uniform(kk, (sb, ss, sh), f32,
+                                          np.log(0.001), np.log(0.1)))
+        decay = -jax.random.uniform(kv, (sh,), f32, 1.0, 16.0)
+        bs, cs = (jax.random.normal(k, (sb, ss, sg, sn), f32) * sn ** -0.5
+                  for k in (kk, kq))
+        starts = np.zeros((sb, ss), np.int32)
+        starts[:, np.linspace(1, ss - 1, 10, dtype=int)] = 1
+        starts[:, [chunk, 3 * chunk - 1]] = 1
+        docs = jnp.asarray(np.cumsum(starts, axis=1, dtype=np.int32))
+        for doc in (None, docs):
+            want = clock.call(jax.jit(lambda *args: jax.vjp(
+                lambda *a: mamba.ssd_chunked(*a, chunk, doc), *args[:5])[1](
+                    args[5]) + (mamba.ssd_chunked(*args[:5], chunk, doc),)),
+                xs, step, decay, bs, cs, weigh, first=True)
+            how = dict(chunk=chunk, p=sp_, groups=sg, interpret=interpret)
+            y, kept = clock.call(functools.partial(
+                ssd_scan.scan_forward, states=True, **how), flat(xs),
+                flat(bs), flat(cs), step, decay, doc, first=True)
+            dx, db, dc, ddt, da, _ = clock.call(functools.partial(
+                ssd_scan.scan_backward, **how), flat(xs), flat(bs), flat(cs),
+                step, decay, doc, None, kept, flat(weigh), first=True)
+            errs = {name: float(jnp.max(jnp.abs(g.reshape(w.shape) - w))
+                                / jnp.max(jnp.abs(w)))
+                    for name, g, w in zip(("dx", "ddt", "da", "db", "dc", "y"),
+                                          (dx, ddt, da, db, dc, y), want)}
+            _require(max(errs.values()) <= 1e-4,
+                     f"ssd_scan {xs.shape} in chunks of {chunk}: error "
+                     f"{errs} of max|ref| exceeds 1e-04")
+            print(f"  ssd_scan kernels {xs.shape} in chunks of {chunk}, "
+                  f"{'12 documents' if doc is not None else 'one document'}"
+                  f": y and the five gradients match the XLA form ("
+                  + ", ".join(f"{k} {v:.1e}" for k, v in errs.items())
+                  + " of max|ref|)", flush=True)
 
     a = jax.random.normal(kq, (reduce_elems,), jnp.float32)
     bb = jax.random.normal(kk, (reduce_elems,), jnp.float32)

@@ -7,6 +7,7 @@ where the caller gives the row's documents, with the mixer's entries in
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +41,13 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     entered it through ``exp(sum_{k<=i} dt_k a)``.  The running sums,
     the exponentials, the states and every product are float32 at the
     highest precision: at a chip's share of the heads they are under a
-    hundredth of a layer's operations.  The backward pass is autodiff's
-    through the same chunks.
+    hundredth of a layer's operations.  The backward pass of this form is
+    autodiff's through the same chunks.
+
+    This XLA form is the CPU's and the oracle of the Pallas kernels
+    (``ops/ssd_scan``) that ``mamba_mixer`` takes where Mosaic compiles and
+    the shape has tiles (``_kernel_scan``): there the chunks' matrices and
+    the states stay in VMEM, forward and backward.
 
     Under ``doc`` a document starts anywhere in a chunk, and with j <= i in
     a chunk: position i reads j iff ``doc_i == doc_j``; j's term reaches
@@ -53,6 +59,7 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     bt, s, h, p = x.shape
     g, n = b.shape[2:]
     r = h // g
+    _count_scan(False)
     _f32 = lambda eq, one, two: contract(eq, one, two, jnp.float32)
     pad = -s % chunk
     if pad:
@@ -107,6 +114,82 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     y = y + _f32("zcgin,zcgrpn->zcgrip", cc, jnp.moveaxis(entered, 0, 1)) \
         * from_entered[..., None]
     return y.transpose(0, 1, 4, 2, 3, 5).reshape(bt, s + pad, h, p)[:, :s]
+
+
+def _count_scan(on_kernel: bool) -> None:
+    """SPC ``ssm_scan_built``: the passes of a Mamba-2 layer's scan made
+    while steps were traced (the XLA form, whose backward pass is
+    autodiff's and not seen here, or the kernel path's forward and
+    backward rules: JAX traces a pass more than once);
+    ``ssm_scan_kernel_built``: those of them made on the Pallas kernels.
+    What reads is the second over the first."""
+    spc.record("ssm_scan_built", 1)
+    if on_kernel:
+        spc.record("ssm_scan_kernel_built", 1)
+
+
+def _scan_views(xbc, heads, p, groups, chunk):
+    """([x | B | C] three times, their lane blocks) as ``ops/ssd_scan``
+    reads the convolution's one array: x's heads from lane 0, group g's B
+    at block ``heads p / 128 + g`` and its C ``groups`` blocks on."""
+    from ompi_tpu.ops import ssd_scan
+
+    first = heads * p // ssd_scan.LANES
+    return (xbc, xbc, xbc), dict(
+        chunk=chunk, p=p, groups=groups, at=(0, first, first + groups))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kernel_scan(xbc, dt, a, skip, doc, chunk, p, groups):
+    """The chunked scan and its skip term on the Pallas kernels
+    (``ops/ssd_scan``): ``y + skip x`` (b, s, heads x p) of the
+    convolution's [x | B | C] (b, s, heads x p + 2 groups x 128), read
+    where it lies, ``dt`` (b, s, heads), ``a`` and ``skip`` (heads,) and
+    ``doc`` (b, s) int32 or None.  The forward kernel also writes, for a
+    backward pass, the state that entered each chunk; the backward kernel
+    makes a chunk's other parts again from that.  Nothing a chunk is kept
+    or recomputed by XLA, and no (b, s, heads, p) view of x or y exists."""
+    from ompi_tpu.ops import ssd_scan
+
+    _count_scan(True)
+    if doc is not None:
+        spc.record("doc_built", 1)
+    views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
+    return ssd_scan.scan_forward(*views, dt, a, doc, skip, **how)
+
+
+def _kernel_scan_fwd(xbc, dt, a, skip, doc, chunk, p, groups):
+    from ompi_tpu.ops import ssd_scan
+
+    _count_scan(True)
+    views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
+    y, kept = ssd_scan.scan_forward(*views, dt, a, doc, skip, states=True,
+                                    **how)
+    return y, (xbc, dt, a, skip, doc, kept)
+
+
+def _kernel_scan_bwd(chunk, p, groups, res, dy):
+    from ompi_tpu.ops import ssd_scan
+
+    _count_scan(True)
+    xbc, dt, a, skip, doc, kept = res
+    views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
+    *d_xbc, d_dt, d_a, d_skip = ssd_scan.scan_backward(
+        *views, dt, a, doc, skip, kept, dy, **how)
+    return jnp.concatenate(d_xbc, axis=-1), d_dt, d_a, d_skip, None
+
+
+_kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+
+
+def _scan_on_kernels(interpret, chunk, p, n, heads, groups, s) -> bool:
+    """Whether the scan runs on the Pallas kernels: where Mosaic compiles
+    (``interpret`` false: a TPU) and the shape has tiles."""
+    if interpret:
+        return False
+    from ompi_tpu.ops import ssd_scan
+
+    return ssd_scan.supported(chunk, p, n, heads, groups, s)
 
 
 def causal_taps(xbc, w, bias, doc=None):
@@ -173,18 +256,27 @@ def mamba_mixer(p, x, cfg, *, interpret: bool = True, at=None, doc=None,
     with jax.named_scope("otpu_ssm_conv"):
         xbc = causal_taps(xbc, p["conv_w"], p["conv_b"], doc)
     with jax.named_scope("otpu_ssm_scan"):
-        xs = xbc[..., :inner].reshape(b, s, nh, hd)
-        bs, cs = (xbc[..., inner + k * g * n:inner + (k + 1) * g * n]
-                  .reshape(b, s, g, n) for k in (0, 1))
         step = jax.nn.softplus(step + p["dt_bias"])
-        y = ssd_chunked(xs, step, -jnp.exp(p["A_log"]), bs, cs,
-                        cfg.chunk_size, doc)
+        a = -jnp.exp(p["A_log"])
         rows = lambda t: t.reshape((b * s,) + t.shape[2:])
+        # what the first held head and its group read, as lane slices of
+        # the convolution's array: no (b, s, heads, p) view for a probe
         seen = {"ssm_dt_seq": rows(step[:, :, 0]),
-                "ssm_x_seq": rows(xs[:, :, 0]),
-                "ssm_b_seq": rows(bs[:, :, 0]),
-                "ssm_c_seq": rows(cs[:, :, 0]), "ssm_y": rows(y[:, :, 0])}
-        y = (y + p["D"][:, None] * xs).reshape(b, s, inner)
+                "ssm_x_seq": rows(xbc[..., :hd]),
+                "ssm_b_seq": rows(xbc[..., inner:inner + n]),
+                "ssm_c_seq": rows(xbc[..., inner + g * n:inner + (g + 1) * n])}
+        if _scan_on_kernels(interpret, cfg.chunk_size, hd, n, nh, g, s):
+            # the skip term inside the kernels; the first head's y less it
+            # (to an ulp of ``D x``, a hundred-thousandth of a check's unit)
+            y = _kernel_scan(xbc, step, a, p["D"], doc, cfg.chunk_size, hd, g)
+            seen["ssm_y"] = rows(y[..., :hd] - p["D"][0] * xbc[..., :hd])
+        else:
+            xs = xbc[..., :inner].reshape(b, s, nh, hd)
+            bs, cs = (xbc[..., inner + k * g * n:inner + (k + 1) * g * n]
+                      .reshape(b, s, g, n) for k in (0, 1))
+            y = ssd_chunked(xs, step, a, bs, cs, cfg.chunk_size, doc)
+            seen["ssm_y"] = rows(y[:, :, 0])
+            y = (y + p["D"][:, None] * xs).reshape(b, s, inner)
     with jax.named_scope("otpu_ssm_norm"):
         y = (y * jax.nn.silu(z)).reshape(b, s, g, inner // g)
         if tp_axis is None or nh * cfg.n_groups >= cfg.mamba_num_heads:

@@ -147,6 +147,12 @@ _COUNTERS = (
     # kernel path's forward and backward rules), and those of them made
     # on the Pallas kernels (ops/gated_delta): the second over the first
     "gdn_rule_built", "gdn_rule_kernel_built",
+    # the Mamba-2 scan's passes made while steps were traced
+    # (parallel/mamba.ssd_chunked: the XLA form's forward, or
+    # mamba._kernel_scan: the kernel path's forward and backward rules),
+    # and those of them made on the Pallas kernels (ops/ssd_scan): the
+    # second over the first
+    "ssm_scan_built", "ssm_scan_kernel_built",
     # the DeltaNet convolution's passes made while steps were traced
     # (parallel/gdn.gated_delta_net: the XLA lines' forward, or the
     # kernel path's forward and backward rules), and those of them made

@@ -580,6 +580,7 @@ OWN_PROGRAMS = (
     "flash_causal_forward", "attn_block_backward",  # ops/flash_attention
     "index_select", "index_loss",                   # ops/sparse_attention
     "rule_forward", "rule_backward",                # ops/gated_delta
+    "scan_forward", "scan_backward",                # ops/ssd_scan
     "conv_forward", "conv_backward",                # ops/causal_conv
     "encode_int8", "decode_int8", "dequant_accumulate",  # ops/pallas_quant
 )

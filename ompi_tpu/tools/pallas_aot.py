@@ -500,6 +500,34 @@ def cases(mesh1d, mesh2d):
 
     case("qwen3next_gdn_conv_forward", lambda: gdn_conv(False))
     case("qwen3next_gdn_conv_backward", lambda: gdn_conv(True))
+    # Mamba-2's chunked scan and its skip term (``mamba._kernel_scan``:
+    # ``ops/ssd_scan``'s two kernels) as the two Mamba cells' steps build it, x, B and C read
+    # from the convolution's one array: Granite's 32 held heads of 64 in
+    # one group, 16,384 positions in chunks of 256 under a packed row's
+    # documents; Nemotron's 16 heads, 8,192 positions in chunks of 128 and
+    # no documents; every product float32 at the highest precision
+    def ssd_scan(backward, heads, s, chunk, documents):
+        from ompi_tpu.parallel import mamba
+
+        rep = lambda *s: _sds(s, f32, one, P())
+        scan = lambda xbc, dt, a, skip, *doc: mamba._kernel_scan(
+            xbc, dt, a, skip, doc[0] if doc else None, chunk, 64, 1)
+        if backward:
+            scan = jax.grad(lambda *a, scan=scan: jnp.sum(scan(*a)),
+                            (0, 1, 2, 3))
+        return jax.jit(scan), (
+            rep(1, s, heads * 64 + 256), rep(1, s, heads), rep(heads),
+            rep(heads)
+        ) + ((_sds((1, s), jnp.int32, one, P()),) if documents else ())
+
+    case("granite_ssd_scan_forward",
+         lambda: ssd_scan(False, 32, 16384, 256, True))
+    case("granite_ssd_scan_backward",
+         lambda: ssd_scan(True, 32, 16384, 256, True))
+    case("nemotron3_ssd_scan_forward",
+         lambda: ssd_scan(False, 16, 8192, 128, False))
+    case("nemotron3_ssd_scan_backward",
+         lambda: ssd_scan(True, 16, 8192, 128, False))
     case("vpu_combine2_sum", lambda: (
         pr.combine2, ("SUM", _sds((PAY,), f32, one, P()),
                       _sds((PAY,), f32, one, P())),

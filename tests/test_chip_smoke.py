@@ -51,7 +51,8 @@ def test_phases_pass_small_on_cpu_mesh(world):
     chip_smoke.kernels(clock, expect_interpret=True,
                        flash_shape=(1, 2, 128, 128), dtype="float32",
                        reduce_elems=1 << 14, rope_shape=(1, 64, 2, 32),
-                       ssm_shape=(1, 44, 4, 8, 2, 8, 16))
+                       ssm_shape=(1, 44, 4, 8, 2, 8, 16),
+                       ssm_kernel_shapes=((1, 44, 4, 64, 2, 128, 8),))
     with pytest.raises(RuntimeError, match="interpret resolved to True"):
         chip_smoke.kernels(clock)
     assert clock.cold > 0 and clock.steady_calls > 0

@@ -230,7 +230,8 @@ def test_without_documents_the_scan_and_the_mixer_hold_no_mask():
     x, dt, a, b, c = scan_inputs(0, 16)
     text = lambda *doc: str(jax.make_jaxpr(
         lambda *args: mamba.ssd_chunked(*args, 8, *doc))(x, dt, a, b, c))
-    assert " eq " not in text() and " eq " in text(doc_of(16, [5]))
+    # the primitive, not a variable the printer happens to name ``eq``
+    assert "= eq " not in text() and "= eq " in text(doc_of(16, [5]))
     nemo = config.load_model_config(
         os.path.join(BENCH, "configs", "nemotron3-super-train-1chip.json"),
         hidden_size=64, head_dim=16, num_attention_heads=4,
@@ -244,7 +245,7 @@ def test_without_documents_the_scan_and_the_mixer_hold_no_mask():
     p = {k: 0.1 * jnp.ones(v) for k, v in shapes.items()}
     text = str(jax.make_jaxpr(lambda p, x: mamba.mamba_mixer(p, x, nemo)[0])(
         p, jnp.ones((1, 32, 64))))
-    assert " eq " not in text and "psum" not in text
+    assert "= eq " not in text and "psum" not in text
 
 
 def test_a_packed_rows_scan_convolution_and_attention_are_its_documents():

@@ -484,6 +484,64 @@ def test_granite_train_step_aot_compiles_from_the_cells_configuration(
         text = f.read()
     assert "s8[1,16384,2048]" in text
     assert not re.search(r"= (f32|bf16)\[(\d+,)*16384,16384[\],]", text)
+    # the scan on its kernels (PR 70) under ``otpu_ssm_scan``, in each of
+    # the two runs of Mamba layers: the forward kernel in the forward and
+    # the recomputed pass, the backward kernel once; no loop of XLA's
+    # there, and no (chunks, heads, chunk, chunk) array anywhere
+    scan = sorted({k for k in kernels if "/otpu_ssm_scan/" in k})
+    assert sorted({(k.split("/")[0], "rematted_computation" in k,
+                    k.split("/")[-2]) for k in scan}) == [
+        ("jvp(otpu_layers)", False, "otpu_ssd_scan_fwd"),
+        ("transpose(jvp(otpu_layers))", False, "otpu_ssd_scan_bwd"),
+        ("transpose(jvp(otpu_layers))", True, "otpu_ssd_scan_fwd")], scan
+    assert not [line for line, path in op_paths(row)
+                if "/otpu_ssm_scan/" in path and " while(" in line]
+    assert not re.search(r"f32\[64,32,256,256\]", text)
+
+
+@pytest.fixture(scope="module")
+def nemotron_scan_rows():
+    """One child for the scan's two kernels at Nemotron-3-Super's shape
+    (the cell's other cases are ``test_pallas_aot.py``'s; about 10 s)."""
+    return rows_with_texts("nemotron3_ssd_scan")
+
+
+@pytest.mark.parametrize("rows,cell,arrays_gb", [
+    ("granite_rows", "granite", 1.5), ("nemotron_scan_rows", "nemotron3", 0.5)])
+def test_the_ssd_scan_kernels_aot_compile_at_a_cells_shape(rows, cell,
+                                                           arrays_gb, request):
+    """``mamba._kernel_scan`` where Mosaic compiles, x, B and C read from
+    the convolution's one array: Granite's 32 heads of 64 over 16,384
+    positions in chunks of 256 under a packed row's documents, Nemotron's
+    16 heads over 8,192 in chunks of 128 without: the forward alone is one
+    kernel call and no loop; its gradient is the forward kernel, which
+    also writes the entering states (67 MB at Granite's shape), and the
+    backward kernel; every product of either is float32 at the highest
+    precision and nothing in them is bfloat16."""
+    rows = request.getfixturevalue(rows)
+    fwd, bwd = (rows[f"{cell}_ssd_scan_{way}"]
+                for way in ("forward", "backward"))
+    for row, calls in ((fwd, 1), (bwd, 2)):
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        assert row["entry_ops"].get("custom-call") == calls, row["entry_ops"]
+        assert "while" not in row["entry_ops"], row["entry_ops"]
+    with open(bwd["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    bodies = kernel_bodies(text, "otpu_ssd_scan_")
+    assert sorted(bodies) == ["otpu_ssd_scan_bwd", "otpu_ssd_scan_fwd"]
+    for name, body in bodies.items():
+        products = [ln for ln in body.split("\n") if "tpu.matmul" in ln]
+        assert len(products) > 10, (name, len(products))
+        assert all("contract_precision<fp32>" in ln
+                   and "xf32>" in ln and "bf16" not in ln
+                   for ln in products), name
+        assert "bf16" not in body, name
+    # no (.., chunk, heads, p) view of x and no (chunks, heads, chunk,
+    # chunk) array around the kernels
+    assert not re.search(r"f32\[1,64,(256|128),1,(32|16),64\]", text)
+    assert not re.search(r"f32\[64,(32|16),(256|128),(256|128)\]", text)
+    # operands and results, the states
+    assert bwd["peak_bytes"] < arrays_gb * (1 << 30)
 
 
 @pytest.fixture(scope="module")
