@@ -76,7 +76,20 @@ def supported(taps: int, c: int, s: int) -> bool:
     channels and ``s`` positions: channels in whole tiles of 128 lanes,
     the ``taps - 1`` rows a step carries no more than one sublane tile.
     Any length: it is padded to whole row tiles (``row_tile``)."""
-    return c % LANES == 0 and 1 <= taps <= HALO + 1 and s >= 1
+    return not refusal(taps, c, s)
+
+
+def refusal(taps: int, c: int, s: int) -> str:
+    """Why the kernels have no tiles for such a convolution ("": they
+    have): the clause of ``supported`` that fails first."""
+    if c % LANES:
+        return f"{c} channels are no whole lane tiles of {LANES}"
+    if not 1 <= taps <= HALO + 1:
+        return (f"{taps} taps: a step carries at most one sublane tile's "
+                f"{HALO} rows")
+    if s < 1:
+        return "no position"
+    return ""
 
 
 def _sub_rows(rows: int) -> int:

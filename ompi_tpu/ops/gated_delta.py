@@ -92,8 +92,24 @@ def supported(chunk: int, dk: int, dv: int, r: int, s: int) -> bool:
     head's columns are a block of the (b, s, heads x 128) array), a chunk
     that is whole sublanes and divides a step's rows, and a state (dk, r
     x dv) that is a few tiles.  Any length: it is padded to whole steps."""
-    return (dk == LANES and dv == LANES and chunk % 8 == 0
-            and chunks_a_step(chunk) is not None and 1 <= r <= 8 and s >= 1)
+    return not refusal(chunk, dk, dv, r, s)
+
+
+def refusal(chunk: int, dk: int, dv: int, r: int, s: int) -> str:
+    """Why the kernels have no tiles for such a rule ("": they have): the
+    clause of ``supported`` that fails first."""
+    for what, width in (("key", dk), ("value", dv)):
+        if width != LANES:
+            return f"a {what} head is {width} wide, not {LANES}"
+    if chunk % 8:
+        return f"a chunk of {chunk} positions is no whole sublanes of 8"
+    if chunks_a_step(chunk) is None:
+        return f"chunk {chunk} does not divide a step's {STEP_ROWS} rows"
+    if not 1 <= r <= 8:
+        return f"{r} value heads a key head: the state is not 1 to 8 tiles"
+    if s < 1:
+        return "no position"
+    return ""
 
 
 def _at(shape, axis):

@@ -96,11 +96,29 @@ def tgmm_tiles(m: int, k: int, n: int, acc: bool = False):
     return best and best[1:]
 
 
+def refusal(m: int, k: int, n: int) -> str:
+    """Why a grouped matmul ``(m, k) x (g, k, n)`` or one of its
+    transposed products has no tiles ("": all three have): the clause of
+    ``supported`` that fails first."""
+    tm = min(m, ROW_TILE)
+    if m % tm or tm % 16:
+        return (f"{m} rows are no whole row tiles of {tm}" if m % tm
+                else f"a row tile of {tm} rows is no whole sublane tiles "
+                "of 16")
+    for width in (k, n):
+        if width % LANES:
+            return f"a width of {width} is not a multiple of {LANES}"
+    if not (gmm_tiles(m, k, n) and gmm_tiles(m, n, k)
+            and tgmm_tiles(m, k, n)):
+        return (f"no tiles of ({m}, {k}) x ({k}, {n}) fit "
+                f"{VMEM_TILES >> 20} MiB of VMEM")
+    return ""
+
+
 def supported(m: int, k: int, n: int) -> bool:
     """Whether a grouped matmul ``(m, k) x (g, k, n)`` and both its
     transposed products have tiles."""
-    return bool(gmm_tiles(m, k, n) and gmm_tiles(m, n, k)
-                and tgmm_tiles(m, k, n))
+    return not refusal(m, k, n)
 
 
 def group_tiles(sizes, m: int, tm: int, visit_empty: bool):

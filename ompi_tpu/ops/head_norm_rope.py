@@ -82,12 +82,25 @@ SUB_ROWS = 512
 VMEM_LIMIT = 64 << 20
 
 
+def refusal(hd: int, rotary_width: int | None, gated: bool) -> str:
+    """Why the kernels have no tiles for heads of ``hd`` ("": they have):
+    the clause of ``supported`` that fails first."""
+    if hd % LANES:
+        return f"head width {hd} is not a multiple of {LANES}"
+    if rotary_width not in (None, hd):
+        return (f"RoPE turns {rotary_width} of a head's {hd} entries: the "
+                "partner is no rotation of the tile")
+    if gated:
+        return "a gate stands behind every query head in the product"
+    return ""
+
+
 def supported(hd: int, rotary_width: int | None, gated: bool) -> bool:
     """Whether the kernels have tiles for heads of ``hd``: whole tiles of
     128 lanes, turned whole (``rotary_width`` None or ``hd``: the partner
     is one rotation of the tile), no gate behind the head in the
     product."""
-    return hd % LANES == 0 and rotary_width in (None, hd) and not gated
+    return not refusal(hd, rotary_width, gated)
 
 
 def row_tile(s: int) -> int:

@@ -64,12 +64,25 @@ def block_rows(rows: int) -> int:
     return min(rows, BLOCK_ROWS)
 
 
+def refusal(rows: int, d: int, dtype=jnp.float32) -> str:
+    """Why ``row_scatter_add`` has no kernel for a chunk of ``rows`` rows
+    of ``d`` entries of ``dtype`` ("": it has one): the clause of
+    ``supported`` that fails first."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return f"the sums are {jnp.dtype(dtype).name}, not float32"
+    if d % LANES:
+        return f"a row of {d} entries is no whole lane tiles of {LANES}"
+    if rows % block_rows(rows):
+        return (f"a chunk of {rows} rows is no whole blocks of "
+                f"{block_rows(rows)}")
+    return ""
+
+
 def supported(rows: int, d: int, dtype=jnp.float32) -> bool:
     """Whether ``row_scatter_add`` has a kernel for a chunk of ``rows``
     rows of ``d`` entries of ``dtype``, by shape alone: float32 sums,
     rows of whole lane tiles, whole blocks."""
-    return (jnp.dtype(dtype) == jnp.float32 and d % LANES == 0
-            and rows % block_rows(rows) == 0)
+    return not refusal(rows, d, dtype)
 
 
 def tile_shape(d: int) -> tuple:

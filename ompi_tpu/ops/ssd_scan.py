@@ -87,9 +87,26 @@ def supported(chunk: int, p: int, n: int, heads: int, groups: int,
     chunk of whole lane blocks, since a chunk's matrices are (chunk,
     chunk), of at most 512 (four such matrices a head in VMEM).  Any
     length: it is padded to whole chunks."""
-    return (n == LANES and groups >= 1 and heads % groups == 0
-            and chunk % LANES == 0 and chunk <= 4 * LANES and s >= 1
-            and heads_a_step(chunk, p, heads // groups) is not None)
+    return not refusal(chunk, p, n, heads, groups, s)
+
+
+def refusal(chunk: int, p: int, n: int, heads: int, groups: int,
+            s: int) -> str:
+    """Why the kernels have no tiles for such a scan ("": they have): the
+    clause of ``supported`` that fails first."""
+    if n != LANES:
+        return f"a state of {n} a channel is not a tile's {LANES} lanes"
+    if groups < 1 or heads % groups:
+        return f"{heads} heads do not divide into {groups} B/C groups"
+    if chunk % LANES or chunk > 4 * LANES:
+        return (f"a chunk of {chunk} positions is not 1 to 4 lane blocks "
+                f"of {LANES}")
+    if s < 1:
+        return "no position"
+    if heads_a_step(chunk, p, heads // groups) is None:
+        return (f"heads {p} wide do not fill lane blocks that divide a "
+                f"group's {heads // groups} heads")
+    return ""
 
 
 def _spread(by_head, h0, tile, p):

@@ -15,12 +15,11 @@ import jax.numpy as jnp
 from ompi_tpu.parallel.causal import (ATTN_KEEPS,
                                       block_diffusion_flash_attention,
                                       causal_flash_attention,
-                                      document_selection,
-                                      selected_flash_attention)
+                                      document_selection, flash_on_kernels,
+                                      pass_counts, selected_flash_attention)
 from ompi_tpu.parallel.layers import (matmul, project_rope, rmsnorm_gain,
                                       rope, rope_tables)
-from ompi_tpu.parallel.sublayer import Sublayer
-from ompi_tpu.runtime import spc
+from ompi_tpu.parallel.sublayer import INTERPRET, Sublayer, held
 
 
 def olmoe_attention(p, x, cfg, *, interpret: bool, at=None):
@@ -132,15 +131,20 @@ def _kernel_heads_bwd(heads, eps, dtype, res, do):
 _kernel_heads.defvjp(_kernel_heads_fwd, _kernel_heads_bwd)
 
 
-def _qk_on_kernels(interpret, cfg, turned, gated) -> bool:
-    """Whether q's and k's way to the flash kernels runs on the Pallas
-    kernels: where Mosaic compiles (``interpret`` false: a TPU), RoPE
-    turns the layer and the head has tiles."""
-    if interpret or not turned:
-        return False
+def qk_on_kernels(interpret, cfg, turned, gated) -> tuple:
+    """``(on_kernel, why)`` of q's and k's way to the flash kernels: on
+    the Pallas kernels where Mosaic compiles (``interpret`` false: a TPU),
+    RoPE turns the layer and the head has tiles
+    (``ops/head_norm_rope.refusal``); ``why`` names the clause that
+    refused, "" where the kernels are taken."""
+    if interpret:
+        return False, INTERPRET
+    if not turned:
+        return False, "RoPE does not turn the layer"
     from ompi_tpu.ops import head_norm_rope
 
-    return head_norm_rope.supported(cfg.head_width, cfg.rotary_width, gated)
+    why = head_norm_rope.refusal(cfg.head_width, cfg.rotary_width, gated)
+    return not why, why
 
 
 def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
@@ -155,12 +159,12 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
     (at ``positions``), all float32, then the cast to ``compute_dtype``.
 
     Where Mosaic compiles, RoPE turns the layer and the head has tiles
-    (``_qk_on_kernels``) each of the two is one pass of
+    (``qk_on_kernels``) each of the two is one pass of
     ``ops/head_norm_rope``'s kernel over its projection's product where it
     lies, and one back, with the norm's step or without; elsewhere the
     ``jnp`` lines (``normed_turned_heads`` between a transposition and a
-    cast).  SPC ``attn_qk_built`` counts both ways' q and k while steps
-    are traced, ``attn_qk_kernel_built`` the kernels'.
+    cast).  SPC ``attn_qk_built`` counts both ways' q and k a layer
+    application, ``attn_qk_kernel_built`` the kernels' (``qk_plan``).
 
     Returns (q (b, nh, s, hd), k (b, nkv, s, hd), the gate (b, nh, s, hd)
     float32 or None, and of the first query head and the first key-value
@@ -173,14 +177,12 @@ def normed_qk(p, h, cfg, *, interpret: bool, turned: bool = True,
     nh, nkv, dt = cfg.n_heads_here, cfg.n_kv_heads_here, cfg.compute_dtype
     gated = p["wq"].shape[-1] == 2 * p["wo"].shape[0]
     gains = [p.get(g) for g in ("q_norm", "k_norm")]
-    on_kernels = _qk_on_kernels(interpret, cfg, turned, gated)
-    spc.record("attn_qk_built", 2)
+    on_kernels, _ = qk_on_kernels(interpret, cfg, turned, gated)
     side = lambda a, c: jnp.concatenate([a, c], -1).reshape(b * s, -1)
     gate = None
     if on_kernels:
         from ompi_tpu.ops.head_norm_rope import signed_sin
 
-        spc.record("attn_qk_kernel_built", 2)
         hd = cfg.head_width
         cos, sin = rope_tables(s, hd, cfg.rope_theta, positions)
         sin = signed_sin(sin)
@@ -368,6 +370,43 @@ def _gqa_reports(cfg, kind: str, windowed: bool,
     return dict.fromkeys(keys, 1)
 
 
+def qk_plan(cfg, interpret: bool, turned: bool = True) -> tuple:
+    """(``normed_qk``'s decision for a layer that RoPE turns or not, the
+    SPC counters one call of it moves: ``attn_qk_built`` 2, q and k, and
+    ``attn_qk_kernel_built`` 2 where they go by the kernels).  A gate
+    stands behind the query heads under ``attn_output_gate``
+    (``gqa_shapes``), which is what ``normed_qk`` reads off ``wq``."""
+    on, why = qk_on_kernels(interpret, cfg, turned, cfg.attn_output_gate)
+    return (on, why), {"attn_qk_built": 2, "attn_qk_kernel_built": 2 * on}
+
+
+def _stacked_plan(cfg, b, s, interpret) -> dict:
+    """What OLMoE's attention and latent attention hold: every query head
+    its own key and value head."""
+    nh = cfg.num_attention_heads
+    return held(pass_counts(b, nh, nh, s, min(cfg.attn_block, s)),
+                flash=flash_on_kernels(interpret))
+
+
+def _gqa_plan(cfg, b, s, interpret, kind: str = "", windowed: bool = False,
+              diffused: bool = False, qk_norm=None) -> dict:
+    """What ``gqa_attention`` holds of a layer of ``kind``, as it
+    branches: under block diffusion's mask no document is read, under a
+    document mask no window; q and k are ``normed_qk``'s where the layer
+    holds a QK-norm or the model goes by ``layer_types``."""
+    doc = cfg.eos_token_here >= 0 and not diffused
+    counts = pass_counts(
+        b, cfg.n_heads_here, cfg.n_kv_heads_here, s,
+        min(cfg.attn_block, s // 2 if diffused else s),
+        window=cfg.sliding_window if windowed and not doc else None,
+        bd=cfg.block_length if diffused else None, doc=doc)
+    parts = {"flash": flash_on_kernels(interpret)}
+    if (cfg.qk_norm if qk_norm is None else qk_norm) or cfg.layer_types:
+        parts["qk"], moved = qk_plan(cfg, interpret, kind in cfg.rope_kinds)
+        counts.update(moved)
+    return held(counts, **parts)
+
+
 def _gqa(name: str, group: str, scope: str, windowed: bool = False,
          diffused: bool = False, post_norm: str = "") -> Sublayer:
     """A ``layer_types`` model's grouped-query attention by its name."""
@@ -377,7 +416,7 @@ def _gqa(name: str, group: str, scope: str, windowed: bool = False,
         run=functools.partial(gqa_attention, **bound), shapes=gqa_shapes,
         undecayed=("ln1", "q_norm", "k_norm"),
         reports=functools.partial(_gqa_reports, **bound), keeps=ATTN_KEEPS,
-        post_norm=post_norm)
+        post_norm=post_norm, plan=functools.partial(_gqa_plan, **bound))
 
 
 #: lfm2's and qwen3_next's attention, smallthinker's in full, ouro's (the
@@ -394,11 +433,13 @@ DIFFUSED = _gqa("block_diffusion_attention", "bd", "otpu_bd", diffused=True)
 SHARED_KV = Sublayer(
     name="*", group="attn", scope="otpu_attention", run=gqa_attention,
     shapes=functools.partial(gqa_shapes, qk_norm=False), undecayed=("ln1",),
-    keeps=ATTN_KEEPS)
+    keeps=ATTN_KEEPS, plan=functools.partial(_gqa_plan, qk_norm=False))
 #: the stacked tree's two (OLMoE's; JoyAI's where ``kv_lora_rank`` is set)
 OLMOE = Sublayer(
     scope="otpu_attention", run=olmoe_attention, shapes=_olmoe_shapes,
-    undecayed=("ln1", "q_norm", "k_norm"), keeps=ATTN_KEEPS)
+    undecayed=("ln1", "q_norm", "k_norm"), keeps=ATTN_KEEPS,
+    plan=_stacked_plan)
 MLA = Sublayer(
     scope="otpu_mla", run=mla_attention, shapes=_mla_shapes,
-    undecayed=("ln1", "q_a_norm", "kv_a_norm"), keeps=ATTN_KEEPS)
+    undecayed=("ln1", "q_a_norm", "kv_a_norm"), keeps=ATTN_KEEPS,
+    plan=_stacked_plan)

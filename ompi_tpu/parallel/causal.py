@@ -5,8 +5,10 @@ noisy and a clean copy of a sequence (``block_diffusion_flash_attention``:
 the one mask here that is not under the diagonal), each a ``custom_vjp`` over
 ``ops/flash_attention``'s kernels with a ``jnp`` twin that the CPU and
 the tests' references run, the backward pass's two walks of the block
-pairs, and the SPC counters of what was built.  The attention sublayers
-(``parallel/attention.py``, ``parallel/dsa.py``) stand on it.
+pairs, and what a pass holds, from the shapes (``flash_on_kernels``,
+``pass_counts``: an attention sublayer's ``plan`` reads them).  The
+attention sublayers (``parallel/attention.py``, ``parallel/dsa.py``) stand
+on it.
 """
 from __future__ import annotations
 
@@ -18,7 +20,15 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.parallel.layers import contract
-from ompi_tpu.runtime import spc
+from ompi_tpu.parallel.sublayer import on_mosaic
+
+
+def flash_on_kernels(interpret: bool) -> tuple:
+    """``(on_kernel, why)`` of causal attention's two passes, under any of
+    this module's masks: ``ops/flash_attention``'s kernels wherever Mosaic
+    compiles, every shape the callers send having tiles; their ``jnp``
+    twins elsewhere."""
+    return on_mosaic(interpret)
 
 
 def _tri_bias(block: int):
@@ -138,7 +148,7 @@ def _causal_fwd_blocks(q, k, v, block, interpret, window=None, select=None,
     sqrt(hd)``, and every call is the one without the argument) is the
     scores' own, handed to the kernel or multiplied in here."""
     w = _window_in_blocks(window, block, q.shape[2])
-    if not interpret:
+    if flash_on_kernels(interpret)[0]:
         from ompi_tpu.ops.flash_attention import flash_causal_forward
 
         if bd is not None:
@@ -233,36 +243,49 @@ def causal_flash_attention(q, k, v, block: int, interpret: bool,
                               scale=scale)[0]
 
 
-def _count_built(q, k, block, window, bd=None) -> None:
-    """SPC ``attn_built``: the causal attention passes made, forward
-    rule or backward rule, while steps were traced (JAX traces a pass
-    more than once); ``attn_shared_kv_built``: those of them whose k and
-    v came with fewer heads than q and went to the kernels, or their
-    twins, that way; ``attn_window_built``: those made under a window;
-    ``attn_pairs_walked`` the block pairs the passes walk and
-    ``attn_pairs_causal`` those full causal passes of their lengths
-    would.  Under block diffusion's mask (``bd``) also ``bd_built``, the
-    passes made under it, ``bd_pairs_visible`` the (query, key) pairs a
-    pass attends to (``bd_visible_pairs``) and ``bd_pairs_causal`` those
-    a causal pass over its rows would, both from the shapes."""
-    nb = q.shape[2] // block
-    w = _window_in_blocks(window, block, q.shape[2])
-    spc.record("attn_built", 1)
-    if k.shape[1] < q.shape[1]:
-        spc.record("attn_shared_kv_built", 1)
-    if w is not None:
-        spc.record("attn_window_built", 1)
-    spc.record("attn_pairs_walked", len(_walked_pairs(nb, block, w, bd)))
-    spc.record("attn_pairs_causal", nb * (nb + 1) // 2)
+def pass_counts(b: int, h: int, n_kv: int, s: int, block: int, window=None,
+                bd=None, topk=None, doc: bool = False) -> dict:
+    """The SPC counters one attention layer application moves (an
+    attention sublayer's ``plan``; the backward rule is no second
+    application), from the shapes: q (b, h, s, .) on ``n_kv`` key-value
+    heads in blocks of ``block``.  ``attn_built`` 1;
+    ``attn_shared_kv_built`` 1 where k and v come with fewer heads than q
+    and go to the kernels, or their twins, that way; ``attn_window_built``
+    1 under a ``window`` shorter than the sequence; ``attn_pairs_walked``
+    the block pairs a pass walks and ``attn_pairs_causal`` those a full
+    causal pass of its length would.  Under block diffusion's mask (``bd``)
+    also ``bd_built`` 1, ``bd_pairs_visible`` the (query, key) pairs a
+    pass attends to (``bd_visible_pairs``) and ``bd_pairs_causal`` those a
+    causal pass over its rows would.  Under a learned selection (``topk``,
+    the most keys a query selects) ``dsa_built`` 1, ``dsa_keys_selected``
+    the (query, key) pairs attended to, ``min(t + 1, topk)`` a query,
+    ``dsa_keys_causal`` those a full causal pass would, and
+    ``dsa_mask_bytes`` the bytes of the selection a pass reads, packed
+    eight keys a byte.  Under a document mask (``doc``) ``doc_built`` 1,
+    the mask made (``document_selection``); nothing was chosen, and
+    ``dsa_*`` count nothing."""
+    nb = s // block
+    w = _window_in_blocks(window, block, s)
+    out = {"attn_built": 1, "attn_shared_kv_built": int(n_kv < h),
+           "attn_window_built": int(w is not None),
+           "attn_pairs_walked": len(_walked_pairs(nb, block, w, bd)),
+           "attn_pairs_causal": nb * (nb + 1) // 2}
     if bd is not None:
-        b, rows = q.shape[0], q.shape[2]
-        spc.record("bd_built", 1)
-        spc.record("bd_pairs_visible", b * bd_visible_pairs(rows // 2, bd))
-        spc.record("bd_pairs_causal", b * rows * (rows + 1) // 2)
+        out.update(bd_built=1,
+                   bd_pairs_visible=b * bd_visible_pairs(s // 2, bd),
+                   bd_pairs_causal=b * s * (s + 1) // 2)
+    if topk is not None:
+        full = min(s, topk)
+        out.update(dsa_built=1, dsa_mask_bytes=b * s * s // 8,
+                   dsa_keys_selected=b * (full * (full + 1) // 2
+                                          + (s - full) * topk),
+                   dsa_keys_causal=b * s * (s + 1) // 2)
+    if doc:
+        out["doc_built"] = 1
+    return out
 
 
 def _causal_fwd(q, k, v, block, interpret, window=None, scale=None):
-    _count_built(q, k, block, window)
     o, lse = _causal_fwd_blocks(q, k, v, block, interpret, window,
                                 scale=scale)
     o = checkpoint_name(o, ATTN_OUT)
@@ -298,13 +321,12 @@ def _bwd_pair(qi, kj, vj, doi, lse_i, delta_i, bias, scale, dt):
 def _causal_bwd(block, interpret, window, res, do, select=None, bd=None,
                 scale=None):
     q, k, v, o, lse = res
-    _count_built(q, k, block, window, bd)
     h, n_kv = q.shape[1], k.shape[1]
     nb = q.shape[2] // block
     w = _window_in_blocks(window, block, q.shape[2])
     do = do.astype(jnp.float32)
     delta = jnp.sum(do * o, axis=-1)                     # (b, h, s)
-    if not interpret:
+    if flash_on_kernels(interpret)[0]:
         return _causal_bwd_fused(q, k, v, do, lse, delta, block, w, select,
                                  bd, scale)
     if nb > UNROLLED_BLOCKS:
@@ -438,27 +460,6 @@ causal_flash_attention.defvjp(_causal_fwd, _causal_bwd_rule)
 
 
 # -- learned sparse attention (DeepSeek-V3.2's DSA) ---------------------------
-def _count_dsa(q, topk: int) -> None:
-    """SPC ``dsa_built``: the attention passes made under a selection,
-    forward rule or backward rule, while steps were traced (as
-    ``attn_window_built``); ``dsa_keys_selected`` the (query, key) pairs
-    those passes attend to, ``min(t + 1, topk)`` a query,
-    ``dsa_keys_causal`` those full causal passes of their lengths would,
-    and ``dsa_mask_bytes`` the bytes of the selection a pass reads, packed
-    eight keys a byte, all from the shapes.  Under a document mask
-    (``topk`` None) nothing was chosen and nothing is counted here
-    (``document_selection`` counts ``doc_built``)."""
-    if topk is None:
-        return
-    b, _, s, _ = q.shape
-    full = min(s, topk)
-    spc.record("dsa_built", 1)
-    spc.record("dsa_mask_bytes", b * s * s // 8)
-    spc.record("dsa_keys_selected",
-               b * (full * (full + 1) // 2 + (s - full) * topk))
-    spc.record("dsa_keys_causal", b * s * (s + 1) // 2)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
                              topk: int, scale=None):
@@ -468,7 +469,8 @@ def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
     ``pack_selection`` packs a given mask), says which keys u <= t query
     t attends to (every row selects a key).  Returns (o (b, h, s, hv)
     float32, the logsumexp (b, h, s) float32 over the selected keys);
-    ``topk``, the most keys a row selects, is read by the counters alone.
+    ``topk``, the most keys a row selects, is read by nothing here (a
+    sublayer's plan counts by it: ``pass_counts``).
     No gradient passes through the selection, and none through the
     logsumexp handed out (what reads it reads a constant).  Both passes
     walk every causal block pair under its tile of the selection
@@ -482,8 +484,6 @@ def selected_flash_attention(q, k, v, select, block: int, interpret: bool,
 
 
 def _selected_fwd(q, k, v, select, block, interpret, topk, scale=None):
-    _count_built(q, k, block, None)
-    _count_dsa(q, topk)
     o, lse = _causal_fwd_blocks(q, k, v, block, interpret, select=select,
                                 scale=scale)
     o = checkpoint_name(o, ATTN_OUT)
@@ -493,7 +493,6 @@ def _selected_fwd(q, k, v, select, block, interpret, topk, scale=None):
 
 def _selected_bwd(block, interpret, topk, scale, res, cts):
     *res, select = res
-    _count_dsa(res[0], topk)
     return (*_causal_bwd(block, interpret, None, tuple(res), cts[0],
                          select=select, scale=scale), None)
 
@@ -507,7 +506,6 @@ def document_selection(doc):
     kernels' flags.  32 MB of bits a row of 16,384."""
     from ompi_tpu.ops.sparse_attention import pack_selection
 
-    spc.record("doc_built", 1)
     t = jnp.arange(doc.shape[1])
     return pack_selection((t[:, None] >= t[None, :])
                           & (doc[:, :, None] == doc[:, None, :]))
@@ -554,7 +552,6 @@ def _bd_fwd(q, k, v, block, interpret, bl):
     if (q.shape[2] // 2) % block:
         raise ValueError(f"a half of {q.shape[2] // 2} rows is no whole "
                          f"number of blocks of {block}")
-    _count_built(q, k, block, None, bl)
     o, lse = _causal_fwd_blocks(q, k, v, block, interpret, bd=bl)
     o = checkpoint_name(o, ATTN_OUT)
     lse = checkpoint_name(lse, ATTN_LSE)

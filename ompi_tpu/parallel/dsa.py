@@ -14,12 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ompi_tpu.parallel.attention import gqa_shapes, normed_qk
-from ompi_tpu.parallel.causal import (ATTN_KEEPS,
-                                                selected_flash_attention)
+from ompi_tpu.parallel.attention import gqa_shapes, normed_qk, qk_plan
+from ompi_tpu.parallel.causal import (ATTN_KEEPS, flash_on_kernels,
+                                      pass_counts, selected_flash_attention)
 from ompi_tpu.parallel.layers import (contract, layernorm, matmul,
                                       rmsnorm_gain, rope)
-from ompi_tpu.parallel.sublayer import Sublayer, zeros
+from ompi_tpu.parallel.sublayer import Sublayer, held, on_mosaic, zeros
 
 
 # what a layer's ``jax.checkpoint`` keeps of a learned sparse attention
@@ -79,6 +79,13 @@ def select_topk(scores, first: int, topk: int):
     return seen & ((key > tau) | ((key == tau) & (col <= last)))
 
 
+def index_on_kernels(interpret: bool) -> tuple:
+    """``(on_kernel, why)`` of the indexer's selection and of its
+    alignment loss: ``ops/sparse_attention``'s kernels wherever Mosaic
+    compiles, their ``jnp`` twins by blocks of rows elsewhere."""
+    return on_mosaic(interpret)
+
+
 def _index_select_blocks(qi, ki, w, topk: int, rows: int, interpret: bool):
     """(the selection (b, s, s / 8) int8, packed eight keys a byte as the
     kernel packs it, each row's logsumexp over its selected scores (b, s)
@@ -90,7 +97,7 @@ def _index_select_blocks(qi, ki, w, topk: int, rows: int, interpret: bool):
     only one block's (rows, s) scores are ever held."""
     from ompi_tpu.ops.sparse_attention import index_select, pack_selection
 
-    if not interpret:
+    if index_on_kernels(interpret)[0]:
         return index_select(qi, ki, w, topk=topk, interpret=False)
     b, heads, s, di = qi.shape
     rows = rows if s % rows == 0 else s
@@ -160,7 +167,7 @@ def _index_loss_blocks(qi, ki, w, q, k, lse, ilse, select, rows, interpret):
     ``ops/sparse_attention.index_loss``, which makes the four in one pass
     over the causal tile pairs; elsewhere ``_index_loss_rows`` and its
     autodiff."""
-    if not interpret:
+    if index_on_kernels(interpret)[0]:
         from ompi_tpu.ops.sparse_attention import index_loss
 
         return index_loss(q, k, lse, qi, ki, w, ilse, select,
@@ -301,6 +308,16 @@ def _dsa_shapes(cfg) -> dict:
             "index_k_bias": (di,), "index_ww": (d, cfg.index_heads)}
 
 
+def _dsa_plan(cfg, b, s, interpret) -> dict:
+    """What ``dsa_attention`` holds: attention under the selection, q and
+    k by ``normed_qk`` with RoPE on, the indexer's two kernels."""
+    counts = pass_counts(b, cfg.n_heads_here, cfg.n_kv_heads_here, s,
+                         min(cfg.attn_block, s), topk=cfg.index_topk)
+    qk, moved = qk_plan(cfg, interpret)
+    return held({**counts, **moved}, flash=flash_on_kernels(interpret),
+                qk=qk, index=index_on_kernels(interpret))
+
+
 #: Keye-VL-2.0's ``sparse_attention`` layers' operator
 DSA = Sublayer(
     name="sparse_attention", group="dsa", scope="otpu_dsa",
@@ -312,4 +329,5 @@ DSA = Sublayer(
         "dsa_kl_at": 0, **{"dsa_" + k: 1 for k in (
             "ki_seq", "k_seq", "v_seq", "kall_seq", "qi_at", "w_at",
             "index_at", "q_at", "lse_at", "o_at")}},
-    keeps=ATTN_KEEPS + (DSA_SELECTION, DSA_INDEX_LSE, DSA_LOSS))
+    keeps=ATTN_KEEPS + (DSA_SELECTION, DSA_INDEX_LSE, DSA_LOSS),
+    plan=_dsa_plan)

@@ -20,8 +20,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ompi_tpu.parallel.layers import (cast_param, matmul, relu2,
                                       rmsnorm_gain, swiglu)
-from ompi_tpu.parallel.sublayer import Sublayer
-from ompi_tpu.runtime import spc
+from ompi_tpu.parallel.sublayer import INTERPRET, Sublayer, held
 
 
 def route_topk(logits, top_k: int, normalize: bool = False):
@@ -50,16 +49,36 @@ def sorted_dispatch(experts, n_experts: int):
     return order // k, place.reshape(t, k), sizes
 
 
-def _count_built(products: int, on_kernel: bool) -> None:
-    """SPC ``moe_gmm_built``: the grouped matmuls ``_grouped_matmul``
-    made, forward or transposed, while steps were traced;
-    ``moe_gmm_kernel_built``: those of them made on the Pallas kernel.
-    JAX traces a product more than once and makes a ``ragged_dot``'s
-    transposes itself: what reads is the second over the first, 0 where
-    no shape took the kernel and 1 where every one did."""
-    spc.record("moe_gmm_built", products)
-    if on_kernel:
-        spc.record("moe_gmm_kernel_built", products)
+def gmm_on_kernel(interpret: bool, compute_dtype, m: int, k: int,
+                  n: int) -> tuple:
+    """``(on_kernel, why)`` of a grouped matmul ``(m, k) x (g, k, n)``
+    with its two transposes: on the Pallas kernel
+    (``ops/grouped_matmul``) where Mosaic compiles (``interpret`` false:
+    a TPU), the inputs are bfloat16 and the shape has tiles (its
+    ``refusal``); ``why`` names the clause that refused, "" where the
+    kernel is taken."""
+    if interpret:
+        return False, INTERPRET
+    if jnp.dtype(compute_dtype) != jnp.bfloat16:
+        return False, (f"compute_dtype {jnp.dtype(compute_dtype).name}: "
+                       "the kernel's inputs are bfloat16")
+    from ompi_tpu.ops import grouped_matmul as kernel
+
+    why = kernel.refusal(m, k, n)
+    return not why, why
+
+
+def scatter_on_kernel(interpret: bool, rows: int, d: int, dtype) -> tuple:
+    """``(on_kernel, why)`` of the held experts' loops' row scatter-adds,
+    chunks of ``rows`` rows of ``d`` entries into sums of ``dtype``: on the
+    Pallas row kernel (``ops/row_scatter``) where Mosaic compiles and a
+    row is whole lane tiles (its ``refusal``)."""
+    if interpret:
+        return False, INTERPRET
+    from ompi_tpu.ops import row_scatter
+
+    why = row_scatter.refusal(rows, d, dtype)
+    return not why, why
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -78,7 +97,6 @@ def _kernel_matmul(a, w, sizes, dtype):
 def _kernel_matmul_fwd(a, w, sizes, dtype):
     from ompi_tpu.ops import grouped_matmul as kernel
 
-    _count_built(1, True)
     a16, w16 = a.astype(dtype), cast_param(w, dtype)
     # the empty arrays carry the primals' dtypes to the backward pass
     return kernel.gmm(a16, w16, sizes), (
@@ -88,7 +106,6 @@ def _kernel_matmul_fwd(a, w, sizes, dtype):
 def _kernel_matmul_bwd(dtype, res, ct):
     from ompi_tpu.ops import grouped_matmul as kernel
 
-    _count_built(2, True)
     a16, w16, sizes, a0, w0 = res
     ct = ct.astype(dtype)
     da = kernel.gmm(ct, w16, sizes, transpose_rhs=True)
@@ -104,7 +121,7 @@ def _grouped_matmul(sizes, compute_dtype, interpret: bool):
     stacked matrices ``w`` (group e is the ``sizes[e]`` rows that expert
     e received), inputs in ``compute_dtype``, float32 results.  Where
     Mosaic compiles (``interpret`` false: a TPU), the inputs are bfloat16
-    and the shape has tiles (``ops/grouped_matmul.supported``) it is the
+    and the shape has tiles (``gmm_on_kernel``) it is the
     Pallas kernel (``_kernel_matmul``); everywhere else
     ``lax.ragged_dot``.  And ``transposes(a, w, ct, acc)``, what a
     backward pass that is written out calls (``local_expert_ffn``'s):
@@ -114,17 +131,13 @@ def _grouped_matmul(sizes, compute_dtype, interpret: bool):
     ``ragged_dot``'s own transposes and an add."""
     f32 = jnp.dtype(compute_dtype) == jnp.float32
     prec = jax.lax.Precision.HIGHEST if f32 else None
-    on_mosaic = not interpret and jnp.dtype(compute_dtype) == jnp.bfloat16
-    if on_mosaic:
-        from ompi_tpu.ops import grouped_matmul as kernel
-
     def on_kernel(a, w):
-        return on_mosaic and kernel.supported(*a.shape, w.shape[2])
+        return gmm_on_kernel(interpret, compute_dtype, *a.shape,
+                             w.shape[2])[0]
 
     def gmm(a, w):
         if on_kernel(a, w):
             return _kernel_matmul(a, w, sizes, compute_dtype)
-        _count_built(1, False)
         return jax.lax.ragged_dot(
             a.astype(compute_dtype), cast_param(w, compute_dtype), sizes,
             precision=prec, preferred_element_type=jnp.float32)
@@ -133,7 +146,8 @@ def _grouped_matmul(sizes, compute_dtype, interpret: bool):
         if not on_kernel(a, w):
             da, dw = jax.vjp(gmm, a, w)[1](ct)
             return da, acc + dw
-        _count_built(2, True)
+        from ompi_tpu.ops import grouped_matmul as kernel
+
         ct = ct.astype(compute_dtype)
         da = kernel.gmm(ct, cast_param(w, compute_dtype), sizes,
                         transpose_rhs=True)
@@ -407,11 +421,9 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
     t, d = h.shape
     k, n_here = cfg.num_experts_per_tok, sizes.shape[0]
     rows = chunk_rows(t, k, n_here, cfg.num_experts)
-    on_kernel = False
-    if not interpret:
+    on_kernel, _ = scatter_on_kernel(interpret, rows, d, h.dtype)
+    if on_kernel:
         from ompi_tpu.ops import row_scatter
-
-        on_kernel = row_scatter.supported(rows, d, h.dtype)
     padded = -(-t * k // rows) * rows
     order = jnp.pad(order, (0, padded - t * k))
 
@@ -442,12 +454,10 @@ def local_expert_ffn(h, order, weights, sizes, mats: tuple, cfg,
         """``acc`` with the chunk's live rows ``y``, times ``w`` where
         given, added by token: on the row kernel (``acc`` as tiles a row)
         or by XLA's scatter-add of every row, the dead ones as zeros.
-        SPC ``moe_scatter_built`` counts the loops' scatter-adds made
-        while steps were traced, ``moe_scatter_kernel_built`` those of
-        them on the kernel."""
-        spc.record("moe_scatter_built", 1)
+        SPC ``moe_scatter_built`` counts a layer application's forward
+        loop's, ``moe_scatter_kernel_built`` those on the kernel
+        (``_held_plan``)."""
         if on_kernel:
-            spc.record("moe_scatter_kernel_built", 1)
             offsets = jnp.concatenate(
                 [jnp.zeros((1,), here.dtype), jnp.cumsum(here)])
             # one kernel for both loops (a step builds it once): the
@@ -713,6 +723,51 @@ def _held_reports(cfg) -> dict:
     return dict.fromkeys(keys, 1)
 
 
+def _routed_plan(cfg, interpret, m: int, products: list,
+                 scatter=None) -> dict:
+    """What an expert block holds whose grouped matmuls are ``products``,
+    (k, n) each, over ``m`` rows: SPC ``moe_gmm_built`` counts them a
+    layer application (a gated expert's three, relu2's two; their
+    transposes are the backward pass's and no second application),
+    ``moe_gmm_kernel_built`` those on the kernel; the part is on the
+    kernel where every product is, else the first refused one says why.
+    ``scatter``: a held experts' loop's row scatter-add's decision."""
+    made = [gmm_on_kernel(interpret, cfg.compute_dtype, m, k, n)
+            for k, n in products]
+    on = sum(ok for ok, _ in made)
+    why = next((why for ok, why in made if not ok), "")
+    counts = {"moe_gmm_built": len(made), "moe_gmm_kernel_built": on}
+    parts = {"gmm": (on == len(made), why)}
+    if scatter is not None:
+        parts["scatter"] = scatter
+        counts.update(moe_scatter_built=1,
+                      moe_scatter_kernel_built=int(scatter[0]))
+    return held(counts, **parts)
+
+
+def _sorted_plan(cfg, b, s, interpret) -> dict:
+    """``moe_sorted_block``'s: every slot of the shard in one call."""
+    d, f = cfg.hidden_size, cfg.expert_width
+    return _routed_plan(cfg, interpret, b * s * cfg.num_experts_per_tok,
+                        [(d, f), (d, f), (f, d)])
+
+
+def _held_plan(cfg, b, s, interpret, latent: bool = False) -> dict:
+    """``local_expert_ffn``'s, as ``moe_shared_local_block`` (gated
+    experts on the hidden width) or ``moe_latent_block`` (relu2 experts in
+    the latent) calls it: a trip's ``chunk_rows`` rows, and the forward
+    loop's one row scatter-add into float32 sums (SPC
+    ``moe_scatter_built``, ``moe_scatter_kernel_built``)."""
+    rows = chunk_rows(b * s, cfg.num_experts_per_tok, cfg.n_experts_here,
+                      cfg.num_experts)
+    f = cfg.expert_width
+    d = cfg.moe_latent_size if latent else cfg.hidden_size
+    return _routed_plan(
+        cfg, interpret, rows,
+        [(d, f), (f, d)] if latent else [(d, f), (d, f), (f, d)],
+        scatter=scatter_on_kernel(interpret, rows, d, jnp.float32))
+
+
 DENSE = Sublayer(group="dense", scope="otpu_dense_mlp", run=dense_mlp,
                  shapes=_dense_shapes, undecayed=("ln2",),
                  post_norm="ln2_post")
@@ -720,14 +775,15 @@ DENSE = Sublayer(group="dense", scope="otpu_dense_mlp", run=dense_mlp,
 SORTED = Sublayer(
     group="moe", scope="otpu_moe", run=moe_sorted_block,
     shapes=_routed_shapes, undecayed=("ln2",),
-    reports=lambda cfg: {"in": 1, "logits": 1, "lse": 0, "weights": 1})
+    reports=lambda cfg: {"in": 1, "logits": 1, "lse": 0, "weights": 1},
+    plan=_sorted_plan)
 #: a share of the experts (``cfg.routes_to_held``)
 SHARED_LOCAL = Sublayer(
     group="moe", scope="otpu_moe", run=moe_shared_local_block,
     shapes=_routed_shapes, undecayed=("ln2",), reports=_held_reports,
-    keeps=CHECKPOINT_KEEPS)
+    keeps=CHECKPOINT_KEEPS, plan=_held_plan)
 #: nemotron_h's ``E``
 LATENT = Sublayer(
     name="E", group="moe", scope="otpu_moe", run=moe_latent_block,
     shapes=_latent_shapes, undecayed=("ln2",), reports=_held_reports,
-    keeps=CHECKPOINT_KEEPS)
+    keeps=CHECKPOINT_KEEPS, plan=functools.partial(_held_plan, latent=True))

@@ -13,9 +13,8 @@ import jax.numpy as jnp
 
 from ompi_tpu.parallel.layers import (cast_param, contract, l2norm, matmul,
                                       rmsnorm_gain)
-from ompi_tpu.parallel.sublayer import (Sublayer, log_uniform_1_16,
-                                        uniform_taps)
-from ompi_tpu.runtime import spc
+from ompi_tpu.parallel.sublayer import (INTERPRET, Sublayer, held,
+                                        log_uniform_1_16, uniform_taps)
 
 
 @jax.custom_vjp
@@ -50,18 +49,6 @@ def _unit_lower_inverse_bwd(t, ct):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _count_gdn(part: str, on_kernel: bool) -> None:
-    """SPC ``gdn_<part>_built``: the passes of a Gated DeltaNet layer's
-    ``part`` (``rule``, ``conv``) made while steps were traced (the XLA
-    form, whose backward pass is autodiff's and not seen here, or the
-    kernel path's forward and backward rules: JAX traces a pass more
-    than once); ``gdn_<part>_kernel_built``: those of them made on the
-    Pallas kernels.  What reads is the second over the first."""
-    spc.record(f"gdn_{part}_built", 1)
-    if on_kernel:
-        spc.record(f"gdn_{part}_kernel_built", 1)
-
-
 def _kernel_views(arrays, hk, hv):
     """(q, k, v, their lane blocks) as ``ops/gated_delta`` reads them: of
     three arrays (bt, s, heads x 128) each from its first block; of one,
@@ -86,7 +73,6 @@ def _kernel_rule(arrays, g, beta, chunk, hk, unit):
     by XLA."""
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_gdn("rule", True)
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     return rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
                                     at=at, unit=unit)
@@ -95,7 +81,6 @@ def _kernel_rule(arrays, g, beta, chunk, hk, unit):
 def _kernel_rule_fwd(arrays, g, beta, chunk, hk, unit):
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_gdn("rule", True)
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     o, kept = rule_kernel.rule_forward(*views, g, beta, chunk=chunk, hk=hk,
                                        at=at, unit=unit, states=True)
@@ -105,7 +90,6 @@ def _kernel_rule_fwd(arrays, g, beta, chunk, hk, unit):
 def _kernel_rule_bwd(chunk, hk, unit, res, do):
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    _count_gdn("rule", True)
     arrays, g, beta, kept = res
     *views, at = _kernel_views(arrays, hk, g.shape[2])
     *d_qkv, dg, dbeta = rule_kernel.rule_backward(
@@ -118,14 +102,17 @@ def _kernel_rule_bwd(chunk, hk, unit, res, do):
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
-def _rule_on_kernels(interpret, chunk, dk, dv, r, s) -> bool:
-    """Whether the rule runs on the Pallas kernels: where Mosaic compiles
-    (``interpret`` false: a TPU) and the shape has tiles."""
+def rule_on_kernels(interpret, chunk, dk, dv, r, s) -> tuple:
+    """``(on_kernel, why)`` of the chunked rule: on the Pallas kernels
+    where Mosaic compiles (``interpret`` false: a TPU) and the shape has
+    tiles (``ops/gated_delta.refusal``); ``why`` names the clause that
+    refused, "" where the kernels are taken."""
     if interpret:
-        return False
+        return False, INTERPRET
     from ompi_tpu.ops import gated_delta as rule_kernel
 
-    return rule_kernel.supported(chunk, dk, dv, r, s)
+    why = rule_kernel.refusal(chunk, dk, dv, r, s)
+    return not why, why
 
 
 def gated_delta_chunked(q, k, v, g, beta, chunk: int, interpret: bool = True):
@@ -162,11 +149,10 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int, interpret: bool = True):
     bt, s, hk, dk = k.shape
     hv, dv = v.shape[2:]
     r = hv // hk
-    if _rule_on_kernels(interpret, chunk, dk, dv, r, s):
+    if rule_on_kernels(interpret, chunk, dk, dv, r, s)[0]:
         flat = lambda t: t.reshape(bt, s, -1)
         return _kernel_rule((flat(q), flat(k), flat(v)), g, beta, chunk, hk,
                             None).reshape(v.shape)
-    _count_gdn("rule", False)
     _f32 = lambda eq, one, two: contract(eq, one, two, jnp.float32)
     pad = -s % chunk
     if pad:
@@ -229,21 +215,18 @@ def _kernel_conv(x, w):
     the cotangent."""
     from ompi_tpu.ops import causal_conv
 
-    _count_gdn("conv", True)
     return causal_conv.conv_forward(x, w)
 
 
 def _kernel_conv_fwd(x, w):
     from ompi_tpu.ops import causal_conv
 
-    _count_gdn("conv", True)
     return causal_conv.conv_forward(x, w), (x, w)
 
 
 def _kernel_conv_bwd(res, dy):
     from ompi_tpu.ops import causal_conv
 
-    _count_gdn("conv", True)
     return causal_conv.conv_backward(*res, dy)
 
 
@@ -297,14 +280,16 @@ def _kernel_conv_rule_bwd(chunk, hk, unit, res, cts):
 _kernel_conv_rule.defvjp(_kernel_conv_rule_fwd, _kernel_conv_rule_bwd)
 
 
-def _conv_on_kernels(interpret, taps, c, s) -> bool:
-    """Whether the convolution runs on the Pallas kernels: where Mosaic
-    compiles (``interpret`` false: a TPU) and the shape has tiles."""
+def conv_on_kernels(interpret, taps, c, s) -> tuple:
+    """``(on_kernel, why)`` of the convolution: on the Pallas kernels
+    where Mosaic compiles (``interpret`` false: a TPU) and the shape has
+    tiles (``ops/causal_conv.refusal``)."""
     if interpret:
-        return False
+        return False, INTERPRET
     from ompi_tpu.ops import causal_conv
 
-    return causal_conv.supported(taps, c, s)
+    why = causal_conv.refusal(taps, c, s)
+    return not why, why
 
 
 #: what the delta rule's L2 norms add under the root (``layers.l2norm``'s)
@@ -328,11 +313,11 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True, at=None):
     the gain); ``y W_out``.  Everything between the two large
     projections is float32.  The sequence is never reset inside a packed
     row.  Where Mosaic compiles (``interpret`` false: a TPU) and the
-    width is whole tiles of lanes (``_conv_on_kernels``) the convolution
+    width is whole tiles of lanes (``conv_on_kernels``) the convolution
     and its silu run in Pallas kernels that read and write each array
     once a pass (``_kernel_conv``); everywhere else the lines here, which
     are the kernels' oracle.  Where the rule runs on its Pallas kernels
-    (``_rule_on_kernels``)
+    (``rule_on_kernels``)
     they read q, k and v where the convolution left them, one array, and
     put q's and k's rows at unit length themselves (``_kernel_rule``):
     a 4D view of q, k or v costs XLA two relayouts of it a pass.
@@ -360,9 +345,12 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True, at=None):
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, :, 1]
                                                    + p["dt_bias"])
     taps, unit = p["conv_w"].shape[0], (L2NORM_EPS, dk ** -0.5)
-    conv_on = _conv_on_kernels(interpret, taps, qkv.shape[2], s)
-    rule_on = _rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk,
-                               s) and 2 * hk % (hv // hk) == 0
+    conv_on, _ = conv_on_kernels(interpret, taps, qkv.shape[2], s)
+    # the kernels read the convolution's one array where a key head's
+    # value heads start at a lane block; else q, k and v apart
+    # (``gated_delta_chunked``): by the kernels either way
+    rule_on = rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk,
+                              s)[0] and 2 * hk % (hv // hk) == 0
     if conv_on and rule_on:
         qkv, o = _kernel_conv_rule(qkv, p["conv_w"], g, beta,
                                    cfg.chunk_size, hk, unit)
@@ -371,7 +359,6 @@ def gated_delta_net(p, x, cfg, *, interpret: bool = True, at=None):
             if conv_on:
                 qkv = _kernel_conv(qkv, p["conv_w"])
             else:
-                _count_gdn("conv", False)
                 padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
                 qkv = jax.nn.silu(sum(padded[:, j:j + s] * p["conv_w"][j]
                                       for j in range(taps)))
@@ -422,6 +409,21 @@ def _gdn_shapes(cfg) -> dict:
             "out_proj": (val, d)}
 
 
+def _gdn_plan(cfg, b, s, interpret) -> dict:
+    """What ``gated_delta_net`` holds: the rule's and the convolution's
+    decisions at the configuration's heads, a layer application one of
+    each (SPC ``gdn_rule_built``, ``gdn_conv_built`` and their
+    ``_kernel_built``)."""
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    rule = rule_on_kernels(interpret, cfg.chunk_size, dk, dv, hv // hk, s)
+    conv = conv_on_kernels(interpret, cfg.conv_kernel,
+                           2 * hk * dk + hv * dv, s)
+    return held({"gdn_rule_built": 1, "gdn_rule_kernel_built": int(rule[0]),
+                 "gdn_conv_built": 1, "gdn_conv_kernel_built": int(conv[0])},
+                rule=rule, conv=conv)
+
+
 #: qwen3_next's ``linear_attention`` (``conv_kernel``: the file's
 #: ``linear_conv_kernel_dim``); ``dt_bias`` starts at one
 GDN = Sublayer(
@@ -430,4 +432,5 @@ GDN = Sublayer(
     undecayed=("ln1", "A_log", "dt_bias", "gate_norm"),
     starts={"conv_w": uniform_taps, "A_log": log_uniform_1_16},
     reports=lambda cfg: {"gdn_q_seq": 1, "gdn_k_seq": 1, "gdn_v_seq": 1,
-                         "gdn_g_seq": 0, "gdn_beta_seq": 0, "gdn_o": 1})
+                         "gdn_g_seq": 0, "gdn_beta_seq": 0, "gdn_o": 1},
+    plan=_gdn_plan)

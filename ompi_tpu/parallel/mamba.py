@@ -14,9 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ompi_tpu.parallel.layers import contract, matmul, rmsnorm_gain
-from ompi_tpu.parallel.sublayer import (Sublayer, log_uniform_1_16,
-                                        uniform_taps)
-from ompi_tpu.runtime import spc
+from ompi_tpu.parallel.sublayer import (INTERPRET, Sublayer, held,
+                                        log_uniform_1_16, uniform_taps)
 
 
 def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
@@ -59,7 +58,6 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     bt, s, h, p = x.shape
     g, n = b.shape[2:]
     r = h // g
-    _count_scan(False)
     _f32 = lambda eq, one, two: contract(eq, one, two, jnp.float32)
     pad = -s % chunk
     if pad:
@@ -69,7 +67,6 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     i = jnp.arange(chunk)
     seen = i[:, None] >= i[None, :]                      # j <= i
     if doc is not None:
-        spc.record("doc_built", 1)
         # (bt, chunks, position); the padding lies in the last document
         dc = jnp.pad(doc, ((0, 0), (0, pad)), mode="edge").reshape(
             bt, nc, chunk)
@@ -116,18 +113,6 @@ def ssd_chunked(x, dt, a, b, c, chunk: int, doc=None):
     return y.transpose(0, 1, 4, 2, 3, 5).reshape(bt, s + pad, h, p)[:, :s]
 
 
-def _count_scan(on_kernel: bool) -> None:
-    """SPC ``ssm_scan_built``: the passes of a Mamba-2 layer's scan made
-    while steps were traced (the XLA form, whose backward pass is
-    autodiff's and not seen here, or the kernel path's forward and
-    backward rules: JAX traces a pass more than once);
-    ``ssm_scan_kernel_built``: those of them made on the Pallas kernels.
-    What reads is the second over the first."""
-    spc.record("ssm_scan_built", 1)
-    if on_kernel:
-        spc.record("ssm_scan_kernel_built", 1)
-
-
 def _scan_views(xbc, heads, p, groups, chunk):
     """([x | B | C] three times, their lane blocks) as ``ops/ssd_scan``
     reads the convolution's one array: x's heads from lane 0, group g's B
@@ -151,9 +136,6 @@ def _kernel_scan(xbc, dt, a, skip, doc, chunk, p, groups):
     or recomputed by XLA, and no (b, s, heads, p) view of x or y exists."""
     from ompi_tpu.ops import ssd_scan
 
-    _count_scan(True)
-    if doc is not None:
-        spc.record("doc_built", 1)
     views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
     return ssd_scan.scan_forward(*views, dt, a, doc, skip, **how)
 
@@ -161,7 +143,6 @@ def _kernel_scan(xbc, dt, a, skip, doc, chunk, p, groups):
 def _kernel_scan_fwd(xbc, dt, a, skip, doc, chunk, p, groups):
     from ompi_tpu.ops import ssd_scan
 
-    _count_scan(True)
     views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
     y, kept = ssd_scan.scan_forward(*views, dt, a, doc, skip, states=True,
                                     **how)
@@ -171,7 +152,6 @@ def _kernel_scan_fwd(xbc, dt, a, skip, doc, chunk, p, groups):
 def _kernel_scan_bwd(chunk, p, groups, res, dy):
     from ompi_tpu.ops import ssd_scan
 
-    _count_scan(True)
     xbc, dt, a, skip, doc, kept = res
     views, how = _scan_views(xbc, dt.shape[2], p, groups, chunk)
     *d_xbc, d_dt, d_a, d_skip = ssd_scan.scan_backward(
@@ -182,14 +162,17 @@ def _kernel_scan_bwd(chunk, p, groups, res, dy):
 _kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
 
 
-def _scan_on_kernels(interpret, chunk, p, n, heads, groups, s) -> bool:
-    """Whether the scan runs on the Pallas kernels: where Mosaic compiles
-    (``interpret`` false: a TPU) and the shape has tiles."""
+def scan_on_kernels(interpret, chunk, p, n, heads, groups, s) -> tuple:
+    """``(on_kernel, why)`` of the scan: on the Pallas kernels where
+    Mosaic compiles (``interpret`` false: a TPU) and the shape has tiles
+    (``ops/ssd_scan.refusal``); ``why`` names the clause that refused, ""
+    where the kernels are taken."""
     if interpret:
-        return False
+        return False, INTERPRET
     from ompi_tpu.ops import ssd_scan
 
-    return ssd_scan.supported(chunk, p, n, heads, groups, s)
+    why = ssd_scan.refusal(chunk, p, n, heads, groups, s)
+    return not why, why
 
 
 def causal_taps(xbc, w, bias, doc=None):
@@ -204,7 +187,6 @@ def causal_taps(xbc, w, bias, doc=None):
     if doc is None:
         return jax.nn.silu(bias + sum(
             padded[:, k:k + s] * w[k] for k in range(taps)))
-    spc.record("doc_built", 1)
     before = jnp.pad(doc, ((0, 0), (taps - 1, 0)), constant_values=-1)
     return jax.nn.silu(bias + sum(
         (padded[:, k:k + s] if k == taps - 1 else jnp.where(
@@ -265,7 +247,7 @@ def mamba_mixer(p, x, cfg, *, interpret: bool = True, at=None, doc=None,
                 "ssm_x_seq": rows(xbc[..., :hd]),
                 "ssm_b_seq": rows(xbc[..., inner:inner + n]),
                 "ssm_c_seq": rows(xbc[..., inner + g * n:inner + (g + 1) * n])}
-        if _scan_on_kernels(interpret, cfg.chunk_size, hd, n, nh, g, s):
+        if scan_on_kernels(interpret, cfg.chunk_size, hd, n, nh, g, s)[0]:
             # the skip term inside the kernels; the first head's y less it
             # (to an ulp of ``D x``, a hundred-thousandth of a check's unit)
             y = _kernel_scan(xbc, step, a, p["D"], doc, cfg.chunk_size, hd, g)
@@ -306,6 +288,19 @@ def _mixer_shapes(cfg) -> dict:
             "gate_norm": (inner,), "out_proj": (inner, d)}
 
 
+def _mixer_plan(cfg, b, s, interpret) -> dict:
+    """What ``mamba_mixer`` holds: the scan's decision at the held heads
+    and groups, a layer application one scan (SPC ``ssm_scan_built``,
+    ``ssm_scan_kernel_built``); over a packed row's documents the
+    convolution and the scan are each made under them (``doc_built``
+    2)."""
+    scan = scan_on_kernels(
+        interpret, cfg.chunk_size, cfg.mamba_head_dim, cfg.ssm_state_size,
+        cfg.n_mamba_heads_here, cfg.n_groups_here, s)
+    return held({"ssm_scan_built": 1, "ssm_scan_kernel_built": int(scan[0]),
+                 "doc_built": 2 * (cfg.eos_token_here >= 0)}, scan=scan)
+
+
 def _mixer_dt_bias(key, shape, cfg):
     """The inverse softplus of a step drawn log-uniformly between
     ``time_step_min`` and ``time_step_max``, at least ``time_step_floor``
@@ -332,7 +327,7 @@ MIXER = Sublayer(
     starts={"conv_w": uniform_taps, "conv_b": uniform_taps,
             "dt_bias": _mixer_dt_bias, "A_log": log_uniform_1_16},
     reports=lambda cfg: {"ssm_dt_seq": 0, "ssm_x_seq": 1, "ssm_b_seq": 1,
-                         "ssm_c_seq": 1, "ssm_y": 1})
+                         "ssm_c_seq": 1, "ssm_y": 1}, plan=_mixer_plan)
 #: granitemoehybrid's ``mamba``: the same mixer under its ``layer_types``
 #: name, before a feed-forward; ``A_log`` starts at the heads' numbers
 TYPED_MIXER = dataclasses.replace(

@@ -25,7 +25,6 @@ from ompi_tpu.parallel.config import ModelConfig
 from ompi_tpu.parallel.layers import matmul, rmsnorm_gain
 from ompi_tpu.parallel.model import (CHECKPOINT_KEEPS, decoder_layer,
                                      kind_of_letter, layer_kinds)
-from ompi_tpu.runtime import spc
 
 SAMPLE_ROWS = 16        # token rows whose activations a step reports
 
@@ -238,6 +237,19 @@ def exit_distribution(gate):
     return jnp.exp(log_p), log_p
 
 
+def loop_counts(cfg: ModelConfig, b: int, s: int) -> dict:
+    """The SPC counters a looped model's step moves beside its layers'
+    (``train.plan_of``), from the shapes: ``loop_built`` 1, the passes,
+    the layers held, the layer applications (passes x layers: over the
+    layers held, the times a leaf is read a pass of the step) and the rows
+    the head reads (passes x tokens of a shard (b, s))."""
+    t = cfg.total_ut_steps
+    return {"loop_built": 1, "loop_passes": t,
+            "loop_layers_held": cfg.layers_here,
+            "loop_layer_applications": t * cfg.layers_here,
+            "loop_head_rows": t * b * s}
+
+
 def looped_loss(params, x, labels, cfg: ModelConfig, run_of, psum,
                 n_global: int, at_head):
     """A looped model's loss (Ouro, arXiv:2510.25741: its Stage I
@@ -269,11 +281,6 @@ def looped_loss(params, x, labels, cfg: ModelConfig, run_of, psum,
     no entry, since nothing routes."""
     b, s, d = x.shape
     n, t = b * s, cfg.total_ut_steps
-    for name, by in (("loop_built", 1), ("loop_passes", t),
-                     ("loop_layers_held", cfg.layers_here),
-                     ("loop_layer_applications", t * cfg.layers_here),
-                     ("loop_head_rows", t * n)):
-        spc.record(name, by)
 
     def one_pass(x, _):
         with jax.named_scope("otpu_loop_pass"):

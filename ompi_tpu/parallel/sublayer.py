@@ -13,6 +13,35 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+#: why a kernel is not taken where Mosaic does not compile (``interpret``:
+#: a CPU): the first clause of every family's decision function
+INTERPRET = "interpret: Mosaic does not compile here"
+
+
+def on_mosaic(interpret: bool) -> tuple:
+    """``(on_kernel, why)`` of a kernel that every shape has tiles for:
+    taken wherever Mosaic compiles (``interpret`` false: a TPU)."""
+    return (False, INTERPRET) if interpret else (True, "")
+
+
+def held(counts: dict = None, **parts) -> dict:
+    """What one application of a sublayer holds (a ``Sublayer.plan``'s
+    answer) from its ``parts``, each a decision function's ``(on_kernel,
+    why)``: ``impl`` ``"kernel"`` where every part is on its Pallas
+    kernels, else ``"xla"``; ``why`` the refused parts' clauses, ``part:
+    clause`` each ("" where none is refused; a sublayer without a kernel
+    says so); ``parts`` the same a part; ``counts`` the SPC counters one
+    application moves, the zeros left out."""
+    each = {k: {"impl": "kernel" if on else "xla", "why": why}
+            for k, (on, why) in parts.items()}
+    refused = [f"{k}: {v['why']}" for k, v in each.items()
+               if v["impl"] == "xla"]
+    return {"impl": "xla" if refused or not parts else "kernel",
+            "why": "; ".join(refused) if parts
+            else "the sublayer has no Pallas kernel",
+            "parts": each,
+            "counts": {k: v for k, v in (counts or {}).items() if v}}
+
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class Sublayer:
@@ -54,6 +83,14 @@ class Sublayer:
     #: a layer holds under ``cfg.sandwich_norm`` ("": the sublayer is in no
     #: such model); undecayed, it starts at one
     post_norm: str = ""
+    #: ``(cfg, b, s, interpret) -> {"impl": "kernel" | "xla", "why",
+    #: "parts", "counts"}`` (``held``): what one application of the
+    #: sublayer to a residual stream (b, s, d) holds, from the
+    #: configuration and the shapes alone, by the decision functions its
+    #: ``run`` asks; ``counts`` are the SPC counters it moves
+    #: (``train.plan_of`` adds them up over a step's layer applications).
+    #: Pure: it traces nothing and calls nothing of ``jax``
+    plan: Callable = lambda cfg, b, s, interpret: held()
 
 
 def zeros(key, shape, cfg):

@@ -7,7 +7,8 @@ widths from a configuration file
 (``parallel/config.py``), not from the mesh; the kinds of layer from
 ``parallel/model.py``'s table.  The parameter tree and its initialisation,
 AdamW, the routers' bias update, ``build_train_step`` around
-``parallel/objective.model_loss`` and what reads a finished step's ``aux``.
+``parallel/objective.model_loss``, what a step holds before anything is
+traced (``plan_of``) and what reads a finished step's ``aux``.
 The batch is sharded over ``dp`` alone; the ``pp`` / ``sp`` / ``tp``
 shardings run in the invented step of ``parallel/flagship.py``, which
 nothing here imports.
@@ -29,7 +30,7 @@ from ompi_tpu.base.jaxenv import pallas_interpret
 # takes it from here; everything else names ``parallel/config.py``
 from ompi_tpu.parallel.config import (ModelConfig,
                                       load_model_config)  # noqa: F401
-from ompi_tpu.parallel.objective import model_loss
+from ompi_tpu.parallel.objective import loop_counts, model_loss
 from ompi_tpu.parallel.mesh import MeshSpec
 from ompi_tpu.parallel.model import (UNDECAYED, kind_of_letter, layer_kinds,
                                      leaf_starts, sample_axes)
@@ -226,6 +227,75 @@ def bias_update(cfg: ModelConfig, bias, loads):
     return bias + cfg.bias_update_gamma * jnp.sign(mean - loads)
 
 
+def _span(at: list) -> str:
+    """Held layers' numbers as a plan's row names them: ``3-5`` of a run
+    of neighbours, ``1,3,5`` of a two-letter unit's every other one."""
+    if len(at) > 1 and at == list(range(at[0], at[-1] + 1)):
+        return f"{at[0]}-{at[-1]}"
+    return ",".join(map(str, at))
+
+
+def plan_of(cfg: ModelConfig, b: int, s: int, interpret=None) -> dict:
+    """What a step of ``cfg`` over a shard of ``b`` sequences of ``s``
+    tokens holds, **before anything is traced**: which implementation
+    every layer application runs and, where a Pallas kernel is refused,
+    the clause that refused it.  Walks what ``objective.model_loss``
+    walks (``cfg.segments`` a run at a time, or the stacked tree's dense
+    group, its sparse layers and the next-next-token module; a looped
+    model's ``total_ut_steps`` passes; block diffusion's ``2 s`` rows)
+    and asks each sublayer's ``plan`` (``parallel/sublayer.py``), which
+    asks the decision functions the traced code asks.  ``interpret``:
+    whether Mosaic does not compile (None: as on the process's default
+    devices, ``pallas_interpret()``).
+
+    Returns ``{"b", "s", "interpret", "rows", "counts"}``: a row a run of
+    like layers, ``{"layers": "1-3" (the held layers, counted from 1;
+    "mtp": the module's), "kind", "run": 3, "passes": 1, "operator":
+    {"scope", "impl": "kernel" | "xla", "why", "parts", "counts"} or
+    None, "ffn": likewise}``; ``counts`` the SPC counters the step moves
+    at its first call: each row's sublayers' counts times its run times
+    its passes, and a looped model's own (``objective.loop_counts``).  **A
+    count is a layer application in one forward pass of the step**: a
+    scanned run of three layers is three, a pass of four over them twelve,
+    the backward rule no second application."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    walked = 2 * s if cfg.block_length else s
+    rows, counts = [], {}
+
+    def add(layers: str, kind, run: int, passes: int = 1):
+        row = {"layers": layers, "kind": kind.name, "run": run,
+               "passes": passes, "operator": None, "ffn": None}
+        for key, part in (("operator", kind.operator),
+                          ("ffn", kind.feed_forward)):
+            if part is None:
+                continue
+            row[key] = {"scope": part.scope,
+                        **part.plan(cfg, b, walked, interpret)}
+            for name, by in row[key]["counts"].items():
+                counts[name] = counts.get(name, 0) + by * run * passes
+        rows.append(row)
+
+    if cfg.pattern_here:
+        by_letter = kind_of_letter(cfg)
+        for unit, n, first in cfg.segments:
+            for j, letter in enumerate(unit):
+                add(_span([first + 1 + j + i * len(unit) for i in range(n)]),
+                    by_letter[letter], n, cfg.total_ut_steps or 1)
+    else:
+        kinds, dense = layer_kinds(cfg), cfg.n_dense_here
+        for name, first, n in (("dense", 1, dense),
+                               ("layers", dense + 1, cfg.n_sparse_here)):
+            if n:
+                add(_span(list(range(first, first + n))), kinds[name], n)
+        if cfg.n_mtp_here:
+            add("mtp", kinds["layers"], 1)
+    if cfg.total_ut_steps:
+        counts.update(loop_counts(cfg, b, s))
+    return {"b": b, "s": s, "interpret": interpret, "rows": rows,
+            "counts": counts}
+
+
 _ran_steps = weakref.WeakSet()      # the model steps that ran, while held
 #: what ``step.memory()`` reads of a compiled step's ``memory_analysis()``
 MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
@@ -352,7 +422,10 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         Nothing here reads the device: what it computed comes back in
         ``aux`` (``record_step_stats`` reads it, outside any timing).
         SPC ``train_steps`` counts the calls; the tokens, routed slots and
-        bias updates in them are constants of ``cfg`` times it."""
+        bias updates in them are constants of ``cfg`` times it.  The
+        first call feeds the ``*_built`` counters and the volumes beside
+        them from ``plan()["counts"]``: once a built step, by layer
+        applications, whatever JAX's trace visits."""
         count[0] += 1
         spc.record("train_steps")
         if count[0] == 1:
@@ -373,6 +446,8 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
             spc.record("device_program_builds")
             spc.record("device_program_first_call_us",
                        (time.perf_counter() - t0) * 1e6)
+            for name, by in plan()["counts"].items():
+                spc.record(name, by)
             return out
         if trace.profiler_on():
             with jax.profiler.StepTraceAnnotation("otpu.train.step",
@@ -411,9 +486,24 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
         m = compiled().memory_analysis()
         return {k: int(getattr(m, k)) for k in MEMORY_FIELDS}
 
+    def plan():
+        """What the step holds, a row a run of like layers: the
+        implementation of every layer application and, where a kernel is
+        refused, the clause (``plan_of`` at the first call's shapes, a
+        shard's (b, s), and the mesh's ``interpret``); its ``counts`` are
+        what the first call added to the SPC counters.  Python over the
+        configuration: nothing is traced, compiled or read."""
+        if not avals:
+            raise RuntimeError("the step has not run yet, so its "
+                               "arguments' shapes are not known "
+                               "(``plan_of(cfg, b, s)`` needs no step)")
+        b, s = avals[0][1].shape
+        return plan_of(cfg, b // spec.dp, s, interpret=interpret)
+
     step.jitted = jitted
     step.scopes = scopes
     step.memory = memory
+    step.plan = plan
 
     def place(params, tokens, labels):
         """``(state, tokens, labels)`` on the mesh: the state is the
@@ -449,6 +539,14 @@ def scopes_of_built_steps() -> list:
     program's name and the instruction's.  It reads a whole program's
     text a step, so it is for after the measurement."""
     return [step.scopes() for step in list(_ran_steps)]
+
+
+def plan_of_built_steps() -> list:
+    """``step.plan()`` of every model step this process built, ran and
+    still holds, each with its program's name (``module``, as
+    ``scopes_of_built_steps()`` names it)."""
+    return [{"module": "jit_" + step.jitted.__name__, **step.plan()}
+            for step in list(_ran_steps)]
 
 
 def memory_of_built_steps() -> list:

@@ -132,81 +132,87 @@ _COUNTERS = (
     # the rows walked that held a slot
     "train_steps_read", "moe_local_slots", "moe_absent_slots",
     "moe_chunk_rows",
-    # the experts' grouped matmuls made while steps were traced
-    # (parallel/experts._grouped_matmul), forward or transposed, and those
-    # of them made on the Pallas kernel (ops/grouped_matmul): the second
-    # over the first says which share of a run's engaged the kernel
+    # what a built train step holds, fed ONCE a built step, at its first
+    # call, from its plan (parallel/train.plan_of: Python over the
+    # configuration and the shapes, by the decision functions the traced
+    # code asks; no traced line records any of these).  A count is a layer
+    # application in one forward pass of the step: a scanned run of three
+    # layers counts three, a looped model's four passes four times its
+    # layers, a custom_vjp's backward rule is no second application.
+    # Each ``*_kernel_built`` over its ``*_built`` is the share of the
+    # applications that took the Pallas kernels; ``step.plan()`` names
+    # the clause that refused the others.
+    #
+    # the experts' grouped matmuls (parallel/experts: a gated expert's
+    # three a layer, relu2's two) and those of them on the Pallas kernel
+    # (ops/grouped_matmul): moe.gmm_kernel_share
     "moe_gmm_built", "moe_gmm_kernel_built",
-    # the held experts' loops' row scatter-adds made while steps were
-    # traced (parallel/experts.local_expert_ffn: one a loop, forward and
-    # backward), and those of them made on the Pallas row kernel
-    # (ops/row_scatter): the second over the first
+    # the held experts' forward loops' row scatter-adds (parallel/
+    # experts.local_expert_ffn: one a layer) and those of them on the
+    # Pallas row kernel (ops/row_scatter): moe.scatter_kernel_share, which
+    # waits for room in per_layer
     "moe_scatter_built", "moe_scatter_kernel_built",
-    # the chunked delta rule's passes made while steps were traced
-    # (parallel/gdn.gated_delta_chunked: the XLA form's forward, or the
-    # kernel path's forward and backward rules), and those of them made
-    # on the Pallas kernels (ops/gated_delta): the second over the first
+    # the chunked delta rules (parallel/gdn: one a Gated DeltaNet layer)
+    # and those of them on the Pallas kernels (ops/gated_delta):
+    # gdn.kernel_share
     "gdn_rule_built", "gdn_rule_kernel_built",
-    # the Mamba-2 scan's passes made while steps were traced
-    # (parallel/mamba.ssd_chunked: the XLA form's forward, or
-    # mamba._kernel_scan: the kernel path's forward and backward rules),
-    # and those of them made on the Pallas kernels (ops/ssd_scan): the
-    # second over the first
+    # the Mamba-2 scans (parallel/mamba: one a mixer) and those of them
+    # on the Pallas kernels (ops/ssd_scan): ssm.kernel_share, waiting
     "ssm_scan_built", "ssm_scan_kernel_built",
-    # the DeltaNet convolution's passes made while steps were traced
-    # (parallel/gdn.gated_delta_net: the XLA lines' forward, or the
-    # kernel path's forward and backward rules), and those of them made
-    # on the Pallas kernels (ops/causal_conv): the second over the first
+    # the DeltaNet convolutions (parallel/gdn.gated_delta_net: one a
+    # layer) and those of them on the Pallas kernels (ops/causal_conv):
+    # gdn.conv_kernel_share
     "gdn_conv_built", "gdn_conv_kernel_built",
-    # the causal attention passes made while steps were traced
-    # (parallel/causal.causal_flash_attention's forward and backward
-    # rules), and those of them whose k and v came with fewer heads than q
-    # and went to the flash kernels, or their twins, unrepeated: the
-    # second over the first is 1 for a grouped-query model, 0 for the rest
+    # the causal attention layers (parallel/causal.pass_counts), and those
+    # of them whose k and v come with fewer heads than q and go to the
+    # flash kernels, or their twins, unrepeated: attn.shared_kv_share, 100
+    # for a grouped-query model, 0 for the rest
     "attn_built", "attn_shared_kv_built",
-    # the q and k arrays a ``layer_types`` model's attention sublayer made
-    # for the flash kernels while steps were traced, with a per-head
-    # QK-norm or without (parallel/attention.normed_qk: two a call), and
-    # those of them made on the Pallas kernels (ops/head_norm_rope: norm,
-    # RoPE, head split and cast in one pass): the second over the first
+    # the q and k arrays a ``layer_types`` model's attention sublayers
+    # make for the flash kernels, with a per-head QK-norm or without
+    # (parallel/attention.normed_qk: two a layer), and those of them made
+    # on the Pallas kernels (ops/head_norm_rope: norm, RoPE, head split
+    # and cast in one pass): attn.qk_kernel_share, waiting
     "attn_qk_built", "attn_qk_kernel_built",
-    # those of the passes made under a sliding window, the block pairs
-    # the passes walk, and those full causal passes of their lengths
-    # would: walked over causal is what the windows spare
+    # those of the attention layers under a sliding window
+    # (attn.window_share), the block pairs the layers walk, and those
+    # full causal layers of their lengths would: walked over causal is
+    # what the windows and masks spare (attn.pairs_walked_share)
     "attn_window_built", "attn_pairs_walked", "attn_pairs_causal",
     # learned sparse attention (parallel/causal.selected_flash_attention):
-    # the attention passes made under a selection while steps were traced,
-    # the (query, key) pairs they attend to and those full causal passes
-    # of their lengths would, both from the shapes: selected over causal
-    # is what the selection leaves of the triangle; and the bytes of the
-    # selection a pass reads, packed eight keys a byte: b x s x s / 8
+    # the attention layers under a selection, the (query, key) pairs they
+    # attend to and those full causal layers of their lengths would, both
+    # from the shapes: selected over causal is what the selection leaves
+    # of the triangle (dsa.selected_share); and the bytes of the selection
+    # a layer reads, packed eight keys a byte: b x s x s / 8
     "dsa_built", "dsa_keys_selected", "dsa_keys_causal", "dsa_mask_bytes",
     # block diffusion (parallel/causal.block_diffusion_flash_attention):
-    # the attention passes made under its mask while steps were traced,
-    # the (query, key) pairs they attend to over a noisy and a clean copy
-    # of every sequence and those causal passes over the same rows would,
-    # both from the shapes (visible over causal: 50.02% at 8,192 tokens in
-    # blocks of 4); and, read back a step outside every window
-    # (parallel/train.record_step_stats), the token rows the steps' noise
-    # replaced by the mask token
+    # the attention layers under its mask, the (query, key) pairs they
+    # attend to over a noisy and a clean copy of every sequence and those
+    # causal layers over the same rows would, both from the shapes
+    # (bd.visible_share: 50.02% at 8,192 tokens in blocks of 4); and, read
+    # back a step outside every window (parallel/train.record_step_stats),
+    # the token rows the steps' noise replaced by the mask token
     "bd_built", "bd_pairs_visible", "bd_pairs_causal", "bd_rows_masked",
-    # a looped model's walk (parallel/objective.looped_loss), from the
-    # shapes while steps were traced: the losses built, the passes, the
-    # layers held, the layer applications (passes x layers: over the
-    # layers held, the times a leaf is read a pass of the step) and the
-    # rows the head reads (passes x tokens); and, read back a step outside
-    # every window (parallel/train.record_step_stats), the batch's mean
-    # exit pass sum_t t p_t in thousandths (1,875 at a gate of zero)
+    # a looped model's walk (parallel/objective.loop_counts), from the
+    # shapes: the looped steps built, the passes, the layers held, the
+    # layer applications (passes x layers: over the layers held, the times
+    # a leaf is read a pass of the step, loop.applications_per_layer) and
+    # the rows the head reads (passes x tokens); and, read back a step
+    # outside every window (parallel/train.record_step_stats), the batch's
+    # mean exit pass sum_t t p_t in thousandths (1,875 at a gate of zero:
+    # loop.exit_depth, waiting)
     "loop_built", "loop_passes", "loop_layers_held",
     "loop_layer_applications", "loop_head_rows", "loop_exit_depth",
     # the documents of a packed row (parallel/objective.documents): the
-    # scans (parallel/mamba.ssd_chunked), convolutions (mamba.causal_taps)
-    # and attention masks (parallel/causal.document_selection) made under a
-    # row's documents while steps were traced; and, read back a step
-    # outside every window (parallel/train.record_step_stats), the
-    # documents begun, the (query, key) pairs one attention layer sees
-    # under the document mask and those it would under the triangle alone:
-    # visible over causal is what the boundaries leave of attention's work
+    # scans and convolutions (two a Mamba-2 mixer) and attention masks
+    # (one a layer: parallel/causal.document_selection) made under a row's
+    # documents; and, read back a step outside every window
+    # (parallel/train.record_step_stats), the documents begun, the (query,
+    # key) pairs one attention layer sees under the document mask and
+    # those it would under the triangle alone: visible over causal is what
+    # the boundaries leave of attention's work (doc.visible_share and
+    # doc.starts_per_row, waiting)
     "doc_built", "doc_starts", "doc_pairs_visible", "doc_pairs_causal",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,

@@ -71,8 +71,9 @@ def traced_step(monkeypatch):
     twins) or, ``on_tpu``, as a TPU does (the Pallas kernels as
     ``pallas_call`` equations; tracing lowers nothing).  Returns
     ``eqns`` (every equation, the sub-programs' included), ``built`` and
-    ``shared`` (what the trace added to the SPC counters ``attn_built``
-    and ``attn_shared_kv_built``), ``kernels(name)`` (the operands' and
+    ``shared`` (what the step's plan, ``train.plan_of`` at the traced
+    shapes, adds to the SPC counters ``attn_built`` and
+    ``attn_shared_kv_built``), ``kernels(name)`` (the operands' and
     the results' shapes of each ``pallas_call`` of that name) and
     ``holds_no_repeat(b, nh, nkv, s, hd)``, which asserts that ``nh``
     query heads read ``nkv`` key-value heads through the kernels' index
@@ -83,7 +84,6 @@ def traced_step(monkeypatch):
 
     from ompi_tpu.parallel import train
     from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
-    from ompi_tpu.runtime import spc
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
@@ -95,15 +95,14 @@ def traced_step(monkeypatch):
         if on_tpu:
             monkeypatch.setattr(train, "pallas_interpret",
                                 lambda devices=None: False)
-        if "attn_built" not in spc.counters():
-            spc.init()
-        names = ("attn_built", "attn_shared_kv_built")
-        before = [spc.read(n) for n in names]
         mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
         step, place = train.build_train_step(mesh, spec, model=cfg)
         args = place(train.init_model_params(cfg, 3), tokens, labels)
         eqns = list(walk(jax.make_jaxpr(step.jitted)(*args).jaxpr))
-        built, shared = (spc.read(n) - b for n, b in zip(names, before))
+        counts = train.plan_of(cfg, *tokens.shape,
+                               interpret=not on_tpu)["counts"]
+        built, shared = (counts.get(n, 0) for n in (
+            "attn_built", "attn_shared_kv_built"))
         shapes = lambda vs: [v.aval.shape for v in vs]
         kernels = lambda name: [
             (shapes(e.invars), shapes(e.outvars)) for e in eqns

@@ -299,6 +299,21 @@ def _cut_batch_granite(p):
 LATER = (KEYE, SDAR, OURO, GRANITE)
 
 
+def held_once(real, new) -> None:
+    """Every metric of the set ``new`` is an entry of ``per_layer``, once:
+    found by membership, wherever a fold or a later PR puts it."""
+    names = [m["name"] for m in real["per_layer"]]
+    assert [names.count(name) for name in new] == [1] * len(new)
+
+
+def cell_and_config(real, name) -> tuple:
+    """(the cell called ``name``, the configuration it names), each found
+    by its name, whatever its place in its list."""
+    (cell,) = [w for w in real["workloads"] if w["name"] == name]
+    (config,) = [c for c in real["configs"] if c["name"] == cell["config"]]
+    return cell, config
+
+
 def _cut_bytes(small):
     """Large points cut to at most 64 KiB, each size to its own so that
     no two points share a program they do not share at full size.  A
@@ -670,19 +685,17 @@ def test_the_build_metrics_are_entries_of_the_manifest(mf, real):
                                  if c.endswith("-train-1chip")]
     assert (peak["moves"], peak["better"]) == ("small_msg_us", "lower")
     assert peak["layer"] == by_name["train.mfu"]["layer"]
-    names = [m["name"] for m in real["per_layer"]]
-    at = names.index(BUILD_METRICS[0])
-    assert names[at:at + 7] == list(BUILD_METRICS) + [PEAK_METRIC]
+    held_once(real, list(BUILD_METRICS) + [PEAK_METRIC])
 
 
 def test_the_convolutions_kernel_share_is_an_entry_of_the_manifest(real):
-    """Appended behind them (PR 54): a data file on ``program_counter``
-    in the one cell whose model has a DeltaNet convolution, under the
-    layer and the end-to-end metric of the rule's ``gdn.kernel_share``."""
+    """An entry (PR 54), found by its name: a data file on
+    ``program_counter`` in the one cell whose model has a DeltaNet
+    convolution, under the layer and the end-to-end metric of the rule's
+    ``gdn.kernel_share``."""
     by_name = {m["name"]: m for m in real["per_layer"]}
     conv, rule = by_name["gdn.conv_kernel_share"], by_name["gdn.kernel_share"]
-    assert real["per_layer"][real["per_layer"].index(
-        by_name[PEAK_METRIC]) + 1] is conv
+    held_once(real, ["gdn.conv_kernel_share", "gdn.kernel_share"])
     assert {k: v for k, v in conv.items() if k != "name"} \
         == {k: v for k, v in rule.items() if k != "name"}
     assert conv["workloads"] == ["qwen3next-train-1chip"]
@@ -931,19 +944,17 @@ def test_a_sparse_attention_step_counts_its_routers_and_its_selection(
 
 
 def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
-    """Appended behind everything that was there (PR 58): data files on
+    """Entries since PR 58, each found by its name: data files on
     readers that are there, in the one cell whose model selects its keys,
     each moving ``small_msg_us``; the selection's seven under a layer of
     their own; the cell's name at the end of the lists every share cell is
     in."""
-    names = [m["name"] for m in real["per_layer"]]
     new = ["keye.mfu", "keye.tokens_per_s", "keye.local_load",
            "keye.remat_share", "keye.unnamed_share", "keye.flash_mfu",
            "keye.attn_bwd_mfu", "dsa.operator_share", "dsa.index_share",
            "dsa.select_share", "dsa.loss_share", "dsa.selected_share",
            "dsa.index_mfu", "dsa.loss_mfu"]
-    at = names.index(new[0])            # PR 59 appended behind
-    assert names[at:at + 14] == new and names[at + 14] == ROUTE_SHARE
+    held_once(real, new + [ROUTE_SHARE])
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -958,9 +969,8 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
                 "trace_kit_flops", "point_rate", "program_counter",
                 "trace_scope_share_wide")
     assert len({by_name[n]["layer"] for n in new[-7:]}) == 1
-    assert real["workloads"][11]["name"] == KEYE \
-        and real["configs"][10]["name"] == real["workloads"][11]["config"]
-    assert real["workloads"][11]["chips"] == 1
+    cell, config = cell_and_config(real, KEYE)
+    assert (cell["chips"], config["name"]) == (1, cell["config"])
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:
@@ -1012,19 +1022,16 @@ def test_a_block_diffusion_step_counts_its_rows_and_its_visible_pairs(
 
 
 def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
-    """Appended behind everything that was there (PR 64): data files on
+    """Entries since PR 64, each found by its name: data files on
     readers that are there, in the one cell trained by block diffusion,
     each moving ``small_msg_us``; the mechanism's five under a layer of
     their own; the cell's name at the end of the lists every share cell is
     in and of the walked pairs' list."""
-    names = [m["name"] for m in real["per_layer"]]
     new = ["sdar.mfu", "sdar.tokens_per_s", "sdar.local_load",
            "sdar.remat_share", "sdar.unnamed_share", "sdar.flash_mfu",
            "sdar.attn_bwd_mfu", "bd.operator_share", "bd.noise_share",
            "bd.loss_share", "bd.visible_share", "bd.masked_share"]
-    # found by name, in their order, whatever a later PR appends
-    at = names.index(new[0])
-    assert names[at:at + 12] == new and at > names.index(ROUTE_SHARE)
+    held_once(real, new)
     by_name = {m["name"]: m for m in real["per_layer"]}
     readers = {}
     for name in new:
@@ -1041,11 +1048,9 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
             "trace_kit_flops", "point_rate", "program_counter",
             "trace_scope_share_wide")
     assert len({by_name[n]["layer"] for n in new[-5:]}) == 1
-    assert real["workloads"][12]["name"] == SDAR \
-        and real["configs"][11]["name"] == real["workloads"][12]["config"]
-    assert real["workloads"][12]["chips"] == 1
-    assert real["configs"][11]["reduced"] == ["layers", "experts", "vocab",
-                                              "ranks"]
+    cell, config = cell_and_config(real, SDAR)
+    assert (cell["chips"], config["reduced"]) == (
+        1, ["layers", "experts", "vocab", "ranks"])
     for m in real["end_to_end"] + real["per_layer"]:
         if KEYE in m.get("workloads", ()) and len(m["workloads"]) > 1:
             assert [c for c in m["workloads"]
@@ -1105,7 +1110,7 @@ def test_a_looped_step_counts_its_passes_and_routes_nothing(rehearsal):
 
 
 def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
-    """Appended behind everything that was there (PR 67): data files on
+    """Entries since PR 67, each found by its name: data files on
     readers that are there, in the one looped cell, each moving
     ``small_msg_us``; the loop's five under a layer of their own; the
     cell's name at the end of the lists SmallThinker's cell is in that
@@ -1115,8 +1120,7 @@ def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
     new = ["ouro.mfu", "ouro.flash_mfu", "ouro.attn_bwd_mfu",
            "loop.pass_share", "loop.head_share", "loop.exit_share",
            "loop.cast_share", "loop.applications_per_layer"]
-    at = names.index(new[0])    # by name, whatever a later PR appends
-    assert names[at:at + 8] == new and at > names.index("bd.masked_share")
+    held_once(real, new)
     assert len(names) <= 128
     by_name = {m["name"]: m for m in real["per_layer"]}
     readers = {}
@@ -1133,9 +1137,7 @@ def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
         assert readers[name]["reader"] in (
             "trace_kit_flops", "program_counter", "trace_scope_share_wide")
     assert len({by_name[n]["layer"] for n in new[-5:]}) == 1
-    at = [w["name"] for w in real["workloads"]].index(OURO)
-    cell = real["workloads"][at]
-    (config,) = [c for c in real["configs"] if c["name"] == cell["config"]]
+    cell, config = cell_and_config(real, OURO)
     assert (cell["traffic"], cell["chips"], config["reduced"]) == (
         "packed-4k-looped-steps", 1, ["layers"])
     for m in real["end_to_end"] + real["per_layer"]:
@@ -1261,19 +1263,17 @@ def test_the_padding_free_cells_entries_are_the_manifests(real):
 
 
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
-    """Appended behind everything that was there (PR 56): data files on
+    """Entries since PR 56, each found by its name: data files on
     readers that are there, in the one cell whose model has a window, each
     moving ``small_msg_us``; the window's three under a layer of their
     own; the cell's name at the end of the lists every step cell is in."""
     cell = "smallthinker-train-1chip"
-    names = [m["name"] for m in real["per_layer"]]
     new = ["smallthinker.mfu", "smallthinker.tokens_per_s",
            "smallthinker.local_load", "smallthinker.remat_share",
            "smallthinker.unnamed_share", "smallthinker.flash_mfu",
            "smallthinker.attn_bwd_mfu", "swa.operator_share",
            "attn.window_share", "attn.pairs_walked_share"]
-    at = names.index(new[0])            # PR 57 and PR 58 appended behind
-    assert names[at:at + 10] == new and names[at + 10] == LIVE_ROWS
+    held_once(real, new + [LIVE_ROWS])
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
@@ -1289,8 +1289,8 @@ def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
     assert by_name["smallthinker.attn_bwd_mfu"]["layer"] \
         == by_name["kernel.flash_mfu"]["layer"]
     assert len({by_name[n]["layer"] for n in new[-3:]}) == 1
-    assert real["workloads"][10]["name"] == cell \
-        and real["configs"][9]["name"] == real["workloads"][10]["config"]
+    held, config = cell_and_config(real, cell)
+    assert (held["chips"], config["name"]) == (1, held["config"])
     for m in real["end_to_end"] + real["per_layer"]:
         if "qwen3next-train-1chip" in m.get("workloads", ()) \
                 and len(m["workloads"]) > 1:

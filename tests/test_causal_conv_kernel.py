@@ -186,7 +186,8 @@ def test_which_convolution_is_built_and_counted(widths, interpret, conv_on,
     width that is no whole tiles anywhere, the layer's program holds
     XLA's lines; where Mosaic compiles and the channels are whole tiles
     it holds the convolution's kernels, beside the rule's where the heads
-    are a tile wide too.  The two SPC counters read what was built."""
+    are a tile wide too.  The layer's plan says what was built and, where
+    a kernel is refused, the clause; tracing moves neither SPC counter."""
     spc.init()
     cfg, p, x = operator(**widths)
     before = (spc.read("gdn_conv_built"), spc.read("gdn_conv_kernel_built"))
@@ -205,9 +206,21 @@ def test_which_convolution_is_built_and_counted(widths, interpret, conv_on,
         + ["otpu_gdn_conv_fwd"] * (conv_on + rule_on)
     assert ("otpu_gdn_rule_bwd" in kernels(both)) == rule_on
     assert bool(kernels(both)) == conv_on
-    built = spc.read("gdn_conv_built") - before[0]
-    on = spc.read("gdn_conv_kernel_built") - before[1]
-    assert built >= 2 and on == (built if conv_on else 0)
+    assert (spc.read("gdn_conv_built"),
+            spc.read("gdn_conv_kernel_built")) == before
+    held = gdn.GDN.plan(cfg, *x.shape[:2], interpret)
+    parts, counts = held["parts"], held["counts"]
+    assert (parts["conv"]["impl"], parts["rule"]["impl"]) == (
+        "kernel" if conv_on else "xla", "kernel" if rule_on else "xla")
+    assert counts == {
+        "gdn_conv_built": 1, "gdn_rule_built": 1,
+        **({"gdn_conv_kernel_built": 1} if conv_on else {}),
+        **({"gdn_rule_kernel_built": 1} if rule_on else {})}
+    assert held["impl"] == ("kernel" if conv_on and rule_on else "xla")
+    if not conv_on:
+        assert parts["conv"]["why"] == (
+            "interpret: Mosaic does not compile here" if interpret
+            else "96 channels are no whole lane tiles of 128")
 
 
 def _equations(jaxpr):

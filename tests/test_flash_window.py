@@ -125,10 +125,7 @@ def test_the_twins_backward_walks_agree_beyond_the_unrolled_blocks(blocks):
 def test_a_window_of_all_blocks_is_full_attention_bit_for_bit():
     """A window that covers the sequence is no window: the same branches,
     so the same bits, forward and backward, and nothing counted as one."""
-    if "attn_built" not in spc.counters():
-        spc.init()
     q, k, v = _qkv(64, 4 * BLOCK, 4, 2, seed=5)
-    before = spc.read("attn_window_built")
     run = lambda *window: jax.value_and_grad(
         lambda *a: jnp.sum(causal.causal_flash_attention(
             *a, BLOCK, True, *window) ** 2), (0, 1, 2))(q, k, v)
@@ -136,7 +133,10 @@ def test_a_window_of_all_blocks_is_full_attention_bit_for_bit():
     for other in (covered, longer):
         for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(other)):
             np.testing.assert_array_equal(a, b)
-    assert spc.read("attn_window_built") == before
+    for window in (4 * BLOCK, 4096):
+        counts = causal.pass_counts(1, 4, 2, 4 * BLOCK, BLOCK, window)
+        assert counts["attn_window_built"] == 0
+        assert counts == causal.pass_counts(1, 4, 2, 4 * BLOCK, BLOCK)
 
 
 def test_the_far_tiles_all_masked_rows_stay_finite():
@@ -248,19 +248,23 @@ def test_a_window_the_kernels_cannot_walk_is_refused(window):
 
 
 def test_the_counters_count_what_was_built():
-    """A forward and a backward rule under a window of 1 block of 4, then
-    without: ``attn_window_built`` 2 of ``attn_built`` 4,
-    ``attn_pairs_walked`` 2 x 7 + 2 x 10 of ``attn_pairs_causal`` 4 x
-    10."""
+    """A layer application under a window of 1 block of 4, then one
+    without (``causal.pass_counts``, from the shapes: the backward rule is
+    no second application): ``attn_window_built`` 1 of ``attn_built`` 2,
+    ``attn_pairs_walked`` 7 + 10 of ``attn_pairs_causal`` 2 x 10; and
+    tracing a pass moves no counter."""
     if "attn_built" not in spc.counters():
         spc.init()
     names = ("attn_built", "attn_window_built", "attn_pairs_walked",
              "attn_pairs_causal", "attn_shared_kv_built")
     before = [spc.read(n) for n in names]
     q, k, v = _qkv(32, 64, 4, 2, seed=9)
+    moved = dict.fromkeys(names, 0)
     for window in (16, None):
         jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
             causal.causal_flash_attention(*a, 16, True, window)),
             (0, 1, 2)))(q, k, v)
-    assert [spc.read(n) - b for n, b in zip(names, before)] \
-        == [4, 2, 2 * 7 + 2 * 10, 4 * 10, 4]
+        for n, by in causal.pass_counts(1, 4, 2, 64, 16, window).items():
+            moved[n] += by
+    assert [moved[n] for n in names] == [2, 1, 7 + 10, 2 * 10, 2]
+    assert [spc.read(n) for n in names] == before

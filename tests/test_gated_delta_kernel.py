@@ -12,6 +12,7 @@ import pytest
 from ompi_tpu.ops import gated_delta as gd
 from ompi_tpu.parallel import gdn, layers
 from ompi_tpu.parallel import qwen3next_reference as ref
+from ompi_tpu.parallel.sublayer import INTERPRET
 from ompi_tpu.runtime import spc
 from test_grouped_matmul import _primitives
 
@@ -202,8 +203,9 @@ def test_which_shapes_have_tiles():
 def test_which_rule_is_built_and_counted(width, interpret, on_kernel):
     """On the CPU, and at a shape without tiles anywhere, the built
     program holds the scan and no ``pallas_call``; at 128-wide heads
-    where Mosaic compiles it holds the two kernels and no scan.  The two
-    SPC counters read what was built."""
+    where Mosaic compiles it holds the two kernels and no scan.  The
+    decision function says what was built and, where the kernels are
+    refused, the clause; tracing moves neither SPC counter."""
     spc.init()
     args = rule_inputs(0, 40, 2, dk=width, dv=width)
     before = (spc.read("gdn_rule_built"), spc.read("gdn_rule_kernel_built"))
@@ -213,11 +215,13 @@ def test_which_rule_is_built_and_counted(width, interpret, on_kernel):
         lambda *a: jnp.sum(rule(*a)), range(5)))(*args).jaxpr)
     assert ("pallas_call" in names) == on_kernel
     assert ("scan" in names) == (not on_kernel)
-    built = spc.read("gdn_rule_built") - before[0]
-    on = spc.read("gdn_rule_kernel_built") - before[1]
-    assert built >= 2 and on == (built if on_kernel else 0)
-    if on_kernel:
-        assert built >= 3      # the forward alone, its rule, the backward
+    assert (spc.read("gdn_rule_built"),
+            spc.read("gdn_rule_kernel_built")) == before
+    on, why = gdn.rule_on_kernels(interpret, 8, width, width, 2, 40)
+    assert on == on_kernel and bool(why) == (not on)
+    if not on:
+        assert why == (INTERPRET if interpret
+                       else "a key head is 16 wide, not 128")
 
 
 def test_the_operator_hands_the_choice_down():

@@ -514,5 +514,11 @@ def test_the_scopes_and_counters_are_named():
     for scope in ("otpu_ssm_scan", "otpu_ssm_conv", "otpu_attention",
                   "otpu_embed", "otpu_dense_mlp"):
         assert scope in text and scope in trace.STEP_SCOPES, scope
-    # a kind of layer is traced once: a scan, a convolution, a mask
-    assert spc.read("doc_built") - before == 3
+    # lowering moves no counter; the plan counts what every layer
+    # application makes under the documents: two mixers' scan and
+    # convolution and one attention layer's mask
+    assert spc.read("doc_built") == before
+    plan = train.plan_of(F32, *tokens.shape)
+    assert [row["layers"] for row in plan["rows"]] == ["1", "2", "3"]
+    assert plan["counts"]["doc_built"] == 2 * 2 + 1
+    assert plan["counts"]["ssm_scan_built"] == 2

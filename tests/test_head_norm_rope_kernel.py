@@ -256,8 +256,9 @@ def test_which_way_is_built_and_counted(config, widths, interpret, turned,
     Mosaic compiles and a head of 128 is turned whole it holds one kernel
     for q and one for k each way, the normed pair where the layer holds
     gains (SDAR, Keye) and the pair without a norm where it holds none
-    (Ouro, SmallThinker's window layers).  The two SPC counters read what
-    was built."""
+    (Ouro, SmallThinker's window layers).  The decision function and the
+    plan's counts say what was built; tracing moves neither SPC
+    counter."""
     spc.init()
     cfg, p, h = layer(config, **widths)
     before = (spc.read("attn_qk_built"), spc.read("attn_qk_kernel_built"))
@@ -274,9 +275,15 @@ def test_which_way_is_built_and_counted(config, widths, interpret, turned,
     assert kernels(fwd) == [name + "_fwd"] * (4 * on)
     assert kernels(both) == [name + "_bwd"] * (2 * on) \
         + [name + "_fwd"] * (4 * on)
-    built = spc.read("attn_qk_built") - before[0]
-    assert built >= 4 and built % 2 == 0
-    assert spc.read("attn_qk_kernel_built") - before[1] == built * on
+    assert (spc.read("attn_qk_built"),
+            spc.read("attn_qk_kernel_built")) == before
+    # the gate the plan reads off the configuration is the one the
+    # sublayer reads off ``wq``
+    assert cfg.attn_output_gate \
+        == (p["wq"].shape[-1] == 2 * p["wo"].shape[0])
+    (taken, why), moved = attention.qk_plan(cfg, interpret, turned)
+    assert taken == bool(on) and bool(why) == (not on)
+    assert moved == {"attn_qk_built": 2, "attn_qk_kernel_built": 2 * on}
 
 
 @pytest.mark.parametrize("config,widths,interpret,turned,on",

@@ -165,9 +165,15 @@ def test_the_scopes_and_counters_are_named():
     for scope in trace.STEP_SCOPES[-3:]:
         assert scope in text, scope
     assert "otpu_bd_loss" not in text
-    moved = {k: spc.read(k) - v for k, v in before.items()}
-    assert moved == dict(loop_built=1, loop_passes=4, loop_layers_held=2,
-                         loop_layer_applications=8, loop_head_rows=512)
+    # lowering moves no counter: the step's plan counts the walk, from
+    # the shapes, and a built step's first call feeds them
+    assert {k: spc.read(k) for k in before} == before
+    counts = train.plan_of(F32, *tokens.shape)["counts"]
+    assert {k: counts[k] for k in before} == objective.loop_counts(
+        F32, *tokens.shape) == dict(
+            loop_built=1, loop_passes=4, loop_layers_held=2,
+            loop_layer_applications=8, loop_head_rows=512)
+    assert counts["attn_built"] == 8 and counts["attn_qk_built"] == 16
 
 
 # -- the objective --------------------------------------------------------------

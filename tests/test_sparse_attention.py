@@ -355,8 +355,11 @@ def test_the_counters_count_what_was_built():
                                        "attn_built")}
     jax.grad(lambda q: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, topk)[0]))(q)
-    moved = {n: spc.read(n) - v for n, v in before.items()}
-    assert moved["dsa_built"] == moved["attn_built"] >= 2
+    # a traced pass moves nothing: a layer application counts, from the
+    # shapes (``causal.pass_counts``), fed once a built step
+    assert {n: spc.read(n) for n in before} == before
+    moved = causal.pass_counts(*q.shape[:2], k.shape[1], s, BLOCK, topk=topk)
+    assert moved["dsa_built"] == moved["attn_built"] == 1
     selected = topk * (topk + 1) // 2 + (s - topk) * topk
     assert moved["dsa_keys_selected"] == moved["dsa_built"] * selected
     assert moved["dsa_keys_causal"] == moved["dsa_built"] * s * (s + 1) // 2

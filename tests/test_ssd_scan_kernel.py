@@ -277,7 +277,8 @@ def test_which_scan_the_mixer_builds_and_counts(chunk, interpret, documents,
     """On the CPU, and at a shape without tiles anywhere, the mixer's
     program holds ``ssd_chunked``'s scan and no ``pallas_call``; where
     Mosaic compiles and the shape has tiles it holds the two kernels and
-    no scan.  The two SPC counters read what was built."""
+    no scan.  The mixer's plan says what was built and, where the kernels
+    are refused, the clause; tracing moves neither SPC counter."""
     cfg, p, x = small_mixer(chunk)
     spc.init()
     doc = doc_of(1, 256, [100]) if documents else None
@@ -289,8 +290,13 @@ def test_which_scan_the_mixer_builds_and_counts(chunk, interpret, documents,
         lambda p, x: jnp.sum(mixer(p, x)), (0, 1)))(p, x).jaxpr)
     assert ("pallas_call" in names) == on_kernel
     assert ("scan" in names) == (not on_kernel)
-    built = spc.read("ssm_scan_built") - before[0]
-    on = spc.read("ssm_scan_kernel_built") - before[1]
-    assert built >= 2 and on == (built if on_kernel else 0)
-    if on_kernel:
-        assert built >= 3      # the forward alone, its rule, the backward
+    assert (spc.read("ssm_scan_built"),
+            spc.read("ssm_scan_kernel_built")) == before
+    held = mamba.TYPED_MIXER.plan(cfg, *x.shape[:2], interpret)
+    assert held["impl"] == ("kernel" if on_kernel else "xla")
+    assert held["counts"]["ssm_scan_built"] == 1
+    assert held["counts"].get("ssm_scan_kernel_built", 0) == on_kernel
+    if not on_kernel:
+        assert held["why"] == "scan: " + (
+            "interpret: Mosaic does not compile here" if interpret else
+            "a chunk of 8 positions is not 1 to 4 lane blocks of 128")
