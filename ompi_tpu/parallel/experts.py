@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ompi_tpu.parallel.layers import (cast_param, matmul, relu2,
-                                      rmsnorm_gain, swiglu)
+from ompi_tpu.parallel.layers import (cast_param, ffn_bwd_written, matmul,
+                                      relu2, rmsnorm_gain, swiglu)
 from ompi_tpu.parallel.sublayer import INTERPRET, Sublayer, held
 
 
@@ -723,15 +723,33 @@ def _held_reports(cfg) -> dict:
     return dict.fromkeys(keys, 1)
 
 
+def _ffn_plan(cfg, first: tuple) -> tuple:
+    """What an application that calls ``layers.swiglu`` or ``relu2`` (a
+    dense feed-forward, a shared expert) adds to its sublayer's plan, from
+    the shape (d, ff) of its ``first`` matrix: (the counters, ``held``'s
+    ``written``).  SPC ``ffn_built`` counts the applications,
+    ``ffn_bwd_written_built`` those whose backward pass is the written
+    rule (``layers.ffn_bwd_written``)."""
+    made = ffn_bwd_written(cfg.compute_dtype, *first)
+    return ({"ffn_built": 1, "ffn_bwd_written_built": int(made[0])},
+            {"ffn_bwd": made})
+
+
+def _dense_plan(cfg, b, s, interpret) -> dict:
+    return held(*_ffn_plan(cfg, _dense_shapes(cfg)["gate"]))
+
+
 def _routed_plan(cfg, interpret, m: int, products: list,
-                 scatter=None) -> dict:
+                 scatter=None, shared=None) -> dict:
     """What an expert block holds whose grouped matmuls are ``products``,
     (k, n) each, over ``m`` rows: SPC ``moe_gmm_built`` counts them a
     layer application (a gated expert's three, relu2's two; their
     transposes are the backward pass's and no second application),
     ``moe_gmm_kernel_built`` those on the kernel; the part is on the
     kernel where every product is, else the first refused one says why.
-    ``scatter``: a held experts' loop's row scatter-add's decision."""
+    ``scatter``: a held experts' loop's row scatter-add's decision.
+    ``shared``: the shape (d, ff) of a shared expert's first matrix where
+    the block runs one (``_ffn_plan``)."""
     made = [gmm_on_kernel(interpret, cfg.compute_dtype, m, k, n)
             for k, n in products]
     on = sum(ok for ok, _ in made)
@@ -742,7 +760,8 @@ def _routed_plan(cfg, interpret, m: int, products: list,
         parts["scatter"] = scatter
         counts.update(moe_scatter_built=1,
                       moe_scatter_kernel_built=int(scatter[0]))
-    return held(counts, **parts)
+    ffn, written = _ffn_plan(cfg, shared) if shared else ({}, None)
+    return held({**counts, **ffn}, written, **parts)
 
 
 def _sorted_plan(cfg, b, s, interpret) -> dict:
@@ -765,12 +784,14 @@ def _held_plan(cfg, b, s, interpret, latent: bool = False) -> dict:
     return _routed_plan(
         cfg, interpret, rows,
         [(d, f), (f, d)] if latent else [(d, f), (d, f), (f, d)],
-        scatter=scatter_on_kernel(interpret, rows, d, jnp.float32))
+        scatter=scatter_on_kernel(interpret, rows, d, jnp.float32),
+        shared=_latent_shapes(cfg)["shared_up"] if latent
+        else _routed_shapes(cfg).get("shared_gate"))
 
 
 DENSE = Sublayer(group="dense", scope="otpu_dense_mlp", run=dense_mlp,
                  shapes=_dense_shapes, undecayed=("ln2",),
-                 post_norm="ln2_post")
+                 post_norm="ln2_post", plan=_dense_plan)
 #: OLMoE's: every expert here
 SORTED = Sublayer(
     group="moe", scope="otpu_moe", run=moe_sorted_block,

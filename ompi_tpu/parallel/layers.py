@@ -5,6 +5,8 @@ embeddings and the two feed-forwards.  ``parallel/experts.py`` and
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -174,16 +176,122 @@ def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
     return rope_partnered(dot(w), dot(wp), theta, seq_axis=1)
 
 
-def swiglu(h, gate, up, down, compute_dtype):
-    """``down(silu(gate h) * up h)``: a dense feed-forward, or a shared
-    expert, on rows ``h`` (T, d)."""
+def ffn_bwd_written(compute_dtype, d: int, ff: int) -> tuple:
+    """``(written, why)``: whether ``swiglu`` and ``relu2`` on a stream
+    ``d`` wide with ``ff`` hidden units take their written backward rule,
+    and where not the clause.  Not under a float32 ``compute_dtype``
+    (``matmul``'s own condition; the references' and the CPU tests' way):
+    nothing is rounded on the way into a product, so there is nothing to
+    make once in a narrower dtype.  Not where the feed-forward is narrower
+    than the stream: the rule spares elementwise passes over (T, ff) and
+    pays a cast of the incoming cotangent (T, d) behind its barrier, which
+    a shared expert of 512 on a stream of 2,048 lost by (Qwen3-Next's
+    step, 16.8 -> 18.2 ms in the scope, PR 72) where every feed-forward
+    at least as wide as its stream gained."""
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return False, ("compute_dtype float32: the plain lines, their "
+                       "backward pass autodiff's")
+    if ff < d:
+        return False, (f"{ff} hidden units on a stream of {d}: narrower "
+                       "than the stream, the cotangent's cast costs more "
+                       "than the rule spares")
+    return True, ""
+
+
+def _ffn_backward(hb, ups, down, ct, act_and_cotangents):
+    """The backward pass both feed-forwards share, on operands in the
+    matmuls' dtype ``dt``: ``ups`` the matrices ``hb`` (T, d) is
+    multiplied by (SwiGLU's gate and up, relu2's up), ``ct`` (T, d) the
+    float32 cotangent of the result.  ``act_and_cotangents(d_act, *pre)``
+    gives, in float32, the activation and the cotangent of each
+    pre-activation ``pre[i] = hb @ ups[i]``.  **Every elementwise array is
+    made once, rounded to ``dt`` where a product's float32 operand would
+    be rounded on the way into the MXU, and handed to the products behind
+    ``optimization_barrier``**: left to itself XLA makes ``act`` and each
+    cotangent anew from the float32 pre-activations as the operand side of
+    every product that reads it (five times a SwiGLU layer, an exponential
+    and a division a value each; ``dW_down`` at 41% of a v5e's peak in
+    Granite's step, PR 72), behind the barrier it makes them in the
+    epilogue of the last recomputed product and every product after is
+    ``dt`` x ``dt``.  Returns (dh, the cotangent of each of ``ups``, that
+    of ``down``), rounded to ``dt`` as the transposes of the casts round
+    them."""
+    dt = hb.dtype
+    dot = functools.partial(matmul, compute_dtype=dt, weight=False)
+    pre = [dot(hb, w) for w in ups]
+    ctb = ct.astype(dt)
+    d_act = dot(ctb, down.T).astype(dt).astype(jnp.float32)
+    act, *d_pre = act_and_cotangents(d_act, *pre)
+    act, ctb, *d_pre = jax.lax.optimization_barrier(
+        (act.astype(dt), ctb, *(d.astype(dt) for d in d_pre)))
+    dh, *more = (dot(d, w.T) for d, w in zip(d_pre, ups))
+    return (sum(more, dh).astype(dt),
+            *(dot(hb.T, d).astype(dt) for d in d_pre),
+            dot(act.T, ctb).astype(dt))
+
+
+def _swiglu_parts(d_act, g, u):
+    s = jax.nn.sigmoid(g)
+    return (g * s * u, d_act * u * (s * (1. + g * (1. - s))),
+            d_act * (g * s))
+
+
+def _relu2_parts(d_act, u):
+    r = jax.nn.relu(u)
+    return r * r, d_act * (2. * r)
+
+
+def _swiglu_lines(h, gate, up, down, compute_dtype):
     act = jax.nn.silu(matmul(h, gate, compute_dtype)) \
         * matmul(h, up, compute_dtype)
     return matmul(act, down, compute_dtype)
 
 
-def relu2(h, up, down, compute_dtype):
-    """``down(relu(up h)^2)``: nemotron_h's feed-forward (no gate), a
-    shared expert on rows ``h`` (T, d)."""
+def _relu2_lines(h, up, down, compute_dtype):
     act = jnp.square(jax.nn.relu(matmul(h, up, compute_dtype)))
     return matmul(act, down, compute_dtype)
+
+
+def _written(lines, parts):
+    """``lines`` (a feed-forward's plain lines) over operands already in the
+    matmuls' dtype, with ``_ffn_backward`` as its backward rule.  The
+    residuals are the operands alone: under a layer's ``jax.checkpoint``
+    nothing more is kept than autodiff keeps, and the pre-activations are
+    made again in the rule as they were in the recomputed pass."""
+    fn = jax.custom_vjp(lambda hb, *ws: lines(hb, *ws, hb.dtype))
+    fn.defvjp(lambda hb, *ws: (lines(hb, *ws, hb.dtype), (hb, *ws)),
+              lambda kept, ct: _ffn_backward(kept[0], kept[1:-1], kept[-1],
+                                             ct, parts))
+    return fn
+
+
+_swiglu_written = _written(_swiglu_lines, _swiglu_parts)
+_relu2_written = _written(_relu2_lines, _relu2_parts)
+
+
+def _feed_forward(lines, written, h, ws, compute_dtype):
+    """``lines`` on rows ``h`` and the leaves ``ws``, the first (d, ff): as
+    they stand where ``ffn_bwd_written`` says so, else ``written`` over
+    the cast operands (the casts, their scope and their transposes stay
+    autodiff's)."""
+    if not ffn_bwd_written(compute_dtype, *ws[0].shape)[0]:
+        return lines(h, *ws, compute_dtype)
+    return written(h.astype(compute_dtype),
+                   *(cast_param(w, compute_dtype) for w in ws))
+
+
+def swiglu(h, gate, up, down, compute_dtype):
+    """``down(silu(gate h) * up h)``: a dense feed-forward, or a shared
+    expert, on rows ``h`` (T, d), float32.  In a narrower
+    ``compute_dtype`` its backward pass is written out (``_ffn_backward``;
+    ``ffn_bwd_written`` decides)."""
+    return _feed_forward(_swiglu_lines, _swiglu_written, h, (gate, up, down),
+                         compute_dtype)
+
+
+def relu2(h, up, down, compute_dtype):
+    """``down(relu(up h)^2)``: nemotron_h's feed-forward (no gate), a
+    shared expert on rows ``h`` (T, d); its backward pass as
+    ``swiglu``'s."""
+    return _feed_forward(_relu2_lines, _relu2_written, h, (up, down),
+                         compute_dtype)

@@ -24,18 +24,24 @@ def on_mosaic(interpret: bool) -> tuple:
     return (False, INTERPRET) if interpret else (True, "")
 
 
-def held(counts: dict = None, **parts) -> dict:
+def held(counts: dict = None, written: dict = None, **parts) -> dict:
     """What one application of a sublayer holds (a ``Sublayer.plan``'s
     answer) from its ``parts``, each a decision function's ``(on_kernel,
     why)``: ``impl`` ``"kernel"`` where every part is on its Pallas
     kernels, else ``"xla"``; ``why`` the refused parts' clauses, ``part:
     clause`` each ("" where none is refused; a sublayer without a kernel
     says so); ``parts`` the same a part; ``counts`` the SPC counters one
-    application moves, the zeros left out."""
+    application moves, the zeros left out.  ``written``: the parts that
+    are no kernel but a backward rule written out, each a decision
+    function's ``(written, why)``: among ``parts`` as ``"written"``, or
+    ``"xla"`` with the clause where the rule is autodiff's; the
+    sublayer's ``impl`` and ``why`` speak of Pallas kernels alone."""
     each = {k: {"impl": "kernel" if on else "xla", "why": why}
             for k, (on, why) in parts.items()}
     refused = [f"{k}: {v['why']}" for k, v in each.items()
                if v["impl"] == "xla"]
+    each.update({k: {"impl": "written" if on else "xla", "why": why}
+                 for k, (on, why) in (written or {}).items()})
     return {"impl": "xla" if refused or not parts else "kernel",
             "why": "; ".join(refused) if parts
             else "the sublayer has no Pallas kernel",

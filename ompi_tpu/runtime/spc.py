@@ -163,6 +163,12 @@ _COUNTERS = (
     # layer) and those of them on the Pallas kernels (ops/causal_conv):
     # gdn.conv_kernel_share
     "gdn_conv_built", "gdn_conv_kernel_built",
+    # the feed-forwards that call ``layers.swiglu`` or ``relu2`` (a dense
+    # layer's, a shared expert's: one a layer application) and those of
+    # them whose backward pass is the written rule (parallel/layers.
+    # ffn_bwd_written: all in bfloat16, none under a float32
+    # ``compute_dtype``): ffn.bwd_written_share, waiting for room
+    "ffn_built", "ffn_bwd_written_built",
     # the causal attention layers (parallel/causal.pass_counts), and those
     # of them whose k and v come with fewer heads than q and go to the
     # flash kernels, or their twins, unrepeated: attn.shared_kv_share, 100

@@ -76,6 +76,44 @@ def op_paths(row):
                 yield line, line.split('op_name="', 1)[1].split('"', 1)[0]
 
 
+def fusion_operands(row, named: str, scope: str) -> dict:
+    """{instruction: its fused computation's parameter types, ``f32[4,
+    2048,8192]`` each} of a row's compiled text's fusions whose name holds
+    ``named`` and whose ``op_name`` path holds ``scope``: what a product
+    reads beside its epilogue (a stacked weight gradient's is
+    ``dynamic-update-slice``: the slot written in place)."""
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    headers = dict(re.findall(r"^%([\w.\-]+) \((.*)\) -> .* \{$", text,
+                              re.M))
+    out = {}
+    for line, path in op_paths(row):
+        made = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .* fusion\(.*"
+                        r"calls=%([\w.\-]+)", line)
+        if made and named in made.group(1) and scope in path:
+            out[made.group(1)] = re.findall(r": ([a-z0-9]+\[[\d,]*\])",
+                                            headers[made.group(2)])
+    return out
+
+
+def stacked_weight_gradients_read_what_was_made(row, scope: str,
+                                               activation: str, n: int):
+    """The feed-forward's backward pass written out (``layers.
+    _ffn_backward``, PR 72): in the step compiled for a v5e the ``n``
+    products under ``scope`` that write a slot of a stacked weight
+    gradient in place read two bfloat16 arrays, the activation or a
+    cotangent made once behind the rule's barrier and the rows, and none
+    makes its operand anew from a float32 pre-activation
+    (``activation``: ``f32[16384,8192]``)."""
+    stacked = fusion_operands(row, "dynamic-update-slice", scope)
+    assert len(stacked) == n, stacked
+    for name, operands in stacked.items():
+        assert activation not in operands, (name, operands)
+        assert sum(o.startswith("bf16[") for o in operands) == 2 \
+            and activation.replace("f32", "bf16") in operands, (name,
+                                                                operands)
+
+
 def fits_a_v5e(row) -> bool:
     """The most a compiled step holds at once (``memory_analysis()``'s
     ``peak_memory_in_bytes``: the arguments, which the donated state's

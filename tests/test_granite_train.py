@@ -13,6 +13,7 @@ reference at rtol 1e-5."""
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -522,3 +523,48 @@ def test_the_scopes_and_counters_are_named():
     assert [row["layers"] for row in plan["rows"]] == ["1", "2", "3"]
     assert plan["counts"]["doc_built"] == 2 * 2 + 1
     assert plan["counts"]["ssm_scan_built"] == 2
+
+
+@pytest.mark.parametrize("dtype,written", [("bfloat16", True),
+                                           ("float32", False)])
+def test_the_feed_forwards_backward_rule_stays_under_its_scopes(dtype,
+                                                                written):
+    """``layers.swiglu``'s written backward rule (a ``compute_dtype``
+    narrower than float32, PR 72): a layer's eight products, the two
+    recomputed pre-activations among them, lie under ``otpu_dense_mlp`` in
+    the backward pass with one ``optimization_barrier``, and none under
+    ``rematted_computation``, where the operands' casts still are, under
+    ``otpu_cast`` like their transposes.  Under float32 autodiff's
+    recomputed pass makes the pre-activations as before."""
+    cfg = small(compute_dtype=dtype)
+    tokens, labels = packed(0, (21, 11, 32), rows=2)
+    text = jax.jit(jax.grad(
+        lambda ps: loss_of(cfg, tokens, labels)(ps)[0])).lower(
+        train.init_model_params(cfg, 0)).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    found = {}
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        path = locs.get(at.group(1), "") if at else ""
+        if "/otpu_dense_mlp/" not in path:
+            continue
+        _, which, _ = trace.scope_of_path(path)
+        op = path.rsplit("/", 1)[1]
+        cast = "otpu_cast/" if "/otpu_cast/" in path else ""
+        found[which, cast + op] = found.get((which, cast + op), 0) + 1
+    layers_here = 3
+    assert found["forward", "dot_general"] == 3 * layers_here
+    assert found.get(("backward", "optimization_barrier"), 0) \
+        == written * layers_here
+    if written:
+        assert found["backward", "dot_general"] == 8 * layers_here
+        assert ("remat", "dot_general") not in found
+    else:
+        assert found["remat", "dot_general"] >= 2 * layers_here
+        assert found["backward", "dot_general"] == 6 * layers_here
+    # a layer's three matrices: cast in the forward pass, cast again in the
+    # recomputed one, the gradients' casts back in the backward pass
+    # (float32 leaves need none)
+    for which in ("forward", "remat", "backward"):
+        assert found.get((which, "otpu_cast/convert_element_type"), 0) \
+            == written * 3 * layers_here, which

@@ -262,6 +262,17 @@ def test_a_checkpoints_recomputed_pass_aot_holds_no_routing(
         request.getfixturevalue(rows)[case])
 
 
+def test_a_shared_experts_stacked_weight_gradients_aot_read_what_was_made(
+        nemotron_rows):
+    """Nemotron's relu2 shared expert over its four ``E`` layers:
+    ``layers._ffn_backward``'s products, and the step's peak at most what
+    it was before the rule."""
+    row = nemotron_rows["nemotron3_step_1chip"]
+    aot_rows.stacked_weight_gradients_read_what_was_made(
+        row, "otpu_shared_expert", "f32[8192,5376]", 2)
+    assert row["peak_bytes"] <= 14_088_552_448
+
+
 @pytest.mark.parametrize("rows,case,calls", [
     ("joyai_rows", "joyai_step_1chip", ["jvp(otpu_layers)/otpu_mla",
                                         "jvp(otpu_layers)/while/body",

@@ -10,8 +10,9 @@ import re
 import pytest
 
 import aot_rows
-from aot_rows import (fits_a_v5e, kernel_bodies, op_paths,
-                      rows_with_texts, run_aot_subprocess)
+from aot_rows import (fits_a_v5e, kernel_bodies, op_paths, rows_with_texts,
+                      run_aot_subprocess,
+                      stacked_weight_gradients_read_what_was_made)
 
 pytestmark = aot_rows.SKIP_AOT
 
@@ -416,6 +417,8 @@ def test_ouro_train_step_aot_compiles_from_the_cells_configuration(
     paths = [path for _, path in op_paths(row)]
     for scope in ("otpu_exit_gate", "otpu_exit_loss", "otpu_head"):
         assert any(scope in p for p in paths), scope
+    stacked_weight_gradients_read_what_was_made(
+        row, "otpu_dense_mlp", "f32[8192,5632]", 3)
 
 
 @pytest.fixture(scope="module")
@@ -497,6 +500,12 @@ def test_granite_train_step_aot_compiles_from_the_cells_configuration(
     assert not [line for line, path in op_paths(row)
                 if "/otpu_ssm_scan/" in path and " while(" in line]
     assert not re.search(r"f32\[64,32,256,256\]", text)
+    # the feed-forward's backward pass written out (PR 72): a run's three
+    # stacked weight gradients read what the rule made once; the step's
+    # peak is at most what it was before
+    stacked_weight_gradients_read_what_was_made(
+        row, "otpu_dense_mlp", "f32[16384,8192]", 6)
+    assert row["peak_bytes"] <= 15_345_353_216
 
 
 @pytest.fixture(scope="module")

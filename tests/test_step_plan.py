@@ -18,7 +18,7 @@ import pytest
 from test_model_tree import CONFIGS, SMALL, TREES
 
 from ompi_tpu.parallel import (attention, causal, config, dsa, experts, gdn,
-                               mamba, model, objective, train)
+                               layers, mamba, model, objective, train)
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.parallel.sublayer import INTERPRET
 from ompi_tpu.runtime import spc
@@ -35,6 +35,10 @@ DECISIONS = {"flash": (causal, "flash_on_kernels"),
              "rule": (gdn, "rule_on_kernels"),
              "conv": (gdn, "conv_on_kernels"),
              "scan": (mamba, "scan_on_kernels")}
+
+
+#: the parts that are a backward rule written out, no kernel
+WRITTEN = {"ffn_bwd": (layers, "ffn_bwd_written")}
 
 
 def full(name, **change):
@@ -58,6 +62,13 @@ def sublayers(plan):
     sublayer a plan's rows hold."""
     return [(row, key, row[key]) for row in plan["rows"]
             for key in ("operator", "ffn") if row[key]]
+
+
+def kernel_parts(part) -> dict:
+    """A sublayer's parts that have a Pallas kernel: all but the backward
+    rules written out (``sublayer.held``'s ``written``: ``ffn_bwd``, whose
+    decision knows no ``interpret``)."""
+    return {k: v for k, v in part["parts"].items() if k not in WRITTEN}
 
 
 def held_layers(row):
@@ -95,9 +106,13 @@ def test_the_rows_cover_every_held_layer_once(name, cut):
         # a kernel taken gives no reason, a kernel refused gives one
         assert bool(part["why"]) == (part["impl"] == "xla")
         for piece in part["parts"].values():
+            assert piece["impl"] in ("kernel", "written", "xla")
             assert bool(piece["why"]) == (piece["impl"] == "xla")
-        assert (part["impl"] == "kernel") == (bool(part["parts"]) and all(
-            piece["impl"] == "kernel" for piece in part["parts"].values()))
+        # a backward rule written out is no kernel: ``impl`` speaks of the
+        # parts that have one
+        kernels = kernel_parts(part)
+        assert (part["impl"] == "kernel") == (bool(kernels) and all(
+            piece["impl"] == "kernel" for piece in kernels.values()))
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -122,7 +137,7 @@ def test_on_the_cpu_every_kernel_is_refused_for_one_reason():
         for _, _, part in sublayers(plan_at(full(name), interpret=True)):
             assert part["impl"] == "xla"
             assert all(piece == {"impl": "xla", "why": INTERPRET}
-                       for piece in part["parts"].values())
+                       for piece in kernel_parts(part).values())
             assert not any(k.endswith("_kernel_built")
                            for k in part["counts"])
     # and ``interpret`` left out is the process's own devices': the CPU's
@@ -131,8 +146,10 @@ def test_on_the_cpu_every_kernel_is_refused_for_one_reason():
 
 
 # -- the ten cells as the chip runs them ------------------------------------------
+NARROW = ("%d hidden units on a stream of %d: narrower than the stream, the "
+          "cotangent's cast costs more than the rule spares")
 #: file -> the parts refused where Mosaic compiles, {(layers, part): why};
-#: every other part of every layer is on its kernels
+#: every other part of every layer is on its kernels or its written rule
 REFUSED = {
     "granite-4.0-h-micro-train-1chip.json": {
         ("6", "qk"): "RoPE does not turn the layer"},
@@ -144,7 +161,12 @@ REFUSED = {
                      "is no rotation of the tile"},
     "smallthinker-21b-a3b-train-1chip.json": {
         ("1", "qk"): "RoPE does not turn the layer"},
+    # a shared expert narrower than the stream keeps autodiff's backward
+    "joyai-flash-train-1chip.json": dict.fromkeys(
+        [("2-5", "ffn_bwd"), ("mtp", "ffn_bwd")], NARROW % (768, 2048)),
 }
+REFUSED["qwen3-next-80b-a3b-train-1chip.json"].update(dict.fromkeys(
+    [("1-3", "ffn_bwd"), ("4", "ffn_bwd")], NARROW % (512, 2048)))
 #: file -> some of the counters its step feeds at the first call
 COUNTS = {
     "granite-4.0-h-micro-train-1chip.json": dict(
@@ -281,20 +303,22 @@ def test_counts_scale_with_a_runs_length_and_a_looped_models_passes():
 # -- the traced step and the plan ask the same functions ---------------------------
 def spy_on(monkeypatch):
     """Every decision function wrapped: ``calls[part]`` lists the
-    arguments behind ``interpret`` of each call made since, and
+    arguments behind ``interpret`` (a written rule's knows none: all of
+    them) of each call made since, and
     ``again(asked, part, interpret)`` asks the unwrapped function each of
     ``asked[part]`` anew."""
     calls, plain = {}, {}
-    for part, (module, fn) in DECISIONS.items():
+    for part, (module, fn) in {**DECISIONS, **WRITTEN}.items():
         plain[part] = getattr(module, fn)
 
-        def spied(interpret, *args, _part=part):
-            calls.setdefault(_part, []).append(args)
-            return plain[_part](interpret, *args)
+        def spied(*args, _part=part):
+            calls.setdefault(_part, []).append(args[_part not in WRITTEN:])
+            return plain[_part](*args)
 
         monkeypatch.setattr(module, fn, spied)
     again = lambda asked, part, interpret: [
-        plain[part](interpret, *args) for args in asked.get(part, [])]
+        plain[part](*(() if part in WRITTEN else (interpret,)), *args)
+        for args in asked.get(part, [])]
     return calls, again
 
 
@@ -359,8 +383,8 @@ def test_the_traced_step_decides_as_the_plan_and_feeds_its_counts(
     # what the trace asked is what the plan asked: here, and for a TPU
     for interpret in (True, False):
         held = sublayers(plan_at(cfg, interpret))
-        for part in DECISIONS:
-            planned = [(made["impl"] == "kernel", made["why"])
+        for part in (*DECISIONS, *WRITTEN):
+            planned = [(made["impl"] != "xla", made["why"])
                        for _, _, sub in held
                        for piece, made in sub["parts"].items()
                        if piece == part]
@@ -383,7 +407,7 @@ def test_no_traced_module_records_a_counter():
     import ast
     import inspect
 
-    from ompi_tpu.parallel import layers, short_conv, sublayer
+    from ompi_tpu.parallel import short_conv, sublayer
 
     for module in (attention, causal, dsa, experts, gdn, mamba, model,
                    objective, layers, short_conv, sublayer):
