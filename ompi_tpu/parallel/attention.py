@@ -1,5 +1,6 @@
 """The attention sublayers on ``causal_flash_attention``: OLMoE's,
-DeepSeek-V3's latent attention (JoyAI-LLM-Flash) and grouped-query
+DeepSeek-V3's latent attention (JoyAI-LLM-Flash; Xing4.0 over a share of
+its heads under YaRN) and grouped-query
 attention in the forms Nemotron-3-Super, LFM2, Qwen3-Next, SmallThinker
 and SDAR (over a noisy and a clean copy of every sequence, on
 ``block_diffusion_flash_attention``) publish, each with its entry in
@@ -57,17 +58,23 @@ def mla_attention(p, x, cfg, *, interpret: bool, at=None):
     The two inner norms, RoPE and the softmax in float32;
     matmul inputs in ``compute_dtype``.  Training holds no cache, so the
     latents are expanded to full keys and values.  q and the shared
-    rotary key leave their projections with RoPE on (``project_rope``)."""
+    rotary key leave their projections with RoPE on (``project_rope``).
+    Over the ``n_heads_here`` heads held here, a tensor-parallel group's
+    member's share: the head-wise leaves ``wq_b``, ``wkv_b`` and ``wo`` are
+    cut, the two latents whole, and what the absent heads would add to the
+    output is left out.  Under a ``rope_scaling`` of type ``yarn`` the
+    rotary frequencies are YaRN's and the scores' scale
+    ``cfg.attention_scale`` (``layers.yarn_inv_freq``)."""
     b, s, _ = x.shape
-    nh, dt, eps = cfg.num_attention_heads, cfg.compute_dtype, cfg.rms_norm_eps
+    nh, dt, eps = cfg.n_heads_here, cfg.compute_dtype, cfg.rms_norm_eps
     nope, rot, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    rank, theta = cfg.kv_lora_rank, cfg.rope_theta
+    rank, theta, yarn = cfg.kv_lora_rank, cfg.rope_theta, cfg.yarn
     with jax.named_scope("otpu_attn_proj"):
         h = rmsnorm_gain(x, p["ln1"], eps)
         cq = rmsnorm_gain(matmul(h, p["wq_a"], dt), p["q_a_norm"], eps)
-        q = project_rope(cq, p["wq_b"], nh, nope, theta, dt)
+        q = project_rope(cq, p["wq_b"], nh, nope, theta, dt, yarn)
         # (b, s, rank + rot), the rotary key behind the latent
-        kv = project_rope(h, p["wkv_a"], 1, rank, theta, dt)[:, :, 0]
+        kv = project_rope(h, p["wkv_a"], 1, rank, theta, dt, yarn)[:, :, 0]
         ckv = rmsnorm_gain(kv[..., :rank], p["kv_a_norm"], eps)
         kvb = matmul(ckv, p["wkv_b"], dt).reshape(b, s, nh, nope + hv)
         k = jnp.concatenate([kvb[..., :nope].astype(dt), jnp.broadcast_to(
@@ -75,7 +82,8 @@ def mla_attention(p, x, cfg, *, interpret: bool, at=None):
         heads = lambda t: t.transpose(0, 2, 1, 3)        # (b, nh, s, .)
         q, k, v = (heads(q.astype(dt)), heads(k),
                    heads(kvb[..., nope:].astype(dt)))
-    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret)
+    o = causal_flash_attention(q, k, v, min(cfg.attn_block, s), interpret,
+                               None, cfg.attention_scale)
     with jax.named_scope("otpu_attn_proj"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * hv)
         return matmul(o, p["wo"], dt), {}, {}
@@ -325,7 +333,7 @@ def _olmoe_shapes(cfg) -> dict:
 
 
 def _mla_shapes(cfg) -> dict:
-    d, nh = cfg.hidden_size, cfg.num_attention_heads
+    d, nh = cfg.hidden_size, cfg.n_heads_here
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     return {"ln1": (d,), "wq_a": (d, cfg.q_lora_rank),
             "q_a_norm": (cfg.q_lora_rank,),
@@ -382,8 +390,8 @@ def qk_plan(cfg, interpret: bool, turned: bool = True) -> tuple:
 
 def _stacked_plan(cfg, b, s, interpret) -> dict:
     """What OLMoE's attention and latent attention hold: every query head
-    its own key and value head."""
-    nh = cfg.num_attention_heads
+    its own key and value head (latent attention's the heads held here)."""
+    nh = cfg.n_heads_here
     return held(pass_counts(b, nh, nh, s, min(cfg.attn_block, s)),
                 flash=flash_on_kernels(interpret))
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 
 #: the operators a ``layer_types`` file names (``parallel/model.py``'s table
 #: holds an entry under each), and the small letter a layer of each is
@@ -33,6 +34,19 @@ HYBRID_LETTERS = "M*E"
 #: on the embedding, the attention scores, the residual adds, the logits
 MULTIPLIERS = ("embedding_multiplier", "attention_multiplier",
                "residual_multiplier", "logits_scaling")
+#: the published keys that only an xing4_0 file may give: the residual
+#: path's (manifold-constrained hyper-connections, arXiv:2512.24880)
+HYPER_KEYS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+              "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+#: the six numbers of a ``rope_scaling`` of type ``yarn``
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "mscale", "mscale_all_dim")
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's ``0.1 mscale ln(scale) + 1`` (1 where nothing is stretched):
+    DeepSeek-V3's ``yarn_get_mscale``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +65,11 @@ class ModelConfig:
     each mixer's heads, as one member of a tensor-parallel group holds
     them (``heads_here`` query heads with the key-value heads they read,
     ``mamba_heads_here`` Mamba heads with their B/C groups; 0: all);
-    ``mtp_here`` of the published next-n modules (-1: all).  A
+    ``mtp_here`` of the published next-n modules (-1: all); of a stacked
+    tree ``dense_here`` of the leading dense layers (-1: as many of the
+    ``first_k_dense_replace`` as ``layers_here`` leaves room for), and
+    under latent attention (``kv_lora_rank``) ``heads_here`` whole heads, a
+    tensor-parallel group's member's share.  A
     ``layer_types`` model holds the same shares of its ``full_attention``
     and ``mamba`` layers (``SHARE_TYPES``: granitemoehybrid's, a
     tensor-parallel pair's member); every other ``layer_types`` operator
@@ -71,7 +89,9 @@ class ModelConfig:
     ``sandwich_norm`` every sublayer is normed behind as well as before,
     ahead of its residual add; under ``total_ut_steps`` the held layers
     are walked that many times over one set of leaves
-    (``objective.model_loss``)."""
+    (``objective.model_loss``).  Under ``hc_mult`` n > 1 the residual is n
+    streams, every sublayer reads a mix of them and writes into all of them
+    (``parallel/hyper.py``)."""
     hidden_size: int
     intermediate_size: int
     num_attention_heads: int
@@ -190,6 +210,19 @@ class ModelConfig:
     #                                 starts a document, across whose start
     #                                 no scan, convolution or attention reads
     #                                 (-1: a row is one document)
+    # xing4_0's keys (Xing4.0-29B-A4B): the residual path's, YaRN's, and
+    # which of the leading dense layers are held
+    hc_mult: int = 1                # residual streams (1: ``x = x + y``)
+    hc_sinkhorn_iters: int = 20     # sweeps, columns then rows each
+    hc_eps: float = 1e-6            # in a sweep's two divisions
+    mhc_h_res_clamp_min: float = -30.0  # on the mixing map, ahead of its
+    mhc_h_res_clamp_max: float = 30.0   # exponential
+    hc_gate_start: float = 0.01     # the path's three gates at step 0
+    hc_offset_std: float = 0.0      # its offsets: normal(0, this), and
+    hc_res_diag: float = 0.0        # this on the mixing map's diagonal
+    rope_scaling: tuple = ()        # a file's ``yarn`` group as sorted
+    #                                 (key, value) pairs (() : plain RoPE)
+    dense_here: int = -1            # the leading dense layers held
 
     @property
     def pattern_here(self) -> str:
@@ -231,6 +264,8 @@ class ModelConfig:
 
     @property
     def n_dense_here(self) -> int:
+        if self.dense_here >= 0:
+            return self.dense_here
         return min(self.first_k_dense_replace, self.layers_here)
 
     @property
@@ -278,9 +313,23 @@ class ModelConfig:
                    // self.mamba_num_heads)
 
     @property
+    def yarn(self):
+        """The ``rope_scaling`` group of type ``yarn`` as a dict (None:
+        plain RoPE): ``layers.yarn_inv_freq`` and ``yarn_mscale`` read it."""
+        return dict(self.rope_scaling) or None
+
+    @property
     def attention_scale(self):
         """The scale of an attention score where the file gives one
-        (``attention_multiplier``); None: 1 / sqrt of the head's width."""
+        (``attention_multiplier``), or YaRN moves it (DeepSeek-V3's: 1 /
+        sqrt of q's width times ``mscale(factor, mscale_all_dim)`` squared);
+        None: 1 / sqrt of the head's width."""
+        if self.rope_scaling:
+            yarn = self.yarn
+            m = yarn_mscale(yarn["factor"], yarn["mscale_all_dim"]) \
+                if yarn["mscale_all_dim"] else 1.0
+            return m * m / math.sqrt(self.qk_nope_head_dim
+                                     + self.qk_rope_head_dim)
         return self.attention_multiplier or None
 
     @property
@@ -330,6 +379,8 @@ class ModelConfig:
         # a file's list; a tuple so that the configuration stays hashable
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "rope_kinds", tuple(self.rope_kinds))
+        object.__setattr__(self, "rope_scaling", tuple(sorted(
+            dict(self.rope_scaling or ()).items())))
         typed = bool(self.layer_types)
         per_kv = self.num_attention_heads // max(1, self.num_key_value_heads)
         if not (hybrid or typed) and self.num_key_value_heads \
@@ -492,6 +543,58 @@ class ModelConfig:
             raise NotImplementedError(
                 f"n_group {self.n_group} / topk_group {self.topk_group}: "
                 "the routers choose among one group of experts")
+        if self.heads_here and not (hybrid or typed) and (
+                not self.kv_lora_rank
+                or self.num_attention_heads % self.heads_here):
+            raise NotImplementedError(
+                f"heads_here {self.heads_here}: a stacked tree holds a share "
+                "of its heads under latent attention (kv_lora_rank) alone, "
+                f"whole heads that divide the {self.num_attention_heads}")
+        if self.rope_scaling and (
+                not self.kv_lora_rank
+                or {k for k, _ in self.rope_scaling} != set(YARN_KEYS)):
+            raise NotImplementedError(
+                f"rope_scaling {dict(self.rope_scaling)}: the rotary "
+                "frequencies are scaled by YaRN alone, under latent attention "
+                f"(kv_lora_rank), by its six numbers {list(YARN_KEYS)}")
+        if self.dense_here != -1 and (
+                hybrid or typed or not 0 <= self.dense_here <= min(
+                    self.first_k_dense_replace, self.layers_here)):
+            raise NotImplementedError(
+                f"dense_here {self.dense_here}: the leading dense layers "
+                "held are a stacked tree's, at most first_k_dense_replace "
+                f"{self.first_k_dense_replace} and layers_here "
+                f"{self.layers_here}")
+        if self.hc_mult != 1:
+            for key, on in (
+                    ("layer_types", typed),
+                    ("hybrid_override_pattern", hybrid),
+                    ("total_ut_steps", self.total_ut_steps),
+                    ("block_length", self.block_length),
+                    ("eos_token_here", self.eos_token_here != -1),
+                    ("sandwich_norm", self.sandwich_norm)):
+                if on:
+                    raise NotImplementedError(
+                        f"hc_mult {self.hc_mult} with {key}: a stream of "
+                        "hc_mult residuals is a stacked tree's, walked once "
+                        "over next-token rows; no file has both and none is "
+                        "guessed")
+            if self.n_mtp_here:
+                raise NotImplementedError(
+                    f"hc_mult {self.hc_mult} with mtp_here "
+                    f"{self.n_mtp_here}: how the next-n module joins a "
+                    "stream of hc_mult is in neither the file nor "
+                    "arXiv:2512.24880; hold 0 of them")
+            if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1 \
+                    or self.hc_eps <= 0.0 or self.mhc_h_res_clamp_min \
+                    >= self.mhc_h_res_clamp_max:
+                raise ValueError(
+                    f"hc_mult {self.hc_mult} / hc_sinkhorn_iters "
+                    f"{self.hc_sinkhorn_iters} / hc_eps {self.hc_eps} / "
+                    f"mhc_h_res_clamp_min {self.mhc_h_res_clamp_min} / "
+                    f"mhc_h_res_clamp_max {self.mhc_h_res_clamp_max}: one "
+                    "stream or more, one sweep or more, a guard above 0 and "
+                    "a clamp with room")
         if (hybrid or typed) and self.n_mtp_here:
             raise NotImplementedError(
                 f"mtp_here {self.n_mtp_here}: the next-n module of a "
@@ -659,8 +762,38 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             "scalars and the documents of a packed row are a "
             f"granitemoehybrid model's; a model_type {body.get('model_type')}"
             " model has none")
+    xing = body.get("model_type") == "xing4_0"
+    if xing:
+        if "kv_lora_rank" not in body or hybrid or "layer_types" in body \
+                or any(key not in body for key in HYPER_KEYS):
+            raise NotImplementedError(
+                f"{path}: an xing4_0 model is run with latent attention "
+                f"(kv_lora_rank) in every layer and its {list(HYPER_KEYS)} "
+                "given, without layer_types or a hybrid_override_pattern")
+        # the family's file is silent on it; DeepSeek-V3's keys otherwise,
+        # whose modelling code turns interleaved pairs (``assumed``)
+        body.setdefault("rope_interleave", True)
+    else:
+        for key in list(body) + list(body.get("train", {})):
+            if key.startswith(("hc_", "mhc_")):
+                raise NotImplementedError(
+                    f"{path}: {key}: the residual path's keys "
+                    f"{list(HYPER_KEYS)} (several residual streams under a "
+                    "doubly stochastic mixing map) are an xing4_0 model's; a "
+                    f"model_type {body.get('model_type')} model adds x + y")
     scaling = body.get("rope_scaling")
-    if scaling:
+    yarn = bool(scaling) and "yarn" in (scaling.get("rope_type"),
+                                        scaling.get("type"))
+    if yarn:
+        if "kv_lora_rank" not in body or set(scaling) - {
+                "rope_type", "type"} != set(YARN_KEYS):
+            raise NotImplementedError(
+                f"{path}: rope_scaling {scaling}: RoPE under YaRN is run with "
+                "latent attention (kv_lora_rank) alone, given its six numbers "
+                f"{list(YARN_KEYS)} and no other key (truncate as its "
+                "default, true; no attention_factor)")
+        body["rope_scaling"] = {k: scaling[k] for k in YARN_KEYS}
+    elif scaling:
         # M-RoPE's three position components are equal on a text token, so
         # on text ids ``default`` scaling with sections is plain RoPE
         kinds = {scaling.get("rope_type", "default"),
@@ -672,8 +805,10 @@ def load_model_config(path: str, **overrides) -> ModelConfig:
             raise NotImplementedError(
                 f"{path}: rope_scaling {scaling}: only rope_type default "
                 "with an mrope_section that sums to half of head_dim is "
-                "run (plain RoPE on text ids); every other scaling of the "
-                "rotary frequencies is not")
+                "run (plain RoPE on text ids), and YaRN under latent "
+                "attention; every other scaling of the rotary frequencies "
+                "is not")
+        body["rope_scaling"] = None
     typed = "layer_types" in body       # lfm2_moe: its file names no
     #                                     activation, its code runs silu
     act = body.get("mlp_hidden_act") if hybrid else body.get(

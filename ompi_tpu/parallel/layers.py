@@ -1,14 +1,18 @@
 """What every sublayer of a public model is made of: a parameter's cast,
 the matmul with float32 results, RMSNorm with a gain, LayerNorm, an L2 norm, the rotary
 embeddings and the two feed-forwards.  ``parallel/experts.py`` and
-``parallel/model.py`` build on these; nothing here imports either.
+``parallel/model.py`` build on these; nothing here imports either (of the
+package only ``parallel/config.py``'s YaRN scale).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+
+from ompi_tpu.parallel.config import yarn_mscale
 
 
 def cast_param(w, dtype):
@@ -97,16 +101,50 @@ def rope(x, theta: float, rotary: int | None = None, positions=None):
     return turned if whole else jnp.concatenate([turned, x[..., rot:]], -1)
 
 
-def _rope_tables(x, theta: float, first: int, seq_axis: int):
+def yarn_inv_freq(rot: int, theta: float, yarn: dict):
+    """The ``rot / 2`` inverse frequencies of a rotary part ``rot`` wide
+    under YaRN (arXiv:2309.00071; ``transformers.modeling_rope_utils.
+    _compute_yarn_parameters`` with ``truncate`` at its default, true),
+    float32: pair i turns at ``extra_i = theta^(-2i / rot)`` where it makes
+    more than ``beta_fast`` turns over the ``original_max_position_
+    embeddings`` the base was trained at, at ``extra_i / factor`` where
+    fewer than ``beta_slow``, and on the line between the two pairs'
+    numbers in between: ``inter (1 - mask) + extra mask``, ``mask = 1 -
+    clip((i - low) / (high - low), 0, 1)``.  Returns (the frequencies,
+    what cos and sin are multiplied by: ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)``)."""
+    factor, original = yarn["factor"], yarn["original_max_position_embeddings"]
+    turns_at = lambda beta: rot * math.log(
+        original / (beta * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(turns_at(yarn["beta_fast"])), 0)
+    high = min(math.ceil(turns_at(yarn["beta_slow"])), rot - 1)
+    if low == high:
+        high += 0.001           # the source's guard against a 0 / 0
+    extra = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    mask = 1.0 - jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                          / (high - low), 0.0, 1.0)
+    return (extra / factor) * (1.0 - mask) + extra * mask, \
+        yarn_mscale(factor, yarn["mscale"]) \
+        / yarn_mscale(factor, yarn["mscale_all_dim"])
+
+
+def _rope_tables(x, theta: float, first: int, seq_axis: int, yarn=None):
     """(cos, sin) of ``rope_interleaved``, shaped to broadcast against
     ``x``: a pair's angle on both its entries, 1 and 0 on the entries
-    before ``first``."""
+    before ``first``.  ``yarn`` (a configuration's ``rope_scaling`` group;
+    None: plain RoPE, and the tables are the call's without it) scales the
+    frequencies and the two tables (``yarn_inv_freq``)."""
     width, s = x.shape[-1], x.shape[seq_axis]
     hd = width - first
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if yarn is None:
+        inv, by = 1.0 / (theta ** (jnp.arange(
+            0, hd, 2, dtype=jnp.float32) / hd)), 1.0
+    else:
+        inv, by = yarn_inv_freq(hd, theta, yarn)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
     pad = lambda a, fill: jnp.concatenate(
-        [jnp.full((s, first), fill, jnp.float32), jnp.repeat(a, 2, -1)], -1)
+        [jnp.full((s, first), fill, jnp.float32),
+         jnp.repeat(a if by == 1.0 else by * a, 2, -1)], -1)
     shape = [1] * x.ndim
     shape[seq_axis], shape[-1] = s, width
     return (pad(jnp.cos(ang), 1.0).reshape(shape),
@@ -144,20 +182,21 @@ def rotary_partner_columns(w, compute_dtype):
     return matmul(w, swap, compute_dtype, weight=False).astype(w.dtype)
 
 
-def rope_partnered(x, partner, theta: float, seq_axis: int = -2):
+def rope_partnered(x, partner, theta: float, seq_axis: int = -2, yarn=None):
     """``rope_interleaved`` of ``x`` on its trailing ``partner.shape[-1]``
     entries, given their partners (``partner[2i] = -x[2i+1]``,
     ``partner[2i+1] = x[2i]``, counted from the first rotary entry): one
     elementwise pass, the partner set behind the leading entries by a pad
     (where those are a multiple of 128 lanes, as a latent head's are, it
-    starts a tile of its own)."""
+    starts a tile of its own).  ``yarn``: ``_rope_tables``'."""
     first = x.shape[-1] - partner.shape[-1]
-    cos, sin = _rope_tables(x, theta, first, seq_axis)
+    cos, sin = _rope_tables(x, theta, first, seq_axis, yarn)
     partner = jnp.pad(partner, ((0, 0),) * (x.ndim - 1) + ((first, 0),))
     return x * cos + partner * sin
 
 
-def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
+def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype,
+                 yarn=None):
     """``a @ w`` (b, s, heads x width) split into ``heads`` with
     ``rope_interleaved(.., first=first, seq_axis=1)`` on each, float32
     (b, s, heads, width), with no shifted copy of the product: the
@@ -166,14 +205,15 @@ def project_rope(a, w, heads: int, first: int, theta: float, compute_dtype):
     **is** the partner, the same dot products of the same inputs
     accumulated the same way (for JoyAI's q 51 GFLOP a layer and pass in
     place of the 2.6 GB the rolled copies moved, PR 41).  Its gradient
-    is autodiff's: elementwise passes and matmuls."""
+    is autodiff's: elementwise passes and matmuls.  ``yarn``: the
+    configuration's ``rope_scaling`` group (``_rope_tables``)."""
     b, s, _ = a.shape
     w = cast_param(w, compute_dtype).reshape(w.shape[0], heads, -1)
     wp = rotary_partner_columns(w[..., first:], compute_dtype)
     dot = lambda cols: matmul(a, cols.reshape(cols.shape[0], -1),
                               compute_dtype, weight=False) \
         .reshape(b, s, heads, -1)
-    return rope_partnered(dot(w), dot(wp), theta, seq_axis=1)
+    return rope_partnered(dot(w), dot(wp), theta, seq_axis=1, yarn=yarn)
 
 
 def ffn_bwd_written(compute_dtype, d: int, ff: int) -> tuple:

@@ -15,7 +15,7 @@ from typing import NamedTuple
 
 import jax
 
-from ompi_tpu.parallel import experts
+from ompi_tpu.parallel import experts, hyper
 from ompi_tpu.parallel.attention import (DIFFUSED, FULL, MLA, OLMOE,
                                          SHARED_KV, WINDOW)
 from ompi_tpu.parallel.config import HYBRID_LETTERS, LAYER_TYPES
@@ -36,7 +36,8 @@ NAMED = {e.name: e for e in SUBLAYERS if e.name}
 #: the leaves AdamW does not decay, by their last name
 UNDECAYED = frozenset(
     leaf for e in SUBLAYERS
-    for leaf in e.undecayed + ((e.post_norm,) if e.post_norm else ()))
+    for leaf in e.undecayed + ((e.post_norm,) if e.post_norm else ())
+) | frozenset(hyper.UNDECAYED)
 #: what a walked layer's ``jax.checkpoint`` keeps for its backward pass
 CHECKPOINT_KEEPS = tuple(dict.fromkeys(
     name for e in SUBLAYERS for name in e.keeps))
@@ -63,13 +64,22 @@ class LayerKind(NamedTuple):
 
     def shapes(self, cfg) -> dict:
         """One layer's leaves, in the tree's order: each sublayer's own,
-        and under ``cfg.sandwich_norm`` the gain of the norm behind it."""
+        under ``cfg.sandwich_norm`` the gain of the norm behind it, and
+        under ``cfg.hc_mult`` > 1 the residual path's around it
+        (``hyper.shapes``: ``hc1_*`` the operator's, ``hc2_*`` the
+        feed-forward's)."""
         out = {}
         for part in self.parts:
             out.update(part.shapes(cfg))
             if cfg.sandwich_norm and part.post_norm:
                 out[part.post_norm] = (cfg.hidden_size,)
+            if cfg.hc_mult > 1:
+                out.update(hyper.shapes(cfg, self.path_of(part)))
         return out
+
+    def path_of(self, part) -> str:
+        """The residual path's set of leaves around ``part``."""
+        return hyper.SETS[part is not self.operator]
 
 
 @functools.cache
@@ -118,18 +128,26 @@ def kinds_here(cfg) -> list:
 def leaf_starts(cfg) -> dict:
     """``{leaf's last name: (key, shape, cfg) -> array}`` of the leaves of
     ``cfg``'s kinds of layer that start neither at one (the undecayed) nor
-    as normal(0, ``init_std``)."""
-    return {leaf: start for kind in layer_kinds(cfg).values()
-            for part in kind.parts for leaf, start in part.starts.items()}
+    as normal(0, ``init_std``): the sublayers', and under ``cfg.hc_mult``
+    > 1 the residual path's gates and offsets."""
+    starts = {leaf: start for kind in layer_kinds(cfg).values()
+              for part in kind.parts for leaf, start in part.starts.items()}
+    return {**starts, **hyper.STARTS} if cfg.hc_mult > 1 else starts
 
 
 def sample_axes(cfg) -> dict:
     """``{key: axes behind the token rows}`` of what the walked layers
     report by row into a step's ``sample``: an operator's under its own
-    names, a router's behind ``router_``."""
-    return {("" if part is kind.operator else "router_") + key: axes
-            for kind in kinds_here(cfg) for part in kind.parts
-            for key, axes in part.reports(cfg).items()}
+    names, a router's behind ``router_``, and under ``cfg.hc_mult`` > 1
+    the residual path's around each (``hyper.reports``)."""
+    out = {("" if part is kind.operator else "router_") + key: axes
+           for kind in kinds_here(cfg) for part in kind.parts
+           for key, axes in part.reports(cfg).items()}
+    if cfg.hc_mult > 1:
+        for kind in kinds_here(cfg):
+            for part in kind.parts:
+                out.update(hyper.reports(cfg, kind.path_of(part)))
+    return out
 
 
 def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
@@ -147,9 +165,16 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
     to the operator where the model resets at their starts (None: it is
     not handed on, and an operator that knows no documents is as it was).
 
+    Under ``cfg.hc_mult`` n > 1 ``x`` is the n residual streams (b, s, n,
+    d) and the layer is ``stream_layer``'s: the sublayers' ``run`` are the
+    same, each still gets and returns (b, s, d).
+
     Returns (x, the sublayers' statistics, what they report by token row:
     a router's under its own keys, an operator's under its prefix)."""
     layer = layer_kinds(cfg)[kind]
+    if cfg.hc_mult > 1:
+        return stream_layer(p, x, cfg, layer, interpret=interpret, bias=bias,
+                            at=at)
     op, ffn = layer.operator, layer.feed_forward
     stats, seen, routed = {}, {}, None
     scaled = (lambda y: y) if cfg.residual_multiplier == 1.0 \
@@ -177,3 +202,33 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
         x = x + y
         stats, seen = {**routing, **stats}, {**made, **seen}
     return x, stats, seen
+
+
+def stream_layer(p, x, cfg, layer: LayerKind, *, interpret: bool, bias=None,
+                 at=None):
+    """One decoder layer on ``cfg.hc_mult`` residual streams ``x`` (b, s, n,
+    d) float32 (``parallel/hyper.py``: manifold-constrained
+    hyper-connections): around each sublayer ``F`` the path makes its three
+    maps from the stream, ``F`` reads ``u = Hpre X`` (the router too, where
+    ``F`` is the experts) and adds no residual, and the stream behind it is
+    ``Hres X + Hpost^T F(u)``.  The path's own work lies under ``otpu_hc``,
+    the sublayer's under its own scope beside it.  Returns what
+    ``decoder_layer`` does, the path's reports (``hyper.reports``) among
+    the rows."""
+    stats, rows = {}, {}
+    for part in layer.parts:
+        at_path = layer.path_of(part)
+        with jax.named_scope("otpu_hc"):
+            pre, post, res = hyper.maps(p, x, cfg, at_path)
+            rows.update(hyper.seen(pre, post, res, x, at_path))
+            u = hyper.read(pre, x)
+        with jax.named_scope(part.scope):
+            if part is layer.operator:
+                y, st, made = part.run(p, u, cfg, interpret=interpret, at=at)
+            else:
+                y, st, made = part.run(p, u, cfg, bias, interpret=interpret,
+                                       routed=None)
+        with jax.named_scope("otpu_hc"):
+            x = hyper.write(res, post, x, y)
+        stats, rows = {**st, **stats}, {**made, **rows}
+    return x, stats, rows

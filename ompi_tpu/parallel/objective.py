@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ompi_tpu.parallel import experts
+from ompi_tpu.parallel import experts, hyper
 from ompi_tpu.parallel.config import ModelConfig
 from ompi_tpu.parallel.layers import matmul, rmsnorm_gain
 from ompi_tpu.parallel.model import (CHECKPOINT_KEEPS, decoder_layer,
@@ -371,7 +371,17 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
     ``doc`` (b, s) int32, every position's document.
     The embedding's rows are times ``cfg.embedding_multiplier`` and the
     logits over ``cfg.logits_scaling``; a model without a router reports
-    ``loads`` and ``experts`` with no entry."""
+    ``loads`` and ``experts`` with no entry.
+
+    A model with several residual streams (``cfg.hc_mult`` n > 1:
+    Xing4.0-29B-A4B's manifold-constrained hyper-connections,
+    ``parallel/hyper.py``) walks a stream (b, s, n, d): n copies of the
+    embedding's rows, made under ``otpu_embed``; the walk's carry and a
+    layer's checkpoint hold all n; the head reads their sum, made under
+    ``otpu_head``.  ``sample`` then holds the path's rows of every held
+    layer (``hyper.reports``), the dense layers' first, and ``aux``
+    ``hc_defect``, the largest defect from doubly stochastic of the mixing
+    maps at the sampled rows."""
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
     ids, levels, masked = tokens, None, None
@@ -396,6 +406,9 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         leaves."""
         operator = layer_kinds(cfg)[kind].operator
         own = set(operator.reports(cfg)) if operator else ()
+        if cfg.hc_mult > 1:     # the residual path's rows, under their names
+            own = {*own, *(k for at in hyper.SETS
+                           for k in hyper.reports(cfg, at))}
 
         def run(layer, x, bias_row):
             x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
@@ -425,6 +438,9 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         x = params["embed"][ids]                             # (b, s, d) f32
         if cfg.embedding_multiplier != 1.0:
             x = cfg.embedding_multiplier * x
+        if cfg.hc_mult > 1:     # the stream starts as hc_mult copies
+            x = jnp.broadcast_to(x[:, :, None], (b, s, cfg.hc_mult,
+                                                 x.shape[-1]))
     if cfg.total_ut_steps:
         return looped_loss(params, x, labels, cfg, run_of, psum, n_global,
                            at_head)
@@ -433,12 +449,20 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             x, (st, chosen, sample) = _walk_pattern(
                 run_of, params["layers"], x, bias.get("layers"), cfg)
         else:
+            lead = None
             if cfg.n_dense_here:
-                x, _ = _walk_layers(run_of("dense"), params["dense"], x,
-                                    None, cfg.n_dense_here)
+                x, lead = _walk_layers(run_of("dense"), params["dense"], x,
+                                       None, cfg.n_dense_here)
             x, (st, chosen, sample) = _walk_layers(
                 run_of("layers"), params["layers"], x, bias.get("layers"),
                 cfg.n_sparse_here)
+            if cfg.hc_mult > 1 and lead is not None:
+                # the residual path's rows of every held layer, the dense
+                # ones' first
+                with jax.named_scope("otpu_stats"):
+                    sample = {k: jnp.concatenate([lead[2][k], v])
+                              if k in lead[2] else v
+                              for k, v in sample.items()}
     head_rows = min(cfg.loss_block_rows, b * s)
     # a tied head reads the embedding matrix itself: one leaf, whose
     # gradient is the sum of the gather's and the cross-entropy's
@@ -453,6 +477,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                 weights = jnp.where(masked, 1.0 / jnp.repeat(
                     levels, cfg.block_length, axis=1), 0.0)
                 weighted = (weights.reshape(b * s),)
+        if cfg.hc_mult > 1:     # the head reads the streams' sum
+            x = jnp.sum(x, axis=2)
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
             h.reshape(b * s, -1), head, targets.reshape(b * s), head_rows,
@@ -490,6 +516,12 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
             loads = jnp.zeros((0, cfg.num_experts), jnp.float32)
             chosen = jnp.zeros((0, b * s, 0), jnp.int32)
     aux = {} if doc is None else {"doc": doc}
+    if cfg.hc_mult > 1:
+        with jax.named_scope("otpu_stats"):
+            # what the sweeps left of every sampled mixing map
+            worst = hyper.defect(jax.lax.stop_gradient(jnp.stack(
+                [sample[f"{at}_res"] for at in hyper.SETS])))
+            aux["hc_defect"] = jax.lax.pmax(worst, axes) if axes else worst
     if masked is not None:
         with jax.named_scope("otpu_stats"):
             aux.update(

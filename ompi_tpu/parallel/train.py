@@ -2,7 +2,7 @@
 Nemotron-3-Super, LFM2-8B-A1B, Qwen3-Next-80B-A3B, SmallThinker-21BA3B,
 Keye-VL-2.0-30B-A3B's language model, SDAR-30B-A3B by block diffusion,
 Ouro-2.6B's looped walk, granite-4.0-h-micro padding-free over the
-documents of a packed row):
+documents of a packed row, Xing4.0-29B-A4B on four residual streams):
 widths from a configuration file
 (``parallel/config.py``), not from the mesh; the kinds of layer from
 ``parallel/model.py``'s table.  The parameter tree and its initialisation,
@@ -30,6 +30,7 @@ from ompi_tpu.base.jaxenv import pallas_interpret
 # takes it from here; everything else names ``parallel/config.py``
 from ompi_tpu.parallel.config import (ModelConfig,
                                       load_model_config)  # noqa: F401
+from ompi_tpu.parallel import hyper
 from ompi_tpu.parallel.objective import loop_counts, model_loss
 from ompi_tpu.parallel.mesh import MeshSpec
 from ompi_tpu.parallel.model import (UNDECAYED, kind_of_letter, layer_kinds,
@@ -76,7 +77,10 @@ PROBE = 64              # entries of each leaf that a step reports
 #: ``experts`` with no entry; of a model trained padding-free
 #: (``eos_token_here``) ``doc`` (b, s) int32, every position's document
 #: (``objective.documents``), and where it has no router ``loads`` and
-#: ``experts`` with no entry
+#: ``experts`` with no entry; of a model with several residual streams
+#: (``hc_mult`` > 1) ``hc_defect``, the largest row- or column-sum defect
+#: from one of the mixing maps at the sampled rows, and in ``sample`` the
+#: residual path's rows (``hyper.reports``)
 
 
 def pattern_layer_shapes(cfg: ModelConfig) -> dict:
@@ -252,7 +256,9 @@ def plan_of(cfg: ModelConfig, b: int, s: int, interpret=None) -> dict:
     like layers, ``{"layers": "1-3" (the held layers, counted from 1;
     "mtp": the module's), "kind", "run": 3, "passes": 1, "operator":
     {"scope", "impl": "kernel" | "xla", "why", "parts", "counts"} or
-    None, "ffn": likewise}``; ``counts`` the SPC counters the step moves
+    None, "ffn": likewise, and under ``hc_mult`` > 1 "hc": the residual
+    path's around each of the row's sublayers (``hyper.plan``)}``;
+    ``counts`` the SPC counters the step moves
     at its first call: each row's sublayers' counts times its run times
     its passes, and a looped model's own (``objective.loop_counts``).  **A
     count is a layer application in one forward pass of the step**: a
@@ -274,6 +280,11 @@ def plan_of(cfg: ModelConfig, b: int, s: int, interpret=None) -> dict:
                         **part.plan(cfg, b, walked, interpret)}
             for name, by in row[key]["counts"].items():
                 counts[name] = counts.get(name, 0) + by * run * passes
+        if cfg.hc_mult > 1:
+            row["hc"] = hyper.plan(cfg)
+            for name, by in row["hc"]["counts"].items():
+                counts[name] = counts.get(name, 0) \
+                    + by * len(kind.parts) * run * passes
         rows.append(row)
 
     if cfg.pattern_here:
@@ -402,6 +413,8 @@ def build_train_step(mesh, spec: MeshSpec, model: ModelConfig):
                          bd_weight_sum=rep)
     if cfg.eos_token_here >= 0:
         aux_specs["doc"] = batch
+    if cfg.hc_mult > 1:
+        aux_specs["hc_defect"] = rep
     if cfg.total_ut_steps:
         aux_specs.update(exit_p=batch, exit_mean=rep)
         aux_specs["sample"].update(exit_logit=batch, exit_entropy=P("dp"))
@@ -572,9 +585,17 @@ def record_step_stats(aux) -> int:
     step over the documents of packed rows the documents begun add to
     ``doc_starts``, the (query, key) pairs one attention layer sees under
     their mask to ``doc_pairs_visible`` and those it would see under the
-    triangle alone to ``doc_pairs_causal``."""
+    triangle alone to ``doc_pairs_causal``; of a step on several residual
+    streams ``hc_defect_ppm`` is kept at the largest defect of a sampled
+    mixing map from doubly stochastic, in parts per million, of any step
+    read so far (what the sweeps leave)."""
     loads = np.asarray(aux["loads"])
     spc.record("train_steps_read")
+    if "hc_defect" in aux:
+        worst = int(round(1e6 * float(np.asarray(aux["hc_defect"]))))
+        seen = spc.read("hc_defect_ppm")
+        if worst > seen:
+            spc.record("hc_defect_ppm", worst - seen)
     if "doc" in aux:
         doc = np.asarray(aux["doc"], np.int64)
         b, s = doc.shape

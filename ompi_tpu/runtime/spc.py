@@ -220,6 +220,15 @@ _COUNTERS = (
     # the boundaries leave of attention's work (doc.visible_share and
     # doc.starts_per_row, waiting)
     "doc_built", "doc_starts", "doc_pairs_visible", "doc_pairs_causal",
+    # several residual streams (parallel/hyper.py: manifold-constrained
+    # hyper-connections): the sublayer applications on a stream and the
+    # Sinkhorn sweeps in them (hc_built x hc_sinkhorn_iters), both from the
+    # plan; and, read back a step outside every window
+    # (parallel/train.record_step_stats), the largest defect from doubly
+    # stochastic of a sampled mixing map in any step read so far, in parts
+    # per million: what the sweeps leave, a high-water gauge (hc.* metrics,
+    # waiting for room in per_layer)
+    "hc_built", "hc_sweeps_built", "hc_defect_ppm",
     # serving front door (serving/frontdoor) + speculative decode
     # (serving/worker): requests shed at admission with a retry-after,
     # batch-class decodes preempted back into the queue on an

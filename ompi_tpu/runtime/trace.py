@@ -1090,6 +1090,15 @@ STEP_SCOPES = (
     "otpu_exit_gate",       # the exit gate's product, lambda, p, log p
     "otpu_exit_loss",       # the expected loss's and the entropy's own
                             # work, inside otpu_head and beside it
+    "otpu_hc",              # the residual path around one sublayer of a
+                            # model with several residual streams, whole
+                            # (parallel/hyper.py; the streams' copies lie
+                            # under otpu_embed, their sum under otpu_head)
+    "otpu_hc_maps",         # inside it: the stream's norm, x' phi, the
+                            # gates, the two sigmoids
+    "otpu_hc_sinkhorn",     # the clamp, the exponential, the sweeps
+    "otpu_hc_read",         # Hpre X: the sublayer's input
+    "otpu_hc_write",        # Hres X + Hpost^T y: the stream behind it
 )
 #: the scopes whose ops are the optimiser's, whatever else their path says
 UPDATE_SCOPES = ("otpu_adamw", "otpu_bias_update")

@@ -21,6 +21,7 @@ committed, ``tests/test_pallas_aot.py`` compiles its own rows).
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 import time
@@ -288,6 +289,15 @@ def cases(mesh1d, mesh2d):
          lambda: attn_backward_walk(2, 16, 4096, 128, 128))
     case("joyai_attn_backward_walk_8k",
          lambda: attn_backward_walk(1, 32, 8192, 192, 128))
+    # Xing4.0's: latent attention's 192 / 128 over the 16 heads of 32 held,
+    # 4,096 positions, the scores' scale YaRN's (a static argument)
+    yarn_scale = (0.1 * math.log(64.0) + 1.0) ** 2 / math.sqrt(192.0)
+    case("xing_flash_causal_forward",
+         lambda: flash_causal_forward(1, 16, 4096, 192, 128,
+                                      scale=yarn_scale))
+    case("xing_attn_block_backward_1k",
+         lambda: attn_block_backward(1, 16, 4096, 192, 128,
+                                     scale=yarn_scale))
     case("lfm2_attn_block_backward_1k",
          lambda: attn_block_backward(2, 32, 8192, 64, 64, 8))
     case("lfm2_attn_backward_walk_8k",
@@ -432,6 +442,7 @@ def cases(mesh1d, mesh2d):
          lambda: gmm_trip_forms(16384, 10, 32, 512, 2048, 512))
     case("gmm_smallthinker",
          lambda: gmm_trip_forms(16384, 6, 16, 64, 2560, 768))
+    case("gmm_xing", lambda: gmm_trip_forms(4096, 4, 8, 64, 3584, 1024))
     # the same loop's two row scatter-adds (``ops/row_scatter``: the
     # trip's output rows times their weights into the layer's sums, the
     # rows' cotangents times one into the cotangent of ``h``), sums and
@@ -767,6 +778,8 @@ def cases(mesh1d, mesh2d):
         topo_devs[:1], "ouro-2.6b-train-1chip"))
     case("granite_step_1chip", lambda: model_step(
         topo_devs[:1], "granite-4.0-h-micro-train-1chip"))
+    case("xing_step_1chip", lambda: model_step(
+        topo_devs[:1], "xing4.0-29b-a4b-train-1chip"))
     case("train_step_1dev", lambda: train_step(topo_devs[:1]))
     if len(topo_devs) >= 4:
         case("train_step_2x2", lambda: train_step(topo_devs[:4]))

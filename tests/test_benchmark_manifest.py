@@ -293,10 +293,38 @@ def _cut_batch_granite(p):
     p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
 
 
+# xing-train-1chip: the same, at the widths of tests/test_xing_train.py (one
+# of 2 leading dense layers and 2 sparse ones on 4 residual streams of 64
+# under 20 sweeps, 2 of 4 heads of 16 + 8 / 16 under YaRN, 2 of 8 experts
+# of 32 beside a shared one, 64 of 512 ids, 2 packed rows of 32 tokens, tiles
+# of 16)
+XING = "xing-train-1chip"
+TINY_XING = dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
+                 num_key_value_heads=4, heads_here=2, q_lora_rank=32,
+                 kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, moe_intermediate_size=32, n_routed_experts=8,
+                 num_experts_per_tok=2, experts_here=2, vocab_size=512,
+                 vocab_here=64, layers_here=3, dense_here=1)
+TINY_XING_TRAIN = dict(seq_len=32, micro_batch=2, attn_block=16,
+                       loss_block_rows=16, compute_dtype="float32")
+
+
+def _tiny_xing(config):
+    config.update(TINY_XING)
+    config["rope_scaling"]["original_max_position_embeddings"] = 16
+    config["train"].update(TINY_XING_TRAIN)
+
+
+def _cut_batch_xing(p):
+    p.update(sequences=TINY_XING_TRAIN["micro_batch"],
+             seq_len=TINY_XING_TRAIN["seq_len"])
+    p["bytes"] = 4 * p["sequences"] * (p["seq_len"] + 2)
+
+
 #: the cells later PRs brought, in the order they were appended: a test of
 #: an earlier cell's place at the end of a list leaves out the ones behind
 #: it (``LATER[LATER.index(cell) + 1:]``)
-LATER = (KEYE, SDAR, OURO, GRANITE)
+LATER = (KEYE, SDAR, OURO, GRANITE, XING)
 
 
 def held_once(real, new) -> None:
@@ -406,6 +434,11 @@ CELLS = {
         metrics={"small_msg_us", "setup_s"},
         config=("granite-4.0-h-micro-train-1chip", _tiny_granite),
         cut={"packed-16k-docs-steps": _cut_batch_granite}),
+    XING: dict(
+        devices=1, points=1, pool_shift=0,
+        metrics={"small_msg_us", "setup_s"},
+        config=("xing4.0-29b-a4b-train-1chip", _tiny_xing),
+        cut={"packed-4k-hyper-steps": _cut_batch_xing}),
 }
 NEW_CELLS = [c for c in CELLS if c != CELL]
 # cells whose calls are steps: many collectives or none a call
@@ -416,7 +449,7 @@ PER_STEP_CONSTANTS = ("train_tokens", "moe_token_slots", "train_mtp_tokens",
 STEP_CELLS = ("rank1-partitioned", "olmoe-train-1chip", "joyai-train-1chip",
               "nemotron3-train-1chip", "lfm2-train-1chip",
               "qwen3next-train-1chip", "smallthinker-train-1chip", KEYE,
-              SDAR, OURO, GRANITE)
+              SDAR, OURO, GRANITE, XING)
 CALL_CELLS = [c for c in NEW_CELLS if c not in STEP_CELLS]
 
 # the per-layer metrics of the build record (PR 53): read by their own
@@ -430,7 +463,7 @@ PEAK_METRIC = "step.hbm_peak_share"
 LIVE_ROWS = "moe.live_row_share"
 SHARE_CELLS = ("joyai-train-1chip", "nemotron3-train-1chip",
                "lfm2-train-1chip", "qwen3next-train-1chip",
-               "smallthinker-train-1chip", KEYE, SDAR)
+               "smallthinker-train-1chip", KEYE, SDAR, XING)
 # what routing and dispatch cost such a cell's step (PR 59)
 ROUTE_SHARE = "moe.route_share"
 
@@ -476,12 +509,24 @@ builds.append(spc.read("device_program_builds"))
 print("counters " + json.dumps({{k: v for k, v in spc.counters().items()
                                  if k.startswith(("device_", "train_",
                                                   "moe_", "attn_", "doc_",
-                                                  "dsa_", "bd_", "loop_"))}}))
+                                                  "dsa_", "bd_", "loop_",
+                                                  "hc_"))}}))
 print("programs " + json.dumps(sorted(set(programs))))
 print("builds " + json.dumps(builds))
 print("layer " + json.dumps(layer))
 print("result " + json.dumps(result))
 """
+
+
+def scopes_up_to(last: str, n: int = None) -> tuple:
+    """The ``n`` names of the program's ``trace.STEP_SCOPES`` that end in
+    ``last`` (all of them up to it where ``n`` is None): where a metric
+    file's ``vocabulary`` stands in the tuple, whatever later PRs put
+    behind it."""
+    from ompi_tpu.runtime import trace
+
+    upto = trace.STEP_SCOPES[:trace.STEP_SCOPES.index(last) + 1]
+    return upto if n is None else upto[-n:]
 
 
 @pytest.fixture(scope="module")
@@ -958,7 +1003,8 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
     by_name = {m["name"]: m for m in real["per_layer"]}
     for name in new:
         m = by_name[name]
-        assert (m["workloads"], m["moves"]) == ([KEYE], "small_msg_us")
+        assert ([c for c in m["workloads"] if c not in LATER[1:]],
+                m["moves"]) == ([KEYE], "small_msg_us")
         twin = by_name.get(name.replace("keye.", "smallthinker."))
         if twin and twin is not m:
             assert {k: m[k] for k in ("unit", "better", "source", "layer")} \
@@ -987,10 +1033,10 @@ def test_the_sparse_cells_metrics_are_entries_of_the_manifest(real):
         spec = json.load(f)
     from ompi_tpu.runtime import trace
 
-    # PR 64 and PR 67 put three names each behind these
+    # later PRs put names behind these
     assert spec["params"]["scopes"] == ["otpu_dsa_loss"] \
-        and tuple(spec["params"]["vocabulary"]) == trace.STEP_SCOPES[
-            -len(spec["params"]["vocabulary"]) - 6:-6]
+        and tuple(spec["params"]["vocabulary"]) == scopes_up_to(
+            "otpu_dsa_loss", len(spec["params"]["vocabulary"]))
 
 
 @of_cells(SDAR)
@@ -1052,9 +1098,9 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
     assert (cell["chips"], config["reduced"]) == (
         1, ["layers", "experts", "vocab", "ranks"])
     for m in real["end_to_end"] + real["per_layer"]:
-        if KEYE in m.get("workloads", ()) and len(m["workloads"]) > 1:
-            assert [c for c in m["workloads"]
-                    if c not in LATER[2:]][-1] == SDAR, m["name"]
+        then = [c for c in m.get("workloads", ()) if c not in LATER[2:]]
+        if KEYE in then and len(then) > 1:
+            assert then[-1] == SDAR, m["name"]
     assert SDAR in by_name["attn.pairs_walked_share"]["workloads"]
     assert (readers["bd.visible_share"]["reader"],
             readers["bd.visible_share"]["params"]) == ("program_counter", {
@@ -1074,10 +1120,10 @@ def test_the_block_diffusion_cells_metrics_are_entries_of_the_manifest(real):
     for name, scope in (("bd.operator_share", "otpu_bd"),
                         ("bd.noise_share", "otpu_bd_noise"),
                         ("bd.loss_share", "otpu_bd_loss")):
-        spec = readers[name]["params"]      # PR 67 put three behind
+        spec = readers[name]["params"]      # later PRs put names behind
         assert spec["scopes"] == [scope] and tuple(
-            spec["vocabulary"]) == trace.STEP_SCOPES[-len(
-                spec["vocabulary"]) - 3:-3]
+            spec["vocabulary"]) == scopes_up_to(
+                "otpu_bd_loss", len(spec["vocabulary"]))
 
 
 @of_cells(OURO)
@@ -1172,8 +1218,8 @@ def test_the_looped_cells_metrics_are_entries_of_the_manifest(real):
             ("loop.cast_share", ["otpu_cast"])):
         spec = readers[name]["params"]
         assert spec["scopes"] == scopes and tuple(
-            spec["vocabulary"]) == trace.STEP_SCOPES[-len(
-                spec["vocabulary"]):]
+            spec["vocabulary"]) == scopes_up_to(
+                "otpu_exit_loss", len(spec["vocabulary"]))
         assert set(scopes) <= set(trace.STEP_SCOPES)
 
 
@@ -1224,8 +1270,12 @@ def test_the_padding_free_cells_entries_are_the_manifests(real):
         "packed-16k-docs-steps", 1, ["layers", "heads", "vocab"])
     assert config["source"] == "https://huggingface.co/ibm-granite/" \
         "granite-4.0-h-micro/blob/main/config.json"
-    assert LATER[-1] == GRANITE and at == len(real["workloads"]) - 1
-    listed = {m["name"]: m["workloads"]
+    # behind every cell that was there before it, whatever came later
+    before = [w["name"] for w in real["workloads"]
+              if w["name"] not in LATER[LATER.index(GRANITE):]]
+    assert [w["name"] for w in real["workloads"]][:at] == before
+    listed = {m["name"]: [c for c in m["workloads"]
+                          if c not in LATER[LATER.index(GRANITE) + 1:]]
               for m in real["end_to_end"] + real["per_layer"]
               if GRANITE in m.get("workloads", ())}
     assert all(cells[-1] == GRANITE and len(cells) > 1
@@ -1260,6 +1310,83 @@ def test_the_padding_free_cells_entries_are_the_manifests(real):
     assert not [m["name"] for m in real["per_layer"]
                 if m["name"].startswith(("moe.", "doc.", "granite."))
                 and GRANITE in m.get("workloads", ())]
+
+
+@of_cells(XING)
+def test_a_stream_step_counts_its_sublayers_and_its_sweeps(rehearsal):
+    """The trainer's counters on one chip's share of Xing4.0-29B-A4B (a dense
+    and two sparse layers here), by the kind that reads everything from the
+    kit and with no file of the harness edited for it: six sublayer
+    applications on four residual streams of 20 sweeps each, a mixing map
+    that 20 sweeps leave a little short of doubly stochastic, 2 sigmoid
+    routers of 2 of 8 experts under a bias with 2 held, latent attention's
+    plain causal walk over the held heads; the step's program is the one
+    program built, in set-up."""
+    (row,), c = rehearsal["points"].values(), rehearsal["counters"]
+    tokens = TINY_XING_TRAIN["micro_batch"] * TINY_XING_TRAIN["seq_len"]
+    assert row["kind"] == "train_step_kit" and row["tolerance"]["why"]
+    assert row["name"] == "train_step.xing.bf16.1x4096"
+    assert rehearsal["run"]["spc_device_collectives"] == 0
+    assert c["train_steps"] > row["k"] * row["windows"]
+    assert (c["hc_built"], c["hc_sweeps_built"]) == (6, 120)
+    assert 0 < c["hc_defect_ppm"] < 50_000
+    assert c["moe_local_slots"] + c["moe_absent_slots"] \
+        == c["train_steps_read"] * tokens * 2 * 2
+    assert c["moe_gmm_built"] == 6 and c["moe_scatter_built"] == 2 \
+        and c["moe_chunk_rows"] >= c["moe_local_slots"] > 0
+    assert c["attn_built"] == 3 and c["attn_shared_kv_built"] == 0 \
+        and c["attn_pairs_walked"] == c["attn_pairs_causal"] > 0
+    for name in ("bd_built", "dsa_built", "attn_window_built", "loop_built",
+                 "doc_built"):
+        assert c.get(name, 0) == 0, name
+    assert rehearsal["builds"] == [1, 1]
+
+
+def test_the_stream_cells_entries_are_the_manifests(real):
+    """PR 73 brought a configuration, a cell and list entries, and no metric
+    (``per_layer`` holds its 128; the path's four wait, PERF.md section 7):
+    everything found by name.  The cell's name stands at the end of every
+    list it is in: the ones every step cell is in, the whole-step shares and
+    the two kernels' rates by the kit's counts, and the experts' four, whose
+    readers take everything from the point's kind and kit."""
+    assert len(real["per_layer"]) == 128
+    cell, config = cell_and_config(real, XING)
+    assert (cell["traffic"], cell["chips"], config["reduced"]) == (
+        "packed-4k-hyper-steps", 1,
+        ["layers", "experts", "heads", "vocab", "mtp"])
+    assert config["source"] == "https://huggingface.co/XingChen-AGI/" \
+        "Xing4.0-29B-A4B/blob/main/config.json"
+    names = [w["name"] for w in real["workloads"]]
+    assert LATER[-1] == XING and names.index(XING) > names.index(GRANITE)
+    listed = {m["name"]: m["workloads"]
+              for m in real["end_to_end"] + real["per_layer"]
+              if XING in m.get("workloads", ())}
+    assert all(cells[-1] == XING and len(cells) > 1
+               for cells in listed.values())
+    every = {m["name"] for m in real["end_to_end"] + real["per_layer"]
+             if {"nemotron3-train-1chip", "lfm2-train-1chip", OURO, GRANITE}
+             <= set(m.get("workloads", ()))}
+    assert every < set(listed) and "small_msg_us" in every \
+        and "step.hbm_peak_share" in every \
+        and {n for n in every if n.startswith("compile.")} >= set(
+            BUILD_METRICS)
+    assert sorted(set(listed) - every) == [
+        "keye.attn_bwd_mfu", "lfm2.flash_mfu", "moe.gmm_kernel_share",
+        "moe.gmm_share", "moe.live_row_share", "moe.route_share", "nemo.mfu",
+        "nemo.remat_share", "nemo.unnamed_share"]
+    for name in listed:
+        if name in {m["name"] for m in real["per_layer"]}:
+            with open(os.path.join(BENCH, "metrics", name + ".json"),
+                      encoding="utf-8") as f:
+                spec = json.load(f)
+            select = spec.get("params", {}).get("select", {})
+            assert select.get("kind", "train_step_kit") in (
+                "train_step_kit", ["train_step_kit"]) \
+                or "train_step_kit" in select["kind"], name
+    assert not [m["name"] for m in real["per_layer"]
+                if m["name"].startswith(("mla.", "joyai.", "hc.", "xing."))
+                and XING in m.get("workloads", ())]
+    assert "nemo.tokens_per_s" not in listed    # its amount is 8,192
 
 
 def test_the_window_cells_metrics_are_entries_of_the_manifest(real):
@@ -1380,7 +1507,7 @@ def test_the_route_share_is_an_entry_of_the_manifest(real):
               encoding="utf-8") as f:
         base = json.load(f)["scopes"]
     assert tuple(base + spec["params"]["vocabulary"]) \
-        == trace.STEP_SCOPES[:-6]
+        == scopes_up_to("otpu_dsa_loss")
     kinds = set()
     for cell in real["workloads"]:
         with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"),

@@ -99,6 +99,18 @@ TREES = {
              shared_intermediate_size=96, vocab_size=256, vocab_here=64,
              eos_token_here=63, layers_here=3, first_layer_here=4),
         "7bb1f947f7dfc79b", "8250c5af4151712d"),
+    # PR 73's own tree, pinned as it was brought: latent attention over a
+    # share of its heads, one of two leading dense layers, and around each
+    # sublayer the residual path's three leaves (``hc1_*``, ``hc2_*``)
+    "xing4.0-29b-a4b-train-1chip.json": (
+        dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
+             num_key_value_heads=4, heads_here=2, q_lora_rank=32,
+             kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+             v_head_dim=16, moe_intermediate_size=32, num_experts=8,
+             num_experts_per_tok=2, vocab_size=512, vocab_here=64,
+             experts_here=2, layers_here=3, hc_gate_start=1.0,
+             hc_offset_std=1.0, hc_res_diag=2.0),
+        "59211c710b2c4185", "c511356b9b705cd6"),
 }
 
 

@@ -154,15 +154,16 @@ def test_the_scopes_and_counters_are_named():
     from ompi_tpu.runtime import spc
 
     spc.init()
-    assert trace.STEP_SCOPES[-3:] == (
-        "otpu_loop_pass", "otpu_exit_gate", "otpu_exit_loss")
+    loop = ("otpu_loop_pass", "otpu_exit_gate", "otpu_exit_loss")
+    at = trace.STEP_SCOPES.index(loop[0])
+    assert trace.STEP_SCOPES[at:at + 3] == loop
     before = {k: spc.read(k) for k in (
         "loop_built", "loop_passes", "loop_layers_held",
         "loop_layer_applications", "loop_head_rows")}
     tokens, labels = batch_of(0)
     text = jax.jit(loss_of(F32, tokens, labels)).lower(
         train.init_model_params(F32, 0)).as_text(debug_info=True)
-    for scope in trace.STEP_SCOPES[-3:]:
+    for scope in loop:
         assert scope in text, scope
     assert "otpu_bd_loss" not in text
     # lowering moves no counter: the step's plan counts the walk, from

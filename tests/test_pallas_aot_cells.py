@@ -509,6 +509,76 @@ def test_granite_train_step_aot_compiles_from_the_cells_configuration(
 
 
 @pytest.fixture(scope="module")
+def xing_rows():
+    """One child for Xing4.0-29B-A4B's cases: both flash kernels at 192 /
+    128 over the 16 held heads under YaRN's scale, and the whole step of the
+    cell's own configuration file on four residual streams, for one v5e
+    device (about a minute of the 600)."""
+    return rows_with_texts("xing_")
+
+
+def test_the_latent_kernels_aot_compile_over_the_held_heads(xing_rows):
+    """q and k 192 wide, v 128, 16 heads x 4,096 positions in 4 blocks of
+    1,024, the scores' scale 2.00474 / sqrt(192) a static argument: one
+    Mosaic call each."""
+    for case in ("xing_flash_causal_forward", "xing_attn_block_backward_1k"):
+        row = xing_rows[case]
+        assert row.get("compiled"), json.dumps(row, indent=1)
+        assert row["entry_ops"].get("custom-call") == 1, (case,
+                                                          row["entry_ops"])
+        with open(row["hlo"], encoding="utf-8") as f:
+            assert "bf16[1,16,4096,192]" in f.read(), case
+
+
+def test_xing_train_step_aot_compiles_from_the_cells_configuration(
+        xing_rows):
+    """The whole step of ``benchmark/configs/xing4.0-29b-a4b-train-1chip
+    .json`` (published widths; 1 dense + 4 sparse layers of 40, 16 of 32
+    heads, 8 of 64 experts, an eighth of the vocabulary, 1 x 4,096 tokens on
+    four residual streams): it fits the chip beside its 8.4 GB of state
+    (700,363,790 parameters and AdamW's two moments); the four sparse layers
+    are one loop; latent attention's two kernels lie under ``otpu_mla`` and
+    the experts' under ``otpu_moe``, none under the residual path; the
+    path's five scopes hold ops in the forward, the recomputed and the
+    backward pass, none of them a custom call or a loop of XLA's; the
+    streams' copies lie under ``otpu_embed`` and their sum under
+    ``otpu_head``; the maps' product is float32."""
+    row = xing_rows["xing_step_1chip"]
+    assert row.get("compiled"), json.dumps(row, indent=1)
+    assert row["entry_ops"]["while"] >= 2
+    assert row["compile_s"] < 300
+    assert fits_a_v5e(row), json.dumps(row, indent=1)
+    assert row["argument_bytes"] < 3 * 4 * 700_363_790 + (1 << 20)
+    kernels = [path.split("jit(otpu_train_step)/")[1]
+               for line, path in op_paths(row) if " custom-call(" in line]
+    for name, scope in (("otpu_flash_causal_forward", "otpu_mla"),
+                        ("otpu_attn_block_backward", "otpu_mla"),
+                        ("otpu_gmm", "otpu_moe")):
+        found = [p for p in kernels if f"/{name}" in p]
+        assert found and all(scope in p for p in found), (name, found)
+    assert not [p for p in kernels if "otpu_hc" in p]
+    paths = [(line, path) for line, path in op_paths(row)
+             if "otpu_hc" in path]
+    assert not [path for line, path in paths if " while(" in line]
+    for scope in ("otpu_hc_maps", "otpu_hc_sinkhorn", "otpu_hc_read",
+                  "otpu_hc_write"):
+        mine = [path for _, path in paths if f"otpu_hc/{scope}" in path]
+        assert [p for p in mine if "rematted_computation" in p], scope
+        assert [p for p in mine if "transpose(" in p
+                and "rematted_computation" not in p], scope
+        assert [p for p in mine if "transpose(" not in p
+                and "rematted_computation" not in p], scope
+    every = [path for _, path in op_paths(row)]
+    for scope in ("otpu_mla", "otpu_moe", "otpu_dense_mlp", "otpu_embed",
+                  "otpu_head"):
+        assert any(scope in p for p in every), scope
+    with open(row["hlo"], encoding="utf-8") as f:
+        text = f.read()
+    assert "f32[1,4096,4,3584]" in text
+    assert not re.search(r"bf16\[4096,14336\]|bf16\[14336,24\]", text)
+
+
+@pytest.fixture(scope="module")
 def nemotron_scan_rows():
     """One child for the scan's two kernels at Nemotron-3-Super's shape
     (the cell's other cases are ``test_pallas_aot.py``'s; about 10 s)."""
@@ -703,7 +773,7 @@ def gmm_rows():
 
 
 @pytest.mark.parametrize("cell", ["lfm2", "olmoe", "joyai", "nemotron",
-                                  "qwen3next", "smallthinker"])
+                                  "qwen3next", "smallthinker", "xing"])
 def test_grouped_matmul_aot_compiles_at_a_cells_shapes(cell, gmm_rows):
     """``ops/grouped_matmul``'s three kernels at the tiles the module
     chooses for a cell's rows a call, held experts and both expert

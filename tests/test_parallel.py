@@ -286,8 +286,8 @@ def test_build_train_step_needs_a_model():
 #: the model path top down: a module imports only those before it (the
 #: configuration, first, nothing of the package)
 MODEL_PATH = ("config", "mesh", "layers", "sublayer", "experts", "causal",
-              "attention", "dsa", "mamba", "short_conv", "gdn", "model",
-              "objective", "train")
+              "attention", "dsa", "mamba", "short_conv", "gdn", "hyper",
+              "model", "objective", "train")
 
 
 def test_model_path_imports_point_one_way_at_module_top():

@@ -24,7 +24,7 @@ from ompi_tpu.parallel.sublayer import INTERPRET
 from ompi_tpu.runtime import spc
 
 CELLS = sorted(TREES)
-assert len(CELLS) == 10
+assert len(CELLS) == 11
 #: a family's decision function, by the part of a sublayer's plan it
 #: answers for
 DECISIONS = {"flash": (causal, "flash_on_kernels"),
@@ -127,6 +127,11 @@ def test_every_count_is_a_declared_counter_times_run_and_passes(name):
                 + by * row["run"] * row["passes"]
     if cfg.total_ut_steps:
         want.update(objective.loop_counts(cfg, cfg.micro_batch, cfg.seq_len))
+    for row in plan["rows"]:        # the residual path's, a sublayer each
+        for counter, by in row.get("hc", {"counts": {}})["counts"].items():
+            held = sum(row[k] is not None for k in ("operator", "ffn"))
+            want[counter] = want.get(counter, 0) \
+                + by * held * row["run"] * row["passes"]
     assert plan["counts"] == want
     assert set(want) <= set(spc._COUNTERS)
     assert "loop_exit_depth" not in want      # read back a step, not built
@@ -164,6 +169,8 @@ REFUSED = {
     # a shared expert narrower than the stream keeps autodiff's backward
     "joyai-flash-train-1chip.json": dict.fromkeys(
         [("2-5", "ffn_bwd"), ("mtp", "ffn_bwd")], NARROW % (768, 2048)),
+    "xing4.0-29b-a4b-train-1chip.json": {
+        ("2-5", "ffn_bwd"): NARROW % (1024, 3584)},
 }
 REFUSED["qwen3-next-80b-a3b-train-1chip.json"].update(dict.fromkeys(
     [("1-3", "ffn_bwd"), ("4", "ffn_bwd")], NARROW % (512, 2048)))
@@ -200,6 +207,10 @@ COUNTS = {
     "lfm2-8b-a1b-train-1chip.json": dict(
         attn_built=2, attn_qk_built=4, moe_gmm_built=15,
         moe_gmm_kernel_built=15),
+    "xing4.0-29b-a4b-train-1chip.json": dict(
+        attn_built=5, attn_pairs_walked=50, hc_built=10,
+        hc_sweeps_built=200, moe_gmm_built=12, moe_gmm_kernel_built=12,
+        ffn_built=5, ffn_bwd_written_built=1),
 }
 
 

@@ -640,7 +640,12 @@ def test_the_vocabulary_is_the_sources_and_the_benchmarks():
         with open(os.path.join(metrics, name), encoding="utf-8") as f:
             listed |= set(json.load(f).get("params", {}).get(
                 "vocabulary", ()))
-    assert set(trace.STEP_SCOPES[held:]) <= listed <= set(trace.STEP_SCOPES)
+    # the residual path's five wait for room in ``per_layer`` with the
+    # metrics that would list them (PERF.md section 7, ROADMAP.md D0)
+    waiting = {s for s in trace.STEP_SCOPES if s.startswith("otpu_hc")}
+    assert len(waiting) == 5
+    assert set(trace.STEP_SCOPES[held:]) - waiting <= listed \
+        <= set(trace.STEP_SCOPES) - waiting
     assert data["passes"] == list(trace.PASSES)
     assert data["update_scopes"] == list(trace.UPDATE_SCOPES)
 
@@ -681,3 +686,28 @@ def test_hlo_same_tells_a_moved_instruction_from_a_renamed_one():
     moved = TEXT.replace("subtract(%param_0.1, %param_0.1)",
                          "multiply(%param_0.1, %param_0.1)")
     assert moved != TEXT and hlo_same.compare(TEXT, moved) == "DIFFERENT"
+
+
+def test_the_residual_paths_scopes_and_counters_are_named():
+    """PR 73's five scopes stand behind every name that was there, the
+    whole before its four parts, and its three counters are SPC's: two fed
+    from a step's plan, one read back (``tests/test_xing_step.py`` moves
+    them)."""
+    from ompi_tpu.runtime import spc
+
+    at = trace.STEP_SCOPES.index("otpu_exit_loss")
+    assert trace.STEP_SCOPES[at + 1:at + 6] == (
+        "otpu_hc", "otpu_hc_maps", "otpu_hc_sinkhorn", "otpu_hc_read",
+        "otpu_hc_write")
+    spc.init()
+    assert {"hc_built", "hc_sweeps_built", "hc_defect_ppm"} \
+        <= set(spc.counters())
+    for which, path in (
+            ("forward", "jit(f)/jvp(otpu_layers)/otpu_hc/otpu_hc_read/mul"),
+            ("remat", "jit(f)/transpose(jvp(otpu_layers))/checkpoint/"
+             "rematted_computation/otpu_hc/otpu_hc_write/add"),
+            ("backward", "jit(f)/transpose(jvp(otpu_layers))/otpu_hc/"
+             "otpu_hc_maps/dot_general")):
+        chain, got, unknown = trace.scope_of_path(path)
+        assert (chain[:2], got, unknown) == (["otpu_layers", "otpu_hc"],
+                                             which, []), path
