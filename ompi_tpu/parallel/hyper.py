@@ -24,14 +24,29 @@ in bfloat16.  The maps are held token-last, (n, T) and (n, n, T), so that a
 sweep's sums run over leading axes and the token rows lie along the lanes.
 There is no Pallas kernel: the lines are ``jnp`` that XLA fuses as it will.
 
+**The stream is held stream-major, (b, n, s, d)**, from the embedding to
+the head: a stream is ``x[:, j]``, a dense (b, s, d) slab whichever of s and
+d the compiler lays along the lanes (on a v5e it chooses s, the maps'
+axis).  ``maps``, ``read`` and ``write`` take the slabs one at a time
+(``slabs``) and never make a (T, n d) or (T, n, d) view of the stream, pad
+it or transpose it: held (b, s, n, d), the compiler's position-minor layout
+made the maps' view a transposing copy of the whole stream (2.8 ms of 235
+MB at Xing's shape, several a layer and pass) and reached single streams
+through pads and broadcasts of all n (``PERF.md`` section 6, PR 74).
+``phi``'s rows are stream-major too, so stream j's block is ``phi[j d:(j +
+1) d]`` and ``x' phi`` the sum of n (T, d) x (d, n^2 + 2 n) products.
+
 ``parallel/model.decoder_layer`` applies it where ``cfg.hc_mult`` > 1,
 under the scopes ``otpu_hc`` (whole), ``otpu_hc_maps``, ``otpu_hc_sinkhorn``,
 ``otpu_hc_read`` and ``otpu_hc_write``; the stream is made from the
-embedding (n copies) and summed before the head by
-``parallel/objective.model_loss``.  A layer holds a set of leaves a
+embedding (n copies along axis 1) and summed over that axis before the head
+by ``parallel/objective.model_loss``.  A layer holds a set of leaves a
 sublayer: ``hc1_*`` the operator's, ``hc2_*`` the feed-forward's.
 """
 from __future__ import annotations
+
+import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -79,9 +94,10 @@ STARTS = {f"{at}_{part}": start for at in SETS
 
 def reports(cfg, at: str) -> dict:
     """What the path around one sublayer reports by token row (``{key:
-    axes behind the rows}``): the stream it read, ``<at>_in`` (T, n, d),
-    and the three maps it made of it, ``<at>_pre`` and ``<at>_post`` (T, n)
-    and ``<at>_res`` (T, n, n)."""
+    axes behind the rows}``): the stream it read, ``<at>_in`` (rows, n, d),
+    and the three maps it made of it, ``<at>_pre`` and ``<at>_post`` (rows,
+    n) and ``<at>_res`` (rows, n, n); ``seen`` cuts them to a step's
+    sampled rows itself."""
     return {f"{at}_in": 2, f"{at}_pre": 1, f"{at}_post": 1, f"{at}_res": 2}
 
 
@@ -98,17 +114,47 @@ def sinkhorn(raw, cfg, iters=None):
     return m
 
 
+def slabs(x):
+    """The n streams of ``x`` (b, n, s, d), each a dense slab (b, s, d).
+    A split, not n slices: its transpose is one concatenation on the major
+    axis, as ``write``'s result is, where a slice's is a pad of the whole
+    stream."""
+    b, n, s, d = x.shape
+    return [v.reshape(b, s, d) for v in jax.lax.split(x, (1,) * n, axis=1)]
+
+
+def _total(terms):
+    """The terms' sum, without ``sum``'s leading zero: an equation less a
+    sum to trace, and the path is traced four times a step."""
+    return functools.reduce(operator.add, terms)
+
+
+def _rows(m, b, s):
+    """The leading axis' rows of a token-last map ``m`` (k, T), each (b, s,
+    1): a slab's scalar a token.  One split a map: k ``jnp`` indexings trace
+    slower, and n^2 of them a write cost set-up 8% (``PERF.md`` section 6,
+    PR 74)."""
+    return [v.reshape(b, s, 1)
+            for v in jax.lax.split(m, (1,) * m.shape[0], axis=0)]
+
+
 def maps(p, x, cfg, at: str):
     """``(Hpre (n, T), Hpost (n, T), Hres (n, n, T))`` of the stream ``x``
-    (b, s, n, d) float32 from the leaves ``<at>_phi``, ``<at>_alpha`` and
-    ``<at>_b`` of ``p``, token-last, T = b s."""
-    b, s, n, d = x.shape
+    (b, n, s, d) float32 from the leaves ``<at>_phi``, ``<at>_alpha`` and
+    ``<at>_b`` of ``p``, token-last, T = b s.  The norm's mean of squares
+    is the n slabs' row sums', ``x' phi`` the sum of the n slabs' products
+    with ``phi``'s row blocks (its rows are stream-major), and the norm's
+    ``rsqrt``, a token's scalar, scales the (T, n^2 + 2 n) result."""
+    b, n, s, d = x.shape
     phi, alpha, off = (p[f"{at}_{part}"] for part in PARTS)
     with jax.named_scope("otpu_hc_maps"):
-        flat = x.reshape(b * s, n * d)
-        normed = flat * jax.lax.rsqrt(
-            jnp.mean(flat * flat, axis=-1, keepdims=True) + cfg.rms_norm_eps)
-        m = jnp.dot(normed, phi, precision=jax.lax.Precision.HIGHEST).T
+        rows = [v.reshape(b * s, d) for v in slabs(x)]
+        squares = _total(jnp.sum(v * v, axis=-1, keepdims=True)
+                         for v in rows)
+        m = _total(jnp.dot(v, phi[j * d:(j + 1) * d],
+                           precision=jax.lax.Precision.HIGHEST)
+                   for j, v in enumerate(rows))
+        m = (m * jax.lax.rsqrt(squares / (n * d) + cfg.rms_norm_eps)).T
         gated = lambda i, lo, hi: alpha[i] * m[lo:hi] + off[lo:hi, None]
         pre = jax.nn.sigmoid(gated(0, 0, n))
         post = 2.0 * jax.nn.sigmoid(gated(1, n, 2 * n))
@@ -120,29 +166,36 @@ def maps(p, x, cfg, at: str):
 
 def read(pre, x):
     """``u = Hpre X`` (b, s, d): a sublayer's input."""
-    b, s, n, _ = x.shape
+    b, n, s, _ = x.shape
     with jax.named_scope("otpu_hc_read"):
-        w = pre.T.reshape(b, s, n, 1)
-        return sum(w[:, :, j] * x[:, :, j] for j in range(n))
+        return _total(w * v for w, v in zip(_rows(pre, b, s), slabs(x)))
 
 
 def write(res, post, x, y):
-    """``Hres X + Hpost^T y`` (b, s, n, d): the stream behind a sublayer
-    whose result is ``y`` (b, s, d)."""
-    b, s, n, _ = x.shape
+    """``Hres X + Hpost^T y`` (b, n, s, d): the stream behind a sublayer
+    whose result is ``y`` (b, s, d), its n slabs made one by one and joined
+    on the major axis."""
+    b, n, s, _ = x.shape
     with jax.named_scope("otpu_hc_write"):
-        mix = res.transpose(2, 0, 1).reshape(b, s, n, n, 1)
-        out = post.T.reshape(b, s, n, 1) * y.astype(jnp.float32)[:, :, None]
-        for j in range(n):
-            out = out + mix[:, :, :, j] * x[:, :, j, None]
-        return out
+        y, xs = y.astype(jnp.float32), slabs(x)
+        mix = _rows(res.reshape(n * n, -1), b, s)
+        return jnp.stack([
+            w * y + _total(m * v
+                           for m, v in zip(mix[i * n:(i + 1) * n], xs))
+            for i, w in enumerate(_rows(post, b, s))], axis=1)
 
 
-def seen(pre, post, res, x, at: str) -> dict:
-    """What ``reports`` lists, by token row."""
-    n, _, t = res.shape
-    return {f"{at}_in": x.reshape(t, n, -1), f"{at}_pre": pre.T,
-            f"{at}_post": post.T, f"{at}_res": res.transpose(2, 0, 1)}
+def seen(pre, post, res, x, at: str, rows=None) -> dict:
+    """What ``reports`` lists, at the token rows ``rows`` (an index into
+    the T rows; None: every row).  The stream's rows are taken from each
+    slab, so the report costs the rows it holds and not a view of the
+    stream."""
+    b, n, s, d = x.shape
+    pick = (lambda v: v) if rows is None else (lambda v: v[rows])
+    return {f"{at}_in": jnp.stack([pick(v.reshape(b * s, d))
+                                   for v in slabs(x)], axis=1),
+            f"{at}_pre": pick(pre.T), f"{at}_post": pick(post.T),
+            f"{at}_res": pick(res.transpose(2, 0, 1))}
 
 
 def defect(res):

@@ -165,7 +165,7 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
     to the operator where the model resets at their starts (None: it is
     not handed on, and an operator that knows no documents is as it was).
 
-    Under ``cfg.hc_mult`` n > 1 ``x`` is the n residual streams (b, s, n,
+    Under ``cfg.hc_mult`` n > 1 ``x`` is the n residual streams (b, n, s,
     d) and the layer is ``stream_layer``'s: the sublayers' ``run`` are the
     same, each still gets and returns (b, s, d).
 
@@ -206,22 +206,25 @@ def decoder_layer(p, x, cfg, *, interpret: bool, kind: str, bias=None,
 
 def stream_layer(p, x, cfg, layer: LayerKind, *, interpret: bool, bias=None,
                  at=None):
-    """One decoder layer on ``cfg.hc_mult`` residual streams ``x`` (b, s, n,
-    d) float32 (``parallel/hyper.py``: manifold-constrained
-    hyper-connections): around each sublayer ``F`` the path makes its three
-    maps from the stream, ``F`` reads ``u = Hpre X`` (the router too, where
-    ``F`` is the experts) and adds no residual, and the stream behind it is
-    ``Hres X + Hpost^T F(u)``.  The path's own work lies under ``otpu_hc``,
-    the sublayer's under its own scope beside it.  Returns what
+    """One decoder layer on ``cfg.hc_mult`` residual streams ``x`` (b, n, s,
+    d) float32, stream-major: a stream is the dense slab ``x[:, j]``
+    (``parallel/hyper.py``: manifold-constrained hyper-connections).
+    Around each sublayer ``F`` the path makes its three maps from the
+    stream, ``F`` reads ``u = Hpre X`` (the router too, where ``F`` is the
+    experts) and adds no residual, and the stream behind it is ``Hres X +
+    Hpost^T F(u)``.  The path's own work lies under ``otpu_hc``,
+    the sublayer's under its own scope beside it, the reports' sampled rows
+    under ``otpu_stats``.  Returns what
     ``decoder_layer`` does, the path's reports (``hyper.reports``) among
-    the rows."""
+    the rows, cut to the rows ``at`` already."""
     stats, rows = {}, {}
     for part in layer.parts:
         at_path = layer.path_of(part)
         with jax.named_scope("otpu_hc"):
             pre, post, res = hyper.maps(p, x, cfg, at_path)
-            rows.update(hyper.seen(pre, post, res, x, at_path))
             u = hyper.read(pre, x)
+        with jax.named_scope("otpu_stats"):     # the sampled rows' gathers
+            rows.update(hyper.seen(pre, post, res, x, at_path, at))
         with jax.named_scope(part.scope):
             if part is layer.operator:
                 y, st, made = part.run(p, u, cfg, interpret=interpret, at=at)

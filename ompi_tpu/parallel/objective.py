@@ -375,13 +375,13 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
 
     A model with several residual streams (``cfg.hc_mult`` n > 1:
     Xing4.0-29B-A4B's manifold-constrained hyper-connections,
-    ``parallel/hyper.py``) walks a stream (b, s, n, d): n copies of the
-    embedding's rows, made under ``otpu_embed``; the walk's carry and a
-    layer's checkpoint hold all n; the head reads their sum, made under
-    ``otpu_head``.  ``sample`` then holds the path's rows of every held
-    layer (``hyper.reports``), the dense layers' first, and ``aux``
-    ``hc_defect``, the largest defect from doubly stochastic of the mixing
-    maps at the sampled rows."""
+    ``parallel/hyper.py``) walks a stream (b, n, s, d), stream-major: n
+    copies of the embedding's rows, made under ``otpu_embed``; the walk's
+    carry and a layer's checkpoint hold all n; the head reads their sum,
+    made under ``otpu_head``.  ``sample`` then holds the path's rows of
+    every held layer (``hyper.reports``), the dense layers' first, and
+    ``aux`` ``hc_defect``, the largest defect from doubly stochastic of the
+    mixing maps at the sampled rows."""
     psum = (lambda a: jax.lax.psum(a, axes)) if axes else (lambda a: a)
     b, s = tokens.shape
     ids, levels, masked = tokens, None, None
@@ -406,9 +406,11 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         leaves."""
         operator = layer_kinds(cfg)[kind].operator
         own = set(operator.reports(cfg)) if operator else ()
-        if cfg.hc_mult > 1:     # the residual path's rows, under their names
-            own = {*own, *(k for at in hyper.SETS
-                           for k in hyper.reports(cfg, at))}
+        # the residual path's rows, under their names; ``hyper.seen`` cut
+        # them at the sampled rows
+        cut = {k for path in hyper.SETS for k in hyper.reports(cfg, path)} \
+            if cfg.hc_mult > 1 else ()
+        own = {*own, *cut}
 
         def run(layer, x, bias_row):
             x, st, seen = decoder_layer(layer, x, cfg, interpret=interpret,
@@ -422,7 +424,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                 # position's result holds every earlier row of (``_seq``)
                 out = (jax.tree.map(psum, st), experts, {
                     k if k in own else "router_" + k:
-                    v if k.endswith(("_seq", "_at")) else v[at]
+                    v if k in cut or k.endswith(("_seq", "_at")) else v[at]
                     for k, v in seen.items()})
             return x, out
 
@@ -439,8 +441,8 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
         if cfg.embedding_multiplier != 1.0:
             x = cfg.embedding_multiplier * x
         if cfg.hc_mult > 1:     # the stream starts as hc_mult copies
-            x = jnp.broadcast_to(x[:, :, None], (b, s, cfg.hc_mult,
-                                                 x.shape[-1]))
+            x = jnp.broadcast_to(x[:, None], (b, cfg.hc_mult, s,
+                                              x.shape[-1]))
     if cfg.total_ut_steps:
         return looped_loss(params, x, labels, cfg, run_of, psum, n_global,
                            at_head)
@@ -478,7 +480,7 @@ def model_loss(params, tokens, labels, cfg: ModelConfig, *, interpret: bool,
                     levels, cfg.block_length, axis=1), 0.0)
                 weighted = (weights.reshape(b * s),)
         if cfg.hc_mult > 1:     # the head reads the streams' sum
-            x = jnp.sum(x, axis=2)
+            x = jnp.sum(x, axis=1)
         h = rmsnorm_gain(x, params["final_norm"], cfg.rms_norm_eps)
         ce_sum, rows = head_cross_entropy(
             h.reshape(b * s, -1), head, targets.reshape(b * s), head_rows,

@@ -542,7 +542,12 @@ def test_xing_train_step_aot_compiles_from_the_cells_configuration(
     path's five scopes hold ops in the forward, the recomputed and the
     backward pass, none of them a custom call or a loop of XLA's; the
     streams' copies lie under ``otpu_embed`` and their sum under
-    ``otpu_head``; the maps' product is float32."""
+    ``otpu_head``; the maps' product is float32.  The streams are held
+    stream-major (PR 74), (1, 4, 4096, 3584), each a dense slab: the step
+    holds no (T, n d) view of them (no ``f32[4096,14336]``, no
+    ``f32[4096,4,3584]``), the one layout of the stream the compiler
+    chose, no ``pad``, ``copy`` or ``transpose`` of the stream's size, and
+    its peak is at most what it was with the streams token-major."""
     row = xing_rows["xing_step_1chip"]
     assert row.get("compiled"), json.dumps(row, indent=1)
     assert row["entry_ops"]["while"] >= 2
@@ -574,8 +579,22 @@ def test_xing_train_step_aot_compiles_from_the_cells_configuration(
         assert any(scope in p for p in every), scope
     with open(row["hlo"], encoding="utf-8") as f:
         text = f.read()
-    assert "f32[1,4096,4,3584]" in text
     assert not re.search(r"bf16\[4096,14336\]|bf16\[14336,24\]", text)
+    layouts = set(re.findall(r"f32\[1,4,4096,3584\]\{[0-9,]*", text))
+    assert len(layouts) == 1, layouts
+    assert not re.search(
+        r"f32\[4096,14336\]|f32\[1,4096,4,3584\]|f32\[4096,4,3584\]", text)
+    # a pass of its own over the stream: an instruction of the entry or of
+    # a loop body, not one inside a fusion (a join is a fused pad there)
+    fused, whole = False, []
+    for line in text.split("\n"):
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(", 1)[0]
+        elif not fused and re.search(
+                r"= f32\[(1,)?4,4096,3584\]\S* (pad|copy|transpose)\(", line):
+            whole.append(line[:200])
+    assert not whole, whole[:3]
+    assert row["peak_bytes"] <= 15_425_060_864
 
 
 @pytest.fixture(scope="module")
