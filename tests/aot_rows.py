@@ -3,7 +3,14 @@
 compiles ``tools/pallas_aot``'s cases offline for a v5e, its rows with their
 compiled texts, and the checks that more than one cell's step is held to.
 The files are cut along the ``*_rows`` fixtures so that no fixture's child
-runs in both: under ``--dist loadfile`` each file is one worker's.
+runs in both.  The driver deals tests with ``--dist load``, runs of
+consecutive items to whichever worker is free: ``conftest.py`` collects a
+fixture's users side by side (the cross-cell cases name theirs in the
+parameter ``rows``), and ``rows_with_texts`` keeps a child's rows for the
+session (``built.shared``), so that a second worker that is dealt some of
+a fixture's users reads them and compiles nothing.  Both files are dealt
+first (``conftest.DEALT_FIRST``): their seconds are spent waiting for a
+child, which is better done beside the whole run than at its end.
 """
 import json
 import os
@@ -13,6 +20,8 @@ import sys
 import tempfile
 
 import pytest
+
+import built
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: both files' ``pytestmark``
@@ -43,14 +52,19 @@ def run_aot_subprocess(*extra, limit: int = 240, **env_extra) -> dict:
 
 def rows_with_texts(only: str, **env_extra) -> dict:
     """{case: its row, with ``hlo`` the file of its compiled text} of one
-    child that compiles the cases named ``only`` for a v5e 2x2."""
+    child a session that compiles the cases named ``only`` for a v5e 2x2."""
     pytest.importorskip("libtpu")
-    dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
-    res = run_aot_subprocess("--only", only, "--topology", "v5e:2x2",
-                              "--dump", dump, limit=600, **env_extra)
-    assert res.get("rows"), res.get("error")
-    return {r["kernel"]: dict(r, hlo=os.path.join(
-        dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
+
+    def compiled():
+        dump = tempfile.mkdtemp(prefix="otpu_aot_hlo_")
+        res = run_aot_subprocess("--only", only, "--topology", "v5e:2x2",
+                                  "--dump", dump, limit=600, **env_extra)
+        assert res.get("rows"), res.get("error")
+        return {r["kernel"]: dict(r, hlo=os.path.join(
+            dump, r["kernel"] + ".hlo.txt")) for r in res["rows"]}
+
+    return built.shared("aot-" + "-".join(
+        [only, *(f"{k}={v}" for k, v in sorted(env_extra.items()))]), compiled)
 
 
 def kernel_bodies(hlo_text: str, prefix: str) -> dict:

@@ -1,21 +1,11 @@
 """coll/adapt — event-driven segmented bcast/reduce (off by default)."""
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_adapt_pipelined_bcast_reduce(tmp_path):

@@ -4,9 +4,8 @@ reduce_scatter_block, nonblocking v-variants, neighbor v/w variants,
 persistent buffered/ready sends, imrecv, MPI_Win_test, cart/graph_map,
 type_match_size, MPI_Pcontrol, and MPI_Register_datarep/external32
 file views."""
+import functools
 import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
@@ -14,15 +13,12 @@ import pytest
 
 import ompi_tpu
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 @pytest.fixture(scope="module")

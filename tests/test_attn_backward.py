@@ -15,6 +15,7 @@ from ompi_tpu.parallel import causal
 from ompi_tpu.parallel.flagship import _full_attention
 
 
+
 # (query heads, key-value heads, q's and k's width, v's)
 _LAYOUTS = pytest.mark.parametrize("h,n_kv,d,hv", [
     (2, 2, 128, 128), (2, 2, 192, 128), (8, 2, 64, 64), (4, 1, 128, 128)],
@@ -144,9 +145,9 @@ def _full_gradients(q, k, v, w):
     """Full attention's gradients with k and v repeated a query head by
     ``jnp.repeat``, whose transpose sums each group's."""
     rep = q.shape[1] // k.shape[1]
-    return jax.grad(lambda q, k, v: jnp.sum(_full_attention(
+    return jax.jit(jax.grad(lambda q, k, v: jnp.sum(_full_attention(
         q, jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1), True) * w),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
 
 
 def _gradients_agree(h, n_kv, d, hv, nb, dt, block, interpret, seed):
@@ -157,9 +158,10 @@ def _gradients_agree(h, n_kv, d, hv, nb, dt, block, interpret, seed):
     draw = lambda w, n=h: jnp.asarray(
         rng.normal(0, 1, (2, n, nb * block, w)), jnp.float32)
     q, k, v, w = draw(d), draw(d, n_kv), draw(hv, n_kv), draw(hv)
-    got = jax.grad(lambda q, k, v: jnp.sum(causal.causal_flash_attention(
-        q, k, v, block, interpret) * w), argnums=(0, 1, 2))(
-            q.astype(dt), k.astype(dt), v.astype(dt))
+    got = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(causal.causal_flash_attention(
+            q, k, v, block, interpret) * w), argnums=(0, 1, 2)))(
+                q.astype(dt), k.astype(dt), v.astype(dt))
     rtol, atol = (1e-4, 2e-5) if dt == jnp.float32 else (0.06, 0.06)
     for g, x in zip(got, _full_gradients(q, k, v, w)):
         assert g.dtype == dt and g.shape == x.shape

@@ -14,6 +14,8 @@ import sys
 
 import pytest
 
+import built
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 CELL = "rank1-ddt"
@@ -617,7 +619,6 @@ def _stage(cell, root):
 def _rehearse(cell, root):
     """One run of ``cell`` at tiny sizes on its CPU devices, in a copy of
     the benchmark under ``root`` and a process of its own."""
-    spec = CELLS[cell]
     env = _stage(cell, root)
     done = subprocess.run(
         [sys.executable, "-c", REHEARSAL.format(
@@ -630,7 +631,7 @@ def _rehearse(cell, root):
     def tagged(tag):
         return [json.loads(ln[len(tag) + 1:]) for ln in lines
                 if ln.startswith(tag + " ")]
-    return {"cell": cell, "spec": spec,
+    return {"cell": cell,
             "points": {p["name"]: p for p in tagged("point")},
             "run": tagged("run")[0], "counters": tagged("counters")[0],
             "programs": tagged("programs")[0], "builds": tagged("builds")[0],
@@ -640,13 +641,17 @@ def _rehearse(cell, root):
 
 @pytest.fixture(scope="module")
 def rehearsals(tmp_path_factory):
-    """Each cell's one child, started by the first test that asks."""
+    """Each cell's one child a session, started by the first test that
+    asks on whichever worker (``built.shared``)."""
     done = {}
 
     def of(cell):
         if cell not in done:
-            done[cell] = _rehearse(
-                cell, str(tmp_path_factory.mktemp("bench-" + cell)))
+            root = str(tmp_path_factory.mktemp("bench-" + cell))
+            done[cell] = dict(
+                built.shared("rehearsal-" + cell,
+                             lambda: _rehearse(cell, root)),
+                spec=CELLS[cell])
         return done[cell]
     return of
 

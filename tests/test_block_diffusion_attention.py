@@ -21,6 +21,7 @@ from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.parallel import causal
 from ompi_tpu.runtime import spc
 
+
 BLOCK = 128
 
 
@@ -96,14 +97,14 @@ def test_the_backward_kernel_under_the_mask_is_autodiff(h, n_kv, d, tiles,
     q, k, v = _qkv(d, 2 * tiles * BLOCK, h, n_kv, seed=1)
     do = jnp.asarray(np.random.default_rng(2).normal(0, 1, q.shape),
                      jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, bl)[0] * do),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, bl)[0] * do),
+                    (0, 1, 2)))(q, k, v)
     o, lse = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                      bd=bl)
     got = walk_backward(q, k, v, do, o, lse, bl)
-    twin = jax.grad(lambda *a: jnp.sum(
+    twin = jax.jit(jax.grad(lambda *a: jnp.sum(
         causal.block_diffusion_flash_attention(*a, BLOCK, True, bl) * do),
-        (0, 1, 2))(q, k, v)
+        (0, 1, 2)))(q, k, v)
     for name, g, t, x in zip("qkv", got, twin, want):
         scale = float(jnp.abs(x).max())
         np.testing.assert_allclose(g, x, rtol=1e-4, atol=2e-5 * scale,
@@ -124,11 +125,11 @@ def test_the_twins_backward_walks_agree_beyond_the_unrolled_blocks(blocks,
     q, k, v = _qkv(32, blocks * block, 4, 2, seed=3)
     do = jnp.asarray(np.random.default_rng(4).normal(0, 1, q.shape),
                      jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, bl)[0] * do),
-                    (0, 1, 2))(q, k, v)
-    got = jax.grad(lambda *a: jnp.sum(
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, bl)[0] * do),
+                    (0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(
         causal.block_diffusion_flash_attention(*a, block, True, bl) * do),
-        (0, 1, 2))(q, k, v)
+        (0, 1, 2)))(q, k, v)
     for g, x in zip(got, want):
         np.testing.assert_allclose(g, x, rtol=1e-4,
                                    atol=2e-5 * float(jnp.abs(x).max()))
@@ -140,8 +141,8 @@ def test_a_kernel_tile_of_several_backward_tiles_is_masked_by_position():
     q, k, v = _qkv(64, 4 * BLOCK, 2, 1, seed=5)
     do = jnp.asarray(np.random.default_rng(6).normal(0, 1, q.shape),
                      jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, 4)[0] * do),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, 4)[0] * do),
+                    (0, 1, 2)))(q, k, v)
     o, lse = dense(q, k, v, 4)
     old = fa.BWD_TILE
     fa.BWD_TILE = 64
@@ -231,8 +232,8 @@ def test_the_counters_of_a_pass_under_the_mask():
              "bd_built", "bd_pairs_visible", "bd_pairs_causal")
     before = {n: spc.read(n) for n in names}
     q, k, v = _qkv(32, 8 * 16, 2, 1, seed=9, b=2)
-    jax.grad(lambda q: jnp.sum(causal.block_diffusion_flash_attention(
-        q, k, v, 16, True, 4)))(q)
+    jax.jit(jax.grad(lambda q: jnp.sum(causal.block_diffusion_flash_attention(
+        q, k, v, 16, True, 4))))(q)
     assert {n: spc.read(n) for n in names} == before
     moved = causal.pass_counts(2, 2, 1, 8 * 16, 16, bd=4)
     walked = len(fa.bd_pairs(8, 16, 4))
@@ -246,8 +247,9 @@ def test_the_counters_of_a_pass_under_the_mask():
 def test_a_half_that_is_no_whole_blocks_is_refused():
     q, k, v = _qkv(32, 6 * 16, 2, 1)
     with pytest.raises(ValueError, match="whole"):
-        jax.grad(lambda q: jnp.sum(causal.block_diffusion_flash_attention(
-            q, k, v, 32, True, 4)))(q)
+        jax.jit(jax.grad(
+            lambda q: jnp.sum(causal.block_diffusion_flash_attention(
+                q, k, v, 32, True, 4))))(q)
 
 
 def _text(fn, *args):

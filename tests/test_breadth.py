@@ -1,21 +1,11 @@
 """Breadth components: coll/inter, coll/sync, hook/comm_method, mpisync."""
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_intercomm_collectives(tmp_path):

@@ -18,7 +18,6 @@ Four layers of coverage:
   hangs; a `slow`-lane soak widens to reset/kill across 8 seeds.
 """
 import os
-import subprocess
 import sys
 import threading
 from pathlib import Path
@@ -27,6 +26,8 @@ import numpy as np
 import pytest
 
 from ompi_tpu.ft import chaos
+
+import launch
 
 REPO = Path(__file__).resolve().parent.parent
 HOSTCOLL = Path(__file__).resolve().parent / "fuzz_hostcoll_worker.py"
@@ -431,23 +432,18 @@ def test_unchecksummed_frame_still_parses():
 # ------------------------------------------------- chaos matrix (tpurun)
 
 def _run_matrix_job(spec: str, seed: int, timeout=150):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", HF_SEED=str(seed),
-               HF_ITERS="4")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
-           "--mca", "otpu_chaos_spec", spec,
-           "--mca", "otpu_chaos_seed", str(seed),
-           # detector on: CTL heartbeat traffic gives the loss faults
-           # something to chew on; generous envelope so injected delays
-           # don't read as deaths
-           "--mca", "ft_detector", "true",
-           "--mca", "ft_detector_period", "0.3",
-           "--mca", "ft_detector_timeout", "6.0",
-           "--mca", "ft_detector_startup_grace", "6.0",
-           sys.executable, str(HOSTCOLL)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+    return launch.tpurun(
+        2, HOSTCOLL, timeout=timeout,
+        extra=("--mca", "otpu_chaos_spec", spec,
+               "--mca", "otpu_chaos_seed", str(seed),
+               # detector on: CTL heartbeat traffic gives the loss faults
+               # something to chew on; generous envelope so injected
+               # delays don't read as deaths
+               "--mca", "ft_detector", "true",
+               "--mca", "ft_detector_period", "0.3",
+               "--mca", "ft_detector_timeout", "6.0",
+               "--mca", "ft_detector_startup_grace", "6.0"),
+        env=dict(HF_SEED=str(seed), HF_ITERS="4"))
 
 
 _MATRIX = ["drop:p=0.05", "delay:ms=2,p=0.2", "dup:p=0.2",
@@ -496,8 +492,7 @@ def test_chaos_soak(seed):
            "--mca", "ft_detector_timeout", "6.0",
            "--mca", "ft_detector_startup_grace", "6.0",
            sys.executable, str(HOSTCOLL)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=240, cwd=REPO, env=env)
+    r = launch.run(cmd, 240, env)
     out = r.stdout + r.stderr
     if r.returncode != 0:
         assert ("corrupted on the wire" in out or "crc32" in out

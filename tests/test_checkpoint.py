@@ -1,11 +1,12 @@
 """Sharded checkpoint/restore (SURVEY §5.4 — the gap the reference leaves)."""
 import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
 import numpy as np
+
+from launch import tpurun as _tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -49,16 +50,6 @@ def test_device_world_save_load_reshard(tmp_path):
     assert isinstance(w2, jax.Array) and w2.sharding == sh24
     assert np.array_equal(np.asarray(w2),
                           np.arange(64, dtype=np.float32).reshape(8, 8))
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_multiprocess_sharded_save(tmp_path):

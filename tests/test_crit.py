@@ -20,7 +20,6 @@ Four layers of coverage:
 """
 import json
 import os
-import subprocess
 import sys
 from pathlib import Path
 
@@ -28,6 +27,8 @@ import pytest
 
 from ompi_tpu.base.var import registry
 from ompi_tpu.runtime import trace
+
+import launch
 
 REPO = Path(__file__).resolve().parent.parent
 WORKER = Path(__file__).resolve().parent / "crit_worker.py"
@@ -455,8 +456,7 @@ def test_critical_path_acceptance_designed_slow_rank(tmp_path):
            # collectives through the pml datapath so sends are spanned
            "--mca", "otpu_coll_sm_coll_priority", "0",
            sys.executable, str(WORKER)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=env)
+    r = launch.run(cmd, 300, env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
     assert out.count("CRIT WORKER DONE") == 3, out

@@ -13,18 +13,15 @@ import subprocess
 import sys
 import textwrap
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun_dw(script, n=2, local=4, timeout=540):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    for k in ("OTPU_RANK", "OTPU_NPROCS", "OTPU_COORD", "XLA_FLAGS"):
-        env.pop(k, None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           "--device-world", "--local-devices", str(local),
-           sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+def _device_world_job(script, n=2, local=4, timeout=540):
+    return tpurun(n, script, timeout=timeout,
+                  extra=("--device-world", "--local-devices", str(local)),
+                  env={"XLA_FLAGS": None})
 
 
 def test_session_device_allreduce_and_train_step_cross_process(tmp_path):
@@ -85,7 +82,7 @@ def test_session_device_allreduce_and_train_step_cross_process(tmp_path):
         comm.free()
         s.finalize()
     """))
-    r = _tpurun_dw(script)
+    r = _device_world_job(script)
     assert r.returncode == 0, r.stdout + r.stderr
     assert r.stdout.count("DWCOLL OK") == 2, r.stdout + r.stderr
     assert r.stdout.count("DWTRAIN OK") == 2, r.stdout + r.stderr
@@ -128,6 +125,6 @@ def test_device_world_reinit_same_process(tmp_path):
         print(f"DWREINIT OK {w.rank}", flush=True)
         ompi_tpu.finalize()
     """))
-    r = _tpurun_dw(script)
+    r = _device_world_job(script)
     assert r.returncode == 0, r.stdout + r.stderr
     assert r.stdout.count("DWREINIT OK") == 2, r.stdout + r.stderr

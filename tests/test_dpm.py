@@ -7,8 +7,6 @@ the payoff VERDICT round 1 asked for — a killed rank is replaced by
 shrink + spawn + merge re-forming a full-size world under
 ``tpurun --enable-recovery``.
 """
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
@@ -18,17 +16,9 @@ import pytest
 import ompi_tpu
 from ompi_tpu.api.errors import ErrorClass, MpiError
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_spawn_parent_child_pingpong(tmp_path):

@@ -13,8 +13,6 @@
 * shrink-only degraded-width continuation (no respawn).
 """
 import json
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
@@ -23,6 +21,8 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import elastic
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -97,17 +97,11 @@ def _run_elastic(tmp_path, n, kill_spec, mode, extra_mca=(), timeout=300):
     script = tmp_path / "job.py"
     script.write_text(_ELASTIC_JOB)
     ckpt = tmp_path / "ckpt"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           "--enable-recovery",
-           "--mca", "otpu_chaos_spec", kill_spec]
+    extra = ["--enable-recovery", "--mca", "otpu_chaos_spec", kill_spec]
     for k, v in extra_mca:
-        cmd += ["--mca", k, v]
-    cmd += [sys.executable, str(script), str(ckpt), mode]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=timeout, cwd=REPO, env=env)
+        extra += ["--mca", k, v]
+    r = tpurun(n, [sys.executable, str(script), str(ckpt), mode],
+               timeout=timeout, extra=extra)
     line = next((ln for ln in r.stdout.splitlines() if "ELASTIC " in ln),
                 None)
     assert line is not None, r.stdout + r.stderr

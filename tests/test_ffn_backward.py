@@ -19,6 +19,7 @@ from test_model_tree import CONFIGS
 from ompi_tpu.parallel import config, layers, train
 from ompi_tpu.runtime import spc
 
+
 BF16 = jnp.bfloat16
 #: two units in bfloat16's last place at a leaf's largest value (8 bits of
 #: significand: a unit is 2^-8 to 2^-7 of the value)
@@ -64,8 +65,8 @@ def both_gradients(name, shape_id, dt):
     """{leaf: (the rule's gradient, autodiff's of the plain lines)}."""
     fn, plain, leaves = FFNS[name]
     h, mats, weight = operands(name, SHAPES[shape_id])
-    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a, dt) * weight),
-                      argnums=tuple(range(1 + len(mats))))(h, *mats)
+    grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a, dt) * weight),
+                      argnums=tuple(range(1 + len(mats)))))(h, *mats)
              for f in (fn, plain)]
     return dict(zip(("h",) + leaves, zip(*grads)))
 
@@ -116,8 +117,8 @@ def stacked_gradients(name):
         out, _ = jax.lax.scan(lambda x, ws: (layer(x, ws), None), h, mats)
         return jnp.sum(out * weight)
 
-    grads = [jax.grad(functools.partial(loss, f),
-                      argnums=tuple(range(1 + len(mats))))(h, *mats)
+    grads = [jax.jit(jax.grad(functools.partial(loss, f),
+                      argnums=tuple(range(1 + len(mats)))))(h, *mats)
              for f in (fn, plain)]
     return dict(zip(("h",) + leaves, zip(*grads)))
 

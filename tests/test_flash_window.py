@@ -18,6 +18,7 @@ from ompi_tpu.ops import flash_attention as fa
 from ompi_tpu.parallel import causal
 from ompi_tpu.runtime import spc
 
+
 BLOCK = 128
 
 
@@ -89,13 +90,13 @@ def test_the_backward_kernel_under_a_window_is_autodiff(h, n_kv, d, w):
     window = w * BLOCK
     do = jnp.asarray(np.random.default_rng(2).normal(0, 1, q.shape),
                      jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, window)[0] * do),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, window)[0] * do),
+                    (0, 1, 2)))(q, k, v)
     o, lse = fa.flash_causal_forward(q, k, v, block=BLOCK, interpret=True,
                                      window=window)
     got = walk_backward(q, k, v, do, o, lse, window)
-    twin = jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
-        *a, BLOCK, True, window) * do), (0, 1, 2))(q, k, v)
+    twin = jax.jit(jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
+        *a, BLOCK, True, window) * do), (0, 1, 2)))(q, k, v)
     for name, g, t, x in zip("qkv", got, twin, want):
         scale = float(jnp.abs(x).max())
         np.testing.assert_allclose(g, x, rtol=1e-4, atol=2e-5 * scale,
@@ -113,10 +114,10 @@ def test_the_twins_backward_walks_agree_beyond_the_unrolled_blocks(blocks):
     q, k, v = _qkv(32, blocks * block, 4, 2, seed=3)
     do = jnp.asarray(np.random.default_rng(4).normal(0, 1, q.shape),
                      jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, window)[0] * do),
-                    (0, 1, 2))(q, k, v)
-    got = jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
-        *a, block, True, window) * do), (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, window)[0] * do),
+                    (0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(causal.causal_flash_attention(
+        *a, block, True, window) * do), (0, 1, 2)))(q, k, v)
     for g, x in zip(got, want):
         np.testing.assert_allclose(g, x, rtol=1e-4,
                                    atol=2e-5 * float(jnp.abs(x).max()))
@@ -126,9 +127,9 @@ def test_a_window_of_all_blocks_is_full_attention_bit_for_bit():
     """A window that covers the sequence is no window: the same branches,
     so the same bits, forward and backward, and nothing counted as one."""
     q, k, v = _qkv(64, 4 * BLOCK, 4, 2, seed=5)
-    run = lambda *window: jax.value_and_grad(
+    run = lambda *window: jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(causal.causal_flash_attention(
-            *a, BLOCK, True, *window) ** 2), (0, 1, 2))(q, k, v)
+            *a, BLOCK, True, *window) ** 2), (0, 1, 2)))(q, k, v)
     plain, covered, longer = run(), run(4 * BLOCK), run(4096)
     for other in (covered, longer):
         for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(other)):

@@ -23,6 +23,7 @@ Coverage layers:
   pool pset (bounded tier-1 run; the full-length version rides the
   ``slow`` lane).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -36,17 +37,12 @@ from ompi_tpu.api.errors import MpiError
 from ompi_tpu.serving.scheduler import (ContinuousBatchScheduler,
                                         ServeRequest)
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), script_args=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script), *script_args]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 # ----------------------------------------------------- fair-share admission
@@ -544,11 +540,10 @@ def _soak(tmp_path, n_a, n_b, timeout):
     script = tmp_path / "fleet_soak.py"
     script.write_text(_SOAK)
     return _tpurun(
-        5, script,
+        5, [sys.executable, str(script), str(n_a), str(n_b)],
         extra=("--enable-recovery", "--pool", "m_a:1,2",
                "--pool", "m_b:3,4",
                "--mca", "otpu_telemetry_interval_ms", "50"),
-        script_args=(str(n_a), str(n_b)),
         timeout=timeout)
 
 

@@ -23,9 +23,8 @@ Coverage layers:
   degrading predictably, every shed counted with its retry-after
   honored by the driver, zero crashes, zero dropped requests.
 """
+import functools
 import os
-import subprocess
-import sys
 import threading
 import weakref
 
@@ -39,17 +38,12 @@ from ompi_tpu.serving.frontdoor import (SLO_BATCH, SLO_INTERACTIVE,
                                         FrontDoor, TokenBucket)
 from ompi_tpu.serving.scheduler import ContinuousBatchScheduler
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), script_args=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script), *script_args]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 # ------------------------------------------------------- token bucket units

@@ -1,8 +1,5 @@
 """ULFM fault tolerance: failure state, revoke/shrink/agree, detector,
 recovery-mode launcher (SURVEY.md §3.5/§5.3)."""
-import os
-import subprocess
-import sys
 import textwrap
 from pathlib import Path
 
@@ -12,6 +9,8 @@ import pytest
 import ompi_tpu
 from ompi_tpu.ft import state as ft_state
 from ompi_tpu.runtime import init as rt
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -92,18 +91,11 @@ class TestDeviceWorldFt:
         assert world.ack_failed() == 1
 
 
-def _tpurun(n, script, timeout=180, recovery=False, mca=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n)]
-    if recovery:
-        cmd.append("--enable-recovery")
+def _job(n, script, timeout=180, recovery=False, mca=()):
+    extra = ["--enable-recovery"] if recovery else []
     for k, v in mca:
-        cmd += ["--mca", k, v]
-    cmd += [sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+        extra += ["--mca", k, v]
+    return tpurun(n, script, timeout=timeout, extra=extra)
 
 
 class TestMultiprocessFt:
@@ -134,7 +126,7 @@ class TestMultiprocessFt:
                 print("FT SHRINK OK")
             ompi_tpu.finalize()
         """))
-        r = _tpurun(4, script, recovery=True)
+        r = _job(4, script, recovery=True)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "FT SHRINK OK" in r.stdout
 
@@ -172,7 +164,7 @@ class TestMultiprocessFt:
                 print("FT AGREE OK")
             ompi_tpu.finalize()
         """))
-        r = _tpurun(3, script, recovery=True)
+        r = _job(3, script, recovery=True)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "FT AGREE OK" in r.stdout
 
@@ -204,7 +196,7 @@ class TestMultiprocessFt:
                 print("FT REVOKE OK")
             ompi_tpu.finalize()
         """))
-        r = _tpurun(3, script)
+        r = _job(3, script)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "FT REVOKE OK" in r.stdout
 
@@ -236,7 +228,7 @@ class TestMultiprocessFt:
                 print("FT DETECTOR OK")
             ompi_tpu.finalize()
         """))
-        r = _tpurun(3, script, recovery=True, timeout=120,
+        r = _job(3, script, recovery=True, timeout=120,
                     mca=[("ft_detector", "true"),
                          ("ft_detector_period", "0.2"),
                          ("ft_detector_timeout", "1.5")])
@@ -285,7 +277,7 @@ class TestCoordFreeAgreement:
             print(f"ROOTDEATH OK {w.rank}", flush=True)
             ompi_tpu.finalize()
         """))
-        r = _tpurun(4, script, recovery=True, timeout=150,
+        r = _job(4, script, recovery=True, timeout=150,
                     mca=[("ft_detector", "true"),
                          ("ft_detector_period", "0.2"),
                          ("ft_detector_timeout", "1.5"),
@@ -330,7 +322,7 @@ class TestCoordFreeAgreement:
             print(f"REVFLOOD OK {w.rank}", flush=True)
             ompi_tpu.finalize()
         """))
-        r = _tpurun(3, script)
+        r = _job(3, script)
         assert r.stdout.count("REVFLOOD OK") == 3, r.stdout + r.stderr
         assert r.returncode == 0, r.stdout + r.stderr
 
@@ -359,7 +351,7 @@ class TestMultiFailure:
             print(f"DOUBLE OK {w.rank}", flush=True)
             ompi_tpu.finalize()
         """))
-        r = _tpurun(4, script, recovery=True, timeout=150,
+        r = _job(4, script, recovery=True, timeout=150,
                     mca=[("ft_detector", "true"),
                          ("ft_detector_period", "0.2"),
                          ("ft_detector_timeout", "1.5"),
@@ -382,7 +374,7 @@ class TestAgreementAlgorithms:
             ompi_tpu.finalize()
         """))
         for alg in ("tree", "kv"):
-            r = _tpurun(3, script,
+            r = _job(3, script,
                         mca=[("coll_ftagree_algorithm", alg)])
             assert r.stdout.count("ALG OK") == 3, (alg, r.stdout + r.stderr)
             assert r.returncode == 0, (alg, r.stdout + r.stderr)

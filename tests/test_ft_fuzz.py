@@ -20,11 +20,12 @@ scenarios.
 """
 import os
 import re
-import subprocess
 import sys
 from pathlib import Path
 
 import pytest
+
+import launch
 
 REPO = Path(__file__).resolve().parent.parent
 WORKER = Path(__file__).resolve().parent / "fuzz_agree_worker.py"
@@ -68,8 +69,7 @@ def test_fuzz_agreement_uniformity(seed):
            "--mca", "ft_detector_timeout", "3.0",
            "--mca", "ft_detector_startup_grace", "4.0",
            sys.executable, str(WORKER)]
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=300,
-                       cwd=REPO, env=env)
+    r = launch.run(cmd, 300, env)
     out = r.stdout
     assert r.returncode == 0, out + r.stderr
 

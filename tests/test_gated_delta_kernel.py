@@ -88,8 +88,8 @@ def test_the_backward_kernel_is_autodiff_of_the_xla_form(length, r):
     """dq, dk, dv, dg and dbeta from what the forward kernel kept."""
     args = rule_inputs(3 * length + r, length, r, alike=1.0)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    want = jax.grad(lambda *a: jnp.sum(
-        gdn.gated_delta_chunked(*a, CHUNK) * weight), range(5))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(
+        gdn.gated_delta_chunked(*a, CHUNK) * weight), range(5)))(*args)
     o, kept = forward(*args, states=True)
     near(o, forward(*args), 0, "the output with and without what is kept")
     got = backward(*args, kept, weight)

@@ -11,10 +11,10 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 
 from test_granite_train import (BENCH, CONFIG, EOS, F32, SMALL, close, near,
                                 packed, ref_grads, spread)
+import built
 
 NAMES = train.leaf_names(F32)
 
@@ -45,7 +45,7 @@ def test_the_kit_names_the_programs_leaves(kit, kit_cfg):
     shapes = train.model_param_shapes(F32)
     assert kit.leaf_sizes(kit_cfg) == {
         n: int(np.prod(train._leaf(shapes, p))) for n, p in NAMES}
-    params = train.init_model_params(F32, 0)
+    params = built.params(F32, 0)
     tree = kit.tree_of({n: kit.leaf_of(params, n)
                         for n in kit.leaves(kit_cfg)})
     assert tree.pop("head").shape == (64, 64)       # the tied matrix's twin
@@ -129,8 +129,7 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(
     of the tolerance of the reference's; every control lies outside it."""
     tokens, labels = packed(4, (21, 11, 30, 2), rows=2)
     params = spread(F32, 11)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state, aux = step(state, t, l)
     aux = jax.device_get(aux)

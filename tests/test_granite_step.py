@@ -9,13 +9,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ompi_tpu.parallel import granite_reference as ref
+from ompi_tpu.parallel import granite_reference
 from ompi_tpu.parallel import train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
 
 from test_granite_train import (F32, close, loss_of, near, packed, ref_grads,
                                 spread)
+import built
+
+ref = built.programs(granite_reference)
 
 
 def batches(n, cfg=F32):
@@ -23,10 +25,9 @@ def batches(n, cfg=F32):
             for i in range(n)]
 
 
-def run_steps(cfg, params, dp=1, n=3):
+def run_steps(cfg, params, dp=1, n=3, fresh=False):
     params = jax.tree.map(jnp.array, params)    # the step donates its state
-    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
+    step, place = (built.fresh_step if fresh else built.step)(cfg, dp)
     state, losses = None, []
     for tokens, labels in batches(n, cfg):
         if state is None:
@@ -59,8 +60,8 @@ def test_three_steps_are_the_references_and_repeat_and_two_ranks_are_one():
     for name, path in train.leaf_names(F32):
         near(train._leaf(got, path), train._leaf(want, path), rel=1e-4,
              err_msg=name)
-    # from one seed the losses repeat bit for bit
-    _, again, _ = run_steps(F32, params)
+    # from one seed the losses repeat bit for bit, through a second build
+    _, again, _ = run_steps(F32, params, fresh=True)
     np.testing.assert_array_equal(np.stack(losses), np.stack(again))
     # two data-parallel ranks, a row each, are one model
     two, two_losses, aux2 = run_steps(F32, params, dp=2)
@@ -91,7 +92,7 @@ def test_three_steps_are_the_references_and_repeat_and_two_ranks_are_one():
 
 
 def test_bfloat16_compute_stays_near_float32():
-    params = train.init_model_params(F32, 2)
+    params = built.params(F32, 2)
     tokens, labels = packed(6, (21, 11, 32), rows=2)
     low = dataclasses.replace(F32, compute_dtype="bfloat16")
     (want, _), g_want = jax.jit(jax.value_and_grad(

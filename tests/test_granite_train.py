@@ -20,10 +20,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import granite_reference as ref
+from ompi_tpu.parallel import granite_reference
 from ompi_tpu.parallel import (attention, causal, config, experts, mamba,
                                model, objective, train)
 from ompi_tpu.runtime import spc, trace
+
+import built
+
+ref = built.programs(granite_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -125,7 +129,7 @@ def test_the_cells_file_loads_at_its_published_widths_to_the_parameter():
     assert train.is_decayed("l0.mamba_dense.conv_w") \
         and train.is_decayed("embed")
     # A_log starts at the held heads' numbers, in every layer
-    a_log = train.init_model_params(F32, 0)["layers"]["l0"]["mamba_dense"][
+    a_log = built.params(F32, 0)["layers"]["l0"]["mamba_dense"][
         "A_log"]
     close(a_log, np.log([[1.0, 2.0]]))
 
@@ -221,8 +225,8 @@ def _scan_both(x, dt, a, b, c, doc):
     weigh = jax.random.normal(jax.random.PRNGKey(9), x.shape)
     with jax.default_matmul_precision("highest"):
         return (run(x, dt, a, b, c), want(x, dt, a, b, c)) + tuple(
-            jax.grad(lambda *args, f=f: jnp.sum(f(*args) * weigh),
-                     argnums=range(5))(x, dt, a, b, c) for f in (run, want))
+            jax.jit(jax.grad(lambda *args, f=f: jnp.sum(f(*args) * weigh),
+                     argnums=range(5)))(x, dt, a, b, c) for f in (run, want))
 
 
 def test_without_documents_the_scan_and_the_mixer_hold_no_mask():
@@ -282,7 +286,8 @@ def test_a_packed_rows_scan_convolution_and_attention_are_its_documents():
         total = lambda *t: sum(jnp.sum(o * w) for o, w in zip(
             ops(*t, doc), ws))
         with jax.default_matmul_precision("highest"):
-            return ops(*args, doc), jax.grad(total, argnums=range(8))(*args)
+            return ops(*args, doc), jax.jit(jax.grad(
+                total, argnums=range(8)))(*args)
 
     outs, grads = jax.jit(lambda *t: both(doc, weigh, *t))(*args)
     for lo, hi in zip(cuts[:-1], cuts[1:]):
@@ -511,7 +516,7 @@ def test_the_scopes_and_counters_are_named():
     before = spc.read("doc_built")
     tokens, labels = packed(0, (21, 11, 32), rows=2)
     text = jax.jit(loss_of(F32, tokens, labels)).lower(
-        train.init_model_params(F32, 0)).as_text(debug_info=True)
+        built.params(F32, 0)).as_text(debug_info=True)
     for scope in ("otpu_ssm_scan", "otpu_ssm_conv", "otpu_attention",
                   "otpu_embed", "otpu_dense_mlp"):
         assert scope in text and scope in trace.STEP_SCOPES, scope
@@ -540,7 +545,7 @@ def test_the_feed_forwards_backward_rule_stays_under_its_scopes(dtype,
     tokens, labels = packed(0, (21, 11, 32), rows=2)
     text = jax.jit(jax.grad(
         lambda ps: loss_of(cfg, tokens, labels)(ps)[0])).lower(
-        train.init_model_params(cfg, 0)).as_text(debug_info=True)
+        built.params(cfg, 0)).as_text(debug_info=True)
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
     found = {}
     for line in text.splitlines():

@@ -13,6 +13,7 @@ from ompi_tpu.ops import row_scatter
 from ompi_tpu.parallel import experts
 from ompi_tpu.runtime import spc
 
+
 BF16, F32 = jnp.bfloat16, jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -211,8 +212,8 @@ def test_gradients_through_the_experts_ffn(ffn, interpreted):
         return jnp.sum(jnp.where(live, y, 0) ** 2)
 
     args = tuple(range(1 + len(mats)))
-    got = jax.value_and_grad(functools.partial(loss, False), args)(xs, *mats)
-    want = jax.value_and_grad(functools.partial(loss, True), args)(xs, *mats)
+    got, want = (jax.jit(jax.value_and_grad(functools.partial(
+        loss, interpret), args))(xs, *mats) for interpret in (False, True))
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for g, w in zip(got[1], want[1]):
         # ragged_dot's transposes round their results to bfloat16
@@ -259,9 +260,9 @@ def test_local_expert_ffn_whole_on_the_kernel(held, form, interpreted):
             h, order, weights, sizes, mats, cfg, ffn, interpret) ** 2)
 
     args = tuple(range(2 + n_mats))
-    got = jax.value_and_grad(functools.partial(loss, False), args)(
+    got = jax.jit(jax.value_and_grad(functools.partial(loss, False), args))(
         h, weights, *mats)
-    want = jax.value_and_grad(functools.partial(loss, True), args)(
+    want = jax.jit(jax.value_and_grad(functools.partial(loss, True), args))(
         h, weights, *mats)
     assert np.isfinite(got[0])
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
@@ -355,8 +356,8 @@ def test_the_loop_is_the_dense_masked_sum(held, form):
         return jnp.sum(jnp.sin(_dense(form, h, weights, chosen, *mats)))
 
     args = tuple(range(2 + n_mats))
-    got = jax.value_and_grad(loop, args)(h, weights, *mats)
-    want = jax.value_and_grad(dense, args)(h, weights, *mats)
+    got = jax.jit(jax.value_and_grad(loop, args))(h, weights, *mats)
+    want = jax.jit(jax.value_and_grad(dense, args))(h, weights, *mats)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
     for g, w in zip(got[1], want[1]):
         assert g.dtype == w.dtype and g.shape == w.shape

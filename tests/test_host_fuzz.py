@@ -13,25 +13,18 @@ rank and check against replicated numpy models:
   checks its exposure epoch (mapped-window puts may land early — MPI
   makes epoch separation the program's job).
 """
-import os
-import subprocess
-import sys
 from pathlib import Path
 
 import pytest
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
 
 def _run(worker, n, env_extra, timeout=420):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         sys.executable, str(REPO / "tests" / worker)],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO,
-        env=env)
+    return tpurun(n, REPO / "tests" / worker, timeout=timeout,
+                  env=env_extra)
 
 
 @pytest.mark.parametrize("seed", [11, 47])

@@ -7,6 +7,8 @@ from pathlib import Path
 
 import numpy as np
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -48,16 +50,6 @@ def test_info_parsable():
     r = _run_info("--all", "--parsable")
     assert r.returncode == 0
     assert any(line.startswith("mca coll:") for line in r.stdout.splitlines())
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_monitoring_p2p_matrix_and_coll_counters(tmp_path):

@@ -6,7 +6,6 @@ two-phase fcoll path, ending in the SURVEY Phase-6 payoff — a sharded-array
 checkpoint written and restored through subarray file views across 4 ranks.
 """
 import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
@@ -14,17 +13,9 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 # -- single-process: views + fbtl ---------------------------------------

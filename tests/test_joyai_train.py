@@ -19,12 +19,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import joyai_reference as ref
-from ompi_tpu.parallel import (attention, causal, config, experts, layers,
-                               objective, train)
+from ompi_tpu.parallel import (attention, causal, config, experts,
+                               joyai_reference, layers, objective, train)
 from ompi_tpu.parallel.flagship import _full_attention
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
+
+import built
+
+ref = built.programs(joyai_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -49,9 +52,7 @@ CLOSE = dict(rtol=1e-5, atol=1e-6)
 def batch_of(seed, vocab=64):
     """(inputs (2, 32), labels (2, 33): the next token and the one after)
     from 34 ids a sequence."""
-    ids = np.random.default_rng(seed).integers(0, vocab, (2, 34)).astype(
-        np.int32)
-    return jnp.asarray(ids[:, :-2]), jnp.asarray(ids[:, 1:])
+    return built.batch(F32, seed, vocab)
 
 
 def some_bias(cfg=F32, scale=0.01):
@@ -65,7 +66,7 @@ def some_bias(cfg=F32, scale=0.01):
 
 @pytest.fixture(scope="module")
 def params():
-    return train.init_model_params(F32, seed=3)
+    return built.params(F32, 3)
 
 
 @pytest.fixture(scope="module")
@@ -84,17 +85,18 @@ def system_loss(params, cfg, batch, bias):
 
 @pytest.fixture(scope="module")
 def system(params):
-    (total, aux), grads = jax.value_and_grad(
+    (total, aux), grads = jax.jit(jax.value_and_grad(
         lambda p: system_loss(p, F32, batch_of(0), some_bias()),
-        has_aux=True)(params)
+        has_aux=True))(params)
     return dict(total=total, aux=aux, grads=grads)
 
 
-def run_steps(cfg, params, seeds, dp=1):
+def run_steps(cfg, params, seeds, dp=1, fresh=False):
     """The state and each step's ``aux`` after one optimiser step a
-    seed's batch, through ``build_train_step`` on ``dp`` CPU devices."""
-    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
+    seed's batch, through ``build_train_step`` on ``dp`` CPU devices:
+    the process's one step of ``cfg``, or (``fresh``) one built now, which
+    is traced under what the caller has patched."""
+    step, place = (built.fresh_step if fresh else built.step)(cfg, dp)
     state, out = None, []
     for seed in seeds:
         tokens, labels = batch_of(seed)
@@ -175,7 +177,8 @@ def test_what_the_checkpoint_keeps_changes_no_number(params, monkeypatch):
     which XLA's CPU backend fuses a recomputed o into ``delta``'s sum and
     adds that loop up in another order than over a stored o, so the bare
     checkpoint's dq and dk move in their last bit.  The step jitted whole
-    (``run_steps``) is compared as compiled."""
+    (``run_steps``) is compared as compiled: two steps of the test's own,
+    each traced under the policy of its moment."""
     def grads():
         with jax.disable_jit():
             return jax.value_and_grad(
@@ -183,11 +186,11 @@ def test_what_the_checkpoint_keeps_changes_no_number(params, monkeypatch):
                 has_aux=True)(params)
 
     (total, kept_aux), kept = grads()
-    _, (stepped,) = run_steps(F32, params, (0,))
+    _, (stepped,) = run_steps(F32, params, (0,), fresh=True)
     monkeypatch.setattr(objective, "layer_checkpoint_policy",
                         lambda: jax.checkpoint_policies.nothing_saveable)
     (bare_total, aux), bare = grads()
-    _, (bare_stepped,) = run_steps(F32, params, (0,))
+    _, (bare_stepped,) = run_steps(F32, params, (0,), fresh=True)
     assert total == bare_total
     for name, path in train.leaf_names(F32):
         np.testing.assert_array_equal(train._leaf(kept, path),
@@ -370,8 +373,9 @@ def test_a_sliced_vocabulary_is_a_smaller_vocabulary(params):
     assert params["head"].shape == (64, 64)
     small = dataclasses.replace(F32, vocab_size=64, vocab_here=0)
     assert train.model_param_shapes(small) == train.model_param_shapes(F32)
-    got = system_loss(params, small, batch_of(0), some_bias())[0]
-    want = system_loss(params, F32, batch_of(0), some_bias())[0]
+    loss = built.program(system_loss)
+    got = loss(params, small, batch_of(0), some_bias())[0]
+    want = loss(params, F32, batch_of(0), some_bias())[0]
     assert float(got) == float(want)
 
 
@@ -386,15 +390,15 @@ def test_the_attention_backward_by_scan_is_the_unrolled_one():
     w = jnp.asarray(rng.normal(0, 1, (2, 4, 32, 16)), jnp.float32)
 
     def grads(block):
-        return jax.grad(lambda q, k, v: jnp.sum(
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
             causal.causal_flash_attention(q, k, v, block, True) * w),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
 
     assert 32 // 4 > causal.UNROLLED_BLOCKS >= 32 // 16
     for got, want in zip(grads(4), grads(16)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    full = jax.grad(lambda q, k, v: jnp.sum(
-        _full_attention(q, k, v, True) * w), argnums=(0, 1, 2))(
+    full = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        _full_attention(q, k, v, True) * w), argnums=(0, 1, 2)))(
         q, k, v)
     for got, want in zip(grads(4), full):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
@@ -477,7 +481,7 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
         terms = lambda t: jnp.abs(t) + jnp.abs(jnp.pad(
             _swapped(t, first), ((0, 0),) * (t.ndim - 1) + ((first, 0),)))
         assert _ulps(new(x), old(x), terms(x)) <= 1
-        dnew, dold = (jax.grad(lambda x: jnp.sum(f(x) * g))(x)
+        dnew, dold = (jax.jit(jax.grad(lambda x: jnp.sum(f(x) * g)))(x)
                       for f in (new, old))
         assert _ulps(dnew, dold, terms(g)) <= 1
         return
@@ -493,9 +497,9 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
         # the same dot products of the same inputs (on the CPU a product
         # of another width may sum them in another order)
         assert _ulps(new(a, w), old(a, w), jnp.max(jnp.abs(old(a, w)))) <= 4
-        for got, want in zip(*(jax.grad(lambda a, w: jnp.sum(f(a, w) * g),
-                                        argnums=(0, 1))(a, w)
-                               for f in (new, old))):
+        for got, want in zip(*(jax.jit(jax.grad(
+                lambda a, w: jnp.sum(f(a, w) * g), argnums=(0, 1)))(a, w)
+                for f in (new, old))):
             assert got.dtype == want.dtype == jnp.float32
             loose = 16 if arg == "float32" else 2 ** 17  # bfloat16 operands
             assert _ulps(got, want, jnp.max(jnp.abs(want))) <= loose
@@ -509,8 +513,8 @@ def test_rope_without_the_rolled_copies_is_rope_interleaved(
     old = lambda p, x: _mla_attention_rolled(p, x, cfg)
     tol = CLOSE if arg == "float32" else dict(rtol=2e-2, atol=2e-3)
     np.testing.assert_allclose(new(leaves, x), old(leaves, x), **tol)
-    (pn, xn), (po, xo) = (jax.grad(lambda p, x: jnp.sum(f(p, x) * g),
-                                   argnums=(0, 1))(leaves, x)
+    (pn, xn), (po, xo) = (jax.jit(jax.grad(lambda p, x: jnp.sum(f(p, x) * g),
+                                   argnums=(0, 1)))(leaves, x)
                           for f in (new, old))
     np.testing.assert_allclose(xn, xo, **tol)
     for k in leaves:

@@ -10,11 +10,11 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 
 from test_keye_train import (BENCH, CONFIG, F32, NAMES, SHARE, TRAIN,
                              batch_of, close, near, ref_grads,
                              spread_params)
+import built
 
 
 @pytest.fixture(scope="module")
@@ -87,8 +87,7 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(kit):
     selection; every control lies outside it."""
     tokens, labels = batch_of(4)
     params = spread_params(F32, 11)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state, aux = step(state, t, l)
     aux = jax.device_get(aux)

@@ -18,9 +18,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import keye_reference as ref
+from ompi_tpu.parallel import keye_reference
 from ompi_tpu.parallel import (attention, config, dsa, model, objective,
                                train)
+
+import built
+
+ref = built.programs(keye_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -134,8 +138,10 @@ def test_a_wrong_selection_or_indexer_differs(control):
     if control == "no_relu":
         import unittest.mock
 
+        # op by op: a program traced under the patch would stay the
+        # process's ``ref.attention`` at these shapes
         with unittest.mock.patch.object(jax.nn, "relu", lambda a: a):
-            wrong = ref.attention(p, x, F32)[0]
+            wrong = ref.plain.attention(p, x, F32)[0]
     else:
         topk = {"every_key": 64, "top_half": 12}[control]
         wrong = ref.attention(p, x, dataclasses.replace(
@@ -198,7 +204,7 @@ def test_the_layers_are_walked_as_one_run_of_sparse_attention():
         and shapes["index_ww"] == (4, 64, 4) \
         and shapes["gate"] == (4, 4, 64, 24)
     assert set(train.pattern_layer_shapes(F32)) == {"dsa_dense", "dsa_moe"}
-    params = train.init_model_params(F32, 0)["layers"]["l0"]["dsa_moe"]
+    params = built.params(F32, 0)["layers"]["l0"]["dsa_moe"]
     assert float(params["index_k_norm"].min()) == 1.0 \
         and not np.any(np.asarray(params["index_k_bias"]))
     assert not train.is_decayed("l0.dsa_moe.index_k_bias") \
@@ -247,7 +253,7 @@ def test_the_embeddings_rows_alone_are_drawn_at_their_own_width():
     cfg = train.load_model_config(CONFIG)
     assert (cfg.init_std, cfg.embed_init_std) == (0.02, 2.0)
     assert F32.embed_init_std is None
-    plain = train.init_model_params(F32, 3)
+    plain = built.params(F32, 3)
     wide = train.init_model_params(
         dataclasses.replace(F32, embed_init_std=2.0), 3)
     for name, path in NAMES:

@@ -20,10 +20,13 @@ import pytest
 
 from ompi_tpu.parallel import (attention, causal, config, experts, layers,
                                objective, short_conv, train)
-from ompi_tpu.parallel import lfm2_reference as ref
+from ompi_tpu.parallel import lfm2_reference
 from ompi_tpu.parallel import nemotron_reference
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
+
+import built
+
+ref = built.programs(lfm2_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -122,17 +125,17 @@ def test_the_short_convolution_is_the_loop_over_positions(length):
     p = layer_of(F32, "conv_dense")
     x = jax.random.normal(jax.random.PRNGKey(length), (2, length, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
-    got, got_g = jax.value_and_grad(
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda p, x: jnp.sum(short_conv.short_conv(p, x, F32)[0] * probe),
-        argnums=(0, 1))(p, x)
+        argnums=(0, 1)))(p, x)
     close(short_conv.short_conv(p, x, F32)[0], conv_by_positions(p, x, F32),
           rtol=1e-4, atol=1e-5)
     with jax.default_matmul_precision("highest"):
         close(ref.short_conv(p, x, F32), conv_by_positions(p, x, F32),
               rtol=1e-4, atol=1e-5)
-        want, want_g = jax.value_and_grad(
+        want, want_g = jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(ref.short_conv(p, x, F32) * probe),
-            argnums=(0, 1))(p, x)
+            argnums=(0, 1)))(p, x)
     close(got, want, rtol=1e-4)
     for k in ("ln1", "in_proj", "conv_w", "out_proj"):
         near(got_g[0][k], want_g[0][k], err_msg=k)
@@ -166,12 +169,12 @@ def test_attention_with_qk_norm_and_rope_is_the_references(heads, kv):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
+        want, want_g = jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(ref.attention(p, x, cfg) * probe),
-            argnums=(0, 1))(p, x)
-    got, got_g = jax.value_and_grad(
+            argnums=(0, 1)))(p, x)
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda p, x: jnp.sum(attention.FULL.run(
-            p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1))(p, x)
+            p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1)))(p, x)
     close(got, want, rtol=1e-4)
     for k in ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm"):
         near(got_g[0][k], want_g[0][k], err_msg=k)
@@ -223,14 +226,14 @@ def test_the_expert_block_without_a_shared_expert_under_uneven_routing():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     with jax.default_matmul_precision("highest"):
-        (want, load), want_g = jax.value_and_grad(
+        (want, load), want_g = jax.jit(jax.value_and_grad(
             lambda p, x: (lambda y, load: (jnp.sum(y * probe), load))(
                 *ref.experts(p, x, bias, F32)),
-            argnums=(0, 1), has_aux=True)(p, x)
-    (got, stats), got_g = jax.value_and_grad(
+            argnums=(0, 1), has_aux=True))(p, x)
+    (got, stats), got_g = jax.jit(jax.value_and_grad(
         lambda p, x: (lambda y, st, _: (jnp.sum(y * probe), st))(
             *experts.moe_shared_local_block(p, x, F32, bias)),
-        argnums=(0, 1), has_aux=True)(p, x)
+        argnums=(0, 1), has_aux=True))(p, x)
     assert load[2] == 64 and load[3] == 0
     # the hot expert's group alone is more than one of the loop's chunks
     assert 64 > experts.chunk_rows(
@@ -286,9 +289,8 @@ def test_the_layers_are_walked_by_their_types():
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    params = train.init_model_params(F32, 3)
+    step, place = built.step(F32)
+    params = built.params(F32, 3)
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     auxes = []
@@ -361,11 +363,11 @@ def test_the_step_holds_no_k_or_v_a_query_head(traced_step, on_tpu):
 
 def test_every_leafs_gradient_is_the_references():
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
-    (_, aux), got = jax.value_and_grad(
+    params, bias = built.params(F32, 11), some_bias()
+    (_, aux), got = jax.jit(jax.value_and_grad(
         lambda ps: objective.model_loss(ps, tokens, labels, F32, interpret=True,
                                     n_global=64, bias=bias),
-        has_aux=True)(params)
+        has_aux=True))(params)
     (_, loads), want = ref.grads(params, tokens, labels, F32, bias)
     close(aux["loads"], loads)
     for name, path in NAMES:
@@ -379,16 +381,16 @@ def test_the_tied_matrixs_gradient_is_the_sum_of_both_uses():
     second, independently drawn matrix has another loss and another
     gradient."""
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
-    got = jax.grad(lambda ps: objective.model_loss(
+    params, bias = built.params(F32, 11), some_bias()
+    got = jax.jit(jax.grad(lambda ps: objective.model_loss(
         ps, tokens, labels, F32, interpret=True, n_global=64,
-        bias=bias)[0])(params)["embed"]
+        bias=bias)[0]))(params)["embed"]
     embed = params["embed"]
     with jax.default_matmul_precision("highest"):
-        gather = jax.grad(lambda ps: ref.loss_parts(
-            ps, tokens, labels, F32, bias, head=embed.T)[0])(params)["embed"]
-        head = jax.grad(lambda h: ref.loss_parts(
-            params, tokens, labels, F32, bias, head=h)[0])(embed.T)
+        gather = jax.jit(jax.grad(lambda ps: ref.loss_parts(
+            ps, tokens, labels, F32, bias, head=embed.T)[0]))(params)["embed"]
+        head = jax.jit(jax.grad(lambda h: ref.loss_parts(
+            params, tokens, labels, F32, bias, head=h)[0]))(embed.T)
         other = 0.02 * jax.random.normal(jax.random.PRNGKey(99),
                                          embed.T.shape)
         (untied, _), untied_g = ref.grads(params, tokens, labels, F32, bias,
@@ -408,13 +410,13 @@ def test_the_tied_matrixs_gradient_is_the_sum_of_both_uses():
 
 def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
+    params, bias = built.params(F32, 11), some_bias()
 
     def grads():
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda ps: objective.model_loss(ps, tokens, labels, F32,
                                         interpret=True, n_global=64,
-                                        bias=bias), has_aux=True)(params)
+                                        bias=bias), has_aux=True))(params)
 
     (loss, aux), got = grads()
     monkeypatch.setattr(objective, "layer_checkpoint_policy",
@@ -432,15 +434,16 @@ def test_the_taps_are_decayed_and_no_gain_is():
     undecayed = {n for n, _ in NAMES if not train.is_decayed(n)}
     assert undecayed == {n for n, _ in NAMES if n.rsplit(".", 1)[-1] in (
         "ln1", "ln2", "q_norm", "k_norm", "final_norm")}
-    params = train.init_model_params(F32, 3)["layers"]["l2"]["conv_moe"]
+    params = built.params(F32, 3)["layers"]["l2"]["conv_moe"]
     assert params["conv_w"].shape == (3, 3, 64)
     assert np.abs(np.asarray(params["conv_w"])).max() <= 3 ** -0.5
     assert np.all(np.asarray(params["ln1"]) == 1.0)
 
 
 def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(train.init_model_params(F32, 3),
                         *stepped["batches"][0])
     for (tokens, labels), first in zip(stepped["batches"],
@@ -452,9 +455,8 @@ def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
 
 def test_bfloat16_compute_stays_near_float32(stepped):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
-    state, tokens, labels = place(train.init_model_params(cfg, 3),
+    step, place = built.step(cfg)
+    state, tokens, labels = place(built.params(cfg, 3),
                                   *stepped["batches"][0])
     _, aux = step(state, tokens, labels)
     close(aux["losses"][0], stepped["auxes"][0]["losses"][0], rtol=3e-3)
@@ -463,9 +465,8 @@ def test_bfloat16_compute_stays_near_float32(stepped):
 def test_two_data_parallel_ranks_are_one_model(stepped):
     if len(jax.devices()) < 2:
         pytest.skip("one device")
-    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    state, tokens, labels = place(train.init_model_params(F32, 3),
+    step, place = built.step(F32, 2)
+    state, tokens, labels = place(built.params(F32, 3),
                                   *stepped["batches"][0])
     state, aux = step(state, tokens, labels)
     first = stepped["auxes"][0]
@@ -573,7 +574,7 @@ def test_a_tied_head_is_any_models(tmp_path):
         compute_dtype="float32")
     assert "head" not in train.model_param_shapes(cfg)
     tokens, labels = batch_of(1)
-    params = train.init_model_params(cfg, 0)
+    params = built.params(cfg, 0)
     loss, aux = objective.model_loss(params, tokens, labels[:, :32], cfg,
                                  interpret=True, n_global=64)
     assert np.isfinite(float(loss)) and aux["rows"].shape == (64, 2)
@@ -625,7 +626,7 @@ def test_the_kit_names_the_programs_leaves(kit):
 
 def test_the_kits_reference_is_the_repositorys(kit):
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
+    params, bias = built.params(F32, 11), some_bias()
     (loss, loads), want = ref.grads(params, tokens, labels, F32, bias)
     wrt = kit.checked(KIT_CFG)
     tree = kit.tree_of({n: kit.leaf_of(params, n)
@@ -648,9 +649,8 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(kit):
     tokens, labels = batch_of(4)
     # a bias wide enough that it turns choices and would move a weight,
     # and not so wide that the first router sends the held experts nothing
-    params, bias = train.init_model_params(F32, 11), some_bias(scale=0.1)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    params, bias = built.params(F32, 11), some_bias(scale=0.1)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state = state[:4] + (jax.tree.map(jnp.copy, bias),)
     state, aux = step(state, t, l)

@@ -1,21 +1,11 @@
 """memchecker — buffer-ownership checking (valgrind-annotation analog)."""
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_racy_write_to_inflight_send_buffer_caught(tmp_path):

@@ -30,6 +30,8 @@ from ompi_tpu.api.errors import MpiError
 from ompi_tpu.parallel import moe
 from ompi_tpu.parallel.elastic import partition
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -221,13 +223,6 @@ _MOE_JOB = textwrap.dedent("""
 """)
 
 
-def _tpurun_env():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    for k in ("OTPU_RANK", "OTPU_NPROCS", "OTPU_COORD"):
-        env.pop(k, None)
-    return env
-
-
 def test_mp_moe_train_bit_exact_and_reconciled(tmp_path):
     """The 2-process acceptance run: expert-parallel training over the
     ragged host collectives lands bit-exact on the oracle, and the
@@ -237,11 +232,8 @@ def test_mp_moe_train_bit_exact_and_reconciled(tmp_path):
     conf = {"steps": 10, "n_experts": 6, "expert_dim": 8,
             "tokens_per_step": 24, "capacity_factor": 0.9,
             "ckpt_every": 4, "seed": 3}
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
-           sys.executable, str(script), str(tmp_path / "ckpt"),
-           json.dumps(conf)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=_tpurun_env())
+    r = tpurun(2, [sys.executable, str(script), str(tmp_path / "ckpt"),
+                   json.dumps(conf)], timeout=300)
     line = next((ln for ln in r.stdout.splitlines()
                  if "MOE " in ln and "MOERANK" not in ln), None)
     assert line is not None, r.stdout + r.stderr
@@ -274,13 +266,10 @@ def test_moe_chaos_kill_reshards_over_survivors(tmp_path):
     conf = {"steps": 12, "ckpt_dir": str(tmp_path / "ckpt"),
             "n_experts": 6, "expert_dim": 8, "tokens_per_step": 24,
             "ckpt_every": 4, "seed": 3}
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "3",
-           "--enable-recovery",
-           "--mca", "otpu_chaos_spec", "kill:rank=2,step=5",
-           sys.executable, "-m", "ompi_tpu.parallel.moe",
-           json.dumps(conf)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=_tpurun_env())
+    r = tpurun(3, [sys.executable, "-m", "ompi_tpu.parallel.moe",
+                   json.dumps(conf)], timeout=300,
+               extra=("--enable-recovery",
+                      "--mca", "otpu_chaos_spec", "kill:rank=2,step=5"))
     line = next((ln for ln in r.stdout.splitlines()
                  if "MOE " in ln), None)
     assert line is not None, r.stdout + r.stderr
@@ -311,13 +300,10 @@ def test_moe_critical_path_blames_hot_expert_rank(tmp_path):
             "capacity_factor": 3.0, "hot_expert": 5, "hot_boost": 0.8,
             "compute_us_per_token": 2000, "ckpt_every": 50, "seed": 0}
     assert partition(2, 3, 6) == (4, 6)      # expert 5 homes on rank 2
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "3",
-           "--mca", "otpu_trace_enable", "1",
-           "--mca", "otpu_trace_dir", str(tdir),
-           sys.executable, "-m", "ompi_tpu.parallel.moe",
-           json.dumps(conf)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=_tpurun_env())
+    r = tpurun(3, [sys.executable, "-m", "ompi_tpu.parallel.moe",
+                   json.dumps(conf)], timeout=300,
+               extra=("--mca", "otpu_trace_enable", "1",
+                      "--mca", "otpu_trace_dir", str(tdir)))
     assert any("MOE " in ln for ln in r.stdout.splitlines()), \
         r.stdout + r.stderr
     events, profiles, meta = oa.load_run([str(tdir)])

@@ -5,20 +5,19 @@ head→child→coord protocol as plain subprocesses — real child
 launchers, distinct node ids, ranks joining one world through the
 head's coord service — without needing sshd in CI.
 """
+import functools
 import os
 import subprocess
 import sys
 import textwrap
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(extra, timeout=180):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", *extra],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO,
-        env=env)
+#: the argument list says how many ranks, after the hostfile
+_tpurun = functools.partial(tpurun, None, timeout=180)
 
 
 def test_hostfile_ring_end_to_end(tmp_path):

@@ -1,24 +1,14 @@
 """Multi-process integration: tpurun + coordination service + btl/sm+tcp +
 coll/basic — the ``mpirun -n N`` smoke tests of SURVEY §4."""
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
 import pytest
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra_env=None):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    env.update(extra_env or {})
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n), *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_mp_ring():

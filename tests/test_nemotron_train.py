@@ -21,9 +21,13 @@ import pytest
 
 from ompi_tpu.parallel import (attention, config, experts, mamba, objective,
                                train)
-from ompi_tpu.parallel import nemotron_reference as ref
+from ompi_tpu.parallel import nemotron_reference
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
+
+import built
+
+ref = built.programs(nemotron_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -103,16 +107,16 @@ def test_the_chunked_scan_is_the_recurrence(length, groups):
     b, c = (jax.random.normal(k, (2, length, groups, n)) for k in keys[3:])
     probe = jax.random.normal(keys[0], (2, length, heads, hd))
     with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
+        want, want_g = jax.jit(jax.value_and_grad(
             lambda *args: jnp.sum(ref.recurrence(*args) * probe),
-            argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
-    got, got_g = jax.value_and_grad(
+            argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda *args: jnp.sum(mamba.ssd_chunked(*args, 8) * probe),
-        argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+        argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
     close(got, want, rtol=1e-4)
     for g, w in zip(got_g, want_g):
         near(g, w)
-    close(mamba.ssd_chunked(x, dt, a, b, c, 8),
+    close(built.program(mamba.ssd_chunked)(x, dt, a, b, c, 8),
           ref.recurrence(x, dt, a, b, c), rtol=1e-4, atol=1e-5)
 
 
@@ -123,10 +127,10 @@ def test_a_long_decay_does_not_overflow_the_chunk():
     dt = jnp.full((1, 16, 2), 30.0)
     a = -jnp.array([3.0, 5.0])
     b = c = jnp.ones((1, 16, 1, 4))
-    y, g = jax.value_and_grad(
-        lambda dt: jnp.sum(mamba.ssd_chunked(x, dt, a, b, c, 8)))(dt)
+    y, g = jax.jit(jax.value_and_grad(
+        lambda dt: jnp.sum(mamba.ssd_chunked(x, dt, a, b, c, 8))))(dt)
     assert np.isfinite(np.asarray(y)) and np.all(np.isfinite(np.asarray(g)))
-    close(mamba.ssd_chunked(x, dt, a, b, c, 8),
+    close(built.program(mamba.ssd_chunked)(x, dt, a, b, c, 8),
           ref.recurrence(x, dt, a, b, c))
 
 
@@ -137,12 +141,12 @@ def test_the_mixer_is_the_references(groups_here):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 27, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
+        want, want_g = jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(ref.mixer(p, x, cfg) * probe),
-            argnums=(0, 1))(p, x)
-    got, got_g = jax.value_and_grad(
+            argnums=(0, 1)))(p, x)
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda p, x: jnp.sum(mamba.mamba_mixer(p, x, cfg)[0] * probe),
-        argnums=(0, 1))(p, x)
+        argnums=(0, 1)))(p, x)
     close(got, want, rtol=1e-4)
     for k in p:
         near(got_g[0][k], want_g[0][k], err_msg=k)
@@ -161,12 +165,12 @@ def test_grouped_query_attention_is_the_references(heads, kv, here):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
+        want, want_g = jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(ref.attention(p, x, cfg) * probe),
-            argnums=(0, 1))(p, x)
-    got, got_g = jax.value_and_grad(
+            argnums=(0, 1)))(p, x)
+    got, got_g = jax.jit(jax.value_and_grad(
         lambda p, x: jnp.sum(attention.gqa_attention(
-            p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1))(p, x)
+            p, x, cfg, interpret=True)[0] * probe), argnums=(0, 1)))(p, x)
     close(got, want, rtol=1e-4)
     for k in p:
         near(got_g[0][k], want_g[0][k], err_msg=k)
@@ -182,14 +186,14 @@ def test_the_latent_block_under_uneven_routing():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
     with jax.default_matmul_precision("highest"):
-        (want, load), want_g = jax.value_and_grad(
+        (want, load), want_g = jax.jit(jax.value_and_grad(
             lambda p, x: (lambda y, load: (jnp.sum(y * probe), load))(
                 *ref.experts(p, x, bias, F32)),
-            argnums=(0, 1), has_aux=True)(p, x)
-    (got, stats), got_g = jax.value_and_grad(
+            argnums=(0, 1), has_aux=True))(p, x)
+    (got, stats), got_g = jax.jit(jax.value_and_grad(
         lambda p, x: (lambda y, st, _: (jnp.sum(y * probe), st))(
             *experts.moe_latent_block(p, x, F32, bias)),
-        argnums=(0, 1), has_aux=True)(p, x)
+        argnums=(0, 1), has_aux=True))(p, x)
     assert load[9] == 64 and load[10] == 0
     # the hot expert's group alone is more than one of the loop's chunks
     assert 64 > experts.chunk_rows(
@@ -269,9 +273,8 @@ def test_the_expert_shares_add_up_to_the_uncut_layer():
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    params = train.init_model_params(F32, 3)
+    step, place = built.step(F32)
+    params = built.params(F32, 3)
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     auxes = []
@@ -352,11 +355,11 @@ def test_one_step_reports_the_references_loads_and_gradients(stepped):
 
 def test_every_leafs_gradient_is_the_references():
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
-    (_, aux), got = jax.value_and_grad(
+    params, bias = built.params(F32, 11), some_bias()
+    (_, aux), got = jax.jit(jax.value_and_grad(
         lambda ps: objective.model_loss(ps, tokens, labels, F32, interpret=True,
                                     n_global=64, bias=bias),
-        has_aux=True)(params)
+        has_aux=True))(params)
     (_, loads), want = ref.grads(params, tokens, labels, F32, bias)
     close(aux["loads"], loads)
     for name, path in NAMES:
@@ -369,13 +372,13 @@ def test_what_the_checkpoint_keeps_changes_no_number(monkeypatch):
     make them again, so every gradient entry, and what a step reports, is
     bit for bit what the bare checkpoint (nothing kept) gives."""
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
+    params, bias = built.params(F32, 11), some_bias()
 
     def grads():
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda ps: objective.model_loss(ps, tokens, labels, F32,
                                         interpret=True, n_global=64,
-                                        bias=bias), has_aux=True)(params)
+                                        bias=bias), has_aux=True))(params)
 
     def one_step():
         mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
@@ -419,7 +422,7 @@ def test_no_gain_and_no_mixer_scalar_is_decayed():
 
 
 def test_a_mixers_leaves_start_as_its_authors_start_them():
-    params = train.init_model_params(F32, 3)["layers"]["l0"]["mamba"]
+    params = built.params(F32, 3)["layers"]["l0"]["mamba"]
     assert np.all(np.asarray(params["D"]) == 1.0)
     assert np.all(np.asarray(params["gate_norm"]) == 1.0)
     a = np.exp(np.asarray(params["A_log"]))
@@ -431,8 +434,9 @@ def test_a_mixers_leaves_start_as_its_authors_start_them():
 
 
 def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(train.init_model_params(F32, 3),
                         *stepped["batches"][0])
     for (tokens, labels), first in zip(stepped["batches"],
@@ -444,9 +448,8 @@ def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
 
 def test_bfloat16_compute_stays_near_float32(stepped):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
-    state, tokens, labels = place(train.init_model_params(cfg, 3),
+    step, place = built.step(cfg)
+    state, tokens, labels = place(built.params(cfg, 3),
                                   *stepped["batches"][0])
     _, aux = step(state, tokens, labels)
     close(aux["losses"][0], stepped["auxes"][0]["losses"][0], rtol=3e-3)
@@ -455,9 +458,8 @@ def test_bfloat16_compute_stays_near_float32(stepped):
 def test_two_data_parallel_ranks_are_one_model(stepped):
     if len(jax.devices()) < 2:
         pytest.skip("one device")
-    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    state, tokens, labels = place(train.init_model_params(F32, 3),
+    step, place = built.step(F32, 2)
+    state, tokens, labels = place(built.params(F32, 3),
                                   *stepped["batches"][0])
     state, aux = step(state, tokens, labels)
     first = stepped["auxes"][0]
@@ -568,7 +570,7 @@ def test_the_kit_names_the_programs_leaves(kit):
 
 def test_the_kits_reference_is_the_repositorys(kit):
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias()
+    params, bias = built.params(F32, 11), some_bias()
     (loss, loads), want = ref.grads(params, tokens, labels, F32, bias)
     wrt = kit.checked(KIT_CFG)
     got = kit.reference_step(params, tokens, labels, KIT_CFG, bias, wrt)
@@ -586,9 +588,8 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(kit):
     routing; every wrong model lies outside it somewhere, and every
     control of a part outside it at that part."""
     tokens, labels = batch_of(4)
-    params, bias = train.init_model_params(F32, 11), some_bias(scale=0.3)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    params, bias = built.params(F32, 11), some_bias(scale=0.3)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state = state[:4] + (jax.tree.map(jnp.copy, bias),)
     state, aux = step(state, t, l)

@@ -14,11 +14,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import olmoe_reference as ref
+from ompi_tpu.parallel import olmoe_reference
 from ompi_tpu.parallel import config, objective, train
 from ompi_tpu.parallel.experts import moe_sorted_block
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
+
+import built
+
+ref = built.programs(olmoe_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -40,7 +44,7 @@ def batch_of(seed):
 
 @pytest.fixture(scope="module")
 def params():
-    return train.init_model_params(F32, seed=3)
+    return built.params(F32, 3)
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +62,8 @@ def system_loss(params, cfg, batch):
 
 @pytest.fixture(scope="module")
 def system(params):
-    (total, aux), grads = jax.value_and_grad(
-        lambda p: system_loss(p, F32, batch_of(0)), has_aux=True)(params)
+    (total, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(p, F32, batch_of(0)), has_aux=True))(params)
     return dict(total=total, aux=aux, grads=grads)
 
 
@@ -79,8 +83,7 @@ def test_a_key_value_head_a_query_head_counts_as_no_shared_one(traced_step,
 def run_steps(cfg, params, seeds, dp=1):
     """Parameters and each step's ``aux`` after one optimiser step a
     seed's batch, through ``build_train_step`` on ``dp`` CPU devices."""
-    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
+    step, place = built.step(cfg, dp)
     state = None
     out = []
     for seed in seeds:
@@ -200,8 +203,8 @@ def test_bfloat16_compute_meets_the_reference_within_its_tolerance(
     top-2 choice of the 128 that bfloat16 flips at a near-tie moves
     whole rows of an expert's gradient (at 8,192 tokens a flip is a
     thousandth of a group; the benchmark's tolerance is set there)."""
-    (total, aux), grads = jax.value_and_grad(
-        lambda p: system_loss(p, BF16, batch_of(0)), has_aux=True)(params)
+    (total, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(p, BF16, batch_of(0)), has_aux=True))(params)
     np.testing.assert_allclose(aux["losses"], reference["parts"], atol=2e-2,
                                rtol=1e-2)
     assert not np.allclose(aux["losses"], reference["parts"], **CLOSE)

@@ -1,8 +1,6 @@
 """One-sided RMA: windows, put/get/accumulate/atomics, fence/lock/PSCW
 (SURVEY.md §2.3 osc framework)."""
-import os
-import subprocess
-import sys
+import functools
 import textwrap
 from pathlib import Path
 
@@ -11,6 +9,8 @@ import pytest
 
 import ompi_tpu
 from ompi_tpu.runtime import init as rt
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -71,14 +71,7 @@ class TestLocalWindows:
             win.put(np.zeros(1), 0)
 
 
-def _tpurun(n, script, timeout=420):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         sys.executable, str(script)],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=420)
 
 
 class TestMultiprocessRma:

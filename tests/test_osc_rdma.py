@@ -5,8 +5,6 @@ reference implements in ``ompi/mca/osc/rdma/``: put/get as direct stores,
 accumulate under the native accumulate lock, CAS-backed passive locks, and
 message-free PSCW over shared counters.
 """
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
@@ -14,6 +12,8 @@ from pathlib import Path
 import pytest
 
 from ompi_tpu import native
+
+from launch import tpurun as _tpurun
 
 # every scenario here asserts RdmaModule SELECTION, and osc/rdma's
 # comm_query requires the native atomics — without the toolchain the
@@ -23,16 +23,6 @@ pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="osc/rdma needs native atomics")
 
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_rdma_selected_and_put_get_fence(tmp_path):

@@ -7,36 +7,30 @@ into SPC, bfloat16 compute, two data-parallel ranks; at
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ompi_tpu.parallel import ouro_reference as ref
 from ompi_tpu.parallel import objective, train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
 
+import built
 from test_ouro_train import (F32, NAMES, batch_of, close, near, ref_grads,
                              spread_params)
-
-
-def built(cfg=F32, dp=1):
-    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
-    return train.build_train_step(mesh, spec, model=cfg)
 
 
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    step, place = built()
-    params = spread_params(F32, 3)
+    step, place = built.step(F32)
+    params = built.params(F32, 3, spread_params)
     batches = [batch_of(s) for s in range(3)]
-    state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
+    state, _, _ = place(built.params(F32, 3, spread_params), *batches[0])
     auxes = []
     for tokens, labels in batches:
         state, aux = step(state, tokens, labels)
         auxes.append(jax.device_get(aux))
-    want = ref.train_steps(params, batches, F32)
+    want = built.program(ref.train_steps)(params, batches, F32)
     return dict(params=params, batches=batches, state=state, auxes=auxes,
                 want=want, step=step)
 
@@ -76,11 +70,11 @@ def test_one_step_reports_the_references_losses_exits_and_gradients(stepped):
 
 def test_the_parameters_after_one_update_are_the_references(stepped):
     tokens, labels = stepped["batches"][0]
-    step, place = built()
-    state, t, l = place(jax.tree.map(jnp.copy, stepped["params"]), tokens,
-                        labels)
+    step, place = built.step(F32)
+    state, t, l = place(built.params(F32, 3, spread_params), tokens, labels)
     state, _ = step(state, t, l)
-    want, _ = ref.train_steps(stepped["params"], [(tokens, labels)], F32)
+    want, _ = built.program(ref.train_steps)(
+        stepped["params"], [(tokens, labels)], F32)
     for name, path in NAMES:
         got, ours = (np.asarray(train._leaf(tree, path))
                      for tree in (state[0], want))
@@ -93,7 +87,9 @@ def test_the_parameters_after_one_update_are_the_references(stepped):
 
 
 def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
-    step, place = built()
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(spread_params(F32, 3), *stepped["batches"][0])
     for (tokens, labels), before in zip(stepped["batches"],
                                         stepped["auxes"]):
@@ -107,9 +103,9 @@ def test_a_step_read_back_counts_its_exit_depth_and_no_slot(stepped):
     names = ("train_steps_read", "loop_exit_depth", "moe_local_slots",
              "moe_max_expert_load")
     before = {k: spc.read(k) for k in names}
-    step, place = built()
+    step, place = built.step(F32)
     tokens, labels = stepped["batches"][0]
-    state, t, l = place(train.init_model_params(F32, 0), tokens, labels)
+    state, t, l = place(built.params(F32, 0), tokens, labels)
     _, aux = step(state, t, l)
     assert train.record_step_stats(aux) == 0
     moved = {k: spc.read(k) - v for k, v in before.items()}
@@ -123,10 +119,10 @@ def test_bfloat16_compute_and_two_ranks_stay_near_the_reference(dp):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
     if dp > len(jax.devices()):
         pytest.skip("one device")
-    step, place = built(cfg, dp)
-    params = spread_params(cfg, 3)
+    step, place = built.step(cfg, dp)
+    params = built.params(cfg, 3, spread_params)
     tokens, labels = batch_of(0)
-    state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
+    state, t, l = place(built.params(cfg, 3, spread_params), tokens, labels)
     _, aux = step(state, t, l)
     (total, (by_pass, expected, bonus, p)), _ = ref_grads(
         params, tokens, labels, F32)

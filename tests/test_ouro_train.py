@@ -17,10 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import ouro_reference as ref
+from ompi_tpu.parallel import ouro_reference
 from ompi_tpu.parallel import (attention, config, experts, model, objective,
                                train)
 from ompi_tpu.runtime import trace
+
+import built
+
+ref = built.programs(ouro_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -93,7 +97,7 @@ def test_the_cells_file_loads_as_a_looped_dense_model():
     assert kind.operator is attention.FULL \
         and kind.feed_forward is experts.DENSE and not kind.routes
     # the tree holds each leaf once: the count at the published widths
-    shapes = jax.eval_shape(lambda: train.init_model_params(cfg, 0))
+    shapes = jax.eval_shape(lambda: built.params(cfg, 0))
     assert sum(a.size for a in jax.tree.leaves(shapes)) == 406_884_353
     assert [n for n, _ in train.leaf_names(cfg)][-3:] == [
         "head", "exit_gate.w", "exit_gate.b"]
@@ -105,7 +109,7 @@ def test_the_cells_file_loads_as_a_looped_dense_model():
 
 
 def test_the_gate_starts_at_zero_and_the_second_norms_at_one():
-    params = train.init_model_params(F32, 1)
+    params = built.params(F32, 1)
     assert not np.any(np.asarray(params["exit_gate"]["w"])) \
         and not np.any(np.asarray(params["exit_gate"]["b"]))
     group = params["layers"]["l0"]["attn_dense"]
@@ -162,7 +166,7 @@ def test_the_scopes_and_counters_are_named():
         "loop_layer_applications", "loop_head_rows")}
     tokens, labels = batch_of(0)
     text = jax.jit(loss_of(F32, tokens, labels)).lower(
-        train.init_model_params(F32, 0)).as_text(debug_info=True)
+        built.params(F32, 0)).as_text(debug_info=True)
     for scope in loop:
         assert scope in text, scope
     assert "otpu_bd_loss" not in text
@@ -277,8 +281,8 @@ def test_the_exit_distribution_sums_to_one_however_far_the_gate_goes(at):
     gate = jnp.full((4, 8), at, jnp.float32).at[:, 1].set(0.0) \
         .at[1, 2].set(-at)
     (p, log_p), dgate = jax.jit(lambda g: (
-        objective.exit_distribution(g), jax.grad(lambda g: jnp.sum(
-            (lambda p, lp: p * lp)(*objective.exit_distribution(g))))(g)))(
+        objective.exit_distribution(g), jax.jit(jax.grad(lambda g: jnp.sum(
+            (lambda p, lp: p * lp)(*objective.exit_distribution(g)))))(g)))(
                 gate)
     assert np.all(np.isfinite(p)) and np.all(np.isfinite(log_p)) \
         and np.all(np.isfinite(dgate))

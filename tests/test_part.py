@@ -4,6 +4,7 @@ Parrived, aggregation onto fewer wire messages, mismatched send/recv
 partition counts, mixed Startall, loud error paths, a seeded Pready-order
 fuzz vs a numpy reference and the partitioned device collective
 (pcoll)."""
+import functools
 import os
 import subprocess
 import sys
@@ -16,15 +17,12 @@ import ompi_tpu
 from ompi_tpu.api.errors import ErrorClass, MpiError
 from ompi_tpu.api.request import start_all
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 @pytest.fixture(scope="module")

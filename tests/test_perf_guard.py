@@ -17,6 +17,8 @@ import subprocess
 import sys
 import textwrap
 
+import launch
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = textwrap.dedent("""
@@ -50,10 +52,10 @@ def test_allreduce_per_byte_cost_stays_linear(tmp_path):
     script = tmp_path / "guard.py"
     script.write_text(_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "4",
          "--mca", "coll", "^sm_coll", sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
+        240, env)
     assert r.returncode == 0, r.stdout + r.stderr
     for rank in range(4):
         line = next(ln for ln in r.stdout.splitlines()
@@ -128,10 +130,10 @@ def test_fastpath_zero_copy_tcp_send(tmp_path):
     script = tmp_path / "copy_pin.py"
     script.write_text(_FASTPATH_COPY_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
          "--fake-nodes", "2", sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
+        240, env)
     assert r.returncode == 0, r.stdout + r.stderr
     for rank in (0, 1):
         line = next(ln for ln in r.stdout.splitlines()
@@ -153,10 +155,10 @@ def test_tuned_schedule_cache_hits_on_second_call(tmp_path):
     script = tmp_path / "sched_pin.py"
     script.write_text(_SCHED_CACHE_SCRIPT)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "4",
          "--mca", "coll", "^sm_coll", sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
+        240, env)
     assert r.returncode == 0, r.stdout + r.stderr
     line = next(ln for ln in r.stdout.splitlines() if "SCHEDPIN" in ln)
     base_hits, hits_after, lane = json.loads(

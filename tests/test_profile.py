@@ -20,12 +20,13 @@ Four layers of coverage:
 """
 import json
 import os
-import subprocess
 import sys
 import time
 from pathlib import Path
 
 import pytest
+
+import launch
 
 REPO = Path(__file__).resolve().parent.parent
 WORKER = Path(__file__).resolve().parent / "telemetry_worker.py"
@@ -290,8 +291,7 @@ def test_stage_breakdown_reconciles_on_loopback_allreduce(tmp_path):
            # datapath the stage clocks instrument
            "--mca", "otpu_coll_sm_coll_priority", "0",
            sys.executable, str(WORKER)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=env)
+    r = launch.run(cmd, 300, env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
     from ompi_tpu.tools import otpu_analyze

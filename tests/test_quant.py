@@ -30,6 +30,8 @@ import ompi_tpu
 from ompi_tpu.api import op as op_mod
 from ompi_tpu.mca.coll import quant
 
+from launch import tpurun
+
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -501,18 +503,15 @@ def test_wire_4MB_moves_at_least_2x_fewer_bytes(tmp_path):
     band."""
     script = tmp_path / "wire_job.py"
     script.write_text(_WIRE_JOB)
-    proc = subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
-         "--fake-nodes", "2",
-         "--mca", "otpu_coll_sm_coll_priority", "0",
-         "--mca", "otpu_coll_quant_wire", "1",
-         "--mca", "otpu_coll_tuned_allreduce_algorithm",
-         "recursive_doubling",
-         "--mca", "pml_ob1_stripe", "0",
-         "--mca", "pml_ob1_rget_limit", "0",
-         sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO,
-        env=_mp_env())
+    proc = tpurun(
+        2, script, timeout=240,
+        extra=("--fake-nodes", "2",
+               "--mca", "otpu_coll_sm_coll_priority", "0",
+               "--mca", "otpu_coll_quant_wire", "1",
+               "--mca", "otpu_coll_tuned_allreduce_algorithm",
+               "recursive_doubling",
+               "--mca", "pml_ob1_stripe", "0",
+               "--mca", "pml_ob1_rget_limit", "0"))
     assert proc.returncode == 0, proc.stderr[-3000:]
     reps = [json.loads(ln.split(" ", 2)[2])
             for ln in proc.stdout.splitlines() if "WIRE" in ln]
@@ -547,21 +546,18 @@ ompi_tpu.finalize()
 def _run_chaos_quant_job(tmp_path, spec):
     script = tmp_path / "chaos_job.py"
     script.write_text(_CHAOS_JOB)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
-         "--fake-nodes", "2",
-         "--mca", "otpu_coll_sm_coll_priority", "0",
-         "--mca", "otpu_coll_quant_wire", "1",
-         "--mca", "otpu_coll_quant_min_bytes", "4k",
-         "--mca", "otpu_chaos_spec", spec,
-         "--mca", "otpu_chaos_seed", "3",
-         "--mca", "ft_detector", "true",
-         "--mca", "ft_detector_period", "0.3",
-         "--mca", "ft_detector_timeout", "6.0",
-         "--mca", "ft_detector_startup_grace", "6.0",
-         sys.executable, str(script)],
-        capture_output=True, text=True, timeout=150, cwd=REPO,
-        env=_mp_env())
+    return tpurun(
+        2, script, timeout=150,
+        extra=("--fake-nodes", "2",
+               "--mca", "otpu_coll_sm_coll_priority", "0",
+               "--mca", "otpu_coll_quant_wire", "1",
+               "--mca", "otpu_coll_quant_min_bytes", "4k",
+               "--mca", "otpu_chaos_spec", spec,
+               "--mca", "otpu_chaos_seed", "3",
+               "--mca", "ft_detector", "true",
+               "--mca", "ft_detector_period", "0.3",
+               "--mca", "ft_detector_timeout", "6.0",
+               "--mca", "ft_detector_startup_grace", "6.0"))
 
 
 def test_chaos_corrupt_quant_frames_loud(tmp_path):

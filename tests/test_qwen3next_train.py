@@ -21,8 +21,11 @@ import pytest
 
 from ompi_tpu.parallel import (attention, causal, config, experts, gdn,
                                layers, train)
-from ompi_tpu.parallel import qwen3next_reference as ref
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+from ompi_tpu.parallel import qwen3next_reference
+
+import built
+
+ref = built.programs(qwen3next_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -116,13 +119,13 @@ def test_the_chunked_rule_is_the_recurrence_over_positions(length):
     args = rule_inputs(length, length)
     with jax.default_matmul_precision("highest"):
         want = by_positions(*args)
-        got = gdn.gated_delta_chunked(*args, 8)
+        got = built.program(gdn.gated_delta_chunked)(*args, 8)
         close(got, want, rtol=1e-4, atol=1e-6)
         weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
         loss = lambda fn: lambda *a: jnp.sum(fn(*a) * weight)
-        g_want = jax.grad(loss(by_positions), range(5))(*args)
-        g_got = jax.grad(loss(lambda *a: gdn.gated_delta_chunked(*a, 8)),
-                         range(5))(*args)
+        g_want = jax.jit(jax.grad(loss(by_positions), range(5)))(*args)
+        g_got = jax.jit(jax.grad(loss(
+            lambda *a: gdn.gated_delta_chunked(*a, 8)), range(5)))(*args)
     for name, a, b in zip("qkvgb", g_got, g_want):
         near(a, b, rel=1e-4, err_msg=name)
 
@@ -131,11 +134,11 @@ def test_without_beta_nothing_is_written_and_the_state_only_decays():
     """beta = 0 writes nothing: from the zero state every output is zero,
     whatever g is; and a chunk's result does not depend on the chunk."""
     q, k, v, g, beta = rule_inputs(3, 24)
-    got = gdn.gated_delta_chunked(q, k, v, g, beta * 0, 8)
+    chunked = built.program(gdn.gated_delta_chunked)
+    got = chunked(q, k, v, g, beta * 0, 8)
     np.testing.assert_array_equal(np.asarray(got), 0.0)
     with jax.default_matmul_precision("highest"):
-        close(gdn.gated_delta_chunked(q, k, v, g, beta, 8),
-              gdn.gated_delta_chunked(q, k, v, g, beta, 4),
+        close(chunked(q, k, v, g, beta, 8), chunked(q, k, v, g, beta, 4),
               rtol=1e-4, atol=1e-6)
 
 
@@ -143,7 +146,8 @@ def test_without_decay_and_with_beta_one_it_is_the_plain_delta_rule():
     """g = 0 and beta = 1: ``S <- S + k (v - S^T k)^T``, in numpy
     float64."""
     q, k, v, g, beta = rule_inputs(4, 19, bt=1, hk=1, hv=1)
-    got = gdn.gated_delta_chunked(q, k, v, g * 0, beta * 0 + 1, 8)
+    got = built.program(gdn.gated_delta_chunked)(
+        q, k, v, g * 0, beta * 0 + 1, 8)
     qn, kn, vn = (np.asarray(t, np.float64)[0, :, 0] for t in (q, k, v))
     state, want = np.zeros((16, 16)), []
     for t in range(19):
@@ -157,10 +161,10 @@ def test_the_unit_lower_inverse_and_its_gradient():
     want = jnp.linalg.inv(jnp.eye(8) + low)
     close(gdn.unit_lower_inverse(low), want, rtol=1e-4, atol=1e-5)
     weight = jax.random.normal(jax.random.PRNGKey(1), low.shape)
-    g_want = jax.grad(lambda a: jnp.sum(
-        jnp.linalg.inv(jnp.eye(8) + jnp.tril(a, -1)) * weight))(low)
-    g_got = jax.grad(lambda a: jnp.sum(
-        gdn.unit_lower_inverse(jnp.tril(a, -1)) * weight))(low)
+    g_want = jax.jit(jax.grad(lambda a: jnp.sum(
+        jnp.linalg.inv(jnp.eye(8) + jnp.tril(a, -1)) * weight)))(low)
+    g_got = jax.jit(jax.grad(lambda a: jnp.sum(
+        gdn.unit_lower_inverse(jnp.tril(a, -1)) * weight)))(low)
     near(g_got, g_want, rel=1e-4)
 
 
@@ -333,7 +337,7 @@ def test_the_layers_are_walked_by_their_types():
         "gdn_dense", "gdn_moe", "attn_dense", "attn_moe"}
     later = dataclasses.replace(F32, first_layer_here=2, layers_here=6)
     assert later.pattern_here == "LALLLA"
-    params = train.init_model_params(F32, 0)
+    params = built.params(F32, 0)
     gdn = params["layers"]["l0"]["gdn_moe"]
     np.testing.assert_array_equal(np.asarray(gdn["dt_bias"]), 1.0)
     assert 0 <= float(gdn["A_log"].min()) \
@@ -350,9 +354,8 @@ def test_the_layers_are_walked_by_their_types():
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    params = train.init_model_params(F32, 3)
+    step, place = built.step(F32)
+    params = built.params(F32, 3)
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     assert state[4]["layers"].shape == (4, 0)       # rows of no entries
@@ -413,8 +416,7 @@ def test_one_step_reports_the_references_loads_and_gradients(stepped):
 
 def test_the_parameters_after_one_update_are_the_references(stepped):
     tokens, labels = stepped["batches"][0]
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, stepped["params"]), tokens,
                         labels)
     state, _ = step(state, t, l)
@@ -431,8 +433,9 @@ def test_the_parameters_after_one_update_are_the_references(stepped):
 
 
 def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(train.init_model_params(F32, 3),
                         *stepped["batches"][0])
     for (tokens, labels), before in zip(stepped["batches"],
@@ -444,9 +447,8 @@ def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
 
 def test_bfloat16_compute_stays_near_float32(stepped):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
-    state, t, l = place(train.init_model_params(cfg, 3),
+    step, place = built.step(cfg)
+    state, t, l = place(built.params(cfg, 3),
                         *stepped["batches"][0])
     _, aux = step(state, t, l)
     close(aux["losses"][1], stepped["auxes"][0]["losses"][1], rtol=5e-3)
@@ -455,9 +457,8 @@ def test_bfloat16_compute_stays_near_float32(stepped):
 def test_two_data_parallel_ranks_are_one_model(stepped):
     if len(jax.devices()) < 2:
         pytest.skip("one device")
-    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    state, t, l = place(train.init_model_params(F32, 3),
+    step, place = built.step(F32, 2)
+    state, t, l = place(built.params(F32, 3),
                         *stepped["batches"][0])
     _, aux = step(state, t, l)
     want = stepped["auxes"][0]
@@ -632,7 +633,7 @@ def test_the_kit_names_the_programs_leaves(kit):
 
 def test_the_kits_reference_is_the_repositorys(kit):
     tokens, labels = batch_of(4)
-    params = train.init_model_params(F32, 11)
+    params = built.params(F32, 11)
     (total, (ce, lb, loads)), want = ref.grads(params, tokens, labels, F32)
     wrt = kit.checked(KIT_CFG)
     tree = kit.tree_of({n: kit.leaf_of(params, n)
@@ -651,9 +652,8 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(kit):
     routing; every wrong model lies outside it somewhere, and every
     control of a part outside it at that part."""
     tokens, labels = batch_of(4)
-    params = train.init_model_params(F32, 11)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    params = built.params(F32, 11)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state, aux = step(state, t, l)
     aux = jax.device_get(aux)

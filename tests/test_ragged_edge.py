@@ -4,7 +4,6 @@ communicators must round-trip without the caller special-casing —
 fuzzed count matrices over the host (``comm.alltoallv``) and device
 (``*v_array`` / ``ops.pallas_collectives``) paths."""
 import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
@@ -13,6 +12,8 @@ import numpy as np
 import pytest
 
 import ompi_tpu
+
+import launch
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -213,9 +214,9 @@ def test_mp_host_alltoallv_zero_count_cells(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", OTPU_SANITIZE="1")
     env.pop("OTPU_RANK", None)
     env.pop("OTPU_NPROCS", None)
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "3",
          sys.executable, str(script)],
-        capture_output=True, text=True, timeout=180, cwd=REPO, env=env)
+        180, env)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-1500:]
     assert "RAGGED ZERO OK" in r.stdout

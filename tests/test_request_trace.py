@@ -23,7 +23,6 @@ Coverage layers:
 """
 import json
 import os
-import subprocess
 import sys
 import threading
 
@@ -33,6 +32,8 @@ import ompi_tpu
 from ompi_tpu.base.var import registry
 from ompi_tpu.tools.otpu_analyze import (REQ_STAGES, _req_collect,
                                          requests_report)
+
+import launch
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -320,7 +321,7 @@ def test_request_soak_chaos_tail_and_slo(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("OTPU_RANK", None)
     env.pop("OTPU_NPROCS", None)
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "5",
          "--pool", "m_a:1,2", "--pool", "m_b:3,4",
          "--mca", "otpu_trace_enable", "1",
@@ -328,7 +329,7 @@ def test_request_soak_chaos_tail_and_slo(tmp_path):
          "--mca", "otpu_trace_dir", str(td),
          "--mca", "otpu_serving_slo_p99_ms", str(_SLO_MS),
          sys.executable, str(script), str(n_a), str(n_b)],
-        capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
+        300, env)
     line = next((ln for ln in r.stdout.splitlines() if "REQSOAK" in ln),
                 None)
     assert r.returncode == 0 and line, r.stdout + r.stderr

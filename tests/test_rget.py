@@ -7,20 +7,16 @@ end-to-end over both transports: true one-sided segment pull on btl/sm,
 request/stream emulation on btl/tcp (forced via --fake-nodes), plus the
 raw btl put/get surface.
 """
+import functools
 import os
-import subprocess
-import sys
 import textwrap
+
+import launch
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=240):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(launch.tpurun, timeout=240)
 
 
 _LARGE_MSG = """

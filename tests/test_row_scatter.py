@@ -14,6 +14,7 @@ from ompi_tpu.ops import row_scatter as rs
 from ompi_tpu.parallel import experts
 from ompi_tpu.runtime import spc
 
+
 F32 = jnp.float32
 TOKENS = 96
 
@@ -213,9 +214,9 @@ def test_local_expert_ffn_on_the_row_kernel(held, form, interpreted):
     before = (spc.read("moe_scatter_built"),
               spc.read("moe_scatter_kernel_built"))
     args = tuple(range(2 + n_mats))
-    got = jax.value_and_grad(functools.partial(loss, False), args)(
+    got = jax.jit(jax.value_and_grad(functools.partial(loss, False), args))(
         h, weights, *mats)
-    want = jax.value_and_grad(functools.partial(loss, True), args)(
+    want = jax.jit(jax.value_and_grad(functools.partial(loss, True), args))(
         h, weights, *mats)
     # neither trace moved a counter: the decision function says which
     # loop's adds went by the kernel, and a step's plan counts them

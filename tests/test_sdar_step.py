@@ -4,27 +4,27 @@ steps' losses and parameters, one step's loads, noise and every leaf's
 gradient, the update, bit-for-bit repeats, bfloat16 compute, two
 data-parallel ranks; at ``tests/test_sdar_train.py``'s small widths."""
 import dataclasses
-import unittest.mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import sdar_reference as ref
+from ompi_tpu.parallel import sdar_reference
 from ompi_tpu.parallel import train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc
 
 from test_sdar_train import (F32, NAMES, batch_of, close, near, ref_grads,
                              spread_params)
+import built
+
+ref = built.programs(sdar_reference)
 
 
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     params = spread_params(F32, 3)
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
@@ -32,8 +32,7 @@ def stepped():
     for tokens, labels in batches:
         state, aux = step(state, tokens, labels)
         auxes.append(jax.device_get(aux))
-    with unittest.mock.patch.object(ref, "grads", ref_grads):
-        want = ref.train_steps(params, batches, F32)
+    want = ref.train_steps(params, batches, F32)
     return dict(params=params, batches=batches, state=state, auxes=auxes,
                 want=want, step=step)
 
@@ -95,13 +94,11 @@ def test_the_mask_tokens_row_learns_from_the_masked_rows(stepped):
 
 def test_the_parameters_after_one_update_are_the_references(stepped):
     tokens, labels = stepped["batches"][0]
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, stepped["params"]), tokens,
                         labels)
     state, _ = step(state, t, l)
-    with unittest.mock.patch.object(ref, "grads", ref_grads):
-        want, _ = ref.train_steps(stepped["params"], [(tokens, labels)], F32)
+    want, _ = ref.train_steps(stepped["params"], [(tokens, labels)], F32)
     for name, path in NAMES:
         got, ours = (np.asarray(train._leaf(tree, path))
                      for tree in (state[0], want))
@@ -110,8 +107,9 @@ def test_the_parameters_after_one_update_are_the_references(stepped):
 
 
 def test_the_losses_and_the_noise_repeat_bit_for_bit_from_one_seed(stepped):
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(spread_params(F32, 3), *stepped["batches"][0])
     for (tokens, labels), before in zip(stepped["batches"],
                                         stepped["auxes"]):
@@ -137,8 +135,7 @@ def test_a_step_read_back_counts_its_masked_rows(stepped):
 
 def test_bfloat16_compute_stays_near_float32(stepped):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
+    step, place = built.step(cfg)
     state, t, l = place(spread_params(cfg, 3), *stepped["batches"][0])
     _, aux = step(state, t, l)
     close(aux["losses"][1], stepped["auxes"][0]["losses"][1], rtol=5e-3)
@@ -149,8 +146,7 @@ def test_bfloat16_compute_stays_near_float32(stepped):
 def test_two_data_parallel_ranks_are_one_model(stepped):
     if len(jax.devices()) < 2:
         pytest.skip("one device")
-    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32, 2)
     state, t, l = place(spread_params(F32, 3), *stepped["batches"][0])
     _, aux = step(state, t, l)
     want = stepped["auxes"][0]

@@ -18,9 +18,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import sdar_reference as ref
+from ompi_tpu.parallel import sdar_reference
 from ompi_tpu.parallel import (attention, config, layers, model, objective,
                                train)
+
+import built
+
+ref = built.programs(sdar_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")

@@ -16,9 +16,8 @@ Four layers of coverage:
   dropped requests), and (slow lane) autoscale via ``dpm.spawn`` +
   the ``mpi://job/<id>`` pset, plus the long Poisson soak.
 """
+import functools
 import os
-import subprocess
-import sys
 import textwrap
 import threading
 
@@ -30,17 +29,12 @@ from ompi_tpu.api.errors import ErrorClass, MpiError
 from ompi_tpu.serving.scheduler import (ContinuousBatchScheduler,
                                         RequestState, ServeRequest)
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 # ---------------------------------------------------------------- scheduler

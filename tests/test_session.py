@@ -7,10 +7,9 @@ Single-process tests run against the conductor device world (conftest's
 8 virtual devices); the multiprocess cases launch real tpurun jobs where
 psets come from the coord service.
 """
+import functools
 import os
 import random
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
@@ -20,17 +19,12 @@ import ompi_tpu
 from ompi_tpu.api.errhandler import ERRORS_RETURN
 from ompi_tpu.api.errors import ErrorClass, MpiError
 
+from launch import tpurun
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=300):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=300)
 
 
 @pytest.fixture(autouse=True)

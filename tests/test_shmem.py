@@ -1,23 +1,13 @@
 """OpenSHMEM-style PGAS layer: symmetric heap, put/get, atomics, scoll."""
-import os
-import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
 import numpy as np
 
+from launch import tpurun as _tpurun
+
 REPO = Path(__file__).resolve().parent.parent
-
-
-def _tpurun(n, args, timeout=120, extra=()):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         *extra, *args],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
 
 
 def test_symmetric_heap_allocator():

@@ -20,8 +20,11 @@ import pytest
 
 from ompi_tpu.parallel import (attention, config, experts, layers, model,
                                objective, train)
-from ompi_tpu.parallel import smallthinker_reference as ref
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+from ompi_tpu.parallel import smallthinker_reference
+
+import built
+
+ref = built.programs(smallthinker_reference)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -192,11 +195,11 @@ def test_the_experts_are_gated_by_relu_not_silu():
     # the written-out backward of the held experts' loop follows the
     # activation
     loss = lambda fn: lambda p: jnp.sum(fn(p) ** 2)
-    g_got = jax.grad(loss(lambda p: experts.moe_shared_local_block(
-        p, x, F32, None, routed=routed)[0]))(p)
+    g_got = jax.jit(jax.grad(loss(lambda p: experts.moe_shared_local_block(
+        p, x, F32, None, routed=routed)[0])))(p)
     with jax.default_matmul_precision("highest"):
-        g_want = jax.grad(loss(lambda p: ref.experts(
-            p, x, ref.route(p, rows, F32), F32)))(p)
+        g_want = jax.jit(jax.grad(loss(lambda p: ref.experts(
+            p, x, ref.route(p, rows, F32), F32))))(p)
     for leaf in ("gate", "up", "down", "ln2"):
         near(g_got[leaf], g_want[leaf], rel=1e-4, err_msg=leaf)
 
@@ -245,9 +248,8 @@ def test_the_layers_are_walked_by_their_kinds():
 @pytest.fixture(scope="module")
 def stepped():
     """Three steps of the program from seed 3, and the reference's."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    params = train.init_model_params(F32, 3)
+    step, place = built.step(F32)
+    params = built.params(F32, 3)
     batches = [batch_of(s) for s in range(3)]
     state, _, _ = place(jax.tree.map(jnp.copy, params), *batches[0])
     assert state[4]["layers"].shape == (4, 0)       # rows of no entries
@@ -311,7 +313,7 @@ def test_the_loss_and_its_gradients_one_primitive_at_a_time():
     sequence): loss parts, loads and the gradient of every leaf are the
     reference's, so nothing rests on what a compiler fused."""
     cfg = dataclasses.replace(F32, layers_here=2, seq_len=24, micro_batch=1)
-    params = train.init_model_params(cfg, 3)
+    params = built.params(cfg, 3)
     tokens, labels = batch_of(0, cfg=cfg)
     with jax.disable_jit():
         (_, aux), got = jax.value_and_grad(
@@ -328,8 +330,7 @@ def test_the_loss_and_its_gradients_one_primitive_at_a_time():
 
 def test_the_parameters_after_one_update_are_the_references(stepped):
     tokens, labels = stepped["batches"][0]
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, stepped["params"]), tokens,
                         labels)
     state, _ = step(state, t, l)
@@ -345,8 +346,9 @@ def test_the_parameters_after_one_update_are_the_references(stepped):
 
 
 def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    # a second build and a second draw, not the process's kept ones:
+    # whether they give the first's numbers is what is asked
+    step, place = built.fresh_step(F32)
     state, _, _ = place(train.init_model_params(F32, 3),
                         *stepped["batches"][0])
     for (tokens, labels), before in zip(stepped["batches"],
@@ -358,9 +360,8 @@ def test_the_losses_repeat_bit_for_bit_from_one_seed(stepped):
 
 def test_bfloat16_compute_stays_near_float32(stepped):
     cfg = dataclasses.replace(F32, compute_dtype="bfloat16")
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
-    state, t, l = place(train.init_model_params(cfg, 3),
+    step, place = built.step(cfg)
+    state, t, l = place(built.params(cfg, 3),
                         *stepped["batches"][0])
     _, aux = step(state, t, l)
     close(aux["losses"][1], stepped["auxes"][0]["losses"][1], rtol=5e-3)
@@ -369,9 +370,8 @@ def test_bfloat16_compute_stays_near_float32(stepped):
 def test_two_data_parallel_ranks_are_one_model(stepped):
     if len(jax.devices()) < 2:
         pytest.skip("one device")
-    mesh, spec = make_mesh(jax.devices()[:2], MeshSpec(dp=2))
-    step, place = train.build_train_step(mesh, spec, model=F32)
-    state, t, l = place(train.init_model_params(F32, 3),
+    step, place = built.step(F32, 2)
+    state, t, l = place(built.params(F32, 3),
                         *stepped["batches"][0])
     _, aux = step(state, t, l)
     want = stepped["auxes"][0]
@@ -573,7 +573,7 @@ def test_the_kit_names_the_programs_leaves(kit):
 
 def test_the_kits_reference_is_the_repositorys(kit):
     tokens, labels = batch_of(4)
-    params = train.init_model_params(F32, 11)
+    params = built.params(F32, 11)
     (total, (ce, lb, loads)), want = ref.grads(params, tokens, labels, F32)
     wrt = kit.checked(KIT_CFG)
     tree = kit.tree_of({n: kit.leaf_of(params, n)
@@ -596,8 +596,7 @@ def test_the_kit_compares_a_step_of_the_program_within_its_tolerance(kit):
     # widths as it does at the published ones
     params = train.init_model_params(
         dataclasses.replace(F32, init_std=0.1), 11)
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     state, t, l = place(jax.tree.map(jnp.copy, params), tokens, labels)
     state, aux = step(state, t, l)
     aux = jax.device_get(aux)

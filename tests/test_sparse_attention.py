@@ -24,6 +24,7 @@ from ompi_tpu.ops import sparse_attention as sa
 from ompi_tpu.parallel import causal, dsa
 from ompi_tpu.runtime import spc
 
+
 BLOCK = 128
 
 
@@ -250,8 +251,8 @@ def test_the_backward_under_a_selection_is_autodiff(h, n_kv, d, blocks):
     sel = _selection(s, 100)
     rng = np.random.default_rng(3)
     do = jnp.asarray(rng.normal(0, 1, (1, h, s, d)), jnp.float32)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, sel)[0] * do),
-                    argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a, sel)[0] * do),
+                    argnums=(0, 1, 2)))(q, k, v)
     o, lse = dense(q, k, v, sel)
     packed = sa.pack_selection(sel)
     twin = causal._causal_bwd(BLOCK, True, None, (q, k, v, o, lse), do,
@@ -271,7 +272,7 @@ def test_the_selected_attention_hands_no_gradient_to_its_logsumexp():
     sel = sa.pack_selection(_selection(s, 50, empty=False))
     fn = lambda q, k, v: jnp.sum(causal.selected_flash_attention(
         q, k, v, sel, BLOCK, True, 50)[1])
-    for g in jax.grad(fn, argnums=(0, 1, 2))(q, k, v):
+    for g in jax.jit(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v):
         assert not np.any(np.asarray(g))
 
 
@@ -302,8 +303,9 @@ def test_the_alignment_loss_kernel_is_the_definition_and_its_autodiff():
     _, lse = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
     mask = sa.unpack_selection(sel)
     want = loss_by_definition(qi, ki, w, q, k, mask)
-    grads = jax.grad(lambda *a: jnp.sum(loss_by_definition(*a, q, k, mask)),
-                     argnums=(0, 1, 2))(qi, ki, w)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(loss_by_definition(*a, q, k, mask)),
+        argnums=(0, 1, 2)))(qi, ki, w)
     got = sa.index_loss(q, k, lse, qi, ki, w, ilse, sel, interpret=True)
     twin = dsa._index_loss_blocks(qi, ki, w, q, k, lse, ilse, sel, 64,
                                     True)
@@ -321,13 +323,14 @@ def test_the_alignment_loss_reaches_the_indexer_alone():
     sel, ilse = dsa._index_select_blocks(qi, ki, w, 40, 64, True)
     _, lse = causal._causal_fwd_blocks(q, k, v, BLOCK, True, select=sel)
     total = lambda *a: dsa.index_alignment_loss(*a, ilse, sel, 64, True)[0]
-    grads = jax.grad(total, argnums=(0, 1, 2, 3, 4, 5))(qi, ki, w, q, k, lse)
+    grads = jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4, 5)))(
+        qi, ki, w, q, k, lse)
     assert all(np.any(np.asarray(g)) for g in grads[:3])
     assert not any(np.any(np.asarray(g)) for g in grads[3:])
     rows = lambda *a: jnp.sum(
         dsa.index_alignment_loss(*a, ilse, sel, 64, True)[1])
-    assert not any(np.any(np.asarray(g)) for g in jax.grad(
-        rows, argnums=(0, 1, 2))(qi, ki, w, q, k, lse))
+    assert not any(np.any(np.asarray(g)) for g in jax.jit(jax.grad(
+        rows, argnums=(0, 1, 2)))(qi, ki, w, q, k, lse))
 
 
 def test_without_a_selection_the_callers_programs_are_what_they_were():
@@ -353,8 +356,8 @@ def test_the_counters_count_what_was_built():
     before = {n: spc.read(n) for n in ("dsa_built", "dsa_keys_selected",
                                        "dsa_keys_causal", "dsa_mask_bytes",
                                        "attn_built")}
-    jax.grad(lambda q: jnp.sum(causal.selected_flash_attention(
-        q, k, v, sel, BLOCK, True, topk)[0]))(q)
+    jax.jit(jax.grad(lambda q: jnp.sum(causal.selected_flash_attention(
+        q, k, v, sel, BLOCK, True, topk)[0])))(q)
     # a traced pass moves nothing: a layer application counts, from the
     # shapes (``causal.pass_counts``), fed once a built step
     assert {n: spc.read(n) for n in before} == before

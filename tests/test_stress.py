@@ -4,20 +4,16 @@ eager (<=512k), RNDV, RGET (>512k), multi-rail striping (>2m) — plus a
 mixed-collective soak against numpy goldens.  The reference leans on
 external suites (ompi-tests/MTT) for this class of coverage; here it is
 in-tree and deterministic (fixed seed)."""
+import functools
 import os
-import subprocess
-import sys
 import textwrap
+
+from launch import tpurun
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _tpurun(n, script, extra=(), timeout=420):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           *extra, sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=420)
 
 
 def test_p2p_protocol_crossover_stress(tmp_path):

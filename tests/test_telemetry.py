@@ -31,6 +31,8 @@ from pathlib import Path
 
 import pytest
 
+import launch
+
 REPO = Path(__file__).resolve().parent.parent
 WORKER = Path(__file__).resolve().parent / "telemetry_worker.py"
 
@@ -488,8 +490,7 @@ def test_flight_bundle_on_chaos_kill(tmp_path):
            "--mca", "otpu_trace_dir", str(tmp_path / "trace"),
            "--mca", "otpu_flight_dir", str(crash),
            sys.executable, str(script), str(tmp_path / "ckpt")]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=env)
+    r = launch.run(cmd, 300, env)
     out = r.stdout + r.stderr
     bundle_path = crash / "bundle.json"
     assert bundle_path.exists(), out
@@ -533,8 +534,7 @@ def test_analyzer_names_designed_straggler(tmp_path):
            "--mca", "otpu_trace_enable", "1",
            "--mca", "otpu_trace_dir", str(tdir),
            sys.executable, str(WORKER)]
-    r = subprocess.run(cmd, capture_output=True, text=True,
-                       timeout=300, cwd=REPO, env=env)
+    r = launch.run(cmd, 300, env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
     merged = tdir / "trace_merged.json"

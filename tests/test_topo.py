@@ -1,8 +1,6 @@
 """Process topologies: dims_create, cart/graph/dist_graph, cart_sub,
 neighbor collectives (SURVEY.md §2.3 topo framework)."""
-import os
-import subprocess
-import sys
+import functools
 import textwrap
 from pathlib import Path
 
@@ -14,6 +12,8 @@ from ompi_tpu.api.errors import MpiError
 from ompi_tpu.api.status import PROC_NULL
 from ompi_tpu.mca.topo import CartTopo, GraphTopo, dims_create
 from ompi_tpu.runtime import init as rt
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -125,14 +125,7 @@ class TestDeviceWorldCart:
         assert got[1].tolist() == [1, 0]
 
 
-def _tpurun(n, script, timeout=240):
-    env = dict(os.environ)
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    return subprocess.run(
-        [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-         sys.executable, str(script)],
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
+_tpurun = functools.partial(tpurun, timeout=240)
 
 
 class TestMultiprocessTopo:

@@ -3,7 +3,6 @@ concurrency, Chrome-JSON schema validity, and the tpurun gather/merge +
 skew report on a real multiprocess run."""
 import json
 import os
-import subprocess
 import sys
 import textwrap
 import threading
@@ -13,6 +12,8 @@ import pytest
 
 from ompi_tpu.base.var import registry
 from ompi_tpu.runtime import trace
+
+import launch
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -287,11 +288,11 @@ def test_boot_path_spans_in_merged_timeline(tmp_path):
     """))
     tdir = tmp_path / "traces"
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
          "--mca", "trace_enable", "1", "--mca", "trace_dir", str(tdir),
          sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
+        240, env)
     assert r.returncode == 0, r.stdout + r.stderr
     for rank in range(2):
         p = json.load(open(tdir / f"trace_rank{rank}.json"))
@@ -327,11 +328,11 @@ def test_tpurun_trace_gather_merge_and_skew(tmp_path):
     """))
     tdir = tmp_path / "traces"
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
+    r = launch.run(
         [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "4",
          "--mca", "trace_enable", "1", "--mca", "trace_dir", str(tdir),
          sys.executable, str(script)],
-        capture_output=True, text=True, timeout=240, cwd=REPO, env=env)
+        240, env)
     assert r.returncode == 0, r.stdout + r.stderr
 
     # per-rank Chrome traces

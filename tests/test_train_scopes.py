@@ -9,6 +9,7 @@ recomputation is told from its first forward pass, the update is the
 update; and the scopes move no computation."""
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import re
@@ -30,6 +31,8 @@ from test_smallthinker_train import F32 as SMALLTHINKER
 
 from ompi_tpu.parallel import model, objective, train
 from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+
+import built as once
 from ompi_tpu.runtime import trace
 from ompi_tpu.tools import hlo_same
 
@@ -198,14 +201,28 @@ def test_nothing_is_inherited_through_a_tuple(parsed):
 
 # -- the two model steps, compiled on the CPU at small widths ---------------
 def built(cfg):
+    """A step of this file's own, which has never run (``scopes()``
+    raises, its text is compiled under the checkpoint policy of the
+    moment), and its arguments."""
     mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
     step, place = train.build_train_step(mesh, spec, model=cfg)
     ids = np.random.default_rng(0).integers(
         0, cfg.vocab_rows, (cfg.micro_batch, cfg.seq_len + 2)).astype(
             np.int32)
     n = cfg.seq_len + cfg.n_mtp_here
-    return step, place(train.init_model_params(cfg, 0),
+    return step, place(once.params(cfg, 0),
                        ids[:, :cfg.seq_len], ids[:, 1:1 + n])
+
+
+def run_once(step, args):
+    """``step`` after its first call, with the text it compiled to as
+    ``step.text``: read from what the call left in memory, like
+    ``scopes()``, so the program is compiled once."""
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=a.sharding), args)
+    step(*args)         # the state is donated: the text needs shapes only
+    step.text = step.jitted.lower(*shapes).compile().as_text()
+    return step
 
 
 @pytest.fixture(scope="module")
@@ -214,57 +231,49 @@ def joyai():
     with pytest.raises(RuntimeError, match="has not run"):
         step.scopes()
     before = len(train.scopes_of_built_steps())
-    step(*args)
-    del args            # the donated state: scopes() holds shapes only
+    run_once(step, args)
     return step, step.scopes(), before
 
 
 @pytest.fixture(scope="module")
 def olmoe():
-    step, args = built(OLMOE)
-    step(*args)
+    step = run_once(*built(OLMOE))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def nemotron():
-    step, args = built(NEMOTRON)
-    step(*args)
+    step = run_once(*built(NEMOTRON))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def lfm2():
-    step, args = built(LFM2)
-    step(*args)
+    step = run_once(*built(LFM2))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def qwen3next():
-    step, args = built(QWEN3NEXT)
-    step(*args)
+    step = run_once(*built(QWEN3NEXT))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def smallthinker():
-    step, args = built(SMALLTHINKER)
-    step(*args)
+    step = run_once(*built(SMALLTHINKER))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def keye():
-    step, args = built(KEYE)
-    step(*args)
+    step = run_once(*built(KEYE))
     return step, step.scopes()
 
 
 @pytest.fixture(scope="module")
 def sdar():
-    step, args = built(SDAR)
-    step(*args)
+    step = run_once(*built(SDAR))
     return step, step.scopes()
 
 
@@ -499,12 +508,32 @@ SELECTION = {
 }
 
 
-def routing_by_pass(cfg, kinds_of=ROUTING):
+CONFIGS = dict(joyai=JOYAI, nemotron=NEMOTRON, lfm2=LFM2,
+               qwen3next=QWEN3NEXT, smallthinker=SMALLTHINKER, keye=KEYE,
+               sdar=SDAR)
+
+
+@functools.cache
+def bare_text(cfg):
+    """``cfg``'s step compiled under the bare checkpoint, which keeps
+    nothing: once a process, for the tests of what the layers' own policy
+    keeps."""
+    kept = objective.layer_checkpoint_policy
+    objective.layer_checkpoint_policy = \
+        lambda: jax.checkpoint_policies.nothing_saveable
+    try:
+        step, args = built(cfg)
+        return step.jitted.lower(*args).compile().as_text()
+    finally:
+        objective.layer_checkpoint_policy = kept
+
+
+def routing_by_pass(text, kinds_of=ROUTING):
     """{pass: the kinds of ``kinds_of`` among its instructions} and
-    {pass: its scopes} of ``cfg``'s step compiled here."""
-    step, args = built(cfg)
+    {pass: its scopes} of a step's compiled ``text``: the module
+    fixture's (``step.text``) or ``bare_text``'s."""
     kinds, scopes = {}, {}
-    for line in step.jitted.lower(*args).compile().as_text().splitlines():
+    for line in text.splitlines():
         path = re.search(r'op_name="([^"]*)"', line)
         if " = " not in line or not path:
             continue
@@ -515,15 +544,15 @@ def routing_by_pass(cfg, kinds_of=ROUTING):
     return kinds, scopes
 
 
-@pytest.mark.parametrize("cfg,loop_is_read", [(JOYAI, False),
-                                              (NEMOTRON, True),
-                                              (LFM2, False),
-                                              (QWEN3NEXT, False),
-                                              (SMALLTHINKER, False)],
+@pytest.mark.parametrize("which,loop_is_read", [("joyai", False),
+                                                ("nemotron", True),
+                                                ("lfm2", False),
+                                                ("qwen3next", False),
+                                                ("smallthinker", False)],
                          ids=["joyai", "nemotron", "lfm2", "qwen3next",
                               "smallthinker"])
 def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
-        cfg, loop_is_read, monkeypatch):
+        which, loop_is_read, request):
     """``model_loss``'s checkpoint keeps what an expert block names
     (``experts.CHECKPOINT_KEEPS``): no recomputed instruction is the
     dispatch's sort, the router's top-k, the chosen scores' compares
@@ -532,7 +561,8 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
     held experts' loop; what is not named (attention's projections, a
     mixer) is recomputed as before.  The bare checkpoint recomputes
     every one of them: the patterns see what they are meant to."""
-    kinds, scopes = routing_by_pass(cfg)
+    cfg = CONFIGS[which]
+    kinds, scopes = routing_by_pass(request.getfixturevalue(which)[0].text)
     assert kinds["forward"] == set(ROUTING)
     assert kinds["remat"] == set()
     assert "otpu_attn_proj" in scopes["remat"]
@@ -541,23 +571,21 @@ def test_a_layers_checkpoint_keeps_the_routing_and_nothing_else(
     assert ("otpu_gdn_rule" in scopes["remat"]) == (cfg is QWEN3NEXT)
     # the elementwise rest of the router is recomputed: scores, weights
     assert "otpu_router" in scopes["remat"]
-    monkeypatch.setattr(objective, "layer_checkpoint_policy",
-                        lambda: jax.checkpoint_policies.nothing_saveable)
-    bare, _ = routing_by_pass(cfg)
+    bare, _ = routing_by_pass(bare_text(cfg))
     assert bare["forward"] == set(ROUTING)
     assert bare["remat"] == set(ROUTING) - (
         set() if loop_is_read else {"experts' loop"})
 
 
 def test_a_layers_checkpoint_keeps_the_selection_and_the_losss_gradients(
-        monkeypatch):
+        keye):
     """``model_loss``'s checkpoint keeps a sparse-attention sublayer's
     selection and its alignment loss's rows and gradients
     (``model.CHECKPOINT_KEEPS``): the recomputed pass does not select
     again, and the loss's one pass runs in the forward pass alone (its
     backward rule scales what was kept).  The bare checkpoint recomputes
     both: the patterns see what they are meant to."""
-    kinds, scopes = routing_by_pass(KEYE, SELECTION)
+    kinds, scopes = routing_by_pass(keye[0].text, SELECTION)
     assert kinds["forward"] == set(SELECTION)
     # (the twin's loss makes its gradients by autodiff inside its forward
     # rule, so those products' paths read ``transpose(jvp(..))``: they are
@@ -565,31 +593,25 @@ def test_a_layers_checkpoint_keeps_the_selection_and_the_losss_gradients(
     assert kinds.get("remat", set()) == set()
     assert "the selection's counting" not in kinds.get("backward", set())
     assert {"otpu_attn_proj", "otpu_dsa_index"} <= scopes["remat"]
-    monkeypatch.setattr(objective, "layer_checkpoint_policy",
-                        lambda: jax.checkpoint_policies.nothing_saveable)
-    bare, _ = routing_by_pass(KEYE, SELECTION)
+    bare, _ = routing_by_pass(bare_text(KEYE), SELECTION)
     assert bare["forward"] == bare["remat"] == set(SELECTION)
 
 
-@pytest.mark.parametrize("cfg", [JOYAI, NEMOTRON, LFM2, QWEN3NEXT,
-                                 SMALLTHINKER, KEYE, SDAR],
-                         ids=["joyai", "nemotron", "lfm2", "qwen3next",
-                              "smallthinker", "keye", "sdar"])
-def test_a_layers_checkpoint_keeps_attentions_forward_results(cfg,
-                                                              monkeypatch):
+@pytest.mark.parametrize("which", list(CONFIGS))
+def test_a_layers_checkpoint_keeps_attentions_forward_results(which,
+                                                              request):
     """``model_loss``'s checkpoint keeps causal attention's o and
     logsumexp (``model.CHECKPOINT_KEEPS``): attention's own products are
     in the forward and the backward pass and in no recomputed one, while
     the projections that make q, k and v (which the backward pass reads
     and nothing keeps) are recomputed as before.  The bare checkpoint
     recomputes the products too: the pattern sees what it is meant to."""
-    kinds, scopes = routing_by_pass(cfg, ATTENTION)
+    kinds, scopes = routing_by_pass(request.getfixturevalue(which)[0].text,
+                                    ATTENTION)
     assert kinds["forward"] == kinds["backward"] == set(ATTENTION)
     assert kinds.get("remat", set()) == set()
     assert "otpu_attn_proj" in scopes["remat"]
-    monkeypatch.setattr(objective, "layer_checkpoint_policy",
-                        lambda: jax.checkpoint_policies.nothing_saveable)
-    bare, _ = routing_by_pass(cfg, ATTENTION)
+    bare, _ = routing_by_pass(bare_text(CONFIGS[which]), ATTENTION)
     assert bare["forward"] == bare["remat"] == set(ATTENTION)
 
 

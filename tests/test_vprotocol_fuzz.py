@@ -11,13 +11,12 @@ any payload mis-pairing across the interleaved channels corrupts the
 asymmetric fold immediately.
 """
 import importlib.util
-import os
-import subprocess
-import sys
 from pathlib import Path
 
 import numpy as np
 import pytest
+
+from launch import tpurun
 
 REPO = Path(__file__).resolve().parent.parent
 WORKER = Path(__file__).resolve().parent / "fuzz_replay_worker.py"
@@ -35,17 +34,10 @@ def _mod():
 
 
 def _run(env_extra, mca=(), timeout=180):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("OTPU_RANK", None)
-    env.pop("OTPU_NPROCS", None)
-    env.update(env_extra)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", "2",
-           "--enable-recovery"]
+    extra = ["--enable-recovery"]
     for k, v in mca:
-        cmd += ["--mca", k, v]
-    cmd += [sys.executable, str(WORKER)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+        extra += ["--mca", k, v]
+    return tpurun(2, WORKER, timeout=timeout, extra=extra, env=env_extra)
 
 
 @pytest.mark.parametrize("seed", SEEDS)

@@ -12,11 +12,11 @@ live execution finishes the remaining iterations.  Final states must
 match the failure-free recurrence computed locally.
 """
 import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
+
+import launch
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -48,15 +48,11 @@ ompi_tpu.finalize()
 
 
 def _run(n, script, env_extra, mca=(), timeout=180):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.update(env_extra)
-    cmd = [sys.executable, "-m", "ompi_tpu.tools.tpurun", "-n", str(n),
-           "--enable-recovery"]
+    extra = ["--enable-recovery"]
     for k, v in mca:
-        cmd += ["--mca", k, v]
-    cmd += [sys.executable, str(script)]
-    return subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout, cwd=REPO, env=env)
+        extra += ["--mca", k, v]
+    return launch.tpurun(n, script, timeout=timeout, extra=extra,
+                         env=env_extra)
 
 
 def _expected(niter, n=3):

@@ -11,10 +11,13 @@ import numpy as np
 import pytest
 
 from ompi_tpu.parallel import train
-from ompi_tpu.parallel import xing_reference as ref
+from ompi_tpu.parallel import xing_reference
 
 from test_xing_train import (BENCH, CONFIGS, F32, batch_of, kit_cfg,
                              some_bias)
+import built
+
+ref = built.programs(xing_reference)
 
 CONFIG = os.path.join(CONFIGS, "xing4.0-29b-a4b-train-1chip.json")
 NAMES = train.leaf_names(F32)
@@ -44,7 +47,7 @@ def test_the_kit_names_the_programs_leaves(kit):
     shapes = train.model_param_shapes(F32)
     assert kit.leaf_sizes(cfg) == {
         n: int(np.prod(train._leaf(shapes, p))) for n, p in NAMES}
-    params = train.init_model_params(F32, 0)
+    params = built.params(F32, 0)
     tree = kit.tree_of({n: kit.leaf_of(params, n) for n in kit.leaves(cfg)})
     assert jax.tree.structure(tree) == jax.tree.structure(params)
     assert {n for n, _ in NAMES if not train.is_decayed(n)} == {
@@ -65,7 +68,7 @@ def test_the_kits_copy_is_the_programs_reference(kit):
     the kit's blocked float32 copy against ``parallel/xing_reference.py``
     (that each wrong variant is another model:
     ``tests/test_xing_train.py``'s controls)."""
-    cfg, params = kit_cfg(), train.init_model_params(F32, 3)
+    cfg, params = kit_cfg(), built.params(F32, 3)
     tokens, labels = batch_of(0)
     bias = some_bias()
     (loss, loads), grads = jax.jit(lambda p: ref.grads(

@@ -9,19 +9,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from ompi_tpu.parallel import hyper, objective, train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
 from ompi_tpu.runtime import spc, trace
 
 from test_xing_train import F32, batch_of, some_bias
+import built
 
 HC = ("otpu_hc", "otpu_hc_maps", "otpu_hc_sinkhorn", "otpu_hc_read",
       "otpu_hc_write")
 COUNTERS = ("hc_built", "hc_sweeps_built", "hc_defect_ppm")
 
 
-def run_steps(cfg, params, dp=1, seeds=(0,)):
-    mesh, spec = make_mesh(jax.devices()[:dp], MeshSpec(dp=dp))
-    step, place = train.build_train_step(mesh, spec, model=cfg)
+def run_steps(cfg, params, dp=1, seeds=(0,), fresh=False):
+    step, place = (built.fresh_step if fresh else built.step)(cfg, dp)
     state = None
     for seed in seeds:
         tokens, labels = batch_of(seed)
@@ -38,7 +37,7 @@ def test_the_scopes_are_named_and_nested():
     text = jax.jit(lambda p: objective.model_loss(
         p, tokens, labels, F32, interpret=True, n_global=tokens.size,
         bias=some_bias())[0]).lower(
-            train.init_model_params(F32, 0)).as_text(debug_info=True)
+            built.params(F32, 0)).as_text(debug_info=True)
     paths = [ln for ln in text.splitlines() if "otpu_hc" in ln]
     assert paths
     for inner in HC[1:]:
@@ -63,8 +62,9 @@ def test_the_counters_the_plan_and_two_ranks():
     spc.init()
     assert set(COUNTERS) <= set(spc.counters())
     before = {k: spc.read(k) for k in COUNTERS}
-    params = train.init_model_params(F32, 3)
-    step, state, aux = run_steps(F32, params)
+    params = built.params(F32, 3)
+    # a step of its own: its first call is what feeds the counters
+    step, state, aux = run_steps(F32, params, fresh=True)
     plan = step.plan()
     # 4 layers of two sublayers, 20 sweeps each
     assert (plan["counts"]["hc_built"], plan["counts"]["hc_sweeps_built"]) \

@@ -18,9 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ompi_tpu.parallel import xing_reference as ref
+from ompi_tpu.parallel import xing_reference
 from ompi_tpu.parallel import config, hyper, layers, objective, train
-from ompi_tpu.parallel.mesh import MeshSpec, make_mesh
+
+import built
+
+ref = built.programs(xing_reference)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
@@ -63,7 +66,7 @@ def some_bias(cfg=F32, scale=0.01):
 
 @pytest.fixture(scope="module")
 def params():
-    return train.init_model_params(F32, seed=3)
+    return built.params(F32, 3)
 
 
 @pytest.fixture(scope="module")
@@ -100,8 +103,7 @@ def test_a_steps_parameters_biases_and_loss(params):
     """Through ``build_train_step``: the parameters after the update, the
     biases the sign rule moved and the loss, against the reference's AdamW;
     the path's gates and offsets are not decayed."""
-    mesh, spec = make_mesh(jax.devices()[:1], MeshSpec(dp=1))
-    step, place = train.build_train_step(mesh, spec, model=F32)
+    step, place = built.step(F32)
     batches = [batch_of(0)]
     state, got = None, []
     for tokens, labels in batches:
@@ -287,7 +289,7 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
     those sums is the uncut layer's stream."""
     whole = dataclasses.replace(F32, heads_here=0, experts_here=0,
                                 expert_share=0)
-    full = train.init_model_params(whole, seed=5)
+    full = built.params(whole, 5)
     x = jax.random.normal(jax.random.PRNGKey(11), (2, 32, 4, 64))
     bias = 0.01 * jax.random.normal(jax.random.PRNGKey(12), (8,))
     nope, hv = F32.qk_nope_head_dim + F32.qk_rope_head_dim, F32.v_head_dim
@@ -362,7 +364,7 @@ def test_at_one_stream_nothing_moves(name):
     text = jax.jit(lambda p: objective.model_loss(
         p, jnp.asarray(ids[:, :-2]), jnp.asarray(ids[:, 1:]), cfg,
         interpret=True, n_global=ids[:, :-2].size)[0]).lower(
-            jax.eval_shape(lambda: train.init_model_params(cfg, 0))
+            jax.eval_shape(lambda: built.params(cfg, 0))
     ).as_text(debug_info=True)
     assert "otpu_layers" in text and "otpu_hc" not in text
 
